@@ -27,10 +27,9 @@ def load_newest_metrics(search_dir: str, path: str | None = None,
     load. ``(None, {})`` when nothing parses.
 
     ``rig`` is the CLAIMING rig (default: this hostname): an artifact
-    whose summary carries a DIFFERENT rig tag is skipped, like the
-    cpu-backend rounds — numbers measured on another machine are not
-    a reference this machine's claims or tripwire should reconcile
-    against. Artifacts predating the rig tag (no ``rig`` field) still
+    whose summary carries a DIFFERENT rig tag is skipped — numbers
+    measured on another machine are not a reference this machine's
+    claims or tripwire should reconcile against. Artifacts predating the rig tag (no ``rig`` field) still
     load. An explicit ``path`` always loads verbatim."""
     if rig is None:
         rig = socket.gethostname()
@@ -51,18 +50,11 @@ def load_newest_metrics(search_dir: str, path: str | None = None,
             continue
         if not isinstance(parsed, dict):
             continue
-        if path is None and parsed.get("backend") == "cpu":
-            # a CPU-fallback round (bench._run_cpu_fallback): honest
-            # degraded numbers, but NOT a reference the README claims
-            # or the perf tripwire should reconcile against — fall
-            # through to the newest real-backend artifact (an explicit
-            # --artifact path still loads it)
-            continue
         art_rig = parsed.get("rig")
         if path is None and art_rig is not None and art_rig != rig:
-            # same honesty rule, generalized: a round measured on a
-            # DIFFERENT rig (the summary's rig tag) cannot anchor this
-            # rig's claims — tuned geometry especially is per-rig
+            # a round measured on a DIFFERENT rig (the summary's rig
+            # tag) cannot anchor this rig's claims — tuned geometry
+            # especially is per-rig
             continue
         metrics = parsed.get("all_metrics")
         if not isinstance(metrics, dict):

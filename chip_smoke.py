@@ -1,0 +1,781 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process drives the main path once, through the entry points a user
+would call, at the sizes the bench runs (depth cut, weights random from
+a seed), on every TPU device it finds:
+
+  * trainer: SSGD logistic regression, 2^20 rows x (125 features + bias)
+    packed 128 wide, bf16, minibatch 0.1, one 1500-step segment through
+    ``ssgd.train`` — the megakernel on one data shard, the block-gather
+    kernel with its per-step psum on more — checked against the XLA
+    Bernoulli trainer on the same rows; ``tda ssgd`` itself on the
+    breast-cancer set against the reference band;
+  * trainer -> server: ``tda als`` at 4096 x 16384 rank 64 writes an
+    artifact, ``tda serve`` answers 64 requests from it through the
+    compiled fused matmul+top-k kernel, checked against
+    ``xla_matmul_topk`` on the same factors;
+  * kernel roll-call: every ``pallas_call`` a default TPU path selects,
+    compiled at its bench geometry and compared with its in-repo XLA
+    reference at the tolerance stated beside each check;
+  * more than one device: the int8 / bucketed gradient-sync rings, and
+    the shard-per-device + memory-in-use assertions.
+
+Every stage runs even after one fails. Exit code 0 and a last stdout
+line ``{"ok": true, "device": {...}}`` only when every stage passed and
+no kernel was built with ``interpret=True``. With no TPU (including
+``JAX_PLATFORMS=cpu``) it exits 2 before any stage and prints no
+result. The full log (tracebacks, captured CLI output) goes to
+``chiprun_out/chip_smoke_<n>dev.log``.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import functools
+import glob
+import io
+import json
+import os
+import re
+import shutil
+import sys
+import tempfile
+import time
+import traceback
+
+REFERENCE_BAND = 0.92       # BASELINE.md: SSGD/LR final acc 0.93-0.94
+N_ROWS = 1 << 20            # bench.py: N_ROWS / N_FEATURES / N_STEPS
+N_FEATURES = 125
+N_STEPS = 1500
+GATHER_BLOCK_ROWS = 8192
+N_TEST = 16384              # held-out rows from the same generator
+ALS_M, ALS_N, ALS_K = 4096, 16384, 64   # BENCH_r04's ALS size
+ATTN_SEQ, ATTN_HEADS, ATTN_DIM = 32768, 8, 128
+PR_VERTICES, PR_AVG_DEGREE = 1_000_000, 8.0
+
+
+class Spy:
+    """What the harness observes about a stage without changing it:
+    every ``pallas_call`` built (name, interpret), every array the
+    partition engine placed (by leaf name), compile seconds and
+    persistent-cache hits/misses from ``jax.monitoring``."""
+
+    def __init__(self):
+        self.kernels: list[tuple[str, bool]] = []
+        self.built: dict[str, set] = {}
+        self.placed: dict[str, object] = {}
+        self.compile_s = 0.0
+        self.hits = 0
+        self.misses = 0
+
+    def install(self):
+        import jax
+        from jax.experimental import pallas as pl
+
+        from tpu_distalg.parallel import partition
+
+        real_call = pl.pallas_call
+
+        def pallas_call(kernel, *a, **kw):
+            fn = getattr(kernel, "func", kernel)
+            name = (f"{getattr(fn, '__module__', '?').rsplit('.', 1)[-1]}"
+                    f".{getattr(fn, '__name__', repr(fn))}")
+            interp = bool(kw.get("interpret", False))
+            self.kernels.append((name, interp))
+            self.built.setdefault(name, set()).add(interp)
+            return real_call(kernel, *a, **kw)
+
+        pl.pallas_call = pallas_call
+
+        real_put, real_place, real_reshard = (
+            partition.put, partition.place, partition.reshard)
+
+        def put(x, leaf_name, tbl, mesh):
+            out = real_put(x, leaf_name, tbl, mesh)
+            self.placed[leaf_name] = out
+            return out
+
+        def place(tree, tbl, mesh):
+            out = real_place(tree, tbl, mesh)
+            self.placed.update(partition.named_leaves(out))
+            return out
+
+        def reshard(tree, *a, **kw):
+            out = real_reshard(tree, *a, **kw)
+            self.placed.update(partition.named_leaves(out))
+            return out
+
+        partition.put, partition.place, partition.reshard = (
+            put, place, reshard)
+
+        def on_duration(name, secs, **_):
+            if name == "/jax/core/compile/backend_compile_duration":
+                self.compile_s += secs
+
+        def on_event(name, **_):
+            if name == "/jax/compilation_cache/cache_hits":
+                self.hits += 1
+            elif name == "/jax/compilation_cache/cache_misses":
+                self.misses += 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+
+    def begin_stage(self):
+        self.kernels = []
+        return self.compile_s, self.hits, self.misses
+
+
+class Smoke:
+    def __init__(self, log_path: str, workdir: str):
+        import jax
+
+        self.devices = jax.devices()
+        self.n = len(self.devices)
+        self.spy = Spy()
+        self.workdir = workdir
+        self.tel_dir = os.path.join(workdir, "telemetry")
+        self.log = open(log_path, "w")
+        self.results: list[tuple[str, bool]] = []
+        self.shared: dict = {}
+
+    # ---- output ------------------------------------------------------
+
+    def note(self, msg: str):
+        """Log file only: captured CLI output, tracebacks."""
+        self.log.write(msg + "\n")
+        self.log.flush()
+
+    def say(self, msg: str):
+        print(msg, flush=True)
+        self.note(msg)
+
+    # ---- the entry points a user would call --------------------------
+
+    def cli(self, argv: list[str]) -> str:
+        """``tda <argv>`` in this process; returns its stdout. A
+        non-zero exit is a stage failure."""
+        from tpu_distalg import cli
+
+        buf = io.StringIO()
+        with contextlib.redirect_stdout(buf):
+            rc = cli.main(argv)
+        out = buf.getvalue()
+        self.note(f"$ tda {' '.join(argv)}  -> rc {rc}\n{out}")
+        if rc != 0:
+            raise AssertionError(f"tda {' '.join(argv)} exited {rc}")
+        return out
+
+    def mesh(self, data=None, model: int = 1):
+        from tpu_distalg.parallel import get_mesh
+
+        return get_mesh(data=data, model=model)
+
+    # ---- per-stage assertions ----------------------------------------
+
+    def _device_events(self, since: float) -> list[dict]:
+        evs = []
+        for path in glob.glob(os.path.join(self.tel_dir, "*.jsonl")):
+            with open(path) as f:
+                for line in f:
+                    try:
+                        e = json.loads(line)
+                    except ValueError:
+                        continue  # a line still being written
+                    if e.get("ev") == "device" \
+                            and e.get("t_mono", 0.0) >= since:
+                        evs.append(e)
+        return evs
+
+    def check_sharded(self, name: str, arr, *, replicated=False):
+        """One shard per device, all equal; 1/n of the global size
+        unless the rule table replicates the leaf."""
+        shards = arr.addressable_shards
+        devs = {s.device for s in shards}
+        if len(shards) != self.n or len(devs) != self.n:
+            raise AssertionError(
+                f"{name}: {len(shards)} shard(s) on {len(devs)} "
+                f"device(s), want one on each of {self.n}")
+        sizes = {int(s.data.size) for s in shards}
+        want = int(arr.size) if replicated else int(arr.size) // self.n
+        if sizes != {want}:
+            raise AssertionError(
+                f"{name}: shard sizes {sorted(sizes)} of global "
+                f"{arr.size}, want {want} each")
+        return (f"{name}: {self.n} x {want} "
+                f"({'replicated' if replicated else '1/n'})")
+
+    def check_memory_everywhere(self):
+        used = []
+        for d in self.devices:
+            stats = d.memory_stats() or {}
+            used.append(int(stats.get("bytes_in_use", 0)))
+        if not all(u > 0 for u in used):
+            raise AssertionError(
+                f"bytes_in_use per device {used}: a device holds "
+                f"nothing")
+        return f"bytes_in_use/device: {used}"
+
+    # ---- the runner --------------------------------------------------
+
+    def stage(self, name: str, fn, *, kernels=(), min_devices: int = 1):
+        if self.n < min_devices:
+            self.say(f"[stage] {name}: not applicable "
+                     f"(needs >= {min_devices} devices, have {self.n})")
+            return
+        from tpu_distalg import telemetry
+
+        # library calls outside cli.main log here; cli.main reopens the
+        # same directory itself (TDA_TELEMETRY_DIR)
+        telemetry.configure(self.tel_dir)
+        c0, h0, m0 = self.spy.begin_stage()
+        t_mono = time.monotonic()
+        t0 = time.perf_counter()
+        detail, err = "", None
+        try:
+            detail = fn() or ""
+            interp = sorted({k for k, i in self.spy.kernels if i})
+            if interp:
+                raise AssertionError(
+                    f"kernel(s) built with interpret=True: {interp}")
+            missing = [k for k in kernels
+                       if False not in self.spy.built.get(k, ())]
+            if missing:
+                raise AssertionError(
+                    f"expected compiled kernel(s) never built: "
+                    f"{missing} (built so far: "
+                    f"{sorted(self.spy.built)})")
+            bad = [e for e in self._device_events(t_mono)
+                   if e.get("pallas") != "compiled"
+                   or e.get("platform") != "tpu"]
+            if bad:
+                raise AssertionError(
+                    f"device mark says {bad[0].get('platform')}/"
+                    f"{bad[0].get('pallas')}")
+        except Exception as e:  # noqa: BLE001 — recorded, next stage runs
+            err = e
+            tb = traceback.format_exc()
+            sys.stderr.write(tb)
+            self.note(tb)
+        wall = time.perf_counter() - t0
+        comp = self.spy.compile_s - c0
+        built = sorted({k for k, _ in self.spy.kernels})
+        self.results.append((name, err is None))
+        self.say(
+            f"[stage] {name}: {'ok' if err is None else 'FAILED'} "
+            f"wall={wall:.1f}s compile={comp:.1f}s "
+            f"cache={self.spy.hits - h0}hit/{self.spy.misses - m0}miss "
+            f"kernels={built} "
+            + (detail if err is None
+               else f"{type(err).__name__}: {str(err)[:300]}"))
+        self.spy.placed = {}   # drop the stage's arrays
+
+
+# ---------------------------------------------------------------- stages
+
+
+def _bench_rows(s: Smoke):
+    """The bench's SSGD rows (+ a held-out tail from the same seed),
+    generated once and shared by the stages that train on them."""
+    if "rows" not in s.shared:
+        from tpu_distalg.utils import datasets
+
+        X, y = datasets.synthetic_two_class(
+            N_ROWS + N_TEST, N_FEATURES, seed=0)
+        X = datasets.add_bias_column(X)
+        s.shared["rows"] = (X[:N_ROWS], y[:N_ROWS],
+                            X[N_ROWS:], y[N_ROWS:])
+    return s.shared["rows"]
+
+
+def _held_out_acc(s: Smoke, w) -> float:
+    """Held-out accuracy of trained weights under the reference's
+    decision rule (utils/metrics.binary_accuracy), on the host."""
+    import numpy as np
+
+    _, _, X_te, y_te = _bench_rows(s)
+    w = np.asarray(w, np.float32)
+    if w.shape != (N_FEATURES + 1,) or not np.isfinite(w).all():
+        raise AssertionError(
+            f"weights {w.shape}, finite={bool(np.isfinite(w).all())}")
+    pred = np.where(X_te @ w < 0.0, 0.0, 1.0)
+    return float((pred == y_te).mean())
+
+
+def _xla_reference_acc(s: Smoke) -> float:
+    """The XLA Bernoulli trainer (f32, no Pallas) on the same rows and
+    schedule — the in-repo reference the kernel trainers are held to."""
+    if "xla_acc" not in s.shared:
+        from tpu_distalg.models import ssgd
+
+        res = ssgd.train(
+            *_bench_rows(s), s.mesh(),
+            ssgd.SSGDConfig(n_iterations=N_STEPS, eval_test=False,
+                            init_seed=7))
+        s.shared["xla_acc"] = _held_out_acc(s, res.w)
+    return s.shared["xla_acc"]
+
+
+def _acc_check(s: Smoke, name: str, w, tol: float = 0.03) -> str:
+    acc, ref = _held_out_acc(s, w), _xla_reference_acc(s)
+    if abs(acc - ref) > tol:
+        raise AssertionError(
+            f"{name} held-out acc {acc:.4f} vs XLA reference "
+            f"{ref:.4f} (tolerance {tol})")
+    return f"{name} acc {acc:.4f} (xla ref {ref:.4f}, tol {tol})"
+
+
+def stage_ssgd_flagship(s: Smoke):
+    """bench.py's flagship cell through ssgd.train; sampler chosen by
+    the mesh exactly as bench._bench_ssgd does."""
+    from tpu_distalg.models import ssgd
+
+    mesh = s.mesh()
+    sampler, kern = (("fused_train", "_train_kernel_gathered")
+                     if s.n == 1 else
+                     ("fused_gather", "_grad_kernel_gathered"))
+    cfg = ssgd.SSGDConfig(
+        n_iterations=N_STEPS, eval_test=False, x_dtype="bfloat16",
+        sampler=sampler, gather_block_rows=GATHER_BLOCK_ROWS,
+        shuffle_seed=0, init_seed=7)
+    res = ssgd.train(*_bench_rows(s), mesh, cfg)
+    if (f"pallas_kernels.{kern}", False) not in s.spy.kernels:
+        raise AssertionError(
+            f"{sampler} did not build {kern}: {s.spy.kernels}")
+    out = [_acc_check(s, sampler, res.w)]
+    if s.n > 1:
+        out.append(s.check_sharded("X2", s.spy.placed["X2"]))
+        out.append(s.check_memory_everywhere())
+    return " | ".join(out)
+
+
+def _cli_ssgd(s: Smoke, sampler: str):
+    """``tda ssgd`` on its hard-wired 398-row breast-cancer set, at the
+    block geometry the repo's own on-chip convergence checks use
+    (tests_tpu, bench convergence_acc_*) and on ONE data shard: the
+    reference band is a property of that geometry — at the CLI's
+    default 1024-row blocks the set is a single block (full-batch GD,
+    0.8947 on the CPU interpreter), and split four ways its ~100 rows
+    per shard leave block sampling too coarse to hold the band (0.883
+    there). dp>1 is covered by the flagship stage."""
+    out = s.cli(["ssgd", "--sampler", sampler, "--n-iterations", "1500",
+                 "--fused-pack", "4", "--gather-block-rows", "32",
+                 "--shuffle-seed", "0", "--n-slices", "1", "--quiet"])
+    acc = float(re.search(r"Final acc: ([0-9.]+)", out).group(1))
+    if acc < REFERENCE_BAND:
+        raise AssertionError(
+            f"tda ssgd --sampler {sampler}: final acc {acc} below the "
+            f"reference band {REFERENCE_BAND}")
+    return f"breast-cancer final acc {acc:.4f} (band >= {REFERENCE_BAND})"
+
+
+def stage_cli_ssgd_fused_train(s: Smoke):
+    return _cli_ssgd(s, "fused_train")
+
+
+def stage_cli_ssgd_fused_gather(s: Smoke):
+    return _cli_ssgd(s, "fused_gather")
+
+
+def _topk_against_xla(s: Smoke, art: str, model_slices: int):
+    """The served (fused-kernel) top-k vs ``xla_matmul_topk`` on the
+    same factors, as the server would call either: both score with one
+    default-precision MXU pass (f32 operands rounded to bf16, f32
+    accumulation), so indices must be equal and scores agree to 1e-5.
+    The share of positions a full-f32 (``highest``) scoring would rank
+    the same way is reported, not asserted — that is what the default
+    precision costs, for both paths alike."""
+    import jax
+    import numpy as np
+
+    from tpu_distalg.ops import pallas_topk as pt
+    from tpu_distalg.serve import artifacts
+
+    mesh = s.mesh(data=1, model=model_slices) if model_slices > 1 \
+        else s.mesh()
+    model = artifacts.load_artifact(art, mesh, k_top=10, use_fused=True)
+    if not model.meta["fused"]:
+        raise AssertionError("served model did not pick the fused path")
+    ids = np.random.default_rng(1).integers(
+        0, model.meta["n_users"], size=32).astype(np.int32)
+    got = model.make_predict(32)(list(ids))
+    g_vals = np.stack([v for v, _ in got])
+    g_idx = np.stack([i for _, i in got])
+    _root, state, _step = artifacts.load_artifact_state(art)
+    U, V = state[0], state[1]
+    r_vals, r_idx = (np.asarray(a) for a in pt.xla_matmul_topk(
+        U[ids], V, 0, model.meta["n_items"], k=10))
+    with jax.default_matmul_precision("highest"):
+        h_idx = np.asarray(pt.xla_matmul_topk(
+            U[ids], V, 0, model.meta["n_items"], k=10)[1])
+    same = float((g_idx == r_idx).mean())
+    if same < 1.0:
+        raise AssertionError(
+            f"fused top-k indices equal xla_matmul_topk's in only "
+            f"{same:.4f} of positions (max score diff "
+            f"{np.abs(g_vals - r_vals).max():.3e})")
+    np.testing.assert_allclose(g_vals, r_vals, rtol=1e-5, atol=1e-5)
+    placed = s.spy.placed.get("V")
+    out = [f"fused top-k == xla_matmul_topk (32 queries x 10, scores "
+           f"1e-5; {float((g_idx == h_idx).mean()):.3f} of positions "
+           f"agree with full-f32 scoring)"]
+    if s.n > 1 and model_slices == s.n:
+        out.append(s.check_sharded("V", placed))
+        out.append(s.check_memory_everywhere())
+    return " | ".join(out)
+
+
+def stage_als_train_serve(s: Smoke):
+    art = os.path.join(s.workdir, "als_artifact")
+    shape = ([] if s.n == 1 or s.n % 2
+             else ["--mesh-shape", f"{s.n // 2}x2"])
+    out = s.cli(["als", "--m", str(ALS_M), "--n", str(ALS_N), "--k",
+                 str(ALS_K), "--n-iterations", "5", "--checkpoint-dir",
+                 art, *shape])
+    rmse = [float(x) for x in re.findall(r"rmse: ([0-9.eE+-]+)", out)]
+    # lam=0.01 (the CLI default) floors the fit: rmse falls ~8x in
+    # two sweeps, then creeps up toward the regularized optimum
+    if len(rmse) != 5 or not rmse[-1] < 0.25 * rmse[0]:
+        raise AssertionError(f"als rmse history {rmse}")
+    slices = ["--model-slices", str(s.n)] if s.n > 1 else []
+    out = s.cli(["serve", "--artifact", art, "--requests", "64",
+                 "--max-batch", "32", "--k-top", "10", *slices])
+    if "64/64 replies" not in out:
+        raise AssertionError(
+            f"serve did not answer all 64: "
+            f"{re.findall(r'[0-9]+/[0-9]+ replies', out)}")
+    return (f"rmse {rmse[0]:.4f}->{rmse[-1]:.4f} | serve 64/64 | "
+            + _topk_against_xla(s, art, s.n))
+
+
+def stage_flash_attention(s: Smoke):
+    """Causal flash attention forward and backward at the bench
+    geometry (32k x 8 heads x 128, bf16), ring over all devices.
+    Forward vs the XLA online-softmax ring at the same geometry
+    (2e-2, bf16 outputs). Backward at 32k: finite, and dQ of the first
+    2048 positions vs the XLA backward of that causal prefix (a causal
+    row's dQ depends on nothing after it); the full dQ/dK/dV vs the XLA
+    backward at 2048 tokens per device (1e-2 relative, the bound
+    tests_tpu holds)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import PartitionSpec as P
+
+    from tpu_distalg.parallel import DATA_AXIS, data_parallel
+    from tpu_distalg.parallel.ring import ring_attention
+    from tpu_distalg.utils import prng
+
+    mesh = s.mesh()
+    S, H, d = ATTN_SEQ, ATTN_HEADS, ATTN_DIM
+    key = prng.root_key(3)
+
+    def qkv(n_tok, dtype):
+        return tuple(
+            jax.random.normal(jax.random.fold_in(key, i), (n_tok, H, d),
+                              dtype) for i in range(3))
+
+    def attn(m, **kw):
+        return data_parallel(
+            functools.partial(ring_attention, causal=True, **kw), m,
+            in_specs=(P(DATA_AXIS, None, None),) * 3,
+            out_specs=P(DATA_AXIS, None, None))
+
+    def grads(f, *a):
+        loss = lambda q, k, v: jnp.sum(  # noqa: E731
+            f(q, k, v).astype(jnp.float32) ** 2)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(*a)
+
+    q, k, v = qkv(S, jnp.bfloat16)
+    flash = np.asarray(jax.jit(attn(mesh, use_flash=True))(q, k, v),
+                       np.float32)
+    xla = np.asarray(jax.jit(attn(mesh, kv_chunk=2048))(q, k, v),
+                     np.float32)
+    np.testing.assert_allclose(flash, xla, rtol=2e-2, atol=2e-2)
+    dq, dk, dv = grads(attn(mesh, use_flash=True), q, k, v)
+    for name, g in (("dq", dq), ("dk", dk), ("dv", dv)):
+        if not bool(jnp.isfinite(g.astype(jnp.float32)).all()):
+            raise AssertionError(f"32k flash backward: {name} not finite")
+    pre = 2048
+    one = s.mesh(data=1)
+    dq_ref = grads(attn(one, kv_chunk=1024),
+                   q[:pre], k[:pre], v[:pre])[0]
+    a = np.asarray(dq[:pre], np.float32)
+    b = np.asarray(dq_ref, np.float32)
+    rel_pre = float(np.abs(a - b).max() / np.abs(b).max())
+    if rel_pre > 2e-2:
+        raise AssertionError(
+            f"32k flash dQ[:2048] vs XLA prefix backward: rel {rel_pre}")
+    qs, ks, vs = qkv(2048 * s.n, jnp.float32)
+    gf = grads(attn(mesh, use_flash=True), qs, ks, vs)
+    gx = grads(attn(mesh, kv_chunk=1024), qs, ks, vs)
+    rels = []
+    for a, b in zip(gf, gx):
+        a, b = np.asarray(a), np.asarray(b)
+        rels.append(float(np.abs(a - b).max() / np.abs(b).max()))
+    if max(rels) > 1e-2:
+        raise AssertionError(f"flash-vs-xla grad rel errs {rels}")
+    return (f"fwd 32k vs xla 2e-2 ok | bwd 32k finite, dQ prefix rel "
+            f"{rel_pre:.1e} | bwd {2048 * s.n} tok rel "
+            f"{max(rels):.1e} (<1e-2)")
+
+
+def stage_pagerank(s: Smoke):
+    """1M vertices / 8M edges, 3 sweeps under each scatter NAMED
+    explicitly (``auto`` degrades spmv -> hybrid -> XLA without saying
+    so): spmv and pallas vs the XLA sweep at 1e-5 relative (the bound
+    tests_tpu holds at 200k vertices)."""
+    import numpy as np
+
+    from tpu_distalg.models import pagerank
+    from tpu_distalg.utils import datasets
+
+    t0 = time.perf_counter()
+    edges = datasets.erdos_renyi_edges(PR_VERTICES, PR_AVG_DEGREE,
+                                       seed=1)
+    t_gen = time.perf_counter() - t0
+    mesh = s.mesh()
+    ranks, walls = {}, {}
+    keep = {}
+    for sc in ("spmv", "pallas", "xla"):
+        t0 = time.perf_counter()
+        res = pagerank.run(
+            edges, mesh,
+            pagerank.PageRankConfig(n_iterations=3, mode="standard",
+                                    scatter=sc),
+            n_vertices=PR_VERTICES)
+        ranks[sc] = np.asarray(res.ranks)
+        walls[sc] = time.perf_counter() - t0
+        if sc == "spmv":
+            keep = {"ranks": res.ranks,
+                    "src_lane": s.spy.placed.get("src_lane")}
+    rel = {sc: float(np.abs(ranks[sc] - ranks["xla"]).max()
+                     / ranks["xla"].max()) for sc in ("spmv", "pallas")}
+    if max(rel.values()) > 1e-5 or not np.isfinite(ranks["xla"]).all():
+        raise AssertionError(f"ranks vs xla sweep: rel errs {rel}")
+    out = [f"rel vs xla {rel} | host+device wall/scatter "
+           + ", ".join(f"{k} {v:.1f}s" for k, v in walls.items())
+           + f" (edge synthesis {t_gen:.1f}s)"]
+    if s.n > 1:
+        # the 'pagerank' rule table replicates the rank vector (the
+        # sweep's all-reduce owns combination) and shards the edge plan
+        out.append(s.check_sharded("ranks", keep["ranks"],
+                                   replicated=True))
+        out.append(s.check_sharded("src_lane", keep["src_lane"]))
+        out.append(s.check_memory_everywhere())
+    return " | ".join(out)
+
+
+def stage_local_sgd_megakernel(s: Smoke):
+    """MA with megakernel local rounds at bench._bench_local_sgd's
+    geometry (300 rounds x 5 local steps over the bench rows), vs the
+    XLA trainer's held-out accuracy; and on breast-cancer against the
+    reference MA golden 0.8538 (ma.py:131)."""
+    import warnings
+
+    from tpu_distalg.models import ma
+    from tpu_distalg.utils import datasets
+
+    mesh = s.mesh()
+    res = ma.train(*_bench_rows(s), mesh, ma.MAConfig(
+        n_iterations=300, n_local_iterations=5, sampler="fused_train",
+        x_dtype="bfloat16", gather_block_rows=GATHER_BLOCK_ROWS,
+        shuffle_seed=0, eval_test=False))
+    out = [_acc_check(s, "ma/fused_train", res.w, tol=0.05)]
+    with warnings.catch_warnings():
+        warnings.filterwarnings("ignore", message="fused_gather:")
+        acc = ma.train(*datasets.breast_cancer_split(), mesh, ma.MAConfig(
+            n_iterations=300, sampler="fused_train",
+            gather_block_rows=64, fused_pack=4,
+            shuffle_seed=0)).final_acc
+    if acc < 0.85:
+        raise AssertionError(f"ma breast-cancer acc {acc} < 0.85")
+    out.append(f"breast-cancer acc {acc:.4f} (>= 0.85)")
+    return " | ".join(out)
+
+
+def stage_v3_sampler(s: Smoke):
+    """The on-core-PRNG streaming kernel (no interpret lowering exists,
+    so only the chip can say): the bench rows for 300 steps vs the XLA
+    trainer, and breast-cancer against the reference band."""
+    from tpu_distalg.models import ssgd
+    from tpu_distalg.utils import datasets
+
+    mesh = s.mesh()
+    res = ssgd.train(*_bench_rows(s), mesh, ssgd.SSGDConfig(
+        n_iterations=300, eval_test=False, sampler="fused",
+        x_dtype="bfloat16", init_seed=7))
+    out = [_acc_check(s, "fused(v3) 300 steps", res.w, tol=0.05)]
+    acc = ssgd.train(*datasets.breast_cancer_split(), mesh,
+                     ssgd.SSGDConfig(n_iterations=1500,
+                                     sampler="fused")).final_acc
+    if acc < REFERENCE_BAND:
+        raise AssertionError(f"fused(v3) breast-cancer acc {acc}")
+    out.append(f"breast-cancer acc {acc:.4f} (band >= {REFERENCE_BAND})")
+    return " | ".join(out)
+
+
+def stage_kmeans_kernel(s: Smoke):
+    """ops/pallas_kmeans.fused_cluster_stats vs ops/kmeans on a small
+    shape, under the kernel's documented contract: distance dots at
+    default MXU precision (f32 operands rounded to bf16), distances
+    compared on the bf16 grid, first-minimum tie-break; stats exact.
+    The reference assignment applies the same roundings in XLA; a
+    point whose two nearest centers then tie to within f32 summation
+    order may still land on either (at most 0.1% of points), and the
+    sums are held to 1e-5 plus what those points can carry."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_distalg.ops import kmeans as kops
+    from tpu_distalg.ops import pallas_kmeans as pk
+
+    rng = np.random.default_rng(0)
+    n, dim, k = 8192, 16, 8
+    pts = (rng.normal(size=(n, dim)) * 3).astype(np.float32)
+    mask = np.ones(n, np.float32)
+    mask[-n // 10:] = 0.0
+    centers = (rng.normal(size=(k, dim)) * 3).astype(np.float32)
+    X2, m2 = pk.pack_points(pts, mask, dim=dim, k=k)
+    sums, counts = pk.fused_cluster_stats(
+        X2, m2, jnp.asarray(centers), dim=dim, k=k)
+    p, c = jnp.asarray(pts), jnp.asarray(centers)
+
+    def bf16(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+    d2 = (jnp.sum(bf16(p * p), axis=1, keepdims=True)
+          - 2.0 * jnp.einsum("nd,kd->nk", bf16(p), bf16(c),
+                             precision="highest")
+          + jnp.sum(c * c, axis=1)[None, :])
+    assign = jnp.argmin(bf16(d2), axis=1)
+    s_ref, c_ref = kops.cluster_stats(p, jnp.asarray(mask), assign, k)
+    moved = float(np.abs(np.asarray(counts) - np.asarray(c_ref)).sum())
+    if moved > 0.001 * n:
+        raise AssertionError(
+            f"cluster counts differ by {moved} points of {n}")
+    np.testing.assert_allclose(
+        np.asarray(sums), np.asarray(s_ref), rtol=1e-5,
+        atol=1e-4 + moved * float(np.abs(pts).max()))
+    return (f"counts differ by {int(moved)} of {n} points (<= 0.1%), "
+            f"sums 1e-5")
+
+
+def _comm_stage(s: Smoke, comm: str):
+    from tpu_distalg.models import ssgd
+
+    res = ssgd.train(*_bench_rows(s), s.mesh(), ssgd.SSGDConfig(
+        n_iterations=300, eval_test=False, x_dtype="bfloat16",
+        sampler="fused_gather", gather_block_rows=GATHER_BLOCK_ROWS,
+        shuffle_seed=0, init_seed=7, comm=comm))
+    return (f"dp={s.n} | "
+            + _acc_check(s, f"fused_gather/{comm} 300 steps", res.w,
+                         tol=0.05))
+
+
+STAGES = (
+    ("ssgd_flagship", stage_ssgd_flagship, {}),  # kernel checked inside
+    ("cli_ssgd_fused_train", stage_cli_ssgd_fused_train,
+     dict(kernels=("pallas_kernels._train_kernel_gathered",))),
+    ("cli_ssgd_fused_gather", stage_cli_ssgd_fused_gather,
+     dict(kernels=("pallas_kernels._grad_kernel_gathered",))),
+    ("als_train_serve", stage_als_train_serve,
+     dict(kernels=("pallas_topk._topk_kernel",))),
+    ("flash_attention_32k", stage_flash_attention,
+     dict(kernels=("pallas_attention._kernel",
+                   "pallas_attention._bwd_dq_kernel",
+                   "pallas_attention._bwd_dkv_kernel"))),
+    ("pagerank_1m", stage_pagerank,
+     dict(kernels=("pallas_pagerank._spmv_kernel",
+                   "pallas_pagerank._kernel"))),
+    ("local_sgd_megakernel", stage_local_sgd_megakernel,
+     dict(kernels=("pallas_kernels._train_kernel_gathered",))),
+    ("v3_on_core_prng_sampler", stage_v3_sampler,
+     dict(kernels=("pallas_kernels._grad_kernel_packed",))),
+    ("kmeans_kernel", stage_kmeans_kernel,
+     dict(kernels=("pallas_kmeans._kernel",))),
+    ("ssgd_comm_int8", functools.partial(_comm_stage, comm="int8"),
+     dict(min_devices=2)),
+    ("ssgd_comm_bucketed",
+     functools.partial(_comm_stage, comm="bucketed"),
+     dict(min_devices=2)),
+)
+
+
+def _versions() -> str:
+    from importlib import metadata
+
+    out = []
+    for pkg in ("jax", "jaxlib", "libtpu"):
+        try:
+            out.append(f"{pkg} {metadata.version(pkg)}")
+        except metadata.PackageNotFoundError:
+            out.append(f"{pkg} (not installed)")
+    return ", ".join(out)
+
+
+def main() -> int:
+    try:
+        import jax
+
+        from tpu_distalg.utils import compile_cache
+    except ImportError as e:
+        print(f"chip_smoke: cannot import the system ({e}); run it from "
+              f"the root of a checkout", file=sys.stderr)
+        return 2
+    backend = jax.default_backend()
+    if backend != "tpu":
+        print(f"chip_smoke: no TPU — the default jax backend is "
+              f"{backend!r} (JAX_PLATFORMS="
+              f"{os.environ.get('JAX_PLATFORMS')!r}); nothing was run",
+              file=sys.stderr)
+        return 2
+    cache_dir = compile_cache.configure()
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    os.makedirs("chiprun_out", exist_ok=True)
+    workdir = tempfile.mkdtemp(prefix="chip_smoke_")
+    os.environ["TDA_TELEMETRY_DIR"] = os.path.join(workdir, "telemetry")
+    s = Smoke(os.path.join(
+        "chiprun_out", f"chip_smoke_{device['count']}dev.log"), workdir)
+    try:
+        s.say(f"chip_smoke: {_versions()}, python "
+              f"{sys.version.split()[0]}")
+        s.say(f"chip_smoke: platform: {device['platform']}, device_kind: "
+              f"{device['kind']}, devices: {device['count']}")
+        n_cached = len(os.listdir(cache_dir)) \
+            if os.path.isdir(cache_dir) else 0
+        s.say(f"chip_smoke: compile cache: {cache_dir} "
+              f"({n_cached} entries at start -> "
+              f"{'warm' if n_cached else 'cold'} run)")
+        s.spy.install()
+        t0 = time.perf_counter()
+        for name, fn, opts in STAGES:
+            s.stage(name, functools.partial(fn, s), **opts)
+        wall = time.perf_counter() - t0
+        failed = [n for n, ok in s.results if not ok]
+        s.say(f"chip_smoke: {len(s.results) - len(failed)}/"
+              f"{len(s.results)} stages ok in {wall:.0f}s; compile "
+              f"{s.spy.compile_s:.1f}s "
+              f"({'warm' if n_cached else 'cold'}: {s.spy.hits} cache "
+              f"hit(s), {s.spy.misses} miss(es)); kernels compiled: "
+              f"{sorted(k for k, v in s.spy.built.items() if False in v)}"
+              + (f"; FAILED: {failed}" if failed else ""))
+        if failed:
+            print(json.dumps({"ok": False, "failed": failed,
+                              "device": device}), flush=True)
+            return 1
+        print(json.dumps({"ok": True, "device": device}), flush=True)
+        return 0
+    finally:
+        from tpu_distalg import telemetry
+
+        telemetry.configure(False)
+        s.log.close()
+        shutil.rmtree(workdir, ignore_errors=True)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 
 from tpu_distalg.models import ssgd
 from tpu_distalg.ops import logistic
-from tpu_distalg.parallel import get_mesh, parallelize
+from tpu_distalg.parallel import get_mesh, mesh_on_tpu, parallelize
 from tpu_distalg.utils import datasets, prng, profiling
 
 N_ROWS = 1 << 20
@@ -43,7 +43,7 @@ def probe(name, config):
 def probe_fused(name, config):
     """Fused-sampler probe via ssgd.prepare_fused (the bench.py path)."""
     mesh = get_mesh()
-    if next(iter(mesh.devices.flat)).platform != "tpu":
+    if not mesh_on_tpu(mesh):
         print(f"{name:30s}       skip (needs TPU)", flush=True)
         return
     X, y = _data()

@@ -1278,7 +1278,6 @@ def test_tda080_negative_engine_and_program_specs():
     from jax.sharding import PartitionSpec as P
 
     from tpu_distalg.parallel import partition
-    from tpu_distalg.parallel.compat import shard_map
 
     def place(x, mesh, rows):
         a = partition.put(x, "w", "ssgd", mesh)
@@ -1286,8 +1285,8 @@ def test_tda080_negative_engine_and_program_specs():
             x, partition.leaf_sharding("ssgd", "X2", mesh))
         c = jax.device_put(x)          # bare staging: no layout
         d = lax.with_sharding_constraint(x, rows)  # engine-bound name
-        f = shard_map(lambda v: v, mesh,
-                      in_specs=(P("data"),), out_specs=P())
+        f = jax.shard_map(lambda v: v, mesh=mesh,
+                          in_specs=(P("data"),), out_specs=P())
         return a, b, c, d, f
     """
     assert lint(clean, path=MODEL) == []

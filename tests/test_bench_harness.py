@@ -1,16 +1,14 @@
-"""Bench harness failure modes: a dead backend must still produce an
-honest artifact (all-metrics summary line + non-zero exit), never a
-silent empty run (r4 verdict: two rounds of headline numbers
-evaporated from the recorded tail)."""
+"""Bench harness failure modes: with no chip the bench measures
+nothing and says so — a non-zero exit and NO metric line (a CPU timing
+or a zero under a device metric's name would read as a measurement);
+a failed phase is recorded, the phases after it still run, and the
+exit code is non-zero."""
 
 import json
 
 
-def test_backend_init_failure_emits_summary_and_fails(monkeypatch,
-                                                      capsys):
-    """Backend dead AND the CPU fallback's own mesh build failing (the
-    same dead get_mesh) still leaves an honest zeroed summary + rc 2 —
-    the pre-fallback contract is the floor, never lost."""
+def test_backend_init_failure_prints_no_metric_and_fails(monkeypatch,
+                                                         capsys):
     import bench
     from tpu_distalg import parallel
 
@@ -18,72 +16,101 @@ def test_backend_init_failure_emits_summary_and_fails(monkeypatch,
 
     def dead_mesh(*a, **k):
         calls["n"] += 1
-        raise RuntimeError("UNAVAILABLE: tunnel down (test)")
+        raise RuntimeError("UNAVAILABLE: backend down (test)")
 
     monkeypatch.setattr(parallel, "get_mesh", dead_mesh)
     monkeypatch.setattr(bench, "INIT_RETRY_ATTEMPTS", 3)
     monkeypatch.setattr(bench, "INIT_RETRY_SECONDS", 0)
     monkeypatch.setattr(bench, "_SUMMARY", {})
-    monkeypatch.setattr(bench, "_BACKEND_TAG", None)
 
     rc = bench.main([])
     assert rc == 2
-    # 3 supervised init attempts, then the CPU fallback's own attempt
-    assert calls["n"] == 4
+    assert calls["n"] == 3     # the supervised attempts, no fallback one
     out = capsys.readouterr()
-    last = json.loads(out.out.strip().splitlines()[-1])
-    # the driver-schema flagship line with the all-metrics map, zeroed
-    assert last["metric"] == "ssgd_lr_steps_per_sec_per_chip"
-    assert last["value"] == 0.0
-    assert "all_metrics" in last
-    assert last["backend"] == "cpu"
+    assert out.out.strip() == ""          # no metric line, no summary
     assert "backend init failed (attempt 3/3)" in out.err
+    assert "no backend, no metrics" in out.err
 
 
-def test_cpu_fallback_tier_emits_full_metric_set(monkeypatch, capsys):
-    """The ROADMAP hygiene rider, unit-tested: with the backend down,
-    the CPU tier emits EVERY canonical metric line — measured on host
-    devices where feasible, skipped-with-zero where TPU-only — all
-    tagged ``backend: cpu``, and the summary carries the tag so
-    bench_artifacts will not serve this round as the claims/tripwire
-    reference."""
+def test_cpu_mesh_prints_no_metric_and_fails(monkeypatch, capsys):
+    """JAX_PLATFORMS=cpu (what this suite runs under) yields a mesh,
+    but the bench refuses it: no phase runs, nothing is printed."""
     import bench
 
+    ran = []
+    monkeypatch.setattr(bench, "_bench_ssgd",
+                        lambda *a, **k: ran.append("ssgd"))
     monkeypatch.setattr(bench, "_SUMMARY", {})
-    monkeypatch.setattr(bench, "_LINES", [])
-    monkeypatch.setattr(bench, "_BACKEND_TAG", None)
+    rc = bench.main([])
+    assert rc == 2 and ran == []
+    out = capsys.readouterr()
+    assert out.out.strip() == ""
+    assert "no TPU" in out.err
 
-    rc = bench._run_cpu_fallback("UNAVAILABLE (test)", fast=True)
-    assert rc == 2
-    out = capsys.readouterr().out
-    lines = [json.loads(ln) for ln in out.strip().splitlines()]
-    by_metric = {}
-    for ln in lines[:-1]:
-        by_metric.setdefault(ln["metric"], ln)
-    # the full canonical metric set, no round is ever blank again
-    missing = [n for n in bench.ALL_METRIC_NAMES if n not in by_metric]
-    assert not missing, missing
-    assert all(ln.get("backend") == "cpu" for ln in lines[:-1])
-    # measured-where-feasible: the flagship and the comm lines carry
-    # real nonzero values; TPU-only lines are explicit skips
-    assert by_metric["ssgd_lr_steps_per_sec_per_chip"]["value"] > 0
-    assert by_metric["ssgd_comm_int8_bytes_wire_per_sync"]["value"] > 0
-    assert by_metric["ssgd_comm_int8_step_speedup"]["value"] > 0
-    assert "skipped" in by_metric[
-        "ring_attention_128k_tokens_per_sec_per_chip"]
-    # the summary line is tagged and regression-free
-    last = lines[-1]
-    assert last["backend"] == "cpu"
-    assert "all_metrics" in last and "regressions" not in last
-    assert set(bench.ALL_METRIC_NAMES) <= set(last["all_metrics"])
+
+def test_failed_phase_is_recorded_and_makes_exit_nonzero(monkeypatch,
+                                                         capsys):
+    """A phase that raises does not sink the phases after it, and the
+    run's exit code says so; a phase that does not apply to this mesh
+    geometry is a recorded skip, not a failure."""
+    import bench
+
+    monkeypatch.setattr(bench, "_FAILED_PHASES", [])
+    ran = []
+
+    def boom():
+        raise ValueError("kernel refused (test)")
+
+    def skip():
+        raise bench.PhaseNotApplicable("needs 4 devices (test)")
+
+    assert bench._phase("a", boom) is None
+    assert bench._phase("b", skip) is None
+    assert bench._phase("c", lambda: ran.append("c") or 7) == 7
+    assert ran == ["c"]
+    assert bench._FAILED_PHASES == ["a"]
+    err = capsys.readouterr().err
+    assert "phase a FAILED" in err and "kernel refused (test)" in err
+    assert "phase b not applicable" in err
+
+    # _run's exit code follows the failed-phase list
+    from tpu_distalg import parallel
+
+    monkeypatch.setattr(parallel, "mesh_on_tpu", lambda mesh: True)
+    real_phase = bench._phase
+    monkeypatch.setattr(
+        bench, "_phase",
+        lambda name, fn, *a: real_phase(
+            name, boom if name == "pagerank" else (lambda: None)))
+    monkeypatch.setattr(bench, "_SUMMARY", {})
+    assert bench.main([]) == 1
+    assert bench._FAILED_PHASES == ["pagerank"]
+    assert "1 phase(s) failed: pagerank" in capsys.readouterr().err
+
+
+def test_peaks_are_looked_up_by_device_kind(monkeypatch):
+    """A roofline share against another chip's peak is not a
+    measurement: an unknown device_kind is an error, never a default."""
+    import jax
+    import pytest
+
+    import bench
+
+    class _Dev:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    assert bench._device_peaks()["hbm_bytes_per_sec"] == 819e9
+    assert bench._hbm_fraction(819e9, 1.0, 2) == 0.5
+    _Dev.device_kind = "cpu"
+    with pytest.raises(KeyError, match="device_kind 'cpu'"):
+        bench._hbm_fraction(1.0, 1.0, 1)
 
 
 def test_all_metric_names_match_emission_sites():
-    """ALL_METRIC_NAMES is the CPU-fallback tier's contract, but the
-    real emissions live in the phase functions — tie the two together
-    statically so a rename/addition in either place fails loudly
-    instead of rotting into stale skipped-with-zero lines (the exact
-    drift the hygiene rider exists to prevent).
+    """ALL_METRIC_NAMES is the canonical metric set, but the real
+    emissions live in the phase functions — tie the two together
+    statically so a rename/addition in either place fails loudly.
 
     The AST walk that used to live here (and, re-implemented, in
     test_cluster/test_partition) is now the TDA102 collector — ONE
@@ -103,31 +130,8 @@ def test_all_metric_names_match_emission_sites():
         f"(renamed phase metric without updating ALL_METRIC_NAMES?): "
         f"{unemitted}")
     assert not rogue, (
-        f"metric emissions missing from ALL_METRIC_NAMES (the CPU "
-        f"fallback would leave these blank on a dead-backend round): "
+        f"metric emissions missing from ALL_METRIC_NAMES: "
         f"{sorted(rogue)}")
-
-
-def test_artifact_loader_skips_cpu_fallback_rounds(tmp_path):
-    """A cpu-tagged artifact must not become the README-claims /
-    tripwire reference — the loader falls through to the newest real
-    round."""
-    import json as _json
-
-    import bench_artifacts
-
-    (tmp_path / "BENCH_r08.json").write_text(_json.dumps(
-        {"parsed": {"backend": "cpu",
-                    "all_metrics": {"x": 1.0}}}))
-    (tmp_path / "BENCH_r07.json").write_text(_json.dumps(
-        {"parsed": {"all_metrics": {"x": 5.0}}}))
-    ref, metrics = bench_artifacts.load_newest_metrics(str(tmp_path))
-    assert ref == "BENCH_r07.json"
-    assert metrics == {"x": 5.0}
-    # an explicit --artifact path still loads the cpu round
-    ref, metrics = bench_artifacts.load_newest_metrics(
-        str(tmp_path), str(tmp_path / "BENCH_r08.json"))
-    assert ref == "BENCH_r08.json" and metrics == {"x": 1.0}
 
 
 def test_summary_preserves_recorded_metrics():

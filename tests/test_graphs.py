@@ -166,25 +166,10 @@ def test_ingest_native_and_numpy_byte_identical(tmp_path, monkeypatch):
         assert a == b, name
 
 
-def test_stale_library_capability_skip(monkeypatch):
-    """A loaded .so missing an optional symbol must degrade that one
-    entry point to NumPy — never crash the caller."""
-    monkeypatch.setattr(native, "_missing_symbols",
-                        frozenset({"tda_pack_edge_rows"}))
-    assert not native.has_symbol("tda_pack_edge_rows")
-    src = np.array([3, 1], np.int64)
-    dst = np.array([0, 2], np.int64)
-    w = np.array([0.5, 0.25], np.float32)
-    out = native.pack_edge_rows(src, dst, w)
-    assert out.dtype == np.int32 and out.shape == (2, 3)
-    np.testing.assert_array_equal(out[:, 0], [3, 1])
-    np.testing.assert_array_equal(out[:, 1], [0, 2])
-    np.testing.assert_array_equal(out[:, 2].view(np.float32), w)
-
-
 def test_pack_edge_rows_native_matches_numpy():
-    if not native.has_symbol("tda_pack_edge_rows"):
-        pytest.skip("stale/absent library — native path not present")
+    if not native.available():
+        pytest.skip("native library unavailable — native path not "
+                    "present")
     rng = np.random.default_rng(11)
     src = rng.integers(0, 1 << 20, 4097).astype(np.int64)
     dst = rng.integers(0, 1 << 20, 4097).astype(np.int64)

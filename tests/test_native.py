@@ -118,3 +118,42 @@ def test_counting_sort_perm_rejects_out_of_range():
         native.counting_sort_perm(np.array([0, 5, 2]), 4)
     with pytest.raises(ValueError, match="out of range"):
         native.counting_sort_perm(np.array([-1, 0]), 4)
+
+
+def test_loader_ignores_library_not_built_from_this_source(
+        tmp_path, monkeypatch, capsys):
+    """The binary's name vouches for the committed source + flags: a
+    ``.so`` under any other name (a stale build, one made for another
+    CPU) is never opened — with no way to build the matching one the
+    loader says so once and NumPy takes over."""
+    import os
+    import shutil
+
+    real = native.lib_path()
+    assert real is not None and os.path.basename(real).startswith(
+        "libtda_ingest-")
+    # a package dir holding only foreign binaries: the pre-hash name
+    # and a hash of some other source
+    for name in ("libtda_ingest.so", "libtda_ingest-000000000000.so"):
+        (tmp_path / name).write_bytes(b"not an ELF file")
+    monkeypatch.setattr(native, "_here", str(tmp_path))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_attempted", False)
+    monkeypatch.setattr(native, "_build", lambda path: "build failed: "
+                        "no compiler (test)")
+    assert native.load() is None          # neither foreign file opened
+    err = capsys.readouterr().err
+    assert err.count("[native] graph ingest path: numpy") == 1
+    assert "no compiler (test)" in err
+    native.load()                          # announced once, not twice
+    assert "[native]" not in capsys.readouterr().err
+    # the fallback still answers
+    np.testing.assert_array_equal(
+        native.dedupe_edges(np.array([[1, 2], [1, 2]], np.int64)),
+        [[1, 2]])
+    # and the name is a function of the source: other bytes, other name
+    src = tmp_path / "src"
+    shutil.copytree(native._SRC_DIR, src)
+    (src / "graph_ingest.cpp").write_text("// changed\n")
+    monkeypatch.setattr(native, "_SRC_DIR", str(src))
+    assert os.path.basename(native.lib_path()) != os.path.basename(real)

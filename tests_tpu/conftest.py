@@ -1,27 +1,26 @@
-"""TPU-only numerics tests — run manually on a TPU-attached host:
+"""On-chip numerics tests — run on a TPU host (through the chip tool):
 
-    python -m pytest tests_tpu/ -x -q
+    python -m pytest tests_tpu -q
 
 Unlike ``tests/`` (which forces an 8-virtual-device CPU mesh), this
-directory uses whatever accelerator JAX finds and SKIPS everything when
-that is not a TPU. bench.py re-records the headline convergence number
-(`convergence_acc`) every round, so the claims these tests verify are
-also captured in the driver's BENCH artifacts.
+directory runs on the accelerator JAX finds, and without a TPU it is a
+usage ERROR, not a skip: a suite that "passes" anywhere proves nothing
+about the chip. One process per chip — do not run it under xdist.
 """
 
 import jax
 import pytest
 
+from tpu_distalg.utils import compile_cache
 
-def pytest_collection_modifyitems(config, items):
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        on_tpu = False
-    if not on_tpu:
-        skip = pytest.mark.skip(reason="needs a TPU device")
-        for item in items:
-            item.add_marker(skip)
+
+def pytest_configure(config):
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise pytest.UsageError(
+            f"tests_tpu needs a TPU: the default jax backend is "
+            f"{backend!r} (the CPU suite is tests/)")
+    compile_cache.configure()
 
 
 @pytest.fixture(scope="session")

@@ -320,3 +320,140 @@ def test_virtual_ssgd_converges_on_tpu(tpu_mesh):
     assert res.final_acc > 0.75
     res2 = ssgd_virtual.train(tpu_mesh, cfg, data)
     assert np.array_equal(np.asarray(res.w), np.asarray(res2.w))
+
+
+def test_fused_topk_matches_xla_topk_incl_ties(tpu_mesh):
+    """The compiled fused matmul+top-k kernel against ``xla_matmul_topk``
+    on hardware. Small-integer factors are exact in every matmul
+    precision (the MXU's default pass rounds f32 operands to bf16), so
+    the scores are bit-equal and crowded with ties: indices and values
+    must match EXACTLY, ties toward the lower item id — including a
+    shard offset, a padded tail and fewer-than-k valid items. Random
+    f32 factors at the serving geometry then agree wherever the
+    default-precision scores leave no near-tie."""
+    from tpu_distalg.ops import pallas_topk as pt
+
+    rng = np.random.default_rng(0)
+    Q = rng.integers(-3, 4, size=(32, 64)).astype(np.float32)
+    V = rng.integers(-3, 4, size=(5000, 64)).astype(np.float32)
+    V[100] = V[7]        # crafted exact ties
+    V[4000] = V[7]
+    for off, nv, k in ((0, 5000, 10), (12345, 4100, 10), (0, 6, 10),
+                       (0, 5000, 130)):
+        fv, fi = pt.fused_matmul_topk(Q, V, off, nv, k=k)
+        xv, xi = pt.xla_matmul_topk(Q, V, off, nv, k=k)
+        np.testing.assert_array_equal(np.asarray(fi), np.asarray(xi),
+                                      err_msg=f"{(off, nv, k)}")
+        np.testing.assert_array_equal(np.asarray(fv), np.asarray(xv),
+                                      err_msg=f"{(off, nv, k)}")
+    Q = rng.normal(size=(32, 64)).astype(np.float32)
+    V = rng.normal(size=(16384, 64)).astype(np.float32)
+    fv, fi = pt.fused_matmul_topk(Q, V, 0, 16384, k=10)
+    xv, xi = pt.xla_matmul_topk(Q, V, 0, 16384, k=10)
+    np.testing.assert_allclose(np.asarray(fv), np.asarray(xv),
+                               rtol=1e-5, atol=1e-5)
+    assert (np.asarray(fi) == np.asarray(xi)).mean() >= 0.99
+
+
+def test_v1_fused_grad_sum_matches_xla(tpu_mesh):
+    """The opt-in v1 kernel (``SSGDConfig.use_pallas``) through the real
+    Mosaic compiler: its (d, 1) VMEM and (1, 1) SMEM scratches had only
+    met the interpreter. bf16-exact inputs keep the default-precision
+    MXU passes exact, so the sums match ``logistic.grad_sum`` to f32
+    accumulation order."""
+    rng = np.random.default_rng(2)
+    n, d = 8192, 30
+    X = rng.integers(-2, 3, size=(n, d)).astype(np.float32)
+    y = (rng.random(n) > 0.5).astype(np.float32)
+    mask = (rng.random(n) < 0.1).astype(np.float32)
+    w = (rng.integers(-4, 5, size=d) / 8.0).astype(np.float32)
+    g, cnt = pk.fused_grad_sum(jnp.asarray(X), jnp.asarray(y),
+                               jnp.asarray(mask), jnp.asarray(w))
+    g_ref, cnt_ref = logistic.grad_sum(
+        jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
+        jnp.asarray(mask))
+    assert float(cnt) == float(cnt_ref)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
+                               rtol=2e-2, atol=2e-2)
+
+
+def test_tp_split_kernels_match_one_pass_kernel(tpu_mesh):
+    """The two-pass dp x tp split (``fused_forward_gathered`` ->
+    residual -> ``fused_backward_gathered``, what ``tda ssgd
+    --mesh-shape NxM`` runs) against the one-pass gathered kernel on
+    the same sampled blocks."""
+    rng = np.random.default_rng(3)
+    n, d = 1 << 14, 30
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    y = (rng.random(n) > 0.5).astype(np.float32)
+    X2, meta = pk.pack_augmented(X, y, np.ones(n, np.float32),
+                                 dtype=jnp.float32, pack=16,
+                                 block_rows=1024)
+    kw = dict(pack=16, d_total=meta["d_total"], gather_block_rows=1024)
+    cols = dict(y_col=meta["y_col"], v_col=meta["v_col"])
+    w = np.zeros(meta["d_total"], np.float32)
+    w[:d] = rng.normal(size=d).astype(np.float32) * 0.1
+    w = jnp.asarray(w)
+    idx = jnp.asarray([3, 9, 0, 14], jnp.int32)
+    g1, cnt1 = pk.fused_grad_sum_gathered(X2, w, idx, **kw, **cols)
+    zyv = pk.fused_forward_gathered(X2, w, idx, **kw, **cols)
+    P = 16
+    z, yy, v = zyv[:, :P], zyv[:, P:2 * P], zyv[:, 2 * P:3 * P]
+    resid = (jax.nn.sigmoid(z) - yy) * v
+    g2 = pk.fused_backward_gathered(X2, resid, idx, **kw)
+    assert float(cnt1) == float(jnp.sum(v)) == 4 * 1024
+    keep = np.arange(meta["d_total"]) < meta["y_col"]
+    np.testing.assert_allclose(np.asarray(g2)[keep],
+                               np.asarray(g1)[keep],
+                               rtol=1e-5, atol=1e-3)
+
+
+def test_carry_leaves_have_one_shard_per_device(tpu_mesh):
+    """Every leaf a trainer carries lands as its rule table says: one
+    addressable shard on EACH device, of the table's shard shape —
+    trivially on one chip, the real layout proof on four."""
+    from tpu_distalg.models import als
+    from tpu_distalg.parallel import get_mesh, partition
+    from tpu_distalg.utils import datasets
+
+    n_dev = len(jax.devices())
+
+    def check(table, mesh, leaves):
+        for name, arr in leaves.items():
+            shards = arr.addressable_shards
+            assert len(shards) == n_dev, (table, name, len(shards))
+            assert len({s.device for s in shards}) == n_dev
+            want = partition.leaf_sharding(
+                table, name, mesh, shape=arr.shape
+            ).shard_shape(arr.shape)
+            assert {s.data.shape for s in shards} == {want}, (
+                table, name, want)
+
+    X, y = datasets.synthetic_two_class(1 << 16, 125, seed=0)
+    X = datasets.add_bias_column(X)
+    cfg = ssgd.SSGDConfig(n_iterations=20, eval_test=False,
+                          x_dtype="bfloat16", sampler="fused_gather",
+                          gather_block_rows=1024, shuffle_seed=0)
+    fn, X2, w0, meta = ssgd.prepare_fused(X, y, tpu_mesh, cfg)
+    dummy = jnp.zeros((1,), jnp.float32)
+    w, _ = fn(X2, dummy, dummy,
+              jnp.zeros((1, meta["d_total"]), jnp.float32),
+              jnp.zeros((1,), jnp.float32), w0)
+    assert bool(jnp.isfinite(w).all())
+    check("ssgd", tpu_mesh, {"X2": X2, "w": w})
+
+    # ALS on a (data x model) mesh when the device count allows, so
+    # the item factors' model-axis rule engages
+    mesh = (get_mesh(data=n_dev // 2, model=2) if n_dev % 2 == 0
+            else tpu_mesh)
+    acfg = als.ALSConfig(m=512, n=1024, k=16, n_iterations=2)
+    rng = np.random.default_rng(0)
+    R = partition.put(als.synthesize_rank_k(acfg), "R", "als_train",
+                      mesh)
+    U0 = partition.put(np.zeros((512, 16), np.float32), "U",
+                       "als_train", mesh)
+    V0 = partition.put(rng.random((1024, 16), dtype=np.float32), "V0",
+                       "als_train", mesh)
+    U, V, errs = als.make_fit_fn(mesh, acfg)(R, U0, V0)
+    assert bool(jnp.isfinite(errs).all())
+    check("als_train", mesh, {"R": R, "U": U, "V": V})

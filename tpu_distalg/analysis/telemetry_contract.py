@@ -14,10 +14,11 @@ cross-module:
   family). F-string names (``f"lint.{code}"``) are checked by their
   static prefix against the family entries.
 
-* a bench metric line's name drifts from ``ALL_METRIC_NAMES`` — the
-  CPU-fallback tier then leaves it blank on a dead-backend round
-  (rogue emission), or keeps emitting a stale skipped-with-zero line
-  forever (canonical-but-unemitted). This was an AST tripwire
+* a bench metric line's name drifts from ``ALL_METRIC_NAMES`` — an
+  emission the canonical set does not know (rogue), or a canonical
+  name nothing emits any more (canonical-but-unemitted), so the
+  tripwire and the claims checker reconcile against a set that is
+  not the one the bench prints. This was an AST tripwire
   duplicated across three test files; the collector here
   (:func:`metric_contract` / :func:`contract_problems` /
   :func:`assert_registered`) is now the ONE implementation — the
@@ -126,8 +127,7 @@ def assert_registered(names, repo_root: str | None = None) -> None:
     contract = bench_contract(repo_root)
     missing = [n for n in names if n not in contract.canonical]
     assert not missing, (
-        f"not in {CANONICAL_TUPLE} (the CPU-fallback tier would "
-        f"leave these blank on a dead-backend round): {missing}")
+        f"not in {CANONICAL_TUPLE}: {missing}")
     unemitted, _ = contract_problems(contract)
     dead = [n for n in names if n in unemitted]
     assert not dead, (
@@ -270,16 +270,12 @@ class TelemetryContract(ProjectRule):
                     project, s["path"], contract.canonical_line,
                     f"canonical metric '{n}' has no emission "
                     f"site in {s['path']} (renamed phase metric "
-                    f"without updating {CANONICAL_TUPLE}?) — the "
-                    f"CPU-fallback tier would emit it as a stale "
-                    f"skipped-with-zero line forever")
+                    f"without updating {CANONICAL_TUPLE}?)")
             for n, line in sorted(rogue.items()):
                 yield self.project_violation(
                     project, s["path"], line,
                     f"metric '{n}' is emitted but missing from "
-                    f"{CANONICAL_TUPLE} — a dead-backend round "
-                    f"would leave it blank (the r05 class); "
-                    f"register it")
+                    f"{CANONICAL_TUPLE}; register it")
 
 
 RULES = (TelemetryContract(),)

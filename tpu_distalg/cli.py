@@ -840,7 +840,10 @@ def main(argv=None):
 
     import jax  # after emulation setup
 
-    from tpu_distalg.utils import profiling
+    from tpu_distalg.parallel.mesh import NoAcceleratorError
+    from tpu_distalg.utils import compile_cache, profiling
+
+    compile_cache.configure()
 
     # stall threshold well above the legitimately silent multi-minute
     # phases a healthy run contains (first XLA/Mosaic compiles, the
@@ -852,6 +855,11 @@ def main(argv=None):
         with profiling.maybe_trace(args.profile):
             with telemetry.span(f"cli:{args.cmd}"):
                 return _dispatch(args, jax)
+    except NoAcceleratorError as e:
+        # never a quiet CPU run: the default backend is not a TPU and
+        # neither --emulate nor JAX_PLATFORMS=cpu asked for the host
+        print(f"tda {args.cmd}: {e}", file=sys.stderr)
+        return 1
     except faults.Preempted as e:
         # the graceful exit: the boundary checkpoint is already on
         # disk — re-running the same command resumes bitwise
@@ -967,6 +975,10 @@ def _run_tune(args):
     backend = (os.environ.get("JAX_PLATFORMS") or "cpu"
                ).split(",")[0] or "cpu"
     with telemetry.span("cli:tune"):
+        # the probe child must reach the chip BEFORE this process
+        # builds a mesh on it — one process per chip
+        init_s = (None if args.no_backend_init
+                  else ttune.measure_backend_init())
         if args.collective:
             import jax
 
@@ -974,8 +986,7 @@ def _run_tune(args):
             collective = ttune.measure_collective(_mesh(args))
         m = ttune.measure_rig(
             seed=args.seed, quick=args.quick,
-            include_backend_init=not args.no_backend_init,
-            collective=collective)
+            backend_init_s=init_s, collective=collective)
         # the one wall-clock read: created_unix orders profile
         # artifacts on disk and tags when the rig was measured — it
         # never influences run behavior or replay

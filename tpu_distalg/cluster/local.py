@@ -158,8 +158,9 @@ def _spawn_process_worker(host, port, slot, *, plan_spec,
     genuine article. The worker's schedule comes from the
     coordinator's welcome frame; the plan is NOT exported into the
     child's environment (a worker-side registry would double-probe)."""
-    env = dict(os.environ,
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
+    # this tier is host-CPU by design; a child that inherited a
+    # platform naming the chip would race its parent for it
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("TDA_FAULT_PLAN", None)
     cmd = [sys.executable, "-m", "tpu_distalg.cli", "cluster",
            "--role", "worker", "--connect", f"{host}:{port}",
@@ -456,8 +457,7 @@ class _ProcCoordinator:
 
     def __init__(self, config: ClusterConfig, telemetry_dir, *,
                  port: int = 0):
-        env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-            "JAX_PLATFORMS", "cpu"))
+        env = dict(os.environ, JAX_PLATFORMS="cpu")  # host-CPU tier
         env.pop("TDA_FAULT_PLAN", None)
         cmd = [sys.executable, "-m", "tpu_distalg.cli", "cluster",
                "--role", "coordinator",

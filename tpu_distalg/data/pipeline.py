@@ -171,13 +171,15 @@ def stream_staged(dataset, ids: np.ndarray):
 
 def make_host_block_sampler(seed: int, n_shards: int, n_blocks: int,
                             n_sampled: int):
-    """Build ONCE the jitted 'fused_gather' block draw on the host CPU
-    backend: threefry is platform-deterministic, so these ids equal the
-    ones the resident path draws on device — the property that keeps
-    streamed trajectories bitwise-equal to resident ones. Returns
-    ``draw(ts) -> (T, n_shards, n_sampled)`` local block ids; the jit
-    is cached per distinct segment length (building it per call would
-    recompile the sampler inside timed/checkpointed loops)."""
+    """Build ONCE the jitted 'fused_gather' block draw: threefry is
+    platform-deterministic, so these ids equal the ones the resident
+    path draws inside its scan — the property that keeps streamed
+    trajectories bitwise-equal to resident ones. Runs on the default
+    backend (a process pinned to ``JAX_PLATFORMS=tpu`` has no CPU
+    backend to ask for). Returns ``draw(ts) -> (T, n_shards,
+    n_sampled)`` local block ids on the host; the jit is cached per
+    distinct segment length (building it per call would recompile the
+    sampler inside timed/checkpointed loops)."""
     import jax
     import jax.numpy as jnp
 
@@ -185,13 +187,10 @@ def make_host_block_sampler(seed: int, n_shards: int, n_blocks: int,
     from tpu_distalg.utils import prng
 
     key = prng.root_key(seed)
-    cpu = jax.devices("cpu")[0]
-    with jax.default_device(cpu):
-        f = jax.jit(jax.vmap(lambda t: sampling.sample_block_ids(
-            jax.random.fold_in(key, t), n_shards, n_blocks, n_sampled)))
+    f = jax.jit(jax.vmap(lambda t: sampling.sample_block_ids(
+        jax.random.fold_in(key, t), n_shards, n_blocks, n_sampled)))
 
     def draw(ts: np.ndarray) -> np.ndarray:
-        with jax.default_device(cpu):
-            return np.asarray(f(jnp.asarray(ts, jnp.int32)))
+        return np.asarray(f(jnp.asarray(ts, jnp.int32)))
 
     return draw

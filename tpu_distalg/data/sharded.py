@@ -86,7 +86,8 @@ class ShardedDataset:
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from tpu_distalg.parallel import DATA_AXIS, data_parallel
+        from tpu_distalg.parallel import (
+            DATA_AXIS, data_parallel, mesh_on_tpu)
 
         self.backend = backend or _infer_backend(storage)
         if self.backend not in BACKENDS:
@@ -143,7 +144,7 @@ class ShardedDataset:
         # CPU-mesh emulation on few host cores starves the rendezvous
         # when several multi-device programs are in flight — consumers
         # (trainers) read this to serialize steps there.
-        self.on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+        self.on_tpu = mesh_on_tpu(mesh)
 
     # ---- constructors ------------------------------------------------
 
@@ -227,10 +228,10 @@ class ShardedDataset:
     def put(self, gathered: np.ndarray):
         """The DEVICE side: async H2D of one gathered batch onto the
         mesh, TOUCHED with a tiny async per-shard reduction so the
-        transfer actually starts now — on tunneled/lazy backends
-        ``device_put`` (and even ``block_until_ready`` on its result)
-        can defer the copy until first use, which would serialize the
-        H2D behind the next step instead of overlapping it."""
+        transfer actually starts now — a lazy backend can defer
+        ``device_put``'s copy (even past ``block_until_ready`` on its
+        result) until first use, which would serialize the H2D behind
+        the next step instead of overlapping it."""
         import jax
 
         with tevents.span("data:h2d", backend=self.backend,

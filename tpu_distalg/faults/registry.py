@@ -204,7 +204,7 @@ DEFAULT_LEAVE_WINDOWS = 2
 
 class InjectedOSError(OSError):
     """A scheduled transient I/O fault (disk hiccup, flaky NFS, torn
-    tunnel) — retryable by construction."""
+    connection) — retryable by construction."""
 
 
 class InjectedCorruptionError(InjectedOSError):

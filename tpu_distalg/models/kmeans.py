@@ -21,7 +21,12 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpu_distalg.ops import kmeans as kops
-from tpu_distalg.parallel import data_parallel, parallelize, tree_allreduce_sum
+from tpu_distalg.parallel import (
+    data_parallel,
+    mesh_on_tpu,
+    parallelize,
+    tree_allreduce_sum,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,7 +177,7 @@ def make_fit_fn_fused(mesh: Mesh, config: KMeansConfig, dim: int, *,
     the shard-contiguous input-row order."""
     from tpu_distalg.ops import pallas_kmeans as pk
 
-    on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+    on_tpu = mesh_on_tpu(mesh)
     dpad, pp, _ = pk.packed_geometry(dim, config.k)
 
     def _local_stats2(X2, m2, centers):

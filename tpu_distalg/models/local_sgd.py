@@ -32,6 +32,7 @@ from tpu_distalg.ops import logistic, sampling
 from tpu_distalg.parallel import (
     DATA_AXIS,
     data_parallel,
+    mesh_on_tpu,
     parallelize,
     tree_allreduce_mean,
 )
@@ -627,7 +628,7 @@ def make_train_fn_fused(mesh: Mesh, config: LocalSGDConfig, meta: dict):
     from tpu_distalg.models.ssgd import fused_gather_geometry
     from tpu_distalg.ops import pallas_kernels
 
-    on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+    on_tpu = mesh_on_tpu(mesh)
     d_t = meta["d_total"]
     col_keep = (jnp.arange(d_t) < meta["y_col"]).astype(jnp.float32)
     n_shards = mesh.shape[DATA_AXIS]

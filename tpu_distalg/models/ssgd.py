@@ -25,6 +25,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from tpu_distalg.ops import logistic, sampling
 from tpu_distalg.parallel import (
     data_parallel,
+    mesh_on_tpu,
     parallelize,
     tree_allreduce_sum,
 )
@@ -293,7 +294,7 @@ def make_train_fn(mesh: Mesh, config: SSGDConfig, n_padded: int,
     if config.use_pallas:
         from tpu_distalg.ops import pallas_kernels
 
-        interpret = next(iter(mesh.devices.flat)).platform != "tpu"
+        interpret = not mesh_on_tpu(mesh)
 
         def _local_grad(X, y, mask, w):
             g, cnt = pallas_kernels.fused_grad_sum(
@@ -432,7 +433,7 @@ def make_ssp_train_fn(mesh: Mesh, config: SSGDConfig, n_padded: int,
 
         from tpu_distalg.ops import pallas_kernels
 
-        on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+        on_tpu = mesh_on_tpu(mesh)
         d_t = meta["d_total"]
         col_keep = (jnp.arange(d_t) < meta["y_col"]).astype(
             jnp.float32)
@@ -999,7 +1000,7 @@ def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
     from tpu_distalg.ops import pallas_kernels
     from tpu_distalg.parallel import DATA_AXIS
 
-    on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+    on_tpu = mesh_on_tpu(mesh)
     d_t = meta["d_total"]
     col_keep = (jnp.arange(d_t) < meta["y_col"]).astype(jnp.float32)
     n_shards = mesh.shape[DATA_AXIS]
@@ -1298,7 +1299,7 @@ def make_train_fn_fused_tp(mesh: Mesh, config: SSGDConfig, meta: dict):
     from tpu_distalg.ops import pallas_kernels
     from tpu_distalg.parallel import DATA_AXIS, MODEL_AXIS, comms
 
-    on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+    on_tpu = mesh_on_tpu(mesh)
     d_t = meta["d_total"]
     Pk = meta["pack"]
     n_shards = mesh.shape[DATA_AXIS]
@@ -1645,8 +1646,6 @@ def prepare_fused_synthetic(
 
     from jax import lax
 
-    from tpu_distalg.parallel.compat import shard_map
-
     from tpu_distalg.ops import pallas_kernels
     from tpu_distalg.parallel import DATA_AXIS, partition
     from tpu_distalg.utils import datasets as dsets
@@ -1690,7 +1689,7 @@ def prepare_fused_synthetic(
         return chunks.reshape(n_local // pk, pk * d_t)
 
     spec = P(DATA_AXIS, None)
-    f = shard_map(body, mesh=mesh, in_specs=(), out_specs=spec)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(), out_specs=spec)
     X2 = jax.jit(f, out_shardings=partition.leaf_sharding(
         "ssgd", "X2", mesh))()
     meta = dict(pack=pk, d_total=d_t, y_col=y_col, v_col=v_col,

@@ -63,7 +63,7 @@ from tpu_distalg.models.ssgd import (
 )
 from tpu_distalg.ops import logistic, pallas_kernels
 from tpu_distalg.parallel import DATA_AXIS, data_parallel, \
-    tree_allreduce_sum
+    mesh_on_tpu, tree_allreduce_sum
 from tpu_distalg.utils import metrics, prng
 
 
@@ -103,7 +103,7 @@ def make_step_fn(mesh: Mesh, config: SSGDConfig, meta: dict,
     (S, n_sampled·bp, pack·d_total): the resident kernel with the
     identity block index — a contiguous read of exactly the staged
     minibatch — then the shared update rule (``ssgd.py:105``)."""
-    on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+    on_tpu = mesh_on_tpu(mesh)
     d_t = meta["d_total"]
     col_keep = (jnp.arange(d_t) < meta["y_col"]).astype(jnp.float32)
     kern = functools.partial(

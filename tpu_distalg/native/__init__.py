@@ -1,85 +1,95 @@
 """ctypes bindings for the native (C++) ingest runtime.
 
-Loads ``libtda_ingest.so`` (built by ``native/Makefile`` into this package
-directory, or auto-built on first use when a compiler is present). Every
-entry point has a NumPy fallback, so the framework works without the
-native library — just slower at 10M+ edge scale.
+The library is a function of the COMMITTED source: it is named
+``libtda_ingest-<hash>.so`` after the SHA-256 of ``native/
+graph_ingest.cpp`` + ``native/Makefile`` (which holds the portable
+build flags), built on first use when a compiler is present, and only
+a binary whose name carries the current hash is ever loaded — a stale
+or foreign ``.so`` lying in the package directory (built from another
+source, or for another CPU) is ignored, never dlopen'ed. Every entry
+point has a byte-identical NumPy fallback, so the framework works
+without the library — just slower at 10M+ edge scale; which path is
+in use is said once per process, on stderr and as a ``native_ingest``
+telemetry event.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 
 import numpy as np
 
-_LIB_NAME = "libtda_ingest.so"
-_here = os.path.dirname(__file__)
+from tpu_distalg.telemetry import events as tevents
+
+_here = os.path.dirname(os.path.abspath(__file__))
+_SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(_here)), "native")
 _lib = None
 _load_attempted = False
-#: symbols added after the first shipped .so — a prebuilt library may
-#: predate them. load() tries ONE rebuild when any is missing; entry
-#: points whose symbol still is not there fall back to NumPy (a stale
-#: binary must degrade per-capability, never crash the import or the
-#: caller).
-_OPTIONAL_SYMBOLS = ("tda_pack_edge_rows",)
-_missing_symbols: frozenset = frozenset()
 
 
-def _build() -> bool:
-    src_dir = os.path.join(_here, os.pardir, os.pardir, "native")
-    makefile = os.path.join(src_dir, "Makefile")
-    if not os.path.exists(makefile):
-        return False
+def lib_path() -> str | None:
+    """Where the binary matching the committed source lives (built or
+    not); None when the source tree is absent (an installed package
+    without ``native/``)."""
+    h = hashlib.sha256()
     try:
-        subprocess.run(
-            ["make", "-C", src_dir], check=True, capture_output=True,
-            timeout=120,
-        )
-        return True
-    except (subprocess.SubprocessError, OSError):
-        return False
-
-
-def _open_lib(path: str) -> ctypes.CDLL | None:
-    try:
-        return ctypes.CDLL(path)
+        # same bytes, same order as the Makefile's `cat ... | sha256sum`
+        for name in ("graph_ingest.cpp", "Makefile"):
+            with open(os.path.join(_SRC_DIR, name), "rb") as f:
+                h.update(f.read())
     except OSError:
         return None
+    return os.path.join(_here, f"libtda_ingest-{h.hexdigest()[:12]}.so")
+
+
+def _build(path: str) -> str | None:
+    """Build ``path`` from the committed source; the failure reason on
+    error, None on success."""
+    try:
+        subprocess.run(
+            ["make", "-C", _SRC_DIR, f"TARGET={path}"], check=True,
+            capture_output=True, text=True, timeout=120,
+        )
+    except subprocess.CalledProcessError as e:
+        tail = (e.stderr or "").strip().splitlines()[-1:]
+        return f"build failed: {tail[0] if tail else e}"
+    except (subprocess.SubprocessError, OSError) as e:
+        return f"build failed: {type(e).__name__}: {e}"
+    return None
+
+
+def _announce(path: str, reason: str = "") -> None:
+    tevents.emit("native_ingest", path=path, reason=reason)
+    print(f"[native] graph ingest path: {path}"
+          f"{' (' + reason + ')' if reason else ''}", file=sys.stderr)
 
 
 def load() -> ctypes.CDLL | None:
     """The loaded library, building it on first use if needed; None when
-    unavailable (callers fall back to NumPy).
-
-    Capability handling for stale binaries: a prebuilt ``.so`` that
-    predates :data:`_OPTIONAL_SYMBOLS` triggers ONE rebuild attempt
-    (same build-if-missing path); if the rebuild cannot run (no
-    compiler, read-only checkout) the library still loads with the
-    missing entry points recorded in :data:`_missing_symbols` — their
-    Python wrappers fall back to NumPy instead of raising
-    ``AttributeError`` mid-ingest."""
-    global _lib, _load_attempted, _missing_symbols
+    unavailable (callers fall back to NumPy, and the reason has been
+    announced)."""
+    global _lib, _load_attempted
     if _lib is not None or _load_attempted:
         return _lib
     _load_attempted = True
-    path = os.path.join(_here, _LIB_NAME)
-    if not os.path.exists(path) and not _build():
+    path = lib_path()
+    if path is None:
+        _announce("numpy", f"no source under {_SRC_DIR}")
         return None
-    lib = _open_lib(path)
-    if lib is None:
+    if not os.path.exists(path):
+        err = _build(path)
+        if err is not None:
+            _announce("numpy", err)
+            return None
+    try:
+        lib = ctypes.CDLL(path)
+    except OSError as e:
+        _announce("numpy", f"cannot load {os.path.basename(path)}: {e}")
         return None
-    stale = [s for s in _OPTIONAL_SYMBOLS if not hasattr(lib, s)]
-    if stale and _build():
-        # a fresh build carries every symbol this binding knows about;
-        # reopen so the new ones resolve (dlopen caches per path, but
-        # the handle we already hold keeps the OLD mapping alive)
-        rebuilt = _open_lib(path)
-        if rebuilt is not None:
-            lib = rebuilt
-            stale = [s for s in _OPTIONAL_SYMBOLS if not hasattr(lib, s)]
-    _missing_symbols = frozenset(stale)
     i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
     i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
     f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
@@ -97,23 +107,16 @@ def load() -> ctypes.CDLL | None:
     lib.tda_counting_sort_perm.argtypes = [i64p, ctypes.c_int64,
                                            ctypes.c_int64, i64p]
     lib.tda_counting_sort_perm.restype = ctypes.c_int32
-    if "tda_pack_edge_rows" not in _missing_symbols:
-        lib.tda_pack_edge_rows.argtypes = [i64p, i64p, f32p,
-                                           ctypes.c_int64, i32p]
-        lib.tda_pack_edge_rows.restype = None
+    lib.tda_pack_edge_rows.argtypes = [i64p, i64p, f32p,
+                                       ctypes.c_int64, i32p]
+    lib.tda_pack_edge_rows.restype = None
     _lib = lib
+    _announce("native", os.path.basename(path))
     return _lib
 
 
 def available() -> bool:
     return load() is not None
-
-
-def has_symbol(name: str) -> bool:
-    """Whether the loaded library exports ``name`` — False when the
-    library is absent OR it loaded as a stale build missing the symbol
-    (the per-capability skip the graph ingest keys its fallback on)."""
-    return load() is not None and name not in _missing_symbols
 
 
 def pack_edge_rows(src: np.ndarray, dst: np.ndarray,
@@ -129,8 +132,9 @@ def pack_edge_rows(src: np.ndarray, dst: np.ndarray,
     w = np.ascontiguousarray(w, dtype=np.float32)
     n = len(src)
     out = np.empty((n, 3), dtype=np.int32)
-    if n and has_symbol("tda_pack_edge_rows"):
-        load().tda_pack_edge_rows(src, dst, w, n, out)
+    lib = load()
+    if n and lib is not None:
+        lib.tda_pack_edge_rows(src, dst, w, n, out)
         return out
     out[:, 0] = src.astype(np.int32)
     out[:, 1] = dst.astype(np.int32)

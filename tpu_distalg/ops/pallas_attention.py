@@ -47,8 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_distalg.ops.pallas_compat import \
-    COMPILER_PARAMS as _COMPILER_PARAMS
 
 _NEG = -1e30
 
@@ -248,7 +246,7 @@ def flash_attention_block(q, k, v, o, m, l, q_off, k_off, *,
             jax.ShapeDtypeStruct((h, s_q, 1), jnp.float32),
             jax.ShapeDtypeStruct((h, s_q, 1), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
@@ -446,7 +444,7 @@ def flash_attention_backward_block(q, k, v, do, lse, delta,
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((h, s_q, d), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
@@ -484,7 +482,7 @@ def flash_attention_backward_block(q, k, v, do, lse, delta,
             jax.ShapeDtypeStruct((h_kv, s_kv, d), jnp.float32),
             jax.ShapeDtypeStruct((h_kv, s_kv, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

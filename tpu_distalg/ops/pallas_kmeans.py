@@ -52,8 +52,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_distalg.ops.pallas_compat import \
-    COMPILER_PARAMS as _COMPILER_PARAMS
 
 _PREC = jax.lax.Precision.HIGHEST
 
@@ -250,7 +248,7 @@ def fused_cluster_stats(X2, mask2, centers, *, dim: int, k: int,
             pltpu.VMEM((L, 128), jnp.float32),
             pltpu.VMEM((1, L), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

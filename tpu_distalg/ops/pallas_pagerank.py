@@ -94,8 +94,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_distalg.ops.pallas_compat import \
-    COMPILER_PARAMS as _COMPILER_PARAMS
 
 LANES = 128
 DEF_CHUNK = 1024  # edges per in-kernel chunk (one matmul each)
@@ -485,7 +483,7 @@ def spmv_table(gbase, sbase, ranks_padded, src_lane, src_row, dst_row,
                                    lambda i, s1, s2: (0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((r8 + ws, LANES), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=128 * 1024 * 1024),
         interpret=interpret,
@@ -520,7 +518,7 @@ def scatter_table(base, contribs, row, lane, *, w: int, r8: int,
                                    lambda i, s: (0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((r8 + 8 * w, LANES), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,

@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_distalg.ops.pallas_compat import \
-    COMPILER_PARAMS as _COMPILER_PARAMS
 
 _NEG_INF = float("-inf")
 _IDX_SENTINEL = 2**31 - 1
@@ -175,7 +173,7 @@ def fused_matmul_topk(Q, V, index_offset, n_valid, *, k: int,
             jax.ShapeDtypeStruct((Bp, kp), jnp.float32),
             jax.ShapeDtypeStruct((Bp, kp), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),

@@ -11,8 +11,10 @@ from tpu_distalg.parallel.mesh import (
     DATA_AXIS,
     MODEL_AXIS,
     MeshContext,
+    NoAcceleratorError,
     get_mesh,
     local_device_count,
+    mesh_on_tpu,
     multihost_initialize,
 )
 from tpu_distalg.parallel.sharding import (
@@ -57,6 +59,7 @@ __all__ = [
     "DATA_AXIS",
     "MODEL_AXIS",
     "MeshContext",
+    "NoAcceleratorError",
     "RuleTable",
     "ShardedMatrix",
     "SyncSpec",
@@ -73,6 +76,7 @@ __all__ = [
     "data_sharding",
     "get_mesh",
     "local_device_count",
+    "mesh_on_tpu",
     "multihost_initialize",
     "pad_rows",
     "parallelize",

@@ -22,8 +22,6 @@ import jax
 from jax import lax
 
 from tpu_distalg.parallel.mesh import DATA_AXIS
-from tpu_distalg.parallel.compat import axis_size as _axis_size
-
 
 
 def tree_allreduce_sum(tree, axis_name: str = DATA_AXIS):
@@ -48,7 +46,7 @@ def ring_shift(x: jax.Array, axis_name: str = DATA_AXIS, shift: int = 1):
     A ``ppermute`` over the mesh axis — the ICI-native neighbour exchange
     used by ring algorithms (ring all-reduce, ring attention).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 

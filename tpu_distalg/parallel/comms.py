@@ -441,9 +441,7 @@ def sparse_allreduce(vals, idx, length: int, *,
     from jax import lax
 
     if n is None:
-        from tpu_distalg.parallel.compat import axis_size
-
-        n = axis_size(axis_name)
+        n = lax.axis_size(axis_name)
     if n == 1:
         return jnp.zeros((length,), vals.dtype).at[idx].add(vals)
     all_v, all_i = _ring_allgather((vals, idx), axis_name, n)

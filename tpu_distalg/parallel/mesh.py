@@ -17,14 +17,21 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import sys
 from typing import Mapping, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from tpu_distalg.telemetry import events as tevents
+
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
+
+
+class NoAcceleratorError(RuntimeError):
+    """The default backend is not a TPU and nobody asked for the CPU."""
 
 
 def emulate_devices(n: int = 8, platform: str = "cpu") -> None:
@@ -39,11 +46,9 @@ def emulate_devices(n: int = 8, platform: str = "cpu") -> None:
             flags + f" --xla_force_host_platform_device_count={n}"
         ).strip()
     os.environ.setdefault("JAX_PLATFORMS", platform)
-    # env vars alone lose to site plugins that force another platform via
-    # jax.config; the config update wins when no backend is initialised yet
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", platform)
+    # the env var was read when jax was imported (above); the config
+    # update is what a not-yet-initialised backend actually honours
+    jax.config.update("jax_platforms", platform)
 
 
 def local_device_count() -> int:
@@ -59,16 +64,33 @@ def multihost_initialize(**kwargs) -> None:
     ``jax.distributed.initialize``, which it wraps). Idempotent: a no-op if
     the distributed client is already up.
     """
-    if getattr(jax.distributed, "is_initialized", None) is not None:
-        if jax.distributed.is_initialized():
-            return
-    else:
-        # pre-0.6 jax: no is_initialized — probe the global client state
-        from jax._src import distributed as _dist
-
-        if getattr(_dist.global_state, "client", None) is not None:
-            return
+    if jax.distributed.is_initialized():
+        return
     jax.distributed.initialize(**kwargs)
+
+
+def cpu_requested() -> bool:
+    """Whether the CPU backend was ASKED for: ``JAX_PLATFORMS`` naming
+    ``cpu`` (what the tier-1 tests export) or :func:`emulate_devices`
+    (the CLI's ``--emulate N``) — both land in ``jax_platforms``,
+    whose first entry is the default backend."""
+    return (jax.config.jax_platforms or "").split(",")[0] == "cpu"
+
+
+def mesh_on_tpu(mesh: Mesh) -> bool:
+    """Whether ``mesh`` runs on TPU chips — the one question every
+    Pallas call site asks to pick compiled Mosaic (``True``) over
+    ``interpret=True``. Each answer leaves a ``device`` telemetry event
+    (platform, ``device_kind``, device count, ``pallas:
+    compiled|interpret``) so a run's log says which way its kernels
+    were built; ``chip_smoke.py`` asserts on it."""
+    dev = next(iter(mesh.devices.flat))
+    tpu = dev.platform == "tpu"
+    tevents.emit("device", platform=dev.platform,
+                 device_kind=dev.device_kind,
+                 n_devices=int(mesh.devices.size),
+                 pallas="compiled" if tpu else "interpret")
+    return tpu
 
 
 def get_mesh(
@@ -97,8 +119,20 @@ def get_mesh(
       * otherwise (CPU emulation, one chip, explicit ``devices``, or a
         shape the topology helpers cannot express): a plain row-major
         grid — deterministic ordering for tests.
+
+    The CPU is used only when asked for: with the default devices on a
+    non-TPU backend and neither ``JAX_PLATFORMS`` naming ``cpu`` nor
+    :func:`emulate_devices`, this raises :class:`NoAcceleratorError`
+    instead of quietly interpreting every kernel on the host.
     """
     devs = list(devices) if devices is not None else jax.devices()
+    if (devices is None and devs[0].platform != "tpu"
+            and not cpu_requested()):
+        raise NoAcceleratorError(
+            f"no TPU: the default jax backend is "
+            f"{devs[0].platform!r}. To run on host devices on purpose "
+            f"pass --emulate N (library: parallel.mesh.emulate_devices) "
+            f"or set JAX_PLATFORMS=cpu")
     n = len(devs)
     if data is None:
         if n % model != 0:
@@ -117,6 +151,7 @@ def _topology_grid(devs, data: int, model: int, *, explicit: bool):
     function so the DCN-hybrid / ICI-torus / fallback branches are unit-
     testable with fake device objects (no TPU hardware required)."""
     need = data * model
+    grid, branch = None, "row_major"
     if (not explicit and need == len(devs) and len(devs) > 1
             and devs[0].platform == "tpu"):
         from jax.experimental import mesh_utils
@@ -124,16 +159,28 @@ def _topology_grid(devs, data: int, model: int, *, explicit: bool):
         n_slices = len({getattr(d, "slice_index", 0) for d in devs})
         try:
             if n_slices > 1 and data % n_slices == 0:
-                return mesh_utils.create_hybrid_device_mesh(
+                grid = mesh_utils.create_hybrid_device_mesh(
                     (data // n_slices, model), (n_slices, 1), devices=devs
                 )
-            if n_slices == 1:
-                return mesh_utils.create_device_mesh(
+                branch = "dcn_hybrid"
+            elif n_slices == 1:
+                grid = mesh_utils.create_device_mesh(
                     (data, model), devices=devs
                 )
-        except (NotImplementedError, ValueError):
-            pass  # topology can't express the shape: row-major fallback
-    return np.array(devs[:need]).reshape(data, model)
+                branch = "ici_torus"
+        except (NotImplementedError, ValueError) as e:
+            # the topology helpers can't express the shape: the mesh is
+            # still correct row-major, but neighbouring coordinates are
+            # no longer neighbouring chips — say so, ring collectives
+            # pay for it
+            branch = "row_major_fallback"
+            print(f"[mesh] {data}x{model}: topology-aware layout "
+                  f"unavailable ({type(e).__name__}: {e}); using the "
+                  f"row-major device order", file=sys.stderr)
+    if grid is None:
+        grid = np.array(devs[:need]).reshape(data, model)
+    tevents.emit("mesh", branch=branch, data=data, model=model)
+    return grid
 
 
 @dataclasses.dataclass(frozen=True)

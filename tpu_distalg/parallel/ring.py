@@ -35,8 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from tpu_distalg.parallel.mesh import DATA_AXIS
-from tpu_distalg.parallel.compat import axis_size as _axis_size
-
 
 
 def _ring_perm(n: int, shift: int = 1):
@@ -50,7 +48,7 @@ def ring_allgather_matmul(a_local, b_local, axis_name: str = DATA_AXIS):
     next block is in flight (XLA overlaps the ppermute with the dot).
     Returns the (Sa_l, Sb) block of the full product owned by this shard.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     sb = b_local.shape[0]
 
@@ -286,7 +284,7 @@ def _ring_flash_backward(q, k, v, out, lse, g, *, axis_name, scale,
     single = q.ndim == 2
     if single:
         q, k, v, out, g = (x[:, None, :] for x in (q, k, v, out, g))
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_q, h, d = q.shape
     s_local = k.shape[0]
@@ -392,7 +390,7 @@ def _zigzag_impl(q, k, v, *, axis_name, scale, use_flash,
     single = q.ndim == 2
     if single:
         q, k, v = (x[:, None, :] for x in (q, k, v))
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_q, h, d = q.shape
     if s_q % 2 or k.shape[0] != s_q:
@@ -479,7 +477,7 @@ def _zigzag_flash_backward(q, k, v, out, lse, g, *, axis_name, scale,
     single = q.ndim == 2
     if single:
         q, k, v, out, g = (x[:, None, :] for x in (q, k, v, out, g))
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_q, h, d = q.shape
     c = s_q // 2
@@ -559,7 +557,7 @@ def _ring_attention_impl(q, k, v, *, axis_name, scale, kv_chunk,
     single = q.ndim == 2
     if single:
         q, k, v = (x[:, None, :] for x in (q, k, v))
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_q, h, d = q.shape
     if h % k.shape[1]:
@@ -768,7 +766,7 @@ def ulysses_attention(q, k, v, axis_name: str = DATA_AXIS, *,
 
 
 def _seq_to_head_impl(x, axis_name):
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     s_l, h, d = x.shape
     if h % n:
         raise ValueError(
@@ -782,7 +780,7 @@ def _seq_to_head_impl(x, axis_name):
 
 
 def _head_to_seq_impl(x, axis_name):
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     s, h_l, d = x.shape
     if s % n:
         raise ValueError(

@@ -126,8 +126,6 @@ def build_sharded(
     """
     from jax import lax
 
-    from tpu_distalg.parallel.compat import shard_map
-
     n_shards = mesh.shape[DATA_AXIS]
     mult = n_shards * row_multiple
     n_padded = -(-n_rows // mult) * mult
@@ -147,7 +145,7 @@ def build_sharded(
     specs = jax.tree.map(
         lambda sh: P(DATA_AXIS, *([None] * (sh.ndim - 1))), shapes
     )
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh, in_specs=(), out_specs=(specs, P(DATA_AXIS)),
     )
     shardings = jax.tree.map(lambda sp: NamedSharding(mesh, sp), specs)

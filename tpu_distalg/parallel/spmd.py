@@ -9,6 +9,7 @@ collectives. ``replica_index`` is the analogue of the partition index that
 
 from __future__ import annotations
 
+import jax
 from jax import lax
 from jax.sharding import Mesh
 
@@ -29,9 +30,7 @@ def data_parallel(fn, mesh: Mesh, *, in_specs, out_specs,
     operands — mirroring exactly which reference values travelled via
     ``parallelize`` vs ``broadcast``.
     """
-    from tpu_distalg.parallel.compat import shard_map
-
-    return shard_map(
-        fn, mesh, in_specs=in_specs, out_specs=out_specs,
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=check_vma,
     )

@@ -204,8 +204,8 @@ def als_model(U, V, mesh, *, k_top: int = 10, merge: str = "sparse",
     from jax.sharding import PartitionSpec as P
 
     from tpu_distalg.ops import pallas_topk as pt
-    from tpu_distalg.parallel import MODEL_AXIS, comms, partition
-    from tpu_distalg.parallel.compat import shard_map
+    from tpu_distalg.parallel import (
+        MODEL_AXIS, comms, mesh_on_tpu, partition)
 
     if merge not in ("sparse", "dense"):
         raise ValueError(f"merge must be 'sparse' or 'dense', "
@@ -235,7 +235,7 @@ def als_model(U, V, mesh, *, k_top: int = 10, merge: str = "sparse",
             f"n_items={n_true} invalid for V with {V.shape[0]} rows")
     if k_top < 1:
         raise ValueError(f"k_top must be >= 1, got {k_top}")
-    on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+    on_tpu = mesh_on_tpu(mesh)
     fused = on_tpu if use_fused is None else bool(use_fused)
     n_model = int(mesh.shape[MODEL_AXIS])
     # pad items so every model shard holds an equal slice; padded rows
@@ -285,8 +285,8 @@ def als_model(U, V, mesh, *, k_top: int = 10, merge: str = "sparse",
         # construction (every shard gathers the same pairs and sorts
         # identically); the static checker can't see through ppermute,
         # so the check is off — same call shape as spmd.data_parallel
-        fn = jax.jit(shard_map(
-            body, mesh, in_specs=(P(), P(), P(MODEL_AXIS, None)),
+        fn = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(P(), P(), P(MODEL_AXIS, None)),
             out_specs=(P(), P()), check_vma=False))
         wire_per_req = 8 * k_top * (n_model - 1)
     else:
@@ -301,8 +301,8 @@ def als_model(U, V, mesh, *, k_top: int = 10, merge: str = "sparse",
             vals, idx = lax.top_k(full, k_top)
             return vals, idx.astype(jnp.int32)
 
-        fn = jax.jit(shard_map(
-            body, mesh, in_specs=(P(), P(), P(MODEL_AXIS, None)),
+        fn = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(P(), P(), P(MODEL_AXIS, None)),
             out_specs=(P(), P()), check_vma=False))
         wire_per_req = 4 * n_pad * (n_model - 1) // n_model
 

@@ -2,10 +2,10 @@
 
 The observability layer the reference never had (its only instrument is
 ``print`` per iteration, SURVEY.md §5) and round 5 proved this repo
-needed (a 26-minute invisible backend hang, VERDICT.md): structured
-JSONL events (:mod:`events`), a liveness heartbeat with stall detection
-(:mod:`heartbeat`), deadline-guarded backend init with retry/backoff/
-degrade (:mod:`supervisor`), and log summarization for humans and CI
+needed (a 26-minute invisible backend hang): structured JSONL events
+(:mod:`events`), a liveness heartbeat with stall detection
+(:mod:`heartbeat`), deadline-guarded backend init with retry/backoff
+(:mod:`supervisor`), and log summarization for humans and CI
 (:mod:`report`, ``tda report <dir>``).
 
 Import cost is stdlib-only (no jax) so the CLI can configure telemetry

@@ -2,7 +2,7 @@
 
 Round 5's defining failure was *invisible*: the TPU backend hung ~26
 minutes during init, the bench window expired, and the artifact recorded
-nothing about where the time went (VERDICT.md). This module is the
+nothing about where the time went. This module is the
 record-keeping half of the fix: every run can append structured events
 to one JSONL file, cheaply enough to leave on everywhere, and a no-op
 when nobody asked for it.
@@ -11,7 +11,7 @@ Event schema — one JSON object per line, every line carries:
 
   ``ev``      event type (``run_start``, ``mark``, ``span_start``,
               ``span_end``, ``heartbeat``, ``stall``, ``backend_init``,
-              ``backend_retry``, ``degraded``, ``backend_unavailable``,
+              ``backend_retry``, ``backend_unavailable``,
               ``restart``, ``quarantine``, ``checkpoint_saved``,
               ``metric``, ``gauge``, ``counters``, ``run_end``)
   ``t_wall``  wall-clock seconds (``time.time()`` — cross-host ordering)

@@ -107,8 +107,6 @@ def summarize(evts: list[dict]) -> dict:
                                   "seconds": e.get("seconds")})
             if e.get("outcome") == "ok":
                 resolution = "ok"
-        elif ev == "degraded":
-            resolution = "degraded"
         elif ev == "backend_unavailable":
             resolution = "backend_unavailable"
         elif ev == "restart":

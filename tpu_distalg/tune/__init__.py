@@ -20,6 +20,7 @@ from tpu_distalg.tune.profile import (
     SCHEMA_VERSION,
     build_profile,
     load_profile,
+    measure_backend_init,
     measure_collective,
     measure_rig,
     newest_profile,
@@ -39,7 +40,8 @@ from tpu_distalg.tune.resolve import (
 __all__ = [
     "Choice", "KNOBS", "ProfileError", "Resolution", "SCHEMA_VERSION",
     "Workload", "build_profile", "defaults", "emit_resolution",
-    "load_profile", "measure_collective", "measure_rig",
+    "load_profile", "measure_backend_init", "measure_collective",
+    "measure_rig",
     "newest_profile", "profile_crc", "resolve", "save_profile",
     "schedule_seconds",
 ]

@@ -225,19 +225,28 @@ def _host_ram_bytes() -> int | None:
         return None
 
 
-def _measure_backend_init(clock, *, timeout: float = 120.0
-                          ) -> float | None:
+def measure_backend_init(clock=None, *, timeout: float = 120.0
+                         ) -> float | None:
     """Wall time of a cold ``import jax; jax.devices()`` in a child
     process — the measured input the bench retry budget re-derives
-    from (satellite 4). None when the backend doesn't come up."""
+    from. A chip belongs to one process at a time, so call this BEFORE
+    the caller's own process touches a jax backend: a child started
+    afterwards cannot reach the chip its parent holds. None (with the
+    reason on stderr) when the backend doesn't come up."""
+    clock = clock or time.perf_counter
     t0 = clock()
     try:
         proc = subprocess.run(
             [sys.executable, "-c", "import jax; jax.devices()"],
-            timeout=timeout, capture_output=True)
-    except (subprocess.TimeoutExpired, OSError):
+            timeout=timeout, capture_output=True, text=True)
+    except (subprocess.TimeoutExpired, OSError) as e:
+        print(f"tune: backend-init probe did not finish: "
+              f"{type(e).__name__}: {e}", file=sys.stderr)
         return None
     if proc.returncode != 0:
+        tail = (proc.stderr or "").strip().splitlines()[-1:]
+        print(f"tune: backend-init probe exited {proc.returncode}"
+              f"{': ' + tail[0] if tail else ''}", file=sys.stderr)
         return None
     return float(max(clock() - t0, 1e-9))
 
@@ -289,13 +298,15 @@ def measure_collective(mesh, *, elems: int = 1 << 20,
 
 
 def measure_rig(*, seed: int = 0, quick: bool = False, clock=None,
-                include_backend_init: bool = True,
+                backend_init_s: float | None = None,
                 collective: dict | None = None) -> dict:
     """Run the seeded profiling pass; the measurements dict of a
     profile. ``clock`` is injectable for the determinism tests
     (default ``time.perf_counter`` — a duration clock, not wall
-    time). ``collective`` is a pre-measured ``measure_collective``
-    result (None = no mesh measured)."""
+    time). ``backend_init_s`` and ``collective`` are pre-measured
+    :func:`measure_backend_init` / :func:`measure_collective` results
+    (None = not measured) — the caller orders them, because the
+    first needs the chip free and the second takes it."""
     clock = clock or time.perf_counter
     rng = np.random.default_rng(seed)
     div = _QUICK_DIV if quick else 1
@@ -313,8 +324,7 @@ def measure_rig(*, seed: int = 0, quick: bool = False, clock=None,
             clock, rng, elems=max(1 << 14, _CODEC_ELEMS // div)),
         "host_ram_bytes": _host_ram_bytes(),
         "collective": collective,
-        "backend_init_s": (_measure_backend_init(clock)
-                           if include_backend_init else None),
+        "backend_init_s": backend_init_s,
         "quick": bool(quick),
     }
     return measurements

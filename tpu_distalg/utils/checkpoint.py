@@ -403,7 +403,7 @@ def run_with_restarts(run_once, max_restarts: int = 0, *, logger=None):
     detection").
 
     ``run_once()`` is invoked up to ``1 + max_restarts`` times; any
-    ``Exception`` (a device/tunnel crash, or :func:`run_segmented`'s
+    ``Exception`` (a device crash, or :func:`run_segmented`'s
     non-finite-state guard trip) triggers a retry. Recovery comes from
     pairing with a ``checkpoint_dir``: every workload's segmented
     runner resumes from the newest checkpoint on disk, so a retry
