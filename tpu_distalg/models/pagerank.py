@@ -64,6 +64,8 @@ from tpu_distalg.parallel import (
     partition,
     tree_allreduce_sum,
 )
+from tpu_distalg.telemetry import events as tevents
+from tpu_distalg.telemetry import names
 
 
 @dataclasses.dataclass(frozen=True)
@@ -197,34 +199,37 @@ def prepare_device_spmv(el: gops.EdgeList, mesh: Mesh,
     itself (``spmv_resident_bytes``) BEFORE paying the sorts, so
     oversized graphs degrade here instead of failing the Mosaic
     compile minutes later. Each plan attempt runs in a telemetry span
-    (``pagerank:plan_spmv:rgN``) — the sorts are exactly the kind of
+    (``pagerank:plan_spmv:rgN``, child of ``pagerank:prepare``, which
+    also covers the puts) — the sorts are exactly the kind of
     multi-minute host phase a stall report must be able to name."""
     from tpu_distalg.ops import pallas_pagerank as ppr
-    from tpu_distalg.telemetry import events as tevents
 
-    inv_deg = _inv_out_degree(el)
-    n_shards = mesh.shape[DATA_AXIS]
-    plan = None
-    for r in ((rg,) if rg is not None else (ppr.SPMV_RG, 256, 512)):
-        with tevents.span(f"pagerank:plan_spmv:rg{r}",
-                          n_edges=int(el.n_edges),
-                          n_vertices=int(el.n_vertices)):
-            plan = ppr.plan_spmv(el.src, el.dst, inv_deg[el.src],
-                                 el.n_vertices, n_shards=n_shards, rg=r)
-        if plan is not None:
-            break
-        tevents.counter("spmv_plan_rejections")
-    if plan is None:
-        return None
-    put = lambda a, n: partition.put(a, n, "pagerank", mesh)  # noqa: E731
-    return DeviceSpMV(
-        gbase=put(plan.gbase, "gbase"), sbase=put(plan.sbase, "sbase"),
-        src_lane=put(plan.src_lane, "src_lane"),
-        src_row=put(plan.src_row, "src_row"),
-        dst_row=put(plan.dst_row, "dst_row"),
-        dst_lane=put(plan.dst_lane, "dst_lane"),
-        w_e=put(plan.w_e, "w_e"), rg=plan.rg, ws=plan.ws, r8=plan.r8,
-        blk=plan.blk, n_chunks=plan.n_chunks)
+    with tevents.span("pagerank:prepare", edges=int(el.n_edges)):
+        inv_deg = _inv_out_degree(el)
+        n_shards = mesh.shape[DATA_AXIS]
+        plan = None
+        for r in ((rg,) if rg is not None else (ppr.SPMV_RG, 256, 512)):
+            with tevents.span(f"pagerank:plan_spmv:rg{r}",
+                              n_edges=int(el.n_edges),
+                              n_vertices=int(el.n_vertices)):
+                plan = ppr.plan_spmv(el.src, el.dst, inv_deg[el.src],
+                                     el.n_vertices, n_shards=n_shards,
+                                     rg=r)
+            if plan is not None:
+                break
+            tevents.counter("spmv_plan_rejections")
+        if plan is None:
+            return None
+        put = lambda a, n: partition.put(  # noqa: E731
+            a, n, "pagerank", mesh)
+        return DeviceSpMV(
+            gbase=put(plan.gbase, "gbase"), sbase=put(plan.sbase, "sbase"),
+            src_lane=put(plan.src_lane, "src_lane"),
+            src_row=put(plan.src_row, "src_row"),
+            dst_row=put(plan.dst_row, "dst_row"),
+            dst_lane=put(plan.dst_lane, "dst_lane"),
+            w_e=put(plan.w_e, "w_e"), rg=plan.rg, ws=plan.ws, r8=plan.r8,
+            blk=plan.blk, n_chunks=plan.n_chunks)
 
 
 def prepare_device_edges(el: gops.EdgeList, mesh: Mesh,
@@ -432,10 +437,11 @@ def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
         pad = (r8 + rg) * 128 - V
 
         def body(gb, sb, slane, srow, drow, dlane, we, ranks):
-            rt = jnp.pad(ranks, (0, pad)).reshape(r8 + rg, 128)
-            acc = ppr.spmv_table(gb, sb, rt, slane, srow, drow, dlane,
-                                 we, rg=rg, ws=ws, r8=r8, blk=blk,
-                                 interpret=interpret)
+            with jax.named_scope(names.PAGERANK_SPMV):
+                rt = jnp.pad(ranks, (0, pad)).reshape(r8 + rg, 128)
+                acc = ppr.spmv_table(gb, sb, rt, slane, srow, drow,
+                                     dlane, we, rg=rg, ws=ws, r8=r8,
+                                     blk=blk, interpret=interpret)
             return tree_allreduce_sum(acc)
 
         sweep_fn = data_parallel(

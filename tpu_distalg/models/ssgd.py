@@ -30,6 +30,7 @@ from tpu_distalg.parallel import (
     tree_allreduce_sum,
 )
 from tpu_distalg.telemetry import events as tevents
+from tpu_distalg.telemetry import names
 from tpu_distalg.utils import metrics, prng
 
 
@@ -157,6 +158,21 @@ def _ssp_comm_sync(mesh, config, d: int):
         config.comm, mesh, jax.ShapeDtypeStruct((d,), jnp.float32))
 
 
+def _eval_acc(config: SSGDConfig, w, t, last_acc, X_test, y_test):
+    """A step's test accuracy: every step, every ``eval_every`` steps
+    (the last one carried between), or never."""
+    if not config.eval_test:
+        return jnp.float32(0)
+    if config.eval_every == 1:
+        return metrics.binary_accuracy(X_test @ w, y_test)
+    return jax.lax.cond(
+        t % config.eval_every == 0,
+        lambda w: metrics.binary_accuracy(X_test @ w, y_test),
+        lambda w: last_acc,
+        w,
+    )
+
+
 def _build_scan_comm(config: SSGDConfig, sample_and_grad, prep_xs=None):
     """Comm-schedule variant of :func:`_build_scan`:
     ``sample_and_grad(X, y, valid, w, payload, t, res)`` → (Σ grad,
@@ -182,19 +198,10 @@ def _build_scan_comm(config: SSGDConfig, sample_and_grad, prep_xs=None):
             t, payload = x
             g, cnt, res, reg = sample_and_grad(
                 X, y, valid, w, payload, t, res)
-            n_batch = jnp.maximum(cnt, 1.0)  # guard empty sample
-            w = w - config.eta * (g / n_batch + config.lam * reg)
-            if config.eval_test and config.eval_every == 1:
-                acc = metrics.binary_accuracy(X_test @ w, y_test)
-            elif config.eval_test:
-                acc = jax.lax.cond(
-                    t % config.eval_every == 0,
-                    lambda w: metrics.binary_accuracy(X_test @ w, y_test),
-                    lambda w: last_acc,
-                    w,
-                )
-            else:
-                acc = jnp.float32(0)
+            with jax.named_scope(names.SSGD_UPDATE):
+                n_batch = jnp.maximum(cnt, 1.0)  # guard empty sample
+                w = w - config.eta * (g / n_batch + config.lam * reg)
+                acc = _eval_acc(config, w, t, last_acc, X_test, y_test)
             return (w, acc, res), acc
 
         (w, _, res), accs = jax.lax.scan(
@@ -233,22 +240,14 @@ def _build_scan(config: SSGDConfig, sample_and_grad, prep_xs=None):
             w, last_acc = carry
             t, payload = x
             g, cnt = sample_and_grad(X, y, valid, w, payload)
-            n_batch = jnp.maximum(cnt, 1.0)  # guard empty sample
-            reg = logistic.reg_gradient(
-                w, config.reg_type, config.elastic_alpha
-            )
-            w = w - config.eta * (g / n_batch + config.lam * reg)  # ssgd.py:105
-            if config.eval_test and config.eval_every == 1:
-                acc = metrics.binary_accuracy(X_test @ w, y_test)
-            elif config.eval_test:
-                acc = jax.lax.cond(
-                    t % config.eval_every == 0,
-                    lambda w: metrics.binary_accuracy(X_test @ w, y_test),
-                    lambda w: last_acc,
-                    w,
+            with jax.named_scope(names.SSGD_UPDATE):
+                n_batch = jnp.maximum(cnt, 1.0)  # guard empty sample
+                reg = logistic.reg_gradient(
+                    w, config.reg_type, config.elastic_alpha
                 )
-            else:
-                acc = jnp.float32(0)
+                w = w - config.eta * (
+                    g / n_batch + config.lam * reg)  # ssgd.py:105
+                acc = _eval_acc(config, w, t, last_acc, X_test, y_test)
             return (w, acc), acc
 
         (w, _), accs = jax.lax.scan(
@@ -1030,12 +1029,13 @@ def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
             # the shared without-replacement draw
             # (sampling.sample_block_ids), per-round key = fold_in(key,
             # absolute step id)
-            return jax.vmap(
-                lambda t: sampling.sample_block_ids(
-                    jax.random.fold_in(key, t),
-                    n_shards, n_blocks, n_sampled,
-                )
-            )(ts)                                        # (T, S, ns)
+            with jax.named_scope(names.SSGD_DRAW):
+                return jax.vmap(
+                    lambda t: sampling.sample_block_ids(
+                        jax.random.fold_in(key, t),
+                        n_shards, n_blocks, n_sampled,
+                    )
+                )(ts)                                    # (T, S, ns)
 
         if sync is not None:
             def _local_grad(X2, w, idx_shards, t, res):
@@ -1043,11 +1043,14 @@ def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
                 idx = lax.dynamic_index_in_dim(
                     idx_shards, shard, keepdims=False
                 )
-                g, cnt = kern(X2, w, idx)
-                (g, cnt), res, reg = sync.reduce(
-                    (g * col_keep, cnt), res, t,
-                    compute=lambda: logistic.reg_gradient(
-                        w, config.reg_type, config.elastic_alpha))
+                with jax.named_scope(names.SSGD_KERNEL):
+                    g, cnt = kern(X2, w, idx)
+                    g = g * col_keep
+                with jax.named_scope(names.SSGD_SYNC):
+                    (g, cnt), res, reg = sync.reduce(
+                        (g, cnt), res, t,
+                        compute=lambda: logistic.reg_gradient(
+                            w, config.reg_type, config.elastic_alpha))
                 return g, cnt, res, reg
         else:
             def _local_grad(X2, w, idx_shards):
@@ -1055,8 +1058,11 @@ def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
                 idx = lax.dynamic_index_in_dim(
                     idx_shards, shard, keepdims=False
                 )
-                g, cnt = kern(X2, w, idx)
-                return tree_allreduce_sum((g * col_keep, cnt))
+                with jax.named_scope(names.SSGD_KERNEL):
+                    g, cnt = kern(X2, w, idx)
+                    g = g * col_keep
+                with jax.named_scope(names.SSGD_SYNC):
+                    return tree_allreduce_sum((g, cnt))
     else:
         if not on_tpu:
             raise ValueError(
@@ -1074,17 +1080,23 @@ def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
         if sync is not None:
             def _local_grad(X2, w, t_payload, t, res):
                 shard = lax.axis_index(DATA_AXIS)
-                g, cnt = kern(X2, w, t_payload + config.seed, shard)
-                (g, cnt), res, reg = sync.reduce(
-                    (g * col_keep, cnt), res, t,
-                    compute=lambda: logistic.reg_gradient(
-                        w, config.reg_type, config.elastic_alpha))
+                with jax.named_scope(names.SSGD_KERNEL):
+                    g, cnt = kern(X2, w, t_payload + config.seed, shard)
+                    g = g * col_keep
+                with jax.named_scope(names.SSGD_SYNC):
+                    (g, cnt), res, reg = sync.reduce(
+                        (g, cnt), res, t,
+                        compute=lambda: logistic.reg_gradient(
+                            w, config.reg_type, config.elastic_alpha))
                 return g, cnt, res, reg
         else:
             def _local_grad(X2, w, t):
                 shard = lax.axis_index(DATA_AXIS)
-                g, cnt = kern(X2, w, t + config.seed, shard)
-                return tree_allreduce_sum((g * col_keep, cnt))
+                with jax.named_scope(names.SSGD_KERNEL):
+                    g, cnt = kern(X2, w, t + config.seed, shard)
+                    g = g * col_keep
+                with jax.named_scope(names.SSGD_SYNC):
+                    return tree_allreduce_sum((g, cnt))
 
     if sync is not None:
         grad_fn = data_parallel(
@@ -1176,18 +1188,21 @@ def _make_train_fn_mega(mesh: Mesh, config: SSGDConfig, meta: dict,
     def train(X2, y, valid, X_test, y_test, w0, t0=0, acc0=0.0):
         del y, valid  # labels/validity ride inside the packed X2
         ts = jnp.arange(T) + t0
-        idx = jax.vmap(
-            lambda t: sampling.sample_block_ids(
-                jax.random.fold_in(key, t), 1, n_blocks, n_sampled)
-        )(ts).reshape(T // mega, mega, n_sampled)
+        with jax.named_scope(names.SSGD_DRAW):
+            idx = jax.vmap(
+                lambda t: sampling.sample_block_ids(
+                    jax.random.fold_in(key, t), 1, n_blocks, n_sampled)
+            )(ts).reshape(T // mega, mega, n_sampled)
         w_tile0 = jnp.tile(w0, (meta["pack"],))[:, None]
 
         def seg(wt, idx_seg):
-            wt = kern(X2, wt, idx_seg)
-            acc = (
-                metrics.binary_accuracy(X_test @ wt[:d_t, 0], y_test)
-                if config.eval_test else jnp.float32(0)
-            )
+            with jax.named_scope(names.SSGD_KERNEL):
+                wt = kern(X2, wt, idx_seg)
+            with jax.named_scope(names.SSGD_UPDATE):
+                acc = (
+                    metrics.binary_accuracy(X_test @ wt[:d_t, 0], y_test)
+                    if config.eval_test else jnp.float32(0)
+                )
             return wt, acc
 
         w_tile, seg_accs = jax.lax.scan(seg, w_tile0, idx)
@@ -1420,6 +1435,14 @@ def fused_train_segment_lengths(checkpoint_dir, checkpoint_every: int,
     return lens
 
 
+def _train_span(config: SSGDConfig):
+    """An unsegmented run: the one call of the compiled schedule
+    (trace and compile with it the first time) and the fetch of the
+    finite-weights guard that ends it."""
+    return tevents.span("ssgd:train", sampler=config.sampler,
+                        steps=config.n_iterations)
+
+
 def _acc_carrying_run_seg(*data_args, w_put=None):
     """Segment runner shared by the XLA, fused and fused-tp checkpoint
     paths: state = (w, last_acc); the final emitted accuracy IS the
@@ -1458,10 +1481,6 @@ def train(
 
     from tpu_distalg.parallel import MODEL_AXIS, partition
 
-    # progress mark: the telemetry heartbeat names this phase if the
-    # compiled schedule wedges (checkpointed runs also mark per segment
-    # inside run_segmented)
-    tevents.mark(f"ssgd:{config.sampler}", emit_event=False)
     _check_comm_sampler(config)
     _check_sync_sampler(config)
     from tpu_distalg.parallel import ssp as _pssp
@@ -1529,8 +1548,9 @@ def train(
 
     if checkpoint_dir is None:
         fn = make_train_fn(mesh, config, Xs.n_padded)
-        w, accs = fn(X_data, ys.data, Xs.mask, X_te, y_te, w0)
-        metrics.guard_finite(w, "SSGD weights")
+        with _train_span(config):
+            w, accs = fn(X_data, ys.data, Xs.mask, X_te, y_te, w0)
+            metrics.guard_finite(w, "SSGD weights")
         return TrainResult(w=w[:d_orig], accs=accs)
 
     from tpu_distalg.utils import checkpoint as ckpt
@@ -1561,9 +1581,10 @@ def _train_comm(mesh, config, d, data_args, w0, *, make_fn,
 
     if checkpoint_dir is None:
         fn = fn if fn is not None else make_fn(config.n_iterations)
-        w, accs, _ = fn(*data_args, w0, res0)
+        with _train_span(config):
+            w, accs, _ = fn(*data_args, w0, res0)
+            metrics.guard_finite(w, "SSGD weights")
         comms.emit_sync_counters(sync, config.n_iterations)
-        metrics.guard_finite(w, "SSGD weights")
         return TrainResult(w=w[:crop], accs=accs)
 
     from tpu_distalg.utils import checkpoint as ckpt
@@ -1606,18 +1627,24 @@ def prepare_fused(X_train, y_train, mesh: Mesh, config: SSGDConfig):
     block = (config.gather_block_rows
              if config.sampler in ("fused_gather", "fused_train")
              else config.fused_block_rows)
-    X2, meta = pallas_kernels.pack_augmented(
-        np.asarray(X_train), np.asarray(y_train), np.ones(n, np.float32),
-        dtype=jnp.dtype(config.x_dtype),
-        pack=config.fused_pack,
-        block_rows=block * n_shards,
-        shuffle_seed=config.shuffle_seed,
-    )
-    X2 = partition.put(X2, "X2", "ssgd", mesh)
-    w0 = jnp.zeros((meta["d_total"],), jnp.float32).at[:d_orig].set(
-        logistic.init_weights(prng.root_key(config.init_seed), d_orig)
-    )
-    fn = make_train_fn_fused(mesh, config, meta)
+    with tevents.span("ssgd:prepare", rows=n):
+        with tevents.span("ssgd:pack", rows=n):
+            X2, meta = pallas_kernels.pack_augmented(
+                np.asarray(X_train), np.asarray(y_train),
+                np.ones(n, np.float32),
+                dtype=jnp.dtype(config.x_dtype),
+                pack=config.fused_pack,
+                block_rows=block * n_shards,
+                shuffle_seed=config.shuffle_seed,
+            )
+        with tevents.span("ssgd:h2d", rows=meta["n_padded"],
+                          bytes=int(X2.nbytes)):
+            X2 = partition.put(X2, "X2", "ssgd", mesh)
+            X2.block_until_ready()   # the span is the copy, not its enqueue
+        w0 = jnp.zeros((meta["d_total"],), jnp.float32).at[:d_orig].set(
+            logistic.init_weights(prng.root_key(config.init_seed), d_orig)
+        )
+        fn = make_train_fn_fused(mesh, config, meta)
     return fn, X2, w0, meta
 
 
@@ -1725,8 +1752,9 @@ def _train_fused_tp(
     y_te = jnp.asarray(y_test)
     dummy = jnp.zeros((1,), jnp.float32)
     if checkpoint_dir is None:
-        w, accs = fn(X2, dummy, dummy, X_te, y_te, w0)
-        metrics.guard_finite(w, "SSGD (fused tp) weights")
+        with _train_span(config):
+            w, accs = fn(X2, dummy, dummy, X_te, y_te, w0)
+            metrics.guard_finite(w, "SSGD (fused tp) weights")
         return TrainResult(w=tp_extract_weights(w, meta), accs=accs)
 
     from tpu_distalg.parallel import partition
@@ -1792,8 +1820,9 @@ def _train_fused(
             crop=d_orig, fn=fn,
         )
     if checkpoint_dir is None:
-        w, accs = fn(X2, dummy, dummy, X_te, y_te, w0)
-        metrics.guard_finite(w, "SSGD (fused) weights")
+        with _train_span(config):
+            w, accs = fn(X2, dummy, dummy, X_te, y_te, w0)
+            metrics.guard_finite(w, "SSGD (fused) weights")
         return TrainResult(w=w[:d_orig], accs=accs)
 
     from tpu_distalg.utils import checkpoint as ckpt

@@ -347,6 +347,7 @@ def fused_grad_sum_gathered(X2, w_aug, block_idx, *, pack: int,
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="_grad_kernel_gathered",
     )(block_idx.astype(jnp.int32), X2, C)
     g = jnp.einsum("ccj->j", gacc.reshape(P, P, D))
     return g, cnt[0, 0]
@@ -567,6 +568,7 @@ def fused_train_gathered(X2, w_tile0, block_idx, *, pack: int,
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="_train_kernel_gathered",
     )(block_idx.astype(jnp.int32), X2, msel, s_tile, eye_d, ew3, eyv,
       w_tile0, center_tile)
     return wout

@@ -487,6 +487,7 @@ def spmv_table(gbase, sbase, ranks_padded, src_lane, src_row, dst_row,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=128 * 1024 * 1024),
         interpret=interpret,
+        name="_spmv_kernel",
     )(gbase, sbase, ranks_padded, src_lane, src_row, dst_row, dst_lane,
       w_e)
 

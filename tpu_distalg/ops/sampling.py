@@ -13,6 +13,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from tpu_distalg.telemetry import names
+
 
 def bernoulli_mask(
     key: jax.Array, t, n: int, fraction: float, valid: jax.Array
@@ -40,11 +42,12 @@ def sample_block_ids(
     the absolute step id (and local-step index where applicable), so
     segmented checkpoint/resume replays identical draws.
     """
-    ks = jax.vmap(
-        lambda s: jax.random.fold_in(base_key, s)
-    )(jnp.arange(n_shards))
-    bits = jax.vmap(lambda k: jax.random.bits(k, (n_blocks,)))(ks)
-    return jnp.argsort(bits, axis=-1)[:, :n_sampled].astype(jnp.int32)
+    with jax.named_scope(names.SSGD_DRAW):
+        ks = jax.vmap(
+            lambda s: jax.random.fold_in(base_key, s)
+        )(jnp.arange(n_shards))
+        bits = jax.vmap(lambda k: jax.random.bits(k, (n_blocks,)))(ks)
+        return jnp.argsort(bits, axis=-1)[:, :n_sampled].astype(jnp.int32)
 
 
 def mc_circle_hits(key: jax.Array, n: int) -> jax.Array:

@@ -29,7 +29,13 @@ Conventions:
     stall deadline; a run that stops marking IS the hang signal.
   * ``span(name)`` wraps a timed phase: ``span_start``/``span_end``
     events with the duration and error status, and a mark at both
-    edges. ``tda report`` aggregates spans into per-phase durations.
+    edges. Every span has an ``id`` and the ``parent`` id of the span
+    open on its thread when it began, so ``tda report`` prints spans as
+    a tree with self times. Once ``jax`` is imported the span is also a
+    ``jax.profiler.TraceAnnotation`` named ``tda:<name>``: under a
+    profiler session (``--profile``) the host phase lands on the
+    profiler's clock beside the device ops; without one it records
+    nothing.
   * counters are in-memory (thread-safe) and flushed as one
     ``counters`` event at close; gauges/metrics are emitted inline.
 
@@ -43,6 +49,7 @@ from __future__ import annotations
 
 import atexit
 import contextlib
+import itertools
 import json
 import os
 import socket
@@ -52,6 +59,8 @@ import time
 import uuid
 
 ENV_DIR = "TDA_TELEMETRY_DIR"
+# a span's name in a profiler trace is this prefix + its name
+ANNOTATION_PREFIX = "tda:"
 
 _LOCK = threading.Lock()  # guards the _SINK swap only
 _SINK: EventSink | None = None
@@ -60,6 +69,8 @@ _SINK: EventSink | None = None
 # the tuple when telemetry is disabled (heartbeat stall math still
 # works against it either way)
 _LAST_MARK: tuple[float, str] = (time.monotonic(), "start")
+_SPAN_IDS = itertools.count(1)   # next() is atomic under the GIL
+_OPEN_SPANS = threading.local()  # .stack: ids of this thread's open spans
 
 
 class EventSink:
@@ -190,33 +201,56 @@ def gauge(name: str, value, **fields) -> None:
     emit("gauge", name=name, value=value, **fields)
 
 
+def _annotation(name: str, **args):
+    """The span as a ``TraceAnnotation`` on the profiler's clock, or
+    ``None`` while ``jax`` is not imported (this package never imports
+    it: the CLI configures telemetry before a backend exists)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **args)
+
+
 @contextlib.contextmanager
 def span(name: str, **fields):
     """Timed phase: ``span_start``/``span_end`` (+duration, +error on
-    failure) around the body, with a progress mark at both edges."""
+    failure) around the body, with a progress mark at both edges, and
+    the same interval as ``tda:<name>`` in a profiler trace. ``id`` and
+    ``parent`` (the span open on this thread when this one began, or
+    ``None``) ride in both. A phase boundary, never a per-step call."""
     mark(name, emit_event=False)
     sink = _SINK
-    if sink is None:
+    stack = _OPEN_SPANS.__dict__.setdefault("stack", [])
+    sid, parent = next(_SPAN_IDS), (stack[-1] if stack else None)
+    note = _annotation(name, id=sid, parent=parent or 0)
+    if sink is None and note is None:
         yield
         return
+    stack.append(sid)
     t0 = time.monotonic()
-    sink.write("span_start", name=name, **fields)
+    if sink is not None:
+        sink.write("span_start", name=name,
+                   **{**fields, "id": sid, "parent": parent})
     err = None
     try:
-        yield
+        with note or contextlib.nullcontext():
+            yield
     except BaseException as e:
         err = f"{type(e).__name__}: {e}"
         raise
     finally:
-        # ONE merged dict, span keys overwriting caller fields: twin
-        # splats would TypeError out of this finally on a caller-
-        # supplied 'error'/'seconds'/'ok' and mask the real exception
-        end = dict(fields)
-        end.update(seconds=round(time.monotonic() - t0, 6),
-                   ok=err is None)
-        if err is not None:
-            end["error"] = err
-        sink.write("span_end", name=name, **end)
+        stack.pop()
+        if sink is not None:
+            # ONE merged dict, span keys overwriting caller fields: twin
+            # splats would TypeError out of this finally on a caller-
+            # supplied 'error'/'seconds'/'ok' and mask the real exception
+            end = dict(fields)
+            end.update(seconds=round(time.monotonic() - t0, 6),
+                       ok=err is None, id=sid, parent=parent)
+            if err is not None:
+                end["error"] = err
+            sink.write("span_end", name=name, **end)
         mark(name, emit_event=False)
 
 

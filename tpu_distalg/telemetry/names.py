@@ -1,0 +1,21 @@
+"""The names the program owns in a profiler trace.
+
+Device side: ``jax.named_scope`` strings set where the work is done, so
+they exist at trace time only and ride in every op's ``op_name``
+metadata (XLA keeps it through fusion; a profiler trace stores each
+program's HLO, ``op_name`` included, beside its events). A reader finds a step's parts by these
+and not by the names XLA gives its instructions, which a refactor or a
+compiler release moves. Host side: every ``telemetry.span(name)`` is
+``tda:<name>`` on the profiler's clock (``events.ANNOTATION_PREFIX``).
+
+Stdlib-only, like the rest of this package.
+"""
+
+# the four parts of an SSGD step (models/ssgd.py, ops/sampling.py);
+# local_sgd's rounds draw through the same sampling function
+SSGD_DRAW = "tda.ssgd.draw"      # threefry bits, the argsort, the slice
+SSGD_KERNEL = "tda.ssgd.kernel"  # the Mosaic call and what XLA puts round it
+SSGD_SYNC = "tda.ssgd.sync"      # the psum (dense) or the comm schedule
+SSGD_UPDATE = "tda.ssgd.update"  # the rest of a step: reg, update, eval
+# the fused SpMV sweep (models/pagerank.py)
+PAGERANK_SPMV = "tda.pagerank.spmv"

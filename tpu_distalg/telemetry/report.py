@@ -1,7 +1,8 @@
 """Event-log summarization — ``tda report <dir>``.
 
 Turns a telemetry JSONL log into the 3-line diagnosis round 5 lacked:
-phase durations (from spans), stall/retry/restart counts, backend-init
+phase durations (spans as a tree, each with its self time: duration
+minus what its child spans cover), stall/retry/restart counts, backend-init
 attempt history and resolution, last heartbeat age, and every recorded
 metric/gauge — for humans (default rendering) and CI (``--json``).
 Tolerates torn tail lines (a killed process loses at most the line it
@@ -49,6 +50,64 @@ def load_events(path: str) -> list[dict]:
                     out.append(rec)
     if torn:
         out.insert(0, {"ev": "_torn_lines", "count": torn})
+    return out
+
+
+def span_tree(evts: list[dict]) -> list[dict]:
+    """Spans as a tree, depth first in order of first appearance: one
+    node per distinct path of names from a root span down, with its
+    count, total and largest duration, and ``self_seconds`` = total
+    minus what its direct child spans cover. A span's parent is the
+    ``parent`` id it recorded (the span open on its thread when it
+    began); spans of logs older than the ids are roots."""
+    names: dict[tuple, tuple] = {}     # (run, id) -> (name, parent key)
+    ended: list[tuple] = []            # (key, seconds, ok)
+    for n, e in enumerate(evts):
+        if e.get("ev") not in ("span_start", "span_end"):
+            continue
+        run, sid = e.get("run"), e.get("id")
+        key = (run, sid) if sid is not None else (run, f"_{n}")
+        parent = e.get("parent")
+        names[key] = (e.get("name", "?"),
+                      (run, parent) if parent is not None else None)
+        if e["ev"] == "span_end":
+            ended.append((key, float(e.get("seconds", 0.0)),
+                          bool(e.get("ok", True))))
+
+    def path_of(key) -> tuple:
+        out, seen = [], set()
+        while key in names and key not in seen:
+            seen.add(key)
+            name, key = names[key]
+            out.append(name)
+        return tuple(reversed(out))
+
+    nodes: dict[tuple, dict] = {}
+    for key, seconds, ok in ended:
+        path = path_of(key)
+        for depth in range(1, len(path) + 1):   # ancestors first, so an
+            nodes.setdefault(path[:depth], {    # open parent has a node
+                "path": list(path[:depth]), "name": path[depth - 1],
+                "depth": depth - 1, "count": 0, "total_seconds": 0.0,
+                "max_seconds": 0.0, "child_seconds": 0.0, "errors": 0})
+        node = nodes[path]
+        node["count"] += 1
+        node["total_seconds"] += seconds
+        node["max_seconds"] = max(node["max_seconds"], seconds)
+        node["errors"] += not ok
+        if len(path) > 1:
+            nodes[path[:-1]]["child_seconds"] += seconds
+    first = {path: n for n, path in enumerate(nodes)}
+    out = []
+    for path in sorted(nodes, key=lambda p: [
+            first[p[:d]] for d in range(1, len(p) + 1)]):
+        node = nodes[path]
+        child = node.pop("child_seconds")
+        node["self_seconds"] = round(
+            max(node["total_seconds"] - child, 0.0), 6)
+        node["total_seconds"] = round(node["total_seconds"], 6)
+        node["max_seconds"] = round(node["max_seconds"], 6)
+        out.append(node)
     return out
 
 
@@ -141,6 +200,7 @@ def summarize(evts: list[dict]) -> dict:
         "wall_seconds": (round(max(t_wall) - min(t_wall), 3)
                          if t_wall else 0.0),
         "phases": phases,
+        "span_tree": span_tree(evts),
         "unfinished_phases": sorted(
             k for k, v in open_spans.items() if v > 0),
         "marks": marks,
@@ -169,14 +229,15 @@ def render(s: dict) -> str:
         f"events: {s['n_events']}  wall: {s['wall_seconds']}s  "
         f"marks: {s['marks']}  heartbeats: {s['heartbeats']}",
     ]
-    if s["phases"]:
-        lines.append("phase durations:")
-        for name, p in sorted(s["phases"].items(),
-                              key=lambda kv: -kv[1]["total_seconds"]):
+    if s["span_tree"]:
+        lines.append("phase durations (self = duration minus child "
+                     "spans):")
+        for p in s["span_tree"]:
             err = f"  errors: {p['errors']}" if p["errors"] else ""
             lines.append(
-                f"  {name}: {p['total_seconds']}s total over "
-                f"{p['count']} span(s), max {p['max_seconds']}s{err}")
+                f"  {'  ' * p['depth']}{p['name']}: "
+                f"{p['total_seconds']}s total over {p['count']} span(s), "
+                f"max {p['max_seconds']}s, self {p['self_seconds']}s{err}")
     for name in s["unfinished_phases"]:
         lines.append(f"  {name}: UNFINISHED (no span_end recorded)")
     hb = s["last_heartbeat"]

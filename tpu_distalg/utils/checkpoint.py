@@ -33,6 +33,7 @@ each compiled segment) — see ``tpu_distalg/faults/registry.py``.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import re
 import struct
@@ -363,26 +364,37 @@ def run_segmented(
         if stop_when is not None and stop_when(state):
             break
         seg = min(checkpoint_every, n_iterations - t)
-        # progress mark per segment: the telemetry heartbeat flags this
-        # phase if a segment wedges (device hang) instead of staying mute
-        tevents.mark(f"segment:{tag or 'train'}@{t}", emit_event=False)
-        faults.inject("segment:run")
-        if seg not in seg_fns:
-            seg_fns[seg] = make_seg_fn(seg)
-        state, accs = run_seg(seg_fns[seg], state, t)
-        metrics.guard_finite(
-            state, f"training state after step {t + seg}"
-        )
+        # a new segment length builds, and its first call traces and
+        # compiles (or loads from the cache): that first segment sits
+        # under train:build, the steady ones stand alone. The spans
+        # mark at both edges, so the telemetry heartbeat names the
+        # phase if a segment wedges (device hang) instead of staying
+        # mute
+        with contextlib.ExitStack() as build:
+            if seg not in seg_fns:
+                build.enter_context(
+                    tevents.span("train:build", tag=tag, seg=seg))
+                seg_fns[seg] = make_seg_fn(seg)
+            with tevents.span("train:segment", tag=tag, t0=t, steps=seg):
+                faults.inject("segment:run")
+                state, accs = run_seg(seg_fns[seg], state, t)
+                metrics.guard_finite(
+                    state, f"training state after step {t + seg}"
+                )
         t += seg
-        accs_parts.append(np.asarray(accs))
-        save(
-            checkpoint_dir,
-            {"tag": encode_tag(tag),
-             "state": [np.asarray(x) for x in jax.tree.leaves(state)],
-             "accs": np.concatenate(accs_parts)},
-            step=t,
-        )
-        prune(checkpoint_dir, keep=keep)
+        # what the checkpoint holds, sized without fetching it
+        held = sum(int(getattr(x, "nbytes", 0)) for x in
+                   [*jax.tree.leaves(state), *accs_parts, accs])
+        with tevents.span("train:checkpoint", step=t, bytes=held):
+            accs_parts.append(np.asarray(accs))
+            save(
+                checkpoint_dir,
+                {"tag": encode_tag(tag),
+                 "state": [np.asarray(x) for x in jax.tree.leaves(state)],
+                 "accs": np.concatenate(accs_parts)},
+                step=t,
+            )
+            prune(checkpoint_dir, keep=keep)
         tevents.emit("checkpoint_saved", step=t, tag=tag)
         tevents.counter("checkpoints_saved")
         if t < n_iterations:
