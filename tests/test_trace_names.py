@@ -123,9 +123,31 @@ def test_checkpointed_train_phases_on_the_profilers_clock(
     seg = [e for e in ends if e["name"] == "train:segment"]
     assert [(e["t0"], e["steps"]) for e in seg] == [(0, 20), (20, 20)]
     assert all(e["tag"] == "ssgd:fused_train" for e in seg)
+    assert all(e["draw_form"] == "few" for e in seg)   # 1 of 13 blocks
     ck = [e for e in ends if e["name"] == "train:checkpoint"]
     assert [e["step"] for e in ck] == [20, 40]
     assert all(e["bytes"] > 0 for e in ck)
+
+
+def test_train_span_and_report_say_how_the_draw_selected(
+        mesh1, cancer_data, sink_dir):
+    """An unsegmented fused run: ``ssgd:train`` carries the form the
+    block draw took at this geometry, and ``tda report`` prints it."""
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")       # coarse-fraction geometry
+        ssgd.train(*cancer_data, mesh1, dataclasses.replace(
+            CFG, mega_steps=2, n_iterations=2))
+    (train,) = [e for e in _spans(sink_dir) if e["name"] == "ssgd:train"]
+    assert train["sampler"] == "fused_train"
+    assert train["draw_form"] == "few"        # 1 of 13 blocks
+    evts = report.load_events(sink_dir)
+    assert "block draw: few" in report.render(
+        report.summarize(evts)).splitlines()
+    # a log of two runs that drew differently names both
+    evts.append({"ev": "span_start", "name": "train:segment",
+                 "draw_form": "sort"})
+    assert "block draw: few, sort" in report.render(
+        report.summarize(evts)).splitlines()
 
 
 def test_span_annotates_with_the_sink_off(tmp_path, monkeypatch):
@@ -322,7 +344,8 @@ def test_report_tree_from_a_recorded_run(sink_dir, capsys):
 
 # ---- device scopes -----------------------------------------------------
 
-def _lowered(sampler, shards, **kw):
+def _trainer(sampler, shards, **kw):
+    """(jitted trainer, abstract arguments) at a tiny geometry."""
     from tpu_distalg.parallel import get_mesh
 
     mesh = get_mesh(data=shards, model=1, devices=jax.devices()[:shards])
@@ -342,7 +365,37 @@ def _lowered(sampler, shards, **kw):
             jax.ShapeDtypeStruct((d_t,), jnp.float32)]
     if config.comm != "dense":      # the error-feedback residual rides
         args.append(jax.ShapeDtypeStruct((shards, d_t), jnp.float32))
-    return fn.lower(*args)
+    return fn, args
+
+
+# what only the block draw runs: the selection behind its batching rule
+# and, in the 'sort' form, the sort (threefry words are also drawn by
+# the int8 schedule's rounding, under the sync scope)
+_DRAW_PRIMITIVES = ("custom_vmap_call", "sort")
+
+
+def _scopes_of(fn, *args, primitives=_DRAW_PRIMITIVES):
+    """(primitive, full name stack) of every equation of ``fn``'s
+    jaxpr whose primitive is one of ``primitives``, at any depth, and
+    of everything under a ``custom_vmap_call``. A nested jaxpr's name
+    stacks are relative to the equation that holds it."""
+    found = []
+
+    def walk(jaxpr, prefix, inside):
+        for eqn in jaxpr.eqns:
+            stack = f"{prefix}/{eqn.source_info.name_stack}"
+            name = eqn.primitive.name
+            if inside or name in primitives:
+                found.append((name, stack))
+            for p in eqn.params.values():
+                inner = getattr(p, "jaxpr", p)
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    walk(inner, stack,
+                         inside or name == "custom_vmap_call")
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, "", False)
+    return found
 
 
 @pytest.mark.parametrize("sampler,shards,extra,scopes", [
@@ -357,26 +410,45 @@ def _lowered(sampler, shards, **kw):
 ])
 def test_lowered_trainers_name_their_scopes(sampler, shards, extra,
                                             scopes):
-    text = _lowered(sampler, shards, **extra).as_text(debug_info=True)
+    fn, args = _trainer(sampler, shards, **extra)
+    text = fn.lower(*args).as_text(debug_info=True)
     for scope in scopes:
         assert scope + "/" in text, scope
     if shards == 1:
         assert names.SSGD_SYNC not in text    # one shard: nothing to sync
-    # the draw's argsort and the kernel sit under their scopes
-    assert any(names.SSGD_DRAW in ln and "argsort" in ln
+    # the draw's words, its selection and all the selection runs sit
+    # under the draw's scope, and nothing of the draw sits outside it
+    drawn = _scopes_of(fn, *args)
+    for primitive in ("custom_vmap_call", "reduce"):
+        assert primitive in {p for p, _ in drawn}, primitive
+    for primitive, stack in drawn:
+        assert names.SSGD_DRAW in stack, (primitive, stack)
+    words = _scopes_of(fn, *args, primitives=("random_bits",))
+    assert any(names.SSGD_DRAW in stack for _, stack in words)
+    assert all("tda.ssgd." in stack for _, stack in words)
+    assert any(names.SSGD_DRAW in ln and "custom_vmap_call" in ln
                for ln in text.splitlines())
     wrapper = ("fused_train_gathered" if sampler == "fused_train"
                else "fused_grad_sum_gathered")
     assert f"{names.SSGD_KERNEL}/jit({wrapper})" in text
 
 
-def test_local_sgd_draws_under_the_same_scope():
+@pytest.mark.parametrize("n_sampled,selects_by", [
+    (4, "reduce"), (600, "sort")], ids=["few", "sort"])
+def test_local_sgd_draws_under_the_same_scope(n_sampled, selects_by):
     from tpu_distalg.ops import sampling
 
-    text = jax.jit(
-        lambda k: sampling.sample_block_ids(k, 2, 16, 4)
-    ).lower(jax.random.key(0)).as_text(debug_info=True)
+    def draw(k):
+        return sampling.sample_block_ids(k, 2, 1024, n_sampled)
+
+    text = jax.jit(draw).lower(jax.random.key(0)).as_text(
+        debug_info=True)
     assert names.SSGD_DRAW + "/" in text
+    drawn = _scopes_of(draw, jax.random.key(0),
+                       primitives=_DRAW_PRIMITIVES + ("random_bits",))
+    assert {selects_by, "random_bits"} <= {p for p, _ in drawn}
+    for primitive, stack in drawn:
+        assert names.SSGD_DRAW in stack, (primitive, stack)
 
 
 def _pallas_call_names(fn, *args):

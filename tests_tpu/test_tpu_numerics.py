@@ -457,3 +457,41 @@ def test_carry_leaves_have_one_shard_per_device(tpu_mesh):
     U, V, errs = als.make_fit_fn(mesh, acfg)(R, U0, V0)
     assert bool(jnp.isfinite(errs).all())
     check("als_train", mesh, {"R": R, "U": U, "V": V})
+
+
+def test_block_draw_selection_equals_compiled_argsort(tpu_mesh,
+                                                      monkeypatch):
+    """The compiled selection of ``sampling.sample_block_ids`` against
+    the compiled ``argsort`` slice at the benchmark cells' shapes (12
+    and 1221 of 12 208 blocks, 250 steps, one and four shards), over
+    the threefry words and over words crowded with ties and all-ones:
+    a tie rule of the chip's sort or reduce that differs from the
+    CPU's shows here and not as a ``w_rel_err``."""
+    from tpu_distalg.ops import sampling
+
+    key = jax.random.key(42)
+    ts = jnp.arange(250) + 1_400_000_103
+    real = jax.random.bits
+    table = jnp.asarray([0, 7, 0xFFFFFFFF], jnp.uint32)
+
+    def tied(k, shape):
+        return table[real(k, shape) % np.uint32(3)]
+
+    def restated(bits, t, n_shards, n_blocks, n_sampled):
+        ks = jax.vmap(lambda s: jax.random.fold_in(
+            jax.random.fold_in(key, t), s))(jnp.arange(n_shards))
+        words = jax.vmap(lambda k: bits(k, (n_blocks,)))(ks)
+        return jnp.argsort(words, axis=-1)[:, :n_sampled].astype(
+            jnp.int32)
+
+    for bits in (real, tied):
+        monkeypatch.setattr(sampling.jax.random, "bits", bits)
+        for shape in ((1, 12208, 12), (1, 12208, 1221),
+                      (4, 12208, 1221), (4, 12208, 12)):
+            got = jax.jit(jax.vmap(lambda t: sampling.sample_block_ids(
+                jax.random.fold_in(key, t), *shape)))(ts)
+            want = jax.jit(jax.vmap(
+                lambda t: restated(bits, t, *shape)))(ts)
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(want),
+                err_msg=f"{shape} {bits.__name__}")

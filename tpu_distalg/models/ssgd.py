@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -971,8 +972,6 @@ def warn_quantized_fraction(prefix: str, n_blocks: int, n_sampled: int,
     so the tolerance and message cannot drift between them."""
     eff = n_sampled / n_blocks
     if abs(eff - frac) > 0.25 * frac:
-        import warnings
-
         warnings.warn(
             f"{prefix}: {n_blocks} blocks/shard quantizes the minibatch "
             f"fraction to {eff:.3f} (configured {frac}); {remedy}",
@@ -1435,12 +1434,28 @@ def fused_train_segment_lengths(checkpoint_dir, checkpoint_every: int,
     return lens
 
 
-def _train_span(config: SSGDConfig):
+def _train_span(config: SSGDConfig, **fields):
     """An unsegmented run: the one call of the compiled schedule
     (trace and compile with it the first time) and the fetch of the
     finite-weights guard that ends it."""
     return tevents.span("ssgd:train", sampler=config.sampler,
-                        steps=config.n_iterations)
+                        steps=config.n_iterations, **fields)
+
+
+def _draw_fields(config: SSGDConfig, meta: dict, mesh: Mesh) -> dict:
+    """What the training spans of a fused run say about its block draw:
+    the form ``sampling.sample_block_ids`` takes at this geometry
+    (``tda report`` prints it). 'fused' draws on the core, not by
+    blocks, and says nothing."""
+    from tpu_distalg.parallel import DATA_AXIS
+
+    if config.sampler not in ("fused_gather", "fused_train"):
+        return {}
+    with warnings.catch_warnings():      # the builder has warned already
+        warnings.simplefilter("ignore")
+        n_blocks, n_sampled = fused_gather_geometry(
+            config, meta, mesh.shape[DATA_AXIS])
+    return {"draw_form": sampling.draw_form(n_blocks, n_sampled)}
 
 
 def _acc_carrying_run_seg(*data_args, w_put=None):
@@ -1569,7 +1584,8 @@ def train(
 
 
 def _train_comm(mesh, config, d, data_args, w0, *, make_fn,
-                checkpoint_dir, checkpoint_every, tag, crop, fn=None):
+                checkpoint_dir, checkpoint_every, tag, crop, fn=None,
+                span_fields=None):
     """Comm-schedule training driver shared by the XLA and fused paths:
     the scan carry/checkpoint state is ``(w, last_acc, residual)`` —
     the flat error-feedback residual persists across segments, so a
@@ -1581,7 +1597,7 @@ def _train_comm(mesh, config, d, data_args, w0, *, make_fn,
 
     if checkpoint_dir is None:
         fn = fn if fn is not None else make_fn(config.n_iterations)
-        with _train_span(config):
+        with _train_span(config, **(span_fields or {})):
             w, accs, _ = fn(*data_args, w0, res0)
             metrics.guard_finite(w, "SSGD weights")
         comms.emit_sync_counters(sync, config.n_iterations)
@@ -1602,6 +1618,7 @@ def _train_comm(mesh, config, d, data_args, w0, *, make_fn,
         run_seg=run_seg,
         state0=(w0, jnp.float32(0), res0),
         tag=f"{tag}:comm={config.comm}",
+        span_fields=span_fields,
     )
     # count only the syncs THIS process ran — a resumed run performed
     # n_iterations - start, not the full schedule
@@ -1807,6 +1824,7 @@ def _train_fused(
     )
     y_te = jnp.asarray(y_test)
     dummy = jnp.zeros((1,), jnp.float32)
+    draw = _draw_fields(config, meta, mesh)
     if config.comm != "dense":
         return _train_comm(
             mesh, config, meta["d_total"],
@@ -1817,10 +1835,10 @@ def _train_fused(
             checkpoint_dir=checkpoint_dir,
             checkpoint_every=checkpoint_every,
             tag=f"ssgd:{config.sampler}",
-            crop=d_orig, fn=fn,
+            crop=d_orig, fn=fn, span_fields=draw,
         )
     if checkpoint_dir is None:
-        with _train_span(config):
+        with _train_span(config, **draw):
             w, accs = fn(X2, dummy, dummy, X_te, y_te, w0)
             metrics.guard_finite(w, "SSGD (fused) weights")
         return TrainResult(w=w[:d_orig], accs=accs)
@@ -1864,5 +1882,6 @@ def _train_fused(
         run_seg=_acc_carrying_run_seg(X2, dummy, dummy, X_te, y_te),
         state0=(w0, jnp.float32(0)),
         tag=f"ssgd:{config.sampler}",
+        span_fields=draw,
     )
     return TrainResult(w=jnp.asarray(w)[:d_orig], accs=jnp.asarray(accs))

@@ -13,7 +13,7 @@ Stdlib-only, like the rest of this package.
 
 # the four parts of an SSGD step (models/ssgd.py, ops/sampling.py);
 # local_sgd's rounds draw through the same sampling function
-SSGD_DRAW = "tda.ssgd.draw"      # threefry bits, the argsort, the slice
+SSGD_DRAW = "tda.ssgd.draw"      # threefry words, the selection of the least
 SSGD_KERNEL = "tda.ssgd.kernel"  # the Mosaic call and what XLA puts round it
 SSGD_SYNC = "tda.ssgd.sync"      # the psum (dense) or the comm schedule
 SSGD_UPDATE = "tda.ssgd.update"  # the rest of a step: reg, update, eval

@@ -126,6 +126,7 @@ def summarize(evts: list[dict]) -> dict:
     last_heartbeat = None
     resolution = None
     runs: list[str] = []
+    draw_forms: list[str] = []
     t_wall = [e["t_wall"] for e in evts if "t_wall" in e]
     for e in evts:
         ev = e.get("ev")
@@ -135,6 +136,11 @@ def summarize(evts: list[dict]) -> dict:
         if ev == "span_start":
             open_spans[e.get("name", "?")] = \
                 open_spans.get(e.get("name", "?"), 0) + 1
+            # the training spans of the block-drawing samplers say how
+            # the draw selected (sampling.draw_form)
+            form = e.get("draw_form")
+            if form and form not in draw_forms:
+                draw_forms.append(form)
         elif ev == "span_end":
             name = e.get("name", "?")
             open_spans[name] = open_spans.get(name, 1) - 1
@@ -201,6 +207,7 @@ def summarize(evts: list[dict]) -> dict:
                          if t_wall else 0.0),
         "phases": phases,
         "span_tree": span_tree(evts),
+        "draw_forms": draw_forms,
         "unfinished_phases": sorted(
             k for k, v in open_spans.items() if v > 0),
         "marks": marks,
@@ -240,6 +247,8 @@ def render(s: dict) -> str:
                 f"max {p['max_seconds']}s, self {p['self_seconds']}s{err}")
     for name in s["unfinished_phases"]:
         lines.append(f"  {name}: UNFINISHED (no span_end recorded)")
+    if s.get("draw_forms"):
+        lines.append(f"block draw: {', '.join(s['draw_forms'])}")
     hb = s["last_heartbeat"]
     lines.append(
         "last heartbeat: "

@@ -284,6 +284,7 @@ def run_segmented(
     tag: str = "",
     keep: int = 3,
     stop_when=None,
+    span_fields: dict | None = None,
 ):
     """Generic segmented/resumable training loop — the machinery behind
     every workload's ``checkpoint_dir`` option.
@@ -310,7 +311,9 @@ def run_segmented(
     to ``n_iterations`` — the segment bodies must make post-convergence
     segments no-ops (carry their convergence signal in ``state``) so
     segmented and straight runs stay bitwise-identical. Returns
-    ``(state, accs_concat, start_step)``.
+    ``(state, accs_concat, start_step)``. ``span_fields`` ride on every
+    ``train:segment`` span (what the builder knows about the segments
+    and the loop does not, e.g. SSGD's ``draw_form``).
 
     Preemption: once ``faults.preempt`` has a pending request (SIGTERM/
     SIGINT), the loop raises :class:`~tpu_distalg.faults.Preempted` at
@@ -375,7 +378,8 @@ def run_segmented(
                 build.enter_context(
                     tevents.span("train:build", tag=tag, seg=seg))
                 seg_fns[seg] = make_seg_fn(seg)
-            with tevents.span("train:segment", tag=tag, t0=t, steps=seg):
+            with tevents.span("train:segment", tag=tag, t0=t, steps=seg,
+                              **(span_fields or {})):
                 faults.inject("segment:run")
                 state, accs = run_seg(seg_fns[seg], state, t)
                 metrics.guard_finite(
