@@ -1389,7 +1389,8 @@ def _dispatch(args, jax):
                                    converge_dist=args.converge_dist,
                                    init="farthest"),
                     checkpoint_dir=args.checkpoint_dir,
-                    checkpoint_every=args.checkpoint_every)
+                    checkpoint_every=args.checkpoint_every,
+                    data_seed=0)
 
             pts = None  # points never leave the devices (O(k) host RAM)
         else:

@@ -21,6 +21,8 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpu_distalg.ops import kmeans as kops
+from tpu_distalg.telemetry import events as tevents
+from tpu_distalg.telemetry import names
 from tpu_distalg.parallel import (
     data_parallel,
     mesh_on_tpu,
@@ -52,10 +54,78 @@ class KMeansResult:
 
 
 def _local_stats(points, mask, centers):
-    assign = kops.assign_clusters(points, centers)
-    sums, counts = kops.cluster_stats(points, mask, assign, centers.shape[0])
-    sums, counts = tree_allreduce_sum((sums, counts))
+    with jax.named_scope(names.KMEANS_ASSIGN):
+        assign = kops.assign_clusters(points, centers)
+    with jax.named_scope(names.KMEANS_STATS):
+        sums, counts = kops.cluster_stats(
+            points, mask, assign, centers.shape[0])
+    with jax.named_scope(names.KMEANS_SYNC):
+        sums, counts = tree_allreduce_sum((sums, counts))
     return sums, counts, assign
+
+
+def _mesh_fns(mesh: Mesh, lanes):
+    """``(stats, assign)`` over the mesh for either layout of the
+    points: ``stats(data, valid, centers) -> (sums, counts)`` summed
+    over the shards, ``assign(data, valid, centers)`` the nearest centre
+    of every point held, padding included, in id order.
+
+    Row layout (``lanes`` None): ``data`` ``(n, dim)``, ``valid`` the
+    ``(n,)`` mask, ``ops/kmeans.py``; counts are float32. Lanes layout:
+    ``data`` ``(n_blocks, dim, R, 128)``, ``valid`` the count of valid
+    points (validity follows from the id), one ``pallas_lloyd`` kernel
+    a pass; counts are int32. ``lanes`` is a
+    ``pallas_lloyd.LanesGeometry``."""
+    if lanes is None:
+        both = data_parallel(
+            _local_stats, mesh,
+            in_specs=(P("data", None), P("data"), P()),
+            out_specs=(P(), P(), P("data")),
+        )
+        return (lambda p, m, c: both(p, m, c)[:2],
+                lambda p, m, c: both(p, m, c)[2])
+
+    # Pallas costs a second to import: only where a kernel is built
+    from tpu_distalg.ops import pallas_lloyd as lloyd
+
+    interpret = not mesh_on_tpu(mesh)
+    x_spec = P("data", None, None, None)
+
+    def mine(x4, n_valid):
+        """How many of this shard's points are valid."""
+        n_local = x4.shape[0] * lanes.block_points
+        first = jax.lax.axis_index("data") * n_local
+        return jnp.clip(n_valid - first, 0, n_local)
+
+    def local_stats(x4, n_valid, centers):
+        with jax.named_scope(names.KMEANS_ASSIGN):
+            sums8, counts8 = lloyd.lloyd_pass(
+                x4, centers, mine(x4, n_valid), interpret=interpret)
+        with jax.named_scope(names.KMEANS_STATS):
+            stats = lloyd.fold_stats(sums8, counts8)
+        with jax.named_scope(names.KMEANS_SYNC):
+            return tree_allreduce_sum(stats)
+
+    def local_assign(x4, n_valid, centers):
+        with jax.named_scope(names.KMEANS_ASSIGN):
+            return lloyd.lloyd_pass(
+                x4, centers, mine(x4, n_valid), stats=False, assign=True,
+                interpret=interpret)[0].reshape(-1)
+
+    return (data_parallel(local_stats, mesh, in_specs=(x_spec, P(), P()),
+                          out_specs=(P(), P())),
+            data_parallel(local_assign, mesh, in_specs=(x_spec, P(), P()),
+                          out_specs=P("data")))
+
+
+def _counts0(k: int, lanes) -> jax.Array:
+    return jnp.zeros((k,), jnp.float32 if lanes is None else jnp.int32)
+
+
+def _update(sums, counts, centers):
+    with jax.named_scope(names.KMEANS_UPDATE):
+        return kops.update_centers(
+            sums, counts.astype(jnp.float32), centers)
 
 
 def init_centers(points: np.ndarray, k: int, seed: int) -> np.ndarray:
@@ -67,67 +137,68 @@ def init_centers(points: np.ndarray, k: int, seed: int) -> np.ndarray:
 
 
 def _seg_loop(one_iter, config: KMeansConfig, seg: int,
-              centers0, shift0, n_run0):
+              centers0, shift0, n_run0, counts0):
     """THE Lloyd loop — both the straight driver (one full-length
     segment) and every checkpoint segment run this exact code, so the
     segmented==straight bitwise contract cannot drift. Fixed-iteration
     mode runs exactly ``seg``; converge mode caps the while_loop at
     ``seg`` more iterations, and because the carried ``shift``
     re-enters the loop condition, post-convergence segments are
-    no-ops. Returns ``(centers, shift, n_run)``."""
+    no-ops. ``one_iter(centers) -> (centers, counts)``; returns
+    ``(centers, shift, n_run, counts)`` with the counts of the last
+    iteration run (``counts0`` where none ran)."""
     if config.converge_dist is None:
-        centers, _ = jax.lax.scan(
-            lambda c, _: (one_iter(c), None), centers0, None,
-            length=seg,
+        (centers, counts), _ = jax.lax.scan(
+            lambda c, _: (one_iter(c[0]), None), (centers0, counts0),
+            None, length=seg,
         )
-        return centers, shift0, n_run0 + seg
+        return centers, shift0, n_run0 + seg, counts
 
     def cond(state):
-        _, shift, it = state
+        _, shift, it, _ = state
         return (shift > config.converge_dist) & (it < seg)
 
     def body(state):
-        centers, _, it = state
-        new = one_iter(centers)
-        shift = jnp.sum(jnp.sqrt(jnp.sum((new - centers) ** 2, axis=1)))
-        return new, shift, it + 1
+        centers, _, it, _ = state
+        new, counts = one_iter(centers)
+        with jax.named_scope(names.KMEANS_UPDATE):
+            shift = jnp.sum(
+                jnp.sqrt(jnp.sum((new - centers) ** 2, axis=1)))
+        return new, shift, it + 1, counts
 
-    centers, shift, it = jax.lax.while_loop(
-        cond, body, (centers0, shift0, jnp.int32(0))
+    centers, shift, it, counts = jax.lax.while_loop(
+        cond, body, (centers0, shift0, jnp.int32(0), counts0)
     )
-    return centers, shift, n_run0 + it
+    return centers, shift, n_run0 + it, counts
 
 
-def _lloyd_loop(one_iter, config: KMeansConfig, centers0):
+def _lloyd_loop(one_iter, config: KMeansConfig, centers0, counts0):
     """Straight Lloyd driver = one full-length segment of
-    :func:`_seg_loop`; ``one_iter(centers) -> centers``. Returns
-    (final centers, iterations run)."""
+    :func:`_seg_loop`. Returns (final centers, iterations run)."""
     n_total = (config.n_iterations if config.converge_dist is None
                else config.max_iterations)
-    centers, _, n_run = _seg_loop(
+    centers, _, n_run, _ = _seg_loop(
         one_iter, config, n_total, centers0,
-        jnp.float32(jnp.inf), jnp.int32(0))
+        jnp.float32(jnp.inf), jnp.int32(0), counts0)
     return centers, n_run
 
 
-def make_fit_fn(mesh: Mesh, config: KMeansConfig):
-    stats_fn = data_parallel(
-        _local_stats,
-        mesh,
-        in_specs=(P("data", None), P("data"), P()),
-        out_specs=(P(), P(), P("data")),
-    )
+def make_fit_fn(mesh: Mesh, config: KMeansConfig,
+                lanes=None):
+    """The straight fit, for either layout (:func:`_mesh_fns` says what
+    ``points`` and ``valid`` are in each)."""
+    stats_fn, assign_fn = _mesh_fns(mesh, lanes)
 
-    def fit(points, mask, centers0):
+    def fit(points, valid, centers0):
         def one_iter(centers):
-            sums, counts, _assign = stats_fn(points, mask, centers)
-            return kops.update_centers(sums, counts, centers)
+            sums, counts = stats_fn(points, valid, centers)
+            return _update(sums, counts, centers), counts
 
-        centers, n_run = _lloyd_loop(one_iter, config, centers0)
+        centers, n_run = _lloyd_loop(
+            one_iter, config, centers0, _counts0(config.k, lanes))
         # final assignment under the final centers (the reference's closing
         # display re-evaluates with updated centers, k-means.py:57-58,76)
-        _, _, assign = stats_fn(points, mask, centers)
-        return centers, assign, n_run
+        return centers, assign_fn(points, valid, centers), n_run
 
     return jax.jit(fit)
 
@@ -167,9 +238,13 @@ def make_fit_fn_fused(mesh: Mesh, config: KMeansConfig, dim: int, *,
                       block_rows: int = 4096):
     """Lloyd iterations through the single-pass Pallas kernel
     (``ops.pallas_kmeans.fused_cluster_stats``): one HBM pass per
-    iteration. NOTE: measured SLOWER than :func:`make_fit_fn` at bench
-    scale (0.64× — see the ``ops/pallas_kmeans`` module docstring for
-    the recorded A/B); kept as a tested alternative, not the default.
+    iteration over points packed 4 to a 128-lane row. Nothing in the
+    program takes it: it lost to :func:`make_fit_fn`'s row path where
+    both fit (the ``ops/pallas_kmeans`` module docstring has both chip
+    readings), neither holds a chip-filling table, and the scale path
+    runs :func:`make_fit_fn` on the lanes layout (``ops/pallas_lloyd``).
+    Kept as a tested alternative; ROADMAP D4 leaves its removal to a
+    ``simplicity`` PR.
     Call with :func:`pack_device` outputs. Centers and
     n_iterations_run match :func:`make_fit_fn`; ASSIGNMENTS are in
     PACKED order with per-shard padding rows interleaved — filter by
@@ -195,9 +270,10 @@ def make_fit_fn_fused(mesh: Mesh, config: KMeansConfig, dim: int, *,
     def fit(X2, m2, centers0):
         def one_iter(centers):
             sums, counts = stats_fn(X2, m2, centers)
-            return kops.update_centers(sums, counts, centers)
+            return kops.update_centers(sums, counts, centers), counts
 
-        centers, n_run = _lloyd_loop(one_iter, config, centers0)
+        centers, n_run = _lloyd_loop(
+            one_iter, config, centers0, _counts0(config.k, None))
         # final assignment from the packed view (free reshape) under the
         # final centers — reference display parity (k-means.py:57-58,76)
         pts = X2.reshape(-1, dpad)[:, :dim]
@@ -207,8 +283,15 @@ def make_fit_fn_fused(mesh: Mesh, config: KMeansConfig, dim: int, *,
     return jax.jit(fit)
 
 
-def init_centers_from_rows(make_rows, n_rows: int, k: int,
-                           seed: int) -> jax.Array:
+def _rows_of(make_rows, ids, data_seed) -> jax.Array:
+    """The rows ``ids``, regenerated; ``data_seed`` as
+    :func:`fit_scaled` takes it."""
+    extra = () if data_seed is None else (jnp.int32(data_seed),)
+    return jax.jit(make_rows)(jnp.asarray(ids, jnp.int32), *extra)
+
+
+def init_centers_from_rows(make_rows, n_rows: int, k: int, seed: int,
+                           data_seed: int | None = None) -> jax.Array:
     """Device-side seeded init for the scale path: draw k DISTINCT
     global row ids host-side (O(k) memory — the ids, never the data)
     and REGENERATE exactly those rows with the counter-based generator.
@@ -227,12 +310,13 @@ def init_centers_from_rows(make_rows, n_rows: int, k: int,
             if i not in seen and len(chosen) < k:
                 seen.add(i)
                 chosen.append(i)
-    ids = jnp.asarray(np.array(chosen), jnp.int32)
-    return jnp.asarray(jax.jit(make_rows)(ids), jnp.float32)
+    return jnp.asarray(
+        _rows_of(make_rows, np.array(chosen), data_seed), jnp.float32)
 
 
 def init_centers_farthest(make_rows, n_rows: int, k: int, seed: int,
-                          oversample: int = 32) -> jax.Array:
+                          oversample: int = 32,
+                          data_seed: int | None = None) -> jax.Array:
     """Farthest-point init for the scale path: regenerate ``oversample·k``
     candidate rows (still O(k) in ``n_rows``) and greedily pick k by
     max-min distance. Random-row init (``init_centers_from_rows``, the
@@ -241,9 +325,9 @@ def init_centers_farthest(make_rows, n_rows: int, k: int, seed: int,
     local optimum while staying a one-shot init, no extra data pass."""
     rng = np.random.default_rng(seed)
     m = oversample * k
-    ids = jnp.asarray(
-        rng.integers(0, n_rows, size=m, dtype=np.int64), jnp.int32)
-    cand = np.asarray(jax.jit(make_rows)(ids), np.float32)  # (m, dim)
+    ids = rng.integers(0, n_rows, size=m, dtype=np.int64)
+    cand = np.asarray(_rows_of(make_rows, ids, data_seed),
+                      np.float32)                           # (m, dim)
     chosen = [int(rng.integers(0, m))]
     d = np.linalg.norm(cand - cand[chosen[0]], axis=1)
     while len(chosen) < k:
@@ -253,30 +337,32 @@ def init_centers_farthest(make_rows, n_rows: int, k: int, seed: int,
     return jnp.asarray(cand[chosen])
 
 
-def make_fit_seg_fn(mesh: Mesh, config: KMeansConfig, seg: int):
+def make_fit_seg_fn(mesh: Mesh, config: KMeansConfig, seg: int,
+                    lanes=None):
     """One compiled checkpoint segment: up to ``seg`` Lloyd iterations
     continuing from ``(centers, shift, n_run)`` — the same
     :func:`_seg_loop` the straight driver runs (the checkpoint/resume
-    bitwise contract every optimizer workload has)."""
-    stats_fn = data_parallel(
-        _local_stats, mesh,
-        in_specs=(P("data", None), P("data"), P()),
-        out_specs=(P(), P(), P("data")),
-    )
+    bitwise contract every optimizer workload has). ``seg_run(points,
+    valid, centers, shift, n_run) -> (centers, shift, n_run, counts)``
+    for either layout (:func:`_mesh_fns`); ``counts`` are those of the
+    last iteration run. Nothing but the arguments varies from call to
+    call: one compile serves every data seed and every start."""
+    stats_fn, _ = _mesh_fns(mesh, lanes)
 
-    def seg_run(points, mask, centers0, shift0, n_run0):
+    def seg_run(points, valid, centers0, shift0, n_run0):
         def one_iter(centers):
-            sums, counts, _ = stats_fn(points, mask, centers)
-            return kops.update_centers(sums, counts, centers)
+            sums, counts = stats_fn(points, valid, centers)
+            return _update(sums, counts, centers), counts
 
         return _seg_loop(one_iter, config, seg, centers0, shift0,
-                         n_run0)
+                         n_run0, _counts0(config.k, lanes))
 
     return jax.jit(seg_run)
 
 
-def _fit_segmented(data, mask, mesh, config: KMeansConfig, centers0,
-                   checkpoint_dir: str, checkpoint_every: int):
+def _fit_segmented(data, valid, mesh, config: KMeansConfig, centers0,
+                   checkpoint_dir: str, checkpoint_every: int,
+                   lanes=None):
     """Checkpointed Lloyd driver (state is tiny: the (k, dim) centers
     plus the convergence carry) — the task-retry capability Spark gives
     the reference's k-means for free (SURVEY.md §5)."""
@@ -289,8 +375,8 @@ def _fit_segmented(data, mask, mesh, config: KMeansConfig, centers0,
         if converge else None)
 
     def run_seg(fn, state, t0):
-        centers, shift, n_run = fn(
-            data, mask, state["centers"], state["shift"],
+        centers, shift, n_run, _ = fn(
+            data, valid, state["centers"], state["shift"],
             state["n_run"])
         new = {"centers": centers, "shift": shift, "n_run": n_run}
         return new, np.asarray(shift, np.float32)[None]
@@ -305,7 +391,7 @@ def _fit_segmented(data, mask, mesh, config: KMeansConfig, centers0,
     }
     state, _, _ = ckpt.run_segmented(
         checkpoint_dir, checkpoint_every, n_total,
-        lambda seg: make_fit_seg_fn(mesh, config, seg),
+        lambda seg: make_fit_seg_fn(mesh, config, seg, lanes),
         run_seg, state0,
         # the two modes share the state signature but fixed mode's
         # shift=0.0 sentinel would alias "converged" on a cross-mode
@@ -313,13 +399,11 @@ def _fit_segmented(data, mask, mesh, config: KMeansConfig, centers0,
         tag="kmeans_converge" if converge else "kmeans_fixed",
         stop_when=stop_when)
 
-    assign_fn = jax.jit(data_parallel(
-        lambda p, m, c: kops.assign_clusters(p, c), mesh,
-        in_specs=(P("data", None), P("data"), P()),
-        out_specs=P("data")))
     centers = state["centers"]
     return KMeansResult(
-        centers=centers, assignments=assign_fn(data, mask, centers),
+        centers=centers,
+        assignments=jax.jit(_mesh_fns(mesh, lanes)[1])(
+            data, valid, centers),
         n_iterations_run=int(state["n_run"]),
     )
 
@@ -452,40 +536,75 @@ def fit_minibatch(dataset, config: KMeansConfig, *, n_steps: int,
                         n_iterations_run=n_steps)
 
 
-def init_centers_scaled(make_rows, n_rows: int,
-                        config: KMeansConfig) -> jax.Array:
+def init_centers_scaled(make_rows, n_rows: int, config: KMeansConfig,
+                        data_seed: int | None = None) -> jax.Array:
     """The scale path's ``config.init`` dispatch — one place, shared by
     :func:`fit_scaled` and bench.py (which times the fit separately)."""
     if config.init == "farthest":
         return init_centers_farthest(
-            make_rows, n_rows, config.k, config.seed)
+            make_rows, n_rows, config.k, config.seed,
+            data_seed=data_seed)
     if config.init == "sample":
         return init_centers_from_rows(
-            make_rows, n_rows, config.k, config.seed)
+            make_rows, n_rows, config.k, config.seed, data_seed)
     raise ValueError(f"unknown init {config.init!r}")
+
+
+def build_scaled(mesh: Mesh, n_rows: int, make_rows, k: int, *,
+                 data_seed: int | None = None):
+    """The scale path's resident table: ``(points, valid, lanes)`` as
+    :func:`make_fit_fn` and :func:`make_fit_seg_fn` take them.
+
+    The layout follows from what can be seen: where the lanes kernel
+    covers the geometry (``pallas_lloyd.lanes_geometry``: k * dim up to
+    1024) the points are drawn block by block into its feature-major
+    layout, 4 * dim bytes a point and no mask; else into plain rows,
+    chunk by chunk, with their mask, for ``ops/kmeans.py``. On one v5e
+    at 100M x 20, k = 10 the lanes path holds 8.0 GB and takes 16.9 ms
+    an iteration, the row path 10.0 GB and 36.9 ms, and
+    ``make_fit_fn_fused``'s packing does not fit (PERF.md §6, PR 26)."""
+    from tpu_distalg.ops import pallas_lloyd as lloyd
+    from tpu_distalg.parallel import build_sharded
+
+    dim = jax.eval_shape(
+        make_rows, jax.ShapeDtypeStruct((1,), jnp.int32)).shape[1]
+    lanes = lloyd.lanes_geometry(dim, k)
+    chunk = (1 << 16) if lanes is None else lanes.block_points
+    per = chunk * mesh.shape["data"]
+    with tevents.span("kmeans:prepare", rows=n_rows,
+                      bytes=-(-n_rows // per) * per * dim * 4,
+                      layout="rows" if lanes is None else "lanes"):
+        ps = build_sharded(
+            mesh, n_rows, make_rows, seed=data_seed, chunk_rows=chunk,
+            pack=None if lanes is None else lanes.pack)
+        jax.block_until_ready(ps.data)
+    valid = ps.mask if lanes is None else jnp.int32(n_rows)
+    return ps.data, valid, lanes
 
 
 def fit_scaled(mesh: Mesh, n_rows: int, make_rows,
                config: KMeansConfig = KMeansConfig(), *,
                checkpoint_dir: str | None = None,
-               checkpoint_every: int = 100) -> KMeansResult:
+               checkpoint_every: int = 100,
+               data_seed: int | None = None) -> KMeansResult:
     """Scale-out fit: the dataset is synthesized ON DEVICE, shard by
-    shard (``parallel.build_sharded``), and the init centers are
-    regenerated from k row ids — host memory is O(k) in ``n_rows``,
-    unlike :func:`fit`, which (like the reference's driver-side
-    ``np.concatenate`` + ``parallelize``, ``k-means.py:49-53``) tops
-    out at host RAM. ``make_rows(row_ids) -> (n, dim)`` must be
-    jittable and counter-based (e.g.
-    ``datasets.gaussian_mixture_rows``)."""
-    from tpu_distalg.parallel import build_sharded
-
-    ps = build_sharded(mesh, n_rows, make_rows)
-    centers0 = init_centers_scaled(make_rows, n_rows, config)
+    shard and chunk by chunk (``parallel.build_sharded``), and the init
+    centers are regenerated from k row ids — host memory is O(k) in
+    ``n_rows``, unlike :func:`fit`, which (like the reference's
+    driver-side ``np.concatenate`` + ``parallelize``,
+    ``k-means.py:49-53``) tops out at host RAM. ``make_rows(row_ids) ->
+    (n, dim)`` must be jittable and counter-based (e.g.
+    ``datasets.gaussian_mixture_rows``); with ``data_seed`` it is
+    called ``make_rows(row_ids, seed)`` and the seed is an argument of
+    the compiled generator, not a constant in it."""
+    data, valid, lanes = build_scaled(
+        mesh, n_rows, make_rows, config.k, data_seed=data_seed)
+    centers0 = init_centers_scaled(make_rows, n_rows, config, data_seed)
     if checkpoint_dir is not None:
-        return _fit_segmented(ps.data, ps.mask, mesh, config, centers0,
-                              checkpoint_dir, checkpoint_every)
-    fn = make_fit_fn(mesh, config)
-    centers, assign, n_run = fn(ps.data, ps.mask, centers0)
+        return _fit_segmented(data, valid, mesh, config, centers0,
+                              checkpoint_dir, checkpoint_every, lanes)
+    fn = make_fit_fn(mesh, config, lanes)
+    centers, assign, n_run = fn(data, valid, centers0)
     return KMeansResult(
         centers=centers, assignments=assign, n_iterations_run=int(n_run)
     )

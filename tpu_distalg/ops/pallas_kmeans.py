@@ -1,17 +1,32 @@
-"""Single-pass Lloyd-iteration Pallas kernel.
+"""Single-pass Lloyd-iteration Pallas kernel (4 points to a 128-lane row).
 
-**Measured outcome (v5e, 10M×16 f32, k=8): the XLA path wins — keep it
-as the default.** Interleaved A/B on the same chip: XLA
-``ops/kmeans.py`` 330 iter/s vs this kernel 212 iter/s (0.64×). The
-XLA iteration moves ~4.5× the dataset bytes (distance matrix, argmin,
-one-hot intermediates) but streams every pass at near-peak HBM
-bandwidth; this kernel reads each point once, yet its 128-lane-wide
-block pipeline measures only ~150-250 GB/s on this rig — the byte
-advantage is more than repaid. The kernel is kept as a correct, tested
-alternative (``kmeans.make_fit_fn_fused``) and as the recorded negative
-result: single-pass fusion is NOT automatically a win when the fused
-layout narrows the stream; the same packed-selector algebra wins for
-SSGD (``pallas_kernels``) where rows are 2048 lanes wide.
+**Nothing in the program takes this kernel; it lost both times it was
+measured, and it cannot hold a chip-filling table.** Two chip readings,
+each with its shape and its run:
+
+* 10M x 16 float32, k = 8, one v5e, an interleaved A/B by this kernel's
+  author (round 5, before the driver's ledger; ``bench.py``'s k-means
+  phase): XLA ``ops/kmeans.py`` 330 iterations/s against this kernel's
+  212 (0.64x).
+* 10M x 20 float32, k = 10, one v5e, jax 0.9.0 (my chip run, PR 26, five
+  iterations a call, best of three): XLA row path 3.91 ms an iteration
+  (2.56e9 rows/s), this kernel 11.60 ms (8.6e8 rows/s, 0.34x), the
+  lanes kernel of ``ops/pallas_lloyd.py`` 2.6 ms a pass. At 100M x 20
+  (HiBench ``huge``) this path does not compile to fit: its closing
+  assignment reshapes the packed rows to ``f32[100007936, 32]``, which
+  the TPU pads to 128 lanes (51.2 GB), and before that the packed rows
+  hold 128 B a point and a mask padded to as much.
+
+Why it loses: the XLA iteration streams each of its passes near peak
+HBM bandwidth; this kernel reads each point once, yet its 128-lane-wide
+block pipeline moves 110 to 250 GB/s, and its distance and shift
+products run on an MXU that a 128 x 64 selector matrix fills to a
+fraction. The kernel is kept as a correct, tested alternative
+(``kmeans.make_fit_fn_fused``) and as the recorded negative result:
+single-pass fusion is NOT automatically a win when the fused layout
+narrows the stream; the same packed-selector algebra wins for SSGD
+(``pallas_kernels``) where rows are 2048 lanes wide. ROADMAP D4 leaves
+its removal to a ``simplicity`` PR.
 
 Design (one HBM pass; distances, argmin, one-hot and the stats matmul
 all happen on the block while it is VMEM-resident):

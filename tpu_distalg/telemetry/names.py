@@ -19,3 +19,12 @@ SSGD_SYNC = "tda.ssgd.sync"      # the psum (dense) or the comm schedule
 SSGD_UPDATE = "tda.ssgd.update"  # the rest of a step: reg, update, eval
 # the fused SpMV sweep (models/pagerank.py)
 PAGERANK_SPMV = "tda.pagerank.spmv"
+# the parts of a Lloyd iteration (models/kmeans.py)
+KMEANS_ASSIGN = "tda.kmeans.assign"  # distances and argmin; on the lanes
+#                                      layout the one kernel that also
+#                                      accumulates the partial sums
+KMEANS_STATS = "tda.kmeans.stats"    # one-hot sums and counts; on the
+#                                      lanes layout what is left outside
+#                                      the kernel: the partial sums' fold
+KMEANS_SYNC = "tda.kmeans.sync"      # the psum of (sums, counts)
+KMEANS_UPDATE = "tda.kmeans.update"  # new centres, the convergence shift

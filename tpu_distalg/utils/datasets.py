@@ -104,20 +104,24 @@ def gaussian_mixture_rows(k: int = 4, dim: int = 2, seed: int = 0,
     depends only on the global row id, not the shard topology).
     Returns ``(make_rows, true_centers_fn)``: ``make_rows(row_ids) ->
     (n, dim) points``; ``true_centers_fn()`` the mixture means, for
-    recovery checks."""
+    recovery checks. Both take the seed as an optional last argument,
+    which may be traced (``build_sharded(..., seed=)`` passes it into
+    the compiled generator, so one compile serves every seed); left
+    out, it is the ``seed`` given here."""
     import jax
-    import jax.numpy as jnp
 
-    from tpu_distalg.utils import prng
+    default_seed = seed
 
-    key = prng.root_key(seed)
-    k_c, k_rows = jax.random.fold_in(key, 0), jax.random.fold_in(key, 1)
+    def keys(seed):
+        key = jax.random.key(default_seed if seed is None else seed)
+        return jax.random.fold_in(key, 0), jax.random.fold_in(key, 1)
 
-    def true_centers():
-        return jax.random.normal(k_c, (k, dim)) * spread
+    def true_centers(seed=None):
+        return jax.random.normal(keys(seed)[0], (k, dim)) * spread
 
-    def make_rows(ids):
-        centers = true_centers()
+    def make_rows(ids, seed=None):
+        k_rows = keys(seed)[1]
+        centers = true_centers(seed)
         row_keys = jax.vmap(lambda i: jax.random.fold_in(k_rows, i))(ids)
         assign = jax.vmap(
             lambda rk: jax.random.randint(rk, (), 0, k)
