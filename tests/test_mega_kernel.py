@@ -167,3 +167,41 @@ def test_local_sgd_fused_train_checkpoint_bitwise(mesh4, cancer_data,
     seg = ma.train(*cancer_data, mesh4, cfg,
                    checkpoint_dir=str(tmp_path), checkpoint_every=10).w
     np.testing.assert_array_equal(np.asarray(straight), np.asarray(seg))
+
+
+# ---- the megakernel's block loop over the shapes it branches on (PR 27;
+# the cases and their tables are tests/test_pallas.py's)
+
+from test_pallas import SCHEDULE_CASES, schedule_case  # noqa: E402
+
+
+@pytest.mark.parametrize("name", sorted(SCHEDULE_CASES))
+def test_fused_train_kernel_over_schedule_shapes(name):
+    """``fused_train_gathered`` over (T, n_sampled) block ids equals the
+    per-step 'fused_gather' trajectory: one ``fused_grad_sum_gathered``
+    launch and the same update (and elastic pull) a step."""
+    import jax
+
+    from tpu_distalg.ops import pallas_kernels as pk
+
+    X2, meta, w_aug, ids, alpha = schedule_case(name)
+    P, D, eta = meta["pack"], meta["d_total"], 0.1
+    kw = dict(pack=P, d_total=D, y_col=meta["y_col"], v_col=meta["v_col"],
+              gather_block_rows=meta["gather_block_rows"], interpret=True)
+    keep = (np.arange(D) < meta["y_col"]).astype(np.float32)
+    centre = w_aug + 0.05 * keep
+    with jax.default_matmul_precision("highest"):
+        wt = pk.fused_train_gathered(
+            X2, jnp.tile(jnp.asarray(w_aug), P)[:, None], jnp.asarray(ids),
+            eta=eta, alpha=alpha,
+            center_tile=jnp.tile(jnp.asarray(centre), P)[:, None], **kw)
+        w = jnp.asarray(w_aug)
+        for step_ids in ids:
+            g, cnt = pk.fused_grad_sum_gathered(
+                X2, w, jnp.asarray(step_ids), **kw)
+            w = (w - eta * g * keep / jnp.maximum(cnt, 1.0)
+                 - alpha * (w - centre))
+    wt = np.asarray(wt).reshape(P, D)
+    assert np.abs(wt - wt[0]).max() == 0.0      # every slot the same w
+    assert np.abs(wt[0] - w_aug).max() > 1e-4   # and it moved
+    np.testing.assert_allclose(wt[0], np.asarray(w), rtol=1e-5, atol=1e-6)

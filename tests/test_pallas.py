@@ -240,3 +240,68 @@ def test_fused_sampler_requires_tpu(mesh4):
     with pytest.raises(ValueError, match="TPU"):
         ssgd.make_train_fn_fused(
             mesh4, ssgd.SSGDConfig(sampler="fused"), meta)
+
+
+# ---- the gathered kernels' block loop (PR 27): a ring of block copies
+# the kernel keeps in flight itself, and a body that takes the block in
+# chunks of packed rows. The cases are the shapes that loop branches on.
+
+import pytest
+
+# id -> (pack, gather_block_rows, block ids (T, n_sampled), alpha)
+SCHEDULE_CASES = {
+    "bp8_one_chunk": (4, 32, [[0, 12, 3, 7], [5, 1, 9, 2], [8, 4, 6, 11]],
+                      0.0),
+    "bp64_one_chunk": (16, 1024, [[4, 0], [2, 3]], 0.0),
+    "bp1024_two_chunks": (4, 4096, [[2, 0], [1, 2]], 0.0),
+    "n_sampled_1": (4, 32, [[3], [0], [12], [7]], 0.0),
+    "n_sampled_5_ring_of_3": (4, 32, [[0, 1, 2, 3, 4], [9, 8, 7, 6, 5]],
+                              0.0),
+    "one_step": (4, 32, [[6, 2, 10, 11, 0]], 0.0),
+    "one_cell": (4, 32, [[5]], 0.0),
+    "same_block_in_a_row": (4, 32, [[2, 2, 5], [5, 5, 1]], 0.0),
+    "easgd_pull_with_centre": (4, 32, [[0, 4, 8], [1, 5, 9]], 0.05),
+}
+
+
+def schedule_case(name):
+    """(X2, meta, w_aug, block ids (T, n), alpha) of a schedule case: a
+    float32 table just large enough for the ids, some rows invalid."""
+    pack, gbr, ids, alpha = SCHEDULE_CASES[name]
+    ids = np.asarray(ids, np.int32)
+    n_blocks = int(ids.max()) + 1
+    n = n_blocks * gbr - gbr // 4          # the last block part padding
+    rng = np.random.default_rng(len(name))
+    X = rng.normal(size=(n, 30)).astype(np.float32)
+    y = rng.integers(0, 2, n).astype(np.float32)
+    valid = (rng.random(n) < 0.9).astype(np.float32)
+    X2, meta = pack_augmented(X, y, valid, dtype=jnp.float32, pack=pack,
+                              block_rows=gbr)
+    w_aug = np.zeros(meta["d_total"], np.float32)
+    w_aug[:30] = rng.normal(size=(30,)).astype(np.float32) * 0.1
+    return X2, dict(meta, gather_block_rows=gbr), w_aug, ids, alpha
+
+
+@pytest.mark.parametrize("name", sorted(SCHEDULE_CASES))
+def test_gathered_kernel_over_schedule_shapes(name):
+    """The v4 kernel over every grid cell of the case (a block drawn
+    twice counts twice) against the flat float32 XLA gradient."""
+    X2, meta, w_aug, ids, _ = schedule_case(name)
+    gbr, d = meta["gather_block_rows"], 30
+    blocks = ids.reshape(-1)
+    with jax.default_matmul_precision("highest"):
+        g, cnt = fused_grad_sum_gathered(
+            X2, jnp.asarray(w_aug), jnp.asarray(blocks),
+            pack=meta["pack"], d_total=meta["d_total"],
+            y_col=meta["y_col"], v_col=meta["v_col"],
+            gather_block_rows=gbr, interpret=True)
+        rows = np.concatenate(
+            [np.arange(b * gbr, (b + 1) * gbr) for b in blocks])
+        flat = np.asarray(X2).reshape(meta["n_padded"], meta["d_total"])
+        g_ref, cnt_ref = logistic.grad_sum(
+            jnp.asarray(flat[rows, :d]),
+            jnp.asarray(flat[rows, meta["y_col"]]),
+            jnp.asarray(w_aug[:d]), jnp.asarray(flat[rows, meta["v_col"]]))
+    assert float(cnt) == float(cnt_ref) > 0
+    np.testing.assert_allclose(np.asarray(g)[:d], np.asarray(g_ref),
+                               rtol=1e-4, atol=1e-4)

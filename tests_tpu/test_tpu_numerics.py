@@ -158,6 +158,102 @@ def test_local_fused_train_convergence(tpu_mesh, cancer_data):
     assert res.final_acc >= 0.85, res.final_acc
 
 
+def test_gathered_kernels_at_the_cells_block_shape(tpu_mesh):
+    """Both gathered kernels compiled at the benchmark cells' block
+    shape (``pack`` 16, ``d_total`` 40, 8192-row bfloat16 blocks = 655 KB,
+    a table of 256) on the program's own draws, against a float32
+    ``jax.numpy`` gradient that rounds where the kernels round (the
+    weights to the bfloat16 selector, the residual to bfloat16 before
+    the MXU) and nowhere else; then a local-SGD round as MA / EASGD
+    launch it (``alpha`` 0 and > 0 with a centre) against its per-step
+    form. MA's guard until it has a cell of its own (ROADMAP R1)."""
+    from tpu_distalg.ops import sampling
+
+    P, gbr, n_blocks, n_sampled, T, eta = 16, 8192, 256, 25, 6, 0.1
+    d = 31
+    D, y_col, v_col = pk.packed_dims(d, P)
+    assert D == 40
+    kx, ky, kv, kw_ = jax.random.split(prng.root_key(11), 4)
+    n = n_blocks * gbr
+    X = jax.random.normal(kx, (n, d - 1), jnp.float32)
+    w_true = jax.random.normal(kw_, (d - 1,), jnp.float32)
+    y = (X @ w_true + jax.random.normal(ky, (n,)) > 0).astype(jnp.float32)
+    valid = (jax.random.uniform(kv, (n,)) < 0.97).astype(jnp.float32)
+    flat = jnp.concatenate(
+        [X, jnp.ones((n, 1)), y[:, None], valid[:, None],
+         jnp.zeros((n, D - d - 2))], axis=1).astype(jnp.bfloat16)
+    del X
+    X2 = flat.reshape(n // P, P * D)
+    kw = dict(pack=P, d_total=D, y_col=y_col, v_col=v_col,
+              gather_block_rows=gbr)
+    keep = (jnp.arange(D) < y_col).astype(jnp.float32)
+    idx = jax.vmap(lambda t: sampling.sample_block_ids(
+        jax.random.fold_in(prng.root_key(42), t), 1, n_blocks,
+        n_sampled))(jnp.arange(T)).reshape(T, n_sampled)
+    w0 = jnp.zeros((D,), jnp.float32).at[:d].set(
+        0.1 * jax.random.normal(prng.root_key(5), (d,)))
+    hi = jax.lax.Precision.HIGHEST
+
+    @jax.jit
+    def ref_grad(flat, w, ids):
+        rows = flat.reshape(n_blocks, gbr, D)[ids].reshape(-1, D).astype(
+            jnp.float32)
+        # reduce_precision, not a cast there and back: XLA drops that
+        # pair (xla_allow_excess_precision) and the reference would
+        # round nowhere
+        to_bf16 = functools.partial(jax.lax.reduce_precision,
+                                    exponent_bits=8, mantissa_bits=7)
+        z = jnp.dot(rows, to_bf16(w * keep), precision=hi)
+        v = rows[:, v_col]
+        resid = to_bf16((jax.nn.sigmoid(z) - rows[:, y_col]) * v)
+        return jnp.dot(resid, rows, precision=hi), jnp.sum(v)
+
+    def step(w, g, cnt, alpha=0.0, centre=0.0):
+        return (w - eta * g * keep / jnp.maximum(cnt, 1.0)
+                - alpha * (w - centre))
+
+    # one launch of the v4 kernel against the float32 gradient
+    g, cnt = pk.fused_grad_sum_gathered(X2, w0, idx[0], **kw)
+    g_ref, cnt_ref = ref_grad(flat, w0, idx[0])
+    assert float(cnt) == float(cnt_ref) > 0.9 * n_sampled * gbr
+    scale = float(jnp.abs(g_ref * keep).max())
+    # (a residual that rounds the other way to bfloat16 is 4e-3 of
+    # itself; a bfloat16 table read as the wrong rows is 1)
+    err = float(jnp.abs((g - g_ref) * keep).max())
+    assert err < 2e-4 * scale, (err, scale)
+
+    # T steps of the megakernel against T float32 steps. The in-kernel
+    # fold of the gradient tile is two float32 matmuls at the MXU's
+    # default precision (as before PR 27): 1.5e-3 of the movement on
+    # the chip, 2e-6 interpreted on the CPU. A wrong block is 1.
+    tile = lambda w: jnp.tile(w, (P,))[:, None]  # noqa: E731
+    wt = pk.fused_train_gathered(X2, tile(w0), idx, eta=eta, **kw)
+    w_ref = w0
+    for t in range(T):
+        w_ref = step(w_ref, *ref_grad(flat, w_ref, idx[t]))
+    wt = np.asarray(wt).reshape(P, D)
+    assert np.abs(wt - wt[0]).max() == 0.0
+    moved = float(jnp.abs(w_ref - w0).max())
+    assert moved > 1e-3
+    err = np.abs(wt[0] - np.asarray(w_ref)).max()
+    assert err < 1e-2 * moved, (err, moved)
+
+    # a local-SGD round (local_sgd._local_models): one launch with the
+    # round's centre against per-step launches and the same pull (a
+    # pull left out is 0.15 of the movement here)
+    centre = w0 + 0.02 * keep
+    for alpha in (0.0, 0.05):
+        wt = pk.fused_train_gathered(
+            X2, tile(w0), idx, eta=eta, alpha=alpha,
+            center_tile=tile(centre), **kw)
+        w_l = w0
+        for t in range(T):
+            g, cnt = pk.fused_grad_sum_gathered(X2, w_l, idx[t], **kw)
+            w_l = step(w_l, g, cnt, alpha, centre)
+        err = np.abs(np.asarray(wt)[:D, 0] - np.asarray(w_l)).max()
+        assert err < 1e-2 * moved, (alpha, err, moved)
+
+
 def test_flash_attention_matches_xla_path(tpu_mesh):
     """The Mosaic flash kernel and the XLA online-softmax ring agree on
     real hardware (both paths round scores through bf16 matmul passes)."""

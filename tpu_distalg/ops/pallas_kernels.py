@@ -30,7 +30,8 @@ pallas_guide.md), discovered the hard way across three kernel generations:
   v4 (:func:`fused_grad_sum_gathered`, production): v3 still streams
      100% of X to sample ``fraction`` of it. v4 moves the sampling into
      the *grid*: the caller draws ``frac·n_blocks`` block ids XLA-side
-     and a scalar-prefetch index map DMAs exactly those blocks — HBM
+     and the kernel copies exactly those blocks, ids scalar-prefetched
+     (a ring of VMEM slots since PR 27, see ``_ring_fetch``) — HBM
      traffic ≈ fraction × |X| per step. (Row-granular gathers are NOT
      the answer: the XLA 'fixed' row-gather sampler measures ~2× slower
      than streaming everything; random access serializes on TPU.)
@@ -236,36 +237,146 @@ def _grad_kernel_packed(s_ref, x_ref, c_ref, gacc_ref, cnt_ref, acc_ref,
         cnt_ref[0, 0] = cacc_ref[0, 0]
 
 
-def _grad_kernel_gathered(idx_ref, x_ref, c_ref, gacc_ref, cnt_ref,
-                          acc_ref, cacc_ref, *, pack: int):
-    """v4 body: like :func:`_grad_kernel_packed` but with NO on-core
-    sampling — the sampling already happened in the *grid*: the block
-    index map reads ``idx_ref`` (scalar-prefetched sampled block ids), so
-    only the minibatch's blocks are ever DMA'd from HBM. Every resident
-    row counts (modulo the packed validity column)."""
-    del idx_ref  # consumed by the BlockSpec index_map, not the body
+# The gathered kernels' block loop (v4's ``_grad_kernel_gathered`` and
+# v5's ``_train_kernel_gathered`` share it). PERF.md section 6, "PR 27",
+# has the chip readings behind both halves:
+#
+#   the ring: X2 stays in HBM and the kernel copies each grid cell's
+#   sampled block into one of a few VMEM slots itself (``_ring_scratch``), the
+#   next cells' copies started before this cell's is waited for, so
+#   that one copy is always in flight behind the one that is landing
+#   (the BlockSpec pipeline starts block i+1 only once block i has
+#   landed, and the gap between the two is paid on every block);
+#
+#   the body: x2 is the MXU's latched operand in BOTH passes. The
+#   forward is ``Cᵀ (3P, P·D) · x2ᵀ``, so z, y, v are sublane slices
+#   of one dense (3P, rows) tile, the sigmoid runs over full vector
+#   registers and the residual is born (P, rows), the shape the
+#   backward ``resid · x2`` wants: no lane slices, no transpose, and
+#   no per-block vector-to-scalar count.
+
+# VMEM the ring's slots may take together (of the 100 MB the calls allow)
+_RING_BYTES = 48 * 1024 * 1024
+# packed rows of a block the body takes at a time: bounds the (3P, rows)
+# f32 tile of the middle to a few vector registers
+_CHUNK_ROWS = 512
+
+
+def _chunk_rows(bp: int) -> int:
+    """Largest divisor of ``bp`` (a multiple of 8) within ``_CHUNK_ROWS``."""
+    return max(r for r in range(8, min(bp, _CHUNK_ROWS) + 1, 8)
+               if bp % r == 0)
+
+
+def _ring_fetch(idx_ref, x_hbm, xbuf, sems, g, n_cells: int):
+    """Start the copies that keep the ring full, wait for grid cell
+    ``g``'s block and return the slot it landed in. ``idx_ref`` holds
+    the block id of every cell, flattened in grid order, so the ids are
+    read ahead across a step boundary of the megakernel."""
+    slots, bp = xbuf.shape[0], xbuf.shape[1]
+
+    def copy(cell):
+        row0 = pl.multiple_of(idx_ref[cell] * bp, bp)
+        slot = cell % slots
+        return pltpu.make_async_copy(
+            x_hbm.at[pl.ds(row0, bp), :], xbuf.at[slot], sems.at[slot])
+
+    @pl.when(g == 0)
+    def _fill():
+        for cell in range(min(slots - 1, n_cells)):
+            copy(cell).start()
+
+    ahead = g + (slots - 1)
+
+    # the slot it overwrites held cell g-1's block, done with
+    @pl.when(ahead < n_cells)
+    def _next():
+        copy(ahead).start()
+
+    copy(g).wait()
+    return xbuf.at[g % slots]
+
+
+def _block_grad(x_ref, ct_ref, acc_ref, cnt_ref, *, pack: int):
+    """One resident block's share of the step: ``acc_ref`` (P, P·D) +=
+    the residual-weighted row sums (the tile whose diagonal band is the
+    gradient), ``cnt_ref`` (P, rows) += the valid flags. Logits,
+    sigmoid, residual and both accumulations in float32; x2 and the
+    residual enter the MXU in x2's dtype."""
     P = pack
+    bp = x_ref.shape[0]
+    rows = cnt_ref.shape[1]
+    g = cnt = None
+    for r0 in range(0, bp, rows):
+        x2 = x_ref[r0:r0 + rows, :]                 # (rows, P·D)
+        zyv = jax.lax.dot_general(                  # (3P, rows)
+            ct_ref[:], x2, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        z, y, v = zyv[:P], zyv[P:2 * P], zyv[2 * P:]
+        resid = ((jax.nn.sigmoid(z) - y) * v).astype(x2.dtype)
+        gk = jnp.dot(resid, x2, preferred_element_type=jnp.float32)
+        g = gk if g is None else g + gk
+        cnt = v if cnt is None else cnt + v
+    acc_ref[:] += g                                 # (P, P·D)
+    cnt_ref[:] += cnt
+
+
+def _gathered_shapes(name: str, X2, pack: int, d_total: int,
+                     gather_block_rows: int) -> int:
+    """Validate the packed table against the block geometry; returns
+    ``bp``, the packed rows of a block."""
+    P, D = pack, d_total
+    n2, pd = X2.shape
+    bp = gather_block_rows // P
+    if (pd != P * D or (P * D) % 128 or gather_block_rows % P
+            or bp == 0 or n2 % bp):
+        raise ValueError(
+            f"{name}: X2 {X2.shape} incompatible with "
+            f"pack={P}, d_total={D}, gather_block_rows={gather_block_rows}"
+        )
+    if bp % 8:
+        # TPU tiling: the block's sublane dim must be a multiple of 8
+        raise ValueError(
+            f"gather_block_rows={gather_block_rows} gives {bp} packed "
+            f"rows per block; need a multiple of 8·pack={8 * P} rows"
+        )
+    return bp
+
+
+def _ring_scratch(bp: int, pd: int, dtype):
+    """The ring's slots and their semaphores (the first two scratch
+    operands of both gathered kernels). The depth follows from the
+    block's static shape: three slots (two copies in flight; a fourth
+    reads the same on the chip) where ``_RING_BYTES`` holds them, never
+    fewer than two."""
+    block_bytes = bp * pd * jnp.dtype(dtype).itemsize
+    slots = max(2, min(3, _RING_BYTES // block_bytes))
+    return [pltpu.VMEM((slots, bp, pd), dtype),
+            pltpu.SemaphoreType.DMA((slots,))]
+
+
+def _grad_kernel_gathered(idx_ref, x_hbm, ct_ref, gacc_ref, cnt_out_ref,
+                          xbuf, sems, acc_ref, cnt_ref, *, pack: int,
+                          n_sampled: int):
+    """v4 body: NO on-core sampling — the sampling already happened in
+    the *grid*: cell i copies block ``idx_ref[i]`` (scalar-prefetched
+    sampled block ids) and no other, so only the minibatch's blocks ever
+    leave HBM. Every resident row counts (modulo the packed validity
+    column). The block loop is the shared ring + body above."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
-        cacc_ref[0, 0] = 0.0
+        cnt_ref[:] = jnp.zeros_like(cnt_ref)
 
-    x2 = x_ref[:]                                   # (bp, P·D), ONE read
-    zyv = jnp.dot(x2, c_ref[:], preferred_element_type=jnp.float32)
-    z, y, v = zyv[:, :P], zyv[:, P:2 * P], zyv[:, 2 * P:3 * P]
-    resid = ((jax.nn.sigmoid(z) - y) * v).astype(x2.dtype)
-    acc_ref[:] += jax.lax.dot_general(
-        resid, x2, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                               # (P, P·D) MXU
-    cacc_ref[0, 0] += jnp.sum(v)
+    x_ref = _ring_fetch(idx_ref, x_hbm, xbuf, sems, i, n_sampled)
+    _block_grad(x_ref, ct_ref, acc_ref, cnt_ref, pack=pack)
 
-    @pl.when(i == pl.num_programs(0) - 1)
+    @pl.when(i == n_sampled - 1)
     def _done():
         gacc_ref[:] = acc_ref[:]
-        cnt_ref[0, 0] = cacc_ref[0, 0]
+        cnt_out_ref[0, 0] = jnp.sum(cnt_ref[:])
 
 
 @functools.partial(
@@ -284,11 +395,12 @@ def fused_grad_sum_gathered(X2, w_aug, block_idx, *, pack: int,
     to sample a ``fraction`` of it — HBM traffic 1/fraction× what the
     algorithm needs. Here the minibatch is drawn at *block* granularity:
     the caller samples ``block_idx`` (ids of ``gather_block_rows``-row
-    blocks, XLA-side PRNG) and the scalar-prefetch index map DMAs exactly
-    those blocks, so traffic ≈ fraction × |X| per step. Row-level random
-    gathers are NOT the answer on TPU — they serialize (the 'fixed'
-    sampler measures ~2× *slower* than streaming everything); whole-block
-    DMA keeps transfers wide.
+    blocks, XLA-side PRNG) and the kernel copies exactly those blocks
+    (a ring of VMEM slots fed by the scalar-prefetched ids), so traffic
+    ≈ fraction × |X| per step. Row-level random gathers are NOT the
+    answer on TPU — they serialize (the 'fixed' sampler measures ~2×
+    *slower* than streaming everything); whole-block DMA keeps transfers
+    wide.
 
     Semantics: block-cluster sampling — sampling whole blocks of
     consecutive rows instead of i.i.d. rows (Spark's per-partition
@@ -302,40 +414,30 @@ def fused_grad_sum_gathered(X2, w_aug, block_idx, *, pack: int,
     the meta col mask) and the kept-row count.
     """
     P, D = pack, d_total
-    n2, pd = X2.shape
-    bp = gather_block_rows // P
-    if (pd != P * D or (P * D) % 128 or gather_block_rows % P
-            or bp == 0 or n2 % bp):
-        raise ValueError(
-            f"fused_grad_sum_gathered: X2 {X2.shape} incompatible with "
-            f"pack={P}, d_total={D}, gather_block_rows={gather_block_rows}"
-        )
-    if bp % 8:
-        # TPU tiling: the block's sublane dim must be a multiple of 8
-        raise ValueError(
-            f"gather_block_rows={gather_block_rows} gives {bp} packed "
-            f"rows per block; need a multiple of 8·pack={8 * P} rows"
-        )
-    C = build_selector(w_aug, pack=P, d_total=D, y_col=y_col,
-                       v_col=v_col, dtype=X2.dtype)
-    kernel = functools.partial(_grad_kernel_gathered, pack=P)
+    bp = _gathered_shapes("fused_grad_sum_gathered", X2, P, D,
+                          gather_block_rows)
+    CT = build_selector_t(w_aug, pack=P, d_total=D, y_col=y_col,
+                          v_col=v_col, dtype=X2.dtype)
+    n_sampled = block_idx.shape[0]
+    kernel = functools.partial(_grad_kernel_gathered, pack=P,
+                               n_sampled=n_sampled)
     gacc, cnt = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(block_idx.shape[0],),
+            grid=(n_sampled,),
             in_specs=[
-                pl.BlockSpec((bp, P * D), lambda i, s: (s[i], 0)),
-                pl.BlockSpec((P * D, 3 * P), lambda i, s: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),      # X2 stays in HBM
+                pl.BlockSpec((3 * P, P * D), lambda i, s: (0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((P, P * D), lambda i, s: (0, 0)),
                 pl.BlockSpec((1, 1), lambda i, s: (0, 0),
                              memory_space=pltpu.SMEM),
             ],
-            scratch_shapes=[
+            scratch_shapes=_ring_scratch(bp, P * D, X2.dtype) + [
                 pltpu.VMEM((P, P * D), jnp.float32),
-                pltpu.SMEM((1, 1), jnp.float32),
+                pltpu.VMEM((P, _chunk_rows(bp)), jnp.float32),
             ],
         ),
         out_shape=[
@@ -348,63 +450,61 @@ def fused_grad_sum_gathered(X2, w_aug, block_idx, *, pack: int,
         ),
         interpret=interpret,
         name="_grad_kernel_gathered",
-    )(block_idx.astype(jnp.int32), X2, C)
+    )(block_idx.astype(jnp.int32), X2, CT)
     g = jnp.einsum("ccj->j", gacc.reshape(P, P, D))
     return g, cnt[0, 0]
 
 
-def _train_kernel_gathered(idx_ref, x_ref, msel_ref, s_ref, eye_ref,
+def _train_kernel_gathered(idx_ref, x_hbm, msel_ref, s_ref, st_ref,
                            ew3_ref, eyv_ref, w0_ref, ctr_ref, wout_ref,
-                           c_ref, wm_ref, acc_ref, cacc_ref, *,
-                           pack: int, eta: float, alpha: float,
-                           n_sampled: int, sel_dtype,
+                           xbuf, sems, ct_ref, wm_ref, acc_ref, cnt_ref,
+                           *, pack: int, eta: float, alpha: float,
+                           n_steps: int, n_sampled: int, sel_dtype,
                            skip_update: bool = False):
     """v5 body: T SGD steps in ONE kernel launch (see
     :func:`fused_train_gathered`). Grid (T, n_sampled); the weight
-    master ``wm`` (P·D, 1) f32 and the bf16 selector ``c`` live in VMEM
-    scratch across ALL grid steps, so between-step cost is zero — no
-    kernel relaunch, no XLA glue, no HBM round-trip for the model state.
+    master ``wm`` (1, P·D) f32 and the selector ``ct`` (3P, P·D) in X2's
+    dtype live in VMEM scratch across ALL grid steps, so between-step
+    cost is zero — no kernel relaunch, no XLA glue, no HBM round-trip
+    for the model state. The ring of sampled blocks runs on across a
+    step's end (the update does not depend on the next step's blocks).
 
     The in-kernel update avoids cross-lane transposes (expensive
     relayouts on TPU) by expressing the gradient fold and the selector
     rebuild as small matmuls/reductions against constant operands:
       y    (P, D)    = (acc ⊙ Msel) · S      — per-slot diagonal band
       grow (1, D)    = Σ_sublanes y          — the gradient, lane-major
-      gcol (D, 1)    = Σ_lanes (I_D ⊙ grow)  — transposed via mask+reduce
-      Δw   (P·D, 1)  = S · gcol              — tiled to every slot
-      C              = bf16(wm ⊙ Ew3) + EyEv — selector rebuilt in place
+      Δw   (1, P·D)  = grow · Sᵀ             — tiled to every slot
+      Cᵀ             = bf16(wm ⊙ Ew3ᵀ) + EyEvᵀ — selector rebuilt in place
     """
-    P = pack
     t = pl.program_id(0)
     i = pl.program_id(1)
+    last_step = t == n_steps - 1
+
+    def rebuild_selector():
+        ct_ref[:] = (
+            jnp.broadcast_to(wm_ref[:], ct_ref.shape) * ew3_ref[:]
+        ).astype(sel_dtype) + eyv_ref[:]
 
     @pl.when((t == 0) & (i == 0))
     def _first():
         wm_ref[:] = w0_ref[:]
-        c_ref[:] = (
-            jnp.broadcast_to(w0_ref[:], c_ref.shape) * ew3_ref[:]
-        ).astype(sel_dtype) + eyv_ref[:]
+        rebuild_selector()
 
     @pl.when(i == 0)
     def _zero():
         acc_ref[:] = jnp.zeros_like(acc_ref)
-        cacc_ref[0, 0] = 0.0
+        cnt_ref[:] = jnp.zeros_like(cnt_ref)
 
-    x2 = x_ref[:]                                   # (bp, P·D), ONE read
-    zyv = jnp.dot(x2, c_ref[:], preferred_element_type=jnp.float32)
-    z, y, v = zyv[:, :P], zyv[:, P:2 * P], zyv[:, 2 * P:3 * P]
-    resid = ((jax.nn.sigmoid(z) - y) * v).astype(x2.dtype)
-    acc_ref[:] += jax.lax.dot_general(
-        resid, x2, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                               # (P, P·D) MXU
-    cacc_ref[0, 0] += jnp.sum(v)
+    x_ref = _ring_fetch(idx_ref, x_hbm, xbuf, sems, t * n_sampled + i,
+                        n_steps * n_sampled)
+    _block_grad(x_ref, ct_ref, acc_ref, cnt_ref, pack=pack)
 
     if skip_update:
         # roofline ablation (bench-only): the full gradient pass with
         # the serialized end-of-step update chain removed — the A/B
         # against the real kernel prices that chain exactly
-        @pl.when((t == pl.num_programs(0) - 1) & (i == n_sampled - 1))
+        @pl.when(last_step & (i == n_sampled - 1))
         def _done_abl():
             wout_ref[:] = wm_ref[:]
 
@@ -412,23 +512,20 @@ def _train_kernel_gathered(idx_ref, x_ref, msel_ref, s_ref, eye_ref,
 
     @pl.when(i == n_sampled - 1)
     def _update():
-        nb = jnp.maximum(cacc_ref[0, 0], 1.0)       # empty-sample guard
+        nb = jnp.maximum(jnp.sum(cnt_ref[:]), 1.0)  # empty-sample guard
         yband = jnp.dot(acc_ref[:] * msel_ref[:], s_ref[:],
                         preferred_element_type=jnp.float32)  # (P, D)
         grow = jnp.sum(yband, axis=0, keepdims=True)          # (1, D)
-        gcol = jnp.sum(eye_ref[:] * grow, axis=1, keepdims=True)
         wm = wm_ref[:] - (eta / nb) * jnp.dot(
-            s_ref[:], gcol, preferred_element_type=jnp.float32)
+            grow, st_ref[:], preferred_element_type=jnp.float32)
         if alpha:
             # EASGD elastic pull toward the round-start center
             # (easgd.py:41-45); both tails are zero, so no column mask
             wm = wm - alpha * (wm_ref[:] - ctr_ref[:])
         wm_ref[:] = wm
-        c_ref[:] = (
-            jnp.broadcast_to(wm_ref[:], c_ref.shape) * ew3_ref[:]
-        ).astype(sel_dtype) + eyv_ref[:]
+        rebuild_selector()
 
-    @pl.when((t == pl.num_programs(0) - 1) & (i == n_sampled - 1))
+    @pl.when(last_step & (i == n_sampled - 1))
     def _done():
         wout_ref[:] = wm_ref[:]
 
@@ -451,7 +548,7 @@ def fused_train_gathered(X2, w_tile0, block_idx, *, pack: int,
     proportional to the minibatch, but still paid a fixed per-STEP cost:
     one Mosaic launch (~8 µs) plus the XLA update glue (~3 µs) against
     ~33 µs of DMA at bench scale — ~25% of the step. Here the grid is
-    ``(T, n_sampled)``: the weight master and the selector C live in
+    ``(T, n_sampled)``: the weight master and the selector live in
     VMEM scratch across the whole schedule, the SGD update runs
     in-kernel at each block-row boundary, and the launch cost amortizes
     over T steps. Per-step work collapses to the minibatch DMA.
@@ -468,7 +565,8 @@ def fused_train_gathered(X2, w_tile0, block_idx, *, pack: int,
     ``w_tile0``: (P·D, 1) f32, the augmented weights tiled per slot
     (``jnp.tile(w_aug, P)[:, None]``). ``block_idx``: (T, n_sampled)
     int32. Returns the final (P·D, 1) weight tile; row j of any slot c
-    (``tile[c*D+j, 0]``) is ``w_aug[j]``.
+    (``tile[c*D+j, 0]``) is ``w_aug[j]``. (Inside, the master is the
+    same tile as a row, (1, P·D): the selector is built transposed.)
 
     ``alpha``/``center_tile`` add the EASGD elastic pull
     ``w −= α·(w − center)`` per step (``easgd.py:41-45``) — the center
@@ -477,35 +575,29 @@ def fused_train_gathered(X2, w_tile0, block_idx, *, pack: int,
     one launch per round; valid at dp>1 because local steps touch no
     interconnect).
 
-    Roofline decomposition (r5, measured on one v5e, recorded so the
-    0.8-vs-1.0 HBM fraction isn't re-hypothesized): the serialized
-    end-of-step update chain costs **0.5 µs/step** (A/B against
-    ``skip_update=True``: 43.35 vs 42.86 µs/step — ~1%, NOT the ~10%
-    the r3 pencil guessed), and the per-block grid-cell overhead is
-    negligible at equal bytes (13 / 6 / 3 cells per step via
-    gather_block_rows 8k/16k/32k all land at 0.72-0.74 of the
-    819 GB/s roofline in the same session — 575-590 GB/s effective).
-    The residual ~20-25% is the achievable DMA rate for randomly
-    ordered 2-8 MB block reads plus shared-chip contention
-    (session-dependent: 0.72-0.81 observed across rounds); the
-    sequential-read microbenchmark's 92% does not transfer, and no
-    update-chain restructuring can recover what the DMA engine never
-    delivers.
+    Roofline decomposition (PR 27, one v5e, the benchmark's bigbatch
+    shapes: blocks of 512 x 640 bf16 = 655 360 B, 1221 of 12 208 a
+    step, µs a block; PERF.md section 6 has the table). The kernel
+    before that PR: 1.177 whole, 1.062 with the block pinned (body
+    only), 0.875 with the body emptied (copies only): the BODY bound
+    it, a serial chain of a forward that streamed x2 through the MXU
+    (0.57), a middle of lane slices, a sigmoid over eighth-filled
+    registers, a transpose and a scalar count (0.44), and the backward
+    (0.33). Streaming a 16-row register through the MXU costs 16
+    cycles, latching it about 4, so this body latches x2 in both
+    passes: 0.54 pinned. Under it the copies bind: 0.875 with the
+    BlockSpec pipeline's one copy in flight behind the landing one
+    (0.948 with this body), **0.872 with the ring** (three or four
+    slots read the same, two read 0.958) = 751 GB/s, 92% of 819, what a
+    sequential read reaches: the order of the blocks costs nothing. The
+    end-of-step update chain is 0.5 µs a step (``skip_update=True``
+    A/B) and now runs under the next step's copies. A block cannot cost
+    less than its bytes; a layout that moves fewer (64 of its 80 B a
+    row are needed) is the next step, not a schedule.
     """
     P, D = pack, d_total
-    n2, pd = X2.shape
-    bp = gather_block_rows // P
-    if (pd != P * D or (P * D) % 128 or gather_block_rows % P
-            or bp == 0 or n2 % bp):
-        raise ValueError(
-            f"fused_train_gathered: X2 {X2.shape} incompatible with "
-            f"pack={P}, d_total={D}, gather_block_rows={gather_block_rows}"
-        )
-    if bp % 8:
-        raise ValueError(
-            f"gather_block_rows={gather_block_rows} gives {bp} packed "
-            f"rows per block; need a multiple of 8·pack={8 * P} rows"
-        )
+    bp = _gathered_shapes("fused_train_gathered", X2, P, D,
+                          gather_block_rows)
     T, n_sampled = block_idx.shape
 
     # constant operands of the in-kernel update (built once per trace;
@@ -515,28 +607,23 @@ def fused_train_gathered(X2, w_tile0, block_idx, *, pack: int,
     # Msel (P, P·D): 1 at [c, c·D+j] for kept j — the diagonal band of
     # the acc tile, with the y/v/pad gradient columns zeroed
     msel = (eyeP[:, :, None] * colmask[None, None, :]).reshape(P, P * D)
-    # S (P·D, D): identity stacked P times — tiles (D,·) to (P·D,·)
+    # S (P·D, D): identity stacked P times — folds (·, P·D) to (·, D);
+    # its transpose tiles (·, D) to (·, P·D)
     s_tile = jnp.tile(jnp.eye(D, dtype=jnp.float32), (P, 1))
-    eye_d = jnp.eye(D, dtype=jnp.float32)
-    # Ew3 (P·D, 3P): w-selector ones in the first P columns (colmasked
-    # rows); zeros over the Ey/Ev columns
-    ew = (eyeP[:, None, :] * colmask[None, :, None]).reshape(P * D, P)
+    # Ew3ᵀ (3P, P·D): w-selector ones in the first P rows (colmasked
+    # columns); zeros over the Ey/Ev rows
     ew3 = jnp.concatenate(
-        [ew, jnp.zeros((P * D, 2 * P), jnp.float32)], axis=1)
-    # EyEv (P·D, 3P) in X2's dtype: zeros over the w columns
-    ey = (eyeP[:, None, :] * jax.nn.one_hot(y_col, D, dtype=X2.dtype)[
-        None, :, None]).reshape(P * D, P)
-    ev = (eyeP[:, None, :] * jax.nn.one_hot(v_col, D, dtype=X2.dtype)[
-        None, :, None]).reshape(P * D, P)
-    eyv = jnp.concatenate(
-        [jnp.zeros((P * D, P), X2.dtype), ey, ev], axis=1
-    ).astype(X2.dtype)  # eyeP is f32; the products promote
+        [msel, jnp.zeros((2 * P, P * D), jnp.float32)], axis=0)
+    # EyEvᵀ (3P, P·D) in X2's dtype: the selector of a zero w
+    eyv = build_selector_t(jnp.zeros((D,), jnp.float32), pack=P,
+                           d_total=D, y_col=y_col, v_col=v_col,
+                           dtype=X2.dtype)
 
     if center_tile is None:
         center_tile = jnp.zeros((P * D, 1), jnp.float32)
     kernel = functools.partial(
         _train_kernel_gathered, pack=P, eta=eta, alpha=alpha,
-        n_sampled=n_sampled, sel_dtype=X2.dtype,
+        n_steps=T, n_sampled=n_sampled, sel_dtype=X2.dtype,
         skip_update=skip_update)
     whole = lambda t, i, s: (0, 0)  # noqa: E731 — resident constants
     wout = pl.pallas_call(
@@ -545,33 +632,34 @@ def fused_train_gathered(X2, w_tile0, block_idx, *, pack: int,
             num_scalar_prefetch=1,
             grid=(T, n_sampled),
             in_specs=[
-                pl.BlockSpec((bp, P * D), lambda t, i, s: (s[t, i], 0)),
+                pl.BlockSpec(memory_space=pl.ANY),     # X2 stays in HBM
                 pl.BlockSpec((P, P * D), whole),       # Msel
                 pl.BlockSpec((P * D, D), whole),       # S
-                pl.BlockSpec((D, D), whole),           # I_D
-                pl.BlockSpec((P * D, 3 * P), whole),   # Ew3
-                pl.BlockSpec((P * D, 3 * P), whole),   # EyEv
-                pl.BlockSpec((P * D, 1), whole),       # w_tile0
-                pl.BlockSpec((P * D, 1), whole),       # center tile
+                pl.BlockSpec((D, P * D), whole),       # Sᵀ
+                pl.BlockSpec((3 * P, P * D), whole),   # Ew3ᵀ
+                pl.BlockSpec((3 * P, P * D), whole),   # EyEvᵀ
+                pl.BlockSpec((1, P * D), whole),       # w_tile0 as a row
+                pl.BlockSpec((1, P * D), whole),       # center tile, too
             ],
-            out_specs=pl.BlockSpec((P * D, 1), whole),
-            scratch_shapes=[
-                pltpu.VMEM((P * D, 3 * P), X2.dtype),   # C
-                pltpu.VMEM((P * D, 1), jnp.float32),    # weight master
+            out_specs=pl.BlockSpec((1, P * D), whole),
+            scratch_shapes=_ring_scratch(bp, P * D, X2.dtype) + [
+                pltpu.VMEM((3 * P, P * D), X2.dtype),   # Cᵀ
+                pltpu.VMEM((1, P * D), jnp.float32),    # weight master
                 pltpu.VMEM((P, P * D), jnp.float32),    # grad acc
-                pltpu.SMEM((1, 1), jnp.float32),        # count acc
+                pltpu.VMEM((P, _chunk_rows(bp)), jnp.float32),  # counts
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((P * D, 1), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, P * D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
         name="_train_kernel_gathered",
-    )(block_idx.astype(jnp.int32), X2, msel, s_tile, eye_d, ew3, eyv,
-      w_tile0, center_tile)
-    return wout
+    )(block_idx.astype(jnp.int32).reshape(-1), X2, msel, s_tile,
+      s_tile.T, ew3, eyv, w_tile0.reshape(1, P * D),
+      center_tile.reshape(1, P * D))
+    return wout.reshape(P * D, 1)
 
 
 def _fwd_kernel_gathered(idx_ref, x_ref, c_ref, zyv_ref):
@@ -728,6 +816,23 @@ def build_selector(w_aug, *, pack: int, d_total: int, y_col: int,
     ev = (eyeP[:, None, :] * jax.nn.one_hot(v_col, D, dtype=dtype)[
         None, :, None]).reshape(P * D, P)
     return jnp.concatenate([wbig, ey, ev], axis=1)
+
+
+def build_selector_t(w_aug, *, pack: int, d_total: int, y_col: int,
+                     v_col: int, dtype=jnp.bfloat16):
+    """:func:`build_selector`'s operand as the gathered kernels latch
+    it, Cᵀ (3P, P·D): row c is ``w`` in slot c's columns, row P+c the
+    one at ``c·D+y_col``, row 2P+c the one at ``c·D+v_col``. Built in
+    this shape (a transpose of C folded into the dot is a form XLA:CPU
+    has no bf16 kernel for, and the kernels run interpreted there)."""
+    P, D = pack, d_total
+    eyeP = jnp.eye(P, dtype=dtype)
+    rows = [w_aug.reshape(-1).astype(dtype),
+            jax.nn.one_hot(y_col, D, dtype=dtype),
+            jax.nn.one_hot(v_col, D, dtype=dtype)]
+    return jnp.concatenate(
+        [(eyeP[:, :, None] * r[None, None, :]).reshape(P, P * D)
+         for r in rows], axis=0)
 
 
 @functools.partial(
