@@ -2,8 +2,7 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
 One process drives the main path once, through the entry points a user
-would call, at the sizes the bench runs (depth cut, weights random from
-a seed), on every TPU device it finds:
+would call, at smoke sizes (depth cut, weights random from a seed), on every TPU device it finds:
 
   * trainer: SSGD logistic regression, 2^20 rows x (125 features + bias)
     packed 128 wide, bf16, minibatch 0.1, one 1500-step segment through
@@ -16,7 +15,7 @@ a seed), on every TPU device it finds:
     compiled fused matmul+top-k kernel, checked against
     ``xla_matmul_topk`` on the same factors;
   * kernel roll-call: every ``pallas_call`` a default TPU path selects,
-    compiled at its bench geometry and compared with its in-repo XLA
+    compiled at its smoke geometry and compared with its in-repo XLA
     reference at the tolerance stated beside each check;
   * more than one device: the int8 / bucketed gradient-sync rings, and
     the shard-per-device + memory-in-use assertions.
@@ -45,12 +44,12 @@ import time
 import traceback
 
 REFERENCE_BAND = 0.92       # BASELINE.md: SSGD/LR final acc 0.93-0.94
-N_ROWS = 1 << 20            # bench.py: N_ROWS / N_FEATURES / N_STEPS
+N_ROWS = 1 << 20            # the smoke sizes: 0.1 to 0.6 GB a stage
 N_FEATURES = 125
 N_STEPS = 1500
 GATHER_BLOCK_ROWS = 8192
 N_TEST = 16384              # held-out rows from the same generator
-ALS_M, ALS_N, ALS_K = 4096, 16384, 64   # BENCH_r04's ALS size
+ALS_M, ALS_N, ALS_K = 4096, 16384, 64
 ATTN_SEQ, ATTN_HEADS, ATTN_DIM = 32768, 8, 128
 PR_VERTICES, PR_AVG_DEGREE = 1_000_000, 8.0
 
@@ -275,8 +274,8 @@ class Smoke:
 # ---------------------------------------------------------------- stages
 
 
-def _bench_rows(s: Smoke):
-    """The bench's SSGD rows (+ a held-out tail from the same seed),
+def _smoke_rows(s: Smoke):
+    """The smoke's SSGD rows (+ a held-out tail from the same seed),
     generated once and shared by the stages that train on them."""
     if "rows" not in s.shared:
         from tpu_distalg.utils import datasets
@@ -294,7 +293,7 @@ def _held_out_acc(s: Smoke, w) -> float:
     decision rule (utils/metrics.binary_accuracy), on the host."""
     import numpy as np
 
-    _, _, X_te, y_te = _bench_rows(s)
+    _, _, X_te, y_te = _smoke_rows(s)
     w = np.asarray(w, np.float32)
     if w.shape != (N_FEATURES + 1,) or not np.isfinite(w).all():
         raise AssertionError(
@@ -310,7 +309,7 @@ def _xla_reference_acc(s: Smoke) -> float:
         from tpu_distalg.models import ssgd
 
         res = ssgd.train(
-            *_bench_rows(s), s.mesh(),
+            *_smoke_rows(s), s.mesh(),
             ssgd.SSGDConfig(n_iterations=N_STEPS, eval_test=False,
                             init_seed=7))
         s.shared["xla_acc"] = _held_out_acc(s, res.w)
@@ -327,8 +326,8 @@ def _acc_check(s: Smoke, name: str, w, tol: float = 0.03) -> str:
 
 
 def stage_ssgd_flagship(s: Smoke):
-    """bench.py's flagship cell through ssgd.train; sampler chosen by
-    the mesh exactly as bench._bench_ssgd does."""
+    """The smoke rows through ssgd.train; sampler chosen by the mesh
+    (the megakernel on one data shard, the block gather on more)."""
     from tpu_distalg.models import ssgd
 
     mesh = s.mesh()
@@ -339,7 +338,7 @@ def stage_ssgd_flagship(s: Smoke):
         n_iterations=N_STEPS, eval_test=False, x_dtype="bfloat16",
         sampler=sampler, gather_block_rows=GATHER_BLOCK_ROWS,
         shuffle_seed=0, init_seed=7)
-    res = ssgd.train(*_bench_rows(s), mesh, cfg)
+    res = ssgd.train(*_smoke_rows(s), mesh, cfg)
     if (f"pallas_kernels.{kern}", False) not in s.spy.kernels:
         raise AssertionError(
             f"{sampler} did not build {kern}: {s.spy.kernels}")
@@ -353,7 +352,7 @@ def stage_ssgd_flagship(s: Smoke):
 def _cli_ssgd(s: Smoke, sampler: str):
     """``tda ssgd`` on its hard-wired 398-row breast-cancer set, at the
     block geometry the repo's own on-chip convergence checks use
-    (tests_tpu, bench convergence_acc_*) and on ONE data shard: the
+    (tests_tpu) and on ONE data shard: the
     reference band is a property of that geometry — at the CLI's
     default 1024-row blocks the set is a single block (full-batch GD,
     0.8947 on the CPU interpreter), and split four ways its ~100 rows
@@ -450,7 +449,7 @@ def stage_als_train_serve(s: Smoke):
 
 
 def stage_flash_attention(s: Smoke):
-    """Causal flash attention forward and backward at the bench
+    """Causal flash attention forward and backward at the smoke
     geometry (32k x 8 heads x 128, bf16), ring over all devices.
     Forward vs the XLA online-softmax ring at the same geometry
     (2e-2, bf16 outputs). Backward at 32k: finite, and dQ of the first
@@ -568,17 +567,17 @@ def stage_pagerank(s: Smoke):
 
 
 def stage_local_sgd_megakernel(s: Smoke):
-    """MA with megakernel local rounds at bench._bench_local_sgd's
-    geometry (300 rounds x 5 local steps over the bench rows), vs the
-    XLA trainer's held-out accuracy; and on breast-cancer against the
-    reference MA golden 0.8538 (ma.py:131)."""
+    """MA with megakernel local rounds (300 rounds x 5 local steps
+    over the smoke rows), vs the XLA trainer's held-out accuracy; and
+    on breast-cancer against the reference MA golden 0.8538
+    (ma.py:131)."""
     import warnings
 
     from tpu_distalg.models import ma
     from tpu_distalg.utils import datasets
 
     mesh = s.mesh()
-    res = ma.train(*_bench_rows(s), mesh, ma.MAConfig(
+    res = ma.train(*_smoke_rows(s), mesh, ma.MAConfig(
         n_iterations=300, n_local_iterations=5, sampler="fused_train",
         x_dtype="bfloat16", gather_block_rows=GATHER_BLOCK_ROWS,
         shuffle_seed=0, eval_test=False))
@@ -597,13 +596,13 @@ def stage_local_sgd_megakernel(s: Smoke):
 
 def stage_v3_sampler(s: Smoke):
     """The on-core-PRNG streaming kernel (no interpret lowering exists,
-    so only the chip can say): the bench rows for 300 steps vs the XLA
+    so only the chip can say): the smoke rows for 300 steps vs the XLA
     trainer, and breast-cancer against the reference band."""
     from tpu_distalg.models import ssgd
     from tpu_distalg.utils import datasets
 
     mesh = s.mesh()
-    res = ssgd.train(*_bench_rows(s), mesh, ssgd.SSGDConfig(
+    res = ssgd.train(*_smoke_rows(s), mesh, ssgd.SSGDConfig(
         n_iterations=300, eval_test=False, sampler="fused",
         x_dtype="bfloat16", init_seed=7))
     out = [_acc_check(s, "fused(v3) 300 steps", res.w, tol=0.05)]
@@ -617,40 +616,35 @@ def stage_v3_sampler(s: Smoke):
 
 
 def stage_kmeans_kernel(s: Smoke):
-    """ops/pallas_kmeans.fused_cluster_stats vs ops/kmeans on a small
-    shape, under the kernel's documented contract: distance dots at
-    default MXU precision (f32 operands rounded to bf16), distances
-    compared on the bf16 grid, first-minimum tie-break; stats exact.
-    The reference assignment applies the same roundings in XLA; a
-    point whose two nearest centers then tie to within f32 summation
-    order may still land on either (at most 0.1% of points), and the
-    sums are held to 1e-5 plus what those points can carry."""
+    """ops/pallas_lloyd.lloyd_pass (the kernel ``kmeans20_100m_k10``
+    runs) vs ops/kmeans on a small lanes geometry at the cell's dim and
+    k. The kernel keeps every product in float32, so the reference
+    assignment is ``ops/kmeans.assign_clusters`` at ``highest`` matmul
+    precision; a point whose two nearest centres then tie to within
+    f32 summation order may still land on either (at most 0.1% of
+    points), and the sums are held to 1e-5 plus what those points can
+    carry."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
     from tpu_distalg.ops import kmeans as kops
-    from tpu_distalg.ops import pallas_kmeans as pk
+    from tpu_distalg.ops import pallas_lloyd as lloyd
 
     rng = np.random.default_rng(0)
-    n, dim, k = 8192, 16, 8
+    dim, k, n_blocks = 20, 10, 8
+    geom = lloyd.lanes_geometry(dim, k, block_rows=32)
+    n = n_blocks * geom.block_points
+    n_valid = n - n // 10
     pts = (rng.normal(size=(n, dim)) * 3).astype(np.float32)
-    mask = np.ones(n, np.float32)
-    mask[-n // 10:] = 0.0
     centers = (rng.normal(size=(k, dim)) * 3).astype(np.float32)
-    X2, m2 = pk.pack_points(pts, mask, dim=dim, k=k)
-    sums, counts = pk.fused_cluster_stats(
-        X2, m2, jnp.asarray(centers), dim=dim, k=k)
     p, c = jnp.asarray(pts), jnp.asarray(centers)
-
-    def bf16(x):
-        return x.astype(jnp.bfloat16).astype(jnp.float32)
-
-    d2 = (jnp.sum(bf16(p * p), axis=1, keepdims=True)
-          - 2.0 * jnp.einsum("nd,kd->nk", bf16(p), bf16(c),
-                             precision="highest")
-          + jnp.sum(c * c, axis=1)[None, :])
-    assign = jnp.argmin(bf16(d2), axis=1)
-    s_ref, c_ref = kops.cluster_stats(p, jnp.asarray(mask), assign, k)
+    x4 = jax.vmap(geom.pack)(p.reshape(n_blocks, geom.block_points, dim))
+    sums, counts = lloyd.fold_stats(*lloyd.lloyd_pass(x4, c, n_valid))
+    with jax.default_matmul_precision("highest"):
+        assign = kops.assign_clusters(p, c)
+    mask = (jnp.arange(n) < n_valid).astype(jnp.float32)
+    s_ref, c_ref = kops.cluster_stats(p, mask, assign, k)
     moved = float(np.abs(np.asarray(counts) - np.asarray(c_ref)).sum())
     if moved > 0.001 * n:
         raise AssertionError(
@@ -665,7 +659,7 @@ def stage_kmeans_kernel(s: Smoke):
 def _comm_stage(s: Smoke, comm: str):
     from tpu_distalg.models import ssgd
 
-    res = ssgd.train(*_bench_rows(s), s.mesh(), ssgd.SSGDConfig(
+    res = ssgd.train(*_smoke_rows(s), s.mesh(), ssgd.SSGDConfig(
         n_iterations=300, eval_test=False, x_dtype="bfloat16",
         sampler="fused_gather", gather_block_rows=GATHER_BLOCK_ROWS,
         shuffle_seed=0, init_seed=7, comm=comm))
@@ -694,7 +688,7 @@ STAGES = (
     ("v3_on_core_prng_sampler", stage_v3_sampler,
      dict(kernels=("pallas_kernels._grad_kernel_packed",))),
     ("kmeans_kernel", stage_kmeans_kernel,
-     dict(kernels=("pallas_kmeans._kernel",))),
+     dict(kernels=("pallas_lloyd._lloyd_kernel",))),
     ("ssgd_comm_int8", functools.partial(_comm_stage, comm="int8"),
      dict(min_devices=2)),
     ("ssgd_comm_bucketed",

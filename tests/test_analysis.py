@@ -1185,7 +1185,7 @@ def test_committed_tree_lints_clean():
     from tpu_distalg import cli
 
     paths = [str(REPO / "tpu_distalg"), str(REPO / "tests"),
-             str(REPO / "scripts"), str(REPO / "bench.py")]
+             str(REPO / "scripts")]
     rc = cli.main(["lint", *paths, "--no-ruff",
                    "--baseline", str(REPO / "lint_baseline.json")])
     assert rc == 0
@@ -1477,7 +1477,6 @@ def test_tda091_wal_append_must_fsync_before_send():
 # ------------------------------------------- TDA1xx: the project graph
 
 from tpu_distalg.analysis import project as projmod  # noqa: E402
-from tpu_distalg.analysis import telemetry_contract as tcmod  # noqa: E402
 
 
 def plint(tmp_path, monkeypatch, files, select=None, ignore=None,
@@ -1666,30 +1665,6 @@ def test_tda101_complete_argv_clean(tmp_path, monkeypatch):
                  "miniproj/launcher.py": LAUNCHER_COMPLETE},
                 select=("TDA101",))
     assert res.violations == []
-
-
-BENCH_DRIFTED = """
-ALL_METRIC_NAMES = ("good_metric", "ghost_metric")
-
-
-def emit(out):
-    out({"metric": "good_metric", "value": 1.0})
-    out({"metric": "rogue_metric", "value": 2.0})
-"""
-
-
-def test_tda102_bench_metric_drift_both_directions(tmp_path,
-                                                   monkeypatch):
-    res = plint(tmp_path, monkeypatch,
-                {"miniproj/__init__.py": "",
-                 "miniproj/bench_emit.py": BENCH_DRIFTED},
-                select=("TDA102",))
-    msgs = sorted(v.message for v in res.violations)
-    assert [v.code for v in res.violations] == ["TDA102", "TDA102"]
-    assert any("ghost_metric" in m and "no emission site" in m
-               for m in msgs)
-    assert any("rogue_metric" in m and "missing from" in m
-               for m in msgs)
 
 
 TELMOD = """
@@ -1943,20 +1918,6 @@ def test_cli_changed_flag_uses_git_view(tmp_path, monkeypatch,
     assert rc == 1                      # the graph finding still gates
     assert "TDA100" in out
     assert "1 linted, graph over all" in out
-
-
-def test_metric_contract_collector_matches_bench():
-    """Satellite: the three per-test AST tripwires now route through
-    THIS collector — pin its verdict on the real bench.py here."""
-    contract = tcmod.bench_contract(str(REPO))
-    assert "ssgd_lr_steps_per_sec_per_chip" in contract.canonical
-    unemitted, rogue = tcmod.contract_problems(contract)
-    assert unemitted == [] and rogue == {}
-    tcmod.assert_registered(["ssgd_lr_steps_per_sec_per_chip"],
-                            str(REPO))
-    with pytest.raises(AssertionError):
-        tcmod.assert_registered(["no_such_metric_anywhere"],
-                                str(REPO))
 
 
 def test_project_rules_have_codes_and_invariants():
@@ -2418,24 +2379,33 @@ def test_cli_json_schema_is_pinned(tmp_path, monkeypatch, capsys):
     assert doc["baselined"] == 0 and doc["stale_baseline"] == []
 
 
-def test_lint_graph_seconds_stays_interactive(tmp_path):
+def test_lint_graph_work_stays_incremental(tmp_path, monkeypatch):
     """TIER-1 perf tripwire: the protocol extraction rides every
-    summary build, so the graph pass must stay cheap — a cold full
-    tree under 10 s, a warm --changed-style run under 2 s."""
+    summary build, so the graph pass must stay incremental. Counted
+    as work, not seconds (a wall clock on a machine running six test
+    workers measures the neighbours): a cold full tree summarises
+    every file once, a warm --changed-style run parses the one
+    changed file and summarises none."""
     paths = [str(REPO / "tpu_distalg"), str(REPO / "tests"),
-             str(REPO / "scripts"), str(REPO / "bench.py")]
+             str(REPO / "scripts")]
     files = engine.iter_python_files(paths)
+    built = []
+    for name in ("summarize_context", "extract_summary"):
+        real = getattr(projmod, name)
+        monkeypatch.setattr(
+            projmod, name,
+            lambda *a, _real=real, **k: built.append(1) or _real(*a, **k))
     cache = str(tmp_path / "graphcache")
     cold = projmod.lint_tree(files, analysis.RULES,
                              analysis.PROJECT_RULES, cache_dir=cache)
-    assert cold.graph_seconds < 10.0, (
-        f"cold graph build took {cold.graph_seconds}s")
+    assert cold.n_cached == 0 and cold.n_linted == len(files)
+    assert len(built) == len(files)
+    del built[:]
     warm = projmod.lint_tree(
         files, analysis.RULES, analysis.PROJECT_RULES,
         changed_only={engine.norm_path(files[0])}, cache_dir=cache)
-    assert warm.n_cached >= len(files) - 1
-    assert warm.graph_seconds < 2.0, (
-        f"warm --changed graph pass took {warm.graph_seconds}s")
+    assert warm.n_cached == len(files) and warm.n_linted == 1
+    assert built == []
 
 
 # --------------------------------------- TDA102: stale-waiver audit

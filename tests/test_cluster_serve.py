@@ -9,13 +9,11 @@ accounting, live hot-swap under a concurrent burst (zero drops,
 per-replica version monotonicity, compressed-delta path), router WAL
 crash recovery on the same port, and the chaos harness verdict
 (replica kill + rpc oserror grid -> bitwise replies + availability
-band). The metric/claims registration contract rides at the end.
+band).
 """
 
 from __future__ import annotations
 
-import os
-import sys
 import threading
 import time
 
@@ -329,32 +327,3 @@ def test_chaos_cluster_serve_kill_and_rpc_grid(tmp_path):
 
 
 # ------------------------------------------------- registration contract
-
-
-def test_cluster_serve_metrics_registered_for_claims_and_fallback():
-    import bench
-    from tpu_distalg.analysis import telemetry_contract as tc
-
-    names = ("cluster_serve_qps",
-             "cluster_serve_p99_under_kill_ms",
-             "cluster_serve_availability")
-    # membership AND a live emission site, via the one TDA102 collector
-    tc.assert_registered(
-        names, os.path.dirname(os.path.abspath(bench.__file__)))
-    assert "cluster_serve_p99_under_kill_ms" in \
-        bench.LOWER_IS_BETTER_METRICS
-    # throughput and availability are higher-is-better: must NOT be in
-    # the lower-is-better set or the tripwire would flag improvements
-    assert "cluster_serve_qps" not in bench.LOWER_IS_BETTER_METRICS
-    assert "cluster_serve_availability" not in \
-        bench.LOWER_IS_BETTER_METRICS
-
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "scripts"))
-    import check_readme_claims as crc
-
-    claimed = {m for m, _, _ in crc.CLAIMS}
-    assert set(names) <= claimed
-    assert "cluster_serve_qps" in crc.FLOOR_CLAIMS
-    assert "cluster_serve_availability" in crc.FLOOR_CLAIMS
-    assert "cluster_serve_p99_under_kill_ms" in crc.CEILING_CLAIMS

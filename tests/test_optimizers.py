@@ -16,13 +16,6 @@ import pytest
 from tpu_distalg.models import bmuf, easgd, logistic_regression, ma, ssgd
 
 
-@pytest.mark.skip(reason="seed-failure[platform-pin]: trajectory pin "
-                  "0.9415 measured on the original rig's BLAS; this "
-                  "container converges the same schedule to 0.8187 "
-                  "(1500 chaotic SGD steps amplify reduction-order "
-                  "drift). Convergence on THIS platform is asserted by "
-                  "tests/test_comms.py::"
-                  "test_trainer_compressed_converges_in_band")
 def test_ssgd_converges(mesh8, cancer_data):
     X_train, y_train, X_test, y_test = cancer_data
     res = ssgd.train(
@@ -37,8 +30,6 @@ def test_ssgd_converges(mesh8, cancer_data):
     assert res.accs.shape == (1500,)
 
 
-@pytest.mark.skip(reason="seed-failure[platform-pin]: same 0.9415 pin "
-                  "and platform divergence as test_ssgd_converges")
 def test_ssgd_with_l2(mesh8, cancer_data):
     X_train, y_train, X_test, y_test = cancer_data
     res = ssgd.train(
@@ -48,9 +39,6 @@ def test_ssgd_with_l2(mesh8, cancer_data):
     np.testing.assert_allclose(res.final_acc, 0.9415, atol=0.01)
 
 
-@pytest.mark.skip(reason="seed-failure[platform-pin]: pin 0.9415 "
-                  "measured on the original rig; this container's BLAS "
-                  "walks a different 1500-step full-batch trajectory")
 def test_full_batch_lr_converges(mesh8, cancer_data):
     X_train, y_train, X_test, y_test = cancer_data
     res = logistic_regression.train(
@@ -73,9 +61,6 @@ def test_ma_converges(mesh4, cancer_data):
     np.testing.assert_allclose(res.final_acc, 0.9298, atol=0.01)
 
 
-@pytest.mark.skip(reason="seed-failure[platform-pin]: pin 0.9415 "
-                  "measured on the original rig; this container "
-                  "converges BMUF's 300 rounds elsewhere in the band")
 def test_bmuf_converges(mesh4, cancer_data):
     X_train, y_train, X_test, y_test = cancer_data
     res = bmuf.train(
@@ -96,12 +81,6 @@ def test_easgd_converges(mesh4, cancer_data):
     np.testing.assert_allclose(res.final_acc, 0.9298, atol=0.01)
 
 
-@pytest.mark.skip(reason="seed-failure[platform-chaos]: the 1-vs-8 "
-                  "device comparison holds to rtol=2e-3 on the "
-                  "original rig but this BLAS's reduction order "
-                  "diverges the two 50-step trajectories beyond it "
-                  "(unnormalized features, |w| ~ 90); the property is "
-                  "still covered at 1 step by test_parallel_core")
 def test_ssgd_topology_independence(mesh1, mesh8, cancer_data):
     """SURVEY.md §4: n-device result ≡ 1-device result. The Bernoulli masks
     come from the partitionable PRNG keyed by row position, so the only
@@ -142,7 +121,7 @@ def test_ssgd_fused_gather_sampler(mesh4, cancer_data):
     """The traffic-proportional gathered kernel end-to-end on the CPU mesh
     (interpret mode — same code path that compiles to Mosaic on TPU).
     Short run: interpret-mode pallas is slow; convergence-to-golden is
-    asserted on TPU (test_tpu_numerics.py) and recorded by bench.py."""
+    asserted on TPU (tests_tpu/test_tpu_numerics.py, chip_smoke.py)."""
     X_train, y_train, X_test, y_test = cancer_data
     cfg = ssgd.SSGDConfig(
         n_iterations=400, sampler="fused_gather", fused_pack=4,
@@ -207,12 +186,6 @@ def test_local_sgd_unknown_sampler_rejected(mesh4, cancer_data):
         ma.train(*cancer_data, mesh4, ma.MAConfig(sampler="nope"))
 
 
-@pytest.mark.skip(reason="seed-failure[platform-chaos]: tp-vs-dp "
-                  "agreement to rtol=2e-3 after 100 chaotic steps "
-                  "holds on the original rig but not under this "
-                  "BLAS's reduction order; the kernel-level tp "
-                  "equivalence is still covered by "
-                  "test_ssgd_feature_sharded_fused_gather_matches_dp")
 def test_ssgd_feature_sharded_matches_dp(mesh_2x4, mesh1, cancer_data):
     """dp*tp (features over the model axis) must match the pure-dp result:
     same Bernoulli masks (topology-independent), same math, different

@@ -1,5 +1,6 @@
 """Pallas fused-gradient kernel vs the XLA path (interpret mode on CPU;
-the same kernel compiles to Mosaic on TPU — exercised by bench.py)."""
+the same kernel compiles to Mosaic on TPU — exercised by tests_tpu/
+and chip_smoke.py)."""
 
 import jax.numpy as jnp
 import numpy as np

@@ -1,6 +1,6 @@
 """Pallas windowed one-hot-MXU scatter (ops/pallas_pagerank): the
 standard-mode PageRank sweep's scatter half. Interpret mode on the CPU
-mesh; the kernel path proper is benchmarked on hardware (bench.py)."""
+mesh; the kernel path proper compiles on the chip in chip_smoke.py."""
 
 import numpy as np
 import pytest

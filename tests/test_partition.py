@@ -7,18 +7,15 @@
     table pair, and the wire-byte accounting against the closed-form
     ring model;
   * the 2-D mesh geometry grid (1×N, N×1, 2×2) as a config;
-  * golden-hash pins: every model's default-config trajectory under
-    rule-table placement is bitwise-identical to the pre-PR commit
-    (the dense SGD-family pins live in tests/test_comms.py — these
-    cover the placements that PR touched beyond them);
+  * placement pins: every model's default-config trajectory under
+    rule-table placement is bitwise the one under explicit
+    ``NamedSharding`` placement, both run here;
   * the checkpoint-restore placement and serve-artifact-load seams;
   * the sparse-closure scale-story satellite (capacity auto-sizing +
     the documented refusal).
 """
 
 from __future__ import annotations
-
-import hashlib
 
 import numpy as np
 import pytest
@@ -28,11 +25,6 @@ from jax.sharding import PartitionSpec as P
 
 from tpu_distalg.parallel import get_mesh
 from tpu_distalg.parallel import partition as pt
-
-
-def _h(x) -> str:
-    return hashlib.sha256(
-        np.ascontiguousarray(np.asarray(x)).tobytes()).hexdigest()[:16]
 
 
 # ------------------------------------------------------- rule matching
@@ -302,67 +294,108 @@ def test_cli_mesh_shape_parse():
             parse_mesh_shape(bad)
 
 
-# ---------------------------------------------------- golden pins
+# ------------------------------------------------- placement pins
 #
-# Captured at the pre-refactor parent commit on this container's CPU
-# BLAS (the dense ma/bmuf/easgd/local_sgd/ssgd pins live in
-# tests/test_comms.py and still hold) — rule-table placement must be
-# BITWISE-invisible in every trajectory.
+# What the rule table owes a trajectory: placing by the table changes
+# nothing. Each workload runs twice in this process, once as it is and
+# once with ``partition.put`` / ``ensure`` / ``constrain`` replaced by
+# the test's own placement: an explicit ``NamedSharding`` per leaf,
+# written out below (the layouts the trainers spelled by hand before
+# the table). Bitwise, and nothing recorded on another machine is
+# compared. ``lr``'s default run and ``kmeans.fit`` place nothing
+# through the table (``parallelize`` only): their entry is empty, what
+# is compared there is a replay, and the test checks which kind each
+# workload is.
 
-_GOLDEN = {
-    "ssgd_fused_gather": ("8377b020a25bc9f2", "0e1f3eb13a30ba2e"),
-    "ssgd_tp_2x2": ("8377b020a25bc9f2", "0e1f3eb13a30ba2e"),
-    "ssgd_feature_sharded_2x2": ("f9922f7350e4e440",
-                                 "1881f0c2e4f7512b"),
-    "ssgd_ssp": ("182c7da6899fc0b8", "3deef5afd58948bc"),
-    "lr": ("c634ad97be0a0a96", "f6feb933335f5106"),
-    "kmeans": ("6513d966ca1a56b1", None),
-    "als": ("0095b0bee38cdf83", "75210c486d7fd894"),
-    "als_2x2": ("39cf9566d45c3af3", "fe05b0375c576a45"),
-    "pagerank": ("cdf4c29b917a486a", None),
+_EXPLICIT = {
+    "ssgd_fused_gather": {"X2": P("data", None)},
+    "ssgd_tp_2x2": {"X2": P("data", "model"), "w": P("model")},
+    "ssgd_feature_sharded_2x2": {"X_data": P("data", "model"),
+                                 "w": P("model")},
+    "ssgd_ssp": {"w": P(), "clocks": P(), "pend": P(), "basegen": P(),
+                 "wl": P("data", None), "accd": P("data", None),
+                 "res": P("data", None)},
+    "lr": {},
+    "kmeans": {},
+    "als": {"R": P("data", None), "U": P("data", None), "V0": P()},
+    "als_2x2": {"R": P("data", None), "U": P("data", None), "V0": P(),
+                "V": P("model", None)},
+    "pagerank": {"src": P("data"), "dst": P("data"), "w_e": P("data"),
+                 "emask": P("data")},
 }
 
 
-def test_golden_hashes_under_rule_table_placement(mesh4, mesh_2x2_4dev,
-                                                  cancer_data):
+def _trajectory(name, mesh4, mesh_2x2, data):
     from tpu_distalg.models import als, kmeans, pagerank, ssgd
     from tpu_distalg.models import logistic_regression as lr
 
-    got = {}
-    r = ssgd.train(*cancer_data, mesh4, ssgd.SSGDConfig(
-        n_iterations=20, sampler="fused_gather"))
-    got["ssgd_fused_gather"] = (_h(r.w), _h(r.accs))
-    r = ssgd.train(*cancer_data, mesh_2x2_4dev, ssgd.SSGDConfig(
-        n_iterations=20, sampler="fused_gather", feature_sharded=True))
-    got["ssgd_tp_2x2"] = (_h(r.w), _h(r.accs))
-    r = ssgd.train(*cancer_data, mesh_2x2_4dev, ssgd.SSGDConfig(
-        n_iterations=20, feature_sharded=True))
-    got["ssgd_feature_sharded_2x2"] = (_h(r.w), _h(r.accs))
-    r = ssgd.train(*cancer_data, mesh4, ssgd.SSGDConfig(
-        n_iterations=24, sync="ssp:4"))
-    got["ssgd_ssp"] = (_h(r.w), _h(r.accs))
-    r = lr.train(*cancer_data, mesh4, lr.LRConfig(n_iterations=12))
-    got["lr"] = (_h(r.w), _h(r.accs))
-    pts = np.asarray(
-        np.random.default_rng(1).normal(size=(512, 8)), np.float32)
-    km = kmeans.fit(pts, mesh4, kmeans.KMeansConfig(
-        k=4, n_iterations=5))
-    got["kmeans"] = (_h(km.centers), None)
-    ar = als.fit(mesh4, als.ALSConfig(m=100, n=500, k=10,
-                                      n_iterations=3))
-    got["als"] = (_h(ar.U), _h(ar.V))
-    ar = als.fit(mesh_2x2_4dev, als.ALSConfig(m=100, n=500, k=10,
-                                              n_iterations=3))
-    got["als_2x2"] = (_h(ar.U), _h(ar.V))
-    rng = np.random.default_rng(0)
-    edges = rng.integers(0, 200, size=(1200, 2), dtype=np.int64)
-    pr = pagerank.run(edges, mesh4, pagerank.PageRankConfig(
-        n_iterations=10))
-    got["pagerank"] = (_h(pr.ranks), None)
+    if name == "ssgd_fused_gather":
+        r = ssgd.train(*data, mesh4, ssgd.SSGDConfig(
+            n_iterations=20, sampler="fused_gather"))
+    elif name == "ssgd_tp_2x2":
+        r = ssgd.train(*data, mesh_2x2, ssgd.SSGDConfig(
+            n_iterations=20, sampler="fused_gather",
+            feature_sharded=True))
+    elif name == "ssgd_feature_sharded_2x2":
+        r = ssgd.train(*data, mesh_2x2, ssgd.SSGDConfig(
+            n_iterations=20, feature_sharded=True))
+    elif name == "ssgd_ssp":
+        r = ssgd.train(*data, mesh4, ssgd.SSGDConfig(
+            n_iterations=24, sync="ssp:4"))
+    elif name == "lr":
+        r = lr.train(*data, mesh4, lr.LRConfig(n_iterations=12))
+    elif name == "kmeans":
+        pts = np.asarray(
+            np.random.default_rng(1).normal(size=(512, 8)), np.float32)
+        return (kmeans.fit(pts, mesh4, kmeans.KMeansConfig(
+            k=4, n_iterations=5)).centers,)
+    elif name in ("als", "als_2x2"):
+        ar = als.fit(mesh4 if name == "als" else mesh_2x2,
+                     als.ALSConfig(m=100, n=500, k=10, n_iterations=3))
+        return ar.U, ar.V
+    else:
+        edges = np.random.default_rng(0).integers(
+            0, 200, size=(1200, 2), dtype=np.int64)
+        return (pagerank.run(edges, mesh4, pagerank.PageRankConfig(
+            n_iterations=10)).ranks,)
+    return r.w, r.accs
 
-    for name, want in _GOLDEN.items():
-        assert got[name] == want, \
-            f"{name}: trajectory changed under rule-table placement"
+
+@pytest.mark.parametrize("name", sorted(_EXPLICIT))
+def test_rule_table_placement_changes_no_trajectory(
+        monkeypatch, mesh4, mesh_2x2_4dev, cancer_data, name):
+    from jax.sharding import NamedSharding
+
+    def run(which=name):
+        return [np.asarray(x).tobytes() for x in _trajectory(
+            which, mesh4, mesh_2x2_4dev, cancer_data)]
+
+    by_table = run()
+    placed = []
+
+    def explicit(mesh, leaf):
+        placed.append(leaf)
+        return NamedSharding(mesh, _EXPLICIT[name][leaf])
+
+    monkeypatch.setattr(
+        pt, "put", lambda x, leaf, tbl, mesh: jax.device_put(
+            x if isinstance(x, jax.Array) else np.asarray(x),
+            explicit(mesh, leaf)))
+    monkeypatch.setattr(
+        pt, "constrain", lambda x, leaf, tbl, mesh:
+        jax.lax.with_sharding_constraint(x, explicit(mesh, leaf)))
+    monkeypatch.setattr(
+        pt, "ensure", lambda tree, tbl, mesh: {
+            leaf: jax.device_put(x, explicit(mesh, leaf))
+            for leaf, x in tree.items()})
+    assert run() == by_table, \
+        f"{name}: trajectory changed under rule-table placement"
+    assert bool(placed) == bool(_EXPLICIT[name])
+    if name == "ssgd_tp_2x2":
+        # the invariance the two old pins shared: the 2x2 dp x tp
+        # trajectory is the 4x1 one, bit for bit
+        monkeypatch.undo()
+        assert run("ssgd_fused_gather") == by_table
 
 
 @pytest.fixture(scope="module")
@@ -456,12 +489,40 @@ def test_ssp_resume_renegotiation_uses_table_placement(tmp_path,
 # ------------------------------------ sparse-closure scale satellite
 
 
+def closure_dag_edges(V: int, deg: int, seed: int = 0):
+    """A forward-random-DAG edge list (dedup'd)."""
+    rng = np.random.default_rng(seed)
+    src = np.repeat(np.arange(V - 1), deg)
+    span = V - 1 - src
+    dst = src + 1 + (rng.random(len(src)) * span).astype(np.int64)
+    return np.unique(np.stack([src, dst], 1), axis=0)
+
+
+def closure_host_count(V: int, edges) -> int:
+    """Exact closure size by reverse-topological bitset DP on the host
+    — O(E·V/64) word ops: the host-side reference the sparse engine's
+    count is held to."""
+    adj: list[list[int]] = [[] for _ in range(V)]
+    for s, dd in edges:
+        adj[int(s)].append(int(dd))
+    words = (V + 63) // 64
+    reach = np.zeros((V, words), np.uint64)
+    total = 0
+    for i in range(V - 1, -1, -1):
+        for j in adj[i]:
+            reach[i] |= reach[j]
+            reach[i, j // 64] |= np.uint64(1 << (j % 64))
+        total += int(np.bitwise_count(reach[i]).sum()) \
+            if hasattr(np, "bitwise_count") else sum(
+                bin(int(w)).count("1") for w in reach[i])
+    return total
+
+
 def test_closure_auto_capacity_grows_and_matches_dense(mesh4):
-    import bench
     from tpu_distalg.models import transitive_closure as tc
 
     V = 120
-    edges = bench.closure_dag_edges(V, 5, seed=1)
+    edges = closure_dag_edges(V, 5, seed=1)
     dense = tc.run(edges, mesh4, n_vertices=V)
     # a deliberately tiny start capacity forces the doubling path
     sp = tc.run_sparse_auto(edges, mesh4, n_vertices=V,
@@ -469,7 +530,7 @@ def test_closure_auto_capacity_grows_and_matches_dense(mesh4):
     dm = np.asarray(dense.paths)[:V, :V]
     assert set(zip(*np.nonzero(dm))) == set(map(tuple, sp.paths))
     assert sp.n_paths == dense.n_paths
-    assert sp.n_paths == bench.closure_host_count(V, edges)
+    assert sp.n_paths == closure_host_count(V, edges)
 
 
 def test_closure_auto_grows_through_checkpoints(tmp_path, mesh4):
@@ -478,53 +539,36 @@ def test_closure_auto_grows_through_checkpoints(tmp_path, mesh4):
     prune them (run_segmented's signature check would otherwise
     reject the regrown shapes as a foreign workload and auto-sizing
     could never complete a checkpointed run)."""
-    import bench
     from tpu_distalg.models import transitive_closure as tc
 
     V = 120
-    edges = bench.closure_dag_edges(V, 5, seed=1)
+    edges = closure_dag_edges(V, 5, seed=1)
     sp = tc.run_sparse_auto(edges, mesh4, n_vertices=V,
                             start_capacity=len(edges) + 4,
                             checkpoint_dir=str(tmp_path / "ck"),
                             checkpoint_every=4)
-    assert sp.n_paths == bench.closure_host_count(V, edges)
+    assert sp.n_paths == closure_host_count(V, edges)
 
 
 def test_closure_auto_start_capacity_below_edges_grows(mesh4):
     """Review-caught: an explicit start_capacity below the edge count
     is a growth starting point, not run_sparse's hard 'capacity < edge
     count' error."""
-    import bench
     from tpu_distalg.models import transitive_closure as tc
 
     V = 120
-    edges = bench.closure_dag_edges(V, 5, seed=1)
+    edges = closure_dag_edges(V, 5, seed=1)
     sp = tc.run_sparse_auto(edges, mesh4, n_vertices=V,
                             start_capacity=8)
-    assert sp.n_paths == bench.closure_host_count(V, edges)
+    assert sp.n_paths == closure_host_count(V, edges)
 
 
 def test_closure_refusal_is_documented(mesh4):
-    import bench
     from tpu_distalg.models import transitive_closure as tc
 
-    edges = bench.closure_dag_edges(200, 5, seed=0)
+    edges = closure_dag_edges(200, 5, seed=0)
     with pytest.raises(ValueError) as ei:
         tc.run_sparse_auto(edges, mesh4, n_vertices=200,
                            budget_bytes=1 << 14)
     msg = str(ei.value)
     assert "refused" in msg and "budget" in msg and "dense" in msg
-
-
-def test_bench_new_metrics_registered():
-    import os
-
-    import bench
-    from tpu_distalg.analysis import telemetry_contract as tc
-
-    names = ("reshard_1gb_gbps", "ssgd_2d_mesh_step_speedup",
-             "closure_10m_paths_per_sec")
-    # membership AND a live emission site, via the one TDA102
-    # collector (this test's hand-rolled membership check is gone)
-    tc.assert_registered(
-        names, os.path.dirname(os.path.abspath(bench.__file__)))

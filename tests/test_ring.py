@@ -410,13 +410,6 @@ def test_ulysses_flash_gradients_match_dense(mesh8):
             np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.skip(reason="seed-failure[jax-version]: this jaxlib's CPU "
-                  "SPMD partitioner rejects the PartitionId op the "
-                  "interpret-mode flash backward lowers to under "
-                  "shard_map ('PartitionId instruction is not "
-                  "supported for SPMD partitioning'); the kernel path "
-                  "is covered on TPU (tests_tpu/) and by the "
-                  "single-device flash tests in test_pallas.py")
 def test_flash_ring_gradients_noncausal_multitile(mesh8):
     """Non-causal flash backward with multi-tile grids per ring step
     (s_local=256 over 128-blocks → 2×2 backward tiles) AND grouped
@@ -494,10 +487,6 @@ def test_flash_backward_block_halves_to_divisor():
                                    rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.skip(reason="seed-failure[jax-version]: PartitionId "
-                  "lowering rejected by this jaxlib's CPU SPMD "
-                  "partitioner (see "
-                  "test_flash_ring_gradients_noncausal_multitile)")
 def test_ring_attention_flash_matches_dense(mesh8):
     """The Pallas flash kernel path (interpret mode on CPU) is the same
     online-softmax algebra: matches the dense oracle and the XLA path
@@ -553,10 +542,6 @@ def test_ulysses_attention_flash_matches_dense(mesh8):
             rtol=2e-4, atol=2e-4, err_msg=f"causal={causal}")
 
 
-@pytest.mark.skip(reason="seed-failure[jax-version]: PartitionId "
-                  "lowering rejected by this jaxlib's CPU SPMD "
-                  "partitioner (see "
-                  "test_flash_ring_gradients_noncausal_multitile)")
 def test_ring_attention_flash_gqa_matches_dense(mesh8):
     """Grouped-query attention through the flash kernel: query head h
     reads KV head h // group straight from the block index map — the

@@ -70,6 +70,19 @@ def _fused(Q, V, off, nv, k=K, blk=128):
                                 k=k, block_items=blk, interpret=True)
 
 
+def _assert_same_topk(fv, fi, rv, ri):
+    """Two programs, one rounding order each (the kernel's blocked dot,
+    XLA's matmul): the indices are equal, the scores agree to a few
+    float32 ulps where an order differs. Read on jax 0.9.0's CPU: 4 and
+    5 ulps in the two tests that use this, 0 in the others; bound 16."""
+    assert np.array_equal(fi, ri)
+    fv, rv = np.asarray(fv), np.asarray(rv)
+    finite = np.isfinite(rv)
+    assert np.array_equal(np.isfinite(fv), finite)
+    assert np.array_equal(fv[~finite], rv[~finite])
+    np.testing.assert_array_max_ulp(fv[finite], rv[finite], maxulp=16)
+
+
 # ------------------------------------------- fused kernel vs lax.top_k
 
 
@@ -108,7 +121,7 @@ def test_fused_topk_offset_and_valid_mask():
     V2[150:] = 100.0  # poison the padded tail
     fv, fi = _fused(Q, V2, 1000, 150)
     rv, ri = pt.xla_matmul_topk(Q, V2, 1000, 150, k=K)
-    assert np.array_equal(fv, rv) and np.array_equal(fi, ri)
+    _assert_same_topk(fv, fi, rv, ri)
     assert int(np.min(fi)) >= 1000
     assert int(np.max(fi)) < 1000 + 150
 
@@ -117,7 +130,7 @@ def test_fused_topk_fewer_than_k_valid_tail():
     Q, V = _rand_qv(seed=3, n=64)
     fv, fi = _fused(Q, V[:4], 0, 4, k=K)
     rv, ri = pt.xla_matmul_topk(Q, V[:4], 0, 4, k=K)
-    assert np.array_equal(fv, rv) and np.array_equal(fi, ri)
+    _assert_same_topk(fv, fi, rv, ri)
     assert np.all(np.asarray(fv)[:, 4:] == -np.inf)
     assert np.all(np.asarray(fi)[:, 4:] == 2**31 - 1)
 

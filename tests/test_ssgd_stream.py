@@ -93,7 +93,7 @@ def test_stream_converges(mesh4, data):
     # platform-spread band: the original rig converges this schedule to
     # 0.9415, this container's BLAS to 0.9006 (chaotic 1500-step
     # trajectory); the reference-golden-band claim (0.9298) is asserted
-    # where the trajectory is the rig's own — bench.py convergence lines
+    # where the trajectory is the chip's own (tests_tpu/, chip_smoke.py)
     assert res.final_acc > 0.88, res.final_acc
 
 

@@ -552,34 +552,29 @@ FUSED_KW = dict(sampler="fused_gather", gather_block_rows=128,
                 eval_every=1)
 
 
-def test_ssp_fused_gather_s1_bsp_parity(mesh1):
+@pytest.mark.parametrize("sampler", ["fused_gather", "bernoulli"])
+def test_ssp_s1_bsp_parity(mesh1, sampler):
     """The s=1 parity pin: one shard, one-tick windows, decay 1 — the
     SSP window algebra degenerates to the BSP update. The ACCURACY
-    trajectory is bitwise the BSP fused trainer's; the weights agree
-    to a few ulps (measured <= 7 over 24 windows; bound 8 here) — exact bitwise
-    equality is structurally out of reach because SSP must MATERIALIZE
-    the shipped delta while XLA contracts BSP's subtract-of-product
-    into a single-rounding FMA (the bernoulli path exhibits the
-    identical bound, asserted alongside so the property cannot
-    silently rot into something looser)."""
+    trajectory is bitwise the BSP trainer's. The weights are not:
+    SSP must MATERIALIZE the shipped delta while XLA contracts BSP's
+    subtract-of-product into a single-rounding FMA, so a step may
+    round once more. That rounding is of the update, so it is an ulp
+    of the vector's scale, not of each coordinate: read on jax 0.9.0's
+    CPU over 24 windows, both samplers differ by 5.96e-8 = 1 ulp of
+    max|w| (0.83) on their worst coordinate, which is 24
+    (fused_gather) and 60 (bernoulli) ulps of that small coordinate
+    itself. Bound: 4 ulps of max|w|."""
     task = _fused_task()
-
-    def ulp_ok(a, b, ulps=8):
-        a, b = np.asarray(a), np.asarray(b)
-        return bool(np.all(
-            np.abs(a - b)
-            <= ulps * np.spacing(np.maximum(np.abs(a), np.abs(b)))))
-
-    for kw in (FUSED_KW, {}):          # fused_gather AND bernoulli
-        cfg = dict(n_iterations=24, eval_every=1, **{
-            k: v for k, v in kw.items() if k != "eval_every"})
-        bsp = ssgd.train(*task, mesh1,
-                         ssgd.SSGDConfig(**cfg, sync="bsp"))
-        s1 = ssgd.train(*task, mesh1,
-                        ssgd.SSGDConfig(**cfg, sync="ssp:1:1.0"))
-        assert np.asarray(bsp.accs).tobytes() == \
-            np.asarray(s1.accs).tobytes(), kw
-        assert ulp_ok(bsp.w, s1.w), kw
+    kw = FUSED_KW if sampler == "fused_gather" else dict(eval_every=1)
+    cfg = dict(n_iterations=24, **kw)
+    bsp = ssgd.train(*task, mesh1, ssgd.SSGDConfig(**cfg, sync="bsp"))
+    s1 = ssgd.train(*task, mesh1,
+                    ssgd.SSGDConfig(**cfg, sync="ssp:1:1.0"))
+    assert np.asarray(bsp.accs).tobytes() == \
+        np.asarray(s1.accs).tobytes()
+    a, b = np.asarray(bsp.w), np.asarray(s1.w)
+    assert np.abs(a - b).max() <= 4 * np.spacing(np.abs(a).max())
 
 
 def test_ssp_fused_gather_replays_bitwise_under_straggle_plan(mesh4):

@@ -1,6 +1,6 @@
 """Platform-aware autotuner (tpu_distalg/tune/): rig profiles, the
-cost-model resolver, the `--tune` CLI plumbing, the TDA120 geometry
-lint, and the bench-tier registration of the tuned A/B metrics.
+cost-model resolver, the `--tune` CLI plumbing and the TDA120
+geometry lint.
 
 The profile tier is tested with an INJECTABLE clock (the measurement
 pass is seeded and sized by constants, so a pinned clock makes two
@@ -13,7 +13,6 @@ import copy
 import json
 import os
 
-import numpy as np
 import pytest
 
 from tpu_distalg import tune as ttune
@@ -349,116 +348,6 @@ def test_tuned_cluster_run_stays_bitwise_deterministic(tmp_path):
     b = clus.run_local_cluster(copy.deepcopy(cfg), spawn="thread",
                                timeout=60.0)
     assert a["center"]["w"].tobytes() == b["center"]["w"].tobytes()
-
-
-# ---------------------------------------------------------------------
-# bench tier: metric registration, honesty paths, retry budget
-
-
-def test_tuned_metrics_registered_everywhere():
-    import bench
-    from tpu_distalg.analysis import telemetry_contract as tc
-
-    names = ("tuned_step_speedup", "cluster_tuned_push_pull_speedup")
-    root = os.path.dirname(os.path.abspath(bench.__file__))
-    tc.assert_registered(names, root)
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "check_readme_claims",
-        os.path.join(root, "scripts", "check_readme_claims.py"))
-    claims = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(claims)
-    claim_metrics = {m for m, _, _ in claims.CLAIMS}
-    assert set(names) <= claim_metrics
-    assert set(names) <= claims.FLOOR_CLAIMS
-    with open(os.path.join(root, "README.md")) as f:
-        extracted = claims.extract_claims(f.read())
-    assert extracted.get("tuned_step_speedup") == 1.0
-
-
-def test_tuned_step_identical_geometry_emits_honest_ratio(mesh4):
-    """On a rig whose profile has no device collective the resolver
-    keeps dense == the default, so the A/B is one compiled program:
-    the phase emits exactly 1.0 flagged identical_geometry instead of
-    two noise samples — and records the measured step gauge."""
-    import bench
-    from tpu_distalg.telemetry import events as tevents
-
-    lines = []
-    bench.run_tuned_step_speedup(
-        mesh4, lines.append, profile=_crafted_profile(),
-        d=1 << 12, steps=3, repeats=1)
-    (line,) = lines
-    assert line["metric"] == "tuned_step_speedup"
-    assert line["value"] == 1.0
-    assert line["identical_geometry"] is True
-    assert line["tune_profile"] == _crafted_profile()["profile_id"]
-    assert line["comm_tuned"] == "dense"
-    assert tevents is not None  # gauge path exercised without a sink
-
-
-def test_cluster_tuned_push_pull_speedup_measures(tmp_path):
-    import bench
-
-    lines = []
-    bench.run_cluster_tuned_push_pull_speedup(
-        lines.append, profile=_crafted_profile(), fast=True)
-    (line,) = lines
-    assert line["metric"] == "cluster_tuned_push_pull_speedup"
-    assert line["value"] > 0
-    assert line["tune_profile"] == _crafted_profile()["profile_id"]
-    # the crafted profile resolves ps_shards=1 (tiny model) — a real
-    # A/B, so both arms' numbers are recorded
-    if not line["identical_geometry"]:
-        assert line["tuned_p50_ms"] > 0 and line["default_p50_ms"] > 0
-
-
-def test_init_retry_budget_uses_measured_init_time():
-    """Satellite 4: a measured backend-init time re-prices the retry
-    budget — more attempts, each under a 3x-measured deadline — while
-    an unmeasured rig keeps the worst-case cap behavior bit for
-    bit."""
-    import bench
-
-    assert bench._init_attempt_timeout(None) \
-        == bench.INIT_TIMEOUT_SECONDS
-    assert bench._init_attempt_timeout(8.0) == 24.0
-    assert bench._init_attempt_timeout(1.0) == 10.0          # floor
-    assert bench._init_attempt_timeout(1e6) \
-        == bench.INIT_TIMEOUT_SECONDS                        # cap
-    base = bench._init_retry_budget(10800)
-    measured = bench._init_retry_budget(10800, init_seconds=8.0)
-    assert measured > base
-    assert measured <= bench.INIT_RETRY_ATTEMPTS - 1
-    # half the window stays reserved for the bench proper
-    assert bench._init_retry_budget(0) == 0
-
-
-def test_artifact_loader_skips_mismatched_rig(tmp_path):
-    """Satellite 3: a round measured on another rig cannot anchor
-    this rig's claims; untagged (pre-rig) artifacts still load."""
-    import socket
-
-    import bench_artifacts
-
-    (tmp_path / "BENCH_r09.json").write_text(json.dumps(
-        {"parsed": {"rig": "some-other-rig",
-                    "all_metrics": {"m": 9.0}}}))
-    (tmp_path / "BENCH_r08.json").write_text(json.dumps(
-        {"parsed": {"rig": socket.gethostname(),
-                    "all_metrics": {"m": 8.0}}}))
-    ref, metrics = bench_artifacts.load_newest_metrics(str(tmp_path))
-    assert ref == "BENCH_r08.json" and metrics == {"m": 8.0}
-    # an explicit path loads the foreign artifact verbatim
-    ref, metrics = bench_artifacts.load_newest_metrics(
-        str(tmp_path), path=str(tmp_path / "BENCH_r09.json"))
-    assert ref == "BENCH_r09.json" and metrics == {"m": 9.0}
-    # untagged artifacts (recorded before the rig tag) still serve
-    (tmp_path / "BENCH_r10.json").write_text(json.dumps(
-        {"parsed": {"all_metrics": {"m": 10.0}}}))
-    ref, _ = bench_artifacts.load_newest_metrics(str(tmp_path))
-    assert ref == "BENCH_r10.json"
 
 
 # ---------------------------------------------------------------------

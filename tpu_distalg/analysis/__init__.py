@@ -63,9 +63,8 @@ TDA101      subprocess config handoff: every config field the CLI
             feeds from a flag is forwarded by the argv builder that
             re-spawns the role (the ``--train-json`` class)
 TDA102      telemetry contract: every emitted counter/gauge is
-            rendered or waived in ``telemetry/report.py``, and bench
-            metric lines stay bijective with ``ALL_METRIC_NAMES``
-            (the test-only AST tripwire, promoted into the engine)
+            rendered or waived in ``telemetry/report.py``, and every
+            waiver there still matches an emission
 TDA103      cross-module lock discipline: an attribute written from
             thread entries in different modules needs ONE common
             lock, not one lock per module (the gap TDA020's

@@ -23,17 +23,17 @@ from tpu_distalg.telemetry import events as tevents
 
 #: the repo's default lint surface (existing entries only, so the
 #: command works from any subdirectory too)
-DEFAULT_PATHS = ("tpu_distalg", "tests", "scripts", "bench.py")
+DEFAULT_PATHS = ("tpu_distalg", "tests", "scripts")
 
-#: the project-graph summary cache home (shared with bench's caches);
-#: silently skipped when unwritable
+#: the project-graph summary cache home; silently skipped when
+#: unwritable
 CACHE_DIR = ".bench_cache"
 
 
 def add_parser_args(p):
     p.add_argument("paths", nargs="*", metavar="PATH",
                    help="files/directories to lint (default: "
-                        "tpu_distalg/ tests/ bench.py, those that "
+                        "tpu_distalg/ tests/ scripts/, those that "
                         "exist)")
     p.add_argument("--format", default="text",
                    choices=["text", "json"],
@@ -85,8 +85,8 @@ def add_protocol_args(p):
 
 def run_protocol(args) -> int:
     """``tda protocol`` — render the extracted wire contract, or
-    ``--check`` it against the committed ``docs/PROTOCOL.md`` (same
-    docs-can-never-drift shape as ``check_readme_claims.py``)."""
+    ``--check`` it against the committed ``docs/PROTOCOL.md`` (the
+    document can never drift from the source)."""
     from tpu_distalg.analysis import protocol as protomod
 
     paths = list(args.paths) or [p for p in DEFAULT_PATHS

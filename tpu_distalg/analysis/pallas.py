@@ -1,9 +1,8 @@
 """Pallas hygiene rules — tiling and VMEM budget (TDA040, TDA041).
 
 The repo's kernels carry these constraints as hand-written guards and
-hard-won docstrings (``ops/pallas_kmeans.py`` rejects over-budget shift
-tables at plan time; ``pallas_pagerank`` documents its ~11M-vertex VMEM
-ceiling). These rules move the statically-decidable half of that to
+hard-won docstrings (``pallas_pagerank`` documents its ~11M-vertex VMEM
+ceiling and refuses an over-budget plan before sorting). These rules move the statically-decidable half of that to
 lint time: f32 blocks tile in (8, 128) — a lane dimension that is not a
 multiple of 128 pads silently (wasted VMEM + MXU occupancy) or fails in
 Mosaic — and the resident block set of one ``pallas_call`` must fit the

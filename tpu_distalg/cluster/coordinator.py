@@ -162,7 +162,7 @@ def _tupled(x):
 class TrainTask:
     """The training job the coordinator OWNS and hands every worker at
     join (a worker needs only the coordinator's address): the synthetic
-    two-class task of bench.comm_comparison_task's shape, sliced into
+    two-class task (``datasets.synthetic_two_class``), sliced into
     per-slot contiguous row blocks."""
 
     algo: str = "ssgd"            # 'ssgd' | 'local_sgd'

@@ -25,7 +25,7 @@ instead of per-trainer:
 
 Consumers: ``models/ssgd_stream`` (ported onto this package),
 ``models/kmeans.fit_minibatch`` and ``models/als.fit_streamed`` (the
->HBM paths this subsystem opened), ``bench.py``, ``cli.py``.
+>HBM paths this subsystem opened), ``cli.py``.
 Every pipeline stage emits telemetry (``data:gather`` / ``data:h2d`` /
 ``data:cache_build`` spans, ``data.*`` counters) so ``tda report``
 shows where a streamed run spends its time.

@@ -245,8 +245,8 @@ class ShardedDataset:
 
     def stage(self, ids_step: np.ndarray):
         """One step's staged batch, any backend: serial gather+put for
-        host storage (the shape bench.py's H2D-roofline probe measures
-        on purpose — no prefetch), a device-side block take for
+        host storage (no prefetch, on purpose: the shape an H2D
+        roofline is read from), a device-side block take for
         resident storage. Bytes are identical across backends."""
         if self.backend == "resident":
             import jax.numpy as jnp

@@ -22,8 +22,8 @@ subsystem landed. This package closes that gap (ROADMAP open item 3):
               a dense O(V) psum. Only O(V) state lives on device.
 
 Consumers: ``cli.py pagerank --data-backend streamed`` (and the
-warn-and-degrade path when the resident VMEM guard trips), bench.py's
-``pagerank_100m_*`` lines, ``tda chaos --workload pagerank_stream``.
+warn-and-degrade path when the resident VMEM guard trips),
+``tda chaos --workload pagerank_stream``.
 """
 
 from tpu_distalg.graphs.engine import (

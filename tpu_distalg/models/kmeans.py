@@ -203,86 +203,6 @@ def make_fit_fn(mesh: Mesh, config: KMeansConfig,
     return jax.jit(fit)
 
 
-def pack_device(mesh: Mesh, points, mask, *, dim: int, k: int,
-                block_rows: int = 4096):
-    """Device-side re-layout of sharded (n, dim) points into the fused
-    kernel's packed rows (``ops.pallas_kmeans.pack_points`` semantics,
-    but each shard packs its own slice — no host materialization, so it
-    composes with ``build_sharded``'s O(1)-host scale path). Appended
-    padding rows carry mask 0 and are inert."""
-    from tpu_distalg.ops import pallas_kmeans as pk
-
-    dpad, pp, _ = pk.packed_geometry(dim, k)
-
-    def body(p, m):
-        n_l = p.shape[0]
-        pad = (-n_l) % pp  # ragged tail rows pad with mask 0, like the
-        #                    host-side pack_points
-        p = jnp.pad(p, ((0, pad), (0, dpad - dim)))
-        m = jnp.pad(m, ((0, pad),))
-        n2 = (n_l + pad) // pp
-        n2p = n2 + (-n2) % block_rows
-        X2 = p.reshape(n2, pp * dpad)
-        return (jnp.pad(X2, ((0, n2p - n2), (0, 0))),
-                jnp.pad(m.reshape(n2, pp), ((0, n2p - n2), (0, 0))))
-
-    f = data_parallel(
-        body, mesh,
-        in_specs=(P("data", None), P("data")),
-        out_specs=(P("data", None), P("data", None)),
-    )
-    return jax.jit(f)(points, mask)
-
-
-def make_fit_fn_fused(mesh: Mesh, config: KMeansConfig, dim: int, *,
-                      block_rows: int = 4096):
-    """Lloyd iterations through the single-pass Pallas kernel
-    (``ops.pallas_kmeans.fused_cluster_stats``): one HBM pass per
-    iteration over points packed 4 to a 128-lane row. Nothing in the
-    program takes it: it lost to :func:`make_fit_fn`'s row path where
-    both fit (the ``ops/pallas_kmeans`` module docstring has both chip
-    readings), neither holds a chip-filling table, and the scale path
-    runs :func:`make_fit_fn` on the lanes layout (``ops/pallas_lloyd``).
-    Kept as a tested alternative; ROADMAP D4 leaves its removal to a
-    ``simplicity`` PR.
-    Call with :func:`pack_device` outputs. Centers and
-    n_iterations_run match :func:`make_fit_fn`; ASSIGNMENTS are in
-    PACKED order with per-shard padding rows interleaved — filter by
-    the flattened packed mask (``mask2.reshape(-1) > 0``) to recover
-    the shard-contiguous input-row order."""
-    from tpu_distalg.ops import pallas_kmeans as pk
-
-    on_tpu = mesh_on_tpu(mesh)
-    dpad, pp, _ = pk.packed_geometry(dim, config.k)
-
-    def _local_stats2(X2, m2, centers):
-        sums, counts = pk.fused_cluster_stats(
-            X2, m2, centers, dim=dim, k=config.k,
-            block_rows=block_rows, interpret=not on_tpu)
-        return tree_allreduce_sum((sums, counts))
-
-    stats_fn = data_parallel(
-        _local_stats2, mesh,
-        in_specs=(P("data", None), P("data", None), P()),
-        out_specs=(P(), P()),
-    )
-
-    def fit(X2, m2, centers0):
-        def one_iter(centers):
-            sums, counts = stats_fn(X2, m2, centers)
-            return kops.update_centers(sums, counts, centers), counts
-
-        centers, n_run = _lloyd_loop(
-            one_iter, config, centers0, _counts0(config.k, None))
-        # final assignment from the packed view (free reshape) under the
-        # final centers — reference display parity (k-means.py:57-58,76)
-        pts = X2.reshape(-1, dpad)[:, :dim]
-        assign = kops.assign_clusters(pts, centers)
-        return centers, assign, n_run
-
-    return jax.jit(fit)
-
-
 def _rows_of(make_rows, ids, data_seed) -> jax.Array:
     """The rows ``ids``, regenerated; ``data_seed`` as
     :func:`fit_scaled` takes it."""
@@ -539,7 +459,7 @@ def fit_minibatch(dataset, config: KMeansConfig, *, n_steps: int,
 def init_centers_scaled(make_rows, n_rows: int, config: KMeansConfig,
                         data_seed: int | None = None) -> jax.Array:
     """The scale path's ``config.init`` dispatch — one place, shared by
-    :func:`fit_scaled` and bench.py (which times the fit separately)."""
+    :func:`fit_scaled` and callers that time the fit separately."""
     if config.init == "farthest":
         return init_centers_farthest(
             make_rows, n_rows, config.k, config.seed,
@@ -561,8 +481,8 @@ def build_scaled(mesh: Mesh, n_rows: int, make_rows, k: int, *,
     layout, 4 * dim bytes a point and no mask; else into plain rows,
     chunk by chunk, with their mask, for ``ops/kmeans.py``. On one v5e
     at 100M x 20, k = 10 the lanes path holds 8.0 GB and takes 16.9 ms
-    an iteration, the row path 10.0 GB and 36.9 ms, and
-    ``make_fit_fn_fused``'s packing does not fit (PERF.md §6, PR 26)."""
+    an iteration, the row path 10.0 GB and 36.9 ms (PERF.md §6,
+    PR 26)."""
     from tpu_distalg.ops import pallas_lloyd as lloyd
     from tpu_distalg.parallel import build_sharded
 

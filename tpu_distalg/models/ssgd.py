@@ -81,7 +81,7 @@ class SSGDConfig:
     # Precision note: with x_dtype='bfloat16' the fused kernels cast the
     # residual AND the selector-replicated weights to bf16 (the XLA bf16
     # path keeps both f32) — a small extra deviation; convergence to the
-    # reference band is verified on-TPU (tests_tpu/, bench convergence_*)
+    # reference band is verified on-TPU (tests_tpu/, chip_smoke.py)
     sampler: str = "bernoulli"
     fused_pack: int = 16        # rows packed per sublane row ('fused*')
     fused_block_rows: int = 8192
@@ -940,11 +940,11 @@ def _make_train_fn_tp(mesh: Mesh, config: SSGDConfig, n_padded: int):
 def fused_gather_geometry(config: SSGDConfig, meta: dict, n_shards: int):
     """Per-shard block-sampling geometry of the 'fused_gather' sampler:
     (blocks per shard, blocks sampled per shard per step). Single source
-    of truth — bench.py derives its bytes-per-step claim from this."""
+    of truth for the bytes a step moves."""
     if config.gather_block_rows % meta["pack"]:
         # the kernel raises the same constraint at trace time; catching it
-        # here keeps the derived n_blocks/n_sampled (and bench.py's
-        # bytes-per-step claim) from silently using a truncated block size
+        # here keeps the derived n_blocks/n_sampled from silently using a
+        # truncated block size
         raise ValueError(
             f"gather_block_rows={config.gather_block_rows} must be a "
             f"multiple of pack={meta['pack']}"
@@ -1627,8 +1627,8 @@ def _train_comm(mesh, config, d, data_args, w0, *, make_fn,
 
 
 def prepare_fused(X_train, y_train, mesh: Mesh, config: SSGDConfig):
-    """One-time setup shared by :func:`_train_fused` and ``bench.py``:
-    pack (X, y, validity) into the fused kernel's layout, shard it over
+    """One-time setup of :func:`_train_fused` (and of callers that time
+    the steps themselves): pack (X, y, validity) into the fused kernel's layout, shard it over
     the data axis, build the augmented initial weights and the jitted
     scan. Returns ``(fn, X2, w0, meta)``; call as
     ``fn(X2, dummy, dummy, X_test_padded, y_test, w0)``.
@@ -1672,8 +1672,8 @@ def prepare_fused_synthetic(
 ):
     """Scale-out variant of :func:`prepare_fused`: the packed design
     matrix is synthesized ON DEVICE, shard by shard — host memory use is
-    O(1) in ``n_rows``, which is what the 1B-row north star
-    (BASELINE.json) requires. The reference materializes its whole
+    O(1) in ``n_rows``, which is what a 1B-row table requires. The
+    reference materializes its whole
     matrix on the driver (``/root/reference/optimization/ssgd.py:86``);
     ``parallelize``/``pack_augmented`` mirror that and top out at host
     RAM. Rows here are generated from a counter-based per-row PRNG

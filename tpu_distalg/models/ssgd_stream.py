@@ -206,8 +206,8 @@ class StreamTrainer:
 
     def _gather(self, ids_step: np.ndarray) -> np.ndarray:
         """Host-side gather of one step's sampled blocks — now
-        ``ShardedDataset.gather`` (kept for the tests/bench that probe
-        the stages individually)."""
+        ``ShardedDataset.gather`` (kept for the tests that probe the
+        stages individually)."""
         return self.dataset.gather(ids_step)
 
     def _put(self, gathered: np.ndarray):
@@ -215,8 +215,8 @@ class StreamTrainer:
         return self.dataset.put(gathered)
 
     def _stage(self, ids_step: np.ndarray):
-        """Serial gather+put of one step's batch — the shape bench.py's
-        H2D-roofline probe measures on purpose (no prefetch)."""
+        """Serial gather+put of one step's batch (no prefetch, on
+        purpose: the shape an H2D roofline is read from)."""
         return self.dataset.stage(ids_step)
 
     def run(self, w, t0: int, n_steps: int, acc0=0.0):

@@ -21,9 +21,8 @@ n — same program, same convergence, host RAM O(1).
 Cost model: a regenerated row costs threefry bits + the normal/logistic
 transforms instead of an HBM DMA — compute-bound where 'fused_gather'
 is bandwidth-bound, so steps/s is lower per sampled row, but unbounded
-in n_rows. The flagship resident-HBM numbers remain the headline for
-datasets that fit; this is the >HBM story (bench:
-``ssgd_lr_virtual_*``).
+in n_rows. The resident-HBM path remains the one for datasets that
+fit; this is the >HBM story (not measured: PERF.md §7).
 """
 
 from __future__ import annotations

@@ -33,9 +33,8 @@ def cluster_stats(
 
     For small k the keyed reduction is a masked one-hot matmul on the
     MXU: ``sums = (onehot ⊙ mask)ᵀ · points``. XLA lowers
-    ``segment_sum`` to a scatter-add, which serializes on TPU —
-    measured 172 ms/iter at 10M×16 points vs ~5 ms for the matmul form
-    (bench.py k-means). Above the one-lane-tile cutoff the (n, k)
+    ``segment_sum`` to a scatter-add, which serializes on TPU (the row
+    path's chip readings: PERF.md §6, PR 26). Above the one-lane-tile cutoff the (n, k)
     one-hot stops being cheap and the scatter path takes over."""
     if k <= 128:
         om = (assign[:, None] == jnp.arange(k)[None, :]).astype(

@@ -57,7 +57,8 @@ _NEG = -1e30
 #
 # The 32k fwd+bwd gap, DECOMPOSED (VERDICT round-5 advice #7 — the
 # measured negative result, budget accounted, megakernel-round style).
-# Measured rates (BENCH_r04 artifact / README claims, 8 heads × d=128,
+# Measured rates (round 4, one v5e, before this round's ledger; no
+# attention cell re-measures them yet: PERF.md §7; 8 heads × d=128,
 # causal, per chip): 32k forward 105 TF, 128k forward 121 TF,
 # backward-only 71.9 TF at this tile (the 2048² sweep winner above),
 # 32k fwd+bwd 68.6 TF vs 128k fwd+bwd ~74.7 TF. With the fwd+bwd

@@ -39,12 +39,11 @@ pallas_guide.md), discovered the hard way across three kernel generations:
 Measured on one v5e chip before PR 1 (jax 0.4.37; not re-measured on
 current code — see PERF.md), 1M rows × 128 packed columns, fraction 0.1
 (steps/s, timed over 1500-step scan segments with host-fetch so
-dispatch overhead is amortized — see bench.py): XLA two-pass f32 503 ·
+dispatch overhead is amortized): XLA two-pass f32 503 ·
 XLA two-pass bf16 668 · XLA 'fixed' row-gather 317-349 · v1 92 · v3
 1398 · **v4 ≈ 11000-13100** (marginal per-step cost 41 µs vs v3's
-360 µs — the traffic argument, realised). Numbers on a shared chip
-vary ±20%; ``bench.py`` reports the current measurement, plus the
-bytes-per-step and HBM-peak-fraction the rate implies.
+360 µs — the traffic argument, realised). The gathered kernels'
+current readings, cell by cell, are in PERF.md §5.
 """
 
 from __future__ import annotations

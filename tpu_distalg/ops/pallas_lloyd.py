@@ -1,14 +1,11 @@
 """One Lloyd pass over points held feature-major, in row blocks.
 
-Why a third k-means path. At a chip-filling shape (HiBench ``huge``:
+Why a second k-means path. At a chip-filling shape (HiBench ``huge``:
 100M points x 20 dimensions, k = 10, float32) the row layout of
 ``ops/kmeans.py`` holds 96 B a point and a 4 B mask (XLA lays
 ``f32[n, 20]`` out column-major in ``(8, 128)`` tiles, 24 sublanes for
 20 columns: 10.0 GB with the mask, 11.0 GB with the pass's
-intermediates), and ``pallas_kmeans``'s 4-points-to-a-row packing 128 B
-a point, a mask padded to as much, and a final assignment that does
-not fit a chip at all. PERF.md §6 (PR 26) has what each does on the
-chip.
+intermediates). PERF.md §6 (PR 26) has what each does on the chip.
 
 Layout (``LanesGeometry``): ``f32[n_blocks, dim, R, 128]``. Point ``p``
 of a shard sits in block ``p // (R * 128)``, sublane row ``(p // 128) %

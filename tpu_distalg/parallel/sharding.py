@@ -117,8 +117,8 @@ def build_sharded(
     of :func:`parallelize`.
 
     ``parallelize`` materializes the full array on the host first
-    (``np.pad`` + ``device_put``) — at the 1B-row north-star scale
-    (BASELINE.json) that is ~100s of GB of host RAM for data that is
+    (``np.pad`` + ``device_put``) — at a 1B-row scale that is ~100s
+    of GB of host RAM for data that is
     synthesized anyway (the reference builds its matrix host-side too,
     ``/root/reference/optimization/ssgd.py:86``, which is exactly the
     pattern that cannot scale). Here each shard's rows are generated

@@ -24,7 +24,7 @@ Pieces:
     ``comms.ring_allgather`` (``8·B·k·(S−1)`` wire bytes per batch);
   * :mod:`~tpu_distalg.serve.server` — :class:`Server`: one batcher
     per served model, aggregate latency stats (p50/p99/QPS), the
-    closed-loop load generator bench.py and ``tda serve`` drive.
+    closed-loop load generator ``tda serve`` drives.
 
 Padding is provably inert: a batch is always padded to exactly
 ``max_batch`` rows, so batched and unbatched requests run the SAME

@@ -1,6 +1,6 @@
 """The serving front end: one :class:`MicroBatcher` per served model,
 aggregate latency/throughput stats, and the closed-loop load generator
-``tda serve`` and bench.py drive.
+``tda serve`` drives.
 
 A :class:`Server` is in-process by design — the request surface is
 ``submit(model, payload) -> Reply`` — because the interesting serving

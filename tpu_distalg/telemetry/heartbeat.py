@@ -6,13 +6,13 @@ snapshot. When ``stall_after`` is set and no :func:`events.mark` lands
 within that deadline, ONE ``stall`` event fires per frozen mark (naming
 the stuck phase — "hung in backend_init for 1560s" instead of round 5's
 silent 26-minute blackout) and the optional ``on_stall`` callback runs
-— bench.py uses it to print its final all-metrics summary and exit
-instead of hanging the harness until the driver's rc=124.
+— a harness can use it to print what it has and exit instead of
+hanging until its driver's rc=124.
 
 The thread never blocks the main loop (it only reads the in-memory mark
 tuple and writes through the sink's own lock), runs fine with telemetry
-disabled (events become no-ops; ``on_stall`` still fires — that is
-bench's watchdog mode), and ``beat()`` is callable directly with an
+disabled (events become no-ops; ``on_stall`` still fires — the
+watchdog mode), and ``beat()`` is callable directly with an
 injected clock so tests exercise the stall logic without sleeping.
 """
 
@@ -55,8 +55,8 @@ class Heartbeat(threading.Thread):
 
     def safe_beat(self) -> None:
         """beat(), but a failing sink (disk full, unlinked dir) must
-        not KILL the thread: stall detection — and bench's watchdog
-        riding ``on_stall`` — stays armed, and the next beat retries.
+        not KILL the thread: stall detection — and a watchdog riding
+        ``on_stall`` — stays armed, and the next beat retries.
         (A dead heartbeat would silently reopen the r5 blind-hang mode
         this subsystem exists to close.)"""
         try:
@@ -94,7 +94,7 @@ def start_heartbeat(interval: float = DEFAULT_INTERVAL_SECONDS,
                     stall_after: float | None = DEFAULT_STALL_SECONDS,
                     on_stall=None) -> Heartbeat | None:
     """Start a heartbeat if it would do anything: telemetry enabled, or
-    an ``on_stall`` action given (bench's watchdog runs even with
+    an ``on_stall`` action given (a watchdog runs even with
     telemetry off). Returns the thread, or ``None`` if skipped."""
     if not events.enabled() and on_stall is None:
         return None

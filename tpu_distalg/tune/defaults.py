@@ -52,8 +52,8 @@ BLOCK_EDGES = 1 << 16
 #: (``--gather-block-rows``)
 GATHER_BLOCK_ROWS = 1024
 
-#: the data-axis size the README's canonical reduction claims are
-#: pinned to (bench.py COMM_CANONICAL_SHARDS)
+#: the data-axis size the comm schedules' wire-reduction factors are
+#: quoted at (they depend on the shard count)
 CANONICAL_DATA_SHARDS = 4
 
 #: per-collective dispatch overhead assumed for device schedules when

@@ -59,7 +59,7 @@ SCHEMA_VERSION = 1
 PROFILE_PREFIX = "RIGPROFILE_"
 
 #: env override for where profiles live (default: ``.tda_profiles``
-#: under the working directory, next to the BENCH_r*.json artifacts)
+#: under the working directory)
 PROFILE_DIR_ENV = "TDA_PROFILE_DIR"
 
 #: loopback bandwidth payload per frame (f32 elems) and frame count

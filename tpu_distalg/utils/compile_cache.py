@@ -1,8 +1,7 @@
 """Where the persistent XLA/Mosaic compilation cache lives.
 
 One rule, one function, called by every entry point (``cli.main``,
-``bench.main``, ``chip_smoke.py``, ``tests_tpu``) before the first
-compile:
+``chip_smoke.py``, ``tests_tpu``) before the first compile:
 
   * ``JAX_COMPILATION_CACHE_DIR`` set — jax reads it itself; this
     module touches nothing, so whoever runs the program places the

@@ -2,8 +2,8 @@
 
 The reference's optimizers all train on sklearn breast-cancer with a fixed
 70/30 split (``/root/reference/optimization/ssgd.py:71-76``); benchmarks
-need synthetic data at scale (BASELINE.json: 1B-row two-class LR data,
-1M-node Erdős–Rényi graphs). Bias handling follows the reference: a ones
+need synthetic data at scale (two-class LR rows by the billion, 1M-node
+Erdős–Rényi graphs). Bias handling follows the reference: a ones
 column is appended to X (``ssgd.py:83-84``), so the model has D+1 weights.
 """
 
@@ -89,7 +89,7 @@ def synthetic_two_class_rows(n_features: int, seed: int = 0,
 def gaussian_mixture(
     n_rows: int, k: int = 4, dim: int = 2, seed: int = 0, spread: float = 8.0
 ) -> np.ndarray:
-    """Gaussian-mixture points for k-means benchmarks (BASELINE.json config)."""
+    """Gaussian-mixture points for k-means benchmarks."""
     rng = np.random.default_rng(seed)
     centers = rng.normal(size=(k, dim)) * spread
     assign = rng.integers(0, k, size=n_rows)
@@ -139,7 +139,7 @@ def erdos_renyi_edges(
     n_vertices: int, avg_degree: float = 8.0, seed: int = 0
 ) -> np.ndarray:
     """Uniform-random directed edge list (src, dst), shape (E, 2), no
-    self-loops — the 1M-node PageRank benchmark graph (BASELINE.json)."""
+    self-loops — the 1M-node PageRank benchmark graph."""
     rng = np.random.default_rng(seed)
     n_edges = int(n_vertices * avg_degree)
     src = rng.integers(0, n_vertices, size=n_edges, dtype=np.int64)
