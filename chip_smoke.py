@@ -640,7 +640,8 @@ def stage_kmeans_kernel(s: Smoke):
     centers = (rng.normal(size=(k, dim)) * 3).astype(np.float32)
     p, c = jnp.asarray(pts), jnp.asarray(centers)
     x4 = jax.vmap(geom.pack)(p.reshape(n_blocks, geom.block_points, dim))
-    sums, counts = lloyd.fold_stats(*lloyd.lloyd_pass(x4, c, n_valid))
+    partial, = lloyd.lloyd_pass(x4, c, n_valid)
+    sums, counts = lloyd.fold_stats(partial, k, dim)
     with jax.default_matmul_precision("highest"):
         assign = kops.assign_clusters(p, c)
     mask = (jnp.arange(n) < n_valid).astype(jnp.float32)
@@ -653,7 +654,7 @@ def stage_kmeans_kernel(s: Smoke):
         np.asarray(sums), np.asarray(s_ref), rtol=1e-5,
         atol=1e-4 + moved * float(np.abs(pts).max()))
     return (f"counts differ by {int(moved)} of {n} points (<= 0.1%), "
-            f"sums 1e-5")
+            f"sums 1e-5, cluster sums on the {lloyd.sums_form(k, dim)}")
 
 
 def _comm_stage(s: Smoke, comm: str):

@@ -6,7 +6,8 @@ against a plain float32 reference, at the HiBench shape's widths
 Blocks of 16 sublane rows (2048 points) instead of the 512 the program
 picks at dim 20, so that 4 096 points are two whole blocks, 20 000 end
 inside the tenth and 20 011 end on an odd lane. On the CPU the kernel
-runs interpreted (a block a step, loops over centres rolled); the chip
+runs interpreted (the loops over centres rolled; the matmul of the
+per-cluster sums is the chip's own bfloat16 ``dot_general``); the chip
 compiles it (``tests_tpu``, ``benchmarks/tools/compile_check_kmeans.py``).
 """
 
@@ -227,6 +228,36 @@ def test_fit_scaled_takes_the_lanes_path_and_spans_it(mesh8, tmp_path):
     segs = [e for e in ends if e["name"] == "train:segment"]
     assert [(e["tag"], e["t0"], e["steps"]) for e in segs] == [
         ("kmeans_fixed", 0, 3), ("kmeans_fixed", 3, 3)]
+    # where the kernel adds up the per-cluster sums at this geometry
+    assert lloyd.sums_form(K, DIM) == "mxu"
+    assert [e["sums_form"] for e in prep + segs] == ["mxu"] * 3
+    assert "cluster sums: mxu" in report.render(
+        report.summarize(report.load_events(tel))).splitlines()
+
+
+@pytest.mark.parametrize("where,row,value", [
+    ("padding", 1005, np.nan), ("valid", 7, np.inf), ("nowhere", -1, 0.0)])
+def test_build_scaled_refuses_a_table_that_is_not_finite(
+        mesh1, where, row, value):
+    """The matmul of the sums multiplies every point, padding included,
+    by every cluster's 0 or 1, and 0 x NaN is NaN: a NaN among the
+    padding rows or an infinity in one valid point would reach every
+    cluster's sum of that feature. The table is checked once, where it
+    is drawn."""
+    def make_rows(ids, seed=None):
+        rows = MAKE_ROWS(ids, seed)
+        return rows.at[:, 3].set(jnp.where(ids == row, value, rows[:, 3]))
+
+    def build():
+        return kmeans.build_scaled(mesh1, 1000, make_rows, K, data_seed=1)
+
+    if where == "nowhere":
+        x4, n_valid, lanes = build()
+        assert int(n_valid) == 1000 and lanes.block_points == 65536
+        assert x4.shape == (1, DIM, 512, 128)
+    else:
+        with pytest.raises(ValueError, match="has to be finite"):
+            build()
 
 
 @pytest.mark.parametrize("lanes", [None, LANES16], ids=["rows", "lanes"])
@@ -270,3 +301,152 @@ def test_chunked_rows_equal_the_one_shot_draw(mesh4):
     assert packed.mask is None and packed.n_padded == 8192
     with pytest.raises(ValueError, match="chunk_rows"):
         build_sharded(mesh4, n, MAKE_ROWS, pack=LANES16.pack)
+
+
+# ---- the kernel alone: where the per-cluster sums are added up ---------
+
+FORMS = {(10, 20): "mxu", (3, 2): "vpu", (7, 5): "vpu", (16, 16): "mxu",
+         (32, 32): "mxu", (9, 17): "mxu", (8, 16): "vpu"}
+SHAPES = sorted(FORMS)
+POINTS16 = 16 * 128                       # a block of 16 sublane rows
+
+
+def _pass(pts, centers, n_valid, **kw):
+    """``lloyd_pass`` over ``pts`` packed into blocks of 16 sublane
+    rows, interpreted; the rows past ``len(pts)`` of the last block
+    hold 1e30 (padding may hold any finite value)."""
+    k, dim = centers.shape
+    g = lloyd.lanes_geometry(dim, k, block_rows=16)
+    nb = -(-len(pts) // POINTS16)
+    full = np.full((nb * POINTS16, dim), 1e30, np.float32)
+    full[:len(pts)] = pts
+    x4 = jnp.stack([g.pack(jnp.asarray(b))
+                    for b in full.reshape(nb, POINTS16, dim)])
+    return lloyd.lloyd_pass(x4, jnp.asarray(centers), n_valid,
+                            interpret=True, **kw)
+
+
+def _float64_stats(pts, assign, k):
+    """Sums, sums of magnitudes (float64) and counts by ``assign``."""
+    sums = np.zeros((k, pts.shape[1]))
+    mags = np.zeros_like(sums)
+    np.add.at(sums, assign, pts.astype(np.float64))
+    np.add.at(mags, assign, np.abs(pts).astype(np.float64))
+    return sums, mags, np.bincount(assign, minlength=k)
+
+
+@pytest.mark.parametrize("k,dim", SHAPES)
+def test_sums_form_is_a_function_of_the_geometry(k, dim):
+    """Both forms have shapes here: the sums on the MXU (k and dim odd
+    and even, planes of up to seven column tiles), and on the VPU."""
+    assert lloyd.sums_form(k, dim) == FORMS[k, dim]
+    assert lloyd.sums_on_mxu(k, dim) == (k * dim >= lloyd.MXU_MIN_WORK)
+
+
+@pytest.mark.parametrize("valid", ["none", "tail", "padded_block"])
+@pytest.mark.parametrize("k,dim", SHAPES)
+def test_pass_sums_and_counts_against_float64(k, dim, valid):
+    """Three blocks; no valid point, a tail inside the last block, a
+    last block that is padding whole (and the one before it in part).
+    Counts exact and int32; every sum within float32 summation error of
+    the float64 sum over the points the pass itself assigned."""
+    n = 3 * POINTS16
+    n_valid = {"none": 0, "tail": 2 * POINTS16 + 777,
+               "padded_block": POINTS16 + 5}[valid]
+    rng = np.random.default_rng(k * dim)
+    pts = (rng.normal(size=(n, dim)) * 5).astype(np.float32)
+    pts[n_valid:] = 1e30                 # padding: finite, and huge
+    centers = pts[rng.choice(max(n_valid, k), k, replace=False)] \
+        if n_valid else rng.normal(size=(k, dim)).astype(np.float32)
+    partial, assign = _pass(pts, centers, n_valid, assign=True)
+    sums, counts = map(np.asarray, lloyd.fold_stats(partial, k, dim))
+    assign = np.asarray(assign).reshape(-1)[:n_valid]
+    want, mags, want_counts = _float64_stats(pts[:n_valid], assign, k)
+    assert counts.dtype == np.int32 and sums.dtype == np.float32
+    np.testing.assert_array_equal(counts, want_counts)
+    assert int(counts.sum()) == n_valid
+    assert sums.shape == (k, dim)
+    assert (np.abs(sums - want) <= 4e-7 * mags + 1e-30).all()
+
+
+def _full_significands(rng, shape):
+    """float32 values with all 24 significand bits in use, mixed signs,
+    magnitudes from 2**-10 to 1e6 (bfloat16 rounds them by up to 0.4%)."""
+    mant = rng.integers(1 << 23, 1 << 24, size=shape).astype(np.float64)
+    mant += 1 - mant % 2                          # the last bit set
+    exp = rng.integers(-33, -3, size=shape)
+    return (mant * 2.0 ** exp * rng.choice([-1, 1], size=shape)
+            ).astype(np.float32)
+
+
+def test_pieces_are_bfloat16_and_add_back_bit_for_bit():
+    x = _full_significands(np.random.default_rng(1), (8, 128))
+    x[0, :2] = 0.0, 1.0
+    hi, mid, lo = map(np.asarray, jax.jit(lloyd.split3)(jnp.asarray(x)))
+    for piece in (hi, mid, lo):
+        assert not (piece.view(np.uint32) & 0xFFFF).any()
+        rounded = jnp.asarray(piece).astype(jnp.bfloat16)
+        np.testing.assert_array_equal(
+            np.asarray(rounded.astype(jnp.float32)), piece)
+    assert (mid != 0).any() and (lo != 0).any()
+    back = hi.astype(np.float64) + mid + lo       # exact in float64
+    np.testing.assert_array_equal(
+        back.astype(np.float32).view(np.uint32), x.view(np.uint32))
+
+
+def test_mxu_sums_are_float32_sums_of_unrounded_points():
+    """The exactness the configuration states: no addend is rounded.
+    Points with full significands over 50 binades, zeros and a
+    subnormal; each of the 200 sums within float32 summation error of
+    the float64 sum (4e-7 of the sum of magnitudes: measured 8e-8), and
+    the same bound refuses the sums of the points rounded to bfloat16
+    once, by orders of magnitude."""
+    k, dim, n = 10, 20, 2 * POINTS16
+    assert lloyd.sums_form(k, dim) == "mxu"
+    rng = np.random.default_rng(29)
+    pts = _full_significands(rng, (n, dim))
+    pts[::97, 3] = 0.0
+    pts[5, 7] = 1e-40                             # subnormal
+    centers = pts[rng.choice(n, k, replace=False)]
+    partial, assign = _pass(pts, centers, n, assign=True)
+    sums, counts = map(np.asarray, lloyd.fold_stats(partial, k, dim))
+    assign = np.asarray(assign).reshape(-1)
+    want, mags, want_counts = _float64_stats(pts, assign, k)
+    np.testing.assert_array_equal(counts, want_counts)
+    err = np.abs(sums - want) / mags
+    assert err.max() < 4e-7, err.max()
+    rounded = np.asarray(
+        jnp.asarray(pts).astype(jnp.bfloat16).astype(jnp.float32))
+    control, _, _ = _float64_stats(rounded, assign, k)
+    control_err = np.abs(control - want) / mags
+    assert control_err.max() > 100 * 4e-7, control_err.max()
+    assert np.median(control_err) > 10 * 4e-7
+
+
+@pytest.mark.parametrize("k,dim", SHAPES)
+def test_assignment_is_the_score_pass_alone(k, dim):
+    """The pass that also adds up the sums assigns as the pass that
+    only assigns (``local_assign``), bit for bit, padding included, and
+    its counts are that assignment's over the valid points; against a
+    float64 argmin the two differ only where the two nearest centres
+    are within float32 rounding of each other."""
+    n, n_valid = 2 * POINTS16, 2 * POINTS16 - 300
+    rng = np.random.default_rng(7 * k + dim)
+    pts = (rng.normal(size=(n, dim)) * 5).astype(np.float32)
+    centers = pts[rng.choice(n, k, replace=False)]
+    centers[k - 1] = centers[0]                   # a tie: the first wins
+    partial, with_stats = _pass(pts, centers, n_valid, assign=True)
+    alone, = _pass(pts, centers, n_valid, stats=False, assign=True)
+    np.testing.assert_array_equal(np.asarray(with_stats),
+                                  np.asarray(alone))
+    a = np.asarray(alone).reshape(-1)
+    assert not (a == k - 1).any()
+    _, counts = lloyd.fold_stats(partial, k, dim)
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(a[:n_valid], minlength=k))
+    d2 = ((pts[:, None, :].astype(np.float64)
+           - centers[None, :k - 1].astype(np.float64)) ** 2).sum(-1)
+    two = np.sort(d2, axis=1)[:, :2]
+    clear = two[:, 1] - two[:, 0] > 1e-3 * (1 + two[:, 1])
+    np.testing.assert_array_equal(a[clear], d2.argmin(1)[clear])
+    assert clear.mean() > 0.9

@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tpu_distalg.models import ssgd
 from tpu_distalg.ops import logistic
@@ -644,3 +645,93 @@ def test_kmeans_lanes_table_fits_and_follows_reference(tpu_mesh):
             data, valid, jnp.asarray(c), jnp.float32(0), jnp.int32(0))
     assert int(n_run) == 2
     assert int(np.asarray(counts, np.int64).sum()) == 100_000_000
+
+
+def test_kmeans_lanes_sums_are_float32_sums_on_the_mxu():
+    """The compiled pass at the cell's block shape (dim 20, k 10,
+    blocks of 512 sublane rows, 1M points, the last block half
+    padding), from fixed centres: the assignment is the score pass's
+    bit for bit, the 10 counts are its bincount exactly, and each of
+    the 200 sums that the MXU adds up (three bfloat16 pieces a point
+    against the 0/1 mask) is within float32 summation error of the
+    float64 sum: 1e-6 of the sum of magnitudes, where the same points
+    rounded to bfloat16 once miss by 5e-4 or more."""
+    from tpu_distalg.ops import pallas_lloyd as lloyd
+
+    k, dim, n = 10, 20, 1_000_000
+    assert lloyd.sums_form(k, dim) == "mxu"
+    geom = lloyd.lanes_geometry(dim, k)
+    assert geom.block_rows == 512
+    nb = -(-n // geom.block_points)
+    rng = np.random.default_rng(29)
+    shape = (nb * geom.block_points, dim)
+    # all 24 significand bits in use, and the 16 that bfloat16 drops
+    # always round down, so that the control's error cannot average out
+    mant = ((rng.integers(1 << 7, 1 << 8, size=shape) << 16)
+            | rng.integers(0x1000, 0x7000, size=shape)).astype(np.float64)
+    pts = (mant * 2.0 ** rng.integers(-24, -19, size=shape)
+           ).astype(np.float32)
+    centers = pts[rng.choice(n, k, replace=False)]
+    x4 = jax.vmap(geom.pack)(
+        jnp.asarray(pts).reshape(nb, geom.block_points, dim))
+    partial, assign = jax.block_until_ready(
+        lloyd.lloyd_pass(x4, jnp.asarray(centers), n, assign=True))
+    alone, = lloyd.lloyd_pass(x4, jnp.asarray(centers), n, stats=False,
+                              assign=True)
+    np.testing.assert_array_equal(np.asarray(assign), np.asarray(alone))
+    sums, counts = map(np.asarray, lloyd.fold_stats(partial, k, dim))
+    a = np.asarray(alone).reshape(-1)[:n]
+    want = np.zeros((k, dim))
+    mags = np.zeros((k, dim))
+    np.add.at(want, a, pts[:n].astype(np.float64))
+    np.add.at(mags, a, np.abs(pts[:n]).astype(np.float64))
+    assert counts.dtype == np.int32
+    np.testing.assert_array_equal(counts, np.bincount(a, minlength=k))
+    err = np.abs(sums - want) / mags
+    assert err.max() < 1e-6, err.max()
+    rounded = np.asarray(jax.lax.reduce_precision(
+        jnp.asarray(pts[:n]), exponent_bits=8, mantissa_bits=7))
+    control = np.zeros((k, dim))
+    np.add.at(control, a, rounded.astype(np.float64))
+    assert (np.abs(control - want) / mags).min() > 5e-4
+    # the float64 argmin agrees wherever the margin is beyond rounding
+    d2 = ((pts[:n, None, :].astype(np.float64)
+           - centers[None].astype(np.float64)) ** 2).sum(-1)
+    two = np.sort(d2, axis=1)[:, :2]
+    clear = two[:, 1] - two[:, 0] > 1e-3 * (1 + two[:, 1])
+    assert clear.mean() > 0.95
+    np.testing.assert_array_equal(a[clear], d2.argmin(1)[clear])
+
+
+@pytest.mark.parametrize("k,dim,form", [
+    (32, 32, "mxu"), (9, 17, "mxu"), (8, 16, "vpu"), (7, 5, "vpu")])
+def test_kmeans_lanes_pass_at_other_shapes(k, dim, form):
+    """The compiled pass in both forms away from the cell's shape (the
+    planes in seven column tiles; k and dim odd; the masked adds on the
+    VPU): 300 000 points in the geometry's own blocks, the last one
+    padding in part; counts the score pass's bincount exactly, sums
+    within float32 summation error of the float64 sums."""
+    from tpu_distalg.ops import pallas_lloyd as lloyd
+
+    n = 300_000
+    assert lloyd.sums_form(k, dim) == form
+    geom = lloyd.lanes_geometry(dim, k)
+    nb = -(-n // geom.block_points)
+    rng = np.random.default_rng(k * dim)
+    pts = (rng.normal(size=(nb * geom.block_points, dim)) * 5
+           ).astype(np.float32)
+    centers = pts[rng.choice(n, k, replace=False)]
+    x4 = jax.vmap(geom.pack)(
+        jnp.asarray(pts).reshape(nb, geom.block_points, dim))
+    partial, = lloyd.lloyd_pass(x4, jnp.asarray(centers), n)
+    alone, = lloyd.lloyd_pass(x4, jnp.asarray(centers), n, stats=False,
+                              assign=True)
+    sums, counts = map(np.asarray, lloyd.fold_stats(partial, k, dim))
+    a = np.asarray(alone).reshape(-1)[:n]
+    want = np.zeros((k, dim))
+    mags = np.zeros((k, dim))
+    np.add.at(want, a, pts[:n].astype(np.float64))
+    np.add.at(mags, a, np.abs(pts[:n]).astype(np.float64))
+    assert counts.dtype == np.int32
+    np.testing.assert_array_equal(counts, np.bincount(a, minlength=k))
+    assert (np.abs(sums - want) <= 1e-6 * mags).all()

@@ -74,8 +74,9 @@ def _mesh_fns(mesh: Mesh, lanes):
     ``(n,)`` mask, ``ops/kmeans.py``; counts are float32. Lanes layout:
     ``data`` ``(n_blocks, dim, R, 128)``, ``valid`` the count of valid
     points (validity follows from the id), one ``pallas_lloyd`` kernel
-    a pass; counts are int32. ``lanes`` is a
-    ``pallas_lloyd.LanesGeometry``."""
+    a pass (the per-cluster sums on the MXU or the VPU as
+    ``pallas_lloyd.sums_form`` says for the geometry); counts are
+    int32. ``lanes`` is a ``pallas_lloyd.LanesGeometry``."""
     if lanes is None:
         both = data_parallel(
             _local_stats, mesh,
@@ -99,10 +100,10 @@ def _mesh_fns(mesh: Mesh, lanes):
 
     def local_stats(x4, n_valid, centers):
         with jax.named_scope(names.KMEANS_ASSIGN):
-            sums8, counts8 = lloyd.lloyd_pass(
+            partial, = lloyd.lloyd_pass(
                 x4, centers, mine(x4, n_valid), interpret=interpret)
         with jax.named_scope(names.KMEANS_STATS):
-            stats = lloyd.fold_stats(sums8, counts8)
+            stats = lloyd.fold_stats(partial, *centers.shape)
         with jax.named_scope(names.KMEANS_SYNC):
             return tree_allreduce_sum(stats)
 
@@ -116,6 +117,16 @@ def _mesh_fns(mesh: Mesh, lanes):
                           out_specs=(P(), P())),
             data_parallel(local_assign, mesh, in_specs=(x_spec, P(), P()),
                           out_specs=P("data")))
+
+
+def _span_fields(k: int, lanes) -> dict:
+    """What the scale path's spans say beyond their sizes: where the
+    lanes kernel adds up the per-cluster sums at this geometry."""
+    if lanes is None:
+        return {}
+    from tpu_distalg.ops import pallas_lloyd as lloyd
+
+    return {"sums_form": lloyd.sums_form(k, lanes.dim)}
 
 
 def _counts0(k: int, lanes) -> jax.Array:
@@ -312,7 +323,7 @@ def _fit_segmented(data, valid, mesh, config: KMeansConfig, centers0,
     state, _, _ = ckpt.run_segmented(
         checkpoint_dir, checkpoint_every, n_total,
         lambda seg: make_fit_seg_fn(mesh, config, seg, lanes),
-        run_seg, state0,
+        run_seg, state0, span_fields=_span_fields(config.k, lanes),
         # the two modes share the state signature but fixed mode's
         # shift=0.0 sentinel would alias "converged" on a cross-mode
         # resume — encode the mode in the tag
@@ -470,6 +481,11 @@ def init_centers_scaled(make_rows, n_rows: int, config: KMeansConfig,
     raise ValueError(f"unknown init {config.init!r}")
 
 
+@jax.jit
+def _all_finite(x):
+    return jnp.isfinite(x).all()
+
+
 def build_scaled(mesh: Mesh, n_rows: int, make_rows, k: int, *,
                  data_seed: int | None = None):
     """The scale path's resident table: ``(points, valid, lanes)`` as
@@ -480,9 +496,14 @@ def build_scaled(mesh: Mesh, n_rows: int, make_rows, k: int, *,
     1024) the points are drawn block by block into its feature-major
     layout, 4 * dim bytes a point and no mask; else into plain rows,
     chunk by chunk, with their mask, for ``ops/kmeans.py``. On one v5e
-    at 100M x 20, k = 10 the lanes path holds 8.0 GB and takes 16.9 ms
-    an iteration, the row path 10.0 GB and 36.9 ms (PERF.md §6,
-    PR 26)."""
+    at 100M x 20, k = 10 the lanes path holds 8.0 GB and takes 13.5 ms
+    an iteration (16.9 before its sums took the MXU), the row path
+    10.0 GB and 36.9 ms (PERF.md §6, PR 26, PR 29).
+
+    The lanes table has to be finite, padding included (the kernel's
+    matmul multiplies every point by every cluster's 0 or 1, and 0 x
+    NaN is NaN): one read of the table checks it, and a ``make_rows``
+    that yields a NaN or an infinity raises ``ValueError`` here."""
     from tpu_distalg.ops import pallas_lloyd as lloyd
     from tpu_distalg.parallel import build_sharded
 
@@ -493,10 +514,15 @@ def build_scaled(mesh: Mesh, n_rows: int, make_rows, k: int, *,
     per = chunk * mesh.shape["data"]
     with tevents.span("kmeans:prepare", rows=n_rows,
                       bytes=-(-n_rows // per) * per * dim * 4,
-                      layout="rows" if lanes is None else "lanes"):
+                      layout="rows" if lanes is None else "lanes",
+                      **_span_fields(k, lanes)):
         ps = build_sharded(
             mesh, n_rows, make_rows, seed=data_seed, chunk_rows=chunk,
             pack=None if lanes is None else lanes.pack)
+        if lanes is not None and not bool(_all_finite(ps.data)):
+            raise ValueError(
+                "build_scaled: make_rows gave a NaN or an infinity; the "
+                "lanes table has to be finite, padding rows included")
         jax.block_until_ready(ps.data)
     valid = ps.mask if lanes is None else jnp.int32(n_rows)
     return ps.data, valid, lanes

@@ -127,6 +127,7 @@ def summarize(evts: list[dict]) -> dict:
     resolution = None
     runs: list[str] = []
     draw_forms: list[str] = []
+    sums_forms: list[str] = []
     t_wall = [e["t_wall"] for e in evts if "t_wall" in e]
     for e in evts:
         ev = e.get("ev")
@@ -141,6 +142,11 @@ def summarize(evts: list[dict]) -> dict:
             form = e.get("draw_form")
             if form and form not in draw_forms:
                 draw_forms.append(form)
+            # and those of k-means' lanes path where a pass adds up the
+            # per-cluster sums (pallas_lloyd.sums_form)
+            form = e.get("sums_form")
+            if form and form not in sums_forms:
+                sums_forms.append(form)
         elif ev == "span_end":
             name = e.get("name", "?")
             open_spans[name] = open_spans.get(name, 1) - 1
@@ -208,6 +214,7 @@ def summarize(evts: list[dict]) -> dict:
         "phases": phases,
         "span_tree": span_tree(evts),
         "draw_forms": draw_forms,
+        "sums_forms": sums_forms,
         "unfinished_phases": sorted(
             k for k, v in open_spans.items() if v > 0),
         "marks": marks,
@@ -249,6 +256,8 @@ def render(s: dict) -> str:
         lines.append(f"  {name}: UNFINISHED (no span_end recorded)")
     if s.get("draw_forms"):
         lines.append(f"block draw: {', '.join(s['draw_forms'])}")
+    if s.get("sums_forms"):
+        lines.append(f"cluster sums: {', '.join(s['sums_forms'])}")
     hb = s["last_heartbeat"]
     lines.append(
         "last heartbeat: "
