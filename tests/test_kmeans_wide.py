@@ -1,0 +1,435 @@
+"""The scale path's Lloyd iteration on the wide layout
+(``ops/pallas_lloyd_wide.py``, ``kmeans.build_scaled`` /
+``make_fit_seg_fn``) against the benchmark's plain float32 reference
+(``benchmarks/reference/kmeans_wide_ref.py``: the distance by its
+definition, its own table, nothing of the program imported), at a small
+wide shape: k 96, dim 49 (k x dim past the lanes kernel's 1024, dim not
+a multiple of 8, so a block holds 64 feature rows for 49), 10 generating
+clusters, blocks of 512 points.
+
+On the CPU both kernels run interpreted, with the bfloat16
+``dot_general``s they ship with; the chip compiles them (``tests_tpu``,
+``benchmarks/tools/compile_check_kmeans_wide.py``).
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_distalg.models import kmeans
+from tpu_distalg.ops import pallas_lloyd as lloyd
+from tpu_distalg.ops import pallas_lloyd_wide as wide
+from tpu_distalg.parallel import build_sharded
+from tpu_distalg.telemetry import events, names, report
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from reference import kmeans_ref, kmeans_wide_ref  # noqa: E402
+
+DIM, K, GEN, SPREAD = 49, 96, 10, 8.0
+GEOM = wide.wide_geometry(DIM, K)
+P = GEOM.block_points
+
+
+def make_rows(ids, seed):
+    return kmeans_ref.make_rows(ids, DIM, GEN, seed, SPREAD)
+
+
+def _table(mesh, n, seed, geom=GEOM, rows=make_rows):
+    ps = build_sharded(mesh, n, rows, seed=seed,
+                       chunk_rows=geom.block_points, pack=geom.pack)
+    return ps.data, jnp.int32(n)
+
+
+def _rows(n, seed):
+    return np.asarray(jax.jit(make_rows)(jnp.arange(n), jnp.int32(seed)))
+
+
+def _seg(mesh, iterations, geom=GEOM):
+    return kmeans.make_fit_seg_fn(
+        mesh, kmeans.KMeansConfig(k=geom.k, n_iterations=iterations),
+        iterations, geom)
+
+
+def _start(centers0):
+    return jnp.asarray(centers0), jnp.float32(0.0), jnp.int32(0)
+
+
+def _reference(n, seed):
+    ref = kmeans_wide_ref.Reference(
+        n_rows=n, dim=DIM, k=K, clusters=GEN, spread=SPREAD,
+        data_seed=seed, init_seed=seed + 1, device=jax.devices()[0],
+        block_rows=1024)
+    ref.build()
+    return ref
+
+
+# one iteration from the same centres, both sides float32; they differ in
+# the form of the distance (|c|^2 - 2 x.c as six bfloat16 products
+# against (x - c)^2) and in the order of the sums. Where no point changes
+# sides: summation order, 2e-5 of the data's spread (measured 6e-7).
+# Where a point does (its two nearest centres within float32 rounding of
+# the score, 1e-3 on 6000 here): it moves two centres by its distance
+# over their counts, 6 / 30 of a cluster at the smallest n: 2e-2.
+SAME, MOVED = 2e-5, 2e-2
+
+
+@pytest.mark.parametrize("iterations", [1, 4])
+@pytest.mark.parametrize("n", [3 * P, 6000, 12043])
+def test_segment_follows_the_float32_reference(mesh1, n, iterations):
+    """The reference follows ``iterations`` calls from the seeded
+    start; the program runs the last one from the reference's centres
+    (tolerances above) and the whole segment from the start (held by
+    what the centres are for: the inertia on the data within 1e-3 of
+    the reference's, because one point that changes sides sends two
+    trajectories apart)."""
+    ref = _reference(n, 11)
+    c0 = ref.init_centers()
+    want, want_counts = ref.follow(iterations, 1)
+    x3, valid = _table(mesh1, n, 11)
+    centers, _, n_run, counts = _seg(mesh1, iterations)(
+        x3, valid, *_start(c0))
+    assert int(n_run) == iterations
+    assert np.asarray(counts).dtype == np.int32
+    assert int(np.asarray(counts).sum()) == n == int(want_counts.sum())
+    X = ref.heldout(2048)
+    assert abs(ref.inertia(X, np.asarray(centers))
+               / ref.inertia(X, want[-1]) - 1) < 1e-3
+
+    before = c0 if iterations == 1 else want[-2]
+    got, _, _, got_counts = _seg(mesh1, 1)(x3, valid, *_start(before))
+    moved = int(np.abs(np.asarray(got_counts) - want_counts).sum())
+    assert moved <= 4
+    err = kmeans_ref.centers_err(got, want[-1], SPREAD)
+    assert err < (SAME if moved == 0 else MOVED), (err, moved)
+
+
+@pytest.mark.parametrize("pieces", [1, 2])
+def test_a_bfloat16_product_is_caught(mesh1, monkeypatch, pieces):
+    """The same comparison with operands of fewer pieces than carry a
+    float32 (one: the MXU's default precision; two: ``HIGH``): points
+    change sides by the dozen and the centres leave the tolerance that
+    holds the shipped product; so does the reference's own bfloat16
+    control."""
+    n = 6000
+    ref = _reference(n, 11)
+    c0 = ref.init_centers()
+    want, want_counts = ref.follow(1, 1)
+    low, _ = ref.follow(1, 1, dtype=jnp.bfloat16)
+    assert kmeans_ref.centers_err(low[-1], want[-1], SPREAD) > MOVED
+    real = wide.split3
+
+    def fewer(x):
+        got = real(x)
+        return got[:pieces] + tuple(
+            jnp.zeros_like(p) for p in got[pieces:])
+
+    monkeypatch.setattr(wide, "split3", fewer)
+    jax.clear_caches()
+    try:
+        got, _, _, counts = _seg(mesh1, 1)(
+            *_table(mesh1, n, 11), *_start(c0))
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    moved = int(np.abs(np.asarray(counts) - want_counts).sum())
+    err = kmeans_ref.centers_err(got, want[-1], SPREAD)
+    if pieces == 1:
+        assert moved > 4 and err > MOVED, (err, moved)
+    else:
+        # three-pass products: closer, and still not the statement's
+        assert err > SAME, (err, moved)
+
+
+@pytest.mark.parametrize("k,dim,layout", [
+    (10, 20, "lanes"), (32, 32, "lanes"), (1024, 1, "lanes"),
+    (25, 41, "wide"), (1025, 1, "wide"), (96, 49, "wide"),
+    (4096, 784, "wide"), (16384, 128, "wide"), (2, 5000, "rows")])
+def test_layout_is_a_function_of_k_and_dim(k, dim, layout):
+    """1024 and 1025 fall on the two sides; nothing else is asked."""
+    geom = kmeans.scale_geometry(dim, k)
+    assert kmeans.layout_of(geom) == layout
+    assert (lloyd.lanes_geometry(dim, k) is None) == (layout != "lanes")
+    if layout == "wide":
+        assert geom == wide.wide_geometry(dim, k)
+        assert geom.k_padded % geom.stats_tile == 0
+        assert geom.stats_tile % geom.centre_tile == 0
+        assert geom.dim_held % 16 == 0 and geom.dim_mxu % 128 == 0
+        assert kmeans._span_fields(k, geom) == {
+            "layout": "wide", "dist_form": "mxu6", "sums_form": "mxu"}
+    if (k, dim) == (4096, 784):
+        # the published widths: 4 x 784 bytes a point, nothing padded
+        assert (geom.dim_held, geom.point_bytes, geom.block_points,
+                geom.centre_tile, geom.stats_tile, geom.k_padded) == (
+                    784, 3136, 512, 512, 4096, 4096)
+
+
+def test_table_is_the_generators_rows_in_id_order(mesh4):
+    n = 3 * P + 5
+    x3, _ = _table(mesh4, n, 3)
+    assert x3.shape == (4, 64, P) and GEOM.dim_held == 64
+    np.testing.assert_array_equal(
+        np.asarray(GEOM.unpack(x3))[:n], _rows(n, 3))
+    assert not np.asarray(x3)[:, DIM:].any()      # the held rows past dim
+
+
+TILES = wide.wide_geometry(2, 600)      # two tiles of 512 centres
+
+
+@pytest.mark.parametrize("first,second", [
+    (3, 550), (6, 13), (511, 512), (0, 599)],
+    ids=["across_tiles", "across_sublanes", "at_the_boundary", "ends"])
+def test_a_tie_goes_to_the_first_centre(mesh1, first, second):
+    """Two equal centres, in one tile of centres or either side of the
+    boundary between two: every point of theirs goes to the lower index
+    (the reference's first minimum), the other stays empty."""
+    assert (TILES.centre_tile, TILES.k_padded) == (512, 1024)
+    n, k = 3000, TILES.k
+
+    def rows(ids, seed):
+        return kmeans_ref.make_rows(ids, 2, 50, seed, 30.0)
+
+    pts = np.asarray(jax.jit(rows)(jnp.arange(n), jnp.int32(4)))
+    c0 = pts[:k].copy()
+    c0[second] = c0[first]
+    x3, valid = _table(mesh1, n, 4, TILES, rows)
+    centers, _, _, counts = _seg(mesh1, 1, TILES)(x3, valid, *_start(c0))
+    counts = np.asarray(counts)
+    assert counts[first] > 0 and counts[second] == 0
+    np.testing.assert_array_equal(np.asarray(centers)[second], c0[first])
+    a = np.asarray(jax.jit(kmeans._mesh_fns(mesh1, TILES)[1])(
+        x3, valid, jnp.asarray(c0)))[:n]
+    want = np.asarray(kmeans_wide_ref.nearest(
+        jnp.asarray(pts.T), jnp.asarray(c0)))
+    np.testing.assert_array_equal(a, want)
+    assert int(counts.sum()) == n
+
+
+def test_an_empty_cluster_keeps_its_centre(mesh1):
+    n = 4000
+    c0 = _rows(n, 2)[:K].copy()
+    c0[7] = 1e3                                  # nobody's nearest
+    centers, _, _, counts = _seg(mesh1, 2)(
+        *_table(mesh1, n, 2), *_start(c0))
+    assert int(np.asarray(counts)[7]) == 0
+    np.testing.assert_array_equal(np.asarray(centers)[7], c0[7])
+    assert int(np.asarray(counts).sum()) == n
+
+
+@pytest.mark.parametrize("n", [2 * P, 2 * P - 300, P + 1, 257])
+def test_padding_and_a_ragged_last_block_are_not_counted(mesh1, n):
+    """Validity follows from the id, in whole blocks, in a block that
+    ends inside a chunk of the stats kernel and in one that holds a
+    single valid point: the counts are those of the valid points'
+    assignment and the sums are theirs alone."""
+    pts = _rows(2 * P, 6)
+    c0 = pts[:K]
+    x3, _ = _table(mesh1, 2 * P, 6)              # every row finite
+    assign = wide.wide_assign(x3, jnp.asarray(c0), geom=GEOM,
+                              interpret=True)
+    sums, counts = wide.wide_stats(x3, assign, n, geom=GEOM,
+                                   interpret=True)
+    a = np.asarray(assign).reshape(-1)
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(a[:n], minlength=K))
+    assert int(np.asarray(counts).sum()) == n
+    want = np.zeros((K, DIM))
+    np.add.at(want, a[:n], pts[:n].astype(np.float64))
+    np.testing.assert_allclose(np.asarray(sums), want, rtol=0, atol=2e-4)
+
+
+def test_shards_agree_with_one_device(mesh1, mesh4):
+    """Four shards, the last one holding the ragged end and one holding
+    only padding, give the counts one device gives and the same centres
+    to float32 summation order."""
+    n = 2 * P + 77
+    c0 = _rows(n, 5)[:K]
+    one = _seg(mesh1, 3)(*_table(mesh1, n, 5), *_start(c0))
+    four = _seg(mesh4, 3)(*_table(mesh4, n, 5), *_start(c0))
+    np.testing.assert_array_equal(np.asarray(one[3]), np.asarray(four[3]))
+    np.testing.assert_allclose(np.asarray(one[0]), np.asarray(four[0]),
+                               rtol=0, atol=1e-4)
+
+
+def test_segments_chain_to_the_straight_run_bitwise(mesh1):
+    n = 3011
+    x3, valid = _table(mesh1, n, 9)
+    c0 = _rows(n, 9)[100:100 + K]
+    state = _start(c0)
+    two = _seg(mesh1, 2)
+    for _ in range(3):
+        *state, counts = two(x3, valid, *state)
+    whole = _seg(mesh1, 6)(x3, valid, *_start(c0))
+    straight = kmeans.make_fit_fn(
+        mesh1, kmeans.KMeansConfig(k=K, n_iterations=6), GEOM)(
+            x3, valid, jnp.asarray(c0))
+    assert int(state[2]) == int(whole[2]) == int(straight[2]) == 6
+    np.testing.assert_array_equal(np.asarray(state[0]),
+                                  np.asarray(whole[0]))
+    np.testing.assert_array_equal(np.asarray(whole[0]),
+                                  np.asarray(straight[0]))
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.asarray(whole[3]))
+    np.testing.assert_array_equal(
+        np.asarray(straight[1])[:n],
+        np.asarray(jax.jit(kmeans._mesh_fns(mesh1, GEOM)[1])(
+            x3, valid, whole[0]))[:n])
+
+
+def test_one_compile_serves_every_seed(mesh1):
+    n = 2 * P
+    fn = _seg(mesh1, 2)
+    for seed in (1, 2147483001):
+        fn(*_table(mesh1, n, seed), *_start(_rows(n, seed)[:K]))
+    assert fn._cache_size() == 1
+
+    def lowered(seed):
+        return jax.jit(lambda s: GEOM.pack(make_rows(jnp.arange(P), s))
+                       ).lower(jnp.int32(seed)).as_text()
+
+    assert lowered(1) == lowered(2147483001)
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_lowered_segment_names_its_scopes(mesh1, mesh4, shards):
+    mesh = mesh1 if shards == 1 else mesh4
+    x3, valid = _table(mesh, 4 * P, 1)
+    text = _seg(mesh, 2).lower(
+        x3, valid, *_start(_rows(K, 1))).as_text(debug_info=True)
+    for scope in (names.KMEANS_ASSIGN, names.KMEANS_STATS,
+                  names.KMEANS_SYNC, names.KMEANS_UPDATE):
+        assert scope + "/" in text, scope
+    assert f"{names.KMEANS_ASSIGN}/jit(wide_assign)" in text
+    assert f"{names.KMEANS_STATS}/jit(wide_stats)" in text
+
+
+def _dots(fn, *args):
+    """(operand dtypes, result dtype) of every ``dot_general`` under
+    ``fn``, kernels' bodies included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                found.append((tuple(str(v.aval.dtype) for v in eqn.invars),
+                              str(eqn.outvars[0].aval.dtype)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("interpret", [True, False])
+def test_the_interpreted_pass_uses_the_products_that_ship(interpret):
+    """Interpreted or compiled, the assign kernel is six bfloat16
+    products accumulated in float32 and the stats kernel three; no
+    float32 ``dot_general`` stands in for them on the CPU."""
+    x3 = jnp.zeros((2, GEOM.dim_held, P), jnp.float32)
+    c = jnp.zeros((K, DIM), jnp.float32)
+    a = jnp.zeros((2, 1, P), jnp.int32)
+    bf16 = (("bfloat16", "bfloat16"), "float32")
+    assert _dots(lambda x, c: wide.wide_assign(
+        x, c, geom=GEOM, interpret=interpret), x3, c) == [bf16] * 6
+    assert _dots(lambda x, a: wide.wide_stats(
+        x, a, 7, geom=GEOM, interpret=interpret), x3, a) == [bf16] * 3
+
+
+def test_pieces_are_bfloat16_and_add_back_bit_for_bit():
+    rng = np.random.default_rng(3)
+    x = (rng.standard_normal(4096) * np.exp2(
+        rng.integers(-30, 30, 4096))).astype(np.float32)
+    x[0] = 0.0
+    hi, mid, lo = (np.asarray(p) for p in wide.split3(jnp.asarray(x)))
+    assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+    back = (lo.astype(np.float32) + mid.astype(np.float32)) \
+        + hi.astype(np.float32)
+    np.testing.assert_array_equal(back.view(np.uint32), x.view(np.uint32))
+    # one piece, or two, is not the float32
+    assert (hi.astype(np.float32) != x).mean() > 0.9
+    assert ((mid.astype(np.float32) + hi.astype(np.float32)) != x
+            ).mean() > 0.9
+
+
+def test_fit_scaled_takes_the_wide_path_and_spans_it(mesh4, tmp_path):
+    """``fit_scaled`` (what ``tda kmeans --scale-points`` calls) picks
+    the layout from the geometry, draws under ``kmeans:prepare`` and
+    says in its spans how a pass scores and sums; ``tda report`` prints
+    both."""
+    tel = str(tmp_path / "tel")
+    events.configure(tel)
+    try:
+        res = kmeans.fit_scaled(
+            mesh4, 5000, make_rows,
+            kmeans.KMeansConfig(k=K, n_iterations=4, init="farthest"),
+            checkpoint_dir=str(tmp_path / "ck"), checkpoint_every=2,
+            data_seed=7)
+    finally:
+        events.configure(False)
+    assert res.n_iterations_run == 4 and res.centers.shape == (K, DIM)
+    a = np.asarray(res.assignments)[:5000]
+    assert a.min() >= 0 and a.max() < K
+    ends = [e for e in report.load_events(tel) if e["ev"] == "span_end"]
+    prep = [e for e in ends if e["name"] == "kmeans:prepare"]
+    assert len(prep) == 1 and prep[0]["layout"] == "wide"
+    assert prep[0]["bytes"] == 3 * 4 * P * 64 * 4     # 64 rows held for 49
+    segs = [e for e in ends if e["name"] == "train:segment"]
+    assert [(e["t0"], e["steps"]) for e in segs] == [(0, 2), (2, 2)]
+    for e in prep + segs:
+        assert (e["layout"], e["dist_form"], e["sums_form"]) == (
+            "wide", "mxu6", "mxu")
+    lines = report.render(
+        report.summarize(report.load_events(tel))).splitlines()
+    assert "distances: mxu6" in lines and "cluster sums: mxu" in lines
+
+
+@pytest.mark.parametrize("row,value", [(1005, np.nan), (7, np.inf)],
+                         ids=["padding", "valid"])
+def test_build_scaled_refuses_a_wide_table_that_is_not_finite(
+        mesh1, row, value):
+    def rows(ids, seed):
+        return jnp.where((ids == row)[:, None], value,
+                         make_rows(ids, seed))
+
+    with pytest.raises(ValueError, match="wide table has to be finite"):
+        kmeans.build_scaled(mesh1, 1000, rows, K, data_seed=1)
+
+
+def test_farthest_start_runs_on_the_device():
+    """The greedy farthest-point start, jitted: k distinct candidates,
+    each next one the farthest from those before it."""
+    rng = np.random.default_rng(0)
+    cand = rng.normal(size=(64, 5)).astype(np.float32)
+    got = np.asarray(kmeans._farthest(jnp.asarray(cand), jnp.int32(9), 6))
+    chosen = [9]
+    d = ((cand - cand[9]) ** 2).sum(1)
+    while len(chosen) < 6:
+        chosen.append(int(d.argmax()))
+        d = np.minimum(d, ((cand - cand[chosen[-1]]) ** 2).sum(1))
+    np.testing.assert_array_equal(got, cand[chosen])
+
+
+def test_rows_path_scores_to_float32_accuracy():
+    """``ops/kmeans.assign_clusters`` (the rows path that remains): the
+    product pinned to ``HIGHEST``, no ``|x|^2`` term."""
+    from tpu_distalg.ops import kmeans as kops
+
+    x, c = jnp.zeros((8, 4)), jnp.zeros((3, 4))
+    eqns = jax.make_jaxpr(kops.assign_clusters)(x, c).jaxpr.eqns
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 1
+    assert "HIGHEST" in str(dots[0].params["precision"])
+    pts = np.random.default_rng(1).normal(size=(500, 6)).astype(np.float32)
+    cen = pts[:7]
+    want = ((pts[:, None].astype(np.float64) - cen[None]) ** 2).sum(
+        -1).argmin(1)
+    np.testing.assert_array_equal(
+        np.asarray(kops.assign_clusters(jnp.asarray(pts),
+                                        jnp.asarray(cen))), want)

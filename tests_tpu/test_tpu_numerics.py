@@ -735,3 +735,150 @@ def test_kmeans_lanes_pass_at_other_shapes(k, dim, form):
     assert counts.dtype == np.int32
     np.testing.assert_array_equal(counts, np.bincount(a, minlength=k))
     assert (np.abs(sums - want) <= 1e-6 * mags).all()
+
+
+def _float64_nearest(pts, centers, chunk=8192):
+    """(argmin, margin to the second nearest, smallest squared
+    distance) of every row by float64 squared distances."""
+    c = centers.astype(np.float64)
+    c2 = (c * c).sum(1)
+    arg, margin, least = [], [], []
+    for i in range(0, len(pts), chunk):
+        x = pts[i:i + chunk].astype(np.float64)
+        d2 = (x * x).sum(1)[:, None] - 2.0 * x @ c.T + c2[None]
+        two = np.partition(d2, 1, axis=1)[:, :2]
+        arg.append(d2.argmin(1))
+        margin.append(two[:, 1] - two[:, 0])
+        least.append(two[:, 0])
+    return (np.concatenate(arg), np.concatenate(margin),
+            np.concatenate(least))
+
+
+def _wide_case(dim, k, n, seed):
+    """Mixture points in the wide geometry's own blocks (the last one
+    padding in part), centres sampled from them, the compiled pass's
+    assignment, sums, counts and its time."""
+    import time
+
+    from tpu_distalg.ops import pallas_lloyd_wide as wide
+
+    geom = wide.wide_geometry(dim, k)
+    p = geom.block_points
+    nb = -(-n // p)
+    rng = np.random.default_rng(seed)
+    mus = rng.normal(size=(10, dim)) * 8.0
+    pts = (mus[rng.integers(0, 10, nb * p)]
+           + rng.normal(size=(nb * p, dim))).astype(np.float32)
+    centers = pts[rng.choice(n, k, replace=False)]
+    x3 = jax.vmap(geom.pack)(jnp.asarray(pts).reshape(nb, p, dim))
+    c = jnp.asarray(centers)
+
+    def run():
+        a = wide.wide_assign(x3, c, geom=geom)
+        return a, wide.wide_stats(x3, a, n, geom=geom)
+
+    jax.block_until_ready(run())
+    t0 = time.perf_counter()
+    assign, (sums, counts) = jax.block_until_ready(run())
+    ms = (time.perf_counter() - t0) * 1e3
+    return (geom, pts[:n], centers, x3, np.asarray(assign).reshape(-1)[:n],
+            np.asarray(sums), np.asarray(counts), ms)
+
+
+def _check_wide_stats(pts, a, k, sums, counts, tol=1e-6):
+    """Counts exact; every sum within ``tol`` of the sum of magnitudes
+    of the float64 sum (float32 summation error: a cluster's points
+    arrive 256 at a time, so a sum is hundreds of float32 additions of
+    partial sums; one addend rounded to bfloat16 is off by 4e-3 of
+    itself)."""
+    want = np.zeros((k, pts.shape[1]))
+    mags = np.zeros((k, pts.shape[1]))
+    np.add.at(want, a, pts.astype(np.float64))
+    np.add.at(mags, a, np.abs(pts).astype(np.float64))
+    assert counts.dtype == np.int32
+    np.testing.assert_array_equal(counts, np.bincount(a, minlength=k))
+    worst = float((np.abs(sums - want) / (mags + 1e-30)).max())
+    print(f"[wide sums] largest error over the sum of magnitudes "
+          f"{worst:.3g} (tolerance {tol:g})")
+    assert worst <= tol, worst
+
+
+def test_kmeans_wide_pass_at_the_published_widths(monkeypatch):
+    """The compiled wide pass at FAISS's MNIST8m widths (784 float32
+    dimensions, 4096 centres, blocks of 512 points, 100 000 points)
+    against a float64 argmin. Float32 accuracy: the scores are 1e5 and
+    carry 0.01 to 0.05 of rounding, so a point whose two nearest
+    centres lie closer than that may go to either (2 in 10 000 at the
+    cell's size: PERF.md §6, PR 30); every other point goes where
+    float64 sends it. Held as: at most 1 point in 1000 differs, and none
+    whose margin is over 1e-6 of the scores' scale (0.15). Counts are
+    the assignment's bincount exactly, adding up to n; sums within
+    float32 summation error of the float64 sums. The control, operands
+    of one bfloat16 piece (the MXU's default precision) in the same
+    kernel, sends hundreds of points wrong whose margin is ten times
+    that."""
+    from tpu_distalg.ops import pallas_lloyd_wide as wide
+
+    dim, k, n = 784, 4096, 100_000
+    geom, pts, centers, x3, a, sums, counts, _ = _wide_case(dim, k, n, 3)
+    assert (geom.dim_held, geom.block_points) == (784, 512)
+    want, margin, least = _float64_nearest(pts, centers)
+    scale = (centers.astype(np.float64) ** 2).sum(1).max() * 3
+    bad = a != want
+    print(f"[wide widths] {int(bad.sum())} of {n} differ from float64, "
+          f"largest margin among them {margin[bad].max(initial=0):.4g}, "
+          f"scale {scale:.4g}")
+    assert int(bad.sum()) <= n // 1000
+    assert margin[bad].max(initial=0.0) < 1e-6 * scale
+    # 391 chunks' partial sums a cluster at worst: 1e-5 where the
+    # smaller shapes hold 1e-6
+    _check_wide_stats(pts, a, k, sums, counts, tol=1e-5)
+    assert int(counts.sum()) == n
+
+    real = wide.split3
+    monkeypatch.setattr(wide, "split3", lambda x: real(x)[:1] + tuple(
+        jnp.zeros_like(p) for p in real(x)[1:]))
+    jax.clear_caches()
+    try:
+        low = np.asarray(wide.wide_assign(
+            x3, jnp.asarray(centers), geom=geom)).reshape(-1)[:n]
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    clear = margin > 1e-5 * scale
+    wrong = int((low[clear] != want[clear]).sum())
+    print(f"[wide widths] control: {int((low != want).sum())} differ, "
+          f"{wrong} of them with a margin over {1e-5 * scale:.3g}")
+    assert wrong > 100, wrong
+
+
+@pytest.mark.parametrize("dim,k", [
+    (96, 1024), (128, 1024), (96, 16384), (128, 16384), (49, 96)])
+def test_kmeans_wide_pass_at_other_shapes(dim, k):
+    """The geometry's choice away from the cell's shape: descriptor
+    widths with a codebook of a thousand and of sixteen thousand centres
+    (one tile of the stats kernel, and four), and a dim that is not a
+    multiple of 8. 60 000 points; assignment against float64 wherever
+    the margin is beyond rounding, counts exact, sums float32 sums. The
+    pass's time and its share of the MXU's bfloat16 peak are printed and
+    kept in ``chiprun_out/kmeans_wide_shapes.jsonl``: a reading, not an
+    assertion."""
+    import json
+    import os
+
+    n = 60_000
+    geom, pts, centers, _, a, sums, counts, ms = _wide_case(
+        dim, k, n, dim * k)
+    want, margin, _ = _float64_nearest(pts, centers)
+    scale = (centers.astype(np.float64) ** 2).sum(1).max() * 3
+    clear = margin > 2e-6 * scale
+    assert clear.mean() > 0.99, clear.mean()
+    np.testing.assert_array_equal(a[clear], want[clear])
+    _check_wide_stats(pts, a, k, sums, counts)
+    line = {"dim": dim, "k": k, "n": n, "ms_a_pass": ms,
+            "mxu_share_pct": 2.0 * n * k * dim / (ms / 1e3) / 197e12 * 100,
+            "stats_tile": geom.stats_tile, "centre_tile": geom.centre_tile}
+    print(f"[wide shapes] {json.dumps(line)}")
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/kmeans_wide_shapes.jsonl", "a") as f:
+        f.write(json.dumps(line) + "\n")

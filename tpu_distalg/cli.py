@@ -215,6 +215,22 @@ def _add_ckpt(p, every_default):
     _add_telemetry(p)
 
 
+def _centers_line(centers, whole: int = 4096) -> str:
+    """What ``tda kmeans`` prints of its centres: all of them up to
+    ``whole`` numbers, else their shape and a summary (a codebook of
+    4096 x 784 is 3.2M numbers)."""
+    import numpy as np
+
+    c = np.asarray(centers)
+    if c.size <= whole:
+        return f"Final centers: {c.tolist()}"
+    norms = np.linalg.norm(c, axis=1)
+    return (f"Final centers: {c.shape[0]} x {c.shape[1]} "
+            f"{c.dtype} (not printed: {c.size} numbers); norm min "
+            f"{norms.min():.6g} mean {norms.mean():.6g} max "
+            f"{norms.max():.6g}; first {c[0, :4].tolist()} ...")
+
+
 def _report_optimizer(name, res, args, t):
     from tpu_distalg.utils import metrics
 
@@ -303,6 +319,11 @@ def main(argv=None):
                         "--n-points)")
     p.add_argument("--dim", type=int, default=16,
                    help="point dimension for --scale-points")
+    p.add_argument("--generating-clusters", type=int, default=0,
+                   help="components of the --scale-points mixture "
+                        "(0 = as many as --k; HiBench draws 5 and "
+                        "fits 10, a codebook fits thousands round a "
+                        "few)")
     p.add_argument("--plot", type=str, default=None,
                    help="save a cluster scatter PNG (2-D data)")
     _add_data_backend(p, block_rows=2048)
@@ -1379,7 +1400,8 @@ def _dispatch(args, jax):
             return 0
         if args.scale_points:
             make_rows, _ = datasets.gaussian_mixture_rows(
-                k=args.k, dim=args.dim, seed=0)
+                k=args.generating_clusters or args.k, dim=args.dim,
+                seed=0)
 
             def run_once():
                 return m.fit_scaled(
@@ -1407,7 +1429,7 @@ def _dispatch(args, jax):
 
         res = ckpt.run_with_restarts(
             run_once, max_restarts=args.max_restarts)
-        print(f"Final centers: {res.centers.tolist()}")
+        print(_centers_line(res.centers))
         print(f"iterations run: {res.n_iterations_run}")
         if args.plot and pts is None:
             print("--plot ignored with --scale-points (points stay "

@@ -14,6 +14,7 @@ fixed iterations for parity and offer a real convergence check behind
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -76,7 +77,12 @@ def _mesh_fns(mesh: Mesh, lanes):
     points (validity follows from the id), one ``pallas_lloyd`` kernel
     a pass (the per-cluster sums on the MXU or the VPU as
     ``pallas_lloyd.sums_form`` says for the geometry); counts are
-    int32. ``lanes`` is a ``pallas_lloyd.LanesGeometry``."""
+    int32. Wide layout (k * dim past the lanes kernel's reach):
+    ``data`` ``(n_blocks, dim_held, P)``, ``valid`` the count again,
+    two ``pallas_lloyd_wide`` kernels a pass (the distance product on
+    the MXU at float32 accuracy under ``assign``, one-hot sums under
+    ``stats``); counts int32. ``lanes`` is the geometry
+    :func:`scale_geometry` gave."""
     if lanes is None:
         both = data_parallel(
             _local_stats, mesh,
@@ -86,17 +92,45 @@ def _mesh_fns(mesh: Mesh, lanes):
         return (lambda p, m, c: both(p, m, c)[:2],
                 lambda p, m, c: both(p, m, c)[2])
 
+    interpret = not mesh_on_tpu(mesh)
+
+    def mine(x, n_valid):
+        """How many of this shard's points are valid."""
+        n_local = x.shape[0] * lanes.block_points
+        first = jax.lax.axis_index("data") * n_local
+        return jnp.clip(n_valid - first, 0, n_local)
+
+    if layout_of(lanes) == "wide":
+        from tpu_distalg.ops import pallas_lloyd_wide as wide
+
+        x_spec = P("data", None, None)
+
+        def nearest(x3, centers):
+            with jax.named_scope(names.KMEANS_ASSIGN):
+                return wide.wide_assign(x3, centers, geom=lanes,
+                                        interpret=interpret)
+
+        def wide_stats(x3, n_valid, centers):
+            assign = nearest(x3, centers)
+            with jax.named_scope(names.KMEANS_STATS):
+                stats = wide.wide_stats(
+                    x3, assign, mine(x3, n_valid), geom=lanes,
+                    interpret=interpret)
+            with jax.named_scope(names.KMEANS_SYNC):
+                return tree_allreduce_sum(stats)
+
+        return (data_parallel(wide_stats, mesh,
+                              in_specs=(x_spec, P(), P()),
+                              out_specs=(P(), P())),
+                data_parallel(
+                    lambda x3, n_valid, c: nearest(x3, c).reshape(-1),
+                    mesh, in_specs=(x_spec, P(), P()),
+                    out_specs=P("data")))
+
     # Pallas costs a second to import: only where a kernel is built
     from tpu_distalg.ops import pallas_lloyd as lloyd
 
-    interpret = not mesh_on_tpu(mesh)
     x_spec = P("data", None, None, None)
-
-    def mine(x4, n_valid):
-        """How many of this shard's points are valid."""
-        n_local = x4.shape[0] * lanes.block_points
-        first = jax.lax.axis_index("data") * n_local
-        return jnp.clip(n_valid - first, 0, n_local)
 
     def local_stats(x4, n_valid, centers):
         with jax.named_scope(names.KMEANS_ASSIGN):
@@ -119,14 +153,42 @@ def _mesh_fns(mesh: Mesh, lanes):
                           out_specs=P("data")))
 
 
-def _span_fields(k: int, lanes) -> dict:
-    """What the scale path's spans say beyond their sizes: where the
-    lanes kernel adds up the per-cluster sums at this geometry."""
-    if lanes is None:
-        return {}
+def scale_geometry(dim: int, k: int):
+    """The resident layout of the scale path, from ``(dim, k)`` alone:
+    the lanes kernel's where it covers the shape (k * dim up to 1024:
+    scores unrolled on the VPU), else the wide pass's (the distance
+    product on the MXU), else ``None``: plain rows."""
     from tpu_distalg.ops import pallas_lloyd as lloyd
 
-    return {"sums_form": lloyd.sums_form(k, lanes.dim)}
+    lanes = lloyd.lanes_geometry(dim, k)
+    if lanes is not None:
+        return lanes
+    from tpu_distalg.ops import pallas_lloyd_wide as wide
+
+    return wide.wide_geometry(dim, k)
+
+
+def layout_of(geometry) -> str:
+    """``rows``, ``lanes`` or ``wide``: what the spans call a layout."""
+    if geometry is None:
+        return "rows"
+    return getattr(geometry, "layout", "lanes")
+
+
+def _span_fields(k: int, lanes) -> dict:
+    """What the scale path's spans say beyond their sizes: which layout
+    took the table, where a pass scores the distances and where it adds
+    up the per-cluster sums at this geometry."""
+    layout = layout_of(lanes)
+    if layout == "rows":
+        return {"layout": layout}
+    if layout == "wide":
+        return {"layout": layout, "dist_form": lanes.dist_form,
+                "sums_form": "mxu"}
+    from tpu_distalg.ops import pallas_lloyd as lloyd
+
+    return {"layout": layout, "dist_form": "vpu",
+            "sums_form": lloyd.sums_form(k, lanes.dim)}
 
 
 def _counts0(k: int, lanes) -> jax.Array:
@@ -257,15 +319,29 @@ def init_centers_farthest(make_rows, n_rows: int, k: int, seed: int,
     rng = np.random.default_rng(seed)
     m = oversample * k
     ids = rng.integers(0, n_rows, size=m, dtype=np.int64)
-    cand = np.asarray(_rows_of(make_rows, ids, data_seed),
-                      np.float32)                           # (m, dim)
-    chosen = [int(rng.integers(0, m))]
-    d = np.linalg.norm(cand - cand[chosen[0]], axis=1)
-    while len(chosen) < k:
-        nxt = int(d.argmax())
-        chosen.append(nxt)
-        d = np.minimum(d, np.linalg.norm(cand - cand[nxt], axis=1))
-    return jnp.asarray(cand[chosen])
+    cand = jnp.asarray(_rows_of(make_rows, ids, data_seed),
+                       jnp.float32)                         # (m, dim)
+    # on the device: k passes over the candidates (131 072 x 784 at
+    # k = 4096: 20 minutes of NumPy on the host, seconds here)
+    return _farthest(cand, jnp.int32(rng.integers(0, m)), k)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _farthest(cand, first, k: int):
+    """``k`` of the candidates, greedily: each next one the farthest
+    from those chosen so far."""
+    def dist(i):
+        return jnp.sum((cand - cand[i]) ** 2, axis=1)
+
+    def pick(j, state):
+        chosen, d = state
+        nxt = jnp.argmax(d).astype(jnp.int32)
+        return chosen.at[j].set(nxt), jnp.minimum(d, dist(nxt))
+
+    chosen, _ = jax.lax.fori_loop(
+        1, k, pick,
+        (jnp.zeros((k,), jnp.int32).at[0].set(first), dist(first)))
+    return cand[chosen]
 
 
 def make_fit_seg_fn(mesh: Mesh, config: KMeansConfig, seg: int,
@@ -491,30 +567,38 @@ def build_scaled(mesh: Mesh, n_rows: int, make_rows, k: int, *,
     """The scale path's resident table: ``(points, valid, lanes)`` as
     :func:`make_fit_fn` and :func:`make_fit_seg_fn` take them.
 
-    The layout follows from what can be seen: where the lanes kernel
-    covers the geometry (``pallas_lloyd.lanes_geometry``: k * dim up to
-    1024) the points are drawn block by block into its feature-major
-    layout, 4 * dim bytes a point and no mask; else into plain rows,
-    chunk by chunk, with their mask, for ``ops/kmeans.py``. On one v5e
-    at 100M x 20, k = 10 the lanes path holds 8.0 GB and takes 13.5 ms
-    an iteration (16.9 before its sums took the MXU), the row path
-    10.0 GB and 36.9 ms (PERF.md §6, PR 26, PR 29).
+    The layout follows from what can be seen (:func:`scale_geometry`):
+    where the lanes kernel covers the geometry (k * dim up to 1024) the
+    points are drawn block by block into its feature-major layout,
+    4 * dim bytes a point and no mask; past it into the wide pass's
+    blocks (``pallas_lloyd_wide``: features down a block's rows, 4 * dim
+    bytes a point where dim is a multiple of 16, no mask); plain rows,
+    chunk by chunk, with their mask, for ``ops/kmeans.py`` only where
+    neither takes the shape. On one v5e at 100M x 20, k = 10 the lanes
+    path holds 8.0 GB and takes 13.5 ms an iteration (16.9 before its
+    sums took the MXU), the row path 10.0 GB and 36.9 ms (PERF.md §6,
+    PR 26, PR 29); at 2 025 000 x 784, k = 4096 the row path cannot
+    compile (18.7 GB of a 15.75 GB chip: a relaid and a masked copy of
+    the table) and the wide one holds 6.35 GB (PR 30).
 
-    The lanes table has to be finite, padding included (the kernel's
-    matmul multiplies every point by every cluster's 0 or 1, and 0 x
-    NaN is NaN): one read of the table checks it, and a ``make_rows``
-    that yields a NaN or an infinity raises ``ValueError`` here."""
-    from tpu_distalg.ops import pallas_lloyd as lloyd
+    A lanes or wide table has to be finite, padding included (the
+    kernels' matmuls multiply every point by every cluster's 0 or 1,
+    and 0 x NaN is NaN): one read of the table checks it, and a
+    ``make_rows`` that yields a NaN or an infinity raises ``ValueError``
+    here."""
     from tpu_distalg.parallel import build_sharded
 
-    dim = jax.eval_shape(
-        make_rows, jax.ShapeDtypeStruct((1,), jnp.int32)).shape[1]
-    lanes = lloyd.lanes_geometry(dim, k)
+    args = (jax.ShapeDtypeStruct((1,), jnp.int32),)
+    if data_seed is not None:
+        args += (jax.ShapeDtypeStruct((), jnp.int32),)
+    dim = jax.eval_shape(make_rows, *args).shape[1]
+    lanes = scale_geometry(dim, k)
+    layout = layout_of(lanes)
     chunk = (1 << 16) if lanes is None else lanes.block_points
     per = chunk * mesh.shape["data"]
+    held = lanes.dim_held if layout == "wide" else dim
     with tevents.span("kmeans:prepare", rows=n_rows,
-                      bytes=-(-n_rows // per) * per * dim * 4,
-                      layout="rows" if lanes is None else "lanes",
+                      bytes=-(-n_rows // per) * per * held * 4,
                       **_span_fields(k, lanes)):
         ps = build_sharded(
             mesh, n_rows, make_rows, seed=data_seed, chunk_rows=chunk,
@@ -522,7 +606,7 @@ def build_scaled(mesh: Mesh, n_rows: int, make_rows, k: int, *,
         if lanes is not None and not bool(_all_finite(ps.data)):
             raise ValueError(
                 "build_scaled: make_rows gave a NaN or an infinity; the "
-                "lanes table has to be finite, padding rows included")
+                f"{layout} table has to be finite, padding rows included")
         jax.block_until_ready(ps.data)
     valid = ps.mask if lanes is None else jnp.int32(n_rows)
     return ps.data, valid, lanes
@@ -545,7 +629,9 @@ def fit_scaled(mesh: Mesh, n_rows: int, make_rows,
     the compiled generator, not a constant in it."""
     data, valid, lanes = build_scaled(
         mesh, n_rows, make_rows, config.k, data_seed=data_seed)
-    centers0 = init_centers_scaled(make_rows, n_rows, config, data_seed)
+    with tevents.span("kmeans:init", init=config.init, k=config.k):
+        centers0 = jax.block_until_ready(
+            init_centers_scaled(make_rows, n_rows, config, data_seed))
     if checkpoint_dir is not None:
         return _fit_segmented(data, valid, mesh, config, centers0,
                               checkpoint_dir, checkpoint_every, lanes)

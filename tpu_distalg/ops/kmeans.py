@@ -16,13 +16,14 @@ import jax.numpy as jnp
 def assign_clusters(points: jax.Array, centers: jax.Array) -> jax.Array:
     """Index of the nearest center per point (squared-Euclidean argmin;
     first-minimum tie-break matches the reference's strict ``<`` scan)."""
-    # (n, k) distance matrix via the expansion trick — one MXU matmul.
-    d2 = (
-        jnp.sum(points * points, axis=1, keepdims=True)
-        - 2.0 * points @ centers.T
-        + jnp.sum(centers * centers, axis=1)[None, :]
-    )
-    return jnp.argmin(d2, axis=1)
+    # (n, k) scores |c|^2 - 2 x.c: the |x|^2 every centre shares cannot
+    # change an argmin. The product is pinned to float32 accuracy: a
+    # TPU's default rounds both operands to bfloat16, and a point
+    # between two near centres then changes sides (the pin
+    # cluster_stats and ops/linalg.py need too).
+    score = jnp.sum(centers * centers, axis=1)[None, :] - 2.0 * jnp.dot(
+        points, centers.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.argmin(score, axis=1)
 
 
 def cluster_stats(

@@ -22,9 +22,13 @@ PAGERANK_SPMV = "tda.pagerank.spmv"
 # the parts of a Lloyd iteration (models/kmeans.py)
 KMEANS_ASSIGN = "tda.kmeans.assign"  # distances and argmin; on the lanes
 #                                      layout the one kernel that also
-#                                      accumulates the partial sums
+#                                      accumulates the partial sums; on
+#                                      the wide one the assign kernel
+#                                      and the split of the centres
 KMEANS_STATS = "tda.kmeans.stats"    # one-hot sums and counts; on the
 #                                      lanes layout what is left outside
-#                                      the kernel: the partial sums' fold
+#                                      the kernel: the partial sums'
+#                                      fold; on the wide one the stats
+#                                      kernel and its transpose
 KMEANS_SYNC = "tda.kmeans.sync"      # the psum of (sums, counts)
 KMEANS_UPDATE = "tda.kmeans.update"  # new centres, the convergence shift

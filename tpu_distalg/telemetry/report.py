@@ -128,6 +128,7 @@ def summarize(evts: list[dict]) -> dict:
     runs: list[str] = []
     draw_forms: list[str] = []
     sums_forms: list[str] = []
+    dist_forms: list[str] = []
     t_wall = [e["t_wall"] for e in evts if "t_wall" in e]
     for e in evts:
         ev = e.get("ev")
@@ -147,6 +148,11 @@ def summarize(evts: list[dict]) -> dict:
             form = e.get("sums_form")
             if form and form not in sums_forms:
                 sums_forms.append(form)
+            # and how it scores the distances (vpu: the lanes kernel;
+            # mxu6: the wide pass's six bfloat16 passes)
+            form = e.get("dist_form")
+            if form and form not in dist_forms:
+                dist_forms.append(form)
         elif ev == "span_end":
             name = e.get("name", "?")
             open_spans[name] = open_spans.get(name, 1) - 1
@@ -215,6 +221,7 @@ def summarize(evts: list[dict]) -> dict:
         "span_tree": span_tree(evts),
         "draw_forms": draw_forms,
         "sums_forms": sums_forms,
+        "dist_forms": dist_forms,
         "unfinished_phases": sorted(
             k for k, v in open_spans.items() if v > 0),
         "marks": marks,
@@ -256,6 +263,8 @@ def render(s: dict) -> str:
         lines.append(f"  {name}: UNFINISHED (no span_end recorded)")
     if s.get("draw_forms"):
         lines.append(f"block draw: {', '.join(s['draw_forms'])}")
+    if s.get("dist_forms"):
+        lines.append(f"distances: {', '.join(s['dist_forms'])}")
     if s.get("sums_forms"):
         lines.append(f"cluster sums: {', '.join(s['sums_forms'])}")
     hb = s["last_heartbeat"]
