@@ -660,14 +660,15 @@ def stage_kmeans_kernel(s: Smoke):
 def stage_kmeans_wide(s: Smoke):
     """The k-means scale path on the wide layout, as ``tda kmeans
     --scale-points`` runs it (``kmeans.build_scaled`` picks the layout
-    from dim 64, k 256; ``make_fit_seg_fn`` runs both
+    from dim 64 and k; ``make_fit_seg_fn`` runs the
     ``ops/pallas_lloyd_wide`` kernels a shard and the psum of sums and
     counts over every chip the stage has) against ``ops/kmeans`` on the
-    gathered rows at ``highest`` precision. Both score to float32
-    accuracy in different forms, so a point whose two nearest centres
-    tie to within rounding may land on either (at most 0.1% of points);
-    the centres of clusters both sides count alike agree to 1e-4 of the
-    spread."""
+    gathered rows at ``highest`` precision, at k 256 (the per-cluster
+    sums as a one-hot product) and at k 2048 (as a scatter-add). Both
+    sides score to float32 accuracy in different forms, so a point
+    whose two nearest centres tie to within rounding may land on either
+    (at most 0.1% of points); the centres of clusters both sides count
+    alike agree to 1e-4 of the spread."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -676,39 +677,46 @@ def stage_kmeans_wide(s: Smoke):
     from tpu_distalg.ops import kmeans as kops
     from tpu_distalg.utils import datasets
 
-    dim, k, n = 64, 256, 40_077
+    dim, n = 64, 40_077
     make_rows, _ = datasets.gaussian_mixture_rows(k=10, dim=dim,
                                                   spread=8.0)
     mesh = s.mesh()
-    data, valid, geom = kmeans.build_scaled(mesh, n, make_rows, k,
-                                            data_seed=3)
-    if kmeans.layout_of(geom) != "wide":
-        raise AssertionError(f"layout {kmeans.layout_of(geom)}, not wide")
-    pts = np.asarray(geom.unpack(data))[:n]
-    c0 = pts[np.random.default_rng(0).choice(n, k, replace=False)]
-    centers, _, _, counts = kmeans.make_fit_seg_fn(
-        mesh, kmeans.KMeansConfig(k=k, n_iterations=1), 1, geom)(
-            data, valid, jnp.asarray(c0), jnp.float32(0), jnp.int32(0))
-    counts = np.asarray(counts)
-    p, c = jnp.asarray(pts), jnp.asarray(c0)
-    with jax.default_matmul_precision("highest"):
-        assign = kops.assign_clusters(p, c)
-    s_ref, c_ref = map(np.asarray, kops.cluster_stats(
-        p, jnp.ones((n,), jnp.float32), assign, k))
-    moved = int(np.abs(counts - c_ref).sum())
-    if int(counts.sum()) != n or moved > 0.001 * n:
-        raise AssertionError(
-            f"counts add up to {int(counts.sum())} of {n}, differ from "
-            f"the rows path's by {moved}")
-    same = (counts == c_ref) & (counts > 0)
-    want = s_ref[same] / c_ref[same][:, None]
-    err = float(np.abs(np.asarray(centers)[same] - want).max() / 8.0)
-    if err > 1e-4:
-        raise AssertionError(f"centres differ by {err:.3g} of the spread")
+    said = []
+    for k, form in ((256, "mxu"), (2048, "scatter")):
+        data, valid, geom = kmeans.build_scaled(mesh, n, make_rows, k,
+                                                data_seed=3)
+        took = (kmeans.layout_of(geom), getattr(geom, "sums_form", None))
+        if took != ("wide", form):
+            raise AssertionError(f"k {k}: {took}, not ('wide', {form!r})")
+        pts = np.asarray(geom.unpack(data))[:n]
+        c0 = pts[np.random.default_rng(0).choice(n, k, replace=False)]
+        centers, _, _, counts = kmeans.make_fit_seg_fn(
+            mesh, kmeans.KMeansConfig(k=k, n_iterations=1), 1, geom)(
+                data, valid, jnp.asarray(c0), jnp.float32(0),
+                jnp.int32(0))
+        counts = np.asarray(counts)
+        p, c = jnp.asarray(pts), jnp.asarray(c0)
+        with jax.default_matmul_precision("highest"):
+            assign = kops.assign_clusters(p, c)
+        s_ref, c_ref = map(np.asarray, kops.cluster_stats(
+            p, jnp.ones((n,), jnp.float32), assign, k))
+        moved = int(np.abs(counts - c_ref).sum())
+        if int(counts.sum()) != n or moved > 0.001 * n:
+            raise AssertionError(
+                f"k {k}: counts add up to {int(counts.sum())} of {n}, "
+                f"differ from the rows path's by {moved}")
+        same = (counts == c_ref) & (counts > 0)
+        want = s_ref[same] / c_ref[same][:, None]
+        err = float(np.abs(np.asarray(centers)[same] - want).max() / 8.0)
+        if err > 1e-4:
+            raise AssertionError(
+                f"k {k}: centres differ by {err:.3g} of the spread")
+        said.append(
+            f"k {k}, sums {form}: counts differ by {moved} of {n} points "
+            f"(<= 0.1%), {int(same.sum())} of {k} centres within "
+            f"{err:.2g} of the spread")
     return (f"dp={mesh.shape['data']} | wide blocks {tuple(data.shape)}, "
-            f"counts differ by {moved} of {n} points (<= 0.1%), "
-            f"{int(same.sum())} of {k} centres within {err:.2g} of the "
-            f"spread, distances {geom.dist_form}")
+            f"distances {geom.dist_form} | " + " | ".join(said))
 
 
 def _comm_stage(s: Smoke, comm: str):
@@ -746,7 +754,8 @@ STAGES = (
      dict(kernels=("pallas_lloyd._lloyd_kernel",))),
     ("kmeans_wide", stage_kmeans_wide,
      dict(kernels=("pallas_lloyd_wide._wide_assign_kernel",
-                   "pallas_lloyd_wide._wide_stats_kernel"))),
+                   "pallas_lloyd_wide._wide_stats_kernel",
+                   "pallas_lloyd_wide._wide_scatter_kernel"))),
     ("ssgd_comm_int8", functools.partial(_comm_stage, comm="int8"),
      dict(min_devices=2)),
     ("ssgd_comm_bucketed",

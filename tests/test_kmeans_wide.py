@@ -7,9 +7,13 @@ wide shape: k 96, dim 49 (k x dim past the lanes kernel's 1024, dim not
 a multiple of 8, so a block holds 64 feature rows for 49), 10 generating
 clusters, blocks of 512 points.
 
-On the CPU both kernels run interpreted, with the bfloat16
-``dot_general``s they ship with; the chip compiles them (``tests_tpu``,
-``benchmarks/tools/compile_check_kmeans_wide.py``).
+On the CPU the kernels run interpreted, with the bfloat16
+``dot_general``s and the loop over the points they ship with; the chip
+compiles them (``tests_tpu``,
+``benchmarks/tools/compile_check_kmeans_wide.py``). The per-cluster sums
+take one of two forms (``wide.sums_form(k, dim)``): k 96 is a one-hot
+product's shape, ``SCAT`` (k 2048 at the same 49 dimensions) a
+scatter-add's.
 """
 
 import os
@@ -35,7 +39,11 @@ from reference import kmeans_ref, kmeans_wide_ref  # noqa: E402
 
 DIM, K, GEN, SPREAD = 49, 96, 10, 8.0
 GEOM = wide.wide_geometry(DIM, K)
+SCAT = wide.wide_geometry(DIM, 2048)      # past the one-hot's reach
+GEOMS = {"mxu": GEOM, "scatter": SCAT}
+FORMS = {"mxu": wide.onehot_stats, "scatter": wide.scatter_stats}
 P = GEOM.block_points
+U = 6e-8                                  # float32's unit roundoff
 
 
 def make_rows(ids, seed):
@@ -148,27 +156,58 @@ def test_a_bfloat16_product_is_caught(mesh1, monkeypatch, pieces):
         assert err > SAME, (err, moved)
 
 
-@pytest.mark.parametrize("k,dim,layout", [
-    (10, 20, "lanes"), (32, 32, "lanes"), (1024, 1, "lanes"),
-    (25, 41, "wide"), (1025, 1, "wide"), (96, 49, "wide"),
-    (4096, 784, "wide"), (16384, 128, "wide"), (2, 5000, "rows")])
-def test_layout_is_a_function_of_k_and_dim(k, dim, layout):
-    """1024 and 1025 fall on the two sides; nothing else is asked."""
+@pytest.mark.parametrize("k,dim,layout,sums", [
+    (10, 20, "lanes", "mxu"), (32, 32, "lanes", "mxu"),
+    (1024, 1, "lanes", "mxu"),
+    (25, 41, "wide", "mxu"), (1025, 1, "wide", "mxu"),
+    (96, 49, "wide", "mxu"), (2048, 49, "wide", "scatter"),
+    (4096, 784, "wide", "scatter"), (16384, 128, "wide", "scatter"),
+    (2, 5000, "rows", None)])
+def test_layout_is_a_function_of_k_and_dim(k, dim, layout, sums):
+    """1024 and 1025 fall on the two sides; nothing else is asked. The
+    spans say how the layout's pass adds up the per-cluster sums."""
     geom = kmeans.scale_geometry(dim, k)
     assert kmeans.layout_of(geom) == layout
     assert (lloyd.lanes_geometry(dim, k) is None) == (layout != "lanes")
+    assert kmeans._span_fields(k, geom).get("sums_form") == sums
     if layout == "wide":
         assert geom == wide.wide_geometry(dim, k)
         assert geom.k_padded % geom.stats_tile == 0
         assert geom.stats_tile % geom.centre_tile == 0
         assert geom.dim_held % 16 == 0 and geom.dim_mxu % 128 == 0
         assert kmeans._span_fields(k, geom) == {
-            "layout": "wide", "dist_form": "mxu6", "sums_form": "mxu"}
+            "layout": "wide", "dist_form": "mxu6",
+            "sums_form": wide.sums_form(k, dim)}
     if (k, dim) == (4096, 784):
-        # the published widths: 4 x 784 bytes a point, nothing padded
+        # the published widths: 4 x 784 bytes a point, nothing padded;
+        # one accumulator of the scatter holds every centre
         assert (geom.dim_held, geom.point_bytes, geom.block_points,
-                geom.centre_tile, geom.stats_tile, geom.k_padded) == (
-                    784, 3136, 512, 512, 4096, 4096)
+                geom.centre_tile, geom.stats_tile, geom.k_padded,
+                geom.scatter_tile) == (
+                    784, 3136, 512, 512, 4096, 4096, 4096)
+
+
+@pytest.mark.parametrize("dim,k,form", [
+    # the shapes tests_tpu compiles
+    (784, 4096, "scatter"), (96, 1024, "mxu"), (128, 1024, "scatter"),
+    (96, 16384, "scatter"), (128, 16384, "scatter"), (49, 96, "mxu"),
+    # either side of the crossing read on the chip (PERF.md §6, PR 31)
+    (784, 308, "mxu"), (784, 309, "scatter"),
+    (128, 927, "mxu"), (128, 928, "scatter"),
+    (1024, 255, "mxu"), (1024, 256, "scatter")])
+def test_sums_form_is_a_function_of_k_and_dim(dim, k, form):
+    """A one-hot product costs k x dim a point, a scatter-add its loop
+    and its transpose: k * dim_held against ``SCATTER_LOOP`` +
+    ``SCATTER_LANE`` * dim_mxu, nothing else."""
+    assert wide.sums_form(k, dim) == form
+    geom = wide.wide_geometry(dim, k)
+    assert geom.sums_form == form
+    held, deep = geom.dim_held, geom.dim_mxu
+    assert (form == "scatter") == (
+        k * held >= wide.SCATTER_LOOP + wide.SCATTER_LANE * deep)
+    # an accumulator and its dump rows stay under the budget
+    assert (geom.scatter_tile + 8) * deep * 4 <= wide.ACC_BYTES
+    assert geom.scatter_tile % 8 == 0
 
 
 def test_table_is_the_generators_rows_in_id_order(mesh4):
@@ -223,19 +262,22 @@ def test_an_empty_cluster_keeps_its_centre(mesh1):
     assert int(np.asarray(counts).sum()) == n
 
 
+@pytest.mark.parametrize("form", ["mxu", "scatter"])
 @pytest.mark.parametrize("n", [2 * P, 2 * P - 300, P + 1, 257])
-def test_padding_and_a_ragged_last_block_are_not_counted(mesh1, n):
+def test_padding_and_a_ragged_last_block_are_not_counted(mesh1, n, form):
     """Validity follows from the id, in whole blocks, in a block that
     ends inside a chunk of the stats kernel and in one that holds a
     single valid point: the counts are those of the valid points'
-    assignment and the sums are theirs alone."""
+    assignment and the sums are theirs alone, in either form of the
+    sums (the scatter sends padding to a row nobody reads)."""
     pts = _rows(2 * P, 6)
     c0 = pts[:K]
     x3, _ = _table(mesh1, 2 * P, 6)              # every row finite
     assign = wide.wide_assign(x3, jnp.asarray(c0), geom=GEOM,
                               interpret=True)
-    sums, counts = wide.wide_stats(x3, assign, n, geom=GEOM,
-                                   interpret=True)
+    sums, counts = jax.jit(
+        lambda x, a: FORMS[form](x, a, n, geom=GEOM, interpret=True))(
+            x3, assign)
     a = np.asarray(assign).reshape(-1)
     np.testing.assert_array_equal(
         np.asarray(counts), np.bincount(a[:n], minlength=K))
@@ -245,30 +287,138 @@ def test_padding_and_a_ragged_last_block_are_not_counted(mesh1, n):
     np.testing.assert_allclose(np.asarray(sums), want, rtol=0, atol=2e-4)
 
 
-def test_shards_agree_with_one_device(mesh1, mesh4):
+def _case(dim, k, n_blocks, seed, ids=None):
+    """Normal points in the wide geometry's blocks and ids for them."""
+    rng = np.random.default_rng(seed)
+    geom = wide.wide_geometry(dim, k)
+    x = np.zeros((n_blocks, geom.dim_held, P), np.float32)
+    x[:, :dim] = rng.standard_normal((n_blocks, dim, P))
+    if ids is None:
+        ids = rng.integers(0, k, (n_blocks, 1, P))
+    ids = np.broadcast_to(ids, (n_blocks, 1, P)).astype(np.int32)
+    return geom, x, ids
+
+
+def _expected(geom, x, ids, n):
+    """float64 sums, exact counts, and what a straight float32 sum of a
+    cluster's m points may be off by: m x 6e-8 of the sum of
+    magnitudes, coordinate by coordinate."""
+    rows = x[:, :geom.dim].transpose(0, 2, 1).reshape(-1, geom.dim)[:n]
+    a = ids.reshape(-1)[:n]
+    want = np.zeros((geom.k, geom.dim))
+    mags = np.zeros((geom.k, geom.dim))
+    np.add.at(want, a, rows.astype(np.float64))
+    np.add.at(mags, a, np.abs(rows).astype(np.float64))
+    counts = np.bincount(a, minlength=geom.k)
+    return want, counts, counts[:, None] * U * mags
+
+
+def _run(form, geom, x, ids, n, **kw):
+    sums, counts = jax.jit(lambda x, a: FORMS[form](
+        x, a, n, geom=geom, interpret=True, **kw))(
+            jnp.asarray(x), jnp.asarray(ids))
+    return np.asarray(sums), np.asarray(counts)
+
+
+@pytest.mark.parametrize("dim,k", [(64, 4096), (49, 2048), (128, 1024),
+                                   (200, 704)])
+def test_scatter_sums_against_float64_and_the_onehot(dim, k):
+    """The scatter form on shapes that take it, a ragged end: the sums
+    within a straight float32 sum's bound of ``np.add.at`` in float64
+    and of the one-hot form at the same ids, the counts equal."""
+    geom, x, ids = _case(dim, k, 3, dim + k)
+    assert geom.sums_form == "scatter"
+    n = 3 * P - 211
+    want, want_counts, tol = _expected(geom, x, ids, n)
+    sums, counts = _run("scatter", geom, x, ids, n)
+    hot, hot_counts = _run("mxu", geom, x, ids, n)
+    np.testing.assert_array_equal(counts, want_counts)
+    np.testing.assert_array_equal(counts, hot_counts)
+    assert counts.dtype == np.int32 and int(counts.sum()) == n
+    assert (np.abs(sums - want) <= tol + 1e-30).all()
+    assert (np.abs(sums - hot) <= 2 * tol + 1e-30).all()
+    assert not sums[want_counts == 0].any()      # an empty cluster: zeros
+
+
+def test_scatter_with_every_point_in_one_cluster():
+    """The longest chain of loads and stores to one row: 2048 points
+    into centre 5, the sum within m x 6e-8 of the sum of magnitudes,
+    every other cluster empty."""
+    geom, x, ids = _case(49, 2048, 4, 1, ids=5)
+    n = 4 * P
+    want, want_counts, tol = _expected(geom, x, ids, n)
+    sums, counts = _run("scatter", geom, x, ids, n)
+    assert int(counts[5]) == n and int(counts.sum()) == n
+    assert (np.abs(sums - want) <= tol + 1e-30).all()
+    assert not np.delete(sums, 5, axis=0).any()
+
+
+def test_scatter_repeats_bit_for_bit():
+    geom, x, ids = _case(49, 2048, 3, 2)
+    first = _run("scatter", geom, x, ids, 3 * P - 9)
+    again = _run("scatter", geom, x, ids, 3 * P - 9)
+    np.testing.assert_array_equal(first[0].view(np.uint32),
+                                  again[0].view(np.uint32))
+    np.testing.assert_array_equal(first[1], again[1])
+
+
+@pytest.mark.parametrize("tile", [1024, 696])
+def test_scatter_tiles_the_centres_past_its_budget(tile):
+    """Centres tiled (as past ``ACC_BYTES``), the last tile ragged: a
+    point of another tile goes to the dump row with the padding, and
+    the tiles together are the one-tile pass bit for bit."""
+    geom, x, ids = _case(49, 2048, 2, 3)
+    n = 2 * P - 100
+    whole = _run("scatter", geom, x, ids, n)
+    tiled = _run("scatter", geom, x, ids, n, tile=tile)
+    np.testing.assert_array_equal(whole[0].view(np.uint32),
+                                  tiled[0].view(np.uint32))
+    np.testing.assert_array_equal(whole[1], tiled[1])
+    assert int(tiled[1].sum()) == n
+
+
+def test_an_empty_cluster_keeps_its_centre_under_the_scatter(mesh1):
+    n = 3000
+    c0 = _rows(n, 2)[:SCAT.k].copy()
+    c0[7] = 1e3                                  # nobody's nearest
+    centers, _, _, counts = _seg(mesh1, 2, SCAT)(
+        *_table(mesh1, n, 2, SCAT), *_start(c0))
+    assert int(np.asarray(counts)[7]) == 0
+    np.testing.assert_array_equal(np.asarray(centers)[7], c0[7])
+    assert int(np.asarray(counts).sum()) == n
+
+
+@pytest.mark.parametrize("form", ["mxu", "scatter"])
+def test_shards_agree_with_one_device(mesh1, mesh4, form):
     """Four shards, the last one holding the ragged end and one holding
     only padding, give the counts one device gives and the same centres
-    to float32 summation order."""
+    to float32 summation order, in either form of the sums."""
     n = 2 * P + 77
-    c0 = _rows(n, 5)[:K]
-    one = _seg(mesh1, 3)(*_table(mesh1, n, 5), *_start(c0))
-    four = _seg(mesh4, 3)(*_table(mesh4, n, 5), *_start(c0))
+    geom = GEOMS[form]
+    c0 = _rows(max(n, geom.k), 5)[:geom.k]
+    one = _seg(mesh1, 3, geom)(*_table(mesh1, n, 5, geom), *_start(c0))
+    four = _seg(mesh4, 3, geom)(*_table(mesh4, n, 5, geom), *_start(c0))
     np.testing.assert_array_equal(np.asarray(one[3]), np.asarray(four[3]))
     np.testing.assert_allclose(np.asarray(one[0]), np.asarray(four[0]),
                                rtol=0, atol=1e-4)
 
 
-def test_segments_chain_to_the_straight_run_bitwise(mesh1):
+@pytest.mark.parametrize("form", ["mxu", "scatter"])
+def test_segments_chain_to_the_straight_run_bitwise(mesh1, form):
+    """The order of the adds is fixed by the ids in either form of the
+    sums: a pass repeats bit for bit."""
     n = 3011
-    x3, valid = _table(mesh1, n, 9)
-    c0 = _rows(n, 9)[100:100 + K]
+    geom = GEOMS[form]
+    k = geom.k
+    x3, valid = _table(mesh1, n, 9, geom)
+    c0 = _rows(n, 9)[100:100 + k]
     state = _start(c0)
-    two = _seg(mesh1, 2)
+    two = _seg(mesh1, 2, geom)
     for _ in range(3):
         *state, counts = two(x3, valid, *state)
-    whole = _seg(mesh1, 6)(x3, valid, *_start(c0))
+    whole = _seg(mesh1, 6, geom)(x3, valid, *_start(c0))
     straight = kmeans.make_fit_fn(
-        mesh1, kmeans.KMeansConfig(k=K, n_iterations=6), GEOM)(
+        mesh1, kmeans.KMeansConfig(k=k, n_iterations=6), geom)(
             x3, valid, jnp.asarray(c0))
     assert int(state[2]) == int(whole[2]) == int(straight[2]) == 6
     np.testing.assert_array_equal(np.asarray(state[0]),
@@ -279,7 +429,7 @@ def test_segments_chain_to_the_straight_run_bitwise(mesh1):
                                   np.asarray(whole[3]))
     np.testing.assert_array_equal(
         np.asarray(straight[1])[:n],
-        np.asarray(jax.jit(kmeans._mesh_fns(mesh1, GEOM)[1])(
+        np.asarray(jax.jit(kmeans._mesh_fns(mesh1, geom)[1])(
             x3, valid, whole[0]))[:n])
 
 
@@ -327,19 +477,47 @@ def _dots(fn, *args):
     return found
 
 
+def _dtypes(fn, *args):
+    """Every dtype a value takes under ``fn``, kernels' bodies
+    included."""
+    found = set()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            found.update(str(v.aval.dtype) for v in eqn.outvars
+                         if hasattr(v.aval, "dtype"))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("form", ["mxu", "scatter"])
 @pytest.mark.parametrize("interpret", [True, False])
-def test_the_interpreted_pass_uses_the_products_that_ship(interpret):
+def test_the_interpreted_pass_uses_the_products_that_ship(interpret, form):
     """Interpreted or compiled, the assign kernel is six bfloat16
-    products accumulated in float32 and the stats kernel three; no
-    float32 ``dot_general`` stands in for them on the CPU."""
-    x3 = jnp.zeros((2, GEOM.dim_held, P), jnp.float32)
-    c = jnp.zeros((K, DIM), jnp.float32)
+    products accumulated in float32 and the one-hot stats kernel three;
+    no float32 ``dot_general`` stands in for them on the CPU. The
+    scatter has no product at all and nothing in bfloat16: float32 adds
+    of the points as they are held."""
+    geom = GEOMS[form]
+    assert geom.sums_form == form
+    x3 = jnp.zeros((2, geom.dim_held, P), jnp.float32)
+    c = jnp.zeros((geom.k, DIM), jnp.float32)
     a = jnp.zeros((2, 1, P), jnp.int32)
     bf16 = (("bfloat16", "bfloat16"), "float32")
     assert _dots(lambda x, c: wide.wide_assign(
-        x, c, geom=GEOM, interpret=interpret), x3, c) == [bf16] * 6
-    assert _dots(lambda x, a: wide.wide_stats(
-        x, a, 7, geom=GEOM, interpret=interpret), x3, a) == [bf16] * 3
+        x, c, geom=geom, interpret=interpret), x3, c) == [bf16] * 6
+
+    def stats(x, a):
+        return wide.wide_stats(x, a, 7, geom=geom, interpret=interpret)
+
+    if form == "mxu":
+        assert _dots(stats, x3, a) == [bf16] * 3
+    else:
+        assert _dots(stats, x3, a) == []
+        assert _dtypes(stats, x3, a) <= {"float32", "int32", "bool"}
 
 
 def test_pieces_are_bfloat16_and_add_back_bit_for_bit():
@@ -358,24 +536,28 @@ def test_pieces_are_bfloat16_and_add_back_bit_for_bit():
             ).mean() > 0.9
 
 
-def test_fit_scaled_takes_the_wide_path_and_spans_it(mesh4, tmp_path):
+@pytest.mark.parametrize("form,init", [("mxu", "farthest"),
+                                       ("scatter", "sample")])
+def test_fit_scaled_takes_the_wide_path_and_spans_it(mesh4, tmp_path,
+                                                     form, init):
     """``fit_scaled`` (what ``tda kmeans --scale-points`` calls) picks
     the layout from the geometry, draws under ``kmeans:prepare`` and
-    says in its spans how a pass scores and sums; ``tda report`` prints
-    both."""
+    says in its spans how a pass scores and how it sums at this
+    geometry; ``tda report`` prints both."""
+    k = GEOMS[form].k
     tel = str(tmp_path / "tel")
     events.configure(tel)
     try:
         res = kmeans.fit_scaled(
             mesh4, 5000, make_rows,
-            kmeans.KMeansConfig(k=K, n_iterations=4, init="farthest"),
+            kmeans.KMeansConfig(k=k, n_iterations=4, init=init),
             checkpoint_dir=str(tmp_path / "ck"), checkpoint_every=2,
             data_seed=7)
     finally:
         events.configure(False)
-    assert res.n_iterations_run == 4 and res.centers.shape == (K, DIM)
+    assert res.n_iterations_run == 4 and res.centers.shape == (k, DIM)
     a = np.asarray(res.assignments)[:5000]
-    assert a.min() >= 0 and a.max() < K
+    assert a.min() >= 0 and a.max() < k
     ends = [e for e in report.load_events(tel) if e["ev"] == "span_end"]
     prep = [e for e in ends if e["name"] == "kmeans:prepare"]
     assert len(prep) == 1 and prep[0]["layout"] == "wide"
@@ -384,10 +566,10 @@ def test_fit_scaled_takes_the_wide_path_and_spans_it(mesh4, tmp_path):
     assert [(e["t0"], e["steps"]) for e in segs] == [(0, 2), (2, 2)]
     for e in prep + segs:
         assert (e["layout"], e["dist_form"], e["sums_form"]) == (
-            "wide", "mxu6", "mxu")
+            "wide", "mxu6", form)
     lines = report.render(
         report.summarize(report.load_events(tel))).splitlines()
-    assert "distances: mxu6" in lines and "cluster sums: mxu" in lines
+    assert "distances: mxu6" in lines and f"cluster sums: {form}" in lines
 
 
 @pytest.mark.parametrize("row,value", [(1005, np.nan), (7, np.inf)],

@@ -785,22 +785,37 @@ def _wide_case(dim, k, n, seed):
             np.asarray(sums), np.asarray(counts), ms)
 
 
-def _check_wide_stats(pts, a, k, sums, counts, tol=1e-6):
-    """Counts exact; every sum within ``tol`` of the sum of magnitudes
-    of the float64 sum (float32 summation error: a cluster's points
-    arrive 256 at a time, so a sum is hundreds of float32 additions of
-    partial sums; one addend rounded to bfloat16 is off by 4e-3 of
-    itself)."""
+def _check_wide_stats(pts, a, k, sums, counts, tol=None):
+    """Counts exact; every sum within what a straight float32 sum of a
+    cluster's m points may be off by, m x 6e-8 of the sum of magnitudes
+    of the float64 sum, whatever the form (the scatter adds a cluster's
+    points one by one into two accumulators; the one-hot's arrive 256 at
+    a time, so a sum is hundreds of float32 additions of partial sums;
+    one addend rounded to bfloat16 is off by 4e-3 of itself); and the
+    largest such error within ``tol``: 1e-6, or 6e-8 times the root of
+    the largest cluster's size where that is more (addends of one sign
+    drift by about that). On one v5e (PR 31) it read 8.57e-7 under the
+    scatter at 784 x 4096 (largest cluster 1271 points: the bare 1e-6
+    would hold with no room; the one-hot at the same ids 1.03e-6), 1.5e-7
+    to 7.5e-7 at the other scatter shapes (clusters of up to 7 to 882),
+    9.0e-7 and 7.7e-7 under the one-hot at (96, 1024) and (49, 96)
+    (898 and 3014)."""
+    if tol is None:
+        tol = max(1e-6, 6e-8 * float(np.sqrt(counts.max())))
     want = np.zeros((k, pts.shape[1]))
     mags = np.zeros((k, pts.shape[1]))
     np.add.at(want, a, pts.astype(np.float64))
     np.add.at(mags, a, np.abs(pts).astype(np.float64))
     assert counts.dtype == np.int32
     np.testing.assert_array_equal(counts, np.bincount(a, minlength=k))
-    worst = float((np.abs(sums - want) / (mags + 1e-30)).max())
+    err = np.abs(sums - want)
+    worst = float((err / (mags + 1e-30)).max())
     print(f"[wide sums] largest error over the sum of magnitudes "
-          f"{worst:.3g} (tolerance {tol:g})")
+          f"{worst:.3g} (tolerance {tol:g}), largest cluster "
+          f"{int(counts.max())} points")
     assert worst <= tol, worst
+    # (+ 2: the one-hot adds three products' sums a chunk)
+    assert (err <= (counts[:, None] + 2) * 6e-8 * mags + 1e-30).all()
 
 
 def test_kmeans_wide_pass_at_the_published_widths(monkeypatch):
@@ -821,7 +836,8 @@ def test_kmeans_wide_pass_at_the_published_widths(monkeypatch):
 
     dim, k, n = 784, 4096, 100_000
     geom, pts, centers, x3, a, sums, counts, _ = _wide_case(dim, k, n, 3)
-    assert (geom.dim_held, geom.block_points) == (784, 512)
+    assert (geom.dim_held, geom.block_points, geom.sums_form) == (
+        784, 512, "scatter")
     want, margin, least = _float64_nearest(pts, centers)
     scale = (centers.astype(np.float64) ** 2).sum(1).max() * 3
     bad = a != want
@@ -830,10 +846,17 @@ def test_kmeans_wide_pass_at_the_published_widths(monkeypatch):
           f"scale {scale:.4g}")
     assert int(bad.sum()) <= n // 1000
     assert margin[bad].max(initial=0.0) < 1e-6 * scale
-    # 391 chunks' partial sums a cluster at worst: 1e-5 where the
-    # smaller shapes hold 1e-6
-    _check_wide_stats(pts, a, k, sums, counts, tol=1e-5)
+    _check_wide_stats(pts, a, k, sums, counts)
     assert int(counts.sum()) == n
+    # the one-hot form at the same ids: the same counts, float32 sums
+    # too (391 chunks' partial sums a cluster at worst: 1e-5 where the
+    # smaller shapes hold 1e-6)
+    a3 = jnp.asarray(a, jnp.int32)
+    a3 = jnp.pad(a3, (0, x3.shape[0] * 512 - n)).reshape(-1, 1, 512)
+    hot, hot_counts = jax.jit(
+        lambda x, i: wide.onehot_stats(x, i, n, geom=geom))(x3, a3)
+    _check_wide_stats(pts, a, k, np.asarray(hot), np.asarray(hot_counts),
+                      tol=1e-5)
 
     real = wide.split3
     monkeypatch.setattr(wide, "split3", lambda x: real(x)[:1] + tuple(
@@ -852,16 +875,19 @@ def test_kmeans_wide_pass_at_the_published_widths(monkeypatch):
     assert wrong > 100, wrong
 
 
-@pytest.mark.parametrize("dim,k", [
-    (96, 1024), (128, 1024), (96, 16384), (128, 16384), (49, 96)])
-def test_kmeans_wide_pass_at_other_shapes(dim, k):
+@pytest.mark.parametrize("dim,k,form", [
+    (96, 1024, "mxu"), (128, 1024, "scatter"), (96, 16384, "scatter"),
+    (128, 16384, "scatter"), (49, 96, "mxu")])
+def test_kmeans_wide_pass_at_other_shapes(dim, k, form):
     """The geometry's choice away from the cell's shape: descriptor
     widths with a codebook of a thousand and of sixteen thousand centres
-    (one tile of the stats kernel, and four), and a dim that is not a
-    multiple of 8. 60 000 points; assignment against float64 wherever
-    the margin is beyond rounding, counts exact, sums float32 sums. The
-    pass's time and its share of the MXU's bfloat16 peak are printed and
-    kept in ``chiprun_out/kmeans_wide_shapes.jsonl``: a reading, not an
+    and a dim that is not a multiple of 8, each with the form of the
+    per-cluster sums that ``sums_form(k, dim)`` gives it (the one-hot
+    product where k x dim is small, the scatter-add elsewhere). 60 000
+    points; assignment against float64 wherever the margin is beyond
+    rounding, counts exact, sums float32 sums. The pass's time and its
+    share of the MXU's bfloat16 peak are printed and kept in
+    ``chiprun_out/kmeans_wide_shapes.jsonl``: a reading, not an
     assertion."""
     import json
     import os
@@ -869,6 +895,7 @@ def test_kmeans_wide_pass_at_other_shapes(dim, k):
     n = 60_000
     geom, pts, centers, _, a, sums, counts, ms = _wide_case(
         dim, k, n, dim * k)
+    assert geom.sums_form == form
     want, margin, _ = _float64_nearest(pts, centers)
     scale = (centers.astype(np.float64) ** 2).sum(1).max() * 3
     clear = margin > 2e-6 * scale
@@ -877,8 +904,32 @@ def test_kmeans_wide_pass_at_other_shapes(dim, k):
     _check_wide_stats(pts, a, k, sums, counts)
     line = {"dim": dim, "k": k, "n": n, "ms_a_pass": ms,
             "mxu_share_pct": 2.0 * n * k * dim / (ms / 1e3) / 197e12 * 100,
-            "stats_tile": geom.stats_tile, "centre_tile": geom.centre_tile}
+            "stats_tile": geom.stats_tile, "centre_tile": geom.centre_tile,
+            "sums_form": form}
     print(f"[wide shapes] {json.dumps(line)}")
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/kmeans_wide_shapes.jsonl", "a") as f:
         f.write(json.dumps(line) + "\n")
+
+
+def test_kmeans_wide_scatter_tiles_the_centres():
+    """Past ``ACC_BYTES`` an accumulator of the scatter holds a tile of
+    the centres (1024 dimensions, 16384 centres: five tiles of 4088),
+    compiled: 20 000 points under random ids against ``np.add.at``,
+    counts exact."""
+    from tpu_distalg.ops import pallas_lloyd_wide as wide
+
+    dim, k, n = 1024, 16384, 20_000
+    geom = wide.wide_geometry(dim, k)
+    assert geom.sums_form == "scatter" and geom.scatter_tile == 4088
+    p = geom.block_points
+    nb = -(-n // p)
+    rng = np.random.default_rng(5)
+    pts = rng.normal(size=(nb * p, dim)).astype(np.float32)
+    a = rng.integers(0, k, nb * p).astype(np.int32)
+    x3 = jax.vmap(geom.pack)(jnp.asarray(pts).reshape(nb, p, dim))
+    sums, counts = wide.wide_stats(
+        x3, jnp.asarray(a).reshape(nb, 1, p), n, geom=geom)
+    _check_wide_stats(pts[:n], a[:n], k, np.asarray(sums),
+                      np.asarray(counts))
+    assert int(np.asarray(counts).sum()) == n

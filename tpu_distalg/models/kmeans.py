@@ -80,8 +80,11 @@ def _mesh_fns(mesh: Mesh, lanes):
     int32. Wide layout (k * dim past the lanes kernel's reach):
     ``data`` ``(n_blocks, dim_held, P)``, ``valid`` the count again,
     two ``pallas_lloyd_wide`` kernels a pass (the distance product on
-    the MXU at float32 accuracy under ``assign``, one-hot sums under
-    ``stats``); counts int32. ``lanes`` is the geometry
+    the MXU at float32 accuracy under ``assign``; under ``stats`` the
+    per-cluster sums as a scatter-add of each point into its centre's
+    row, or as a one-hot product where k x dim is small enough that the
+    product is the cheaper: ``pallas_lloyd_wide.sums_form``); counts
+    int32. ``lanes`` is the geometry
     :func:`scale_geometry` gave."""
     if lanes is None:
         both = data_parallel(
@@ -184,7 +187,7 @@ def _span_fields(k: int, lanes) -> dict:
         return {"layout": layout}
     if layout == "wide":
         return {"layout": layout, "dist_form": lanes.dist_form,
-                "sums_form": "mxu"}
+                "sums_form": lanes.sums_form}
     from tpu_distalg.ops import pallas_lloyd as lloyd
 
     return {"layout": layout, "dist_form": "vpu",

@@ -17,8 +17,8 @@ are held padded to 896 lanes), no mask: validity follows from the id,
 as on the lanes layout, and padding points hold any finite value. The
 table has to be finite, padding included (0 x NaN in both products).
 
-Two kernels a pass, both a flash-attention forward pass in shape, with
-a minimum where the softmax is:
+Two kernels a pass. The first is a flash-attention forward pass in
+shape, with a minimum where the softmax is:
 
 ``_wide_assign_kernel``, grid ``(point blocks, centre tiles)``:
 
@@ -46,8 +46,36 @@ a minimum where the softmax is:
                                  folds the 8 sublanes, smallest index
                                  among equals again
 
+The per-cluster sums and counts take one of two forms, chosen from
+``(k, dim)`` alone (:func:`sums_form`; ``kmeans:prepare`` and
+``train:segment`` say which, ``scatter`` or ``mxu``). Both add unrounded
+float32 points in float32, in an order the ids fix (a pass repeats bit
+for bit), and count in int32:
+
+``_wide_scatter_kernel``, grid ``(tiles of centres, point blocks)``,
+where a product against k centres would cost more than the adds:
+
+  sums[id] += x                  the block transposed in VMEM (points
+                                 down the rows, the features padded to
+                                 ``dim_mxu`` lanes), then a point at a
+                                 time: its centre read from SMEM, its
+                                 row added to that centre's row of an
+                                 accumulator ``(TK + 8, dim_mxu)`` that
+                                 stays in VMEM while all points stream
+                                 past (TK = k = 4096 at dim 784: 14.7
+                                 MB, one read of the table). Two
+                                 accumulators taken in turn, added once
+                                 a pass: a load of a row waits for the
+                                 last store to its accumulator. Padding
+                                 points, and another tile's, go to a
+                                 dump row nobody reads. What the
+                                 algorithm needs: n x dim adds, 18 ms a
+                                 pass at 784 x 4096 on one v5e where the
+                                 product below took 202
+  counts[id] += 1                int32 in SMEM, one array an accumulator
+
 ``_wide_stats_kernel``, grid ``(tiles of TK centres, chunks of 256
-points)``:
+points)``, where k x dim is small:
 
   sums   += x . onehot^T         ``(TK, 256)`` 0 or 1, exact in
                                  bfloat16, under the chunk's three
@@ -57,18 +85,18 @@ points)``:
                                  unrounded points. The accumulators
                                  ``(dim_held, TK)``, features down the
                                  rows so that no column is padding, stay
-                                 in VMEM while all points stream past
-                                 (TK = k = 4096 at dim 784: one read of
-                                 the table); XLA transposes them once a
-                                 pass
+                                 in VMEM while all points stream past;
+                                 XLA transposes them once a pass. Its
+                                 cost grows with k (every point times
+                                 every centre's 0 or 1)
   counts += onehot               int32, lane by lane; XLA folds the 128
                                  lanes once a pass
 
-No scatter-add anywhere. In VMEM the distance product's contraction is
+In VMEM the distance product's contraction is
 padded to whole 128-deep slabs (``dim_mxu``: 896 for 784, so an eighth
 of its MXU passes multiplies zeros: PERF.md §7); in HBM nothing is.
 Interpreted on the CPU the kernels run the same bfloat16
-``dot_general``s.
+``dot_general``s and the same loop over the points.
 """
 
 from __future__ import annotations
@@ -88,7 +116,11 @@ BLOCK_POINTS = 512         # P
 CENTRE_TILE = 512          # TN at most: centres scored a grid step
 STATS_TILE = 4096          # TK at most: the one-hot is (TK, 256)
 STATS_POINTS = 256         # points a grid step of the stats kernel
-ACC_BYTES = 16 << 20       # the sums' accumulators a tile of TK centres
+ACC_BYTES = 16 << 20       # one accumulator of the sums a tile of centres
+SCATTER_POINTS = 8         # points written out a trip of the scatter's loop
+SCATTER_ACCS = 2           # accumulators the scatter takes in turn
+SCATTER_LOOP = 96 << 10    # products a point the scatter's loop is worth
+SCATTER_LANE = 160         # ... and its transpose, a lane of ``dim_mxu``
 MAX_DIM = 4096             # a block and its pieces stay under 32 MB
 DIST_FORM = "mxu6"         # six bfloat16 passes: float32 accuracy
 _TOP = 0xFFFF0000          # the half of a float32 that is a bfloat16
@@ -130,6 +162,18 @@ class WideGeometry:
     layout = "wide"        # what the spans call it
     dist_form = DIST_FORM  # how a pass scores the distances
 
+    @property
+    def sums_form(self) -> str:
+        """How a pass adds up the per-cluster sums: :func:`sums_form`."""
+        return sums_form(self.k, self.dim)
+
+    @property
+    def scatter_tile(self) -> int:
+        """Centres a tile of the scatter: one accumulator, its dump rows
+        included, stays under ``ACC_BYTES``."""
+        room = ACC_BYTES // (4 * self.dim_mxu) - SUBLANES
+        return min(_round_up(self.k, SUBLANES), room // SUBLANES * SUBLANES)
+
     def pack(self, rows):
         """``(block_points, dim)`` rows -> one ``(dim_held, P)`` block."""
         return jnp.pad(rows.T, ((0, self.dim_held - self.dim), (0, 0)))
@@ -153,6 +197,24 @@ def wide_geometry(dim: int, k: int) -> WideGeometry | None:
         cap *= 2
     return WideGeometry(dim, k, BLOCK_POINTS, tn,
                         min(_round_up(k, tn), cap))
+
+
+def sums_form(k: int, dim: int) -> str:
+    """``"scatter"`` or ``"mxu"``: how :func:`wide_stats` adds up the
+    per-cluster sums of ``k`` centres in ``dim`` dimensions, from these
+    two alone. A one-hot product costs k * dim products a point whatever
+    the point's cluster; a scatter-add costs its loop (a point's row into
+    its centre's, whatever k) and the block's transpose (in proportion to
+    the padded width). On one v5e, 400 000 points a pass (PERF.md §6, PR
+    31; ms, one-hot / scatter): the forms cross between k 1024 and 2048
+    at dim 96 (2.71 / 2.98, 4.11 / 2.96), 512 and 1024 at 128 (2.41 /
+    2.92, 3.24 / 2.81), 256 and 512 at 784 (3.99 / 4.57, 6.39 / 4.70),
+    at 256 at 1024 (4.63 / 4.56); at 784 x 4096 201.7 / 18.2 a 2 025 000
+    points, at 128 x 16384 28.6 / 3.2."""
+    held, deep = _round_up(dim, PIECE_ROWS), _round_up(dim, LANES)
+    if k * held >= SCATTER_LOOP + SCATTER_LANE * deep:
+        return "scatter"
+    return "mxu"
 
 
 def _u32(x):
@@ -259,6 +321,65 @@ def _wide_stats_kernel(nv_ref, x_ref, a_ref, sums_ref, cnt_ref, *,
                       for c in range(p // LANES)])
 
 
+def _wide_scatter_kernel(ids_ref, x_ref, *refs, n_acc: int, points: int):
+    """One block of points into one tile of centres' sums, a point at a
+    time: ``acc[id] += x``. ``ids_ref`` (SMEM) holds the block's centres
+    relative to the tile, the tile's dump row for a point that is padding
+    or another tile's. ``refs``: ``n_acc`` outputs of sums (HBM) and of
+    counts (SMEM), ``n_acc`` accumulators ``(TK + 8, dim_mxu)``, the
+    transposed block, the copies' semaphores.
+
+    Neighbouring points go to different accumulators, and each is its
+    own allocation: a point's load of its centre's row has to wait for
+    the last store to that accumulator (the compiler cannot tell two
+    rows apart), 7 bundles on a v5e, and two accumulators halve the
+    chain (18.2 ms a pass at 784 x 4096 against 22.2 with one, 18.9 with
+    four; as one array with a leading index: no gain)."""
+    outs, cnts = refs[:n_acc], refs[n_acc:2 * n_acc]
+    accs, (xt_ref, sem) = refs[2 * n_acc:3 * n_acc], refs[3 * n_acc:]
+    j, i = pl.program_id(0), pl.program_id(1)
+    held, p = x_ref.shape
+
+    @pl.when((j == 0) & (i == 0))
+    def _pad():
+        # the columns past dim_held: written once, never again
+        xt_ref[...] = jnp.zeros_like(xt_ref)
+
+    @pl.when(i == 0)
+    def _new_tile():
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)
+
+        def zero(c, carry):
+            for cnt in cnts:
+                cnt[0, c] = 0
+            return carry
+
+        jax.lax.fori_loop(0, cnts[0].shape[1], zero, 0)
+
+    # points down the rows: a point's features are one row
+    xt_ref[:, pl.ds(0, held)] = x_ref[...].T
+
+    def some(t, carry):
+        first = pl.multiple_of(t * points, points)
+        for u in range(points):
+            c = ids_ref[0, first + u]
+            accs[u % n_acc][pl.ds(c, 1), :] += xt_ref[pl.ds(first + u, 1), :]
+            cnts[u % n_acc][0, c] += 1
+        return carry
+
+    jax.lax.fori_loop(0, p // points, some, 0)
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _out():
+        copies = [pltpu.make_async_copy(acc, out.at[j], sem.at[n])
+                  for n, (acc, out) in enumerate(zip(accs, outs))]
+        for copy in copies:
+            copy.start()
+        for copy in copies:
+            copy.wait()
+
+
 def _check(x3, geom: WideGeometry):
     if x3.shape[1:] != (geom.dim_held, geom.block_points):
         raise ValueError(
@@ -305,12 +426,23 @@ def wide_stats(x3, assign, n_valid, *, geom: WideGeometry,
                interpret: bool = False):
     """``(k, dim)`` float32 sums and ``(k,)`` int32 counts of this
     shard's valid points (ids below ``n_valid``) under ``assign`` as
-    :func:`wide_assign` returns it. A grid step takes ``STATS_POINTS``
-    lanes of a block (on one v5e at 784 x 4096 a pass took 202.6 ms in
-    chunks of 256 points, 240.7 in 512, 222.4 in 1024; the sums as
-    ``(TK, dim)`` with the features padded to 896 columns 30 ms more
-    each: PERF.md §6, PR 30)."""
+    :func:`wide_assign` returns it, in the form ``geom.sums_form`` names:
+    a scatter-add of each point into its centre's row where k x dim makes
+    a one-hot product the dearer (:func:`sums_form`), else the one-hot
+    product. Either way float32 sums of unrounded points in an order the
+    ids fix, and exact counts."""
     _check(x3, geom)
+    form = scatter_stats if geom.sums_form == "scatter" else onehot_stats
+    return form(x3, assign, n_valid, geom=geom, interpret=interpret)
+
+
+def onehot_stats(x3, assign, n_valid, *, geom: WideGeometry,
+                 interpret: bool = False):
+    """:func:`wide_stats` as a one-hot product on the MXU. A grid step
+    takes ``STATS_POINTS`` lanes of a block (on one v5e at 784 x 4096 a
+    pass took 202.6 ms in chunks of 256 points, 240.7 in 512, 222.4 in
+    1024; the sums as ``(TK, dim)`` with the features padded to 896
+    columns 30 ms more each: PERF.md §6, PR 30)."""
     nb, held, p = x3.shape
     tk = geom.stats_tile
     q = min(p, STATS_POINTS)
@@ -338,6 +470,57 @@ def wide_stats(x3, assign, n_valid, *, geom: WideGeometry,
         interpret=interpret,
     )(jnp.asarray(n_valid, jnp.int32).reshape(1), x3, assign)
     return (sums.T[:geom.k, :geom.dim], counts.sum(axis=1)[:geom.k])
+
+
+def scatter_stats(x3, assign, n_valid, *, geom: WideGeometry,
+                  interpret: bool = False, tile: int | None = None):
+    """:func:`wide_stats` as a scatter-add: every point's row added to
+    its centre's row of a float32 accumulator that stays in VMEM while
+    all blocks stream past, counts in SMEM. Grid ``(tiles of centres,
+    blocks)``; ``tile`` (``geom.scatter_tile``) centres an accumulator
+    holds, so past ``ACC_BYTES`` the centres are tiled and a tile costs
+    a whole pass of its own (the table's read and the loop over every
+    point: 4.6 ms a 400 000 points at dim 784 on one v5e), the points of
+    other tiles going to the dump row with the padding."""
+    nb, held, p = x3.shape
+    tk = geom.scatter_tile if tile is None else tile
+    tiles, rows, deep = -(-geom.k // tk), tk + SUBLANES, geom.dim_mxu
+    n_acc = SCATTER_ACCS
+    pid = jnp.arange(nb * p, dtype=jnp.int32).reshape(nb, 1, p)
+    # padding points join no cluster: the tile's dump row takes them
+    rel = jnp.where(pid < n_valid, assign, -1)[None] - tk * jnp.arange(
+        tiles, dtype=jnp.int32)[:, None, None, None]
+    ids = jnp.where((rel >= 0) & (rel < tk), rel, tk)
+    kernel = functools.partial(_wide_scatter_kernel, n_acc=n_acc,
+                               points=SCATTER_POINTS)
+    got = pl.pallas_call(
+        kernel,
+        name="_wide_scatter_kernel",
+        grid=(tiles, nb),
+        in_specs=[pl.BlockSpec((None, None, 1, p),
+                               lambda j, i: (j, i, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((None, held, p), lambda j, i: (i, 0, 0))],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_acc
+        + [pl.BlockSpec((None, 1, rows), lambda j, i: (j, 0, 0),
+                        memory_space=pltpu.SMEM)] * n_acc,
+        out_shape=[jax.ShapeDtypeStruct((tiles, rows, deep),
+                                        jnp.float32)] * n_acc
+        + [jax.ShapeDtypeStruct((tiles, 1, rows), jnp.int32)] * n_acc,
+        scratch_shapes=[pltpu.VMEM((rows, deep), jnp.float32)] * n_acc
+        + [pltpu.VMEM((p, deep), jnp.float32),
+           pltpu.SemaphoreType.DMA((n_acc,))],
+        compiler_params=pltpu.CompilerParams(
+            # the accumulators live across the grid
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem(
+                geom, n_acc * rows * deep * 4 + p * deep * 4)),
+        interpret=interpret,
+    )(ids, x3)
+    sums = functools.reduce(jax.lax.add, got[:n_acc])[:, :tk]
+    counts = functools.reduce(jax.lax.add, got[n_acc:])[:, 0, :tk]
+    return (sums.reshape(tiles * tk, deep)[:geom.k, :geom.dim],
+            counts.reshape(tiles * tk)[:geom.k])
 
 
 def _vmem(geom: WideGeometry, working: int) -> int:

@@ -143,8 +143,9 @@ def summarize(evts: list[dict]) -> dict:
             form = e.get("draw_form")
             if form and form not in draw_forms:
                 draw_forms.append(form)
-            # and those of k-means' lanes path where a pass adds up the
-            # per-cluster sums (pallas_lloyd.sums_form)
+            # and those of k-means' scale path how a pass adds up the
+            # per-cluster sums (pallas_lloyd.sums_form: mxu / vpu;
+            # pallas_lloyd_wide.sums_form: scatter / mxu)
             form = e.get("sums_form")
             if form and form not in sums_forms:
                 sums_forms.append(form)
