@@ -25,7 +25,9 @@ line ``{"ok": true, "device": {...}}`` only when every stage passed and
 no kernel was built with ``interpret=True``. With no TPU (including
 ``JAX_PLATFORMS=cpu``) it exits 2 before any stage and prints no
 result. The full log (tracebacks, captured CLI output) goes to
-``chiprun_out/chip_smoke_<n>dev.log``.
+``chiprun_out/chip_smoke_<n>dev.log``. Stage names as arguments run
+those stages alone (``python chip_smoke.py ssgd_hashed``: what a
+four-chip call is given when one stage's path is all that changed).
 """
 
 from __future__ import annotations
@@ -719,6 +721,54 @@ def stage_kmeans_wide(s: Smoke):
             f"distances {geom.dist_form} | " + " | ".join(said))
 
 
+def stage_ssgd_hashed(s: Smoke):
+    """SSGD over hashed rows as ``tda ssgd --hashed-rows`` runs it, on
+    every chip the stage has: the loader's table (39 fields into 2**20
+    weights, blocks of 8192 rows: the benchmark's widths at 400 000
+    rows), 12 steps of the block-sampled trainer with both Mosaic
+    passes compiled and, on several chips, the psum of the whole 4 MB
+    gradient a step; against the same steps with the passes in their
+    XLA form, to float32 rounding, and held-out rows scored better than
+    zero weights score them."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_distalg.models import ssgd
+    from tpu_distalg.ops import pallas_hashed
+
+    mesh = s.mesh()
+    n_rows, nnz, bits = 400_000, 39, 20
+    cfg = ssgd.SSGDConfig(
+        n_iterations=12, eval_test=False, sampler="fused_gather",
+        gather_block_rows=8192, mini_batch_fraction=0.25)
+    fn, X, w0, meta = ssgd.prepare_hashed_synthetic(
+        n_rows, nnz, bits, mesh, cfg, data_seed=5)
+    form = ssgd.hashed_geometry(cfg, meta).pass_form
+    if form != "vmem":
+        raise AssertionError(f"passes {form!r}, not 'vmem'")
+    s.check_sharded("X", X)
+    d = jnp.zeros((1,), jnp.float32)
+    w, _ = fn(X, d, d, d, d, w0)
+    real = pallas_hashed.pass_form
+    pallas_hashed.pass_form = lambda *a: "xla"
+    try:
+        w_xla, _ = ssgd.make_train_fn_fused(mesh, cfg, meta)(
+            X, d, d, d, d, w0)
+    finally:
+        pallas_hashed.pass_form = real
+    w, w_xla = np.asarray(w, np.float64), np.asarray(w_xla, np.float64)
+    err = float(np.linalg.norm(w - w_xla) / np.linalg.norm(w_xla))
+    if not err < 1e-5:
+        raise AssertionError(f"vmem passes differ from XLA's by {err:.3g}")
+    acc, loss = ssgd.evaluate_hashed(w, meta, data_seed=5)
+    if not loss < 0.68:
+        raise AssertionError(f"held-out log-loss {loss:.4f} after 12 "
+                             f"steps (zero weights: 0.6931)")
+    return (f"dp={mesh.shape['data']} | table {tuple(X.shape)} | vmem "
+            f"against xla passes {err:.2g} | held-out log-loss "
+            f"{loss:.4f} acc {acc:.4f}")
+
+
 def _comm_stage(s: Smoke, comm: str):
     from tpu_distalg.models import ssgd
 
@@ -756,6 +806,9 @@ STAGES = (
      dict(kernels=("pallas_lloyd_wide._wide_assign_kernel",
                    "pallas_lloyd_wide._wide_stats_kernel",
                    "pallas_lloyd_wide._wide_scatter_kernel"))),
+    ("ssgd_hashed", stage_ssgd_hashed,
+     dict(kernels=("pallas_hashed._hashed_gather_kernel",
+                   "pallas_hashed._hashed_scatter_kernel"))),
     ("ssgd_comm_int8", functools.partial(_comm_stage, comm="int8"),
      dict(min_devices=2)),
     ("ssgd_comm_bucketed",
@@ -813,8 +866,13 @@ def main() -> int:
               f"{'warm' if n_cached else 'cold'} run)")
         s.spy.install()
         t0 = time.perf_counter()
+        only = sys.argv[1:]
+        unknown = sorted(set(only) - {name for name, _, _ in STAGES})
+        if unknown:
+            raise SystemExit(f"chip_smoke: no stage named {unknown}")
         for name, fn, opts in STAGES:
-            s.stage(name, functools.partial(fn, s), **opts)
+            if not only or name in only:
+                s.stage(name, functools.partial(fn, s), **opts)
         wall = time.perf_counter() - t0
         failed = [n for n, ok in s.results if not ok]
         s.say(f"chip_smoke: {len(s.results) - len(failed)}/"

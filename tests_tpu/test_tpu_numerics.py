@@ -933,3 +933,59 @@ def test_kmeans_wide_scatter_tiles_the_centres():
     _check_wide_stats(pts[:n], a[:n], k, np.asarray(sums),
                       np.asarray(counts))
     assert int(np.asarray(counts).sum()) == n
+
+
+def test_hashed_passes_at_the_published_widths(tpu_mesh):
+    """The compiled gather and scatter of SSGD over hashed rows at the
+    benchmark's widths (39 slots a row into 2**20 float32 weights,
+    blocks of 8192 rows; 200 000 rows of the loader's skewed table, 6
+    blocks sampled) against the definition a pair at a time in float64:
+    margins to float32 rounding of a sum of 40 terms; per-slot sums
+    within float32 summation error of their sum of magnitudes, the slot
+    that nearly every row hits included; the bias slot the residuals'
+    sum; a residual of 1 everywhere counts a slot's occurrences exactly.
+    The XLA form at the same ids agrees likewise."""
+    from tpu_distalg.models import ssgd
+    from tpu_distalg.ops import pallas_hashed as ph
+    from tpu_distalg.parallel import get_mesh
+
+    mesh = get_mesh(data=1, devices=jax.devices()[:1])
+    cfg = ssgd.SSGDConfig(sampler="fused_gather", gather_block_rows=8192,
+                          eval_test=False, mini_batch_fraction=0.25)
+    X, meta = ssgd.build_hashed_table(200_000, 39, 20, mesh, cfg,
+                                      data_seed=17)
+    geom = ssgd.hashed_geometry(cfg, meta)
+    assert geom.pass_form == "vmem" and X.shape == (25, 40, 8192)
+    ids = jnp.array([24, 3, 11, 0, 17, 8], jnp.int32)
+    key = jax.random.key(2)
+    w = jax.random.normal(key, (geom.w_len,)).at[geom.n_slots + 1:].set(0)
+    r = jax.random.normal(jax.random.fold_in(key, 1), (6, 8192))
+    idx = np.asarray(X)[np.asarray(ids)][:, :39, :]
+    w64, r64 = np.asarray(w, np.float64), np.asarray(r, np.float64)
+    m_def = w64[geom.n_slots] + w64[idx].sum(axis=1)
+    g_def = np.zeros((geom.n_slots,), np.float64)
+    mags = np.zeros((geom.n_slots,), np.float64)
+    counts = np.zeros((geom.n_slots,), np.int64)
+    both = np.broadcast_to(r64[:, None, :], idx.shape)
+    np.add.at(g_def, idx, both)
+    np.add.at(mags, idx, np.abs(both))
+    np.add.at(counts, idx, 1)
+    assert counts.max() > 0.4 * r.size           # the hot slot is there
+    ones = jnp.ones_like(r)
+    for name, margins, sums in (
+            ("vmem", ph.margins_vmem, ph.slot_sums_vmem),
+            ("xla", ph.margins_xla, ph.slot_sums_xla)):
+        m = np.asarray(margins(X, w, ids, geom), np.float64)
+        g = np.asarray(sums(X, r, ids, geom), np.float64)
+        n1 = np.asarray(sums(X, ones, ids, geom), np.float64)
+        err = np.abs(g[:geom.n_slots] - g_def)
+        print(f"[hashed widths] {name}: margins max err "
+              f"{np.abs(m - m_def).max():.3g}, slot sums max err over "
+              f"magnitudes {(err / np.maximum(mags, 1e-30)).max():.3g}, "
+              f"hot slot {counts.max()} occurrences")
+        assert np.abs(m - m_def).max() < 2e-5, name
+        assert (err <= (np.log2(counts + 2) + 2) * 6e-8 * mags * 8
+                + 1e-30).all(), name
+        assert abs(g[geom.n_slots] - r64.sum()) < 1e-2, name
+        assert not g[geom.n_slots + 1:].any(), name
+        np.testing.assert_array_equal(n1[:geom.n_slots], counts)

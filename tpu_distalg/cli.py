@@ -234,9 +234,14 @@ def _centers_line(centers, whole: int = 4096) -> str:
 def _report_optimizer(name, res, args, t):
     from tpu_distalg.utils import metrics
 
-    if not args.quiet:
-        print(f"Final w: {list(map(float, res.w))}")
-    print(f"Final acc: {res.final_acc:.6f}")
+    if hasattr(res, "heldout_log_loss"):
+        # a table of 2**hash_bits weights is not printed
+        print(f"Held-out accuracy: {res.heldout_acc:.6f}  log-loss: "
+              f"{res.heldout_log_loss:.6f}")
+    else:
+        if not args.quiet:
+            print(f"Final w: {list(map(float, res.w))}")
+        print(f"Final acc: {res.final_acc:.6f}")
     print(f"[{name}] {args.n_iterations} iterations in {t:.3f}s "
           f"({args.n_iterations / t:.1f} steps/s)")
     if args.plot:
@@ -292,6 +297,20 @@ def main(argv=None):
                         "--mega-steps.")
     p.add_argument("--stream-rows", type=int, default=1 << 22,
                    help="rows to generate when --stream-cache is new")
+    p.add_argument("--hashed-rows", type=int, default=0, metavar="N",
+                   help="train on N seeded click-log rows made ON "
+                        "DEVICE whose fields are hashed one-hot into a "
+                        "table of 2**--hash-bits weights (a row is "
+                        "--fields int32 slots and a label, not "
+                        "columns: models/ssgd.py's second row format). "
+                        "The rows decide the trainer: block-sampled BSP "
+                        "(--sampler is taken as fused_gather), held-out "
+                        "accuracy and log-loss printed at the end")
+    p.add_argument("--fields", type=int, default=39,
+                   help="fields a row for --hashed-rows (39: Criteo's "
+                        "13 integer + 26 categorical)")
+    p.add_argument("--hash-bits", type=int, default=20,
+                   help="log2 of the weight table for --hashed-rows")
 
     for name in ("ma", "bmuf", "easgd"):
         p = sub.add_parser(name)
@@ -1197,10 +1216,35 @@ def _dispatch(args, jax):
     if args.cmd in ("lr", "ssgd", "ma", "bmuf", "easgd"):
         from tpu_distalg.utils import datasets
 
-        data = datasets.breast_cancer_split()
+        hashed = args.cmd == "ssgd" and args.hashed_rows > 0
+        data = None if hashed else datasets.breast_cancer_split()
         mesh = _mesh(args)
         t0 = time.perf_counter()
-        if args.cmd == "lr":
+        if hashed:
+            from tpu_distalg.models import ssgd as m
+
+            if args.stream_cache is not None:
+                raise SystemExit(
+                    "--hashed-rows builds its table on the device; "
+                    "--stream-cache streams packed columns from disk")
+            # the options that describe the data pick the trainer; the
+            # one sampler that takes rows of indices is the default's
+            # stand-in, any other named one is refused by the builder
+            sampler = ("fused_gather" if args.sampler == "bernoulli"
+                       else args.sampler)
+            cfg = m.SSGDConfig(
+                n_iterations=args.n_iterations, eta=args.eta,
+                mini_batch_fraction=args.mini_batch_fraction,
+                lam=args.lam, reg_type=args.reg_type, sampler=sampler,
+                gather_block_rows=args.gather_block_rows,
+                comm=args.comm, sync=args.sync, eval_test=False)
+
+            def run_once():
+                return m.train_hashed(
+                    args.hashed_rows, args.fields, args.hash_bits, mesh,
+                    cfg, checkpoint_dir=args.checkpoint_dir,
+                    checkpoint_every=args.checkpoint_every)
+        elif args.cmd == "lr":
             from tpu_distalg.models import logistic_regression as m
 
             def run_once():

@@ -998,6 +998,10 @@ def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
     from tpu_distalg.ops import pallas_kernels
     from tpu_distalg.parallel import DATA_AXIS
 
+    if meta.get("row_format", "packed") == "hashed":
+        # the loader's meta decides: rows that are indices have their
+        # own two passes and share everything round them
+        return _make_train_fn_hashed(mesh, config, meta)
     on_tpu = mesh_on_tpu(mesh)
     d_t = meta["d_total"]
     col_keep = (jnp.arange(d_t) < meta["y_col"]).astype(jnp.float32)
@@ -1885,3 +1889,296 @@ def _train_fused(
         span_fields=draw,
     )
     return TrainResult(w=jnp.asarray(w)[:d_orig], accs=jnp.asarray(accs))
+
+
+# ---- rows that are indices and not columns ------------------------------
+#
+# The second row format (``meta["row_format"] == "hashed"``): a row is
+# ``nnz`` int32 slots of a table of ``2 ** hash_bits`` float32 weights and
+# a label (``ops/pallas_hashed.py`` documents the block layout and the two
+# passes). Everything round the passes is the packed rows': the block grid
+# of ``fused_gather_geometry``, the draw, ``_build_scan``'s step, the psum.
+
+@dataclasses.dataclass
+class HashedResult:
+    """A hashed run: the model vector (table, bias, zeros), and what it
+    scores on rows the table does not hold."""
+
+    w: jax.Array
+    accs: jax.Array
+    heldout_acc: float
+    heldout_log_loss: float
+
+    @property
+    def final_acc(self) -> float:
+        return self.heldout_acc
+
+
+def hashed_geometry(config: SSGDConfig, meta: dict):
+    from tpu_distalg.ops import pallas_hashed
+
+    return pallas_hashed.HashedGeometry(
+        nnz=meta["nnz"], hash_bits=meta["hash_bits"],
+        block_rows=config.gather_block_rows)
+
+
+def _check_hashed_config(config: SSGDConfig) -> None:
+    """The one place that says which trainers take hashed rows: the
+    per-step block-sampled one ('fused_gather'), dense BSP. The others
+    read a row as columns."""
+    from tpu_distalg.parallel import ssp as pssp
+
+    why = {
+        "bernoulli": "masks every row of a dense matrix each step",
+        "fixed": "gathers dense rows one at a time",
+        "fused": "streams packed bfloat16 columns through the one-pass "
+                 "kernel",
+        "fused_train": "keeps a packed step's 40 weights in the "
+                       "megakernel's VMEM; a table of 2**hash_bits "
+                       "weights and a psum a step do not fit one launch",
+        "virtual": "regenerates packed columns on the device",
+    }
+    if config.sampler != "fused_gather":
+        raise ValueError(
+            f"hashed rows: sampler={config.sampler!r} cannot take the "
+            f"format ({why.get(config.sampler, 'unknown sampler')}); "
+            f"use sampler='fused_gather'")
+    if config.feature_sharded:
+        raise ValueError(
+            "hashed rows: feature_sharded splits packed columns over "
+            "the model axis and cannot take the format; a table past "
+            "one chip is not built yet (ROADMAP R4m)")
+    if config.comm != "dense":
+        raise ValueError(
+            f"hashed rows: comm={config.comm!r} cannot take the format "
+            f"yet: the schedules of parallel/comms.py have not met a "
+            f"gradient of 2**hash_bits floats (ROADMAP R4m); use 'dense'")
+    if pssp.SyncSpec.parse(config.sync).is_ssp:
+        raise ValueError(
+            f"hashed rows: sync={config.sync!r} cannot take the format: "
+            f"the guarantee is BSP (no slot updated from stale weights)")
+    if config.use_pallas:
+        raise ValueError(
+            "hashed rows: use_pallas names the dense one-pass kernel of "
+            "the 'bernoulli' sampler and cannot take the format")
+
+
+def _hashed_fields(config: SSGDConfig, meta: dict) -> dict:
+    """What the spans of a hashed run say (``tda report`` prints it)."""
+    form = hashed_geometry(config, meta).pass_form
+    return {"row_format": "hashed", "nnz": meta["nnz"],
+            "hash_bits": meta["hash_bits"], "gather_form": form,
+            "scatter_form": form}
+
+
+def _make_train_fn_hashed(mesh: Mesh, config: SSGDConfig, meta: dict):
+    """:func:`make_train_fn_fused` for a hashed ``meta``: the same scan
+    (``fn(X, dummy, dummy, dummy, dummy, w0, t0=, acc0=)``), the same
+    draw, update and psum; only the local gradient is new. The carried
+    ``w`` is ``f32[2 ** hash_bits + 128]``: table, bias, zeros. The
+    scan scores nothing (there is no dense test matrix to multiply):
+    :func:`evaluate_hashed` scores held-out rows between segments."""
+    from jax import lax
+
+    from tpu_distalg.ops import pallas_hashed
+    from tpu_distalg.parallel import DATA_AXIS
+
+    _check_hashed_config(config)
+    geom = hashed_geometry(config, meta)
+    n_shards = mesh.shape[DATA_AXIS]
+    n_blocks, n_sampled = fused_gather_geometry(config, meta, n_shards)
+    interpret = not mesh_on_tpu(mesh)
+    n_rows, B = meta["n_rows"], geom.block_rows
+    key = prng.root_key(config.seed)
+
+    def prep_xs(ts):
+        with jax.named_scope(names.SSGD_DRAW):
+            return jax.vmap(
+                lambda t: sampling.sample_block_ids(
+                    jax.random.fold_in(key, t),
+                    n_shards, n_blocks, n_sampled))(ts)     # (T, S, ns)
+
+    def _local_grad(X, w, idx_shards):
+        shard = lax.axis_index(DATA_AXIS)
+        ids = lax.dynamic_index_in_dim(idx_shards, shard, keepdims=False)
+        with jax.named_scope(names.SSGD_GATHER):
+            m = pallas_hashed.margins(X, w, ids, geom,
+                                      interpret=interpret)
+            y = pallas_hashed.labels(X, ids, geom)
+            row = ((shard * n_blocks + ids) * B)[:, None] \
+                + jnp.arange(B)[None, :]
+            valid = (row < n_rows).astype(jnp.float32)
+            r = (jax.nn.sigmoid(m) - y) * valid
+        with jax.named_scope(names.SSGD_SCATTER):
+            g = pallas_hashed.slot_sums(X, r, ids, geom,
+                                        interpret=interpret)
+            cnt = jnp.sum(valid)
+        with jax.named_scope(names.SSGD_SYNC):
+            return tree_allreduce_sum((g, cnt))
+
+    grad_fn = data_parallel(
+        _local_grad, mesh,
+        in_specs=(P("data", None, None), P(), P()),
+        out_specs=(P(), P()))
+
+    def sample_and_grad(X, y, valid, w, x):
+        del y, valid                 # labels and validity ride in X
+        return grad_fn(X, w, x)
+
+    return _build_scan(dataclasses.replace(config, eval_test=False),
+                       sample_and_grad, prep_xs=prep_xs)
+
+
+@functools.lru_cache(maxsize=8)
+def hashed_table_fn(mesh: Mesh, n_rows: int, n_padded: int, geom,
+                    cardinalities: tuple, rows_kw: tuple = ()):
+    """The compiled loader of one geometry: the seed is its argument,
+    so a second seed costs no compile."""
+    import math
+
+    from jax import lax
+
+    from tpu_distalg.parallel import DATA_AXIS, partition
+    from tpu_distalg.utils import datasets as dsets
+
+    n_shards = mesh.shape[DATA_AXIS]
+    B, F, nnz = geom.block_rows, geom.fields_held, geom.nnz
+    n_local = n_padded // n_shards
+    n_blocks = n_local // B
+    per = math.gcd(n_blocks, 16)                 # blocks a chunk
+    chunk, n_chunks = B * per, n_blocks // per
+    make_rows = dsets.hashed_click_rows(cardinalities, geom.hash_bits,
+                                        **dict(rows_kw))
+
+    def body(seed):
+        s = lax.axis_index(DATA_AXIS)
+        bias = make_rows.planted_bias(seed)
+
+        def one(c):
+            ids = s * n_local + c * chunk + jnp.arange(chunk)
+            slots, y = make_rows(ids, seed, bias)
+            # a padding row keeps its slots (any slot is a safe
+            # address); the trainer tells it by its id
+            cols = jnp.concatenate(
+                [slots, y.astype(jnp.int32)[:, None],
+                 jnp.zeros((chunk, F - nnz - 1), jnp.int32)], axis=1)
+            return cols.reshape(per, B, F).transpose(0, 2, 1)
+
+        return lax.map(one, jnp.arange(n_chunks)).reshape(n_blocks, F, B)
+
+    return jax.jit(
+        jax.shard_map(body, mesh=mesh, in_specs=P(),
+                      out_specs=P(DATA_AXIS, None, None)),
+        out_shardings=partition.leaf_sharding("ssgd", "X", mesh))
+
+
+def build_hashed_table(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
+                       config: SSGDConfig, *, data_seed: int = 0,
+                       cardinalities=None, **rows_kw):
+    """The loader of hashed rows: ``n_rows`` seeded click-log rows
+    (``datasets.hashed_click_rows``, which ``rows_kw`` reach) made ON
+    DEVICE, shard by shard, as ``int32[n_blocks, fields_held,
+    gather_block_rows]``, and the ``meta`` that states the format.
+    Returns ``(X, meta)``."""
+    from tpu_distalg.ops import pallas_hashed
+    from tpu_distalg.parallel import DATA_AXIS
+    from tpu_distalg.utils import datasets as dsets
+
+    cards = tuple(cardinalities
+                  or dsets.click_field_cardinalities(nnz))
+    if len(cards) != nnz:
+        raise ValueError(f"{len(cards)} cardinalities for {nnz} fields")
+    geom = pallas_hashed.HashedGeometry(
+        nnz=nnz, hash_bits=hash_bits, block_rows=config.gather_block_rows)
+    mult = geom.block_rows * mesh.shape[DATA_AXIS]
+    # pack 1: fused_gather_geometry's block grid counts rows
+    meta = dict(row_format="hashed", nnz=nnz, hash_bits=hash_bits,
+                pack=1, n_rows=n_rows, n_padded=n_rows + (-n_rows) % mult,
+                d_total=geom.w_len, cardinalities=cards,
+                rows_kw=tuple(sorted(rows_kw.items())))
+    with tevents.span("ssgd:prepare", rows=n_rows,
+                      bytes=meta["n_padded"] * geom.row_bytes,
+                      row_format="hashed", nnz=nnz, hash_bits=hash_bits):
+        with tevents.span("ssgd:generate", rows=meta["n_padded"]):
+            X = hashed_table_fn(mesh, n_rows, meta["n_padded"], geom,
+                                cards, meta["rows_kw"])(
+                jnp.int32(data_seed))
+            X.block_until_ready()
+    return X, meta
+
+
+def prepare_hashed_synthetic(n_rows: int, nnz: int, hash_bits: int,
+                             mesh: Mesh, config: SSGDConfig, *,
+                             data_seed: int = 0, cardinalities=None,
+                             **rows_kw):
+    """:func:`prepare_fused_synthetic` for hashed rows: returns ``(fn,
+    X, w0, meta)``, the weights zero as the source's."""
+    from tpu_distalg.parallel import partition
+
+    X, meta = build_hashed_table(
+        n_rows, nnz, hash_bits, mesh, config, data_seed=data_seed,
+        cardinalities=cardinalities, **rows_kw)
+    # placed as the trainer returns it: the first call and every later
+    # one (a next segment's, a benchmark window's) are one program
+    w0 = partition.put(jnp.zeros((meta["d_total"],), jnp.float32), "w",
+                       "ssgd", mesh)
+    return make_train_fn_fused(mesh, config, meta), X, w0, meta
+
+
+def evaluate_hashed(w, meta: dict, *, data_seed: int = 0,
+                    n: int = 1 << 16):
+    """``(accuracy, log-loss)`` of the model vector ``w`` on ``n`` rows
+    the table of ``meta`` does not hold (ids past its padded end),
+    float32."""
+    from tpu_distalg.utils import datasets as dsets
+
+    make_rows = dsets.hashed_click_rows(
+        meta["cardinalities"], meta["hash_bits"], **dict(meta["rows_kw"]))
+    n_slots = 1 << meta["hash_bits"]
+
+    @jax.jit
+    def score(w, seed):
+        slots, y = make_rows(meta["n_padded"] + jnp.arange(n), seed)
+        m = jnp.sum(w[:n_slots][slots], axis=1) + w[n_slots]
+        loss = jnp.mean(jax.nn.softplus(m) - y * m)
+        return jnp.mean(((m > 0) == (y > 0.5)).astype(jnp.float32)), loss
+
+    acc, loss = score(jnp.asarray(w, jnp.float32), jnp.int32(data_seed))
+    return float(acc), float(loss)
+
+
+def train_hashed(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
+                 config: SSGDConfig, *, data_seed: int = 0,
+                 checkpoint_dir: str | None = None,
+                 checkpoint_every: int = 500) -> HashedResult:
+    """End-to-end training on hashed rows (``tda ssgd --hashed-rows``):
+    the loader's table, the block-sampled BSP trainer, held-out rows
+    scored at the end; checkpointed and resumable like :func:`train`."""
+    fn, X, w0, meta = prepare_hashed_synthetic(
+        n_rows, nnz, hash_bits, mesh, config, data_seed=data_seed)
+    dummy = jnp.zeros((1,), jnp.float32)
+    fields = dict(_draw_fields(config, meta, mesh),
+                  **_hashed_fields(config, meta))
+    if checkpoint_dir is None:
+        with _train_span(config, **fields):
+            w, accs = fn(X, dummy, dummy, dummy, dummy, w0)
+            metrics.guard_finite(w, "SSGD (hashed) weights")
+    else:
+        from tpu_distalg.parallel import partition
+        from tpu_distalg.utils import checkpoint as ckpt
+
+        (w, _), accs, _ = ckpt.run_segmented(
+            checkpoint_dir, checkpoint_every, config.n_iterations,
+            make_seg_fn=lambda seg: make_train_fn_fused(
+                mesh, dataclasses.replace(config, n_iterations=seg),
+                meta),
+            run_seg=_acc_carrying_run_seg(X, dummy, dummy, dummy, dummy),
+            state0=(w0, partition.put(jnp.float32(0), "acc0", "ssgd",
+                                      mesh)),
+            tag=f"ssgd:hashed:{nnz}x{hash_bits}",
+            span_fields=fields,
+        )
+    with tevents.span("ssgd:heldout"):
+        acc, loss = evaluate_hashed(w, meta, data_seed=data_seed)
+    return HashedResult(w=jnp.asarray(w), accs=jnp.asarray(accs),
+                        heldout_acc=acc, heldout_log_loss=loss)

@@ -17,6 +17,13 @@ SSGD_DRAW = "tda.ssgd.draw"      # threefry words, the selection of the least
 SSGD_KERNEL = "tda.ssgd.kernel"  # the Mosaic call and what XLA puts round it
 SSGD_SYNC = "tda.ssgd.sync"      # the psum (dense) or the comm schedule
 SSGD_UPDATE = "tda.ssgd.update"  # the rest of a step: reg, update, eval
+# a step over hashed rows (ops/pallas_hashed.py) has two passes where the
+# packed rows have one kernel; benchmarks/layer_metrics/
+# gather_ms_per_step.lr, scatter_ms_per_step.lr and hashed_pass_roofline
+# read them
+SSGD_GATHER = "tda.ssgd.gather"    # margins (w at each row's slots),
+#                                    labels, validity, residuals
+SSGD_SCATTER = "tda.ssgd.scatter"  # the residuals added up slot by slot
 # the fused SpMV sweep (models/pagerank.py)
 PAGERANK_SPMV = "tda.pagerank.spmv"
 # the parts of a Lloyd iteration (models/kmeans.py)

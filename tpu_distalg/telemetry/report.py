@@ -129,6 +129,8 @@ def summarize(evts: list[dict]) -> dict:
     draw_forms: list[str] = []
     sums_forms: list[str] = []
     dist_forms: list[str] = []
+    row_formats: list[str] = []
+    pass_forms: dict[str, list[str]] = {"gather": [], "scatter": []}
     t_wall = [e["t_wall"] for e in evts if "t_wall" in e]
     for e in evts:
         ev = e.get("ev")
@@ -154,6 +156,16 @@ def summarize(evts: list[dict]) -> dict:
             form = e.get("dist_form")
             if form and form not in dist_forms:
                 dist_forms.append(form)
+            # and SSGD's how its rows are held (packed columns say
+            # nothing; hashed rows say so) and the form of each of a
+            # hashed step's passes (pallas_hashed.pass_form: vmem / xla)
+            form = e.get("row_format")
+            if form and form not in row_formats:
+                row_formats.append(form)
+            for which, seen in pass_forms.items():
+                form = e.get(which + "_form")
+                if form and form not in seen:
+                    seen.append(form)
         elif ev == "span_end":
             name = e.get("name", "?")
             open_spans[name] = open_spans.get(name, 1) - 1
@@ -223,6 +235,8 @@ def summarize(evts: list[dict]) -> dict:
         "draw_forms": draw_forms,
         "sums_forms": sums_forms,
         "dist_forms": dist_forms,
+        "row_formats": row_formats,
+        "pass_forms": pass_forms,
         "unfinished_phases": sorted(
             k for k, v in open_spans.items() if v > 0),
         "marks": marks,
@@ -262,8 +276,13 @@ def render(s: dict) -> str:
                 f"max {p['max_seconds']}s, self {p['self_seconds']}s{err}")
     for name in s["unfinished_phases"]:
         lines.append(f"  {name}: UNFINISHED (no span_end recorded)")
+    if s.get("row_formats"):
+        lines.append(f"row format: {', '.join(s['row_formats'])}")
     if s.get("draw_forms"):
         lines.append(f"block draw: {', '.join(s['draw_forms'])}")
+    for which, seen in (s.get("pass_forms") or {}).items():
+        if seen:
+            lines.append(f"{which} pass: {', '.join(seen)}")
     if s.get("dist_forms"):
         lines.append(f"distances: {', '.join(s['dist_forms'])}")
     if s.get("sums_forms"):

@@ -86,6 +86,126 @@ def synthetic_two_class_rows(n_features: int, seed: int = 0,
     return make_rows
 
 
+# Values a field of a click log takes. The 26 categorical fields are the
+# table sizes the public DLRM scripts pass for the Criteo Kaggle set; the
+# 13 integer fields' counts of distinct values are ASSUMED (the set
+# cannot be fetched here). Integer fields first, as the set's columns.
+CLICK_INTEGER_CARDINALITIES = (
+    649, 9364, 14746, 490, 476707, 11618, 4142, 1373, 7275, 13, 169,
+    407, 1376)
+CLICK_CATEGORICAL_CARDINALITIES = (
+    1460, 583, 10131227, 2202608, 305, 24, 12517, 633, 3, 93145, 5683,
+    8351593, 3194, 27, 14992, 5461306, 10, 5652, 2173, 4, 7046547, 18,
+    15, 286181, 105, 142572)
+
+
+def click_field_cardinalities(nnz: int) -> tuple[int, ...]:
+    """Distinct values of each of a click-log row's ``nnz`` fields: the
+    39 above, taken round again past them."""
+    both = CLICK_INTEGER_CARDINALITIES + CLICK_CATEGORICAL_CARDINALITIES
+    return tuple(both[f % len(both)] for f in range(nnz))
+
+
+def _mix32(x):
+    """A fixed 32-bit integer mix (Wellons' lowbias32), uint32 in and
+    out."""
+    import jax.numpy as jnp
+
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(0x846CA68B)
+    return x ^ (x >> 16)
+
+
+def hashed_click_rows(cardinalities, hash_bits: int, *,
+                      zipf_exponent: float = 1.1,
+                      planted_scale: float = 0.25,
+                      click_rate: float = 0.256):
+    """Jittable per-row generator of hashed click-log rows, counter
+    based like :func:`synthetic_two_class_rows` (a row is a function of
+    the seed and its global id alone). Returns ``make_rows(row_ids,
+    seed) -> (slots int32 (n, nnz), labels float32 (n,))``; the seed may
+    be traced, so one compiled loader serves every seed.
+
+    Field ``f`` takes value ``v`` in ``[0, cardinalities[f])`` by a
+    bounded power law (the inverse of the continuous distribution with
+    density ~ x ** -zipf_exponent on [1, N + 1)), so a few values of
+    every field fill most rows and a field of millions has a long tail.
+    Its slot is a fixed integer mix of ``(f, v)`` modulo ``2 **
+    hash_bits``: the hashing trick, collisions and all. The label is a
+    Bernoulli draw of a planted logistic model: slot ``s`` weighs
+    ``planted_scale`` times a unit-variance uniform hashed from ``(seed,
+    s)`` (no table to look up), and the bias is set on 65 536 rows of a
+    stream of their own so that ``click_rate`` of the rows are clicks.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    cards = jnp.asarray(cardinalities, jnp.float32)
+    nnz = len(cardinalities)
+    a1 = 1.0 - float(zipf_exponent)
+    if abs(a1) < 1e-3:
+        raise ValueError("zipf_exponent 1 has its own inverse; use "
+                         "another")
+    span = (cards + 1.0) ** a1 - 1.0
+    field_salt = (jnp.arange(nnz, dtype=jnp.uint32) + 1) \
+        * jnp.uint32(0x85EBCA6B)
+    mask = jnp.uint32((1 << hash_bits) - 1)
+
+    def slots_and_scores(row_keys, w_salt):
+        u = jax.vmap(lambda k: jax.random.uniform(k, (nnz,)))(row_keys)
+        v = jnp.floor((1.0 + u * span) ** (1.0 / a1)) - 1.0
+        v = jnp.clip(v, 0.0, cards - 1.0).astype(jnp.uint32)
+        slots = _mix32((v + 1) * jnp.uint32(0x9E3779B1) + field_salt) \
+            & mask
+        # the planted weight of a slot: uniform on [-sqrt 3, sqrt 3)
+        bits = _mix32(slots ^ w_salt) >> 8
+        planted = (bits.astype(jnp.float32) * (2.0 ** -23) - 1.0) \
+            * (3.0 ** 0.5)
+        z = planted_scale * jnp.sum(planted, axis=1)
+        return slots.astype(jnp.int32), z
+
+    def streams(seed):
+        key = jax.random.key(seed)
+        w_salt = jax.random.bits(jax.random.fold_in(key, 0), (),
+                                 jnp.uint32)
+        return w_salt, jax.random.fold_in(key, 1), \
+            jax.random.fold_in(key, 2)
+
+    def row_keys_of(stream, ids):
+        return jax.vmap(lambda i: jax.random.fold_in(stream, i))(ids)
+
+    def planted_bias(seed):
+        """The bias under which ``click_rate`` of the rows click."""
+        w_salt, _, k_cal = streams(seed)
+        _, z = slots_and_scores(
+            row_keys_of(k_cal, jnp.arange(1 << 16)), w_salt)
+
+        def halve(_, lo_hi):
+            lo, hi = lo_hi
+            mid = 0.5 * (lo + hi)
+            over = jnp.mean(jax.nn.sigmoid(mid + z)) > click_rate
+            return jnp.where(over, lo, mid), jnp.where(over, mid, hi)
+
+        lo, hi = jax.lax.fori_loop(
+            0, 40, halve, (jnp.float32(-30.0), jnp.float32(30.0)))
+        return 0.5 * (lo + hi)
+
+    def make_rows(ids, seed, bias=None):
+        w_salt, k_rows, _ = streams(seed)
+        bias = planted_bias(seed) if bias is None else bias
+        row_keys = row_keys_of(k_rows, ids)
+        slots, z = slots_and_scores(row_keys, w_salt)
+        coin = jax.vmap(lambda k: jax.random.uniform(
+            jax.random.fold_in(k, 7)))(row_keys)
+        return slots, (coin < jax.nn.sigmoid(bias + z)).astype(
+            jnp.float32)
+
+    make_rows.planted_bias = planted_bias
+    return make_rows
+
+
 def gaussian_mixture(
     n_rows: int, k: int = 4, dim: int = 2, seed: int = 0, spread: float = 8.0
 ) -> np.ndarray:
