@@ -726,10 +726,11 @@ def stage_ssgd_hashed(s: Smoke):
     every chip the stage has: the loader's table (39 fields into 2**20
     weights, blocks of 8192 rows: the benchmark's widths at 400 000
     rows), 12 steps of the block-sampled trainer with both Mosaic
-    passes compiled and, on several chips, the psum of the whole 4 MB
-    gradient a step; against the same steps with the passes in their
-    XLA form, to float32 rounding, and held-out rows scored better than
-    zero weights score them."""
+    passes compiled (21 of the 39 fields read by value against the
+    loader's dictionaries, 18 by address) and, on several chips, the
+    psum of the whole 4 MB gradient a step; against the same steps with
+    the passes in their XLA form, to float32 rounding, and held-out
+    rows scored better than zero weights score them."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -746,6 +747,12 @@ def stage_ssgd_hashed(s: Smoke):
     form = ssgd.hashed_geometry(cfg, meta).pass_form
     if form != "vmem":
         raise AssertionError(f"passes {form!r}, not 'vmem'")
+    plan = ssgd.hashed_field_plan(cfg, meta)
+    forms = (len(plan.dict_fields), len(plan.addr_fields), plan.n_values)
+    if forms != (21, 18, 13027):
+        raise AssertionError(
+            f"fields by value, by address, values {forms}, not "
+            f"(21, 18, 13027) at the published cardinalities")
     s.check_sharded("X", X)
     d = jnp.zeros((1,), jnp.float32)
     w, _ = fn(X, d, d, d, d, w0)
@@ -764,9 +771,10 @@ def stage_ssgd_hashed(s: Smoke):
     if not loss < 0.68:
         raise AssertionError(f"held-out log-loss {loss:.4f} after 12 "
                              f"steps (zero weights: 0.6931)")
-    return (f"dp={mesh.shape['data']} | table {tuple(X.shape)} | vmem "
-            f"against xla passes {err:.2g} | held-out log-loss "
-            f"{loss:.4f} acc {acc:.4f}")
+    return (f"dp={mesh.shape['data']} | table {tuple(X.shape)} | "
+            f"{forms[0]} fields by value ({forms[2]} values), {forms[1]} "
+            f"by address | vmem against xla passes {err:.2g} | held-out "
+            f"log-loss {loss:.4f} acc {acc:.4f}")
 
 
 def _comm_stage(s: Smoke, comm: str):
@@ -808,7 +816,10 @@ STAGES = (
                    "pallas_lloyd_wide._wide_scatter_kernel"))),
     ("ssgd_hashed", stage_ssgd_hashed,
      dict(kernels=("pallas_hashed._hashed_gather_kernel",
-                   "pallas_hashed._hashed_scatter_kernel"))),
+                   "pallas_hashed._hashed_scatter_kernel",
+                   "pallas_hashed._hashed_rows_kernel",
+                   "pallas_hashed._hashed_value_gather_kernel",
+                   "pallas_hashed._hashed_value_sums_kernel"))),
     ("ssgd_comm_int8", functools.partial(_comm_stage, comm="int8"),
      dict(min_devices=2)),
     ("ssgd_comm_bucketed",

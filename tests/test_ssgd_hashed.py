@@ -357,7 +357,10 @@ def test_lowered_hashed_trainer_names_its_scopes(shards, mesh1, mesh4):
                   names.SSGD_UPDATE, names.SSGD_SYNC):
         assert scope + "/" in text, scope
     assert names.SSGD_KERNEL not in text       # that is the dense rows'
-    for kernel in ("_hashed_gather_kernel", "_hashed_scatter_kernel"):
+    # 490 values of field 3 fold into 385 slots: read by value
+    for kernel in ("_hashed_gather_kernel", "_hashed_scatter_kernel",
+                   "_hashed_rows_kernel", "_hashed_value_gather_kernel",
+                   "_hashed_value_sums_kernel"):
         assert kernel in text, kernel
 
 
@@ -376,11 +379,268 @@ def test_spans_and_report_say_the_row_format(mesh1, tmp_path):
     assert (prep["row_format"], prep["nnz"], prep["hash_bits"],
             prep["rows"], prep["bytes"]) == ("hashed", 5, 10, 2000,
                                              2048 * 32)
+    assert (prep["dict_fields"], prep["addr_fields"],
+            prep["dict_values"]) == (1, 4, 385)
     assert "ssgd:generate" in ends and "ssgd:heldout" in ends
     seg = ends["train:segment"]
     assert (seg["row_format"], seg["gather_form"], seg["scatter_form"],
             seg["draw_form"]) == ("hashed", "vmem", "vmem", "few")
+    assert (seg["dict_fields"], seg["addr_fields"],
+            seg["dict_values"]) == (1, 4, 385)
     lines = report.render(report.summarize(evts)).splitlines()
     for line in ("row format: hashed", "block draw: few",
-                 "gather pass: vmem", "scatter pass: vmem"):
+                 "gather pass: vmem", "scatter pass: vmem",
+                 "fields by value: 1 (385 values), by address: 4"):
         assert line in lines, line
+
+
+# ---- fields read by value (PR 33) ---------------------------------------
+
+def _by_value_table(kind, geom, nb):
+    """A table and the dictionaries a loader would state for it: fields
+    1 and 3 (and 4 in 'twice') take few slots, the rest any."""
+    B, F, nnz, D = geom.block_rows, geom.fields_held, geom.nnz, geom.n_slots
+    rng = np.random.default_rng(3)
+    X = rng.integers(0, D, (nb, F, B)).astype(np.int32)
+    dicts = [None] * nnz
+    for f, c in ((1, 5), (3, 40)):
+        dicts[f] = rng.choice(D, c, replace=False).astype(np.int32)
+    if kind == "folded":
+        # two values of field 3 fold to one slot: the loader's list has
+        # the slot twice, the plan once, and rows of both values hold it
+        dicts[3] = np.concatenate([dicts[3], dicts[3][:7]])
+    elif kind == "meets_addr":
+        # field 0 is read by address and holds, on every other row, a
+        # slot of field 1's dictionary
+        X[:, 0, ::2] = dicts[1][2]
+    elif kind == "twice":
+        # fields 3 and 4 state one dictionary and agree on every row
+        dicts[4] = dicts[3].copy()
+    for f, d in enumerate(dicts):
+        if d is not None:
+            X[:, f, :] = d[rng.integers(0, len(d), (nb, B))]
+    if kind == "twice":
+        X[:, 4, :] = X[:, 3, :]
+    X[:, nnz, :] = rng.integers(0, 2, (nb, B))
+    X[:, nnz + 1:, :] = 0
+    return X, dicts
+
+
+@pytest.mark.parametrize("block_rows", [128, 1024])
+@pytest.mark.parametrize("kind", ["plain", "folded", "meets_addr", "twice",
+                                  "tiles"])
+def test_fields_by_value_and_by_address_against_xla(kind, block_rows,
+                                                    monkeypatch):
+    geom = ph.HashedGeometry(nnz=6, hash_bits=10, block_rows=block_rows)
+    nb = 5
+    X, dicts = _by_value_table(kind, geom, nb)
+    if kind == "tiles":
+        # three sampled blocks in tiles of two: the last tile's second
+        # block is padding
+        monkeypatch.setattr(ph, "VALUE_TILE_ROWS", 2 * block_rows)
+    plan = ph.field_plan(geom, dicts)
+    want = (1, 3, 4) if kind == "twice" else (1, 3)
+    assert plan.dict_fields == want
+    assert plan.addr_fields == tuple(f for f in range(6) if f not in want)
+    assert plan.n_values == sum(len(np.unique(dicts[f])) for f in want)
+    ids = jnp.array([3, 0, 4], jnp.int32)
+    rng = np.random.default_rng(1)
+    w = np.zeros((geom.w_len,), np.float32)
+    w[:geom.n_slots + 1] = rng.normal(size=geom.n_slots + 1)
+    r = rng.normal(size=(3, block_rows)).astype(np.float32)
+    Xj, wj, rj = jnp.asarray(X), jnp.asarray(w), jnp.asarray(r)
+    m = ph.margins(Xj, wj, ids, geom, plan=plan, interpret=True)
+    g = ph.slot_sums(Xj, rj, ids, geom, plan=plan, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(m), np.asarray(ph.margins_xla(Xj, wj, ids, geom)),
+        atol=1e-5)
+    g_xla = np.asarray(ph.slot_sums_xla(Xj, rj, ids, geom))
+    np.testing.assert_allclose(np.asarray(g), g_xla, atol=2e-4)
+    assert g.shape == (geom.w_len,)
+    assert not np.any(np.asarray(g)[geom.n_slots + 1:])
+    if kind == "twice":
+        # a residual of one counts a shared slot twice a row
+        ones = jnp.ones_like(rj)
+        n1 = np.asarray(ph.slot_sums(Xj, ones, ids, geom, plan=plan,
+                                     interpret=True))
+        idx = X[np.asarray(ids)][:, :6, :]
+        counts = np.zeros((geom.n_slots,), np.int64)
+        np.add.at(counts, idx, 1)
+        np.testing.assert_array_equal(n1[:geom.n_slots], counts)
+        assert counts[dicts[3]].sum() >= 2 * 3 * block_rows
+
+
+def test_every_field_by_value_needs_no_address_pass():
+    geom = ph.HashedGeometry(nnz=2, hash_bits=10, block_rows=128)
+    rng = np.random.default_rng(5)
+    dicts = [rng.choice(1024, 9, replace=False).astype(np.int32),
+             rng.choice(1024, 3, replace=False).astype(np.int32)]
+    X = np.zeros((3, 8, 128), np.int32)
+    for f, d in enumerate(dicts):
+        X[:, f, :] = d[rng.integers(0, len(d), (3, 128))]
+    X[:, 2, :] = rng.integers(0, 2, (3, 128))
+    plan = ph.field_plan(geom, dicts)
+    assert plan.addr_fields == () and plan.dict_fields == (0, 1)
+    ids = jnp.array([2, 0], jnp.int32)
+    w = jnp.asarray(rng.normal(size=geom.w_len).astype(np.float32)
+                    ).at[geom.n_slots + 1:].set(0)
+    r = jnp.asarray(rng.normal(size=(2, 128)).astype(np.float32))
+    Xj = jnp.asarray(X)
+    np.testing.assert_allclose(
+        np.asarray(ph.margins(Xj, w, ids, geom, plan=plan, interpret=True)),
+        np.asarray(ph.margins_xla(Xj, w, ids, geom)), atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(ph.slot_sums(Xj, r, ids, geom, plan=plan,
+                                interpret=True)),
+        np.asarray(ph.slot_sums_xla(Xj, r, ids, geom)), atol=2e-4)
+
+
+@pytest.mark.parametrize("n_values,block_rows,form", [
+    (1, 8192, "dict"), (ph.DICT_MAX_VALUES, 8192, "dict"),
+    (ph.DICT_MAX_VALUES + 1, 8192, "addr"), (0, 8192, "addr"),
+    (ph.DICT_MAX_VALUES, 1024, "dict"),
+    # a block of 128 rows fills an eighth of a vector
+    (ph.DICT_MAX_VALUES // 8, 128, "dict"),
+    (ph.DICT_MAX_VALUES // 8 + 1, 128, "addr"),
+    (ph.DICT_MAX_VALUES * 3 // 4, 1536, "dict"),
+    (ph.DICT_MAX_VALUES * 3 // 4 + 1, 1536, "addr"),
+    (5, 64, "addr"),                        # not whole lanes
+])
+def test_field_form_follows_from_the_dictionary_and_the_block(
+        n_values, block_rows, form):
+    assert ph.field_form(n_values, block_rows) == form
+
+
+@pytest.mark.parametrize("hash_bits,block_rows", [
+    (23, 8192), (9, 128), (20, 64)])
+def test_no_field_is_read_by_value_where_the_passes_are_xla(hash_bits,
+                                                            block_rows):
+    geom = ph.HashedGeometry(3, hash_bits, block_rows)
+    assert geom.pass_form == "xla"
+    dicts = [np.arange(4, dtype=np.int32)] * 3
+    assert ph.field_plan(geom, dicts) is None
+
+
+def test_field_plan_lays_the_dictionaries_out_in_whole_groups():
+    geom = ph.HashedGeometry(nnz=4, hash_bits=12, block_rows=1024)
+    long = np.arange(ph.DICT_MAX_VALUES + 1, dtype=np.int32)
+    dicts = [np.array([9, 3, 3, 7], np.int32), long, None,
+             np.arange(100, 100 + ph.VALUE_GROUP + 1, dtype=np.int32)]
+    plan = ph.field_plan(geom, dicts)
+    assert (plan.dict_fields, plan.addr_fields) == ((0, 3), (1, 2))
+    G = ph.VALUE_GROUP
+    assert list(plan.group_field) == [0, 1, 1]
+    assert list(plan.entries[:G]) == [3, 7, 9] + [ph.NO_SLOT] * (G - 3)
+    assert list(plan.entries[G:2 * G]) == list(range(100, 100 + G))
+    assert plan.entries[2 * G] == 100 + G
+    assert (plan.entries[2 * G + 1:] == ph.NO_SLOT).all()
+    assert plan.n_values == 3 + G + 1
+    # nothing stated, nothing short enough: every field by address
+    assert ph.field_plan(geom, None) is None
+    assert ph.field_plan(geom, [None, long, None, None]) is None
+    with pytest.raises(ValueError, match="leaves the table"):
+        ph.field_plan(geom, [np.array([1 << 12], np.int32)] + [None] * 3)
+    with pytest.raises(ValueError, match="dictionaries for"):
+        ph.field_plan(geom, [None] * 3)
+
+
+def test_a_meta_without_dictionaries_runs_the_passes_as_they_were(mesh1):
+    """The loader's ``meta`` less its dictionaries has no plan, and the
+    passes are the by-address kernels over every field, bit for bit;
+    with the dictionaries the weights agree to float32 rounding."""
+    cfg = _cfg(128, 0.5, 3)
+    fn, X, w0, meta = ssgd.prepare_hashed_synthetic(
+        3000, 5, 10, mesh1, cfg, data_seed=6)
+    plan = ssgd.hashed_field_plan(cfg, meta)
+    assert plan is not None and plan.dict_fields
+    bare = {k: v for k, v in meta.items() if k != "dictionaries"}
+    assert ssgd.hashed_field_plan(cfg, bare) is None
+    geom = ssgd.hashed_geometry(cfg, bare)
+    ids = jnp.array([5, 2, 9], jnp.int32)
+    key = jax.random.key(4)
+    w = jax.random.normal(key, (geom.w_len,)).at[geom.n_slots + 1:].set(0)
+    r = jax.random.normal(jax.random.fold_in(key, 1), (3, 128))
+    every = tuple(range(geom.nnz))
+    np.testing.assert_array_equal(
+        np.asarray(ph.margins(X, w, ids, geom, interpret=True)),
+        np.asarray(ph.margins_vmem(X, w, ids, geom, interpret=True,
+                                   fields=every)))
+    np.testing.assert_array_equal(
+        np.asarray(ph.slot_sums(X, r, ids, geom, interpret=True)),
+        np.asarray(ph.slot_sums_vmem(X, r, ids, geom, interpret=True,
+                                     fields=every)))
+    text = ssgd.make_train_fn_fused(mesh1, cfg, bare).lower(
+        X, *[jnp.zeros((1,), jnp.float32)] * 4, w0).as_text(
+        debug_info=True)
+    assert "_hashed_gather_kernel" in text
+    for kernel in ("_hashed_value_gather_kernel",
+                   "_hashed_value_sums_kernel", "_hashed_rows_kernel"):
+        assert kernel not in text, kernel
+    d = jnp.zeros((1,), jnp.float32)
+    w_bare, _ = ssgd.make_train_fn_fused(mesh1, cfg, bare)(
+        X, d, d, d, d, w0)
+    w_dict, _ = fn(X, d, d, d, d, w0)
+    n = (1 << 10) + 1
+    assert np.linalg.norm(np.asarray(w_bare)) > 0.01
+    assert ref_mod.rel_err(np.asarray(w_dict)[:n], np.asarray(w_bare)[:n],
+                           np.zeros(n)) < W_LIMIT
+
+
+def test_padding_rows_of_a_field_read_by_value_add_nothing(mesh1):
+    """The invalid tail again, with a field known to be read by value:
+    all blocks sampled, one step from zero weights."""
+    n_rows, nnz, bits = 1000, 5, 10
+    cfg = _cfg(128, 1.0, 1)
+    w, X, meta = _train(mesh1, n_rows, nnz, bits, cfg, seed=2)
+    plan = ssgd.hashed_field_plan(cfg, meta)
+    assert plan.dict_fields and plan.addr_fields
+    Xn = np.asarray(X)
+    flat = Xn.transpose(0, 2, 1).reshape(-1, Xn.shape[1])
+    resid = 0.5 - flat[:n_rows, nnz].astype(np.float64)
+    f = plan.dict_fields[0]
+    own = np.zeros((1 << bits,), np.float64)
+    np.add.at(own, flat[:n_rows, f], resid)
+    every = np.zeros((1 << bits,), np.float64)
+    np.add.at(every, flat[:n_rows, :nnz], resid[:, None] * np.ones((1, nnz)))
+    np.testing.assert_allclose(w[:1 << bits], -0.1 * every / n_rows,
+                               atol=1e-7)
+    # the padding rows hold slots of the field's dictionary like any row
+    pad_slots = flat[n_rows:, f]
+    assert len(pad_slots) and np.isin(pad_slots,
+                                      meta["dictionaries"][f]).all()
+    assert np.abs(own).max() > 1
+
+
+@pytest.mark.parametrize("hash_bits", [10, 20, 22])
+def test_every_drawn_slot_is_in_its_fields_dictionary(hash_bits):
+    cards = datasets.click_field_cardinalities(39)
+    dicts = datasets.click_field_dictionaries(cards, hash_bits)
+    make_rows = datasets.hashed_click_rows(cards, hash_bits)
+    slots, _ = jax.jit(make_rows)(jnp.arange(30000) + 45_000_000,
+                                  jnp.int32(3))
+    slots = np.asarray(slots)
+    stated = 0
+    for f, (c, d) in enumerate(zip(cards, dicts)):
+        if c > datasets.DICTIONARY_MAX_VALUES:
+            assert d is None
+            continue
+        stated += 1
+        assert d.dtype == np.int32 and len(d) <= min(c, 1 << hash_bits)
+        assert (np.diff(d) > 0).all() and d[0] >= 0 \
+            and d[-1] < 1 << hash_bits
+        assert np.isin(slots[:, f], d).all(), f
+        # the dictionary is the slots of the field's values, one by one
+        v = np.arange(min(c, 50), dtype=np.uint32)
+        one = datasets.click_slots(np.full((1,), f, np.uint32), v,
+                                   hash_bits)
+        assert np.isin(one.astype(np.int32), d).all()
+    assert stated == 30
+    if hash_bits == 20:
+        # the cell: 21 fields by value, 18 by address
+        geom = ph.HashedGeometry(39, 20, 8192)
+        plan = ph.field_plan(geom, dicts)
+        assert (len(plan.dict_fields), len(plan.addr_fields),
+                plan.n_values) == (21, 18, 13027)
+        assert max(len(dicts[f]) for f in plan.dict_fields) == 3193
+        assert min(len(dicts[f]) for f in plan.addr_fields
+                   if dicts[f] is not None) == 4130

@@ -944,7 +944,9 @@ def test_hashed_passes_at_the_published_widths(tpu_mesh):
     within float32 summation error of their sum of magnitudes, the slot
     that nearly every row hits included; the bias slot the residuals'
     sum; a residual of 1 everywhere counts a slot's occurrences exactly.
-    The XLA form at the same ids agrees likewise."""
+    Three forms at the same ids: every field by address, the loader's
+    plan (21 fields by value against their dictionaries, 18 by
+    address), XLA's."""
     from tpu_distalg.models import ssgd
     from tpu_distalg.ops import pallas_hashed as ph
     from tpu_distalg.parallel import get_mesh
@@ -956,6 +958,14 @@ def test_hashed_passes_at_the_published_widths(tpu_mesh):
                                       data_seed=17)
     geom = ssgd.hashed_geometry(cfg, meta)
     assert geom.pass_form == "vmem" and X.shape == (25, 40, 8192)
+    plan = ssgd.hashed_field_plan(cfg, meta)
+    forms = [ph.field_form(0 if d is None else len(d), 8192)
+             for d in meta["dictionaries"]]
+    print(f"[hashed widths] fields by value {forms.count('dict')} "
+          f"({plan.n_values} values), by address {forms.count('addr')}")
+    assert (forms.count("dict"), forms.count("addr")) == (21, 18)
+    assert (len(plan.dict_fields), len(plan.addr_fields),
+            plan.n_values) == (21, 18, 13027)
     ids = jnp.array([24, 3, 11, 0, 17, 8], jnp.int32)
     key = jax.random.key(2)
     w = jax.random.normal(key, (geom.w_len,)).at[geom.n_slots + 1:].set(0)
@@ -974,6 +984,8 @@ def test_hashed_passes_at_the_published_widths(tpu_mesh):
     ones = jnp.ones_like(r)
     for name, margins, sums in (
             ("vmem", ph.margins_vmem, ph.slot_sums_vmem),
+            ("plan", functools.partial(ph.margins, plan=plan),
+             functools.partial(ph.slot_sums, plan=plan)),
             ("xla", ph.margins_xla, ph.slot_sums_xla)):
         m = np.asarray(margins(X, w, ids, geom), np.float64)
         g = np.asarray(sums(X, r, ids, geom), np.float64)

@@ -1963,12 +1963,26 @@ def _check_hashed_config(config: SSGDConfig) -> None:
             "the 'bernoulli' sampler and cannot take the format")
 
 
+def hashed_field_plan(config: SSGDConfig, meta: dict):
+    """Which fields of a hashed ``meta`` the passes read by value
+    (``pallas_hashed.field_plan`` over the dictionaries its loader
+    states), or ``None``: every field by address."""
+    from tpu_distalg.ops import pallas_hashed
+
+    return pallas_hashed.field_plan(hashed_geometry(config, meta),
+                                    meta.get("dictionaries"))
+
+
 def _hashed_fields(config: SSGDConfig, meta: dict) -> dict:
     """What the spans of a hashed run say (``tda report`` prints it)."""
     form = hashed_geometry(config, meta).pass_form
+    plan = hashed_field_plan(config, meta)
+    n_dict = len(plan.dict_fields) if plan else 0
     return {"row_format": "hashed", "nnz": meta["nnz"],
             "hash_bits": meta["hash_bits"], "gather_form": form,
-            "scatter_form": form}
+            "scatter_form": form, "dict_fields": n_dict,
+            "addr_fields": meta["nnz"] - n_dict,
+            "dict_values": plan.n_values if plan else 0}
 
 
 def _make_train_fn_hashed(mesh: Mesh, config: SSGDConfig, meta: dict):
@@ -1985,6 +1999,7 @@ def _make_train_fn_hashed(mesh: Mesh, config: SSGDConfig, meta: dict):
 
     _check_hashed_config(config)
     geom = hashed_geometry(config, meta)
+    plan = hashed_field_plan(config, meta)
     n_shards = mesh.shape[DATA_AXIS]
     n_blocks, n_sampled = fused_gather_geometry(config, meta, n_shards)
     interpret = not mesh_on_tpu(mesh)
@@ -2002,7 +2017,7 @@ def _make_train_fn_hashed(mesh: Mesh, config: SSGDConfig, meta: dict):
         shard = lax.axis_index(DATA_AXIS)
         ids = lax.dynamic_index_in_dim(idx_shards, shard, keepdims=False)
         with jax.named_scope(names.SSGD_GATHER):
-            m = pallas_hashed.margins(X, w, ids, geom,
+            m = pallas_hashed.margins(X, w, ids, geom, plan=plan,
                                       interpret=interpret)
             y = pallas_hashed.labels(X, ids, geom)
             row = ((shard * n_blocks + ids) * B)[:, None] \
@@ -2010,7 +2025,7 @@ def _make_train_fn_hashed(mesh: Mesh, config: SSGDConfig, meta: dict):
             valid = (row < n_rows).astype(jnp.float32)
             r = (jax.nn.sigmoid(m) - y) * valid
         with jax.named_scope(names.SSGD_SCATTER):
-            g = pallas_hashed.slot_sums(X, r, ids, geom,
+            g = pallas_hashed.slot_sums(X, r, ids, geom, plan=plan,
                                         interpret=interpret)
             cnt = jnp.sum(valid)
         with jax.named_scope(names.SSGD_SYNC):
@@ -2095,10 +2110,12 @@ def build_hashed_table(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
     meta = dict(row_format="hashed", nnz=nnz, hash_bits=hash_bits,
                 pack=1, n_rows=n_rows, n_padded=n_rows + (-n_rows) % mult,
                 d_total=geom.w_len, cardinalities=cards,
-                rows_kw=tuple(sorted(rows_kw.items())))
+                rows_kw=tuple(sorted(rows_kw.items())),
+                dictionaries=dsets.click_field_dictionaries(
+                    cards, hash_bits))
     with tevents.span("ssgd:prepare", rows=n_rows,
                       bytes=meta["n_padded"] * geom.row_bytes,
-                      row_format="hashed", nnz=nnz, hash_bits=hash_bits):
+                      **_hashed_fields(config, meta)):
         with tevents.span("ssgd:generate", rows=meta["n_padded"]):
             X = hashed_table_fn(mesh, n_rows, meta["n_padded"], geom,
                                 cards, meta["rows_kw"])(

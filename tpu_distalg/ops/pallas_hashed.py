@@ -19,9 +19,9 @@ blocks ``ids`` is
 
 with ``r`` whatever the caller made of ``m`` (residual times validity).
 Both are bound by addresses and not by bytes: 35.8M dependent accesses
-a step at the benchmark's shape beside 73 MB read. Each has two forms
-that give the same numbers up to the order of float32 additions, and
-:func:`pass_form` picks one from the geometry alone:
+a step at the benchmark's shape beside 73 MB read. Each pass has two
+forms that give the same numbers up to the order of float32 additions,
+and :func:`pass_form` picks one from the geometry alone:
 
 ``vmem``  Mosaic: the table (or the accumulators) ``(n_slots / 128,
           128)`` stays in VMEM, a chunk of the block's indices comes
@@ -38,8 +38,39 @@ that give the same numbers up to the order of float32 additions, and
           the only form where the table is past VMEM or a block is not
           whole lanes.
 
-Interpreted on the CPU the kernels run the same loads, masks and adds
-in the same order.
+Inside the ``vmem`` form a field is read in one of two ways, and
+:func:`field_form` picks one from the size of the field's dictionary:
+every slot the field can hold, which the loader states in its ``meta``
+where a field takes few values (a click log's device type or weekday,
+not its user id). :func:`field_plan` makes the choice for a table.
+
+``addr``  by address, as above: the loops of the two kernels run over
+          these fields only.
+``dict``  by value: ``_hashed_rows_kernel`` copies the field's rows of
+          the sampled blocks from one sublane of a block's tiles to
+          whole vectors of 1024 rows, and for every entry ``d`` of the
+          dictionary ``_hashed_value_gather_kernel`` keeps ``w[d]``
+          where a row's slot equals ``d``, ``_hashed_value_sums_kernel``
+          the row's residual, summed into one float32 vector an entry
+          that XLA folds and adds at ``d``. Two vector operations an
+          entry and 1024 rows for the margins, three for the sums, no
+          address and no chain; every (row, field) occurrence still
+          counts once, in float32. A table whose ``meta`` states no
+          dictionary, or none short enough, runs the ``addr`` loops
+          over every field, operation for operation as before there
+          was a choice.
+
+``DICT_MAX_VALUES`` is where the two cross on one v5e at the
+benchmark's shape (PR 33's Step 0, ``scripts/step0_hashed_fields.py``):
+by address a field costs both passes 2.31 ms a step of 458 752 rows
+(5.0 ns a (row, field) pair; 39 fields against 18), by value 0.583 us an
+entry (1.30 ns an entry and 1024 rows; a field of 1024, 2048, 4096
+entries), equal at 3960 entries; the constant stands 4% under that. A
+block shorter than 1024 rows fills part of a vector, and the bound
+falls in proportion.
+
+Interpreted on the CPU the kernels run the same loads, masks, compares
+and adds in the same order.
 """
 
 from __future__ import annotations
@@ -49,6 +80,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -63,6 +95,14 @@ ACC_VMEM_BYTES = 64 << 20   # ... and what they and their second buffers
 #                             2**22
 VMEM_BITS = 22         # a table of 16 MB and its accumulators fit VMEM
 MIN_BITS = 10          # one (8, 128) tile of slots
+DICT_MAX_VALUES = 3800  # a field of so many slots or fewer is read by
+#                         value where its rows fill whole vectors (the
+#                         module docstring says where it comes from)
+VALUE_GROUP = 8        # dictionary entries a grid step compares
+VALUE_ROWS = SUBLANES * LANES   # rows one vector of a field holds
+VALUE_TILE_ROWS = 1 << 19   # rows of one field a grid step keeps in VMEM
+NO_SLOT = -1           # pads a dictionary to whole groups: no row holds it
+NO_ROW = -2            # pads the sampled blocks to whole tiles
 
 
 def _round_up(n: int, m: int) -> int:
@@ -75,6 +115,67 @@ def pass_form(hash_bits: int, block_rows: int) -> str:
     if MIN_BITS <= hash_bits <= VMEM_BITS and block_rows % LANES == 0:
         return "vmem"
     return "xla"
+
+
+def field_form(n_values: int, block_rows: int) -> str:
+    """How one field of a block is read: ``'dict'`` (every row compared
+    with each of the ``n_values`` slots the field can hold) or ``'addr'``
+    (each row's slot chased by address). By value costs ``n_values``
+    times the vectors a block's rows fill, by address the block's rows:
+    they cross at ``DICT_MAX_VALUES`` values where the rows fill whole
+    vectors, and lower in proportion where a block is shorter than a
+    vector."""
+    if n_values < 1 or block_rows % LANES:
+        return "addr"
+    fill = block_rows / _round_up(block_rows, VALUE_ROWS)
+    return "dict" if n_values <= DICT_MAX_VALUES * fill else "addr"
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class FieldPlan:
+    """Which fields are read by value, and their dictionaries laid out
+    for the by-value passes: one after another, each padded with
+    ``NO_SLOT`` to whole groups of ``VALUE_GROUP`` entries."""
+
+    addr_fields: tuple
+    dict_fields: tuple
+    entries: np.ndarray        # int32[n_groups * VALUE_GROUP]
+    group_field: np.ndarray    # int32[n_groups]: index into dict_fields
+
+    @property
+    def n_values(self) -> int:
+        return int(np.count_nonzero(self.entries != NO_SLOT))
+
+
+def field_plan(geom: "HashedGeometry", dictionaries) -> FieldPlan | None:
+    """The plan for a table whose loader states ``dictionaries`` (for
+    each of the ``nnz`` fields every slot it can hold, or ``None``), or
+    ``None`` where every field is read by address: no dictionary
+    stated, none short enough, or the passes in their ``xla`` form."""
+    if dictionaries is None or geom.pass_form != "vmem":
+        return None
+    if len(dictionaries) != geom.nnz:
+        raise ValueError(f"{len(dictionaries)} dictionaries for "
+                         f"{geom.nnz} fields")
+    dict_fields = tuple(
+        f for f, d in enumerate(dictionaries) if d is not None
+        and field_form(len(d), geom.block_rows) == "dict")
+    if not dict_fields:
+        return None
+    entries, group_field = [], []
+    for n, f in enumerate(dict_fields):
+        d = np.unique(np.asarray(dictionaries[f], np.int32))
+        if d[0] < 0 or d[-1] >= geom.n_slots:
+            raise ValueError(f"field {f}'s dictionary leaves the table")
+        groups = -(-len(d) // VALUE_GROUP)
+        entries.append(np.full((groups * VALUE_GROUP,), NO_SLOT, np.int32))
+        entries[-1][:len(d)] = d
+        group_field += [n] * groups
+    return FieldPlan(
+        addr_fields=tuple(f for f in range(geom.nnz)
+                          if f not in dict_fields),
+        dict_fields=dict_fields, entries=np.concatenate(entries),
+        group_field=np.asarray(group_field, np.int32))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -152,25 +253,25 @@ def slot_sums_xla(X, r, ids, geom: HashedGeometry):
 
 # ---- Mosaic forms ------------------------------------------------------
 
-def _hashed_gather_kernel(ids_ref, idx_ref, w_ref, out_ref, *, nnz: int,
-                          rows: int):
-    """One chunk of one sampled block: ``out[i, :]`` holds row ``i``'s
-    ``nnz`` weights in the lanes their slots have in the table, slots of
-    one lane added up; the sum over lanes is the margin less the bias.
-    ``GATHER_SUMS`` partial vectors keep the adds of one row off one
-    chain."""
+def _hashed_gather_kernel(ids_ref, idx_ref, w_ref, out_ref, *,
+                          fields: tuple, rows: int):
+    """One chunk of one sampled block: ``out[i, :]`` holds the weights
+    of row ``i``'s ``fields`` in the lanes their slots have in the
+    table, slots of one lane added up; the sum over lanes is their
+    share of the margin. ``GATHER_SUMS`` partial vectors keep the adds
+    of one row off one chain."""
     del ids_ref                         # the index maps read it
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
     def some(t, carry):
         first = pl.multiple_of(t * rows, rows)
         for u in range(rows):
-            sums = [None] * min(GATHER_SUMS, nnz)
-            for j in range(nnz):
+            sums = [None] * min(GATHER_SUMS, len(fields))
+            for n, j in enumerate(fields):
                 h = idx_ref[j, first + u]
                 got = jnp.where(lane == (h & (LANES - 1)),
                                 w_ref[pl.ds(h >> 7, 1), :], 0.0)
-                k = j % len(sums)
+                k = n % len(sums)
                 sums[k] = got if sums[k] is None else sums[k] + got
             while len(sums) > 1:        # pairwise, a fixed order
                 sums = [a + b for a, b in zip(sums[::2], sums[1::2])] \
@@ -181,12 +282,12 @@ def _hashed_gather_kernel(ids_ref, idx_ref, w_ref, out_ref, *, nnz: int,
     jax.lax.fori_loop(0, idx_ref.shape[1] // rows, some, 0)
 
 
-def _hashed_scatter_kernel(ids_ref, idx_ref, rb_ref, *accs, nnz: int,
-                           rows: int):
+def _hashed_scatter_kernel(ids_ref, idx_ref, rb_ref, *accs,
+                           fields: tuple, rows: int):
     """One chunk of one sampled block into the accumulators ``(n_slots /
     128, 128)``, which stay in VMEM over the whole grid: row ``i``'s
-    residual (``rb[i, :]``, the same in every lane) is added at each of
-    its ``nnz`` slots, in lane ``h & 127`` of row ``h >> 7``.
+    residual (``rb[i, :]``, the same in every lane) is added at the
+    slot of each of its ``fields``, in lane ``h & 127`` of row ``h >> 7``.
     Neighbouring accesses go to different accumulators, each its own
     allocation."""
     del ids_ref
@@ -201,9 +302,9 @@ def _hashed_scatter_kernel(ids_ref, idx_ref, rb_ref, *accs, nnz: int,
         first = pl.multiple_of(t * rows, rows)
         for u in range(rows):
             r = rb_ref[pl.ds(first + u, 1), :]
-            for j in range(nnz):
+            for n, j in enumerate(fields):
                 h = idx_ref[j, first + u]
-                acc = accs[(u * nnz + j) % len(accs)]
+                acc = accs[(u * len(fields) + n) % len(accs)]
                 acc[pl.ds(h >> 7, 1), :] += jnp.where(
                     lane == (h & (LANES - 1)), r, 0.0)
         return carry
@@ -232,11 +333,19 @@ def _vmem_limit(geom: HashedGeometry, tables: int) -> int:
             + 8 * geom.chunk_rows * LANES * 4 + (8 << 20))
 
 
+def _fields(geom: HashedGeometry, fields) -> tuple:
+    return tuple(range(geom.nnz)) if fields is None else tuple(fields)
+
+
 def margins_vmem(X, w, ids, geom: HashedGeometry, *,
-                 interpret: bool = False, rows: int | None = None):
+                 interpret: bool = False, rows: int | None = None,
+                 fields=None):
+    """The margins by address over ``fields`` (all of them unless
+    given), the bias added."""
     cr = geom.chunk_rows
     table = w[:geom.n_slots].reshape(geom.n_slots // LANES, LANES)
-    kernel = functools.partial(_hashed_gather_kernel, nnz=geom.nnz,
+    kernel = functools.partial(_hashed_gather_kernel,
+                               fields=_fields(geom, fields),
                                rows=_loop_rows(geom, rows))
     parts = pl.pallas_call(
         kernel,
@@ -257,12 +366,15 @@ def margins_vmem(X, w, ids, geom: HashedGeometry, *,
 
 def slot_sums_vmem(X, r, ids, geom: HashedGeometry, *,
                    interpret: bool = False, n_acc: int | None = None,
-                   rows: int | None = None):
+                   rows: int | None = None, fields=None):
+    """The per-slot sums by address over ``fields`` (all of them unless
+    given), the residuals' sum where the bias is."""
     cr = geom.chunk_rows
     n_acc = geom.scatter_accs if n_acc is None else n_acc
     shape = (geom.n_slots // LANES, LANES)
     rb = jnp.broadcast_to(r[:, :, None], r.shape + (LANES,))
-    kernel = functools.partial(_hashed_scatter_kernel, nnz=geom.nnz,
+    kernel = functools.partial(_hashed_scatter_kernel,
+                               fields=_fields(geom, fields),
                                rows=_loop_rows(geom, rows))
     accs = pl.pallas_call(
         kernel,
@@ -284,23 +396,229 @@ def slot_sums_vmem(X, r, ids, geom: HashedGeometry, *,
     return jnp.concatenate([g, tail])
 
 
+# ---- by value: the fields whose dictionaries the loader states -----------
+
+def _hashed_value_gather_kernel(gf_ref, d_ref, wd_ref, x_ref, out_ref, *,
+                                group: int):
+    """One group of one field's dictionary against a tile of sampled
+    blocks: ``x[b]`` is the field's slots of block ``b``'s rows as whole
+    vectors ``(block_rows / 128, 128)``; a row takes the weight of the
+    entry its slot equals (at most one of a group: a dictionary's
+    entries differ), added into ``out``, which stays in VMEM over the
+    tile's groups."""
+    del gf_ref                          # the index maps read it
+    g = pl.program_id(1)
+    ds = [d_ref[g * group + k] for k in range(group)]
+    ws = [wd_ref[g * group + k] for k in range(group)]
+
+    @pl.when(g == 0)
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    def one(b, carry):
+        x = x_ref[b]
+        got = jnp.zeros(x.shape, jnp.float32)
+        for d, wv in zip(ds, ws):
+            got = jnp.where(x == d, wv, got)
+        out_ref[b] += got
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0], one, 0)
+
+
+def _hashed_value_sums_kernel(gf_ref, d_ref, r_ref, x_ref, out_ref, *,
+                              group: int, fold: int):
+    """The same group and tile for the sums: ``out[k]`` is one float32
+    vector of entry ``k``'s residuals, a row's residual kept where its
+    slot equals the entry, the tile's blocks and a block's vectors added
+    up; XLA folds the vector."""
+    del gf_ref
+    g = pl.program_id(1)
+    ds = [d_ref[g * group + k] for k in range(group)]
+
+    def one(b, accs):
+        x, r = x_ref[b], r_ref[b]
+        out = []
+        for acc, d in zip(accs, ds):
+            kept = jnp.where(x == d, r, 0.0)
+            if kept.shape[0] != fold:
+                kept = jnp.sum(kept.reshape(-1, fold, LANES), axis=0)
+            out.append(acc + kept)
+        return tuple(out)
+
+    accs = jax.lax.fori_loop(
+        0, x_ref.shape[0], one,
+        tuple(jnp.zeros((fold, LANES), jnp.float32) for _ in ds))
+    for k, acc in enumerate(accs):
+        out_ref[k] = acc
+
+
+def _value_tiles(n_sampled: int, geom: HashedGeometry):
+    """``(blocks a tile, tiles)``: a grid step keeps one field's slots
+    of a tile of sampled blocks in VMEM."""
+    tb = max(1, min(n_sampled, VALUE_TILE_ROWS // geom.block_rows))
+    return tb, -(-n_sampled // tb)
+
+
+def _hashed_rows_kernel(ids_ref, x_ref, out_ref, *, fields: tuple):
+    """One sampled block: the rows of each of ``fields``, one sublane
+    of the block's tiles, written out as whole vectors."""
+    del ids_ref
+    for n, f in enumerate(fields):
+        out_ref[n] = x_ref[pl.ds(f, 1), :].reshape(out_ref.shape[1:])
+
+
+def dict_rows(X, ids, geom: HashedGeometry, plan: FieldPlan, *,
+              interpret: bool = False):
+    """``int32[n_tiles * tb, dict fields, block_rows / 128, 128]``: the
+    sampled blocks' slots of the by-value fields, a field's rows of a
+    block brought from one sublane of the block's tiles to whole
+    vectors (a copy; ``X`` stays as it is). Blocks past the sampled
+    ones hold ``NO_ROW``."""
+    ns, rows = ids.shape[0], geom.block_rows // LANES
+    tb, n_tiles = _value_tiles(ns, geom)
+    n_dict = len(plan.dict_fields)
+    x = pl.pallas_call(
+        functools.partial(_hashed_rows_kernel, fields=plan.dict_fields),
+        name="_hashed_rows_kernel",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(ns,),
+            in_specs=[pl.BlockSpec(
+                (None, geom.fields_held, geom.block_rows),
+                lambda s, ids: (ids[s], 0, 0))],
+            out_specs=pl.BlockSpec((None, n_dict, rows, LANES),
+                                   lambda s, ids: (s, 0, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((ns, n_dict, rows, LANES),
+                                       jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * (geom.fields_held + n_dict)
+            * geom.block_rows * 4 + (8 << 20)),
+        interpret=interpret,
+    )(ids, X)
+    pad = tb * n_tiles - ns
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0), (0, 0), (0, 0)),
+                    constant_values=NO_ROW)
+    return x
+
+
+def _value_grid_spec(n_sampled, geom: HashedGeometry, plan: FieldPlan,
+                     other_in, out_specs):
+    """The grid (tiles of sampled blocks, groups of entries); ``x``,
+    the tile's slots of the group's field, is the last input."""
+    tb, n_tiles = _value_tiles(n_sampled, geom)
+    rows = geom.block_rows // LANES
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_tiles, len(plan.group_field)),
+        in_specs=other_in + [
+            pl.BlockSpec((tb, None, rows, LANES),
+                         lambda t, g, gf, d: (t, gf[g], 0, 0))],
+        out_specs=out_specs)
+
+
+def _value_params(n_sampled, geom: HashedGeometry):
+    tb, _ = _value_tiles(n_sampled, geom)
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        # a field's slots, the residuals or margins beside them, each
+        # with its second buffer
+        vmem_limit_bytes=6 * tb * geom.block_rows * 4 + (8 << 20))
+
+
+def margins_dict(X, w, ids, geom: HashedGeometry, plan: FieldPlan, *,
+                 interpret: bool = False):
+    """``f32[n_sampled, block_rows]``: the by-value fields' share of
+    every row's margin."""
+    ns, rows = ids.shape[0], geom.block_rows // LANES
+    tb, n_tiles = _value_tiles(ns, geom)
+    # an entry's weight, once a step (a padding entry's is never kept)
+    wd = w[jnp.maximum(jnp.asarray(plan.entries), 0)]
+    out = pl.pallas_call(
+        functools.partial(_hashed_value_gather_kernel, group=VALUE_GROUP),
+        name="_hashed_value_gather_kernel",
+        grid_spec=_value_grid_spec(
+            ns, geom, plan, [pl.BlockSpec(memory_space=pltpu.SMEM)],
+            pl.BlockSpec((tb, rows, LANES),
+                         lambda t, g, gf, d: (t, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((tb * n_tiles, rows, LANES),
+                                       jnp.float32),
+        compiler_params=_value_params(ns, geom),
+        interpret=interpret,
+    )(jnp.asarray(plan.group_field), jnp.asarray(plan.entries), wd,
+      dict_rows(X, ids, geom, plan, interpret=interpret))
+    return out[:ns].reshape(ns, geom.block_rows)
+
+
+def slot_sums_dict(X, r, ids, geom: HashedGeometry, plan: FieldPlan, *,
+                   interpret: bool = False):
+    """``(slots int32[n_values], sums f32[n_values])``: for every entry
+    of every by-value field's dictionary the residuals of the rows that
+    hold it; two fields' entries of one slot are two elements."""
+    ns, rows = ids.shape[0], geom.block_rows // LANES
+    tb, n_tiles = _value_tiles(ns, geom)
+    fold = SUBLANES if rows % SUBLANES == 0 else rows
+    rv = r.reshape(ns, rows, LANES)
+    if tb * n_tiles > ns:
+        rv = jnp.pad(rv, ((0, tb * n_tiles - ns), (0, 0), (0, 0)))
+    n_entries = len(plan.entries)
+    parts = pl.pallas_call(
+        functools.partial(_hashed_value_sums_kernel, group=VALUE_GROUP,
+                          fold=fold),
+        name="_hashed_value_sums_kernel",
+        grid_spec=_value_grid_spec(
+            ns, geom, plan,
+            [pl.BlockSpec((tb, rows, LANES),
+                          lambda t, g, gf, d: (t, 0, 0))],
+            pl.BlockSpec((None, VALUE_GROUP, fold, LANES),
+                         lambda t, g, gf, d: (t, g, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_tiles, n_entries, fold, LANES), jnp.float32),
+        compiler_params=_value_params(ns, geom),
+        interpret=interpret,
+    )(jnp.asarray(plan.group_field), jnp.asarray(plan.entries), rv,
+      dict_rows(X, ids, geom, plan, interpret=interpret))
+    real = np.flatnonzero(plan.entries != NO_SLOT)
+    return plan.entries[real], jnp.sum(parts, axis=(0, 2, 3))[real]
+
+
 # ---- what the trainer calls ---------------------------------------------
 
-def margins(X, w, ids, geom: HashedGeometry, *, interpret: bool = False):
+def margins(X, w, ids, geom: HashedGeometry, *,
+            plan: FieldPlan | None = None, interpret: bool = False):
     """``f32[n_sampled, block_rows]``: every row's margin under ``w``,
     the rows of the blocks ``ids`` of ``X int32[n_blocks, fields_held,
-    block_rows]``, padding rows included."""
+    block_rows]``, padding rows included. ``plan`` (:func:`field_plan`)
+    says which fields are read by value."""
     _check(X, geom)
-    if geom.pass_form == "vmem":
+    if geom.pass_form != "vmem":
+        return margins_xla(X, w, ids, geom)
+    if plan is None:
         return margins_vmem(X, w, ids, geom, interpret=interpret)
-    return margins_xla(X, w, ids, geom)
+    m = margins_dict(X, w, ids, geom, plan, interpret=interpret)
+    if not plan.addr_fields:
+        return m + w[geom.n_slots]
+    return m + margins_vmem(X, w, ids, geom, interpret=interpret,
+                            fields=plan.addr_fields)
 
 
-def slot_sums(X, r, ids, geom: HashedGeometry, *, interpret: bool = False):
+def slot_sums(X, r, ids, geom: HashedGeometry, *,
+              plan: FieldPlan | None = None, interpret: bool = False):
     """``f32[w_len]``: ``r f32[n_sampled, block_rows]`` added at every
     (row, field) occurrence's slot, once each; the sum of ``r`` where
     the bias is."""
     _check(X, geom)
-    if geom.pass_form == "vmem":
+    if geom.pass_form != "vmem":
+        return slot_sums_xla(X, r, ids, geom)
+    if plan is None:
         return slot_sums_vmem(X, r, ids, geom, interpret=interpret)
-    return slot_sums_xla(X, r, ids, geom)
+    slots, sums = slot_sums_dict(X, r, ids, geom, plan,
+                                 interpret=interpret)
+    if plan.addr_fields:
+        g = slot_sums_vmem(X, r, ids, geom, interpret=interpret,
+                           fields=plan.addr_fields)
+    else:
+        g = jnp.zeros((geom.w_len,), jnp.float32).at[geom.n_slots].set(
+            jnp.sum(r))
+    return g.at[slots].add(sums)

@@ -131,6 +131,7 @@ def summarize(evts: list[dict]) -> dict:
     dist_forms: list[str] = []
     row_formats: list[str] = []
     pass_forms: dict[str, list[str]] = {"gather": [], "scatter": []}
+    field_splits: list[tuple] = []
     t_wall = [e["t_wall"] for e in evts if "t_wall" in e]
     for e in evts:
         ev = e.get("ev")
@@ -166,6 +167,13 @@ def summarize(evts: list[dict]) -> dict:
                 form = e.get(which + "_form")
                 if form and form not in seen:
                     seen.append(form)
+            # and how many of a row's fields those passes read by value
+            # against their dictionaries (pallas_hashed.field_form)
+            if "dict_fields" in e:
+                split = (e["dict_fields"], e.get("dict_values", 0),
+                         e.get("addr_fields", 0))
+                if split not in field_splits:
+                    field_splits.append(split)
         elif ev == "span_end":
             name = e.get("name", "?")
             open_spans[name] = open_spans.get(name, 1) - 1
@@ -237,6 +245,7 @@ def summarize(evts: list[dict]) -> dict:
         "dist_forms": dist_forms,
         "row_formats": row_formats,
         "pass_forms": pass_forms,
+        "field_splits": field_splits,
         "unfinished_phases": sorted(
             k for k, v in open_spans.items() if v > 0),
         "marks": marks,
@@ -283,6 +292,9 @@ def render(s: dict) -> str:
     for which, seen in (s.get("pass_forms") or {}).items():
         if seen:
             lines.append(f"{which} pass: {', '.join(seen)}")
+    for n_dict, n_values, n_addr in s.get("field_splits") or ():
+        lines.append(f"fields by value: {n_dict} ({n_values} values), "
+                     f"by address: {n_addr}")
     if s.get("dist_forms"):
         lines.append(f"distances: {', '.join(s['dist_forms'])}")
     if s.get("sums_forms"):

@@ -108,14 +108,50 @@ def click_field_cardinalities(nnz: int) -> tuple[int, ...]:
 
 def _mix32(x):
     """A fixed 32-bit integer mix (Wellons' lowbias32), uint32 in and
-    out."""
-    import jax.numpy as jnp
+    out (a NumPy or a jax array: the host's dictionaries and the
+    device's rows are one function)."""
+    u = np.uint32
+    x = x ^ (x >> u(16))
+    x = x * u(0x7FEB352D)
+    x = x ^ (x >> u(15))
+    x = x * u(0x846CA68B)
+    return x ^ (x >> u(16))
 
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x7FEB352D)
-    x = x ^ (x >> 15)
-    x = x * jnp.uint32(0x846CA68B)
-    return x ^ (x >> 16)
+
+def click_slots(fields, values, hash_bits: int):
+    """The slot of value ``values`` of field ``fields`` in a table of
+    ``2 ** hash_bits``: a fixed mix of the pair, no seed in it. uint32
+    arrays in (NumPy or jax, broadcast against each other), uint32 out.
+    The generator and :func:`click_field_dictionary` both call it."""
+    u = np.uint32
+    salt = (fields + u(1)) * u(0x85EBCA6B)
+    return _mix32((values + u(1)) * u(0x9E3779B1) + salt) \
+        & u((1 << hash_bits) - 1)
+
+
+# A loader states a field's dictionary up to this many values, as a
+# columnar file keeps a dictionary page for its low-cardinality columns
+# and falls back to plain values past it.
+DICTIONARY_MAX_VALUES = 1 << 16
+
+
+def click_field_dictionary(field: int, cardinality: int,
+                           hash_bits: int) -> np.ndarray:
+    """Every slot field ``field`` can hold: the slots of its values ``0
+    .. cardinality - 1``, unique and ascending, a host ``int32`` array
+    (two values that fold to one slot are one entry)."""
+    values = np.arange(cardinality, dtype=np.uint32)
+    slots = click_slots(np.full((1,), field, np.uint32), values, hash_bits)
+    return np.unique(slots).astype(np.int32)
+
+
+def click_field_dictionaries(cardinalities, hash_bits: int) -> tuple:
+    """For each field its dictionary, or ``None`` past
+    ``DICTIONARY_MAX_VALUES`` values."""
+    return tuple(
+        click_field_dictionary(f, c, hash_bits)
+        if c <= DICTIONARY_MAX_VALUES else None
+        for f, c in enumerate(cardinalities))
 
 
 def hashed_click_rows(cardinalities, hash_bits: int, *,
@@ -149,16 +185,13 @@ def hashed_click_rows(cardinalities, hash_bits: int, *,
         raise ValueError("zipf_exponent 1 has its own inverse; use "
                          "another")
     span = (cards + 1.0) ** a1 - 1.0
-    field_salt = (jnp.arange(nnz, dtype=jnp.uint32) + 1) \
-        * jnp.uint32(0x85EBCA6B)
-    mask = jnp.uint32((1 << hash_bits) - 1)
+    fields = jnp.arange(nnz, dtype=jnp.uint32)
 
     def slots_and_scores(row_keys, w_salt):
         u = jax.vmap(lambda k: jax.random.uniform(k, (nnz,)))(row_keys)
         v = jnp.floor((1.0 + u * span) ** (1.0 / a1)) - 1.0
         v = jnp.clip(v, 0.0, cards - 1.0).astype(jnp.uint32)
-        slots = _mix32((v + 1) * jnp.uint32(0x9E3779B1) + field_salt) \
-            & mask
+        slots = click_slots(fields, v, hash_bits)
         # the planted weight of a slot: uniform on [-sqrt 3, sqrt 3)
         bits = _mix32(slots ^ w_salt) >> 8
         planted = (bits.astype(jnp.float32) * (2.0 ** -23) - 1.0) \
