@@ -59,8 +59,13 @@ PR_VERTICES, PR_AVG_DEGREE = 1_000_000, 8.0
 class Spy:
     """What the harness observes about a stage without changing it:
     every ``pallas_call`` built (name, interpret), every array the
-    partition engine placed (by leaf name), compile seconds and
-    persistent-cache hits/misses from ``jax.monitoring``."""
+    partition engine placed (by leaf name), and what JAX compiled:
+    seconds, persistent-cache hits and misses, and the functions that
+    compiled for over ``SLOW_COMPILE_S``, read from the program's own
+    record (the ``jit:compile`` spans ``compile_cache.configure``
+    starts, kept in memory by ``telemetry/events.py``)."""
+
+    SLOW_COMPILE_S = 0.5
 
     def __init__(self):
         self.kernels: list[tuple[str, bool]] = []
@@ -69,9 +74,9 @@ class Spy:
         self.compile_s = 0.0
         self.hits = 0
         self.misses = 0
+        self._t_stage = time.perf_counter()
 
     def install(self):
-        import jax
         from jax.experimental import pallas as pl
 
         from tpu_distalg.parallel import partition
@@ -110,22 +115,29 @@ class Spy:
         partition.put, partition.place, partition.reshard = (
             put, place, reshard)
 
-        def on_duration(name, secs, **_):
-            if name == "/jax/core/compile/backend_compile_duration":
-                self.compile_s += secs
-
-        def on_event(name, **_):
-            if name == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-            elif name == "/jax/compilation_cache/cache_misses":
-                self.misses += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
     def begin_stage(self):
         self.kernels = []
-        return self.compile_s, self.hits, self.misses
+        self._t_stage = time.perf_counter()
+
+    def end_stage(self) -> tuple[float, int, int, list[str]]:
+        """The stage's compile seconds, cache hits, misses and slow
+        compiles by function, added to the run's totals (a stage at a
+        time, so the ring's bound never costs the totals a span)."""
+        from tpu_distalg.telemetry import events
+        from tpu_distalg.utils import compile_cache
+
+        done = [sp for sp in events.finished()
+                if sp.name == compile_cache.COMPILE
+                and sp.t0 >= self._t_stage]
+        comp = sum(sp.seconds for sp in done)
+        hits = sum(sp.fields.get("hit") is True for sp in done)
+        misses = sum(sp.fields.get("hit") is False for sp in done)
+        self.compile_s += comp
+        self.hits += hits
+        self.misses += misses
+        return comp, hits, misses, [
+            f"{sp.fields['fun']} {sp.seconds:.1f}s" for sp in done
+            if sp.seconds > self.SLOW_COMPILE_S]
 
 
 class Smoke:
@@ -230,7 +242,7 @@ class Smoke:
         # library calls outside cli.main log here; cli.main reopens the
         # same directory itself (TDA_TELEMETRY_DIR)
         telemetry.configure(self.tel_dir)
-        c0, h0, m0 = self.spy.begin_stage()
+        self.spy.begin_stage()
         t_mono = time.monotonic()
         t0 = time.perf_counter()
         detail, err = "", None
@@ -260,14 +272,15 @@ class Smoke:
             sys.stderr.write(tb)
             self.note(tb)
         wall = time.perf_counter() - t0
-        comp = self.spy.compile_s - c0
+        comp, hits, misses, slow = self.spy.end_stage()
         built = sorted({k for k, _ in self.spy.kernels})
         self.results.append((name, err is None))
         self.say(
             f"[stage] {name}: {'ok' if err is None else 'FAILED'} "
             f"wall={wall:.1f}s compile={comp:.1f}s "
-            f"cache={self.spy.hits - h0}hit/{self.spy.misses - m0}miss "
-            f"kernels={built} "
+            f"cache={hits}hit/{misses}miss "
+            + (f"slow_compiles={slow} " if slow else "")
+            + f"kernels={built} "
             + (detail if err is None
                else f"{type(err).__name__}: {str(err)[:300]}"))
         self.spy.placed = {}   # drop the stage's arrays

@@ -42,8 +42,12 @@ def sink_dir(tmp_path):
 
 
 def _spans(d, ev="span_end"):
+    """The program's own spans: what JAX traced and compiled under them
+    (``jit:*``, there once any test of the process has called
+    ``compile_cache.configure``) is tests/test_compile_record.py's."""
     events.configure(False)
-    return [e for e in report.load_events(d) if e["ev"] == ev]
+    return [e for e in report.load_events(d) if e["ev"] == ev
+            and not e["name"].startswith(report.JIT_PREFIX)]
 
 
 def _start_trace(trace_dir):
@@ -177,7 +181,8 @@ def test_span_with_sink_off_and_no_jax_imports_nothing(tmp_path):
     """``events.py`` is stdlib-only (loaded here by path: the parent
     package's ``__init__`` imports the jax-backed layers): a span in a
     process that never imported jax leaves jax out and touches no
-    file."""
+    file; the ring keeps both spans, and the marks land at both edges
+    as they do with a sink."""
     code = (
         "import builtins, importlib.util, sys\n"
         f"spec = importlib.util.spec_from_file_location('ev', "
@@ -190,7 +195,10 @@ def test_span_with_sink_off_and_no_jax_imports_nothing(tmp_path):
         "with events.span('outer', rows=1):\n"
         "    with events.span('inner'):\n"
         "        pass\n"
-        "assert events.last_mark()[1] == 'inner'\n"
+        "assert events.last_mark()[1] == 'outer'\n"
+        "inner, outer = events.finished()\n"
+        "assert (inner.name, inner.parent) == ('inner', outer.id)\n"
+        "assert outer.fields == {'rows': 1} and outer.parent is None\n"
         "bad = [m for m in sys.modules if m.split('.')[0] in "
         "('jax', 'jaxlib', 'numpy', 'tpu_distalg')]\n"
         "assert not bad, bad\n"

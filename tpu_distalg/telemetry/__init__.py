@@ -6,7 +6,8 @@ needed (a 26-minute invisible backend hang): structured JSONL events
 (:mod:`events`), a liveness heartbeat with stall detection
 (:mod:`heartbeat`), deadline-guarded backend init with retry/backoff
 (:mod:`supervisor`), and log summarization for humans and CI
-(:mod:`report`, ``tda report <dir>``).
+(:mod:`report`, ``tda report <dir>``). Every finished span is also kept
+in memory (:func:`finished`), sink or no sink.
 
 Import cost is stdlib-only (no jax) so the CLI can configure telemetry
 before the backend exists — which is exactly when it matters most.
@@ -18,6 +19,7 @@ from tpu_distalg.telemetry.events import (
     counter,
     emit,
     enabled,
+    finished,
     gauge,
     get_sink,
     last_mark,
@@ -39,6 +41,7 @@ __all__ = [
     "emit",
     "enabled",
     "events",
+    "finished",
     "gauge",
     "get_sink",
     "heartbeat",

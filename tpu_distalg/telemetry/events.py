@@ -34,8 +34,25 @@ Conventions:
     a tree with self times. Once ``jax`` is imported the span is also a
     ``jax.profiler.TraceAnnotation`` named ``tda:<name>``: under a
     profiler session (``--profile``) the host phase lands on the
-    profiler's clock beside the device ops; without one it records
-    nothing.
+    profiler's clock beside the device ops.
+  * every finished span is also kept in memory, sink or no sink: one
+    :class:`Finished` tuple ``(name, id, parent, t0, seconds, ok,
+    fields)`` in a bounded ring (``RING_SIZE`` entries, the oldest
+    dropped), ``t0`` on ``time.perf_counter()``. :func:`finished`
+    returns a copy, so a reader in the same process (a benchmark's
+    per-layer metric, ``chip_smoke.py``'s stage lines) reads the
+    program's spans without a sink or a profiler session.
+  * ``jit:trace``, ``jit:lower``, ``jit:compile`` and
+    ``jit:cache_load`` are what JAX did to one function: jaxpr
+    tracing, jaxpr to MLIR (where a Pallas kernel's body is lowered),
+    the backend compile, and inside it the persistent cache's read.
+    ``utils/compile_cache.configure`` opens and closes them from
+    ``jax.monitoring``'s events through :func:`begin` / :func:`end`,
+    with ``fun`` (the function's name), ``hit`` on ``jit:compile``
+    where a persistent cache answered, and the span open on the
+    calling thread as ``parent``. They land in the ring and, with a
+    sink, as ``span_end`` lines, so ``tda report`` nests them in its
+    tree and prints its per-function table from them.
   * counters are in-memory (thread-safe) and flushed as one
     ``counters`` event at close; gauges/metrics are emitted inline.
 
@@ -48,6 +65,7 @@ emitting function returns before touching any file — guarded by a test
 from __future__ import annotations
 
 import atexit
+import collections
 import contextlib
 import itertools
 import json
@@ -57,6 +75,7 @@ import sys
 import threading
 import time
 import uuid
+from typing import NamedTuple
 
 ENV_DIR = "TDA_TELEMETRY_DIR"
 # a span's name in a profiler trace is this prefix + its name
@@ -70,7 +89,25 @@ _SINK: EventSink | None = None
 # works against it either way)
 _LAST_MARK: tuple[float, str] = (time.monotonic(), "start")
 _SPAN_IDS = itertools.count(1)   # next() is atomic under the GIL
-_OPEN_SPANS = threading.local()  # .stack: ids of this thread's open spans
+_OPEN_SPANS = threading.local()  # .stack: this thread's open spans
+# finished spans, newest last: a span is a phase boundary, so a run
+# appends tens to hundreds; append and list() are atomic under the GIL
+RING_SIZE = 4096
+
+
+class Finished(NamedTuple):
+    """One finished span as the ring keeps it."""
+
+    name: str
+    id: int
+    parent: int | None     # the span open on its thread when it began
+    t0: float              # time.perf_counter() at its start
+    seconds: float
+    ok: bool
+    fields: dict
+
+
+_FINISHED: collections.deque[Finished] = collections.deque(maxlen=RING_SIZE)
 
 
 class EventSink:
@@ -212,26 +249,85 @@ def _annotation(name: str, **args):
     return profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **args)
 
 
+class OpenSpan:
+    """A span that has begun; :func:`end` finishes it."""
+
+    __slots__ = ("name", "id", "parent", "t0", "fields")
+
+    def __init__(self, name: str, parent: int | None, fields: dict):
+        self.name = name
+        self.id = next(_SPAN_IDS)
+        self.parent = parent
+        self.fields = fields
+        self.t0 = time.perf_counter()
+
+
+def _stack() -> list[OpenSpan]:
+    return _OPEN_SPANS.__dict__.setdefault("stack", [])
+
+
+def current() -> OpenSpan | None:
+    """The innermost span open on the calling thread."""
+    stack = _stack()
+    return stack[-1] if stack else None
+
+
+def finished() -> list[Finished]:
+    """A copy of the ring of finished spans, oldest first."""
+    return list(_FINISHED)
+
+
+def begin(name: str, **fields) -> OpenSpan:
+    """Open a span on the calling thread: the half of :func:`span`
+    for a caller that learns of a phase's two edges in two calls (the
+    ``jax.monitoring`` listeners of ``utils/compile_cache``). ``fields``
+    may be added to until :func:`end`."""
+    stack = _stack()
+    sp = OpenSpan(name, stack[-1].id if stack else None, fields)
+    stack.append(sp)
+    sink = _SINK
+    if sink is not None:
+        sink.write("span_start", name=name,
+                   **{**fields, "id": sp.id, "parent": sp.parent})
+    return sp
+
+
+def end(sp: OpenSpan, error: str | None = None) -> Finished:
+    """Finish a span opened by :func:`begin` on this thread: into the
+    ring, and as a ``span_end`` line where a sink is on."""
+    seconds = time.perf_counter() - sp.t0
+    stack = _stack()
+    if sp in stack:
+        stack.remove(sp)          # the top, unless an inner one leaked
+    done = Finished(sp.name, sp.id, sp.parent, sp.t0, seconds,
+                    error is None, sp.fields)
+    _FINISHED.append(done)
+    sink = _SINK
+    if sink is not None:
+        # ONE merged dict, span keys overwriting caller fields: twin
+        # splats would TypeError out of a finally on a caller-
+        # supplied 'error'/'seconds'/'ok' and mask the real exception
+        line = dict(sp.fields)
+        line.update(seconds=round(seconds, 6), ok=error is None,
+                    id=sp.id, parent=sp.parent)
+        if error is not None:
+            line["error"] = error
+        sink.write("span_end", name=sp.name, **line)
+    return done
+
+
 @contextlib.contextmanager
 def span(name: str, **fields):
     """Timed phase: ``span_start``/``span_end`` (+duration, +error on
-    failure) around the body, with a progress mark at both edges, and
-    the same interval as ``tda:<name>`` in a profiler trace. ``id`` and
-    ``parent`` (the span open on this thread when this one began, or
-    ``None``) ride in both. A phase boundary, never a per-step call."""
+    failure) around the body, with a progress mark at both edges, the
+    same interval as ``tda:<name>`` in a profiler trace, and a
+    :class:`Finished` entry in the ring whether or not anything else
+    listens. ``id`` and ``parent`` (the span open on this thread when
+    this one began, or ``None``) ride in all three. A phase boundary,
+    never a per-step call."""
     mark(name, emit_event=False)
-    sink = _SINK
-    stack = _OPEN_SPANS.__dict__.setdefault("stack", [])
-    sid, parent = next(_SPAN_IDS), (stack[-1] if stack else None)
-    note = _annotation(name, id=sid, parent=parent or 0)
-    if sink is None and note is None:
-        yield
-        return
-    stack.append(sid)
-    t0 = time.monotonic()
-    if sink is not None:
-        sink.write("span_start", name=name,
-                   **{**fields, "id": sid, "parent": parent})
+    sp = begin(name, **fields)
+    note = _annotation(name, id=sp.id, parent=sp.parent or 0)
     err = None
     try:
         with note or contextlib.nullcontext():
@@ -240,17 +336,7 @@ def span(name: str, **fields):
         err = f"{type(e).__name__}: {e}"
         raise
     finally:
-        stack.pop()
-        if sink is not None:
-            # ONE merged dict, span keys overwriting caller fields: twin
-            # splats would TypeError out of this finally on a caller-
-            # supplied 'error'/'seconds'/'ok' and mask the real exception
-            end = dict(fields)
-            end.update(seconds=round(time.monotonic() - t0, 6),
-                       ok=err is None, id=sid, parent=parent)
-            if err is not None:
-                end["error"] = err
-            sink.write("span_end", name=name, **end)
+        end(sp, err)
         mark(name, emit_event=False)
 
 

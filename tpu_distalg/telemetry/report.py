@@ -2,7 +2,8 @@
 
 Turns a telemetry JSONL log into the 3-line diagnosis round 5 lacked:
 phase durations (spans as a tree, each with its self time: duration
-minus what its child spans cover), stall/retry/restart counts, backend-init
+minus what its child spans cover), what JAX traced, lowered, compiled
+and loaded by function (``jit:*`` spans), stall/retry/restart counts, backend-init
 attempt history and resolution, last heartbeat age, and every recorded
 metric/gauge — for humans (default rendering) and CI (``--json``).
 Tolerates torn tail lines (a killed process loses at most the line it
@@ -109,6 +110,51 @@ def span_tree(evts: list[dict]) -> list[dict]:
         node["max_seconds"] = round(node["max_seconds"], 6)
         out.append(node)
     return out
+
+
+JIT_PREFIX = "jit:"   # utils/compile_cache.py's spans, and a row's
+JIT_COLUMN = {"jit:trace": "trace_s", "jit:lower": "lower_s",  # column
+              "jit:compile": "compile_s", "jit:cache_load": "load_s"}
+
+
+def jit_functions(evts: list[dict]) -> list[dict]:
+    """What JAX did to each function, from the ``jit:*`` spans: one row
+    a ``fun`` with how often it was traced, its trace / lower / compile
+    seconds, of the compile seconds those a persistent cache's load
+    took, the cache's hits and misses, and under which program span
+    (``name#id``, the nearest ancestor that is no ``jit:*`` span) each
+    trace happened, in order. Largest total first."""
+    spans: dict[tuple, dict] = {}
+    for e in evts:
+        if e.get("ev") in ("span_start", "span_end") and "id" in e:
+            spans[(e.get("run"), e["id"])] = e
+    rows: dict[str, dict] = {}
+    for e in evts:
+        column = JIT_COLUMN.get(e.get("name"))
+        if e.get("ev") != "span_end" or column is None:
+            continue
+        row = rows.setdefault(str(e.get("fun", "?")), {
+            "fun": str(e.get("fun", "?")), "traced": 0, "trace_s": 0.0,
+            "lower_s": 0.0, "compile_s": 0.0, "load_s": 0.0, "hits": 0,
+            "misses": 0, "under": []})
+        row[column] = round(row[column] + float(e.get("seconds", 0.0)), 6)
+        if column == "trace_s":
+            row["traced"] += 1
+            up, seen = e, set()
+            while up is not None and id(up) not in seen and \
+                    up.get("name", "").startswith(JIT_PREFIX):
+                seen.add(id(up))
+                up = spans.get((up.get("run"), up.get("parent")))
+            row["under"].append(
+                f"{up.get('name', '?')}#{up.get('id')}" if up else "-")
+        elif column == "compile_s" and "hit" in e:
+            row["hits" if e["hit"] else "misses"] += 1
+    return sorted(rows.values(), key=lambda r: -_jit_total(r))
+
+
+def _jit_total(row: dict) -> float:
+    """A function's seconds (the load lies inside the compile)."""
+    return row["trace_s"] + row["lower_s"] + row["compile_s"]
 
 
 def summarize(evts: list[dict]) -> dict:
@@ -240,6 +286,7 @@ def summarize(evts: list[dict]) -> dict:
                          if t_wall else 0.0),
         "phases": phases,
         "span_tree": span_tree(evts),
+        "jit_functions": jit_functions(evts),
         "draw_forms": draw_forms,
         "sums_forms": sums_forms,
         "dist_forms": dist_forms,
@@ -267,6 +314,41 @@ def summarize(evts: list[dict]) -> dict:
     }
 
 
+JIT_ROW_MIN_SECONDS = 0.01   # smaller functions share one line
+
+
+def _render_jit_functions(rows: list[dict]) -> list[str]:
+    """The per-function table: a row for every function that took
+    ``JIT_ROW_MIN_SECONDS`` or was traced more than once (marked
+    ``*``, with the span each trace happened under), one line for the
+    rest."""
+    if not rows:
+        return []
+    shown, rest = [], []
+    for r in rows:
+        (shown if _jit_total(r) >= JIT_ROW_MIN_SECONDS or r["traced"] > 1
+         else rest).append(r)
+    lines = ["compiles by function (s; load is the part of compile a "
+             "cache hit took; * traced more than once):"]
+    width = max([len(r["fun"]) for r in shown] + [8])
+    lines.append(f"  {'function'.ljust(width)}  traced    trace    lower"
+                 f"  compile     load  cache  under")
+    for r in shown:
+        cache = (f"{r['hits']}h/{r['misses']}m"
+                 if r["hits"] or r["misses"] else "-")
+        lines.append(
+            f"  {r['fun'].ljust(width)}  {r['traced']:>4} "
+            f"{'*' if r['traced'] > 1 else ' '} {r['trace_s']:>8.3f} "
+            f"{r['lower_s']:>8.3f} {r['compile_s']:>8.3f} "
+            f"{r['load_s']:>8.3f}  {cache:>5}  "
+            f"{', '.join(r['under']) or '-'}")
+    if rest:
+        lines.append(
+            f"  {len(rest)} more under {JIT_ROW_MIN_SECONDS} s each: "
+            f"{sum(map(_jit_total, rest)):.3f} s")
+    return lines
+
+
 def render(s: dict) -> str:
     """Human rendering of :func:`summarize`'s dict."""
     lines = [
@@ -285,6 +367,7 @@ def render(s: dict) -> str:
                 f"max {p['max_seconds']}s, self {p['self_seconds']}s{err}")
     for name in s["unfinished_phases"]:
         lines.append(f"  {name}: UNFINISHED (no span_end recorded)")
+    lines.extend(_render_jit_functions(s.get("jit_functions") or []))
     if s.get("row_formats"):
         lines.append(f"row format: {', '.join(s['row_formats'])}")
     if s.get("draw_forms"):
