@@ -731,7 +731,8 @@ def stage_kmeans_wide(s: Smoke):
             f"(<= 0.1%), {int(same.sum())} of {k} centres within "
             f"{err:.2g} of the spread")
     return (f"dp={mesh.shape['data']} | wide blocks {tuple(data.shape)}, "
-            f"distances {geom.dist_form} | " + " | ".join(said))
+            f"distances {geom.dist_form} depth {geom.dist_depth} | "
+            + " | ".join(said))
 
 
 def stage_ssgd_hashed(s: Smoke):

@@ -16,6 +16,7 @@ product's shape, ``SCAT`` (k 2048 at the same 49 dimensions) a
 scatter-add's.
 """
 
+import functools
 import os
 import sys
 
@@ -119,11 +120,58 @@ def test_segment_follows_the_float32_reference(mesh1, n, iterations):
     assert err < (SAME if moved == 0 else MOVED), (err, moved)
 
 
-@pytest.mark.parametrize("pieces", [1, 2])
-def test_a_bfloat16_product_is_caught(mesh1, monkeypatch, pieces):
+def _fewer(pieces):
+    """``split3`` with only its first ``pieces`` pieces, the rest zero."""
+    real = wide.split3
+
+    def split(x):
+        got = real(x)
+        return got[:pieces] + tuple(jnp.zeros_like(p) for p in got[pieces:])
+
+    return {"split3": split}
+
+
+def _with_a_zero_piece():
+    """``split3`` with a fourth piece of zeros for a band to point at,
+    where it splits the assign kernel's block (the one-hot sums split
+    chunks of ``STATS_POINTS`` and take their three)."""
+    real = wide.split3
+
+    def split(x):
+        got = real(x)
+        if x.shape == (GEOM.dim_held, P):
+            got += (jnp.zeros_like(got[0]),)
+        return got
+
+    return split
+
+
+# what each fault puts in place of the module's names, and whether it
+# moves points by the dozen (an operand of one piece, a band that meets
+# the wrong band) or only leaves the tolerance of the shipped product (a
+# term of 2^-16 of the product missing)
+FAULTS = {
+    "one_piece": (lambda: _fewer(1), True),
+    "two_pieces": (lambda: _fewer(2), False),
+    # the block's fifth band (x_mid under c_hi) holds zeros: the score
+    # is short of a term of 2^-8 of the product
+    "band_zeroed": (lambda: {
+        "split3": _with_a_zero_piece(),
+        "BANDS": wide.BANDS[:4] + ((0, 3),) + wide.BANDS[5:]}, True),
+    # the block's first two bands change places, the centres' do not:
+    # lo.lo and hi.hi where lo.hi and hi.lo were
+    "bands_swapped": (lambda: {
+        "BANDS": ((2, 2), (0, 0)) + wide.BANDS[2:]}, True),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_a_bfloat16_product_is_caught(mesh1, monkeypatch, fault):
     """The same comparison with operands of fewer pieces than carry a
-    float32 (one: the MXU's default precision; two: ``HIGH``): points
-    change sides by the dozen and the centres leave the tolerance that
+    float32 (one: the MXU's default precision; two: ``HIGH``) and with a
+    stack whose bands do not meet their partners (one band zero, two
+    bands swapped on one side only): points
+    change sides by the dozen or the centres leave the tolerance that
     holds the shipped product; so does the reference's own bfloat16
     control."""
     n = 6000
@@ -132,14 +180,9 @@ def test_a_bfloat16_product_is_caught(mesh1, monkeypatch, pieces):
     want, want_counts = ref.follow(1, 1)
     low, _ = ref.follow(1, 1, dtype=jnp.bfloat16)
     assert kmeans_ref.centers_err(low[-1], want[-1], SPREAD) > MOVED
-    real = wide.split3
-
-    def fewer(x):
-        got = real(x)
-        return got[:pieces] + tuple(
-            jnp.zeros_like(p) for p in got[pieces:])
-
-    monkeypatch.setattr(wide, "split3", fewer)
+    patch, by_the_dozen = FAULTS[fault]
+    for name, value in patch().items():
+        monkeypatch.setattr(wide, name, value)
     jax.clear_caches()
     try:
         got, _, _, counts = _seg(mesh1, 1)(
@@ -149,10 +192,10 @@ def test_a_bfloat16_product_is_caught(mesh1, monkeypatch, pieces):
         jax.clear_caches()
     moved = int(np.abs(np.asarray(counts) - want_counts).sum())
     err = kmeans_ref.centers_err(got, want[-1], SPREAD)
-    if pieces == 1:
+    if by_the_dozen:
         assert moved > 4 and err > MOVED, (err, moved)
     else:
-        # three-pass products: closer, and still not the statement's
+        # closer, and still not the statement's
         assert err > SAME, (err, moved)
 
 
@@ -177,14 +220,15 @@ def test_layout_is_a_function_of_k_and_dim(k, dim, layout, sums):
         assert geom.dim_held % 16 == 0 and geom.dim_mxu % 128 == 0
         assert kmeans._span_fields(k, geom) == {
             "layout": "wide", "dist_form": "mxu6",
+            "dist_depth": geom.dist_depth,
             "sums_form": wide.sums_form(k, dim)}
     if (k, dim) == (4096, 784):
         # the published widths: 4 x 784 bytes a point, nothing padded;
         # one accumulator of the scatter holds every centre
         assert (geom.dim_held, geom.point_bytes, geom.block_points,
                 geom.centre_tile, geom.stats_tile, geom.k_padded,
-                geom.scatter_tile) == (
-                    784, 3136, 512, 512, 4096, 4096, 4096)
+                geom.scatter_tile, geom.dist_depth) == (
+                    784, 3136, 512, 512, 4096, 4096, 4096, 4736)
 
 
 @pytest.mark.parametrize("dim,k,form", [
@@ -208,6 +252,109 @@ def test_sums_form_is_a_function_of_k_and_dim(dim, k, form):
     # an accumulator and its dump rows stay under the budget
     assert (geom.scatter_tile + 8) * deep * 4 <= wide.ACC_BYTES
     assert geom.scatter_tile % 8 == 0
+
+
+@pytest.mark.parametrize("dim,depth,tile", [
+    (784, 4736, 512), (64, 384, 512), (96, 640, 512), (49, 384, 512),
+    (100, 768, 512), (200, 1280, 512),
+    # whole slabs already: the same depth as six products of dim_mxu
+    (128, 768, 512), (1024, 6144, 512),
+    # a tile of the centres' stack past ACC_BYTES: half as many centres
+    (2720, 16384, 512), (2721, 16512, 256), (4096, 24576, 256)])
+def test_dist_depth_is_a_function_of_dim(dim, depth, tile):
+    """The one contraction is ``6 * dim_held`` rows padded to whole
+    128-deep slabs once, never deeper than six products of ``dim_mxu``
+    each; a tile of centres' stack stays under ``ACC_BYTES``; whatever
+    k."""
+    for k in (600, 4096):
+        geom = wide.wide_geometry(dim, k)
+        assert (geom.dist_depth, geom.centre_tile) == (depth, tile)
+        assert geom.dist_form == "mxu6"
+        assert kmeans._span_fields(k, geom)["dist_depth"] == depth
+    assert depth == -(-6 * geom.dim_held // 128) * 128 <= 6 * geom.dim_mxu
+    assert tile * depth * 2 <= wide.ACC_BYTES < 2 * tile * depth * 2 \
+        or tile == wide.CENTRE_TILE
+    assert wide.wide_geometry(dim, 96).centre_tile == 128
+
+
+def _eqns(fn, *args):
+    """Every equation under ``fn``, kernels' bodies included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def _depths(fn, *args):
+    """How deep every ``dot_general`` under ``fn`` contracts."""
+    return [e.invars[0].aval.shape[e.params["dimension_numbers"][0][0][0]]
+            for e in _eqns(fn, *args) if e.primitive.name == "dot_general"]
+
+
+@pytest.mark.parametrize("dim,k", [(784, 512), (64, 128), (96, 256),
+                                   (100, 256), (128, 128), (1024, 128)])
+def test_lowered_kernel_holds_one_product_as_deep_as_the_geometry_says(
+        dim, k):
+    geom = wide.wide_geometry(dim, k)
+    x3 = jnp.zeros((1, geom.dim_held, P), jnp.float32)
+    c = jnp.zeros((k, dim), jnp.float32)
+    assert _depths(lambda x, c: wide.wide_assign(x, c, geom=geom),
+                   x3, c) == [geom.dist_depth]
+
+
+def _six_products(x3, centers):
+    """The first minimum of ``|c|^2 - 2 x.c`` with the product as the sum
+    of six ``dot_general``s of ``split3``'s pieces, smallest terms first
+    (what the kernel's one contraction stacks), a block at a time."""
+    ch, cm, cl = wide.split3(-2.0 * centers)
+    c2 = jnp.sum(centers * centers, axis=1)[:, None]
+
+    def block(xb):
+        xh, xm, xl = wide.split3(xb[:centers.shape[1]])
+        dot = functools.partial(jnp.matmul,
+                                preferred_element_type=jnp.float32)
+        s = dot(cl, xh) + dot(ch, xl)
+        s = s + dot(cm, xm)
+        s = s + (dot(cm, xh) + dot(ch, xm))
+        return jnp.argmin((s + dot(ch, xh)) + c2, axis=0)
+
+    return jax.jit(lambda x: jax.lax.map(block, x))(x3)
+
+
+@pytest.mark.parametrize("dim,k", [(49, 128), (64, 512), (96, 256),
+                                   (100, 256), (200, 128), (784, 512)])
+def test_stacked_product_is_the_six_products(dim, k):
+    """The kernel's one contraction against the sum of six
+    ``dot_general``s and against float64, three blocks with a ragged
+    last one: each sends a point where float64 does wherever the two
+    nearest centres lie further apart than 1e-6 of the distance, so the
+    two agree there; elsewhere on all but a few."""
+    n = 3 * P - 137
+    rng = np.random.default_rng(dim * k)
+    geom = wide.wide_geometry(dim, k)
+    pts = np.zeros((3 * P, dim), np.float32)
+    pts[:n] = rng.standard_normal((n, dim))
+    centers = pts[rng.choice(n, k, replace=False)] \
+        + 0.5 * rng.standard_normal((k, dim)).astype(np.float32)
+    x3 = jax.vmap(geom.pack)(jnp.asarray(pts).reshape(3, P, dim))
+    one = np.asarray(wide.wide_assign(
+        x3, jnp.asarray(centers), geom=geom, interpret=True)
+        ).reshape(-1)[:n]
+    six = np.asarray(_six_products(x3, jnp.asarray(centers))
+                     ).reshape(-1)[:n]
+    d = ((pts[:n, None].astype(np.float64)
+          - centers[None].astype(np.float64)) ** 2).sum(-1)
+    want = d.argmin(1)
+    two = np.partition(d, 1, axis=1)[:, :2]
+    clear = two[:, 1] - two[:, 0] > 1e-6 * two[:, 0]
+    assert clear.mean() > 0.99
+    np.testing.assert_array_equal(one[clear], want[clear])
+    np.testing.assert_array_equal(six[clear], want[clear])
+    assert (one != six).sum() <= 2
+    assert one.min() >= 0 and one.max() < k
 
 
 def test_table_is_the_generators_rows_in_id_order(mesh4):
@@ -462,42 +609,24 @@ def test_lowered_segment_names_its_scopes(mesh1, mesh4, shards):
 
 def _dots(fn, *args):
     """(operand dtypes, result dtype) of every ``dot_general`` under
-    ``fn``, kernels' bodies included."""
-    found = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "dot_general":
-                found.append((tuple(str(v.aval.dtype) for v in eqn.invars),
-                              str(eqn.outvars[0].aval.dtype)))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
+    ``fn``."""
+    return [(tuple(str(v.aval.dtype) for v in e.invars),
+             str(e.outvars[0].aval.dtype))
+            for e in _eqns(fn, *args) if e.primitive.name == "dot_general"]
 
 
 def _dtypes(fn, *args):
-    """Every dtype a value takes under ``fn``, kernels' bodies
-    included."""
-    found = set()
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            found.update(str(v.aval.dtype) for v in eqn.outvars
-                         if hasattr(v.aval, "dtype"))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
+    """Every dtype a value takes under ``fn``."""
+    return {str(v.aval.dtype) for e in _eqns(fn, *args)
+            for v in e.outvars if hasattr(v.aval, "dtype")}
 
 
 @pytest.mark.parametrize("form", ["mxu", "scatter"])
 @pytest.mark.parametrize("interpret", [True, False])
 def test_the_interpreted_pass_uses_the_products_that_ship(interpret, form):
-    """Interpreted or compiled, the assign kernel is six bfloat16
-    products accumulated in float32 and the one-hot stats kernel three;
+    """Interpreted or compiled, the assign kernel is one bfloat16
+    contraction over the six products' bands, accumulated in float32,
+    and the one-hot stats kernel three;
     no float32 ``dot_general`` stands in for them on the CPU. The
     scatter has no product at all and nothing in bfloat16: float32 adds
     of the points as they are held."""
@@ -508,7 +637,7 @@ def test_the_interpreted_pass_uses_the_products_that_ship(interpret, form):
     a = jnp.zeros((2, 1, P), jnp.int32)
     bf16 = (("bfloat16", "bfloat16"), "float32")
     assert _dots(lambda x, c: wide.wide_assign(
-        x, c, geom=geom, interpret=interpret), x3, c) == [bf16] * 6
+        x, c, geom=geom, interpret=interpret), x3, c) == [bf16]
 
     def stats(x, a):
         return wide.wide_stats(x, a, 7, geom=geom, interpret=interpret)
@@ -565,11 +694,12 @@ def test_fit_scaled_takes_the_wide_path_and_spans_it(mesh4, tmp_path,
     segs = [e for e in ends if e["name"] == "train:segment"]
     assert [(e["t0"], e["steps"]) for e in segs] == [(0, 2), (2, 2)]
     for e in prep + segs:
-        assert (e["layout"], e["dist_form"], e["sums_form"]) == (
-            "wide", "mxu6", form)
+        assert (e["layout"], e["dist_form"], e["dist_depth"],
+                e["sums_form"]) == ("wide", "mxu6", 384, form)
     lines = report.render(
         report.summarize(report.load_events(tel))).splitlines()
-    assert "distances: mxu6" in lines and f"cluster sums: {form}" in lines
+    assert "distances: mxu6 (depth 384)" in lines
+    assert f"cluster sums: {form}" in lines
 
 
 @pytest.mark.parametrize("row,value", [(1005, np.nan), (7, np.inf)],

@@ -838,6 +838,9 @@ def test_kmeans_wide_pass_at_the_published_widths(monkeypatch):
     geom, pts, centers, x3, a, sums, counts, _ = _wide_case(dim, k, n, 3)
     assert (geom.dim_held, geom.block_points, geom.sums_form) == (
         784, 512, "scatter")
+    # the six products as one contraction: 37 slabs a tile where six
+    # products of 896 took 42
+    assert (geom.dist_form, geom.dist_depth) == ("mxu6", 4736)
     want, margin, least = _float64_nearest(pts, centers)
     scale = (centers.astype(np.float64) ** 2).sum(1).max() * 3
     bad = a != want
@@ -877,7 +880,10 @@ def test_kmeans_wide_pass_at_the_published_widths(monkeypatch):
 
 @pytest.mark.parametrize("dim,k,form", [
     (96, 1024, "mxu"), (128, 1024, "scatter"), (96, 16384, "scatter"),
-    (128, 16384, "scatter"), (49, 96, "mxu")])
+    (128, 16384, "scatter"), (49, 96, "mxu"),
+    # the distance product's contraction at 3 slabs (six products of 128
+    # took 6) and at 10 (12)
+    (64, 4096, "scatter"), (200, 1024, "scatter")])
 def test_kmeans_wide_pass_at_other_shapes(dim, k, form):
     """The geometry's choice away from the cell's shape: descriptor
     widths with a codebook of a thousand and of sixteen thousand centres
@@ -905,7 +911,7 @@ def test_kmeans_wide_pass_at_other_shapes(dim, k, form):
     line = {"dim": dim, "k": k, "n": n, "ms_a_pass": ms,
             "mxu_share_pct": 2.0 * n * k * dim / (ms / 1e3) / 197e12 * 100,
             "stats_tile": geom.stats_tile, "centre_tile": geom.centre_tile,
-            "sums_form": form}
+            "dist_depth": geom.dist_depth, "sums_form": form}
     print(f"[wide shapes] {json.dumps(line)}")
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/kmeans_wide_shapes.jsonl", "a") as f:

@@ -180,13 +180,15 @@ def layout_of(geometry) -> str:
 
 def _span_fields(k: int, lanes) -> dict:
     """What the scale path's spans say beyond their sizes: which layout
-    took the table, where a pass scores the distances and where it adds
-    up the per-cluster sums at this geometry."""
+    took the table, where a pass scores the distances (on the wide
+    layout also how deep a tile's contraction is, padding included) and
+    where it adds up the per-cluster sums at this geometry."""
     layout = layout_of(lanes)
     if layout == "rows":
         return {"layout": layout}
     if layout == "wide":
         return {"layout": layout, "dist_form": lanes.dist_form,
+                "dist_depth": lanes.dist_depth,
                 "sums_form": lanes.sums_form}
     from tpu_distalg.ops import pallas_lloyd as lloyd
 

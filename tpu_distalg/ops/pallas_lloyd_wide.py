@@ -29,16 +29,25 @@ shape, with a minimum where the softmax is:
                                  six products XLA's
                                  ``Precision.HIGHEST`` keeps (hi.hi,
                                  hi.mid, mid.hi, mid.mid, hi.lo, lo.hi),
-                                 accumulated in float32, smallest terms
-                                 first. Centres down the sublanes,
-                                 points along the lanes: a tile of
-                                 centres ``(TN, dim)`` times the block
-                                 ``(dim, P)``, no transpose. The block
-                                 is split once, when its first tile of
-                                 centres comes; the tiles of centres
-                                 stream past it (19.3 MB a block at
-                                 k = 4096, a quarter of the product's
-                                 own time at P = 512)
+                                 each exact in float32, as ONE
+                                 contraction: the six products' operands
+                                 laid end to end as bands of ``dim_held``
+                                 rows, ``[x_hi; x_lo; x_mid; x_hi; x_mid;
+                                 x_hi]`` under ``[c_lo | c_hi | c_mid |
+                                 c_mid | c_hi | c_hi]`` (smallest terms
+                                 first), so that band b of one side
+                                 meets band b of the other and one
+                                 float32 accumulator takes all six.
+                                 Centres down the sublanes, points along
+                                 the lanes: a tile of the centres' stack
+                                 ``(TN, depth)`` times the block's
+                                 ``(depth, P)``, no transpose. The block
+                                 is split and stacked once, when its
+                                 first tile of centres comes; the tiles
+                                 of centres stream past it (38.8 MB a
+                                 block at k = 4096, 370 GB/s beside the
+                                 product: hidden, a pass reads the same
+                                 415 ms with the tile pinned)
   assign  = first minimum        a running ``(8, P)`` minimum and its
                                  centre a sublane, strict ``<`` from
                                  tile to tile, the smallest index among
@@ -92,9 +101,13 @@ points)``, where k x dim is small:
   counts += onehot               int32, lane by lane; XLA folds the 128
                                  lanes once a pass
 
-In VMEM the distance product's contraction is
-padded to whole 128-deep slabs (``dim_mxu``: 896 for 784, so an eighth
-of its MXU passes multiplies zeros: PERF.md §7); in HBM nothing is.
+In VMEM the distance product's contraction is padded to whole
+128-deep slabs once, past all six bands (``dist_depth``: 4736 for 6 x
+784, 37 slabs a tile, 0.7% of them zeros; as six products of ``dim_mxu``
+= 896 each, until PR 35, 42 slabs, an eighth zeros: 479.7 -> 415.1 ms a
+pass at 784 x 4096 on one v5e, and no width read slower: PERF.md §6);
+in HBM nothing is. The scatter pads a point's features to ``dim_mxu``
+lanes.
 Interpreted on the CPU the kernels run the same bfloat16
 ``dot_general``s and the same loop over the points.
 """
@@ -116,13 +129,17 @@ BLOCK_POINTS = 512         # P
 CENTRE_TILE = 512          # TN at most: centres scored a grid step
 STATS_TILE = 4096          # TK at most: the one-hot is (TK, 256)
 STATS_POINTS = 256         # points a grid step of the stats kernel
-ACC_BYTES = 16 << 20       # one accumulator of the sums a tile of centres
+ACC_BYTES = 16 << 20       # one accumulator of the sums a tile of centres,
+#                            one tile of the centres' stack
 SCATTER_POINTS = 8         # points written out a trip of the scatter's loop
 SCATTER_ACCS = 2           # accumulators the scatter takes in turn
 SCATTER_LOOP = 96 << 10    # products a point the scatter's loop is worth
 SCATTER_LANE = 160         # ... and its transpose, a lane of ``dim_mxu``
-MAX_DIM = 4096             # a block and its pieces stay under 32 MB
+MAX_DIM = 4096             # a block and its stack stay under 34 MB
 DIST_FORM = "mxu6"         # six bfloat16 passes: float32 accuracy
+# the six products, smallest terms first: band b of either stack holds
+# (the piece of -2c, the piece of x) of split3's (hi, mid, lo)
+BANDS = ((2, 0), (0, 2), (1, 1), (1, 0), (0, 1), (0, 0))
 _TOP = 0xFFFF0000          # the half of a float32 that is a bfloat16
 _BIG = 3.0e38              # over any centre's index, as a float32
 _NT = (((1,), (1,)), ((), ()))
@@ -131,6 +148,12 @@ _NN = (((1,), (0,)), ((), ()))
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def _stack_depth(held: int) -> int:
+    """Rows of either stack: the bands end to end, padded to whole
+    128-deep slabs once."""
+    return _round_up(len(BANDS) * held, LANES)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,6 +186,12 @@ class WideGeometry:
     dist_form = DIST_FORM  # how a pass scores the distances
 
     @property
+    def dist_depth(self) -> int:
+        """Depth of the distance product's one contraction in VMEM: six
+        bands of ``dim_held`` rows."""
+        return _stack_depth(self.dim_held)
+
+    @property
     def sums_form(self) -> str:
         """How a pass adds up the per-cluster sums: :func:`sums_form`."""
         return sums_form(self.k, self.dim)
@@ -190,8 +219,13 @@ def wide_geometry(dim: int, k: int) -> WideGeometry | None:
     ``ops/kmeans.py`` then)."""
     if dim > MAX_DIM:
         return None
+    held = _round_up(dim, PIECE_ROWS)
     tn = min(CENTRE_TILE, _round_up(k, LANES))
-    room = ACC_BYTES // (4 * _round_up(dim, PIECE_ROWS))
+    # a tile of the centres' stack stays under the budget too (TN 256
+    # past dim 2720: two tiles in flight beside the block's own stack)
+    while tn * _stack_depth(held) * 2 > ACC_BYTES:
+        tn //= 2
+    room = ACC_BYTES // (4 * held)
     cap = tn
     while 2 * cap <= min(room, STATS_TILE):
         cap *= 2
@@ -245,34 +279,30 @@ def _dot(a, b, dims):
 
 
 def _wide_assign_kernel(c_ref, c2_ref, x_ref, out_ref,
-                        pieces_ref, best_ref, arg_ref, *, tn: int):
+                        stack_ref, best_ref, arg_ref, *, tn: int):
     """One block of points against one tile of centres. ``c_ref`` holds
-    the pieces of ``-2 c``, ``c2_ref`` ``|c|^2`` (infinite for the
-    padding past k)."""
+    the stacked pieces of ``-2 c`` ``(TN, depth)``, ``c2_ref`` ``|c|^2``
+    (infinite for the padding past k)."""
     i, j = pl.program_id(0), pl.program_id(1)
-    p = x_ref.shape[1]
+    held, p = x_ref.shape
 
-    held, deep = x_ref.shape[0], pieces_ref.shape[1]
-    if deep > held:
+    used, deep = len(BANDS) * held, stack_ref.shape[0]
+    if deep > used:
         @pl.when((i == 0) & (j == 0))
         def _zero():
             # the contraction's padding: written once, never again
-            pieces_ref[:, pl.ds(held, deep - held), :] = jnp.zeros(
-                (3, deep - held, p), jnp.bfloat16)
+            stack_ref[pl.ds(used, deep - used), :] = jnp.zeros(
+                (deep - used, p), jnp.bfloat16)
 
     @pl.when(j == 0)
     def _new_block():
-        for q, piece in enumerate(split3(x_ref[...])):
-            pieces_ref[q, pl.ds(0, held), :] = piece
+        pieces = split3(x_ref[...])
+        for b, (_, q) in enumerate(BANDS):
+            stack_ref[pl.ds(b * held, held), :] = pieces[q]
         best_ref[...] = jnp.full(best_ref.shape, jnp.inf, jnp.float32)
         arg_ref[...] = jnp.zeros(arg_ref.shape, jnp.float32)
 
-    xh, xm, xl = pieces_ref[0], pieces_ref[1], pieces_ref[2]
-    ch, cm, cl = c_ref[0], c_ref[1], c_ref[2]
-    s = _dot(cl, xh, _NN) + _dot(ch, xl, _NN)
-    s = s + _dot(cm, xm, _NN)
-    s = s + (_dot(cm, xh, _NN) + _dot(ch, xm, _NN))
-    s = (s + _dot(ch, xh, _NN)) + c2_ref[...]
+    s = _dot(c_ref[...], stack_ref[...], _NN) + c2_ref[...]
 
     # per sublane first: elementwise over the tile's TN / 8 registers
     s3 = s.reshape(tn // SUBLANES, SUBLANES, p)
@@ -395,9 +425,14 @@ def wide_assign(x3, centers, *, geom: WideGeometry,
     order."""
     _check(x3, geom)
     nb, held, p = x3.shape
-    k, dim, tn, deep = geom.k, geom.dim, geom.centre_tile, geom.dim_mxu
+    k, dim, tn, deep = geom.k, geom.dim, geom.centre_tile, geom.dist_depth
     c32 = centers.astype(jnp.float32)
-    cm2 = jnp.pad(-2.0 * c32, ((0, geom.k_padded - k), (0, deep - dim)))
+    pieces = split3(jnp.pad(-2.0 * c32, ((0, geom.k_padded - k),
+                                         (0, held - dim))))
+    # the zeros by a pad of their own: as one more operand of the
+    # concatenate the kernel reads its tiles 0.4% slower (PERF.md §6, PR 35)
+    stack = jnp.pad(jnp.concatenate([pieces[q] for q, _ in BANDS], axis=1),
+                    ((0, 0), (0, deep - len(BANDS) * held)))
     c2 = jnp.pad(jnp.sum(c32 * c32, axis=1), (0, geom.k_padded - k),
                  constant_values=jnp.inf)[:, None]
     kernel = functools.partial(_wide_assign_kernel, tn=tn)
@@ -405,20 +440,20 @@ def wide_assign(x3, centers, *, geom: WideGeometry,
         kernel,
         name="_wide_assign_kernel",
         grid=(nb, geom.k_padded // tn),
-        in_specs=[pl.BlockSpec((3, tn, deep), lambda i, j: (0, j, 0)),
+        in_specs=[pl.BlockSpec((tn, deep), lambda i, j: (j, 0)),
                   pl.BlockSpec((tn, 1), lambda i, j: (j, 0)),
                   pl.BlockSpec((None, held, p), lambda i, j: (i, 0, 0))],
         out_specs=pl.BlockSpec((None, 1, p), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, 1, p), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((3, deep, p), jnp.bfloat16),
+        scratch_shapes=[pltpu.VMEM((deep, p), jnp.bfloat16),
                         pltpu.VMEM((SUBLANES, p), jnp.float32),
                         pltpu.VMEM((SUBLANES, p), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            # the running minimum and the pieces live across the grid
+            # the running minimum and the stack live across the grid
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_vmem(geom, 8 * tn * p * 4)),
         interpret=interpret,
-    )(jnp.stack(split3(cm2)), c2, x3)
+    )(stack, c2, x3)
 
 
 @functools.partial(jax.jit, static_argnames=("geom", "interpret"))
@@ -524,8 +559,9 @@ def scatter_stats(x3, assign, n_valid, *, geom: WideGeometry,
 
 
 def _vmem(geom: WideGeometry, working: int) -> int:
-    """A kernel's VMEM limit: two blocks in flight, their pieces, two
-    tiles of centres' pieces, and the kernel's own working set."""
-    p, deep = geom.block_points, geom.dim_mxu
-    return (2 * geom.dim_held * p * 4 + 3 * deep * p * 2
-            + 2 * 3 * geom.centre_tile * deep * 2 + working + (16 << 20))
+    """A kernel's VMEM limit: two blocks in flight and one being split,
+    the block's stack, two tiles of the centres' stack, and the kernel's
+    own working set."""
+    p, deep = geom.block_points, geom.dist_depth
+    return (3 * geom.dim_held * p * 4 + deep * p * 2
+            + 2 * geom.centre_tile * deep * 2 + working + (16 << 20))

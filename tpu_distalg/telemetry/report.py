@@ -199,8 +199,11 @@ def summarize(evts: list[dict]) -> dict:
             if form and form not in sums_forms:
                 sums_forms.append(form)
             # and how it scores the distances (vpu: the lanes kernel;
-            # mxu6: the wide pass's six bfloat16 passes)
+            # mxu6: the wide pass's six bfloat16 passes, over how many
+            # rows a tile of centres contracts: 128-deep slabs x 128)
             form = e.get("dist_form")
+            if form and e.get("dist_depth"):
+                form = f"{form} (depth {e['dist_depth']})"
             if form and form not in dist_forms:
                 dist_forms.append(form)
             # and SSGD's how its rows are held (packed columns say
