@@ -791,6 +791,66 @@ def stage_ssgd_hashed(s: Smoke):
             f"log-loss {loss:.4f} acc {acc:.4f}")
 
 
+def stage_als_sparse(s: Smoke):
+    """ALS on a ratings list as ``tda als --ratings`` runs it, on every
+    chip the stage has: the seeded loader (2 000 000 ratings of 20 000
+    users by 12 000 items at rank 100, the benchmark's segments, classes
+    and pieces at a batch of 768 so that every class and the pieces
+    occur), three iterations of the sparse trainer, and on several chips
+    the all-gather of each half's rows; against the plain reference
+    (``benchmarks/reference/als_sparse_ref.py``) on the followed owners
+    of the last half, to float32 rounding; every rating entered each
+    half once; the held-out RMSE falls."""
+    import numpy as np
+
+    from tpu_distalg.models import als
+
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmarks")
+    if here not in sys.path:
+        sys.path.insert(0, here)
+    from reference import als_sparse_ref as ref_mod
+
+    mesh = s.mesh()
+    n, m_u, m_i, k = 2_000_000, 20_000, 12_000, 100
+    gen = dict(d_min=20, user_d_max=20_000, item_d_max=40_000)
+    geometry = dict(seg_slots=32, piece_segs=64, batch=768,
+                    classes=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48))
+    arrays, meta = als.build_ratings_table(
+        n, m_u, m_i, k, mesh, data_seed=5, n_heldout=65_536,
+        geometry=geometry, **gen)
+    s.check_sharded("user idx", arrays[0])
+    cfg = als.ALSConfig(lam=1.4, m=m_u, n=m_i, k=k, n_iterations=1, seed=3)
+    fn = als.make_fit_fn(mesh, cfg, meta)
+    X, Theta = als.start_factors(meta, mesh, cfg.seed)
+    pu, pi = meta["user"], meta["item"]
+    held = []
+    for _ in range(3):
+        X, Theta, errs, seen = fn(*arrays, X, Theta)
+        held.append(float(errs[0, 1]))
+        if np.asarray(seen).tolist() != [[n, n]]:
+            raise AssertionError(f"ratings entered {seen}, not {n} a half")
+    U = np.asarray(als.owners_from_rows(X, pu, k))
+    V = np.asarray(als.owners_from_rows(Theta, pi, k))
+    ref = ref_mod.Reference(
+        config=dict(k=k, lam=1.4, n_users=m_u, n_items=m_i, n_ratings=n,
+                    n_heldout=65_536, rating_low=0.0, rating_high=100.0,
+                    reference_sample=2048, reference_heavy_over=2048,
+                    generator=dict(als.RATINGS_DEFAULTS, **gen)),
+        data_seed=5, start_seed=3)
+    own, want = ref.half(1, U)          # the last half: items from U
+    err = ref_mod.rel_err(V[own], want, np.zeros_like(want))
+    if not err < 1e-4:
+        raise AssertionError(f"item factors differ from the reference's "
+                             f"by {err:.3g}")
+    if not held[-1] < held[0] < 30:
+        raise AssertionError(f"held-out RMSE {held}")
+    return (f"dp={mesh.shape['data']} | blocks a side {meta['blocks']} | "
+            f"slots held / ratings {meta['padding_share']:.3f} | "
+            f"{len(own)} owners against the reference {err:.2g} | "
+            f"held-out RMSE {held[0]:.3f} -> {held[-1]:.3f}")
+
+
 def _comm_stage(s: Smoke, comm: str):
     from tpu_distalg.models import ssgd
 
@@ -834,6 +894,7 @@ STAGES = (
                    "pallas_hashed._hashed_rows_kernel",
                    "pallas_hashed._hashed_value_gather_kernel",
                    "pallas_hashed._hashed_value_sums_kernel"))),
+    ("als_sparse", stage_als_sparse, {}),     # XLA forms: no kernel
     ("ssgd_comm_int8", functools.partial(_comm_stage, comm="int8"),
      dict(min_devices=2)),
     ("ssgd_comm_bucketed",

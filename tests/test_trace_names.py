@@ -526,3 +526,34 @@ def test_pagerank_sweep_names_its_scope(mesh8):
     text = fn.lower(de.src, de.dst, de.w_e, de.emask, de.has_out,
                     de.n_ref).as_text(debug_info=True)
     assert f"{names.PAGERANK_SPMV}/jit(spmv_table)" in text
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_sparse_als_names_its_scopes(shards, mesh1, mesh4):
+    """The five parts of a sparse ALS half-sweep (ops/als_sparse.py);
+    the benchmark's ``*_ms_per_sweep.als`` and ``als_*_roofline`` read
+    the first three. XLA forms all: no ``pallas_call``."""
+    from tpu_distalg.models import als
+
+    mesh = mesh1 if shards == 1 else mesh4
+    du = np.array([3, 12, 20, 30, 70, 200, 8, 16, 17, 33, 1, 0])
+    di = np.full(10, 41)
+    meta = als.plan_ratings(410, 12, 10, 5, shards, n_heldout=8,
+                            degrees=(du, di), geometry=dict(
+                                seg_slots=8, piece_segs=4, batch=8,
+                                classes=(1, 2)))
+    arrays, _ = als.build_ratings_table(
+        410, 12, 10, 5, mesh, n_heldout=8, degrees=(du, di),
+        geometry=dict(seg_slots=8, piece_segs=4, batch=8,
+                                classes=(1, 2)))
+    cfg = als.ALSConfig(lam=1.4, m=12, n=10, k=5, n_iterations=1)
+    X, Theta = als.start_factors(meta, mesh, 0)
+    text = als.make_fit_fn(mesh, cfg, meta).lower(
+        *arrays, X, Theta).as_text(debug_info=True)
+    for scope in (names.ALS_GATHER, names.ALS_GRAM, names.ALS_SOLVE,
+                  names.ALS_SYNC, names.ALS_UPDATE):
+        assert scope + "/" in text, scope
+    assert "pallas_call" not in text and "tpu_custom_call" not in text
+    assert {names.ALS_GATHER, names.ALS_GRAM, names.ALS_SOLVE,
+            names.ALS_SYNC, names.ALS_UPDATE} == {
+        v for k, v in vars(names).items() if k.startswith("ALS_")}

@@ -1007,3 +1007,78 @@ def test_hashed_passes_at_the_published_widths(tpu_mesh):
         assert abs(g[geom.n_slots] - r64.sum()) < 1e-2, name
         assert not g[geom.n_slots + 1:].any(), name
         np.testing.assert_array_equal(n1[:geom.n_slots], counts)
+
+
+def test_sparse_als_half_sweep_at_rank_100(tpu_mesh):
+    """A sparse ALS half-sweep compiled at the benchmark's rank and
+    widths (rank 100 in 128 lanes, segments of 32 slots, the eleven
+    classes and pieces of 64 at a batch of 768; 1 500 000 seeded ratings
+    of 15 000 users by 9 000 items) against the plain reference's
+    float32 ``jnp.linalg.solve`` owner by owner on the followed owners,
+    and the solve along the lanes against NumPy in float64 on systems
+    taken from the run: the six-pass Gramians and the Cholesky hold
+    float32 accuracy where a bfloat16 Gramian does not."""
+    import os
+    import sys
+
+    from tpu_distalg.models import als
+    from tpu_distalg.ops import als_sparse as ops
+    from tpu_distalg.parallel import get_mesh
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import als_sparse_ref as ref_mod
+
+    mesh = get_mesh(data=1, devices=jax.devices()[:1])
+    n, m_u, m_i, k = 1_500_000, 15_000, 9_000, 100
+    gen = dict(d_min=20, user_d_max=20_000, item_d_max=40_000)
+    geometry = dict(seg_slots=32, piece_segs=64, batch=768,
+                    classes=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48))
+    arrays, meta = als.build_ratings_table(
+        n, m_u, m_i, k, mesh, data_seed=9, n_heldout=4096,
+        geometry=geometry, **gen)
+    geom, pu, pi = meta["geometry"], meta["user"], meta["item"]
+    assert geom.width == 128 and geom.solve_n == 104
+    cfg = als.ALSConfig(lam=1.4, m=m_u, n=m_i, k=k, n_iterations=1, seed=4)
+    X, Theta = als.start_factors(meta, mesh, cfg.seed)
+    V0 = np.asarray(als.owners_from_rows(Theta, pi, k))
+    X, Theta, errs, seen = als.make_fit_fn(mesh, cfg, meta)(
+        *arrays, X, Theta)
+    assert np.asarray(seen).tolist() == [[n, n]]
+    U = np.asarray(als.owners_from_rows(X, pu, k))
+    V = np.asarray(als.owners_from_rows(Theta, pi, k))
+    ref = ref_mod.Reference(
+        config=dict(k=k, lam=1.4, n_users=m_u, n_items=m_i, n_ratings=n,
+                    n_heldout=4096, rating_low=0.0, rating_high=100.0,
+                    reference_sample=4096, reference_heavy_over=2048,
+                    generator=dict(als.RATINGS_DEFAULTS, **gen)),
+        data_seed=9, start_seed=4)
+    np.testing.assert_array_equal(ref.start_items(), V0)
+    for side, other, got, before in ((0, V0, U, np.zeros_like(U)),
+                                     (1, U, V, V0)):
+        own, want = ref.half(side, other)
+        err = ref_mod.rel_err(got[own], want, before[own])
+        _, low = ref.half(side, other, dtype=jnp.bfloat16)
+        control = ref_mod.rel_err(low, want, before[own])
+        print(f"[als rank 100] side {side}: {len(own)} owners, rel err "
+              f"{err:.3g}, bfloat16 control {control:.3g}")
+        assert err < 1e-4 < control
+    # the solve alone, against float64
+    rng = np.random.default_rng(0)
+    G = np.zeros((geom.batch, 300, geom.width), np.float32)
+    G[:, :, :k] = rng.random((geom.batch, 300, k))
+    G[:, :, k] = rng.integers(0, 101, (geom.batch, 300))
+    G[:, :, k + 1] = 1.0
+    Ap = np.einsum("bsd,bse->bde", G.astype(np.float64),
+                   G.astype(np.float64))
+    rows, has, _, _ = jax.jit(
+        lambda a: ops.solve_batch(ops.to_lanes(a), 1.4, geom))(
+        jnp.asarray(Ap, jnp.float32))
+    want = np.stack([np.linalg.solve(
+        Ap[i, :k, :k] + 1.4 * 300 * np.eye(k), Ap[i, :k, k])
+        for i in range(geom.batch)])
+    err = np.abs(np.asarray(rows)[:, :k] - want).max() / np.abs(want).max()
+    print(f"[als rank 100] solve along the lanes: max err {err:.3g}")
+    assert bool(np.asarray(has).all()) and err < 1e-3

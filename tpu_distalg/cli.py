@@ -436,6 +436,18 @@ def main(argv=None):
     p.add_argument("--k", type=int, default=10)
     p.add_argument("--lam", type=float, default=0.01)
     p.add_argument("--n-iterations", type=int, default=5)
+    p.add_argument("--ratings", type=int, default=0,
+                   help="R as a seeded list of this many explicit "
+                        "ratings of --users by --items, made and held "
+                        "on the device (power-law degrees from 20, a "
+                        "planted rank-k model on a 0 to 100 scale); 0 "
+                        "= the dense rank-k R of --m x --n")
+    p.add_argument("--users", type=int, default=0)
+    p.add_argument("--items", type=int, default=0)
+    p.add_argument("--heldout", type=int, default=-1,
+                   help="with --ratings: pairs never trained on that "
+                        "are scored every sweep; -1 = ratings / 64")
+    p.add_argument("--data-seed", type=int, default=0)
     _add_data_backend(p, block_rows=256)
     p.add_argument("--rmse-every", type=int, default=1,
                    help="streamed/virtual backends: stream one extra "
@@ -1630,7 +1642,37 @@ def _dispatch(args, jax):
         mesh = _mesh(args)
         cfg = m.ALSConfig(lam=args.lam, m=args.m, n=args.n, k=args.k,
                           n_iterations=args.n_iterations)
-        if args.data_backend != "resident":
+        if args.ratings:
+            # R is a ratings list: the loader says so in its meta and
+            # als picks the sparse trainer from that (models/als.py)
+            if args.users < 1 or args.items < 1:
+                raise SystemExit(
+                    "--ratings needs --users and --items (the two "
+                    "sides' sizes)")
+            if args.data_backend != "resident":
+                raise SystemExit(
+                    "--ratings is held on the device: --data-backend "
+                    f"{args.data_backend} does not apply")
+            cfg = m.ALSConfig(lam=args.lam, m=args.users, n=args.items,
+                              k=args.k, n_iterations=args.n_iterations)
+            n_heldout = args.ratings // 64 if args.heldout < 0 \
+                else args.heldout
+            arrays, meta = m.build_ratings_table(
+                args.ratings, args.users, args.items, args.k, mesh,
+                data_seed=args.data_seed, n_heldout=n_heldout)
+            print(f"ratings: {meta['n_ratings']} of {meta['n_users']} "
+                  f"users by {meta['n_items']} items, rank {meta['k']}; "
+                  f"blocks a side {meta['blocks']}, slots held / "
+                  f"ratings {meta['padding_share']:.4f}, "
+                  f"{(meta['ratings_bytes'] + meta['factor_bytes']) / 1e9:.3f}"
+                  f" GB resident")
+            res = ckpt.run_with_restarts(
+                lambda: m.fit_ratings(
+                    mesh, cfg, arrays, meta,
+                    checkpoint_dir=args.checkpoint_dir,
+                    checkpoint_every=args.checkpoint_every),
+                max_restarts=args.max_restarts)
+        elif args.data_backend != "resident":
             # R behind a ShardedDataset: host RAM or a disk cache —
             # each sweep streams the row blocks per solve epoch, so R
             # is bounded by disk, not HBM (models/als.fit_streamed)
@@ -1663,8 +1705,12 @@ def _dispatch(args, jax):
         # ONE device fetch for the whole history: float(e) per element
         # is a D2H round-trip per line (the per-step-host-sync shape
         # TDA011 polices); values print bitwise-identically
+        held = None if res.heldout_history is None \
+            else np.asarray(res.heldout_history)
         for t, e in enumerate(np.asarray(res.rmse_history)):
-            print(f"iterations: {t}, rmse: {float(e):f}")
+            tail = "" if held is None else \
+                f", held-out rmse: {float(held[t]):f}"
+            print(f"iterations: {t}, rmse: {float(e):f}{tail}")
         if args.checkpoint_dir:
             # machine-readable artifact handoff: `tda serve --artifact`
             # consumes this exact line (and the telemetry event) — no

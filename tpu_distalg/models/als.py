@@ -15,7 +15,15 @@ zero factor rows (zero RHS against a PD Gram), so they contribute nothing to
 Grams, RMSE numerator, or the V-update — the RMSE denominator uses the true
 m·n (``matrix_decomposition.py:19-21``).
 
-Measured cost attribution at bench scale (4096×16384 rank-64, one v5e,
+**R as a ratings list** (PR 36) is another trainer in this file
+(:func:`fit_ratings`, :func:`build_ratings_table`,
+``ops/als_sparse.py``): one normal-equation system an owner, chosen by
+what the loader's ``meta`` states (``layout: ratings``); the benchmark's
+cell ``als100_253m_sweep1`` and ``PERF.md`` hold its chip readings.
+
+Cost attribution of the DENSE path from before the ledger (a claim of
+its day, not a record: no benchmark cell runs the dense fit and
+``PERF_LEDGER.jsonl`` has no line of it; 4096×16384 rank-64, one v5e,
 ``scripts/als_profile.py`` — scan-wrapped component benchmarks):
 ~2.16 ms/sweep total = solves ~1.5-1.7 ms + per-sweep RMSE ~1.4 ms
 (overlapped by XLA). The sweep is bound by full passes over the 268 MB
@@ -69,6 +77,8 @@ class ALSResult:
     U: jax.Array
     V: jax.Array
     rmse_history: jax.Array  # per-sweep RMSE
+    # the sparse trainer's held-out RMSE per sweep (None on dense R)
+    heldout_history: jax.Array | None = None
 
     @property
     def final_rmse(self) -> float:
@@ -98,7 +108,13 @@ def model_padded_n(config: ALSConfig, mesh: Mesh) -> int:
     return -(-config.n // n_model) * n_model
 
 
-def make_fit_fn(mesh: Mesh, config: ALSConfig):
+def make_fit_fn(mesh: Mesh, config: ALSConfig, meta: dict | None = None):
+    """The jitted fit. ``meta`` is what the loader states of R: a
+    ratings list (``layout: ratings``) takes the sparse trainer
+    (:func:`_make_fit_fn_sparse`, another signature: the packed
+    ratings, then the two factor tables), anything else the dense one."""
+    if meta is not None and meta.get("layout") == RATINGS_LAYOUT:
+        return _make_fit_fn_sparse(mesh, config, meta)
     import warnings
 
     from tpu_distalg.parallel import MODEL_AXIS, partition
@@ -222,6 +238,463 @@ def fit(mesh: Mesh, config: ALSConfig = ALSConfig(),
         U=jnp.asarray(U)[: config.m], V=jnp.asarray(V)[: config.n],
         rmse_history=jnp.asarray(errs),
     )
+
+
+# ------------------------------------------------------------ sparse R
+#
+# R as a ratings list: one normal-equation system an owner
+# (``ops/als_sparse.py``). The loader's ``meta`` states the layout
+# (``layout: ratings``) and :func:`make_fit_fn` picks the trainer from
+# it, as ``row_format`` picks SSGD's hashed trainer; no flag names it.
+
+RATINGS_LAYOUT = "ratings"
+# the planted model and the degree sequences of the seeded table
+RATINGS_DEFAULTS = dict(d_min=20, user_d_max=200_000, item_d_max=500_000,
+                        mean=50.0, scale=6.0, noise=15.0)
+
+
+def _sparse_geometry(k: int, n_users: int, n_items: int,
+                     stated: dict | None):
+    """The pack's geometry: what the caller states, else the published
+    shape's scaled to the sides (``als_sparse.geometry_for``)."""
+    from tpu_distalg.ops import als_sparse
+
+    if stated:
+        return als_sparse.SparseGeometry(k=k, **stated)
+    return als_sparse.geometry_for(k, n_users, n_items)
+
+
+def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
+                  n_shards: int, **extra) -> dict:
+    pu, pi = plans
+    held = (pu.slots_held + pi.slots_held) * 8
+    tables = (pu.static.table_rows + pi.static.table_rows) \
+        * geom.width * 4
+    return dict(
+        layout=RATINGS_LAYOUT, n_users=int(pu.degrees.shape[0]),
+        n_items=int(pi.degrees.shape[0]), n_ratings=int(n_ratings),
+        k=geom.k, geometry=geom, user=pu, item=pi, n_shards=n_shards,
+        n_heldout=int(n_heldout), ratings_bytes=held, factor_bytes=tables,
+        blocks=(pu.static.n_blocks, pi.static.n_blocks),
+        padding_share=(pu.slots_held + pi.slots_held)
+        / max(2 * n_ratings, 1),
+        forms=dict(als_gather_form="xla", als_gram_form="xla",
+                   als_solve_form="xla"),
+        **extra)
+
+
+def _prepare_fields(meta: dict) -> dict:
+    return dict(ratings=meta["n_ratings"], users=meta["n_users"],
+                items=meta["n_items"], k=meta["k"],
+                bytes=meta["ratings_bytes"] + meta["factor_bytes"],
+                user_blocks=meta["blocks"][0],
+                item_blocks=meta["blocks"][1],
+                padding_share=round(meta["padding_share"], 4))
+
+
+def segment_fields(meta: dict) -> dict:
+    """What a ``train:segment`` span says of the sparse trainer."""
+    return dict(layout=meta["layout"], **meta["forms"])
+
+
+def ratings_from_coo(users, items, ratings, n_users: int, n_items: int,
+                     k: int, mesh: Mesh, *, heldout=None, **geom_kw):
+    """The loader of an explicit ratings list held on the host: packs
+    both sides (``als_sparse.pack_coo``) and states the layout. A pair
+    listed twice is held twice. ``heldout`` is ``(users, items,
+    ratings)`` never trained on, or None. Returns ``(arrays, meta)``."""
+    from tpu_distalg.ops import als_sparse
+    from tpu_distalg.telemetry import events as tevents
+
+    geom = _sparse_geometry(k, n_users, n_items, geom_kw)
+    S = mesh.shape[DATA_AXIS]
+    users = np.asarray(users, np.int64)
+    items = np.asarray(items, np.int64)
+    with tevents.span("als:pack", ratings=int(users.shape[0])):
+        pu = als_sparse.plan_side(
+            np.bincount(users, minlength=n_users), geom, S)
+        pi = als_sparse.plan_side(
+            np.bincount(items, minlength=n_items), geom, S)
+        ui, uv = als_sparse.pack_coo(pu, geom, users, items, ratings,
+                                     pi.row_of_owner, pi.static.zero_row)
+        ii, iv = als_sparse.pack_coo(pi, geom, items, users, ratings,
+                                     pu.row_of_owner, pu.static.zero_row)
+    if heldout is None:
+        heldout = (np.zeros(1, np.int64), np.zeros(1, np.int64),
+                   np.zeros(1, np.float32))
+        n_heldout = 0
+    else:
+        n_heldout = len(heldout[0])
+    hu = pu.row_of_owner[np.asarray(heldout[0], np.int64)]
+    hv = pi.row_of_owner[np.asarray(heldout[1], np.int64)]
+    meta = _ratings_meta(geom, (pu, pi), users.shape[0], n_heldout, S)
+    arrays = tuple(_put(a, "ratings", mesh) for a in
+                   (ui, uv, pu.piece_slot, ii, iv, pi.piece_slot)) \
+        + tuple(_put(a, "heldout", mesh) for a in
+                (hu.astype(np.int32), hv.astype(np.int32),
+                 np.asarray(heldout[2], np.float32)))
+    return arrays, meta
+
+
+def _put(x, leaf: str, mesh: Mesh):
+    """One array placed as the ``als_sparse`` rule table says."""
+    from tpu_distalg.parallel import partition
+
+    return partition.put(x, leaf, "als_sparse", mesh)
+
+
+def _stub_rows(plan, n_ratings: int):
+    """``int32 (n_ratings,)`` on the device: the factor row of the owner
+    of each place of a side's owner-ordered stub list. The rows' steps
+    scattered at the owners' offsets, then one running sum (exact in
+    int32); owners with no rating share an offset and their steps add
+    up."""
+    deg, rows = plan.degrees, plan.row_of_owner.astype(np.int64)
+    off = np.cumsum(deg) - deg
+    step = np.diff(rows, prepend=0)
+    return _running_rows(jnp.asarray(off, jnp.int32),
+                         jnp.asarray(step, jnp.int32), n_ratings)
+
+
+def _running_rows(off, step, n_ratings: int):
+    marks = jnp.zeros((n_ratings,), jnp.int32).at[off].add(step, mode="drop")
+    return jnp.cumsum(marks)
+
+
+_running_rows = jax.jit(_running_rows, static_argnames="n_ratings")
+
+
+def _planted_table(plan, gen, seed, side: int, width: int):
+    """The planted factor of every row of a side's table, zero where a
+    row has no owner."""
+    own = jnp.asarray(plan.owner_of_row)
+
+    @jax.jit
+    def build(own, seed):
+        rows = gen.planted(jnp.maximum(own, 0), seed, side)
+        rows = jnp.where((own >= 0)[:, None], rows, 0.0)
+        return jnp.pad(rows, ((0, 0), (0, width - rows.shape[1])))
+
+    return build(own, seed)
+
+
+def side_generator(mesh: Mesh, geom, gen, side: int, zero_row: int):
+    """The jitted draw of one side's packed ratings, a shard's blocks to
+    a chip: ``f(k0, n_valid, seg_owner, stub_row_other, planted_other,
+    seed) -> (idx, val)``."""
+    from jax.sharding import PartitionSpec as P
+
+    from tpu_distalg.parallel import partition
+
+    L, k = geom.seg_slots, geom.k
+
+    def block(args, stub_row_other, planted_other, seed):
+        k0, n_valid, owner = args
+        lane = jnp.arange(L, dtype=jnp.int32)[None, :]
+        ok = lane < n_valid[:, None]
+        mine = (k0[:, None] + lane).astype(jnp.uint32)
+        mine = jnp.where(ok, mine, jnp.uint32(0))
+        if side == 0:        # a user's stub: its item stub, pair id p
+            pair, theirs = mine, gen.item_stub(mine, seed)
+        else:                # an item's stub: its user stub is the pair
+            theirs = gen.user_stub(mine, seed)
+            pair = theirs
+        row = stub_row_other.at[theirs.astype(jnp.int32).reshape(-1)].get(
+            mode="promise_in_bounds")
+        other = planted_other.at[row].get(mode="promise_in_bounds")
+        own = gen.planted(jnp.maximum(owner, 0), seed, side)
+        dot = jnp.sum(own[:, None, :]
+                      * other.reshape(-1, L, other.shape[-1])[..., :k],
+                      axis=-1)
+        r = gen.rating(dot, pair, seed)
+        idx = jnp.where(ok, row.reshape(-1, L), zero_row)
+        return idx.astype(jnp.int32).reshape(geom.block_shape), \
+            jnp.where(ok, r, 0.0).reshape(geom.block_shape)
+
+    def body(k0, n_valid, owner, stub_row_other, planted_other, seed):
+        return jax.lax.map(
+            lambda a: block(a, stub_row_other, planted_other, seed),
+            (k0, n_valid, owner))
+
+    spec = P(DATA_AXIS)
+    return jax.jit(
+        data_parallel(body, mesh,
+                      in_specs=(spec, spec, spec, P(), P(), P()),
+                      out_specs=(spec, spec)),
+        out_shardings=(partition.leaf_sharding(
+            "als_sparse", "ratings", mesh),) * 2)
+
+
+def plan_ratings(n_ratings: int, n_users: int, n_items: int, k: int,
+                 n_shards: int = 1, *, n_heldout: int = 0, degrees=None,
+                 geometry: dict | None = None, **gen_kw) -> dict:
+    """The host's half of the seeded loader: both degree sequences
+    (functions of the sizes alone, ``datasets.power_law_degrees``; or
+    ``degrees=(users', items')``), both sides' pack, and the ``meta``
+    that states the layout. No device is touched and no seed is read:
+    every seed's table has these sizes."""
+    from tpu_distalg.ops import als_sparse
+    from tpu_distalg.utils import datasets as dsets
+
+    par = dict(RATINGS_DEFAULTS, **gen_kw)
+    geom = _sparse_geometry(k, n_users, n_items, geometry)
+    if degrees is None:
+        degrees = (
+            dsets.power_law_degrees(n_users, n_ratings, par["d_min"],
+                                    par["user_d_max"], 1),
+            dsets.power_law_degrees(n_items, n_ratings, par["d_min"],
+                                    par["item_d_max"], 2))
+    du, di = (np.asarray(d, np.int64) for d in degrees)
+    if du.sum() != n_ratings or di.sum() != n_ratings:
+        raise ValueError(
+            f"the two degree sequences add up to {du.sum()} and "
+            f"{di.sum()}, not to {n_ratings} ratings")
+    if (du.shape[0], di.shape[0]) != (n_users, n_items):
+        raise ValueError(
+            f"degrees of {du.shape[0]} users and {di.shape[0]} items "
+            f"for a table of {n_users} by {n_items}")
+    plans = (als_sparse.plan_side(du, geom, n_shards),
+             als_sparse.plan_side(di, geom, n_shards))
+    return _ratings_meta(geom, plans, n_ratings, n_heldout, n_shards,
+                         generator=tuple(sorted(par.items())))
+
+
+def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
+                        k: int, mesh: Mesh, *, data_seed: int = 0,
+                        **plan_kw):
+    """The loader of a seeded ratings table: ``n_ratings`` explicit
+    ratings of ``n_users`` by ``n_items`` made ON DEVICE, packed for the
+    sparse trainer, and the ``meta`` that states the layout
+    (:func:`plan_ratings`, which ``plan_kw`` reach: the sizes never
+    depend on the seed, so the pack and every compiled program are the
+    same for every seed). The seed pairs the two sides' stubs, plants
+    the model and draws the noise (``datasets.seeded_ratings``);
+    ``n_heldout`` more pairs are drawn the same way and never trained
+    on. Returns ``(arrays, meta)``: ``arrays`` is what the trainer's
+    function takes before the two factor tables."""
+    from tpu_distalg.ops import als_sparse
+    from tpu_distalg.telemetry import events as tevents
+    from tpu_distalg.utils import datasets as dsets
+
+    S = mesh.shape[DATA_AXIS]
+    seed = jnp.int32(data_seed)
+    with tevents.span("als:prepare", ratings=n_ratings, users=n_users,
+                      items=n_items, k=k, layout=RATINGS_LAYOUT):
+        with tevents.span("als:pack", ratings=n_ratings):
+            meta = plan_ratings(n_ratings, n_users, n_items, k, S,
+                                **plan_kw)
+            meta["data_seed"] = int(data_seed)
+            geom, plans = meta["geometry"], (meta["user"], meta["item"])
+            stubs = [tuple(_put(a, "ratings", mesh) for a in (
+                *als_sparse.segment_stubs(p, geom), p.seg_owner))
+                for p in plans]
+        tevents.current().fields.update(_prepare_fields(meta))
+        par = dict(meta["generator"])
+        gen = dsets.seeded_ratings(
+            n_ratings, k, mean=par["mean"], scale=par["scale"],
+            noise=par["noise"])
+        with tevents.span("als:generate", slots=plans[0].slots_held
+                          + plans[1].slots_held):
+            stub_rows = [_stub_rows(p, n_ratings) for p in plans]
+            planted = [_planted_table(p, gen, seed, s, geom.width)
+                       for s, p in enumerate(plans)]
+            sides = []
+            for s in (0, 1):
+                o = 1 - s
+                fn = side_generator(mesh, geom, gen, s,
+                                    plans[o].static.zero_row)
+                sides.append(fn(*stubs[s], stub_rows[o], planted[o], seed))
+            jax.block_until_ready(sides)
+        with tevents.span("als:heldout", pairs=meta["n_heldout"]):
+            held = _heldout_pairs(gen, stub_rows, planted, seed,
+                                  max(meta["n_heldout"], 1), geom.k)
+            jax.block_until_ready(held)
+        del stub_rows, planted, stubs
+    pieces = [_put(p.piece_slot, "ratings", mesh) for p in plans]
+    arrays = (*sides[0], pieces[0], *sides[1], pieces[1], *held)
+    return arrays, meta
+
+
+def _heldout_pairs(gen, stub_rows, planted, seed, n: int, k: int):
+    """``n`` held-out pairs as factor rows and their ratings: each side
+    picked by a random stub (so by degree), rated by the planted model
+    with a noise stream of its own."""
+    @jax.jit
+    def draw(rows_u, rows_i, pl_u, pl_i, seed):
+        i = jnp.arange(n, dtype=jnp.uint32)
+        ju, jv = gen.heldout_stubs(i, seed)
+        hu = rows_u.at[ju.astype(jnp.int32)].get(mode="promise_in_bounds")
+        hv = rows_i.at[jv.astype(jnp.int32)].get(mode="promise_in_bounds")
+        dot = jnp.sum(pl_u.at[hu].get(mode="promise_in_bounds")[:, :k]
+                      * pl_i.at[hv].get(mode="promise_in_bounds")[:, :k],
+                      axis=1)
+        return hu, hv, gen.rating(dot, i, seed, 1)
+
+    return draw(stub_rows[0], stub_rows[1], planted[0], planted[1], seed)
+
+
+def start_factors(meta: dict, mesh: Mesh, seed: int):
+    """The two factor tables a fit starts from: the item side a uniform
+    draw on [0, 1) hashed from the seed and the item's id (the same for
+    every layout), the user side zero (the first half never reads it),
+    placed as the trainer returns them."""
+    from tpu_distalg.utils import datasets as dsets
+
+    geom = meta["geometry"]
+    k, W = geom.k, geom.width
+    own = jnp.asarray(meta["item"].owner_of_row)
+
+    @jax.jit
+    def draw(own, seed):
+        ids = jnp.maximum(own, 0).astype(jnp.uint32)[:, None] \
+            * np.uint32(k) + jnp.arange(k, dtype=jnp.uint32)[None, :]
+        key = dsets._mix32(seed.astype(jnp.uint32) * np.uint32(0x9E3779B1)
+                           + np.uint32(0x51ED270B))
+        bits = dsets._mix32(ids ^ key) >> np.uint32(8)
+        rows = bits.astype(jnp.float32) * (2.0 ** -24)
+        rows = jnp.where((own >= 0)[:, None], rows, 0.0)
+        return jnp.pad(rows, ((0, 0), (0, W - k)))
+
+    Theta = _put(draw(own, jnp.int32(seed)), "factors", mesh)
+    X = _put(jnp.zeros((meta["user"].static.table_rows, W), jnp.float32),
+             "factors", mesh)
+    return X, Theta
+
+
+def rows_from_owners(F, plan, width: int):
+    """A side's factor table from its factors in owner order."""
+    return _place_rows(jnp.asarray(F, jnp.float32),
+                       jnp.asarray(plan.owner_of_row), width)
+
+
+def owners_from_rows(table, plan, k: int):
+    """A side's factors in owner order from its table."""
+    return _take_rows(table, jnp.asarray(plan.row_of_owner), k)
+
+
+def _place_rows(F, own, width: int):
+    rows = jnp.where((own >= 0)[:, None], F[jnp.maximum(own, 0)], 0.0)
+    return jnp.pad(rows, ((0, 0), (0, width - F.shape[1])))
+
+
+def _take_rows(table, rows, k: int):
+    return table[rows][:, :k]
+
+
+# one trace a shape, not one a call
+_place_rows = jax.jit(_place_rows, static_argnames="width")
+_take_rows = jax.jit(_take_rows, static_argnames="k")
+
+
+def _make_fit_fn_sparse(mesh: Mesh, config: ALSConfig, meta: dict):
+    """``fit(user idx, val, pieces, item idx, val, pieces, held-out
+    users', items' rows, ratings, X, Theta) -> (X, Theta, errs, seen)``:
+    ``config.n_iterations`` ALS iterations (the user half from Theta,
+    then the item half from the new X: one function over (owners'
+    ratings, the other side's table)), ``errs`` float32 ``(iterations,
+    2)`` the training and the held-out RMSE after each, ``seen`` int32
+    ``(iterations, 2)`` the ratings that entered each half."""
+    from jax.sharding import PartitionSpec as P
+
+    from tpu_distalg.ops import als_sparse
+    from tpu_distalg.parallel import partition
+
+    geom = meta["geometry"]
+    if config.k != geom.k:
+        raise ValueError(f"the table was packed for rank {geom.k}, the "
+                         f"configuration asks for {config.k}")
+    su, si = meta["user"].static, meta["item"].static
+    n_ratings = max(meta["n_ratings"], 1)
+
+    def half(static, other_zero_row):
+        def run(idx, val, pieces, other, own):
+            return als_sparse.half_sweep(
+                idx, val, pieces, other, own, static=static,
+                other_zero_row=other_zero_row, geom=geom,
+                lam=config.lam, axis=DATA_AXIS)
+
+        return data_parallel(
+            run, mesh, in_specs=(*(P(DATA_AXIS),) * 3, P(), P()),
+            out_specs=(P(), P(), P()))
+
+    user_half, item_half = half(su, si.zero_row), half(si, su.zero_row)
+
+    def fit(ui, uv, up, ii, iv, ip, hu, hv, hr, X, Theta):
+        def iteration(carry, _):
+            X, Theta = carry
+            X, _, seen_u = user_half(ui, uv, up, Theta, X)
+            Theta, sse, seen_i = item_half(ii, iv, ip, X, Theta)
+            from tpu_distalg.telemetry import names
+
+            with jax.named_scope(names.ALS_UPDATE):
+                errs = jnp.stack([
+                    jnp.sqrt(jnp.maximum(sse, 0.0) / n_ratings),
+                    als_sparse.heldout_rmse(X, Theta, hu, hv, hr)])
+            return (X, Theta), (errs, jnp.stack([seen_u, seen_i]))
+
+        (X, Theta), (errs, seen) = jax.lax.scan(
+            iteration, (X, Theta), None, length=config.n_iterations)
+        return X, Theta, errs, seen
+
+    rep = partition.leaf_sharding("als_sparse", "factors", mesh)
+    # the tables in are the tables out: no second pair is held
+    return jax.jit(fit, out_shardings=(rep, rep, rep, rep),
+                   donate_argnums=(9, 10))
+
+
+def fit_ratings(mesh: Mesh, config: ALSConfig, arrays, meta: dict, *,
+                init=None, checkpoint_dir: str | None = None,
+                checkpoint_every: int = 5) -> ALSResult:
+    """Fit ``X Theta^T`` to a ratings table (:func:`build_ratings_table`
+    or :func:`ratings_from_coo`). ``init`` is ``(U0, V0)`` in owner
+    order, or None for the seed's draw (:func:`start_factors`). The
+    checkpointed state is the factor pair in owner order, which is what
+    ``tda serve`` reads; segmented and straight runs are bitwise equal
+    (an iteration is a function of the factors alone)."""
+    geom = meta["geometry"]
+    pu, pi = meta["user"], meta["item"]
+    if init is None:
+        X, Theta = start_factors(meta, mesh, config.seed)
+    else:
+        X = _put(rows_from_owners(init[0], pu, geom.width), "factors", mesh)
+        Theta = _put(rows_from_owners(init[1], pi, geom.width), "factors",
+                     mesh)
+
+    def result(X, Theta, errs):
+        errs = jnp.asarray(errs).reshape(-1, 2)
+        metrics.guard_finite(errs, "sparse ALS rmse history")
+        return ALSResult(U=owners_from_rows(X, pu, geom.k),
+                         V=owners_from_rows(Theta, pi, geom.k),
+                         rmse_history=errs[:, 0],
+                         heldout_history=errs[:, 1])
+
+    if checkpoint_dir is None:
+        fn = make_fit_fn(mesh, config, meta)
+        X, Theta, errs, _ = fn(*arrays, X, Theta)
+        return result(X, Theta, errs)
+
+    from tpu_distalg.utils import checkpoint as ckpt
+
+    def run_seg(fn, state, t0):
+        del t0        # an iteration is a function of the factors alone
+        X = _put(rows_from_owners(state[0], pu, geom.width), "factors", mesh)
+        Theta = _put(rows_from_owners(state[1], pi, geom.width), "factors",
+                     mesh)
+        X, Theta, errs, _ = fn(*arrays, X, Theta)
+        return (owners_from_rows(X, pu, geom.k),
+                owners_from_rows(Theta, pi, geom.k)), errs
+
+    (U, V), errs, _ = ckpt.run_segmented(
+        checkpoint_dir, checkpoint_every, config.n_iterations,
+        make_seg_fn=lambda seg: make_fit_fn(
+            mesh, dataclasses.replace(config, n_iterations=seg), meta),
+        run_seg=run_seg,
+        state0=(owners_from_rows(X, pu, geom.k),
+                owners_from_rows(Theta, pi, geom.k)),
+        tag="als", span_fields=segment_fields(meta))
+    errs = jnp.asarray(errs).reshape(-1, 2)
+    return ALSResult(U=jnp.asarray(U), V=jnp.asarray(V),
+                     rmse_history=errs[:, 0], heldout_history=errs[:, 1])
 
 
 def _make_streamed_block_fns(mesh: Mesh, config: ALSConfig, n: int):

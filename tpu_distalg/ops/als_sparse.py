@@ -1,0 +1,604 @@
+"""Sparse ALS: a Gramian and a solve per owner, from a ratings list.
+
+The dense path (``models/als.py``, ``ops/linalg.py``) amortises one
+``k x k`` Gram and one Cholesky over every row of a half-sweep, because
+a dense ``R`` gives every row the same system. A ratings list does not:
+owner ``u`` (a user in the user half, an item in the item half) has
+
+    A_u = sum_{v in Omega_u} theta_v theta_v^T + lam * n_u * I
+    b_u = sum_{v in Omega_u} r_uv theta_v
+
+so a half-sweep is a gather of ``theta`` rows by index, one small
+Gramian an owner, and one small solve an owner. This file holds the
+three pieces in XLA forms and the pack that gives them static shapes.
+
+**The pack** (:func:`plan_side`, host, from the degrees alone). An
+owner's ratings are cut into *segments* of ``seg_slots`` (32) slots.
+Owners are grouped by how many segments they need, rounded up to the
+next of ``classes`` (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48). A *block* is
+always ``batch`` segments (``batch * seg_slots`` rating slots, one
+static shape): in class ``K`` it holds ``batch / K`` owners of ``K``
+segments each, so an owner's Gramian is ONE contraction ``K *
+seg_slots`` deep on the MXU and nothing is added up afterwards. An owner
+with more segments than the largest class is cut into *pieces* of
+``piece_segs`` (64) segments; a block holds ``batch / piece_segs`` pieces, an
+owner larger than a block is carried across blocks, and the pieces'
+Gramians are added into that owner's row of a buffer (a scatter-add of a
+few tens of thousands of tiles a half, where a segment at a time would
+be millions). The skew pays for itself: half of a power-law set's
+ratings sit in owners of thousands, which contract deep; the price is
+the padding of the small (slots held / ratings, stated by the plan).
+
+Factor rows live in *processing order* (class by class, shard-major), so
+a solved batch is one contiguous slab of the table and the mesh's
+all-gather returns the table as it is read; ``row_of_owner`` maps back.
+Row ``zero_row`` (past every shard's rows) is zero and is what a padding
+slot points at. Lane ``k`` of a gathered row takes the rating and lane
+``k + 1`` the slot's validity before the product, so ``b_u``, ``sum
+r^2`` and ``n_u`` come out of the same MXU pass as ``A_u`` (rows ``k``
+and ``k + 1`` of the product) and the training error needs no second
+gather.
+
+**The solve** (:func:`solve_batch`) is a Cholesky factorisation with the
+batch along the lanes: ``(n, n, batch)`` in panels of 8 columns, every
+step an elementwise operation over ``batch`` systems at once (the
+Gramians come out of :func:`block_gramians` in that layout). XLA's own
+``cho_factor`` walks 100 dependent columns per system batch with the
+matrix on the minor dimensions: 13.9 us a system on one v5e against 2.4
+here (Step 0, ``benchmarks/tools/step0_als.py``).
+
+Precision: the Gramians are ``Precision.HIGHEST`` products of float32
+rows (six bfloat16 passes: every factor enters with its 24 bits),
+float32 accumulation; the solve is float32 on the VPU. Nothing here is
+imported before the sparse trainer's first call.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import numpy as np
+
+LANES = 128
+# the light classes at the published shape: steps of a half and a third,
+# so that an owner is padded by a third at most (the powers of two alone
+# pad by a half) and every size divides a batch of 192 x 2^n
+CLASSES = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48)
+BATCH_UNIT = 192            # the least batch those sizes and 64 divide
+PANEL = 8                   # columns a Cholesky panel (16 compile for
+#                             five minutes unrolled: PERF.md section 6)
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseGeometry:
+    """The static numbers of the pack and of a block's programs."""
+
+    k: int                  # rank
+    seg_slots: int = 32     # rating slots a segment
+    piece_segs: int = 64    # segments a piece (the top class)
+    batch: int = 6144       # owners a solve; segments a block
+    # segments an owner of each light class is padded to, ascending
+    classes: tuple[int, ...] = CLASSES
+
+    def __post_init__(self):
+        object.__setattr__(self, "classes", tuple(self.classes))
+        sizes = (*self.classes, self.piece_segs)
+        if self.piece_segs < 2 or list(sizes) != sorted(set(sizes)) \
+                or any(self.batch % c for c in sizes):
+            raise ValueError(
+                f"piece_segs {self.piece_segs} and the classes "
+                f"{self.classes} must ascend to it and each divide "
+                f"batch {self.batch}")
+
+    @property
+    def width(self) -> int:
+        """Lanes a factor row is held in: ``k`` columns, the rating's
+        lane, the validity's lane, rounded up to whole vectors."""
+        return _round_up(self.k + 2, LANES)
+
+    @property
+    def solve_n(self) -> int:
+        return _round_up(self.k, PANEL)
+
+    @property
+    def block_slots(self) -> int:
+        return self.batch * self.seg_slots
+
+    @property
+    def block_shape(self) -> tuple[int, int]:
+        """How a block's slots are held: whole vectors of 128 lanes
+        (a last dimension of 32 would be padded to 128 in HBM, four
+        times the bytes), segment after segment."""
+        n = self.block_slots
+        return (n // LANES, LANES) if n % LANES == 0 else (1, n)
+
+
+def geometry_for(k: int, n_users: int, n_items: int) -> SparseGeometry:
+    """The geometry a loader takes where none is stated: the published
+    shape's segments, classes and pieces, the batch scaled down with
+    the larger side (a batch of 6144 owners a class is all padding at a
+    thousand owners) in whole ``BATCH_UNIT``s."""
+    want = -(-max(n_users, n_items) // (16 * BATCH_UNIT)) * BATCH_UNIT
+    return SparseGeometry(k=k, batch=max(BATCH_UNIT, min(6144, want)))
+
+
+@dataclasses.dataclass(frozen=True)
+class SideStatic:
+    """What of a side's plan the compiled half-sweep is built from."""
+
+    n_shards: int
+    rows_local: int                       # factor rows a shard
+    n_blocks: int                         # blocks a shard
+    # (class K, first block, superblocks, first row), one a light class
+    light: tuple[tuple[int, int, int, int], ...]
+    # (first block, blocks, first row, heavy rows) of the piece class
+    heavy: tuple[int, int, int, int]
+
+    @property
+    def zero_row(self) -> int:
+        return self.n_shards * self.rows_local
+
+    @property
+    def table_rows(self) -> int:
+        return self.zero_row + 8
+
+
+@dataclasses.dataclass
+class SidePlan:
+    """One side's pack: the static part and the host arrays that place
+    every segment."""
+
+    static: SideStatic
+    degrees: np.ndarray        # int64 (owners,)
+    row_of_owner: np.ndarray   # int32 (owners,) global factor row
+    owner_of_row: np.ndarray   # int32 (table rows,), -1 where none
+    seg_owner: np.ndarray      # int32 (shards * blocks, batch), -1 none
+    seg_pos: np.ndarray        # int32 the same: segment within its owner
+    piece_slot: np.ndarray     # int32 (shards * heavy blocks, pieces a
+    #                            block): heavy row of the piece's owner,
+    #                            ``heavy rows`` (a dump row) where none
+    slots_held: int
+    padding_share: float       # slots held / ratings
+
+
+def plan_side(degrees, geom: SparseGeometry, n_shards: int = 1) -> SidePlan:
+    """Place every owner of one side: its class, its shard, its factor
+    row and its segments, from the degrees alone."""
+    deg = np.asarray(degrees, np.int64)
+    n = deg.shape[0]
+    L, P, B, S = geom.seg_slots, geom.piece_segs, geom.batch, n_shards
+    segs = -(-deg // L)
+    per_piece_block = B // P
+
+    # who goes where: class lists, dealt round-robin to the shards
+    lists: list[list[np.ndarray]] = []
+    lo = 0
+    for K in geom.classes:
+        own = np.flatnonzero((segs > lo) & (segs <= K))
+        lists.append([own[s::S] for s in range(S)])
+        lo = K
+    heavy_all = np.flatnonzero(segs > lo)
+    pieces = -(-segs // P)
+    heavy_all = heavy_all[np.argsort(-pieces[heavy_all], kind="stable")]
+    heavy_lists = [heavy_all[s::S] for s in range(S)]
+    empty_all = np.flatnonzero(deg == 0)
+    empty_lists = [empty_all[s::S] for s in range(S)]
+
+    light, block0, row0 = [], 0, 0
+    for K, per_shard in zip(geom.classes, lists):
+        most = max(len(x) for x in per_shard)
+        n_super = -(-most // B)
+        light.append((K, block0, n_super, row0))
+        block0 += n_super * K
+        row0 += n_super * B
+    heavy_rows = _round_up(max(len(x) for x in heavy_lists), B)
+    heavy_pieces = max(int(pieces[x].sum()) for x in heavy_lists)
+    heavy_blocks = -(-heavy_pieces // per_piece_block)
+    heavy = (block0, heavy_blocks, row0, heavy_rows)
+    block0 += heavy_blocks
+    row0 += heavy_rows
+    empty_row0 = row0
+    row0 += max(len(x) for x in empty_lists)
+    R = _round_up(max(row0, 8), 8)
+    n_blocks = max(block0, 1)
+    static = SideStatic(S, R, n_blocks, tuple(light), heavy)
+
+    row_of_owner = np.full(n, -1, np.int32)
+    owner_of_row = np.full(static.table_rows, -1, np.int32)
+    seg_owner = np.full((S, n_blocks * B), -1, np.int32)
+    seg_pos = np.zeros((S, n_blocks * B), np.int32)
+    piece_slot = np.full((S, max(heavy_blocks, 1) * per_piece_block),
+                         heavy_rows, np.int32)
+    for s in range(S):
+        for (K, b0, _, r0), per_shard in zip(light, lists):
+            own = per_shard[s]
+            row_of_owner[own] = s * R + r0 + np.arange(len(own))
+            at = b0 * B + np.arange(len(own) * K)
+            seg_owner[s, at] = np.repeat(own, K)
+            seg_pos[s, at] = np.tile(np.arange(K), len(own))
+        own = heavy_lists[s]
+        row_of_owner[own] = s * R + heavy[2] + np.arange(len(own))
+        pc = pieces[own]
+        n_pc = int(pc.sum())
+        piece_slot[s, :n_pc] = np.repeat(np.arange(len(own)), pc)
+        at = heavy[0] * B + np.arange(n_pc * P)
+        seg_owner[s, at] = np.repeat(own, pc * P)
+        first = np.repeat(np.cumsum(pc) - pc, pc * P) * P
+        seg_pos[s, at] = np.arange(n_pc * P) - first
+        own = empty_lists[s]
+        row_of_owner[own] = s * R + empty_row0 + np.arange(len(own))
+    owner_of_row[row_of_owner] = np.arange(n, dtype=np.int32)
+    held = S * n_blocks * B * L
+    return SidePlan(
+        static=static, degrees=deg, row_of_owner=row_of_owner,
+        owner_of_row=owner_of_row,
+        seg_owner=seg_owner.reshape(S * n_blocks, B),
+        seg_pos=seg_pos.reshape(S * n_blocks, B),
+        piece_slot=piece_slot.reshape(
+            S * max(heavy_blocks, 1), per_piece_block),
+        slots_held=held,
+        padding_share=held / max(int(deg.sum()), 1))
+
+
+def segment_stubs(plan: SidePlan, geom: SparseGeometry):
+    """``(k0, n_valid)`` int32 ``(shards * blocks, batch)``: the place
+    of each segment's first rating in the owner-ordered list of the
+    side's ratings, and how many of its slots hold one."""
+    deg = plan.degrees
+    off = np.cumsum(deg) - deg
+    own = plan.seg_owner
+    at = np.where(own >= 0, own, 0)
+    start = plan.seg_pos.astype(np.int64) * geom.seg_slots
+    n_valid = np.clip(deg[at] - start, 0, geom.seg_slots)
+    n_valid = np.where(own >= 0, n_valid, 0)
+    k0 = np.where(n_valid > 0, off[at] + start, 0)
+    return k0.astype(np.int32), n_valid.astype(np.int32)
+
+
+def pack_coo(plan: SidePlan, geom: SparseGeometry, owners, others,
+             ratings, other_row_of_owner, other_zero_row: int):
+    """Host pack of an explicit ratings list for one side: ``idx`` int32
+    and ``val`` float32 ``(shards * blocks, *geom.block_shape)``, a
+    block's ``batch`` segments one after another. A pair listed twice is
+    held twice."""
+    owners = np.asarray(owners, np.int64)
+    order = np.argsort(owners, kind="stable")
+    got = np.bincount(owners, minlength=plan.degrees.shape[0])
+    if not np.array_equal(got, plan.degrees):
+        raise ValueError("the plan's degrees are not this list's")
+    rows = np.asarray(other_row_of_owner)[np.asarray(others)[order]]
+    vals = np.asarray(ratings, np.float32)[order]
+    k0, n_valid = segment_stubs(plan, geom)
+    lane = np.arange(geom.seg_slots)
+    ok = lane < n_valid[..., None]
+    at = np.where(ok, k0[..., None] + lane, 0)
+    if rows.size == 0:
+        rows = np.zeros(1, np.int32)
+        vals = np.zeros(1, np.float32)
+    shape = (k0.shape[0], *geom.block_shape)
+    idx = np.where(ok, rows[at], other_zero_row).astype(np.int32)
+    val = np.where(ok, vals[at], 0.0).astype(np.float32)
+    return idx.reshape(shape), val.reshape(shape)
+
+
+# ---------------------------------------------------------------- device
+
+
+def block_gramians(other, idx_b, val_b, K: int, geom: SparseGeometry,
+                   zero_row: int):
+    """One block's ``batch / K`` extended Gramians with the owners
+    along the lanes, ``(width, width, batch / K)``: the gather of the
+    other side's rows, the rating and the validity written into lanes
+    ``k`` and ``k + 1``, one float32-accurate product ``K * seg_slots``
+    deep an owner."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_distalg.telemetry import names
+
+    k, W = geom.k, geom.width
+    flat = idx_b.reshape(-1)
+    with jax.named_scope(names.ALS_GATHER):
+        G = other.at[flat].get(mode="promise_in_bounds")
+    with jax.named_scope(names.ALS_GRAM):
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+        r = val_b.reshape(-1, 1)
+        ok = (flat != zero_row).astype(jnp.float32)[:, None]
+        G = jnp.where(lane == k, r, jnp.where(lane == k + 1, ok, G))
+        G = G.reshape(geom.batch // K, K * geom.seg_slots, W)
+        return to_lanes(jnp.einsum(
+            "osd,ose->ode", G, G, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32))
+
+
+def to_lanes(Ap):
+    """``(owners, width, width)`` to ``(width, width, owners)``: the
+    layout the solve works in."""
+    import jax.numpy as jnp
+
+    return jnp.transpose(Ap, (1, 2, 0))
+
+
+def cholesky_solve_lanes(M, rhs, panel: int):
+    """Solve ``A x = rhs`` for a batch of symmetric positive definite
+    systems held with the batch along the lanes: ``M`` is ``(n, n,
+    batch)``, ``rhs`` ``(n, batch)``, ``n`` a multiple of ``panel``.
+    Right-looking blocked Cholesky of the matrix with the right-hand
+    side as one more row (which does the forward substitution), then the
+    backward substitution, every step elementwise over the batch. The loops over panels are
+    rolled: the factorisation in about four stages, between which the
+    trailing matrix shrinks, a panel's update inside a stage taken over
+    the stage's whole matrix with the finished rows zero (1.4 times the
+    arithmetic of the form that shrinks every panel, a fortieth of its
+    code: unrolled at every call site it compiled for twelve minutes).
+    The matrix is held a column panel to a leading index, ``(panels,
+    panel, n, batch)``: a panel taken by a dynamic slice of the columns
+    of ``(n, n, batch)`` made XLA copy the whole batch into another
+    layout and back every panel (a third of the solve's time)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n, _, batch = M.shape
+    w = panel
+    n_panels = n // w
+    row = lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+
+    def diag_block(D):
+        """Unblocked Cholesky of a diagonal block given as ``D[j, i]``
+        (column, row): ``Lb[i][j]``."""
+        Lb = [[None] * w for _ in range(w)]
+        for j in range(w):
+            s = D[j, j]
+            for t in range(j):
+                s = s - Lb[j][t] * Lb[j][t]
+            Lb[j][j] = jnp.sqrt(s)
+            for i in range(j + 1, w):
+                s = D[j, i]
+                for t in range(j):
+                    s = s - Lb[i][t] * Lb[j][t]
+                Lb[i][j] = s / Lb[j][j]
+        return Lb
+
+    def stage(Ms, count):
+        """``count`` panels of the trailing matrix ``Ms`` ``(panels,
+        w, m + w, batch)``: its first ``count`` panels of L and what is
+        left of it."""
+        m = Ms.shape[2] - w
+        rows = lax.broadcasted_iota(jnp.int32, (m + w, 1), 0)
+
+        def factor(p, carry):
+            Ms, Ls = carry
+            q = p * w
+            pan = lax.dynamic_index_in_dim(Ms, p, 0, keepdims=False)
+            Lb = diag_block(
+                lax.dynamic_slice(pan, (0, q, 0), (w, w, batch)))
+            xc = []
+            for j in range(w):
+                s = pan[j]
+                for t in range(j):
+                    s = s - xc[t] * Lb[j][t][None, :]
+                # column j of L: nothing above its diagonal entry
+                xc.append(jnp.where(rows >= q + j,
+                                    s / Lb[j][j][None, :], 0.0))
+            upd = None
+            for t in range(w):
+                term = xc[t][None, None, :, :] \
+                    * xc[t][:m].reshape(m // w, w, 1, batch)
+                upd = term if upd is None else upd + term
+            Ls = lax.dynamic_update_index_in_dim(
+                Ls, jnp.stack(xc, axis=0), p, 0)
+            return Ms - upd, Ls
+
+        Ms, Ls = lax.fori_loop(
+            0, count, factor,
+            (Ms, jnp.zeros((count, w, m + w, batch), Ms.dtype)))
+        return Ms[count:, :, count * w:, :], Ls
+
+    # the right-hand side rides along as one more row of the matrix
+    # (under w - 1 rows of zeros): its row of L is L^-1 rhs, so the
+    # forward substitution costs no loop of its own. The trailing matrix
+    # shrinks between stages and not inside one: about four stages, each
+    # one rolled loop
+    extra = jnp.pad(rhs.reshape(n_panels, w, 1, batch),
+                    ((0, 0), (0, 0), (0, w - 1), (0, 0)))
+    Ms = jnp.concatenate([M.reshape(n_panels, w, n, batch), extra], axis=2)
+    per = -(-n_panels // 4)
+    parts, done = [], 0
+    while done < n_panels:
+        count = min(per, n_panels - done)
+        Ms, Ls = stage(Ms, count)
+        parts.append(jnp.pad(Ls, ((0, 0), (0, 0), (done * w, 0), (0, 0))))
+        done += count
+    Lm = jnp.concatenate(parts, axis=0)       # [panel, column, row, b]
+    y = Lm[:, :, n, :].reshape(n, batch)
+    Lm = Lm[:, :, :n, :]
+
+    def backward(i, x):
+        p = n_panels - 1 - i
+        q = p * w
+        pan = lax.dynamic_index_in_dim(Lm, p, 0, keepdims=False)
+        Lb = lax.dynamic_slice(pan, (0, q, 0), (w, w, batch))
+        v = lax.dynamic_slice(y, (q, 0), (w, batch))
+        below = row >= q + w          # x is still zero elsewhere
+        vp = [v[j] - jnp.sum(jnp.where(below, pan[j] * x, 0.0), axis=0)
+              for j in range(w)]
+        xp = [None] * w
+        for j in reversed(range(w)):
+            s = vp[j]
+            for t in range(j + 1, w):
+                s = s - Lb[j, t] * xp[t]
+            xp[j] = s / Lb[j, j]
+        return lax.dynamic_update_slice(x, jnp.stack(xp), (q, 0))
+
+    return lax.fori_loop(0, n_panels, backward, jnp.zeros_like(rhs))
+
+
+def solve_batch(Ap, lam: float, geom: SparseGeometry):
+    """From a batch of extended Gramians with the owners along the
+    lanes, ``(width, width, batch)``: the new factor rows ``(batch,
+    width)``, which owners have a rating, the squared training error of
+    those that have, and the ratings counted. ``A_u + lam n_u I`` is
+    solved exactly (Cholesky); an owner with no rating solves the
+    identity and is flagged."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_distalg.telemetry import names
+
+    k, W, w, n8 = geom.k, geom.width, PANEL, geom.solve_n
+    with jax.named_scope(names.ALS_SOLVE):
+        cnt = Ap[k + 1, k + 1]
+        has = cnt > 0
+        ridge = jnp.where(has, jnp.float32(lam) * cnt, 1.0)
+        b = Ap[:k, k]                                 # (k, batch)
+        ri = jax.lax.broadcasted_iota(jnp.int32, (n8, n8, 1), 0)
+        ci = jax.lax.broadcasted_iota(jnp.int32, (n8, n8, 1), 1)
+        M = jnp.where((ri < k) & (ci < k), Ap[:n8, :n8], 0.0) + jnp.where(
+            ri == ci, jnp.where(ri < k, ridge[None, None, :], 1.0), 0.0)
+        x = cholesky_solve_lanes(
+            M, jnp.pad(b, ((0, n8 - k), (0, 0))), w)[:k]   # (k, batch)
+    with jax.named_scope(names.ALS_UPDATE):
+        Ax = jnp.sum(Ap[:k, :k] * x[None, :, :], axis=1)
+        err = Ap[k, k] - 2.0 * jnp.sum(x * b, axis=0) \
+            + jnp.sum(x * Ax, axis=0)
+        sse = jnp.sum(jnp.where(has, err, 0.0))
+        seen = jnp.sum(cnt.astype(jnp.int32))
+        rows = jnp.pad(x.T, ((0, 0), (0, W - k)))
+    return rows, has, sse, seen
+
+
+def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
+               other_zero_row: int, geom: SparseGeometry, lam: float,
+               axis: str):
+    """One shard's half of an iteration: every owner of this shard from
+    the other side's table ``other`` (whole, constant through the half),
+    written into the shard's rows of ``own``; the shards' rows gathered
+    once at the end. Returns ``(table, sse, seen)``, the two sums over
+    all shards. Runs inside ``shard_map`` over ``axis``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from tpu_distalg.telemetry import names
+
+    B, P, W, R = geom.batch, geom.piece_segs, geom.width, static.rows_local
+    s = lax.axis_index(axis)
+    with jax.named_scope(names.ALS_UPDATE):
+        local = lax.dynamic_slice_in_dim(own, s * R, R, axis=0)
+    sse = jnp.float32(0.0)
+    seen = jnp.int32(0)
+
+    def grams(block, K):
+        return block_gramians(
+            other, lax.dynamic_index_in_dim(idx, block, keepdims=False),
+            lax.dynamic_index_in_dim(val, block, keepdims=False), K,
+            geom, other_zero_row)
+
+    def solve_into(carry, Ap, row):
+        local, sse, seen = carry
+        rows, has, e, c = solve_batch(Ap, lam, geom)
+        with jax.named_scope(names.ALS_UPDATE):
+            old = lax.dynamic_slice_in_dim(local, row, B, axis=0)
+            new = jnp.where(has[:, None], rows, old)
+            local = lax.dynamic_update_slice_in_dim(local, new, row, 0)
+        return local, sse + e, seen + c
+
+    block0, n_heavy_blocks, heavy_row0, heavy_rows = static.heavy
+    acc = None
+    if heavy_rows:
+        def piece_block(acc, i):
+            got = grams(block0 + i, P)
+            with jax.named_scope(names.ALS_GRAM):
+                slot = lax.dynamic_index_in_dim(piece_slot, i,
+                                                keepdims=False)
+                return acc.at[:, :, slot].add(got), None
+
+        acc, _ = lax.scan(
+            piece_block, jnp.zeros((W, W, heavy_rows + 1), jnp.float32),
+            jnp.arange(n_heavy_blocks))
+
+    # every batch of owners that is solved, of whatever class, is one
+    # step of ONE loop: a step's class picks how its Gramians are made,
+    # and the solve that follows is compiled once (a loop and a solve a
+    # class compiled for 190 s at eleven classes)
+    branches, kind, local_steps, rows = [], [], [], []
+
+    def light_branch(K, block0):
+        def make(i):
+            if K == 1:
+                return grams(block0 + i, 1)
+
+            def part(j, staging):
+                got = grams(block0 + i * K + j, K)
+                with jax.named_scope(names.ALS_GRAM):
+                    return lax.dynamic_update_slice_in_dim(
+                        staging, got, j * (B // K), 2)
+
+            with jax.named_scope(names.ALS_GRAM):
+                empty = jnp.zeros((W, W, B), jnp.float32)
+            return lax.fori_loop(0, K, part, empty)
+
+        return make
+
+    for K, block0, n_super, row0 in static.light:
+        if n_super:
+            kind += [len(branches)] * n_super
+            local_steps += list(range(n_super))
+            rows += [row0 + i * B for i in range(n_super)]
+            branches.append(light_branch(K, block0))
+    if heavy_rows:
+        n = heavy_rows // B
+        kind += [len(branches)] * n
+        local_steps += list(range(n))
+        rows += [heavy_row0 + i * B for i in range(n)]
+        branches.append(
+            lambda i: lax.dynamic_slice_in_dim(acc, i * B, B, axis=2))
+
+    if kind:
+        def step(carry, at):
+            which, i, row = at
+            Ap = branches[0](i) if len(branches) == 1 else \
+                lax.switch(which, branches, i)
+            return solve_into(carry, Ap, row), None
+
+        (local, sse, seen), _ = lax.scan(
+            step, (local, sse, seen),
+            tuple(jnp.asarray(np.asarray(a, np.int32))
+                  for a in (kind, local_steps, rows)))
+
+    with jax.named_scope(names.ALS_SYNC):
+        table = lax.all_gather(local, axis, axis=0, tiled=True)
+        sse = lax.psum(sse, axis)
+        seen = lax.psum(seen, axis)
+    with jax.named_scope(names.ALS_UPDATE):
+        table = jnp.concatenate(
+            [table, jnp.zeros((8, W), jnp.float32)], axis=0)
+    return table, sse, seen
+
+
+def heldout_rmse(X, Theta, hu, hv, hr, chunk: int = 1 << 16):
+    """Root mean squared error of ``x_u . theta_v`` on pairs given as
+    factor rows, float32 on the VPU, ``chunk`` pairs at a time (four
+    million pairs' rows at once are 4 GB)."""
+    import jax
+    import jax.numpy as jnp
+
+    n = hr.shape[0]
+    pad = (-n) % chunk if n > chunk else 0
+    step = chunk if n > chunk else n
+    ok = jnp.pad(jnp.ones((n,), jnp.float32), (0, pad))
+    parts = [jnp.pad(a, (0, pad)).reshape(-1, step) for a in (hu, hv, hr)]
+
+    def some(args):
+        u, v, r, ok = args
+        xu = X.at[u].get(mode="promise_in_bounds")
+        tv = Theta.at[v].get(mode="promise_in_bounds")
+        d = (jnp.sum(xu * tv, axis=1) - r) * ok
+        return jnp.sum(d * d)
+
+    total = jnp.sum(jax.lax.map(some, (*parts, ok.reshape(-1, step))))
+    return jnp.sqrt(total / n)

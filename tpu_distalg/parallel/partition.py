@@ -815,6 +815,16 @@ TABLE_ALS_TRAIN = register(RuleTable("als_train", (
     (r"^V$", _P(MODEL_AXIS, None)),
 )))
 
+#: ALS on a ratings list (models/als.py's sparse trainer): each side's
+#: packed blocks (rows, ratings, pieces, the loader's stubs) sharded
+#: over data along the blocks, both factor tables and the held-out
+#: pairs replicated (a half-sweep reads the other side's table whole
+#: and all-gathers its own).
+TABLE_ALS_SPARSE = register(RuleTable("als_sparse", (
+    (r"^ratings$", _P(DATA_AXIS)),
+    (r"^(factors|heldout)$", _P()),
+)))
+
 #: ALS serving layout (serve/artifacts.py): user factors replicated
 #: (any shard may score any user), item factors model-sharded for the
 #: fused per-shard top-k. reshard('als_train' → 'als_serve') is the

@@ -39,3 +39,16 @@ KMEANS_STATS = "tda.kmeans.stats"    # one-hot sums and counts; on the
 #                                      kernel and its transpose
 KMEANS_SYNC = "tda.kmeans.sync"      # the psum of (sums, counts)
 KMEANS_UPDATE = "tda.kmeans.update"  # new centres, the convergence shift
+# the parts of a sparse ALS half-sweep (ops/als_sparse.py); the
+# benchmark's gather_ms_per_sweep.als, gram_ms_per_sweep.als,
+# solve_ms_per_sweep.als and the two als_*_roofline metrics read them
+ALS_GATHER = "tda.als.gather"  # the other side's rows fetched by index
+ALS_GRAM = "tda.als.gram"      # the rating's and validity's lanes, the
+#                                per-owner products on the MXU, the
+#                                pieces of a large owner added up
+ALS_SOLVE = "tda.als.solve"    # the ridge, the Cholesky factorisation
+#                                along the lanes, the two substitutions
+ALS_SYNC = "tda.als.sync"      # the all-gather of a half's rows and the
+#                                psum of its sums; empty on one shard
+ALS_UPDATE = "tda.als.update"  # what is left: the rows' write, the
+#                                training and held-out RMSE

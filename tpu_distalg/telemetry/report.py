@@ -178,6 +178,7 @@ def summarize(evts: list[dict]) -> dict:
     row_formats: list[str] = []
     pass_forms: dict[str, list[str]] = {"gather": [], "scatter": []}
     field_splits: list[tuple] = []
+    als_forms: list[str] = []
     t_wall = [e["t_wall"] for e in evts if "t_wall" in e]
     for e in evts:
         ev = e.get("ev")
@@ -223,6 +224,16 @@ def summarize(evts: list[dict]) -> dict:
                          e.get("addr_fields", 0))
                 if split not in field_splits:
                     field_splits.append(split)
+            # and ALS' how R is held (a dense R says nothing; a ratings
+            # list says so) with the form of each piece of a half-sweep
+            # (models/als.segment_fields: xla / mosaic)
+            if "als_gram_form" in e:
+                form = (f"{e.get('layout', '?')} (gather: "
+                        f"{e.get('als_gather_form', '?')}, gramians: "
+                        f"{e.get('als_gram_form', '?')}, solve: "
+                        f"{e.get('als_solve_form', '?')})")
+                if form not in als_forms:
+                    als_forms.append(form)
         elif ev == "span_end":
             name = e.get("name", "?")
             open_spans[name] = open_spans.get(name, 1) - 1
@@ -296,6 +307,7 @@ def summarize(evts: list[dict]) -> dict:
         "row_formats": row_formats,
         "pass_forms": pass_forms,
         "field_splits": field_splits,
+        "als_forms": als_forms,
         "unfinished_phases": sorted(
             k for k, v in open_spans.items() if v > 0),
         "marks": marks,
@@ -381,6 +393,8 @@ def render(s: dict) -> str:
     for n_dict, n_values, n_addr in s.get("field_splits") or ():
         lines.append(f"fields by value: {n_dict} ({n_values} values), "
                      f"by address: {n_addr}")
+    if s.get("als_forms"):
+        lines.append(f"R layout: {', '.join(s['als_forms'])}")
     if s.get("dist_forms"):
         lines.append(f"distances: {', '.join(s['dist_forms'])}")
     if s.get("sums_forms"):

@@ -239,6 +239,155 @@ def hashed_click_rows(cardinalities, hash_bits: int, *,
     return make_rows
 
 
+def power_law_degrees(n_owners: int, total: int, d_min: int, d_max: int,
+                      salt: int) -> np.ndarray:
+    """How many ratings each of ``n_owners`` owners has: a bounded power
+    law on ``[d_min, d_max]`` (the quantiles of a density ~ d ** -a, the
+    exponent found by bisection so that the degrees add up to ``total``,
+    the last few units given one each to the first owners), dealt to the
+    owner ids by a fixed integer mix of ``salt``. A function of its
+    arguments alone: no seed reaches it, so every seed's table has the
+    same sizes. int64 ``(n_owners,)``."""
+    if not n_owners * d_min <= total <= n_owners * d_max:
+        raise ValueError(
+            f"{total} ratings do not fit {n_owners} owners of {d_min} "
+            f"to {d_max}")
+    q = (np.arange(n_owners, dtype=np.float64) + 0.5) / n_owners
+
+    def seq(a):
+        a1 = 1.0 - a
+        lo, hi = float(d_min) ** a1, (d_max + 1.0) ** a1
+        return np.clip(np.floor((lo + q * (hi - lo)) ** (1.0 / a1)),
+                       d_min, d_max)
+
+    lo, hi = 1.0 + 1e-6, 16.0        # a larger exponent: a smaller sum
+    if seq(hi).sum() > total:
+        d = np.full(n_owners, float(d_min))
+    else:
+        for _ in range(80):
+            mid = 0.5 * (lo + hi)
+            if seq(mid).sum() > total:
+                lo = mid
+            else:
+                hi = mid
+        d = seq(hi)
+    d = d.astype(np.int64)
+    # what is left goes a unit at a time to the largest that have room
+    left = int(total - d.sum())
+    at = n_owners - 1
+    while left > 0:
+        take = min(left, int(d_max - d[at]))
+        d[at] += take
+        left -= take
+        at -= 1
+    order = np.argsort(
+        _mix32(np.arange(n_owners, dtype=np.uint32) * np.uint32(0x9E3779B1)
+               + np.uint32(salt)), kind="stable")
+    out = np.empty(n_owners, np.int64)
+    out[order] = d
+    return out
+
+
+def feistel_permutation(n: int):
+    """A seeded permutation of ``[0, n)`` that needs no table and no
+    sort: four rounds of a balanced Feistel network over the least even
+    number of bits that hold ``n``, walked until the value is under
+    ``n``. Returns ``(forward, inverse)``, each ``f(x uint32 array, key
+    uint32) -> uint32 array`` (jax); ``inverse(forward(x)) == x``."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = max(2, int(n - 1).bit_length())
+    bits += bits % 2
+    half = bits // 2
+    mask = np.uint32((1 << half) - 1)
+    u = np.uint32
+
+    def keys(key):
+        return [_mix32(key + u((0x9E3779B1 * (r + 1)) & 0xFFFFFFFF))
+                for r in range(4)]
+
+    def rnd(v, k):
+        return _mix32(v * u(0x85EBCA6B) + k) & mask
+
+    def once(x, ks, backwards):
+        left, right = x >> u(half), x & mask
+        if backwards:
+            for k in reversed(ks):
+                left, right = right ^ rnd(left, k), left
+        else:
+            for k in ks:
+                left, right = right, left ^ rnd(right, k)
+        return (left << u(half)) | right
+
+    def walk(x, key, backwards):
+        ks = keys(jnp.asarray(key, jnp.uint32))
+        y = once(jnp.asarray(x, jnp.uint32), ks, backwards)
+        return jax.lax.while_loop(
+            lambda y: jnp.any(y >= u(n)),
+            lambda y: jnp.where(y >= u(n), once(y, ks, backwards), y), y)
+
+    return (lambda x, key: walk(x, key, False),
+            lambda x, key: walk(x, key, True))
+
+
+def seeded_ratings(n_ratings: int, k: int, *, mean: float = 50.0,
+                   scale: float = 6.0, noise: float = 15.0,
+                   low: float = 0.0, high: float = 100.0):
+    """The pieces of a seeded explicit-ratings set drawn by the
+    configuration model: the stubs of the two sides (owner ``u``
+    repeated ``degree[u]`` times, owner-ordered) are paired by a seeded
+    permutation, so both degree sequences are kept exactly and a pair
+    may come twice. Counter-based throughout: rating ``p`` (the place of
+    its user stub) is a function of the seed and ``p`` alone.
+
+    Returns a namespace of jax functions: ``item_stub(p, seed)`` /
+    ``user_stub(j, seed)`` (the permutation and its inverse),
+    ``heldout_stubs(i, seed)`` (a held-out pair's two stubs),
+    ``planted(owner_ids, seed, side)`` (float32 ``(n, k)`` rows of a
+    planted rank-``k`` model, multiples of 1/8 in [-1, 7/8], so that a
+    dot of two rows is exact in float32 in any order and on the MXU),
+    ``rating(dot, p, seed, stream)``: ``mean + scale * dot`` plus a
+    triangular noise of half-width ``2 * noise``, rounded to a whole
+    number and clipped to ``[low, high]``."""
+    import jax.numpy as jnp
+
+    u = np.uint32
+    fwd, inv = feistel_permutation(n_ratings)
+
+    def key_of(seed, stream):
+        return _mix32(jnp.asarray(seed, jnp.uint32) * u(0x9E3779B1)
+                      + u((stream * 0x85EBCA6B + 1) & 0xFFFFFFFF))
+
+    def planted(owner_ids, seed, side: int):
+        ids = jnp.asarray(owner_ids, jnp.uint32)[:, None] * u(k) \
+            + jnp.arange(k, dtype=jnp.uint32)[None, :]
+        bits = _mix32(ids ^ key_of(seed, 8 + side)) >> u(28)
+        return (bits.astype(jnp.float32) - 8.0) * 0.125
+
+    def unit(p, seed, stream):
+        bits = _mix32(jnp.asarray(p, jnp.uint32) ^ key_of(seed, stream))
+        return (bits >> u(8)).astype(jnp.float32) * (2.0 ** -23) - 1.0
+
+    def rating(dot, p, seed, stream: int = 0):
+        eps = unit(p, seed, 2 + 2 * stream) + unit(p, seed, 3 + 2 * stream)
+        r = jnp.float32(mean) + jnp.float32(scale) * dot \
+            + jnp.float32(noise) * eps
+        return jnp.clip(jnp.round(r), low, high)
+
+    import types
+
+    return types.SimpleNamespace(
+        item_stub=lambda p, seed: fwd(p, key_of(seed, 0)),
+        user_stub=lambda j, seed: inv(j, key_of(seed, 0)),
+        planted=planted, rating=rating,
+        heldout_stubs=lambda i, seed: (
+            _mix32(jnp.asarray(i, jnp.uint32) ^ key_of(seed, 16))
+            % u(n_ratings),
+            _mix32(jnp.asarray(i, jnp.uint32) ^ key_of(seed, 17))
+            % u(n_ratings)))
+
+
 def gaussian_mixture(
     n_rows: int, k: int = 4, dim: int = 2, seed: int = 0, spread: float = 8.0
 ) -> np.ndarray:
