@@ -800,7 +800,8 @@ def stage_als_sparse(s: Smoke):
     the all-gather of each half's rows; against the plain reference
     (``benchmarks/reference/als_sparse_ref.py``) on the followed owners
     of the last half, to float32 rounding; every rating entered each
-    half once; the held-out RMSE falls."""
+    half once; the held-out RMSE falls. On one chip the gather runs in
+    its Mosaic form, on several in XLA's, with the same two RMSEs."""
     import numpy as np
 
     from tpu_distalg.models import als
@@ -820,6 +821,11 @@ def stage_als_sparse(s: Smoke):
         n, m_u, m_i, k, mesh, data_seed=5, n_heldout=65_536,
         geometry=geometry, **gen)
     s.check_sharded("user idx", arrays[0])
+    # the gather's form follows the mesh: one shard's table has one
+    # heavy range, which the Mosaic kernel keeps in VMEM
+    form = meta["forms"]["als_gather_form"]
+    if form != ("mosaic" if mesh.shape["data"] == 1 else "xla"):
+        raise AssertionError(f"gather form {form} on {mesh.shape}")
     cfg = als.ALSConfig(lam=1.4, m=m_u, n=m_i, k=k, n_iterations=1, seed=3)
     fn = als.make_fit_fn(mesh, cfg, meta)
     X, Theta = als.start_factors(meta, mesh, cfg.seed)
@@ -847,6 +853,8 @@ def stage_als_sparse(s: Smoke):
         raise AssertionError(f"held-out RMSE {held}")
     return (f"dp={mesh.shape['data']} | blocks a side {meta['blocks']} | "
             f"slots held / ratings {meta['padding_share']:.3f} | "
+            f"gather {form}, resident share "
+            f"{meta['gather_resident_share']:.4f} | "
             f"{len(own)} owners against the reference {err:.2g} | "
             f"held-out RMSE {held[0]:.3f} -> {held[-1]:.3f}")
 
@@ -894,7 +902,8 @@ STAGES = (
                    "pallas_hashed._hashed_rows_kernel",
                    "pallas_hashed._hashed_value_gather_kernel",
                    "pallas_hashed._hashed_value_sums_kernel"))),
-    ("als_sparse", stage_als_sparse, {}),     # XLA forms: no kernel
+    # one chip builds pallas_als._als_gather_kernel, a mesh no kernel
+    ("als_sparse", stage_als_sparse, {}),
     ("ssgd_comm_int8", functools.partial(_comm_stage, comm="int8"),
      dict(min_devices=2)),
     ("ssgd_comm_bucketed",

@@ -1,0 +1,185 @@
+"""The two forms of sparse ALS' row gather (``ops/als_sparse.gather_rows``,
+``ops/pallas_als.py``): the Mosaic kernel, interpreted, against
+``other[idx]`` bit for bit on blocks of every kind; a fit whose halves
+gather in either form; the choice of form from what the code can
+observe; the resident share against a count over packed indices."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_distalg.models import als
+from tpu_distalg.ops import als_sparse as ops
+from tpu_distalg.ops import pallas_als
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# a block of 64 segments x 32 slots = (16, 128): one chunk
+GEOM = dict(seg_slots=32, piece_segs=8, batch=64, classes=(1, 2, 4))
+K, LAM = 5, 1.4
+ROWS, HOT0 = 1000, 808          # a table of 1000 rows and 8 zero rows
+
+
+def _block(case: str, rng) -> np.ndarray:
+    n = 96 * 128                # two chunks of 48 rows of 128 slots
+    if case == "all_hot":
+        idx = rng.integers(HOT0, ROWS, n)
+    elif case == "all_cold":
+        idx = rng.integers(0, HOT0, n)
+    elif case == "all_padding":
+        idx = np.full(n, ROWS)
+    elif case == "mixed":       # the cell's shares: 57% heavy, 17%
+        u = rng.random(n)       # padding, 26% cold
+        idx = np.where(u < 0.568, rng.integers(HOT0, ROWS, n),
+                       np.where(u < 0.736, ROWS,
+                                rng.integers(0, HOT0, n)))
+    elif case == "edges":
+        idx = rng.integers(0, ROWS + 1, n)
+        idx[:4] = [HOT0 - 1, HOT0, ROWS - 1, ROWS]
+        idx[-4:] = [ROWS, ROWS - 1, HOT0, HOT0 - 1]
+    else:                       # one cold slot, and it is the last
+        idx = np.full(n, ROWS - 1)
+        idx[-1] = 3
+    return idx.astype(np.int32).reshape(96, 128)
+
+
+@pytest.mark.parametrize("case", ["all_hot", "all_cold", "all_padding",
+                                  "mixed", "edges", "last_cold"])
+def test_mosaic_gather_returns_the_rows_bitwise(case):
+    rng = np.random.default_rng(11)
+    T = rng.standard_normal((ROWS + 8, 128)).astype(np.float32)
+    T[ROWS:] = 0.0
+    idx = _block(case, rng)
+    assert pallas_als.chunk_rows(96) == 48 and pallas_als.chunk_rows(4) == 0
+    plan = ops.GatherPlan("mosaic", HOT0, ROWS + 8 - HOT0, interpret=True)
+    got = jax.jit(lambda T, i: ops.gather_rows(T, i, plan))(
+        jnp.asarray(T), jnp.asarray(idx))
+    assert got.shape == (96 * 128, 128)
+    assert np.array_equal(np.asarray(got), T[idx.reshape(-1)])
+    # and XLA's form, which is what no plan means
+    assert np.array_equal(np.asarray(ops.gather_rows(
+        jnp.asarray(T), jnp.asarray(idx))), T[idx.reshape(-1)])
+
+
+def _toy(mesh, seed=4):
+    rng = np.random.default_rng(5)
+    du = np.concatenate([[700, 300], rng.integers(1, 120, 90)])
+    di = np.full(60, du.sum() // 60)
+    di[:du.sum() - di.sum()] += 1
+    arrays, meta = als.build_ratings_table(
+        int(du.sum()), len(du), len(di), K, mesh, data_seed=seed,
+        n_heldout=64, degrees=(du, di), geometry=GEOM)
+    return du, di, arrays, meta
+
+
+def test_a_fit_is_the_same_in_both_forms_bit_for_bit(mesh1):
+    du, di, arrays, meta = _toy(mesh1)
+    geom = meta["geometry"]
+    assert geom.block_shape == (16, 128) and geom.width == 128
+    assert meta["forms"]["als_gather_form"] == "xla"      # on the CPU
+    cfg = als.ALSConfig(lam=LAM, m=len(du), n=len(di), k=K,
+                        n_iterations=2, seed=3)
+    mosaic = tuple(
+        dataclasses.replace(ops.gather_plan(o.static, geom, True),
+                            interpret=True)
+        for o in (meta["item"], meta["user"]))
+    assert [g.form for g in mosaic] == ["mosaic", "mosaic"]
+    # the whole of a toy table fits the budget: cut the range at the
+    # heavy class, so that both kinds of slot are there
+    mosaic = tuple(
+        dataclasses.replace(g, hot_row0=o.static.heavy[2],
+                            resident_rows=o.static.table_rows
+                            - o.static.heavy[2])
+        for g, o in zip(mosaic, (meta["item"], meta["user"])))
+    assert all(0 < g.hot_row0 for g in mosaic)
+    out = []
+    for gather in (meta["gather"], mosaic):
+        fn = als.make_fit_fn(mesh1, cfg, dict(meta, gather=gather))
+        X, Theta = als.start_factors(meta, mesh1, cfg.seed)
+        out.append([np.asarray(a) for a in fn(*arrays, X, Theta)])
+    for a, b in zip(*out):       # X, Theta, (training, held-out RMSE),
+        assert np.array_equal(a, b)              # ratings seen
+    assert out[0][3].tolist() == [[int(du.sum())] * 2] * 2
+
+
+def _static(n_shards=1, heavy_rows=64, light_rows=128):
+    rows = light_rows + heavy_rows
+    return ops.SideStatic(n_shards, rows, 4, ((1, 0, 2, 0),),
+                          (2, 2, light_rows, heavy_rows))
+
+
+def test_the_form_follows_what_the_code_can_observe():
+    geom = ops.SparseGeometry(k=K, **GEOM)
+    st = _static()
+    got = ops.gather_plan(st, geom, True)
+    assert (got.form, got.hot_row0, got.resident_rows) == (
+        "mosaic", 0, st.table_rows)      # all of it under the budget
+    xla = ops.GatherPlan("xla", st.table_rows, 0)
+    assert ops.gather_plan(st, geom, False) == xla          # not a TPU
+    two = _static(n_shards=2)
+    assert ops.gather_plan(two, geom, True) == ops.GatherPlan(
+        "xla", two.table_rows, 0)                           # a mesh
+    wide = ops.SparseGeometry(k=127, **GEOM)
+    assert wide.width == 256
+    assert ops.gather_plan(st, wide, True) == xla           # two vectors
+    thin = ops.SparseGeometry(k=K, seg_slots=8, piece_segs=4, batch=8,
+                              classes=(1, 2))
+    assert ops.gather_plan(st, thin, True) == xla    # a block of 64 slots
+    big = _static(heavy_rows=ops.GATHER_VMEM_BYTES // 512)
+    assert ops.resident_row0(big, geom) is None
+    assert ops.gather_plan(big, geom, True) == ops.GatherPlan(
+        "xla", big.table_rows, 0)                    # past the budget
+    # a class at a time, largest first, while the budget holds
+    assert ops.resident_row0(st, geom, (64 + 8) * 512) == 128
+    assert ops.resident_row0(st, geom, (64 + 8) * 512 - 1) is None
+
+
+@pytest.fixture(scope="module")
+def cell_meta():
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "als-yahoomusic-f100.json")) as f:
+        c = json.load(f)
+    return als.plan_ratings(
+        c["n_ratings"], c["n_users"], c["n_items"], c["k"], 1,
+        n_heldout=c["n_heldout"], geometry=dict(c["geometry"]),
+        on_tpu=True, **c["generator"])
+
+
+def test_the_cells_statics_take_the_kernel(cell_meta):
+    meta = cell_meta
+    assert meta["forms"]["als_gather_form"] == "mosaic"
+    user_half, item_half = meta["gather"]
+    # the heavy class's 18 432 rows and the 8 zero rows, 9.4 MB a side
+    assert (user_half.hot_row0, user_half.resident_rows) == (645120, 18440)
+    assert (item_half.hot_row0, item_half.resident_rows) == (1013760, 18440)
+    assert meta["gather_resident_rows"] == (18440, 18440)
+    np.testing.assert_allclose(meta["gather_resident_shares"],
+                               (0.79089, 0.67984), atol=1e-5)
+    assert abs(meta["gather_resident_share"] - 0.73643) < 1e-5
+    fields = als.segment_fields(meta)
+    assert (fields["als_gather_form"], fields["gather_resident_rows"],
+            fields["gather_resident_share"]) == (
+        "mosaic", [18440, 18440], 0.7364)
+    # the same sizes where the fit will not run on a TPU: XLA's form,
+    # nothing resident
+    off = als._ratings_meta(meta["geometry"], (meta["user"], meta["item"]),
+                            meta["n_ratings"], 0, 1, False)
+    assert off["forms"]["als_gather_form"] == "xla"
+    assert off["gather_resident_rows"] == (0, 0)
+    assert off["gather_resident_share"] == 0.0
+
+
+def test_resident_share_is_a_count_over_the_packed_indices(mesh1):
+    _, _, arrays, meta = _toy(mesh1, seed=8)
+    pu, pi = meta["user"], meta["item"]
+    for idx, own, other in ((arrays[0], pu, pi), (arrays[3], pi, pu)):
+        idx = np.asarray(idx)
+        assert idx.size == own.slots_held
+        for row0 in (other.static.heavy[2], other.static.light[1][3], 0,
+                     other.static.zero_row):
+            assert ops.resident_slots(own, other, row0) == int(
+                np.count_nonzero(idx >= row0)), row0

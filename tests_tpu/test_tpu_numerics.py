@@ -1082,3 +1082,64 @@ def test_sparse_als_half_sweep_at_rank_100(tpu_mesh):
     err = np.abs(np.asarray(rows)[:, :k] - want).max() / np.abs(want).max()
     print(f"[als rank 100] solve along the lanes: max err {err:.3g}")
     assert bool(np.asarray(has).all()) and err < 1e-3
+
+
+@pytest.mark.parametrize("table_rows,hot_row0", [
+    (663_560, 645_120),        # the items' table, what the user half reads
+    (1_032_200, 1_013_760)])   # the users'
+def test_sparse_als_resident_gather_is_xlas_bitwise(table_rows, hot_row0):
+    """The Mosaic gather (``ops/pallas_als.py``) against XLA's
+    ``other[idx]``, bit for bit, on one block at the benchmark's block
+    shape (6144 segments x 32 slots = 196 608 rows of 128 lanes) and both
+    tables' row counts with the heavy class and the zero rows resident:
+    the cell's mix of heavy, padding and cold slots, then the rows at the
+    range's two ends."""
+    from tpu_distalg.ops import als_sparse as ops
+
+    zero_row = table_rows - 8
+    rng = np.random.default_rng(7)
+    T = jnp.asarray(rng.standard_normal((table_rows, 128), np.float32)
+                    ).at[zero_row:].set(0.0)
+    n = 1536 * 128
+    u = rng.random(n)
+    idx = np.where(u < 0.568, rng.integers(hot_row0, zero_row, n),
+                   np.where(u < 0.736, zero_row,
+                            rng.integers(0, hot_row0, n)))
+    idx[:4] = [hot_row0 - 1, hot_row0, zero_row - 1, zero_row]
+    idx[-4:] = [zero_row, 0, table_rows - 1, hot_row0 - 1]
+    idx = jnp.asarray(idx.astype(np.int32).reshape(1536, 128))
+    plan = ops.GatherPlan("mosaic", hot_row0, table_rows - hot_row0)
+    got = jax.jit(lambda T, i: ops.gather_rows(T, i, plan))(T, idx)
+    want = jax.jit(lambda T, i: ops.gather_rows(T, i))(T, idx)
+    assert got.shape == (n, 128)
+    assert bool(jnp.array_equal(got, want))
+    assert float(jnp.abs(got).sum()) > 0
+
+
+def test_sparse_als_resident_gather_over_many_blocks():
+    """No row of 256 blocks' 50M slots differs from XLA's: the cold
+    rows' copies land over rows that pass 1 has stored, chunk after
+    chunk and call after call, and a race between the two would show
+    as a stale row here and as a digit in a fit."""
+    from tpu_distalg.ops import als_sparse as ops
+
+    table_rows, hot_row0 = 663_560, 645_120
+    zero_row = table_rows - 8
+    T = jax.random.normal(jax.random.PRNGKey(1), (table_rows, 128),
+                          jnp.float32).at[zero_row:].set(0.0)
+    plan = ops.GatherPlan("mosaic", hot_row0, table_rows - hot_row0)
+
+    def one(bad, key):
+        ku, kh, kc = jax.random.split(key, 3)
+        u = jax.random.uniform(ku, (1536, 128))
+        idx = jnp.where(
+            u < 0.568, jax.random.randint(kh, u.shape, hot_row0, zero_row),
+            jnp.where(u < 0.736, zero_row,
+                      jax.random.randint(kc, u.shape, 0, hot_row0)))
+        differ = jnp.any(ops.gather_rows(T, idx, plan)
+                         != ops.gather_rows(T, idx), axis=1)
+        return bad + jnp.sum(differ.astype(jnp.int32)), None
+
+    bad, _ = jax.jit(lambda keys: jax.lax.scan(one, jnp.int32(0), keys))(
+        jax.random.split(jax.random.PRNGKey(2), 256))
+    assert int(bad) == 0

@@ -54,6 +54,7 @@ from tpu_distalg.ops import linalg
 from tpu_distalg.parallel import (
     DATA_AXIS,
     data_parallel,
+    mesh_on_tpu,
     pad_rows,
     tree_allreduce_sum,
 )
@@ -265,8 +266,16 @@ def _sparse_geometry(k: int, n_users: int, n_items: int,
 
 
 def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
-                  n_shards: int, **extra) -> dict:
+                  n_shards: int, on_tpu: bool = False, **extra) -> dict:
+    from tpu_distalg.ops import als_sparse
+
     pu, pi = plans
+    # a half gathers from the OTHER side's table: the user half first
+    gathers = tuple(als_sparse.gather_plan(o.static, geom, on_tpu)
+                    for o in (pi, pu))
+    resident = tuple(
+        als_sparse.resident_slots(p, o, g.hot_row0) if g.resident_rows
+        else 0 for p, o, g in zip(plans, (pi, pu), gathers))
     held = (pu.slots_held + pi.slots_held) * 8
     tables = (pu.static.table_rows + pi.static.table_rows) \
         * geom.width * 4
@@ -278,8 +287,15 @@ def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
         blocks=(pu.static.n_blocks, pi.static.n_blocks),
         padding_share=(pu.slots_held + pi.slots_held)
         / max(2 * n_ratings, 1),
-        forms=dict(als_gather_form="xla", als_gram_form="xla",
-                   als_solve_form="xla"),
+        gather_resident_rows=tuple(g.resident_rows for g in gathers),
+        gather_resident_shares=tuple(
+            r / max(p.slots_held, 1) for r, p in zip(resident, plans)),
+        gather_resident_share=sum(resident)
+        / max(pu.slots_held + pi.slots_held, 1),
+        gather=gathers,
+        forms=dict(als_gather_form="/".join(
+            dict.fromkeys(g.form for g in gathers)),
+            als_gram_form="xla", als_solve_form="xla"),
         **extra)
 
 
@@ -289,12 +305,25 @@ def _prepare_fields(meta: dict) -> dict:
                 bytes=meta["ratings_bytes"] + meta["factor_bytes"],
                 user_blocks=meta["blocks"][0],
                 item_blocks=meta["blocks"][1],
-                padding_share=round(meta["padding_share"], 4))
+                padding_share=round(meta["padding_share"], 4),
+                **_gather_fields(meta))
+
+
+def _gather_fields(meta: dict) -> dict:
+    """The gather's form and how often its resident range engages: the
+    range's rows in the items' and the users' table (what the user and
+    the item half read) and the share of the slots held that point into
+    it."""
+    return dict(
+        als_gather_form=meta["forms"]["als_gather_form"],
+        gather_resident_rows=list(meta["gather_resident_rows"]),
+        gather_resident_share=round(meta["gather_resident_share"], 4))
 
 
 def segment_fields(meta: dict) -> dict:
     """What a ``train:segment`` span says of the sparse trainer."""
-    return dict(layout=meta["layout"], **meta["forms"])
+    return {"layout": meta["layout"], **meta["forms"],
+            **_gather_fields(meta)}
 
 
 def ratings_from_coo(users, items, ratings, n_users: int, n_items: int,
@@ -327,7 +356,8 @@ def ratings_from_coo(users, items, ratings, n_users: int, n_items: int,
         n_heldout = len(heldout[0])
     hu = pu.row_of_owner[np.asarray(heldout[0], np.int64)]
     hv = pi.row_of_owner[np.asarray(heldout[1], np.int64)]
-    meta = _ratings_meta(geom, (pu, pi), users.shape[0], n_heldout, S)
+    meta = _ratings_meta(geom, (pu, pi), users.shape[0], n_heldout, S,
+                         mesh_on_tpu(mesh))
     arrays = tuple(_put(a, "ratings", mesh) for a in
                    (ui, uv, pu.piece_slot, ii, iv, pi.piece_slot)) \
         + tuple(_put(a, "heldout", mesh) for a in
@@ -427,12 +457,15 @@ def side_generator(mesh: Mesh, geom, gen, side: int, zero_row: int):
 
 def plan_ratings(n_ratings: int, n_users: int, n_items: int, k: int,
                  n_shards: int = 1, *, n_heldout: int = 0, degrees=None,
-                 geometry: dict | None = None, **gen_kw) -> dict:
+                 geometry: dict | None = None, on_tpu: bool = False,
+                 **gen_kw) -> dict:
     """The host's half of the seeded loader: both degree sequences
     (functions of the sizes alone, ``datasets.power_law_degrees``; or
     ``degrees=(users', items')``), both sides' pack, and the ``meta``
     that states the layout. No device is touched and no seed is read:
-    every seed's table has these sizes."""
+    every seed's table has these sizes. ``on_tpu`` says where the fit
+    will run (the loaders pass their mesh's answer): the gather takes
+    its Mosaic form only there (``als_sparse.gather_plan``)."""
     from tpu_distalg.ops import als_sparse
     from tpu_distalg.utils import datasets as dsets
 
@@ -456,7 +489,7 @@ def plan_ratings(n_ratings: int, n_users: int, n_items: int, k: int,
     plans = (als_sparse.plan_side(du, geom, n_shards),
              als_sparse.plan_side(di, geom, n_shards))
     return _ratings_meta(geom, plans, n_ratings, n_heldout, n_shards,
-                         generator=tuple(sorted(par.items())))
+                         on_tpu, generator=tuple(sorted(par.items())))
 
 
 def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
@@ -482,7 +515,7 @@ def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
                       items=n_items, k=k, layout=RATINGS_LAYOUT):
         with tevents.span("als:pack", ratings=n_ratings):
             meta = plan_ratings(n_ratings, n_users, n_items, k, S,
-                                **plan_kw)
+                                on_tpu=mesh_on_tpu(mesh), **plan_kw)
             meta["data_seed"] = int(data_seed)
             geom, plans = meta["geometry"], (meta["user"], meta["item"])
             stubs = [tuple(_put(a, "ratings", mesh) for a in (
@@ -606,18 +639,20 @@ def _make_fit_fn_sparse(mesh: Mesh, config: ALSConfig, meta: dict):
     su, si = meta["user"].static, meta["item"].static
     n_ratings = max(meta["n_ratings"], 1)
 
-    def half(static, other_zero_row):
+    def half(static, other_zero_row, gather):
         def run(idx, val, pieces, other, own):
             return als_sparse.half_sweep(
                 idx, val, pieces, other, own, static=static,
                 other_zero_row=other_zero_row, geom=geom,
-                lam=config.lam, axis=DATA_AXIS)
+                lam=config.lam, axis=DATA_AXIS, gather=gather)
 
         return data_parallel(
             run, mesh, in_specs=(*(P(DATA_AXIS),) * 3, P(), P()),
             out_specs=(P(), P(), P()))
 
-    user_half, item_half = half(su, si.zero_row), half(si, su.zero_row)
+    gather_u, gather_i = meta["gather"]
+    user_half = half(su, si.zero_row, gather_u)
+    item_half = half(si, su.zero_row, gather_i)
 
     def fit(ui, uv, up, ii, iv, ip, hu, hv, hr, X, Theta):
         def iteration(carry, _):

@@ -10,7 +10,8 @@ owner ``u`` (a user in the user half, an item in the item half) has
 
 so a half-sweep is a gather of ``theta`` rows by index, one small
 Gramian an owner, and one small solve an owner. This file holds the
-three pieces in XLA forms and the pack that gives them static shapes.
+three pieces (in XLA forms; the gather also as a Mosaic kernel) and the
+pack that gives them static shapes.
 
 **The pack** (:func:`plan_side`, host, from the degrees alone). An
 owner's ratings are cut into *segments* of ``seg_slots`` (32) slots.
@@ -38,6 +39,38 @@ slot points at. Lane ``k`` of a gathered row takes the rating and lane
 r^2`` and ``n_u`` come out of the same MXU pass as ``A_u`` (rows ``k``
 and ``k + 1`` of the product) and the training error needs no second
 gather.
+
+**The gather** (:func:`gather_rows`) has two forms that return the same
+rows bit for bit, and :func:`gather_plan` picks one from what the code
+can observe, with no flag:
+
+``mosaic``  ``ops/pallas_als.py``: the table stays in HBM, the *resident
+            range* of it is copied into VMEM once a call, a slot that
+            points there is a dynamic-row vector load and a slot that
+            does not a row DMA. On a TPU, where a factor row is one
+            vector of 128 lanes, a block's slots are whole vectors, the
+            table is one shard's (on a mesh it is shard-major and the
+            heavy rows are ``n_shards`` ranges) and the range fits.
+``xla``     ``other.at[idx].get(...)``: a DMA a 512 B row, 9 to 13.6 ns
+            whatever the block; everywhere else.
+
+The resident range (:func:`resident_row0`) is the table's tail from a
+class boundary of the side that is read to ``table_rows``: the heavy
+class, the owners without a rating and the zero rows at least, then the
+classes before the heavy one, largest first, while the range stays under
+``GATHER_VMEM_BYTES``. Rows are in class order, so the owners that most
+slots point at are that tail, "hot" is one compare, and padding is hot by
+construction. At the published shape the heavy class is 18 432 rows,
+9.4 MB a side, and holds 74.4% (items) and 62.3% (users) of the stubs;
+with the padding 73.6% of all slots held are resident
+(:func:`resident_slots` counts them from the degrees alone). The budget
+is 12 MiB because a resident row costs every call 2.9 ns to copy in and
+a cold slot 4 ns more than a hot one (one v5e, PR 37's Step 0,
+``scripts/step0_als_gather.py``): a block of 196 608 slots has to read
+a row about once for its place to pay, which the heavy class's rows do
+(six times) and the next classes' do not (a row of the four classes
+before it is read by a block in three; with them resident, 31.5 / 40.9
+MB, a block reads 7 and 4% slower than with the heavy class alone).
 
 **The solve** (:func:`solve_batch`) is a Cholesky factorisation with the
 batch along the lanes: ``(n, n, batch)`` in panels of 8 columns, every
@@ -67,6 +100,10 @@ CLASSES = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48)
 BATCH_UNIT = 192            # the least batch those sizes and 64 divide
 PANEL = 8                   # columns a Cholesky panel (16 compile for
 #                             five minutes unrolled: PERF.md section 6)
+# what the gather's resident range may take of VMEM: the heavy class of
+# the published shape and not the class before it (the module docstring
+# says where the number comes from)
+GATHER_VMEM_BYTES = 12 << 20
 
 
 def _round_up(x: int, m: int) -> int:
@@ -163,6 +200,59 @@ class SidePlan:
     #                            ``heavy rows`` (a dump row) where none
     slots_held: int
     padding_share: float       # slots held / ratings
+
+
+@dataclasses.dataclass(frozen=True)
+class GatherPlan:
+    """How one half gathers the other side's rows."""
+
+    form: str               # 'mosaic' or 'xla'
+    hot_row0: int           # first resident row; the table's rows where
+    #                         none is resident
+    resident_rows: int
+    interpret: bool = False  # the kernel interpreted (tests, on the CPU)
+
+
+def resident_row0(other: SideStatic, geom: SparseGeometry,
+                  budget: int = GATHER_VMEM_BYTES) -> int | None:
+    """First row of the resident range of one shard's table: the tail
+    from a class boundary of ``other`` to ``table_rows`` (the heavy
+    class, the owners without a rating and the zero rows at least, and
+    as many of the classes before the heavy one, largest first, as
+    ``budget`` bytes hold), or None where not even that fits."""
+    starts = [row0 for _, _, n_super, row0 in other.light if n_super]
+    fits = [r for r in (*starts, other.heavy[2])
+            if r <= other.heavy[2]
+            and (other.table_rows - r) * geom.width * 4 <= budget]
+    return min(fits, default=None)
+
+
+def gather_plan(other: SideStatic, geom: SparseGeometry,
+                on_tpu: bool) -> GatherPlan:
+    """The form of a half's gather from what the code can observe: the
+    Mosaic kernel on a TPU where a factor row is one vector of 128
+    lanes, a block's slots are whole vectors in whole chunks, the table
+    is one shard's (on a mesh it is shard-major: ``n_shards`` heavy
+    ranges, not one) and the resident range fits; XLA's gather
+    elsewhere."""
+    from tpu_distalg.ops import pallas_als
+
+    row0 = resident_row0(other, geom)
+    rows, lanes = geom.block_shape
+    if (on_tpu and geom.width == LANES and lanes == LANES
+            and pallas_als.chunk_rows(rows) and other.n_shards == 1
+            and row0 is not None):
+        return GatherPlan("mosaic", row0, other.table_rows - row0)
+    return GatherPlan("xla", other.table_rows, 0)
+
+
+def resident_slots(plan: SidePlan, other: SidePlan, hot_row0: int) -> int:
+    """Slots of ``plan``'s pack that point at the other side's rows from
+    ``hot_row0`` on, from the degrees alone: every rating of an owner of
+    the other side that lives there, and every padding slot (the zero
+    row is behind every owner's)."""
+    hot = other.degrees[other.row_of_owner >= hot_row0].sum()
+    return int(hot + plan.slots_held - plan.degrees.sum())
 
 
 def plan_side(degrees, geom: SparseGeometry, n_shards: int = 1) -> SidePlan:
@@ -288,8 +378,19 @@ def pack_coo(plan: SidePlan, geom: SparseGeometry, owners, others,
 # ---------------------------------------------------------------- device
 
 
+def gather_rows(other, idx_b, gather: GatherPlan | None = None):
+    """``other[idx_b.reshape(-1)]`` in the plan's form (XLA's where
+    none is given): the same rows, bit for bit."""
+    if gather is not None and gather.form == "mosaic":
+        from tpu_distalg.ops import pallas_als
+
+        return pallas_als.gather_rows_resident(
+            other, idx_b, gather.hot_row0, interpret=gather.interpret)
+    return other.at[idx_b.reshape(-1)].get(mode="promise_in_bounds")
+
+
 def block_gramians(other, idx_b, val_b, K: int, geom: SparseGeometry,
-                   zero_row: int):
+                   zero_row: int, gather: GatherPlan | None = None):
     """One block's ``batch / K`` extended Gramians with the owners
     along the lanes, ``(width, width, batch / K)``: the gather of the
     other side's rows, the rating and the validity written into lanes
@@ -303,7 +404,7 @@ def block_gramians(other, idx_b, val_b, K: int, geom: SparseGeometry,
     k, W = geom.k, geom.width
     flat = idx_b.reshape(-1)
     with jax.named_scope(names.ALS_GATHER):
-        G = other.at[flat].get(mode="promise_in_bounds")
+        G = gather_rows(other, idx_b, gather)
     with jax.named_scope(names.ALS_GRAM):
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
         r = val_b.reshape(-1, 1)
@@ -473,7 +574,7 @@ def solve_batch(Ap, lam: float, geom: SparseGeometry):
 
 def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
                other_zero_row: int, geom: SparseGeometry, lam: float,
-               axis: str):
+               axis: str, gather: GatherPlan | None = None):
     """One shard's half of an iteration: every owner of this shard from
     the other side's table ``other`` (whole, constant through the half),
     written into the shard's rows of ``own``; the shards' rows gathered
@@ -496,7 +597,7 @@ def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
         return block_gramians(
             other, lax.dynamic_index_in_dim(idx, block, keepdims=False),
             lax.dynamic_index_in_dim(val, block, keepdims=False), K,
-            geom, other_zero_row)
+            geom, other_zero_row, gather)
 
     def solve_into(carry, Ap, row):
         local, sse, seen = carry

@@ -228,8 +228,13 @@ def summarize(evts: list[dict]) -> dict:
             # list says so) with the form of each piece of a half-sweep
             # (models/als.segment_fields: xla / mosaic)
             if "als_gram_form" in e:
-                form = (f"{e.get('layout', '?')} (gather: "
-                        f"{e.get('als_gather_form', '?')}, gramians: "
+                gather = e.get("als_gather_form", "?")
+                if e.get("gather_resident_share"):
+                    # how often the resident range engages
+                    gather += (f" with {e['gather_resident_share']} of "
+                               f"the slots resident")
+                form = (f"{e.get('layout', '?')} (gather: {gather}, "
+                        f"gramians: "
                         f"{e.get('als_gram_form', '?')}, solve: "
                         f"{e.get('als_solve_form', '?')})")
                 if form not in als_forms:
