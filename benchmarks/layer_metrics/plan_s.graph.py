@@ -1,0 +1,16 @@
+"""Seconds of the program's own ``pagerank:plan`` span (the sort into
+the kernel's order and the layout of the seven plan arrays, compiles
+or cache loads included), from the ring of finished spans the program
+keeps (``telemetry/events.finished``). Nothing where the program has no
+such span or keeps no ring."""
+
+
+def read(ctx):
+    try:
+        from tpu_distalg.telemetry import events
+
+        done = events.finished()
+    except (ImportError, AttributeError):
+        return None
+    got = [s.seconds for s in done if s.name == "pagerank:plan"]
+    return sum(got) if got else None

@@ -1,0 +1,65 @@
+"""The fused SpMV kernel compiled for a described v5e at the benchmark
+cell's real shapes, with no chip attached: what interpret mode cannot
+show (the 64 MB output table in VMEM, a kernel call's scalars in SMEM's
+1 MB, the tiling of the windows). Nothing runs: a compile that passes
+is not a chip run. The topology is described inside a fixture, in this
+one file (only one process may hold the TPU's library)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tpu_distalg.ops import pallas_pagerank as ppr
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(geom, one_chip, seg_steps=None):
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    per_slot = (geom.n_chunks * 8, 128)
+    return jax.jit(lambda *a: ppr.spmv_table(
+        *a, rg=geom.rg, ws=geom.ws, r8=geom.r8, blk=geom.blk,
+        seg_steps=seg_steps or geom.seg_steps)).lower(
+            arr((geom.n_chunks,), jnp.int32),
+            arr((geom.n_chunks,), jnp.int32),
+            arr((geom.n_groups * geom.rg, 128), jnp.float32),
+            *[arr(per_slot, jnp.int32)] * 4,
+            arr(per_slot, jnp.float32)).compile()
+
+
+def test_kernel_compiles_at_graph500_scale_24(one_chip):
+    geom = ppr.spmv_geometry(1 << 24, 16 << 24)
+    mem = _compile(geom, one_chip).memory_analysis()
+    assert mem.output_size_in_bytes == (geom.r8 + geom.ws) * 512
+    assert 5.4e9 < mem.argument_size_in_bytes < 5.6e9
+
+
+def test_a_whole_sweeps_scalars_do_not_fit_smem(one_chip):
+    """Why a sweep is several kernel calls: one call's worth of every
+    chunk's base is past SMEM at this size."""
+    geom = ppr.spmv_geometry(1 << 24, 16 << 24)
+    with pytest.raises(Exception, match="smem|SMEM"):
+        _compile(geom, one_chip, seg_steps=geom.n_steps)
+
+
+def test_kernel_compiles_at_the_vmem_budgets_edge(one_chip):
+    """The tallest table the geometry admits (just under
+    ``SPMV_VMEM_BUDGET``) is one the chip's compiler takes."""
+    v = (ppr.SPMV_VMEM_BUDGET // 512 - 4096) * 128
+    geom = ppr.spmv_geometry(v, 8 * v)
+    assert geom is not None
+    assert ppr.spmv_geometry(v + (1 << 20), 8 * v) is None
+    _compile(geom, one_chip)

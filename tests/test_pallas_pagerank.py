@@ -87,20 +87,52 @@ def test_spmv_plan_and_kernel_match_numpy():
     w_e = rng.random(e).astype(np.float32)
     ranks = rng.random(v).astype(np.float32)
     plan = ppr.plan_spmv(src, dst, w_e, v)
-    assert plan is not None
-    rt = np.zeros((plan.r8 + plan.rg, 128), np.float32)
-    rt[: (v + 127) // 128].reshape(-1)[:v] = ranks
-    out = ppr.spmv_table(
-        jnp.asarray(plan.gbase), jnp.asarray(plan.sbase),
-        jnp.asarray(rt), jnp.asarray(plan.src_lane),
-        jnp.asarray(plan.src_row), jnp.asarray(plan.dst_row),
-        jnp.asarray(plan.dst_lane), jnp.asarray(plan.w_e),
-        rg=plan.rg, ws=plan.ws, r8=plan.r8, blk=plan.blk,
-        interpret=True)
+    assert plan is not None and plan.geom.n_groups > 1
+    got = _spmv(plan, ranks, v)
     want = np.zeros(v, np.float64)
     np.add.at(want, dst, ranks[src].astype(np.float64) * w_e)
-    got = np.asarray(out)[:plan.r8].reshape(-1)[:v]
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+
+
+def _spmv(plan, ranks, v, seg_steps=None):
+    """One interpreted sweep of a host plan over a ranks vector."""
+    g = plan.geom
+    rt = np.zeros((g.n_groups * g.rg, 128), np.float32)
+    rt.reshape(-1)[:v] = ranks
+    out = ppr.spmv_table(
+        *(jnp.asarray(a) for a in (
+            plan.gbase, plan.sbase, rt, plan.src_lane, plan.src_row,
+            plan.dst_row, plan.dst_lane, plan.w_e)),
+        rg=g.rg, ws=g.ws, r8=g.r8, blk=g.blk,
+        seg_steps=seg_steps or g.seg_steps, interpret=True)
+    return np.asarray(out)[:g.r8].reshape(-1)[:v]
+
+
+def test_windowed_ranks_equal_the_resident_form_bit_for_bit():
+    """The ranks table read a source group's window at a time (a group
+    height small enough to force several at test size) returns what the
+    one-group form, the whole table one window, returns, bit for bit:
+    small whole numbers, so that no order of the sums rounds. Several
+    kernel calls a sweep (the scalars' segments) change nothing."""
+    v, e = 20000, 120000
+    rng = np.random.default_rng(8)
+    src, dst = rng.integers(0, v, size=e), rng.integers(0, v, size=e)
+    ranks = rng.integers(1, 8, size=v).astype(np.float32)
+    w_e = np.ones(e, np.float32)
+    resident = ppr.plan_spmv(src, dst, w_e, v, rg=1024)
+    windowed = ppr.plan_spmv(src, dst, w_e, v, rg=32)
+    assert resident.geom.ranks_form == "resident"
+    assert windowed.geom.ranks_form == "windowed"
+    assert windowed.geom.n_groups >= 4
+    want = np.zeros(v, np.float64)
+    np.add.at(want, dst, ranks[src])
+    got = _spmv(resident, ranks, v)
+    np.testing.assert_array_equal(got, want.astype(np.float32))
+    np.testing.assert_array_equal(_spmv(windowed, ranks, v), got)
+    steps = windowed.geom.n_steps
+    seg = next(d for d in range(2, steps) if steps % d == 0)
+    np.testing.assert_array_equal(
+        _spmv(windowed, ranks, v, seg_steps=seg), got)
 
 
 def test_standard_mode_spmv_matches_xla(mesh8):
@@ -149,18 +181,19 @@ def test_run_auto_prefers_spmv_and_matches_xla(mesh8):
                                rtol=1e-5, atol=1e-8)
 
 
-def test_spmv_rg_escalation_plans_sparse_graph(mesh8):
+def test_spmv_sparse_graph_takes_a_taller_gather_window(mesh8):
     """A graph whose within-group dst span overflows at rg=128 (the
-    span grows as R²/(rg·E)) escalates to a taller gather window
-    instead of giving up — the 10M-vertex regime in miniature. Plan
-    invariants are checked; the rg=512 kernel's numerics are verified
-    on hardware (tests_tpu / the recorded 10M run)."""
+    span grows as R²/(rg·E)) is given a taller gather window from its
+    sizes alone, with no attempt at 128 — the 10M-vertex regime in
+    miniature. A span past the window fixed for a forced rg=128 is
+    reported (``None``, counted), not hidden. Plan invariants are
+    checked; the tall kernel's numerics are verified on hardware."""
     v, e = 1_000_000, 1_000_000
     edges = _random_graph(v, e, seed=7)
     el = gops.prepare_edges(edges, v)
     # rg=128 must fail on this sparsity...
     assert pagerank.prepare_device_spmv(el, mesh8, rg=128) is None
-    # ...and the escalating default must land a valid taller plan
+    # ...and the geometry the sizes give must land a valid taller plan
     spmv = pagerank.prepare_device_spmv(el, mesh8)
     assert spmv is not None
     assert spmv.rg > 128

@@ -446,18 +446,20 @@ def test_report_last_wins_fields_come_from_newest_run_by_mtime(tmp_path):
 def test_plan_spmv_rejects_vmem_overflow_before_sorting():
     from tpu_distalg.ops import pallas_pagerank as ppr
 
-    # 20M vertices: the two vertex tables alone are ~160 MB > budget;
-    # must return None FAST (before the host sorts), not at compile
+    # 40M vertices: the output table alone is 160 MB > budget (the
+    # ranks table stays in HBM since PR 38, so 20M fit); must return
+    # None FAST (before any sort), not at compile
     src = np.array([0, 1, 2, 3], dtype=np.int64)
     dst = np.array([1, 2, 3, 0], dtype=np.int64)
     w_e = np.full(4, 0.25, np.float32)
     t0 = time.monotonic()
-    assert ppr.plan_spmv(src, dst, w_e, n_vertices=20_000_000) is None
+    assert ppr.plan_spmv(src, dst, w_e, n_vertices=40_000_000) is None
     assert time.monotonic() - t0 < 5.0
-    assert ppr.spmv_resident_bytes(20_000_000, ppr.SPMV_RG, 8) \
+    assert ppr.spmv_resident_bytes(40_000_000, ppr.SPMV_RG, 8) \
         > ppr.SPMV_VMEM_BUDGET
-    # and the bound is tight the other way: the benchmark graph fits
-    assert ppr.spmv_resident_bytes(1_000_000, ppr.SPMV_RG,
+    # and the bound is tight the other way: the benchmark's graph
+    # (Graph500 SCALE 24) fits at its tallest windows
+    assert ppr.spmv_resident_bytes(1 << 24, ppr.SPMV_RGS[-1],
                                    ppr.SPMV_WS_CAP) \
         < ppr.SPMV_VMEM_BUDGET
 
@@ -466,7 +468,7 @@ def test_spmv_resident_bytes_formula():
     from tpu_distalg.ops import pallas_pagerank as ppr
 
     r8 = ((1_000_000 + 127) // 128 + 7) // 8 * 8
-    want = (r8 + 128 + r8 + 80) * 128 * 4 + 2 * 5 * 8 * 8 * 128 * 4
+    want = (r8 + 80) * 128 * 4 + 2 * (128 + 5 * 8 * 8) * 128 * 4
     assert ppr.spmv_resident_bytes(1_000_000, 128, 80, 8) == want
 
 

@@ -274,10 +274,13 @@ def test_pagerank_prepare_is_the_plans_parent(mesh8, sink_dir):
     assert pagerank.prepare_device_spmv(el, mesh8) is not None
     ends = _spans(sink_dir)
     ids = {e["id"]: e["name"] for e in ends}
+    ends = [e for e in ends if not e["name"].startswith("jit:")]
     assert [(e["name"], ids.get(e["parent"])) for e in ends] == [
-        (f"pagerank:plan_spmv:rg{ppr.SPMV_RG}", "pagerank:prepare"),
+        ("pagerank:plan", "pagerank:prepare"),
         ("pagerank:prepare", None)]
-    assert ends[1]["edges"] == int(el.n_edges)
+    assert ends[1]["distinct"] == int(el.n_edges)
+    assert ends[1]["ranks_form"] == "resident"
+    assert ends[0]["span"] <= ends[0]["ws"] == ends[1]["ws"]
 
 
 # ---- tda report --------------------------------------------------------
@@ -506,7 +509,7 @@ def test_pallas_calls_carry_the_kernel_bodys_name(kernel):
                 plan.src_row, plan.dst_row, plan.dst_lane, plan.w_e,
                 rg=plan.rg, ws=plan.ws, r8=plan.r8, blk=plan.blk,
                 interpret=True),
-            jnp.zeros((plan.r8 + plan.rg, 128)))
+            jnp.zeros((plan.geom.n_groups * plan.rg, 128)))
     assert got == [kernel]
     assert getattr(
         ppr if kernel == "_spmv_kernel" else pallas_kernels, kernel)
@@ -526,6 +529,7 @@ def test_pagerank_sweep_names_its_scope(mesh8):
     text = fn.lower(de.src, de.dst, de.w_e, de.emask, de.has_out,
                     de.n_ref).as_text(debug_info=True)
     assert f"{names.PAGERANK_SPMV}/jit(spmv_table)" in text
+    assert f"{names.PAGERANK_UPDATE}/" in text
 
 
 @pytest.mark.parametrize("shards", [1, 4])

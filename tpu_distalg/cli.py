@@ -376,6 +376,19 @@ def main(argv=None):
     p.add_argument("--n-vertices", type=int, default=0,
                    help="0 = the reference's 4-edge toy graph; else an "
                         "Erdős–Rényi graph of this many vertices")
+    p.add_argument("--rmat-scale", type=int, default=0, metavar="SCALE",
+                   help="rank a Graph500 Kronecker graph of 2**SCALE "
+                        "vertices drawn, deduplicated and planned on "
+                        "the device (overrides --n-vertices; standard "
+                        "mode, the fused sweep; --seed picks the graph)")
+    p.add_argument("--edge-factor", type=int, default=16,
+                   help="generated edges a vertex (--rmat-scale)")
+    p.add_argument("--rmat-abcd", type=float, nargs=4,
+                   default=None, metavar=("A", "B", "C", "D"),
+                   help="the generator's quadrant probabilities "
+                        "(default Graph500's 0.57 0.19 0.19 0.05)")
+    p.add_argument("--seed", type=int, default=0,
+                   help="the generated graph's seed (--rmat-scale)")
     p.add_argument("--edge-file", type=str, default=None,
                    help="load the graph from a '#'-commented whitespace "
                         "edge-list file (overrides --n-vertices); parsed "
@@ -386,8 +399,8 @@ def main(argv=None):
                    choices=["resident", "virtual", "streamed"],
                    help="where the EDGE SET lives: resident = device "
                         "HBM (the fused-SpMV/Pallas/XLA sweeps; "
-                        "self-caps at ~12M vertices on the VMEM "
-                        "guard), streamed = a dst-sorted CSR edge-"
+                        "the fused sweep keeps 4 B a vertex in VMEM "
+                        "and caps itself at 26M vertices), streamed = a dst-sorted CSR edge-"
                         "block disk cache swept out-of-core "
                         "(tpu_distalg/graphs/ — only O(V) state in "
                         "HBM; sparse rank combine), virtual = the "
@@ -1505,27 +1518,31 @@ def _dispatch(args, jax):
         from tpu_distalg.models import pagerank as m
         from tpu_distalg.utils import datasets
 
-        if args.edge_file is not None:
-            from tpu_distalg import native
-
-            edges = native.parse_edges_text(
-                args.edge_file, args.edge_capacity)
-        elif args.n_vertices == 0:
-            edges = datasets.toy_graph_edges()
-        else:
-            edges = datasets.erdos_renyi_edges(args.n_vertices)
-        from tpu_distalg.utils import checkpoint as ckpt
-
         import numpy as np
 
+        from tpu_distalg.utils import checkpoint as ckpt
+
+        if args.rmat_scale:
+            edges = None
+            n_v = 1 << args.rmat_scale
+        else:
+            if args.edge_file is not None:
+                from tpu_distalg import native
+
+                edges = native.parse_edges_text(
+                    args.edge_file, args.edge_capacity)
+            elif args.n_vertices == 0:
+                edges = datasets.toy_graph_edges()
+            else:
+                edges = datasets.erdos_renyi_edges(args.n_vertices)
+            # the edge content is authoritative for --edge-file (it
+            # documents itself as overriding --n-vertices, and an
+            # undersized count must never reach the degree histogram);
+            # the synthetic path keeps its isolated tail vertices
+            n_v = int(np.asarray(edges).max()) + 1 if len(edges) else 1
+            if args.edge_file is None and args.n_vertices:
+                n_v = max(n_v, args.n_vertices)
         mesh = _mesh(args)
-        # the edge content is authoritative for --edge-file (it
-        # documents itself as overriding --n-vertices, and an
-        # undersized count must never reach the degree histogram); the
-        # synthetic path keeps its isolated tail vertices
-        n_v = int(np.asarray(edges).max()) + 1 if len(edges) else 1
-        if args.edge_file is None and args.n_vertices:
-            n_v = max(n_v, args.n_vertices)
         backend, warn = m.choose_data_backend(args.data_backend, n_v,
                                               scatter=args.scatter)
         if warn:
@@ -1537,9 +1554,29 @@ def _dispatch(args, jax):
                 "mode='standard' — drop --mode reference or use "
                 "--data-backend resident on a smaller graph")
         mode = args.mode or ("reference" if backend == "resident"
-                             else "standard")
+                             and edges is not None else "standard")
         t0 = time.perf_counter()
-        if backend == "resident":
+        if edges is None:
+            if backend != "resident":
+                raise SystemExit(
+                    f"[pagerank] 2**{args.rmat_scale} vertices are past "
+                    f"the resident fused sweep, and --rmat-scale draws "
+                    f"its graph on the device only: write the edges to "
+                    f"a file for --data-backend streamed")
+            res = ckpt.run_with_restarts(
+                lambda: m.run_rmat(
+                    mesh, m.PageRankConfig(
+                        n_iterations=args.n_iterations, q=args.q,
+                        mode=mode, scatter=args.scatter),
+                    args.rmat_scale, args.edge_factor, args.rmat_abcd,
+                    args.seed, checkpoint_dir=args.checkpoint_dir,
+                    checkpoint_every=args.checkpoint_every),
+                max_restarts=args.max_restarts)
+            ranks = np.asarray(res.ranks)
+            mask = np.ones(len(ranks), bool)
+            tail = (f" [fused sweep over a Kronecker graph of "
+                    f"2**{args.rmat_scale} vertices drawn on the device]")
+        elif backend == "resident":
             res = ckpt.run_with_restarts(
                 lambda: m.run(edges, mesh, m.PageRankConfig(
                     n_iterations=args.n_iterations, q=args.q,

@@ -1,7 +1,7 @@
 """Out-of-core power-law graph engine — streamed CSR PageRank.
 
 Graph workloads were the last resident-only island: the fused SpMV
-sweep (``ops/pallas_pagerank``) self-caps at ~12M vertices on its VMEM
+sweep (``ops/pallas_pagerank``) self-caps at 26M vertices on its VMEM
 table budget and every resident path needs the full edge set in HBM,
 while the SGD family has streamed >HBM datasets since the data
 subsystem landed. This package closes that gap (ROADMAP open item 3):

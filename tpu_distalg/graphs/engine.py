@@ -6,7 +6,7 @@ subsystem's prefetch pipeline (disk gather ∥ H2D ∥ SpMV — the same
 ``Prefetcher`` machinery that feeds the >HBM SGD trainers), and only
 the O(V) state — rank vector, out-degree mask, per-shard window
 accumulators — ever resides in device memory. This lifts the vertex
-ceiling from the resident SpMV path's ~12M (its VMEM table budget,
+ceiling from the resident SpMV path's 26M (its VMEM table budget,
 ``ops/pallas_pagerank.SPMV_VMEM_BUDGET``) to whatever the disk holds.
 
 One power iteration = map over edge blocks, then one sparse reduce

@@ -25,17 +25,19 @@ not bandwidth — bounds the sweep):
     Rejected alternatives, measured no faster: pull/ELL in-edge tables
     (doubles the random accesses) and prefix-sum segmented reduction
     (f32 prefix differences can't resolve 1e-6-scale ranks);
-  * standard mode goes one further: dst-sortedness means consecutive
+  * standard mode goes further: dst-sortedness means consecutive
     edges target a narrow band of a (V/128, 128) vertex table, so the
     scatter becomes a Pallas kernel (``ops/pallas_pagerank``) that
     keeps the table VMEM-resident and scatter-adds each 1024-edge
-    chunk with ONE one-hot MXU matmul — no random-access engine at
-    all. Measured: sweep drops ~17 → ~9.2 ns/edge (13.5 iter/s at
-    1M×8M on one v5e). The remaining random op, the ``ranks[src]``
-    gather, stays in XLA: a Pallas windowed gather is 4× faster in
-    isolation but needs src-sorted edges, and re-crossing the per-edge
-    array between sort orders costs exactly the random access it
-    saves (full analysis: ``ops/pallas_pagerank`` docstring).
+    chunk with ONE one-hot MXU matmul (the hybrid sweep: the
+    ``ranks[src]`` gather stays in XLA); and with the edges sorted by
+    (source group, destination row) the gather joins it in one kernel
+    (the fused SpMV, ``scatter='spmv'``, what ``'auto'`` prefers): no
+    random-access engine at all. Its plan is made on the device for
+    every graph (:func:`prepare_device_spmv`), and a Graph500
+    Kronecker graph is drawn and deduplicated there too
+    (:func:`build_rmat_graph`): SCALE 24, 268M generated edges, drawn,
+    deduplicated and planned on one chip in 8.3 s warm (PERF.md, PR 38).
 
 Two modes (SURVEY.md §7 hard part #6):
   * ``mode='reference'`` reproduces the reference's semantics exactly: n is
@@ -50,6 +52,7 @@ Two modes (SURVEY.md §7 hard part #6):
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -100,8 +103,8 @@ class DevicePlan:
 
 @dataclasses.dataclass
 class DeviceSpMV:
-    """Device-resident :class:`ops.pallas_pagerank.SpMVPlan` arrays —
-    the fully-fused Path E sweep (``scatter='spmv'``)."""
+    """A fused-SpMV plan's arrays on the device
+    (:class:`ops.pallas_pagerank.SpMVPlan`; ``scatter='spmv'``)."""
 
     gbase: jax.Array      # (NCH,) int32, sharded over data
     sbase: jax.Array      # (NCH,) int32
@@ -115,6 +118,49 @@ class DeviceSpMV:
     r8: int
     blk: int
     n_chunks: int
+    seg_steps: int = 0    # grid steps a kernel call (0: one call)
+    n_groups: int = 1
+
+    LEAVES = ("gbase", "sbase", "src_lane", "src_row", "dst_row",
+              "dst_lane", "w_e")   # the arrays, by their rule-table names
+
+    @classmethod
+    def of(cls, arrays, geom) -> "DeviceSpMV":
+        """The seven arrays (in ``LEAVES``' order) of a plan of
+        ``geom`` (``ops.pallas_pagerank.SpMVGeometry``)."""
+        return cls(*arrays, rg=geom.rg, ws=geom.ws, r8=geom.r8,
+                   blk=geom.blk, n_chunks=geom.n_chunks,
+                   seg_steps=geom.seg_steps, n_groups=geom.n_groups)
+
+    @property
+    def ranks_form(self) -> str:
+        """'windowed' past one gather group, else 'resident'."""
+        return "windowed" if self.n_groups > 1 else "resident"
+
+    @property
+    def arrays(self) -> tuple:
+        return tuple(getattr(self, n) for n in self.LEAVES)
+
+    @property
+    def nbytes(self) -> int:
+        return sum(a.nbytes for a in self.arrays)
+
+
+@dataclasses.dataclass
+class DeviceGraph:
+    """A graph's distinct edges on the device as the planner takes
+    them: ``geom.n_slots`` slots, an edge wherever ``src >= 0`` among
+    the first ``n_in``, spare slots behind."""
+
+    src: jax.Array        # (n_slots,) int32, -1 where no edge
+    dst: jax.Array        # (n_slots,) int32
+    inv_deg: jax.Array    # (V,) f32, 1 / distinct out-edges, 0 for none
+    has_out: jax.Array    # (V,) f32
+    n_in: int
+    n_vertices: int
+    n_edges: int          # distinct edges
+    geom: object          # ops.pallas_pagerank.SpMVGeometry
+    meta: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -134,16 +180,16 @@ class DeviceEdges:
 
 
 def resident_guard_trips(n_vertices: int) -> bool:
-    """True when the fused-SpMV VMEM guard would reject this vertex
-    count even at the smallest scatter window — the documented ~12M
-    resident ceiling (``ops/pallas_pagerank.SPMV_VMEM_BUDGET``). The
+    """True when the fused SpMV cannot hold this many vertices: its
+    output table, 4 B a vertex, has to stay in VMEM
+    (``ops/pallas_pagerank.SPMV_VMEM_BUDGET``: 26M vertices). The
     signal the CLI keys its warn-and-degrade-to-streamed on: past this
     line the resident paths either refuse (spmv) or fall back to
     sweeps that need the whole edge set HBM-resident anyway."""
     from tpu_distalg.ops import pallas_pagerank as ppr
 
-    return ppr.spmv_resident_bytes(n_vertices, ppr.SPMV_RG, 8) \
-        > ppr.SPMV_VMEM_BUDGET
+    return ppr.spmv_resident_bytes(n_vertices, ppr.SPMV_RGS[-1],
+                                   ppr.SPMV_WS_CAP) > ppr.SPMV_VMEM_BUDGET
 
 
 def choose_data_backend(requested: str, n_vertices: int,
@@ -151,19 +197,18 @@ def choose_data_backend(requested: str, n_vertices: int,
                         ) -> tuple[str, str | None]:
     """Resolve the pagerank ``--data-backend`` knob against the
     resident VMEM guard: a resident request past the ceiling degrades
-    to streamed WITH a warning instead of dying minutes later in the
-    sweep prep (the guard used to just refuse). An EXPLICIT
-    ``--scatter xla``/``pallas`` resident request is honored — the
-    ceiling is the fused-SpMV kernel's table budget, and those sweeps
+    to streamed WITH a warning instead of dying in the sweep prep. An
+    EXPLICIT ``--scatter xla``/``pallas`` resident request is honored:
+    the ceiling is the fused SpMV's table budget, and those sweeps
     carry their own (HBM/plan) limits with remedy-naming errors.
     Returns ``(backend, warning-or-None)``."""
     if requested == "resident" and scatter in ("auto", "spmv") \
             and resident_guard_trips(n_vertices):
         return "streamed", (
             f"[pagerank] {n_vertices} vertices exceed the resident "
-            f"sweep's VMEM guard (~12M ceiling, "
-            f"ops/pallas_pagerank.SPMV_VMEM_BUDGET) — degrading to "
-            f"--data-backend streamed (tpu_distalg/graphs/: edge "
+            f"sweep's VMEM guard (the fused SpMV keeps 4 B a vertex in "
+            f"VMEM, ops/pallas_pagerank.SPMV_VMEM_BUDGET) — degrading "
+            f"to --data-backend streamed (tpu_distalg/graphs/: edge "
             f"blocks stream from disk, only O(V) state stays in HBM)")
     return requested, None
 
@@ -178,58 +223,201 @@ def _inv_out_degree(el: gops.EdgeList) -> np.ndarray:
     return inv_out_degree(el.out_degree)
 
 
-def prepare_device_spmv(el: gops.EdgeList, mesh: Mesh,
-                        rg: int | None = None) -> DeviceSpMV | None:
-    """Host prep for the fused Path E sweep: two-key edge sort +
-    per-chunk window metadata (``ops/pallas_pagerank.plan_spmv``),
-    device_put sharded over the data axis by chunk blocks. ``None``
-    when the graph's structure exceeds the window caps — callers fall
-    back to the hybrid/XLA sweep.
+def _replicated(mesh: Mesh):
+    """The sharding of the edge slots before the plan: whole on every
+    chip (the ``pagerank`` rule table's ``slots``)."""
+    return partition.leaf_sharding("pagerank", "slots", mesh)
 
-    With ``rg=None`` the gather window ESCALATES (128 → 256 → 512
-    rows) until the within-group scatter span fits: the span grows as
-    R²/(rg·E), so larger vertex counts need taller windows — 10M
-    vertices / 80M edges plans at rg=512 (ws=184) where rg=128
-    overflows. Taller windows cost proportionally more unrolled gather
-    rows (and Mosaic compile time: ~3 min at rg=512 vs ~10 s at 128);
-    each escalation re-sorts, so the 512 attempt on an 80M-edge graph
-    spends ~2-3 minutes of host prep. VMEM bounds the table:
-    (r8 + ws + rg) · 512 B must stay under the ~100 MB budget, which
-    holds to ~12M vertices — ``plan_spmv`` now enforces that budget
-    itself (``spmv_resident_bytes``) BEFORE paying the sorts, so
-    oversized graphs degrade here instead of failing the Mosaic
-    compile minutes later. Each plan attempt runs in a telemetry span
-    (``pagerank:plan_spmv:rgN``, child of ``pagerank:prepare``, which
-    also covers the puts) — the sorts are exactly the kind of
-    multi-minute host phase a stall report must be able to name."""
+
+def rmat_programs(mesh: Mesh, scale: int, abcd, geom, n_in: int):
+    """:func:`build_rmat_graph`'s two jitted programs, ``generate(seed)
+    -> (src, dst)`` and ``dedup(src, dst) -> (src, dst, inv_deg,
+    has_out, n_distinct)`` (the chipless compile check lowers them at
+    the cell's shapes)."""
+    from tpu_distalg.utils import datasets
+
+    V = 1 << scale
+    draw = datasets.kronecker_edges(scale, abcd)
+    rep = _replicated(mesh)
+
+    def generate(seed):
+        src, dst = draw(jnp.arange(n_in, dtype=jnp.uint32), seed)
+        spare = geom.n_slots - n_in      # sorted behind every edge
+        return (jnp.concatenate([src, jnp.full((spare,), V, jnp.int32)]),
+                jnp.concatenate([dst, jnp.zeros((spare,), jnp.int32)]))
+
+    def dedup(src, dst):
+        src, dst = jax.lax.sort((src, dst), num_keys=2, is_stable=False)
+        again = (src[1:] == src[:-1]) & (dst[1:] == dst[:-1])
+        real = (src < V) & ~jnp.concatenate([jnp.zeros((1,), bool), again])
+        deg = jax.ops.segment_sum(real.astype(jnp.int32), src,
+                                  num_segments=V + 1,
+                                  indices_are_sorted=True)[:V]
+        inv_deg = jnp.where(deg > 0, 1.0 / jnp.maximum(deg, 1), 0.0)
+        return (jnp.where(real, src, -1), dst,
+                inv_deg.astype(jnp.float32),
+                (deg > 0).astype(jnp.float32), jnp.sum(deg))
+
+    return (jax.jit(generate, out_shardings=rep),
+            jax.jit(dedup, out_shardings=rep, donate_argnums=(0, 1)))
+
+
+def plan_programs(mesh: Mesh, geom, n_in: int):
+    """:func:`prepare_device_spmv`'s two jitted programs: ``sort(src,
+    dst) -> (src, dst)`` in the kernel's order and ``lay_out(src, dst,
+    inv_deg) -> (the seven plan arrays, widest span)``."""
     from tpu_distalg.ops import pallas_pagerank as ppr
 
-    with tevents.span("pagerank:prepare", edges=int(el.n_edges)):
-        inv_deg = _inv_out_degree(el)
-        n_shards = mesh.shape[DATA_AXIS]
-        plan = None
-        for r in ((rg,) if rg is not None else (ppr.SPMV_RG, 256, 512)):
-            with tevents.span(f"pagerank:plan_spmv:rg{r}",
-                              n_edges=int(el.n_edges),
-                              n_vertices=int(el.n_vertices)):
-                plan = ppr.plan_spmv(el.src, el.dst, inv_deg[el.src],
-                                     el.n_vertices, n_shards=n_shards,
-                                     rg=r)
-            if plan is not None:
-                break
+    def lay_out(src, dst, inv_deg):
+        w_e = inv_deg[jnp.maximum(src, 0)]
+        arrays, span = ppr.slot_arrays(jnp, src, dst, w_e, geom)
+        return tuple(partition.constrain(a, n, "pagerank", mesh)
+                     for a, n in zip(arrays, DeviceSpMV.LEAVES)), span
+
+    return (jax.jit(functools.partial(ppr.sort_slots, geom=geom,
+                                      n_in=n_in),
+                    out_shardings=_replicated(mesh),
+                    donate_argnums=(0, 1)),
+            jax.jit(lay_out, donate_argnums=(0, 1)))
+
+
+def build_rmat_graph(mesh: Mesh, scale: int, edge_factor: int = 16,
+                     abcd=None, seed: int = 0,
+                     rg: int | None = None) -> DeviceGraph:
+    """The program's own loader of a Graph500 Kronecker graph, on the
+    device: ``edge_factor * 2**scale`` edges drawn from the seed as an
+    argument (``utils/datasets.kronecker_edges``), sorted by (source,
+    destination), a repeated edge marked and counted once, the distinct
+    out-degrees added up from the sorted sources. Nothing crosses to
+    the host but the count of distinct edges. Every shape follows from
+    (scale, edge_factor, shards): one compile serves every seed."""
+    from tpu_distalg.ops import pallas_pagerank as ppr
+    from tpu_distalg.utils import datasets
+
+    V, n_in = 1 << scale, edge_factor << scale
+    abcd = tuple(abcd or datasets.GRAPH500_ABCD)
+    geom = ppr.spmv_geometry(V, n_in, mesh.shape[DATA_AXIS], rg)
+    if geom is None:
+        raise ValueError(
+            f"2**{scale} vertices are past the resident fused SpMV "
+            f"(4 B a vertex in VMEM): --data-backend streamed")
+    generate, dedup = rmat_programs(mesh, scale, abcd, geom, n_in)
+    with tevents.span("pagerank:generate", scale=scale,
+                      generated=n_in, seed=int(seed)):
+        src, dst = jax.block_until_ready(
+            generate(np.uint32(seed & 0xFFFFFFFF)))
+    with tevents.span("pagerank:dedup", generated=n_in):
+        src, dst, inv_deg, has_out, n_edges = dedup(src, dst)
+        n_edges = int(n_edges)
+        tevents.current().fields["distinct"] = n_edges
+    return DeviceGraph(
+        src=src, dst=dst, inv_deg=inv_deg, has_out=has_out, n_in=n_in,
+        n_vertices=V, n_edges=n_edges, geom=geom,
+        meta=dict(generator="kronecker", scale=scale,
+                  edge_factor=edge_factor, abcd=abcd, seed=int(seed),
+                  generated=n_in, distinct=n_edges))
+
+
+def device_graph(el: gops.EdgeList, mesh: Mesh,
+                 rg: int | None = None) -> DeviceGraph | None:
+    """A host edge list (distinct already) copied up once in the form
+    the device planner takes; ``None`` past the VMEM budget."""
+    from tpu_distalg.ops import pallas_pagerank as ppr
+
+    geom = ppr.spmv_geometry(el.n_vertices, el.n_edges,
+                             mesh.shape[DATA_AXIS], rg) \
+        if el.n_edges else None
+    if geom is None:
+        return None
+    put = lambda a, n: partition.put(a, n, "pagerank", mesh)  # noqa: E731
+
+    def slots(x, fill):
+        out = np.full(geom.n_slots, fill, np.int32)
+        out[:el.n_edges] = x
+        return put(out, "slots")
+
+    inv_deg = _inv_out_degree(el)
+    return DeviceGraph(
+        src=slots(el.src, -1), dst=slots(el.dst, 0),
+        inv_deg=put(inv_deg, "inv_deg"),
+        has_out=put((inv_deg > 0).astype(np.float32), "has_out"),
+        n_in=el.n_edges, n_vertices=el.n_vertices, n_edges=el.n_edges,
+        geom=geom)
+
+
+def prepare_device_spmv(graph: gops.EdgeList | DeviceGraph, mesh: Mesh,
+                        rg: int | None = None) -> DeviceSpMV | None:
+    """The fused sweep's plan, made on the device for every graph: a
+    host edge list is copied up once (:func:`device_graph`), a
+    :class:`DeviceGraph` is there already. One sort by (source group,
+    destination row) with the padding in its keys puts every slot
+    where the kernel reads it, and array code lays the seven plan
+    arrays out, sharded over the data axis by chunk
+    (``ops/pallas_pagerank.sort_slots`` / ``slot_arrays``). The
+    geometry (``rg``, ``ws``, the slot count) is a function of the
+    sizes alone, so a second graph of a size compiles nothing.
+
+    ``None`` when the table passes the VMEM budget or a chunk's
+    destinations span more than the geometry's ``ws`` rows (a graph
+    sparser or more skewed than the window was sized for): counted in
+    ``spmv_plan_rejections`` and said in the ``pagerank:plan`` span, so
+    ``scatter='auto'`` falls back knowingly and ``'spmv'`` raises.
+    ``pagerank:prepare`` covers the copy and the plan; the graph's own
+    spans (``pagerank:generate``, ``pagerank:dedup``) come before it
+    where the program drew the graph. The graph's edge arrays are
+    donated to the sort."""
+    from tpu_distalg.ops import pallas_pagerank as ppr
+
+    with tevents.span("pagerank:prepare"):
+        sp = tevents.current().fields
+        if isinstance(graph, gops.EdgeList):
+            graph = device_graph(graph, mesh, rg)
+            if graph is None:
+                tevents.counter("spmv_plan_rejections")
+                return None
+        geom = graph.geom
+        sp.update(vertices=graph.n_vertices, distinct=graph.n_edges,
+                  generated=graph.n_in, rg=geom.rg, ws=geom.ws,
+                  chunks=geom.n_chunks, ranks_form=geom.ranks_form,
+                  padding_share=geom.n_slots / max(graph.n_edges, 1))
+        sort, lay_out = plan_programs(mesh, geom, graph.n_in)
+        with tevents.span("pagerank:plan", rg=geom.rg, ws=geom.ws):
+            src, dst = sort(graph.src, graph.dst)
+            graph.src = graph.dst = None
+            arrays, span = lay_out(src, dst, graph.inv_deg)
+            span = int(span)
+            tevents.current().fields["span"] = span
+        if span > geom.ws:
             tevents.counter("spmv_plan_rejections")
-        if plan is None:
+            tevents.emit("spmv_span_rejected", span=span, ws=geom.ws,
+                         rg=geom.rg, n_vertices=graph.n_vertices)
             return None
-        put = lambda a, n: partition.put(  # noqa: E731
-            a, n, "pagerank", mesh)
-        return DeviceSpMV(
-            gbase=put(plan.gbase, "gbase"), sbase=put(plan.sbase, "sbase"),
-            src_lane=put(plan.src_lane, "src_lane"),
-            src_row=put(plan.src_row, "src_row"),
-            dst_row=put(plan.dst_row, "dst_row"),
-            dst_lane=put(plan.dst_lane, "dst_lane"),
-            w_e=put(plan.w_e, "w_e"), rg=plan.rg, ws=plan.ws, r8=plan.r8,
-            blk=plan.blk, n_chunks=plan.n_chunks)
+        plan = DeviceSpMV.of(arrays, geom)
+        sp["bytes"] = plan.nbytes
+        tevents.counter("spmv_slots_padded",
+                        geom.n_slots - graph.n_edges)
+        return plan
+
+
+def _plan_only_edges(mesh: Mesh, inv_deg, has_out,
+                     n_vertices: int) -> DeviceEdges:
+    """What the fused sweep's ``run`` takes beside its plan: the vertex
+    tables; the per-edge arrays of the other sweeps are placeholders
+    (the plan holds the edges)."""
+    n_shards = mesh.shape[DATA_AXIS]
+    put = lambda a, n: partition.put(a, n, "pagerank", mesh)  # noqa: E731
+    z, zf = np.zeros(n_shards, np.int32), np.zeros(n_shards, np.float32)
+    return DeviceEdges(
+        src=put(z, "src"), dst=put(z, "dst"), w_e=put(zf, "w_e"),
+        emask=put(zf, "emask"), inv_deg=jnp.asarray(inv_deg),
+        has_out=jnp.asarray(has_out), n_vertices=n_vertices,
+        n_ref=float(jnp.sum(has_out)))
+
+
+def spmv_device_edges(graph: DeviceGraph, mesh: Mesh) -> DeviceEdges:
+    """:func:`_plan_only_edges` of a graph on the device."""
+    return _plan_only_edges(mesh, graph.inv_deg, graph.has_out,
+                            graph.n_vertices)
 
 
 def prepare_device_edges(el: gops.EdgeList, mesh: Mesh,
@@ -259,13 +447,7 @@ def prepare_device_edges(el: gops.EdgeList, mesh: Mesh,
         # the spmv path deletes src/dst/w_e/emask on its first line —
         # skip the counting sort, per-edge gather, and the ~16 B/edge
         # of device uploads entirely; only has_out/n_ref are consumed
-        z = np.zeros(n_shards, np.int32)
-        zf = np.zeros(n_shards, np.float32)
-        return DeviceEdges(
-            src=put(z, "src"), dst=put(z, "dst"), w_e=put(zf, "w_e"),
-            emask=put(zf, "emask"),
-            inv_deg=jnp.asarray(inv_deg), has_out=jnp.asarray(has_out),
-            n_vertices=V, n_ref=float(has_out.sum()), plan=None)
+        return _plan_only_edges(mesh, inv_deg, has_out, V)
 
     order = native.counting_sort_perm(el.dst, el.n_vertices)
     src_o = el.src[order].astype(np.int32)
@@ -321,6 +503,26 @@ def prepare_device_edges(el: gops.EdgeList, mesh: Mesh,
     )
 
 
+class _PlanBound:
+    """The fused sweep's jitted run with its plan bound as ARGUMENTS
+    (gigabytes at Graph500 SCALE 24: closed over, they would be
+    constants of the program, copied to the host to be lowered), under
+    the signature every sweep's ``run`` has."""
+
+    def __init__(self, jitted, plan):
+        self.jitted, self.plan = jitted, plan
+
+    def _args(self, src, dst, w_e, emask, has_out, n_ref,
+              ranks0=None, has_rank0=None):
+        return self.plan, has_out, ranks0
+
+    def __call__(self, *args):
+        return self.jitted(*self._args(*args))
+
+    def lower(self, *args):
+        return self.jitted.lower(*self._args(*args))
+
+
 def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
                 plan: DevicePlan | None = None,
                 spmv: DeviceSpMV | None = None):
@@ -335,12 +537,11 @@ def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
 
     Standard-mode path choice: with an ``spmv`` plan (and scatter
     'auto'/'spmv') the fully-fused tiled SpMV runs — gather AND
-    scatter in one Pallas kernel, measured ~2.9 ns/edge full-iteration
-    at 1M×8M on one v5e. 'auto' PREFERS it; the hybrid sweep (XLA
-    ``ranks[src]·w`` gather + the windowed one-hot-MXU scatter
-    ``plan``, ~9.2 ns/edge) is the fallback when the spmv windows
-    exceed their caps, and the XLA-only sweep (~17 ns/edge) the final
-    fallback. ``scatter='pallas'``/'spmv' without their plan raise;
+    scatter in one Pallas kernel, the plan's arrays its arguments.
+    'auto' PREFERS it; the hybrid sweep (XLA ``ranks[src]·w`` gather +
+    the windowed one-hot-MXU scatter ``plan``) is the fallback when a
+    chunk's span passes the spmv window, and the XLA-only sweep the
+    final fallback. ``scatter='pallas'``/'spmv' without their plan raise;
     'xla' forces the legacy path (benchmark A/B).
     """
     V = n_vertices
@@ -374,7 +575,7 @@ def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
             "DeviceSpMV via prepare_device_spmv (None means the "
             "graph's windows exceeded ops/pallas_pagerank caps, or "
             "the kernel-resident VMEM footprint blew "
-            "SPMV_VMEM_BUDGET — the ~12M-vertex ceiling). Graphs "
+            "SPMV_VMEM_BUDGET, 4 B a vertex). Graphs "
             "past the resident ceiling belong on the out-of-core "
             "engine: --data-backend streamed (tpu_distalg/graphs/ "
             "streams edge blocks from disk; only O(V) state stays "
@@ -429,19 +630,21 @@ def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
             and spmv is not None):
         # Path E: the fully-fused tiled SpMV — gather AND scatter in
         # one Pallas kernel, no XLA random-access op in the sweep.
-        # 'auto' prefers it (measured 3.7x the hybrid sweep at 1Mx8M)
+        # 'auto' prefers it
         from tpu_distalg.ops import pallas_pagerank as ppr
 
         interpret = not mesh_on_tpu(mesh)
         rg, ws, r8, blk = spmv.rg, spmv.ws, spmv.r8, spmv.blk
-        pad = (r8 + rg) * 128 - V
+        rows = spmv.n_groups * rg         # the ranks table, whole groups
 
         def body(gb, sb, slane, srow, drow, dlane, we, ranks):
             with jax.named_scope(names.PAGERANK_SPMV):
-                rt = jnp.pad(ranks, (0, pad)).reshape(r8 + rg, 128)
+                rt = jnp.pad(ranks, (0, rows * 128 - V)).reshape(rows, 128)
                 acc = ppr.spmv_table(gb, sb, rt, slane, srow, drow,
                                      dlane, we, rg=rg, ws=ws, r8=r8,
-                                     blk=blk, interpret=interpret)
+                                     blk=blk,
+                                     seg_steps=spmv.seg_steps or None,
+                                     interpret=interpret)
             return tree_allreduce_sum(acc)
 
         sweep_fn = data_parallel(
@@ -451,21 +654,18 @@ def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
             out_specs=P(),
         )
 
-        def run(src, dst, w_e, emask, has_out, n_ref,
-                ranks0=None, has_rank0=None):
-            del src, dst, w_e, emask, n_ref, has_rank0  # plan-encoded
+        def run(plan, has_out, ranks0=None):
             if ranks0 is None:
                 ranks0 = jnp.full((V,), 1.0 / V, dtype=jnp.float32)
 
             def step(ranks, _):
-                acc = sweep_fn(spmv.gbase, spmv.sbase, spmv.src_lane,
-                               spmv.src_row, spmv.dst_row,
-                               spmv.dst_lane, spmv.w_e, ranks)
-                c = acc[:r8].reshape(-1)[:V]
-                if config.redistribute_dangling:
-                    dangling = jnp.sum(ranks * (1.0 - has_out))
-                    c = c + dangling / V
-                ranks = q / V + (1 - q) * c
+                acc = sweep_fn(*plan, ranks)
+                with jax.named_scope(names.PAGERANK_UPDATE):
+                    c = acc[:r8].reshape(-1)[:V]
+                    if config.redistribute_dangling:
+                        dangling = jnp.sum(ranks * (1.0 - has_out))
+                        c = c + dangling / V
+                    ranks = q / V + (1 - q) * c
                 return ranks, None
 
             ranks, _ = jax.lax.scan(
@@ -473,7 +673,7 @@ def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
             )
             return ranks, jnp.ones((V,), dtype=jnp.float32)
 
-        return jax.jit(run)
+        return _PlanBound(jax.jit(run), spmv.arrays)
 
     if use_pallas:
         from tpu_distalg.ops import pallas_pagerank as ppr
@@ -575,6 +775,40 @@ def run(edges: np.ndarray, mesh: Mesh,
         # uploads it deletes anyway
         light=spmv is not None)
     de.spmv = spmv
+    return _run_prepared(de, mesh, config, checkpoint_dir,
+                         checkpoint_every)
+
+
+def run_rmat(mesh: Mesh, config: PageRankConfig, scale: int,
+             edge_factor: int = 16, abcd=None, seed: int = 0, *,
+             checkpoint_dir: str | None = None,
+             checkpoint_every: int = 5) -> PageRankResult:
+    """``tda pagerank --rmat-scale``: a Graph500 Kronecker graph drawn,
+    deduplicated and planned on the device (:func:`build_rmat_graph`),
+    ranked by the fused sweep. A span past the geometry's window is an
+    error here: the loader has no host copy to fall back with."""
+    if config.mode != "standard" or \
+            config.scatter not in ("auto", "spmv"):
+        raise ValueError(
+            "a graph drawn on the device is ranked by the fused sweep: "
+            "mode='standard', scatter 'auto' or 'spmv'")
+    graph = build_rmat_graph(mesh, scale, edge_factor, abcd, seed)
+    spmv = prepare_device_spmv(graph, mesh)
+    if spmv is None:
+        raise RuntimeError(
+            f"the graph's chunks span more destination rows than the "
+            f"window fixed for its size (ws {graph.geom.ws} at rg "
+            f"{graph.geom.rg}; the pagerank:plan span says by how "
+            f"much): ops/pallas_pagerank.SPMV_SPAN_ROOM")
+    de = spmv_device_edges(graph, mesh)
+    de.spmv = spmv
+    return _run_prepared(de, mesh, config, checkpoint_dir,
+                         checkpoint_every)
+
+
+def _run_prepared(de: DeviceEdges, mesh: Mesh, config: PageRankConfig,
+                  checkpoint_dir: str | None,
+                  checkpoint_every: int) -> PageRankResult:
     if checkpoint_dir is not None:
         return _run_segmented(de, mesh, config, checkpoint_dir,
                               checkpoint_every)
@@ -623,6 +857,10 @@ def _run_segmented(de: DeviceEdges, mesh: Mesh, config: PageRankConfig,
         {"ranks": ranks0, "has_rank": has_rank0},
         # both modes carry the same (V,) f32 pair, so the shape check
         # alone cannot catch a cross-mode resume — encode the mode
-        tag=f"pagerank_{config.mode}")
+        tag=f"pagerank_{config.mode}",
+        span_fields=(dict(ranks_form=de.spmv.ranks_form, rg=de.spmv.rg,
+                          ws=de.spmv.ws)
+                     if de.spmv is not None and config.mode == "standard"
+                     and config.scatter in ("auto", "spmv") else None))
     return PageRankResult(ranks=jnp.asarray(state["ranks"]),
                           has_rank=jnp.asarray(state["has_rank"]))

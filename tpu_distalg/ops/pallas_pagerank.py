@@ -1,86 +1,79 @@
-"""Windowed one-hot-MXU scatter: the PageRank sweep's Pallas half.
+"""PageRank's sweep as Pallas kernels: the windowed one-hot-MXU scatter
+and the fused tiled SpMV built on it.
 
 The reference pays a full shuffle per PageRank iteration
-(``/root/reference/graph_computation/pagerank.py:52-57`` — join +
+(``/root/reference/graph_computation/pagerank.py:52-57``: join +
 flatMap + reduceByKey). The XLA re-design (``ops/graph.py``) reduced
 that to one random gather (``ranks[src]``) plus one sorted
-``segment_sum`` per edge per sweep, measured ~16-17 ns/edge on one
-v5e — bound by the ~8 ns/element issue rate of EACH random-access XLA
-op, not by bandwidth (the sweep streams ~12 B/edge, <1% of HBM).
+``segment_sum`` per edge per sweep, each bound by the issue rate of a
+random-access XLA op and not by bandwidth (the benchmark's reference,
+which is those two ops, takes 4.8 s a sweep of 263M edges on one v5e
+where a fused sweep takes 0.75 s: PERF.md, PR 38). The kernels here
+touch no random-access engine.
 
-This module replaces the scatter half with a Pallas kernel measured
-~2.1 ns/edge, taking the full sweep to ~9.2 ns/edge (13.5 iter/s at
-1M vertices / 8M edges, ~1.8× the XLA sweep), exact to f32.
-
-How the scatter dodges the random-access engine
------------------------------------------------
+The scatter (:func:`scatter_table`, the hybrid sweep's half)
+------------------------------------------------------------
 Vertex ``v`` lives at (row ``v//128``, lane ``v%128``) of an
-(R, 128) f32 table that stays VMEM-resident across the whole pass
-(4 MB at 1M vertices). Because edges are dst-sorted (graph prep,
-``models/pagerank.py``), any chunk of 1024 consecutive edges lands in
-a narrow band of table rows — the prep computes each chunk's base row
-and verifies the worst-case span (``plan_scatter``). Per chunk the
-kernel builds two small masks from lane-major loads (no relayouts):
+(R, 128) f32 table that stays VMEM-resident across the whole pass.
+Because edges are dst-sorted (graph prep, ``models/pagerank.py``), any
+chunk of 1024 consecutive edges lands in a narrow band of table rows:
+the prep computes each chunk's base row and verifies the worst-case
+span (``plan_scatter``). Per chunk the kernel builds two small masks
+from lane-major loads (no relayouts):
 
-  * ``m[ρ, e]   = contrib[e] · (row[e] == base + ρ)``   (8W, 1024)
-  * ``onehotᵀ[λ, e] = (lane[e] == λ)``                  (128, 1024)
+  * ``m[rho, e]   = contrib[e] * (row[e] == base + rho)``   (8W, 1024)
+  * ``onehot_t[l, e] = (lane[e] == l)``                     (128, 1024)
 
-and one MXU matmul ``m @ onehotᵀ.T`` scatter-adds the whole chunk into
+and one MXU matmul ``m @ onehot_t.T`` scatter-adds the whole chunk into
 the resident window ``acc[base : base+8W]``. The matmul runs
-``precision=HIGHEST`` (6-pass) because one operand carries real f32
-contributions — DEFAULT truncates to bf16 and costs ~1e-3 relative
-error in rank sums; measured, HIGHEST is within noise of DEFAULT here
-because the kernel is mask-build/VPU-bound, not MXU-bound.
+``precision=HIGHEST`` (six bf16 passes) because one operand carries
+real f32 contributions: DEFAULT truncates to bf16 and costs ~1e-3
+relative error in rank sums.
 
-What was tried and rejected for the gather half (recorded so the next
-round doesn't re-walk it):
+What does not work, kept so that nobody walks it again: Mosaic's
+sublane ``dynamic_gather`` is vreg-local (it gathers only within one
+(8, 128) vreg: there is no primitive gather from a tall VMEM table);
+1-D dynamic slices inside a kernel scalarise, and an (E, 1) column
+layout pads its lane dimension to 128: everything here is 2-D
+lane-major blocks; a gather in source order and a scatter in
+destination order cannot share one edge order, and crossing a per-edge
+array from one to the other is itself a random permutation.
 
-  * Mosaic's ``tpu.dynamic_gather`` is vreg-local: it gathers along
-    sublanes ONLY within one (8, 128) vreg ("Multiple source vregs
-    along gather dimension" otherwise) — there is no primitive gather
-    from a tall VMEM table.
-  * A windowed Pallas gather (edges src-sorted, per-chunk vreg window,
-    selector over ≤32 vregs) measures ~2.2 ns/edge — 4× under XLA's
-    ~8.8. BUT it requires src-sorted edges while this scatter requires
-    dst-sorted edges, and crossing a per-edge array from one order to
-    the other is itself a random permutation at the same ~8 ns/element
-    XLA cost — the crossing eats the entire gather win. One side must
-    stay in XLA; the scatter is the better Pallas half because its
-    XLA form (segment_sum over 1M segments) measures 15-20 ns/edge
-    in isolation vs the gather's 8.8.
-  * 1D dynamic slices inside a kernel (``ref[pl.ds(i*1024, 1024)]``)
-    scalarise: a loads-only ablation measured ~13 ns/edge. Everything
-    here is therefore 2D lane-major blocks. An (E, 1) column layout is
-    equally fatal: TPU pads the lane dim to 128 (128× HBM traffic).
+The fused tiled SpMV (:func:`spmv_table`)
+-----------------------------------------
+Mosaic does lower a LANE-direction ``dynamic_gather``
+(``take_along_axis(x, idx, axis=1)`` on same-shape operands), so a
+table row broadcast over a vreg and gathered by ``src % 128`` serves
+every edge of a chunk whose source is in that row. Edges are sorted by
+(source group, destination row), a group ``rg`` rows of the ranks table
+(``rg * 128`` vertices), and a group is padded to whole grid steps. A
+chunk then reads ranks from ONE window of ``rg`` rows (a lane-gather
+and a three-level select tree a tile of 8 rows) and, the destinations
+sorted inside the group, writes a scatter window of ``ws`` rows: the
+one-hot matmul above, built per gather sublane (8 matmuls of
+(ws, 128) x (128, 128): the gather chunk is (8, 128), the matmul wants
+the edge dimension along lanes).
 
-The fully-fused tiled SpMV (Path E) was costed in round 4 and BUILT in
-round 5 (:func:`plan_spmv` / :func:`spmv_table`): measured
-**1.5-1.75 ns/edge** at 1M×8M on one v5e — ~6x the hybrid sweep above
-and beyond the 3-4 ns/edge pencil, because the scatter got cheaper than
-priced (ws=80 windows at rg=128) while the unrolled gather row-loop
-hits the VPU issue rate. The round-4 pencil, kept for the record:
+Since PR 38 the ranks table stays in HBM and a group's window is the
+block the pipeline copies in when the group changes; only the output
+table (4 B a vertex) is kept in VMEM, which is what bounds the path
+(``SPMV_VMEM_BUDGET``: 26M vertices). The plan is made on the device
+(:func:`sort_slots`, :func:`slot_arrays`) with every static shape a
+function of the sizes (:func:`spmv_geometry`), and a sweep is a few
+kernel calls so that each call's per-chunk scalars fit SMEM.
 
-  * the missing primitive EXISTS: Mosaic also lowers a LANE-direction
-    ``dynamic_gather`` (``take_along_axis(x, idx, axis=1)`` with
-    same-shape operands, verified working including multi-vreg row
-    batches), so a full (8, 128)-vreg gather is 8 lane-gathers + 8
-    selects — no lane constraint on edge placement;
-  * sort edges by (src-block of V/n vertices, dst); per 1024-edge
-    chunk the gather windows over 1024/n vregs of the rank table
-    (selector ≈ 24·W ops) and the scatter windows over ≈n/8+1 vregs
-    (dst-sorted within group). With a bf16 hi+lo split for the
-    scatter matmul (2-pass, ~1.5e-5 relative — near-f32) the optimum
-    near n=32 pencils to ~1.4 VPU-cycles/edge + builds ≈ 3 ns/edge,
-    ~2× this hybrid;
-  * the costs NOT in the pencil: the gather chunk must be (8, 128)
-    (lane-gather needs a 128-lane axis) while the scatter matmul
-    wants the edge dim as one 1024-lane axis — bridging them means 8
-    per-sublane (rows_w, 128)@(128, 128) matmuls and sublane
-    extraction glue; plus per-group chunk padding and a two-key host
-    sort. Every windowed-kernel estimate this round landed ~2× under
-    the measured result once loop overhead was counted, which prices
-    the fused kernel at ~5-7 ns/edge end-to-end — a 1.3-1.8× for
-    ~300 lines of delicate kernel; deferred, not disproven.
+Measured on one v5e (PR 38, ``scripts/step0_pagerank_resident.py``;
+PERF.md section 6 has the table): at Graph500 SCALE 24 (rg 512, ws
+224, 270.6M slots) a sweep takes 752.9 ms, 2.78 ns a slot, with the
+gather loop whole in one turn, and 828.7 / 920.8 / 1095.2 / 2131.7 ms
+at 16 / 8 / 4 / 1 tiles a turn; at SCALE 20 (rg 128, ws 72) 0.94 ns a
+slot whole and 2.12 rolled. The static schedule of a chipless compile
+says why: a chunk is 4333 bundles (at 1.5 GHz, 264 240 chunks: 0.76 s),
+2895 of them the scatter's eight HIGHEST matmuls and 1438 the 512
+gathered rows; rolled to one tile a turn the loop alone is 9152 (the
+selects of a tile are a chain, and tiles overlap only inside a turn).
+The widest span a chunk writes was 201 to 206 rows on three seeds,
+1.54 to 1.58 x the uniform mean.
 """
 
 from __future__ import annotations
@@ -100,37 +93,40 @@ DEF_CHUNK = 1024  # edges per in-kernel chunk (one matmul each)
 DEF_BLK = 32      # chunks per grid step (keeps per-shard padding small)
 MAX_W = 4         # widest row window: 8*W rows; beyond -> fall back
 
-# ---- Path E (the fully-fused tiled SpMV) geometry ----
-# rg=128 measured 1.5-1.75 ns/edge at 1M×8M on one v5e vs 2.1-2.4 for
-# rg=64 (ws shrinks 168 -> 80: the 8 per-sublane scatter builds cost
-# more than the extra 64 unrolled gather rows save).
-# Scale law: the within-group scatter span grows as R²/(rg·E) rows, so
-# bigger graphs need taller gather windows — 10M×80M plans at rg=512
-# (ws=184; numerics verified on hardware, 1.5e-7) where rg=128
-# overflows; models/pagerank.prepare_device_spmv escalates rg
-# automatically. Costs at rg=512: ~50 s host sort per attempt and
-# ~3 min Mosaic compile (the gather row-loop unrolls rg iterations).
-# VMEM bounds the whole path at ~11M vertices (table + acc ≈ 81 MB).
-SPMV_RG = 128      # gather window rows (vertices / window = rg*128)
-SPMV_WS_CAP = 192  # max scatter window rows before falling back
-SPMV_BLK = 8       # chunks per grid step
-# plan-time VMEM budget: spmv_table compiles with vmem_limit_bytes =
-# 128 MB, but Mosaic also needs scratch for the per-chunk temporaries
-# (the (ws,128) upd accumulator, (128,128) one-hots, select masks), so
-# plans whose RESIDENT footprint passes ~100 MB fail at compile time —
-# after the multi-minute host sorts. plan_spmv rejects them up front
-# (spmv_resident_bytes), so scatter='auto' degrades to the hybrid/XLA
-# sweep instead. ~100 MB ≈ 8 bytes/vertex → the path self-caps at
-# ~12-13M vertices, matching the module docstring's measured bound.
+# ---- the fused tiled SpMV's geometry ----
+# A gather group is ``rg`` rows of the ranks table (rg * 128 vertices);
+# a chunk of 1024 edges reads one group's window and, its destinations
+# sorted by row inside the group, writes a window of ``ws`` rows. The
+# span a chunk writes is R^2 * 1024 / (rg * E) rows where destinations
+# are uniform (R table rows, E edges), so a sparser graph needs taller
+# groups: spmv_geometry picks rg and fixes ws from the sizes alone.
+SPMV_RGS = (128, 256, 512)  # gather window heights tried, in order
+SPMV_RG = SPMV_RGS[0]
+SPMV_WS_CAP = 256  # most scatter window rows (the (ws, 128) update)
+SPMV_BLK = 8       # chunks per grid step; a step is one group's
+SPMV_UNROLL = 64   # most tiles of 8 window rows a turn of the gather
+# loop: the whole loop at rg 512. A turn's tiles overlap in the
+# schedule; a tile alone is a chain of gathers and selects
+SPMV_SEG_STEPS = 4096   # grid steps a kernel call: its scalars (a
+# group a step, a base a chunk: 144 KB) have to fit SMEM's 1 MB, which
+# 278k chunks' bases at SCALE 24 do not (chipless compile, PR 38)
+# A chunk's span over the uniform mean: room for the skew of a
+# Kronecker graph's destinations (SPAN_ROOM x mean + SPAN_SLACK rows,
+# the slack for the base's rounding down to a sublane)
+SPMV_SPAN_ROOM = 1.6
+SPMV_SPAN_SLACK = 16
+# What the kernel keeps in VMEM is the output table, 4 B a vertex (the
+# ranks table stays in HBM and is read a group's window at a time), of
+# the chip's 128 MiB; the rest of SPMV_VMEM_LIMIT is Mosaic's own
+# temporaries. 100 MB is 26M vertices.
 SPMV_VMEM_BUDGET = 100 * 1024 * 1024
+SPMV_VMEM_LIMIT = 120 * 1024 * 1024
 
 
 def _emit_vmem_rejection(n_vertices: int, rg: int) -> None:
-    """Record a VMEM-budget plan rejection AND its remedy: the guard
-    used to just refuse, leaving the caller to discover the ~12M
-    resident ceiling from a docstring. The event (and the CLI's
-    warn-and-degrade built on ``models/pagerank.choose_data_backend``)
-    names the out-of-core engine instead."""
+    """Record a VMEM-budget plan rejection AND its remedy (the CLI's
+    warn-and-degrade built on ``models/pagerank.choose_data_backend``
+    names the out-of-core engine too)."""
     from tpu_distalg.telemetry import events as tevents
 
     tevents.emit(
@@ -140,16 +136,100 @@ def _emit_vmem_rejection(n_vertices: int, rg: int) -> None:
                "blocks stream from disk, only O(V) state in HBM)")
 
 
+def _table_rows(n_vertices: int) -> int:
+    return ((n_vertices + LANES - 1) // LANES + 7) // 8 * 8
+
+
 def spmv_resident_bytes(n_vertices: int, rg: int, ws: int,
                         blk: int = SPMV_BLK) -> int:
-    """Kernel-resident VMEM bytes of an SpMV plan geometry: the ranks
-    table (r8+rg, 128) f32 + the output table (r8+ws, 128) f32 + the 5
-    per-grid-step edge-block operands (blk·8, 128) i32/f32, double-
-    buffered by the grid pipeline."""
-    r8 = ((n_vertices + LANES - 1) // LANES + 7) // 8 * 8
-    tables = (r8 + rg + r8 + ws) * LANES * 4
-    edge_blocks = 2 * 5 * blk * 8 * LANES * 4
-    return tables + edge_blocks
+    """Kernel-resident VMEM bytes of an SpMV geometry: the output table
+    (r8 + ws, 128) f32, and double-buffered by the grid pipeline a
+    group's window of the ranks table (rg, 128) f32 and the 5 edge-block
+    operands (blk * 8, 128) a grid step."""
+    table = (_table_rows(n_vertices) + ws) * LANES * 4
+    return table + 2 * (rg + 5 * blk * 8) * LANES * 4
+
+
+@dataclasses.dataclass(frozen=True)
+class SpMVGeometry:
+    """Every static shape of a fused-SpMV plan, from the sizes alone
+    (vertices, edges held, shards): no seed and no edge moves it, so
+    one executable serves every graph of a size."""
+
+    rg: int          # rows of a gather group (a multiple of 8)
+    n_groups: int    # the ranks table is (n_groups * rg, 128)
+    ws: int          # scatter window rows (a multiple of 8)
+    r8: int          # rows of the vertex table
+    blk: int
+    chunk: int
+    seg_steps: int   # grid steps a kernel call
+    n_steps: int     # grid steps a shard, whole segments
+    n_shards: int
+
+    @property
+    def step_slots(self) -> int:
+        return self.blk * self.chunk
+
+    @property
+    def n_chunks(self) -> int:
+        return self.n_steps * self.n_shards * self.blk
+
+    @property
+    def n_slots(self) -> int:
+        return self.n_chunks * self.chunk
+
+    @property
+    def ranks_form(self) -> str:
+        return "windowed" if self.n_groups > 1 else "resident"
+
+
+def spmv_geometry(n_vertices: int, n_edges: int, n_shards: int = 1,
+                  rg: int | None = None, blk: int = SPMV_BLK,
+                  chunk: int = DEF_CHUNK) -> SpMVGeometry | None:
+    """The plan's geometry for a graph of at most ``n_edges`` edges, or
+    ``None`` past the VMEM budget. ``rg`` is the first of ``SPMV_RGS``
+    whose expected span (``SPMV_SPAN_ROOM`` x the uniform mean) fits
+    ``SPMV_WS_CAP``, the tallest where none does, each raised until
+    the table's last group is nearly full. Slots: the edges, a grid step of padding a
+    group, rounded up to whole segments a shard."""
+    r8 = _table_rows(n_vertices)
+
+    def groups(rg_cap):
+        # tiles of 8 rows a group: the first height from the cap up
+        # that leaves the last group at least 0.85 full (a skinny last
+        # group spreads its few edges over every destination row)
+        tiles, cap = r8 // 8, max(rg_cap // 8, 1)
+        if tiles <= cap:
+            return r8, 1
+
+        def fill(k):
+            return (tiles - (-(-tiles // k) - 1) * k) / k
+
+        heights = range(cap, 2 * cap)
+        k = next((k for k in heights if fill(k) >= 0.85),
+                 max(heights, key=fill))
+        return 8 * k, -(-tiles // k)
+
+    def window(n_groups):
+        mean = r8 * chunk * n_groups / max(n_edges, 1)
+        return (int(SPMV_SPAN_ROOM * mean) + SPMV_SPAN_SLACK + 7) // 8 * 8
+
+    if rg is None:
+        fits = [r for r in SPMV_RGS
+                if window(groups(r)[1]) <= SPMV_WS_CAP]
+        rg = fits[0] if fits else SPMV_RGS[-1]
+    rg, n_groups = groups(rg)
+    ws = min(window(n_groups), SPMV_WS_CAP)
+    if spmv_resident_bytes(n_vertices, rg, ws, blk) > SPMV_VMEM_BUDGET:
+        _emit_vmem_rejection(n_vertices, rg)
+        return None
+    steps = -(-(-(-max(n_edges, 1) // (blk * chunk)) + n_groups)
+              // n_shards)
+    n_segs = -(-steps // SPMV_SEG_STEPS)
+    seg_steps = -(-steps // n_segs)
+    return SpMVGeometry(rg=rg, n_groups=n_groups, ws=ws, r8=r8, blk=blk,
+                        chunk=chunk, seg_steps=seg_steps,
+                        n_steps=n_segs * seg_steps, n_shards=n_shards)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,7 +304,7 @@ def plan_scatter(dst_sorted: np.ndarray, n_vertices: int,
     w = span // 8 + 1
     if w > MAX_W:
         return None
-    r8 = ((n_vertices + LANES - 1) // LANES + 7) // 8 * 8
+    r8 = _table_rows(n_vertices)
     return ScatterPlan(base=base, row=rows, lane=lanes, w=w,
                        chunk=chunk, blk=blk, n_chunks=rows.shape[0],
                        r8=r8, n_pad_edges=e_pad - e,
@@ -261,235 +341,302 @@ def _kernel(base_ref, c_ref, row_ref, lane_ref, acc_ref, *,
 
 @dataclasses.dataclass(frozen=True)
 class SpMVPlan:
-    """Host prep for :func:`spmv_table` — Path E, the fully-fused tiled
-    SpMV (gather AND scatter in one kernel, costed in the module
-    docstring and built in round 5).
+    """A fused-SpMV plan's arrays on the host (:func:`plan_spmv`; the
+    program plans on the device, :func:`sort_slots` and
+    :func:`slot_arrays`, and holds the same seven arrays there).
 
-    Edges are two-key sorted by (gather group, dst) where a gather
-    group is a ``SPMV_RG``-row window of the rank table (``rg·128``
-    vertices): every 1024-edge chunk then reads ranks from ONE window
-    (lane-direction ``dynamic_gather`` + sublane selects — no random
-    access engine) and, because dst is sorted within the group, writes
-    into a narrow scatter window (the same one-hot-MXU scatter as
-    :func:`scatter_table`, built per gather sublane). All per-edge
-    arrays are (NCH·8, 128) lane-major — the (8, 128) chunk layout the
-    lane-gather requires.
-    """
+    Edges are sorted by (gather group, destination row), a group
+    ``geom.rg`` rows of the ranks table, and every group is padded to
+    whole grid steps: a step's chunks read ranks from ONE window
+    (lane-direction ``dynamic_gather`` + sublane selects, no random
+    access engine) and, the destinations sorted inside the group,
+    each chunk writes a narrow scatter window (the one-hot-MXU scatter
+    of :func:`scatter_table`, built per gather sublane). The per-slot
+    arrays are (NCH * 8, 128) lane-major: the (8, 128) chunk layout
+    the lane-gather needs. A slot with no edge has weight 0 and zero
+    indices."""
 
     gbase: np.ndarray     # (NCH,) int32 gather window base row
-    sbase: np.ndarray     # (NCH,) int32 scatter window base row (8-mult)
+    sbase: np.ndarray     # (NCH,) int32 scatter base row (8-mult), or -1
     src_lane: np.ndarray  # (NCH*8, 128) int32  src % 128
     src_row: np.ndarray   # (NCH*8, 128) int32  src//128 - gbase
     dst_row: np.ndarray   # (NCH*8, 128) int32  dst//128 - sbase
     dst_lane: np.ndarray  # (NCH*8, 128) int32  dst % 128
     w_e: np.ndarray       # (NCH*8, 128) f32    inv_deg[src], 0 on pad
-    rg: int               # gather window rows
-    ws: int               # scatter window rows (8-mult)
-    r8: int
-    n_chunks: int
-    chunk: int
-    blk: int
+    geom: SpMVGeometry
     n_pad_edges: int
+
+    rg = property(lambda self: self.geom.rg)
+    ws = property(lambda self: self.geom.ws)
+    r8 = property(lambda self: self.geom.r8)
+    blk = property(lambda self: self.geom.blk)
+    n_chunks = property(lambda self: self.geom.n_chunks)
+
+
+def slot_arrays(xp, src, dst, w_e, geom: SpMVGeometry):
+    """The plan's arrays from its slots in their final order (``src``
+    -1 where a slot holds no edge), as ``xp`` (NumPy on the host,
+    ``jax.numpy`` under jit) array code. Returns the seven arrays of
+    :class:`SpMVPlan` in its order and the widest scatter span of a
+    chunk, which has to fit ``geom.ws``."""
+    shape8 = (geom.n_chunks * 8, LANES)
+    real = (src >= 0).reshape(geom.n_chunks, geom.chunk)
+    srow = (src >> 7).reshape(real.shape)
+    drow = (dst >> 7).reshape(real.shape)
+    # a step's first slot holds an edge unless the whole step is tail
+    first = src[::geom.step_slots]
+    group = xp.where(first >= 0, (first >> 7) // geom.rg,
+                     geom.n_groups - 1)
+    gbase = xp.repeat(group * geom.rg, geom.blk).astype(xp.int32)
+    low = xp.where(real, drow, geom.r8).min(axis=1)
+    live = low < geom.r8
+    sbase = xp.where(live, low // 8 * 8, -1).astype(xp.int32)
+    span = xp.where(real, drow, -1).max(axis=1) - sbase + 1
+    zero = xp.zeros((), xp.int32)
+
+    def held(x):
+        return xp.where(real, x, zero).astype(xp.int32).reshape(shape8)
+
+    return ((gbase, sbase,
+             held((src & 127).reshape(real.shape)),
+             held(srow - gbase[:, None]), held(drow - sbase[:, None]),
+             held((dst & 127).reshape(real.shape)),
+             xp.where(real.reshape(shape8), w_e.reshape(shape8),
+                      xp.zeros((), xp.float32))),
+            xp.where(live, span, 0).max())
 
 
 def plan_spmv(src: np.ndarray, dst: np.ndarray, w_e: np.ndarray,
               n_vertices: int, n_shards: int = 1, chunk: int = DEF_CHUNK,
-              blk: int = SPMV_BLK, rg: int = SPMV_RG) -> SpMVPlan | None:
-    """Two-key sort + per-group chunk padding + window metadata, or
-    ``None`` when a group's within-chunk dst span exceeds
-    ``SPMV_WS_CAP`` rows (very sparse/skewed graphs) or the kernel's
-    resident VMEM footprint would exceed ``SPMV_VMEM_BUDGET`` (vertex
-    tables at V≳12M — checked BEFORE the multi-minute host sorts) —
-    callers fall back to the hybrid or XLA path; correctness never
-    depends on the plan.
-
-    Padding edges replicate a chunk's last (src, dst) with zero weight
-    — inert in both the gather (reads a real window row) and the
-    scatter (adds 0)."""
+              blk: int = SPMV_BLK, rg: int | None = None
+              ) -> SpMVPlan | None:
+    """The plan on the host in NumPy, for per-edge weights of any kind:
+    the tests' way to the kernel (the program plans on the device).
+    ``None`` where a chunk's destinations span more than the geometry's
+    ``ws`` rows or the output table passes ``SPMV_VMEM_BUDGET``."""
     src = np.asarray(src, np.int64)
     dst = np.asarray(dst, np.int64)
-    w_e = np.asarray(w_e, np.float32)
     e = len(src)
-    if e == 0:
+    geom = spmv_geometry(n_vertices, e, n_shards, rg, blk, chunk) \
+        if e else None
+    if geom is None:
         return None
-    # VMEM guard BEFORE the expensive host work: when even the smallest
-    # possible scatter window (ws=8) cannot fit the budget, the Mosaic
-    # compile is guaranteed to fail AFTER the multi-minute sorts — bail
-    # now so scatter='auto' degrades to the hybrid/XLA sweep instead
-    # (ADVICE r5: the tables alone blow the budget at V≳12M).
-    if spmv_resident_bytes(n_vertices, rg, 8, blk) > SPMV_VMEM_BUDGET:
-        _emit_vmem_rejection(n_vertices, rg)
-        return None
-    # groups = EVEN partitions of the table rows (a fixed rg-row stride
-    # would leave a skinny remainder group whose few edges span the
-    # whole dst range — measured 1791-row chunks vs a 137-row p99).
-    # Sizes are capped at rg-7 so the 8-aligned window base still
-    # covers the whole group within rg rows.
-    R = (n_vertices + LANES - 1) // LANES
-    n_groups = max(1, -(-R // max(rg - 7, 1)))
-    sizes = np.full(n_groups, R // n_groups, np.int64)
-    sizes[: R % n_groups] += 1
-    row_group = np.repeat(np.arange(n_groups), sizes)      # (R,)
-    group_start = (np.concatenate([[0], np.cumsum(sizes)])[:-1]
-                   // 8 * 8).astype(np.int32)
-    group = row_group[src // LANES]
-    # two-key sort as two stable LSD counting-sort passes (native C++,
-    # O(E)): ~6x np.lexsort's comparison sort at 8M edges on this host
-    from tpu_distalg import native
+    group = (src // LANES) // geom.rg
+    order = np.lexsort((dst // LANES, group))
+    n_g = np.bincount(group, minlength=geom.n_groups)
+    p_g = -(-n_g // geom.step_slots) * geom.step_slots
+    shift = np.cumsum(p_g) - p_g - (np.cumsum(n_g) - n_g)
+    at = shift[group[order]] + np.arange(e)
 
-    p1 = native.counting_sort_perm(dst, n_vertices)
-    p2 = native.counting_sort_perm(group[p1], n_groups)
-    order = p1[p2]
-    src, dst, w_e, group = (src[order], dst[order], w_e[order],
-                            group[order])
-    # per-group padding to whole chunks (replicated last edge, w=0)
-    parts = []
-    bounds = np.flatnonzero(np.diff(group)) + 1
-    lo = 0
-    for hi in list(bounds) + [e]:
-        n_g = hi - lo
-        pad = (-n_g) % chunk
-        parts.append((lo, hi, pad))
-        lo = hi
-    sp, dp, wp = [], [], []
-    for lo, hi, pad in parts:
-        sp.append(src[lo:hi])
-        dp.append(dst[lo:hi])
-        wp.append(w_e[lo:hi])
-        if pad:
-            sp.append(np.full(pad, src[hi - 1]))
-            dp.append(np.full(pad, dst[hi - 1]))
-            wp.append(np.zeros(pad, np.float32))
-    # inert whole chunks to reach the (blk × shards) grid granularity
-    n_ch = sum(len(x) for x in sp) // chunk
-    gran = blk * n_shards
-    extra = (-n_ch) % gran
-    if extra:
-        sp.append(np.full(extra * chunk, src[e - 1]))
-        dp.append(np.full(extra * chunk, dst[e - 1]))
-        wp.append(np.zeros(extra * chunk, np.float32))
-    src_p = np.concatenate(sp).astype(np.int64)
-    dst_p = np.concatenate(dp).astype(np.int64)
-    w_p = np.concatenate(wp)
-    n_ch += extra
-    if n_ch * chunk > 2 * e + gran * chunk:
-        return None  # padding would dominate — tiny graph
-    srows = (src_p // LANES).astype(np.int32).reshape(n_ch, chunk)
-    drows = (dst_p // LANES).astype(np.int32).reshape(n_ch, chunk)
-    gbase = group_start[row_group[srows[:, 0]]].astype(np.int32)
-    if int((srows.max(axis=1) - gbase).max()) >= rg:
-        return None  # group sizing guarantees this; belt&braces
-    sbase = (drows.min(axis=1) // 8 * 8).astype(np.int32)
-    span = int((drows.max(axis=1) - sbase).max()) + 1
-    ws = (span + 7) // 8 * 8
-    if ws > SPMV_WS_CAP:
+    def slots(x, fill, dtype):
+        out = np.full(geom.n_slots, fill, dtype)
+        out[at] = x[order]
+        return out
+
+    arrays, span = slot_arrays(
+        np, slots(src, -1, np.int32), slots(dst, 0, np.int32),
+        slots(np.asarray(w_e), 0, np.float32), geom)
+    if span > geom.ws:
         return None
-    if spmv_resident_bytes(n_vertices, rg, ws, blk) > SPMV_VMEM_BUDGET:
-        _emit_vmem_rejection(n_vertices, rg)
-        return None  # actual ws confirmed the footprint overflow
-    r8 = ((n_vertices + LANES - 1) // LANES + 7) // 8 * 8
-    shape8 = (n_ch * 8, LANES)
-    return SpMVPlan(
-        gbase=gbase, sbase=sbase,
-        src_lane=(src_p % LANES).astype(np.int32).reshape(shape8),
-        src_row=(srows - gbase[:, None]).reshape(shape8),
-        dst_row=(drows - sbase[:, None]).reshape(shape8),
-        dst_lane=(dst_p % LANES).astype(np.int32).reshape(shape8),
-        w_e=w_p.reshape(shape8), rg=rg, ws=ws, r8=r8, n_chunks=n_ch,
-        chunk=chunk, blk=blk, n_pad_edges=n_ch * chunk - e)
+    return SpMVPlan(*arrays, geom=geom, n_pad_edges=geom.n_slots - e)
 
 
-def _spmv_kernel(gbase_ref, sbase_ref, ranks_ref, slane_ref, srow_ref,
-                 drow_ref, dlane_ref, we_ref, out_ref, *, rg: int,
-                 ws: int, blk: int):
-    """Per chunk: unrolled window-row gather (broadcast row ρ →
-    lane-gather by src_lane → select src_row==ρ), then the one-hot-MXU
-    scatter built per gather sublane (8 small matmuls instead of one
-    wide one — the price of bridging the (8,128) gather layout to the
-    scatter, see the module docstring's Path E costing)."""
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+def sort_slots(src, dst, *, geom: SpMVGeometry, n_in: int):
+    """The device's half of the plan before the layout: ``src`` and
+    ``dst`` int32 of ``geom.n_slots``, ``src`` -1 wherever a slot holds
+    no edge: a duplicate among the first ``n_in``, and every spare slot
+    past them. One key a slot, ``group * (r8 + 1) + row``: the
+    edges by (source group, destination row); as many spare slots
+    after each group as pad it to whole grid steps (row ``r8``: past
+    its last edge); everything else behind the last group. One sort
+    and every slot is where the kernel reads it: nothing is gathered
+    into place. jit it with ``geom`` and ``n_in`` static."""
+    if (geom.n_groups + 1) * (geom.r8 + 1) >= 2 ** 31:
+        raise ValueError("the plan's sort key does not fit int32")
+    stride = geom.r8 + 1
+    real = src[:n_in] >= 0
+    group = jnp.where(real, (src[:n_in] >> 7) // geom.rg, geom.n_groups)
+    n_g = jnp.sum(group[None, :]
+                  == jnp.arange(geom.n_groups, dtype=jnp.int32)[:, None],
+                  axis=1, dtype=jnp.int32)
+    spare = jnp.searchsorted(
+        jnp.cumsum((-n_g) % geom.step_slots),
+        jnp.arange(geom.n_slots - n_in, dtype=jnp.int32), side="right")
+    key = jnp.concatenate([
+        group * stride + jnp.where(real, dst[:n_in] >> 7, 0),
+        spare.astype(jnp.int32) * stride + geom.r8])
+    _, src, dst = jax.lax.sort((key, src, dst), num_keys=1,
+                               is_stable=False)
+    return src, dst
+
+
+def _spmv_kernel(seg_ref, grp_ref, sbase_ref, win_ref, slane_ref,
+                 srow_ref, drow_ref, dlane_ref, we_ref, acc_in, acc_out,
+                 acc, sem, *, rg: int, ws: int, blk: int, unroll: int):
+    """One grid step is ``blk`` chunks of one source group. Per chunk:
+    the gather over the group's window of the ranks table (a rolled
+    loop over tiles of 8 rows: broadcast row rho, lane-gather by
+    ``src_lane``, keep where ``src_row == rho``), then the one-hot-MXU
+    scatter built per gather sublane (8 small matmuls, the price of
+    bridging the (8, 128) gather layout to the scatter).
+
+    ``win_ref`` is the group's ``(rg, 128)`` window of the ranks table,
+    which stays in HBM: its block index is the step's group
+    (``grp_ref``), so the pipeline copies a window in when the group
+    changes and not otherwise. ``acc`` is the whole output table in
+    VMEM, copied in from ``acc_in`` at the first step and out to
+    ``acc_out`` at the last: a sweep is several calls (segments) that
+    hand the table on, each with its own slice of the scalars. A chunk
+    with no edge has ``sbase`` -1 and is skipped."""
+    del seg_ref, grp_ref                      # the index maps read them
+    pid = pl.program_id(0)
+
+    def copy(src, dst):
+        dma = pltpu.make_async_copy(src, dst, sem.at[0])
+        dma.start()
+        dma.wait()
+
+    @pl.when(pid == 0)
+    def _load():
+        copy(acc_in, acc)
 
     sub_iota_ws = jax.lax.broadcasted_iota(jnp.int32, (ws, LANES), 0)
     sub_iota128 = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
-    pid = pl.program_id(0)
 
-    def body(i, _):
-        gi = pid * blk + i
-        gb = gbase_ref[gi]
-        sb = sbase_ref[gi]
-        slane = slane_ref[pl.ds(8 * i, 8), :]
-        srow = srow_ref[pl.ds(8 * i, 8), :]
-        drow = drow_ref[pl.ds(8 * i, 8), :]
-        dlane = dlane_ref[pl.ds(8 * i, 8), :]
-        we = we_ref[pl.ds(8 * i, 8), :]
-        win = ranks_ref[pl.ds(gb, rg), :]               # (rg, 128)
-        g = jnp.zeros((8, LANES), jnp.float32)
-        for rho in range(rg):                           # static unroll
-            rowv = jnp.broadcast_to(win[rho:rho + 1, :], (8, LANES))
-            picked = jnp.take_along_axis(rowv, slane, axis=1)
-            g = g + jnp.where(srow == rho, picked, 0.0)
-        g = g * we
-        upd = jnp.zeros((ws, LANES), jnp.float32)
-        for s in range(8):                              # static unroll
-            cb = jnp.broadcast_to(g[s:s + 1, :], (ws, LANES))
-            m = jnp.where(
-                jnp.broadcast_to(drow[s:s + 1, :], (ws, LANES))
-                == sub_iota_ws, cb, 0.0)
-            onehot_t = (jnp.broadcast_to(dlane[s:s + 1, :],
-                                         (LANES, LANES))
-                        == sub_iota128).astype(jnp.float32)
-            upd += jax.lax.dot_general(
-                m, onehot_t, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.HIGHEST)
-        out_ref[pl.ds(sb, ws), :] += upd
+    def chunk(i, _):
+        sb = sbase_ref[pid * blk + i]
+
+        @pl.when(sb >= 0)
+        def _live():
+            at = pl.ds(pl.multiple_of(8 * i, 8), 8)
+            slane = slane_ref[at, :]
+            srow = srow_ref[at, :]
+            drow = drow_ref[at, :]
+            dlane = dlane_ref[at, :]
+            we = we_ref[at, :]
+            # a slot's row inside a tile of 8, bit by bit: the three
+            # levels of the select tree below (seven selects three
+            # deep, where a chain over the eight rows is eight deep
+            # and the loop's critical path)
+            bits = [(srow & (1 << b)) != 0 for b in range(3)]
+            tile_of = srow >> 3
+
+            def gather_tiles(turn, g):
+                for u in range(unroll):
+                    t = turn * unroll + u
+                    tile = win_ref[pl.ds(pl.multiple_of(8 * t, 8), 8), :]
+                    picked = [jnp.take_along_axis(
+                        jnp.broadcast_to(tile[r:r + 1, :], (8, LANES)),
+                        slane, axis=1) for r in range(8)]
+                    for bit in bits:
+                        picked = [jnp.where(bit, hi, lo) for lo, hi
+                                  in zip(picked[::2], picked[1::2])]
+                    g = jnp.where(tile_of == t, picked[0], g)
+                return g
+
+            g = jax.lax.fori_loop(0, rg // (8 * unroll), gather_tiles,
+                                  jnp.zeros((8, LANES), jnp.float32))
+            g = g * we
+            upd = jnp.zeros((ws, LANES), jnp.float32)
+            for s in range(8):                          # static unroll
+                cb = jnp.broadcast_to(g[s:s + 1, :], (ws, LANES))
+                m = jnp.where(
+                    jnp.broadcast_to(drow[s:s + 1, :], (ws, LANES))
+                    == sub_iota_ws, cb, 0.0)
+                onehot_t = (jnp.broadcast_to(dlane[s:s + 1, :],
+                                             (LANES, LANES))
+                            == sub_iota128).astype(jnp.float32)
+                upd += jax.lax.dot_general(
+                    m, onehot_t, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
+            rows = pl.ds(pl.multiple_of(sb, 8), ws)
+            acc[rows, :] += upd
+
         return 0
 
-    jax.lax.fori_loop(0, blk, body, 0)
+    jax.lax.fori_loop(0, blk, chunk, 0)
+
+    @pl.when(pid == pl.num_programs(0) - 1)
+    def _store():
+        copy(acc, acc_out)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("rg", "ws", "r8", "blk", "interpret"))
-def spmv_table(gbase, sbase, ranks_padded, src_lane, src_row, dst_row,
+                   static_argnames=("rg", "ws", "r8", "blk", "seg_steps",
+                                    "unroll", "interpret"))
+def spmv_table(gbase, sbase, ranks_table, src_lane, src_row, dst_row,
                dst_lane, w_e, *, rg: int, ws: int, r8: int,
-               blk: int = SPMV_BLK, interpret: bool = False):
-    """Per-shard fused SpMV: contributions ``ranks[src]·w_e``
-    scatter-added into a dense (r8 + ws, 128) vertex table in ONE
-    kernel — no XLA random-access op anywhere in the sweep.
+               blk: int = SPMV_BLK, seg_steps: int | None = None,
+               unroll: int = SPMV_UNROLL, interpret: bool = False):
+    """Per-shard fused SpMV: contributions ``ranks[src] * w_e``
+    scatter-added into a dense ``(r8 + ws, 128)`` vertex table, no XLA
+    random-access op anywhere in the sweep.
 
-    ``ranks_padded`` must be (r8 + rg, 128) (``rg`` zero guard rows so
-    the last gather window slices in-bounds). Callers slice the result
+    ``ranks_table`` is ``(n_groups * rg, 128)`` (:func:`spmv_geometry`:
+    the vertex table padded with zero rows to whole groups); a grid
+    step's chunks read one group's window of it. ``gbase`` holds each
+    chunk's window base row (a multiple of ``rg``), ``sbase`` its
+    scatter base row or -1 for a chunk with no edge. The sweep runs as
+    ``n_steps / seg_steps`` calls of the kernel so that a call's
+    scalars fit SMEM; the output table is handed from call to call in
+    HBM and lives in VMEM inside one. Callers slice the result
     ``[:r8]`` and psum across shards."""
-    nch8 = src_lane.shape[0]
-    nch = nch8 // 8
+    nch = src_lane.shape[0] // 8
     if nch % blk:
         raise ValueError(f"n_chunks {nch} must be a multiple of {blk}")
-    if ranks_padded.shape != (r8 + rg, LANES):
+    n_steps = nch // blk
+    seg_steps = seg_steps or n_steps
+    if n_steps % seg_steps:
+        raise ValueError(f"{n_steps} grid steps are not whole segments "
+                         f"of {seg_steps}")
+    if ranks_table.shape[0] % rg or ranks_table.shape[1] != LANES:
         raise ValueError(
-            f"ranks_padded must be ({r8 + rg}, {LANES}), got "
-            f"{ranks_padded.shape}")
-    return pl.pallas_call(
-        functools.partial(_spmv_kernel, rg=rg, ws=ws, blk=blk),
+            f"ranks_table must be (n_groups * {rg}, {LANES}), got "
+            f"{ranks_table.shape}")
+    grp = gbase[::blk] // rg                       # a step's group
+    edge_block = pl.BlockSpec(
+        (blk * 8, LANES),
+        lambda i, seg, grp, sb: (seg[0] * seg_steps + i, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    call = pl.pallas_call(
+        functools.partial(
+            _spmv_kernel, rg=rg, ws=ws, blk=blk,
+            # the largest whole divisor of the window's tiles; the
+            # interpreter gains nothing from a longer body
+            unroll=1 if interpret else max(
+                d for d in range(1, min(unroll, rg // 8) + 1)
+                if (rg // 8) % d == 0)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(nch // blk,),
-            in_specs=[
-                pl.BlockSpec((r8 + rg, LANES), lambda i, s1, s2: (0, 0)),
-            ] + [pl.BlockSpec((blk * 8, LANES),
-                              lambda i, s1, s2: (i, 0))] * 5,
-            out_specs=pl.BlockSpec((r8 + ws, LANES),
-                                   lambda i, s1, s2: (0, 0)),
+            num_scalar_prefetch=3,
+            grid=(seg_steps,),
+            in_specs=[pl.BlockSpec((rg, LANES),
+                                   lambda i, seg, grp, sb: (grp[i], 0))]
+            + [edge_block] * 5 + [hbm],
+            out_specs=hbm,
+            scratch_shapes=[pltpu.VMEM((r8 + ws, LANES), jnp.float32),
+                            pltpu.SemaphoreType.DMA((1,))],
         ),
         out_shape=jax.ShapeDtypeStruct((r8 + ws, LANES), jnp.float32),
+        input_output_aliases={9: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=128 * 1024 * 1024),
+            vmem_limit_bytes=SPMV_VMEM_LIMIT),
         interpret=interpret,
         name="_spmv_kernel",
-    )(gbase, sbase, ranks_padded, src_lane, src_row, dst_row, dst_lane,
-      w_e)
+    )
+
+    def segment(s, acc):
+        seg = jnp.full((1,), s, jnp.int32)
+        return call(
+            seg, jax.lax.dynamic_slice(grp, (s * seg_steps,), (seg_steps,)),
+            jax.lax.dynamic_slice(sbase, (s * seg_steps * blk,),
+                                  (seg_steps * blk,)),
+            ranks_table, src_lane, src_row, dst_row, dst_lane, w_e, acc)
+
+    return jax.lax.fori_loop(
+        0, n_steps // seg_steps, segment,
+        jnp.zeros((r8 + ws, LANES), jnp.float32))
 
 
 @functools.partial(jax.jit,

@@ -844,12 +844,13 @@ TABLE_CLOSURE = register(RuleTable("closure_dense", (
 
 #: PageRank: edge/plan arrays contiguously sharded over data, the
 #: rank vector and degree tables replicated (the sweep's all-reduce
-#: owns rank combination).
+#: owns rank combination), and so the edge slots the device planner
+#: sorts (every chip sorts the whole list and keeps its chunks).
 TABLE_PAGERANK = register(RuleTable("pagerank", (
     (r"^(src|dst|w_e|emask|gbase|sbase|base)$", _P(DATA_AXIS)),
     (r"^(src_lane|src_row|dst_row|dst_lane|row|lane)$",
      _P(DATA_AXIS, None)),
-    (r"^(ranks|inv_deg|has_out)$", _P()),
+    (r"^(ranks|inv_deg|has_out|slots)$", _P()),
 )))
 
 #: cluster-sharded PageRank: the rank vector ROW-PARTITIONED across

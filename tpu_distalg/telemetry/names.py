@@ -24,8 +24,12 @@ SSGD_UPDATE = "tda.ssgd.update"  # the rest of a step: reg, update, eval
 SSGD_GATHER = "tda.ssgd.gather"    # margins (w at each row's slots),
 #                                    labels, validity, residuals
 SSGD_SCATTER = "tda.ssgd.scatter"  # the residuals added up slot by slot
-# the fused SpMV sweep (models/pagerank.py)
-PAGERANK_SPMV = "tda.pagerank.spmv"
+# the two parts of a fused PageRank sweep (models/pagerank.py); the
+# benchmark's spmv_ms_per_sweep.graph and pagerank_spmv_roofline read
+# the first
+PAGERANK_SPMV = "tda.pagerank.spmv"      # the ranks' table and the kernel
+PAGERANK_UPDATE = "tda.pagerank.update"  # the table back to a vector,
+#                                          dangling mass, teleport
 # the parts of a Lloyd iteration (models/kmeans.py)
 KMEANS_ASSIGN = "tda.kmeans.assign"  # distances and argmin; on the lanes
 #                                      layout the one kernel that also

@@ -179,12 +179,21 @@ def summarize(evts: list[dict]) -> dict:
     pass_forms: dict[str, list[str]] = {"gather": [], "scatter": []}
     field_splits: list[tuple] = []
     als_forms: list[str] = []
+    ranks_forms: list[str] = []
     t_wall = [e["t_wall"] for e in evts if "t_wall" in e]
     for e in evts:
         ev = e.get("ev")
         run = e.get("run")
         if run and run not in runs:
             runs.append(run)
+        if ev in ("span_start", "span_end") and e.get("ranks_form"):
+            # PageRank's fused sweep says how it reads the ranks table
+            # (one gather group: resident; more: a group's window at a
+            # time) and its windows (pagerank:prepare, train:segment)
+            form = (f"{e['ranks_form']} (rg {e.get('rg', '?')}, ws "
+                    f"{e.get('ws', '?')})")
+            if form not in ranks_forms:
+                ranks_forms.append(form)
         if ev == "span_start":
             open_spans[e.get("name", "?")] = \
                 open_spans.get(e.get("name", "?"), 0) + 1
@@ -313,6 +322,7 @@ def summarize(evts: list[dict]) -> dict:
         "pass_forms": pass_forms,
         "field_splits": field_splits,
         "als_forms": als_forms,
+        "ranks_forms": ranks_forms,
         "unfinished_phases": sorted(
             k for k, v in open_spans.items() if v > 0),
         "marks": marks,
@@ -400,6 +410,8 @@ def render(s: dict) -> str:
                      f"by address: {n_addr}")
     if s.get("als_forms"):
         lines.append(f"R layout: {', '.join(s['als_forms'])}")
+    if s.get("ranks_forms"):
+        lines.append(f"ranks table: {', '.join(s['ranks_forms'])}")
     if s.get("dist_forms"):
         lines.append(f"distances: {', '.join(s['dist_forms'])}")
     if s.get("sums_forms"):
@@ -702,6 +714,7 @@ SUMMARY_ONLY_COUNTERS = (
     "serve.failed_batches",
     "serve.merge_bytes_wire",
     "spmv_plan_rejections",
+    "spmv_slots_padded",        # pagerank:prepare's padding_share says it
     "reshard.bytes_logical",    # the reshard line renders wire/host;
     #                             logical is accounting input only
 )

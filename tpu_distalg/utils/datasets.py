@@ -450,6 +450,53 @@ def erdos_renyi_edges(
     return np.stack([src, dst], axis=1)
 
 
+GRAPH500_ABCD = (0.57, 0.19, 0.19, 0.05)
+
+
+def kronecker_edges(scale: int, abcd=GRAPH500_ABCD):
+    """Graph500's Kronecker (R-MAT) generator as a function of the edge
+    id: ``f(ids uint32 array, seed uint32) -> (src, dst)`` int32 (jax),
+    so that any slice of the list is drawn on the device with the seed
+    as an argument and nothing crosses to the host. An edge picks one
+    quadrant of the adjacency matrix at each of ``scale`` bit levels
+    with probabilities (A, B, C, D), each level from its own 32-bit
+    hash of (seed, level, id) (:func:`_mix32`: the thresholds are the
+    probabilities times 2^32); the quadrant gives one bit of the source
+    and one of the destination. The vertex labels are then permuted
+    (:func:`feistel_permutation`, keyed by the seed). Edges are
+    directed as drawn, duplicates and self-loops included."""
+    import jax
+    import jax.numpy as jnp
+
+    u = np.uint32
+    a, b, c, _ = (float(x) for x in abcd)
+    t_a, t_ab, t_abc = (u(min(int(p * 2 ** 32), 2 ** 32 - 1))
+                        for p in (a, a + b, a + b + c))
+    relabel, _ = feistel_permutation(1 << scale)
+
+    def draw(ids, seed):
+        ids = jnp.asarray(ids, jnp.uint32) * u(0x9E3779B1)
+        seed = jnp.asarray(seed, jnp.uint32)
+
+        def level(lv, bits):
+            src, dst = bits
+            key = _mix32(seed + (lv.astype(jnp.uint32) + u(1))
+                         * u(0x85EBCA6B))
+            h = _mix32(ids + key)
+            down = h >= t_ab                      # quadrants C and D
+            right = ((h >= t_a) & ~down) | (h >= t_abc)      # B and D
+            return (src * u(2) + down.astype(jnp.uint32),
+                    dst * u(2) + right.astype(jnp.uint32))
+
+        zero = jnp.zeros(ids.shape, jnp.uint32)
+        src, dst = jax.lax.fori_loop(0, scale, level, (zero, zero))
+        key = _mix32(seed ^ u(0x68E31DA4))
+        return (relabel(src, key).astype(jnp.int32),
+                relabel(dst, key).astype(jnp.int32))
+
+    return draw
+
+
 def chain_forest_edges(n_vertices: int, chain_len: int = 8) -> np.ndarray:
     """Disjoint directed chains — a bounded-closure benchmark graph (the
     closure of an ER graph in the supercritical regime is Θ(V²) pairs, an
