@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from tpu_distalg.models import kmeans
+from tpu_distalg.ops import bf16_pieces
 from tpu_distalg.ops import pallas_lloyd as lloyd
 from tpu_distalg.parallel import build_sharded
 from tpu_distalg.telemetry import events, names, report
@@ -382,7 +383,7 @@ def _full_significands(rng, shape):
 def test_pieces_are_bfloat16_and_add_back_bit_for_bit():
     x = _full_significands(np.random.default_rng(1), (8, 128))
     x[0, :2] = 0.0, 1.0
-    hi, mid, lo = map(np.asarray, jax.jit(lloyd.split3)(jnp.asarray(x)))
+    hi, mid, lo = map(np.asarray, jax.jit(bf16_pieces.split3)(jnp.asarray(x)))
     for piece in (hi, mid, lo):
         assert not (piece.view(np.uint32) & 0xFFFF).any()
         rounded = jnp.asarray(piece).astype(jnp.bfloat16)

@@ -273,7 +273,7 @@ def test_cli_rmat_end_to_end_with_its_report(tmp_path, capsys):
     for name in ("pagerank:generate", "pagerank:dedup",
                  "pagerank:prepare", "pagerank:plan", "train:segment"):
         assert name in text, name
-    assert "ranks table: resident (rg 8, ws 16)" in text
+    assert "ranks table: resident (rg 8, ws 16), scatter passes 3" in text
     evts = report.load_events(tel)
     prepare = [e for e in evts if e.get("ev") == "span_end"
                and e["name"] == "pagerank:prepare"][0]
@@ -283,4 +283,4 @@ def test_cli_rmat_end_to_end_with_its_report(tmp_path, capsys):
     seg = [e for e in evts if e.get("ev") == "span_start"
            and e["name"] == "train:segment"]
     assert seg and all(e["ranks_form"] == "resident" and e["rg"] == 8
-                       for e in seg)
+                       and e["scatter_passes"] == 3 for e in seg)

@@ -2,12 +2,16 @@
 standard-mode PageRank sweep's scatter half. Interpret mode on the CPU
 mesh; the kernel path proper compiles on the chip in chip_smoke.py."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from tpu_distalg.models import pagerank
+from tpu_distalg.ops import bf16_pieces
 from tpu_distalg.ops import graph as gops
 from tpu_distalg.ops import pallas_pagerank as ppr
 
@@ -94,18 +98,161 @@ def test_spmv_plan_and_kernel_match_numpy():
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
 
 
-def _spmv(plan, ranks, v, seg_steps=None):
-    """One interpreted sweep of a host plan over a ranks vector."""
-    g = plan.geom
+def _spmv(plan, ranks, v, seg_steps=None, geom=None):
+    """One interpreted sweep of a host plan over a ranks vector: a call
+    a shard on its slice of the chunks (as ``shard_map`` hands them
+    out), the tables added as the psum adds them. ``geom`` stands in
+    for the plan's own (a wider scatter window over the same slots)."""
+    g = geom or plan.geom
     rt = np.zeros((g.n_groups * g.rg, 128), np.float32)
     rt.reshape(-1)[:v] = ranks
-    out = ppr.spmv_table(
-        *(jnp.asarray(a) for a in (
-            plan.gbase, plan.sbase, rt, plan.src_lane, plan.src_row,
-            plan.dst_row, plan.dst_lane, plan.w_e)),
-        rg=g.rg, ws=g.ws, r8=g.r8, blk=g.blk,
-        seg_steps=seg_steps or g.seg_steps, interpret=True)
-    return np.asarray(out)[:g.r8].reshape(-1)[:v]
+    per = g.n_steps * g.blk                     # chunks a shard
+    total = np.zeros((g.r8 + g.ws, 128), np.float32)
+    for k in range(g.n_shards):
+        chunks = slice(k * per, (k + 1) * per)
+        slots = slice(8 * k * per, 8 * (k + 1) * per)
+        total += np.asarray(ppr.spmv_table(
+            jnp.asarray(plan.gbase[chunks]), jnp.asarray(plan.sbase[chunks]),
+            jnp.asarray(rt), *(jnp.asarray(a[slots]) for a in (
+                plan.src_lane, plan.src_row, plan.dst_row, plan.dst_lane,
+                plan.w_e)),
+            rg=g.rg, ws=g.ws, r8=g.r8, blk=g.blk,
+            seg_steps=seg_steps or g.seg_steps, interpret=True))
+    return total[:g.r8].reshape(-1)[:v]
+
+
+# a scatter window's rows -> the gather group's rows and the edges that
+# give a chunk about half that span over V vertices, every one with at
+# most one in-edge (the span is rows x 1024 x groups / edges)
+V = 32768
+WINDOWS = {24: (256, V), 72: (128, V // 2), 224: (64, V // 4)}
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("ws", sorted(WINDOWS))
+def test_scatter_product_keeps_every_bit(ws, n_shards, monkeypatch):
+    """The product that ships, interpreted: three single-pass products
+    of the contribution's three exact pieces against the one-hot.
+    Ranks a third and 2^-20 apart, weights one over odd degrees: every
+    contribution has a full 24-bit significand. (a) Where every vertex
+    has at most one in-edge the table IS the contributions, bit for
+    bit; (b) with all edges on eight vertices (a star) a cell is
+    NumPy's float32 sum of its thousand terms within 2^-22 of their sum
+    (read: up to 1.3 x 2^-23, NumPy's own sum 0.75 x 2^-23 off the
+    float64 one; two pieces would miss by 2^-17); (c) the control, the
+    same helper fed two pieces (hi, mid: the hi/lo split the benchmark's
+    configuration forbids), fails (a)."""
+    rg, e = WINDOWS[ws]
+    rng = np.random.default_rng(ws)
+    src = rng.integers(0, V, size=e)
+    w_e = (1.0 / (2 * rng.integers(1, 2000, size=e) + 1)).astype(np.float32)
+    ranks = (1 / 3 + np.arange(V) * 2.0 ** -20).astype(np.float32)
+    terms = ranks[src] * w_e
+    assert (terms.view(np.uint32) & 0xFF).astype(bool).mean() > 0.98
+
+    def sweep(dst):
+        plan = ppr.plan_spmv(src, dst, w_e, V, n_shards=n_shards, rg=rg)
+        assert plan is not None and int(plan.dst_row.max()) < ws
+        assert plan.geom.n_groups == 256 // rg
+        geom = dataclasses.replace(plan.geom, ws=ws)
+        return _spmv(plan, ranks, V, geom=geom)
+
+    once = rng.permutation(V)[:e]
+    want = np.zeros(V, np.float32)
+    want[once] = terms
+    np.testing.assert_array_equal(sweep(once).view(np.uint32),
+                                  want.view(np.uint32))
+
+    hubs = 128 * rng.integers(0, 2, size=8) + rng.permutation(128)[:8]
+    star = hubs[rng.integers(0, 8, size=e)]
+    got = sweep(star)
+    assert not got[np.setdiff1d(np.arange(V), hubs)].any()
+    for hub in hubs:
+        mine = terms[star == hub]
+        assert len(mine) > 500
+        assert abs(got[hub] - np.sum(mine, dtype=np.float32)) \
+            <= 2.0 ** -22 * mine.sum(dtype=np.float64)
+
+    real = ppr.split3
+    monkeypatch.setattr(ppr, "split3", lambda x: real(x)[:2])
+    jax.clear_caches()
+    try:
+        short = sweep(once)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    lost = short.view(np.uint32) != want.view(np.uint32)
+    assert lost.sum() > 0.9 * e
+    # what two pieces drop: up to 2^-16 of a contribution, never more
+    assert np.abs(short - want).max() <= 2.0 ** -16 * want.max()
+
+
+@pytest.mark.parametrize("kernel", ["spmv", "hybrid"])
+def test_scatter_passes_is_what_the_kernels_pass(kernel, monkeypatch):
+    """The spans' ``scatter_passes`` tag (``SCATTER_PASSES``) is the
+    number of pieces each kernel hands ``scatter_window``: one bf16 pass
+    a piece."""
+    seen = []
+    real = ppr.scatter_window
+
+    def counting(pieces, *args):
+        seen.append(len(pieces))
+        return real(pieces, *args)
+
+    monkeypatch.setattr(ppr, "scatter_window", counting)
+    jax.clear_caches()
+    rng = np.random.default_rng(5)
+    v, e = 2048, 8192
+    dst = np.sort(rng.integers(0, v, size=e).astype(np.int32))
+    try:
+        if kernel == "spmv":
+            plan = ppr.plan_spmv(rng.integers(0, v, size=e), dst,
+                                 np.ones(e, np.float32), v)
+            _spmv(plan, np.ones(v, np.float32), v)
+        else:
+            plan = ppr.plan_scatter(dst, v, chunk=128, blk=4)
+            ppr.scatter_table(
+                jnp.asarray(plan.base), jnp.ones(plan.row.shape),
+                jnp.asarray(plan.row), jnp.asarray(plan.lane),
+                w=plan.w, r8=plan.r8, blk=plan.blk, interpret=True)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert seen and set(seen) == {ppr.SCATTER_PASSES}
+
+
+@pytest.mark.parametrize("what,lo,hi", [
+    ("denormals and the smallest normals", -149, -102),
+    ("one over a degree", -17, 0),        # w_e: 1 to 1 / 97 455
+    ("rank x weight", -38, -10)])         # the Graph500 cell's range
+def test_split3_adds_back_bit_for_bit(what, lo, hi):
+    """Every piece is a bfloat16 (low half zero), and hi + mid + lo is
+    the float32 again, bit for bit, in any order of float32 additions
+    (the MXU's is its own): over ``w_e``'s range and the contributions'
+    (the largest rank x weight of SCALE 24 is 12 050 / 2^24 x 1). Under
+    2^-102 a piece can be a denormal, which XLA:CPU and the TPU flush:
+    the pieces then fall short of x by less than 2^-126 and never pass
+    it (``Precision.HIGHEST`` splits the same way)."""
+    rng = np.random.default_rng(hi - lo)
+    mant = rng.integers(1, 1 << 24, size=(8, 128)) | 1
+    x = np.ldexp(mant.astype(np.float64) / (1 << 23),
+                 rng.integers(lo, hi, size=(8, 128))).astype(np.float32)
+    x[0, :4] = 0.0, 2.0 ** lo, 2.0 ** (hi - 1), 12050 / 2.0 ** 24
+    pieces = [np.asarray(p) for p in
+              jax.jit(bf16_pieces.split3)(jnp.asarray(x))]
+    for piece in pieces:
+        assert not (piece.view(np.uint32) & 0xFFFF).any()
+    back = sum(p.astype(np.float64) for p in pieces)   # exact in float64
+    if hi <= -102:
+        assert ((0 <= x - back) & (x - back < 2.0 ** -126)).all()
+        return
+    np.testing.assert_array_equal(
+        back.astype(np.float32).view(np.uint32), x.view(np.uint32))
+    first, mid, last = pieces
+    for a, b, c in ((first, mid, last), (last, mid, first),
+                    (first, last, mid)):
+        np.testing.assert_array_equal(
+            ((a + b) + c).view(np.uint32), x.view(np.uint32))
 
 
 def test_windowed_ranks_equal_the_resident_form_bit_for_bit():

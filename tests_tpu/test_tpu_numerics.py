@@ -326,9 +326,10 @@ def test_flash_backward_matches_xla_backward_on_tpu(tpu_mesh):
 
 
 def test_pagerank_pallas_scatter_matches_xla_on_tpu(tpu_mesh):
-    """Round-4 Pallas scatter on hardware: the HIGHEST-precision
-    one-hot matmul keeps standard-mode ranks within f32 noise of the
-    XLA segment_sum sweep."""
+    """Round-4 Pallas scatter on hardware: the one-hot product (three
+    exact bf16 pieces since PR 39, ``scatter_window``) keeps
+    standard-mode ranks within f32 noise of the XLA segment_sum
+    sweep."""
     import numpy as np
 
     from tpu_distalg.models import pagerank
@@ -378,6 +379,95 @@ def test_pagerank_spmv_matches_xla_on_tpu(tpu_mesh):
     rel = (np.abs(outs["spmv"] - outs["xla"]).max()
            / outs["xla"].max())
     assert rel < 1e-5, f"spmv-vs-xla ranks rel err {rel}"
+
+
+def _scatter_products(c, row, lane, ws, pieces=3, interpret=False):
+    """One small Pallas kernel, two windows of the same operands: the
+    shipped one-hot scatter product (``scatter_window`` over the first
+    ``pieces`` of ``split3``) and the ``Precision.HIGHEST`` product it
+    replaced in PR 39 (six bf16 passes of the whole float32 operand)."""
+    from jax.experimental import pallas as pl
+
+    from tpu_distalg.ops import pallas_pagerank as ppr
+
+    def kernel(c_ref, row_ref, lane_ref, new_ref, old_ref):
+        c, row, lane = c_ref[...], row_ref[...], lane_ref[...]
+        kept = ppr.split3(c)[:pieces]
+        new_ref[...] = ppr.scatter_window(kept, row, lane, ws)
+        row_iota = jax.lax.broadcasted_iota(jnp.int32, (ws, 128), 0)
+        lane_iota = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+        old = jnp.zeros((ws, 128), jnp.float32)
+        for s in range(8):
+            m = jnp.where(
+                jnp.broadcast_to(row[s:s + 1, :], (ws, 128)) == row_iota,
+                jnp.broadcast_to(c[s:s + 1, :], (ws, 128)), 0.0)
+            onehot_t = (jnp.broadcast_to(lane[s:s + 1, :], (128, 128))
+                        == lane_iota).astype(jnp.float32)
+            old += jax.lax.dot_general(
+                m, onehot_t, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
+        old_ref[...] = old
+
+    window = jax.ShapeDtypeStruct((ws, 128), jnp.float32)
+    new, old = pl.pallas_call(kernel, out_shape=[window, window],
+                              interpret=interpret)(
+        jnp.asarray(c), jnp.asarray(row), jnp.asarray(lane))
+    return np.asarray(new), np.asarray(old)
+
+
+def _full_mantissas(rng, shape):
+    """Positive float32 with all 24 significand bits in use (the last
+    one set), over the ten binades a rank x weight of the Graph500 cell
+    spans."""
+    mant = rng.integers(1 << 23, 1 << 24, size=shape) | 1
+    return (mant * 2.0 ** rng.integers(-56, -46, size=shape)
+            ).astype(np.float32)
+
+
+@pytest.mark.parametrize("ws", [24, 72, 224])
+def test_pagerank_scatter_product_is_float32_on_tpu(ws):
+    """PR 39's product on the MXU itself: three single bf16 passes of
+    the contribution's three exact pieces against the one-hot. (a) With
+    every window cell written by at most one slot the window holds each
+    contribution bit for bit, and so does the ``HIGHEST`` product; (b)
+    with 1024 slots on eight cells (a star) the two agree within the
+    reordering of one float32 sum, 2^-22 of the sum a cell; (c) the
+    control, the same helper fed two pieces (hi, mid: a hi/lo split,
+    ``Precision.HIGH``), fails (a): the test can tell the split the
+    configuration forbids from this one."""
+    rng = np.random.default_rng(ws)
+    c = _full_mantissas(rng, (8, 128))
+    cells = rng.permutation(ws * 128)[:1024].reshape(8, 128)
+    row, lane = (cells // 128).astype(np.int32), (cells % 128).astype(
+        np.int32)
+    want = np.zeros((ws, 128), np.float32)
+    want[row, lane] = c
+    new, old = _scatter_products(c, row, lane, ws)
+    np.testing.assert_array_equal(new.view(np.uint32),
+                                  want.view(np.uint32))
+    np.testing.assert_array_equal(old.view(np.uint32),
+                                  want.view(np.uint32))
+    two, _ = _scatter_products(c, row, lane, ws, pieces=2)
+    short = int((two.view(np.uint32) != want.view(np.uint32)).sum())
+    assert short > 900, short
+    # (b) a star: every slot on one of eight cells
+    hubs = rng.permutation(ws * 128)[:8]
+    cells = hubs[rng.integers(0, 8, size=(8, 128))]
+    row, lane = (cells // 128).astype(np.int32), (cells % 128).astype(
+        np.int32)
+    new, old = _scatter_products(c, row, lane, ws)
+    total = np.zeros((ws, 128), np.float64)
+    np.add.at(total, (row, lane), c.astype(np.float64))
+    assert (new[total == 0] == 0).all() and (old[total == 0] == 0).all()
+    bound = 2.0 ** -22 * total
+    assert (np.abs(new - old) <= bound).all()
+    assert (np.abs(new - total) <= bound).all()
+    print(f"[scatter product] ws {ws}: star cells off the float64 sum by "
+          f"{np.abs(new - total)[total > 0].max() / total.max():.3g} "
+          f"(shipped), {np.abs(old - total)[total > 0].max() / total.max():.3g}"
+          f" (HIGHEST) of the largest; two pieces left {short} of 1024 "
+          f"single cells short")
 
 
 def test_streamed_ssgd_bitwise_on_tpu(tpu_mesh, cancer_data):

@@ -379,6 +379,7 @@ def prepare_device_spmv(graph: gops.EdgeList | DeviceGraph, mesh: Mesh,
         sp.update(vertices=graph.n_vertices, distinct=graph.n_edges,
                   generated=graph.n_in, rg=geom.rg, ws=geom.ws,
                   chunks=geom.n_chunks, ranks_form=geom.ranks_form,
+                  scatter_passes=ppr.SCATTER_PASSES,
                   padding_share=geom.n_slots / max(graph.n_edges, 1))
         sort, lay_out = plan_programs(mesh, geom, graph.n_in)
         with tevents.span("pagerank:plan", rg=geom.rg, ws=geom.ws):
@@ -830,6 +831,7 @@ def _run_segmented(de: DeviceEdges, mesh: Mesh, config: PageRankConfig,
     (``graph_computation/pagerank.py:52-57``)."""
     import dataclasses as dc
 
+    from tpu_distalg.ops import pallas_pagerank as ppr
     from tpu_distalg.utils import checkpoint as ckpt
 
     V = de.n_vertices
@@ -859,7 +861,8 @@ def _run_segmented(de: DeviceEdges, mesh: Mesh, config: PageRankConfig,
         # alone cannot catch a cross-mode resume — encode the mode
         tag=f"pagerank_{config.mode}",
         span_fields=(dict(ranks_form=de.spmv.ranks_form, rg=de.spmv.rg,
-                          ws=de.spmv.ws)
+                          ws=de.spmv.ws,
+                          scatter_passes=ppr.SCATTER_PASSES)
                      if de.spmv is not None and config.mode == "standard"
                      and config.scatter in ("auto", "spmv") else None))
     return PageRankResult(ranks=jnp.asarray(state["ranks"]),

@@ -97,6 +97,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpu_distalg.ops.bf16_pieces import pieces as _pieces
+
 LANES = 128
 SUBLANES = 8
 # the kernel unrolls k * dim multiply-adds, and holds as many (8, 128)
@@ -104,7 +106,6 @@ SUBLANES = 8
 MAX_UNROLL = 1024
 BLOCK_BYTES = 6 << 20      # a block's share of VMEM; two are in flight
 MXU_MIN_WORK = 144         # k * dim from which the sums take the MXU
-_TOP = 0xFFFF0000          # the half of a float32 that is a bfloat16
 _ONE_HI = 0x3F800000       # 1.0 as that half
 _ONE_LO = 0x00003F80       # 1.0 as the other
 
@@ -172,29 +173,6 @@ def sums_form(k: int, dim: int) -> str:
     """Where a pass adds up the per-cluster sums at this geometry: a
     tag for the spans of the scale path (``tda report`` prints it)."""
     return "mxu" if sums_on_mxu(k, dim) else "vpu"
-
-
-def _bits(x):
-    return pltpu.bitcast(x, jnp.uint32)
-
-
-def _f32(u):
-    return pltpu.bitcast(u, jnp.float32)
-
-
-def _pieces(x):
-    """The bits of ``hi``, of ``r = x - hi`` (whose top half is ``mid``)
-    and of ``lo = r - mid``: every step exact."""
-    hi = _bits(x) & jnp.uint32(_TOP)
-    r = _bits(x - _f32(hi))
-    return hi, r, _bits(_f32(r) - _f32(r & jnp.uint32(_TOP)))
-
-
-def split3(x):
-    """``x`` as three float32 pieces, each exact in bfloat16 (its low
-    16 bits are zero), that add back to ``x`` bit for bit."""
-    hi, r, lo = _pieces(x)
-    return _f32(hi), _f32(r & jnp.uint32(_TOP)), _f32(lo)
 
 
 def _pair(low, high):

@@ -8,7 +8,7 @@ that to one random gather (``ranks[src]``) plus one sorted
 ``segment_sum`` per edge per sweep, each bound by the issue rate of a
 random-access XLA op and not by bandwidth (the benchmark's reference,
 which is those two ops, takes 4.8 s a sweep of 263M edges on one v5e
-where a fused sweep takes 0.75 s: PERF.md, PR 38). The kernels here
+where a fused sweep takes 0.52 s: PERF.md, PRs 38 and 39). The kernels here
 touch no random-access engine.
 
 The scatter (:func:`scatter_table`, the hybrid sweep's half)
@@ -24,11 +24,18 @@ from lane-major loads (no relayouts):
   * ``m[rho, e]   = contrib[e] * (row[e] == base + rho)``   (8W, 1024)
   * ``onehot_t[l, e] = (lane[e] == l)``                     (128, 1024)
 
-and one MXU matmul ``m @ onehot_t.T`` scatter-adds the whole chunk into
-the resident window ``acc[base : base+8W]``. The matmul runs
-``precision=HIGHEST`` (six bf16 passes) because one operand carries
-real f32 contributions: DEFAULT truncates to bf16 and costs ~1e-3
-relative error in rank sums.
+and the MXU product ``m @ onehot_t.T`` scatter-adds the whole chunk
+into the resident window ``acc[base : base+8W]``. One operand carries
+real f32 contributions, which a single pass at the default precision
+truncates to bf16 (~1e-3 relative error in rank sums); the other is 0
+or 1, which bf16 holds exactly. So the product is three single passes
+(:func:`scatter_window`): the contributions split once into three
+pieces that bf16 holds exactly and that add back bit for bit
+(``ops/bf16_pieces.split3``: all 24 bits), each piece masked and
+multiplied by the one-hot, the three summed in f32. Every product is a
+piece times 0 or 1, so nothing is rounded before the f32 sum.
+``precision=HIGHEST``, the form until PR 39, splits both operands and
+takes six passes, three of them against the one-hot's zero pieces.
 
 What does not work, kept so that nobody walks it again: Mosaic's
 sublane ``dynamic_gather`` is vreg-local (it gathers only within one
@@ -50,9 +57,9 @@ every edge of a chunk whose source is in that row. Edges are sorted by
 chunk then reads ranks from ONE window of ``rg`` rows (a lane-gather
 and a three-level select tree a tile of 8 rows) and, the destinations
 sorted inside the group, writes a scatter window of ``ws`` rows: the
-one-hot matmul above, built per gather sublane (8 matmuls of
-(ws, 128) x (128, 128): the gather chunk is (8, 128), the matmul wants
-the edge dimension along lanes).
+one-hot product above, built per gather sublane (8 sublanes x 3 pieces
+of (ws, 128) x (128, 128): the gather chunk is (8, 128), the matmul
+wants the edge dimension along lanes).
 
 Since PR 38 the ranks table stays in HBM and a group's window is the
 block the pipeline copies in when the group changes; only the output
@@ -62,16 +69,23 @@ table (4 B a vertex) is kept in VMEM, which is what bounds the path
 function of the sizes (:func:`spmv_geometry`), and a sweep is a few
 kernel calls so that each call's per-chunk scalars fit SMEM.
 
-Measured on one v5e (PR 38, ``scripts/step0_pagerank_resident.py``;
-PERF.md section 6 has the table): at Graph500 SCALE 24 (rg 512, ws
-224, 270.6M slots) a sweep takes 752.9 ms, 2.78 ns a slot, with the
-gather loop whole in one turn, and 828.7 / 920.8 / 1095.2 / 2131.7 ms
-at 16 / 8 / 4 / 1 tiles a turn; at SCALE 20 (rg 128, ws 72) 0.94 ns a
-slot whole and 2.12 rolled. The static schedule of a chipless compile
-says why: a chunk is 4333 bundles (at 1.5 GHz, 264 240 chunks: 0.76 s),
-2895 of them the scatter's eight HIGHEST matmuls and 1438 the 512
-gathered rows; rolled to one tile a turn the loop alone is 9152 (the
-selects of a tile are a chain, and tiles overlap only inside a turn).
+Measured on one v5e (PERF.md section 6 has the tables). PR 38,
+``scripts/step0_pagerank_resident.py``, the scatter still ``HIGHEST``:
+at Graph500 SCALE 24 (rg 512, ws 224, 270.6M slots) a sweep took 752.9
+ms with the gather loop whole in one turn, and 828.7 / 920.8 / 1095.2 /
+2131.7 ms at 16 / 8 / 4 / 1 tiles a turn (the selects of a tile are a
+chain, and tiles overlap only inside a turn). The static schedule of a
+chipless compile said why: a chunk was 4333 bundles (at 1.5 GHz,
+264 240 chunks: 0.76 s), 2895 of them the scatter's eight ``HIGHEST``
+matmuls and 1438 the 512 gathered rows. PR 39,
+``scripts/step0_pagerank_scatter.py``: with three passes a chunk is
+3062 bundles, 1641 the scatter and 1421 the gather, and a sweep takes
+521.9 ms, 1.93 ns a slot (754.4 the six passes in the same run); at
+SCALE 20 (rg 128, ws 72) 12.2 ms, 0.70 ns a slot (16.9, 0.98). Each of
+the four MXUs streams a row a cycle and pops a row a cycle, so 224
+rows x 3 pieces x 8 sublanes are 1344 cycles a chunk however the
+pieces are stacked (along the rows, along the contraction, as bf16:
+521.6 to 523.5 ms, the v5e's MXU does not add across a contraction).
 The widest span a chunk writes was 201 to 206 rows on three seeds,
 1.54 to 1.58 x the uniform mean.
 """
@@ -87,9 +101,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpu_distalg.ops.bf16_pieces import split3
+
 
 LANES = 128
-DEF_CHUNK = 1024  # edges per in-kernel chunk (one matmul each)
+DEF_CHUNK = 1024  # edges per in-kernel chunk
+SCATTER_PASSES = 3  # bf16 MXU passes of the one-hot scatter product:
+# one a piece of split3 (the spans' scatter_passes; HIGHEST took six;
+# tests/test_pallas_pagerank.py holds it to what the kernels pass)
 DEF_BLK = 32      # chunks per grid step (keeps per-shard padding small)
 MAX_W = 4         # widest row window: 8*W rows; beyond -> fall back
 
@@ -311,14 +330,49 @@ def plan_scatter(dst_sorted: np.ndarray, n_vertices: int,
                        shard_len=shard_len, real_per_shard=tuple(real))
 
 
+def scatter_window(pieces, row, lane, n_rows: int):
+    """The one-hot scatter of a chunk's slots, on the MXU: the
+    ``(n_rows, 128)`` float32 window in which slot ``(s, e)`` adds the
+    sum of its ``pieces`` at row ``row[s, e]``, lane ``lane[s, e]`` (a
+    slot whose row is outside the window adds nothing).
+
+    ``pieces`` are ``(S, E)`` float32 arrays that bfloat16 holds exactly
+    (:func:`split3` of the contributions: all 24 bits of each), ``row``
+    and ``lane`` ``(S, E)`` int32. Per sublane ``s`` the slots' rows
+    make a 0/1 mask under each piece and their lanes a 0/1 one-hot,
+    which bfloat16 holds exactly too, so ONE bfloat16 pass a piece
+    (``SCATTER_PASSES``) multiplies every piece by 0 or 1 without
+    rounding and adds in float32: a window cell that one slot writes
+    holds that slot's contribution bit for bit, one that several write
+    their float32 sum. ``Precision.HIGHEST`` (the form until PR 39)
+    splits BOTH operands in three and takes six passes, three of them
+    against the one-hot's zero pieces."""
+    n_sub, n_slots = row.shape
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_slots), 0)
+    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (LANES, n_slots), 0)
+    upd = jnp.zeros((n_rows, LANES), jnp.float32)
+    for s in range(n_sub):                              # static unroll
+        hit = jnp.broadcast_to(row[s:s + 1, :], row_iota.shape) == row_iota
+        onehot_t = (jnp.broadcast_to(lane[s:s + 1, :], lane_iota.shape)
+                    == lane_iota).astype(jnp.float32)
+        for piece in pieces:
+            m = jnp.where(hit, jnp.broadcast_to(piece[s:s + 1, :],
+                                                row_iota.shape), 0.0)
+            # DEFAULT by name: one pass whatever the process's
+            # jax_default_matmul_precision (the words' low halves are 0)
+            upd += jax.lax.dot_general(
+                m, onehot_t, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT)
+    return upd
+
+
 def _kernel(base_ref, c_ref, row_ref, lane_ref, acc_ref, *,
             w: int, chunk: int, blk: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    sub_iota = jax.lax.broadcasted_iota(jnp.int32, (8 * w, chunk), 0)
-    lane_sub_iota = jax.lax.broadcasted_iota(jnp.int32, (LANES, chunk), 0)
     pid = pl.program_id(0)  # hoisted: not interpretable inside fori_loop
 
     def body(i, _):
@@ -327,13 +381,8 @@ def _kernel(base_ref, c_ref, row_ref, lane_ref, acc_ref, *,
         c = c_ref[pl.ds(i, 1), :]                       # (1, chunk)
         r = row_ref[pl.ds(i, 1), :]
         ln = lane_ref[pl.ds(i, 1), :]
-        m = jnp.where((r - b) == sub_iota, c, 0.0)      # (8w, chunk)
-        onehot_t = (ln == lane_sub_iota).astype(jnp.float32)
-        upd = jax.lax.dot_general(                      # (8w, LANES)
-            m, onehot_t, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
-        acc_ref[pl.ds(b, 8 * w), :] += upd
+        acc_ref[pl.ds(b, 8 * w), :] += scatter_window(
+            split3(c), r - b, ln, 8 * w)
         return 0
 
     jax.lax.fori_loop(0, blk, body, 0)
@@ -477,7 +526,8 @@ def _spmv_kernel(seg_ref, grp_ref, sbase_ref, win_ref, slane_ref,
     the gather over the group's window of the ranks table (a rolled
     loop over tiles of 8 rows: broadcast row rho, lane-gather by
     ``src_lane``, keep where ``src_row == rho``), then the one-hot-MXU
-    scatter built per gather sublane (8 small matmuls, the price of
+    scatter built per gather sublane (:func:`scatter_window`: 8
+    sublanes x 3 exact pieces, a single bf16 pass each, the price of
     bridging the (8, 128) gather layout to the scatter).
 
     ``win_ref`` is the group's ``(rg, 128)`` window of the ranks table,
@@ -499,9 +549,6 @@ def _spmv_kernel(seg_ref, grp_ref, sbase_ref, win_ref, slane_ref,
     @pl.when(pid == 0)
     def _load():
         copy(acc_in, acc)
-
-    sub_iota_ws = jax.lax.broadcasted_iota(jnp.int32, (ws, LANES), 0)
-    sub_iota128 = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
 
     def chunk(i, _):
         sb = sbase_ref[pid * blk + i]
@@ -536,20 +583,7 @@ def _spmv_kernel(seg_ref, grp_ref, sbase_ref, win_ref, slane_ref,
 
             g = jax.lax.fori_loop(0, rg // (8 * unroll), gather_tiles,
                                   jnp.zeros((8, LANES), jnp.float32))
-            g = g * we
-            upd = jnp.zeros((ws, LANES), jnp.float32)
-            for s in range(8):                          # static unroll
-                cb = jnp.broadcast_to(g[s:s + 1, :], (ws, LANES))
-                m = jnp.where(
-                    jnp.broadcast_to(drow[s:s + 1, :], (ws, LANES))
-                    == sub_iota_ws, cb, 0.0)
-                onehot_t = (jnp.broadcast_to(dlane[s:s + 1, :],
-                                             (LANES, LANES))
-                            == sub_iota128).astype(jnp.float32)
-                upd += jax.lax.dot_general(
-                    m, onehot_t, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.HIGHEST)
+            upd = scatter_window(split3(g * we), drow, dlane, ws)
             rows = pl.ds(pl.multiple_of(sb, 8), ws)
             acc[rows, :] += upd
 

@@ -189,9 +189,12 @@ def summarize(evts: list[dict]) -> dict:
         if ev in ("span_start", "span_end") and e.get("ranks_form"):
             # PageRank's fused sweep says how it reads the ranks table
             # (one gather group: resident; more: a group's window at a
-            # time) and its windows (pagerank:prepare, train:segment)
+            # time), its windows and the bf16 MXU passes of its one-hot
+            # scatter product (pagerank:prepare, train:segment)
             form = (f"{e['ranks_form']} (rg {e.get('rg', '?')}, ws "
                     f"{e.get('ws', '?')})")
+            if "scatter_passes" in e:    # (a log from before PR 39: 6)
+                form += f", scatter passes {e['scatter_passes']}"
             if form not in ranks_forms:
                 ranks_forms.append(form)
         if ev == "span_start":
