@@ -846,7 +846,8 @@ def stage_als_sparse(s: Smoke):
     (``benchmarks/reference/als_sparse_ref.py``) on the followed owners
     of the last half, to float32 rounding; every rating entered each
     half once; the held-out RMSE falls. On one chip the gather runs in
-    its Mosaic form, on several in XLA's, with the same two RMSEs."""
+    its Mosaic form, on several in XLA's, with the same two RMSEs; the
+    solve is shard-local and runs in its Mosaic form on every chip."""
     import numpy as np
 
     from tpu_distalg.models import als
@@ -871,6 +872,9 @@ def stage_als_sparse(s: Smoke):
     form = meta["forms"]["als_gather_form"]
     if form != ("mosaic" if mesh.shape["data"] == 1 else "xla"):
         raise AssertionError(f"gather form {form} on {mesh.shape}")
+    solve = meta["forms"]["als_solve_form"]
+    if (solve, meta["solve"].tile_systems) != ("mosaic", 128):
+        raise AssertionError(f"solve form {meta['solve']} on {mesh.shape}")
     cfg = als.ALSConfig(lam=1.4, m=m_u, n=m_i, k=k, n_iterations=1, seed=3)
     fn = als.make_fit_fn(mesh, cfg, meta)
     X, Theta = als.start_factors(meta, mesh, cfg.seed)
@@ -899,7 +903,7 @@ def stage_als_sparse(s: Smoke):
     return (f"dp={mesh.shape['data']} | blocks a side {meta['blocks']} | "
             f"slots held / ratings {meta['padding_share']:.3f} | "
             f"gather {form}, resident share "
-            f"{meta['gather_resident_share']:.4f} | "
+            f"{meta['gather_resident_share']:.4f} | solve: {solve} | "
             f"{len(own)} owners against the reference {err:.2g} | "
             f"held-out RMSE {held[0]:.3f} -> {held[-1]:.3f}")
 

@@ -3,7 +3,9 @@ cell's real shapes, with no chip attached: what interpret mode cannot
 show (the 64 MB output table in VMEM, a kernel call's scalars in SMEM's
 1 MB, the tiling of the windows). Nothing runs: a compile that passes
 is not a chip run. The topology is described inside a fixture, in this
-one file (only one process may hold the TPU's library)."""
+one file (only one process may hold the TPU's library), so sparse ALS'
+solve kernel is compiled here too: a tile of 128 systems at rank 100
+from a batch of 6144, and the widest rank ``solve_plan`` admits."""
 
 import jax
 import jax.numpy as jnp
@@ -63,3 +65,22 @@ def test_kernel_compiles_at_the_vmem_budgets_edge(one_chip):
     assert geom is not None
     assert ppr.spmv_geometry(v + (1 << 20), 8 * v) is None
     _compile(geom, one_chip)
+
+
+@pytest.mark.parametrize("k,batch", [(100, 6144), (152, 1024)])
+def test_als_solve_kernel_compiles_for_the_chip(one_chip, k, batch):
+    """The rank of the benchmark's cell, and the widest whose tile
+    ``als_sparse.solve_plan`` lets into VMEM (38 MB of the budget's 40):
+    both fit what the chip's compiler allows a kernel."""
+    from tpu_distalg.ops import als_sparse, pallas_als
+
+    geom = als_sparse.SparseGeometry(k=k, batch=batch, classes=(1,),
+                                     piece_segs=batch)
+    assert als_sparse.solve_plan(geom, True).form == "mosaic"
+    Ap = jax.ShapeDtypeStruct((geom.width, geom.width, batch),
+                              jnp.float32, sharding=one_chip)
+    done = jax.jit(lambda a: pallas_als.solve_lanes(a, k, 1.4)).lower(
+        Ap).compile()
+    assert "_als_solve_kernel" in done.as_text()
+    assert done.memory_analysis().output_size_in_bytes \
+        == geom.solve_n * batch * 4

@@ -1174,6 +1174,52 @@ def test_sparse_als_half_sweep_at_rank_100(tpu_mesh):
     assert bool(np.asarray(has).all()) and err < 1e-3
 
 
+def test_sparse_als_solve_kernel_at_rank_100_against_float64():
+    """The Mosaic solve (``ops/pallas_als.solve_lanes``) compiled at the
+    benchmark's tile (128 systems of rank 100 in 104, six tiles) against
+    NumPy in float64 and against XLA's ``cholesky_solve_lanes`` on the
+    same systems: Gramians of 0 to 2000 rows of eighths with ratings 0
+    to 100, ``lam n_u`` on the diagonal, so some owners have fewer
+    ratings than the rank and three have none. What this guards is the
+    VPU's square root and division: an approximate reciprocal would
+    read 1e-3 here."""
+    from tpu_distalg.ops import als_sparse as ops
+
+    k, batch, lam = 100, 768, 1.4
+    geom = ops.SparseGeometry(k=k, batch=batch)
+    assert ops.solve_plan(geom, True) == ops.SolvePlan("mosaic", 128)
+    rng = np.random.default_rng(41)
+    cnt = np.concatenate([[0, 0, 0], rng.integers(1, 100, 253),
+                          rng.integers(100, 2000, batch - 256)])
+    Ap = np.zeros((batch, geom.width, geom.width))
+    for i in range(batch):
+        G = np.zeros((cnt[i], geom.width))
+        G[:, :k] = rng.integers(-8, 9, (cnt[i], k)) / 8
+        G[:, k] = rng.integers(0, 101, cnt[i])
+        G[:, k + 1] = 1.0
+        Ap[i] = G.T @ G                 # exact in float32 too
+    want = np.stack([np.linalg.solve(
+        Ap[i, :k, :k] + (lam * cnt[i] if cnt[i] else 1.0) * np.eye(k),
+        Ap[i, :k, k]) for i in range(batch)])
+    lanes = ops.to_lanes(jnp.asarray(Ap, jnp.float32))
+    got = {}
+    for name, plan in (("mosaic", ops.SolvePlan("mosaic", 128)),
+                       ("xla", None)):
+        rows, has, _, seen = jax.jit(
+            lambda a, plan=plan: ops.solve_batch(a, lam, geom, plan))(lanes)
+        assert np.asarray(has).tolist() == (cnt > 0).tolist()
+        assert int(seen) == int(cnt.sum())
+        rows = np.asarray(rows)
+        assert not rows[:3].any() and not rows[:, k:].any()
+        got[name] = np.linalg.norm(rows[:, :k] - want) / np.linalg.norm(want)
+        worst = (np.linalg.norm(rows[3:, :k] - want[3:], axis=1)
+                 / np.linalg.norm(want[3:], axis=1)).max()
+        print(f"[als solve rank 100] {name}: rel err {got[name]:.3g}, "
+              f"worst system {worst:.3g}")
+        assert worst < 1e-4
+    assert got["mosaic"] < 5e-6 and got["mosaic"] <= 2 * got["xla"]
+
+
 @pytest.mark.parametrize("table_rows,hot_row0", [
     (663_560, 645_120),        # the items' table, what the user half reads
     (1_032_200, 1_013_760)])   # the users'

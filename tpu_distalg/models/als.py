@@ -273,6 +273,7 @@ def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
     # a half gathers from the OTHER side's table: the user half first
     gathers = tuple(als_sparse.gather_plan(o.static, geom, on_tpu)
                     for o in (pi, pu))
+    solve = als_sparse.solve_plan(geom, on_tpu)
     resident = tuple(
         als_sparse.resident_slots(p, o, g.hot_row0) if g.resident_rows
         else 0 for p, o, g in zip(plans, (pi, pu), gathers))
@@ -292,10 +293,10 @@ def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
             r / max(p.slots_held, 1) for r, p in zip(resident, plans)),
         gather_resident_share=sum(resident)
         / max(pu.slots_held + pi.slots_held, 1),
-        gather=gathers,
+        gather=gathers, solve=solve,
         forms=dict(als_gather_form="/".join(
             dict.fromkeys(g.form for g in gathers)),
-            als_gram_form="xla", als_solve_form="xla"),
+            als_gram_form="xla", als_solve_form=solve.form),
         **extra)
 
 
@@ -321,9 +322,13 @@ def _gather_fields(meta: dict) -> dict:
 
 
 def segment_fields(meta: dict) -> dict:
-    """What a ``train:segment`` span says of the sparse trainer."""
+    """What a ``train:segment`` span says of the sparse trainer: each
+    piece's form, how often the gather's resident range engages and how
+    many systems a tile of the solve holds (0 in XLA's form; the form
+    is one for every batch of a run, so a size says all a share would)."""
     return {"layout": meta["layout"], **meta["forms"],
-            **_gather_fields(meta)}
+            **_gather_fields(meta),
+            "solve_tile_systems": meta["solve"].tile_systems}
 
 
 def ratings_from_coo(users, items, ratings, n_users: int, n_items: int,
@@ -644,7 +649,8 @@ def _make_fit_fn_sparse(mesh: Mesh, config: ALSConfig, meta: dict):
             return als_sparse.half_sweep(
                 idx, val, pieces, other, own, static=static,
                 other_zero_row=other_zero_row, geom=geom,
-                lam=config.lam, axis=DATA_AXIS, gather=gather)
+                lam=config.lam, axis=DATA_AXIS, gather=gather,
+                solve=meta["solve"])
 
         return data_parallel(
             run, mesh, in_specs=(*(P(DATA_AXIS),) * 3, P(), P()),

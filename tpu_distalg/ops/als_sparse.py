@@ -10,8 +10,9 @@ owner ``u`` (a user in the user half, an item in the item half) has
 
 so a half-sweep is a gather of ``theta`` rows by index, one small
 Gramian an owner, and one small solve an owner. This file holds the
-three pieces (in XLA forms; the gather also as a Mosaic kernel) and the
-pack that gives them static shapes.
+three pieces (in XLA forms; the gather and the solve also as Mosaic
+kernels, ``ops/pallas_als.py``) and the pack that gives them static
+shapes.
 
 **The pack** (:func:`plan_side`, host, from the degrees alone). An
 owner's ratings are cut into *segments* of ``seg_slots`` (32) slots.
@@ -72,13 +73,29 @@ a row about once for its place to pay, which the heavy class's rows do
 before it is read by a block in three; with them resident, 31.5 / 40.9
 MB, a block reads 7 and 4% slower than with the heavy class alone).
 
-**The solve** (:func:`solve_batch`) is a Cholesky factorisation with the
-batch along the lanes: ``(n, n, batch)`` in panels of 8 columns, every
-step an elementwise operation over ``batch`` systems at once (the
-Gramians come out of :func:`block_gramians` in that layout). XLA's own
-``cho_factor`` walks 100 dependent columns per system batch with the
-matrix on the minor dimensions: 13.9 us a system on one v5e against 2.4
-here (Step 0, ``benchmarks/tools/step0_als.py``).
+**The solve** (:func:`solve_batch`) is a right-looking Cholesky
+factorisation of ``A_u + lam n_u I`` with the batch along the lanes, in
+panels of 8 columns, the right-hand side carried as one more row, then
+the backward substitution: every step an elementwise operation over the
+batch's systems at once (the Gramians come out of :func:`block_gramians`
+in that layout, ``(width, width, batch)``). It has two forms that solve
+the same systems by the same steps in float32 (a true square root and a
+true division), and :func:`solve_plan` picks one from what the code can
+observe, with no flag:
+
+``mosaic``  ``pallas_als.solve_lanes``: a tile of 128 systems is read
+            out of the Gramians once and stays in VMEM from the ridge
+            to the solved row (17 MB at rank 100); the staged matrices
+            of XLA's form never exist in HBM. On a TPU, where a batch
+            is whole tiles and a tile fits ``SOLVE_VMEM_BYTES`` (to
+            rank 152); on a mesh every shard runs it on its own batches.
+``xla``     :func:`cholesky_solve_lanes`: each panel's update streams
+            the stage's whole trailing matrix through HBM (286 MB each
+            way a first-stage panel at the published shape, at 640 of
+            the chip's 819 GB/s: 2.07 us a system on one v5e, where
+            XLA's own ``cho_factor``, the matrix on the minor
+            dimensions, took 13.9); everywhere else (the CPU, a batch
+            of ``BATCH_UNIT``), and the kernel's reference in the tests.
 
 Precision: the Gramians are ``Precision.HIGHEST`` products of float32
 rows (six bfloat16 passes: every factor enters with its 24 bits),
@@ -98,12 +115,18 @@ LANES = 128
 # pad by a half) and every size divides a batch of 192 x 2^n
 CLASSES = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48)
 BATCH_UNIT = 192            # the least batch those sizes and 64 divide
-PANEL = 8                   # columns a Cholesky panel (16 compile for
-#                             five minutes unrolled: PERF.md section 6)
+PANEL = 8                   # columns a Cholesky panel: a vector's
+#                             sublanes in the Mosaic form, where a
+#                             vector holds 8 rows of a column; in XLA's
+#                             16 compiled for five minutes unrolled
 # what the gather's resident range may take of VMEM: the heavy class of
 # the published shape and not the class before it (the module docstring
 # says where the number comes from)
 GATHER_VMEM_BYTES = 12 << 20
+# what a tile of the Mosaic solve may take of VMEM (a block of the
+# Gramians twice and the matrix it factors: 17.2 MB at rank 100, 38.3 at
+# rank 152, the widest that fits)
+SOLVE_VMEM_BYTES = 40 << 20
 
 
 def _round_up(x: int, m: int) -> int:
@@ -211,6 +234,30 @@ class GatherPlan:
     #                         none is resident
     resident_rows: int
     interpret: bool = False  # the kernel interpreted (tests, on the CPU)
+
+
+@dataclasses.dataclass(frozen=True)
+class SolvePlan:
+    """How a half solves a batch of systems."""
+
+    form: str               # 'mosaic' or 'xla'
+    tile_systems: int       # systems held in VMEM at once; 0 in XLA's form
+    interpret: bool = False  # the kernel interpreted (tests, on the CPU)
+
+
+def solve_plan(geom: SparseGeometry, on_tpu: bool) -> SolvePlan:
+    """The form of the per-owner solve from what the code can observe:
+    the Mosaic kernel on a TPU where a batch is whole tiles of systems
+    (a lane each) and a tile at this rank fits ``SOLVE_VMEM_BYTES``;
+    XLA's :func:`cholesky_solve_lanes` elsewhere. The solve reads no
+    table and no index, so every shard of a mesh runs the same form."""
+    from tpu_distalg.ops import pallas_als
+
+    tile = pallas_als.SOLVE_TILE
+    if (on_tpu and geom.batch % tile == 0
+            and pallas_als.solve_tile_bytes(geom.k) <= SOLVE_VMEM_BYTES):
+        return SolvePlan("mosaic", tile)
+    return SolvePlan("xla", 0)
 
 
 def resident_row0(other: SideStatic, geom: SparseGeometry,
@@ -538,13 +585,15 @@ def cholesky_solve_lanes(M, rhs, panel: int):
     return lax.fori_loop(0, n_panels, backward, jnp.zeros_like(rhs))
 
 
-def solve_batch(Ap, lam: float, geom: SparseGeometry):
+def solve_batch(Ap, lam: float, geom: SparseGeometry,
+                solve: SolvePlan | None = None):
     """From a batch of extended Gramians with the owners along the
     lanes, ``(width, width, batch)``: the new factor rows ``(batch,
     width)``, which owners have a rating, the squared training error of
     those that have, and the ratings counted. ``A_u + lam n_u I`` is
-    solved exactly (Cholesky); an owner with no rating solves the
-    identity and is flagged."""
+    solved exactly (Cholesky) in the plan's form (XLA's where none is
+    given); an owner with no rating solves the identity and is
+    flagged."""
     import jax
     import jax.numpy as jnp
 
@@ -554,14 +603,22 @@ def solve_batch(Ap, lam: float, geom: SparseGeometry):
     with jax.named_scope(names.ALS_SOLVE):
         cnt = Ap[k + 1, k + 1]
         has = cnt > 0
-        ridge = jnp.where(has, jnp.float32(lam) * cnt, 1.0)
         b = Ap[:k, k]                                 # (k, batch)
-        ri = jax.lax.broadcasted_iota(jnp.int32, (n8, n8, 1), 0)
-        ci = jax.lax.broadcasted_iota(jnp.int32, (n8, n8, 1), 1)
-        M = jnp.where((ri < k) & (ci < k), Ap[:n8, :n8], 0.0) + jnp.where(
-            ri == ci, jnp.where(ri < k, ridge[None, None, :], 1.0), 0.0)
-        x = cholesky_solve_lanes(
-            M, jnp.pad(b, ((0, n8 - k), (0, 0))), w)[:k]   # (k, batch)
+        if solve is not None and solve.form == "mosaic":
+            from tpu_distalg.ops import pallas_als
+
+            x = pallas_als.solve_lanes(
+                Ap, k, float(lam), interpret=solve.interpret)[:k]
+        else:
+            ridge = jnp.where(has, jnp.float32(lam) * cnt, 1.0)
+            ri = jax.lax.broadcasted_iota(jnp.int32, (n8, n8, 1), 0)
+            ci = jax.lax.broadcasted_iota(jnp.int32, (n8, n8, 1), 1)
+            M = jnp.where((ri < k) & (ci < k), Ap[:n8, :n8], 0.0) \
+                + jnp.where(ri == ci,
+                            jnp.where(ri < k, ridge[None, None, :], 1.0),
+                            0.0)
+            x = cholesky_solve_lanes(
+                M, jnp.pad(b, ((0, n8 - k), (0, 0))), w)[:k]  # (k, batch)
     with jax.named_scope(names.ALS_UPDATE):
         Ax = jnp.sum(Ap[:k, :k] * x[None, :, :], axis=1)
         err = Ap[k, k] - 2.0 * jnp.sum(x * b, axis=0) \
@@ -574,7 +631,8 @@ def solve_batch(Ap, lam: float, geom: SparseGeometry):
 
 def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
                other_zero_row: int, geom: SparseGeometry, lam: float,
-               axis: str, gather: GatherPlan | None = None):
+               axis: str, gather: GatherPlan | None = None,
+               solve: SolvePlan | None = None):
     """One shard's half of an iteration: every owner of this shard from
     the other side's table ``other`` (whole, constant through the half),
     written into the shard's rows of ``own``; the shards' rows gathered
@@ -599,9 +657,12 @@ def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
             lax.dynamic_index_in_dim(val, block, keepdims=False), K,
             geom, other_zero_row, gather)
 
+    # (XLA's form is ``solve_batch``'s own: named only where it is not)
+    how = {"solve": solve} if solve and solve.form == "mosaic" else {}
+
     def solve_into(carry, Ap, row):
         local, sse, seen = carry
-        rows, has, e, c = solve_batch(Ap, lam, geom)
+        rows, has, e, c = solve_batch(Ap, lam, geom, **how)
         with jax.named_scope(names.ALS_UPDATE):
             old = lax.dynamic_slice_in_dim(local, row, B, axis=0)
             new = jnp.where(has[:, None], rows, old)

@@ -1,4 +1,5 @@
-"""The Mosaic form of sparse ALS' row gather (``ops/als_sparse.py``).
+"""The Mosaic forms of sparse ALS' row gather and of its per-owner
+solve (``ops/als_sparse.py``); the solve's is the file's second half.
 
 ``gather_rows_resident(table, idx_b, hot_row0)`` returns ``table[idx_b
 .reshape(-1)]``, bit for bit, for a table of 128-lane float32 rows whose
@@ -165,3 +166,237 @@ def gather_rows_resident(table, idx_b, hot_row0: int, *,
             disable_bounds_checks=True),
         interpret=interpret,
     )(idx_b.reshape(-1), table)
+
+
+# ------------------------------------------------------------ the solve
+#
+# ``solve_lanes(Ap, k, lam)`` solves ``(A_u + lam n_u I) x = b_u`` for
+# every owner of a batch of extended Gramians ``Ap`` ``(width, width,
+# batch)`` (``A_u`` rows and columns under ``k``, ``b_u`` row ``k``,
+# ``n_u`` entry ``(k + 1, k + 1)``; an owner of no rating solves the
+# identity), by the steps of ``als_sparse.cholesky_solve_lanes`` in the
+# same order: right-looking Cholesky in panels of 8 columns, the
+# right-hand side as one more row, the backward substitution.
+#
+# A grid step takes a tile of 128 systems, a lane each: the pipeline
+# copies ``Ap[:ext, :ext, tile]`` in (``ext`` = ``k + 2`` in whole
+# sublane tiles; 5.5 MB at rank 100, read once), the ridge and the ``k``
+# mask are made on the way into ``m_ref``, column ``c`` a leading index
+# and rows down the sublanes, so a vector is 8 rows of one column of
+# 128 systems. Only the lower triangle is kept. A panel's eight columns
+# are finished a row tile at a time (the diagonal block first, which
+# gives the 28 multipliers and 8 pivots the tiles below reuse) and go
+# back to ``m_ref`` and into ``p_ref``, an allocation of its own at a
+# static index: the trailing update reads the panel only there, so no
+# load of it waits on a store to the matrix. That update takes a tile of
+# eight columns by two row tiles a trip, a column's multiplier spread
+# down the sublanes once for both. The loops over panels, column tiles
+# and row tiles are rolled with dynamic bounds; what a trip does is
+# unrolled.
+#
+# One v5e at rank 100 (``scripts/step0_als_solve.py``, PR 41): 324.8
+# bundles a system by the static schedule, 58% of them the trailing
+# update; 1.47 ms a batch of 6144 (0.24 us a system, a tenth over the
+# schedule) where XLA's form takes 14.5, the tile's copy (0.45 ms a
+# batch alone) hidden behind the arithmetic; tiles of 256 and 512
+# systems read the same, and a tile of 1024 with a matrix entry a whole
+# vector the same again before its view of ``Ap`` is paid for (1.2 ms).
+
+SOLVE_TILE = LANES     # systems a grid step: one a lane
+
+
+def _solve_sizes(k: int) -> tuple[int, int]:
+    """``(n, ext)`` in whole sublane tiles: the rows and columns that
+    are factored (``k`` and the identity's padding), and those read of
+    ``Ap`` (``b_u`` in row ``k``, ``n_u`` at ``k + 1``)."""
+    return -(-k // SUBLANES) * SUBLANES, -(-(k + 2) // SUBLANES) * SUBLANES
+
+
+def solve_tile_bytes(k: int) -> int:
+    """VMEM a grid step of :func:`solve_lanes` holds at rank ``k``: the
+    block of ``Ap`` and the result's twice (the pipeline's two buffers),
+    the matrix that is factored in place, right-hand side and all, and
+    its panel."""
+    n, ext = _solve_sizes(k)
+    return 4 * SOLVE_TILE * (2 * (ext * ext + n)
+                             + (n + SUBLANES) * (n + 2 * SUBLANES))
+
+
+def _als_solve_kernel(a_ref, x_ref, m_ref, p_ref, *, k: int, lam: float):
+    n = x_ref.shape[0]
+    w = SUBLANES
+    n_tiles = n // w            # column tiles, and the matrix's row tiles
+    row_tiles = n_tiles + 1     # the right-hand side rides in one more
+    f32 = jnp.float32
+    sub = jax.lax.broadcasted_iota(jnp.int32, (w, LANES), 0)
+
+    def rows8(x):
+        return jnp.broadcast_to(x, (w, LANES))
+
+    def tile(i):
+        return pl.ds(pl.multiple_of(i * w, w), w)
+
+    cnt = a_ref[k + 1, pl.ds(k + 1, 1), :]
+    ridge = rows8(jnp.where(cnt > 0, f32(lam) * cnt, f32(1.0)))
+
+    # the matrix of a tile's systems, the lower triangle of it: column c
+    # a leading index, rows down the sublanes, the right-hand side as
+    # row n. What lies above the diagonal inside a column's first tile
+    # is never read: no step mixes sublanes but by a row it names
+    def build(ct, carry):
+        c0 = pl.multiple_of(ct * w, w)
+        live = [c0 + u < k for u in range(w)]
+        for u in range(w):      # the diagonal block, with the ridge
+            v = jnp.where((sub + c0 < k) & live[u],
+                          a_ref[c0 + u, tile(ct), :], f32(0.0))
+            m_ref[c0 + u, tile(ct), :] = v + jnp.where(
+                sub == u, jnp.where(live[u], ridge, f32(1.0)), f32(0.0))
+            b = rows8(a_ref[c0 + u, pl.ds(k, 1), :])
+            m_ref[c0 + u, pl.ds(n, w), :] = jnp.where(
+                (sub == 0) & live[u], b, f32(0.0))
+
+        def one(i, carry):
+            keep = sub + i * w < k
+            for u in range(w):
+                m_ref[c0 + u, tile(i), :] = jnp.where(
+                    keep & live[u], a_ref[c0 + u, tile(i), :], f32(0.0))
+            return carry
+
+        jax.lax.fori_loop(ct + 1, n_tiles, one, 0)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, build, 0)
+
+    def factor(p, carry):
+        q = pl.multiple_of(p * w, w)
+
+        # a panel's eight columns, a row tile at a time from the
+        # diagonal block down (the right-hand side's row with them,
+        # which is the forward substitution): finished, a column goes
+        # back to the matrix for the backward substitution and into
+        # ``p_ref``, an allocation of its own at a static index, for
+        # the trailing update
+        def columns(tiles, mult, piv):
+            Xs = [[m_ref[q + t, tile(i), :] for t in range(w)]
+                  for i in tiles]
+            for X in Xs:
+                for j in range(w):
+                    s = X[j]
+                    for t in range(j):
+                        s = s - X[t] * mult[t][j]
+                    if piv[j] is None:      # the diagonal block: row j
+                        piv[j] = rows8(jnp.sqrt(s[j:j + 1, :]))
+                    X[j] = s / piv[j]
+                    for u in range(j + 1, w):
+                        if mult[j][u] is None:
+                            mult[j][u] = rows8(X[j][u:u + 1, :])
+            for i, X in zip(tiles, Xs):
+                for t in range(w):
+                    m_ref[q + t, tile(i), :] = X[t]
+                    p_ref[t, tile(i), :] = X[t]
+
+        mult = [[None] * w for _ in range(w)]
+        piv = [None] * w
+        columns([p], mult, piv)
+
+        # (two row tiles a trip, each a chain of eight columns; an odd
+        # count's last trip runs into the spare tile under the
+        # right-hand side's, which nothing reads)
+        def below(g, carry):
+            columns([p + 1 + 2 * g, p + 2 + 2 * g], mult, piv)
+            return carry
+
+        jax.lax.fori_loop(0, (row_tiles - p) // 2, below, 0)
+
+        # the trailing matrix less the panel's outer product: a tile of
+        # eight columns at a time, two row tiles a trip from the bottom
+        # up (a row of a column is a sublane of its vector, so a
+        # column's multiplier is spread down the sublanes once for both
+        # tiles); an odd count's first trip also takes the tile above
+        # the diagonal block, which nothing reads
+        def trailing(jt, carry):
+            c0 = pl.multiple_of(jt * w, w)
+            first = jt - (row_tiles - jt) % 2
+
+            def two(g, carry):
+                i0 = first + 2 * g
+                upd = [[None] * w, [None] * w]
+                for t in range(w):
+                    C = p_ref[t, tile(jt), :]
+                    X = [p_ref[t, tile(i0 + h), :] for h in range(2)]
+                    for u in range(w):
+                        c = rows8(C[u:u + 1, :])
+                        for h in range(2):
+                            term = X[h] * c
+                            upd[h][u] = term if t == 0 \
+                                else upd[h][u] + term
+                M = [[m_ref[c0 + u, tile(i0 + h), :] - upd[h][u]
+                      for u in range(w)] for h in range(2)]
+                for h in range(2):
+                    for u in range(w):
+                        m_ref[c0 + u, tile(i0 + h), :] = M[h][u]
+                return carry
+
+            jax.lax.fori_loop(0, (row_tiles - first) // 2, two, 0)
+            return carry
+
+        jax.lax.fori_loop(p + 1, n_tiles, trailing, 0)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, factor, 0)
+
+    # backward: a panel's eight unknowns from those below it
+    def backward(ip, carry):
+        p = n_tiles - 1 - ip
+        q = pl.multiple_of(p * w, w)
+
+        def dots(i, acc):
+            x = x_ref[tile(i), :]
+            return tuple(a + m_ref[q + j, tile(i), :] * x
+                         for j, a in enumerate(acc))
+
+        acc = jax.lax.fori_loop(
+            p + 1, n_tiles, dots,
+            tuple(jnp.zeros((w, LANES), f32) for _ in range(w)))
+        xp = [None] * w
+        out = jnp.zeros((w, LANES), f32)
+        for j in reversed(range(w)):
+            s = m_ref[q + j, pl.ds(n, 1), :] \
+                - jnp.sum(acc[j], axis=0, keepdims=True)
+            for t in range(j + 1, w):
+                s = s - m_ref[q + j, pl.ds(q + t, 1), :] * xp[t]
+            xp[j] = s / m_ref[q + j, pl.ds(q + j, 1), :]
+            out = jnp.where(sub == j, rows8(xp[j]), out)
+        x_ref[tile(p), :] = out
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, backward, 0)
+
+
+def solve_lanes(Ap, k: int, lam: float, *, interpret: bool = False):
+    """The ``k`` unknowns of every system of a batch of extended
+    Gramians held with the owners along the lanes, ``Ap`` ``(width,
+    width, batch)`` float32 -> ``(round_up(k, 8), batch)`` (rows from
+    ``k`` on are zero)."""
+    width, _, batch = Ap.shape
+    n, ext = _solve_sizes(k)
+    if batch % SOLVE_TILE or ext > width:
+        raise ValueError(f"Gramians {Ap.shape} are not whole tiles of "
+                         f"{SOLVE_TILE} systems of rank {k}")
+    rows = n + 2 * SUBLANES     # the right-hand side's tile and a spare
+    return pl.pallas_call(
+        functools.partial(_als_solve_kernel, k=k, lam=lam),
+        name="_als_solve_kernel",
+        grid=(batch // SOLVE_TILE,),
+        in_specs=[pl.BlockSpec((ext, ext, SOLVE_TILE),
+                               lambda i: (0, 0, i))],
+        out_specs=pl.BlockSpec((n, SOLVE_TILE), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((n, batch), Ap.dtype),
+        scratch_shapes=[pltpu.VMEM((n, rows, SOLVE_TILE), Ap.dtype),
+                        pltpu.VMEM((SUBLANES, rows, SOLVE_TILE), Ap.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            # a tile's systems are its own: no step reads another's
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=solve_tile_bytes(k) + VMEM_SLACK),
+        interpret=interpret,
+    )(Ap)

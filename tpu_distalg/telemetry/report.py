@@ -245,10 +245,14 @@ def summarize(evts: list[dict]) -> dict:
                     # how often the resident range engages
                     gather += (f" with {e['gather_resident_share']} of "
                                f"the slots resident")
+                solve = e.get("als_solve_form", "?")
+                if e.get("solve_tile_systems"):
+                    # the systems a tile holds in VMEM from the Gramian
+                    # to the solved row
+                    solve += f" in tiles of {e['solve_tile_systems']}"
                 form = (f"{e.get('layout', '?')} (gather: {gather}, "
                         f"gramians: "
-                        f"{e.get('als_gram_form', '?')}, solve: "
-                        f"{e.get('als_solve_form', '?')})")
+                        f"{e.get('als_gram_form', '?')}, solve: {solve})")
                 if form not in als_forms:
                     als_forms.append(form)
         elif ev == "span_end":
