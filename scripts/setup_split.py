@@ -4,7 +4,8 @@ record: one run of a cell through ``benchmarks/run.py`` (unchanged: its
 ``run_cell``, in this process), then the harness's three set-up spans
 (``import_program``, ``data_build``, ``warm_up``) and what set-up does
 between them, each with the ``jit:*`` spans of ``utils/compile_cache``
-that lie in it:
+that lie in it (re-reads no constant; its standing reader is
+``ROADMAP.md`` S3 (2), the set-up that stands under its ``best``):
 
     chiprun -- python3 scripts/setup_split.py --workload <cell> \
         --seed <n> [--seconds 10] [--trace 1]

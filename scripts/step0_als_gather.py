@@ -3,7 +3,9 @@
 costs alone on the chip at the ``als100_253m_sweep1`` cell's shape (a
 block of 6144 segments x 32 slots = 196 608 rows of 128 float32 lanes,
 from the items' table of 663 560 rows and the users' of 1 032 200), ms a
-block, least of three, each with its dispatch:
+block, least of three, each with its dispatch. Kept as the way to
+re-read ``GATHER_VMEM_BYTES`` in ``tpu_distalg/ops/als_sparse.py`` (the
+``heavy`` / ``five`` / ``budget`` rows):
 
     chiprun -- python3 scripts/step0_als_gather.py
     JAX_PLATFORMS=cpu python3 scripts/step0_als_gather.py --rehearse

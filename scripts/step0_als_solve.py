@@ -6,7 +6,9 @@ the ``als100_253m_sweep1`` cell's shape (extended Gramians ``(128, 128,
 100, ``lam n_u`` on the diagonal), every form inside ONE program over
 eight blocks (ms a block: a form timed on one block alone reads its
 dispatch too, PR 37's lesson; the kernels take the eight as one batch
-of 49 152, XLA's form in a scan):
+of 49 152, XLA's form in a scan). Kept as the way to re-read
+``SOLVE_VMEM_BYTES`` in ``tpu_distalg/ops/als_sparse.py`` (the tile
+``solve_plan`` admits: ``tile128`` against ``tile1024`` and ``xla``):
 
     chiprun -- python3 scripts/step0_als_solve.py
     JAX_PLATFORMS=cpu python3 scripts/step0_als_solve.py --rehearse

@@ -3,7 +3,8 @@
 pass costs alone on the chip at the ``lrhash39_46m_frac01`` cell's shape
 (56 sampled blocks of 8192 rows, 39 fields, 2^20 weights; the program's
 loader and its dictionaries), ms a step, least of three, each with its
-dispatch:
+dispatch. Kept as the way to re-read ``DICT_MAX_VALUES`` in
+``tpu_distalg/ops/pallas_hashed.py`` (the ``value.*.cN`` rows):
 
     chiprun -- python3 scripts/step0_hashed_fields.py
     JAX_PLATFORMS=cpu python3 scripts/step0_hashed_fields.py --rehearse

@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Step 0 of PR 38 on the chip: what each piece of the resident
 PageRank path costs alone at Graph500 SCALE 24, before the design is
-fixed (readings in PERF.md section 6).
+fixed (readings in PERF.md section 6). Kept as the way to re-read
+``SPMV_UNROLL`` in ``tpu_distalg/ops/pallas_pagerank.py`` (iii).
 
     chiprun -- python3 scripts/step0_pagerank_resident.py [--scale 24]
     JAX_PLATFORMS=cpu python3 scripts/step0_pagerank_resident.py --rehearse

@@ -14,6 +14,8 @@ from tpu_distalg.models import pagerank
 from tpu_distalg.ops import bf16_pieces
 from tpu_distalg.ops import graph as gops
 from tpu_distalg.ops import pallas_pagerank as ppr
+from tpu_distalg.telemetry import events as tevents
+from tpu_distalg.telemetry import report
 
 
 def _random_graph(v, e, seed):
@@ -360,6 +362,94 @@ def test_scatter_pallas_without_plan_raises(mesh8):
     cfg = pagerank.PageRankConfig(mode="standard", scatter="pallas")
     with pytest.raises(ValueError, match="scatter plan"):
         pagerank.make_run_fn(mesh8, cfg, 64, None)
+
+
+@pytest.mark.parametrize("mode,scatter,fused,hybrid,want", [
+    ("reference", "auto", False, False, "reference"),
+    ("reference", "auto", True, True, "reference"),
+    # 'auto': fused, else the hybrid, else XLA
+    ("standard", "auto", True, True, "fused"),
+    ("standard", "auto", True, False, "fused"),
+    ("standard", "auto", False, True, "hybrid"),
+    ("standard", "auto", False, False, "xla"),
+    # a named sweep is that sweep, whatever other plan came
+    ("standard", "spmv", True, True, "fused"),
+    ("standard", "pallas", True, True, "hybrid"),
+    ("standard", "xla", True, True, "xla"),
+    ("standard", "xla", False, False, "xla"),
+    # and without its plan names the remedy
+    ("standard", "spmv", False, True, "prepare_device_spmv"),
+    ("standard", "pallas", True, False, "scatter plan"),
+    # the reference sweep takes no scatter but 'auto'
+    ("reference", "spmv", True, True, "only applies to mode='standard'"),
+    ("reference", "xla", False, False, "only applies to mode='standard'"),
+    ("standard", "mxu", True, True, "unknown scatter mode 'mxu'"),
+])
+def test_sweep_form_is_the_whole_rule(mode, scatter, fused, hybrid, want):
+    """``sweep_form`` names the sweep for every (mode, scatter, which
+    plans exist), and raises where no sweep answers."""
+    cfg = pagerank.PageRankConfig(mode=mode, scatter=scatter)
+    if want in ("reference", "fused", "hybrid", "xla"):
+        assert pagerank.sweep_form(cfg, fused, hybrid) == want
+    else:
+        with pytest.raises(ValueError, match=want):
+            pagerank.sweep_form(cfg, fused, hybrid)
+
+
+def test_auto_on_a_sparse_graph_is_the_hybrid_sweep_and_says_so(
+        mesh8, tmp_path):
+    """A graph too sparse for the fused window (2^20 vertices, 400k
+    edges: sixteen groups at rg 512 put a chunk's mean span at 335
+    rows, past ``SPMV_WS_CAP``) whose 1024 destination-sorted edges
+    still span under 32 rows: ``'auto'`` ranks it by the hybrid sweep,
+    bit for bit as ``scatter='pallas'`` does and as the XLA sweep does
+    to float32 noise, and the fused plan's refusal is counted and
+    named (one v5e reads this regime 2.1x ahead of XLA: PERF.md, PR
+    43)."""
+    v, e = 1 << 20, 400_000
+    edges = _random_graph(v, e, seed=3)
+    cfg = pagerank.PageRankConfig(n_iterations=4, mode="standard")
+    sink = str(tmp_path / "tele")
+    tevents.configure(sink)
+    try:
+        auto = pagerank.run(edges, mesh8, cfg, v)
+    finally:
+        tevents.configure(False)
+    evts = report.load_events(sink)
+    assert report.summarize(evts)["counters"]["spmv_plan_rejections"] >= 1
+    rejected = [x for x in evts if x.get("ev") == "spmv_span_rejected"]
+    assert rejected and rejected[0]["span"] > rejected[0]["ws"]
+    el = gops.prepare_edges(edges, v)
+    assert pagerank.prepare_device_edges(el, mesh8).plan is not None
+    hybrid, xla = (np.asarray(pagerank.run(
+        edges, mesh8, dataclasses.replace(cfg, scatter=sc), v).ranks)
+        for sc in ("pallas", "xla"))
+    np.testing.assert_array_equal(
+        np.asarray(auto.ranks).view(np.uint32), hybrid.view(np.uint32))
+    np.testing.assert_allclose(hybrid, xla, rtol=1e-5, atol=1e-10)
+
+
+@pytest.mark.parametrize("n_shards", [1, 4, 8])
+def test_device_edges_without_a_plan_are_sorted_with_inert_padding(
+        n_shards):
+    """``prepare_device_edges``' form where no plan is made: every
+    shard's slice sorted by destination, the padding ``dst = V-1`` with
+    zero weight and mask behind every edge."""
+    from tpu_distalg.parallel import get_mesh
+
+    mesh = get_mesh(data=n_shards, devices=jax.devices()[:n_shards])
+    v = 1000
+    el = gops.prepare_edges(_random_graph(v, 8003, seed=n_shards), v)
+    de = pagerank.prepare_device_edges(el, mesh, build_plan=False)
+    assert de.plan is None and de.spmv is None
+    dst, w_e, emask = (np.asarray(a) for a in (de.dst, de.w_e, de.emask))
+    assert len(dst) % n_shards == 0 and len(dst) - el.n_edges < n_shards
+    for part in np.split(dst, n_shards):
+        assert (np.diff(part) >= 0).all()
+    assert (emask[:el.n_edges] == 1).all() and (w_e[:el.n_edges] > 0).all()
+    assert (dst[el.n_edges:] == v - 1).all()
+    assert not w_e[el.n_edges:].any() and not emask[el.n_edges:].any()
+    np.testing.assert_array_equal(np.sort(el.dst), dst[:el.n_edges])
 
 
 def test_run_auto_falls_back_when_no_plan(mesh8):

@@ -33,7 +33,12 @@ not bandwidth — bounds the sweep):
     ``ranks[src]`` gather stays in XLA); and with the edges sorted by
     (source group, destination row) the gather joins it in one kernel
     (the fused SpMV, ``scatter='spmv'``, what ``'auto'`` prefers): no
-    random-access engine at all. Its plan is made on the device for
+    random-access engine at all. Which sweep ranks a graph is
+    :func:`sweep_form`'s to say and nobody else's: under ``'auto'``
+    the fused sweep where its plan exists, the hybrid where only its
+    own does (a sparse graph: 63 against XLA's 134 ms a sweep of 8.4M
+    edges over 4.2M vertices, PERF.md, PR 43), XLA where neither. The
+    fused plan is made on the device for
     every graph (:func:`prepare_device_spmv`), and a Graph500
     Kronecker graph is drawn and deduplicated there too
     (:func:`build_rmat_graph`): SCALE 24, 268M generated edges, drawn,
@@ -79,7 +84,7 @@ class PageRankConfig:
     q: float = 0.15
     mode: str = "reference"  # 'reference' | 'standard'
     redistribute_dangling: bool = True  # standard mode only
-    scatter: str = "auto"  # 'auto' | 'pallas' | 'xla' (standard mode)
+    scatter: str = "auto"  # 'auto' | 'spmv' | 'pallas' | 'xla' (standard)
 
 
 @dataclasses.dataclass
@@ -192,17 +197,72 @@ def resident_guard_trips(n_vertices: int) -> bool:
                                    ppr.SPMV_WS_CAP) > ppr.SPMV_VMEM_BUDGET
 
 
+def sweep_form(config: PageRankConfig, fused: bool, hybrid: bool) -> str:
+    """Which sweep ranks a graph, ``'reference'``, ``'fused'``,
+    ``'hybrid'`` or ``'xla'``: the one place that knows. ``fused`` and
+    ``hybrid`` say whether that sweep's plan exists
+    (:func:`prepare_device_spmv` refuses a graph whose chunks span more
+    rows than its window, ``ops/pallas_pagerank.plan_scatter`` one
+    whose 1024 destination-sorted edges span more than 32); ask with
+    ``True`` whether a plan is worth making. ``mode='reference'`` is
+    the reference sweep and takes no scatter but 'auto'. In standard
+    mode 'auto' is the fused sweep where planned, else the hybrid
+    where planned, else XLA; 'spmv' is fused and 'pallas' the hybrid,
+    each raising the remedy without its plan; 'xla' is XLA."""
+    if config.scatter not in ("auto", "pallas", "xla", "spmv"):
+        raise ValueError(f"unknown scatter mode {config.scatter!r}")
+    if config.mode != "standard" and config.scatter != "auto":
+        raise ValueError(
+            f"scatter={config.scatter!r} only applies to mode="
+            "'standard' — the reference-parity mode always uses the "
+            "XLA segment_sum path"
+        )
+    if config.mode == "reference":
+        return "reference"
+    if config.scatter == "pallas" and not hybrid:
+        raise ValueError(
+            "scatter='pallas' needs a scatter plan — the graph's dst "
+            "distribution was too sparse/skewed for a bounded window "
+            "(ops/pallas_pagerank.plan_scatter returned None). For "
+            "graphs past the resident ceiling, use the streamed "
+            "engine instead: --data-backend streamed "
+            "(tpu_distalg/graphs/)"
+        )
+    if config.scatter == "spmv" and not fused:
+        raise ValueError(
+            "scatter='spmv' needs the fused-SpMV plan — build the "
+            "DeviceSpMV via prepare_device_spmv (None means the "
+            "graph's windows exceeded ops/pallas_pagerank caps, or "
+            "the kernel-resident VMEM footprint blew "
+            "SPMV_VMEM_BUDGET, 4 B a vertex). Graphs "
+            "past the resident ceiling belong on the out-of-core "
+            "engine: --data-backend streamed (tpu_distalg/graphs/ "
+            "streams edge blocks from disk; only O(V) state stays "
+            "in HBM)"
+        )
+    if config.scatter in ("auto", "spmv") and fused:
+        return "fused"
+    if config.scatter in ("auto", "pallas") and hybrid:
+        return "hybrid"
+    return "xla"
+
+
 def choose_data_backend(requested: str, n_vertices: int,
                         scatter: str = "auto"
                         ) -> tuple[str, str | None]:
     """Resolve the pagerank ``--data-backend`` knob against the
     resident VMEM guard: a resident request past the ceiling degrades
-    to streamed WITH a warning instead of dying in the sweep prep. An
-    EXPLICIT ``--scatter xla``/``pallas`` resident request is honored:
-    the ceiling is the fused SpMV's table budget, and those sweeps
-    carry their own (HBM/plan) limits with remedy-naming errors.
+    to streamed WITH a warning instead of dying in the sweep prep. The
+    ceiling is the fused sweep's table budget, so it applies where a
+    standard-mode sweep under this ``scatter`` would be fused
+    (:func:`sweep_form`); an EXPLICIT ``--scatter xla``/``pallas``
+    resident request is honored: those sweeps carry their own
+    (HBM/plan) limits with remedy-naming errors.
     Returns ``(backend, warning-or-None)``."""
-    if requested == "resident" and scatter in ("auto", "spmv") \
+    would_fuse = sweep_form(
+        PageRankConfig(mode="standard", scatter=scatter),
+        fused=True, hybrid=True) == "fused"
+    if requested == "resident" and would_fuse \
             and resident_guard_trips(n_vertices):
         return "streamed", (
             f"[pagerank] {n_vertices} vertices exceed the resident "
@@ -524,204 +584,157 @@ class _PlanBound:
         return self.jitted.lower(*self._args(*args))
 
 
-def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
-                plan: DevicePlan | None = None,
-                spmv: DeviceSpMV | None = None):
-    """Build the jitted n-iteration sweep.
+def _teleport(config: PageRankConfig, V: int, ranks, c, has_out):
+    """The standard update from a sweep's contributions ``c``: the
+    dangling vertices' mass spread over all, then the teleport."""
+    if config.redistribute_dangling:
+        dangling = jnp.sum(ranks * (1.0 - has_out))
+        c = c + dangling / V
+    return config.q / V + (1 - config.q) * c
 
-    PRECONDITION: the edge arrays passed to the returned ``run`` MUST be
-    dst-sorted per shard with order-preserving padding — exactly what
-    :func:`prepare_device_edges` produces. The segment-sums inside promise
-    ``indices_are_sorted=True`` to XLA, which is unchecked: unsorted
-    ``dst`` yields silently wrong rank sums, not an error. Construct the
-    inputs via :func:`prepare_device_edges` (or :func:`run`, which does).
 
-    Standard-mode path choice: with an ``spmv`` plan (and scatter
-    'auto'/'spmv') the fully-fused tiled SpMV runs — gather AND
-    scatter in one Pallas kernel, the plan's arrays its arguments.
-    'auto' PREFERS it; the hybrid sweep (XLA ``ranks[src]·w`` gather +
-    the windowed one-hot-MXU scatter ``plan``) is the fallback when a
-    chunk's span passes the spmv window, and the XLA-only sweep the
-    final fallback. ``scatter='pallas'``/'spmv' without their plan raise;
-    'xla' forces the legacy path (benchmark A/B).
-    """
-    V = n_vertices
+def _reference_run(mesh: Mesh, config: PageRankConfig, V: int):
+    """The reference's semantics in XLA (the module docstring's
+    ``mode='reference'``): a rank only where a contribution came."""
     q = config.q
 
-    if config.scatter not in ("auto", "pallas", "xla", "spmv"):
-        raise ValueError(f"unknown scatter mode {config.scatter!r}")
-    if config.mode != "standard" and config.scatter != "auto":
-        raise ValueError(
-            f"scatter={config.scatter!r} only applies to mode="
-            "'standard' — the reference-parity mode always uses the "
-            "XLA segment_sum path"
+    def body(src, dst, w_e, emask, ranks, has_rank):
+        active = emask * has_rank[src]
+        c = gops.contribs(ranks, src, dst, w_e * active,
+                          V, indices_sorted=True)
+        received = gops.scatter_add(active, dst, V,
+                                    indices_sorted=True)
+        return tree_allreduce_sum((c, received))
+
+    sweep_fn = data_parallel(
+        body, mesh,
+        in_specs=(P("data"),) * 4 + (P(), P()),
+        out_specs=(P(), P()),
+    )
+
+    def run(src, dst, w_e, emask, has_out, n_ref,
+            ranks0=None, has_rank0=None):
+        # optional carry-in: the checkpointed driver resumes the
+        # power iteration mid-schedule (iterations are
+        # time-invariant, so segmenting the scan is bitwise-exact)
+        if ranks0 is None:
+            ranks0 = jnp.where(has_out > 0, 1.0 / n_ref, 0.0)  # :47
+        if has_rank0 is None:
+            has_rank0 = has_out
+
+        def step(carry, _):
+            ranks, has_rank = carry
+            c, received = sweep_fn(src, dst, w_e, emask, ranks,
+                                   has_rank)
+            new_has = (received > 0).astype(jnp.float32)
+            ranks = jnp.where(
+                received > 0, q / n_ref + (1 - q) * c, 0.0
+            )  # :57
+            return (ranks, new_has), None
+
+        (ranks, has_rank), _ = jax.lax.scan(
+            step, (ranks0, has_rank0), None,
+            length=config.n_iterations,
         )
-    use_pallas = (config.mode == "standard"
-                  and config.scatter in ("auto", "pallas")
-                  and plan is not None)
-    if config.mode == "standard" and config.scatter == "pallas" \
-            and plan is None:
-        raise ValueError(
-            "scatter='pallas' needs a scatter plan — the graph's dst "
-            "distribution was too sparse/skewed for a bounded window "
-            "(ops/pallas_pagerank.plan_scatter returned None). For "
-            "graphs past the resident ceiling, use the streamed "
-            "engine instead: --data-backend streamed "
-            "(tpu_distalg/graphs/)"
-        )
-    if config.mode == "standard" and config.scatter == "spmv" \
-            and spmv is None:
-        raise ValueError(
-            "scatter='spmv' needs the fused-SpMV plan — build the "
-            "DeviceSpMV via prepare_device_spmv (None means the "
-            "graph's windows exceeded ops/pallas_pagerank caps, or "
-            "the kernel-resident VMEM footprint blew "
-            "SPMV_VMEM_BUDGET, 4 B a vertex). Graphs "
-            "past the resident ceiling belong on the out-of-core "
-            "engine: --data-backend streamed (tpu_distalg/graphs/ "
-            "streams edge blocks from disk; only O(V) state stays "
-            "in HBM)"
-        )
+        return ranks, has_rank
 
-    if config.mode == "reference":
-        def body(src, dst, w_e, emask, ranks, has_rank):
-            active = emask * has_rank[src]
-            c = gops.contribs(ranks, src, dst, w_e * active,
-                              V, indices_sorted=True)
-            received = gops.scatter_add(active, dst, V,
-                                        indices_sorted=True)
-            return tree_allreduce_sum((c, received))
+    return jax.jit(run)
 
-        sweep_fn = data_parallel(
-            body, mesh,
-            in_specs=(P("data"),) * 4 + (P(), P()),
-            out_specs=(P(), P()),
-        )
 
-        def run(src, dst, w_e, emask, has_out, n_ref,
-                ranks0=None, has_rank0=None):
-            # optional carry-in: the checkpointed driver resumes the
-            # power iteration mid-schedule (iterations are
-            # time-invariant, so segmenting the scan is bitwise-exact)
-            if ranks0 is None:
-                ranks0 = jnp.where(has_out > 0, 1.0 / n_ref, 0.0)  # :47
-            if has_rank0 is None:
-                has_rank0 = has_out
+def _fused_run(mesh: Mesh, config: PageRankConfig, V: int,
+               spmv: DeviceSpMV):
+    """The fully-fused tiled SpMV: gather AND scatter in one Pallas
+    kernel, no XLA random-access op in the sweep, the plan's arrays
+    its arguments."""
+    from tpu_distalg.ops import pallas_pagerank as ppr
 
-            def step(carry, _):
-                ranks, has_rank = carry
-                c, received = sweep_fn(src, dst, w_e, emask, ranks,
-                                       has_rank)
-                new_has = (received > 0).astype(jnp.float32)
-                ranks = jnp.where(
-                    received > 0, q / n_ref + (1 - q) * c, 0.0
-                )  # :57
-                return (ranks, new_has), None
+    interpret = not mesh_on_tpu(mesh)
+    rg, ws, r8, blk = spmv.rg, spmv.ws, spmv.r8, spmv.blk
+    rows = spmv.n_groups * rg         # the ranks table, whole groups
 
-            (ranks, has_rank), _ = jax.lax.scan(
-                step, (ranks0, has_rank0), None,
-                length=config.n_iterations,
-            )
-            return ranks, has_rank
+    def body(gb, sb, slane, srow, drow, dlane, we, ranks):
+        with jax.named_scope(names.PAGERANK_SPMV):
+            rt = jnp.pad(ranks, (0, rows * 128 - V)).reshape(rows, 128)
+            acc = ppr.spmv_table(gb, sb, rt, slane, srow, drow,
+                                 dlane, we, rg=rg, ws=ws, r8=r8,
+                                 blk=blk,
+                                 seg_steps=spmv.seg_steps or None,
+                                 interpret=interpret)
+        return tree_allreduce_sum(acc)
 
-        return jax.jit(run)
+    sweep_fn = data_parallel(
+        body, mesh,
+        in_specs=(P("data"), P("data"))
+        + (P("data", None),) * 5 + (P(),),
+        out_specs=P(),
+    )
 
-    if (config.mode == "standard"
-            and config.scatter in ("auto", "spmv")
-            and spmv is not None):
-        # Path E: the fully-fused tiled SpMV — gather AND scatter in
-        # one Pallas kernel, no XLA random-access op in the sweep.
-        # 'auto' prefers it
-        from tpu_distalg.ops import pallas_pagerank as ppr
+    def run(plan, has_out, ranks0=None):
+        if ranks0 is None:
+            ranks0 = jnp.full((V,), 1.0 / V, dtype=jnp.float32)
 
-        interpret = not mesh_on_tpu(mesh)
-        rg, ws, r8, blk = spmv.rg, spmv.ws, spmv.r8, spmv.blk
-        rows = spmv.n_groups * rg         # the ranks table, whole groups
-
-        def body(gb, sb, slane, srow, drow, dlane, we, ranks):
-            with jax.named_scope(names.PAGERANK_SPMV):
-                rt = jnp.pad(ranks, (0, rows * 128 - V)).reshape(rows, 128)
-                acc = ppr.spmv_table(gb, sb, rt, slane, srow, drow,
-                                     dlane, we, rg=rg, ws=ws, r8=r8,
-                                     blk=blk,
-                                     seg_steps=spmv.seg_steps or None,
-                                     interpret=interpret)
-            return tree_allreduce_sum(acc)
-
-        sweep_fn = data_parallel(
-            body, mesh,
-            in_specs=(P("data"), P("data"))
-            + (P("data", None),) * 5 + (P(),),
-            out_specs=P(),
-        )
-
-        def run(plan, has_out, ranks0=None):
-            if ranks0 is None:
-                ranks0 = jnp.full((V,), 1.0 / V, dtype=jnp.float32)
-
-            def step(ranks, _):
-                acc = sweep_fn(*plan, ranks)
-                with jax.named_scope(names.PAGERANK_UPDATE):
-                    c = acc[:r8].reshape(-1)[:V]
-                    if config.redistribute_dangling:
-                        dangling = jnp.sum(ranks * (1.0 - has_out))
-                        c = c + dangling / V
-                    ranks = q / V + (1 - q) * c
-                return ranks, None
-
-            ranks, _ = jax.lax.scan(
-                step, ranks0, None, length=config.n_iterations
-            )
-            return ranks, jnp.ones((V,), dtype=jnp.float32)
-
-        return _PlanBound(jax.jit(run), spmv.arrays)
-
-    if use_pallas:
-        from tpu_distalg.ops import pallas_pagerank as ppr
-
-        interpret = not mesh_on_tpu(mesh)
-        w, r8, blk = plan.w, plan.r8, plan.blk
-        nch_local = plan.n_chunks // mesh.shape[DATA_AXIS]
-        chunk = plan.row.shape[1]
-
-        def body(src, w_e, base, row, lane, ranks):
-            g = (ranks[src] * w_e).reshape(nch_local, chunk)
-            acc = ppr.scatter_table(base, g, row, lane, w=w, r8=r8,
-                                    blk=blk, interpret=interpret)
-            return tree_allreduce_sum(acc)
-
-        sweep_fn = data_parallel(
-            body, mesh,
-            in_specs=(P("data"), P("data"), P("data"),
-                      P("data", None), P("data", None), P()),
-            out_specs=P(),
-        )
-
-        def run(src, dst, w_e, emask, has_out, n_ref,
-                ranks0=None, has_rank0=None):
-            del dst, emask, n_ref, has_rank0  # plan encodes padded dst
-            if ranks0 is None:
-                ranks0 = jnp.full((V,), 1.0 / V, dtype=jnp.float32)
-
-            def step(ranks, _):
-                acc = sweep_fn(src, w_e, plan.base, plan.row,
-                               plan.lane, ranks)
+        def step(ranks, _):
+            acc = sweep_fn(*plan, ranks)
+            with jax.named_scope(names.PAGERANK_UPDATE):
                 c = acc[:r8].reshape(-1)[:V]
-                if config.redistribute_dangling:
-                    dangling = jnp.sum(ranks * (1.0 - has_out))
-                    c = c + dangling / V
-                ranks = q / V + (1 - q) * c
-                return ranks, None
+                ranks = _teleport(config, V, ranks, c, has_out)
+            return ranks, None
 
-            ranks, _ = jax.lax.scan(
-                step, ranks0, None, length=config.n_iterations
-            )
-            return ranks, jnp.ones((V,), dtype=jnp.float32)
+        ranks, _ = jax.lax.scan(
+            step, ranks0, None, length=config.n_iterations
+        )
+        return ranks, jnp.ones((V,), dtype=jnp.float32)
 
-        return jax.jit(run)
+    return _PlanBound(jax.jit(run), spmv.arrays)
 
-    # standard mode, XLA path: every vertex ranked, Σranks preserved;
-    # one gather + one sorted scatter per iteration
+
+def _hybrid_run(mesh: Mesh, config: PageRankConfig, V: int,
+                plan: DevicePlan):
+    """XLA's ``ranks[src] * w`` gather, then the windowed one-hot-MXU
+    scatter (``ops/pallas_pagerank.scatter_table``) over ``plan``."""
+    from tpu_distalg.ops import pallas_pagerank as ppr
+
+    interpret = not mesh_on_tpu(mesh)
+    w, r8, blk = plan.w, plan.r8, plan.blk
+    nch_local = plan.n_chunks // mesh.shape[DATA_AXIS]
+    chunk = plan.row.shape[1]
+
+    def body(src, w_e, base, row, lane, ranks):
+        g = (ranks[src] * w_e).reshape(nch_local, chunk)
+        acc = ppr.scatter_table(base, g, row, lane, w=w, r8=r8,
+                                blk=blk, interpret=interpret)
+        return tree_allreduce_sum(acc)
+
+    sweep_fn = data_parallel(
+        body, mesh,
+        in_specs=(P("data"), P("data"), P("data"),
+                  P("data", None), P("data", None), P()),
+        out_specs=P(),
+    )
+
+    def run(src, dst, w_e, emask, has_out, n_ref,
+            ranks0=None, has_rank0=None):
+        del dst, emask, n_ref, has_rank0  # plan encodes padded dst
+        if ranks0 is None:
+            ranks0 = jnp.full((V,), 1.0 / V, dtype=jnp.float32)
+
+        def step(ranks, _):
+            acc = sweep_fn(src, w_e, plan.base, plan.row,
+                           plan.lane, ranks)
+            c = acc[:r8].reshape(-1)[:V]
+            return _teleport(config, V, ranks, c, has_out), None
+
+        ranks, _ = jax.lax.scan(
+            step, ranks0, None, length=config.n_iterations
+        )
+        return ranks, jnp.ones((V,), dtype=jnp.float32)
+
+    return jax.jit(run)
+
+
+def _xla_run(mesh: Mesh, config: PageRankConfig, V: int):
+    """Standard mode in XLA: every vertex ranked, Σranks preserved; one
+    gather + one sorted scatter per iteration."""
     def body(src, dst, w_e, ranks):
         c = gops.contribs(ranks, src, dst, w_e, V, indices_sorted=True)
         return tree_allreduce_sum(c)
@@ -740,11 +753,7 @@ def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
 
         def step(ranks, _):
             c = sweep_fn(src, dst, w_e, ranks)
-            if config.redistribute_dangling:
-                dangling = jnp.sum(ranks * (1.0 - has_out))
-                c = c + dangling / V
-            ranks = q / V + (1 - q) * c
-            return ranks, None
+            return _teleport(config, V, ranks, c, has_out), None
 
         ranks, _ = jax.lax.scan(
             step, ranks0, None, length=config.n_iterations
@@ -754,27 +763,52 @@ def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
     return jax.jit(run)
 
 
+def make_run_fn(mesh: Mesh, config: PageRankConfig, n_vertices: int,
+                plan: DevicePlan | None = None,
+                spmv: DeviceSpMV | None = None):
+    """Build the jitted n-iteration sweep :func:`sweep_form` names for
+    ``config`` and the plans that came: ``spmv`` the fused sweep's,
+    ``plan`` the hybrid's.
+
+    PRECONDITION of every sweep but the fused one: the edge arrays
+    passed to the returned ``run`` MUST be dst-sorted per shard with
+    order-preserving padding — exactly what
+    :func:`prepare_device_edges` produces. The segment-sums inside promise
+    ``indices_are_sorted=True`` to XLA, which is unchecked: unsorted
+    ``dst`` yields silently wrong rank sums, not an error. Construct the
+    inputs via :func:`prepare_device_edges` (or :func:`run`, which does).
+    The fused sweep's ``run`` has the same signature and reads of it
+    ``has_out`` and the carry alone: its plan holds the edges.
+    """
+    form = sweep_form(config, spmv is not None, plan is not None)
+    if form == "reference":
+        return _reference_run(mesh, config, n_vertices)
+    if form == "fused":
+        return _fused_run(mesh, config, n_vertices, spmv)
+    if form == "hybrid":
+        return _hybrid_run(mesh, config, n_vertices, plan)
+    return _xla_run(mesh, config, n_vertices)
+
+
 def run(edges: np.ndarray, mesh: Mesh,
         config: PageRankConfig = PageRankConfig(),
         n_vertices: int | None = None, *,
         checkpoint_dir: str | None = None,
         checkpoint_every: int = 5) -> PageRankResult:
     el = gops.prepare_edges(edges, n_vertices)
-    if config.mode == "standard" and config.scatter in ("auto", "spmv"):
-        spmv = prepare_device_spmv(el, mesh)
-    else:
-        spmv = None
+    # each plan is made only where it would run; a refused fused plan
+    # is counted (spmv_plan_rejections) and said (spmv_span_rejected /
+    # spmv_vmem_rejected) where it is refused
+    spmv = (prepare_device_spmv(el, mesh)
+            if sweep_form(config, fused=True, hybrid=True) == "fused"
+            else None)
+    fused = spmv is not None
     de = prepare_device_edges(
         el, mesh,
-        # the hybrid plan is only needed when it will actually run:
-        # explicit 'pallas', or 'auto' falling back from a failed spmv
-        build_plan=(config.mode == "standard"
-                    and (config.scatter == "pallas"
-                         or (config.scatter == "auto"
-                             and spmv is None))),
-        # when the spmv path will run, skip the dst-sort prep + edge
-        # uploads it deletes anyway
-        light=spmv is not None)
+        build_plan=sweep_form(config, fused, hybrid=True) == "hybrid",
+        # the fused sweep reads no edge array: skip the dst-sort prep
+        # and the uploads
+        light=fused)
     de.spmv = spmv
     return _run_prepared(de, mesh, config, checkpoint_dir,
                          checkpoint_every)
@@ -788,8 +822,7 @@ def run_rmat(mesh: Mesh, config: PageRankConfig, scale: int,
     deduplicated and planned on the device (:func:`build_rmat_graph`),
     ranked by the fused sweep. A span past the geometry's window is an
     error here: the loader has no host copy to fall back with."""
-    if config.mode != "standard" or \
-            config.scatter not in ("auto", "spmv"):
+    if sweep_form(config, fused=True, hybrid=True) != "fused":
         raise ValueError(
             "a graph drawn on the device is ranked by the fused sweep: "
             "mode='standard', scatter 'auto' or 'spmv'")
@@ -863,7 +896,8 @@ def _run_segmented(de: DeviceEdges, mesh: Mesh, config: PageRankConfig,
         span_fields=(dict(ranks_form=de.spmv.ranks_form, rg=de.spmv.rg,
                           ws=de.spmv.ws,
                           scatter_passes=ppr.SCATTER_PASSES)
-                     if de.spmv is not None and config.mode == "standard"
-                     and config.scatter in ("auto", "spmv") else None))
+                     if sweep_form(config, de.spmv is not None,
+                                   de.plan is not None) == "fused"
+                     else None))
     return PageRankResult(ranks=jnp.asarray(state["ranks"]),
                           has_rank=jnp.asarray(state["has_rank"]))

@@ -77,9 +77,8 @@ ms with the gather loop whole in one turn, and 828.7 / 920.8 / 1095.2 /
 chain, and tiles overlap only inside a turn). The static schedule of a
 chipless compile said why: a chunk was 4333 bundles (at 1.5 GHz,
 264 240 chunks: 0.76 s), 2895 of them the scatter's eight ``HIGHEST``
-matmuls and 1438 the 512 gathered rows. PR 39,
-``scripts/step0_pagerank_scatter.py``: with three passes a chunk is
-3062 bundles, 1641 the scatter and 1421 the gather, and a sweep takes
+matmuls and 1438 the 512 gathered rows. PR 39 (PERF.md section 6):
+with three passes a chunk is 3062 bundles, 1641 the scatter and 1421 the gather, and a sweep takes
 521.9 ms, 1.93 ns a slot (754.4 the six passes in the same run); at
 SCALE 20 (rg 128, ws 72) 12.2 ms, 0.70 ns a slot (16.9, 0.98). Each of
 the four MXUs streams a row a cycle and pops a row a cycle, so 224
