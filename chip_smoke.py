@@ -583,8 +583,12 @@ def stage_pagerank_rmat(s: Smoke):
     """Graph500 SCALE 20 drawn, deduplicated and planned on the device
     (``pagerank.build_rmat_graph`` / ``prepare_device_spmv``), 3 fused
     sweeps against the XLA sweep on the same edges pulled to the host,
-    at 1e-5 relative; on several chips the plan's chunks are sharded
-    by the ``pagerank`` rule table and the ranks replicated."""
+    at 1e-5 relative; on several chips the graph is sharded by
+    destination range, the ranges cut to equal loads: a chip's slots
+    and plan hold the edges that point into its range (``pagerank``
+    rule table), the ranks it reads
+    and the ranks the run returns are whole on every chip, and the
+    ranks a sweep writes are the chip's own range."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -596,6 +600,7 @@ def stage_pagerank_rmat(s: Smoke):
     mesh = s.mesh()
     t0 = time.perf_counter()
     graph = pagerank.build_rmat_graph(mesh, scale, 16, None, seed)
+    slots = (s.check_sharded("slots", graph.src) if s.n > 1 else "")
     plan = pagerank.prepare_device_spmv(graph, mesh)
     t_plan = time.perf_counter() - t0
     if plan is None:
@@ -620,7 +625,24 @@ def stage_pagerank_rmat(s: Smoke):
            f"{graph.n_in} | ranks {plan.ranks_form} rg {plan.rg} ws "
            f"{plan.ws} | draw, dedup and plan {t_plan:.1f}s"]
     if s.n > 1:
+        cuts = np.asarray(plan.bounds)
+        if plan.ranks_out_form != "range" or cuts[-1] != plan.r8 \
+                or not 0 <= np.diff(cuts).max() <= plan.rows_out < plan.r8:
+            raise AssertionError(
+                f"output ranks {plan.ranks_out_form}: ranges cut at "
+                f"{cuts.tolist()} of {plan.r8} table rows, a chip's "
+                f"table {plan.rows_out}")
+        if sum(graph.shard_edges) != graph.n_edges \
+                or max(graph.shard_edges) > graph.geom.shard_cap:
+            raise AssertionError(
+                f"shards hold {graph.shard_edges} of {graph.n_edges} "
+                f"distinct edges, capacity {graph.geom.shard_cap}")
+        out.append(f"ranks out: ranges cut at rows {cuts.tolist()}, "
+                   f"edges a chip {list(graph.shard_edges)}")
+        out.append(slots)
         out.append(s.check_sharded("ranks", got, replicated=True))
+        out.append(s.check_sharded("has_out", de.has_out,
+                                   replicated=True))
         out.append(s.check_sharded("src_lane", plan.src_lane))
         out.append(s.check_memory_everywhere())
     return " | ".join(out)

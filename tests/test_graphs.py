@@ -371,6 +371,13 @@ def test_resident_guard_degrades_to_streamed():
     backend, warn = m.choose_data_backend("resident", 50_000_000)
     assert backend == "streamed"
     assert "--data-backend streamed" in warn
+    # the ceiling is a shard's: the warning says how many hold the
+    # graph, and a mesh of that many keeps it resident
+    assert "a mesh of 2 data shards" in warn
+    assert not m.resident_guard_trips(50_000_000, 2)
+    backend, warn = m.choose_data_backend("resident", 50_000_000,
+                                          n_shards=2)
+    assert backend == "resident" and warn is None
     # an explicit streamed request never degrades or warns
     backend, warn = m.choose_data_backend("streamed", 50_000_000)
     assert backend == "streamed" and warn is None
@@ -384,20 +391,21 @@ def test_resident_guard_degrades_to_streamed():
     assert backend == "streamed"
 
 
-def test_vmem_rejection_event_names_streamed_remedy(tmp_path):
+def test_vmem_rejection_event_names_the_shards_needed(tmp_path):
     from tpu_distalg.ops import pallas_pagerank as ppr
     from tpu_distalg.telemetry import events, report
 
     sink = str(tmp_path / "tele")
     events.configure(sink)
     try:
-        ppr._emit_vmem_rejection(50_000_000, ppr.SPMV_RG)
+        assert ppr.spmv_geometry(1 << 26, 16 << 26, 2) is None
     finally:
         events.configure(False)
     evts = [e for e in report.load_events(sink)
             if e.get("ev") == "spmv_vmem_rejected"]
     assert len(evts) == 1
-    assert "--data-backend streamed" in evts[0]["remedy"]
+    assert evts[0]["n_shards"] == 2 and evts[0]["shards_needed"] == 4
+    assert "a mesh of 4 data shards" in evts[0]["remedy"]
 
 
 # ------------------------------------------------- review-round pins

@@ -28,15 +28,17 @@ def one_chip():
 
 
 def _compile(geom, one_chip, seg_steps=None):
+    """A shard's kernel calls: its own chunks, its own range's table."""
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    per_slot = (geom.n_chunks * 8, 128)
+    n_chunks = geom.n_chunks // geom.n_shards
+    per_slot = (n_chunks * 8, 128)
     return jax.jit(lambda *a: ppr.spmv_table(
-        *a, rg=geom.rg, ws=geom.ws, r8=geom.r8, blk=geom.blk,
+        *a, rg=geom.rg, ws=geom.ws, r8=geom.rows_out, blk=geom.blk,
         seg_steps=seg_steps or geom.seg_steps)).lower(
-            arr((geom.n_chunks,), jnp.int32),
-            arr((geom.n_chunks,), jnp.int32),
+            arr((n_chunks,), jnp.int32),
+            arr((n_chunks,), jnp.int32),
             arr((geom.n_groups * geom.rg, 128), jnp.float32),
             *[arr(per_slot, jnp.int32)] * 4,
             arr(per_slot, jnp.float32)).compile()
@@ -47,6 +49,28 @@ def test_kernel_compiles_at_graph500_scale_24(one_chip):
     mem = _compile(geom, one_chip).memory_analysis()
     assert mem.output_size_in_bytes == (geom.r8 + geom.ws) * 512
     assert 5.4e9 < mem.argument_size_in_bytes < 5.6e9
+
+
+@pytest.mark.parametrize("scale,ws", [(26, None), (25, None),
+                                      (26, ppr.SPMV_WS_CAP)])
+def test_kernel_compiles_at_a_quarter_of_graph500_scale_26(one_chip,
+                                                           scale, ws):
+    """The four-chip cell's shard (and SCALE 25's, the cut the cell
+    would take): groups of the whole 524 288-row table, the output
+    table of the shard's own rows (a quarter of them and room for a
+    range that is cut wide) in VMEM with the wider window a sparser
+    block needs; and the widest window the geometry
+    admits beside that table."""
+    import dataclasses
+
+    geom = ppr.spmv_geometry(1 << scale, 16 << scale, 4)
+    assert geom.r8 / 4 < geom.rows_out < 1.05 * geom.r8 / 4
+    if ws:
+        geom = dataclasses.replace(geom, ws=ws)
+    mem = _compile(geom, one_chip).memory_analysis()
+    assert mem.output_size_in_bytes == (geom.rows_out + geom.ws) * 512
+    if scale == 26:
+        assert 5.5e9 < mem.argument_size_in_bytes < 6.0e9
 
 
 def test_a_whole_sweeps_scalars_do_not_fit_smem(one_chip):

@@ -102,25 +102,29 @@ def test_spmv_plan_and_kernel_match_numpy():
 
 def _spmv(plan, ranks, v, seg_steps=None, geom=None):
     """One interpreted sweep of a host plan over a ranks vector: a call
-    a shard on its slice of the chunks (as ``shard_map`` hands them
-    out), the tables added as the psum adds them. ``geom`` stands in
-    for the plan's own (a wider scatter window over the same slots)."""
+    a shard on its own slots (as ``shard_map`` hands them out), each
+    writing the table of its destination range, the tables laid in
+    the ranges' order where their ranges start, as the sweep lays
+    them. ``geom`` stands in for the plan's own (a wider scatter
+    window over the same slots)."""
     g = geom or plan.geom
     rt = np.zeros((g.n_groups * g.rg, 128), np.float32)
     rt.reshape(-1)[:v] = ranks
     per = g.n_steps * g.blk                     # chunks a shard
-    total = np.zeros((g.r8 + g.ws, 128), np.float32)
+    out = np.zeros((g.r8 + g.rows_out, 128), np.float32)
     for k in range(g.n_shards):
         chunks = slice(k * per, (k + 1) * per)
         slots = slice(8 * k * per, 8 * (k + 1) * per)
-        total += np.asarray(ppr.spmv_table(
+        out[plan.bounds[k]:plan.bounds[k] + g.rows_out] = np.asarray(
+            ppr.spmv_table(
             jnp.asarray(plan.gbase[chunks]), jnp.asarray(plan.sbase[chunks]),
             jnp.asarray(rt), *(jnp.asarray(a[slots]) for a in (
                 plan.src_lane, plan.src_row, plan.dst_row, plan.dst_lane,
                 plan.w_e)),
-            rg=g.rg, ws=g.ws, r8=g.r8, blk=g.blk,
-            seg_steps=seg_steps or g.seg_steps, interpret=True))
-    return total[:g.r8].reshape(-1)[:v]
+            rg=g.rg, ws=g.ws, r8=g.rows_out, blk=g.blk,
+            seg_steps=seg_steps or g.seg_steps,
+            interpret=True))[:g.rows_out]
+    return out.reshape(-1)[:v]
 
 
 # a scatter window's rows -> the gather group's rows and the edges that
@@ -165,7 +169,10 @@ def test_scatter_product_keeps_every_bit(ws, n_shards, monkeypatch):
     np.testing.assert_array_equal(sweep(once).view(np.uint32),
                                   want.view(np.uint32))
 
-    hubs = 128 * rng.integers(0, 2, size=8) + rng.permutation(128)[:8]
+    # two hubs a destination range (a star on one range would pass
+    # its shard's capacity, as it should)
+    hubs = 128 * rng.integers(0, 2, size=8) + rng.permutation(128)[:8] \
+        + np.arange(8) % n_shards * (V // n_shards)
     star = hubs[rng.integers(0, 8, size=e)]
     got = sweep(star)
     assert not got[np.setdiff1d(np.arange(V), hubs)].any()
@@ -331,25 +338,25 @@ def test_run_auto_prefers_spmv_and_matches_xla(mesh8):
 
 
 def test_spmv_sparse_graph_takes_a_taller_gather_window(mesh8):
-    """A graph whose within-group dst span overflows at rg=128 (the
-    span grows as R²/(rg·E)) is given a taller gather window from its
-    sizes alone, with no attempt at 128 — the 10M-vertex regime in
-    miniature. A span past the window fixed for a forced rg=128 is
-    reported (``None``, counted), not hidden. Plan invariants are
+    """A graph whose within-group dst span is wide at rg=128 (the span
+    grows as R²/(rg·E)) is given a taller gather window from its sizes
+    alone, the height whose chunk costs least by the schedule law: the
+    10M-vertex regime in miniature. A forced rg=128 pays for its short
+    groups with a window four times as wide. Plan invariants are
     checked; the tall kernel's numerics are verified on hardware."""
     v, e = 1_000_000, 1_000_000
     edges = _random_graph(v, e, seed=7)
     el = gops.prepare_edges(edges, v)
-    # rg=128 must fail on this sparsity...
-    assert pagerank.prepare_device_spmv(el, mesh8, rg=128) is None
-    # ...and the geometry the sizes give must land a valid taller plan
     spmv = pagerank.prepare_device_spmv(el, mesh8)
     assert spmv is not None
-    assert spmv.rg > 128
+    assert spmv.rg > 128 and spmv.ranks_out_form == "range"
     assert spmv.ws <= ppr.SPMV_WS_CAP
+    short = pagerank.prepare_device_spmv(el, mesh8, rg=128)
+    assert short is not None and short.ws > 3 * spmv.ws
     # window-relative indices must honor the planned windows
     assert int(np.asarray(spmv.src_row).max()) < spmv.rg
     assert int(np.asarray(spmv.dst_row).max()) < spmv.ws
+    assert int(np.asarray(spmv.sbase).max()) < spmv.rows_out
 
 
 def test_spmv_without_plan_raises(mesh8):
@@ -397,15 +404,19 @@ def test_sweep_form_is_the_whole_rule(mode, scatter, fused, hybrid, want):
 
 
 def test_auto_on_a_sparse_graph_is_the_hybrid_sweep_and_says_so(
-        mesh8, tmp_path):
-    """A graph too sparse for the fused window (2^20 vertices, 400k
-    edges: sixteen groups at rg 512 put a chunk's mean span at 335
-    rows, past ``SPMV_WS_CAP``) whose 1024 destination-sorted edges
-    still span under 32 rows: ``'auto'`` ranks it by the hybrid sweep,
-    bit for bit as ``scatter='pallas'`` does and as the XLA sweep does
-    to float32 noise, and the fused plan's refusal is counted and
-    named (one v5e reads this regime 2.1x ahead of XLA: PERF.md, PR
-    43)."""
+        mesh8, tmp_path, monkeypatch):
+    """A graph too sparse for the fused window whose 1024
+    destination-sorted edges still span under 32 rows: ``'auto'``
+    ranks it by the hybrid sweep, bit for bit as ``scatter='pallas'``
+    does and as the XLA sweep does to float32 noise, and the fused
+    plan's refusal is counted and named (one v5e reads this regime
+    2.1x ahead of XLA: PERF.md, PR 43). With the heights and the
+    window cap that ship (PR 44) the regime starts past 14M vertices;
+    the caps of PR 43 bring it to a size the interpreter runs: 2^20
+    vertices, 400k edges, sixteen groups at rg 512, a chunk's mean
+    span 335 rows."""
+    monkeypatch.setattr(ppr, "SPMV_RGS", (128, 256, 512))
+    monkeypatch.setattr(ppr, "SPMV_WS_CAP", 256)
     v, e = 1 << 20, 400_000
     edges = _random_graph(v, e, seed=3)
     cfg = pagerank.PageRankConfig(n_iterations=4, mode="standard")

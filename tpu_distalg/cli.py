@@ -1543,8 +1543,9 @@ def _dispatch(args, jax):
             if args.edge_file is None and args.n_vertices:
                 n_v = max(n_v, args.n_vertices)
         mesh = _mesh(args)
-        backend, warn = m.choose_data_backend(args.data_backend, n_v,
-                                              scatter=args.scatter)
+        backend, warn = m.choose_data_backend(
+            args.data_backend, n_v, scatter=args.scatter,
+            n_shards=int(mesh.shape["data"]))
         if warn:
             print(warn, file=sys.stderr)
         if backend != "resident" and args.mode == "reference":
@@ -1560,9 +1561,11 @@ def _dispatch(args, jax):
             if backend != "resident":
                 raise SystemExit(
                     f"[pagerank] 2**{args.rmat_scale} vertices are past "
-                    f"the resident fused sweep, and --rmat-scale draws "
-                    f"its graph on the device only: write the edges to "
-                    f"a file for --data-backend streamed")
+                    f"the resident fused sweep on this mesh (the "
+                    f"warning above says how many data shards hold "
+                    f"them), and --rmat-scale draws its graph on the "
+                    f"device only: write the edges to a file for "
+                    f"--data-backend streamed")
             res = ckpt.run_with_restarts(
                 lambda: m.run_rmat(
                     mesh, m.PageRankConfig(

@@ -42,7 +42,22 @@ not bandwidth — bounds the sweep):
     every graph (:func:`prepare_device_spmv`), and a Graph500
     Kronecker graph is drawn and deduplicated there too
     (:func:`build_rmat_graph`): SCALE 24, 268M generated edges, drawn,
-    deduplicated and planned on one chip in 8.3 s warm (PERF.md, PR 38).
+    deduplicated and planned on one chip in 8.3 s warm (PERF.md, PR 38);
+  * on a mesh the fused sweep is sharded by DESTINATION RANGE, one
+    form for every shard count: a shard holds the edges that point
+    into its range (drawn a slice of the ids a shard and exchanged by
+    one ``all_to_all`` at load, the shuffle the reference's
+    ``reduceByKey`` pays every sweep), plans them itself, reads the
+    whole ranks vector and writes the output table of its own range,
+    which is what VMEM has to hold; the new ranges are all-gathered
+    once a sweep and the dangling mass is a scalar psum. No chip holds
+    the whole edge list or the whole output table: Graph500 SCALE 26
+    (67M vertices, 1.07G edges) on four chips (PERF.md, PR 44). The
+    ranges are cut where the edges are, a quarter of them a shard
+    (``ops/pallas_pagerank.balanced_bounds``): a sweep ends when its
+    fullest shard does. The
+    XLA, hybrid and reference sweeps shard a destination-sorted list
+    by position and all-reduce whole tables.
 
 Two modes (SURVEY.md §7 hard part #6):
   * ``mode='reference'`` reproduces the reference's semantics exactly: n is
@@ -57,7 +72,6 @@ Two modes (SURVEY.md §7 hard part #6):
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -67,9 +81,13 @@ from jax.sharding import Mesh, PartitionSpec as P
 from tpu_distalg.ops import graph as gops
 from tpu_distalg.parallel import (
     DATA_AXIS,
+    all_gather,
+    all_to_all,
+    comms,
     data_parallel,
     mesh_on_tpu,
     partition,
+    replica_index,
     tree_allreduce_sum,
 )
 from tpu_distalg.telemetry import events as tevents
@@ -125,22 +143,45 @@ class DeviceSpMV:
     n_chunks: int
     seg_steps: int = 0    # grid steps a kernel call (0: one call)
     n_groups: int = 1
+    rows_out: int = 0     # rows of a shard's output table (0: r8)
+    n_shards: int = 1
+    bounds: jax.Array | None = None   # (n_shards + 1,) int32: where the
+    # destination ranges are cut, in table rows; None: equal widths
 
     LEAVES = ("gbase", "sbase", "src_lane", "src_row", "dst_row",
               "dst_lane", "w_e")   # the arrays, by their rule-table names
 
     @classmethod
-    def of(cls, arrays, geom) -> "DeviceSpMV":
+    def of(cls, arrays, geom, bounds=None) -> "DeviceSpMV":
         """The seven arrays (in ``LEAVES``' order) of a plan of
-        ``geom`` (``ops.pallas_pagerank.SpMVGeometry``)."""
+        ``geom`` (``ops.pallas_pagerank.SpMVGeometry``) whose ranges
+        are cut at ``bounds``."""
         return cls(*arrays, rg=geom.rg, ws=geom.ws, r8=geom.r8,
                    blk=geom.blk, n_chunks=geom.n_chunks,
-                   seg_steps=geom.seg_steps, n_groups=geom.n_groups)
+                   seg_steps=geom.seg_steps, n_groups=geom.n_groups,
+                   rows_out=geom.rows_out, n_shards=geom.n_shards,
+                   bounds=bounds)
 
     @property
     def ranks_form(self) -> str:
         """'windowed' past one gather group, else 'resident'."""
         return "windowed" if self.n_groups > 1 else "resident"
+
+    @property
+    def ranks_out_form(self) -> str:
+        """'range' where a shard writes its own destination range of
+        the table (``rows_out`` of ``r8`` rows), 'whole' on one."""
+        return "range" if self.n_shards > 1 else "whole"
+
+    @property
+    def forms(self) -> dict:
+        """What the spans say of the sweep (``tda report``'s ``ranks
+        table`` line)."""
+        from tpu_distalg.ops import pallas_pagerank as ppr
+
+        return dict(ranks_form=self.ranks_form,
+                    ranks_out_form=self.ranks_out_form, rg=self.rg,
+                    ws=self.ws, scatter_passes=ppr.SCATTER_PASSES)
 
     @property
     def arrays(self) -> tuple:
@@ -154,11 +195,13 @@ class DeviceSpMV:
 @dataclasses.dataclass
 class DeviceGraph:
     """A graph's distinct edges on the device as the planner takes
-    them: ``geom.n_slots`` slots, an edge wherever ``src >= 0`` among
-    the first ``n_in``, spare slots behind."""
+    them: ``geom.shard_slots`` slots a shard, the edges whose
+    destination lies in the shard's range (``bounds``) wherever ``src
+    >= 0`` among its first ``geom.shard_cap``, spare slots behind.
+    ``n_in`` counts the edges that came in, all shards together."""
 
-    src: jax.Array        # (n_slots,) int32, -1 where no edge
-    dst: jax.Array        # (n_slots,) int32
+    src: jax.Array        # (n_slots,) int32 over data, -1 where no edge
+    dst: jax.Array        # (n_slots,) int32 over data
     inv_deg: jax.Array    # (V,) f32, 1 / distinct out-edges, 0 for none
     has_out: jax.Array    # (V,) f32
     n_in: int
@@ -166,6 +209,8 @@ class DeviceGraph:
     n_edges: int          # distinct edges
     geom: object          # ops.pallas_pagerank.SpMVGeometry
     meta: dict = dataclasses.field(default_factory=dict)
+    shard_edges: tuple = ()   # distinct edges a shard
+    bounds: jax.Array | None = None   # as DeviceSpMV's
 
 
 @dataclasses.dataclass
@@ -184,17 +229,23 @@ class DeviceEdges:
     spmv: DeviceSpMV | None = None  # fused Path E prep (scatter='spmv')
 
 
-def resident_guard_trips(n_vertices: int) -> bool:
-    """True when the fused SpMV cannot hold this many vertices: its
-    output table, 4 B a vertex, has to stay in VMEM
-    (``ops/pallas_pagerank.SPMV_VMEM_BUDGET``: 26M vertices). The
-    signal the CLI keys its warn-and-degrade-to-streamed on: past this
-    line the resident paths either refuse (spmv) or fall back to
+def resident_guard_trips(n_vertices: int, n_shards: int = 1) -> bool:
+    """True when the fused SpMV cannot hold this many vertices on this
+    many shards: a shard's output table, 4 B a vertex of its
+    destination range, has to stay in VMEM
+    (``ops/pallas_pagerank.SPMV_VMEM_BUDGET``: 26M vertices a shard).
+    The signal the CLI keys its warn-and-degrade-to-streamed on: past
+    this line the resident paths either refuse (spmv) or fall back to
     sweeps that need the whole edge set HBM-resident anyway."""
     from tpu_distalg.ops import pallas_pagerank as ppr
 
-    return ppr.spmv_resident_bytes(n_vertices, ppr.SPMV_RGS[-1],
-                                   ppr.SPMV_WS_CAP) > ppr.SPMV_VMEM_BUDGET
+    return ppr.shards_needed(n_vertices) > n_shards
+
+
+class ShardOverflow(ValueError):
+    """A destination range drew more edges than a shard's capacity
+    (``ops/pallas_pagerank.SPMV_SHARD_SIGMAS``): the load fails, no
+    edge is dropped."""
 
 
 def sweep_form(config: PageRankConfig, fused: bool, hybrid: bool) -> str:
@@ -232,10 +283,12 @@ def sweep_form(config: PageRankConfig, fused: bool, hybrid: bool) -> str:
         raise ValueError(
             "scatter='spmv' needs the fused-SpMV plan — build the "
             "DeviceSpMV via prepare_device_spmv (None means the "
-            "graph's windows exceeded ops/pallas_pagerank caps, or "
-            "the kernel-resident VMEM footprint blew "
-            "SPMV_VMEM_BUDGET, 4 B a vertex). Graphs "
-            "past the resident ceiling belong on the out-of-core "
+            "graph's windows exceeded ops/pallas_pagerank caps, a "
+            "destination range overflowed its shard, or a shard's "
+            "output table blew SPMV_VMEM_BUDGET, 4 B a vertex of "
+            "its range: ops/pallas_pagerank.shards_needed says how "
+            "many data shards a graph of that size takes). Graphs "
+            "past the mesh's ceiling belong on the out-of-core "
             "engine: --data-backend streamed (tpu_distalg/graphs/ "
             "streams edge blocks from disk; only O(V) state stays "
             "in HBM)"
@@ -248,28 +301,34 @@ def sweep_form(config: PageRankConfig, fused: bool, hybrid: bool) -> str:
 
 
 def choose_data_backend(requested: str, n_vertices: int,
-                        scatter: str = "auto"
+                        scatter: str = "auto", n_shards: int = 1
                         ) -> tuple[str, str | None]:
     """Resolve the pagerank ``--data-backend`` knob against the
-    resident VMEM guard: a resident request past the ceiling degrades
-    to streamed WITH a warning instead of dying in the sweep prep. The
-    ceiling is the fused sweep's table budget, so it applies where a
-    standard-mode sweep under this ``scatter`` would be fused
+    resident VMEM guard: a resident request past the mesh's ceiling
+    degrades to streamed WITH a warning that says how many shards
+    would hold the graph, instead of dying in the sweep prep. The
+    ceiling is the fused sweep's table budget a shard, so it applies
+    where a standard-mode sweep under this ``scatter`` would be fused
     (:func:`sweep_form`); an EXPLICIT ``--scatter xla``/``pallas``
     resident request is honored: those sweeps carry their own
     (HBM/plan) limits with remedy-naming errors.
     Returns ``(backend, warning-or-None)``."""
+    from tpu_distalg.ops import pallas_pagerank as ppr
+
     would_fuse = sweep_form(
         PageRankConfig(mode="standard", scatter=scatter),
         fused=True, hybrid=True) == "fused"
     if requested == "resident" and would_fuse \
-            and resident_guard_trips(n_vertices):
+            and resident_guard_trips(n_vertices, n_shards):
         return "streamed", (
             f"[pagerank] {n_vertices} vertices exceed the resident "
-            f"sweep's VMEM guard (the fused SpMV keeps 4 B a vertex in "
-            f"VMEM, ops/pallas_pagerank.SPMV_VMEM_BUDGET) — degrading "
-            f"to --data-backend streamed (tpu_distalg/graphs/: edge "
-            f"blocks stream from disk, only O(V) state stays in HBM)")
+            f"sweep's VMEM guard on {n_shards} data shard(s) (the fused "
+            f"SpMV keeps 4 B a vertex of a shard's destination range in "
+            f"VMEM, ops/pallas_pagerank.SPMV_VMEM_BUDGET): a mesh of "
+            f"{ppr.shards_needed(n_vertices)} data shards holds it "
+            f"resident; degrading to --data-backend streamed "
+            f"(tpu_distalg/graphs/: edge blocks stream from disk, only "
+            f"O(V) state stays in HBM)")
     return requested, None
 
 
@@ -283,61 +342,162 @@ def _inv_out_degree(el: gops.EdgeList) -> np.ndarray:
     return inv_out_degree(el.out_degree)
 
 
-def _replicated(mesh: Mesh):
-    """The sharding of the edge slots before the plan: whole on every
-    chip (the ``pagerank`` rule table's ``slots``)."""
-    return partition.leaf_sharding("pagerank", "slots", mesh)
+_SLOTS = P(DATA_AXIS)    # a shard's edge slots: the ``pagerank`` rule
+#                          table's ``slots``
 
 
 def rmat_programs(mesh: Mesh, scale: int, abcd, geom, n_in: int):
-    """:func:`build_rmat_graph`'s two jitted programs, ``generate(seed)
-    -> (src, dst)`` and ``dedup(src, dst) -> (src, dst, inv_deg,
-    has_out, n_distinct)`` (the chipless compile check lowers them at
-    the cell's shapes)."""
+    """:func:`build_rmat_graph`'s jitted programs ``generate(seed) ->
+    (src, dst)`` and ``dedup(src, dst) -> (src, dst, inv_deg, has_out,
+    distinct edges a shard)``, every edge array sharded over the data
+    axis (the chipless compile check lowers them at the cell's
+    shapes). A shard draws its slice of the ``n_in`` edge ids; on one
+    shard ``generate`` appends the spare slots, on several
+    :func:`exchange_program` comes between the two and does."""
     from tpu_distalg.utils import datasets
 
-    V = 1 << scale
+    V, n = 1 << scale, geom.n_shards
+    per = -(-n_in // n)                   # ids a shard draws
     draw = datasets.kronecker_edges(scale, abcd)
-    rep = _replicated(mesh)
 
     def generate(seed):
-        src, dst = draw(jnp.arange(n_in, dtype=jnp.uint32), seed)
-        spare = geom.n_slots - n_in      # sorted behind every edge
-        return (jnp.concatenate([src, jnp.full((spare,), V, jnp.int32)]),
-                jnp.concatenate([dst, jnp.zeros((spare,), jnp.int32)]))
+        ids = replica_index().astype(jnp.uint32) * np.uint32(per) \
+            + jnp.arange(per, dtype=jnp.uint32)
+        src, dst = draw(ids, seed)
+        if per * n != n_in:               # ids past the last edge
+            src = jnp.where(ids < n_in, src, V)
+        if n > 1:
+            return src, dst
+        return _spare_behind(src, dst, V, geom.shard_slots - per)
 
     def dedup(src, dst):
         src, dst = jax.lax.sort((src, dst), num_keys=2, is_stable=False)
         again = (src[1:] == src[:-1]) & (dst[1:] == dst[:-1])
         real = (src < V) & ~jnp.concatenate([jnp.zeros((1,), bool), again])
-        deg = jax.ops.segment_sum(real.astype(jnp.int32), src,
-                                  num_segments=V + 1,
-                                  indices_are_sorted=True)[:V]
+        # a duplicate pair shares its destination, so it shares its
+        # shard: the mask is local, the out-degrees add up over shards
+        deg = comms.psum(jax.ops.segment_sum(
+            real.astype(jnp.int32), src, num_segments=V + 1,
+            indices_are_sorted=True)[:V])
         inv_deg = jnp.where(deg > 0, 1.0 / jnp.maximum(deg, 1), 0.0)
         return (jnp.where(real, src, -1), dst,
                 inv_deg.astype(jnp.float32),
-                (deg > 0).astype(jnp.float32), jnp.sum(deg))
+                (deg > 0).astype(jnp.float32),
+                all_gather(jnp.sum(real, dtype=jnp.int32)[None]))
 
-    return (jax.jit(generate, out_shardings=rep),
-            jax.jit(dedup, out_shardings=rep, donate_argnums=(0, 1)))
+    return (jax.jit(data_parallel(generate, mesh, in_specs=P(),
+                                  out_specs=(_SLOTS, _SLOTS))),
+            jax.jit(data_parallel(
+                dedup, mesh, in_specs=(_SLOTS, _SLOTS),
+                out_specs=(_SLOTS, _SLOTS, P(), P(), P())),
+                donate_argnums=(0, 1)))
+
+
+def _spare_behind(src, dst, V: int, spare: int):
+    """``spare`` slots that hold no edge, sorted behind every edge."""
+    return (jnp.concatenate([src, jnp.full((spare,), V, jnp.int32)]),
+            jnp.concatenate([dst, jnp.zeros((spare,), jnp.int32)]))
+
+
+def exchange_program(mesh: Mesh, scale: int, geom):
+    """``exchange(src, dst) -> (src, dst, bounds, overflow)``: every
+    shard's draws sorted by destination, the destination ranges cut
+    where a quarter (an n-th) of all shards' draws lies behind
+    (``ops/pallas_pagerank.balanced_bounds`` over the psummed counts a
+    tile of 8 table rows: ``bounds``, in rows), a shard's draws cut at
+    the same places into one bucket of ``geom.bucket`` slots a range
+    and exchanged by ``all_to_all`` (the shuffle the reference's
+    ``reduceByKey`` pays a sweep, once, at load), the spare slots
+    appended. A shard then holds exactly the drawn edges whose
+    destination lies in its range. ``overflow`` counts the edges that
+    fit no bucket and the rows of a range past a shard's table: the
+    load fails on any."""
+    from tpu_distalg.ops import pallas_pagerank as ppr
+
+    V, n, bucket = 1 << scale, geom.n_shards, geom.bucket
+    tiles = np.arange(geom.r8 // 8 + 1, dtype=np.int32) * (8 * 128)
+
+    def exchange(src, dst):
+        dst, src = jax.lax.sort((jnp.where(src < V, dst, V), src),
+                                num_keys=1, is_stable=False)
+        behind = jnp.searchsorted(dst, tiles, side="left")
+        bounds = ppr.balanced_bounds(
+            jnp, comms.psum(behind[1:] - behind[:-1]), n)
+        start = jnp.searchsorted(dst, bounds * 128, side="left")
+        count = start[1:] - start[:-1]
+        # a bucket is a slice from its range's first edge; room behind
+        # the last edge so that no slice is clamped back into another's
+        src, dst = _spare_behind(src, dst, V, bucket)
+        held = jnp.arange(bucket, dtype=jnp.int32)
+
+        def buckets(x, empty):
+            return jnp.concatenate([
+                jnp.where(held < count[k], jax.lax.dynamic_slice(
+                    x, (start[k],), (bucket,)), empty)
+                for k in range(n)])
+
+        src, dst = all_to_all(buckets(src, V)), all_to_all(buckets(dst, 0))
+        overflow = comms.psum(jnp.sum(jnp.maximum(count - bucket, 0))) \
+            + jnp.sum(jnp.maximum(
+                bounds[1:] - bounds[:-1] - geom.rows_out, 0))
+        return _spare_behind(src, dst, V, geom.shard_slots - n * bucket) \
+            + (bounds, overflow)
+
+    return jax.jit(data_parallel(
+        exchange, mesh, in_specs=(_SLOTS, _SLOTS),
+        out_specs=(_SLOTS, _SLOTS, P(), P())), donate_argnums=(0, 1))
+
+
+def even_bounds(geom) -> np.ndarray:
+    """Ranges of equal width (the whole table on one shard): where a
+    plan of ``geom`` (a geometry or a :class:`DeviceSpMV`: its ``r8``
+    and ``n_shards``) is cut that nobody balanced."""
+    wide = -(-geom.r8 // (8 * geom.n_shards)) * 8
+    return np.minimum(np.arange(geom.n_shards + 1) * wide,
+                      geom.r8).astype(np.int32)
 
 
 def plan_programs(mesh: Mesh, geom, n_in: int):
-    """:func:`prepare_device_spmv`'s two jitted programs: ``sort(src,
-    dst) -> (src, dst)`` in the kernel's order and ``lay_out(src, dst,
-    inv_deg) -> (the seven plan arrays, widest span)``."""
+    """:func:`prepare_device_spmv`'s two jitted programs, each a shard
+    on its own slots: ``sort(src, dst, bounds) -> (src, dst)`` in the
+    kernel's order and ``lay_out(src, dst, inv_deg, bounds) -> (the
+    seven plan arrays, widest span of any shard)``; ``bounds`` as
+    :class:`DeviceSpMV` holds them (left out: :func:`even_bounds`).
+    ``n_in`` counts the edges that came in, all shards together; a
+    shard's lie among its first ``geom.shard_cap`` slots."""
     from tpu_distalg.ops import pallas_pagerank as ppr
 
-    def lay_out(src, dst, inv_deg):
-        w_e = inv_deg[jnp.maximum(src, 0)]
-        arrays, span = ppr.slot_arrays(jnp, src, dst, w_e, geom)
-        return tuple(partition.constrain(a, n, "pagerank", mesh)
-                     for a, n in zip(arrays, DeviceSpMV.LEAVES)), span
+    del n_in
 
-    return (jax.jit(functools.partial(ppr.sort_slots, geom=geom,
-                                      n_in=n_in),
-                    out_shardings=_replicated(mesh),
-                    donate_argnums=(0, 1)),
+    def sort_shard(src, dst, bounds):
+        return ppr.sort_slots(src, dst, geom=geom, n_in=geom.shard_cap,
+                              row0=bounds[replica_index()])
+
+    def lay_out_shard(src, dst, inv_deg, bounds):
+        w_e = inv_deg[jnp.maximum(src, 0)]
+        arrays, span = ppr.slot_arrays(jnp, src, dst, w_e, geom,
+                                       bounds[replica_index()])
+        return arrays, comms.pmax(span)
+
+    leaves = tuple(partition.table("pagerank").spec_for(n, (2, 2))
+                   for n in DeviceSpMV.LEAVES)
+    sort_mesh = data_parallel(sort_shard, mesh,
+                              in_specs=(_SLOTS, _SLOTS, P()),
+                              out_specs=(_SLOTS, _SLOTS))
+    lay_out_mesh = data_parallel(lay_out_shard, mesh,
+                                 in_specs=(_SLOTS, _SLOTS, P(), P()),
+                                 out_specs=(leaves, P()))
+
+    def cut(bounds):
+        return even_bounds(geom) if bounds is None else bounds
+
+    def sort(src, dst, bounds=None):
+        return sort_mesh(src, dst, cut(bounds))
+
+    def lay_out(src, dst, inv_deg, bounds=None):
+        return lay_out_mesh(src, dst, inv_deg, cut(bounds))
+
+    return (jax.jit(sort, donate_argnums=(0, 1)),
             jax.jit(lay_out, donate_argnums=(0, 1)))
 
 
@@ -345,34 +505,72 @@ def build_rmat_graph(mesh: Mesh, scale: int, edge_factor: int = 16,
                      abcd=None, seed: int = 0,
                      rg: int | None = None) -> DeviceGraph:
     """The program's own loader of a Graph500 Kronecker graph, on the
-    device: ``edge_factor * 2**scale`` edges drawn from the seed as an
-    argument (``utils/datasets.kronecker_edges``), sorted by (source,
-    destination), a repeated edge marked and counted once, the distinct
-    out-degrees added up from the sorted sources. Nothing crosses to
-    the host but the count of distinct edges. Every shape follows from
-    (scale, edge_factor, shards): one compile serves every seed."""
+    device, sharded by destination range: every shard draws its slice
+    of the ``edge_factor * 2**scale`` edge ids from the seed as an
+    argument (``utils/datasets.kronecker_edges``), the draws are
+    exchanged so that a shard holds the edges that point into its
+    range (:func:`exchange_program`; nothing to exchange on one
+    shard), and each shard sorts its own by (source, destination),
+    marks a repeated edge and counts it once; the distinct out-degrees
+    are added up over the shards. No shard ever holds the whole edge
+    list, and nothing crosses to the host but the counts. Every shape
+    follows from (scale, edge_factor, shards): one compile serves
+    every seed. A range that draws more than a shard's capacity
+    (``geom.shard_cap``) fails the load (:class:`ShardOverflow`,
+    counted in ``pagerank_shard_overflow``)."""
     from tpu_distalg.ops import pallas_pagerank as ppr
     from tpu_distalg.utils import datasets
 
     V, n_in = 1 << scale, edge_factor << scale
     abcd = tuple(abcd or datasets.GRAPH500_ABCD)
-    geom = ppr.spmv_geometry(V, n_in, mesh.shape[DATA_AXIS], rg)
+    n = mesh.shape[DATA_AXIS]
+    geom = ppr.spmv_geometry(V, n_in, n, rg)
     if geom is None:
         raise ValueError(
-            f"2**{scale} vertices are past the resident fused SpMV "
-            f"(4 B a vertex in VMEM): --data-backend streamed")
+            f"2**{scale} vertices are past the resident fused SpMV on "
+            f"{n} data shard(s) (4 B a vertex of a shard's destination "
+            f"range in VMEM): a mesh of {ppr.shards_needed(V)} data "
+            f"shards holds the graph")
     generate, dedup = rmat_programs(mesh, scale, abcd, geom, n_in)
-    with tevents.span("pagerank:generate", scale=scale,
-                      generated=n_in, seed=int(seed)):
+    sharding = dict(shards=n, shard_capacity=geom.shard_cap)
+    with tevents.span("pagerank:generate", scale=scale, generated=n_in,
+                      seed=int(seed), shard_edges=[-(-n_in // n)] * n,
+                      **sharding):
         src, dst = jax.block_until_ready(
             generate(np.uint32(seed & 0xFFFFFFFF)))
-    with tevents.span("pagerank:dedup", generated=n_in):
-        src, dst, inv_deg, has_out, n_edges = dedup(src, dst)
-        n_edges = int(n_edges)
-        tevents.current().fields["distinct"] = n_edges
+    overflow, bounds = 0, None
+    if n > 1:
+        with tevents.span("pagerank:exchange", bucket=geom.bucket,
+                          **sharding):
+            src, dst, bounds, overflow = exchange_program(
+                mesh, scale, geom)(src, dst)
+            overflow = int(overflow)
+            tevents.current().fields.update(
+                overflow=overflow,
+                bounds=[int(x) for x in np.asarray(bounds)])
+    tevents.counter("pagerank_shard_overflow", overflow)
+    if overflow:
+        raise ShardOverflow(
+            f"pagerank_shard_overflow: {overflow} edges of seed {seed} "
+            f"fit no bucket of {geom.bucket} slots, or rows of a range "
+            f"no table of {geom.rows_out} (a shard's capacity "
+            f"{geom.shard_cap} at SCALE {scale} on {n} shards, "
+            f"ops/pallas_pagerank.SPMV_SHARD_SIGMAS); no edge is "
+            f"dropped: the load fails")
+    with tevents.span("pagerank:dedup", generated=n_in, **sharding):
+        src, dst, inv_deg, has_out, shard_edges = dedup(src, dst)
+        shard_edges = [int(x) for x in
+                       np.atleast_1d(np.asarray(shard_edges))]
+        n_edges = sum(shard_edges)
+        tevents.current().fields.update(distinct=n_edges,
+                                        shard_edges=shard_edges)
+    tevents.counter("pagerank_shard_edges_max", max(shard_edges))
+    tevents.counter("pagerank_shard_edges_mean",
+                    n_edges // len(shard_edges))
     return DeviceGraph(
         src=src, dst=dst, inv_deg=inv_deg, has_out=has_out, n_in=n_in,
         n_vertices=V, n_edges=n_edges, geom=geom,
+        shard_edges=tuple(shard_edges), bounds=bounds,
         meta=dict(generator="kronecker", scale=scale,
                   edge_factor=edge_factor, abcd=abcd, seed=int(seed),
                   generated=n_in, distinct=n_edges))
@@ -381,19 +579,31 @@ def build_rmat_graph(mesh: Mesh, scale: int, edge_factor: int = 16,
 def device_graph(el: gops.EdgeList, mesh: Mesh,
                  rg: int | None = None) -> DeviceGraph | None:
     """A host edge list (distinct already) copied up once in the form
-    the device planner takes; ``None`` past the VMEM budget."""
+    the device planner takes, the destination ranges cut to equal
+    loads and every edge to the shard that owns its destination;
+    ``None`` past the VMEM budget, or where a range holds more edges
+    than a shard's capacity or more rows than its table (counted in
+    ``pagerank_shard_overflow``)."""
     from tpu_distalg.ops import pallas_pagerank as ppr
 
-    geom = ppr.spmv_geometry(el.n_vertices, el.n_edges,
-                             mesh.shape[DATA_AXIS], rg) \
+    n = mesh.shape[DATA_AXIS]
+    geom = ppr.spmv_geometry(el.n_vertices, el.n_edges, n, rg) \
         if el.n_edges else None
     if geom is None:
         return None
     put = lambda a, n: partition.put(a, n, "pagerank", mesh)  # noqa: E731
+    bounds, owner, over = ppr.host_ranges(el.dst, geom)
+    held = np.bincount(owner, minlength=n)
+    tevents.counter("pagerank_shard_overflow", over)
+    if over:
+        return None
+    order = np.argsort(owner, kind="stable")
+    at = np.arange(el.n_edges) + np.repeat(
+        np.arange(n) * geom.shard_slots - (np.cumsum(held) - held), held)
 
     def slots(x, fill):
         out = np.full(geom.n_slots, fill, np.int32)
-        out[:el.n_edges] = x
+        out[at] = x[order]
         return put(out, "slots")
 
     inv_deg = _inv_out_degree(el)
@@ -402,22 +612,25 @@ def device_graph(el: gops.EdgeList, mesh: Mesh,
         inv_deg=put(inv_deg, "inv_deg"),
         has_out=put((inv_deg > 0).astype(np.float32), "has_out"),
         n_in=el.n_edges, n_vertices=el.n_vertices, n_edges=el.n_edges,
-        geom=geom)
+        geom=geom, shard_edges=tuple(int(x) for x in held),
+        bounds=put(bounds, "bounds"))
 
 
 def prepare_device_spmv(graph: gops.EdgeList | DeviceGraph, mesh: Mesh,
                         rg: int | None = None) -> DeviceSpMV | None:
     """The fused sweep's plan, made on the device for every graph: a
     host edge list is copied up once (:func:`device_graph`), a
-    :class:`DeviceGraph` is there already. One sort by (source group,
-    destination row) with the padding in its keys puts every slot
-    where the kernel reads it, and array code lays the seven plan
-    arrays out, sharded over the data axis by chunk
+    :class:`DeviceGraph` is there already. Every shard sorts its own
+    slots, the edges that point into its destination range, by (source
+    group over the whole table, destination row of the range) with the
+    padding in the keys, which puts every slot where the kernel reads
+    it, and array code lays the shard's seven plan arrays out
     (``ops/pallas_pagerank.sort_slots`` / ``slot_arrays``). The
     geometry (``rg``, ``ws``, the slot count) is a function of the
     sizes alone, so a second graph of a size compiles nothing.
 
-    ``None`` when the table passes the VMEM budget or a chunk's
+    ``None`` when a shard's table passes the VMEM budget, a range
+    holds more edges than a shard's capacity, or a chunk's
     destinations span more than the geometry's ``ws`` rows (a graph
     sparser or more skewed than the window was sized for): counted in
     ``spmv_plan_rejections`` and said in the ``pagerank:plan`` span, so
@@ -439,13 +652,15 @@ def prepare_device_spmv(graph: gops.EdgeList | DeviceGraph, mesh: Mesh,
         sp.update(vertices=graph.n_vertices, distinct=graph.n_edges,
                   generated=graph.n_in, rg=geom.rg, ws=geom.ws,
                   chunks=geom.n_chunks, ranks_form=geom.ranks_form,
+                  ranks_out_form=geom.ranks_out_form,
+                  shards=geom.n_shards,
                   scatter_passes=ppr.SCATTER_PASSES,
                   padding_share=geom.n_slots / max(graph.n_edges, 1))
         sort, lay_out = plan_programs(mesh, geom, graph.n_in)
         with tevents.span("pagerank:plan", rg=geom.rg, ws=geom.ws):
-            src, dst = sort(graph.src, graph.dst)
+            src, dst = sort(graph.src, graph.dst, graph.bounds)
             graph.src = graph.dst = None
-            arrays, span = lay_out(src, dst, graph.inv_deg)
+            arrays, span = lay_out(src, dst, graph.inv_deg, graph.bounds)
             span = int(span)
             tevents.current().fields["span"] = span
         if span > geom.ws:
@@ -453,7 +668,7 @@ def prepare_device_spmv(graph: gops.EdgeList | DeviceGraph, mesh: Mesh,
             tevents.emit("spmv_span_rejected", span=span, ws=geom.ws,
                          rg=geom.rg, n_vertices=graph.n_vertices)
             return None
-        plan = DeviceSpMV.of(arrays, geom)
+        plan = DeviceSpMV.of(arrays, geom, graph.bounds)
         sp["bytes"] = plan.nbytes
         tevents.counter("spmv_slots_padded",
                         geom.n_slots - graph.n_edges)
@@ -570,12 +785,12 @@ class _PlanBound:
     constants of the program, copied to the host to be lowered), under
     the signature every sweep's ``run`` has."""
 
-    def __init__(self, jitted, plan):
-        self.jitted, self.plan = jitted, plan
+    def __init__(self, jitted, plan, bounds):
+        self.jitted, self.plan, self.bounds = jitted, plan, bounds
 
     def _args(self, src, dst, w_e, emask, has_out, n_ref,
               ranks0=None, has_rank0=None):
-        return self.plan, has_out, ranks0
+        return self.plan, self.bounds, has_out, ranks0
 
     def __call__(self, *args):
         return self.jitted(*self._args(*args))
@@ -584,11 +799,20 @@ class _PlanBound:
         return self.jitted.lower(*self._args(*args))
 
 
-def _teleport(config: PageRankConfig, V: int, ranks, c, has_out):
-    """The standard update from a sweep's contributions ``c``: the
-    dangling vertices' mass spread over all, then the teleport."""
-    if config.redistribute_dangling:
-        dangling = jnp.sum(ranks * (1.0 - has_out))
+def _dangling(config: PageRankConfig, ranks, has_out):
+    """The mass of the vertices with no out-edge among ``ranks`` (the
+    whole vector, or a shard's range of it), ``None`` where it is not
+    spread."""
+    if not config.redistribute_dangling:
+        return None
+    return jnp.sum(ranks * (1.0 - has_out))
+
+
+def _teleport(config: PageRankConfig, V: int, c, dangling):
+    """The standard update from a sweep's contributions ``c`` (of all
+    vertices or of a range): the dangling vertices' mass spread over
+    all ``V``, then the teleport."""
+    if dangling is not None:
         c = c + dangling / V
     return config.q / V + (1 - config.q) * c
 
@@ -641,51 +865,91 @@ def _reference_run(mesh: Mesh, config: PageRankConfig, V: int):
     return jax.jit(run)
 
 
+def _fit(x, n: int, fill=0.0):
+    """``x`` cut or filled to ``n`` elements."""
+    return x[:n] if x.shape[0] >= n else jnp.pad(
+        x, (0, n - x.shape[0]), constant_values=fill)
+
+
 def _fused_run(mesh: Mesh, config: PageRankConfig, V: int,
                spmv: DeviceSpMV):
     """The fully-fused tiled SpMV: gather AND scatter in one Pallas
     kernel, no XLA random-access op in the sweep, the plan's arrays
-    its arguments."""
+    its arguments. A shard reads the whole ranks vector and writes the
+    table of its own destination range (``spmv.bounds``); the teleport
+    runs on the range, the dangling mass is a scalar psum and the new
+    ranges are all-gathered and laid into the next sweep's vector
+    where they belong (``tda.pagerank.sync``). On one shard the range
+    is the whole table and none of that exists."""
     from tpu_distalg.ops import pallas_pagerank as ppr
 
     interpret = not mesh_on_tpu(mesh)
-    rg, ws, r8, blk = spmv.rg, spmv.ws, spmv.r8, spmv.blk
+    n = mesh.shape[DATA_AXIS]
+    rg, ws, blk = spmv.rg, spmv.ws, spmv.blk
+    rows_out = spmv.rows_out or spmv.r8
     rows = spmv.n_groups * rg         # the ranks table, whole groups
+    own = rows_out * 128              # vertices of a shard's table
+    # room for the last range's table wherever it starts
+    held = own if n == 1 else (spmv.r8 + rows_out) * 128
+    bounds = spmv.bounds
+    if bounds is None:
+        bounds = even_bounds(spmv)
 
-    def body(gb, sb, slane, srow, drow, dlane, we, ranks):
+    def sweep(gb, sb, slane, srow, drow, dlane, we, bounds, has_out,
+              ranks):
         with jax.named_scope(names.PAGERANK_SPMV):
-            rt = jnp.pad(ranks, (0, rows * 128 - V)).reshape(rows, 128)
+            rt = _fit(ranks, rows * 128).reshape(rows, 128)
             acc = ppr.spmv_table(gb, sb, rt, slane, srow, drow,
-                                 dlane, we, rg=rg, ws=ws, r8=r8,
+                                 dlane, we, rg=rg, ws=ws, r8=rows_out,
                                  blk=blk,
                                  seg_steps=spmv.seg_steps or None,
                                  interpret=interpret)
-        return tree_allreduce_sum(acc)
+        if n == 1:
+            with jax.named_scope(names.PAGERANK_UPDATE):
+                return _teleport(config, V, acc[:rows_out].reshape(-1),
+                                 _dangling(config, ranks, has_out))
+        mine = bounds[replica_index()] * 128
+        with jax.named_scope(names.PAGERANK_SYNC):
+            # a table's rows past its range are the next shard's
+            wide = (bounds[replica_index() + 1] * 128 - mine)
+            inside = jnp.arange(own, dtype=jnp.int32) < wide
+            dangling = tree_allreduce_sum(_dangling(
+                config,
+                jnp.where(inside, jax.lax.dynamic_slice(
+                    ranks, (mine,), (own,)), 0.0),
+                jax.lax.dynamic_slice(has_out, (mine,), (own,))))
+        with jax.named_scope(names.PAGERANK_UPDATE):
+            c = acc[:rows_out].reshape(-1)
+            new = _teleport(config, V, c, dangling)
+        with jax.named_scope(names.PAGERANK_SYNC):
+            tables = all_gather(new, tiled=False)
+            # in the ranges' order: a table's first rows overwrite what
+            # the one before left past its range
+            for k in range(n):
+                ranks = jax.lax.dynamic_update_slice(
+                    ranks, tables[k], (bounds[k] * 128,))
+            return ranks
 
     sweep_fn = data_parallel(
-        body, mesh,
+        sweep, mesh,
         in_specs=(P("data"), P("data"))
-        + (P("data", None),) * 5 + (P(),),
+        + (P("data", None),) * 5 + (P(), P(), P()),
         out_specs=P(),
     )
 
-    def run(plan, has_out, ranks0=None):
+    def run(plan, bounds, has_out, ranks0=None):
         if ranks0 is None:
             ranks0 = jnp.full((V,), 1.0 / V, dtype=jnp.float32)
-
-        def step(ranks, _):
-            acc = sweep_fn(*plan, ranks)
-            with jax.named_scope(names.PAGERANK_UPDATE):
-                c = acc[:r8].reshape(-1)[:V]
-                ranks = _teleport(config, V, ranks, c, has_out)
-            return ranks, None
-
+        # a vertex past V (the table's filling) has no edge, and as one
+        # with out-edges adds nothing to the dangling mass
+        has_out = _fit(has_out, held, 1.0)
         ranks, _ = jax.lax.scan(
-            step, ranks0, None, length=config.n_iterations
-        )
-        return ranks, jnp.ones((V,), dtype=jnp.float32)
+            lambda ranks, _: (sweep_fn(*plan, bounds, has_out, ranks),
+                              None),
+            _fit(ranks0, held), None, length=config.n_iterations)
+        return ranks[:V], jnp.ones((V,), dtype=jnp.float32)
 
-    return _PlanBound(jax.jit(run), spmv.arrays)
+    return _PlanBound(jax.jit(run), spmv.arrays, bounds)
 
 
 def _hybrid_run(mesh: Mesh, config: PageRankConfig, V: int,
@@ -722,7 +986,8 @@ def _hybrid_run(mesh: Mesh, config: PageRankConfig, V: int,
             acc = sweep_fn(src, w_e, plan.base, plan.row,
                            plan.lane, ranks)
             c = acc[:r8].reshape(-1)[:V]
-            return _teleport(config, V, ranks, c, has_out), None
+            return _teleport(config, V, c,
+                             _dangling(config, ranks, has_out)), None
 
         ranks, _ = jax.lax.scan(
             step, ranks0, None, length=config.n_iterations
@@ -753,7 +1018,8 @@ def _xla_run(mesh: Mesh, config: PageRankConfig, V: int):
 
         def step(ranks, _):
             c = sweep_fn(src, dst, w_e, ranks)
-            return _teleport(config, V, ranks, c, has_out), None
+            return _teleport(config, V, c,
+                             _dangling(config, ranks, has_out)), None
 
         ranks, _ = jax.lax.scan(
             step, ranks0, None, length=config.n_iterations
@@ -864,7 +1130,6 @@ def _run_segmented(de: DeviceEdges, mesh: Mesh, config: PageRankConfig,
     (``graph_computation/pagerank.py:52-57``)."""
     import dataclasses as dc
 
-    from tpu_distalg.ops import pallas_pagerank as ppr
     from tpu_distalg.utils import checkpoint as ckpt
 
     V = de.n_vertices
@@ -893,9 +1158,7 @@ def _run_segmented(de: DeviceEdges, mesh: Mesh, config: PageRankConfig,
         # both modes carry the same (V,) f32 pair, so the shape check
         # alone cannot catch a cross-mode resume — encode the mode
         tag=f"pagerank_{config.mode}",
-        span_fields=(dict(ranks_form=de.spmv.ranks_form, rg=de.spmv.rg,
-                          ws=de.spmv.ws,
-                          scatter_passes=ppr.SCATTER_PASSES)
+        span_fields=(de.spmv.forms
                      if sweep_form(config, de.spmv is not None,
                                    de.plan is not None) == "fused"
                      else None))

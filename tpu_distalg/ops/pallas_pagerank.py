@@ -69,6 +69,16 @@ table (4 B a vertex) is kept in VMEM, which is what bounds the path
 function of the sizes (:func:`spmv_geometry`), and a sweep is a few
 kernel calls so that each call's per-chunk scalars fit SMEM.
 
+On a mesh a shard owns a range of destinations (``rows_out`` rows of
+the table) and the edges that point into it (PR 44): its gather groups
+run over the whole ranks table, its scatter rows are counted from its
+range's first row, and the table it keeps in VMEM is its range's, so
+the budget bounds the vertices a shard, not the graph. A range of a
+larger graph is a sparser block: a chunk's span grows with the whole
+table's groups over the shard's edges, and :func:`spmv_geometry`
+weighs a taller group against a wider window by the schedule law
+below (``SPMV_GATHER_ROW``, ``SPMV_SCATTER_ROW``).
+
 Measured on one v5e (PERF.md section 6 has the tables). PR 38,
 ``scripts/step0_pagerank_resident.py``, the scatter still ``HIGHEST``:
 at Graph500 SCALE 24 (rg 512, ws 224, 270.6M slots) a sweep took 752.9
@@ -93,6 +103,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -118,9 +129,14 @@ MAX_W = 4         # widest row window: 8*W rows; beyond -> fall back
 # span a chunk writes is R^2 * 1024 / (rg * E) rows where destinations
 # are uniform (R table rows, E edges), so a sparser graph needs taller
 # groups: spmv_geometry picks rg and fixes ws from the sizes alone.
-SPMV_RGS = (128, 256, 512)  # gather window heights tried, in order
+SPMV_RGS = (128, 256, 512, 1024, 2048)  # gather window heights tried
 SPMV_RG = SPMV_RGS[0]
-SPMV_WS_CAP = 256  # most scatter window rows (the (ws, 128) update)
+# A chunk's bundles by its two loops' rows (PR 39's schedule laws, from
+# chipless compiles: gather 53 + 2.67 a window row, scatter 297 + 6.0 a
+# window row; 3062 at rg 512 / ws 224): what spmv_geometry weighs a
+# taller group against a wider window by
+SPMV_GATHER_ROW = 2.67
+SPMV_SCATTER_ROW = 6.0
 SPMV_BLK = 8       # chunks per grid step; a step is one group's
 SPMV_UNROLL = 64   # most tiles of 8 window rows a turn of the gather
 # loop: the whole loop at rg 512. A turn's tiles overlap in the
@@ -129,42 +145,122 @@ SPMV_SEG_STEPS = 4096   # grid steps a kernel call: its scalars (a
 # group a step, a base a chunk: 144 KB) have to fit SMEM's 1 MB, which
 # 278k chunks' bases at SCALE 24 do not (chipless compile, PR 38)
 # A chunk's span over the uniform mean: room for the skew of a
-# Kronecker graph's destinations (SPAN_ROOM x mean + SPAN_SLACK rows,
-# the slack for the base's rounding down to a sublane)
+# Kronecker graph's destinations (SPAN_ROOM x mean + the slack: 16
+# rows, for the base's rounding down to a sublane, or a tenth of the
+# mean where that is more). The widest span of a seed is 1.57 to 1.61
+# x the mean at SCALE 24 (mean 128) and 1.59 x at a quarter of SCALE
+# 26 (mean 256: 407 rows, PR 44's Step 0), so the slack is all the
+# margin there is, and 16 rows of a taller window are less of it
 SPMV_SPAN_ROOM = 1.6
 SPMV_SPAN_SLACK = 16
+SPMV_SPAN_SLACK_SHARE = 0.1
 # What the kernel keeps in VMEM is the output table, 4 B a vertex (the
 # ranks table stays in HBM and is read a group's window at a time), of
 # the chip's 128 MiB; the rest of SPMV_VMEM_LIMIT is Mosaic's own
 # temporaries. 100 MB is 26M vertices.
 SPMV_VMEM_BUDGET = 100 * 1024 * 1024
 SPMV_VMEM_LIMIT = 120 * 1024 * 1024
+# Most scatter window rows: what VMEM leaves beside the table for the
+# scatter's temporaries, a piece's (ws, 1024) row mask and its masked
+# operand (12 KB a window row)
+SPMV_WS_CAP = (SPMV_VMEM_LIMIT - SPMV_VMEM_BUDGET) // (12 * 1024) // 8 * 8
+# A shard owns a range of destination rows and holds the edges that
+# point into it. The ranges are cut where the edges are (whole tiles of
+# 8 rows, every shard as near the mean load as a tile allows:
+# :func:`balanced_bounds`), because a sweep ends when its fullest shard
+# does: equal-width ranges of Graph500's permuted labels carry their
+# share of the edges to a relative sqrt((n - 1) x 0.6352^scale) (the
+# share of edges a vertex receives has the second moment ((A + C)^2 +
+# (B + D)^2)^scale; 0.47% at SCALE 26 on 4), and three seeds of that
+# form read sweeps 0.7% apart (PR 44's Step 0). What varies instead is
+# a range's width, by the same law: a shard's table has room for the
+# mean width and SPMV_SHARD_SIGMAS deviations. A shard's capacity in
+# edges is the mean load and the tiles its two cuts may fall beside (a
+# mean tile, and twice the heaviest vertex, which receives (A +
+# C)^scale of the edges: 0.42% of a shard's at SCALE 25 on 4) and six
+# deviations of a bucket's count. A graph more skewed than that
+# overflows a shard and is refused by name, never trimmed.
+SPMV_SHARD_SIGMAS = 6.0
+SPMV_SHARD_SKEW = (0.57 + 0.19) ** 2 + (0.19 + 0.05) ** 2
+SPMV_SHARD_HUB = 0.57 + 0.19
 
 
-def _emit_vmem_rejection(n_vertices: int, rg: int) -> None:
-    """Record a VMEM-budget plan rejection AND its remedy (the CLI's
-    warn-and-degrade built on ``models/pagerank.choose_data_backend``
-    names the out-of-core engine too)."""
+def _emit_vmem_rejection(n_vertices: int, rg: int, n_shards: int) -> None:
+    """Record a VMEM-budget plan rejection AND its remedy: the shards
+    whose destination ranges would fit (:func:`shards_needed`)."""
     from tpu_distalg.telemetry import events as tevents
 
+    need = shards_needed(n_vertices)
     tevents.emit(
         "spmv_vmem_rejected", n_vertices=int(n_vertices), rg=int(rg),
-        budget_bytes=SPMV_VMEM_BUDGET,
-        remedy="--data-backend streamed (tpu_distalg/graphs/: edge "
-               "blocks stream from disk, only O(V) state in HBM)")
+        n_shards=int(n_shards), budget_bytes=SPMV_VMEM_BUDGET,
+        shards_needed=need,
+        remedy=f"a mesh of {need} data shards: a shard keeps its own "
+               f"destination range's table in VMEM, 4 B a vertex of "
+               f"the range")
 
 
 def _table_rows(n_vertices: int) -> int:
     return ((n_vertices + LANES - 1) // LANES + 7) // 8 * 8
 
 
+def shard_rows(n_vertices: int, n_shards: int) -> int:
+    """Most rows of the vertex table a shard may own (the rows of its
+    output table): the mean width of a range and ``SPMV_SHARD_SIGMAS``
+    deviations of it, in whole sublane tiles; the whole table on one
+    shard."""
+    r8 = _table_rows(n_vertices)
+    if n_shards == 1:
+        return r8
+    sigma = math.sqrt((n_shards - 1) * SPMV_SHARD_SKEW
+                      ** math.log2(max(n_vertices, 2)))
+    wide = r8 / n_shards * (1 + SPMV_SHARD_SIGMAS * sigma)
+    return min(r8, (int(wide) // 8 + 2) * 8)
+
+
+def balanced_bounds(xp, tile_edges, n_shards: int):
+    """Where the destination ranges are cut: ``n_shards + 1`` rows, 0
+    first and the table's last, shard ``k`` owning rows ``[b[k], b[k +
+    1])``, from the edges that point into each tile of 8 rows
+    (``tile_edges``, all shards' together). A cut is the tile edge
+    with the nearest count of edges behind it to a ``k / n_shards``
+    share, so a shard's load is the mean to within the tiles its two
+    cuts fall beside; where a run of empty tiles leaves the choice
+    open, the edge of them nearest an equal width. ``xp`` is NumPy on
+    the host, ``jax.numpy`` under jit."""
+    n_tiles = tile_edges.shape[0]
+    behind = xp.concatenate([xp.zeros((1,), tile_edges.dtype),
+                             xp.cumsum(tile_edges)])
+    k = xp.arange(n_shards, dtype=tile_edges.dtype)
+    share = (behind[-1] // n_shards) * k
+    before = xp.searchsorted(behind, share, side="right") - 1
+    past = xp.minimum(before + 1, n_tiles)
+    near = behind[xp.where(
+        share - behind[before] <= behind[past] - share, before, past)]
+    cuts = xp.clip(k * n_tiles // n_shards,
+                   xp.searchsorted(behind, near, side="left"),
+                   xp.searchsorted(behind, near, side="right") - 1)
+    last = xp.full((1,), n_tiles, cuts.dtype)
+    return (8 * xp.concatenate([cuts, last])).astype(xp.int32)
+
+
+def shards_needed(n_vertices: int) -> int:
+    """The fewest data shards (a power of two) whose destination range
+    fits the fused sweep's VMEM budget."""
+    n = 1
+    while spmv_resident_bytes(n_vertices, SPMV_RGS[0], 8,
+                              n_shards=n) > SPMV_VMEM_BUDGET:
+        n *= 2
+    return n
+
+
 def spmv_resident_bytes(n_vertices: int, rg: int, ws: int,
-                        blk: int = SPMV_BLK) -> int:
-    """Kernel-resident VMEM bytes of an SpMV geometry: the output table
-    (r8 + ws, 128) f32, and double-buffered by the grid pipeline a
-    group's window of the ranks table (rg, 128) f32 and the 5 edge-block
-    operands (blk * 8, 128) a grid step."""
-    table = (_table_rows(n_vertices) + ws) * LANES * 4
+                        blk: int = SPMV_BLK, n_shards: int = 1) -> int:
+    """Kernel-resident VMEM bytes of an SpMV geometry: the shard's
+    output table (rows + ws, 128) f32, and double-buffered by the grid
+    pipeline a group's window of the ranks table (rg, 128) f32 and the
+    5 edge-block operands (blk * 8, 128) a grid step."""
+    table = (shard_rows(n_vertices, n_shards) + ws) * LANES * 4
     return table + 2 * (rg + 5 * blk * 8) * LANES * 4
 
 
@@ -172,7 +268,9 @@ def spmv_resident_bytes(n_vertices: int, rg: int, ws: int,
 class SpMVGeometry:
     """Every static shape of a fused-SpMV plan, from the sizes alone
     (vertices, edges held, shards): no seed and no edge moves it, so
-    one executable serves every graph of a size."""
+    one executable serves every graph of a size. A shard owns the
+    destinations of ``rows_out`` table rows and plans the edges that
+    point into them; the gather groups run over the whole table."""
 
     rg: int          # rows of a gather group (a multiple of 8)
     n_groups: int    # the ranks table is (n_groups * rg, 128)
@@ -183,6 +281,8 @@ class SpMVGeometry:
     seg_steps: int   # grid steps a kernel call
     n_steps: int     # grid steps a shard, whole segments
     n_shards: int
+    rows_out: int    # rows of a shard's output table (r8 on one shard)
+    bucket: int      # most edges one shard draws for another's range
 
     @property
     def step_slots(self) -> int:
@@ -197,20 +297,49 @@ class SpMVGeometry:
         return self.n_chunks * self.chunk
 
     @property
+    def shard_slots(self) -> int:
+        return self.n_steps * self.step_slots
+
+    @property
+    def shard_cap(self) -> int:
+        """Most edges a shard holds (a bucket from every shard); they
+        lie among its first ``shard_cap`` slots."""
+        return self.bucket * self.n_shards
+
+    @property
     def ranks_form(self) -> str:
         return "windowed" if self.n_groups > 1 else "resident"
+
+    @property
+    def ranks_out_form(self) -> str:
+        """``'range'``: a shard writes its own destination range and
+        the ranges are gathered; ``'whole'`` on one shard."""
+        return "range" if self.n_shards > 1 else "whole"
 
 
 def spmv_geometry(n_vertices: int, n_edges: int, n_shards: int = 1,
                   rg: int | None = None, blk: int = SPMV_BLK,
                   chunk: int = DEF_CHUNK) -> SpMVGeometry | None:
-    """The plan's geometry for a graph of at most ``n_edges`` edges, or
-    ``None`` past the VMEM budget. ``rg`` is the first of ``SPMV_RGS``
-    whose expected span (``SPMV_SPAN_ROOM`` x the uniform mean) fits
-    ``SPMV_WS_CAP``, the tallest where none does, each raised until
-    the table's last group is nearly full. Slots: the edges, a grid step of padding a
-    group, rounded up to whole segments a shard."""
+    """The plan's geometry for a graph of at most ``n_edges`` edges
+    sharded by destination range over ``n_shards``, or ``None`` past
+    the VMEM budget. A chunk of a shard spans ``rows a shard * n_groups
+    * chunk / edges a shard`` rows where destinations are uniform (the
+    span law: a range of a larger graph is a sparser block), and its
+    window is ``SPMV_SPAN_ROOM`` x that and the slack. ``rg`` is the height of
+    ``SPMV_RGS`` whose chunk costs the fewest bundles by the schedule
+    law (``SPMV_GATHER_ROW`` a group row + ``SPMV_SCATTER_ROW`` a
+    window row) among those whose window fits ``SPMV_WS_CAP``, the
+    tallest where none does, each raised until the table's last group
+    is nearly full. The ranges are cut to equal loads
+    (:func:`balanced_bounds`), so a shard's capacity is the mean load,
+    room for the heaviest tile beside a cut and six deviations of a
+    bucket's count (all the edges on one shard), and its table has
+    :func:`shard_rows` rows. Slots a shard: its capacity, a grid step
+    of padding a group, rounded up to whole segments."""
     r8 = _table_rows(n_vertices)
+    rows_out = shard_rows(n_vertices, n_shards)
+    owners = min(n_shards, r8 // 8)    # shards a tile of rows can go to
+    n_edges = max(n_edges, 1)
 
     def groups(rg_cap):
         # tiles of 8 rows a group: the first height from the cap up
@@ -229,25 +358,38 @@ def spmv_geometry(n_vertices: int, n_edges: int, n_shards: int = 1,
         return 8 * k, -(-tiles // k)
 
     def window(n_groups):
-        mean = r8 * chunk * n_groups / max(n_edges, 1)
-        return (int(SPMV_SPAN_ROOM * mean) + SPMV_SPAN_SLACK + 7) // 8 * 8
+        mean = r8 * chunk * n_groups / n_edges
+        slack = max(SPMV_SPAN_SLACK, int(SPMV_SPAN_SLACK_SHARE * mean))
+        return (int(SPMV_SPAN_ROOM * mean) + slack + 7) // 8 * 8
+
+    def bundles(r):
+        height, n_groups = groups(r)
+        return (SPMV_GATHER_ROW * height
+                + SPMV_SCATTER_ROW * window(n_groups))
 
     if rg is None:
         fits = [r for r in SPMV_RGS
                 if window(groups(r)[1]) <= SPMV_WS_CAP]
-        rg = fits[0] if fits else SPMV_RGS[-1]
+        rg = min(fits, key=bundles) if fits else SPMV_RGS[-1]
     rg, n_groups = groups(rg)
     ws = min(window(n_groups), SPMV_WS_CAP)
-    if spmv_resident_bytes(n_vertices, rg, ws, blk) > SPMV_VMEM_BUDGET:
-        _emit_vmem_rejection(n_vertices, rg)
+    if spmv_resident_bytes(n_vertices, rg, ws, blk,
+                           n_shards) > SPMV_VMEM_BUDGET:
+        _emit_vmem_rejection(n_vertices, rg, n_shards)
         return None
-    steps = -(-(-(-max(n_edges, 1) // (blk * chunk)) + n_groups)
-              // n_shards)
+    mean = n_edges / (n_shards * owners)       # a bucket's edges
+    slack = 0.0 if n_shards == 1 else (
+        2 * owners * SPMV_SHARD_HUB ** math.log2(max(n_vertices, 2))
+        + 8 * LANES * owners / n_vertices
+        + SPMV_SHARD_SIGMAS / math.sqrt(mean))
+    bucket = -(-int(n_edges * (1 + slack)) // (n_shards * owners))
+    steps = -(-bucket * n_shards // (blk * chunk)) + n_groups
     n_segs = -(-steps // SPMV_SEG_STEPS)
     seg_steps = -(-steps // n_segs)
     return SpMVGeometry(rg=rg, n_groups=n_groups, ws=ws, r8=r8, blk=blk,
                         chunk=chunk, seg_steps=seg_steps,
-                        n_steps=n_segs * seg_steps, n_shards=n_shards)
+                        n_steps=n_segs * seg_steps, n_shards=n_shards,
+                        rows_out=rows_out, bucket=bucket)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -413,6 +555,7 @@ class SpMVPlan:
     w_e: np.ndarray       # (NCH*8, 128) f32    inv_deg[src], 0 on pad
     geom: SpMVGeometry
     n_pad_edges: int
+    bounds: np.ndarray    # (n_shards + 1,) int32: the ranges' cuts, rows
 
     rg = property(lambda self: self.geom.rg)
     ws = property(lambda self: self.geom.ws)
@@ -421,23 +564,26 @@ class SpMVPlan:
     n_chunks = property(lambda self: self.geom.n_chunks)
 
 
-def slot_arrays(xp, src, dst, w_e, geom: SpMVGeometry):
-    """The plan's arrays from its slots in their final order (``src``
-    -1 where a slot holds no edge), as ``xp`` (NumPy on the host,
-    ``jax.numpy`` under jit) array code. Returns the seven arrays of
-    :class:`SpMVPlan` in its order and the widest scatter span of a
-    chunk, which has to fit ``geom.ws``."""
-    shape8 = (geom.n_chunks * 8, LANES)
-    real = (src >= 0).reshape(geom.n_chunks, geom.chunk)
+def slot_arrays(xp, src, dst, w_e, geom: SpMVGeometry, row0=0):
+    """One shard's plan arrays from its slots in their final order
+    (``src`` -1 where a slot holds no edge), as ``xp`` (NumPy on the
+    host, ``jax.numpy`` under jit) array code; ``row0`` is the first
+    table row of the shard's destination range (``shard *
+    geom.rows_out``), which its scatter rows are counted from. Returns
+    the seven arrays of :class:`SpMVPlan` in its order and the widest
+    scatter span of a chunk, which has to fit ``geom.ws``."""
+    n_chunks = src.shape[0] // geom.chunk
+    shape8 = (n_chunks * 8, LANES)
+    real = (src >= 0).reshape(n_chunks, geom.chunk)
     srow = (src >> 7).reshape(real.shape)
-    drow = (dst >> 7).reshape(real.shape)
+    drow = ((dst >> 7) - row0).reshape(real.shape)
     # a step's first slot holds an edge unless the whole step is tail
     first = src[::geom.step_slots]
     group = xp.where(first >= 0, (first >> 7) // geom.rg,
                      geom.n_groups - 1)
     gbase = xp.repeat(group * geom.rg, geom.blk).astype(xp.int32)
-    low = xp.where(real, drow, geom.r8).min(axis=1)
-    live = low < geom.r8
+    low = xp.where(real, drow, geom.rows_out).min(axis=1)
+    live = low < geom.rows_out
     sbase = xp.where(live, low // 8 * 8, -1).astype(xp.int32)
     span = xp.where(real, drow, -1).max(axis=1) - sbase + 1
     zero = xp.zeros((), xp.int32)
@@ -459,16 +605,54 @@ def plan_spmv(src: np.ndarray, dst: np.ndarray, w_e: np.ndarray,
               blk: int = SPMV_BLK, rg: int | None = None
               ) -> SpMVPlan | None:
     """The plan on the host in NumPy, for per-edge weights of any kind:
-    the tests' way to the kernel (the program plans on the device).
-    ``None`` where a chunk's destinations span more than the geometry's
-    ``ws`` rows or the output table passes ``SPMV_VMEM_BUDGET``."""
+    the tests' way to the kernel (the program plans on the device). A
+    shard plans the edges whose destination lies in its range (the
+    ranges cut to equal loads, :func:`balanced_bounds`), its arrays
+    one after another's. ``None`` where a chunk's destinations span
+    more than the geometry's ``ws`` rows, a range holds more edges
+    than a shard's capacity or more rows than its table, or the
+    output table passes ``SPMV_VMEM_BUDGET``."""
     src = np.asarray(src, np.int64)
     dst = np.asarray(dst, np.int64)
+    w_e = np.asarray(w_e)
     e = len(src)
     geom = spmv_geometry(n_vertices, e, n_shards, rg, blk, chunk) \
         if e else None
     if geom is None:
         return None
+    bounds, owner, overflow = host_ranges(dst, geom)
+    if overflow:
+        return None
+    parts, spans = [], []
+    for k in range(n_shards):
+        mine = owner == k
+        arrays, span = _plan_shard(src[mine], dst[mine], w_e[mine], geom,
+                                   int(bounds[k]))
+        parts.append(arrays)
+        spans.append(span)
+    if max(spans) > geom.ws:
+        return None
+    return SpMVPlan(*(np.concatenate(a) for a in zip(*parts)), geom=geom,
+                    n_pad_edges=geom.n_slots - e, bounds=bounds)
+
+
+def host_ranges(dst: np.ndarray, geom: SpMVGeometry):
+    """A host edge list's destination ranges: ``(bounds, the shard that
+    owns each edge, overflow)``, the ranges cut to equal loads
+    (:func:`balanced_bounds`); ``overflow`` counts the edges past a
+    shard's capacity and the rows of a range past its table."""
+    bounds = balanced_bounds(
+        np, np.bincount(dst >> 10, minlength=geom.r8 // 8), geom.n_shards)
+    owner = np.searchsorted(bounds, dst >> 7, side="right") - 1
+    held = np.bincount(owner, minlength=geom.n_shards)
+    return bounds, owner, int(
+        np.maximum(held - geom.shard_cap, 0).sum()
+        + np.maximum(np.diff(bounds) - geom.rows_out, 0).sum())
+
+
+def _plan_shard(src, dst, w_e, geom: SpMVGeometry, row0: int):
+    """:func:`plan_spmv` of one shard's edges."""
+    e = len(src)
     group = (src // LANES) // geom.rg
     order = np.lexsort((dst // LANES, group))
     n_g = np.bincount(group, minlength=geom.n_groups)
@@ -477,31 +661,24 @@ def plan_spmv(src: np.ndarray, dst: np.ndarray, w_e: np.ndarray,
     at = shift[group[order]] + np.arange(e)
 
     def slots(x, fill, dtype):
-        out = np.full(geom.n_slots, fill, dtype)
+        out = np.full(geom.shard_slots, fill, dtype)
         out[at] = x[order]
         return out
 
-    arrays, span = slot_arrays(
+    return slot_arrays(
         np, slots(src, -1, np.int32), slots(dst, 0, np.int32),
-        slots(np.asarray(w_e), 0, np.float32), geom)
-    if span > geom.ws:
-        return None
-    return SpMVPlan(*arrays, geom=geom, n_pad_edges=geom.n_slots - e)
+        slots(w_e, 0, np.float32), geom, row0)
 
 
-def sort_slots(src, dst, *, geom: SpMVGeometry, n_in: int):
-    """The device's half of the plan before the layout: ``src`` and
-    ``dst`` int32 of ``geom.n_slots``, ``src`` -1 wherever a slot holds
-    no edge: a duplicate among the first ``n_in``, and every spare slot
-    past them. One key a slot, ``group * (r8 + 1) + row``: the
-    edges by (source group, destination row); as many spare slots
-    after each group as pad it to whole grid steps (row ``r8``: past
-    its last edge); everything else behind the last group. One sort
-    and every slot is where the kernel reads it: nothing is gathered
-    into place. jit it with ``geom`` and ``n_in`` static."""
-    if (geom.n_groups + 1) * (geom.r8 + 1) >= 2 ** 31:
+def slot_keys(src, dst, *, geom: SpMVGeometry, n_in: int, row0=0):
+    """One int32 sort key a slot of a shard, ``group * (rows_out + 1)
+    + row`` (:func:`sort_slots` sorts by it): the edges by (source
+    group, destination row of the shard's range); as many spare slots
+    after each group as pad it to whole grid steps (row ``rows_out``:
+    past its last edge); everything else behind the last group."""
+    if (geom.n_groups + 1) * (geom.rows_out + 1) >= 2 ** 31:
         raise ValueError("the plan's sort key does not fit int32")
-    stride = geom.r8 + 1
+    stride = geom.rows_out + 1
     real = src[:n_in] >= 0
     group = jnp.where(real, (src[:n_in] >> 7) // geom.rg, geom.n_groups)
     n_g = jnp.sum(group[None, :]
@@ -509,10 +686,22 @@ def sort_slots(src, dst, *, geom: SpMVGeometry, n_in: int):
                   axis=1, dtype=jnp.int32)
     spare = jnp.searchsorted(
         jnp.cumsum((-n_g) % geom.step_slots),
-        jnp.arange(geom.n_slots - n_in, dtype=jnp.int32), side="right")
-    key = jnp.concatenate([
-        group * stride + jnp.where(real, dst[:n_in] >> 7, 0),
-        spare.astype(jnp.int32) * stride + geom.r8])
+        jnp.arange(geom.shard_slots - n_in, dtype=jnp.int32),
+        side="right")
+    return jnp.concatenate([
+        group * stride + jnp.where(real, (dst[:n_in] >> 7) - row0, 0),
+        spare.astype(jnp.int32) * stride + geom.rows_out])
+
+
+def sort_slots(src, dst, *, geom: SpMVGeometry, n_in: int, row0=0):
+    """The device's half of a shard's plan before the layout: ``src``
+    and ``dst`` int32 of ``geom.shard_slots``, ``src`` -1 wherever a
+    slot holds no edge: a duplicate among the first ``n_in``, and
+    every spare slot past them; ``row0`` as :func:`slot_arrays` takes
+    it. One sort by :func:`slot_keys` and every slot is where the
+    kernel reads it: nothing is gathered into place. jit it with
+    ``geom`` and ``n_in`` static."""
+    key = slot_keys(src, dst, geom=geom, n_in=n_in, row0=row0)
     _, src, dst = jax.lax.sort((key, src, dst), num_keys=1,
                                is_stable=False)
     return src, dst
@@ -613,8 +802,10 @@ def spmv_table(gbase, sbase, ranks_table, src_lane, src_row, dst_row,
     scatter base row or -1 for a chunk with no edge. The sweep runs as
     ``n_steps / seg_steps`` calls of the kernel so that a call's
     scalars fit SMEM; the output table is handed from call to call in
-    HBM and lives in VMEM inside one. Callers slice the result
-    ``[:r8]`` and psum across shards."""
+    HBM and lives in VMEM inside one. ``r8`` is the rows of the table
+    written: a shard's own destination range (``geom.rows_out``; the
+    whole table on one shard), which ``dst_row`` and ``sbase`` count
+    from. Callers slice the result ``[:r8]``."""
     nch = src_lane.shape[0] // 8
     if nch % blk:
         raise ValueError(f"n_chunks {nch} must be a multiple of {blk}")

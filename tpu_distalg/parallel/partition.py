@@ -842,15 +842,21 @@ TABLE_CLOSURE = register(RuleTable("closure_dense", (
     (r"^(paths|edges)$", _P(DATA_AXIS, None)),
 )))
 
-#: PageRank: edge/plan arrays contiguously sharded over data, the
-#: rank vector and degree tables replicated (the sweep's all-reduce
-#: owns rank combination), and so the edge slots the device planner
-#: sorts (every chip sorts the whole list and keeps its chunks).
+#: PageRank: edge/plan arrays contiguously sharded over data. The
+#: fused sweep shards by destination range: a shard's edge slots and
+#: plan arrays hold the edges that point into its range (no chip ever
+#: holds the whole edge list), the rank vector it reads and the degree
+#: tables are whole on every chip, and the ranks it writes are its
+#: own range (``ranks_out``; the ranges are cut at ``bounds``, where
+#: the loads are equal), all-gathered once a sweep. The XLA and
+#: hybrid sweeps shard a destination-sorted list by position and
+#: all-reduce whole tables.
 TABLE_PAGERANK = register(RuleTable("pagerank", (
-    (r"^(src|dst|w_e|emask|gbase|sbase|base)$", _P(DATA_AXIS)),
+    (r"^(src|dst|w_e|emask|gbase|sbase|base|slots|ranks_out)$",
+     _P(DATA_AXIS)),
     (r"^(src_lane|src_row|dst_row|dst_lane|row|lane)$",
      _P(DATA_AXIS, None)),
-    (r"^(ranks|inv_deg|has_out|slots)$", _P()),
+    (r"^(ranks|inv_deg|has_out|bounds)$", _P()),
 )))
 
 #: cluster-sharded PageRank: the rank vector ROW-PARTITIONED across

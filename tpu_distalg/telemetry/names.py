@@ -24,12 +24,16 @@ SSGD_UPDATE = "tda.ssgd.update"  # the rest of a step: reg, update, eval
 SSGD_GATHER = "tda.ssgd.gather"    # margins (w at each row's slots),
 #                                    labels, validity, residuals
 SSGD_SCATTER = "tda.ssgd.scatter"  # the residuals added up slot by slot
-# the two parts of a fused PageRank sweep (models/pagerank.py); the
+# the three parts of a fused PageRank sweep (models/pagerank.py); the
 # benchmark's spmv_ms_per_sweep.graph and pagerank_spmv_roofline read
-# the first
+# the first, sync_ms_per_sweep.graph and sync_exposed_ms_per_sweep.graph
+# the last
 PAGERANK_SPMV = "tda.pagerank.spmv"      # the ranks' table and the kernel
-PAGERANK_UPDATE = "tda.pagerank.update"  # the table back to a vector,
-#                                          dangling mass, teleport
+PAGERANK_UPDATE = "tda.pagerank.update"  # a shard's table back to a
+#                                          vector, the teleport on its range
+PAGERANK_SYNC = "tda.pagerank.sync"      # across shards: the dangling
+#                                          mass (a scalar psum), the new
+#                                          ranges all-gathered
 # the parts of a Lloyd iteration (models/kmeans.py)
 KMEANS_ASSIGN = "tda.kmeans.assign"  # distances and argmin; on the lanes
 #                                      layout the one kernel that also

@@ -193,6 +193,10 @@ def summarize(evts: list[dict]) -> dict:
             # scatter product (pagerank:prepare, train:segment)
             form = (f"{e['ranks_form']} (rg {e.get('rg', '?')}, ws "
                     f"{e.get('ws', '?')})")
+            if e.get("ranks_out_form") == "range":
+                # sharded by destination range: a shard writes its own
+                # rows of the output table (PR 44)
+                form += ", written a shard's range"
             if "scatter_passes" in e:    # (a log from before PR 39: 6)
                 form += f", scatter passes {e['scatter_passes']}"
             if form not in ranks_forms:
@@ -722,6 +726,8 @@ SUMMARY_ONLY_COUNTERS = (
     "serve.merge_bytes_wire",
     "spmv_plan_rejections",
     "spmv_slots_padded",        # pagerank:prepare's padding_share says it
+    "pagerank_shard_*",         # pagerank:dedup's shard_edges and
+    #                             pagerank:exchange's overflow say them
     "reshard.bytes_logical",    # the reshard line renders wire/host;
     #                             logical is accounting input only
 )
