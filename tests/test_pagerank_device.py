@@ -494,8 +494,10 @@ def test_cli_rmat_end_to_end_with_its_report(tmp_path, capsys):
         assert name in text, name
     # (the test process's backend is up with 8 devices, so --emulate 1
     # leaves the mesh at 8 data shards: the sweep is sharded by range)
+    # (a shard's 48 chunks in one kernel call: all but the first overlap)
     assert ("ranks table: resident (rg 8, ws 16), written a shard's "
-            "range, scatter passes 3") in text
+            "range, scatter passes 3, overlap step on 0.9792 of the "
+            "chunks") in text
     evts = report.load_events(tel)
     prepare = [e for e in evts if e.get("ev") == "span_end"
                and e["name"] == "pagerank:prepare"][0]
@@ -506,7 +508,12 @@ def test_cli_rmat_end_to_end_with_its_report(tmp_path, capsys):
            and e["name"] == "train:segment"]
     assert seg and all(e["ranks_form"] == "resident" and e["rg"] == 8
                        and e["ranks_out_form"] == "range"
-                       and e["scatter_passes"] == 3 for e in seg)
+                       and e["scatter_passes"] == 3
+                       and e["spmv_overlap"] == "step"
+                       and e["overlapped_chunk_share"] == round(47 / 48, 6)
+                       for e in seg)
+    assert prepare["spmv_overlap"] == "step" \
+        and prepare["overlapped_chunk_share"] == round(47 / 48, 6)
     assert prepare["ranks_out_form"] == "range" and prepare["shards"] == 8
     dedup = [e for e in evts if e.get("ev") == "span_end"
              and e["name"] == "pagerank:dedup"][0]

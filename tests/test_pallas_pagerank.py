@@ -196,6 +196,147 @@ def test_scatter_product_keeps_every_bit(ws, n_shards, monkeypatch):
     assert np.abs(short - want).max() <= 2.0 ** -16 * want.max()
 
 
+def _without_chunks(plan, dead):
+    """``plan`` with the chunks ``dead`` emptied as the planner empties
+    a chunk with no edge: base -1, weights and indices 0."""
+    arrays = {n: getattr(plan, n).copy() for n in (
+        "sbase", "src_lane", "src_row", "dst_row", "dst_lane", "w_e")}
+    for c in dead:
+        arrays["sbase"][c] = -1
+        for n in ("src_lane", "src_row", "dst_row", "dst_lane", "w_e"):
+            arrays[n][8 * c:8 * c + 8] = 0
+    return dataclasses.replace(plan, **arrays)
+
+
+def _plan_edges(plan):
+    """The edges a plan holds, read back from its arrays: ``(src, dst,
+    w_e)`` of every slot with a weight, in slot order."""
+    g = plan.geom
+    per = g.n_steps * g.blk
+    row0 = np.repeat(np.asarray(plan.bounds[:-1]), per)
+    held = plan.w_e.reshape(-1, 1024) != 0
+
+    def slots(x):
+        return x.reshape(-1, 1024).astype(np.int64)
+
+    src = (plan.gbase[:, None] + slots(plan.src_row)) * 128 \
+        + slots(plan.src_lane)
+    dst = ((row0 + plan.sbase)[:, None] + slots(plan.dst_row)) * 128 \
+        + slots(plan.dst_lane)
+    return src[held], dst[held], plan.w_e.reshape(-1, 1024)[held]
+
+
+# what the scatter's lag of one chunk can get wrong: (rg, vertices,
+# edges, shards, kernel calls a sweep (0: the geometry's own, -1: a
+# call a step), the chunks emptied: (which of a shard's steps that
+# hold an edge, chunk of the step) pairs)
+CARRIES = {
+    "rg128": (128, 1 << 16, 60000, 1, 0, ()),
+    "rg512": (512, 1 << 17, 40000, 1, 0, ()),
+    "rg1024": (1024, 1 << 18, 60000, 1, 0, ()),
+    "dead-chunk-first-of-a-step": (128, 1 << 16, 60000, 1, 0, ((2, 0),)),
+    "dead-chunk-in-the-middle": (128, 1 << 16, 60000, 1, 0, ((2, 3),)),
+    "dead-chunk-last-of-a-step": (128, 1 << 16, 60000, 1, 0, ((2, 7),)),
+    "dead-step-between-live-ones": (
+        128, 1 << 16, 60000, 1, 0, tuple((2, c) for c in range(8))),
+    "a-call-a-step": (128, 1 << 16, 60000, 1, -1, ()),
+    "three-calls-and-a-dead-first-chunk": (
+        128, 1 << 16, 60000, 1, 3, ((4, 0),)),
+    "two-shards": (128, 1 << 16, 60000, 2, 0, ((1, 7),)),
+    "four-shards-a-call-a-step": (128, 1 << 16, 60000, 4, -1, ((1, 0),)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CARRIES))
+def test_pipelined_chunk_loop_is_the_segment_sum(case):
+    """The kernel scatters a chunk one turn after it gathered it, and
+    holds it across grid steps; a call starts with nothing held and
+    its last step scatters what is held. With every vertex given at
+    most one in-edge the table IS ``ranks[src] * w_e`` at ``dst``, bit
+    for bit (contributions of full 24-bit significands); with random
+    destinations it is their sum to float32's noise. Over: three group
+    heights (the interpreter rolls the gather a tile a turn at every
+    one: the several-turn order); a chunk with no edge first, last and in the
+    middle of a step and a step with none between two with some (the
+    planner leaves such chunks only behind a group's edges: emptied
+    here by hand); groups changing between steps; the plan's tail
+    (steps with no edge, skipped whole); several calls a sweep, down
+    to a call a step; two and four shards."""
+    rg, v, e, n_shards, calls, dead = CARRIES[case]
+    rng = np.random.default_rng(len(case) + e)
+    src = rng.integers(0, v, size=e)
+    w_e = (1.0 / (2 * rng.integers(1, 2000, size=e) + 1)).astype(np.float32)
+    ranks = (1 / 3 + np.arange(v) * 2.0 ** -20).astype(np.float32)
+    for dst in (rng.permutation(v)[:e], rng.integers(0, v, size=e)):
+        plan = ppr.plan_spmv(src, dst, w_e, v, n_shards=n_shards, rg=rg)
+        g = plan.geom
+        assert g.rg == rg and g.n_groups >= 2
+        per = g.n_steps * g.blk
+        live = (plan.sbase.reshape(-1, g.blk) >= 0)
+        assert live[:, 0].any() and not live.all(axis=1).all()  # a tail
+        kill = [k * per + np.flatnonzero(mine[:, 0])[step] * g.blk + c
+                for k, mine in enumerate(np.split(live, n_shards))
+                for step, c in dead]
+        assert all(plan.sbase[c] >= 0 for c in kill)
+        lost = sum(np.count_nonzero(plan.w_e[8 * c:8 * c + 8])
+                   for c in kill)
+        assert lost >= len(kill)
+        plan = _without_chunks(plan, kill)
+        seg = {0: g.seg_steps, -1: 1}.get(calls, g.n_steps // max(calls, 1))
+        assert g.n_steps % seg == 0 and (calls <= 0 or seg > 1)
+        got = _spmv(plan, ranks, v, seg_steps=seg)
+        s, d, w = _plan_edges(plan)
+        assert len(s) == e - lost
+        if len(np.unique(dst)) == e:
+            want = np.zeros(v, np.float32)
+            want[d] = ranks[s] * w
+            np.testing.assert_array_equal(got.view(np.uint32),
+                                          want.view(np.uint32))
+        else:
+            want = np.zeros(v, np.float64)
+            np.add.at(want, d, ranks[s].astype(np.float64) * w)
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+
+
+def test_a_table_built_by_two_calls_is_the_table_built_by_one():
+    """A sweep cut into kernel calls hands the table on through HBM
+    and carries nothing else: the same chunks add in the same order,
+    so one call, two and a call a step build one table, bit for bit
+    (random destinations: cells of many terms)."""
+    v, e = 1 << 16, 64000
+    rng = np.random.default_rng(45)
+    src, dst = rng.integers(0, v, size=e), rng.integers(0, v, size=e)
+    plan = ppr.plan_spmv(src, dst, rng.random(e).astype(np.float32), v)
+    steps = plan.geom.n_steps
+    assert steps % 2 == 0 and plan.geom.n_groups >= 2
+    ranks = rng.random(v).astype(np.float32)
+    one = _spmv(plan, ranks, v, seg_steps=steps)
+    assert np.count_nonzero(one) > 0.6 * v
+    for seg in (steps // 2, 1):
+        np.testing.assert_array_equal(
+            _spmv(plan, ranks, v, seg_steps=seg).view(np.uint32),
+            one.view(np.uint32))
+
+
+@pytest.mark.parametrize("rg,blk,chunks,seg_steps,form,share", [
+    (512, 8, 264240, 3670, "step", 1 - 9 / 264240),    # the resident cell
+    (512, 8, 136440, 3411, "step", 1 - 5 / 136440),    # a shard of four
+    (128, 8, 16, 0, "step", 1 - 1 / 16),               # one call
+    (1024, 8, 268128, 3724, "step", 1 - 9 / 268128),   # 128 tiles a turn
+    (2048, 8, 800, 50, "none", 0.0),                   # two turns
+    (1040, 8, 800, 50, "none", 0.0),                   # 130 tiles: two turns
+])
+def test_overlap_fields_say_where_the_loop_is_pipelined(
+        rg, blk, chunks, seg_steps, form, share):
+    """The spans' ``spmv_overlap`` / ``overlapped_chunk_share``: the
+    chunk loop overlaps a gather with a scatter where the gather is
+    one turn (up to ``SPMV_UNROLL`` tiles: 1024 rows), on every chunk
+    but the first of a kernel call."""
+    got = ppr.overlap_fields(rg, blk, chunks, seg_steps)
+    assert got["spmv_overlap"] == form == ppr.spmv_overlap(rg)
+    assert got["overlapped_chunk_share"] == pytest.approx(share, abs=1e-6)
+
+
 @pytest.mark.parametrize("kernel", ["spmv", "hybrid"])
 def test_scatter_passes_is_what_the_kernels_pass(kernel, monkeypatch):
     """The spans' ``scatter_passes`` tag (``SCATTER_PASSES``) is the

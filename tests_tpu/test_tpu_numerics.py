@@ -412,6 +412,62 @@ def test_pagerank_sharded_by_range_matches_xla_on_four_chips():
     assert abs(float(got.sum(dtype=np.float64)) - 1.0) < 1e-4
 
 
+@pytest.mark.parametrize("ws,edges", [(224, 1 << 20), (440, 1 << 19)])
+def test_pagerank_pipelined_kernel_is_segment_sum_bit_for_bit_on_tpu(
+        ws, edges):
+    """PR 45's chunk loop (a chunk's gather in one block with the chunk
+    before's scatter) at the two cells' geometries, ``rg`` 512 with
+    ``ws`` 224 and 440, compiled by Mosaic: (a) every vertex with at
+    most one in-edge and contributions of full 24-bit significands,
+    the table IS XLA's ``segment_sum`` of ``ranks[src] * w_e``, bit for
+    bit, in one kernel call and in several (the drain and the empty
+    first scatter at every border); (b) random destinations and
+    contributions that are one power of two, every sum exact: bit for
+    bit again, as PR 44's Step 0 read the sequential loop."""
+    from tpu_distalg.ops import pallas_pagerank as ppr
+
+    v = 1 << 20
+    rng = np.random.default_rng(ws)
+    src = rng.integers(0, v, size=edges)
+
+    def tables(dst, w_e, ranks):
+        plan = ppr.plan_spmv(src, dst, w_e, v, rg=512)
+        g = plan.geom
+        assert (g.rg, g.ws, g.n_groups) == (512, ws, 16)
+        assert ppr.spmv_overlap(g.rg) == "step"
+        rt = jnp.asarray(ranks.reshape(-1, 128))
+        steps = g.n_steps
+        several = next(d for d in range(steps // 6, 1, -1)
+                       if steps % d == 0)
+        want = np.asarray(jax.jit(lambda s, d, w, r: jax.ops.segment_sum(
+            r[s] * w, d, num_segments=v))(src, dst, w_e, ranks))
+        for seg_steps in (g.seg_steps, several):
+            got = np.asarray(ppr.spmv_table(
+                plan.gbase, plan.sbase, rt, plan.src_lane, plan.src_row,
+                plan.dst_row, plan.dst_lane, plan.w_e, rg=g.rg, ws=g.ws,
+                r8=g.rows_out, blk=g.blk,
+                seg_steps=seg_steps))[:g.r8].reshape(-1)
+            yield steps // seg_steps, got, want
+
+    once = rng.permutation(v)[:edges]
+    for calls, got, want in tables(
+            once, _full_mantissas(rng, edges) * 2.0 ** 40,
+            _full_mantissas(rng, v)):
+        assert (want != 0).sum() == edges
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32),
+                                      err_msg=f"{calls} calls")
+    for calls, got, want in tables(
+            rng.integers(0, v, size=edges),
+            np.full(edges, 1 / 16, np.float32),
+            np.full(v, 1.0 / v, np.float32)):
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32),
+                                      err_msg=f"{calls} calls")
+    print(f"[pipelined spmv] rg 512 ws {ws}: the table is segment_sum "
+          f"bit for bit over {edges} edges, in 1 and in {calls} calls")
+
+
 def _scatter_products(c, row, lane, ws, pieces=3, interpret=False):
     """One small Pallas kernel, two windows of the same operands: the
     shipped one-hot scatter product (``scatter_window`` over the first

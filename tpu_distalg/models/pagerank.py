@@ -181,7 +181,10 @@ class DeviceSpMV:
 
         return dict(ranks_form=self.ranks_form,
                     ranks_out_form=self.ranks_out_form, rg=self.rg,
-                    ws=self.ws, scatter_passes=ppr.SCATTER_PASSES)
+                    ws=self.ws, scatter_passes=ppr.SCATTER_PASSES,
+                    **ppr.overlap_fields(
+                        self.rg, self.blk, self.n_chunks // self.n_shards,
+                        self.seg_steps))
 
     @property
     def arrays(self) -> tuple:
@@ -655,6 +658,9 @@ def prepare_device_spmv(graph: gops.EdgeList | DeviceGraph, mesh: Mesh,
                   ranks_out_form=geom.ranks_out_form,
                   shards=geom.n_shards,
                   scatter_passes=ppr.SCATTER_PASSES,
+                  **ppr.overlap_fields(
+                      geom.rg, geom.blk, geom.n_steps * geom.blk,
+                      geom.seg_steps),
                   padding_share=geom.n_slots / max(graph.n_edges, 1))
         sort, lay_out = plan_programs(mesh, geom, graph.n_in)
         with tevents.span("pagerank:plan", rg=geom.rg, ws=geom.ws):

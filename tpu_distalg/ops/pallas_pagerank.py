@@ -8,7 +8,8 @@ that to one random gather (``ranks[src]``) plus one sorted
 ``segment_sum`` per edge per sweep, each bound by the issue rate of a
 random-access XLA op and not by bandwidth (the benchmark's reference,
 which is those two ops, takes 4.8 s a sweep of 263M edges on one v5e
-where a fused sweep takes 0.52 s: PERF.md, PRs 38 and 39). The kernels here
+where a fused sweep takes 0.27 s: PERF.md, PRs 38, 39 and 45). The kernels
+here
 touch no random-access engine.
 
 The scatter (:func:`scatter_table`, the hybrid sweep's half)
@@ -88,13 +89,30 @@ chain, and tiles overlap only inside a turn). The static schedule of a
 chipless compile said why: a chunk was 4333 bundles (at 1.5 GHz,
 264 240 chunks: 0.76 s), 2895 of them the scatter's eight ``HIGHEST``
 matmuls and 1438 the 512 gathered rows. PR 39 (PERF.md section 6):
-with three passes a chunk is 3062 bundles, 1641 the scatter and 1421 the gather, and a sweep takes
-521.9 ms, 1.93 ns a slot (754.4 the six passes in the same run); at
-SCALE 20 (rg 128, ws 72) 12.2 ms, 0.70 ns a slot (16.9, 0.98). Each of
-the four MXUs streams a row a cycle and pops a row a cycle, so 224
-rows x 3 pieces x 8 sublanes are 1344 cycles a chunk however the
-pieces are stacked (along the rows, along the contraction, as bf16:
-521.6 to 523.5 ms, the v5e's MXU does not add across a contraction).
+with three passes a chunk was 3062 bundles, 1641 the scatter and 1421
+the gather, one after the other, and a sweep took 521.9 ms, 1.93 ns a
+slot (754.4 the six passes in the same run); at SCALE 20 (rg 128, ws
+72) 12.2 ms, 0.70 ns a slot (16.9, 0.98). Each of the four MXUs
+streams a row a cycle and pops a row a cycle, so 224 rows x 3 pieces x
+8 sublanes are 1344 cycles a chunk however the pieces are stacked
+(along the rows, along the contraction, as bf16: 521.6 to 523.5 ms,
+the v5e's MXU does not add across a contraction). PR 45: the two
+loops share no unit (the gather is XLU lane gathers and selects, the
+scatter MXU streaming), so the chunk loop is pipelined by one chunk:
+a turn scatters the chunk before and gathers its own, in ONE basic
+block, and the scheduler issues the gather under the products whatever
+their order in the source. A turn is 1544 bundles at rg 512 / ws 224
+where the sequential body of the same source reads 3000 (200 over the
+MXUs' 1344) and 2839 at ws 440 where it reads 4296 (the MXUs' 2640):
+what bounds a chunk now is the MXUs' streaming of the window's rows.
+On the chip a sweep of SCALE 24 takes 274.7 ms where the sequential
+loop takes 520.9 in the same run (1.02 ns a slot for 1.93), and a
+shard of four's block of SCALE 25 252.7 for 374.2, the tables bit for
+bit the same (``scripts/step0_pagerank_overlap.py``; the schedule read
+2.5% low both times). With ``SPMV_UNROLL`` 128 a group of 1024 rows is
+one turn too: a shard of four's block of SCALE 26 (rg 1024, ws 440)
+takes 507.9 ms where two turns of 64 tiles after the scatter take
+1014.7 (2870 bundles a chunk for 5845).
 The widest span a chunk writes was 201 to 206 rows on three seeds,
 1.54 to 1.58 x the uniform mean.
 """
@@ -134,13 +152,22 @@ SPMV_RG = SPMV_RGS[0]
 # A chunk's bundles by its two loops' rows (PR 39's schedule laws, from
 # chipless compiles: gather 53 + 2.67 a window row, scatter 297 + 6.0 a
 # window row; 3062 at rg 512 / ws 224): what spmv_geometry weighs a
-# taller group against a wider window by
+# taller group against a wider window by. The law is a SUM, of loops
+# that ran one after the other; since PR 45 they overlap at heights up
+# to 1024 and the chip is nearer their MAX (1544, 2839 and 2870 bundles
+# where the law says 3062, 4357 and 5724), so the heights want
+# re-reading, with the cells' `geometry` (the benchmark's): PERF.md
+# section 7
 SPMV_GATHER_ROW = 2.67
 SPMV_SCATTER_ROW = 6.0
 SPMV_BLK = 8       # chunks per grid step; a step is one group's
-SPMV_UNROLL = 64   # most tiles of 8 window rows a turn of the gather
-# loop: the whole loop at rg 512. A turn's tiles overlap in the
-# schedule; a tile alone is a chain of gathers and selects
+SPMV_UNROLL = 128  # most tiles of 8 window rows a turn of the gather
+# loop: the whole loop up to rg 1024. A turn's tiles overlap in the
+# schedule; a tile alone is a chain of gathers and selects. A gather
+# of ONE turn is straight-line code in the chunk's body, and overlaps
+# the scatter of the chunk before (spmv_overlap): 64 until PR 45,
+# when rg 1024 (a shard of four of SCALE 26) ran two turns after its
+# scatter, 5830 bundles a chunk where one turn under it is 2870
 SPMV_SEG_STEPS = 4096   # grid steps a kernel call: its scalars (a
 # group a step, a base a chunk: 144 KB) have to fit SMEM's 1 MB, which
 # 278k chunks' bases at SCALE 24 do not (chipless compile, PR 38)
@@ -707,16 +734,50 @@ def sort_slots(src, dst, *, geom: SpMVGeometry, n_in: int, row0=0):
     return src, dst
 
 
+def spmv_overlap(rg: int) -> str:
+    """How the fused kernel's chunk loop runs at a group height, the
+    spans' ``spmv_overlap`` tag: ``'step'`` where the gather loop is
+    one turn (at most ``SPMV_UNROLL`` tiles of 8 window rows: every
+    height up to 1024), so a chunk's gather and the chunk before's
+    scatter are one straight-line body that the scheduler overlaps;
+    ``'none'`` at a taller group, whose gather is a rolled loop of
+    several turns and a block of its own, after the scatter."""
+    return "step" if rg // 8 <= SPMV_UNROLL else "none"
+
+
+def overlap_fields(rg: int, blk: int, shard_chunks: int,
+                   seg_steps: int) -> dict:
+    """The spans' two tags of the pipelined chunk loop:
+    ``spmv_overlap`` (:func:`spmv_overlap`) and
+    ``overlapped_chunk_share``, the chunks of a shard's sweep whose
+    gather runs in one body with a scatter, of all its chunks: all but
+    the first of each kernel call (``seg_steps`` grid steps, 0 for one
+    call; nothing is held across calls), or none."""
+    form = spmv_overlap(rg)
+    calls = shard_chunks // blk // seg_steps if seg_steps else 1
+    share = 1.0 - calls / shard_chunks if form == "step" else 0.0
+    return dict(spmv_overlap=form, overlapped_chunk_share=round(share, 6))
+
+
 def _spmv_kernel(seg_ref, grp_ref, sbase_ref, win_ref, slane_ref,
                  srow_ref, drow_ref, dlane_ref, we_ref, acc_in, acc_out,
-                 acc, sem, *, rg: int, ws: int, blk: int, unroll: int):
-    """One grid step is ``blk`` chunks of one source group. Per chunk:
-    the gather over the group's window of the ranks table (a rolled
-    loop over tiles of 8 rows: broadcast row rho, lane-gather by
-    ``src_lane``, keep where ``src_row == rho``), then the one-hot-MXU
-    scatter built per gather sublane (:func:`scatter_window`: 8
-    sublanes x 3 exact pieces, a single bf16 pass each, the price of
-    bridging the (8, 128) gather layout to the scatter).
+                 acc, held_c, held_row, held_lane, held_base, sem, *,
+                 rg: int, ws: int, blk: int, unroll: int):
+    """One grid step is ``blk`` chunks of one source group, and the
+    chunk loop is pipelined by one chunk: a turn scatters the chunk
+    BEFORE (its contributions, destination rows and lanes and its base
+    row, held in ``held_*``) and gathers its own, which it then holds.
+    The two halves share no value and no unit: the gather over the
+    group's window of the ranks table is lane gathers and selects (a
+    tile of 8 rows: broadcast row rho, lane-gather by ``src_lane``,
+    keep where ``src_row == rho``), the one-hot scatter built per
+    gather sublane is MXU streaming (:func:`scatter_window`: 8 sublanes
+    x 3 exact pieces, a single bf16 pass each, the price of bridging
+    the (8, 128) gather layout to the scatter). Where the gather is
+    one turn (:func:`spmv_overlap`) the body is one basic block, with
+    no branch between the halves, and the scheduler issues one under
+    the other. Chunks still add into ``acc`` in chunk order, each the
+    same float32 sum of the same 24 products.
 
     ``win_ref`` is the group's ``(rg, 128)`` window of the ranks table,
     which stays in HBM: its block index is the step's group
@@ -724,8 +785,12 @@ def _spmv_kernel(seg_ref, grp_ref, sbase_ref, win_ref, slane_ref,
     changes and not otherwise. ``acc`` is the whole output table in
     VMEM, copied in from ``acc_in`` at the first step and out to
     ``acc_out`` at the last: a sweep is several calls (segments) that
-    hand the table on, each with its own slice of the scalars. A chunk
-    with no edge has ``sbase`` -1 and is skipped."""
+    hand the table on, each with its own slice of the scalars, so a
+    call starts with nothing held (zeros, which add an exact zero) and
+    its last step scatters what is held before the table leaves. A
+    chunk with no edge has ``sbase`` -1, weights 0 and indices 0: it
+    runs the body and adds an exact zero at row 0; a step with no edge
+    at all (the plan's tail) is skipped whole."""
     del seg_ref, grp_ref                      # the index maps read them
     pid = pl.program_id(0)
 
@@ -737,50 +802,65 @@ def _spmv_kernel(seg_ref, grp_ref, sbase_ref, win_ref, slane_ref,
     @pl.when(pid == 0)
     def _load():
         copy(acc_in, acc)
+        held_c[...] = jnp.zeros_like(held_c)
+        held_row[...] = jnp.zeros_like(held_row)
+        held_lane[...] = jnp.zeros_like(held_lane)
+        held_base[0] = 0
+
+    def scatter_held():
+        upd = scatter_window(split3(held_c[...]), held_row[...],
+                             held_lane[...], ws)
+        rows = pl.ds(pl.multiple_of(held_base[0], 8), ws)
+        acc[rows, :] += upd
 
     def chunk(i, _):
-        sb = sbase_ref[pid * blk + i]
+        scatter_held()
+        at = pl.ds(pl.multiple_of(8 * i, 8), 8)
+        slane = slane_ref[at, :]
+        srow = srow_ref[at, :]
+        # a slot's row inside a tile of 8, bit by bit: the three
+        # levels of the select tree below (seven selects three deep,
+        # where a chain over the eight rows is eight deep and the
+        # loop's critical path)
+        bits = [(srow & (1 << b)) != 0 for b in range(3)]
+        tile_of = srow >> 3
 
-        @pl.when(sb >= 0)
-        def _live():
-            at = pl.ds(pl.multiple_of(8 * i, 8), 8)
-            slane = slane_ref[at, :]
-            srow = srow_ref[at, :]
-            drow = drow_ref[at, :]
-            dlane = dlane_ref[at, :]
-            we = we_ref[at, :]
-            # a slot's row inside a tile of 8, bit by bit: the three
-            # levels of the select tree below (seven selects three
-            # deep, where a chain over the eight rows is eight deep
-            # and the loop's critical path)
-            bits = [(srow & (1 << b)) != 0 for b in range(3)]
-            tile_of = srow >> 3
+        def gather_tiles(turn, g):
+            for u in range(unroll):
+                t = turn * unroll + u
+                tile = win_ref[pl.ds(pl.multiple_of(8 * t, 8), 8), :]
+                picked = [jnp.take_along_axis(
+                    jnp.broadcast_to(tile[r:r + 1, :], (8, LANES)),
+                    slane, axis=1) for r in range(8)]
+                for bit in bits:
+                    picked = [jnp.where(bit, hi, lo) for lo, hi
+                              in zip(picked[::2], picked[1::2])]
+                g = jnp.where(tile_of == t, picked[0], g)
+            return g
 
-            def gather_tiles(turn, g):
-                for u in range(unroll):
-                    t = turn * unroll + u
-                    tile = win_ref[pl.ds(pl.multiple_of(8 * t, 8), 8), :]
-                    picked = [jnp.take_along_axis(
-                        jnp.broadcast_to(tile[r:r + 1, :], (8, LANES)),
-                        slane, axis=1) for r in range(8)]
-                    for bit in bits:
-                        picked = [jnp.where(bit, hi, lo) for lo, hi
-                                  in zip(picked[::2], picked[1::2])]
-                    g = jnp.where(tile_of == t, picked[0], g)
-                return g
-
-            g = jax.lax.fori_loop(0, rg // (8 * unroll), gather_tiles,
-                                  jnp.zeros((8, LANES), jnp.float32))
-            upd = scatter_window(split3(g * we), drow, dlane, ws)
-            rows = pl.ds(pl.multiple_of(sb, 8), ws)
-            acc[rows, :] += upd
-
+        g = jnp.zeros((8, LANES), jnp.float32)
+        turns = rg // (8 * unroll)
+        # one turn is straight-line code: a loop, even of one trip, is
+        # a block of its own that nothing of the scatter is issued in
+        g = gather_tiles(0, g) if turns == 1 else jax.lax.fori_loop(
+            0, turns, gather_tiles, g)
+        held_c[...] = g * we_ref[at, :]
+        held_row[...] = drow_ref[at, :]
+        held_lane[...] = dlane_ref[at, :]
+        held_base[0] = jnp.maximum(sbase_ref[pid * blk + i], 0)
         return 0
 
-    jax.lax.fori_loop(0, blk, chunk, 0)
+    live = sbase_ref[pid * blk]
+    for j in range(1, blk):
+        live = jnp.maximum(live, sbase_ref[pid * blk + j])
+
+    @pl.when(live >= 0)
+    def _step():
+        jax.lax.fori_loop(0, blk, chunk, 0)
 
     @pl.when(pid == pl.num_programs(0) - 1)
     def _store():
+        scatter_held()
         copy(acc, acc_out)
 
 
@@ -839,6 +919,11 @@ def spmv_table(gbase, sbase, ranks_table, src_lane, src_row, dst_row,
             + [edge_block] * 5 + [hbm],
             out_specs=hbm,
             scratch_shapes=[pltpu.VMEM((r8 + ws, LANES), jnp.float32),
+                            # the chunk the scatter lags by
+                            pltpu.VMEM((8, LANES), jnp.float32),
+                            pltpu.VMEM((8, LANES), jnp.int32),
+                            pltpu.VMEM((8, LANES), jnp.int32),
+                            pltpu.SMEM((1,), jnp.int32),
                             pltpu.SemaphoreType.DMA((1,))],
         ),
         out_shape=jax.ShapeDtypeStruct((r8 + ws, LANES), jnp.float32),

@@ -199,6 +199,12 @@ def summarize(evts: list[dict]) -> dict:
                 form += ", written a shard's range"
             if "scatter_passes" in e:    # (a log from before PR 39: 6)
                 form += f", scatter passes {e['scatter_passes']}"
+            if "spmv_overlap" in e:      # (none before PR 45)
+                # the chunk loop pipelined: a chunk's gather under the
+                # chunk before's scatter, and on which share of chunks
+                form += (f", overlap {e['spmv_overlap']} on "
+                         f"{e.get('overlapped_chunk_share', 0):.4f} of "
+                         f"the chunks")
             if form not in ranks_forms:
                 ranks_forms.append(form)
         if ev == "span_start":
