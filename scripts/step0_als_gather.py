@@ -1,50 +1,97 @@
 #!/usr/bin/env python3
 """Step 0 of sparse ALS' resident gather: what one block's row gather
-costs alone on the chip at the ``als100_253m_sweep1`` cell's shape (a
-block of 6144 segments x 32 slots = 196 608 rows of 128 float32 lanes,
-from the items' table of 663 560 rows and the users' of 1 032 200), ms a
-block, least of three, each with its dispatch. Kept as the way to
-re-read ``GATHER_VMEM_BYTES`` in ``tpu_distalg/ops/als_sparse.py`` (the
-``heavy`` / ``five`` / ``budget`` rows):
+costs on the chip at the ``als100_253m_sweep1`` cell's shape (a block of
+6144 segments x 32 slots = 196 608 rows of 128 float32 lanes, from the
+items' table of 663 560 rows and the users' of 1 032 200), ms a block,
+least of three. Kept as the way to re-read ``GATHER_VMEM_BYTES`` in
+``tpu_distalg/ops/als_sparse.py`` (the ``heavy`` / ``five`` rows) and
+the forms PR 46 chose among (the ``form.*`` rows):
 
     chiprun -- python3 scripts/step0_als_gather.py
     JAX_PLATFORMS=cpu python3 scripts/step0_als_gather.py --rehearse
+    JAX_PLATFORMS=cpu python3 scripts/step0_als_gather.py --bundles
 
 Rows of the output, one a line as ``[step0] <name> <ms> [<ns a slot>]``:
 
-  <table>.xla.<block>            XLA's ``other[idx]``
-  <table>.<range>.<block>[.cN]   the Mosaic kernel with that resident
-                                 range, chunks of N rows of 128 slots
-                                 (the module's own where none is named)
+  <table>.xla.<block>            XLA's ``other[idx]`` and the ``where``
+                                 that writes the two lanes
+  <table>.<range>.<block>        the Mosaic kernel as it ships
+                                 (``pallas_als.gather_rows_resident``
+                                 on ``als_sparse.gather_lists``' arrays)
+                                 with that resident range
+  <table>.form.<form>.<block>    a form of PR 46's Step 0 (below), the
+                                 heavy range resident
+  <table>.gram.<form>.<block>    ``block_gramians`` whole at the heavy
+                                 class's depth (gather, the two lanes,
+                                 the product): ``xla``, ``i`` (the
+                                 parent: its kernel, then XLA's
+                                 ``where``) or ``ships``
+  <table>.heavy.mix.u16.x8       as it ships but 16 slots a trip of
+                                 pass 1 (32 ships: PR 46 read 1.6%)
   <...>.x8                       eight blocks in one program (a scan
                                  that keeps a column sum of each), ms a
                                  block: no dispatch in it
 
 ``<table>`` is ``items`` (what the user half reads) or ``users``;
-``<range>`` is ``heavy`` (the heavy class and the zero rows, 9.4 MB),
-``five`` (the last five classes, 31.5 / 40.9 MB) or ``budget`` (what
-``als_sparse.resident_row0`` picks under ``GATHER_VMEM_BYTES``);
-``<block>`` is ``hot`` (every slot in the heavy class), ``pad`` (every
-slot the zero row), ``cold`` (every slot below every range) or ``mix``
-(the cell's shares for that half: the plan's count of slots in the heavy
-class, in the classes between and below). Every kernel result is
-compared with XLA's, bit for bit. A summary lands in
-``chiprun_out/step0_als_gather.json``.
+``<range>`` is ``heavy`` (the heavy class and the zero rows, 9.4 MB) or
+``five`` (the last five classes, 31.5 / 40.9 MB); ``<block>`` is ``hot``
+(every slot in the heavy class), ``pad`` (every slot the zero row),
+``cold`` (every slot below every range) or ``mix`` (the cell's shares
+for that half: the plan's count of slots in the heavy class, in the
+classes between and below). Every result is compared with XLA's, bit
+for bit. A summary lands in ``chiprun_out/step0_als_gather.json``.
 
-PR 37's readings (``PERF.md`` section 6) also name forms of the kernel
-that are not in the code any more, each timed once on the ``.x8`` rows:
-8 slots a trip of pass 1 (7% slower than 16), 8 copies a trip of pass 2
-(5% slower on a cold block than 16), the resident range copied in by 4
-or 16 DMAs (the same as one), a chunk's slots in parts of 2048 with a
+The forms (``_form_kernel``; none but the last is in
+``ops/pallas_als.py``): a name is the cold list's maker, what pass 1
+reads, and who writes the rating's lane.
+
+  i        the parent (PR 37): pass 1 lists the cold slots itself, a
+           cursor in SMEM, and turns the index into a resident row
+           (difference, clamp)
+  i.v      the same, pass 1's store at the trip's address plus a
+           constant (a view of the trip's rows)
+  ii       the list loaded (int32, a chunk's slots + 1024 words, the
+           count its last word); difference and clamp in pass 1
+  ii.h     the list loaded, the index re-based by the loader: the clamp
+           is left
+  iii      the list and the resident row loaded, ready made; pass 2
+           reads the index
+  <f>.t    the rating's lane after pass 3: a 128 x 128 transpose of a
+           row of ratings spread down the sublanes, a select a vector
+  <f>.q    the same from ratings the loader turned a group of eight
+           slots down the sublanes: a lane rotation and a masked store
+           a group
+  <f>.p    the lane in pass 1 (the rating through SMEM), then again
+           over the cold list once the rows have landed
+  ships    ``ii.h.q`` with two 16-bit positions a word of the list and
+           the counts by scalar prefetch: ``pallas_als``' own
+
+``--bundles`` compiles every form for a described v5e with libtpu's
+dump in a temporary directory and prints the bundles of each loop of the
+static schedule, post-RA, in the kernel's order (pass 1 a trip of 16
+slots, 32 in what ships; pass 2 a trip of 16 copies, pass 3 a wait,
+then the lane's).
+
+PR 37's readings (``PERF.md`` section 6) also name forms that are not
+in any code any more, each timed once on the ``.x8`` rows: 8 slots a
+trip of pass 1 (7% slower than 16), 8 copies a trip of pass 2 (5%
+slower on a cold block than 16), the resident range copied in by 4 or
+16 DMAs (the same as one), a chunk's slots in parts of 2048 with a
 part's copies started before the next part's pass 1 (nothing over
-chunks of 2048).
+chunks of 2048), chunks of 8, 16 and 32 rows (7, 3 and 1% slower than
+64).
 """
 
 from __future__ import annotations
 
+import functools
+import glob
 import json
 import os
+import re
+import subprocess
 import sys
+import tempfile
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -54,7 +101,332 @@ sys.path.insert(0, ROOT)
 N_RATINGS, N_USERS, N_ITEMS, K = 252_800_275, 1_000_990, 624_961, 100
 GEOMETRY = dict(seg_slots=32, piece_segs=64, batch=6144)
 FIVE = 4           # light classes before the heavy one in ``five``
+FORMS = ("i", "i.v", "ii", "ii.h", "iii", "ii.h.t", "ii.h.q", "ii.h.p",
+         "iii.q")
+FEW = ("i", "ii.h", "iii", "ii.h.q")    # the forms the users' table times
+LIST_PAD = 1024    # a 1-D block in HBM is whole tiles of 1024 words
+UNROLL = 16        # slots a trip of the forms' pass 1 (what ships: 32)
 
+
+def say(msg):
+    print(msg, flush=True)
+
+
+# ---- the forms ------------------------------------------------------------
+
+def _parse(form: str):
+    parts = form.split(".")
+    lanes = parts.pop() if parts[-1] in "tqp" else None
+    view = parts[-1] == "v" or parts[0] != "i"
+    row = {"i": "idx", "ii": "idx", "iii": "rel"}[parts[0]]
+    if parts[1:] == ["h"]:
+        row = "rebased"
+    return parts[0] != "i", row, lanes, view
+
+
+def _form_kernel(*refs, hot_row0, k, loaded, row, lanes, view, n_res):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_distalg.ops.pallas_als import FETCH, LANES
+
+    refs = list(refs)
+    idx_ref = refs.pop(0)                 # idx, re-based or resident row
+    cold_ref = refs.pop(0) if loaded else None
+    pidx_ref = refs.pop(0) if row == "rel" else idx_ref   # pass 2's rows
+    val_ref = refs.pop(0) if lanes else None
+    tab_ref, out_ref, res_ref = refs[:3]
+    if not loaded:
+        cold_ref = refs[3]
+    res_sem, row_sem = refs[-2:]
+    slots = out_ref.shape[0]
+    last = n_res - 1
+
+    @pl.when(pl.program_id(0) == 0)
+    def _load():
+        cp = pltpu.make_async_copy(
+            tab_ref.at[pl.ds(hot_row0, n_res), :], res_ref, res_sem)
+        cp.start()
+        cp.wait()
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    lane8 = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 1)
+
+    def some(t, n_cold):
+        first = pl.multiple_of(t * UNROLL, UNROLL)
+        rows = out_ref.at[pl.ds(first, UNROLL), :]
+        for u in range(UNROLL):
+            h = idx_ref[first + u]
+            if row == "idx":
+                h = h - hot_row0
+            if not loaded:
+                cold_ref[n_cold] = first + u
+                n_cold = n_cold - (h >> 31)
+            if row != "rel":
+                h = jnp.minimum(h.astype(jnp.uint32),
+                                jnp.uint32(last)).astype(jnp.int32)
+            v = res_ref[pl.ds(h, 1), :]
+            if lanes == "p":
+                v = jnp.where(lane == k, val_ref[first + u], v)
+            if view:
+                rows[pl.ds(u, 1), :] = v
+            else:
+                out_ref[pl.ds(first + u, 1), :] = v
+        return n_cold
+
+    n_cold = jax.lax.fori_loop(0, slots // UNROLL, some, jnp.int32(0))
+    if loaded:
+        n_cold = cold_ref[slots + LIST_PAD - 1]
+    else:
+        again = cold_ref[jnp.maximum(n_cold - 1, 0)]
+        for u in range(FETCH - 1):
+            cold_ref[n_cold + u] = again
+    trips = (n_cold + FETCH - 1) // FETCH
+
+    def fetch(g, carry):
+        first = pl.multiple_of(g * FETCH, FETCH)
+        at = [cold_ref[first + u] for u in range(FETCH)]
+        rows = [pidx_ref[a] for a in at]
+        if row == "rebased":
+            rows = [h + hot_row0 for h in rows]
+        for a, h in zip(at, rows):
+            pltpu.make_async_copy(
+                tab_ref.at[pl.ds(h, 1), :], out_ref.at[pl.ds(a, 1), :],
+                row_sem).start()
+        return carry
+
+    jax.lax.fori_loop(0, trips, fetch, 0)
+
+    def land(g, carry):
+        pltpu.make_async_copy(
+            tab_ref.at[pl.ds(0, FETCH), :], out_ref.at[pl.ds(0, FETCH), :],
+            row_sem).wait()
+        return carry
+
+    jax.lax.fori_loop(0, trips, land, 0)
+
+    if lanes == "p":
+        def fix(g, carry):
+            first = pl.multiple_of(g * FETCH, FETCH)
+            at = [cold_ref[first + u] for u in range(FETCH)]
+            got = [jnp.where(lane == k, val_ref[a],
+                             out_ref[pl.ds(a, 1), :]) for a in at]
+            for a, v in zip(at, got):
+                out_ref[pl.ds(a, 1), :] = v
+            return carry
+
+        jax.lax.fori_loop(0, trips, fix, 0)
+    elif lanes == "q":
+        def tile(q, carry):
+            first = pl.multiple_of(q * 1024, 1024)
+            vals = val_ref[pl.ds(pl.multiple_of(q * 8, 8), 8), :]
+            for m in range(LANES):
+                rot = pltpu.roll(vals, (k - m) % LANES, axis=1) \
+                    if (k - m) % LANES else vals
+                pltpu.store(out_ref.at[pl.ds(first + 8 * m, 8), :], rot,
+                            mask=lane8 == k)
+            return carry
+
+        jax.lax.fori_loop(0, slots // 1024, tile, 0)
+    elif lanes == "t":
+        def row_of(q, carry):
+            first = pl.multiple_of(q * LANES, LANES)
+            spread = jnp.broadcast_to(val_ref[pl.ds(q, 1), :],
+                                      (LANES, LANES)).T
+            for m in range(LANES // 8):
+                at = pl.ds(first + 8 * m, 8)
+                out_ref[at, :] = jnp.where(
+                    lane8 == k, spread[8 * m:8 * m + 8, :], out_ref[at, :])
+            return carry
+
+        jax.lax.fori_loop(0, slots // LANES, row_of, 0)
+
+
+def form_gather(form: str, table, hot_row0: int, arrays: dict, *,
+                k: int = K, interpret: bool = False):
+    """One block through a form: ``arrays`` holds ``idx`` and what the
+    form's loader would have made (``block_arrays``)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_distalg.ops.pallas_als import (
+        CHUNK_ROWS, FETCH, LANES, VMEM_SLACK, chunk_rows)
+
+    loaded, row, lanes, view = _parse(form)
+    idx_b = arrays["idx"]
+    cr = chunk_rows(idx_b.shape[0], CHUNK_ROWS)
+    n_res = table.shape[0] - hot_row0
+    slots = cr * LANES
+
+    def smem(n):
+        return pl.BlockSpec((n,), lambda c: (c,), memory_space=pltpu.SMEM)
+
+    args, specs = [arrays[row].reshape(-1)], [smem(slots)]
+    if loaded:
+        args.append(arrays["cold"].reshape(-1))
+        specs.append(smem(slots + LIST_PAD))
+    if row == "rel":
+        args.append(idx_b.reshape(-1))
+        specs.append(smem(slots))
+    if lanes == "p":
+        args.append(arrays["val"].reshape(-1))
+        specs.append(smem(slots))
+    elif lanes:
+        args.append(arrays["val_t" if lanes == "q" else "val"])
+        specs.append(pl.BlockSpec((cr, LANES), lambda c: (c, 0)))
+    args.append(table)
+    specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    scratch = [pltpu.VMEM((n_res, LANES), table.dtype)]
+    if not loaded:
+        scratch.append(pltpu.SMEM((slots + FETCH,), jnp.int32))
+    scratch += [pltpu.SemaphoreType.DMA(()), pltpu.SemaphoreType.DMA(())]
+    return pl.pallas_call(
+        functools.partial(_form_kernel, hot_row0=hot_row0, k=k,
+                          loaded=loaded, row=row, lanes=lanes, view=view,
+                          n_res=n_res),
+        name="_als_gather_kernel",
+        grid=(idx_b.shape[0] // cr,),
+        in_specs=specs,
+        out_specs=pl.BlockSpec((slots, LANES), lambda c: (c, 0)),
+        out_shape=jax.ShapeDtypeStruct((idx_b.size, LANES), table.dtype),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=4 * (n_res + 2 * slots) * LANES + VMEM_SLACK,
+            disable_bounds_checks=True),
+        interpret=interpret,
+    )(*args)
+
+
+def block_arrays(idx, val, hot_row0: int, n_res: int):
+    """What the forms' loaders hand over for blocks ``idx``, ``val``
+    ``(blocks, rows, 128)``, by NumPy: the re-based index, the resident
+    row, the int32 cold lists (the count a chunk's last word) and the
+    turned ratings."""
+    import numpy as np
+
+    from tpu_distalg.ops.pallas_als import CHUNK_ROWS, chunk_rows
+
+    blocks, rows, lanes = idx.shape
+    slots = chunk_rows(rows, CHUNK_ROWS) * lanes
+    rebased = idx - np.int32(hot_row0)
+    rel = np.minimum(rebased.astype(np.uint32), np.uint32(n_res - 1)) \
+        .astype(np.int32)
+    chunks = rebased.reshape(blocks, -1, slots)
+    cold = np.zeros((*chunks.shape[:2], slots + LIST_PAD), np.int32)
+    for b, c in np.ndindex(*chunks.shape[:2]):
+        at = np.flatnonzero(chunks[b, c] < 0)
+        cold[b, c, :at.size] = at
+        cold[b, c, at.size:slots] = at[-1] if at.size else 0
+        cold[b, c, -1] = at.size
+    val_t = val.reshape(blocks, -1, lanes, 8).swapaxes(-1, -2) \
+        .reshape(idx.shape)
+    return dict(idx=idx, val=val, rebased=rebased, rel=rel,
+                cold=cold.reshape(blocks, -1), val_t=val_t)
+
+
+# ---- the static schedule --------------------------------------------------
+
+def compile_one(form: str):
+    """In a child with the dump on: compile one form for a v5e at the
+    cell's block and the items' table."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_distalg.models import als
+    from tpu_distalg.ops import pallas_als
+
+    meta = als.plan_ratings(N_RATINGS, N_USERS, N_ITEMS, K,
+                            geometry=GEOMETRY, on_tpu=True)
+    plan = meta["gather"][0]
+    rows_b = meta["geometry"].block_shape[0]
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    table = arr((meta["item"].static.table_rows, 128), jnp.float32)
+    block_i = arr((rows_b, 128), jnp.int32)
+    block_f = arr((rows_b, 128), jnp.float32)
+    chunks = rows_b // pallas_als.chunk_rows(rows_b)
+    t0 = time.perf_counter()
+    if form == "ships":
+        jax.jit(lambda t, r, v, c, n: pallas_als.gather_rows_resident(
+            t, r, v, c, n, plan.hot_row0, K)).lower(
+                table, block_i, block_f,
+                arr((rows_b * 64,), jnp.int32),
+                arr((chunks,), jnp.int32)).compile()
+    else:
+        names = ("idx", "val", "rebased", "rel", "cold", "val_t")
+        shapes = dict(idx=block_i, val=block_f, rebased=block_i,
+                      rel=block_i, val_t=block_f, cold=arr(
+                          (rows_b * 128 + chunks * LIST_PAD,), jnp.int32))
+        jax.jit(lambda t, *a: form_gather(
+            form, t, plan.hot_row0, dict(zip(names, a)))).lower(
+                table, *(shapes[n] for n in names)).compile()
+    say(f"[compiled] {time.perf_counter() - t0:.2f}")
+
+
+def read_loops(dump: str):
+    """``(first bundle, last)`` of every backward branch of the gather
+    kernel's post-RA schedule, in the file's order."""
+    files = [f for f in sorted(glob.glob(os.path.join(
+        dump, "*_als_gather_kernel*packed-bundles-post-ra.txt")))
+        if "schedule-analysis" not in f]
+    if not files:
+        return None
+    spans = []
+    for line in open(files[-1]):
+        m = re.match(r"\s*(0x[0-9a-f]+)\s+\w*\s*:", line)
+        if not m:
+            continue
+        no = int(m.group(1), 16)
+        for b in re.finditer(r"sbr\.rel \(!?%\w+\) target bundleno = (\d+)",
+                             line):
+            if int(b.group(1)) <= no:
+                spans.append((int(b.group(1)), no))
+    return sorted(spans)
+
+
+def bundles(forms):
+    out = {}
+    for form in forms:
+        # (the dump may abort the child at its very end, its files
+        # written by then: read them whatever the exit code)
+        with tempfile.TemporaryDirectory(prefix="llo_step0_") as dump:
+            done = subprocess.run(
+                [sys.executable, __file__, "--compile-one", form],
+                capture_output=True, text=True, env=dict(
+                    os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+                    LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={dump} "
+                    "--xla_jf_dump_llo_text=true"))
+            loops = read_loops(dump)
+        if not loops:
+            say(f"[bundles] {form}: no schedule (exit {done.returncode}): "
+                + (done.stderr.strip().splitlines() or ["?"])[-1][:300])
+            continue
+        inner = [hi - lo + 1 for lo, hi in loops[1:]]   # [0]: the grid's
+        from tpu_distalg.ops import pallas_als
+
+        trip = pallas_als.UNROLL if form == "ships" else UNROLL
+        names = [f"pass1 a {trip} slots", "pass2 a 16 copies",
+                 "pass3 a wait", "lane"]
+        out[form] = dict(zip(names, inner))
+        say(f"[bundles] {form}: " + ", ".join(
+            f"{n} {b}" for n, b in zip(names, inner))
+            + f" (pass 1: {inner[0] * 8 / trip:.1f} for 8 slots)")
+    return out
+
+
+# ---- on the chip (or interpreted) ----------------------------------------
 
 def least_ms(fn, *args, n: int = 3) -> float:
     import jax
@@ -69,12 +441,19 @@ def least_ms(fn, *args, n: int = 3) -> float:
 
 
 def main(argv) -> int:
+    if "--compile-one" in argv:
+        compile_one(argv[argv.index("--compile-one") + 1])
+        return 0
+    if "--bundles" in argv:
+        bundles([*FORMS, "ships"])
+        return 0
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from tpu_distalg.models import als
-    from tpu_distalg.ops import als_sparse, pallas_als
+    from tpu_distalg.ops import als_sparse
 
     interp = "--rehearse" in argv
     if jax.devices()[0].platform != "tpu" and not interp:
@@ -87,6 +466,7 @@ def main(argv) -> int:
         meta = als.plan_ratings(N_RATINGS, N_USERS, N_ITEMS, K,
                                 geometry=GEOMETRY)
     geom = meta["geometry"]
+    k = geom.k
     rows_b = geom.block_shape[0]
     slots = geom.block_slots
     rng = np.random.default_rng(37)
@@ -94,8 +474,16 @@ def main(argv) -> int:
 
     def row(name, ms):
         out[name] = ms
-        print(f"[step0] {name} {ms:.4f} ms  {ms * 1e6 / slots:.2f} ns/slot",
-              flush=True)
+        say(f"[step0] {name} {ms:.4f} ms  {ms * 1e6 / slots:.2f} ns/slot")
+
+    def eight(fn):
+        def run(T, a8):
+            def one(c, a):
+                return c, jnp.sum(fn(T, a), axis=0)
+
+            return jax.lax.scan(one, 0, a8)[1]
+
+        return jax.jit(run)
 
     # the half that reads a table is the OTHER side's
     for table, own, other in (("items", meta["user"], meta["item"]),
@@ -105,10 +493,11 @@ def main(argv) -> int:
         starts = [r0 for _, _, n, r0 in st.light if n]
         ranges = {
             "heavy": heavy0,
-            "five": starts[-FIVE] if len(starts) >= FIVE else starts[0],
-            "budget": als_sparse.resident_row0(st, geom)}
-        T = jnp.asarray(rng.standard_normal(
-            (st.table_rows, geom.width), np.float32)).at[st.zero_row:].set(0)
+            "five": starts[-FIVE] if len(starts) >= FIVE else starts[0]}
+        T = rng.standard_normal((st.table_rows, geom.width), np.float32)
+        T[st.zero_row:] = 0
+        T[:, k:] = 0
+        T = jnp.asarray(T)
         # the cell's shares of this half's slots: heavy class and
         # padding, the classes of ``five`` below it, the rest
         held = own.slots_held
@@ -117,64 +506,157 @@ def main(argv) -> int:
         out[f"{table}.share"] = share
         out[f"{table}.rows"] = {n: st.table_rows - r for n, r in
                                 ranges.items()}
-        print(f"[step0] {table}: table rows {st.table_rows}, resident "
-              f"rows {out[table + '.rows']}, shares {share}", flush=True)
+        say(f"[step0] {table}: table rows {st.table_rows}, resident "
+            f"rows {out[table + '.rows']}, shares {share}")
         lo = min(ranges.values())
-        u = rng.random(slots)
+        shape = (8, rows_b, 128)
+        u = rng.random(shape)
         blocks = {
-            "hot": rng.integers(heavy0, st.zero_row, slots),
-            "pad": np.full(slots, st.zero_row),
-            "cold": rng.integers(0, max(lo, 1), slots),
+            "hot": rng.integers(heavy0, st.zero_row, shape),
+            "pad": np.full(shape, st.zero_row),
+            "cold": rng.integers(0, max(lo, 1), shape),
             "mix": np.where(
                 u < share["heavy"],
-                rng.integers(heavy0, st.zero_row + 1, slots),
+                rng.integers(heavy0, st.zero_row + 1, shape),
                 np.where(u < share["five"],
                          rng.integers(ranges["five"], max(
-                             heavy0, ranges["five"] + 1), slots),
-                         rng.integers(0, max(ranges["five"], 1), slots)))}
-        blocks = {k: jnp.asarray(v.astype(np.int32).reshape(rows_b, -1))
-                  for k, v in blocks.items()}
-        xla = jax.jit(lambda T, i: als_sparse.gather_rows(T, i))
+                             heavy0, ranges["five"] + 1), shape),
+                         rng.integers(0, max(ranges["five"], 1), shape)))}
+        blocks = {b: v.astype(np.int32) for b, v in blocks.items()}
+        val = rng.integers(0, 101, shape).astype(np.float32)
 
-        def kernel(r0, chunk=None):
-            return jax.jit(lambda T, i: pallas_als.gather_rows_resident(
-                T, i, r0, interpret=interp, chunk=chunk))
+        def xla(T, a):
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, geom.width), 1)
+            flat = a["idx"].reshape(-1)
+            ok = (flat != st.zero_row).astype(jnp.float32)[:, None]
+            return jnp.where(lane == k, a["val"].reshape(-1, 1), jnp.where(
+                lane == k + 1, ok, als_sparse.gather_rows(T, a["idx"])))
 
-        def eight(fn):
-            def run(T, i8):
-                def one(c, i):
-                    return c, jnp.sum(fn(T, i), axis=0)
+        def _ships(M, a, plan):
+            from tpu_distalg.ops import pallas_als
 
-                return jax.lax.scan(one, 0, i8)[1]
+            return pallas_als.gather_rows_resident(
+                M, a["rel"], a["val_t"], a["cold"], a["n_cold"],
+                plan.hot_row0, k, interpret=interp)
 
-            return jax.jit(run)
+        def form(name):
+            # (a form that writes no lane returns the rows alone)
+            return lambda T, a: form_gather(name, T, heavy0, a, k=k,
+                                            interpret=interp)
 
+        def shipped_arrays(idx, plan):
+            made = jax.jit(lambda i, v: als_sparse.gather_lists(
+                i, v, plan))(idx, val)
+            return dict(zip(("rel", "val_t", "cold", "n_cold"), made))
+
+        def first(a8):
+            return jax.tree_util.tree_map(lambda x: x[0], a8)
+
+        # the table as the Mosaic form reads it, made once a half
+        n_res = st.table_rows - heavy0
+        marked = als_sparse.gather_table(
+            T, geom, st.zero_row, als_sparse.GatherPlan("mosaic", heavy0,
+                                                        n_res))
         want = {}
         for b, idx in blocks.items():
-            want[b] = xla(T, idx)
-            row(f"{table}.xla.{b}", least_ms(xla, T, idx))
-        i8 = {b: jnp.stack([jnp.roll(idx, s, axis=0) for s in range(8)])
-              for b, idx in blocks.items()}
-        for b in blocks:
-            row(f"{table}.xla.{b}.x8", least_ms(eight(xla), T, i8[b]) / 8)
+            a8 = dict(idx=jnp.asarray(idx), val=jnp.asarray(val))
+            want[b] = jax.jit(xla)(T, first(a8))
+            row(f"{table}.xla.{b}", least_ms(jax.jit(xla), T, first(a8)))
+            row(f"{table}.xla.{b}.x8", least_ms(eight(xla), T, a8) / 8)
         for name, r0 in ranges.items():
-            if name == "budget" and r0 in (ranges["heavy"], ranges["five"]):
-                continue
+            plan = als_sparse.GatherPlan("mosaic", r0, st.table_rows - r0,
+                                         interpret=interp)
             for b, idx in blocks.items():
-                fn = kernel(r0)
-                if not bool(jnp.array_equal(fn(T, idx), want[b])):
-                    print(f"[step0] {table}.{name}.{b}: NOT the rows "
-                          f"XLA returns", flush=True)
+                if name != "heavy" and b != "mix":
+                    continue
+                a8 = shipped_arrays(jnp.asarray(idx), plan)
+                fn = jax.jit(functools.partial(_ships, plan=plan))
+                if not bool(jnp.array_equal(fn(marked, first(a8)),
+                                            want[b])):
+                    say(f"[step0] {table}.{name}.{b}: NOT the block XLA "
+                        f"makes")
                     return 1
-                row(f"{table}.{name}.{b}", least_ms(fn, T, idx))
-            for b in blocks:
-                row(f"{table}.{name}.{b}.x8",
-                    least_ms(eight(kernel(r0)), T, i8[b]) / 8)
+                row(f"{table}.{name}.{b}", least_ms(fn, marked, first(a8)))
+                row(f"{table}.{name}.{b}.x8", least_ms(eight(
+                    functools.partial(_ships, plan=plan)), marked, a8) / 8)
         if not interp:
-            for chunk in (8, 16, 32):
-                row(f"{table}.heavy.mix.x8.c{chunk}", least_ms(
-                    eight(kernel(ranges["heavy"], chunk)), T, i8["mix"]) / 8)
-        del T, want
+            # the shipped kernel with 16 slots a trip of pass 1
+            from tpu_distalg.ops import pallas_als
+
+            plan = als_sparse.GatherPlan("mosaic", heavy0,
+                                         st.table_rows - heavy0)
+            a8 = shipped_arrays(jnp.asarray(blocks["mix"]), plan)
+
+            def wide(M, a):
+                return pallas_als.gather_rows_resident.__wrapped__(
+                    M, a["rel"], a["val_t"], a["cold"], a["n_cold"],
+                    heavy0, k)
+
+            ships = pallas_als.UNROLL
+            pallas_als.UNROLL = 16
+            try:
+                row(f"{table}.heavy.mix.u16.x8",
+                    least_ms(eight(wide), marked, a8) / 8)
+            finally:
+                pallas_als.UNROLL = ships
+        for b in (("mix",) if table == "users" else ("mix", "hot", "cold")):
+            a8 = {n: jnp.asarray(v) for n, v in block_arrays(
+                blocks[b], val, heavy0, n_res).items()}
+            for name in (FORMS if table == "items" else FEW):
+                lanes = _parse(name)[2]
+                if b != "mix" and lanes:
+                    continue
+                fn = jax.jit(form(name))
+                got = fn(marked if lanes else T, first(a8))
+                ref = want[b] if lanes else als_sparse.gather_rows(
+                    T, blocks[b][0])
+                if not bool(jnp.array_equal(got, ref)):
+                    say(f"[step0] {table}.form.{name}.{b}: NOT the block "
+                        f"XLA makes")
+                    return 1
+                row(f"{table}.form.{name}.{b}.x8", least_ms(
+                    eight(form(name)), marked if lanes else T, a8) / 8)
+        # block_gramians whole, at the heavy class's depth
+        P = geom.piece_segs
+        plan = als_sparse.GatherPlan("mosaic", heavy0, n_res,
+                                     interpret=interp)
+
+        def product(G):
+            G = G.reshape(geom.batch // P, P * geom.seg_slots, geom.width)
+            return jnp.einsum("osd,ose->ode", G, G,
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)
+
+        def gram_xla(T, a):
+            return als_sparse.block_gramians(
+                T, a["idx"], a["val"], P, geom, st.zero_row)
+
+        def gram_parent(T, a):
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, geom.width), 1)
+            ok = (a["idx"].reshape(-1) != st.zero_row).astype(
+                jnp.float32)[:, None]
+            G = form_gather("i", T, heavy0, a, k=k, interpret=interp)
+            return als_sparse.to_lanes(product(jnp.where(
+                lane == k, a["val"].reshape(-1, 1),
+                jnp.where(lane == k + 1, ok, G))))
+
+        def gram_ships(M, a):
+            return als_sparse.block_gramians(
+                M, a["rel"], a["val_t"], P, geom, st.zero_row, plan,
+                (a["cold"], a["n_cold"]))
+
+        a8 = {n: jnp.asarray(v) for n, v in block_arrays(
+            blocks["mix"], val, heavy0, n_res).items()}
+        a8.update(shipped_arrays(a8["idx"], plan))
+        ref = jax.jit(gram_xla)(T, first(a8))
+        for name, fn, tab in (("xla", gram_xla, T), ("i", gram_parent, T),
+                              ("ships", gram_ships, marked)):
+            if not bool(jnp.array_equal(jax.jit(fn)(tab, first(a8)), ref)):
+                say(f"[step0] {table}.gram.{name}.mix: NOT XLA's Gramians")
+                return 1
+            row(f"{table}.gram.{name}.mix.x8",
+                least_ms(eight(fn), tab, a8) / 8)
+        del T, want, marked
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "step0_als_gather.json"),
               "w") as f:

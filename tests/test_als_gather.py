@@ -1,8 +1,10 @@
-"""The two forms of sparse ALS' row gather (``ops/als_sparse.gather_rows``,
-``ops/pallas_als.py``): the Mosaic kernel, interpreted, against
-``other[idx]`` bit for bit on blocks of every kind; a fit whose halves
-gather in either form; the choice of form from what the code can
-observe; the resident share against a count over packed indices."""
+"""The two forms of sparse ALS' row gather (``ops/als_sparse.py``,
+``ops/pallas_als.py``): the loader's lists against what a pass over the
+slots would list; the Mosaic kernel, interpreted, against ``other[idx]``
+and XLA's ``where`` bit for bit on blocks of every kind; a block's
+Gramians and a fit whose halves gather in either form; the choice of
+form from what the code can observe; the resident share against a count
+over packed indices."""
 
 import dataclasses
 import json
@@ -40,6 +42,7 @@ def _block(case: str, rng) -> np.ndarray:
     elif case == "edges":
         idx = rng.integers(0, ROWS + 1, n)
         idx[:4] = [HOT0 - 1, HOT0, ROWS - 1, ROWS]
+        idx[4:6] = [ROWS + 7, ROWS + 1]    # zero rows no padding names
         idx[-4:] = [ROWS, ROWS - 1, HOT0, HOT0 - 1]
     else:                       # one cold slot, and it is the last
         idx = np.full(n, ROWS - 1)
@@ -47,22 +50,110 @@ def _block(case: str, rng) -> np.ndarray:
     return idx.astype(np.int32).reshape(96, 128)
 
 
-@pytest.mark.parametrize("case", ["all_hot", "all_cold", "all_padding",
-                                  "mixed", "edges", "last_cold"])
-def test_mosaic_gather_returns_the_rows_bitwise(case):
-    rng = np.random.default_rng(11)
+CASES = ["all_hot", "all_cold", "all_padding", "mixed", "edges",
+         "last_cold"]
+GEOM96 = ops.SparseGeometry(k=K, seg_slots=32, piece_segs=8, batch=384,
+                            classes=(1, 2, 4))
+PLAN = ops.GatherPlan("mosaic", HOT0, ROWS + 8 - HOT0, interpret=True)
+
+
+def _table(rng) -> np.ndarray:
+    """A factor table as the trainer holds it: ``K`` columns, zero
+    lanes behind them, zero rows at the end."""
     T = rng.standard_normal((ROWS + 8, 128)).astype(np.float32)
     T[ROWS:] = 0.0
+    T[:, K:] = 0.0
+    return T
+
+
+def _lists(idx, val):
+    """The loader's four for one block."""
+    return tuple(a[0] for a in jax.jit(
+        lambda i, v: ops.gather_lists(i, v, PLAN))(idx[None], val[None]))
+
+
+def _pass1_lists(idx_b: np.ndarray, slots: int, fetch: int):
+    """What the kernel that kept the list itself (PR 37) wrote a chunk:
+    a cursor that moves on where a slot is cold, then the last one
+    again to a whole trip of ``fetch``."""
+    out = []
+    for chunk in idx_b.reshape(-1, slots):
+        cold, n = np.zeros(slots + fetch, np.int64), 0
+        for at, row in enumerate(chunk):
+            cold[n] = at
+            n += int(row < HOT0)
+        cold[n:n + fetch - 1] = cold[max(n - 1, 0)]
+        out.append((cold[:-(-n // fetch) * fetch], n))
+    return out
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_loaders_lists_are_what_pass_1_listed(case):
+    rng = np.random.default_rng(11)
     idx = _block(case, rng)
+    val = rng.standard_normal(idx.shape).astype(np.float32)
+    rel, val_t, cold, n_cold = (np.asarray(a) for a in _lists(idx, val))
+    slots = pallas_als.chunk_rows(96) * 128
+    assert slots == 48 * 128 and cold.shape == (96 * 128 // 2,)
+    assert np.array_equal(rel, idx - HOT0)
+    # a tile of 1024 slots: slot 8 m + j at row j, lane m
+    assert np.array_equal(
+        val_t.reshape(-1, 8, 128).transpose(0, 2, 1).reshape(-1),
+        val.reshape(-1))
+    want = _pass1_lists(idx, slots, pallas_als.FETCH)
+    assert n_cold.tolist() == [n for _, n in want]
+    assert int(n_cold.sum()) == int(np.count_nonzero(idx < HOT0))
+    got = np.stack([cold & 0xFFFF, cold >> 16], axis=-1).reshape(-1, slots)
+    for mine, (theirs, n) in zip(got, want):
+        assert np.array_equal(mine[:theirs.size], theirs)
+        assert np.all(mine[n:] == (mine[n - 1] if n else 0))
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_mosaic_gather_returns_the_rows_bitwise(case):
+    rng = np.random.default_rng(11)
+    T = _table(rng)
+    idx = _block(case, rng)
+    val = rng.standard_normal(idx.shape).astype(np.float32)
     assert pallas_als.chunk_rows(96) == 48 and pallas_als.chunk_rows(4) == 0
-    plan = ops.GatherPlan("mosaic", HOT0, ROWS + 8 - HOT0, interpret=True)
-    got = jax.jit(lambda T, i: ops.gather_rows(T, i, plan))(
-        jnp.asarray(T), jnp.asarray(idx))
+    table = ops.gather_table(jnp.asarray(T), GEOM96, ROWS, PLAN)
+    assert np.array_equal(np.asarray(ops.gather_table(
+        jnp.asarray(T), GEOM96, ROWS)), T)      # XLA's form: as it is
+    got = np.asarray(jax.jit(
+        lambda t, *a: pallas_als.gather_rows_resident(
+            t, *a, HOT0, K, interpret=True))(table, *_lists(idx, val)))
     assert got.shape == (96 * 128, 128)
-    assert np.array_equal(np.asarray(got), T[idx.reshape(-1)])
-    # and XLA's form, which is what no plan means
+    flat = idx.reshape(-1)
+    # the rows bit for bit, the rating's and the validity's lanes the
+    # values XLA's ``where`` writes
+    lanes = [K, K + 1]
+    assert np.array_equal(np.delete(got, lanes, 1),
+                          np.delete(T[flat], lanes, 1))
+    lane = np.arange(128)[None, :]
+    assert np.array_equal(got, np.where(
+        lane == K, val.reshape(-1, 1), np.where(
+            lane == K + 1, (flat != ROWS)[:, None].astype(np.float32),
+            T[flat])))
+    # and XLA's gather, which is what the rows are held to
     assert np.array_equal(np.asarray(ops.gather_rows(
-        jnp.asarray(T), jnp.asarray(idx))), T[idx.reshape(-1)])
+        jnp.asarray(T), jnp.asarray(idx))), T[flat])
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_a_blocks_gramians_are_the_same_in_both_forms_bit_for_bit(case):
+    rng = np.random.default_rng(13)
+    T = jnp.asarray(_table(rng))
+    idx = _block(case, rng)
+    val = rng.integers(0, 101, idx.shape).astype(np.float32)
+    rel, val_t, *cold = _lists(idx, val)
+    for depth in (1, 8):
+        xla = jax.jit(lambda t, i, v: ops.block_gramians(
+            t, i, v, depth, GEOM96, ROWS))(T, idx, val)
+        mosaic = jax.jit(lambda t, i, v, *c: ops.block_gramians(
+            ops.gather_table(t, GEOM96, ROWS, PLAN), i, v, depth, GEOM96,
+            ROWS, PLAN, c))(T, rel, val_t, *cold)
+        assert xla.shape == (128, 128, 384 // depth)
+        assert np.array_equal(np.asarray(xla), np.asarray(mosaic))
 
 
 def _toy(mesh, seed=4):
@@ -96,14 +187,43 @@ def test_a_fit_is_the_same_in_both_forms_bit_for_bit(mesh1):
                             - o.static.heavy[2])
         for g, o in zip(mosaic, (meta["item"], meta["user"])))
     assert all(0 < g.hot_row0 for g in mosaic)
+    # the arrays as a loader on a TPU would hold them: the lists made
+    # of the pack's own two, a half each
+    assert len(arrays) == 9
+    made = [jax.jit(lambda i, v, g=g: ops.gather_lists(i, v, g))(
+        arrays[at], arrays[at + 1]) for g, at in zip(mosaic, (0, 3))]
+    held = (*made[0][:2], arrays[2], *made[1][:2], *arrays[5:],
+            *made[0][2:], *made[1][2:])
+    fields = als.segment_fields(dict(meta, gather=mosaic))
+    assert [int(jnp.sum(m[3])) for m in made] == [
+        own.slots_held - ops.resident_slots(own, other, g.hot_row0)
+        for own, other, g in zip((meta["user"], meta["item"]),
+                                 (meta["item"], meta["user"]), mosaic)]
+    assert (fields["gather_cold_list"], fields["gather_lanes"]) == (
+        "loader", "kernel")
     out = []
-    for gather in (meta["gather"], mosaic):
+    for gather, args in ((meta["gather"], arrays), (mosaic, held)):
         fn = als.make_fit_fn(mesh1, cfg, dict(meta, gather=gather))
         X, Theta = als.start_factors(meta, mesh1, cfg.seed)
-        out.append([np.asarray(a) for a in fn(*arrays, X, Theta)])
+        out.append([np.asarray(a) for a in fn(*args, X, Theta)])
     for a, b in zip(*out):       # X, Theta, (training, held-out RMSE),
         assert np.array_equal(a, b)              # ratings seen
     assert out[0][3].tolist() == [[int(du.sum())] * 2] * 2
+    # a form's function takes its own arrays and no others
+    with pytest.raises(TypeError, match="cold lists"):
+        als.make_fit_fn(mesh1, cfg, dict(meta, gather=mosaic))(
+            *arrays, *als.start_factors(meta, mesh1, cfg.seed))
+
+
+def test_no_list_is_built_on_a_mesh_or_off_the_chip(mesh4, mesh1):
+    for mesh in (mesh4, mesh1):
+        _, _, arrays, meta = _toy(mesh)
+        assert meta["forms"]["als_gather_form"] == "xla"
+        assert len(arrays) == 9          # as long as it has always been
+        fields = als.segment_fields(meta)
+        assert (fields["gather_cold_list"], fields["gather_cold_slots"],
+                fields["gather_cold_share"], fields["gather_list_bytes"],
+                fields["gather_lanes"]) == ("none", [0, 0], 0.0, 0, "xla")
 
 
 def _static(n_shards=1, heavy_rows=64, light_rows=128):
@@ -164,6 +284,16 @@ def test_the_cells_statics_take_the_kernel(cell_meta):
     assert (fields["als_gather_form"], fields["gather_resident_rows"],
             fields["gather_resident_share"]) == (
         "mosaic", [18440, 18440], 0.7364)
+    # the lists are the loader's: 160.2M live entries over both halves,
+    # half a word a slot held, and the kernel writes the lanes
+    assert (fields["gather_cold_list"], fields["gather_lanes"]) == (
+        "loader", "kernel")
+    assert fields["gather_cold_slots"] == [64753249, 95425029]
+    assert fields["gather_cold_share"] == 0.2636
+    assert fields["gather_list_bytes"] == 2 * (
+        meta["user"].slots_held + meta["item"].slots_held) == 1215430656
+    assert als._prepare_fields(meta)["gather_cold_slots"] == [
+        64753249, 95425029]
     # the same sizes where the fit will not run on a TPU: XLA's form,
     # nothing resident
     off = als._ratings_meta(meta["geometry"], (meta["user"], meta["item"]),
@@ -171,6 +301,20 @@ def test_the_cells_statics_take_the_kernel(cell_meta):
     assert off["forms"]["als_gather_form"] == "xla"
     assert off["gather_resident_rows"] == (0, 0)
     assert off["gather_resident_share"] == 0.0
+
+
+def test_the_report_prints_what_the_kernel_is_handed(cell_meta):
+    from tpu_distalg.telemetry import report
+
+    start = dict(ev="span_start", name="train:segment", id=1, parent=None,
+                 t=0.0, **als.segment_fields(cell_meta))
+    line, = [ln for ln in report.render(report.summarize([start]))
+             .splitlines() if ln.startswith("R layout")]
+    assert line == (
+        "R layout: ratings (gather: mosaic with 0.7364 of the slots "
+        "resident, 0.2636 cold and listed by the loader (160178278 slots, "
+        "1215430656 B), lanes by the kernel, gramians: xla, solve: mosaic "
+        "in tiles of 128)")
 
 
 def test_resident_share_is_a_count_over_the_packed_indices(mesh1):

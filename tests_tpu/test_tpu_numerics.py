@@ -1311,18 +1311,19 @@ def test_sparse_als_solve_kernel_at_rank_100_against_float64():
     (663_560, 645_120),        # the items' table, what the user half reads
     (1_032_200, 1_013_760)])   # the users'
 def test_sparse_als_resident_gather_is_xlas_bitwise(table_rows, hot_row0):
-    """The Mosaic gather (``ops/pallas_als.py``) against XLA's
-    ``other[idx]``, bit for bit, on one block at the benchmark's block
-    shape (6144 segments x 32 slots = 196 608 rows of 128 lanes) and both
-    tables' row counts with the heavy class and the zero rows resident:
-    the cell's mix of heavy, padding and cold slots, then the rows at the
-    range's two ends."""
+    """The Mosaic gather (``ops/pallas_als.py``, on the loader's lists)
+    against XLA's ``other[idx]`` and its ``where`` for the rating's and
+    the validity's lanes, bit for bit, on one block at the benchmark's
+    block shape (6144 segments x 32 slots = 196 608 rows of 128 lanes)
+    and both tables' row counts with the heavy class and the zero rows
+    resident: the cell's mix of heavy, padding and cold slots, then the
+    rows at the range's two ends."""
     from tpu_distalg.ops import als_sparse as ops
 
     zero_row = table_rows - 8
     rng = np.random.default_rng(7)
     T = jnp.asarray(rng.standard_normal((table_rows, 128), np.float32)
-                    ).at[zero_row:].set(0.0)
+                    ).at[zero_row:].set(0.0).at[:, 100:].set(0.0)
     n = 1536 * 128
     u = rng.random(n)
     idx = np.where(u < 0.568, rng.integers(hot_row0, zero_row, n),
@@ -1331,12 +1332,37 @@ def test_sparse_als_resident_gather_is_xlas_bitwise(table_rows, hot_row0):
     idx[:4] = [hot_row0 - 1, hot_row0, zero_row - 1, zero_row]
     idx[-4:] = [zero_row, 0, table_rows - 1, hot_row0 - 1]
     idx = jnp.asarray(idx.astype(np.int32).reshape(1536, 128))
-    plan = ops.GatherPlan("mosaic", hot_row0, table_rows - hot_row0)
-    got = jax.jit(lambda T, i: ops.gather_rows(T, i, plan))(T, idx)
-    want = jax.jit(lambda T, i: ops.gather_rows(T, i))(T, idx)
+    val = jnp.asarray(rng.integers(0, 101, (1536, 128)).astype(np.float32))
+    got = jax.jit(_sparse_als_block_mosaic, static_argnums=(3, 4))(
+        T, idx, val, hot_row0, zero_row)
+    want = jax.jit(_sparse_als_block_xla, static_argnums=3)(
+        T, idx, val, zero_row)
     assert got.shape == (n, 128)
     assert bool(jnp.array_equal(got, want))
+    assert bool(jnp.array_equal(got[:, :100],
+                                ops.gather_rows(T, idx)[:, :100]))
     assert float(jnp.abs(got).sum()) > 0
+
+
+def _sparse_als_block_mosaic(T, idx, val, hot_row0, zero_row):
+    """One block as the product wants it, through the kernel: the
+    loader's lists, the table as the gather reads it, the kernel."""
+    from tpu_distalg.ops import als_sparse as ops
+    from tpu_distalg.ops import pallas_als
+
+    plan = ops.GatherPlan("mosaic", hot_row0, T.shape[0] - hot_row0)
+    lists = (a[0] for a in ops.gather_lists(idx[None], val[None], plan))
+    table = ops.gather_table(T, ops.SparseGeometry(k=100), zero_row, plan)
+    return pallas_als.gather_rows_resident(table, *lists, hot_row0, 100)
+
+
+def _sparse_als_block_xla(T, idx, val, zero_row):
+    from tpu_distalg.ops import als_sparse as ops
+
+    lane = jnp.arange(128)[None, :]
+    ok = (idx.reshape(-1) != zero_row).astype(jnp.float32)[:, None]
+    return jnp.where(lane == 100, val.reshape(-1, 1),
+                     jnp.where(lane == 101, ok, ops.gather_rows(T, idx)))
 
 
 def test_sparse_als_resident_gather_over_many_blocks():
@@ -1344,23 +1370,23 @@ def test_sparse_als_resident_gather_over_many_blocks():
     rows' copies land over rows that pass 1 has stored, chunk after
     chunk and call after call, and a race between the two would show
     as a stale row here and as a digit in a fit."""
-    from tpu_distalg.ops import als_sparse as ops
-
     table_rows, hot_row0 = 663_560, 645_120
     zero_row = table_rows - 8
     T = jax.random.normal(jax.random.PRNGKey(1), (table_rows, 128),
-                          jnp.float32).at[zero_row:].set(0.0)
-    plan = ops.GatherPlan("mosaic", hot_row0, table_rows - hot_row0)
+                          jnp.float32)
+    T = T.at[zero_row:].set(0.0).at[:, 100:].set(0.0)
 
     def one(bad, key):
-        ku, kh, kc = jax.random.split(key, 3)
+        ku, kh, kc, kv = jax.random.split(key, 4)
         u = jax.random.uniform(ku, (1536, 128))
         idx = jnp.where(
             u < 0.568, jax.random.randint(kh, u.shape, hot_row0, zero_row),
             jnp.where(u < 0.736, zero_row,
                       jax.random.randint(kc, u.shape, 0, hot_row0)))
-        differ = jnp.any(ops.gather_rows(T, idx, plan)
-                         != ops.gather_rows(T, idx), axis=1)
+        val = jax.random.randint(kv, u.shape, 0, 101).astype(jnp.float32)
+        differ = jnp.any(
+            _sparse_als_block_mosaic(T, idx, val, hot_row0, zero_row)
+            != _sparse_als_block_xla(T, idx, val, zero_row), axis=1)
         return bad + jnp.sum(differ.astype(jnp.int32)), None
 
     bad, _ = jax.jit(lambda keys: jax.lax.scan(one, jnp.int32(0), keys))(

@@ -43,6 +43,7 @@ precision contract.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -277,7 +278,14 @@ def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
     resident = tuple(
         als_sparse.resident_slots(p, o, g.hot_row0) if g.resident_rows
         else 0 for p, o, g in zip(plans, (pi, pu), gathers))
-    held = (pu.slots_held + pi.slots_held) * 8
+    # what the Mosaic gather's lists hold (``als_sparse.gather_lists``:
+    # the loader's, made once): a half's cold slots, and half a word a
+    # slot held
+    cold = tuple(p.slots_held - r if g.form == "mosaic" else 0
+                 for p, r, g in zip(plans, resident, gathers))
+    lists = sum(2 * p.slots_held for p, g in zip(plans, gathers)
+                if g.form == "mosaic")
+    held = (pu.slots_held + pi.slots_held) * 8 + lists
     tables = (pu.static.table_rows + pi.static.table_rows) \
         * geom.width * 4
     return dict(
@@ -293,6 +301,7 @@ def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
             r / max(p.slots_held, 1) for r, p in zip(resident, plans)),
         gather_resident_share=sum(resident)
         / max(pu.slots_held + pi.slots_held, 1),
+        gather_cold_slots=cold, gather_list_bytes=lists,
         gather=gathers, solve=solve,
         forms=dict(als_gather_form="/".join(
             dict.fromkeys(g.form for g in gathers)),
@@ -314,11 +323,23 @@ def _gather_fields(meta: dict) -> dict:
     """The gather's form and how often its resident range engages: the
     range's rows in the items' and the users' table (what the user and
     the item half read) and the share of the slots held that point into
-    it."""
+    it. Then what the Mosaic form is handed ready made: who lists a
+    chunk's cold slots (``loader``, once; ``none`` where no half takes
+    the kernel), the lists' live entries a half with their share of the
+    slots held, the lists' bytes, and who writes the rating's and the
+    validity's lanes."""
+    mosaic = any(g.form == "mosaic" for g in meta["gather"])
+    held = meta["user"].slots_held + meta["item"].slots_held
     return dict(
         als_gather_form=meta["forms"]["als_gather_form"],
         gather_resident_rows=list(meta["gather_resident_rows"]),
-        gather_resident_share=round(meta["gather_resident_share"], 4))
+        gather_resident_share=round(meta["gather_resident_share"], 4),
+        gather_cold_list="loader" if mosaic else "none",
+        gather_cold_slots=list(meta["gather_cold_slots"]),
+        gather_cold_share=round(
+            sum(meta["gather_cold_slots"]) / max(held, 1), 4),
+        gather_list_bytes=meta["gather_list_bytes"],
+        gather_lanes="kernel" if mosaic else "xla")
 
 
 def segment_fields(meta: dict) -> dict:
@@ -363,12 +384,42 @@ def ratings_from_coo(users, items, ratings, n_users: int, n_items: int,
     hv = pi.row_of_owner[np.asarray(heldout[1], np.int64)]
     meta = _ratings_meta(geom, (pu, pi), users.shape[0], n_heldout, S,
                          mesh_on_tpu(mesh))
-    arrays = tuple(_put(a, "ratings", mesh) for a in
-                   (ui, uv, pu.piece_slot, ii, iv, pi.piece_slot)) \
-        + tuple(_put(a, "heldout", mesh) for a in
-                (hu.astype(np.int32), hv.astype(np.int32),
-                 np.asarray(heldout[2], np.float32)))
-    return arrays, meta
+    sides = [tuple(_put(a, "ratings", mesh) for a in side)
+             for side in ((ui, uv), (ii, iv))]
+    pieces = [_put(p.piece_slot, "ratings", mesh) for p in (pu, pi)]
+    held = tuple(_put(a, "heldout", mesh) for a in
+                 (hu.astype(np.int32), hv.astype(np.int32),
+                  np.asarray(heldout[2], np.float32)))
+    return _ratings_arrays(sides, pieces, held, meta, mesh), meta
+
+
+def _ratings_arrays(sides, pieces, held, meta: dict, mesh: Mesh):
+    """What the trainer's function takes before the two factor tables:
+    a side's packed indices, ratings and pieces' slots, the users' then
+    the items', and the held-out pairs. A half whose gather takes the
+    Mosaic form holds its indices and ratings as the kernel reads them
+    and two arrays more at the end, its cold lists and their counts
+    (``als_sparse.gather_lists``, made here, once, on the device; the
+    pack's own two are given up to it)."""
+    from tpu_distalg.ops import als_sparse
+    from tpu_distalg.parallel import partition
+    from tpu_distalg.telemetry import events as tevents
+
+    lists = []
+    rows = partition.leaf_sharding("als_sparse", "ratings", mesh)
+    for s, gather in enumerate(meta["gather"]):
+        if gather.form != "mosaic":
+            continue
+        with tevents.span("als:lists", side=s,
+                          cold_slots=meta["gather_cold_slots"][s]):
+            made = jax.jit(
+                functools.partial(als_sparse.gather_lists, gather=gather),
+                donate_argnums=(0, 1), out_shardings=(rows,) * 4)(
+                    *sides[s])
+            jax.block_until_ready(made)
+        sides[s] = made[:2]
+        lists += made[2:]
+    return (*sides[0], pieces[0], *sides[1], pieces[1], *held, *lists)
 
 
 def _put(x, leaf: str, mesh: Mesh):
@@ -509,7 +560,8 @@ def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
     the model and draws the noise (``datasets.seeded_ratings``);
     ``n_heldout`` more pairs are drawn the same way and never trained
     on. Returns ``(arrays, meta)``: ``arrays`` is what the trainer's
-    function takes before the two factor tables."""
+    function takes before the two factor tables
+    (:func:`_ratings_arrays`)."""
     from tpu_distalg.ops import als_sparse
     from tpu_distalg.telemetry import events as tevents
     from tpu_distalg.utils import datasets as dsets
@@ -548,8 +600,8 @@ def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
                                   max(meta["n_heldout"], 1), geom.k)
             jax.block_until_ready(held)
         del stub_rows, planted, stubs
-    pieces = [_put(p.piece_slot, "ratings", mesh) for p in plans]
-    arrays = (*sides[0], pieces[0], *sides[1], pieces[1], *held)
+        pieces = [_put(p.piece_slot, "ratings", mesh) for p in plans]
+        arrays = _ratings_arrays(sides, pieces, held, meta, mesh)
     return arrays, meta
 
 
@@ -626,7 +678,9 @@ _take_rows = jax.jit(_take_rows, static_argnames="k")
 
 def _make_fit_fn_sparse(mesh: Mesh, config: ALSConfig, meta: dict):
     """``fit(user idx, val, pieces, item idx, val, pieces, held-out
-    users', items' rows, ratings, X, Theta) -> (X, Theta, errs, seen)``:
+    users', items' rows, ratings[, a Mosaic half's cold lists and
+    counts], X, Theta) -> (X, Theta, errs, seen)`` (the loaders'
+    ``arrays``, :func:`_ratings_arrays`, then the two tables):
     ``config.n_iterations`` ALS iterations (the user half from Theta,
     then the item half from the new X: one function over (owners'
     ratings, the other side's table)), ``errs`` float32 ``(iterations,
@@ -645,26 +699,37 @@ def _make_fit_fn_sparse(mesh: Mesh, config: ALSConfig, meta: dict):
     n_ratings = max(meta["n_ratings"], 1)
 
     def half(static, other_zero_row, gather):
-        def run(idx, val, pieces, other, own):
+        n_cold = 2 if gather.form == "mosaic" else 0
+
+        def run(idx, val, pieces, other, own, *cold):
             return als_sparse.half_sweep(
                 idx, val, pieces, other, own, static=static,
                 other_zero_row=other_zero_row, geom=geom,
                 lam=config.lam, axis=DATA_AXIS, gather=gather,
-                solve=meta["solve"])
+                solve=meta["solve"], cold=cold)
 
-        return data_parallel(
-            run, mesh, in_specs=(*(P(DATA_AXIS),) * 3, P(), P()),
+        return n_cold, data_parallel(
+            run, mesh, in_specs=(*(P(DATA_AXIS),) * 3, P(), P(),
+                                 *(P(DATA_AXIS),) * n_cold),
             out_specs=(P(), P(), P()))
 
     gather_u, gather_i = meta["gather"]
-    user_half = half(su, si.zero_row, gather_u)
-    item_half = half(si, su.zero_row, gather_i)
+    cold_u, user_half = half(su, si.zero_row, gather_u)
+    cold_i, item_half = half(si, su.zero_row, gather_i)
 
-    def fit(ui, uv, up, ii, iv, ip, hu, hv, hr, X, Theta):
+    def fit(ui, uv, up, ii, iv, ip, hu, hv, hr, *rest):
+        *cold, X, Theta = rest
+        if len(cold) != cold_u + cold_i:
+            raise TypeError(
+                f"the gathers' forms {meta['forms']['als_gather_form']} "
+                f"want {cold_u + cold_i} arrays of cold lists, "
+                f"{len(cold)} were handed in")
+
         def iteration(carry, _):
             X, Theta = carry
-            X, _, seen_u = user_half(ui, uv, up, Theta, X)
-            Theta, sse, seen_i = item_half(ii, iv, ip, X, Theta)
+            X, _, seen_u = user_half(ui, uv, up, Theta, X, *cold[:cold_u])
+            Theta, sse, seen_i = item_half(ii, iv, ip, X, Theta,
+                                           *cold[cold_u:])
             from tpu_distalg.telemetry import names
 
             with jax.named_scope(names.ALS_UPDATE):
@@ -680,7 +745,8 @@ def _make_fit_fn_sparse(mesh: Mesh, config: ALSConfig, meta: dict):
     rep = partition.leaf_sharding("als_sparse", "factors", mesh)
     # the tables in are the tables out: no second pair is held
     return jax.jit(fit, out_shardings=(rep, rep, rep, rep),
-                   donate_argnums=(9, 10))
+                   donate_argnums=(9 + cold_u + cold_i,
+                                   10 + cold_u + cold_i))
 
 
 def fit_ratings(mesh: Mesh, config: ALSConfig, arrays, meta: dict, *,

@@ -41,19 +41,29 @@ r^2`` and ``n_u`` come out of the same MXU pass as ``A_u`` (rows ``k``
 and ``k + 1`` of the product) and the training error needs no second
 gather.
 
-**The gather** (:func:`gather_rows`) has two forms that return the same
-rows bit for bit, and :func:`gather_plan` picks one from what the code
-can observe, with no flag:
+**The gather** has two forms that hand the product the same block bit
+for bit (a slot's row of the other side's table, the rating in lane
+``k``, the validity in lane ``k + 1``), and :func:`gather_plan` picks
+one from what the code can observe, with no flag:
 
 ``mosaic``  ``ops/pallas_als.py``: the table stays in HBM, the *resident
             range* of it is copied into VMEM once a call, a slot that
             points there is a dynamic-row vector load and a slot that
-            does not a row DMA. On a TPU, where a factor row is one
-            vector of 128 lanes, a block's slots are whole vectors, the
-            table is one shard's (on a mesh it is shard-major and the
-            heavy rows are ``n_shards`` ranges) and the range fits.
-``xla``     ``other.at[idx].get(...)``: a DMA a 512 B row, 9 to 13.6 ns
-            whatever the block; everywhere else.
+            does not a row DMA, and the kernel writes the rating's lane
+            itself. What of a slot is the same all run long the loader
+            makes once (:func:`gather_lists`: the index re-based on the
+            range, a chunk's cold slots listed, the ratings turned a
+            group of slots down the sublanes), and the validity's lane
+            is the table's own, set once a half (:func:`gather_table`:
+            a slot is valid where its row is not ``zero_row``). On a TPU,
+            where a factor row is one vector of 128 lanes, a block's
+            slots are whole vectors, the table is one shard's (on a
+            mesh it is shard-major and the heavy rows are ``n_shards``
+            ranges) and the range fits.
+``xla``     :func:`gather_rows`, ``other.at[idx].get(...)``: a DMA a
+            512 B row, 9 to 13.6 ns whatever the block, and a ``where``
+            for the two lanes that XLA fuses into it; everywhere else,
+            and the kernel's reference in the tests.
 
 The resident range (:func:`resident_row0`) is the table's tail from a
 class boundary of the side that is read to ``table_rows``: the heavy
@@ -425,38 +435,110 @@ def pack_coo(plan: SidePlan, geom: SparseGeometry, owners, others,
 # ---------------------------------------------------------------- device
 
 
-def gather_rows(other, idx_b, gather: GatherPlan | None = None):
-    """``other[idx_b.reshape(-1)]`` in the plan's form (XLA's where
-    none is given): the same rows, bit for bit."""
-    if gather is not None and gather.form == "mosaic":
-        from tpu_distalg.ops import pallas_als
-
-        return pallas_als.gather_rows_resident(
-            other, idx_b, gather.hot_row0, interpret=gather.interpret)
+def gather_rows(other, idx_b):
+    """``other[idx_b.reshape(-1)]``, XLA's gather: what the Mosaic
+    kernel's rows are held to, bit for bit."""
     return other.at[idx_b.reshape(-1)].get(mode="promise_in_bounds")
 
 
+def gather_lists(idx, val, gather: GatherPlan):
+    """What of a side's packed blocks the Mosaic gather wants made once,
+    on the device: from ``idx`` int32 and ``val`` float32 ``(blocks,
+    rows, 128)`` as the pack holds them,
+
+    ``rel``     int32, ``idx`` re-based on the resident range: negative
+                where the slot is cold;
+    ``val_t``   float32, ``val`` turned tile by tile of 1024 slots: a
+                tile's row ``j`` holds slot ``8 m + j`` at lane ``m``, a
+                group of eight slots down the sublanes;
+    ``cold``    int32 ``(blocks, slots / 2)``: for every chunk (a grid
+                step of the kernel) the positions in it of its cold
+                slots, in slot order, filled to the chunk's end with
+                the last one again (0 where none is cold), two 16-bit
+                positions a word, the earlier one low;
+    ``n_cold``  int32 ``(blocks, chunks)``: how many of them are slots.
+
+    None of it changes through a run: ``idx`` is the loader's and the
+    range is static."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_distalg.ops import pallas_als
+
+    blocks, rows, lanes = idx.shape
+    slots = pallas_als.chunk_rows(rows) * lanes
+    rel = idx - jnp.int32(gather.hot_row0)
+    val_t = val.reshape(blocks, -1, lanes, pallas_als.SUBLANES) \
+        .swapaxes(-1, -2).reshape(idx.shape)
+    pos = jnp.arange(slots, dtype=jnp.int32)
+
+    def lists(rel_b):
+        cold = rel_b.reshape(-1, slots) < 0
+        order = jnp.sort(jnp.where(cold, pos, pos + slots), axis=-1)
+        n = jnp.sum(cold, axis=-1, dtype=jnp.int32)[:, None]
+        again = jnp.take_along_axis(order, jnp.maximum(n - 1, 0), axis=-1)
+        order = jnp.where(pos < n, order, jnp.where(n > 0, again, 0))
+        words = order[:, 0::2] | (order[:, 1::2] << 16)
+        return words.reshape(-1), n[:, 0]
+
+    cold, n_cold = jax.lax.map(lists, rel)
+    return rel, val_t, cold, n_cold
+
+
+def gather_table(other, geom: SparseGeometry, zero_row: int,
+                 gather: GatherPlan | None = None):
+    """The other side's table as a half's gather reads it. The Mosaic
+    form finds a slot's validity where it finds the slot's row: lane
+    ``k + 1`` is 1.0 in every row but ``zero_row`` (a slot is valid
+    where it does not point there, as XLA's form has it), made once a
+    half. XLA's form reads the table as it is."""
+    import jax
+    import jax.numpy as jnp
+
+    if gather is None or gather.form != "mosaic":
+        return other
+    row = jax.lax.broadcasted_iota(jnp.int32, (other.shape[0], 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, geom.width), 1)
+    return jnp.where((lane == geom.k + 1) & (row != zero_row),
+                     jnp.float32(1.0), other)
+
+
 def block_gramians(other, idx_b, val_b, K: int, geom: SparseGeometry,
-                   zero_row: int, gather: GatherPlan | None = None):
+                   zero_row: int, gather: GatherPlan | None = None,
+                   cold=None):
     """One block's ``batch / K`` extended Gramians with the owners
     along the lanes, ``(width, width, batch / K)``: the gather of the
-    other side's rows, the rating and the validity written into lanes
-    ``k`` and ``k + 1``, one float32-accurate product ``K * seg_slots``
-    deep an owner."""
+    other side's rows with the rating and the validity in lanes ``k``
+    and ``k + 1``, one float32-accurate product ``K * seg_slots`` deep
+    an owner. In the Mosaic form ``other`` is :func:`gather_table`'s,
+    ``idx_b`` and ``val_b`` are the block's ``rel`` and ``val_t`` and
+    ``cold`` its list and counts (:func:`gather_lists`), and the kernel
+    hands the block over whole; in XLA's form (no plan, or the plan's)
+    a ``where`` writes the two lanes, which XLA fuses into its own
+    gather."""
     import jax
     import jax.numpy as jnp
 
     from tpu_distalg.telemetry import names
 
     k, W = geom.k, geom.width
-    flat = idx_b.reshape(-1)
-    with jax.named_scope(names.ALS_GATHER):
-        G = gather_rows(other, idx_b, gather)
+    if gather is not None and gather.form == "mosaic":
+        from tpu_distalg.ops import pallas_als
+
+        with jax.named_scope(names.ALS_GATHER):
+            G = pallas_als.gather_rows_resident(
+                other, idx_b, val_b, *cold, gather.hot_row0, k,
+                interpret=gather.interpret)
+    else:
+        flat = idx_b.reshape(-1)
+        with jax.named_scope(names.ALS_GATHER):
+            G = gather_rows(other, idx_b)
+        with jax.named_scope(names.ALS_GRAM):
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+            r = val_b.reshape(-1, 1)
+            ok = (flat != zero_row).astype(jnp.float32)[:, None]
+            G = jnp.where(lane == k, r, jnp.where(lane == k + 1, ok, G))
     with jax.named_scope(names.ALS_GRAM):
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-        r = val_b.reshape(-1, 1)
-        ok = (flat != zero_row).astype(jnp.float32)[:, None]
-        G = jnp.where(lane == k, r, jnp.where(lane == k + 1, ok, G))
         G = G.reshape(geom.batch // K, K * geom.seg_slots, W)
         return to_lanes(jnp.einsum(
             "osd,ose->ode", G, G, precision=jax.lax.Precision.HIGHEST,
@@ -632,12 +714,14 @@ def solve_batch(Ap, lam: float, geom: SparseGeometry,
 def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
                other_zero_row: int, geom: SparseGeometry, lam: float,
                axis: str, gather: GatherPlan | None = None,
-               solve: SolvePlan | None = None):
+               solve: SolvePlan | None = None, cold=()):
     """One shard's half of an iteration: every owner of this shard from
     the other side's table ``other`` (whole, constant through the half),
     written into the shard's rows of ``own``; the shards' rows gathered
-    once at the end. Returns ``(table, sse, seen)``, the two sums over
-    all shards. Runs inside ``shard_map`` over ``axis``."""
+    once at the end. In the Mosaic form of the gather ``idx``, ``val``
+    and ``cold`` are :func:`gather_lists`' four. Returns ``(table, sse,
+    seen)``, the two sums over all shards. Runs inside ``shard_map``
+    over ``axis``."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -648,14 +732,20 @@ def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
     s = lax.axis_index(axis)
     with jax.named_scope(names.ALS_UPDATE):
         local = lax.dynamic_slice_in_dim(own, s * R, R, axis=0)
+    with jax.named_scope(names.ALS_GATHER):
+        other = gather_table(other, geom, other_zero_row, gather)
     sse = jnp.float32(0.0)
     seen = jnp.int32(0)
 
     def grams(block, K):
-        return block_gramians(
-            other, lax.dynamic_index_in_dim(idx, block, keepdims=False),
-            lax.dynamic_index_in_dim(val, block, keepdims=False), K,
-            geom, other_zero_row, gather)
+        idx_b, val_b, *cold_b = (
+            lax.dynamic_index_in_dim(a, block, keepdims=False)
+            for a in (idx, val, *cold))
+        # (XLA's form is ``block_gramians``' own: named only where it
+        # is not)
+        how = {"gather": gather, "cold": cold_b} if cold else {}
+        return block_gramians(other, idx_b, val_b, K, geom,
+                              other_zero_row, **how)
 
     # (XLA's form is ``solve_batch``'s own: named only where it is not)
     how = {"solve": solve} if solve and solve.form == "mosaic" else {}

@@ -1,41 +1,58 @@
 """The Mosaic forms of sparse ALS' row gather and of its per-owner
 solve (``ops/als_sparse.py``); the solve's is the file's second half.
 
-``gather_rows_resident(table, idx_b, hot_row0)`` returns ``table[idx_b
-.reshape(-1)]``, bit for bit, for a table of 128-lane float32 rows whose
-tail ``[hot_row0, table rows)`` (the *resident range*: the rows most
-slots point at, ``als_sparse.gather_plan`` picks it) fits VMEM.
+``gather_rows_resident(table, rel_b, val_t, cold_b, n_cold, hot_row0,
+lane)`` returns ``table[rel_b.reshape(-1) + hot_row0]``, bit for bit,
+with lane ``lane`` of every row the slot's value (its rating), for a
+table of 128-lane float32 rows whose tail ``[hot_row0, table rows)``
+(the *resident range*: the rows most slots point at,
+``als_sparse.gather_plan`` picks it) fits VMEM.
+
+What of a slot never changes through a run is made once, by the loader
+(``als_sparse.gather_lists``), and handed in: the slot's index re-based
+on the range (``rel``: negative where the slot is *cold*), a chunk's
+cold slots' positions in slot order, two 16-bit positions a word and
+filled to the chunk's end with the last one again, the chunk's count of
+them, and the values a group of eight slots down the sublanes.
 
 The table stays in HBM. Grid step 0 copies the resident range into a
 VMEM scratch with one DMA; the scratch is held once and lives over the
 whole grid. A grid step then takes a chunk of the block's slots: their
-indices come through SMEM, the gathered rows leave through the
-pipelined output block.
+re-based indices and cold list come through SMEM, the counts by scalar
+prefetch, the values as a VMEM block, and the gathered rows leave
+through the pipelined output block.
 
   pass 1  every slot, no branch: the slot's row of the scratch (the
-          last row of it where the slot is cold) is loaded by a
-          dynamic-row vector load and stored to the slot's row of the
-          output, and the slot's position is written at the cursor of
-          a list in SMEM, which moves on only where the slot is cold;
+          last row of it where the slot is cold: as unsigned a negative
+          index lies past every row) is loaded by a dynamic-row vector
+          load and stored to the slot's row of the output;
   pass 2  every cold slot of the list: a row DMA from the HBM table
           straight over the slot's row of the output block, all on one
-          semaphore, ``FETCH`` a trip and many in flight (the list is
-          filled to whole trips with its last slot again: a row copied
+          semaphore, ``FETCH`` a trip and many in flight (a row copied
           twice is the same row);
-  pass 3  one wait a trip of pass 2, before the chunk leaves.
+  pass 3  one wait a trip of pass 2;
+  pass 4  the values' lane, 1024 slots a trip: a tile of the values is
+          128 groups of eight slots, a group down the sublanes at its
+          own lane; a lane rotation brings a group to lane ``lane`` and
+          a masked store writes that lane of the group's eight rows.
 
-"Hot" is one compare, ``row >= hot_row0``, and a branch a slot would
-cost more than the cursor does: pass 1 is bound by the scalar slots of
-a bundle, two of them for ten operations a slot (the index's load, the
-difference, its sign, the cursor, the list's address and store, the
-clamp, three addresses), 5 cycles a slot where a taken branch alone
-has 4 delay slots. One v5e at the benchmark's block (196 608 slots, a
-table of 663 560 rows, ``scripts/step0_als_gather.py``, PR 37): XLA's
-gather 9.0 ns a slot whatever the rows; this kernel 3.8 ns a hot or
-padding slot and 3.9 to 4.2 more a cold one; copying the resident range
-in costs 2.9 ns a row a call (one DMA or sixteen: 175 GB/s), so a row
-pays for its place only if a call reads it about once: the heavy class
-does, the classes before it do not.
+Pass 1 is bound by the scalar slots of a bundle, two of them for four
+operations a slot (the index's address and load, the clamp, the row's
+address; the store's address is the trip's plus a constant): 69 bundles
+for 32 slots by the static schedule where the kernel that kept the list
+itself took 84 for 16 and ten operations (``scripts/step0_als_gather.py
+--bundles``, PR 46). Pass 2 is bound the same way, eight operations and
+the DMA's own bundle a cold slot (81 bundles for 16), and pass 4 by the
+three units that rotate, eight cycles a rotation (456 bundles for 1024
+slots). ``PERF.md`` section 6, PR 46, has the chip's readings beside
+the schedule's and the forms that were dropped (the resident row ready
+made, which saves the clamp and costs a second list; the lane written
+in pass 1 and again over the list; a transpose in the kernel). One v5e
+at the benchmark's block (196 608 slots, a table of 663 560 rows): XLA's
+gather 9.0 ns a slot whatever the rows; copying the resident range in
+costs 2.9 ns a row a call (one DMA or sixteen: 175 GB/s), so a row pays
+for its place only if a call reads it about once: the heavy class does,
+the classes before it do not (PR 37).
 
 Interpreted on the CPU the kernel runs the same loads, stores and
 copies in the same order.
@@ -54,10 +71,12 @@ LANES = 128
 SUBLANES = 8
 CHUNK_ROWS = 64        # rows of 128 slots a grid step takes (32 KB of
 #                        indices in SMEM, a 4 MB output block; 32, 16
-#                        and 8 read 1, 3 and 7% slower)
-UNROLL = 16            # slots a trip of pass 1 (8: 7% slower)
+#                        and 8 read 1, 3 and 7% slower, PR 37)
+UNROLL = 32            # slots a trip of pass 1 (16: 1.6% slower, PR 46; 8:
+#                        7% more, PR 37)
 FETCH = 16             # row copies a trip of pass 2, and rows a wait
-#                        (8: 5% slower on a cold block)
+#                        (8: 5% slower on a cold block, PR 37)
+TILE = SUBLANES * LANES  # slots a trip of pass 4: a tile of the values
 VMEM_SLACK = 8 << 20   # beside the resident range and the output blocks
 
 
@@ -69,9 +88,10 @@ def chunk_rows(block_rows: int, most: int = CHUNK_ROWS) -> int:
     return max(fits, default=0)
 
 
-def _als_gather_kernel(idx_ref, tab_ref, out_ref, res_ref, cold_ref,
-                       res_sem, row_sem, *, hot_row0: int):
-    slots = idx_ref.shape[0]
+def _als_gather_kernel(n_cold_ref, rel_ref, cold_ref, val_ref, tab_ref,
+                       out_ref, res_ref, res_sem, row_sem, *,
+                       hot_row0: int, lane: int):
+    slots = rel_ref.shape[0]
     last = res_ref.shape[0] - 1
 
     @pl.when(pl.program_id(0) == 0)
@@ -82,29 +102,26 @@ def _als_gather_kernel(idx_ref, tab_ref, out_ref, res_ref, cold_ref,
         cp.start()
         cp.wait()
 
-    def some(t, n_cold):
+    def some(t, carry):
         first = pl.multiple_of(t * UNROLL, UNROLL)
+        rows = out_ref.at[pl.ds(first, UNROLL), :]
         for u in range(UNROLL):
-            h = idx_ref[first + u] - hot_row0
-            cold_ref[n_cold] = first + u
-            n_cold = n_cold - (h >> 31)       # one more where h < 0
             # a cold slot reads the range's last row: as unsigned it
             # lies past every row of it
-            row = jnp.minimum(h.astype(jnp.uint32), jnp.uint32(last))
-            out_ref[pl.ds(first + u, 1), :] = \
+            row = jnp.minimum(rel_ref[first + u].astype(jnp.uint32),
+                              jnp.uint32(last))
+            rows[pl.ds(u, 1), :] = \
                 res_ref[pl.ds(row.astype(jnp.int32), 1), :]
-        return n_cold
+        return carry
 
-    n_cold = jax.lax.fori_loop(0, slots // UNROLL, some, jnp.int32(0))
-    again = cold_ref[jnp.maximum(n_cold - 1, 0)]
-    for u in range(FETCH - 1):
-        cold_ref[n_cold + u] = again
-    trips = (n_cold + FETCH - 1) // FETCH
+    jax.lax.fori_loop(0, slots // UNROLL, some, 0)
+    trips = (n_cold_ref[pl.program_id(0)] + FETCH - 1) // FETCH
 
     def fetch(g, carry):
-        first = pl.multiple_of(g * FETCH, FETCH)
-        at = [cold_ref[first + u] for u in range(FETCH)]
-        rows = [idx_ref[a] for a in at]
+        first = pl.multiple_of(g * (FETCH // 2), FETCH // 2)
+        words = [cold_ref[first + u] for u in range(FETCH // 2)]
+        at = [a for w in words for a in (w & 0xFFFF, w >> 16)]
+        rows = [rel_ref[a] + hot_row0 for a in at]
         for a, h in zip(at, rows):
             pltpu.make_async_copy(
                 tab_ref.at[pl.ds(h, 1), :], out_ref.at[pl.ds(a, 1), :],
@@ -121,43 +138,70 @@ def _als_gather_kernel(idx_ref, tab_ref, out_ref, res_ref, cold_ref,
         return carry
 
     jax.lax.fori_loop(0, trips, land, 0)
+    at_lane = jax.lax.broadcasted_iota(jnp.int32, (TILE, LANES), 1) == lane
+
+    def values(q, carry):
+        vals = val_ref[pl.ds(pl.multiple_of(q * SUBLANES, SUBLANES),
+                             SUBLANES), :]
+        # (one store a tile as it is written, a masked store a group as
+        # it is compiled: a group is a vector of the tile's)
+        groups = [pltpu.roll(vals, (lane - m) % LANES, axis=1)
+                  if (lane - m) % LANES else vals for m in range(LANES)]
+        pltpu.store(out_ref.at[pl.ds(pl.multiple_of(q * TILE, TILE), TILE), :],
+                    jnp.concatenate(groups, axis=0), mask=at_lane)
+        return carry
+
+    jax.lax.fori_loop(0, slots // TILE, values, 0)
 
 
 # jitted so that a fit's two dozen call sites (a class each, a half
 # each) trace and lower the kernel once a table, not once a site: 7 s of
 # a run's set-up at the benchmark's shape
 @functools.partial(jax.jit,
-                   static_argnames=("hot_row0", "interpret", "chunk"))
-def gather_rows_resident(table, idx_b, hot_row0: int, *,
-                         interpret: bool = False,
-                         chunk: int | None = None):
-    """``table[idx_b.reshape(-1)]`` for ``table`` float32 ``(rows,
-    128)`` and ``idx_b`` int32 ``(block rows, 128)``, every index in
-    bounds, with the rows from ``hot_row0`` on read out of VMEM."""
+                   static_argnames=("hot_row0", "lane", "interpret"))
+def gather_rows_resident(table, rel_b, val_t, cold_b, n_cold,
+                         hot_row0: int, lane: int, *,
+                         interpret: bool = False):
+    """``table[rel_b.reshape(-1) + hot_row0]`` with lane ``lane`` of
+    every row the slot's value, for ``table`` float32 ``(rows, 128)``
+    and a block as ``als_sparse.gather_lists`` holds it: ``rel_b`` int32
+    ``(block rows, 128)`` the indices less ``hot_row0`` (every index in
+    bounds), ``val_t`` float32 the same shape (the values in tiles of
+    1024 slots, eight slots down the sublanes), ``cold_b`` int32
+    ``(slots / 2,)`` and ``n_cold`` int32 ``(chunks,)`` the chunks'
+    cold lists and counts. The rows from ``hot_row0`` on are read out
+    of VMEM."""
     n_rows, width = table.shape
-    if width != LANES or idx_b.ndim != 2 or idx_b.shape[1] != LANES:
-        raise ValueError(f"table {table.shape} and indices {idx_b.shape} "
+    if width != LANES or rel_b.ndim != 2 or rel_b.shape[1] != LANES:
+        raise ValueError(f"table {table.shape} and indices {rel_b.shape} "
                          f"are not rows of {LANES} lanes")
-    cr = chunk_rows(idx_b.shape[0]) if chunk is None else chunk
-    if cr < SUBLANES or idx_b.shape[0] % cr or not 0 <= hot_row0 < n_rows:
+    cr = chunk_rows(rel_b.shape[0])    # as the lists were made
+    if cr < SUBLANES or not 0 <= hot_row0 < n_rows:
         raise ValueError(f"no chunk of {cr} rows in a block of "
-                         f"{idx_b.shape[0]}, or no resident row from "
+                         f"{rel_b.shape[0]}, or no resident row from "
                          f"{hot_row0} of {n_rows}")
     n_res = n_rows - hot_row0
     slots = cr * LANES
+
+    def smem(n):
+        return pl.BlockSpec((n,), lambda c, n_cold: (c,),
+                            memory_space=pltpu.SMEM)
+
     return pl.pallas_call(
-        functools.partial(_als_gather_kernel, hot_row0=hot_row0),
+        functools.partial(_als_gather_kernel, hot_row0=hot_row0, lane=lane),
         name="_als_gather_kernel",
-        grid=(idx_b.shape[0] // cr,),
-        in_specs=[pl.BlockSpec((slots,), lambda c: (c,),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((slots, LANES), lambda c: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((idx_b.size, LANES), table.dtype),
-        scratch_shapes=[pltpu.VMEM((n_res, LANES), table.dtype),
-                        pltpu.SMEM((slots + FETCH,), jnp.int32),
-                        pltpu.SemaphoreType.DMA(()),
-                        pltpu.SemaphoreType.DMA(())],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rel_b.shape[0] // cr,),
+            in_specs=[smem(slots), smem(slots // 2),
+                      pl.BlockSpec((cr, LANES), lambda c, n_cold: (c, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((slots, LANES),
+                                   lambda c, n_cold: (c, 0)),
+            scratch_shapes=[pltpu.VMEM((n_res, LANES), table.dtype),
+                            pltpu.SemaphoreType.DMA(()),
+                            pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct((rel_b.size, LANES), table.dtype),
         compiler_params=pltpu.CompilerParams(
             # the resident range lives across the whole grid
             dimension_semantics=("arbitrary",),
@@ -165,7 +209,7 @@ def gather_rows_resident(table, idx_b, hot_row0: int, *,
             # every index is in bounds, as XLA's form is promised
             disable_bounds_checks=True),
         interpret=interpret,
-    )(idx_b.reshape(-1), table)
+    )(n_cold, rel_b.reshape(-1), cold_b, val_t, table)
 
 
 # ------------------------------------------------------------ the solve

@@ -255,6 +255,15 @@ def summarize(evts: list[dict]) -> dict:
                     # how often the resident range engages
                     gather += (f" with {e['gather_resident_share']} of "
                                f"the slots resident")
+                if e.get("gather_cold_list") == "loader":
+                    # what the kernel is handed ready made
+                    # (models/als._gather_fields)
+                    gather += (
+                        f", {e.get('gather_cold_share', '?')} cold and "
+                        f"listed by the loader ("
+                        f"{sum(e.get('gather_cold_slots', []))} slots, "
+                        f"{e.get('gather_list_bytes', 0)} B), lanes by "
+                        f"the {e.get('gather_lanes', '?')}")
                 solve = e.get("als_solve_form", "?")
                 if e.get("solve_tile_systems"):
                     # the systems a tile holds in VMEM from the Gramian
