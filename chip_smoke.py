@@ -858,6 +858,65 @@ def stage_ssgd_hashed(s: Smoke):
             f"log-loss {loss:.4f} acc {acc:.4f}")
 
 
+def stage_ssgd_indexed(s: Smoke):
+    """SSGD over indexed rows as ``tda ssgd --indexed-rows`` runs it,
+    on every chip the stage has: the loader's table at a small size
+    with the benchmark's eleven fields in their order and a field of
+    every form at the real VMEM bound (three by value, six by address
+    in three groups, two id fields of 5M and 4.5M values whose ranges
+    stay in HBM: 18.0M weights), 12 steps of the block-sampled trainer
+    with every Mosaic pass compiled, the two ranges in HBM gathered a
+    row a DMA and scattered by XLA; against the same steps with every field in XLA's
+    form over the whole table, which no chip had run past 2**22 slots
+    before this stage, to float32 rounding; and held-out rows scored
+    better than zero weights score them."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_distalg.models import ssgd
+    from tpu_distalg.ops import pallas_hashed
+
+    mesh = s.mesh()
+    cards = (24323, 594098, 13745, 3, 3, 5_000_000, 1157062, 3750862,
+             2936510, 4_500_000, 21)
+    cfg = ssgd.SSGDConfig(
+        n_iterations=12, eval_test=False, sampler="fused_gather",
+        gather_block_rows=8192, mini_batch_fraction=0.25)
+    fn, X, w0, meta = ssgd.prepare_hashed_synthetic(
+        400_000, len(cards), 0, mesh, cfg, data_seed=5,
+        cardinalities=cards, row_format="indexed")
+    geom = ssgd.hashed_geometry(cfg, meta)
+    plan = ssgd.hashed_field_plan(cfg, meta)
+    forms = (geom.pass_form, plan.dict_fields,
+             tuple(g.fields for g in plan.addr_groups), plan.hbm_fields)
+    want = ("fields", (3, 4, 10), ((0, 1, 2, 6), (7,), (8,)), (5, 9))
+    if forms != want:
+        raise AssertionError(f"forms {forms}, not {want}")
+    s.check_sharded("X", X)
+    d = jnp.zeros((1,), jnp.float32)
+    w, _ = fn(X, d, d, d, d, w0)
+    real = pallas_hashed.pass_form
+    pallas_hashed.pass_form = lambda *a: "xla"
+    try:
+        w_xla, _ = ssgd.make_train_fn_fused(mesh, cfg, meta)(
+            X, d, d, d, d, w0)
+    finally:
+        pallas_hashed.pass_form = real
+    w, w_xla = np.asarray(w, np.float64), np.asarray(w_xla, np.float64)
+    err = float(np.linalg.norm(w - w_xla) / np.linalg.norm(w_xla))
+    if not err < 1e-5:
+        raise AssertionError(f"the fields' forms differ from XLA's over "
+                             f"the whole table by {err:.3g}")
+    acc, loss = ssgd.evaluate_hashed(w, meta, data_seed=5)
+    if not loss < 0.68:
+        raise AssertionError(f"held-out log-loss {loss:.4f} after 12 "
+                             f"steps (zero weights: 0.6931)")
+    return (f"dp={mesh.shape['data']} | table {tuple(X.shape)} | "
+            f"{geom.n_slots} weights | by value {forms[1]}, by address "
+            f"{forms[2]}, in HBM {forms[3]} | fields against xla passes "
+            f"{err:.2g} | held-out log-loss {loss:.4f} acc {acc:.4f}")
+
+
 def stage_als_sparse(s: Smoke):
     """ALS on a ratings list as ``tda als --ratings`` runs it, on every
     chip the stage has: the seeded loader (2 000 000 ratings of 20 000
@@ -975,6 +1034,13 @@ STAGES = (
                    "pallas_hashed._hashed_rows_kernel",
                    "pallas_hashed._hashed_value_gather_kernel",
                    "pallas_hashed._hashed_value_sums_kernel"))),
+    ("ssgd_indexed", stage_ssgd_indexed,
+     dict(kernels=("pallas_hashed._hashed_gather_kernel",
+                   "pallas_hashed._hashed_scatter_kernel",
+                   "pallas_hashed._hashed_rows_kernel",
+                   "pallas_hashed._hashed_value_gather_kernel",
+                   "pallas_hashed._hashed_value_sums_kernel",
+                   "pallas_hashed._hashed_hbm_gather_kernel"))),
     # one chip builds pallas_als._als_gather_kernel, a mesh no kernel
     ("als_sparse", stage_als_sparse, {}),
     ("ssgd_comm_int8", functools.partial(_comm_stage, comm="int8"),

@@ -5,7 +5,8 @@ show (the 64 MB output table in VMEM, a kernel call's scalars in SMEM's
 is not a chip run. The topology is described inside a fixture, in this
 one file (only one process may hold the TPU's library), so sparse ALS'
 solve kernel is compiled here too: a tile of 128 systems at rank 100
-from a batch of 6144, and the widest rank ``solve_plan`` admits."""
+from a batch of 6144, and the widest rank ``solve_plan`` admits; and
+indexed LR's gather from a weight table in HBM at KDD Cup 2012's shape."""
 
 import jax
 import jax.numpy as jnp
@@ -108,3 +109,27 @@ def test_als_solve_kernel_compiles_for_the_chip(one_chip, k, batch):
     assert "_als_solve_kernel" in done.as_text()
     assert done.memory_analysis().output_size_in_bytes \
         == geom.solve_n * batch * 4
+
+
+def test_hbm_gather_kernel_compiles_at_kdd12s_shape(one_chip):
+    """Indexed LR's gather of the two fields past VMEM at the
+    benchmark's shape (183 sampled blocks of 8192 rows, the query and
+    user ids' columns of an ``int32[18267, 16, 8192]`` table, the model
+    vector of 54 686 452 weights read in HBM as 427 238 rows of 128
+    lanes): the chip's compiler takes the table in ``ANY`` memory, the
+    landing rows in VMEM and a DMA a pair."""
+    from tpu_distalg.ops import pallas_hashed as ph
+
+    cards = (24323, 594098, 13745, 3, 3, 24296581, 1157062, 3750862,
+             2936510, 21913244, 21)
+    geom = ph.HashedGeometry(11, 0, 8192, field_sizes=cards)
+    assert geom.w_len == 427238 * 128
+    X = jax.ShapeDtypeStruct((18267, 16, 8192), jnp.int32,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((geom.w_len,), jnp.float32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((183,), jnp.int32, sharding=one_chip)
+    done = jax.jit(lambda X, w, ids: ph.margins_hbm(
+        X, w, ids, geom, (5, 9))).lower(X, w, ids).compile()
+    assert "_hashed_hbm_gather_kernel" in done.as_text()
+    # (the margins' share, 183 rows of 8192 padded to a tile of 8)
+    assert done.memory_analysis().output_size_in_bytes == 184 * 8192 * 4

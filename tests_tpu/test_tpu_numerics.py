@@ -1186,6 +1186,60 @@ def test_hashed_passes_at_the_published_widths(tpu_mesh):
         np.testing.assert_array_equal(n1[:geom.n_slots], counts)
 
 
+def test_indexed_passes_against_the_plain_reference(tpu_mesh):
+    """The compiled steps of SSGD over indexed rows (eleven fields in
+    the benchmark's order, 18.0M weights: three fields by value, six by
+    address in three groups, two ranges of 5M and 4.5M slots in HBM
+    under XLA's gather and scatter-add) against the benchmark's plain
+    reference (``benchmarks/reference/ssgd_indexed_ref.py``: no table,
+    the rows regenerated, one flat ``w[idx]`` and ``.at[idx].add``) over
+    two calls of three steps: every weight and the bias to float32
+    rounding, where the reference in bfloat16 stands three orders off."""
+    import os
+    import sys
+
+    from tpu_distalg.parallel import get_mesh
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import ssgd_indexed_ref as ref_mod
+
+    cards = (24323, 594098, 13745, 3, 3, 5_000_000, 1157062, 3750862,
+             2936510, 4_500_000, 21)
+    c = dict(n_rows=400_000, nnz=11, n_features=sum(cards),
+             gather_block_rows=8192, eta=0.1, field_cardinalities=cards,
+             zipf_exponent=1.1, planted_scale=0.25, click_rate=0.0349)
+    mesh = get_mesh(data=1, devices=jax.devices()[:1])
+    cfg = ssgd.SSGDConfig(
+        n_iterations=3, eta=0.1, lam=0.0, mini_batch_fraction=0.25,
+        seed=42, eval_test=False, sampler="fused_gather",
+        gather_block_rows=8192)
+    fn, X, w, meta = ssgd.prepare_hashed_synthetic(
+        c["n_rows"], 11, 0, mesh, cfg, data_seed=17, cardinalities=cards,
+        row_format="indexed", zipf_exponent=1.1, planted_scale=0.25,
+        click_rate=0.0349)
+    plan = ssgd.hashed_field_plan(cfg, meta)
+    assert plan.hbm_fields == (5, 9) and len(plan.addr_groups) == 3
+    d = jnp.zeros((1,), jnp.float32)
+    got = []
+    for call in range(2):
+        w, _ = fn(X, d, d, d, d, w, t0=3 * call)
+        got.append(ref_mod.model_vector(w, c["n_features"]))
+    ref = ref_mod.Reference(config=c, fraction=0.25, data_seed=17,
+                            sample_seed=42)
+    w0 = np.zeros((c["n_features"] + 1,), np.float32)
+    good = ref.follow(2, 3)
+    low = ref.follow(2, 3, dtype=jnp.bfloat16)
+    for k in range(2):
+        err = ref_mod.rel_err(got[k], good[k], w0)
+        ctl = ref_mod.rel_err(low[k], good[k], w0)
+        print(f"[indexed] call {k + 1}: program against reference "
+              f"{err:.3g}, bfloat16 control {ctl:.3g}")
+        assert err < 6e-5 < ctl
+
+
 def test_sparse_als_half_sweep_at_rank_100(tpu_mesh):
     """A sparse ALS half-sweep compiled at the benchmark's rank and
     widths (rank 100 in 128 lanes, segments of 32 slots, the eleven

@@ -235,7 +235,8 @@ def _report_optimizer(name, res, args, t):
     from tpu_distalg.utils import metrics
 
     if hasattr(res, "heldout_log_loss"):
-        # a table of 2**hash_bits weights is not printed
+        # a weight table (hashed or indexed) is not printed
+        print(res.forms)
         print(f"Held-out accuracy: {res.heldout_acc:.6f}  log-loss: "
               f"{res.heldout_log_loss:.6f}")
     else:
@@ -307,10 +308,26 @@ def main(argv=None):
                         "(--sampler is taken as fused_gather), held-out "
                         "accuracy and log-loss printed at the end")
     p.add_argument("--fields", type=int, default=39,
-                   help="fields a row for --hashed-rows (39: Criteo's "
-                        "13 integer + 26 categorical)")
+                   help="fields a row for --hashed-rows / "
+                        "--indexed-rows (39: Criteo's 13 integer + 26 "
+                        "categorical)")
     p.add_argument("--hash-bits", type=int, default=20,
                    help="log2 of the weight table for --hashed-rows")
+    p.add_argument("--indexed-rows", type=int, default=0, metavar="N",
+                   help="as --hashed-rows, but nothing is hashed: the "
+                        "fields' ranges lie end to end in the weight "
+                        "table and every value of every field is its "
+                        "own weight (a LIBSVM one-hot file's indices; "
+                        "Criteo's 39 fields are 33.8M weights, KDD Cup "
+                        "2012's eleven 54.7M). A table wider than VMEM "
+                        "stays in HBM; each field takes the form its "
+                        "size gives it (by value, by address in VMEM, "
+                        "in HBM: ops/pallas_hashed.field_form), which "
+                        "tda report prints; no option chooses a form")
+    p.add_argument("--field-values", default=None, metavar="N,N,...",
+                   help="distinct values of each field for "
+                        "--indexed-rows (their count is --fields); "
+                        "default: the click log's cardinalities")
 
     for name in ("ma", "bmuf", "easgd"):
         p = sub.add_parser(name)
@@ -1241,7 +1258,8 @@ def _dispatch(args, jax):
     if args.cmd in ("lr", "ssgd", "ma", "bmuf", "easgd"):
         from tpu_distalg.utils import datasets
 
-        hashed = args.cmd == "ssgd" and args.hashed_rows > 0
+        hashed = args.cmd == "ssgd" and (args.hashed_rows > 0
+                                         or args.indexed_rows > 0)
         data = None if hashed else datasets.breast_cancer_split()
         mesh = _mesh(args)
         t0 = time.perf_counter()
@@ -1250,8 +1268,21 @@ def _dispatch(args, jax):
 
             if args.stream_cache is not None:
                 raise SystemExit(
-                    "--hashed-rows builds its table on the device; "
-                    "--stream-cache streams packed columns from disk")
+                    "--hashed-rows / --indexed-rows build their table on "
+                    "the device; --stream-cache streams packed columns "
+                    "from disk")
+            if args.hashed_rows > 0 and args.indexed_rows > 0:
+                raise SystemExit(
+                    "--hashed-rows and --indexed-rows name two tables: "
+                    "give one")
+            indexed = args.indexed_rows > 0
+            cards = None
+            if args.field_values is not None:
+                if not indexed:
+                    raise SystemExit(
+                        "--field-values sizes the fields' ranges of "
+                        "--indexed-rows")
+                cards = tuple(int(v) for v in args.field_values.split(","))
             # the options that describe the data pick the trainer; the
             # one sampler that takes rows of indices is the default's
             # stand-in, any other named one is refused by the builder
@@ -1265,6 +1296,13 @@ def _dispatch(args, jax):
                 comm=args.comm, sync=args.sync, eval_test=False)
 
             def run_once():
+                if indexed:
+                    return m.train_hashed(
+                        args.indexed_rows,
+                        len(cards) if cards else args.fields, 0, mesh,
+                        cfg, cardinalities=cards, row_format="indexed",
+                        checkpoint_dir=args.checkpoint_dir,
+                        checkpoint_every=args.checkpoint_every)
                 return m.train_hashed(
                     args.hashed_rows, args.fields, args.hash_bits, mesh,
                     cfg, checkpoint_dir=args.checkpoint_dir,

@@ -998,7 +998,7 @@ def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
     from tpu_distalg.ops import pallas_kernels
     from tpu_distalg.parallel import DATA_AXIS
 
-    if meta.get("row_format", "packed") == "hashed":
+    if meta.get("row_format", "packed") in INDEX_ROW_FORMATS:
         # the loader's meta decides: rows that are indices have their
         # own two passes and share everything round them
         return _make_train_fn_hashed(mesh, config, meta)
@@ -1893,21 +1893,29 @@ def _train_fused(
 
 # ---- rows that are indices and not columns ------------------------------
 #
-# The second row format (``meta["row_format"] == "hashed"``): a row is
-# ``nnz`` int32 slots of a table of ``2 ** hash_bits`` float32 weights and
-# a label (``ops/pallas_hashed.py`` documents the block layout and the two
-# passes). Everything round the passes is the packed rows': the block grid
-# of ``fused_gather_geometry``, the draw, ``_build_scan``'s step, the psum.
+# The second row format: a row is ``nnz`` int32 slots of a table of float32
+# weights and a label (``ops/pallas_hashed.py`` documents the block layout
+# and the two passes). ``meta["row_format"]`` says which table: ``hashed``,
+# ``2 ** hash_bits`` slots that every field's values are mixed into, or
+# ``indexed``, the fields' ranges end to end (``meta["cardinalities"]``),
+# every value its own weight. Everything round the passes is the packed
+# rows': the block grid of ``fused_gather_geometry``, the draw,
+# ``_build_scan``'s step, the psum.
+
+INDEX_ROW_FORMATS = ("hashed", "indexed")
+
 
 @dataclasses.dataclass
 class HashedResult:
-    """A hashed run: the model vector (table, bias, zeros), and what it
-    scores on rows the table does not hold."""
+    """A run over rows of indices, hashed or indexed: the model vector
+    (table, bias, zeros), and what it scores on rows the table does not
+    hold."""
 
     w: jax.Array
     accs: jax.Array
     heldout_acc: float
     heldout_log_loss: float
+    forms: str = ""       # :func:`describe_forms`: what ``tda ssgd`` prints
 
     @property
     def final_acc(self) -> float:
@@ -1919,14 +1927,19 @@ def hashed_geometry(config: SSGDConfig, meta: dict):
 
     return pallas_hashed.HashedGeometry(
         nnz=meta["nnz"], hash_bits=meta["hash_bits"],
-        block_rows=config.gather_block_rows)
+        block_rows=config.gather_block_rows,
+        field_sizes=tuple(meta["cardinalities"])
+        if meta["row_format"] == "indexed" else ())
 
 
-def _check_hashed_config(config: SSGDConfig) -> None:
-    """The one place that says which trainers take hashed rows: the
-    per-step block-sampled one ('fused_gather'), dense BSP. The others
-    read a row as columns."""
+def _check_hashed_config(config: SSGDConfig,
+                         row_format: str = "hashed") -> None:
+    """The one place that says which trainers take rows of indices
+    (``row_format`` hashed or indexed): the per-step block-sampled one
+    ('fused_gather'), dense BSP. The others read a row as columns."""
     from tpu_distalg.parallel import ssp as pssp
+
+    rows = f"{row_format} rows"
 
     why = {
         "bernoulli": "masks every row of a dense matrix each step",
@@ -1934,39 +1947,41 @@ def _check_hashed_config(config: SSGDConfig) -> None:
         "fused": "streams packed bfloat16 columns through the one-pass "
                  "kernel",
         "fused_train": "keeps a packed step's 40 weights in the "
-                       "megakernel's VMEM; a table of 2**hash_bits "
-                       "weights and a psum a step do not fit one launch",
+                       "megakernel's VMEM; a weight table (2**hash_bits "
+                       "slots hashed, a slot a feature indexed) and a "
+                       "psum a step do not fit one launch",
         "virtual": "regenerates packed columns on the device",
     }
     if config.sampler != "fused_gather":
         raise ValueError(
-            f"hashed rows: sampler={config.sampler!r} cannot take the "
+            f"{rows}: sampler={config.sampler!r} cannot take the "
             f"format ({why.get(config.sampler, 'unknown sampler')}); "
             f"use sampler='fused_gather'")
     if config.feature_sharded:
         raise ValueError(
-            "hashed rows: feature_sharded splits packed columns over "
-            "the model axis and cannot take the format; a table past "
-            "one chip is not built yet (ROADMAP R4m)")
+            f"{rows}: feature_sharded splits packed columns over "
+            f"the model axis and cannot take the format; a weight table "
+            f"sharded over chips is not built yet (ROADMAP R4m)")
     if config.comm != "dense":
         raise ValueError(
-            f"hashed rows: comm={config.comm!r} cannot take the format "
+            f"{rows}: comm={config.comm!r} cannot take the format "
             f"yet: the schedules of parallel/comms.py have not met a "
-            f"gradient of 2**hash_bits floats (ROADMAP R4m); use 'dense'")
+            f"gradient as long as a weight table (ROADMAP R4m); use "
+            f"'dense'")
     if pssp.SyncSpec.parse(config.sync).is_ssp:
         raise ValueError(
-            f"hashed rows: sync={config.sync!r} cannot take the format: "
+            f"{rows}: sync={config.sync!r} cannot take the format: "
             f"the guarantee is BSP (no slot updated from stale weights)")
     if config.use_pallas:
         raise ValueError(
-            "hashed rows: use_pallas names the dense one-pass kernel of "
-            "the 'bernoulli' sampler and cannot take the format")
+            f"{rows}: use_pallas names the dense one-pass kernel of "
+            f"the 'bernoulli' sampler and cannot take the format")
 
 
 def hashed_field_plan(config: SSGDConfig, meta: dict):
-    """Which fields of a hashed ``meta`` the passes read by value
+    """Which form each field of a hashed or indexed ``meta`` takes
     (``pallas_hashed.field_plan`` over the dictionaries its loader
-    states), or ``None``: every field by address."""
+    states), or ``None``: every field of a hashed table by address."""
     from tpu_distalg.ops import pallas_hashed
 
     return pallas_hashed.field_plan(hashed_geometry(config, meta),
@@ -1974,22 +1989,55 @@ def hashed_field_plan(config: SSGDConfig, meta: dict):
 
 
 def _hashed_fields(config: SSGDConfig, meta: dict) -> dict:
-    """What the spans of a hashed run say (``tda report`` prints it)."""
-    form = hashed_geometry(config, meta).pass_form
+    """What the spans of a hashed or indexed run say (``tda report``
+    prints it): the format, the table's bytes, the passes' form and how
+    many fields take each form (by value, by address in VMEM, in HBM;
+    an ``xla`` pass reads every field the same way and counts none)."""
+    geom = hashed_geometry(config, meta)
+    form = geom.pass_form
     plan = hashed_field_plan(config, meta)
     n_dict = len(plan.dict_fields) if plan else 0
-    return {"row_format": "hashed", "nnz": meta["nnz"],
-            "hash_bits": meta["hash_bits"], "gather_form": form,
+    n_hbm = len(plan.hbm_fields) if plan else 0
+    n_addr = 0 if form == "xla" else meta["nnz"] - n_dict - n_hbm
+    return {"row_format": meta["row_format"], "nnz": meta["nnz"],
+            "hash_bits": meta["hash_bits"],
+            "table_bytes": 4 * geom.n_slots, "gather_form": form,
             "scatter_form": form, "dict_fields": n_dict,
-            "addr_fields": meta["nnz"] - n_dict,
-            "dict_values": plan.n_values if plan else 0}
+            "addr_fields": meta["nnz"] - n_dict - n_hbm,
+            "dict_values": plan.n_values if plan else 0,
+            "fields_dict": n_dict, "fields_vmem": n_addr,
+            "fields_hbm": n_hbm}
+
+
+def describe_forms(config: SSGDConfig, meta: dict) -> str:
+    """One line that says which table the rows index and which form
+    each field's share of the two passes takes (``pallas_hashed.
+    pass_form`` / ``field_form``, from sizes alone)."""
+    from tpu_distalg.ops import pallas_hashed
+
+    geom = hashed_geometry(config, meta)
+    plan = hashed_field_plan(config, meta)
+    head = (f"row format {meta['row_format']}: {geom.n_slots} weights "
+            f"({4 * geom.n_slots / 1e6:.1f} MB), passes {geom.pass_form}")
+    if plan is None:
+        return head + (": every field by address" if geom.pass_form
+                       == "vmem" else ": every field through XLA")
+    if plan.addr_groups is None:
+        return head + (f": fields by value {list(plan.dict_fields)}, by "
+                       f"address {list(plan.addr_fields)}")
+    return head + (
+        f": fields by value {list(plan.dict_fields)}, by address in VMEM "
+        f"{[list(g.fields) for g in plan.addr_groups]} (a group a "
+        f"table of at most 2**{pallas_hashed.VMEM_BITS} slots), in HBM "
+        f"{list(plan.hbm_fields)}")
 
 
 def _make_train_fn_hashed(mesh: Mesh, config: SSGDConfig, meta: dict):
-    """:func:`make_train_fn_fused` for a hashed ``meta``: the same scan
-    (``fn(X, dummy, dummy, dummy, dummy, w0, t0=, acc0=)``), the same
-    draw, update and psum; only the local gradient is new. The carried
-    ``w`` is ``f32[2 ** hash_bits + 128]``: table, bias, zeros. The
+    """:func:`make_train_fn_fused` for a hashed or indexed ``meta``:
+    the same scan (``fn(X, dummy, dummy, dummy, dummy, w0, t0=,
+    acc0=)``), the same draw, update and psum; only the local gradient
+    is new. The carried ``w`` is ``f32[geom.w_len]``: table (``2 **
+    hash_bits`` slots, or a slot a feature), bias, zeros. The
     scan scores nothing (there is no dense test matrix to multiply):
     :func:`evaluate_hashed` scores held-out rows between segments."""
     from jax import lax
@@ -1997,7 +2045,7 @@ def _make_train_fn_hashed(mesh: Mesh, config: SSGDConfig, meta: dict):
     from tpu_distalg.ops import pallas_hashed
     from tpu_distalg.parallel import DATA_AXIS
 
-    _check_hashed_config(config)
+    _check_hashed_config(config, meta["row_format"])
     geom = hashed_geometry(config, meta)
     plan = hashed_field_plan(config, meta)
     n_shards = mesh.shape[DATA_AXIS]
@@ -2054,7 +2102,6 @@ def hashed_table_fn(mesh: Mesh, n_rows: int, n_padded: int, geom,
     from jax import lax
 
     from tpu_distalg.parallel import DATA_AXIS, partition
-    from tpu_distalg.utils import datasets as dsets
 
     n_shards = mesh.shape[DATA_AXIS]
     B, F, nnz = geom.block_rows, geom.fields_held, geom.nnz
@@ -2062,8 +2109,8 @@ def hashed_table_fn(mesh: Mesh, n_rows: int, n_padded: int, geom,
     n_blocks = n_local // B
     per = math.gcd(n_blocks, 16)                 # blocks a chunk
     chunk, n_chunks = B * per, n_blocks // per
-    make_rows = dsets.hashed_click_rows(cardinalities, geom.hash_bits,
-                                        **dict(rows_kw))
+    make_rows = _row_generator(geom.row_format, cardinalities,
+                            geom.hash_bits, rows_kw)
 
     def body(seed):
         s = lax.axis_index(DATA_AXIS)
@@ -2087,32 +2134,55 @@ def hashed_table_fn(mesh: Mesh, n_rows: int, n_padded: int, geom,
         out_shardings=partition.leaf_sharding("ssgd", "X", mesh))
 
 
+def _row_generator(row_format: str, cardinalities, hash_bits: int, rows_kw):
+    """The generator of either format's rows (``utils/datasets.py``)."""
+    from tpu_distalg.utils import datasets as dsets
+
+    if row_format == "indexed":
+        return dsets.indexed_click_rows(cardinalities, **dict(rows_kw))
+    return dsets.hashed_click_rows(cardinalities, hash_bits,
+                                   **dict(rows_kw))
+
+
 def build_hashed_table(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
                        config: SSGDConfig, *, data_seed: int = 0,
-                       cardinalities=None, **rows_kw):
-    """The loader of hashed rows: ``n_rows`` seeded click-log rows
-    (``datasets.hashed_click_rows``, which ``rows_kw`` reach) made ON
-    DEVICE, shard by shard, as ``int32[n_blocks, fields_held,
-    gather_block_rows]``, and the ``meta`` that states the format.
+                       cardinalities=None, row_format: str = "hashed",
+                       **rows_kw):
+    """The loader of rows of indices: ``n_rows`` seeded click-log rows
+    (``datasets.hashed_click_rows`` or, for ``row_format='indexed'``,
+    ``indexed_click_rows``, which ``rows_kw`` reach) made ON DEVICE,
+    shard by shard, as ``int32[n_blocks, fields_held,
+    gather_block_rows]``, and the ``meta`` that states the format: for
+    an indexed table (``hash_bits`` 0) the fields' ``cardinalities`` are
+    their ranges of the table, ``offsets`` where each starts, and every
+    field of at most 65 536 values states its range as its dictionary.
     Returns ``(X, meta)``."""
     from tpu_distalg.ops import pallas_hashed
     from tpu_distalg.parallel import DATA_AXIS
     from tpu_distalg.utils import datasets as dsets
 
+    if row_format not in INDEX_ROW_FORMATS:
+        raise ValueError(f"row_format {row_format!r}: one of "
+                         f"{INDEX_ROW_FORMATS}")
     cards = tuple(cardinalities
                   or dsets.click_field_cardinalities(nnz))
     if len(cards) != nnz:
         raise ValueError(f"{len(cards)} cardinalities for {nnz} fields")
+    indexed = row_format == "indexed"
     geom = pallas_hashed.HashedGeometry(
-        nnz=nnz, hash_bits=hash_bits, block_rows=config.gather_block_rows)
+        nnz=nnz, hash_bits=hash_bits, block_rows=config.gather_block_rows,
+        field_sizes=cards if indexed else ())
     mult = geom.block_rows * mesh.shape[DATA_AXIS]
     # pack 1: fused_gather_geometry's block grid counts rows
-    meta = dict(row_format="hashed", nnz=nnz, hash_bits=hash_bits,
+    meta = dict(row_format=row_format, nnz=nnz, hash_bits=hash_bits,
                 pack=1, n_rows=n_rows, n_padded=n_rows + (-n_rows) % mult,
-                d_total=geom.w_len, cardinalities=cards,
+                d_total=geom.w_len, n_slots=geom.n_slots, cardinalities=cards,
                 rows_kw=tuple(sorted(rows_kw.items())),
-                dictionaries=dsets.click_field_dictionaries(
-                    cards, hash_bits))
+                dictionaries=dsets.indexed_field_dictionaries(cards)
+                if indexed
+                else dsets.click_field_dictionaries(cards, hash_bits))
+    if indexed:
+        meta["offsets"] = geom.offsets
     with tevents.span("ssgd:prepare", rows=n_rows,
                       bytes=meta["n_padded"] * geom.row_bytes,
                       **_hashed_fields(config, meta)):
@@ -2127,14 +2197,14 @@ def build_hashed_table(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
 def prepare_hashed_synthetic(n_rows: int, nnz: int, hash_bits: int,
                              mesh: Mesh, config: SSGDConfig, *,
                              data_seed: int = 0, cardinalities=None,
-                             **rows_kw):
-    """:func:`prepare_fused_synthetic` for hashed rows: returns ``(fn,
-    X, w0, meta)``, the weights zero as the source's."""
+                             row_format: str = "hashed", **rows_kw):
+    """:func:`prepare_fused_synthetic` for rows of indices: returns
+    ``(fn, X, w0, meta)``, the weights zero as the source's."""
     from tpu_distalg.parallel import partition
 
     X, meta = build_hashed_table(
         n_rows, nnz, hash_bits, mesh, config, data_seed=data_seed,
-        cardinalities=cardinalities, **rows_kw)
+        cardinalities=cardinalities, row_format=row_format, **rows_kw)
     # placed as the trainer returns it: the first call and every later
     # one (a next segment's, a benchmark window's) are one program
     w0 = partition.put(jnp.zeros((meta["d_total"],), jnp.float32), "w",
@@ -2147,11 +2217,9 @@ def evaluate_hashed(w, meta: dict, *, data_seed: int = 0,
     """``(accuracy, log-loss)`` of the model vector ``w`` on ``n`` rows
     the table of ``meta`` does not hold (ids past its padded end),
     float32."""
-    from tpu_distalg.utils import datasets as dsets
-
-    make_rows = dsets.hashed_click_rows(
-        meta["cardinalities"], meta["hash_bits"], **dict(meta["rows_kw"]))
-    n_slots = 1 << meta["hash_bits"]
+    make_rows = _row_generator(meta["row_format"], meta["cardinalities"],
+                            meta["hash_bits"], meta["rows_kw"])
+    n_slots = meta["n_slots"]
 
     @jax.jit
     def score(w, seed):
@@ -2166,13 +2234,16 @@ def evaluate_hashed(w, meta: dict, *, data_seed: int = 0,
 
 def train_hashed(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
                  config: SSGDConfig, *, data_seed: int = 0,
+                 cardinalities=None, row_format: str = "hashed",
                  checkpoint_dir: str | None = None,
                  checkpoint_every: int = 500) -> HashedResult:
-    """End-to-end training on hashed rows (``tda ssgd --hashed-rows``):
-    the loader's table, the block-sampled BSP trainer, held-out rows
-    scored at the end; checkpointed and resumable like :func:`train`."""
+    """End-to-end training on rows of indices (``tda ssgd
+    --hashed-rows`` / ``--indexed-rows``): the loader's table, the
+    block-sampled BSP trainer, held-out rows scored at the end;
+    checkpointed and resumable like :func:`train`."""
     fn, X, w0, meta = prepare_hashed_synthetic(
-        n_rows, nnz, hash_bits, mesh, config, data_seed=data_seed)
+        n_rows, nnz, hash_bits, mesh, config, data_seed=data_seed,
+        cardinalities=cardinalities, row_format=row_format)
     dummy = jnp.zeros((1,), jnp.float32)
     fields = dict(_draw_fields(config, meta, mesh),
                   **_hashed_fields(config, meta))
@@ -2192,10 +2263,11 @@ def train_hashed(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
             run_seg=_acc_carrying_run_seg(X, dummy, dummy, dummy, dummy),
             state0=(w0, partition.put(jnp.float32(0), "acc0", "ssgd",
                                       mesh)),
-            tag=f"ssgd:hashed:{nnz}x{hash_bits}",
+            tag=f"ssgd:{row_format}:{nnz}x{hash_bits or meta['d_total']}",
             span_fields=fields,
         )
     with tevents.span("ssgd:heldout"):
         acc, loss = evaluate_hashed(w, meta, data_seed=data_seed)
     return HashedResult(w=jnp.asarray(w), accs=jnp.asarray(accs),
-                        heldout_acc=acc, heldout_log_loss=loss)
+                        heldout_acc=acc, heldout_log_loss=loss,
+                        forms=describe_forms(config, meta))

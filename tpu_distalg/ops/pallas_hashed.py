@@ -1,16 +1,23 @@
-"""The two passes of logistic regression over hashed rows.
+"""The two passes of logistic regression over rows of indices.
 
-A hashed row is ``nnz`` int32 slots of a weight table of ``2 **
-hash_bits`` float32 and a 0/1 label; every value is 1 (a hashed one-hot:
-click logs, ``models/ssgd.py``'s second row format). A block of
+A row is ``nnz`` int32 slots of a weight table of float32 and a 0/1
+label; every value is 1 (a one-hot: click logs, ``models/ssgd.py``'s
+second row format). The table is either *hashed*, ``2 ** hash_bits``
+slots that every field's values are mixed into, or *indexed*, the
+fields' ranges laid end to end so that every value of every field is
+its own slot (``HashedGeometry.field_sizes``; 54.7M slots at KDD Cup
+2012's shape, which no VMEM holds). A block of
 ``block_rows`` rows is held field-major, ``int32[fields_held,
 block_rows]``: field ``j`` of the block's rows is one dense run of lanes,
 the label is row ``nnz``, the rows up to ``fields_held`` (``nnz + 1``
 rounded up to a sublane tile) are zero. 160 B a row at 39 fields, 157
 of them needed.
 
-The model is one vector ``w`` of ``n_slots + 128`` float32: the table,
-the bias at ``[n_slots]``, zeros behind it. A step over the sampled
+The model is one vector ``w`` of float32: the table, the bias at
+``[n_slots]``, zeros behind it to whole rows of 128 lanes (``n_slots +
+128`` for a hashed table; an indexed table is as long as its fields,
+``n_slots`` no multiple of anything, and ``w_len`` the next multiple
+of 128 past the bias). A step over the sampled
 blocks ``ids`` is
 
   margins:    m_i = b + sum_j w[h_ij]            (two fields of a row in
@@ -35,17 +42,40 @@ and :func:`pass_form` picks one from the geometry alone:
           benchmark's shape a step's scatter took 100.1 ms with one
           accumulator, 59.9 with two, 39.6 with four; its gather 54.4).
 ``xla``   ``w[idx]`` and ``zeros.at[idx].add``: what XLA makes of them;
-          the only form where the table is past VMEM or a block is not
-          whole lanes.
+          the only form where a hashed table is past VMEM or a block is
+          not whole lanes.
+``fields`` an indexed table in whole-lane blocks: no form for the
+          table, one for each field, since a field's slots lie in its
+          own range and residency can be decided a range at a time.
 
-Inside the ``vmem`` form a field is read in one of two ways, and
-:func:`field_form` picks one from the size of the field's dictionary:
-every slot the field can hold, which the loader states in its ``meta``
-where a field takes few values (a click log's device type or weekday,
-not its user id). :func:`field_plan` makes the choice for a table.
+Inside the ``vmem`` and ``fields`` forms a field is read in one of
+three ways, and :func:`field_form` picks one from sizes alone: the
+field's dictionary (every slot the field can hold, which the loader
+states in its ``meta`` where a field takes few values: a click log's
+device type or weekday, not its user id) and, in an indexed table, the
+field's range. :func:`field_plan` makes the choice for a table.
 
 ``addr``  by address, as above: the loops of the two kernels run over
-          these fields only.
+          these fields only. In an indexed table the by-address fields
+          are taken in *groups* of at most ``2 ** VMEM_BITS`` slots
+          (:class:`AddrGroup`): a group's ranges are copied end to end
+          into one table that a call of the same two kernels keeps in
+          VMEM, each field's slots re-based by a constant.
+``hbm``   an indexed field whose range alone is past ``2 ** VMEM_BITS``
+          slots (a user id, a query id): its weights stay in HBM, under
+          the scope ``tda.ssgd.table_hbm`` inside the pass's own. The
+          gather is ``_hashed_hbm_gather_kernel``: the model vector as
+          rows of 128 lanes in HBM, a chunk's indices through SMEM, one
+          DMA a (row, field) pair of the table's row ``h >> 7`` into a
+          landing row in VMEM, a wait a trip of copies, then lane ``h &
+          127`` kept and a row's fields added up as the by-address
+          kernel does. No resident head: a pair costs the same whatever
+          the skew (on one v5e at KDD Cup 2012's shape 32.6 ms a step
+          for two fields of 1.5M rows where XLA's ``w[idx]`` takes 52.6,
+          the same float32 weights bit for bit: PR 47's Step 0). The
+          scatter is XLA's ``zeros.at[idx].add`` over those fields'
+          pairs (30.8 ms): a read-modify-write of HBM rows by DMA has no
+          order between two copies to one row.
 ``dict``  by value: ``_hashed_rows_kernel`` copies the field's rows of
           the sampled blocks from one sublane of a block's tiles to
           whole vectors of 1024 rows, and for every entry ``d`` of the
@@ -77,12 +107,15 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from tpu_distalg.telemetry import names
 
 LANES = 128
 SUBLANES = 8
@@ -94,6 +127,8 @@ ACC_VMEM_BYTES = 64 << 20   # ... and what they and their second buffers
 #                             may take of VMEM: 4 up to 2**21 slots, 2 at
 #                             2**22
 VMEM_BITS = 22         # a table of 16 MB and its accumulators fit VMEM
+HBM_TRIP = 16          # rows whose copies from a table in HBM a loop trip
+#                        starts, and a wait lands
 MIN_BITS = 10          # one (8, 128) tile of slots
 DICT_MAX_VALUES = 3800  # a field of so many slots or fewer is read by
 #                         value where its rows fill whole vectors (the
@@ -109,26 +144,77 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def pass_form(hash_bits: int, block_rows: int) -> str:
-    """``'vmem'`` where the table and the scatter's accumulators stay in
-    VMEM and a block's rows are whole lanes, else ``'xla'``."""
-    if MIN_BITS <= hash_bits <= VMEM_BITS and block_rows % LANES == 0:
-        return "vmem"
-    return "xla"
+def pass_form(hash_bits: int, block_rows: int,
+              field_sizes: tuple = ()) -> str:
+    """How a table's two passes run. Hashed (no ``field_sizes``):
+    ``'vmem'`` where the table and the scatter's accumulators stay in
+    VMEM and a block's rows are whole lanes, else ``'xla'``. Indexed:
+    ``'fields'`` (each field the form :func:`field_form` gives its
+    range) where a block's rows are whole lanes, else ``'xla'``."""
+    if block_rows % LANES:
+        return "xla"
+    if field_sizes:
+        return "fields"
+    return "vmem" if MIN_BITS <= hash_bits <= VMEM_BITS else "xla"
 
 
-def field_form(n_values: int, block_rows: int) -> str:
+def field_form(n_values: int, block_rows: int,
+               range_slots: int | None = None) -> str:
     """How one field of a block is read: ``'dict'`` (every row compared
-    with each of the ``n_values`` slots the field can hold) or ``'addr'``
-    (each row's slot chased by address). By value costs ``n_values``
+    with each of the ``n_values`` slots the field can hold: the length
+    of its stated dictionary, 0 where none is stated), ``'addr'`` (each
+    row's slot chased by address in a table in VMEM) or ``'hbm'`` (the
+    same in a table that stays in HBM). By value costs ``n_values``
     times the vectors a block's rows fill, by address the block's rows:
     they cross at ``DICT_MAX_VALUES`` values where the rows fill whole
     vectors, and lower in proportion where a block is shorter than a
-    vector."""
-    if n_values < 1 or block_rows % LANES:
-        return "addr"
-    fill = block_rows / _round_up(block_rows, VALUE_ROWS)
-    return "dict" if n_values <= DICT_MAX_VALUES * fill else "addr"
+    vector. ``range_slots`` is the range an indexed table gives the
+    field alone (a hashed table's fields share one table, whose
+    residency is :func:`pass_form`'s): past ``2 ** VMEM_BITS`` slots no
+    VMEM holds it with the scatter's accumulators."""
+    if block_rows % LANES == 0 and n_values >= 1:
+        fill = block_rows / _round_up(block_rows, VALUE_ROWS)
+        if n_values <= DICT_MAX_VALUES * fill:
+            return "dict"
+    if range_slots is not None and range_slots > 1 << VMEM_BITS:
+        return "hbm"
+    return "addr"
+
+
+@dataclasses.dataclass(frozen=True)
+class AddrGroup:
+    """The by-address fields of an indexed table that one call of the
+    two kernels serves: their ranges ``spans`` of the model vector,
+    copied end to end into one table of ``n_slots`` (whole ``(8, 128)``
+    tiles); field ``fields[k]``'s slot ``h`` lies at ``h - bases[k]``
+    there."""
+
+    fields: tuple
+    spans: tuple
+    bases: tuple
+    n_slots: int
+
+
+def addr_groups(fields, offsets) -> tuple:
+    """The by-address ``fields`` in order, a new group wherever the
+    next range would take the group's table past ``2 ** VMEM_BITS``."""
+    groups, cur, held = [], [], 0
+    for f in list(fields) + [None]:
+        size = 0 if f is None else offsets[f + 1] - offsets[f]
+        if cur and (f is None or held + size > 1 << VMEM_BITS):
+            at, bases = 0, []
+            for g in cur:
+                bases.append(offsets[g] - at)
+                at += offsets[g + 1] - offsets[g]
+            groups.append(AddrGroup(
+                fields=tuple(cur), bases=tuple(bases),
+                spans=tuple((offsets[g], offsets[g + 1]) for g in cur),
+                n_slots=_round_up(at, SUBLANES * LANES)))
+            cur, held = [], 0
+        if f is not None:
+            cur.append(f)
+            held += size
+    return tuple(groups)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -141,6 +227,10 @@ class FieldPlan:
     dict_fields: tuple
     entries: np.ndarray        # int32[n_groups * VALUE_GROUP]
     group_field: np.ndarray    # int32[n_groups]: index into dict_fields
+    # an indexed table's: the by-address fields' groups (``None``: one
+    # hashed table serves ``addr_fields``) and the fields left in HBM
+    addr_groups: tuple | None = None
+    hbm_fields: tuple = ()
 
     @property
     def n_values(self) -> int:
@@ -149,19 +239,32 @@ class FieldPlan:
 
 def field_plan(geom: "HashedGeometry", dictionaries) -> FieldPlan | None:
     """The plan for a table whose loader states ``dictionaries`` (for
-    each of the ``nnz`` fields every slot it can hold, or ``None``), or
-    ``None`` where every field is read by address: no dictionary
-    stated, none short enough, or the passes in their ``xla`` form."""
-    if dictionaries is None or geom.pass_form != "vmem":
+    each of the ``nnz`` fields every slot it can hold, or ``None``).
+    A hashed table: ``None`` where every field is read by address (no
+    dictionary stated, none short enough, or the passes in their
+    ``xla`` form). An indexed table in its ``fields`` form always has
+    a plan: every field's form from its range and its dictionary."""
+    form = geom.pass_form
+    if form == "xla" or (dictionaries is None and form == "vmem"):
         return None
+    if dictionaries is None:
+        dictionaries = (None,) * geom.nnz
     if len(dictionaries) != geom.nnz:
         raise ValueError(f"{len(dictionaries)} dictionaries for "
                          f"{geom.nnz} fields")
-    dict_fields = tuple(
-        f for f, d in enumerate(dictionaries) if d is not None
-        and field_form(len(d), geom.block_rows) == "dict")
-    if not dict_fields:
+    sizes = geom.field_sizes or (None,) * geom.nnz
+    forms = [field_form(0 if d is None else len(d), geom.block_rows, n)
+             for d, n in zip(dictionaries, sizes)]
+    dict_fields = tuple(f for f, v in enumerate(forms) if v == "dict")
+    addr_fields = tuple(f for f, v in enumerate(forms) if v == "addr")
+    if form == "fields":
+        more = dict(addr_groups=addr_groups(addr_fields, geom.offsets),
+                    hbm_fields=tuple(f for f, v in enumerate(forms)
+                                     if v == "hbm"))
+    elif not dict_fields:
         return None
+    else:
+        more = {}
     entries, group_field = [], []
     for n, f in enumerate(dict_fields):
         d = np.unique(np.asarray(dictionaries[f], np.int32))
@@ -172,32 +275,55 @@ def field_plan(geom: "HashedGeometry", dictionaries) -> FieldPlan | None:
         entries[-1][:len(d)] = d
         group_field += [n] * groups
     return FieldPlan(
-        addr_fields=tuple(f for f in range(geom.nnz)
-                          if f not in dict_fields),
-        dict_fields=dict_fields, entries=np.concatenate(entries),
-        group_field=np.asarray(group_field, np.int32))
+        addr_fields=addr_fields, dict_fields=dict_fields,
+        entries=np.concatenate(entries + [np.zeros((0,), np.int32)]),
+        group_field=np.asarray(group_field, np.int32), **more)
 
 
 @dataclasses.dataclass(frozen=True)
 class HashedGeometry:
     nnz: int               # fields a row
-    hash_bits: int
+    hash_bits: int         # 0: an indexed table
     block_rows: int
+    field_sizes: tuple = ()   # indexed: the values of each field, whose
+    #                           ranges lie end to end in the table
 
     def __post_init__(self):
-        if self.nnz < 1 or not 1 <= self.hash_bits <= 30:
+        if self.field_sizes:
+            if self.hash_bits or len(self.field_sizes) != self.nnz \
+                    or min(self.field_sizes) < 1 \
+                    or sum(self.field_sizes) >= 1 << 31:
+                raise ValueError(
+                    f"indexed rows: {len(self.field_sizes)} field sizes "
+                    f"for nnz {self.nnz} (each >= 1, {sum(self.field_sizes)}"
+                    f" slots in all must fit int32) and no hash_bits "
+                    f"({self.hash_bits})")
+        elif self.nnz < 1 or not 1 <= self.hash_bits <= 30:
             raise ValueError(
                 f"hashed rows: nnz {self.nnz} must be >= 1 and hash_bits "
                 f"{self.hash_bits} in [1, 30] (int32 slots)")
 
     @property
+    def row_format(self) -> str:
+        return "indexed" if self.field_sizes else "hashed"
+
+    @property
+    def offsets(self) -> tuple:
+        """Indexed: where each field's range starts, and the table's
+        end."""
+        return tuple(itertools.accumulate(self.field_sizes, initial=0))
+
+    @property
     def n_slots(self) -> int:
-        return 1 << self.hash_bits
+        return sum(self.field_sizes) if self.field_sizes \
+            else 1 << self.hash_bits
 
     @property
     def w_len(self) -> int:
-        """The model vector: the table, the bias, zeros to a lane row."""
-        return self.n_slots + LANES
+        """The model vector: the table, the bias, zeros; whole rows of
+        128 lanes, so that a table in HBM is the vector itself."""
+        return _round_up(self.n_slots + 1, LANES) if self.field_sizes \
+            else self.n_slots + LANES
 
     @property
     def fields_held(self) -> int:
@@ -209,7 +335,8 @@ class HashedGeometry:
 
     @property
     def pass_form(self) -> str:
-        return pass_form(self.hash_bits, self.block_rows)
+        return pass_form(self.hash_bits, self.block_rows,
+                         self.field_sizes)
 
     @property
     def chunk_rows(self) -> int:
@@ -219,8 +346,11 @@ class HashedGeometry:
     def scatter_accs(self) -> int:
         """Accumulators of the scatter: ``SCATTER_ACCS``, fewer where
         so many tables and their second buffers would not fit."""
-        return max(1, min(SCATTER_ACCS,
-                          ACC_VMEM_BYTES // (2 * 4 * self.n_slots)))
+        return _scatter_accs(self.n_slots)
+
+
+def _scatter_accs(n_slots: int) -> int:
+    return max(1, min(SCATTER_ACCS, ACC_VMEM_BYTES // (2 * 4 * n_slots)))
 
 
 def _check(X, geom: HashedGeometry):
@@ -247,19 +377,128 @@ def slot_sums_xla(X, r, ids, geom: HashedGeometry):
     idx = X[ids][:, :geom.nnz, :]
     g = jnp.zeros((geom.n_slots,), jnp.float32).at[idx].add(
         jnp.broadcast_to(r[:, None, :], idx.shape))
-    tail = jnp.zeros((LANES,), jnp.float32).at[0].set(jnp.sum(r))
+    tail = jnp.zeros((geom.w_len - geom.n_slots,), jnp.float32).at[0].set(
+        jnp.sum(r))
     return jnp.concatenate([g, tail])
+
+
+def _field_slots(X, ids, fields):
+    """``int32[n_sampled, len(fields), block_rows]``: static slices of
+    the sampled blocks (a gather over (block, field) pairs is the form
+    XLA once placed in VMEM and halted the core with: PR 33)."""
+    blocks = X[ids]
+    return jnp.stack([blocks[:, f, :] for f in fields], axis=1)
+
+
+def margins_hbm_xla(X, w, ids, fields):
+    """:func:`margins_hbm` as XLA's gather over the model vector (Step
+    0's yardstick and the tests'; the ``xla`` pass form gathers every
+    field so)."""
+    with jax.named_scope(names.SSGD_TABLE_HBM):
+        return jnp.sum(w[_field_slots(X, ids, fields)], axis=1)
+
+
+def _hashed_hbm_gather_kernel(ids_ref, idx_ref, tab_ref, out_ref, land_ref,
+                              sem, *, fields: tuple, trip: int, rows: int):
+    """One chunk of one sampled block with the table in HBM: pass 1
+    starts a DMA a (row, field) pair, the table's row ``h >> 7`` into
+    landing row ``n * chunk + i``; pass 2 waits a trip's copies at a
+    time (the semaphore counts what has arrived); pass 3 is the
+    by-address gather's: lane ``h & 127`` of each landed row kept, a
+    row's fields added up."""
+    del ids_ref                         # the index maps read it
+    cr = idx_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def fetch(t, carry):
+        first = pl.multiple_of(t * trip, trip)
+        for u in range(trip):
+            for n, j in enumerate(fields):
+                h = idx_ref[j, first + u]
+                pltpu.make_async_copy(
+                    tab_ref.at[pl.ds(h >> 7, 1), :],
+                    land_ref.at[pl.ds(n * cr + first + u, 1), :],
+                    sem).start()
+        return carry
+
+    jax.lax.fori_loop(0, cr // trip, fetch, 0)
+
+    def land(t, carry):
+        pltpu.make_async_copy(
+            tab_ref.at[pl.ds(0, trip * len(fields)), :],
+            land_ref.at[pl.ds(0, trip * len(fields)), :], sem).wait()
+        return carry
+
+    jax.lax.fori_loop(0, cr // trip, land, 0)
+
+    def some(t, carry):
+        first = pl.multiple_of(t * rows, rows)
+        for u in range(rows):
+            acc = None
+            for n, j in enumerate(fields):
+                h = idx_ref[j, first + u]
+                got = jnp.where(
+                    lane == (h & (LANES - 1)),
+                    land_ref[pl.ds(n * cr + first + u, 1), :], 0.0)
+                acc = got if acc is None else acc + got
+            out_ref[pl.ds(first + u, 1), :] = acc
+        return carry
+
+    jax.lax.fori_loop(0, cr // rows, some, 0)
+
+
+def margins_hbm(X, w, ids, geom: HashedGeometry, fields, *,
+                interpret: bool = False):
+    """The share of the margins of an indexed table's ``fields`` whose
+    ranges stay in HBM (no bias): the model vector read where it lies,
+    as rows of 128 lanes, a row a DMA."""
+    cr = geom.chunk_rows
+    trip = HBM_TRIP if cr % HBM_TRIP == 0 else 1
+    kernel = functools.partial(_hashed_hbm_gather_kernel,
+                               fields=tuple(fields), trip=trip,
+                               rows=_loop_rows(geom, None))
+    with jax.named_scope(names.SSGD_TABLE_HBM):
+        parts = pl.pallas_call(
+            kernel,
+            name="_hashed_hbm_gather_kernel",
+            grid_spec=_grid_spec(
+                ids, geom, [pl.BlockSpec(memory_space=pl.ANY)],
+                pl.BlockSpec((None, cr, LANES),
+                             lambda s, c, ids: (s, c, 0)),
+                scratch_shapes=[
+                    pltpu.VMEM((len(fields) * cr, LANES), jnp.float32),
+                    pltpu.SemaphoreType.DMA(())]),
+            out_shape=jax.ShapeDtypeStruct(
+                (ids.shape[0], geom.block_rows, LANES), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                # every slot is a row of the table, as XLA's form is
+                # promised
+                disable_bounds_checks=True),
+            interpret=interpret,
+        )(ids, X, w.reshape(geom.w_len // LANES, LANES))
+        return jnp.sum(parts, axis=-1)
+
+
+def slot_sums_hbm(X, r, ids, geom: HashedGeometry, fields):
+    """``f32[w_len]`` with those fields' per-slot sums: XLA's
+    scatter-add into a zeroed model vector."""
+    with jax.named_scope(names.SSGD_TABLE_HBM):
+        idx = _field_slots(X, ids, fields)
+        return jnp.zeros((geom.w_len,), jnp.float32).at[idx].add(
+            jnp.broadcast_to(r[:, None, :], idx.shape))
 
 
 # ---- Mosaic forms ------------------------------------------------------
 
 def _hashed_gather_kernel(ids_ref, idx_ref, w_ref, out_ref, *,
-                          fields: tuple, rows: int):
+                          fields: tuple, bases: tuple, rows: int):
     """One chunk of one sampled block: ``out[i, :]`` holds the weights
     of row ``i``'s ``fields`` in the lanes their slots have in the
     table, slots of one lane added up; the sum over lanes is their
     share of the margin. ``GATHER_SUMS`` partial vectors keep the adds
-    of one row off one chain."""
+    of one row off one chain. ``bases`` re-base each field's slots on
+    the table handed in (zeros: the table is the whole of it)."""
     del ids_ref                         # the index maps read it
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
@@ -268,7 +507,8 @@ def _hashed_gather_kernel(ids_ref, idx_ref, w_ref, out_ref, *,
         for u in range(rows):
             sums = [None] * min(GATHER_SUMS, len(fields))
             for n, j in enumerate(fields):
-                h = idx_ref[j, first + u]
+                h = idx_ref[j, first + u] - bases[n] if bases[n] \
+                    else idx_ref[j, first + u]
                 got = jnp.where(lane == (h & (LANES - 1)),
                                 w_ref[pl.ds(h >> 7, 1), :], 0.0)
                 k = n % len(sums)
@@ -283,7 +523,7 @@ def _hashed_gather_kernel(ids_ref, idx_ref, w_ref, out_ref, *,
 
 
 def _hashed_scatter_kernel(ids_ref, idx_ref, rb_ref, *accs,
-                           fields: tuple, rows: int):
+                           fields: tuple, bases: tuple, rows: int):
     """One chunk of one sampled block into the accumulators ``(n_slots /
     128, 128)``, which stay in VMEM over the whole grid: row ``i``'s
     residual (``rb[i, :]``, the same in every lane) is added at the
@@ -303,7 +543,8 @@ def _hashed_scatter_kernel(ids_ref, idx_ref, rb_ref, *accs,
         for u in range(rows):
             r = rb_ref[pl.ds(first + u, 1), :]
             for n, j in enumerate(fields):
-                h = idx_ref[j, first + u]
+                h = idx_ref[j, first + u] - bases[n] if bases[n] \
+                    else idx_ref[j, first + u]
                 acc = accs[(u * len(fields) + n) % len(accs)]
                 acc[pl.ds(h >> 7, 1), :] += jnp.where(
                     lane == (h & (LANES - 1)), r, 0.0)
@@ -312,7 +553,8 @@ def _hashed_scatter_kernel(ids_ref, idx_ref, rb_ref, *accs,
     jax.lax.fori_loop(0, idx_ref.shape[1] // rows, some, 0)
 
 
-def _grid_spec(ids, geom: HashedGeometry, more_in, out_specs):
+def _grid_spec(ids, geom: HashedGeometry, more_in, out_specs,
+               scratch_shapes=()):
     cr = geom.chunk_rows
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -320,7 +562,7 @@ def _grid_spec(ids, geom: HashedGeometry, more_in, out_specs):
         in_specs=[pl.BlockSpec((None, geom.fields_held, cr),
                                lambda s, c, ids: (ids[s], 0, c),
                                memory_space=pltpu.SMEM)] + more_in,
-        out_specs=out_specs)
+        out_specs=out_specs, scratch_shapes=scratch_shapes)
 
 
 def _loop_rows(geom: HashedGeometry, rows: int | None) -> int:
@@ -328,8 +570,8 @@ def _loop_rows(geom: HashedGeometry, rows: int | None) -> int:
     return rows if geom.chunk_rows % rows == 0 else 1
 
 
-def _vmem_limit(geom: HashedGeometry, tables: int) -> int:
-    return (tables * 4 * geom.n_slots
+def _vmem_limit(geom: HashedGeometry, tables: int, n_slots: int) -> int:
+    return (tables * 4 * n_slots
             + 8 * geom.chunk_rows * LANES * 4 + (8 << 20))
 
 
@@ -337,16 +579,36 @@ def _fields(geom: HashedGeometry, fields) -> tuple:
     return tuple(range(geom.nnz)) if fields is None else tuple(fields)
 
 
+def _served(geom: HashedGeometry, fields, group: AddrGroup | None):
+    """``(fields, bases, slots)`` of one call of a by-address kernel:
+    a hashed table's ``fields`` in the table itself, or an indexed
+    table's ``group`` in the group's."""
+    if group is not None:
+        return group.fields, group.bases, group.n_slots
+    fields = _fields(geom, fields)
+    return fields, (0,) * len(fields), geom.n_slots
+
+
+def _group_table(w, group: AddrGroup):
+    """The group's ranges of the model vector end to end, zeros to
+    whole tiles: a copy of at most 16 MB a step."""
+    at = sum(hi - lo for lo, hi in group.spans)
+    return jnp.concatenate(
+        [w[lo:hi] for lo, hi in group.spans]
+        + [jnp.zeros((group.n_slots - at,), w.dtype)])
+
+
 def margins_vmem(X, w, ids, geom: HashedGeometry, *,
                  interpret: bool = False, rows: int | None = None,
-                 fields=None):
+                 fields=None, group: AddrGroup | None = None):
     """The margins by address over ``fields`` (all of them unless
-    given), the bias added."""
+    given), the bias added; or a ``group``'s share of them, no bias."""
     cr = geom.chunk_rows
-    table = w[:geom.n_slots].reshape(geom.n_slots // LANES, LANES)
-    kernel = functools.partial(_hashed_gather_kernel,
-                               fields=_fields(geom, fields),
-                               rows=_loop_rows(geom, rows))
+    fields, bases, n_slots = _served(geom, fields, group)
+    table = (w[:n_slots] if group is None else _group_table(w, group)
+             ).reshape(n_slots // LANES, LANES)
+    kernel = functools.partial(_hashed_gather_kernel, fields=fields,
+                               bases=bases, rows=_loop_rows(geom, rows))
     parts = pl.pallas_call(
         kernel,
         name="_hashed_gather_kernel",
@@ -358,24 +620,27 @@ def margins_vmem(X, w, ids, geom: HashedGeometry, *,
             (ids.shape[0], geom.block_rows, LANES), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(geom, 2)),
+            vmem_limit_bytes=_vmem_limit(geom, 2, n_slots)),
         interpret=interpret,
     )(ids, X, table)
-    return jnp.sum(parts, axis=-1) + w[geom.n_slots]
+    m = jnp.sum(parts, axis=-1)
+    return m + w[geom.n_slots] if group is None else m
 
 
 def slot_sums_vmem(X, r, ids, geom: HashedGeometry, *,
                    interpret: bool = False, n_acc: int | None = None,
-                   rows: int | None = None, fields=None):
+                   rows: int | None = None, fields=None,
+                   group: AddrGroup | None = None):
     """The per-slot sums by address over ``fields`` (all of them unless
-    given), the residuals' sum where the bias is."""
+    given), the residuals' sum where the bias is; or ``f32[group.
+    n_slots]``, a ``group``'s sums in its own table's order."""
     cr = geom.chunk_rows
-    n_acc = geom.scatter_accs if n_acc is None else n_acc
-    shape = (geom.n_slots // LANES, LANES)
+    fields, bases, n_slots = _served(geom, fields, group)
+    n_acc = _scatter_accs(n_slots) if n_acc is None else n_acc
+    shape = (n_slots // LANES, LANES)
     rb = jnp.broadcast_to(r[:, :, None], r.shape + (LANES,))
-    kernel = functools.partial(_hashed_scatter_kernel,
-                               fields=_fields(geom, fields),
-                               rows=_loop_rows(geom, rows))
+    kernel = functools.partial(_hashed_scatter_kernel, fields=fields,
+                               bases=bases, rows=_loop_rows(geom, rows))
     accs = pl.pallas_call(
         kernel,
         name="_hashed_scatter_kernel",
@@ -388,10 +653,12 @@ def slot_sums_vmem(X, r, ids, geom: HashedGeometry, *,
         compiler_params=pltpu.CompilerParams(
             # the accumulators live across the whole grid
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(geom, 2 * n_acc)),
+            vmem_limit_bytes=_vmem_limit(geom, 2 * n_acc, n_slots)),
         interpret=interpret,
     )(ids, X, rb)
-    g = functools.reduce(jnp.add, accs).reshape(geom.n_slots)
+    g = functools.reduce(jnp.add, accs).reshape(n_slots)
+    if group is not None:
+        return g
     tail = jnp.zeros((LANES,), jnp.float32).at[0].set(jnp.sum(r))
     return jnp.concatenate([g, tail])
 
@@ -592,15 +859,31 @@ def margins(X, w, ids, geom: HashedGeometry, *,
     block_rows]``, padding rows included. ``plan`` (:func:`field_plan`)
     says which fields are read by value."""
     _check(X, geom)
-    if geom.pass_form != "vmem":
+    if geom.pass_form == "xla":
         return margins_xla(X, w, ids, geom)
     if plan is None:
         return margins_vmem(X, w, ids, geom, interpret=interpret)
+    if plan.addr_groups is not None:
+        return _margins_fields(X, w, ids, geom, plan, interpret)
     m = margins_dict(X, w, ids, geom, plan, interpret=interpret)
     if not plan.addr_fields:
         return m + w[geom.n_slots]
     return m + margins_vmem(X, w, ids, geom, interpret=interpret,
                             fields=plan.addr_fields)
+
+
+def _margins_fields(X, w, ids, geom, plan, interpret):
+    """An indexed table's margins: the bias and each form's share."""
+    m = w[geom.n_slots]
+    if plan.dict_fields:
+        m = m + margins_dict(X, w, ids, geom, plan, interpret=interpret)
+    for group in plan.addr_groups:
+        m = m + margins_vmem(X, w, ids, geom, interpret=interpret,
+                             group=group)
+    if plan.hbm_fields:
+        m = m + margins_hbm(X, w, ids, geom, plan.hbm_fields,
+                            interpret=interpret)
+    return jnp.broadcast_to(m, (ids.shape[0], geom.block_rows))
 
 
 def slot_sums(X, r, ids, geom: HashedGeometry, *,
@@ -609,10 +892,12 @@ def slot_sums(X, r, ids, geom: HashedGeometry, *,
     (row, field) occurrence's slot, once each; the sum of ``r`` where
     the bias is."""
     _check(X, geom)
-    if geom.pass_form != "vmem":
+    if geom.pass_form == "xla":
         return slot_sums_xla(X, r, ids, geom)
     if plan is None:
         return slot_sums_vmem(X, r, ids, geom, interpret=interpret)
+    if plan.addr_groups is not None:
+        return _slot_sums_fields(X, r, ids, geom, plan, interpret)
     slots, sums = slot_sums_dict(X, r, ids, geom, plan,
                                  interpret=interpret)
     if plan.addr_fields:
@@ -622,3 +907,24 @@ def slot_sums(X, r, ids, geom: HashedGeometry, *,
         g = jnp.zeros((geom.w_len,), jnp.float32).at[geom.n_slots].set(
             jnp.sum(r))
     return g.at[slots].add(sums)
+
+
+def _slot_sums_fields(X, r, ids, geom, plan, interpret):
+    """An indexed table's sums: the fields in HBM scattered into the
+    zeroed vector, each group's table added back range by range, the
+    by-value entries added at their slots, the residuals' sum where the
+    bias is."""
+    if plan.hbm_fields:
+        g = slot_sums_hbm(X, r, ids, geom, plan.hbm_fields)
+    else:
+        g = jnp.zeros((geom.w_len,), jnp.float32)
+    for group in plan.addr_groups:
+        acc = slot_sums_vmem(X, r, ids, geom, interpret=interpret,
+                             group=group)
+        for (lo, hi), base in zip(group.spans, group.bases):
+            g = g.at[lo:hi].add(acc[lo - base:hi - base])
+    if plan.dict_fields:
+        slots, sums = slot_sums_dict(X, r, ids, geom, plan,
+                                     interpret=interpret)
+        g = g.at[slots].add(sums)
+    return g.at[geom.n_slots].set(jnp.sum(r))

@@ -24,6 +24,12 @@ SSGD_UPDATE = "tda.ssgd.update"  # the rest of a step: reg, update, eval
 SSGD_GATHER = "tda.ssgd.gather"    # margins (w at each row's slots),
 #                                    labels, validity, residuals
 SSGD_SCATTER = "tda.ssgd.scatter"  # the residuals added up slot by slot
+# inside either pass of an indexed table: whatever serves the fields
+# whose ranges are past VMEM (pallas_hashed.field_form 'hbm': the DMA
+# gather kernel, XLA's scatter-add);
+# benchmarks/layer_metrics/hbm_fields_ms_per_step.lr reads it, and
+# update_ms_per_step.lr the 219 MB update under SSGD_UPDATE
+SSGD_TABLE_HBM = "tda.ssgd.table_hbm"
 # the three parts of a fused PageRank sweep (models/pagerank.py); the
 # benchmark's spmv_ms_per_sweep.graph and pagerank_spmv_roofline read
 # the first, sync_ms_per_sweep.graph and sync_exposed_ms_per_sweep.graph

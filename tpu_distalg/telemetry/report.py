@@ -244,6 +244,11 @@ def summarize(evts: list[dict]) -> dict:
             if "dict_fields" in e:
                 split = (e["dict_fields"], e.get("dict_values", 0),
                          e.get("addr_fields", 0))
+                if e.get("gather_form") == "fields":
+                    # an indexed table: each field its own form, the
+                    # ranges past VMEM left in HBM (field_form 'hbm')
+                    split += (e.get("fields_hbm", 0),
+                              e.get("table_bytes", 0))
                 if split not in field_splits:
                     field_splits.append(split)
             # and ALS' how R is held (a dense R says nothing; a ratings
@@ -431,9 +436,13 @@ def render(s: dict) -> str:
     for which, seen in (s.get("pass_forms") or {}).items():
         if seen:
             lines.append(f"{which} pass: {', '.join(seen)}")
-    for n_dict, n_values, n_addr in s.get("field_splits") or ():
-        lines.append(f"fields by value: {n_dict} ({n_values} values), "
-                     f"by address: {n_addr}")
+    for n_dict, n_values, n_addr, *indexed in s.get("field_splits") or ():
+        line = (f"fields by value: {n_dict} ({n_values} values), "
+                f"by address: {n_addr}")
+        if indexed:
+            line += (f", in HBM: {indexed[0]} (a table of "
+                     f"{indexed[1] / 1e6:.1f} MB)")
+        lines.append(line)
     if s.get("als_forms"):
         lines.append(f"R layout: {', '.join(s['als_forms'])}")
     if s.get("ranks_forms"):

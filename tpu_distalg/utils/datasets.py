@@ -9,6 +9,7 @@ column is appended to X (``ssgd.py:83-84``), so the model has D+1 weights.
 
 from __future__ import annotations
 
+import itertools
 from typing import Tuple
 
 import numpy as np
@@ -180,20 +181,113 @@ def hashed_click_rows(cardinalities, hash_bits: int, *,
 
     cards = jnp.asarray(cardinalities, jnp.float32)
     nnz = len(cardinalities)
-    a1 = 1.0 - float(zipf_exponent)
-    if abs(a1) < 1e-3:
-        raise ValueError("zipf_exponent 1 has its own inverse; use "
-                         "another")
-    span = (cards + 1.0) ** a1 - 1.0
+    a1, span = _power_law_span(cards, zipf_exponent)
     fields = jnp.arange(nnz, dtype=jnp.uint32)
 
-    def slots_and_scores(row_keys, w_salt):
+    def draw(row_keys):
         u = jax.vmap(lambda k: jax.random.uniform(k, (nnz,)))(row_keys)
         v = jnp.floor((1.0 + u * span) ** (1.0 / a1)) - 1.0
         v = jnp.clip(v, 0.0, cards - 1.0).astype(jnp.uint32)
         slots = click_slots(fields, v, hash_bits)
+        return slots, slots          # a slot's planted weight is its own
+
+    return _click_rows(draw, planted_scale, click_rate)
+
+
+def click_field_offsets(cardinalities) -> tuple[int, ...]:
+    """Where each field's range starts in an *indexed* table, the
+    fields' ranges laid end to end, and (last) where the table ends:
+    value ``v`` of field ``f`` is feature ``offsets[f] + v``, its own
+    weight, no hash."""
+    out = tuple(itertools.accumulate(map(int, cardinalities), initial=0))
+    if out[-1] >= 1 << 31:
+        raise ValueError(f"{out[-1]} features do not fit int32 indices")
+    return out
+
+
+def indexed_field_dictionaries(cardinalities) -> tuple:
+    """:func:`click_field_dictionaries` for an indexed table: a field
+    of at most ``DICTIONARY_MAX_VALUES`` values holds exactly its own
+    range."""
+    off = click_field_offsets(cardinalities)
+    return tuple(
+        np.arange(off[f], off[f + 1], dtype=np.int32)
+        if c <= DICTIONARY_MAX_VALUES else None
+        for f, c in enumerate(cardinalities))
+
+
+def indexed_click_rows(cardinalities, *, zipf_exponent: float = 1.1,
+                       planted_scale: float = 0.25,
+                       click_rate: float = 0.256):
+    """:func:`hashed_click_rows` without the hash: a row's slot for
+    field ``f`` is ``click_field_offsets(cardinalities)[f] + v``, the
+    one-hot index a LIBSVM file would hold (every feature its own
+    weight). Two things differ beside that.
+
+    The draw is exact however many values a field has. A float32
+    uniform has 2**23 levels and a float32 ``x`` past 2**24 holds no odd
+    integer, so the inverse above reaches one value in a hundred of a
+    field of 24 million. Here the uniform picks a stratum of the
+    distribution (``x`` down to ``x - |dx/du| 2**-23``), a second
+    uniform a point inside it, and the value is taken in int32: every
+    value can be drawn, odd ones past 2**24 among them, and the
+    density inside a stratum is flat where the law's falls by a part in
+    2**23.
+
+    The planted weight of a feature is hashed from ``(seed, field,
+    value)`` and so does not depend on how a table lays the fields out.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    cards = jnp.asarray(cardinalities, jnp.float32)
+    top = jnp.asarray(cardinalities, jnp.int32) - 1
+    offsets = jnp.asarray(click_field_offsets(cardinalities)[:-1],
+                          jnp.int32)
+    nnz = len(cardinalities)
+    a1, span = _power_law_span(cards, zipf_exponent)
+    stratum = jnp.abs(span / a1) * (2.0 ** -23)
+    fields = jnp.arange(nnz, dtype=jnp.uint32)
+
+    def draw(row_keys):
+        u = jax.vmap(lambda k: jax.random.uniform(k, (nnz,)))(row_keys)
+        j = jax.vmap(lambda k: jax.random.uniform(
+            jax.random.fold_in(k, 11), (nnz,)))(row_keys)
+        x = (1.0 + u * span) ** (1.0 / a1)
+        whole = jnp.floor(x)
+        # how far below ``x`` the point lies, less what ``floor`` took
+        down = jnp.ceil(j * stratum * x ** float(zipf_exponent)
+                        - (x - whole))
+        v = whole.astype(jnp.int32) - jnp.maximum(down, 0.0).astype(
+            jnp.int32) - 1
+        v = jnp.clip(v, 0, top)
+        return offsets + v, click_slots(fields, v.astype(jnp.uint32), 32)
+
+    return _click_rows(draw, planted_scale, click_rate)
+
+
+def _power_law_span(cards, zipf_exponent: float):
+    """``(a1, span)`` of the bounded power law's inverse: a uniform
+    ``u`` gives ``x = (1 + u span) ** (1 / a1)`` on ``[1, N + 1)``."""
+    a1 = 1.0 - float(zipf_exponent)
+    if abs(a1) < 1e-3:
+        raise ValueError("zipf_exponent 1 has its own inverse; use "
+                         "another")
+    return a1, (cards + 1.0) ** a1 - 1.0
+
+
+def _click_rows(draw, planted_scale: float, click_rate: float):
+    """What the hashed and the indexed generator share: the seed's
+    streams, the planted model, its bias, the labels. ``draw(row_keys)
+    -> (slots (n, nnz), uint32 (n, nnz) that name each slot's planted
+    weight)``."""
+    import jax
+    import jax.numpy as jnp
+
+    def slots_and_scores(row_keys, w_salt):
+        slots, named = draw(row_keys)
         # the planted weight of a slot: uniform on [-sqrt 3, sqrt 3)
-        bits = _mix32(slots ^ w_salt) >> 8
+        bits = _mix32(named ^ w_salt) >> 8
         planted = (bits.astype(jnp.float32) * (2.0 ** -23) - 1.0) \
             * (3.0 ** 0.5)
         z = planted_scale * jnp.sum(planted, axis=1)
