@@ -194,6 +194,25 @@ def test_shipped_kernels_interpreted_against_their_xla_form(
         np.asarray(ph.margins_vmem(X, w, ids, geom, interpret=True)))
 
 
+@pytest.mark.parametrize("block_rows", [128, 256, 8192])
+@pytest.mark.parametrize("n_fields,rows", [
+    (1, 32), (2, 16), (3, 8), (4, 8), (8, 4), (16, 2), (18, 2), (39, 2)])
+def test_rows_a_trip_follow_the_fields_a_call_serves(n_fields, rows,
+                                                     block_rows):
+    """A trip of a by-address loop is a number of (row, field) pairs:
+    the most rows, a power of two times ``LOOP_ROWS``, that keep it at
+    ``TRIP_PAIRS`` or fewer; the benchmark's hashed calls (18 of 39
+    fields, or all 39) run the 2 rows they always did."""
+    geom = ph.HashedGeometry(nnz=39, hash_bits=20, block_rows=block_rows)
+    assert geom.chunk_rows == min(block_rows, ph.CHUNK_ROWS)
+    assert (ph.LOOP_ROWS, ph.TRIP_PAIRS) == (2, 32)
+    assert ph._loop_rows(geom, n_fields) == rows
+    assert rows * n_fields <= max(ph.TRIP_PAIRS, ph.LOOP_ROWS * n_fields)
+    # rows handed in are taken where they divide the chunk
+    assert ph._loop_rows(geom, n_fields, 4) == 4
+    assert ph._loop_rows(geom, n_fields, 3) == 1
+
+
 def test_a_table_of_another_shape_is_refused():
     geom = ph.HashedGeometry(nnz=6, hash_bits=10, block_rows=128)
     ids = jnp.zeros((1,), jnp.int32)

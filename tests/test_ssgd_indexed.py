@@ -345,12 +345,14 @@ def test_lowered_trainer_names_the_table_in_hbm(mesh1, small_vmem):
 
 
 @pytest.mark.parametrize("block_rows,fields", [
-    (256, (5, 9)), (128, (9,)), (1024, (0, 5, 7))])
+    (256, (5, 9)), (128, (9,)), (1024, (0, 5, 7)), (256, (5,)),
+    (128, (1, 5, 8, 9))])
 def test_the_gather_from_hbm_is_xlas_bit_for_bit(mesh1, block_rows, fields):
     """``_hashed_hbm_gather_kernel`` interpreted (a DMA a pair from the
     model vector as rows of 128 lanes, the lane kept) against ``w[idx]``:
     the same float32 weights, one field's alone bit for bit, several
-    fields' sums to the order of their additions."""
+    fields' sums to the order of their additions; its third pass at the
+    rule's rows a trip and at 2, bit for bit."""
     cfg = _cfg(block_rows, 0.5, 1)
     X, meta = ssgd.build_hashed_table(
         5 * block_rows - 9, 11, 0, mesh1, cfg, data_seed=6,
@@ -360,11 +362,84 @@ def test_the_gather_from_hbm_is_xlas_bit_for_bit(mesh1, block_rows, fields):
     ids = jnp.array([4, 1, 2], jnp.int32)
     w = jax.random.normal(jax.random.key(3), (geom.w_len,))
     got = ph.margins_hbm(X, w, ids, geom, fields, interpret=True)
+    assert ph._loop_rows(geom, len(fields)) > ph.LOOP_ROWS
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(
+        ph.margins_hbm(X, w, ids, geom, fields, interpret=True, rows=2)))
     want = ph.margins_hbm_xla(X, w, ids, fields)
     if len(fields) == 1:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     else:
         np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+
+# ---- a call's trip follows the fields it serves --------------------------
+
+# thirteen fields: a block holds 16 rows of indices, as the benchmark's
+CARDS13 = (300, 200, 150, 3, 3, 900, 500, 700, 400, 600, 21, 50, 350)
+
+
+def _crafted13(block_rows, nb):
+    geom = ph.HashedGeometry(13, 0, block_rows, field_sizes=CARDS13)
+    rng = np.random.default_rng(7)
+    X = np.zeros((nb, geom.fields_held, block_rows), np.int32)
+    for f, (lo, hi) in enumerate(zip(geom.offsets, geom.offsets[1:])):
+        X[:, f, :] = rng.integers(lo, hi, (nb, block_rows))
+    X[:, 13, :] = rng.integers(0, 2, (nb, block_rows))
+    return geom, jnp.asarray(X)
+
+
+@pytest.mark.parametrize("n_acc", [1, 2, 4])
+@pytest.mark.parametrize("fields,rows", [
+    ((7,), 32), ((8,), 32), ((0, 1, 2, 6), 8), ((3, 12), 16)])
+def test_a_calls_trip_follows_its_fields(fields, rows, n_acc, tmp_path):
+    """One call of each by-address kernel over a group with bases, at
+    the rule's rows a trip against 2 rows a trip: the same float32
+    numbers bit for bit (a row's sums fold in one order, a pair goes to
+    the accumulator its row's parity gives it), XLA's to rounding; and
+    the call says once what it runs."""
+    geom, X = _crafted13(256, 5)
+    group, = ph.addr_groups(fields, geom.offsets)
+    assert any(group.bases) and group.fields == fields
+    assert ph._loop_rows(geom, len(fields)) == rows
+    ids = jnp.array([3, 0, 4], jnp.int32)
+    key = jax.random.key(5)
+    w = jax.random.normal(key, (geom.w_len,))
+    r = jax.random.normal(jax.random.fold_in(key, 1), (3, 256))
+
+    def both(**kw):
+        return (np.asarray(ph.margins_vmem(
+                    X, w, ids, geom, interpret=True, group=group, **kw)),
+                np.asarray(ph.slot_sums_vmem(
+                    X, r, ids, geom, interpret=True, group=group,
+                    n_acc=n_acc, **kw)))
+
+    events.configure(str(tmp_path))
+    try:
+        m, g = both()
+    finally:
+        events.configure(False)
+    said = [e for e in report.load_events(str(tmp_path))
+            if e["ev"] == "ssgd:addr_call"]
+    assert [(e["kernel"], e["fields"], e["rows"], e["pairs"],
+             e["smem_rows"]) for e in said] == [
+        (k, list(fields), rows, rows * len(fields), 16)
+        for k in ("_hashed_gather_kernel", "_hashed_scatter_kernel")]
+    assert (f"by-address call: _hashed_gather_kernel over fields "
+            f"{list(fields)}: {rows} rows a trip ({rows * len(fields)} "
+            f"pairs), 16 index rows a chunk in SMEM") in \
+        report.render(report.summarize(said))
+    m2, g2 = both(rows=2)
+    np.testing.assert_array_equal(m, m2)
+    np.testing.assert_array_equal(g, g2)
+    np.testing.assert_allclose(
+        m, ph.margins_hbm_xla(X, w, ids, fields), rtol=1e-6, atol=1e-6)
+    idx = np.asarray(X)[np.asarray(ids)][:, list(fields), :]
+    want = np.zeros((geom.w_len,), np.float64)
+    np.add.at(want, idx, np.broadcast_to(
+        np.asarray(r, np.float64)[:, None, :], idx.shape))
+    for (lo, hi), base in zip(group.spans, group.bases):
+        np.testing.assert_allclose(g[lo - base:hi - base], want[lo:hi],
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_spans_report_and_result_say_the_forms(mesh1, small_vmem, tmp_path):
@@ -398,7 +473,16 @@ def test_spans_report_and_result_say_the_forms(mesh1, small_vmem, tmp_path):
     for line in ("row format: indexed", "gather pass: fields",
                  "scatter pass: fields",
                  "fields by value: 5 (477 values), by address: 4, in "
-                 "HBM: 2 (a table of 0.1 MB)"):
+                 "HBM: 2 (a table of 0.1 MB)",
+                 "by-address call: _hashed_gather_kernel over fields "
+                 "[1, 6]: 16 rows a trip (32 pairs), 16 index rows a "
+                 "chunk in SMEM",
+                 "by-address call: _hashed_scatter_kernel over fields "
+                 "[8]: 32 rows a trip (32 pairs), 16 index rows a chunk "
+                 "in SMEM",
+                 "by-address call: _hashed_hbm_gather_kernel over fields "
+                 "[5, 9]: 16 rows a trip (32 pairs), 16 index rows a "
+                 "chunk in SMEM"):
         assert line in lines, line
 
 

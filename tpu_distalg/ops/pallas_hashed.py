@@ -60,7 +60,34 @@ field's range. :func:`field_plan` makes the choice for a table.
           are taken in *groups* of at most ``2 ** VMEM_BITS`` slots
           (:class:`AddrGroup`): a group's ranges are copied end to end
           into one table that a call of the same two kernels keeps in
-          VMEM, each field's slots re-based by a constant.
+          VMEM, each field's slots re-based by a constant. A trip of
+          either kernel's loop is a number of (row, field) pairs and
+          one basic block (the scheduler overlaps independent chains
+          only inside one): ``_loop_rows`` gives the most rows, 2, 4, 8
+          ..., that keep it at ``TRIP_PAIRS`` pairs, so 2 rows of 18 or
+          of 39 fields, 8 of four, 16 of two, 32 of one. A pair of a
+          one-field call costs 12 scalar operations on two scalar slots
+          (the row, the SMEM tile address, the load, base, shift, mask,
+          two addresses), seven of which a row's other fields would
+          share; at 2 pairs a trip the loop's own overhead came on top:
+          8.5 bundles a pair, 6.2 at 32, where four fields take 4.4 and
+          3.8 (the post-RA schedule), and every trip's taken branch 3.8
+          ns that no bundle shows (a call's time is 0.667 ns a bundle +
+          3.8 ns a trip + 0.06 us a grid step to 1%, in both cells). On
+          one v5e at KDD Cup 2012's shape
+          (PR 48's Step 0, ``scripts/step0_indexed.py``, ms a call over
+          1.5M rows) a one-field gather read 13.18 / 9.56 / 8.84 / 8.62 /
+          8.37 at 2 / 8 / 16 / 32 / 64 rows a trip and its scatter 13.67
+          / 10.07 / 9.37 / 9.16 / 9.15, the four-field group's 22.31 /
+          19.18 / 17.99 / 17.31 and 23.05 / 21.73 / 19.40 / 18.35 at 2 /
+          4 / 8 / 16, every trip the same float32 numbers bit for bit;
+          tracing and lowering a trip's pairs is paid by every run
+          (``_each_row``), which is what holds ``TRIP_PAIRS`` at 32. A
+          chunk brings the block's every field row through SMEM
+          whatever the call serves (16 x 256 indices at 11 fields, 40 x
+          256 at 39): the tile of 8 rows that holds a call's fields
+          alone read the same (8.58 against 8.62 ms), the copy is hidden
+          behind the chunk before.
 ``hbm``   an indexed field whose range alone is past ``2 ** VMEM_BITS``
           slots (a user id, a query id): its weights stay in HBM, under
           the scope ``tda.ssgd.table_hbm`` inside the pass's own. The
@@ -69,10 +96,12 @@ field's range. :func:`field_plan` makes the choice for a table.
           DMA a (row, field) pair of the table's row ``h >> 7`` into a
           landing row in VMEM, a wait a trip of copies, then lane ``h &
           127`` kept and a row's fields added up as the by-address
-          kernel does. No resident head: a pair costs the same whatever
-          the skew (on one v5e at KDD Cup 2012's shape 32.6 ms a step
-          for two fields of 1.5M rows where XLA's ``w[idx]`` takes 52.6,
-          the same float32 weights bit for bit: PR 47's Step 0). The
+          kernel does, at the by-address rule's rows a trip (16 for two
+          fields: 27.43 ms where 2 rows read 32.20, PR 48). No resident
+          head: a pair costs the same whatever the skew (on one v5e at
+          KDD Cup 2012's shape 32.6 ms a step for two fields of 1.5M
+          rows where XLA's ``w[idx]`` takes 52.6, the same float32
+          weights bit for bit: PR 47's Step 0). The
           scatter is XLA's ``zeros.at[idx].add`` over those fields'
           pairs (30.8 ms): a read-modify-write of HBM rows by DMA has no
           order between two copies to one row.
@@ -108,6 +137,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -115,12 +145,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpu_distalg.telemetry import events as tevents
 from tpu_distalg.telemetry import names
 
 LANES = 128
 SUBLANES = 8
-CHUNK_ROWS = 256       # rows a grid step takes: 40 x 256 indices in SMEM
-LOOP_ROWS = 2          # rows written out a trip of the loop
+CHUNK_ROWS = 256       # rows a grid step takes: fields_held x 256 indices
+#                        in SMEM (40 x 256 at 39 fields, 16 x 256 at 11)
+LOOP_ROWS = 2          # rows a trip of a by-address loop writes out at
+#                        least, and the rows of it that are traced
+TRIP_PAIRS = 32        # (row, field) pairs a trip where fewer fields leave
+#                        room for more rows: 64 read 2 to 5% under 32 on
+#                        the chip and cost every run twice the lowering
 GATHER_SUMS = 4        # partial sums a row's loads are added into
 SCATTER_ACCS = 4       # accumulators the scatter takes in turn, at most
 ACC_VMEM_BYTES = 64 << 20   # ... and what they and their second buffers
@@ -431,32 +467,29 @@ def _hashed_hbm_gather_kernel(ids_ref, idx_ref, tab_ref, out_ref, land_ref,
 
     jax.lax.fori_loop(0, cr // trip, land, 0)
 
-    def some(t, carry):
-        first = pl.multiple_of(t * rows, rows)
-        for u in range(rows):
-            acc = None
-            for n, j in enumerate(fields):
-                h = idx_ref[j, first + u]
-                got = jnp.where(
-                    lane == (h & (LANES - 1)),
-                    land_ref[pl.ds(n * cr + first + u, 1), :], 0.0)
-                acc = got if acc is None else acc + got
-            out_ref[pl.ds(first + u, 1), :] = acc
-        return carry
+    def one(i, u):
+        acc = None
+        for n, j in enumerate(fields):
+            h = idx_ref[j, i]
+            got = jnp.where(lane == (h & (LANES - 1)),
+                            land_ref[pl.ds(n * cr + i, 1), :], 0.0)
+            acc = got if acc is None else acc + got
+        out_ref[pl.ds(i, 1), :] = acc
 
-    jax.lax.fori_loop(0, cr // rows, some, 0)
+    _each_row(cr, rows, one)
 
 
 def margins_hbm(X, w, ids, geom: HashedGeometry, fields, *,
-                interpret: bool = False):
+                interpret: bool = False, rows: int | None = None):
     """The share of the margins of an indexed table's ``fields`` whose
     ranges stay in HBM (no bias): the model vector read where it lies,
-    as rows of 128 lanes, a row a DMA."""
+    as rows of 128 lanes, a row a DMA. ``rows``: the third pass's rows
+    a trip, the by-address rule's unless given."""
     cr = geom.chunk_rows
     trip = HBM_TRIP if cr % HBM_TRIP == 0 else 1
-    kernel = functools.partial(_hashed_hbm_gather_kernel,
-                               fields=tuple(fields), trip=trip,
-                               rows=_loop_rows(geom, None))
+    fields = tuple(fields)
+    kernel = _addr_call(_hashed_hbm_gather_kernel, geom, fields, rows,
+                        trip=trip)
     with jax.named_scope(names.SSGD_TABLE_HBM):
         parts = pl.pallas_call(
             kernel,
@@ -491,6 +524,30 @@ def slot_sums_hbm(X, r, ids, geom: HashedGeometry, fields):
 
 # ---- Mosaic forms ------------------------------------------------------
 
+def _each_row(n_rows: int, rows: int, one) -> None:
+    """``one(i, u)`` for every row ``i`` of a chunk of ``n_rows``,
+    ``rows`` of them a trip of the loop and one basic block. What is
+    traced is ``LOOP_ROWS`` rows (``u`` counts them: the scatter's
+    accumulators go by it); a longer trip is that piece written out again
+    at lowering (a loop unrolled whole, its index a constant in every
+    copy), so it costs the lowering of its pairs and no tracing."""
+    per = math.gcd(rows, LOOP_ROWS)
+
+    def trip(t, carry):
+        first = pl.multiple_of(t * rows, rows)
+
+        def piece(v, carry):
+            for u in range(per):
+                one(first + (v * per + u), u)
+            return carry
+
+        if rows == per:
+            return piece(0, carry)
+        return jax.lax.fori_loop(0, rows // per, piece, carry, unroll=True)
+
+    jax.lax.fori_loop(0, n_rows // rows, trip, 0)
+
+
 def _hashed_gather_kernel(ids_ref, idx_ref, w_ref, out_ref, *,
                           fields: tuple, bases: tuple, rows: int):
     """One chunk of one sampled block: ``out[i, :]`` holds the weights
@@ -502,24 +559,20 @@ def _hashed_gather_kernel(ids_ref, idx_ref, w_ref, out_ref, *,
     del ids_ref                         # the index maps read it
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
-    def some(t, carry):
-        first = pl.multiple_of(t * rows, rows)
-        for u in range(rows):
-            sums = [None] * min(GATHER_SUMS, len(fields))
-            for n, j in enumerate(fields):
-                h = idx_ref[j, first + u] - bases[n] if bases[n] \
-                    else idx_ref[j, first + u]
-                got = jnp.where(lane == (h & (LANES - 1)),
-                                w_ref[pl.ds(h >> 7, 1), :], 0.0)
-                k = n % len(sums)
-                sums[k] = got if sums[k] is None else sums[k] + got
-            while len(sums) > 1:        # pairwise, a fixed order
-                sums = [a + b for a, b in zip(sums[::2], sums[1::2])] \
-                    + sums[len(sums) & ~1:]
-            out_ref[pl.ds(first + u, 1), :] = sums[0]
-        return carry
+    def one(i, u):
+        sums = [None] * min(GATHER_SUMS, len(fields))
+        for n, j in enumerate(fields):
+            h = idx_ref[j, i] - bases[n] if bases[n] else idx_ref[j, i]
+            got = jnp.where(lane == (h & (LANES - 1)),
+                            w_ref[pl.ds(h >> 7, 1), :], 0.0)
+            k = n % len(sums)
+            sums[k] = got if sums[k] is None else sums[k] + got
+        while len(sums) > 1:            # pairwise, a fixed order
+            sums = [a + b for a, b in zip(sums[::2], sums[1::2])] \
+                + sums[len(sums) & ~1:]
+        out_ref[pl.ds(i, 1), :] = sums[0]
 
-    jax.lax.fori_loop(0, idx_ref.shape[1] // rows, some, 0)
+    _each_row(idx_ref.shape[1], rows, one)
 
 
 def _hashed_scatter_kernel(ids_ref, idx_ref, rb_ref, *accs,
@@ -538,19 +591,15 @@ def _hashed_scatter_kernel(ids_ref, idx_ref, rb_ref, *accs,
         for acc in accs:
             acc[...] = jnp.zeros_like(acc)
 
-    def some(t, carry):
-        first = pl.multiple_of(t * rows, rows)
-        for u in range(rows):
-            r = rb_ref[pl.ds(first + u, 1), :]
-            for n, j in enumerate(fields):
-                h = idx_ref[j, first + u] - bases[n] if bases[n] \
-                    else idx_ref[j, first + u]
-                acc = accs[(u * len(fields) + n) % len(accs)]
-                acc[pl.ds(h >> 7, 1), :] += jnp.where(
-                    lane == (h & (LANES - 1)), r, 0.0)
-        return carry
+    def one(i, u):
+        r = rb_ref[pl.ds(i, 1), :]
+        for n, j in enumerate(fields):
+            h = idx_ref[j, i] - bases[n] if bases[n] else idx_ref[j, i]
+            acc = accs[(u * len(fields) + n) % len(accs)]
+            acc[pl.ds(h >> 7, 1), :] += jnp.where(
+                lane == (h & (LANES - 1)), r, 0.0)
 
-    jax.lax.fori_loop(0, idx_ref.shape[1] // rows, some, 0)
+    _each_row(idx_ref.shape[1], rows, one)
 
 
 def _grid_spec(ids, geom: HashedGeometry, more_in, out_specs,
@@ -565,9 +614,30 @@ def _grid_spec(ids, geom: HashedGeometry, more_in, out_specs,
         out_specs=out_specs, scratch_shapes=scratch_shapes)
 
 
-def _loop_rows(geom: HashedGeometry, rows: int | None) -> int:
-    rows = LOOP_ROWS if rows is None else rows
+def _loop_rows(geom: HashedGeometry, n_fields: int,
+               rows: int | None = None) -> int:
+    """Rows a trip of a by-address loop writes out over ``n_fields``
+    fields: the most, doubling from ``LOOP_ROWS`` while the chunk
+    divides, that keep a trip at ``TRIP_PAIRS`` (row, field) pairs or
+    fewer."""
+    if rows is None:
+        rows = LOOP_ROWS
+        while 2 * rows * n_fields <= TRIP_PAIRS \
+                and geom.chunk_rows % (2 * rows) == 0:
+            rows *= 2
     return rows if geom.chunk_rows % rows == 0 else 1
+
+
+def _addr_call(kernel, geom: HashedGeometry, fields: tuple,
+               rows: int | None, **more):
+    """A by-address kernel bound to its call's fields and the rows a
+    trip they give; what the call runs is said once, when it is traced
+    (``tda report``: ``by-address call``)."""
+    rows = _loop_rows(geom, len(fields), rows)
+    tevents.emit("ssgd:addr_call", kernel=kernel.__name__,
+                 fields=list(fields), rows=rows, pairs=rows * len(fields),
+                 smem_rows=geom.fields_held)
+    return functools.partial(kernel, fields=fields, rows=rows, **more)
 
 
 def _vmem_limit(geom: HashedGeometry, tables: int, n_slots: int) -> int:
@@ -607,8 +677,8 @@ def margins_vmem(X, w, ids, geom: HashedGeometry, *,
     fields, bases, n_slots = _served(geom, fields, group)
     table = (w[:n_slots] if group is None else _group_table(w, group)
              ).reshape(n_slots // LANES, LANES)
-    kernel = functools.partial(_hashed_gather_kernel, fields=fields,
-                               bases=bases, rows=_loop_rows(geom, rows))
+    kernel = _addr_call(_hashed_gather_kernel, geom, fields, rows,
+                        bases=bases)
     parts = pl.pallas_call(
         kernel,
         name="_hashed_gather_kernel",
@@ -639,8 +709,8 @@ def slot_sums_vmem(X, r, ids, geom: HashedGeometry, *,
     n_acc = _scatter_accs(n_slots) if n_acc is None else n_acc
     shape = (n_slots // LANES, LANES)
     rb = jnp.broadcast_to(r[:, :, None], r.shape + (LANES,))
-    kernel = functools.partial(_hashed_scatter_kernel, fields=fields,
-                               bases=bases, rows=_loop_rows(geom, rows))
+    kernel = _addr_call(_hashed_scatter_kernel, geom, fields, rows,
+                        bases=bases)
     accs = pl.pallas_call(
         kernel,
         name="_hashed_scatter_kernel",

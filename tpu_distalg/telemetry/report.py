@@ -178,6 +178,7 @@ def summarize(evts: list[dict]) -> dict:
     row_formats: list[str] = []
     pass_forms: dict[str, list[str]] = {"gather": [], "scatter": []}
     field_splits: list[tuple] = []
+    addr_calls: list[tuple] = []
     als_forms: list[str] = []
     ranks_forms: list[str] = []
     t_wall = [e["t_wall"] for e in evts if "t_wall" in e]
@@ -291,6 +292,15 @@ def summarize(evts: list[dict]) -> dict:
             p["max_seconds"] = round(max(p["max_seconds"], s), 6)
             if not e.get("ok", True):
                 p["errors"] += 1
+        elif ev == "ssgd:addr_call":
+            # what one by-address call of a hashed or indexed step runs,
+            # said when it is traced (pallas_hashed._addr_call): the
+            # rows a trip of its loop follow the fields it serves, the
+            # index rows a chunk brings into SMEM are the block's
+            call = (e.get("kernel", "?"), tuple(e.get("fields") or ()),
+                    e.get("rows"), e.get("pairs"), e.get("smem_rows"))
+            if call not in addr_calls:
+                addr_calls.append(call)
         elif ev == "mark":
             marks += 1
         elif ev == "heartbeat":
@@ -352,6 +362,7 @@ def summarize(evts: list[dict]) -> dict:
         "row_formats": row_formats,
         "pass_forms": pass_forms,
         "field_splits": field_splits,
+        "addr_calls": addr_calls,
         "als_forms": als_forms,
         "ranks_forms": ranks_forms,
         "unfinished_phases": sorted(
@@ -443,6 +454,10 @@ def render(s: dict) -> str:
             line += (f", in HBM: {indexed[0]} (a table of "
                      f"{indexed[1] / 1e6:.1f} MB)")
         lines.append(line)
+    for kernel, fields, rows, pairs, smem_rows in s.get("addr_calls") or ():
+        lines.append(f"by-address call: {kernel} over fields "
+                     f"{list(fields)}: {rows} rows a trip ({pairs} pairs), "
+                     f"{smem_rows} index rows a chunk in SMEM")
     if s.get("als_forms"):
         lines.append(f"R layout: {', '.join(s['als_forms'])}")
     if s.get("ranks_forms"):
