@@ -1,0 +1,9 @@
+"""Self time of the program's ``ssgd:generate`` span inside
+``data_build`` (harness/spans.self_seconds: its seconds less the spans
+directly beneath it, ``jit:*`` included)."""
+
+from harness import spans
+
+
+def read(ctx):
+    return spans.self_seconds(ctx, "ssgd:generate")

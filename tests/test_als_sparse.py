@@ -393,7 +393,13 @@ def test_spans_and_report_say_the_layout(mesh1, tmp_path):
     prep = ends["als:prepare"]
     assert (prep["layout"], prep["ratings"], prep["users"], prep["items"],
             prep["k"]) == ("ratings", int(du.sum()), 12, 9, K)
-    assert prep["bytes"] == meta["ratings_bytes"] + meta["factor_bytes"]
+    # what the loader left on the device, by the arrays' own count (the
+    # plan's count, the two factor tables included, is the CLI's line)
+    assert prep["bytes"] == sum(a.nbytes for a in arrays) \
+        >= meta["ratings_bytes"]
+    made = {n: ends[n]["bytes"] for n in ("als:pack", "als:generate",
+                                          "als:heldout")}
+    assert all(v > 0 for v in made.values()) and "hbm_in_use" not in prep
     assert (prep["user_blocks"], prep["item_blocks"]) == meta["blocks"]
     assert prep["padding_share"] == round(meta["padding_share"], 4) > 1
     parents = {e["name"]: e["parent"] for e in evts

@@ -111,21 +111,28 @@ def test_concurrent_emitters_produce_wellformed_jsonl(sink_dir):
 
 def test_disabled_path_does_zero_file_io(tmp_path, monkeypatch):
     """With telemetry off, emit/mark/span/counter/gauge must never
-    touch a file — asserted by making every sink write explode."""
+    touch a file — asserted by making every sink write, and ``open``
+    itself, explode. The counter is kept all the same: the store is the
+    process's, as the ring of finished spans is."""
     events.configure(False)
 
     def forbidden(*a, **k):
         raise AssertionError("file I/O on the disabled telemetry path")
 
     monkeypatch.setattr(events.EventSink, "write", forbidden)
-    monkeypatch.setattr(events.EventSink, "bump", forbidden)
     monkeypatch.setattr(events.EventSink, "__init__", forbidden)
+    monkeypatch.setattr("builtins.open", forbidden)
+    monkeypatch.setattr(os, "makedirs", forbidden)
     events.emit("nope", x=1)
     events.mark("nope")
     events.counter("nope")
+    events.counter("nope", 2)
     events.gauge("nope", 1)
     with events.span("nope"):
         pass
+    assert events.counters() == {"nope": 3}
+    assert events.memory([]) is None
+    monkeypatch.undo()
     assert list(tmp_path.iterdir()) == []
 
 

@@ -1446,3 +1446,52 @@ def test_sparse_als_resident_gather_over_many_blocks():
     bad, _ = jax.jit(lambda keys: jax.lax.scan(one, jnp.int32(0), keys))(
         jax.random.split(jax.random.PRNGKey(2), 256))
     assert int(bad) == 0
+
+
+def test_a_loader_span_ends_with_at_least_the_bytes_it_states():
+    """On the chip the allocator keeps stats: after
+    ``als.build_ratings_table`` at the half-sweep test's shape every
+    loader span carries ``hbm_in_use`` / ``hbm_peak`` / ``hbm_in_use_start``
+    (one entry, one chip), ``als:prepare`` ends with at least the
+    ``bytes`` it states resident (its arrays' own ``nbytes``), the
+    generate phase's rise is no less than what it says it made (the
+    programs it loaded lie in HBM too), and a ``memory`` sample costs
+    microseconds."""
+    import time
+
+    from tpu_distalg.models import als
+    from tpu_distalg.parallel import get_mesh
+    from tpu_distalg.telemetry import events
+
+    mesh = get_mesh(data=1, devices=jax.devices()[:1])
+    geometry = dict(seg_slots=32, piece_segs=64, batch=768,
+                    classes=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48))
+    events._FINISHED.clear()
+    arrays, meta = als.build_ratings_table(
+        1_500_000, 15_000, 9_000, 100, mesh, data_seed=9, n_heldout=4096,
+        geometry=geometry, d_min=20, user_d_max=20_000, item_d_max=40_000)
+    done = {}
+    for s in events.finished():
+        done.setdefault(s.name, s)
+    root = done["als:prepare"].fields
+    held = sum(a.nbytes for a in arrays)
+    assert root["bytes"] == held
+    assert len(root["hbm_in_use"]) == 1
+    assert root["hbm_in_use"][0] >= root["bytes"]
+    assert root["hbm_peak"][0] >= root["hbm_in_use"][0]
+    gen = done["als:generate"].fields
+    rise = gen["hbm_in_use"][0] - gen["hbm_in_use_start"][0]
+    assert rise >= 0.9 * gen["bytes"], (rise, gen)
+    for name in ("als:pack", "als:heldout", "als:lists"):
+        assert done[name].fields["hbm_in_use"][0] > 0, name
+    assert not [s.name for s in events.finished()
+                if s.name.startswith("jit:") and "hbm_in_use" in s.fields]
+    t0 = time.perf_counter()
+    for _ in range(1000):
+        events.memory(mesh.local_devices)
+    us = (time.perf_counter() - t0) * 1e3
+    print(f"[memory] a sample of {len(mesh.local_devices)} device(s): "
+          f"{us:.2f} us; als:prepare in use {root['hbm_in_use'][0]} B for "
+          f"{held} B stated, peak {root['hbm_peak'][0]} B; als:generate "
+          f"rose {rise} B for {gen['bytes']} B stated")
+    assert us < 1000

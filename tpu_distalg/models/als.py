@@ -312,7 +312,6 @@ def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
 def _prepare_fields(meta: dict) -> dict:
     return dict(ratings=meta["n_ratings"], users=meta["n_users"],
                 items=meta["n_items"], k=meta["k"],
-                bytes=meta["ratings_bytes"] + meta["factor_bytes"],
                 user_blocks=meta["blocks"][0],
                 item_blocks=meta["blocks"][1],
                 padding_share=round(meta["padding_share"], 4),
@@ -365,7 +364,8 @@ def ratings_from_coo(users, items, ratings, n_users: int, n_items: int,
     S = mesh.shape[DATA_AXIS]
     users = np.asarray(users, np.int64)
     items = np.asarray(items, np.int64)
-    with tevents.span("als:pack", ratings=int(users.shape[0])):
+    with tevents.span("als:pack", mesh.local_devices,
+                      ratings=int(users.shape[0])):
         pu = als_sparse.plan_side(
             np.bincount(users, minlength=n_users), geom, S)
         pi = als_sparse.plan_side(
@@ -410,13 +410,16 @@ def _ratings_arrays(sides, pieces, held, meta: dict, mesh: Mesh):
     for s, gather in enumerate(meta["gather"]):
         if gather.form != "mosaic":
             continue
-        with tevents.span("als:lists", side=s,
-                          cold_slots=meta["gather_cold_slots"][s]):
+        with tevents.span("als:lists", mesh.local_devices, side=s,
+                          cold_slots=meta["gather_cold_slots"][s],
+                          slots=(meta["user"], meta["item"])[s].slots_held):
             made = jax.jit(
                 functools.partial(als_sparse.gather_lists, gather=gather),
                 donate_argnums=(0, 1), out_shardings=(rows,) * 4)(
                     *sides[s])
             jax.block_until_ready(made)
+            # in place of the pack's two, which were donated
+            tevents.current().fields["bytes"] = metrics.nbytes(made)
         sides[s] = made[:2]
         lists += made[2:]
     return (*sides[0], pieces[0], *sides[1], pieces[1], *held, *lists)
@@ -568,9 +571,12 @@ def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
 
     S = mesh.shape[DATA_AXIS]
     seed = jnp.int32(data_seed)
-    with tevents.span("als:prepare", ratings=n_ratings, users=n_users,
-                      items=n_items, k=k, layout=RATINGS_LAYOUT):
-        with tevents.span("als:pack", ratings=n_ratings):
+    devices = mesh.local_devices
+    with tevents.span("als:prepare", devices, ratings=n_ratings,
+                      users=n_users, items=n_items, k=k,
+                      layout=RATINGS_LAYOUT):
+        prepare = tevents.current().fields
+        with tevents.span("als:pack", devices, ratings=n_ratings):
             meta = plan_ratings(n_ratings, n_users, n_items, k, S,
                                 on_tpu=mesh_on_tpu(mesh), **plan_kw)
             meta["data_seed"] = int(data_seed)
@@ -578,13 +584,14 @@ def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
             stubs = [tuple(_put(a, "ratings", mesh) for a in (
                 *als_sparse.segment_stubs(p, geom), p.seg_owner))
                 for p in plans]
-        tevents.current().fields.update(_prepare_fields(meta))
+            tevents.current().fields["bytes"] = metrics.nbytes(stubs)
+        prepare.update(_prepare_fields(meta))
         par = dict(meta["generator"])
         gen = dsets.seeded_ratings(
             n_ratings, k, mean=par["mean"], scale=par["scale"],
             noise=par["noise"])
-        with tevents.span("als:generate", slots=plans[0].slots_held
-                          + plans[1].slots_held):
+        with tevents.span("als:generate", devices,
+                          slots=plans[0].slots_held + plans[1].slots_held):
             stub_rows = [_stub_rows(p, n_ratings) for p in plans]
             planted = [_planted_table(p, gen, seed, s, geom.width)
                        for s, p in enumerate(plans)]
@@ -595,13 +602,22 @@ def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
                                     plans[o].static.zero_row)
                 sides.append(fn(*stubs[s], stub_rows[o], planted[o], seed))
             jax.block_until_ready(sides)
-        with tevents.span("als:heldout", pairs=meta["n_heldout"]):
+            # the sides stay; the stubs' rows and the planted tables go
+            # once the held-out pairs are drawn
+            tevents.current().fields["bytes"] = metrics.nbytes(
+                sides, stub_rows, planted)
+        with tevents.span("als:heldout", devices,
+                          pairs=meta["n_heldout"]):
             held = _heldout_pairs(gen, stub_rows, planted, seed,
                                   max(meta["n_heldout"], 1), geom.k)
             jax.block_until_ready(held)
+            tevents.current().fields["bytes"] = metrics.nbytes(held)
         del stub_rows, planted, stubs
         pieces = [_put(p.piece_slot, "ratings", mesh) for p in plans]
         arrays = _ratings_arrays(sides, pieces, held, meta, mesh)
+        # what the loader leaves on the chip (the plan's count, with the
+        # two factor tables no loader makes, is ``meta``'s and the CLI's)
+        prepare["bytes"] = metrics.nbytes(arrays)
     return arrays, meta
 
 

@@ -602,7 +602,7 @@ def build_scaled(mesh: Mesh, n_rows: int, make_rows, k: int, *,
     chunk = (1 << 16) if lanes is None else lanes.block_points
     per = chunk * mesh.shape["data"]
     held = lanes.dim_held if layout == "wide" else dim
-    with tevents.span("kmeans:prepare", rows=n_rows,
+    with tevents.span("kmeans:prepare", mesh.local_devices, rows=n_rows,
                       bytes=-(-n_rows // per) * per * held * 4,
                       **_span_fields(k, lanes)):
         ps = build_sharded(
@@ -634,7 +634,8 @@ def fit_scaled(mesh: Mesh, n_rows: int, make_rows,
     the compiled generator, not a constant in it."""
     data, valid, lanes = build_scaled(
         mesh, n_rows, make_rows, config.k, data_seed=data_seed)
-    with tevents.span("kmeans:init", init=config.init, k=config.k):
+    with tevents.span("kmeans:init", mesh.local_devices,
+                      init=config.init, k=config.k):
         centers0 = jax.block_until_ready(
             init_centers_scaled(make_rows, n_rows, config, data_seed))
     if checkpoint_dir is not None:

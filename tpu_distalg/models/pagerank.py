@@ -92,6 +92,7 @@ from tpu_distalg.parallel import (
 )
 from tpu_distalg.telemetry import events as tevents
 from tpu_distalg.telemetry import names
+from tpu_distalg.utils import metrics
 
 
 @dataclasses.dataclass(frozen=True)
@@ -536,20 +537,22 @@ def build_rmat_graph(mesh: Mesh, scale: int, edge_factor: int = 16,
             f"shards holds the graph")
     generate, dedup = rmat_programs(mesh, scale, abcd, geom, n_in)
     sharding = dict(shards=n, shard_capacity=geom.shard_cap)
-    with tevents.span("pagerank:generate", scale=scale, generated=n_in,
-                      seed=int(seed), shard_edges=[-(-n_in // n)] * n,
-                      **sharding):
+    devices = mesh.local_devices
+    with tevents.span("pagerank:generate", devices, scale=scale,
+                      generated=n_in, rows=n_in, seed=int(seed),
+                      shard_edges=[-(-n_in // n)] * n, **sharding):
         src, dst = jax.block_until_ready(
             generate(np.uint32(seed & 0xFFFFFFFF)))
+        tevents.current().fields["bytes"] = metrics.nbytes(src, dst)
     overflow, bounds = 0, None
     if n > 1:
-        with tevents.span("pagerank:exchange", bucket=geom.bucket,
-                          **sharding):
+        with tevents.span("pagerank:exchange", devices,
+                          bucket=geom.bucket, **sharding):
             src, dst, bounds, overflow = exchange_program(
                 mesh, scale, geom)(src, dst)
             overflow = int(overflow)
             tevents.current().fields.update(
-                overflow=overflow,
+                overflow=overflow, bytes=metrics.nbytes(src, dst, bounds),
                 bounds=[int(x) for x in np.asarray(bounds)])
     tevents.counter("pagerank_shard_overflow", overflow)
     if overflow:
@@ -560,13 +563,15 @@ def build_rmat_graph(mesh: Mesh, scale: int, edge_factor: int = 16,
             f"{geom.shard_cap} at SCALE {scale} on {n} shards, "
             f"ops/pallas_pagerank.SPMV_SHARD_SIGMAS); no edge is "
             f"dropped: the load fails")
-    with tevents.span("pagerank:dedup", generated=n_in, **sharding):
+    with tevents.span("pagerank:dedup", devices, generated=n_in,
+                      **sharding):
         src, dst, inv_deg, has_out, shard_edges = dedup(src, dst)
         shard_edges = [int(x) for x in
                        np.atleast_1d(np.asarray(shard_edges))]
         n_edges = sum(shard_edges)
-        tevents.current().fields.update(distinct=n_edges,
-                                        shard_edges=shard_edges)
+        tevents.current().fields.update(
+            distinct=n_edges, rows=n_edges, shard_edges=shard_edges,
+            bytes=metrics.nbytes(src, dst, inv_deg, has_out))
     tevents.counter("pagerank_shard_edges_max", max(shard_edges))
     tevents.counter("pagerank_shard_edges_mean",
                     n_edges // len(shard_edges))
@@ -644,7 +649,8 @@ def prepare_device_spmv(graph: gops.EdgeList | DeviceGraph, mesh: Mesh,
     donated to the sort."""
     from tpu_distalg.ops import pallas_pagerank as ppr
 
-    with tevents.span("pagerank:prepare"):
+    devices = mesh.local_devices
+    with tevents.span("pagerank:prepare", devices):
         sp = tevents.current().fields
         if isinstance(graph, gops.EdgeList):
             graph = device_graph(graph, mesh, rg)
@@ -656,19 +662,21 @@ def prepare_device_spmv(graph: gops.EdgeList | DeviceGraph, mesh: Mesh,
                   generated=graph.n_in, rg=geom.rg, ws=geom.ws,
                   chunks=geom.n_chunks, ranks_form=geom.ranks_form,
                   ranks_out_form=geom.ranks_out_form,
-                  shards=geom.n_shards,
+                  shards=geom.n_shards, slots=geom.n_slots,
                   scatter_passes=ppr.SCATTER_PASSES,
                   **ppr.overlap_fields(
                       geom.rg, geom.blk, geom.n_steps * geom.blk,
                       geom.seg_steps),
                   padding_share=geom.n_slots / max(graph.n_edges, 1))
         sort, lay_out = plan_programs(mesh, geom, graph.n_in)
-        with tevents.span("pagerank:plan", rg=geom.rg, ws=geom.ws):
+        with tevents.span("pagerank:plan", devices, rg=geom.rg,
+                          ws=geom.ws, slots=geom.n_slots):
             src, dst = sort(graph.src, graph.dst, graph.bounds)
             graph.src = graph.dst = None
             arrays, span = lay_out(src, dst, graph.inv_deg, graph.bounds)
             span = int(span)
-            tevents.current().fields["span"] = span
+            tevents.current().fields.update(span=span,
+                                            bytes=metrics.nbytes(arrays))
         if span > geom.ws:
             tevents.counter("spmv_plan_rejections")
             tevents.emit("spmv_span_rejected", span=span, ws=geom.ws,

@@ -1648,7 +1648,7 @@ def prepare_fused(X_train, y_train, mesh: Mesh, config: SSGDConfig):
     block = (config.gather_block_rows
              if config.sampler in ("fused_gather", "fused_train")
              else config.fused_block_rows)
-    with tevents.span("ssgd:prepare", rows=n):
+    with tevents.span("ssgd:prepare", mesh.local_devices, rows=n):
         with tevents.span("ssgd:pack", rows=n):
             X2, meta = pallas_kernels.pack_augmented(
                 np.asarray(X_train), np.asarray(y_train),
@@ -1658,8 +1658,8 @@ def prepare_fused(X_train, y_train, mesh: Mesh, config: SSGDConfig):
                 block_rows=block * n_shards,
                 shuffle_seed=config.shuffle_seed,
             )
-        with tevents.span("ssgd:h2d", rows=meta["n_padded"],
-                          bytes=int(X2.nbytes)):
+        with tevents.span("ssgd:h2d", mesh.local_devices,
+                          rows=meta["n_padded"], bytes=int(X2.nbytes)):
             X2 = partition.put(X2, "X2", "ssgd", mesh)
             X2.block_until_ready()   # the span is the copy, not its enqueue
         w0 = jnp.zeros((meta["d_total"],), jnp.float32).at[:d_orig].set(
@@ -2183,14 +2183,17 @@ def build_hashed_table(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
                 else dsets.click_field_dictionaries(cards, hash_bits))
     if indexed:
         meta["offsets"] = geom.offsets
-    with tevents.span("ssgd:prepare", rows=n_rows,
+    devices = mesh.local_devices
+    with tevents.span("ssgd:prepare", devices, rows=n_rows,
                       bytes=meta["n_padded"] * geom.row_bytes,
                       **_hashed_fields(config, meta)):
-        with tevents.span("ssgd:generate", rows=meta["n_padded"]):
+        with tevents.span("ssgd:generate", devices,
+                          rows=meta["n_padded"]):
             X = hashed_table_fn(mesh, n_rows, meta["n_padded"], geom,
                                 cards, meta["rows_kw"])(
                 jnp.int32(data_seed))
             X.block_until_ready()
+            tevents.current().fields["bytes"] = metrics.nbytes(X)
     return X, meta
 
 

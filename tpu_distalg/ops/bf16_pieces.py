@@ -17,7 +17,8 @@ and that conversion rounds where a mask truncates."""
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
+
+from tpu_distalg.ops.pallas_api import pltpu
 
 TOP = 0xFFFF0000           # the half of a float32 that is a bfloat16
 

@@ -64,8 +64,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from tpu_distalg.ops.pallas_api import pl, pltpu
 
 LANES = 128
 SUBLANES = 8

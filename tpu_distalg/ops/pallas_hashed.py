@@ -142,9 +142,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from tpu_distalg.ops.pallas_api import pl, pltpu
 from tpu_distalg.telemetry import events as tevents
 from tpu_distalg.telemetry import names
 

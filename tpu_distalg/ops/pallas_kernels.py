@@ -52,8 +52,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from tpu_distalg.ops.pallas_api import pl, pltpu
 
 
 # Weyl-sequence constant (2^32/φ, as int32) for mixing the block index

@@ -94,10 +94,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from tpu_distalg.ops.bf16_pieces import pieces as _pieces
+from tpu_distalg.ops.pallas_api import pl, pltpu
 
 LANES = 128
 SUBLANES = 8

@@ -126,10 +126,9 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from tpu_distalg.ops.bf16_pieces import split3
+from tpu_distalg.ops.pallas_api import pl, pltpu
 
 
 LANES = 128

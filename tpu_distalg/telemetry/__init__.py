@@ -7,7 +7,8 @@ needed (a 26-minute invisible backend hang): structured JSONL events
 (:mod:`heartbeat`), deadline-guarded backend init with retry/backoff
 (:mod:`supervisor`), and log summarization for humans and CI
 (:mod:`report`, ``tda report <dir>``). Every finished span is also kept
-in memory (:func:`finished`), sink or no sink.
+in memory (:func:`finished`) and every counter (:func:`counters`), sink
+or no sink.
 
 Import cost is stdlib-only (no jax) so the CLI can configure telemetry
 before the backend exists — which is exactly when it matters most.
@@ -17,6 +18,7 @@ from tpu_distalg.telemetry import events, heartbeat, report, supervisor
 from tpu_distalg.telemetry.events import (
     configure,
     counter,
+    counters,
     emit,
     enabled,
     finished,
@@ -38,6 +40,7 @@ __all__ = [
     "Heartbeat",
     "configure",
     "counter",
+    "counters",
     "emit",
     "enabled",
     "events",

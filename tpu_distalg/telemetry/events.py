@@ -53,8 +53,19 @@ Conventions:
     calling thread as ``parent``. They land in the ring and, with a
     sink, as ``span_end`` lines, so ``tda report`` nests them in its
     tree and prints its per-function table from them.
-  * counters are in-memory (thread-safe) and flushed as one
-    ``counters`` event at close; gauges/metrics are emitted inline.
+  * a span that is handed ``devices`` (a mesh's, the ones its caller
+    already holds) records what it left on the chip: the fields
+    ``hbm_in_use`` and ``hbm_peak`` (:func:`memory` at its end) and
+    ``hbm_in_use_start`` (at its start), bytes, one entry a device.
+    Loader phases and ``train:*`` spans take it; a ``jit:*`` pair and
+    anything a step never do. Absent where the backend keeps no stats
+    (the CPU). :func:`memory` is the one place in ``tpu_distalg`` that
+    asks a device for its ``memory_stats()``.
+  * counters are in memory as the spans are, sink or no sink: one
+    process-wide store (thread-safe) that :func:`counters` copies, that
+    starts empty at every :func:`configure` (a run's counts) and that a
+    sink flushes as one ``counters`` event at close; gauges/metrics are
+    emitted inline.
 
 The process-global default sink is selected by :func:`configure` (CLI
 ``--telemetry-dir``, env ``TDA_TELEMETRY_DIR``); when disabled, every
@@ -108,6 +119,9 @@ class Finished(NamedTuple):
 
 
 _FINISHED: collections.deque[Finished] = collections.deque(maxlen=RING_SIZE)
+# the run's counters, sink or no sink; _COUNT_LOCK guards every access
+_COUNT_LOCK = threading.Lock()
+_COUNTERS: dict[str, int] = {}
 
 
 class EventSink:
@@ -128,7 +142,6 @@ class EventSink:
         self.path = os.path.join(directory, f"events-{self.run_id}.jsonl")
         self._f = open(self.path, "a", buffering=1)
         self._lock = threading.Lock()
-        self._counters: dict[str, int] = {}
         self._host = socket.gethostname()
         self.closed = False
         self.write("run_start", argv=list(sys.argv))
@@ -146,17 +159,13 @@ class EventSink:
             if not self.closed:
                 self._f.write(line)
 
-    def bump(self, name: str, n: int = 1) -> None:
-        with self._lock:
-            self._counters[name] = self._counters.get(name, 0) + n
-
     def counters(self) -> dict[str, int]:
-        with self._lock:
-            return dict(self._counters)
+        """The run's counters (:func:`counters`: the store is the
+        process's, not the sink's)."""
+        return counters()
 
     def close(self) -> None:
-        counters = self.counters()
-        end = self._record("counters", {"counters": counters}) + "\n" \
+        end = self._record("counters", {"counters": counters()}) + "\n" \
             + self._record("run_end", {}) + "\n"
         with self._lock:
             if self.closed:
@@ -173,7 +182,9 @@ def configure(directory: str | None | bool = None, *,
     default). ``directory=False`` force-disables, IGNORING the env var
     — the teardown/no-really-off spelling (with the env var exported,
     ``configure(None)`` would re-enable). Replacing an active sink
-    closes it. Returns the new sink (or ``None`` when disabled)."""
+    closes it (its ``counters`` line takes the run's counts, and the
+    store starts empty again). Returns the new sink (or ``None`` when
+    disabled)."""
     global _SINK
     if directory is False:
         directory = None
@@ -183,6 +194,8 @@ def configure(directory: str | None | bool = None, *,
         old, _SINK = _SINK, None
     if old is not None:
         old.close()
+    with _COUNT_LOCK:
+        _COUNTERS.clear()
     if directory:
         sink = EventSink(directory, run_id=run_id)
         with _LOCK:
@@ -226,12 +239,35 @@ def last_mark() -> tuple[float, str]:
 
 
 def counter(name: str, n: int = 1) -> None:
-    """Increment an in-memory counter (flushed as one ``counters``
-    event at close; also snapshotted into every heartbeat)."""
-    sink = _SINK
-    if sink is None:
-        return
-    sink.bump(name, n)
+    """Increment an in-memory counter, sink or no sink (flushed as one
+    ``counters`` event where a sink closes; also snapshotted into every
+    heartbeat)."""
+    with _COUNT_LOCK:
+        _COUNTERS[name] = _COUNTERS.get(name, 0) + n
+
+
+def counters() -> dict[str, int]:
+    """A copy of the run's counters: every :func:`counter` since the
+    last :func:`configure` (or the process's start)."""
+    with _COUNT_LOCK:
+        return dict(_COUNTERS)
+
+
+def memory(devices) -> list[tuple[int, int]] | None:
+    """``(bytes_in_use, peak_bytes_in_use)`` of each of ``devices``, in
+    their order, as the backend's allocator has them now: a host-side
+    read that never waits for the device. ``devices`` are the ones the
+    caller already holds (``mesh.local_devices``): this package looks
+    none up and starts no backend. ``None`` where a device keeps no
+    stats (the CPU), and for no devices."""
+    out = []
+    for d in devices:
+        stats = d.memory_stats()
+        if not stats or "bytes_in_use" not in stats:
+            return None
+        out.append((int(stats["bytes_in_use"]),
+                    int(stats.get("peak_bytes_in_use", 0))))
+    return out or None
 
 
 def gauge(name: str, value, **fields) -> None:
@@ -252,13 +288,17 @@ def _annotation(name: str, **args):
 class OpenSpan:
     """A span that has begun; :func:`end` finishes it."""
 
-    __slots__ = ("name", "id", "parent", "t0", "fields")
+    __slots__ = ("name", "id", "parent", "t0", "fields", "devices",
+                 "hbm_start")
 
-    def __init__(self, name: str, parent: int | None, fields: dict):
+    def __init__(self, name: str, parent: int | None, fields: dict,
+                 devices=None):
         self.name = name
         self.id = next(_SPAN_IDS)
         self.parent = parent
         self.fields = fields
+        self.devices = tuple(devices) if devices is not None else ()
+        self.hbm_start = memory(self.devices) if self.devices else None
         self.t0 = time.perf_counter()
 
 
@@ -277,13 +317,14 @@ def finished() -> list[Finished]:
     return list(_FINISHED)
 
 
-def begin(name: str, **fields) -> OpenSpan:
+def begin(name: str, devices=None, **fields) -> OpenSpan:
     """Open a span on the calling thread: the half of :func:`span`
     for a caller that learns of a phase's two edges in two calls (the
     ``jax.monitoring`` listeners of ``utils/compile_cache``). ``fields``
-    may be added to until :func:`end`."""
+    may be added to until :func:`end`. With ``devices`` the span
+    samples :func:`memory` here and at its end."""
     stack = _stack()
-    sp = OpenSpan(name, stack[-1].id if stack else None, fields)
+    sp = OpenSpan(name, stack[-1].id if stack else None, fields, devices)
     stack.append(sp)
     sink = _SINK
     if sink is not None:
@@ -296,6 +337,11 @@ def end(sp: OpenSpan, error: str | None = None) -> Finished:
     """Finish a span opened by :func:`begin` on this thread: into the
     ring, and as a ``span_end`` line where a sink is on."""
     seconds = time.perf_counter() - sp.t0
+    held = memory(sp.devices) if sp.hbm_start else None
+    if held:
+        sp.fields.update(
+            hbm_in_use=[b for b, _ in held], hbm_peak=[p for _, p in held],
+            hbm_in_use_start=[b for b, _ in sp.hbm_start])
     stack = _stack()
     if sp in stack:
         stack.remove(sp)          # the top, unless an inner one leaked
@@ -317,16 +363,17 @@ def end(sp: OpenSpan, error: str | None = None) -> Finished:
 
 
 @contextlib.contextmanager
-def span(name: str, **fields):
+def span(name: str, devices=None, **fields):
     """Timed phase: ``span_start``/``span_end`` (+duration, +error on
     failure) around the body, with a progress mark at both edges, the
     same interval as ``tda:<name>`` in a profiler trace, and a
     :class:`Finished` entry in the ring whether or not anything else
     listens. ``id`` and ``parent`` (the span open on this thread when
-    this one began, or ``None``) ride in all three. A phase boundary,
-    never a per-step call."""
+    this one began, or ``None``) ride in all three. With ``devices`` it
+    ends with ``hbm_in_use``, ``hbm_peak`` and ``hbm_in_use_start``
+    (:func:`memory`). A phase boundary, never a per-step call."""
     mark(name, emit_event=False)
-    sp = begin(name, **fields)
+    sp = begin(name, devices, **fields)
     note = _annotation(name, id=sp.id, parent=sp.parent or 0)
     err = None
     try:

@@ -69,10 +69,9 @@ class Heartbeat(threading.Thread):
         directly with an injected ``now``)."""
         t_mark, phase = events.last_mark()
         age = self._now() - t_mark
-        sink = events.get_sink()
         self._emit("heartbeat", phase=phase,
                    seconds_since_mark=round(age, 3),
-                   counters=sink.counters() if sink is not None else {})
+                   counters=events.counters())
         self.n_beats += 1
         if (self.stall_after is not None and age > self.stall_after
                 and self._flagged_mark != t_mark):

@@ -60,9 +60,14 @@ def span_tree(evts: list[dict]) -> list[dict]:
     count, total and largest duration, and ``self_seconds`` = total
     minus what its direct child spans cover. A span's parent is the
     ``parent`` id it recorded (the span open on its thread when it
-    began); spans of logs older than the ids are roots."""
+    began); spans of logs older than the ids are roots. A node whose
+    spans sampled the devices' memory (``events.memory``: the fullest
+    device's numbers here) also has ``hbm_rise_bytes`` (in use at a
+    span's end less at its start, added up), ``hbm_in_use_bytes`` (at
+    its last span's end) and ``hbm_set_peak``: true on the one node
+    under which the run's peak last rose."""
     names: dict[tuple, tuple] = {}     # (run, id) -> (name, parent key)
-    ended: list[tuple] = []            # (key, seconds, ok)
+    ended: list[tuple] = []            # (key, seconds, ok, span_end)
     for n, e in enumerate(evts):
         if e.get("ev") not in ("span_start", "span_end"):
             continue
@@ -73,7 +78,7 @@ def span_tree(evts: list[dict]) -> list[dict]:
                       (run, parent) if parent is not None else None)
         if e["ev"] == "span_end":
             ended.append((key, float(e.get("seconds", 0.0)),
-                          bool(e.get("ok", True))))
+                          bool(e.get("ok", True)), e))
 
     def path_of(key) -> tuple:
         out, seen = [], set()
@@ -84,7 +89,8 @@ def span_tree(evts: list[dict]) -> list[dict]:
         return tuple(reversed(out))
 
     nodes: dict[tuple, dict] = {}
-    for key, seconds, ok in ended:
+    peak_run, peak, peak_path = None, 0, None
+    for key, seconds, ok, e in ended:
         path = path_of(key)
         for depth in range(1, len(path) + 1):   # ancestors first, so an
             nodes.setdefault(path[:depth], {    # open parent has a node
@@ -98,6 +104,22 @@ def span_tree(evts: list[dict]) -> list[dict]:
         node["errors"] += not ok
         if len(path) > 1:
             nodes[path[:-1]]["child_seconds"] += seconds
+        if e.get("hbm_in_use"):
+            start = max(e.get("hbm_in_use_start") or [0])
+            node["hbm_rise_bytes"] = node.get("hbm_rise_bytes", 0) \
+                + max(e["hbm_in_use"]) - start
+            node["hbm_in_use_bytes"] = max(e["hbm_in_use"])
+            if key[0] != peak_run:          # a new process, a new peak
+                peak_run, peak, peak_path = key[0], 0, None
+            # the peak rose under this span if it stands over what any
+            # span that ended before it saw and over what was in use
+            # when it began (children end first: the innermost is told)
+            top = max(e.get("hbm_peak") or [0])
+            if top > max(peak, start):
+                peak_path = path
+            peak = max(peak, top)
+    if peak_path is not None:
+        nodes[peak_path]["hbm_set_peak"] = True
     first = {path: n for n, path in enumerate(nodes)}
     out = []
     for path in sorted(nodes, key=lambda p: [
@@ -150,6 +172,24 @@ def jit_functions(evts: list[dict]) -> list[dict]:
         elif column == "compile_s" and "hit" in e:
             row["hits" if e["hit"] else "misses"] += 1
     return sorted(rows.values(), key=lambda r: -_jit_total(r))
+
+
+def hbm_summary(evts: list[dict]) -> dict | None:
+    """The newest run's device memory as its spans sampled it (the
+    fullest device): the largest ``hbm_peak`` any span ended with, what
+    was in use at the last sampled span's end, and how many devices a
+    sample covered (:func:`summarize` adds ``set_under``, the path of
+    the tree's ``hbm_set_peak`` node); ``None`` where no span sampled
+    (the CPU, a log from before the fields)."""
+    got = [e for e in evts if e.get("ev") == "span_end"
+           and e.get("hbm_in_use")]
+    if not got:
+        return None
+    got = [e for e in got if e.get("run") == got[-1].get("run")]
+    return {"peak_bytes": max(max(e.get("hbm_peak") or [0]) for e in got),
+            "in_use_bytes": max(got[-1]["hbm_in_use"]),
+            "last_span": got[-1].get("name", "?"),
+            "devices": len(got[-1]["hbm_in_use"])}
 
 
 def _jit_total(row: dict) -> float:
@@ -348,13 +388,18 @@ def summarize(evts: list[dict]) -> dict:
         elif ev == "counters":
             for k, v in (e.get("counters") or {}).items():
                 counters[k] = counters.get(k, 0) + int(v)
+    tree, hbm = span_tree(evts), hbm_summary(evts)
+    if hbm:
+        hbm["set_under"] = next(
+            (p["path"] for p in tree if p.get("hbm_set_peak")), None)
     return {
         "runs": runs,
         "n_events": len(evts),
         "wall_seconds": (round(max(t_wall) - min(t_wall), 3)
                          if t_wall else 0.0),
         "phases": phases,
-        "span_tree": span_tree(evts),
+        "span_tree": tree,
+        "hbm": hbm,
         "jit_functions": jit_functions(evts),
         "draw_forms": draw_forms,
         "sums_forms": sums_forms,
@@ -431,12 +476,31 @@ def render(s: dict) -> str:
     if s["span_tree"]:
         lines.append("phase durations (self = duration minus child "
                      "spans):")
+        if s.get("hbm"):
+            lines.append("  (hbm: the rise of the bytes in use over the "
+                         "span -> in use at its end; *peak where the "
+                         "peak last rose)")
         for p in s["span_tree"]:
             err = f"  errors: {p['errors']}" if p["errors"] else ""
+            hbm = ""
+            if "hbm_in_use_bytes" in p:
+                hbm = (f", hbm {p['hbm_rise_bytes'] / 1e9:+.3f} GB -> "
+                       f"{p['hbm_in_use_bytes'] / 1e9:.3f} GB"
+                       + (" *peak" if p.get("hbm_set_peak") else ""))
             lines.append(
                 f"  {'  ' * p['depth']}{p['name']}: "
                 f"{p['total_seconds']}s total over {p['count']} span(s), "
-                f"max {p['max_seconds']}s, self {p['self_seconds']}s{err}")
+                f"max {p['max_seconds']}s, self {p['self_seconds']}s"
+                f"{hbm}{err}")
+        hbm = s.get("hbm")
+        if hbm:
+            lines.append(
+                f"hbm: peak {hbm['peak_bytes'] / 1e9:.3f} GB set under "
+                f"{' > '.join(hbm['set_under'] or ['no span'])}, "
+                f"{hbm['in_use_bytes'] / 1e9:.3f} GB in use at "
+                f"the last span's end ({hbm['last_span']})"
+                + (f"; the fullest of {hbm['devices']} devices"
+                   if hbm["devices"] > 1 else ""))
     for name in s["unfinished_phases"]:
         lines.append(f"  {name}: UNFINISHED (no span_end recorded)")
     lines.extend(_render_jit_functions(s.get("jit_functions") or []))

@@ -361,6 +361,10 @@ def run_segmented(
 
     from tpu_distalg.utils import metrics
 
+    # this process's devices the state lies on (no mesh reaches this
+    # loop): the train:* spans sample their memory at both ends
+    devices = sorted({s.device for x in leaves0 if isinstance(x, jax.Array)
+                      for s in x.addressable_shards}, key=lambda d: d.id)
     seg_fns = {}
     t = start
     while t < n_iterations:
@@ -375,11 +379,11 @@ def run_segmented(
         # mute
         with contextlib.ExitStack() as build:
             if seg not in seg_fns:
-                build.enter_context(
-                    tevents.span("train:build", tag=tag, seg=seg))
+                build.enter_context(tevents.span(
+                    "train:build", devices, tag=tag, seg=seg))
                 seg_fns[seg] = make_seg_fn(seg)
-            with tevents.span("train:segment", tag=tag, t0=t, steps=seg,
-                              **(span_fields or {})):
+            with tevents.span("train:segment", devices, tag=tag, t0=t,
+                              steps=seg, **(span_fields or {})):
                 faults.inject("segment:run")
                 state, accs = run_seg(seg_fns[seg], state, t)
                 metrics.guard_finite(
@@ -387,9 +391,9 @@ def run_segmented(
                 )
         t += seg
         # what the checkpoint holds, sized without fetching it
-        held = sum(int(getattr(x, "nbytes", 0)) for x in
-                   [*jax.tree.leaves(state), *accs_parts, accs])
-        with tevents.span("train:checkpoint", step=t, bytes=held):
+        held = metrics.nbytes(state, accs_parts, accs)
+        with tevents.span("train:checkpoint", devices, step=t,
+                          bytes=held):
             accs_parts.append(np.asarray(accs))
             save(
                 checkpoint_dir,

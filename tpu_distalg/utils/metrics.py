@@ -41,6 +41,15 @@ def guard_finite(tree, context: str):
     return tree
 
 
+def nbytes(*trees) -> int:
+    """The bytes the arrays of ``trees`` hold by their own ``nbytes``
+    (all shards' together; a leaf without one counts 0): what a loader
+    phase's span says it made, and what a checkpoint holds, sized
+    without fetching anything."""
+    return sum(int(getattr(a, "nbytes", 0))
+               for a in jax.tree.leaves(trees))
+
+
 def ewma(values: np.ndarray, alpha: float = 0.9) -> np.ndarray:
     """EWMA with the reference's recurrence s[t] = α·s[t-1] + (1-α)·v[t],
     s[0] = v[0] (``ssgd.py:51-59``)."""
