@@ -984,7 +984,8 @@ def stage_als_sparse(s: Smoke):
     return (f"dp={mesh.shape['data']} | blocks a side {meta['blocks']} | "
             f"slots held / ratings {meta['padding_share']:.3f} | "
             f"gather {form}, resident share "
-            f"{meta['gather_resident_share']:.4f} | solve: {solve} | "
+            f"{meta['gather_resident_share']:.4f} | gramians by "
+            f"{meta['forms']['als_gram_layout']}, solve: {solve} | "
             f"{len(own)} owners against the reference {err:.2g} | "
             f"held-out RMSE {held[0]:.3f} -> {held[-1]:.3f}")
 

@@ -636,9 +636,9 @@ def main(argv) -> int:
             ok = (a["idx"].reshape(-1) != st.zero_row).astype(
                 jnp.float32)[:, None]
             G = form_gather("i", T, heavy0, a, k=k, interpret=interp)
-            return als_sparse.to_lanes(product(jnp.where(
+            return product(jnp.where(
                 lane == k, a["val"].reshape(-1, 1),
-                jnp.where(lane == k + 1, ok, G))))
+                jnp.where(lane == k + 1, ok, G)))
 
         def gram_ships(M, a):
             return als_sparse.block_gramians(

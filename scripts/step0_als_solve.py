@@ -1,42 +1,60 @@
 #!/usr/bin/env python3
 """Step 0 of sparse ALS' Mosaic solve: what a batch of 6144
 normal-equation systems at rank 100 costs in each form, on the chip at
-the ``als100_253m_sweep1`` cell's shape (extended Gramians ``(128, 128,
-6144)`` float32 drawn like the cell's: rows of eighths, ratings 0 to
-100, ``lam n_u`` on the diagonal), every form inside ONE program over
-eight blocks (ms a block: a form timed on one block alone reads its
-dispatch too, PR 37's lesson; the kernels take the eight as one batch
-of 49 152, XLA's form in a scan). Kept as the way to re-read
-``SOLVE_VMEM_BYTES`` in ``tpu_distalg/ops/als_sparse.py`` (the tile
-``solve_plan`` admits: ``tile128`` against ``tile1024`` and ``xla``):
+the ``als100_253m_sweep1`` cell's shape (extended Gramians ``(6144, 128,
+128)`` float32, owner-major as the product makes them, drawn like the
+cell's: rows of eighths, ratings 0 to 100, ``lam n_u`` on the diagonal),
+every form inside ONE program over eight blocks (ms a block: a form
+timed on one block alone reads its dispatch too, PR 37's lesson; the
+kernels take the eight as one batch of 49 152, XLA's form in a scan).
+Kept as the way to re-read ``SOLVE_VMEM_BYTES`` in
+``tpu_distalg/ops/als_sparse.py`` (the tile ``solve_plan`` admits) and
+the layout the batch travels in (``owners`` against ``tile128+turn``):
 
-    chiprun -- python3 scripts/step0_als_solve.py
+    chiprun -- python3 scripts/step0_als_solve.py [form ...]
     JAX_PLATFORMS=cpu python3 scripts/step0_als_solve.py --rehearse
     JAX_PLATFORMS=cpu python3 scripts/step0_als_solve.py --bundles
 
 Rows of the output, one a line as ``[step0] <form> <ms a block> ...``:
 
-  copies    (i) the shipped kernel's blocks and nothing else: a tile of
-            ``(104, 104, 128)`` read, ``(104, 128)`` written (the floor)
-  tile128   (ii) ``pallas_als.solve_lanes``, what ships: 128 systems a
-            tile, a vector holds 8 rows of one column
-  tile1024  (iii) ``Ap`` viewed as ``(128, 128, 6, 8, 128)`` and the
-            kernel below on it: 1024 systems a tile, a matrix entry one
-            whole vector, every step a plain elementwise operation,
-            nothing spread along sublanes. The view is not free: the
-            tiles of ``(8, 128)`` change from (8 rows, 128 systems) to
-            (8 groups of systems, 128), so XLA copies the batch
-  wide1024  the same kernel on blocks viewed before the program began:
-            (iii) less its view
-  xla       (v) ``als_sparse.solve_batch`` in XLA's form
+  copies        (i) the shipped kernel's blocks and nothing else: a tile
+                of ``(128, 104, 128)`` owners' rows read, two of ``(104,
+                128)`` written (the floor)
+  owners        (ii) ``pallas_als.solve_lanes``, what ships since PR 50:
+                128 systems a tile read owner-major and turned to lanes
+                in VMEM (a sublane-strided load and a transpose a
+                column), a vector 8 rows of one column
+  owners+quad   the same with ``x^T A x`` taken from the block in the
+                kernel (a second turn of every column): what PR 50
+                weighed against ``quad.xla`` and did not ship
+  quad.xla      the error's quadratic form as ``solve_batch`` takes it,
+                a product of the owner-major batch and a sum down its
+                second dimension (given ``x``; no solve)
+  tile128       what shipped from PR 41 to PR 49: the same
+                factorisation over blocks ``(104, 104, 128)`` of a batch
+                held along the lanes, ``(128, 128, batch)``
+  tile128+turn  the same fed the owner-major batch through the copy it
+                needs (XLA's transpose in HBM in front of the call)
+  tile1024      (iii) the lanes batch viewed as ``(128, 128, 6, 8,
+                128)`` and the kernel below on it: 1024 systems a tile,
+                a matrix entry one whole vector, every step a plain
+                elementwise operation, nothing spread along sublanes.
+                The view is not free: the tiles of ``(8, 128)`` change
+                from (8 rows, 128 systems) to (8 groups of systems,
+                128), so XLA copies the batch
+  wide1024      the same kernel on blocks viewed before the program
+                began: (iii) less its view
+  xla           (v) ``als_sparse.solve_batch`` in XLA's form (it turns
+                the batch to lanes itself)
 
-(iv), the panel loop rolled and the diagonal block unrolled, is how both
+(iv), the panel loop rolled and the diagonal block unrolled, is how all
 kernels are written. Each form's rows are compared with XLA's and with a
-float64 solve of 256 of the systems. ``--bundles`` compiles both kernels
-for a described v5e with libtpu's dump in a temporary directory and
-prints each loop of the static schedule with its trips a tile, the
-bundles a system that makes, and the compile's seconds. A summary lands
-in ``chiprun_out/step0_als_solve.json``.
+float64 solve of 256 of the systems, and ``tile128``'s with ``owners``'
+bit for bit. ``--bundles`` compiles the kernels for a described v5e with
+libtpu's dump in a temporary directory and prints each loop of the
+static schedule with its trips a tile, the bundles a system that makes,
+and the compile's seconds. A summary lands in
+``chiprun_out/step0_als_solve.json``.
 """
 
 from __future__ import annotations
@@ -236,6 +254,146 @@ def solve_wide(Ap5, k: int, lam: float, interpret: bool = False):
     return x.reshape(n, tiles * WIDE)
 
 
+# ---- the error's quadratic form inside the kernel (weighed, not shipped) ---
+
+def _quad_kernel(a_ref, x_ref, b_ref, q_ref, m_ref, p_ref, *, k: int,
+                 lam: float):
+    """The shipped kernel, then ``x^T A x`` of the tile's systems from
+    the block as it came in (it is still whole in VMEM; ``x`` is zero
+    from ``k`` on): a second turn of every column, a system a lane."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from tpu_distalg.ops import pallas_als
+
+    pallas_als._solve_build(a_ref, b_ref, m_ref, k=k, lam=lam)
+    pallas_als._solve_factor(x_ref, m_ref, p_ref)
+    n, w = x_ref.shape[0], 8
+    column = pallas_als._column_of(a_ref)
+    xs = [x_ref[i * w:(i + 1) * w, :] for i in range(n // w)]
+
+    def quad(ct, acc):
+        c0 = pl.multiple_of(ct * w, w)
+        for u in range(w):
+            col = column(c0 + u)
+            s = col[:w, :] * xs[0]
+            for i in range(1, n // w):
+                s = s + col[i * w:(i + 1) * w, :] * xs[i]
+            acc = acc + x_ref[pl.ds(c0 + u, 1), :] * jnp.sum(
+                s, axis=0, keepdims=True)
+        return acc
+
+    q_ref[...] = jax.lax.fori_loop(0, n // w, quad,
+                                   jnp.zeros((1, 128), jnp.float32))
+
+
+def solve_with_quad(Ap, k: int, lam: float, interpret: bool = False):
+    """``pallas_als.solve_lanes`` with a third result, ``x^T A x`` ``(1,
+    batch)``."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_distalg.ops import pallas_als
+
+    n, ext = pallas_als._solve_sizes(k)
+    batch, width, _ = Ap.shape
+
+    def out(rows):
+        return (pl.BlockSpec((rows, 128), lambda i: (0, i)),
+                jax.ShapeDtypeStruct((rows, batch), Ap.dtype))
+
+    specs, shapes = zip(out(n), out(n), out(1))
+    return pl.pallas_call(
+        functools.partial(_quad_kernel, k=k, lam=lam),
+        name="_als_solve_quad_kernel",
+        grid=(batch // 128,),
+        in_specs=[pl.BlockSpec((128, ext, width), lambda i: (i, 0, 0))],
+        out_specs=list(specs), out_shape=list(shapes),
+        scratch_shapes=[pltpu.VMEM((n, n + 16, 128), Ap.dtype),
+                        pltpu.VMEM((8, n + 16, 128), Ap.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=pallas_als.solve_tile_bytes(k) + (8 << 20)),
+        interpret=interpret,
+    )(Ap)
+
+
+# ---- what shipped from PR 41 to PR 49: blocks of a batch along the lanes --
+
+def _lanes_kernel(a_ref, x_ref, m_ref, p_ref, *, k: int, lam: float):
+    """``a_ref`` ``(ext, ext, 128)``, a system a lane: the matrix built
+    entry by entry from a block that already lies as the factorisation
+    wants it, then the shipped kernel's factorisation."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from tpu_distalg.ops import pallas_als
+
+    n = x_ref.shape[0]
+    w = 8
+    n_tiles = n // w
+    f32 = jnp.float32
+    sub = jax.lax.broadcasted_iota(jnp.int32, (w, 128), 0)
+    rows8, tile = pallas_als._rows8, pallas_als._tile
+    cnt = a_ref[k + 1, pl.ds(k + 1, 1), :]
+    ridge = rows8(jnp.where(cnt > 0, f32(lam) * cnt, f32(1.0)))
+
+    def build(ct, carry):
+        c0 = pl.multiple_of(ct * w, w)
+        live = [c0 + u < k for u in range(w)]
+        for u in range(w):      # the diagonal block, with the ridge
+            v = jnp.where((sub + c0 < k) & live[u],
+                          a_ref[c0 + u, tile(ct), :], f32(0.0))
+            m_ref[c0 + u, tile(ct), :] = v + jnp.where(
+                sub == u, jnp.where(live[u], ridge, f32(1.0)), f32(0.0))
+            b = rows8(a_ref[c0 + u, pl.ds(k, 1), :])
+            m_ref[c0 + u, pl.ds(n, w), :] = jnp.where(
+                (sub == 0) & live[u], b, f32(0.0))
+
+        def one(i, carry):
+            keep = sub + i * w < k
+            for u in range(w):
+                m_ref[c0 + u, tile(i), :] = jnp.where(
+                    keep & live[u], a_ref[c0 + u, tile(i), :], f32(0.0))
+            return carry
+
+        jax.lax.fori_loop(ct + 1, n_tiles, one, 0)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, build, 0)
+    pallas_als._solve_factor(x_ref, m_ref, p_ref)
+
+
+def solve_from_lanes(Ap, k: int, lam: float, interpret: bool = False):
+    """(ii) as it was: ``Ap`` ``(width, width, batch)`` -> ``(round_up(k,
+    8), batch)``."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_distalg.ops import pallas_als
+
+    n, ext = pallas_als._solve_sizes(k)
+    batch = Ap.shape[2]
+    return pl.pallas_call(
+        functools.partial(_lanes_kernel, k=k, lam=lam),
+        name="_als_solve_lanes_kernel",
+        grid=(batch // 128,),
+        in_specs=[pl.BlockSpec((ext, ext, 128), lambda i: (0, 0, i))],
+        out_specs=pl.BlockSpec((n, 128), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((n, batch), Ap.dtype),
+        scratch_shapes=[pltpu.VMEM((n, n + 16, 128), Ap.dtype),
+                        pltpu.VMEM((8, n + 16, 128), Ap.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=pallas_als.solve_tile_bytes(k) + (8 << 20)),
+        interpret=interpret,
+    )(Ap)
+
+
 def copies(Ap, k: int):
     """(i): the shipped kernel's blocks moved and nothing computed."""
     import jax
@@ -244,20 +402,22 @@ def copies(Ap, k: int):
 
     from tpu_distalg.ops import pallas_als
 
-    n, ext = -(-k // 8) * 8, -(-(k + 2) // 8) * 8
+    n, ext = pallas_als._solve_sizes(k)
+    batch, width, _ = Ap.shape
 
-    def body(a_ref, x_ref):
-        x_ref[...] = a_ref[0, pl.ds(0, n), :]
+    def body(a_ref, x_ref, b_ref):
+        x_ref[...] = a_ref[pl.ds(0, n), 0, :]
+        b_ref[...] = a_ref[pl.ds(0, n), 1, :]
 
     return pl.pallas_call(
-        body, name="_als_solve_copies", grid=(Ap.shape[2] // 128,),
-        in_specs=[pl.BlockSpec((ext, ext, 128), lambda i: (0, 0, i))],
-        out_specs=pl.BlockSpec((n, 128), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, Ap.shape[2]), Ap.dtype),
+        body, name="_als_solve_copies", grid=(batch // 128,),
+        in_specs=[pl.BlockSpec((128, ext, width), lambda i: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((n, 128), lambda i: (0, i))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((n, batch), Ap.dtype)] * 2,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=pallas_als.solve_tile_bytes(k) + (8 << 20)),
-    )(Ap)
+    )(Ap)[0]
 
 
 # ---- the static schedule, without the chip -------------------------------
@@ -267,10 +427,15 @@ def trips(form: str, k: int) -> dict:
     nest (a loop's children in the order the schedule lists them)."""
     n, w = -(-k // 8) * 8, 8
     T, RT = n // w, n // w + 1
-    if form == "tile128":
+    if form in ("owners", "owners+quad", "tile128"):
         two = sum(-(-(RT - jt) // 2) for p in range(T)
                   for jt in range(p + 1, T))
-        return {"build": T, "build.rows": T * (T - 1) // 2,
+        # the owner-major build stores every row tile of a column: no
+        # loop inside it
+        build = {"build": T, "build.rows": T * (T - 1) // 2} \
+            if form == "tile128" else {"build": T}
+        quad = {"quad": T} if form == "owners+quad" else {}
+        return {**build, **quad,
                 "factor": T,
                 "factor.below": sum((RT - p) // 2 for p in range(T)),
                 "factor.trailing": T * (T - 1) // 2,
@@ -301,10 +466,14 @@ def compile_one(form: str, k: int):
     one = SingleDeviceSharding(topo.devices[0])
     W = -(-(k + 2) // 128) * 128
     t0 = time.perf_counter()
-    if form == "tile128":
-        Ap = jax.ShapeDtypeStruct((W, W, BATCH), jnp.float32, sharding=one)
-        jax.jit(lambda a: pallas_als.solve_lanes(a, k, LAM)).lower(
+    if form in ("owners", "owners+quad"):
+        Ap = jax.ShapeDtypeStruct((BATCH, W, W), jnp.float32, sharding=one)
+        jax.jit(lambda a: (solve_with_quad if form == "owners+quad"
+                           else pallas_als.solve_lanes)(a, k, LAM)).lower(
             Ap).compile()
+    elif form == "tile128":
+        Ap = jax.ShapeDtypeStruct((W, W, BATCH), jnp.float32, sharding=one)
+        jax.jit(lambda a: solve_from_lanes(a, k, LAM)).lower(Ap).compile()
     else:
         Ap = jax.ShapeDtypeStruct((W, W, BATCH // WIDE, 8, 128),
                                   jnp.float32, sharding=one)
@@ -345,10 +514,12 @@ def read_loops(dump: str, kernel: str):
 
 
 def bundles(forms, k: int):
-    names = {"tile128": ("_als_solve_kernel",
-                         ["build", ["rows"], "factor",
-                          ["below", "trailing", ["two"]],
-                          "backward", ["dots"]]),
+    factor = ["factor", ["below", "trailing", ["two"]], "backward", ["dots"]]
+    names = {"owners": ("_als_solve_kernel", ["build", *factor]),
+             "owners+quad": ("_als_solve_quad_kernel",
+                             ["build", *factor, "quad"]),
+             "tile128": ("_als_solve_lanes_kernel",
+                         ["build", ["rows"], *factor]),
              "tile1024": ("_als_solve_wide_kernel",
                           ["build", ["rows"], "factor",
                            ["below", "columns", ["rows"]],
@@ -393,7 +564,7 @@ def bundles(forms, k: int):
         per = trips(form, k)
         body = hi - lo + 1 - sum(h - l + 1 for l, h, _ in top)
         total = body + sum(own * per[name] for name, own in named)
-        systems = 128 if form == "tile128" else WIDE
+        systems = WIDE if form == "tile1024" else 128
         out[form] = dict(bundles_a_tile=total, systems=systems,
                          bundles_a_system=total / systems,
                          compile_s=float(took.group(1)) if took else None,
@@ -410,8 +581,8 @@ def bundles(forms, k: int):
 # ---- on the chip (or interpreted) -----------------------------------------
 
 def draw_blocks(k: int, batch: int, blocks: int, slots: int, width: int):
-    """``blocks`` batches of extended Gramians ``(blocks, width, width,
-    batch)`` on the device, and the ratings counted a system."""
+    """``blocks`` batches of extended Gramians ``(blocks, batch, width,
+    width)`` on the device, owner-major as the product makes them."""
     import jax
     import jax.numpy as jnp
 
@@ -427,9 +598,8 @@ def draw_blocks(k: int, batch: int, blocks: int, slots: int, width: int):
                       jnp.where(lane == k + 1, 1.0,
                                 jnp.where(lane < k, G, 0.0)))
         G = G * ok[..., None]
-        return jnp.transpose(jnp.einsum(
-            "osd,ose->ode", G, G, precision=jax.lax.Precision.HIGHEST),
-            (1, 2, 0))
+        return jnp.einsum("osd,ose->ode", G, G,
+                          precision=jax.lax.Precision.HIGHEST)
 
     return jax.jit(lambda keys: jax.lax.map(one, keys))(
         jax.random.split(jax.random.PRNGKey(41), blocks))
@@ -442,7 +612,8 @@ def main(argv) -> int:
                     int(argv[argv.index("--compile-one") + 2]))
         return 0
     if "--bundles" in flags:
-        bundles(["tile128", "tile1024"], K)
+        bundles([a for a in argv if not a.startswith("--")]
+                or ["owners", "owners+quad", "tile128", "tile1024"], K)
         return 0
 
     import jax
@@ -462,6 +633,8 @@ def main(argv) -> int:
     Aps = draw_blocks(k, batch, blocks, slots, geom.width)
     jax.block_until_ready(Aps)
 
+    W = geom.width
+
     def xla(Aps):
         def one(c, Ap):
             x = als_sparse.solve_batch(Ap, LAM, geom)[0][:, :k].T
@@ -469,56 +642,94 @@ def main(argv) -> int:
         xs = jax.lax.scan(one, 0, Aps)[1]           # (blocks, k, batch)
         return jnp.moveaxis(xs, 0, 1).reshape(k, -1)
 
-    # the Mosaic forms take the blocks as one batch, block after block
-    # along the lanes: a scan's slice of a block would be a copy of
-    # 400 MB a step in front of each call (1.1 ms, read once as the
-    # floor: call 2), which the trainer's step does not make
+    def quad_xla(A, x):
+        rows = x.T
+        Ax = jnp.sum(A[:, :k, :k] * rows[:, :, None], axis=1)
+        return jnp.sum(rows * Ax, axis=1)[None, :]
+
+    # the Mosaic forms take the blocks as one batch, block after block:
+    # a scan's slice of a block would be a copy of 400 MB a step in
+    # front of each call (1.1 ms, read once as the floor: PR 41's call
+    # 2), which the trainer's step does not make. Each form is handed
+    # its input as the program would hand it over: ``owners`` the
+    # product's own layout, the lanes forms a batch turned beforehand,
+    # ``tile128+turn`` the owner-major batch and the turn inside
+    to_lanes = als_sparse.to_lanes
+
     forms = {
-        "xla": xla,
-        "tile128": lambda A: pallas_als.solve_lanes(
-            A, k, LAM, interpret=interp)[:k],
-        "tile1024": lambda A: solve_wide(
-            wide_view(A), k, LAM, interpret=interp)[:k],
-        "wide1024": lambda A5: solve_wide(
-            A5, k, LAM, interpret=interp)[:k],
+        "xla": (xla, "blocks"),
+        "copies": (lambda A: copies(A, k)[:k], "owners"),
+        "owners": (lambda A: pallas_als.solve_lanes(
+            A, k, LAM, interpret=interp)[0][:k], "owners"),
+        "owners+quad": (lambda A: solve_with_quad(
+            A, k, LAM, interp)[2], "owners"),
+        "quad.xla": (quad_xla, "owners+x"),
+        "tile128+turn": (lambda A: solve_from_lanes(
+            to_lanes(A), k, LAM, interp)[:k], "owners"),
+        "tile128": (lambda A: solve_from_lanes(
+            A, k, LAM, interp)[:k], "lanes"),
+        "tile1024": (lambda A: solve_wide(
+            wide_view(A), k, LAM, interpret=interp)[:k], "lanes"),
+        "wide1024": (lambda A5: solve_wide(
+            A5, k, LAM, interpret=interp)[:k], "wide"),
     }
-    if not interp:
-        forms["copies"] = lambda A: copies(A, k)[:k]
+    if interp:
+        del forms["copies"]
+    asked = [a for a in argv if not a.startswith("--")]
     out: dict = {"k": k, "batch": batch, "blocks": blocks}
 
     # a float64 solve of the first block's first 256 systems
-    A0 = np.asarray(Aps[0][:, :, :256], np.float64)
-    cnt = A0[k + 1, k + 1]
+    A0 = np.asarray(Aps[0][:256], np.float64)
+    cnt = A0[:, k + 1, k + 1]
     want = np.stack([np.linalg.solve(
-        A0[:k, :k, i] + (LAM * cnt[i] if cnt[i] else 1.0) * np.eye(k),
-        A0[:k, k, i]) for i in range(256)]).T
-    base = None
-    args = {"xla": Aps}
-    for name, fn in forms.items():
-        if name not in args and "long" not in args:
-            W = geom.width
-            args["long"] = jax.jit(lambda a: jnp.moveaxis(
-                a, 0, 2).reshape(W, W, -1))(Aps)
-            args["xla"] = Aps = None                 # 3.2 GB back
-        if name == "wide1024":
-            args[name] = jax.jit(wide_view)(args["long"])
-        arg = args.get(name, args.get("long"))
+        A0[i, :k, :k] + (LAM * cnt[i] if cnt[i] else 1.0) * np.eye(k),
+        A0[i, :k, k]) for i in range(256)]).T
+    base = xs_owners = None
+    held: dict = {"blocks": Aps}
+
+    def arg_of(kind):
+        """A form's input, made when first asked for; the blocks'
+        3.2 GB go once the batch is one array."""
+        if kind not in held:
+            if "owners" not in held:
+                held["owners"] = jax.jit(
+                    lambda a: a.reshape(-1, W, W))(held.pop("blocks"))
+            if kind == "owners+x":
+                held[kind] = (held["owners"], jnp.asarray(xs_owners))
+            elif kind in ("lanes", "wide"):
+                if "lanes" not in held:
+                    held["lanes"] = jax.jit(to_lanes)(held["owners"])
+                if kind == "wide":
+                    held["wide"] = jax.jit(wide_view)(held["lanes"])
+        a = held[kind]
+        return a if isinstance(a, tuple) else (a,)
+
+    for name, (fn, kind) in forms.items():
+        if asked and name not in asked and name not in ("xla", "owners"):
+            continue
+        arg = arg_of(kind)
         t0 = time.perf_counter()
-        done = jax.jit(fn).lower(arg).compile()
+        done = jax.jit(fn).lower(*arg).compile()
         took = time.perf_counter() - t0
-        xs = jax.block_until_ready(done(arg))
+        xs = jax.block_until_ready(done(*arg))
         best = float("inf")
         for _ in range(3):
             t0 = time.perf_counter()
-            jax.block_until_ready(done(arg))
+            jax.block_until_ready(done(*arg))
             best = min(best, time.perf_counter() - t0)
         ms = best * 1e3 / blocks
         row = dict(ms_a_block=ms, us_a_system=ms * 1e3 / batch,
                    compile_s=took)
-        text = (f"[step0] {name:9s} {ms:9.4f} ms a block  "
+        text = (f"[step0] {name:12s} {ms:9.4f} ms a block  "
                 f"{ms * 1e3 / batch:7.4f} us a system  "
                 f"(compiled in {took:.1f} s)")
-        if name != "copies":
+        if name in ("owners+quad", "quad.xla"):
+            x0 = np.asarray(xs_owners[:, :256], np.float64)
+            q = np.einsum("do,ode,eo->o", x0, A0[:, :k, :k], x0)
+            row["rel_err_f64"] = float(
+                np.abs(np.asarray(xs)[0, :256] - q).max() / np.abs(q).max())
+            text += f"  x^T A x against float64 {row['rel_err_f64']:.3g}"
+        elif name != "copies":
             x0 = np.asarray(xs[:, :256], np.float64)
             row["rel_err_f64"] = float(
                 np.linalg.norm(x0 - want) / np.linalg.norm(want))
@@ -529,6 +740,12 @@ def main(argv) -> int:
                 row["max_diff_xla"] = float(np.abs(
                     np.asarray(xs) - base).max() / np.abs(base).max())
                 text += f"  against xla {row['max_diff_xla']:.3g}"
+            if name == "owners":
+                xs_owners = np.asarray(xs)
+            elif name.startswith("tile128"):
+                row["bitwise_owners"] = bool(
+                    np.array_equal(np.asarray(xs), xs_owners))
+                text += f"  owners' bit for bit: {row['bitwise_owners']}"
         out[name] = row
         say(text)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)

@@ -152,7 +152,7 @@ def test_a_blocks_gramians_are_the_same_in_both_forms_bit_for_bit(case):
         mosaic = jax.jit(lambda t, i, v, *c: ops.block_gramians(
             ops.gather_table(t, GEOM96, ROWS, PLAN), i, v, depth, GEOM96,
             ROWS, PLAN, c))(T, rel, val_t, *cold)
-        assert xla.shape == (128, 128, 384 // depth)
+        assert xla.shape == (384 // depth, 128, 128)
         assert np.array_equal(np.asarray(xla), np.asarray(mosaic))
 
 
@@ -313,8 +313,8 @@ def test_the_report_prints_what_the_kernel_is_handed(cell_meta):
     assert line == (
         "R layout: ratings (gather: mosaic with 0.7364 of the slots "
         "resident, 0.2636 cold and listed by the loader (160178278 slots, "
-        "1215430656 B), lanes by the kernel, gramians: xla, solve: mosaic "
-        "in tiles of 128)")
+        "1215430656 B), lanes by the kernel, gramians: xla by owners, "
+        "solve: mosaic in tiles of 128)")
 
 
 def test_resident_share_is_a_count_over_the_packed_indices(mesh1):

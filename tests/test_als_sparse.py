@@ -304,7 +304,7 @@ def test_solve_batch_against_numpy(k, batch):
     Ap = np.einsum("bsd,bse->bde", G.astype(np.float64),
                    G.astype(np.float64))
     rows, has, sse, seen = jax.jit(
-        lambda a: ops.solve_batch(ops.to_lanes(a), LAM, geom))(
+        lambda a: ops.solve_batch(a, LAM, geom))(
         jnp.asarray(Ap, jnp.float32))
     assert np.asarray(has).tolist() == [i != 1 for i in range(batch)]
     assert int(seen) == 3 * k * (batch - 1)
@@ -408,8 +408,8 @@ def test_spans_and_report_say_the_layout(mesh1, tmp_path):
         assert parents[child] == prep["id"], child
     seg = ends["train:segment"]
     assert (seg["layout"], seg["als_gather_form"], seg["als_gram_form"],
-            seg["als_solve_form"], seg["tag"]) == (
-        "ratings", "xla", "xla", "xla", "als")
+            seg["als_gram_layout"], seg["als_solve_form"], seg["tag"]) == (
+        "ratings", "xla", "xla", "lanes", "xla", "als")
     lines = report.render(report.summarize(evts)).splitlines()
-    assert "R layout: ratings (gather: xla, gramians: xla, solve: xla)" \
-        in lines
+    assert ("R layout: ratings (gather: xla, gramians: xla by lanes, "
+            "solve: xla)") in lines

@@ -5,8 +5,14 @@ show (the 64 MB output table in VMEM, a kernel call's scalars in SMEM's
 is not a chip run. The topology is described inside a fixture, in this
 one file (only one process may hold the TPU's library), so sparse ALS'
 solve kernel is compiled here too: a tile of 128 systems at rank 100
-from a batch of 6144, and the widest rank ``solve_plan`` admits; and
-indexed LR's gather from a weight table in HBM at KDD Cup 2012's shape."""
+from an owner-major batch of 6144, and the widest rank ``solve_plan``
+admits; a half-sweep's steps at the cell's batch, whose compiled module
+copies no batch of Gramians into another layout; and indexed LR's gather
+from a weight table in HBM at KDD Cup 2012's shape."""
+
+import re
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -92,23 +98,86 @@ def test_kernel_compiles_at_the_vmem_budgets_edge(one_chip):
     _compile(geom, one_chip)
 
 
-@pytest.mark.parametrize("k,batch", [(100, 6144), (152, 1024)])
+@pytest.mark.parametrize("k,batch", [(100, 6144), (126, 1024)])
 def test_als_solve_kernel_compiles_for_the_chip(one_chip, k, batch):
     """The rank of the benchmark's cell, and the widest whose tile
-    ``als_sparse.solve_plan`` lets into VMEM (38 MB of the budget's 40):
-    both fit what the chip's compiler allows a kernel."""
+    ``als_sparse.solve_plan`` lets into VMEM (27 MB of the budget's 40;
+    from rank 127 an owner's row is two vectors and the tile's block of
+    them 46 MB): both fit what the chip's compiler allows a kernel that
+    is handed the batch owner-major and turns its tile in VMEM."""
     from tpu_distalg.ops import als_sparse, pallas_als
 
     geom = als_sparse.SparseGeometry(k=k, batch=batch, classes=(1,),
                                      piece_segs=batch)
     assert als_sparse.solve_plan(geom, True).form == "mosaic"
-    Ap = jax.ShapeDtypeStruct((geom.width, geom.width, batch),
+    assert als_sparse.solve_plan(
+        als_sparse.SparseGeometry(k=127), True).form == "xla"
+    Ap = jax.ShapeDtypeStruct((batch, geom.width, geom.width),
                               jnp.float32, sharding=one_chip)
     done = jax.jit(lambda a: pallas_als.solve_lanes(a, k, 1.4)).lower(
         Ap).compile()
     assert "_als_solve_kernel" in done.as_text()
-    assert done.memory_analysis().output_size_in_bytes \
-        == geom.solve_n * batch * 4
+    # the unknowns and the right-hand sides, a system a lane (and the
+    # pair's table)
+    assert 0 <= done.memory_analysis().output_size_in_bytes \
+        - 2 * geom.solve_n * batch * 4 <= 1024
+
+
+@pytest.mark.parametrize("with_error", [True, False],
+                         ids=["item_half", "user_half"])
+def test_a_half_sweep_copies_no_batch_of_gramians(one_chip, with_error):
+    """One shard's half at the cell's batch, rank and width (6144, 100,
+    128) with a step of every kind (a class of one segment an owner, a
+    class staged in two parts, the heavy class's accumulator), XLA's
+    gather and the Mosaic solve, as a mesh's shard runs it: the batch
+    travels owner-major from the product to the kernel, which takes it
+    inside ``solve_tile_bytes`` + ``VMEM_SLACK`` (the limit it is
+    compiled under), and no ``copy`` of the
+    compiled module has a batch's 128 x 128 x 6144 floats in any order
+    (until PR 50 three had, 12% of an iteration: a turn to lanes for the
+    kernel in each half and one more for the item half's error sums,
+    which the user half drops)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpu_distalg.ops import als_sparse
+
+    geom = als_sparse.SparseGeometry(k=100)
+    B, W = geom.batch, geom.width
+    plan = als_sparse.plan_side(np.concatenate([
+        np.full(B, 20), np.full(B // 2, 50), np.full(40, 5000)]), geom)
+    st = plan.static
+    assert [n for _, _, n, _ in st.light][:2] == [1, 1] and st.heavy[3] == B
+    solve = als_sparse.solve_plan(geom, True)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    rows = 700_000
+
+    def arr(shape, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def run(idx, val, pieces, other, own):
+        table, sse, seen = als_sparse.half_sweep(
+            idx, val, pieces, other, own, static=st,
+            other_zero_row=rows - 8, geom=geom, lam=1.4, axis="data",
+            solve=solve)
+        return (table, sse, seen) if with_error else (table, seen)
+
+    done = jax.jit(jax.shard_map(
+        run, mesh=mesh, in_specs=(P("data"),) * 3 + (P(), P()),
+        out_specs=(P(),) * (3 if with_error else 2),
+        check_vma=False)).lower(
+            arr((st.n_blocks, *geom.block_shape), jnp.int32, row),
+            arr((st.n_blocks, *geom.block_shape), jnp.float32, row),
+            arr(plan.piece_slot.shape, jnp.int32, row),
+            arr((rows, W), jnp.float32),
+            arr((st.table_rows, W), jnp.float32)).compile()
+    text = done.as_text()
+    assert "_als_solve_kernel" in text
+    batches = [m.group(0) for m in re.finditer(
+        r"= f32\[([\d,]+)\]\S* copy\(", text)
+        if np.prod([int(d) for d in m.group(1).split(",")]) == B * W * W]
+    assert not batches, batches
 
 
 def test_hbm_gather_kernel_compiles_at_kdd12s_shape(one_chip):

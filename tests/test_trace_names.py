@@ -558,6 +558,11 @@ def test_sparse_als_names_its_scopes(shards, mesh1, mesh4):
                   names.ALS_SYNC, names.ALS_UPDATE):
         assert scope + "/" in text, scope
     assert "pallas_call" not in text and "tpu_custom_call" not in text
+    # and what ``train:segment`` carries of it: XLA's solve turns the
+    # owner-major batch along the lanes itself
+    seg = als.segment_fields(meta)
+    assert (seg["als_gram_form"], seg["als_gram_layout"],
+            seg["als_solve_form"]) == ("xla", "lanes", "xla")
     assert {names.ALS_GATHER, names.ALS_GRAM, names.ALS_SOLVE,
             names.ALS_SYNC, names.ALS_UPDATE} == {
         v for k, v in vars(names).items() if k.startswith("ALS_")}

@@ -1305,26 +1305,40 @@ def test_sparse_als_half_sweep_at_rank_100(tpu_mesh):
     Ap = np.einsum("bsd,bse->bde", G.astype(np.float64),
                    G.astype(np.float64))
     rows, has, _, _ = jax.jit(
-        lambda a: ops.solve_batch(ops.to_lanes(a), 1.4, geom))(
+        lambda a: ops.solve_batch(a, 1.4, geom))(
         jnp.asarray(Ap, jnp.float32))
     want = np.stack([np.linalg.solve(
         Ap[i, :k, :k] + 1.4 * 300 * np.eye(k), Ap[i, :k, k])
         for i in range(geom.batch)])
     err = np.abs(np.asarray(rows)[:, :k] - want).max() / np.abs(want).max()
-    print(f"[als rank 100] solve along the lanes: max err {err:.3g}")
+    print(f"[als rank 100] XLA's solve along the lanes: max err {err:.3g}")
     assert bool(np.asarray(has).all()) and err < 1e-3
 
 
 def test_sparse_als_solve_kernel_at_rank_100_against_float64():
     """The Mosaic solve (``ops/pallas_als.solve_lanes``) compiled at the
-    benchmark's tile (128 systems of rank 100 in 104, six tiles) against
-    NumPy in float64 and against XLA's ``cholesky_solve_lanes`` on the
-    same systems: Gramians of 0 to 2000 rows of eighths with ratings 0
-    to 100, ``lam n_u`` on the diagonal, so some owners have fewer
-    ratings than the rank and three have none. What this guards is the
-    VPU's square root and division: an approximate reciprocal would
-    read 1e-3 here."""
+    benchmark's tile (128 owners' rows of rank 100 in 104, turned along
+    the lanes in VMEM; six tiles) against NumPy in float64 and against
+    XLA's ``cholesky_solve_lanes`` on the same systems: Gramians of 0 to
+    2000 rows of eighths with ratings 0 to 100, ``lam n_u`` on the
+    diagonal, so some owners have fewer ratings than the rank and three
+    have none. What this guards is the VPU's square root and division
+    (an approximate reciprocal would read 1e-3 here) and the turn: the
+    unknowns are bit for bit those of the form that was handed the batch
+    along the lanes (``scripts/step0_als_solve.solve_from_lanes``, what
+    shipped until PR 50), on Gramians that are not symmetric to the
+    last bit."""
+    import os
+    import sys
+
     from tpu_distalg.ops import als_sparse as ops
+    from tpu_distalg.ops import pallas_als
+
+    scripts = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts")
+    if scripts not in sys.path:
+        sys.path.insert(0, scripts)
+    import step0_als_solve as step0
 
     k, batch, lam = 100, 768, 1.4
     geom = ops.SparseGeometry(k=k, batch=batch)
@@ -1342,12 +1356,19 @@ def test_sparse_als_solve_kernel_at_rank_100_against_float64():
     want = np.stack([np.linalg.solve(
         Ap[i, :k, :k] + (lam * cnt[i] if cnt[i] else 1.0) * np.eye(k),
         Ap[i, :k, k]) for i in range(batch)])
-    lanes = ops.to_lanes(jnp.asarray(Ap, jnp.float32))
+    owners = jnp.asarray(Ap, jnp.float32)
+    skew = owners * (1 + 1e-7 * jnp.asarray(
+        rng.standard_normal(Ap.shape), jnp.float32))
+    x, b = jax.jit(lambda a: pallas_als.solve_lanes(a, k, lam))(skew)
+    was = jax.jit(lambda a: step0.solve_from_lanes(
+        ops.to_lanes(a), k, lam))(skew)
+    assert np.array_equal(np.asarray(x), np.asarray(was))
+    assert np.array_equal(np.asarray(b)[:k], np.asarray(skew[:, :k, k]).T)
     got = {}
     for name, plan in (("mosaic", ops.SolvePlan("mosaic", 128)),
                        ("xla", None)):
         rows, has, _, seen = jax.jit(
-            lambda a, plan=plan: ops.solve_batch(a, lam, geom, plan))(lanes)
+            lambda a, plan=plan: ops.solve_batch(a, lam, geom, plan))(owners)
         assert np.asarray(has).tolist() == (cnt > 0).tolist()
         assert int(seen) == int(cnt.sum())
         rows = np.asarray(rows)

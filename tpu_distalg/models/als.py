@@ -305,7 +305,11 @@ def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
         gather=gathers, solve=solve,
         forms=dict(als_gather_form="/".join(
             dict.fromkeys(g.form for g in gathers)),
-            als_gram_form="xla", als_solve_form=solve.form),
+            als_gram_form="xla", als_solve_form=solve.form,
+            # a batch of Gramians is made owner-major; the Mosaic solve
+            # reads it so, XLA's turns it to lanes first
+            als_gram_layout="owners" if solve.form == "mosaic"
+            else "lanes"),
         **extra)
 
 

@@ -87,23 +87,34 @@ MB, a block reads 7 and 4% slower than with the heavy class alone).
 factorisation of ``A_u + lam n_u I`` with the batch along the lanes, in
 panels of 8 columns, the right-hand side carried as one more row, then
 the backward substitution: every step an elementwise operation over the
-batch's systems at once (the Gramians come out of :func:`block_gramians`
-in that layout, ``(width, width, batch)``). It has two forms that solve
-the same systems by the same steps in float32 (a true square root and a
-true division), and :func:`solve_plan` picks one from what the code can
-observe, with no flag:
+batch's systems at once. The Gramians do not travel in that layout: a
+batch is owner-major ``(batch, width, width)`` as :func:`block_gramians`'
+product makes it, through a class's staging (whole rows of it), the
+heavy class's accumulator (an owner a row) and the ``switch`` that picks
+a step's class, and the error's sums read it as it lies; only the solve
+wants a system a lane, and each form turns the batch where it is
+cheapest for it. The two forms solve the same systems by the same steps
+in float32 (a true square root and a true division), and
+:func:`solve_plan` picks one from what the code can observe, with no
+flag:
 
-``mosaic``  ``pallas_als.solve_lanes``: a tile of 128 systems is read
-            out of the Gramians once and stays in VMEM from the ridge
-            to the solved row (17 MB at rank 100); the staged matrices
-            of XLA's form never exist in HBM. On a TPU, where a batch
-            is whole tiles and a tile fits ``SOLVE_VMEM_BYTES`` (to
-            rank 152); on a mesh every shard runs it on its own batches.
-``xla``     :func:`cholesky_solve_lanes`: each panel's update streams
-            the stage's whole trailing matrix through HBM (286 MB each
-            way a first-stage panel at the published shape, at 640 of
-            the chip's 819 GB/s: 2.07 us a system on one v5e, where
-            XLA's own ``cho_factor``, the matrix on the minor
+``mosaic``  ``pallas_als.solve_lanes``: a tile of 128 owners' rows is
+            read out of the batch once, turned along the lanes in VMEM
+            (a strided load and a transpose a column) and stays there
+            from the ridge to the solved row (21 MB at rank 100); the
+            batch is never copied in HBM (until PR 50 it was, 806 MB a
+            batch each way at the rate of the memory: more than the
+            solve it served) and the staged matrices of XLA's form
+            never exist there. On a TPU, where a batch is whole tiles
+            and a tile fits ``SOLVE_VMEM_BYTES`` (to rank 126: from 127
+            an owner's row is two vectors); on a mesh every shard runs
+            it on its own batches.
+``xla``     :func:`cholesky_solve_lanes` on the batch turned by
+            :func:`to_lanes` (a copy in HBM): each panel's update
+            streams the stage's whole trailing matrix through HBM (286
+            MB each way a first-stage panel at the published shape, at
+            640 of the chip's 819 GB/s: 2.07 us a system on one v5e,
+            where XLA's own ``cho_factor``, the matrix on the minor
             dimensions, took 13.9); everywhere else (the CPU, a batch
             of ``BATCH_UNIT``), and the kernel's reference in the tests.
 
@@ -133,9 +144,10 @@ PANEL = 8                   # columns a Cholesky panel: a vector's
 # the published shape and not the class before it (the module docstring
 # says where the number comes from)
 GATHER_VMEM_BYTES = 12 << 20
-# what a tile of the Mosaic solve may take of VMEM (a block of the
-# Gramians twice and the matrix it factors: 17.2 MB at rank 100, 38.3 at
-# rank 152, the widest that fits)
+# what a tile of the Mosaic solve may take of VMEM (128 owners' rows of
+# the Gramians twice and the matrix it factors: 20.6 MB at rank 100, 26.9
+# at rank 126, the widest that fits: from rank 127 an owner's row is two
+# vectors wide and the rows alone 35.7 MB)
 SOLVE_VMEM_BYTES = 40 << 20
 
 
@@ -258,7 +270,8 @@ class SolvePlan:
 def solve_plan(geom: SparseGeometry, on_tpu: bool) -> SolvePlan:
     """The form of the per-owner solve from what the code can observe:
     the Mosaic kernel on a TPU where a batch is whole tiles of systems
-    (a lane each) and a tile at this rank fits ``SOLVE_VMEM_BYTES``;
+    (a lane each, once turned) and a tile at this rank fits
+    ``SOLVE_VMEM_BYTES``;
     XLA's :func:`cholesky_solve_lanes` elsewhere. The solve reads no
     table and no index, so every shard of a mesh runs the same form."""
     from tpu_distalg.ops import pallas_als
@@ -506,8 +519,8 @@ def gather_table(other, geom: SparseGeometry, zero_row: int,
 def block_gramians(other, idx_b, val_b, K: int, geom: SparseGeometry,
                    zero_row: int, gather: GatherPlan | None = None,
                    cold=None):
-    """One block's ``batch / K`` extended Gramians with the owners
-    along the lanes, ``(width, width, batch / K)``: the gather of the
+    """One block's ``batch / K`` extended Gramians as the product makes
+    them, owner-major ``(batch / K, width, width)``: the gather of the
     other side's rows with the rating and the validity in lanes ``k``
     and ``k + 1``, one float32-accurate product ``K * seg_slots`` deep
     an owner. In the Mosaic form ``other`` is :func:`gather_table`'s,
@@ -540,14 +553,15 @@ def block_gramians(other, idx_b, val_b, K: int, geom: SparseGeometry,
             G = jnp.where(lane == k, r, jnp.where(lane == k + 1, ok, G))
     with jax.named_scope(names.ALS_GRAM):
         G = G.reshape(geom.batch // K, K * geom.seg_slots, W)
-        return to_lanes(jnp.einsum(
+        return jnp.einsum(
             "osd,ose->ode", G, G, precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32))
+            preferred_element_type=jnp.float32)
 
 
 def to_lanes(Ap):
     """``(owners, width, width)`` to ``(width, width, owners)``: the
-    layout the solve works in."""
+    layout XLA's form of the solve works in (the Mosaic kernel turns its
+    tile itself, in VMEM)."""
     import jax.numpy as jnp
 
     return jnp.transpose(Ap, (1, 2, 0))
@@ -669,13 +683,15 @@ def cholesky_solve_lanes(M, rhs, panel: int):
 
 def solve_batch(Ap, lam: float, geom: SparseGeometry,
                 solve: SolvePlan | None = None):
-    """From a batch of extended Gramians with the owners along the
-    lanes, ``(width, width, batch)``: the new factor rows ``(batch,
-    width)``, which owners have a rating, the squared training error of
-    those that have, and the ratings counted. ``A_u + lam n_u I`` is
-    solved exactly (Cholesky) in the plan's form (XLA's where none is
-    given); an owner with no rating solves the identity and is
-    flagged."""
+    """From a batch of extended Gramians as :func:`block_gramians` makes
+    them, owner-major ``(batch, width, width)``: the new factor rows
+    ``(batch, width)``, which owners have a rating, the squared training
+    error of those that have, and the ratings counted. ``A_u + lam n_u
+    I`` is solved exactly (Cholesky) in the plan's form (XLA's where
+    none is given); an owner with no rating solves the identity and is
+    flagged. Only XLA's solve wants the batch along the lanes and turns
+    it (a copy of the batch in HBM); the kernel turns a tile in VMEM,
+    and everything else reads the batch as it lies."""
     import jax
     import jax.numpy as jnp
 
@@ -683,31 +699,36 @@ def solve_batch(Ap, lam: float, geom: SparseGeometry,
 
     k, W, w, n8 = geom.k, geom.width, PANEL, geom.solve_n
     with jax.named_scope(names.ALS_SOLVE):
-        cnt = Ap[k + 1, k + 1]
+        cnt = Ap[:, k + 1, k + 1]
         has = cnt > 0
-        b = Ap[:k, k]                                 # (k, batch)
         if solve is not None and solve.form == "mosaic":
             from tpu_distalg.ops import pallas_als
 
-            x = pallas_als.solve_lanes(
-                Ap, k, float(lam), interpret=solve.interpret)[:k]
+            x, b = (a[:k] for a in pallas_als.solve_lanes(
+                Ap, k, float(lam), interpret=solve.interpret))
         else:
+            lanes = to_lanes(Ap[:, :n8, :n8])
+            b = Ap[:, :k, k].T                        # (k, batch)
             ridge = jnp.where(has, jnp.float32(lam) * cnt, 1.0)
             ri = jax.lax.broadcasted_iota(jnp.int32, (n8, n8, 1), 0)
             ci = jax.lax.broadcasted_iota(jnp.int32, (n8, n8, 1), 1)
-            M = jnp.where((ri < k) & (ci < k), Ap[:n8, :n8], 0.0) \
+            M = jnp.where((ri < k) & (ci < k), lanes, 0.0) \
                 + jnp.where(ri == ci,
                             jnp.where(ri < k, ridge[None, None, :], 1.0),
                             0.0)
             x = cholesky_solve_lanes(
                 M, jnp.pad(b, ((0, n8 - k), (0, 0))), w)[:k]  # (k, batch)
     with jax.named_scope(names.ALS_UPDATE):
-        Ax = jnp.sum(Ap[:k, :k] * x[None, :, :], axis=1)
-        err = Ap[k, k] - 2.0 * jnp.sum(x * b, axis=0) \
-            + jnp.sum(x * Ax, axis=0)
+        rows = x.T                                    # (batch, k)
+        # x^T A x with the sum over A's rows: the batch is read as it
+        # lies (summed over its minor dimension, or sliced for b_u
+        # beside a lanes-major x, XLA copies all of it owners-last)
+        Ax = jnp.sum(Ap[:, :k, :k] * rows[:, :, None], axis=1)
+        err = Ap[:, k, k] - 2.0 * jnp.sum(x * b, axis=0) \
+            + jnp.sum(rows * Ax, axis=1)
         sse = jnp.sum(jnp.where(has, err, 0.0))
         seen = jnp.sum(cnt.astype(jnp.int32))
-        rows = jnp.pad(x.T, ((0, 0), (0, W - k)))
+        rows = jnp.pad(rows, ((0, 0), (0, W - k)))
     return rows, has, sse, seen
 
 
@@ -767,10 +788,10 @@ def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
             with jax.named_scope(names.ALS_GRAM):
                 slot = lax.dynamic_index_in_dim(piece_slot, i,
                                                 keepdims=False)
-                return acc.at[:, :, slot].add(got), None
+                return acc.at[slot].add(got), None
 
         acc, _ = lax.scan(
-            piece_block, jnp.zeros((W, W, heavy_rows + 1), jnp.float32),
+            piece_block, jnp.zeros((heavy_rows + 1, W, W), jnp.float32),
             jnp.arange(n_heavy_blocks))
 
     # every batch of owners that is solved, of whatever class, is one
@@ -788,10 +809,10 @@ def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
                 got = grams(block0 + i * K + j, K)
                 with jax.named_scope(names.ALS_GRAM):
                     return lax.dynamic_update_slice_in_dim(
-                        staging, got, j * (B // K), 2)
+                        staging, got, j * (B // K), 0)
 
             with jax.named_scope(names.ALS_GRAM):
-                empty = jnp.zeros((W, W, B), jnp.float32)
+                empty = jnp.zeros((B, W, W), jnp.float32)
             return lax.fori_loop(0, K, part, empty)
 
         return make
@@ -808,7 +829,7 @@ def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
         local_steps += list(range(n))
         rows += [heavy_row0 + i * B for i in range(n)]
         branches.append(
-            lambda i: lax.dynamic_slice_in_dim(acc, i * B, B, axis=2))
+            lambda i: lax.dynamic_slice_in_dim(acc, i * B, B, axis=0))
 
     if kind:
         def step(carry, at):

@@ -215,101 +215,147 @@ def gather_rows_resident(table, rel_b, val_t, cold_b, n_cold,
 # ------------------------------------------------------------ the solve
 #
 # ``solve_lanes(Ap, k, lam)`` solves ``(A_u + lam n_u I) x = b_u`` for
-# every owner of a batch of extended Gramians ``Ap`` ``(width, width,
-# batch)`` (``A_u`` rows and columns under ``k``, ``b_u`` row ``k``,
-# ``n_u`` entry ``(k + 1, k + 1)``; an owner of no rating solves the
-# identity), by the steps of ``als_sparse.cholesky_solve_lanes`` in the
-# same order: right-looking Cholesky in panels of 8 columns, the
-# right-hand side as one more row, the backward substitution.
+# every owner of a batch of extended Gramians as the product makes them,
+# owner-major ``Ap`` ``(batch, width, width)`` (``A_u`` rows and columns
+# under ``k``, ``b_u`` column ``k``, ``n_u`` entry ``(k + 1, k + 1)``; an
+# owner of no rating solves the identity), by the steps of
+# ``als_sparse.cholesky_solve_lanes`` in the same order: right-looking
+# Cholesky in panels of 8 columns, the right-hand side as one more row,
+# the backward substitution. It hands back the right-hand sides too, a
+# system a lane as it read them (XLA, asked for that column beside
+# ``x``, copies the whole batch into another layout to slice it).
 #
-# A grid step takes a tile of 128 systems, a lane each: the pipeline
-# copies ``Ap[:ext, :ext, tile]`` in (``ext`` = ``k + 2`` in whole
-# sublane tiles; 5.5 MB at rank 100, read once), the ridge and the ``k``
-# mask are made on the way into ``m_ref``, column ``c`` a leading index
-# and rows down the sublanes, so a vector is 8 rows of one column of
-# 128 systems. Only the lower triangle is kept. A panel's eight columns
-# are finished a row tile at a time (the diagonal block first, which
-# gives the 28 multipliers and 8 pivots the tiles below reuse) and go
-# back to ``m_ref`` and into ``p_ref``, an allocation of its own at a
-# static index: the trailing update reads the panel only there, so no
-# load of it waits on a store to the matrix. That update takes a tile of
-# eight columns by two row tiles a trip, a column's multiplier spread
-# down the sublanes once for both. The loops over panels, column tiles
-# and row tiles are rolled with dynamic bounds; what a trip does is
-# unrolled.
+# A grid step takes a tile of 128 owners: the pipeline copies their
+# first ``ext`` rows ``Ap[tile, :ext, :]`` in (``ext`` = ``k + 2`` in
+# whole sublane tiles; 6.8 MB at rank 100, 53 KB an owner in one piece,
+# read once) and the kernel turns them along the lanes itself, a column
+# at a time: row ``c`` of the 128 owners is one sublane-strided load
+# ``(128 owners, 128 entries)``, its transpose ``(128 entries, 128
+# owners)`` is column ``c`` of 128 systems, a system a lane (entry for
+# entry what a block of the batch held along the lanes gave: the
+# unknowns are that form's bit for bit). The ridge and the ``k`` mask
+# are made on the way into ``m_ref``, column ``c`` a leading index and
+# rows down the sublanes, so a vector is 8 rows of one column of 128
+# systems. Every row tile of a column is stored (13 stores, half of
+# them above the diagonal, where nothing reads: cheaper than a loop of
+# its own); eight columns a trip keep the three transpose units busy
+# (540 bundles a trip, 67 a column, where one column alone waits 290).
+# A panel's eight columns are finished a row tile at a time (the
+# diagonal block first, which gives the 28 multipliers and 8 pivots the
+# tiles below reuse) and go back to ``m_ref`` and into ``p_ref``, an
+# allocation of its own at a static index: the trailing update reads the
+# panel only there, so no load of it waits on a store to the matrix.
+# That update takes a tile of eight columns by two row tiles a trip, a
+# column's multiplier spread down the sublanes once for both. The loops
+# over panels, column tiles and row tiles are rolled with dynamic
+# bounds; what a trip does is unrolled.
 #
-# One v5e at rank 100 (``scripts/step0_als_solve.py``, PR 41): 324.8
-# bundles a system by the static schedule, 58% of them the trailing
-# update; 1.47 ms a batch of 6144 (0.24 us a system, a tenth over the
-# schedule) where XLA's form takes 14.5, the tile's copy (0.45 ms a
-# batch alone) hidden behind the arithmetic; tiles of 256 and 512
-# systems read the same, and a tile of 1024 with a matrix entry a whole
-# vector the same again before its view of ``Ap`` is paid for (1.2 ms).
+# One v5e at rank 100 (``scripts/step0_als_solve.py``; PR 50, PR 41):
+# 363.3 bundles a system by the static schedule, 15% of them the turn
+# (7020 a tile where a block that came along the lanes took 2444) and
+# half the trailing update; **1.62 ms a batch of 6144** (0.264 us a
+# system, 0.14 ms over the 1.48 of the form that was handed the batch
+# along the lanes, which with the 806 MB copy in HBM it needed read
+# 2.68) where XLA's form takes 14.5, the tile's copy (0.54 ms a batch
+# alone) hidden behind the arithmetic; tiles of 256 and 512 systems read
+# the same, and a tile of 1024 with a matrix entry a whole vector the
+# same again before its view of ``Ap`` is paid for (1.2 ms). The
+# error's ``x^T A x`` taken here too, from the block while it is still
+# in VMEM, is a second turn of every column: +0.23 ms on every batch,
+# where XLA's sum over the owner-major batch costs 0.54 ms on the item
+# half's batches alone (the user half drops its error): not shipped.
 
 SOLVE_TILE = LANES     # systems a grid step: one a lane
 
 
 def _solve_sizes(k: int) -> tuple[int, int]:
     """``(n, ext)`` in whole sublane tiles: the rows and columns that
-    are factored (``k`` and the identity's padding), and those read of
-    ``Ap`` (``b_u`` in row ``k``, ``n_u`` at ``k + 1``)."""
+    are factored (``k`` and the identity's padding), and an owner's rows
+    read of ``Ap`` (``b_u`` is their entries ``k``, ``n_u`` entry ``k +
+    1`` of row ``k + 1``)."""
     return -(-k // SUBLANES) * SUBLANES, -(-(k + 2) // SUBLANES) * SUBLANES
 
 
 def solve_tile_bytes(k: int) -> int:
     """VMEM a grid step of :func:`solve_lanes` holds at rank ``k``: the
-    block of ``Ap`` and the result's twice (the pipeline's two buffers),
-    the matrix that is factored in place, right-hand side and all, and
-    its panel."""
+    tile's owners' rows of ``Ap`` (``ext`` of them, whole vectors wide)
+    and the result's twice (the pipeline's two buffers), the matrix that
+    is factored in place, right-hand side and all, and its panel."""
     n, ext = _solve_sizes(k)
-    return 4 * SOLVE_TILE * (2 * (ext * ext + n)
+    width = -(-ext // LANES) * LANES
+    return 4 * SOLVE_TILE * (2 * (ext * width + 2 * n)
                              + (n + SUBLANES) * (n + 2 * SUBLANES))
 
 
-def _als_solve_kernel(a_ref, x_ref, m_ref, p_ref, *, k: int, lam: float):
+def _rows8(x):
+    return jnp.broadcast_to(x, (SUBLANES, LANES))
+
+
+def _tile(i):
+    return pl.ds(pl.multiple_of(i * SUBLANES, SUBLANES), SUBLANES)
+
+
+def _column_of(a_ref):
+    """``column(c)``: row ``c`` of every owner of the block ``a_ref``
+    ``(owners, ext, width)`` as ``(width, owners)``, a sublane-strided
+    load and one transpose."""
+    systems, ext, width = a_ref.shape
+    rows_ref = a_ref.reshape(systems * ext, width)
+    return lambda c: rows_ref[pl.ds(c, systems, stride=ext), :].T
+
+
+def _solve_build(a_ref, b_ref, m_ref, *, k: int, lam: float):
+    """The matrix of a tile's systems out of their owners' rows of
+    ``Ap``, ``a_ref`` ``(128 owners, ext, width)``: column ``c`` a
+    leading index of ``m_ref``, rows down the sublanes, a system a lane,
+    the right-hand side as row ``n``, the ridge on the diagonal and
+    zeros (a one on the diagonal) from ``k`` on. Row ``c`` of the 128
+    owners is one sublane-strided load ``(owners, width)``, and its
+    transpose ``(width, owners)`` is what the lanes form read as
+    ``Ap[c, :, tile]``: entry for entry the same floats. Every row tile
+    is stored (what lies above the diagonal block is never read: no
+    step mixes sublanes but by a row it names)."""
+    n = m_ref.shape[0]
+    w = SUBLANES
+    f32 = jnp.float32
+    sub = jax.lax.broadcasted_iota(jnp.int32, (w, LANES), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, LANES), 0)
+    column = _column_of(a_ref)
+    cnt = column(k + 1)[k + 1:k + 2, :]
+    ridge = jnp.where(cnt > 0, f32(lam) * cnt, f32(1.0))
+
+    def build(ct, carry):
+        c0 = pl.multiple_of(ct * w, w)
+        for u in range(w):
+            c = c0 + u
+            live = c < k
+            col = column(c)
+            v = jnp.where((row < k) & live, col[:n, :], f32(0.0))
+            diag = jnp.where(row == c, jnp.where(live, ridge, f32(1.0)),
+                             f32(0.0))
+            # (the sum over the diagonal block alone, as the lanes form
+            # made it: elsewhere a zero keeps its sign)
+            m_ref[c, pl.ds(0, n), :] = jnp.where(
+                (row >= c0) & (row < c0 + w), v + diag, v)
+            b = jnp.where(live, _rows8(col[k:k + 1, :]), f32(0.0))
+            m_ref[c, pl.ds(n, w), :] = jnp.where(sub == 0, b, f32(0.0))
+            rhs = b if u == 0 else jnp.where(sub == u, b, rhs)
+        b_ref[_tile(ct), :] = rhs
+        return carry
+
+    jax.lax.fori_loop(0, n // w, build, 0)
+
+
+def _solve_factor(x_ref, m_ref, p_ref):
+    """``m_ref`` as :func:`_solve_build` leaves it, factored in place
+    (``p_ref`` the finished panel), and the systems' unknowns into
+    ``x_ref`` ``(n, 128)``."""
     n = x_ref.shape[0]
     w = SUBLANES
     n_tiles = n // w            # column tiles, and the matrix's row tiles
     row_tiles = n_tiles + 1     # the right-hand side rides in one more
     f32 = jnp.float32
     sub = jax.lax.broadcasted_iota(jnp.int32, (w, LANES), 0)
-
-    def rows8(x):
-        return jnp.broadcast_to(x, (w, LANES))
-
-    def tile(i):
-        return pl.ds(pl.multiple_of(i * w, w), w)
-
-    cnt = a_ref[k + 1, pl.ds(k + 1, 1), :]
-    ridge = rows8(jnp.where(cnt > 0, f32(lam) * cnt, f32(1.0)))
-
-    # the matrix of a tile's systems, the lower triangle of it: column c
-    # a leading index, rows down the sublanes, the right-hand side as
-    # row n. What lies above the diagonal inside a column's first tile
-    # is never read: no step mixes sublanes but by a row it names
-    def build(ct, carry):
-        c0 = pl.multiple_of(ct * w, w)
-        live = [c0 + u < k for u in range(w)]
-        for u in range(w):      # the diagonal block, with the ridge
-            v = jnp.where((sub + c0 < k) & live[u],
-                          a_ref[c0 + u, tile(ct), :], f32(0.0))
-            m_ref[c0 + u, tile(ct), :] = v + jnp.where(
-                sub == u, jnp.where(live[u], ridge, f32(1.0)), f32(0.0))
-            b = rows8(a_ref[c0 + u, pl.ds(k, 1), :])
-            m_ref[c0 + u, pl.ds(n, w), :] = jnp.where(
-                (sub == 0) & live[u], b, f32(0.0))
-
-        def one(i, carry):
-            keep = sub + i * w < k
-            for u in range(w):
-                m_ref[c0 + u, tile(i), :] = jnp.where(
-                    keep & live[u], a_ref[c0 + u, tile(i), :], f32(0.0))
-            return carry
-
-        jax.lax.fori_loop(ct + 1, n_tiles, one, 0)
-        return carry
-
-    jax.lax.fori_loop(0, n_tiles, build, 0)
 
     def factor(p, carry):
         q = pl.multiple_of(p * w, w)
@@ -321,7 +367,7 @@ def _als_solve_kernel(a_ref, x_ref, m_ref, p_ref, *, k: int, lam: float):
         # ``p_ref``, an allocation of its own at a static index, for
         # the trailing update
         def columns(tiles, mult, piv):
-            Xs = [[m_ref[q + t, tile(i), :] for t in range(w)]
+            Xs = [[m_ref[q + t, _tile(i), :] for t in range(w)]
                   for i in tiles]
             for X in Xs:
                 for j in range(w):
@@ -329,15 +375,15 @@ def _als_solve_kernel(a_ref, x_ref, m_ref, p_ref, *, k: int, lam: float):
                     for t in range(j):
                         s = s - X[t] * mult[t][j]
                     if piv[j] is None:      # the diagonal block: row j
-                        piv[j] = rows8(jnp.sqrt(s[j:j + 1, :]))
+                        piv[j] = _rows8(jnp.sqrt(s[j:j + 1, :]))
                     X[j] = s / piv[j]
                     for u in range(j + 1, w):
                         if mult[j][u] is None:
-                            mult[j][u] = rows8(X[j][u:u + 1, :])
+                            mult[j][u] = _rows8(X[j][u:u + 1, :])
             for i, X in zip(tiles, Xs):
                 for t in range(w):
-                    m_ref[q + t, tile(i), :] = X[t]
-                    p_ref[t, tile(i), :] = X[t]
+                    m_ref[q + t, _tile(i), :] = X[t]
+                    p_ref[t, _tile(i), :] = X[t]
 
         mult = [[None] * w for _ in range(w)]
         piv = [None] * w
@@ -366,19 +412,19 @@ def _als_solve_kernel(a_ref, x_ref, m_ref, p_ref, *, k: int, lam: float):
                 i0 = first + 2 * g
                 upd = [[None] * w, [None] * w]
                 for t in range(w):
-                    C = p_ref[t, tile(jt), :]
-                    X = [p_ref[t, tile(i0 + h), :] for h in range(2)]
+                    C = p_ref[t, _tile(jt), :]
+                    X = [p_ref[t, _tile(i0 + h), :] for h in range(2)]
                     for u in range(w):
-                        c = rows8(C[u:u + 1, :])
+                        c = _rows8(C[u:u + 1, :])
                         for h in range(2):
                             term = X[h] * c
                             upd[h][u] = term if t == 0 \
                                 else upd[h][u] + term
-                M = [[m_ref[c0 + u, tile(i0 + h), :] - upd[h][u]
+                M = [[m_ref[c0 + u, _tile(i0 + h), :] - upd[h][u]
                       for u in range(w)] for h in range(2)]
                 for h in range(2):
                     for u in range(w):
-                        m_ref[c0 + u, tile(i0 + h), :] = M[h][u]
+                        m_ref[c0 + u, _tile(i0 + h), :] = M[h][u]
                 return carry
 
             jax.lax.fori_loop(0, (row_tiles - first) // 2, two, 0)
@@ -395,8 +441,8 @@ def _als_solve_kernel(a_ref, x_ref, m_ref, p_ref, *, k: int, lam: float):
         q = pl.multiple_of(p * w, w)
 
         def dots(i, acc):
-            x = x_ref[tile(i), :]
-            return tuple(a + m_ref[q + j, tile(i), :] * x
+            x = x_ref[_tile(i), :]
+            return tuple(a + m_ref[q + j, _tile(i), :] * x
                          for j, a in enumerate(acc))
 
         acc = jax.lax.fori_loop(
@@ -410,19 +456,29 @@ def _als_solve_kernel(a_ref, x_ref, m_ref, p_ref, *, k: int, lam: float):
             for t in range(j + 1, w):
                 s = s - m_ref[q + j, pl.ds(q + t, 1), :] * xp[t]
             xp[j] = s / m_ref[q + j, pl.ds(q + j, 1), :]
-            out = jnp.where(sub == j, rows8(xp[j]), out)
-        x_ref[tile(p), :] = out
+            out = jnp.where(sub == j, _rows8(xp[j]), out)
+        x_ref[_tile(p), :] = out
         return carry
 
     jax.lax.fori_loop(0, n_tiles, backward, 0)
 
 
+def _als_solve_kernel(a_ref, x_ref, b_ref, m_ref, p_ref, *, k: int,
+                      lam: float):
+    _solve_build(a_ref, b_ref, m_ref, k=k, lam=lam)
+    _solve_factor(x_ref, m_ref, p_ref)
+
+
+# jitted like the gather: a fit's two halves trace and lower the kernel
+# once
+@functools.partial(jax.jit, static_argnames=("k", "lam", "interpret"))
 def solve_lanes(Ap, k: int, lam: float, *, interpret: bool = False):
     """The ``k`` unknowns of every system of a batch of extended
-    Gramians held with the owners along the lanes, ``Ap`` ``(width,
-    width, batch)`` float32 -> ``(round_up(k, 8), batch)`` (rows from
-    ``k`` on are zero)."""
-    width, _, batch = Ap.shape
+    Gramians as the product makes them, owner-major ``Ap`` ``(batch,
+    width, width)`` float32, and the right-hand sides as they were read
+    (``Ap[:, :k, k]``): two of ``(round_up(k, 8), batch)``, a system a
+    lane (rows from ``k`` on are zero)."""
+    batch, width, _ = Ap.shape
     n, ext = _solve_sizes(k)
     if batch % SOLVE_TILE or ext > width:
         raise ValueError(f"Gramians {Ap.shape} are not whole tiles of "
@@ -432,10 +488,10 @@ def solve_lanes(Ap, k: int, lam: float, *, interpret: bool = False):
         functools.partial(_als_solve_kernel, k=k, lam=lam),
         name="_als_solve_kernel",
         grid=(batch // SOLVE_TILE,),
-        in_specs=[pl.BlockSpec((ext, ext, SOLVE_TILE),
-                               lambda i: (0, 0, i))],
-        out_specs=pl.BlockSpec((n, SOLVE_TILE), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, batch), Ap.dtype),
+        in_specs=[pl.BlockSpec((SOLVE_TILE, ext, width),
+                               lambda i: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((n, SOLVE_TILE), lambda i: (0, i))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((n, batch), Ap.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((n, rows, SOLVE_TILE), Ap.dtype),
                         pltpu.VMEM((SUBLANES, rows, SOLVE_TILE), Ap.dtype)],
         compiler_params=pltpu.CompilerParams(

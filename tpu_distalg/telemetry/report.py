@@ -315,9 +315,13 @@ def summarize(evts: list[dict]) -> dict:
                     # the systems a tile holds in VMEM from the Gramian
                     # to the solved row
                     solve += f" in tiles of {e['solve_tile_systems']}"
+                gram = e.get("als_gram_form", "?")
+                if e.get("als_gram_layout"):
+                    # how a batch reaches the solve: as the product
+                    # makes it, or through a copy along the lanes
+                    gram += f" by {e['als_gram_layout']}"
                 form = (f"{e.get('layout', '?')} (gather: {gather}, "
-                        f"gramians: "
-                        f"{e.get('als_gram_form', '?')}, solve: {solve})")
+                        f"gramians: {gram}, solve: {solve})")
                 if form not in als_forms:
                     als_forms.append(form)
         elif ev == "span_end":
