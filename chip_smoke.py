@@ -990,6 +990,60 @@ def stage_als_sparse(s: Smoke):
             f"held-out RMSE {held[0]:.3f} -> {held[-1]:.3f}")
 
 
+def stage_closure(s: Smoke):
+    """Both forms of transitive closure on every chip the stage has.
+    Dense, as ``tda closure --grid-side`` runs it: BigDatalog's grid at
+    side 63 (4096 vertices, 8064 arcs, 126 across: 8 doubling rounds),
+    labels permuted, against the generator's closed form; on one chip
+    the round is the Mosaic byte kernel (``ops/pallas_closure.py``), on
+    several XLA's product of row-sharded paths. Sparse, which had never
+    compiled for a chip either: the grid at side 20 (441 vertices, 40
+    linear rounds) through ``run_sparse``, its pair set against the
+    dense form's matrix, cell for cell."""
+    import numpy as np
+
+    from tpu_distalg.models import transitive_closure as tc
+    from tpu_distalg.utils import datasets
+
+    side = 63
+    t0 = time.perf_counter()
+    out = s.cli(["closure", "--grid-side", str(side), "--seed", "5"])
+    t_dense = time.perf_counter() - t0
+    pairs = datasets.grid_closure_pairs(side)
+    if f"has {pairs} paths (8 rounds)" not in out \
+            or "pairs (equal)" not in out:
+        raise AssertionError(f"dense closure of Grid{side}: {out!r}")
+    mesh = s.mesh()
+    geom = tc.dense_geometry((side + 1) ** 2, mesh)
+    want_form = "mosaic" if s.n == 1 else "xla"
+    if geom.form != want_form:
+        raise AssertionError(f"compose form {geom.form} on {mesh.shape}")
+    kernel = "pallas_closure._compose_kernel"
+    if (s.n == 1) != (False in s.spy.built.get(kernel, ())):
+        raise AssertionError(
+            f"{kernel} compiled: {s.spy.built.get(kernel)} on {s.n} chip(s)")
+    small = datasets.grid_edges(20, 3)
+    v = 21 * 21
+    dense = tc.run(small, mesh, n_vertices=v)
+    t0 = time.perf_counter()
+    sparse = tc.run_sparse(small, mesh,
+                           tc.SparseClosureConfig(capacity=1 << 16),
+                           n_vertices=v)
+    t_sparse = time.perf_counter() - t0
+    got = np.zeros((v, v), bool)
+    got[sparse.paths[:, 0], sparse.paths[:, 1]] = True
+    if sparse.n_paths != dense.n_paths != datasets.grid_closure_pairs(20) \
+            or not np.array_equal(got, np.asarray(dense.paths)[:v, :v]):
+        raise AssertionError(
+            f"sparse {sparse.n_paths} pairs in {sparse.n_rounds} rounds, "
+            f"dense {dense.n_paths} in {dense.n_rounds}")
+    return (f"dp={mesh.shape['data']} | dense Grid{side}: {pairs} pairs, 8 "
+            f"rounds, compose {geom.form}, padded {geom.v_padded}, "
+            f"{t_dense:.1f}s | sparse Grid20: {sparse.n_paths} pairs in "
+            f"{sparse.n_rounds} rounds = dense ({dense.n_rounds} rounds), "
+            f"{t_sparse:.1f}s")
+
+
 def _comm_stage(s: Smoke, comm: str):
     from tpu_distalg.models import ssgd
 
@@ -1044,6 +1098,8 @@ STAGES = (
                    "pallas_hashed._hashed_hbm_gather_kernel"))),
     # one chip builds pallas_als._als_gather_kernel, a mesh no kernel
     ("als_sparse", stage_als_sparse, {}),
+    # one chip builds pallas_closure._compose_kernel, a mesh no kernel
+    ("closure", stage_closure, {}),
     ("ssgd_comm_int8", functools.partial(_comm_stage, comm="int8"),
      dict(min_devices=2)),
     ("ssgd_comm_bucketed",

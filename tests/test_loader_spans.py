@@ -641,7 +641,9 @@ def test_the_manifest_lists_the_new_metrics_where_the_issue_says():
     als = ["als100_253m_sweep1"]
     graph = ["pagerank_g500_24_resident", "pagerank_g500_sharded4_job10"]
     lr = ["lrhash39_46m_frac01", "lrwide11_150m_frac01"]
-    five = [lr[0], *als, *graph, lr[1]]
+    # the five loader cells of PR 49 and the dense closure's, which
+    # PR 52 appended to the three lists its loader's spans feed
+    five = [lr[0], *als, *graph, lr[1], "closure_grid250_round1"]
     want = {"pack_s.als": als, "generate_s.als": als, "heldout_s.als": als,
             "lists_s.als": als, "generate_s.graph": graph,
             "dedup_s.graph": graph, "generate_s.lr": lr,
@@ -649,8 +651,12 @@ def test_the_manifest_lists_the_new_metrics_where_the_issue_says():
             "hbm_resident_gb": five, "padding_pct.als": als,
             "gather_cold_pct.als": als, "padding_pct.graph": graph}
     assert set(want) == set(READERS)
-    tail = manifest["per_layer"][-len(want):]
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index("pack_s.als")
+    tail = manifest["per_layer"][at:at + len(want)]
     assert [m["name"] for m in tail] == list(want)     # appended, in order
+    assert not any(n.split(".")[-1] in ("als", "graph", "lr", "kmeans")
+                   for n in names[at + len(want):])    # then PR 52's
     for m in tail:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}

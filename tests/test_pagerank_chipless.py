@@ -7,8 +7,10 @@ one file (only one process may hold the TPU's library), so sparse ALS'
 solve kernel is compiled here too: a tile of 128 systems at rank 100
 from an owner-major batch of 6144, and the widest rank ``solve_plan``
 admits; a half-sweep's steps at the cell's batch, whose compiled module
-copies no batch of Gramians into another layout; and indexed LR's gather
-from a weight table in HBM at KDD Cup 2012's shape."""
+copies no batch of Gramians into another layout; indexed LR's gather
+from a weight table in HBM at KDD Cup 2012's shape; and the dense
+closure's donated round at BigDatalog's Grid250, which holds the matrix
+it reads and the one it writes and no third."""
 
 import re
 
@@ -202,3 +204,40 @@ def test_hbm_gather_kernel_compiles_at_kdd12s_shape(one_chip):
     assert "_hashed_hbm_gather_kernel" in done.as_text()
     # (the margins' share, 183 rows of 8192 padded to a tile of 8)
     assert done.memory_analysis().output_size_in_bytes == 184 * 8192 * 4
+
+
+def test_closure_round_compiles_at_grid250_and_holds_two_matrices(one_chip):
+    """The byte kernel at the shipped tiles on the padded 63 488 vertices
+    (an 8 MB float32 accumulator, the byte tiles and their bfloat16
+    turns under the VMEM limit the call states), inside the donated
+    round ``transitive_closure.make_round_fn`` compiles: both matrices
+    are aliased to the outputs, and the module holds no third beside
+    them and makes no copy of one. The start state's scatter, cut into
+    blocks of rows, holds the matrix and three blocks."""
+    from jax.sharding import Mesh
+
+    from tpu_distalg.models import transitive_closure as tc
+    from tpu_distalg.ops import pallas_closure
+
+    v = pallas_closure.padded_vertices(63001, "mosaic", 1)
+    assert v == 63488
+    mesh = Mesh(np.array([one_chip._device]).reshape(1, 1),
+                ("data", "model"))
+    geom = tc.DenseGeometry(63001, v, "mosaic", False)
+    matrix = jax.ShapeDtypeStruct((v, v), jnp.int8, sharding=one_chip)
+    words = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    compiled = tc.make_round_fn(mesh, geom).lower(
+        matrix, matrix, words).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"= s8\[63488,63488\]\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert 2 * v * v < mem.argument_size_in_bytes < 2 * v * v + 4096
+    assert mem.alias_size_in_bytes == 2 * v * v
+    assert mem.temp_size_in_bytes < 1 << 20
+
+    assert tc.start_blocks(v) == 8 and tc.start_blocks(46340) == 1
+    arcs = jax.ShapeDtypeStruct((125500,), jnp.int32, sharding=one_chip)
+    start = tc.make_start_fn(mesh, geom).lower(arcs, arcs).compile()
+    mem = start.memory_analysis()
+    assert mem.temp_size_in_bytes < 3.1 * (v // 8) * v

@@ -1516,3 +1516,50 @@ def test_a_loader_span_ends_with_at_least_the_bytes_it_states():
           f"{held} B stated, peak {root['hbm_peak'][0]} B; als:generate "
           f"rose {rise} B for {gen['bytes']} B stated")
     assert us < 1000
+
+
+@pytest.mark.parametrize("density", [1e-4, 0.02, 1.0])
+def test_closure_compose_kernel_is_xlas_boolean_product(density):
+    """``ops/pallas_closure.compose`` compiled, at the shipped tiles, on
+    4096 vertices: bit for bit XLA's own bfloat16 product or-ed into the
+    left operand, and per-tile counts that add up to its pairs."""
+    from tpu_distalg.ops import pallas_closure
+
+    n = 4096
+    kp, kq = jax.random.split(jax.random.key(7))
+    p = jax.random.bernoulli(kp, density, (n, n)).astype(jnp.int8)
+    q = jax.random.bernoulli(kq, density, (n, n)).astype(jnp.int8)
+    new, partials = pallas_closure.compose(p, q)
+    want = (jnp.dot(p.astype(jnp.bfloat16), q.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32) > 0) | (p != 0)
+    np.testing.assert_array_equal(np.asarray(new) != 0, np.asarray(want))
+    assert partials.shape == (n // pallas_closure.TILE_M,
+                              n // pallas_closure.TILE_N)
+    assert int(np.asarray(partials, np.int64).sum()) \
+        == int(np.asarray(want).sum())
+
+
+def test_closure_forms_close_the_sources_grid(tpu_mesh):
+    """The dense form through the model's own choice (the kernel on one
+    chip, XLA's product on a mesh) closes the grid at side 63 to the
+    closed form in 8 doubling rounds; the sparse form, the reference's
+    linear join, reaches the dense form's set at side 12."""
+    from tpu_distalg.models import transitive_closure as tc
+    from tpu_distalg.utils import datasets
+
+    one = tpu_mesh.shape["data"] == 1
+    dense = tc.run(datasets.grid_edges(63, 9), tpu_mesh)
+    assert tc.dense_geometry(4096, tpu_mesh).form == \
+        ("mosaic" if one else "xla")
+    assert dense.n_paths == datasets.grid_closure_pairs(63)
+    assert dense.n_rounds == 8
+    edges = datasets.grid_edges(12, 2)
+    small = tc.run(edges, tpu_mesh, n_vertices=169)
+    sparse = tc.run_sparse(edges, tpu_mesh,
+                           tc.SparseClosureConfig(capacity=1 << 14),
+                           n_vertices=169)
+    assert sparse.n_paths == small.n_paths \
+        == datasets.grid_closure_pairs(12)
+    got = np.zeros((169, 169), bool)
+    got[sparse.paths[:, 0], sparse.paths[:, 1]] = True
+    np.testing.assert_array_equal(got, np.asarray(small.paths)[:169, :169])

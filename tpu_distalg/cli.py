@@ -442,13 +442,28 @@ def main(argv=None):
                         "byte accounting")
     _add_ckpt(p, 5)
 
-    p = sub.add_parser("closure", help="transitive closure")
+    p = sub.add_parser(
+        "closure",
+        help="transitive closure: a V x V byte matrix whose round "
+             "doubles the path length (dense), or a sorted pair "
+             "buffer joined with the edges a round (--sparse)")
     p.add_argument("--n-slices", type=int, default=0)
     _add_mesh_shape(p)
     p.add_argument("--n-vertices", type=int, default=0)
+    p.add_argument("--grid-side", type=int, default=0,
+                   help="close BigDatalog's Grid<N> (SIGMOD'16, Table "
+                        "2): an (N+1) x (N+1) grid, arcs right and "
+                        "down, labels permuted by --seed; the form is "
+                        "picked from the bytes each would hold "
+                        "(Grid250: 63 001 vertices, 1 000 140 875 "
+                        "pairs, dense)")
+    p.add_argument("--seed", type=int, default=0,
+                   help="permutes the grid's vertex labels (--grid-side)")
     p.add_argument("--sparse", action="store_true",
                    help="sort-dedup path-set closure (O(closure) memory "
-                        "— required beyond ~30k vertices). NOTE: with "
+                        "— what a matrix of V x V bytes cannot hold; "
+                        "its round is the reference's linear join). "
+                        "NOTE: with "
                         "--n-vertices the generated graph is a chain "
                         "forest, not the dense mode's Erdős–Rényi graph "
                         "(an ER closure is an inherently quadratic "
@@ -1685,7 +1700,11 @@ def _dispatch(args, jax):
         from tpu_distalg.models import transitive_closure as m
         from tpu_distalg.utils import datasets
 
-        if args.n_vertices == 0:
+        pairs_bound = None
+        if args.grid_side:
+            edges = datasets.grid_edges(args.grid_side, args.seed)
+            pairs_bound = datasets.grid_closure_pairs(args.grid_side)
+        elif args.n_vertices == 0:
             edges = datasets.toy_graph_edges()
         elif args.sparse:
             # bounded-closure graph: an ER graph's closure is Θ(V²) pairs
@@ -1696,7 +1715,19 @@ def _dispatch(args, jax):
         from tpu_distalg.utils import checkpoint as ckpt
 
         mesh = _mesh(args)
-        if args.sparse:
+        sparse = args.sparse
+        if args.grid_side and not sparse:
+            # the generator knows its answer's size: the form comes from
+            # the bytes each would hold (models/transitive_closure.py)
+            picked = m.choose_form(
+                int(edges.max()) + 1, len(edges), mesh,
+                pairs_bound=pairs_bound)
+            sparse = picked["closure_form"] == "sparse"
+            print(f"[closure] {picked['closure_form']}: two byte matrices "
+                  f"{picked['dense_bytes'] / 1e9:.3f} GB, a pair buffer "
+                  f"{picked['sparse_bytes'] / 1e9:.3f} GB, budget "
+                  f"{picked['budget_bytes'] / 1e9:.3f} GB")
+        if sparse:
             def run_once():
                 return m.run_sparse(
                     edges, mesh,
@@ -1712,6 +1743,11 @@ def _dispatch(args, jax):
             run_once, max_restarts=args.max_restarts)
         print(f"The original graph has {res.n_paths} paths "
               f"({res.n_rounds} rounds)")
+        if pairs_bound is not None:
+            print(f"[closure] the grid's closed form: {pairs_bound} pairs"
+                  f" ({'equal' if res.n_paths == pairs_bound else 'NOT EQUAL'})")
+            if res.n_paths != pairs_bound:
+                return 1
 
     elif args.cmd == "als":
         from tpu_distalg.models import als as m

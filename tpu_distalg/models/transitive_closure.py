@@ -5,11 +5,21 @@ the reference joins the full path set against reversed edges, unions, dedups
 and counts every round until the count stops growing (``:27-40``) — a
 shuffle-heavy O(rounds) Spark pipeline with dynamic-size sets. Dynamic set
 semantics don't exist under XLA's static shapes (SURVEY.md §7 hard part #3),
-so the path set is a dense boolean V×V matrix: one round is a boolean
-matmul on the MXU (edge ∘ path composition) + logical-or union, the
-``distinct`` is free (idempotent |), and the fixpoint test compares popcounts
-inside ``lax.while_loop`` — matching the reference's count-based convergence
-(``:38-40``).
+so the dense form holds the path set as a V×V matrix of bytes: one round is
+a boolean product on the MXU and a logical-or union, the ``distinct`` is
+free (idempotent |), and the fixpoint test compares the round's pair count
+with the one before — the reference's count-based convergence (``:38-40``).
+
+The dense round *doubles* (path ∘ path) where the reference's is *linear*
+(edge ∘ path, one more arc a round): the same fixpoint, set and count in
+⌈log2 longest path⌉ + 1 rounds instead of longest path + 1 (a 251 × 251
+grid: 10 against 501, each a product of the whole matrix). The sparse form
+below keeps the reference's linear join.
+
+Two entry points make everything the dense form runs:
+:func:`make_round_fn` the compiled round, :func:`make_start_fn` the start
+state scattered on the device from an edge list. :func:`run`, its
+checkpointed segments and the benchmark's adapter all use those two.
 """
 
 from __future__ import annotations
@@ -19,11 +29,12 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 from jax.sharding import Mesh
 
 from tpu_distalg.ops import graph as gops
-from tpu_distalg.parallel import DATA_AXIS, partition
+from tpu_distalg.parallel import DATA_AXIS, mesh_on_tpu, partition
+from tpu_distalg.telemetry import events as tevents
+from tpu_distalg.telemetry import names as tnames
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,7 +46,214 @@ class ClosureConfig:
 class ClosureResult:
     paths: jax.Array  # (V, V) bool reachability
     n_paths: int      # the reference's final paths.count() (:42)
-    n_rounds: int
+    n_rounds: int     # doublings, the one that saw the count stand included
+
+
+@dataclasses.dataclass(frozen=True)
+class DenseGeometry:
+    """What the dense form's two entry points are built from: the
+    graph's vertices, the matrix's padded side, which product composes
+    (``ops/pallas_closure.compose_form``) and whether the kernel is
+    interpreted (no TPU under the mesh)."""
+
+    n_vertices: int
+    v_padded: int
+    form: str
+    interpret: bool
+
+    @property
+    def matrix_bytes(self) -> int:
+        return self.v_padded * self.v_padded
+
+
+def dense_geometry(n_vertices: int, mesh: Mesh) -> DenseGeometry:
+    """The geometry for a graph on a mesh, from the platform, the shards
+    and the size alone: the padded vertices are isolated (no edges) and
+    add no paths."""
+    from tpu_distalg.ops import pallas_closure
+
+    n_shards = mesh.shape[DATA_AXIS]
+    on_tpu = mesh_on_tpu(mesh)
+    form = pallas_closure.compose_form(n_vertices, on_tpu, n_shards)
+    return DenseGeometry(
+        n_vertices=n_vertices,
+        v_padded=pallas_closure.padded_vertices(n_vertices, form, n_shards),
+        form=form, interpret=not on_tpu)
+
+
+def make_round_fn(mesh: Mesh, geom: DenseGeometry):
+    """The compiled round: ``(spare, paths, count) -> (paths', spare',
+    count', still)``. ``spare`` and ``paths`` are ``int8[V, V]`` and both
+    donated: the new matrix is written into the spare's buffer (by the
+    kernel; XLA's form is given it as room) and the matrix that was read
+    is handed back as the next round's spare, so a chain of rounds holds
+    two matrices, allocates nothing and copies nothing. The spare comes
+    first because XLA pairs a donated argument with the result of the
+    same position: crossed, it keeps its promise with a copy of each.
+    ``count`` is ``ops/graph.path_count``'s two words and ``still`` says
+    that the round added no pair (the fixpoint test), all on the
+    device."""
+
+    def one_round(spare, paths, count):
+        with jax.named_scope(tnames.CLOSURE_COMPOSE):
+            new, partials = gops.closure_step(
+                paths, into=spare, form=geom.form,
+                interpret=geom.interpret)
+            new = partition.constrain(new, "paths", "closure_dense", mesh)
+        with jax.named_scope(tnames.CLOSURE_COUNT):
+            new_count = gops.path_count(partials)
+            still = jnp.all(new_count == count)
+        return new, paths, new_count, still
+
+    return jax.jit(one_round, donate_argnums=(0, 1))
+
+
+#: the cells of one scatter of the start state: XLA:TPU compiles a
+#: scatter into 2^31 cells or more for 33 to 43 s (Grid250's 4.03e9: my
+#: compiles, PR 52) and into fewer in about a second
+START_SCATTER_CELLS = 1 << 29
+
+
+def start_blocks(v_padded: int) -> int:
+    """In how many blocks of rows the start state is scattered: one
+    while the matrix is under 2^31 cells, else the fewest equal blocks of
+    at most ``START_SCATTER_CELLS`` (Grid250's 63 488: 8 of 7936 rows)."""
+    if v_padded * v_padded < 1 << 31:
+        return 1
+    least = -(-v_padded * v_padded // START_SCATTER_CELLS)
+    return next(n for n in range(least, v_padded + 1) if v_padded % n == 0)
+
+
+def make_start_fn(mesh: Mesh, geom: DenseGeometry):
+    """The compiled start state: ``(src, dst) -> (paths0, count0)``, the
+    edge list scattered into a zero matrix on the device (no V × V array
+    on the host) and its pairs counted there; an arc given twice counts
+    once."""
+    v = geom.v_padded
+    n_blocks = start_blocks(v)
+    rows = v // n_blocks
+
+    def block(src, dst, b):
+        # the arcs whose source lies in block b; the others fall past
+        # the block's last row and are dropped
+        r = src - b * rows
+        r = jnp.where((r >= 0) & (r < rows), r, rows)
+        return jnp.zeros((rows, v), jnp.int8).at[r, dst].set(1, mode="drop")
+
+    def start(src, dst):
+        if n_blocks == 1:
+            paths = block(src, dst, 0)
+        else:
+            paths = jax.lax.fori_loop(
+                0, n_blocks,
+                lambda b, p: jax.lax.dynamic_update_slice(
+                    p, block(src, dst, b), (b * rows, 0)),
+                jnp.zeros((v, v), jnp.int8))
+        paths = partition.constrain(paths, "paths", "closure_dense", mesh)
+        return paths, gops.path_count(
+            jnp.sum(paths, axis=1, dtype=jnp.int32))
+
+    return jax.jit(start)
+
+
+@dataclasses.dataclass
+class DenseJob:
+    """A dense closure job as ``closure:prepare`` leaves it: the
+    geometry, the edge list on the device, the compiled start, the start
+    state it made and the spare matrix the first round writes to
+    (``paths`` and ``spare`` are donated to that round)."""
+
+    geom: DenseGeometry
+    n_edges: int
+    src: jax.Array
+    dst: jax.Array
+    start_fn: object
+    paths: jax.Array
+    spare: jax.Array
+    count: jax.Array
+
+    def start(self):
+        """``(paths0, count0)`` again, from the edge list on the device:
+        the same compiled scatter, nothing from the host."""
+        return self.start_fn(self.src, self.dst)
+
+
+def prepare_dense(edges: np.ndarray, mesh: Mesh,
+                  n_vertices: int | None = None) -> DenseJob:
+    """``closure:prepare``: the edge list made (``prepare_edges``: arcs
+    given twice once), laid on the device, the start matrix scattered
+    there."""
+    devices = list(mesh.local_devices)
+    with tevents.span("closure:prepare", devices):
+        el = gops.prepare_edges(edges, n_vertices)
+        geom = dense_geometry(el.n_vertices, mesh)
+        start_fn = make_start_fn(mesh, geom)
+        src, dst = jnp.asarray(el.src), jnp.asarray(el.dst)
+        paths, count = start_fn(src, dst)
+        spare = jnp.zeros_like(paths)
+        jax.block_until_ready((count, spare))
+    return DenseJob(geom, el.n_edges, src, dst, start_fn, paths, spare,
+                    count)
+
+
+def run(edges: np.ndarray, mesh: Mesh,
+        config: ClosureConfig = ClosureConfig(),
+        n_vertices: int | None = None, *,
+        checkpoint_dir: str | None = None,
+        checkpoint_every: int = 8) -> ClosureResult:
+    job = prepare_dense(edges, mesh, n_vertices)
+    geom, paths, count, spare = job.geom, job.paths, job.count, job.spare
+    job.paths = job.spare = None        # the first round donates both
+    cap = (config.max_iterations if config.max_iterations is not None
+           else geom.v_padded + 1)
+    round_fn = make_round_fn(mesh, geom)
+
+    def rounds(paths, count, still, it, seg):
+        # up to ``seg`` more rounds from the carried state; the host
+        # reads one flag a round (a round is a product of the whole
+        # matrix: the read costs nothing beside it). A round past the
+        # fixpoint is never run, so segments of any length run the same
+        # sequence of rounds, bit for bit. The spare is handed from
+        # round to round and never saved.
+        nonlocal spare
+        it_hi = min(it + seg, cap)
+        while it < it_hi and not still:
+            paths, spare, count, flag = round_fn(spare, paths, count)
+            still, it = bool(flag), it + 1
+        return paths, count, still, it
+
+    def run_seg(seg, state, t0):
+        paths, count, still, it = rounds(
+            state["paths"], state["cnt"], bool(state["still"]),
+            int(state["it"]), seg)
+        new = {"paths": paths, "cnt": count,
+               "still": np.bool_(still), "it": np.int32(it)}
+        return new, np.float32([gops.count_of(count)])
+
+    # the fields are what ``tda report``'s closure line says of the fit
+    with tevents.span("closure:fit", list(mesh.local_devices),
+                      closure_form="dense", compose_form=geom.form,
+                      vertices=geom.n_vertices, v_padded=geom.v_padded,
+                      matrix_bytes=geom.matrix_bytes):
+        if checkpoint_dir is None:
+            paths, count, _, n_rounds = rounds(paths, count, False, 0, cap)
+        else:
+            from tpu_distalg.utils import checkpoint as ckpt
+
+            state, _, _ = ckpt.run_segmented(
+                checkpoint_dir, checkpoint_every, cap, lambda seg: seg,
+                run_seg,
+                {"paths": paths, "cnt": count, "still": np.bool_(False),
+                 "it": np.int32(0)},
+                tag="closure_dense",
+                stop_when=lambda s: bool(s["still"]))
+            paths, count = jnp.asarray(state["paths"]), state["cnt"]
+            n_rounds = int(state["it"])
+    n_paths = gops.count_of(count)
+    tevents.counter("closure.rounds", n_rounds)
+    tevents.counter("closure.pairs", n_paths)
+    return ClosureResult(paths=paths != 0, n_paths=n_paths,
+                         n_rounds=n_rounds)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,75 +278,6 @@ class SparseClosureResult:
     paths: np.ndarray  # (n_paths, 2) distinct (x, z) pairs
     n_paths: int
     n_rounds: int
-
-
-def run(edges: np.ndarray, mesh: Mesh,
-        config: ClosureConfig = ClosureConfig(),
-        n_vertices: int | None = None, *,
-        checkpoint_dir: str | None = None,
-        checkpoint_every: int = 8) -> ClosureResult:
-    el = gops.prepare_edges(edges, n_vertices)
-    n_shards = mesh.shape[DATA_AXIS]
-    # pad vertex count so path-matrix rows shard evenly; padded vertices are
-    # isolated (no edges) and add no paths
-    V = -(-el.n_vertices // n_shards) * n_shards
-    cap = config.max_iterations if config.max_iterations is not None else V + 1
-
-    adj = np.zeros((V, V), dtype=bool)
-    adj[el.src, el.dst] = True
-    edges_bool = jnp.asarray(adj)
-
-    def make_seg_fn(seg):
-        # one compiled segment: up to ``seg`` more rounds from the
-        # carried (paths, old_cnt, cnt, it). With seg=cap this IS the
-        # straight fixpoint; smaller seg inserts checkpoint boundaries
-        # without changing the round sequence (bitwise-identical).
-        @jax.jit
-        def seg_fix(eb, paths, old_cnt, cnt, it):
-            it_hi = jnp.minimum(it + seg, cap)
-
-            def cond(state):
-                _, old, c, i = state
-                return (c != old) & (i < it_hi)
-
-            def body(state):
-                paths, _, c, i = state
-                new_paths = gops.closure_step(paths, eb)
-                new_paths = partition.constrain(
-                    new_paths, "paths", "closure_dense", mesh)
-                return new_paths, c, gops.path_count(new_paths), i + 1
-
-            return lax.while_loop(cond, body, (paths, old_cnt, cnt, it))
-
-        return seg_fix
-
-    state0 = (edges_bool, jnp.int32(-1),  # paths start as the edge set
-              gops.path_count(edges_bool), jnp.int32(0))
-
-    if checkpoint_dir is None:
-        paths, _, cnt, rounds = make_seg_fn(cap)(edges_bool, *state0)
-        return ClosureResult(
-            paths=paths, n_paths=int(cnt), n_rounds=int(rounds)
-        )
-
-    from tpu_distalg.utils import checkpoint as ckpt
-
-    def run_seg(fn, state, t0):
-        paths, old, cnt, it = fn(edges_bool, state["paths"],
-                                 state["old"], state["cnt"],
-                                 state["it"])
-        new = {"paths": paths, "old": old, "cnt": cnt, "it": it}
-        return new, np.asarray(cnt, np.float32)[None]
-
-    state, _, _ = ckpt.run_segmented(
-        checkpoint_dir, checkpoint_every, cap, make_seg_fn, run_seg,
-        {"paths": state0[0], "old": state0[1], "cnt": state0[2],
-         "it": state0[3]},
-        tag="closure_dense",
-        stop_when=lambda s: int(s["cnt"]) == int(s["old"]))
-    return ClosureResult(paths=jnp.asarray(state["paths"]),
-                         n_paths=int(state["cnt"]),
-                         n_rounds=int(state["it"]))
 
 
 def run_sparse(edges: np.ndarray, mesh: Mesh,
@@ -321,17 +470,61 @@ def run_sparse(edges: np.ndarray, mesh: Mesh,
     )
 
 
+#: the bytes a closure may plan with where nobody says otherwise:
+#: :func:`run_sparse_auto`'s budget, and :func:`choose_form`'s where the
+#: devices keep no memory statistics (the CPU)
+DEFAULT_BUDGET_BYTES = 4 << 30
+
 #: per-path buffer cost of one :func:`run_sparse` fixpoint round:
 #: px/pz (2 int32) plus the two-key sort's union copy at C + J slots
 #: (J defaults to 2C) — ~8 B/slot across ~4C live slots. The auto-
 #: sizer budgets against THIS figure, so its refusal names real bytes.
 SPARSE_BYTES_PER_CAPACITY_SLOT = 32
 
+def choose_form(n_vertices: int, n_edges: int, mesh: Mesh, *,
+                pairs_bound: int | None = None,
+                budget_bytes: int | None = None) -> dict:
+    """``dense`` or ``sparse`` from the bytes each form would hold, and
+    the numbers that decided it (``closure:fit``'s fields; the counter
+    ``closure.form`` counts the decisions).
+
+    The dense form holds two V × V byte matrices (the one a round reads,
+    the one it writes) whatever the answer; the sparse form's buffer must
+    hold every pair of the answer at ``SPARSE_BYTES_PER_CAPACITY_SLOT``
+    (``run_sparse_auto``'s rule), which nobody knows beforehand:
+    ``pairs_bound`` is what the caller can say (a generator's closed
+    form), V^2 otherwise. The smaller of the two that fits the budget
+    (three quarters of the mesh's device memory) runs; neither: raises.
+    BigDatalog's Grid250: 8.06 GB dense against 32.0 GB sparse."""
+    geom = dense_geometry(n_vertices, mesh)
+    dense = 2 * geom.matrix_bytes
+    pairs = n_vertices * n_vertices
+    if pairs_bound is not None:
+        pairs = min(pairs, int(pairs_bound))
+    sparse = max(pairs, 8 * n_edges, 1024) * SPARSE_BYTES_PER_CAPACITY_SLOT
+    if budget_bytes is None:
+        limit = tevents.memory_limit(mesh.local_devices)
+        budget_bytes = limit * 3 // 4 if limit else DEFAULT_BUDGET_BYTES
+    fits = [(b, f) for b, f in ((dense, "dense"), (sparse, "sparse"))
+            if b <= budget_bytes]
+    if not fits:
+        raise ValueError(
+            f"closure refused: {n_vertices} vertices need {dense / 1e9:.2f} "
+            f"GB as two byte matrices and up to {sparse / 1e9:.2f} GB as a "
+            f"pair buffer of {pairs} pairs, over the "
+            f"{budget_bytes / 1e9:.2f} GB budget")
+    picked = {"closure_form": min(fits)[1], "dense_bytes": dense,
+              "sparse_bytes": sparse, "budget_bytes": int(budget_bytes),
+              "compose_form": geom.form, "v_padded": geom.v_padded}
+    tevents.counter("closure.form")
+    tevents.emit("closure_form", **picked)
+    return picked
+
 
 def run_sparse_auto(edges: np.ndarray, mesh: Mesh, *,
                     n_vertices: int | None = None,
                     start_capacity: int | None = None,
-                    budget_bytes: int = 4 << 30,
+                    budget_bytes: int = DEFAULT_BUDGET_BYTES,
                     max_iterations: int | None = None,
                     checkpoint_dir: str | None = None,
                     checkpoint_every: int = 8) -> SparseClosureResult:

@@ -132,19 +132,54 @@ def block_contribs(ranks: jax.Array, rows: jax.Array, lo: jax.Array,
                        indices_sorted=True)
 
 
-def closure_step(paths: jax.Array, edges_bool: jax.Array) -> jax.Array:
-    """One linear-closure round: new (x,z) ≙ edge (x,y) ∘ path (y,z), then
-    union — the reference's join-with-reversed-edges + union + distinct
-    (``transitive_closure.py:33-37``) as a boolean matmul + logical-or.
+def closure_step(paths: jax.Array, other: jax.Array | None = None, *,
+                 into: jax.Array | None = None, form: str = "xla",
+                 interpret: bool = False):
+    """One closure round over ``int8`` 0/1 matrices: ``paths | (paths ∘
+    other > 0)`` and the round's partial pair counts. ``other`` is
+    ``paths`` itself by default, the *doubling* round: paths of at most L
+    arcs become paths of at most 2 L, so a graph whose longest path has L
+    arcs closes in ⌈log2 L⌉ rounds and one more sees the count stand
+    still. The reference's linear round (``transitive_closure.py:33-37``:
+    join with the edges, union, distinct) is ``closure_step(paths,
+    edges)`` up to the side the arc is added on; both reach the same
+    fixpoint, the same set and the same count.
 
-    Boolean matmul rides the MXU as a float matmul > 0 test.
+    ``form`` is ``pallas_closure.compose_form``'s: ``mosaic`` is the byte
+    kernel (a bfloat16 pass on the MXU, the partials one a tile), ``xla``
+    a float32 product of the whole operands (the MXU's default precision
+    rounds 0 and 1 to themselves) with one partial a row. Either way
+    every partial is under 2^31 and :func:`path_count` adds them up.
+    ``into`` is a spare matrix the kernel writes its result to (XLA's
+    form finds its own room: a donated spare is room enough).
     """
-    composed = (
-        edges_bool.astype(jnp.float32) @ paths.astype(jnp.float32)
-    ) > 0.0
-    return paths | composed
+    other = paths if other is None else other
+    if form == "mosaic":
+        from tpu_distalg.ops import pallas_closure
+
+        return pallas_closure.compose(paths, other, into,
+                                      interpret=interpret)
+    composed = (paths.astype(jnp.float32) @ other.astype(jnp.float32)) > 0.0
+    new = (composed | (paths != 0)).astype(jnp.int8)
+    return new, jnp.sum(new, axis=1, dtype=jnp.int32)
 
 
-def path_count(paths: jax.Array) -> jax.Array:
-    """``paths.count()`` (``transitive_closure.py:38``)."""
-    return jnp.sum(paths.astype(jnp.int32))
+def path_count(partials: jax.Array) -> jax.Array:
+    """``paths.count()`` (``transitive_closure.py:38``) as ``int32[2]``
+    words, ``total = words[0] * 2**16 + words[1]`` with ``words[1] <
+    2**16``: a V x V matrix holds up to V^2 pairs, past int32 from V
+    46 341 on, and 64-bit integers are off. ``partials`` are non-negative
+    int32 counts (a row's, a tile's); three byte-wise sums cannot wrap
+    below 2^23 partials or a total of 2^46, and equal totals have equal
+    words."""
+    p = partials.reshape(-1)
+    lo = jnp.sum(p & 0xFF)
+    mid = jnp.sum((p >> 8) & 0xFF) + (lo >> 8)
+    hi = jnp.sum(p >> 16) + (mid >> 8)
+    return jnp.stack([hi, ((mid & 0xFF) << 8) | (lo & 0xFF)])
+
+
+def count_of(words) -> int:
+    """The Python integer of :func:`path_count`'s words."""
+    hi, lo = (int(w) for w in np.asarray(words))
+    return (hi << 16) | lo

@@ -270,6 +270,15 @@ def memory(devices) -> list[tuple[int, int]] | None:
     return out or None
 
 
+def memory_limit(devices) -> int | None:
+    """The bytes the backend's allocator may hand out on ``devices``
+    together (``bytes_limit``), or ``None`` where a device keeps no
+    stats (the CPU), and for no devices."""
+    limits = [int((d.memory_stats() or {}).get("bytes_limit", 0))
+              for d in devices]
+    return sum(limits) if limits and all(limits) else None
+
+
 def gauge(name: str, value, **fields) -> None:
     emit("gauge", name=name, value=value, **fields)
 

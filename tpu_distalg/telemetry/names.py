@@ -66,3 +66,12 @@ ALS_SYNC = "tda.als.sync"      # the all-gather of a half's rows and the
 #                                psum of its sums; empty on one shard
 ALS_UPDATE = "tda.als.update"  # what is left: the rows' write, the
 #                                training and held-out RMSE
+# the two parts of a dense closure round (models/transitive_closure.py);
+# the benchmark's compose_ms_per_round.closure and closure_mxu_roofline
+# read the first, count_ms_per_round.closure the second
+CLOSURE_COMPOSE = "tda.closure.compose"  # the boolean product or-ed into
+#                                          the paths: the byte kernel with
+#                                          its per-tile counts, or XLA's
+#                                          product and the rows' sums
+CLOSURE_COUNT = "tda.closure.count"      # the partials' sum in two words
+#                                          and the fixpoint test

@@ -221,6 +221,7 @@ def summarize(evts: list[dict]) -> dict:
     addr_calls: list[tuple] = []
     als_forms: list[str] = []
     ranks_forms: list[str] = []
+    closure_forms: list[str] = []
     t_wall = [e["t_wall"] for e in evts if "t_wall" in e]
     for e in evts:
         ev = e.get("ev")
@@ -248,6 +249,24 @@ def summarize(evts: list[dict]) -> dict:
                          f"the chunks")
             if form not in ranks_forms:
                 ranks_forms.append(form)
+        if ev in ("span_start", "closure_form") and e.get("closure_form"):
+            # the closure says which form ran (closure:fit) and, where
+            # the form was picked from the bytes each would hold
+            # (transitive_closure.choose_form), what decided it
+            if ev == "closure_form":
+                form = (f"{e['closure_form']} picked: two byte matrices "
+                        f"{e.get('dense_bytes', 0) / 1e9:.3f} GB against a "
+                        f"pair buffer of {e.get('sparse_bytes', 0) / 1e9:.3f}"
+                        f" GB, budget {e.get('budget_bytes', 0) / 1e9:.3f} "
+                        f"GB")
+            else:
+                form = (f"{e['closure_form']}, compose "
+                        f"{e.get('compose_form', '?')} over "
+                        f"{e.get('v_padded', '?')} of "
+                        f"{e.get('vertices', '?')} vertices held, a matrix "
+                        f"{e.get('matrix_bytes', 0) / 1e9:.3f} GB")
+            if form not in closure_forms:
+                closure_forms.append(form)
         if ev == "span_start":
             open_spans[e.get("name", "?")] = \
                 open_spans.get(e.get("name", "?"), 0) + 1
@@ -414,6 +433,7 @@ def summarize(evts: list[dict]) -> dict:
         "addr_calls": addr_calls,
         "als_forms": als_forms,
         "ranks_forms": ranks_forms,
+        "closure_forms": closure_forms,
         "unfinished_phases": sorted(
             k for k, v in open_spans.items() if v > 0),
         "marks": marks,
@@ -530,6 +550,12 @@ def render(s: dict) -> str:
         lines.append(f"R layout: {', '.join(s['als_forms'])}")
     if s.get("ranks_forms"):
         lines.append(f"ranks table: {', '.join(s['ranks_forms'])}")
+    if s.get("closure_forms"):
+        c = s.get("counters") or {}
+        lines.append(
+            f"closure: {'; '.join(s['closure_forms'])}; "
+            f"{c.get('closure.rounds', '?')} round(s), "
+            f"{c.get('closure.pairs', '?')} pairs")
     if s.get("dist_forms"):
         lines.append(f"distances: {', '.join(s['dist_forms'])}")
     if s.get("sums_forms"):
@@ -821,6 +847,9 @@ SUMMARY_ONLY_COUNTERS = (
     "quarantines",
     "preemptions",
     "closure.capacity_regrows",
+    "closure.form",             # the closure line says which and why
+    "closure.pairs",            # (closure:fit's fields, the
+    "closure.rounds",           #  closure_form event, these two)
     "data.*",                   # gather/h2d byte+batch bookkeeping
     "faults.*",                 # the fault table reads the events
     "graph.ingest_edges",

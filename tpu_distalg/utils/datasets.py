@@ -604,6 +604,34 @@ def chain_forest_edges(n_vertices: int, chain_len: int = 8) -> np.ndarray:
     return np.stack([src, src + 1], axis=1).astype(np.int64)
 
 
+def grid_edges(side: int, seed: int | None = None) -> np.ndarray:
+    """BigDatalog's ``Grid<side>`` (Shkapsky et al., SIGMOD'16, Table 2):
+    a (side + 1) × (side + 1) grid, each vertex with an arc to its right
+    and to its lower neighbour, ``2 · side · (side + 1)`` arcs, the
+    longest path ``2 · side`` arcs (Grid250: 63 001 vertices, 125 500
+    arcs, 500 across). ``seed`` permutes the vertex labels (a Datalog
+    engine's ids carry no order; row-major labels would leave every arc
+    pointing up and half the path matrix empty by construction);
+    ``None`` keeps them row-major. Shape (E, 2)."""
+    n = side + 1
+    ids = np.arange(n * n, dtype=np.int64).reshape(n, n)
+    src = np.concatenate([ids[:, :-1].ravel(), ids[:-1, :].ravel()])
+    dst = np.concatenate([ids[:, 1:].ravel(), ids[1:, :].ravel()])
+    if seed is not None:
+        perm = np.random.default_rng(int(seed)).permutation(n * n)
+        src, dst = perm[src], perm[dst]
+    return np.stack([src, dst], axis=1)
+
+
+def grid_closure_pairs(side: int) -> int:
+    """The pairs of :func:`grid_edges`' transitive closure: a vertex
+    reaches every vertex to its right and below, itself excepted, so
+    ``(n (n + 1) / 2)^2 - n^2`` at ``n = side + 1`` (Grid250: the
+    source's published 1 000 140 875; Grid150: 131 675 775)."""
+    n = side + 1
+    return (n * (n + 1) // 2) ** 2 - n * n
+
+
 def toy_graph_edges() -> np.ndarray:
     """The reference's 4-edge toy graph (``pagerank.py:35-38``,
     ``transitive_closure.py:18``), 0-indexed."""
