@@ -5,11 +5,15 @@ costs on the chip at the ``als100_253m_sweep1`` cell's shape (a block of
 items' table of 663 560 rows and the users' of 1 032 200), ms a block,
 least of three. Kept as the way to re-read ``GATHER_VMEM_BYTES`` in
 ``tpu_distalg/ops/als_sparse.py`` (the ``heavy`` / ``five`` rows) and
-the forms PR 46 chose among (the ``form.*`` rows):
+the forms PRs 46 and 53 chose among (the ``form.*`` rows):
 
     chiprun -- python3 scripts/step0_als_gather.py
     JAX_PLATFORMS=cpu python3 scripts/step0_als_gather.py --rehearse
     JAX_PLATFORMS=cpu python3 scripts/step0_als_gather.py --bundles
+
+``--q2`` beside any of the three keeps PR 53's rows alone (what ships,
+what shipped before it, the packed form; the mix, all hot, all cold;
+both tables).
 
 Rows of the output, one a line as ``[step0] <name> <ms> [<ns a slot>]``:
 
@@ -19,8 +23,8 @@ Rows of the output, one a line as ``[step0] <name> <ms> [<ns a slot>]``:
                                  (``pallas_als.gather_rows_resident``
                                  on ``als_sparse.gather_lists``' arrays)
                                  with that resident range
-  <table>.form.<form>.<block>    a form of PR 46's Step 0 (below), the
-                                 heavy range resident
+  <table>.form.<form>.<block>    a form of PR 46's or PR 53's Step 0
+                                 (below), the heavy range resident
   <table>.gram.<form>.<block>    ``block_gramians`` whole at the heavy
                                  class's depth (gather, the two lanes,
                                  the product): ``xla``, ``i`` (the
@@ -41,7 +45,7 @@ for that half: the plan's count of slots in the heavy class, in the
 classes between and below). Every result is compared with XLA's, bit
 for bit. A summary lands in ``chiprun_out/step0_als_gather.json``.
 
-The forms (``_form_kernel``; none but the last is in
+The forms (``_form_kernel``, ``_q2_kernel``; none but ``ships`` is in
 ``ops/pallas_als.py``): a name is the cold list's maker, what pass 1
 reads, and who writes the rating's lane.
 
@@ -63,14 +67,34 @@ reads, and who writes the rating's lane.
            a group
   <f>.p    the lane in pass 1 (the rating through SMEM), then again
            over the cold list once the rows have landed
-  ships    ``ii.h.q`` with two 16-bit positions a word of the list and
-           the counts by scalar prefetch: ``pallas_als``' own
+  ii.h.q2  ``ii.h.q`` with two 16-bit positions a word of the list, the
+           counts by scalar prefetch and 32 slots a trip of pass 1:
+           ``pallas_als``' own from PR 46 to PR 53 (``_q2_kernel``)
+  ships    ``iii.q2``, form (a) of PR 53: pass 1 loads the resident row
+           ready made (the loader's clamp), pass 2 a cold slot's table
+           row from the pack's own indices, both through SMEM, 4 B a
+           slot each: ``pallas_als``' own
+  iii.b.q2 form (b) of PR 53, not shipped: pass 1 as ``ships``; the cold
+           slots' table rows alone, in list order, a chunk's at an
+           offset of whole 1024-word tiles of one array in HBM
+           (``q2_arrays``: by NumPy here; a loader would pack it by a
+           scan), copied into SMEM by hand, started before pass 1 and
+           waited after it. 1.8 GB less resident at the cell's shape; its
+           pass 2 pays an address a row (``base + u``) where ``idx[a]``
+           takes the position it holds
+
+One v5e, PR 53, call 1 (``--q2``; ms a block inside one program, items'
+table / users'): at the mix ``ii.h.q2`` 0.6718 / 0.7684, ``ships``
+0.5998 / 0.6883, ``iii.b.q2`` 0.6102 / 0.7072; all hot 0.5106 / 0.5141,
+0.4363 / 0.4462, 0.4433 / 0.4453; all cold 1.2882 / 1.2962, 1.1888 /
+1.1996, 1.2308 / 1.2381. By ``--bundles`` (pass 1 a 32 slots / pass 2 a
+16 copies / the lane): 69 / 81 / 456, 51 / 75 / 451, 51 / 83 / 451.
 
 ``--bundles`` compiles every form for a described v5e with libtpu's
 dump in a temporary directory and prints the bundles of each loop of the
 static schedule, post-RA, in the kernel's order (pass 1 a trip of 16
-slots, 32 in what ships; pass 2 a trip of 16 copies, pass 3 a wait,
-then the lane's).
+slots, 32 in the three above; pass 2 a trip of 16 copies, pass 3 a
+wait, then the lane's).
 
 PR 37's readings (``PERF.md`` section 6) also name forms that are not
 in any code any more, each timed once on the ``.x8`` rows: 8 slots a
@@ -104,6 +128,9 @@ FIVE = 4           # light classes before the heavy one in ``five``
 FORMS = ("i", "i.v", "ii", "ii.h", "iii", "ii.h.t", "ii.h.q", "ii.h.p",
          "iii.q")
 FEW = ("i", "ii.h", "iii", "ii.h.q")    # the forms the users' table times
+# what shipped until PR 53, and PR 53's form that did not ship
+# (``_q2_kernel``); ``ships`` is timed beside them
+Q2 = ("ii.h.q2", "iii.b.q2")
 LIST_PAD = 1024    # a 1-D block in HBM is whole tiles of 1024 words
 UNROLL = 16        # slots a trip of the forms' pass 1 (what ships: 32)
 
@@ -302,6 +329,184 @@ def form_gather(form: str, table, hot_row0: int, arrays: dict, *,
     )(*args)
 
 
+def _q2_kernel(*refs, hot_row0, lane, packed):
+    """``pallas_als._als_gather_kernel`` as it stood until PR 53
+    (``ii.h.q2``: pass 1 clamps the re-based index, pass 2 adds
+    ``hot_row0`` back to it) and the packed form of PR 53 (``iii.b.q2``:
+    pass 1 loads the resident row ready made, pass 2 the cold slots'
+    table rows in list order, a chunk's at its offset of an array in
+    HBM, copied into SMEM by hand while pass 1 runs)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_distalg.ops.pallas_als import FETCH, LANES, TILE, UNROLL
+
+    refs = list(refs)
+    n_cold_ref = refs.pop(0)
+    off_ref = refs.pop(0) if packed else None
+    row_ref, cold_ref, val_ref, tab_ref = refs[:4]
+    refs = refs[4:]
+    list_hbm = refs.pop(0) if packed else None
+    out_ref, res_ref = refs[:2]
+    refs = refs[2:]
+    list_ref = refs.pop(0) if packed else None
+    res_sem, row_sem = refs[:2]
+    slots = row_ref.shape[0]
+    last = res_ref.shape[0] - 1
+    c = pl.program_id(0)
+
+    @pl.when(c == 0)
+    def _load():
+        cp = pltpu.make_async_copy(
+            tab_ref.at[pl.ds(hot_row0, res_ref.shape[0]), :], res_ref,
+            res_sem)
+        cp.start()
+        cp.wait()
+
+    if packed:
+        mine = pltpu.make_async_copy(
+            list_hbm.at[pl.ds(pl.multiple_of(off_ref[c] * LIST_PAD,
+                                             LIST_PAD), slots)],
+            list_ref, refs[2])
+        mine.start()
+
+    def some(t, carry):
+        first = pl.multiple_of(t * UNROLL, UNROLL)
+        rows = out_ref.at[pl.ds(first, UNROLL), :]
+        for u in range(UNROLL):
+            row = row_ref[first + u]
+            if not packed:
+                row = jnp.minimum(row.astype(jnp.uint32),
+                                  jnp.uint32(last)).astype(jnp.int32)
+            rows[pl.ds(u, 1), :] = res_ref[pl.ds(row, 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, slots // UNROLL, some, 0)
+    if packed:
+        mine.wait()
+    trips = (n_cold_ref[c] + FETCH - 1) // FETCH
+
+    def fetch(g, carry):
+        first = pl.multiple_of(g * (FETCH // 2), FETCH // 2)
+        words = [cold_ref[first + u] for u in range(FETCH // 2)]
+        at = [a for w in words for a in (w & 0xFFFF, w >> 16)]
+        if packed:
+            base = pl.multiple_of(g * FETCH, FETCH)
+            rows = [list_ref[base + u] for u in range(FETCH)]
+        else:
+            rows = [row_ref[a] + hot_row0 for a in at]
+        for a, h in zip(at, rows):
+            pltpu.make_async_copy(
+                tab_ref.at[pl.ds(h, 1), :], out_ref.at[pl.ds(a, 1), :],
+                row_sem).start()
+        return carry
+
+    jax.lax.fori_loop(0, trips, fetch, 0)
+
+    def land(g, carry):
+        pltpu.make_async_copy(
+            tab_ref.at[pl.ds(0, FETCH), :], out_ref.at[pl.ds(0, FETCH), :],
+            row_sem).wait()
+        return carry
+
+    jax.lax.fori_loop(0, trips, land, 0)
+    at_lane = jax.lax.broadcasted_iota(jnp.int32, (TILE, LANES), 1) == lane
+
+    def values(q, carry):
+        vals = val_ref[pl.ds(pl.multiple_of(q * 8, 8), 8), :]
+        groups = [pltpu.roll(vals, (lane - m) % LANES, axis=1)
+                  if (lane - m) % LANES else vals for m in range(LANES)]
+        pltpu.store(out_ref.at[pl.ds(pl.multiple_of(q * TILE, TILE), TILE), :],
+                    jnp.concatenate(groups, axis=0), mask=at_lane)
+        return carry
+
+    jax.lax.fori_loop(0, slots // TILE, values, 0)
+
+
+def q2_gather(form: str, table, a: dict, *, hot_row0: int, k: int = K,
+              interpret: bool = False):
+    """One block through ``ii.h.q2`` or ``iii.b.q2``: ``a`` is a block
+    of :func:`q2_arrays`'."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_distalg.ops.pallas_als import LANES, VMEM_SLACK, chunk_rows
+
+    packed = form == "iii.b.q2"
+    row_b = a["row" if packed else "rebased"]
+    cr = chunk_rows(row_b.shape[0])
+    n_res = table.shape[0] - hot_row0
+    slots = cr * LANES
+    n_pre = 2 if packed else 1
+
+    def smem(n):
+        return pl.BlockSpec((n,), lambda c, *pre: (c,),
+                            memory_space=pltpu.SMEM)
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_q2_kernel, hot_row0=hot_row0, lane=k,
+                          packed=packed),
+        name="_als_gather_kernel",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_pre,
+            grid=(row_b.shape[0] // cr,),
+            in_specs=[smem(slots), smem(slots // 2),
+                      pl.BlockSpec((cr, LANES), lambda c, *pre: (c, 0)),
+                      hbm, *([hbm] if packed else [])],
+            out_specs=pl.BlockSpec((slots, LANES), lambda c, *pre: (c, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((n_res, LANES), table.dtype),
+                *([pltpu.SMEM((slots,), jnp.int32)] if packed else []),
+                pltpu.SemaphoreType.DMA(()), pltpu.SemaphoreType.DMA(()),
+                *([pltpu.SemaphoreType.DMA(())] if packed else [])]),
+        out_shape=jax.ShapeDtypeStruct((row_b.size, LANES), table.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=4 * (n_res + 2 * slots) * LANES + VMEM_SLACK,
+            disable_bounds_checks=True),
+        interpret=interpret,
+    )(a["n_cold"], *([a["off"]] if packed else []), row_b.reshape(-1),
+      a["cold"], a["val_t"], table, *([a["packed"]] if packed else []))
+
+
+def q2_arrays(idx, made: dict, hot_row0: int):
+    """What ``ships`` and the two ``q2`` forms are handed for blocks
+    ``idx`` ``(blocks, rows, 128)``: ``made`` (``gather_lists``' four
+    beside ``idx``), the re-based index, and by NumPy the packed form's
+    list of the cold slots' table rows: a chunk's in list order, filled
+    to a whole trip of pass 2 with the last one again, from an offset
+    of whole tiles of 1024 words (``off``, in tiles, a chunk), and room
+    behind the last for a copy of a chunk's slots."""
+    import numpy as np
+
+    from tpu_distalg.ops.pallas_als import FETCH, chunk_rows
+
+    blocks, rows, lanes = idx.shape
+    slots = chunk_rows(rows) * lanes
+    chunks = idx.reshape(blocks, -1, slots)
+    n_chunks = chunks.shape[1]
+    packed = np.zeros((blocks, rows * lanes + n_chunks * LIST_PAD + slots),
+                      np.int32)
+    off = np.zeros((blocks, n_chunks), np.int32)
+    for b in range(blocks):
+        at = 0
+        for c in range(n_chunks):
+            mine = chunks[b, c][chunks[b, c] < hot_row0]
+            fill = -mine.size % FETCH
+            off[b, c] = at // LIST_PAD
+            packed[b, at:at + mine.size] = mine
+            packed[b, at + mine.size:at + mine.size + fill] = \
+                mine[-1] if mine.size else 0
+            at += -(-(mine.size + fill) // LIST_PAD) * LIST_PAD
+    return dict(made, idx=idx, rebased=idx - np.int32(hot_row0),
+                packed=packed, off=off)
+
+
 def block_arrays(idx, val, hot_row0: int, n_res: int):
     """What the forms' loaders hand over for blocks ``idx``, ``val``
     ``(blocks, rows, 128)``, by NumPy: the re-based index, the resident
@@ -358,12 +563,21 @@ def compile_one(form: str):
     block_f = arr((rows_b, 128), jnp.float32)
     chunks = rows_b // pallas_als.chunk_rows(rows_b)
     t0 = time.perf_counter()
+    cold = arr((rows_b * 64,), jnp.int32)
+    counts = arr((chunks,), jnp.int32)
     if form == "ships":
-        jax.jit(lambda t, r, v, c, n: pallas_als.gather_rows_resident(
-            t, r, v, c, n, plan.hot_row0, K)).lower(
-                table, block_i, block_f,
-                arr((rows_b * 64,), jnp.int32),
-                arr((chunks,), jnp.int32)).compile()
+        jax.jit(lambda t, i, v, r, c, n: pallas_als.gather_rows_resident(
+            t, i, v, r, c, n, plan.hot_row0, K)).lower(
+                table, block_i, block_f, block_i, cold, counts).compile()
+    elif form in Q2:
+        names = ("rebased", "row", "val_t", "cold", "n_cold", "off",
+                 "packed")
+        slots = rows_b * 128 // chunks
+        shapes = (block_i, block_i, block_f, cold, counts, counts, arr(
+            (rows_b * 128 + chunks * LIST_PAD + slots,), jnp.int32))
+        jax.jit(lambda t, *a: q2_gather(
+            form, t, dict(zip(names, a)), hot_row0=plan.hot_row0)).lower(
+                table, *shapes).compile()
     else:
         names = ("idx", "val", "rebased", "rel", "cold", "val_t")
         shapes = dict(idx=block_i, val=block_f, rebased=block_i,
@@ -416,7 +630,7 @@ def bundles(forms):
         inner = [hi - lo + 1 for lo, hi in loops[1:]]   # [0]: the grid's
         from tpu_distalg.ops import pallas_als
 
-        trip = pallas_als.UNROLL if form == "ships" else UNROLL
+        trip = pallas_als.UNROLL if form in ("ships", *Q2) else UNROLL
         names = [f"pass1 a {trip} slots", "pass2 a 16 copies",
                  "pass3 a wait", "lane"]
         out[form] = dict(zip(names, inner))
@@ -445,7 +659,7 @@ def main(argv) -> int:
         compile_one(argv[argv.index("--compile-one") + 1])
         return 0
     if "--bundles" in argv:
-        bundles([*FORMS, "ships"])
+        bundles([*(() if "--q2" in argv else FORMS), *Q2, "ships"])
         return 0
 
     import jax
@@ -456,6 +670,7 @@ def main(argv) -> int:
     from tpu_distalg.ops import als_sparse
 
     interp = "--rehearse" in argv
+    q2_only = "--q2" in argv      # PR 53's rows and no others
     if jax.devices()[0].platform != "tpu" and not interp:
         print("step0_als_gather: no chip", file=sys.stderr)
         return 2
@@ -536,7 +751,7 @@ def main(argv) -> int:
             from tpu_distalg.ops import pallas_als
 
             return pallas_als.gather_rows_resident(
-                M, a["rel"], a["val_t"], a["cold"], a["n_cold"],
+                M, a["idx"], a["val_t"], a["row"], a["cold"], a["n_cold"],
                 plan.hot_row0, k, interpret=interp)
 
         def form(name):
@@ -547,7 +762,8 @@ def main(argv) -> int:
         def shipped_arrays(idx, plan):
             made = jax.jit(lambda i, v: als_sparse.gather_lists(
                 i, v, plan))(idx, val)
-            return dict(zip(("rel", "val_t", "cold", "n_cold"), made))
+            return dict(zip(("val_t", "row", "cold", "n_cold"), made),
+                        idx=idx)
 
         def first(a8):
             return jax.tree_util.tree_map(lambda x: x[0], a8)
@@ -561,8 +777,33 @@ def main(argv) -> int:
         for b, idx in blocks.items():
             a8 = dict(idx=jnp.asarray(idx), val=jnp.asarray(val))
             want[b] = jax.jit(xla)(T, first(a8))
+            if q2_only:
+                continue
             row(f"{table}.xla.{b}", least_ms(jax.jit(xla), T, first(a8)))
             row(f"{table}.xla.{b}.x8", least_ms(eight(xla), T, a8) / 8)
+        # PR 53's three: what shipped until then, what ships, and the
+        # packed form that did not
+        heavy = als_sparse.GatherPlan("mosaic", heavy0, n_res,
+                                      interpret=interp)
+        for b in ("mix", "hot", "cold"):
+            a8 = {n: jnp.asarray(v) for n, v in q2_arrays(
+                blocks[b], shipped_arrays(jnp.asarray(blocks[b]), heavy),
+                heavy0).items()}
+            for name in (Q2[0], "ships", Q2[1]):
+                fn = functools.partial(_ships, plan=heavy) \
+                    if name == "ships" else functools.partial(
+                        q2_gather, name, hot_row0=heavy0, k=k,
+                        interpret=interp)
+                if not bool(jnp.array_equal(jax.jit(fn)(marked, first(a8)),
+                                            want[b])):
+                    say(f"[step0] {table}.form.{name}.{b}: NOT the block "
+                        f"XLA makes")
+                    return 1
+                row(f"{table}.form.{name}.{b}.x8",
+                    least_ms(eight(fn), marked, a8, n=5) / 8)
+        if q2_only:
+            del T, want, marked
+            continue
         for name, r0 in ranges.items():
             plan = als_sparse.GatherPlan("mosaic", r0, st.table_rows - r0,
                                          interpret=interp)
@@ -589,8 +830,8 @@ def main(argv) -> int:
 
             def wide(M, a):
                 return pallas_als.gather_rows_resident.__wrapped__(
-                    M, a["rel"], a["val_t"], a["cold"], a["n_cold"],
-                    heavy0, k)
+                    M, a["idx"], a["val_t"], a["row"], a["cold"],
+                    a["n_cold"], heavy0, k)
 
             ships = pallas_als.UNROLL
             pallas_als.UNROLL = 16
@@ -642,8 +883,8 @@ def main(argv) -> int:
 
         def gram_ships(M, a):
             return als_sparse.block_gramians(
-                M, a["rel"], a["val_t"], P, geom, st.zero_row, plan,
-                (a["cold"], a["n_cold"]))
+                M, a["idx"], a["val_t"], P, geom, st.zero_row, plan,
+                (a["row"], a["cold"], a["n_cold"]))
 
         a8 = {n: jnp.asarray(v) for n, v in block_arrays(
             blocks["mix"], val, heavy0, n_res).items()}

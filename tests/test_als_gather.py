@@ -44,6 +44,10 @@ def _block(case: str, rng) -> np.ndarray:
         idx[:4] = [HOT0 - 1, HOT0, ROWS - 1, ROWS]
         idx[4:6] = [ROWS + 7, ROWS + 1]    # zero rows no padding names
         idx[-4:] = [ROWS, ROWS - 1, HOT0, HOT0 - 1]
+    elif case == "split":       # a chunk with no cold slot, then a
+        idx = np.concatenate([  # chunk whose every slot is cold
+            rng.integers(HOT0, ROWS + 1, n // 2),
+            rng.integers(0, HOT0, n // 2)])
     else:                       # one cold slot, and it is the last
         idx = np.full(n, ROWS - 1)
         idx[-1] = 3
@@ -51,7 +55,7 @@ def _block(case: str, rng) -> np.ndarray:
 
 
 CASES = ["all_hot", "all_cold", "all_padding", "mixed", "edges",
-         "last_cold"]
+         "last_cold", "split"]
 GEOM96 = ops.SparseGeometry(k=K, seg_slots=32, piece_segs=8, batch=384,
                             classes=(1, 2, 4))
 PLAN = ops.GatherPlan("mosaic", HOT0, ROWS + 8 - HOT0, interpret=True)
@@ -67,9 +71,10 @@ def _table(rng) -> np.ndarray:
 
 
 def _lists(idx, val):
-    """The loader's four for one block."""
-    return tuple(a[0] for a in jax.jit(
-        lambda i, v: ops.gather_lists(i, v, PLAN))(idx[None], val[None]))
+    """What the kernel is handed for one block: the pack's indices and
+    the loader's four."""
+    return (idx, *(a[0] for a in jax.jit(
+        lambda i, v: ops.gather_lists(i, v, PLAN))(idx[None], val[None])))
 
 
 def _pass1_lists(idx_b: np.ndarray, slots: int, fetch: int):
@@ -92,10 +97,9 @@ def test_the_loaders_lists_are_what_pass_1_listed(case):
     rng = np.random.default_rng(11)
     idx = _block(case, rng)
     val = rng.standard_normal(idx.shape).astype(np.float32)
-    rel, val_t, cold, n_cold = (np.asarray(a) for a in _lists(idx, val))
+    _, val_t, _, cold, n_cold = (np.asarray(a) for a in _lists(idx, val))
     slots = pallas_als.chunk_rows(96) * 128
     assert slots == 48 * 128 and cold.shape == (96 * 128 // 2,)
-    assert np.array_equal(rel, idx - HOT0)
     # a tile of 1024 slots: slot 8 m + j at row j, lane m
     assert np.array_equal(
         val_t.reshape(-1, 8, 128).transpose(0, 2, 1).reshape(-1),
@@ -107,6 +111,42 @@ def test_the_loaders_lists_are_what_pass_1_listed(case):
     for mine, (theirs, n) in zip(got, want):
         assert np.array_equal(mine[:theirs.size], theirs)
         assert np.all(mine[n:] == (mine[n - 1] if n else 0))
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_loaders_rows_are_what_passes_1_and_2_computed(case):
+    """Until PR 53 the kernel made a slot's two row addresses itself,
+    every call, from the index re-based on the range (``rel``): pass 1
+    ``min(uint32(rel), n_res - 1)``, pass 2 ``rel + hot_row0`` at every
+    listed position. The loader's ``row`` is the first at every slot,
+    and the pack's own indices are the second."""
+    rng = np.random.default_rng(11)
+    idx = _block(case, rng)
+    val = rng.standard_normal(idx.shape).astype(np.float32)
+    idx_b, _, row, cold, n_cold = (np.asarray(a) for a in _lists(idx, val))
+    n_res = PLAN.resident_rows
+    rel = idx - np.int32(HOT0)
+    want = np.minimum(rel.astype(np.uint32), np.uint32(n_res - 1))
+    assert row.dtype == np.int32 and row.shape == idx.shape
+    assert np.array_equal(row, want.astype(np.int32))
+    assert row.min() >= 0 and row.max() <= n_res - 1
+    # hot: its own row of the range; cold: the range's last row
+    assert np.array_equal(row[rel >= 0], rel[rel >= 0])
+    assert np.all(row[rel < 0] == n_res - 1)
+    slots = pallas_als.chunk_rows(96) * 128
+    listed = np.stack([cold & 0xFFFF, cold >> 16], axis=-1) \
+        .reshape(-1, slots)
+    assert np.array_equal(idx_b, idx)
+    for chunk, rel_c, at, n in zip(idx_b.reshape(-1, slots),
+                                   rel.reshape(-1, slots), listed, n_cold):
+        trips = -(-int(n) // pallas_als.FETCH) * pallas_als.FETCH
+        assert np.array_equal(chunk[at[:trips]], rel_c[at[:trips]] + HOT0)
+        # every row pass 2 copies is a cold slot's own row of the table
+        assert np.all(chunk[at[:trips]] < HOT0)
+        assert sorted(set(at[:n].tolist())) == np.flatnonzero(
+            chunk < HOT0).tolist()
+    if case == "split":
+        assert n_cold.tolist() == [0, slots]
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -188,19 +228,20 @@ def test_a_fit_is_the_same_in_both_forms_bit_for_bit(mesh1):
         for g, o in zip(mosaic, (meta["item"], meta["user"])))
     assert all(0 < g.hot_row0 for g in mosaic)
     # the arrays as a loader on a TPU would hold them: the lists made
-    # of the pack's own two, a half each
+    # of the pack's own two, a half each, the turned ratings in their
+    # place
     assert len(arrays) == 9
     made = [jax.jit(lambda i, v, g=g: ops.gather_lists(i, v, g))(
         arrays[at], arrays[at + 1]) for g, at in zip(mosaic, (0, 3))]
-    held = (*made[0][:2], arrays[2], *made[1][:2], *arrays[5:],
-            *made[0][2:], *made[1][2:])
+    held = (arrays[0], made[0][0], arrays[2], arrays[3], made[1][0],
+            *arrays[5:], *made[0][1:], *made[1][1:])
     fields = als.segment_fields(dict(meta, gather=mosaic))
     assert [int(jnp.sum(m[3])) for m in made] == [
         own.slots_held - ops.resident_slots(own, other, g.hot_row0)
         for own, other, g in zip((meta["user"], meta["item"]),
                                  (meta["item"], meta["user"]), mosaic)]
-    assert (fields["gather_cold_list"], fields["gather_lanes"]) == (
-        "loader", "kernel")
+    assert (fields["gather_cold_list"], fields["gather_slot_rows"],
+            fields["gather_lanes"]) == ("loader", "loader", "kernel")
     out = []
     for gather, args in ((meta["gather"], arrays), (mosaic, held)):
         fn = als.make_fit_fn(mesh1, cfg, dict(meta, gather=gather))
@@ -223,7 +264,12 @@ def test_no_list_is_built_on_a_mesh_or_off_the_chip(mesh4, mesh1):
         fields = als.segment_fields(meta)
         assert (fields["gather_cold_list"], fields["gather_cold_slots"],
                 fields["gather_cold_share"], fields["gather_list_bytes"],
-                fields["gather_lanes"]) == ("none", [0, 0], 0.0, 0, "xla")
+                fields["gather_slot_rows"], fields["gather_lanes"]) == (
+            "none", [0, 0], 0.0, 0, "none", "xla")
+        # the pack's own indices and ratings, as the pack made them
+        assert arrays[0].dtype == arrays[3].dtype == jnp.int32
+        assert meta["ratings_bytes"] == 8 * (
+            meta["user"].slots_held + meta["item"].slots_held)
 
 
 def _static(n_shards=1, heavy_rows=64, light_rows=128):
@@ -285,13 +331,16 @@ def test_the_cells_statics_take_the_kernel(cell_meta):
             fields["gather_resident_share"]) == (
         "mosaic", [18440, 18440], 0.7364)
     # the lists are the loader's: 160.2M live entries over both halves,
-    # half a word a slot held, and the kernel writes the lanes
-    assert (fields["gather_cold_list"], fields["gather_lanes"]) == (
-        "loader", "kernel")
+    # half a word a slot held; so is a slot's resident row, a word a
+    # slot held (3.65 GB with the lists); and the kernel writes the lanes
+    assert (fields["gather_cold_list"], fields["gather_slot_rows"],
+            fields["gather_lanes"]) == ("loader", "loader", "kernel")
     assert fields["gather_cold_slots"] == [64753249, 95425029]
     assert fields["gather_cold_share"] == 0.2636
-    assert fields["gather_list_bytes"] == 2 * (
-        meta["user"].slots_held + meta["item"].slots_held) == 1215430656
+    assert fields["gather_list_bytes"] == 6 * (
+        meta["user"].slots_held + meta["item"].slots_held) == 3646291968
+    assert meta["ratings_bytes"] == 14 * (
+        meta["user"].slots_held + meta["item"].slots_held) == 8508014592
     assert als._prepare_fields(meta)["gather_cold_slots"] == [
         64753249, 95425029]
     # the same sizes where the fit will not run on a TPU: XLA's form,
@@ -301,6 +350,50 @@ def test_the_cells_statics_take_the_kernel(cell_meta):
     assert off["forms"]["als_gather_form"] == "xla"
     assert off["gather_resident_rows"] == (0, 0)
     assert off["gather_resident_share"] == 0.0
+
+
+def test_the_lists_have_to_fit_the_chip(cell_meta):
+    """The kernel is taken where what the loader makes for it fits a
+    device's memory beside the table, the tables and a half's
+    accumulator (8.51 + 0.87 + 1.61 GB at the cell's shape); XLA's
+    gather, which wants no list, where it does not; and the plan as it
+    always was where nobody says how much there is."""
+    from tpu_distalg.telemetry import report
+
+    meta = cell_meta
+    plans = (meta["user"], meta["item"])
+
+    def plan(hbm_bytes):
+        return als._ratings_meta(meta["geometry"], plans, meta["n_ratings"],
+                                 0, 1, True, hbm_bytes)
+
+    need = meta["ratings_bytes"] + meta["factor_bytes"] \
+        + (18432 + 1 + 6144) * 128 * 128 * 4
+    assert 10.9e9 < need < 11.1e9
+    for room in (None, 16_911_433_728, need):        # a v5e: 15.75 GiB
+        fits = plan(room)
+        assert fits["forms"]["als_gather_form"] == "mosaic"
+        assert fits["gather_list_bytes"] == meta["gather_list_bytes"]
+        assert als.segment_fields(fits)["gather_cold_list"] == "loader"
+    short = plan(need - 1)
+    assert short["forms"]["als_gather_form"] == "xla"
+    assert short["forms"]["als_solve_form"] == "mosaic"     # as it was
+    assert short["gather_list_bytes"] == 0
+    assert short["ratings_bytes"] == 8 * (
+        plans[0].slots_held + plans[1].slots_held)
+    fields = als.segment_fields(short)
+    assert (fields["gather_cold_list"], fields["gather_slot_rows"],
+            fields["gather_cold_slots"], fields["gather_lanes"]) == (
+        "no room", "none", [0, 0], "xla")
+    start = dict(ev="span_start", name="train:segment", id=1, parent=None,
+                 t=0.0, **fields)
+    line, = [ln for ln in report.render(report.summarize([start]))
+             .splitlines() if ln.startswith("R layout")]
+    assert "gather: xla (no room for the kernel's lists)" in line
+    # off the chip there is no list to fit
+    off = als._ratings_meta(meta["geometry"], plans, meta["n_ratings"],
+                            0, 1, False, 1)
+    assert als.segment_fields(off)["gather_cold_list"] == "none"
 
 
 def test_the_report_prints_what_the_kernel_is_handed(cell_meta):
@@ -313,8 +406,8 @@ def test_the_report_prints_what_the_kernel_is_handed(cell_meta):
     assert line == (
         "R layout: ratings (gather: mosaic with 0.7364 of the slots "
         "resident, 0.2636 cold and listed by the loader (160178278 slots, "
-        "1215430656 B), lanes by the kernel, gramians: xla by owners, "
-        "solve: mosaic in tiles of 128)")
+        "3646291968 B), a slot's rows by the loader, lanes by the kernel, "
+        "gramians: xla by owners, solve: mosaic in tiles of 128)")
 
 
 def test_resident_share_is_a_count_over_the_packed_indices(mesh1):

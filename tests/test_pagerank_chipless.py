@@ -125,23 +125,33 @@ def test_als_solve_kernel_compiles_for_the_chip(one_chip, k, batch):
         - 2 * geom.solve_n * batch * 4 <= 1024
 
 
+@pytest.mark.parametrize("gather_form", ["xla", "mosaic"])
 @pytest.mark.parametrize("with_error", [True, False],
                          ids=["item_half", "user_half"])
-def test_a_half_sweep_copies_no_batch_of_gramians(one_chip, with_error):
+def test_a_half_sweep_copies_no_batch_of_gramians(one_chip, with_error,
+                                                  gather_form):
     """One shard's half at the cell's batch, rank and width (6144, 100,
     128) with a step of every kind (a class of one segment an owner, a
-    class staged in two parts, the heavy class's accumulator), XLA's
-    gather and the Mosaic solve, as a mesh's shard runs it: the batch
-    travels owner-major from the product to the kernel, which takes it
-    inside ``solve_tile_bytes`` + ``VMEM_SLACK`` (the limit it is
-    compiled under), and no ``copy`` of the
+    class staged in two parts, the heavy class's accumulator), the
+    Mosaic solve and the gather in either form (XLA's, as a mesh's shard
+    runs it; the kernel's, as the cell does, the heavy class's 18 440
+    rows of a table of 700 000 resident): the batch travels owner-major
+    from the product to the kernel, which takes it inside
+    ``solve_tile_bytes`` + ``VMEM_SLACK`` (the limit it is compiled
+    under), and no ``copy`` of the
     compiled module has a batch's 128 x 128 x 6144 floats in any order
     (until PR 50 three had, 12% of an iteration: a turn to lanes for the
     kernel in each half and one more for the item half's error sums,
-    which the user half drops)."""
+    which the user half drops). Nor is the gathered block copied (the
+    same count of floats: 196 608 rows of 128), nor a block's indices,
+    resident rows or ratings, nor a side's: the gather kernel reads
+    them where a slice left them, four int32 operands (the counts by
+    scalar prefetch, then the indices, the resident rows and the cold
+    list through SMEM: one more than until PR 53) and two float32 (the
+    turned ratings, the table)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from tpu_distalg.ops import als_sparse
+    from tpu_distalg.ops import als_sparse, pallas_als
 
     geom = als_sparse.SparseGeometry(k=100)
     B, W = geom.batch, geom.width
@@ -158,28 +168,53 @@ def test_a_half_sweep_copies_no_batch_of_gramians(one_chip, with_error):
     def arr(shape, dtype, sharding=rep):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    def run(idx, val, pieces, other, own):
+    block = (st.n_blocks, *geom.block_shape)
+    how, lists = {}, ()
+    if gather_form == "mosaic":
+        how = {"gather": als_sparse.GatherPlan("mosaic", rows - 18440,
+                                               18440)}
+        chunks = block[1] // pallas_als.chunk_rows(block[1])
+        lists = (arr(block, jnp.int32, row),
+                 arr((st.n_blocks, geom.block_slots // 2), jnp.int32, row),
+                 arr((st.n_blocks, chunks), jnp.int32, row))
+
+    def run(idx, val, pieces, other, own, *cold):
         table, sse, seen = als_sparse.half_sweep(
             idx, val, pieces, other, own, static=st,
             other_zero_row=rows - 8, geom=geom, lam=1.4, axis="data",
-            solve=solve)
+            solve=solve, cold=cold, **how)
         return (table, sse, seen) if with_error else (table, seen)
 
     done = jax.jit(jax.shard_map(
-        run, mesh=mesh, in_specs=(P("data"),) * 3 + (P(), P()),
+        run, mesh=mesh,
+        in_specs=(P("data"),) * 3 + (P(), P()) + (P("data"),) * len(lists),
         out_specs=(P(),) * (3 if with_error else 2),
         check_vma=False)).lower(
-            arr((st.n_blocks, *geom.block_shape), jnp.int32, row),
-            arr((st.n_blocks, *geom.block_shape), jnp.float32, row),
+            arr(block, jnp.int32, row), arr(block, jnp.float32, row),
             arr(plan.piece_slot.shape, jnp.int32, row),
             arr((rows, W), jnp.float32),
-            arr((st.table_rows, W), jnp.float32)).compile()
+            arr((st.table_rows, W), jnp.float32), *lists).compile()
     text = done.as_text()
     assert "_als_solve_kernel" in text
-    batches = [m.group(0) for m in re.finditer(
-        r"= f32\[([\d,]+)\]\S* copy\(", text)
-        if np.prod([int(d) for d in m.group(1).split(",")]) == B * W * W]
-    assert not batches, batches
+    sizes = {B * W * W, geom.block_slots, st.n_blocks * geom.block_slots}
+    copies = [m.group(0) for m in re.finditer(
+        r"= [fs]32\[([\d,]+)\]\S* copy\(", text)
+        if np.prod([int(d) for d in m.group(1).split(",")]) in sizes]
+    assert not copies, copies
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "_als_gather_kernel" in ln]
+    if gather_form == "xla":
+        assert not calls
+        return
+    assert calls
+    slots = geom.block_slots
+    for ln in calls:
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}, ",
+                             ln).group(1)
+        assert re.findall(r"([fs]32\[[\d,]+\])", operands) == [
+            f"s32[{chunks}]", f"s32[{slots}]", f"s32[{slots}]",
+            f"s32[{slots // 2}]", f"f32[{block[1]},128]",
+            f"f32[{rows},128]"], operands
 
 
 def test_hbm_gather_kernel_compiles_at_kdd12s_shape(one_chip):

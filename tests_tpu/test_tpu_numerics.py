@@ -1428,7 +1428,8 @@ def _sparse_als_block_mosaic(T, idx, val, hot_row0, zero_row):
     plan = ops.GatherPlan("mosaic", hot_row0, T.shape[0] - hot_row0)
     lists = (a[0] for a in ops.gather_lists(idx[None], val[None], plan))
     table = ops.gather_table(T, ops.SparseGeometry(k=100), zero_row, plan)
-    return pallas_als.gather_rows_resident(table, *lists, hot_row0, 100)
+    return pallas_als.gather_rows_resident(table, idx, *lists, hot_row0,
+                                           100)
 
 
 def _sparse_als_block_xla(T, idx, val, zero_row):

@@ -267,27 +267,44 @@ def _sparse_geometry(k: int, n_users: int, n_items: int,
 
 
 def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
-                  n_shards: int, on_tpu: bool = False, **extra) -> dict:
+                  n_shards: int, on_tpu: bool = False,
+                  hbm_bytes: int | None = None, **extra) -> dict:
     from tpu_distalg.ops import als_sparse
 
     pu, pi = plans
-    # a half gathers from the OTHER side's table: the user half first
-    gathers = tuple(als_sparse.gather_plan(o.static, geom, on_tpu)
-                    for o in (pi, pu))
+    tables = (pu.static.table_rows + pi.static.table_rows) \
+        * geom.width * 4
+    # what a half holds beside the table and the tables: the heavy
+    # class's accumulator and a batch of Gramians
+    fit = max(p.static.heavy[3] + 1 + geom.batch for p in plans) \
+        * geom.width ** 2 * 4
+
+    def gathers_on(on_tpu):
+        # a half gathers from the OTHER side's table: the user half first
+        return tuple(als_sparse.gather_plan(o.static, geom, on_tpu)
+                     for o in (pi, pu))
+
+    gathers = gathers_on(on_tpu)
+    # what the Mosaic gather is handed ready made
+    # (``als_sparse.gather_lists``: the loader's, made once): a word a
+    # slot held for its resident row and half a word for the cold list
+    lists = sum(6 * p.slots_held for p, g in zip(plans, gathers)
+                if g.form == "mosaic")
+    packed = (pu.slots_held + pi.slots_held) * 8
+    # they have to fit the chip beside the table: XLA's gather, which
+    # wants none, where they do not
+    no_room = hbm_bytes is not None and lists > 0 \
+        and packed + lists + tables + fit > hbm_bytes
+    if no_room:
+        gathers, lists = gathers_on(False), 0
     solve = als_sparse.solve_plan(geom, on_tpu)
     resident = tuple(
         als_sparse.resident_slots(p, o, g.hot_row0) if g.resident_rows
         else 0 for p, o, g in zip(plans, (pi, pu), gathers))
-    # what the Mosaic gather's lists hold (``als_sparse.gather_lists``:
-    # the loader's, made once): a half's cold slots, and half a word a
-    # slot held
+    # a half's cold slots: the lists' live entries
     cold = tuple(p.slots_held - r if g.form == "mosaic" else 0
                  for p, r, g in zip(plans, resident, gathers))
-    lists = sum(2 * p.slots_held for p, g in zip(plans, gathers)
-                if g.form == "mosaic")
-    held = (pu.slots_held + pi.slots_held) * 8 + lists
-    tables = (pu.static.table_rows + pi.static.table_rows) \
-        * geom.width * 4
+    held = packed + lists
     return dict(
         layout=RATINGS_LAYOUT, n_users=int(pu.degrees.shape[0]),
         n_items=int(pi.degrees.shape[0]), n_ratings=int(n_ratings),
@@ -302,7 +319,7 @@ def _ratings_meta(geom, plans, n_ratings: int, n_heldout: int,
         gather_resident_share=sum(resident)
         / max(pu.slots_held + pi.slots_held, 1),
         gather_cold_slots=cold, gather_list_bytes=lists,
-        gather=gathers, solve=solve,
+        gather_lists_fit=not no_room, gather=gathers, solve=solve,
         forms=dict(als_gather_form="/".join(
             dict.fromkeys(g.form for g in gathers)),
             als_gram_form="xla", als_solve_form=solve.form,
@@ -328,19 +345,24 @@ def _gather_fields(meta: dict) -> dict:
     the item half read) and the share of the slots held that point into
     it. Then what the Mosaic form is handed ready made: who lists a
     chunk's cold slots (``loader``, once; ``none`` where no half takes
-    the kernel), the lists' live entries a half with their share of the
-    slots held, the lists' bytes, and who writes the rating's and the
-    validity's lanes."""
+    the kernel, ``no room`` where it would but the lists do not fit the
+    chip beside the table), the lists' live entries a half with their
+    share of the slots held, who makes a slot's two row addresses (its
+    row of the resident range and its row of the table: ``loader``,
+    once), the bytes of all the loader makes for the kernel, and who
+    writes the rating's and the validity's lanes."""
     mosaic = any(g.form == "mosaic" for g in meta["gather"])
     held = meta["user"].slots_held + meta["item"].slots_held
+    made = "loader" if mosaic else "none"
     return dict(
         als_gather_form=meta["forms"]["als_gather_form"],
         gather_resident_rows=list(meta["gather_resident_rows"]),
         gather_resident_share=round(meta["gather_resident_share"], 4),
-        gather_cold_list="loader" if mosaic else "none",
+        gather_cold_list=made if meta["gather_lists_fit"] else "no room",
         gather_cold_slots=list(meta["gather_cold_slots"]),
         gather_cold_share=round(
             sum(meta["gather_cold_slots"]) / max(held, 1), 4),
+        gather_slot_rows=made,
         gather_list_bytes=meta["gather_list_bytes"],
         gather_lanes="kernel" if mosaic else "xla")
 
@@ -387,7 +409,8 @@ def ratings_from_coo(users, items, ratings, n_users: int, n_items: int,
     hu = pu.row_of_owner[np.asarray(heldout[0], np.int64)]
     hv = pi.row_of_owner[np.asarray(heldout[1], np.int64)]
     meta = _ratings_meta(geom, (pu, pi), users.shape[0], n_heldout, S,
-                         mesh_on_tpu(mesh))
+                         mesh_on_tpu(mesh),
+                         tevents.memory_limit(mesh.local_devices[:1]))
     sides = [tuple(_put(a, "ratings", mesh) for a in side)
              for side in ((ui, uv), (ii, iv))]
     pieces = [_put(p.piece_slot, "ratings", mesh) for p in (pu, pi)]
@@ -401,10 +424,11 @@ def _ratings_arrays(sides, pieces, held, meta: dict, mesh: Mesh):
     """What the trainer's function takes before the two factor tables:
     a side's packed indices, ratings and pieces' slots, the users' then
     the items', and the held-out pairs. A half whose gather takes the
-    Mosaic form holds its indices and ratings as the kernel reads them
-    and two arrays more at the end, its cold lists and their counts
-    (``als_sparse.gather_lists``, made here, once, on the device; the
-    pack's own two are given up to it)."""
+    Mosaic form holds its ratings as the kernel reads them and three
+    arrays more at the end, its slots' resident rows, its cold lists
+    and their counts (``als_sparse.gather_lists``, made here, once, on
+    the device; the pack's ratings are given up to it, its indices stay:
+    they are the cold slots' rows of the table)."""
     from tpu_distalg.ops import als_sparse
     from tpu_distalg.parallel import partition
     from tpu_distalg.telemetry import events as tevents
@@ -417,15 +441,16 @@ def _ratings_arrays(sides, pieces, held, meta: dict, mesh: Mesh):
         with tevents.span("als:lists", mesh.local_devices, side=s,
                           cold_slots=meta["gather_cold_slots"][s],
                           slots=(meta["user"], meta["item"])[s].slots_held):
+            idx, val = sides[s]
             made = jax.jit(
                 functools.partial(als_sparse.gather_lists, gather=gather),
-                donate_argnums=(0, 1), out_shardings=(rows,) * 4)(
-                    *sides[s])
+                donate_argnums=1, out_shardings=(rows,) * 4)(idx, val)
             jax.block_until_ready(made)
-            # in place of the pack's two, which were donated
-            tevents.current().fields["bytes"] = metrics.nbytes(made)
-        sides[s] = made[:2]
-        lists += made[2:]
+            # beside the pack's indices, in place of its ratings, which
+            # were donated
+            tevents.current().fields["bytes"] = metrics.nbytes(idx, made)
+        sides[s] = (idx, made[0])
+        lists += made[1:]
     return (*sides[0], pieces[0], *sides[1], pieces[1], *held, *lists)
 
 
@@ -521,14 +546,16 @@ def side_generator(mesh: Mesh, geom, gen, side: int, zero_row: int):
 def plan_ratings(n_ratings: int, n_users: int, n_items: int, k: int,
                  n_shards: int = 1, *, n_heldout: int = 0, degrees=None,
                  geometry: dict | None = None, on_tpu: bool = False,
-                 **gen_kw) -> dict:
+                 hbm_bytes: int | None = None, **gen_kw) -> dict:
     """The host's half of the seeded loader: both degree sequences
     (functions of the sizes alone, ``datasets.power_law_degrees``; or
     ``degrees=(users', items')``), both sides' pack, and the ``meta``
     that states the layout. No device is touched and no seed is read:
     every seed's table has these sizes. ``on_tpu`` says where the fit
     will run (the loaders pass their mesh's answer): the gather takes
-    its Mosaic form only there (``als_sparse.gather_plan``)."""
+    its Mosaic form only there (``als_sparse.gather_plan``), and only
+    where what the loader makes for it fits ``hbm_bytes``, a device's
+    memory, beside the table (None: not known, and not asked)."""
     from tpu_distalg.ops import als_sparse
     from tpu_distalg.utils import datasets as dsets
 
@@ -552,7 +579,8 @@ def plan_ratings(n_ratings: int, n_users: int, n_items: int, k: int,
     plans = (als_sparse.plan_side(du, geom, n_shards),
              als_sparse.plan_side(di, geom, n_shards))
     return _ratings_meta(geom, plans, n_ratings, n_heldout, n_shards,
-                         on_tpu, generator=tuple(sorted(par.items())))
+                         on_tpu, hbm_bytes,
+                         generator=tuple(sorted(par.items())))
 
 
 def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
@@ -581,8 +609,10 @@ def build_ratings_table(n_ratings: int, n_users: int, n_items: int,
                       layout=RATINGS_LAYOUT):
         prepare = tevents.current().fields
         with tevents.span("als:pack", devices, ratings=n_ratings):
-            meta = plan_ratings(n_ratings, n_users, n_items, k, S,
-                                on_tpu=mesh_on_tpu(mesh), **plan_kw)
+            meta = plan_ratings(
+                n_ratings, n_users, n_items, k, S,
+                on_tpu=mesh_on_tpu(mesh),
+                hbm_bytes=tevents.memory_limit(devices[:1]), **plan_kw)
             meta["data_seed"] = int(data_seed)
             geom, plans = meta["geometry"], (meta["user"], meta["item"])
             stubs = [tuple(_put(a, "ratings", mesh) for a in (
@@ -698,8 +728,8 @@ _take_rows = jax.jit(_take_rows, static_argnames="k")
 
 def _make_fit_fn_sparse(mesh: Mesh, config: ALSConfig, meta: dict):
     """``fit(user idx, val, pieces, item idx, val, pieces, held-out
-    users', items' rows, ratings[, a Mosaic half's cold lists and
-    counts], X, Theta) -> (X, Theta, errs, seen)`` (the loaders'
+    users', items' rows, ratings[, a Mosaic half's resident rows, cold
+    lists and counts], X, Theta) -> (X, Theta, errs, seen)`` (the loaders'
     ``arrays``, :func:`_ratings_arrays`, then the two tables):
     ``config.n_iterations`` ALS iterations (the user half from Theta,
     then the item half from the new X: one function over (owners'
@@ -719,7 +749,7 @@ def _make_fit_fn_sparse(mesh: Mesh, config: ALSConfig, meta: dict):
     n_ratings = max(meta["n_ratings"], 1)
 
     def half(static, other_zero_row, gather):
-        n_cold = 2 if gather.form == "mosaic" else 0
+        n_cold = 3 if gather.form == "mosaic" else 0
 
         def run(idx, val, pieces, other, own, *cold):
             return als_sparse.half_sweep(
@@ -742,7 +772,7 @@ def _make_fit_fn_sparse(mesh: Mesh, config: ALSConfig, meta: dict):
         if len(cold) != cold_u + cold_i:
             raise TypeError(
                 f"the gathers' forms {meta['forms']['als_gather_form']} "
-                f"want {cold_u + cold_i} arrays of cold lists, "
+                f"want {cold_u + cold_i} arrays of rows and cold lists, "
                 f"{len(cold)} were handed in")
 
         def iteration(carry, _):

@@ -51,15 +51,19 @@ one from what the code can observe, with no flag:
             points there is a dynamic-row vector load and a slot that
             does not a row DMA, and the kernel writes the rating's lane
             itself. What of a slot is the same all run long the loader
-            makes once (:func:`gather_lists`: the index re-based on the
-            range, a chunk's cold slots listed, the ratings turned a
-            group of slots down the sublanes), and the validity's lane
+            makes once (:func:`gather_lists`: the slot's row of the
+            resident range, beside the pack's index, which is its row
+            of the table: the kernel computes neither; a chunk's cold
+            slots listed; the ratings turned a group of slots down the
+            sublanes: 6 bytes a slot held more than the pack's 8), and
+            the validity's lane
             is the table's own, set once a half (:func:`gather_table`:
             a slot is valid where its row is not ``zero_row``). On a TPU,
             where a factor row is one vector of 128 lanes, a block's
             slots are whole vectors, the table is one shard's (on a
             mesh it is shard-major and the heavy rows are ``n_shards``
-            ranges) and the range fits.
+            ranges) and the range fits VMEM; and where what the loader
+            makes for it fits the chip (``models/als._ratings_meta``).
 ``xla``     :func:`gather_rows`, ``other.at[idx].get(...)``: a DMA a
             512 B row, 9 to 13.6 ns whatever the block, and a ``where``
             for the two lanes that XLA fuses into it; everywhere else,
@@ -456,14 +460,17 @@ def gather_rows(other, idx_b):
 
 def gather_lists(idx, val, gather: GatherPlan):
     """What of a side's packed blocks the Mosaic gather wants made once,
-    on the device: from ``idx`` int32 and ``val`` float32 ``(blocks,
-    rows, 128)`` as the pack holds them,
+    on the device, beside the pack's own ``idx``: from ``idx`` int32 and
+    ``val`` float32 ``(blocks, rows, 128)`` as the pack holds them,
 
-    ``rel``     int32, ``idx`` re-based on the resident range: negative
-                where the slot is cold;
     ``val_t``   float32, ``val`` turned tile by tile of 1024 slots: a
                 tile's row ``j`` holds slot ``8 m + j`` at lane ``m``, a
                 group of eight slots down the sublanes;
+    ``row``     int32, a slot's row of the resident range, ``idx`` less
+                ``hot_row0``, and the range's last row where that is
+                negative, the slot *cold* (what pass 1 loads: a cold
+                slot's row of the output is written again by pass 2,
+                from row ``idx`` of the table);
     ``cold``    int32 ``(blocks, slots / 2)``: for every chunk (a grid
                 step of the kernel) the positions in it of its cold
                 slots, in slot order, filled to the chunk's end with
@@ -472,7 +479,8 @@ def gather_lists(idx, val, gather: GatherPlan):
     ``n_cold``  int32 ``(blocks, chunks)``: how many of them are slots.
 
     None of it changes through a run: ``idx`` is the loader's and the
-    range is static."""
+    range is static. Six bytes a slot held beside the pack's eight: 3.65
+    GB of 8.51 at the published shape, made in 0.47 s on one v5e."""
     import jax
     import jax.numpy as jnp
 
@@ -480,13 +488,16 @@ def gather_lists(idx, val, gather: GatherPlan):
 
     blocks, rows, lanes = idx.shape
     slots = pallas_als.chunk_rows(rows) * lanes
-    rel = idx - jnp.int32(gather.hot_row0)
+    hot_row0 = jnp.int32(gather.hot_row0)
+    # (as unsigned a negative index lies past every row of the range)
+    row = jnp.minimum((idx - hot_row0).astype(jnp.uint32),
+                      jnp.uint32(gather.resident_rows - 1)).astype(jnp.int32)
     val_t = val.reshape(blocks, -1, lanes, pallas_als.SUBLANES) \
         .swapaxes(-1, -2).reshape(idx.shape)
     pos = jnp.arange(slots, dtype=jnp.int32)
 
-    def lists(rel_b):
-        cold = rel_b.reshape(-1, slots) < 0
+    def lists(idx_b):
+        cold = idx_b.reshape(-1, slots) < hot_row0
         order = jnp.sort(jnp.where(cold, pos, pos + slots), axis=-1)
         n = jnp.sum(cold, axis=-1, dtype=jnp.int32)[:, None]
         again = jnp.take_along_axis(order, jnp.maximum(n - 1, 0), axis=-1)
@@ -494,8 +505,8 @@ def gather_lists(idx, val, gather: GatherPlan):
         words = order[:, 0::2] | (order[:, 1::2] << 16)
         return words.reshape(-1), n[:, 0]
 
-    cold, n_cold = jax.lax.map(lists, rel)
-    return rel, val_t, cold, n_cold
+    cold, n_cold = jax.lax.map(lists, idx)
+    return val_t, row, cold, n_cold
 
 
 def gather_table(other, geom: SparseGeometry, zero_row: int,
@@ -524,10 +535,10 @@ def block_gramians(other, idx_b, val_b, K: int, geom: SparseGeometry,
     other side's rows with the rating and the validity in lanes ``k``
     and ``k + 1``, one float32-accurate product ``K * seg_slots`` deep
     an owner. In the Mosaic form ``other`` is :func:`gather_table`'s,
-    ``idx_b`` and ``val_b`` are the block's ``rel`` and ``val_t`` and
-    ``cold`` its list and counts (:func:`gather_lists`), and the kernel
-    hands the block over whole; in XLA's form (no plan, or the plan's)
-    a ``where`` writes the two lanes, which XLA fuses into its own
+    ``val_b`` is the block's ``val_t`` and ``cold`` its resident rows,
+    cold list and counts (:func:`gather_lists`), and the kernel hands
+    the block over whole; in XLA's form (no plan, or the plan's) a
+    ``where`` writes the two lanes, which XLA fuses into its own
     gather."""
     import jax
     import jax.numpy as jnp
@@ -739,8 +750,8 @@ def half_sweep(idx, val, piece_slot, other, own, *, static: SideStatic,
     """One shard's half of an iteration: every owner of this shard from
     the other side's table ``other`` (whole, constant through the half),
     written into the shard's rows of ``own``; the shards' rows gathered
-    once at the end. In the Mosaic form of the gather ``idx``, ``val``
-    and ``cold`` are :func:`gather_lists`' four. Returns ``(table, sse,
+    once at the end. In the Mosaic form of the gather ``val`` and
+    ``cold`` are :func:`gather_lists`' four. Returns ``(table, sse,
     seen)``, the two sums over all shards. Runs inside ``shard_map``
     over ``axis``."""
     import jax

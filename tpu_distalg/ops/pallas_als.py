@@ -1,58 +1,66 @@
 """The Mosaic forms of sparse ALS' row gather and of its per-owner
 solve (``ops/als_sparse.py``); the solve's is the file's second half.
 
-``gather_rows_resident(table, rel_b, val_t, cold_b, n_cold, hot_row0,
-lane)`` returns ``table[rel_b.reshape(-1) + hot_row0]``, bit for bit,
+``gather_rows_resident(table, idx_b, val_t, row_b, cold_b, n_cold,
+hot_row0, lane)`` returns ``table[idx_b.reshape(-1)]``, bit for bit,
 with lane ``lane`` of every row the slot's value (its rating), for a
 table of 128-lane float32 rows whose tail ``[hot_row0, table rows)``
 (the *resident range*: the rows most slots point at,
 ``als_sparse.gather_plan`` picks it) fits VMEM.
 
 What of a slot never changes through a run is made once, by the loader
-(``als_sparse.gather_lists``), and handed in: the slot's index re-based
-on the range (``rel``: negative where the slot is *cold*), a chunk's
-cold slots' positions in slot order, two 16-bit positions a word and
-filled to the chunk's end with the last one again, the chunk's count of
-them, and the values a group of eight slots down the sublanes.
+(``als_sparse.gather_lists``), and handed in beside the pack's indices:
+the slot's row of the resident range (``row``: the index re-based on
+the range, the range's last row where the slot is *cold*, which as
+unsigned lies past every row of it), a chunk's cold slots' positions in
+slot order, two 16-bit positions a word and filled to the chunk's end
+with the last one again, the chunk's count of them, and the values a
+group of eight slots down the sublanes.
 
 The table stays in HBM. Grid step 0 copies the resident range into a
 VMEM scratch with one DMA; the scratch is held once and lives over the
 whole grid. A grid step then takes a chunk of the block's slots: their
-re-based indices and cold list come through SMEM, the counts by scalar
-prefetch, the values as a VMEM block, and the gathered rows leave
-through the pipelined output block.
+indices, resident rows and cold list come through SMEM, the counts by
+scalar prefetch, the values as a VMEM block, and the gathered rows
+leave through the pipelined output block.
 
-  pass 1  every slot, no branch: the slot's row of the scratch (the
-          last row of it where the slot is cold: as unsigned a negative
-          index lies past every row) is loaded by a dynamic-row vector
-          load and stored to the slot's row of the output;
-  pass 2  every cold slot of the list: a row DMA from the HBM table
-          straight over the slot's row of the output block, all on one
-          semaphore, ``FETCH`` a trip and many in flight (a row copied
-          twice is the same row);
+  pass 1  every slot, no branch: the slot's resident row is loaded from
+          SMEM, that row of the scratch by a dynamic-row vector load,
+          and stored to the slot's row of the output;
+  pass 2  every cold slot of the list: its index, and a row DMA from
+          that row of the HBM table straight over the slot's row of the
+          output block, all on one semaphore, ``FETCH`` a trip and many
+          in flight (a row copied twice is the same row);
   pass 3  one wait a trip of pass 2;
   pass 4  the values' lane, 1024 slots a trip: a tile of the values is
           128 groups of eight slots, a group down the sublanes at its
           own lane; a lane rotation brings a group to lane ``lane`` and
           a masked store writes that lane of the group's eight rows.
 
-Pass 1 is bound by the scalar slots of a bundle, two of them for four
-operations a slot (the index's address and load, the clamp, the row's
-address; the store's address is the trip's plus a constant): 69 bundles
-for 32 slots by the static schedule where the kernel that kept the list
-itself took 84 for 16 and ten operations (``scripts/step0_als_gather.py
---bundles``, PR 46). Pass 2 is bound the same way, eight operations and
-the DMA's own bundle a cold slot (81 bundles for 16), and pass 4 by the
-three units that rotate, eight cycles a rotation (456 bundles for 1024
-slots). ``PERF.md`` section 6, PR 46, has the chip's readings beside
-the schedule's and the forms that were dropped (the resident row ready
-made, which saves the clamp and costs a second list; the lane written
-in pass 1 and again over the list; a transpose in the kernel). One v5e
-at the benchmark's block (196 608 slots, a table of 663 560 rows): XLA's
-gather 9.0 ns a slot whatever the rows; copying the resident range in
-costs 2.9 ns a row a call (one DMA or sixteen: 175 GB/s), so a row pays
-for its place only if a call reads it about once: the heavy class does,
-the classes before it do not (PR 37).
+Neither of the first two passes computes a row: a slot's two row
+addresses are loads. Pass 1 is bound by the scalar slots of a bundle,
+two of them for three operations a slot (the resident row's address and
+load, the row's address; the store's address is the trip's plus a
+constant): 51 bundles for 32 slots by the static schedule, where the
+kernel that clamped the re-based index itself took 69 (until PR 53) and
+the one that kept the list too 84 for 16 (until PR 46;
+``scripts/step0_als_gather.py --bundles``). Pass 2 is bound the same
+way, seven operations and the DMA's own bundle a cold slot (75 bundles
+for 16; 81 while it added ``hot_row0`` back to a re-based index), and
+pass 4 by the three units that rotate, eight cycles a rotation (451
+bundles for 1024 slots). ``PERF.md`` section 6, PRs 46 and 53, has the
+chip's readings beside the schedule's and the forms that were dropped
+(the cold slots' table rows packed at offsets and copied into SMEM by
+hand, which holds 1.8 GB less and whose pass 2 takes 83 bundles; the
+lane written in pass 1 and again over the list; a transpose in the
+kernel). One v5e at the benchmark's block (196 608 slots, a table of
+663 560 rows): 0.600 ms a block at the cell's mix of slots (0.688 from
+the users' table) where the kernel that clamped read 0.672 (0.768), all
+hot 0.436, all cold 1.189 (PR 53); XLA's gather 9.0 ns a slot whatever
+the rows; copying
+the resident range in costs 2.9 ns a row a call (one DMA or sixteen:
+175 GB/s), so a row pays for its place only if a call reads it about
+once: the heavy class does, the classes before it do not (PR 37).
 
 Interpreted on the CPU the kernel runs the same loads, stores and
 copies in the same order.
@@ -88,11 +96,10 @@ def chunk_rows(block_rows: int, most: int = CHUNK_ROWS) -> int:
     return max(fits, default=0)
 
 
-def _als_gather_kernel(n_cold_ref, rel_ref, cold_ref, val_ref, tab_ref,
-                       out_ref, res_ref, res_sem, row_sem, *,
+def _als_gather_kernel(n_cold_ref, idx_ref, row_ref, cold_ref, val_ref,
+                       tab_ref, out_ref, res_ref, res_sem, row_sem, *,
                        hot_row0: int, lane: int):
-    slots = rel_ref.shape[0]
-    last = res_ref.shape[0] - 1
+    slots = row_ref.shape[0]
 
     @pl.when(pl.program_id(0) == 0)
     def _load():
@@ -106,12 +113,8 @@ def _als_gather_kernel(n_cold_ref, rel_ref, cold_ref, val_ref, tab_ref,
         first = pl.multiple_of(t * UNROLL, UNROLL)
         rows = out_ref.at[pl.ds(first, UNROLL), :]
         for u in range(UNROLL):
-            # a cold slot reads the range's last row: as unsigned it
-            # lies past every row of it
-            row = jnp.minimum(rel_ref[first + u].astype(jnp.uint32),
-                              jnp.uint32(last))
-            rows[pl.ds(u, 1), :] = \
-                res_ref[pl.ds(row.astype(jnp.int32), 1), :]
+            # (a cold slot's is the range's last row: the loader's clamp)
+            rows[pl.ds(u, 1), :] = res_ref[pl.ds(row_ref[first + u], 1), :]
         return carry
 
     jax.lax.fori_loop(0, slots // UNROLL, some, 0)
@@ -121,7 +124,7 @@ def _als_gather_kernel(n_cold_ref, rel_ref, cold_ref, val_ref, tab_ref,
         first = pl.multiple_of(g * (FETCH // 2), FETCH // 2)
         words = [cold_ref[first + u] for u in range(FETCH // 2)]
         at = [a for w in words for a in (w & 0xFFFF, w >> 16)]
-        rows = [rel_ref[a] + hot_row0 for a in at]
+        rows = [idx_ref[a] for a in at]
         for a, h in zip(at, rows):
             pltpu.make_async_copy(
                 tab_ref.at[pl.ds(h, 1), :], out_ref.at[pl.ds(a, 1), :],
@@ -159,26 +162,29 @@ def _als_gather_kernel(n_cold_ref, rel_ref, cold_ref, val_ref, tab_ref,
 # a run's set-up at the benchmark's shape
 @functools.partial(jax.jit,
                    static_argnames=("hot_row0", "lane", "interpret"))
-def gather_rows_resident(table, rel_b, val_t, cold_b, n_cold,
+def gather_rows_resident(table, idx_b, val_t, row_b, cold_b, n_cold,
                          hot_row0: int, lane: int, *,
                          interpret: bool = False):
-    """``table[rel_b.reshape(-1) + hot_row0]`` with lane ``lane`` of
-    every row the slot's value, for ``table`` float32 ``(rows, 128)``
-    and a block as ``als_sparse.gather_lists`` holds it: ``rel_b`` int32
-    ``(block rows, 128)`` the indices less ``hot_row0`` (every index in
-    bounds), ``val_t`` float32 the same shape (the values in tiles of
-    1024 slots, eight slots down the sublanes), ``cold_b`` int32
-    ``(slots / 2,)`` and ``n_cold`` int32 ``(chunks,)`` the chunks'
-    cold lists and counts. The rows from ``hot_row0`` on are read out
-    of VMEM."""
+    """``table[idx_b.reshape(-1)]`` with lane ``lane`` of every row the
+    slot's value, for ``table`` float32 ``(rows, 128)``, a block of the
+    pack's indices ``idx_b`` int32 ``(block rows, 128)`` (every index in
+    bounds) and what ``als_sparse.gather_lists`` made of it: ``val_t``
+    float32 the same shape (the values in tiles of 1024 slots, eight
+    slots down the sublanes), ``row_b`` int32 the same shape (a slot's
+    row of the resident range, its last row where the slot is cold),
+    ``cold_b`` int32 ``(slots / 2,)`` and ``n_cold`` int32 ``(chunks,)``
+    the chunks' cold lists and counts. The rows from ``hot_row0`` on are
+    read out of VMEM."""
     n_rows, width = table.shape
-    if width != LANES or rel_b.ndim != 2 or rel_b.shape[1] != LANES:
-        raise ValueError(f"table {table.shape} and indices {rel_b.shape} "
-                         f"are not rows of {LANES} lanes")
-    cr = chunk_rows(rel_b.shape[0])    # as the lists were made
+    if width != LANES or idx_b.ndim != 2 or idx_b.shape[1] != LANES \
+            or row_b.shape != idx_b.shape:
+        raise ValueError(f"table {table.shape}, indices {idx_b.shape} and "
+                         f"resident rows {row_b.shape} are not rows of "
+                         f"{LANES} lanes")
+    cr = chunk_rows(idx_b.shape[0])    # as the lists were made
     if cr < SUBLANES or not 0 <= hot_row0 < n_rows:
         raise ValueError(f"no chunk of {cr} rows in a block of "
-                         f"{rel_b.shape[0]}, or no resident row from "
+                         f"{idx_b.shape[0]}, or no resident row from "
                          f"{hot_row0} of {n_rows}")
     n_res = n_rows - hot_row0
     slots = cr * LANES
@@ -192,8 +198,8 @@ def gather_rows_resident(table, rel_b, val_t, cold_b, n_cold,
         name="_als_gather_kernel",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(rel_b.shape[0] // cr,),
-            in_specs=[smem(slots), smem(slots // 2),
+            grid=(idx_b.shape[0] // cr,),
+            in_specs=[smem(slots), smem(slots), smem(slots // 2),
                       pl.BlockSpec((cr, LANES), lambda c, n_cold: (c, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((slots, LANES),
@@ -201,7 +207,7 @@ def gather_rows_resident(table, rel_b, val_t, cold_b, n_cold,
             scratch_shapes=[pltpu.VMEM((n_res, LANES), table.dtype),
                             pltpu.SemaphoreType.DMA(()),
                             pltpu.SemaphoreType.DMA(())]),
-        out_shape=jax.ShapeDtypeStruct((rel_b.size, LANES), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((idx_b.size, LANES), table.dtype),
         compiler_params=pltpu.CompilerParams(
             # the resident range lives across the whole grid
             dimension_semantics=("arbitrary",),
@@ -209,7 +215,7 @@ def gather_rows_resident(table, rel_b, val_t, cold_b, n_cold,
             # every index is in bounds, as XLA's form is promised
             disable_bounds_checks=True),
         interpret=interpret,
-    )(n_cold, rel_b.reshape(-1), cold_b, val_t, table)
+    )(n_cold, idx_b.reshape(-1), row_b.reshape(-1), cold_b, val_t, table)
 
 
 # ------------------------------------------------------------ the solve

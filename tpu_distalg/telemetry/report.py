@@ -327,8 +327,11 @@ def summarize(evts: list[dict]) -> dict:
                         f", {e.get('gather_cold_share', '?')} cold and "
                         f"listed by the loader ("
                         f"{sum(e.get('gather_cold_slots', []))} slots, "
-                        f"{e.get('gather_list_bytes', 0)} B), lanes by "
-                        f"the {e.get('gather_lanes', '?')}")
+                        f"{e.get('gather_list_bytes', 0)} B), a slot's "
+                        f"rows by the {e.get('gather_slot_rows', '?')}, "
+                        f"lanes by the {e.get('gather_lanes', '?')}")
+                elif e.get("gather_cold_list") == "no room":
+                    gather += " (no room for the kernel's lists)"
                 solve = e.get("als_solve_form", "?")
                 if e.get("solve_tile_systems"):
                     # the systems a tile holds in VMEM from the Gramian
