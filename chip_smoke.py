@@ -917,6 +917,78 @@ def stage_ssgd_indexed(s: Smoke):
             f"{err:.2g} | held-out log-loss {loss:.4f} acc {acc:.4f}")
 
 
+def stage_ssgd_pairs(s: Smoke):
+    """SSGD over rows of (feature, value) pairs as ``tda ssgd
+    --row-format pairs`` runs it, on every chip the stage has: the
+    loader's table at a small size (40 000 ragged rows of 8 to 16 384
+    pairs, 20M pairs, 2M weights in HBM, blocks of 2^16 pair slots
+    sharded over the data axis), 12 steps of the block-sampled trainer
+    (the valued gather and scatter against the table in HBM, the row
+    sums by vectors, on a mesh the 8 MB gradient psummed); against the
+    same steps in float64 on the host over the table's own CSR arrays
+    on the first step's blocks, to float32 rounding; and held-out rows
+    scored better than zero weights score them."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_distalg.models import ssgd, ssgd_pairs
+    from tpu_distalg.ops import pairs, sampling
+    from tpu_distalg.utils import prng
+
+    mesh = s.mesh()
+    shards = mesh.shape["data"]
+    spec = ssgd_pairs.PairsSpec(
+        n_rows=40_000, n_features=2_000_003,
+        length_mu=ssgd_pairs.length_mu_for(40_000, 500.0,
+                                           length_max=1 << 14),
+        block_slots=1 << 16, block_rows=256, n_blocks=352,
+        length_max=1 << 14, scatter_c=12345)
+    cfg = ssgd.SSGDConfig(
+        n_iterations=1, eval_test=False, sampler="fused_gather",
+        mini_batch_fraction=0.05)
+    fn, X, w0, meta = ssgd_pairs.prepare_synthetic(spec, mesh, cfg,
+                                                   data_seed=5)
+    s.check_sharded("X", X)
+    geom = ssgd_pairs.geometry(meta)
+    d = jnp.zeros((1,), jnp.float32)
+    w1, _ = fn(X, d, d, d, d, w0, t0=7)
+    # the first step again in float64, from the table's own rows
+    n_blocks, n_sampled = ssgd.fused_gather_geometry(cfg, meta, shards)
+    draws = np.asarray(sampling.sample_block_ids(
+        jax.random.fold_in(prng.root_key(cfg.seed), 7), shards, n_blocks,
+        n_sampled))
+    drawn = (draws + np.arange(shards)[:, None] * n_blocks).reshape(-1)
+    indptr, ids, vals, y, _ = pairs.csr_from_blocks(
+        np.asarray(X)[drawn], geom)
+    g = np.zeros(geom.n_features + 1)
+    r = 0.5 - y.astype(np.float64)          # zero weights: sigmoid(0)
+    np.add.at(g, ids, np.repeat(r, np.diff(indptr)) * vals)
+    g[-1] = r.sum()
+    want = -cfg.eta * g / len(y)
+    got = np.asarray(w1, np.float64)[:geom.n_features + 1]
+    err = float(np.linalg.norm(got - want) / np.linalg.norm(want))
+    if not err < 1e-4:
+        raise AssertionError(f"a step differs from float64 over the "
+                             f"drawn blocks' {len(y)} rows by {err:.3g}")
+    if len(y) != int(meta["block_counts"][drawn].sum()):
+        raise AssertionError("the drawn blocks' rows")
+    run = ssgd.make_train_fn_fused(
+        mesh, dataclasses.replace(cfg, n_iterations=12), meta)
+    w, _ = run(X, d, d, d, d, w0)
+    acc, loss = ssgd_pairs.evaluate(w, meta, data_seed=5)
+    if not loss < 0.69:
+        raise AssertionError(f"held-out log-loss {loss:.4f} after 12 "
+                             f"steps (zero weights: 0.6931)")
+    return (f"dp={shards} | table {tuple(X.shape)} | {geom.n_slots} "
+            f"weights in HBM | {meta['n_pairs']} pairs in "
+            f"{meta['blocks_used']} of {meta['n_blocks']} blocks | a "
+            f"step of {len(y)} rows against float64 {err:.2g} | "
+            f"held-out log-loss {loss:.4f} acc {acc:.4f}")
+
+
 def stage_als_sparse(s: Smoke):
     """ALS on a ratings list as ``tda als --ratings`` runs it, on every
     chip the stage has: the seeded loader (2 000 000 ratings of 20 000
@@ -1096,6 +1168,8 @@ STAGES = (
                    "pallas_hashed._hashed_value_gather_kernel",
                    "pallas_hashed._hashed_value_sums_kernel",
                    "pallas_hashed._hashed_hbm_gather_kernel"))),
+    # XLA's gather and scatter-add: no kernel of the program's
+    ("ssgd_pairs", stage_ssgd_pairs, {}),
     # one chip builds pallas_als._als_gather_kernel, a mesh no kernel
     ("als_sparse", stage_als_sparse, {}),
     # one chip builds pallas_closure._compose_kernel, a mesh no kernel

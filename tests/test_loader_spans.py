@@ -640,10 +640,12 @@ def test_the_manifest_lists_the_new_metrics_where_the_issue_says():
         manifest = json.load(f)
     als = ["als100_253m_sweep1"]
     graph = ["pagerank_g500_24_resident", "pagerank_g500_sharded4_job10"]
-    lr = ["lrhash39_46m_frac01", "lrwide11_150m_frac01"]
-    # the five loader cells of PR 49 and the dense closure's, which
-    # PR 52 appended to the three lists its loader's spans feed
-    five = [lr[0], *als, *graph, lr[1], "closure_grid250_round1"]
+    pairs = "lrpairs3728_350k_frac01"
+    lr = ["lrhash39_46m_frac01", "lrwide11_150m_frac01", pairs]
+    # the five loader cells of PR 49, the dense closure's, which PR 52
+    # appended to the three lists its loader's spans feed, and PR 54's
+    # cell of ragged rows, appended to those and to ``generate_s.lr``
+    five = [lr[0], *als, *graph, lr[1], "closure_grid250_round1", pairs]
     want = {"pack_s.als": als, "generate_s.als": als, "heldout_s.als": als,
             "lists_s.als": als, "generate_s.graph": graph,
             "dedup_s.graph": graph, "generate_s.lr": lr,
@@ -655,8 +657,10 @@ def test_the_manifest_lists_the_new_metrics_where_the_issue_says():
     at = names.index("pack_s.als")
     tail = manifest["per_layer"][at:at + len(want)]
     assert [m["name"] for m in tail] == list(want)     # appended, in order
-    assert not any(n.split(".")[-1] in ("als", "graph", "lr", "kmeans")
-                   for n in names[at + len(want):])    # then PR 52's
+    later = [n for n in names[at + len(want):]          # then PR 52's
+             if n.split(".")[-1] in ("als", "graph", "lr", "kmeans")]
+    assert later == ["rowsum_ms_per_step.lr", "pair_padding_pct.lr",
+                     "median_call_pairs_per_s.lr"]       # and PR 54's
     for m in tail:
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}

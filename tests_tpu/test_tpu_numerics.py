@@ -1240,6 +1240,66 @@ def test_indexed_passes_against_the_plain_reference(tpu_mesh):
         assert err < 6e-5 < ctl
 
 
+def test_pairs_passes_against_the_plain_reference(tpu_mesh):
+    """The compiled steps of SSGD over rows of (feature, value) pairs
+    (40 000 ragged rows of 8 to 16 384 pairs, 2M weights in HBM, blocks
+    of 2^16 pair slots) against the benchmark's plain reference
+    (``benchmarks/reference/ssgd_pairs_ref.py``: no table, the sampled
+    blocks' rows regenerated, ``w[idx] * val``, a ``segment_sum`` a row,
+    ``.at[idx].add`` a block) over two calls of three steps: every
+    weight and the bias to float32 rounding, where the reference in
+    bfloat16 stands orders off."""
+    import os
+    import sys
+
+    from tpu_distalg.models import ssgd_pairs
+    from tpu_distalg.parallel import get_mesh
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import ssgd_pairs_ref as ref_mod
+
+    mu = ssgd_pairs.length_mu_for(40_000, 500.0, length_max=1 << 14)
+    c = dict(n_rows=40_000, n_features=2_000_003, pair_block_slots=1 << 16,
+             pair_block_rows=256, pair_row_granule=128, pair_blocks=352,
+             length_mu=mu, length_sigma=1.0, length_min=8,
+             length_max=1 << 14, zipf_exponent=1.1, scatter_a=251,
+             scatter_c=12345, planted_scale=0.25, positive_rate=0.6,
+             eta=0.1, bias_blocks=64, heldout_blocks=64,
+             heldout_offset=1 << 20)
+    spec = ssgd_pairs.PairsSpec(
+        n_rows=c["n_rows"], n_features=c["n_features"], length_mu=mu,
+        block_slots=1 << 16, block_rows=256, n_blocks=352,
+        length_max=1 << 14, scatter_c=12345)
+    mesh = get_mesh(data=1, devices=jax.devices()[:1])
+    cfg = ssgd.SSGDConfig(
+        n_iterations=3, eta=0.1, lam=0.0, mini_batch_fraction=0.05,
+        seed=42, eval_test=False, sampler="fused_gather")
+    fn, X, w, meta = ssgd_pairs.prepare_synthetic(spec, mesh, cfg,
+                                                  data_seed=17)
+    ref = ref_mod.Reference(config=c, fraction=0.05, data_seed=17,
+                            sample_seed=42)
+    assert np.array_equal(ref.starts, meta["block_starts"])
+    assert np.array_equal(ref.counts, meta["block_counts"])
+    assert float(ref.bias) == meta["bias"]
+    d = jnp.zeros((1,), jnp.float32)
+    got = []
+    for call in range(2):
+        w, _ = fn(X, d, d, d, d, w, t0=3 * call)
+        got.append(ref_mod.model_vector(w, c["n_features"]))
+    w0 = np.zeros((c["n_features"] + 1,), np.float32)
+    good = ref.follow(2, 3)
+    low = ref.follow(2, 3, dtype=jnp.bfloat16)
+    for k in range(2):
+        err = ref_mod.rel_err(got[k], good[k], w0)
+        ctl = ref_mod.rel_err(low[k], good[k], w0)
+        print(f"[pairs] call {k + 1}: program against reference "
+              f"{err:.3g}, bfloat16 control {ctl:.3g}")
+        assert err < 3e-4 < ctl
+
+
 def test_sparse_als_half_sweep_at_rank_100(tpu_mesh):
     """A sparse ALS half-sweep compiled at the benchmark's rank and
     widths (rank 100 in 128 lanes, segments of 32 slots, the eleven

@@ -328,6 +328,32 @@ def main(argv=None):
                    help="distinct values of each field for "
                         "--indexed-rows (their count is --fields); "
                         "default: the click log's cardinalities")
+    p.add_argument("--row-format", default=None, choices=["pairs"],
+                   help="'pairs' trains on --pair-rows ragged rows, "
+                        "each a list of (int32 feature, float32 value) "
+                        "pairs of its own length, unit-length rows: a "
+                        "LIBSVM file's SparseVectors (webspam's "
+                        "trigrams), the weights in HBM, a block a fixed "
+                        "number of pair slots that holds whole rows "
+                        "(ops/pairs.py); tda report prints the rows, "
+                        "pairs, padding and the form of each pass. The "
+                        "two one-hot formats are named by their tables, "
+                        "--hashed-rows and --indexed-rows")
+    p.add_argument("--pair-rows", type=int, default=1 << 14, metavar="N",
+                   help="rows of the --row-format pairs table")
+    p.add_argument("--features", type=int, default=1 << 20, metavar="D",
+                   help="features (weights) of the pairs table; a "
+                        "pair's feature is a power-law rank scattered "
+                        "over the ids by a fixed bijection")
+    p.add_argument("--length-mu", type=float, default=5.0,
+                   help="mu of the pairs table's row lengths: "
+                        "log-normal, sigma 1, clipped to --min-pairs .. "
+                        "--max-pairs (5.0: 245 pairs a row on average)")
+    p.add_argument("--min-pairs", type=int, default=8)
+    p.add_argument("--max-pairs", type=int, default=1 << 16)
+    p.add_argument("--pair-block-slots", type=int, default=1 << 18,
+                   help="pair slots a block of the pairs table (whole "
+                        "vectors of 128; at least the longest row)")
 
     for name in ("ma", "bmuf", "easgd"):
         p = sub.add_parser(name)
@@ -1273,8 +1299,10 @@ def _dispatch(args, jax):
     if args.cmd in ("lr", "ssgd", "ma", "bmuf", "easgd"):
         from tpu_distalg.utils import datasets
 
+        pairs = args.cmd == "ssgd" and args.row_format == "pairs"
         hashed = args.cmd == "ssgd" and (args.hashed_rows > 0
-                                         or args.indexed_rows > 0)
+                                         or args.indexed_rows > 0
+                                         or pairs)
         data = None if hashed else datasets.breast_cancer_split()
         mesh = _mesh(args)
         t0 = time.perf_counter()
@@ -1283,13 +1311,14 @@ def _dispatch(args, jax):
 
             if args.stream_cache is not None:
                 raise SystemExit(
-                    "--hashed-rows / --indexed-rows build their table on "
-                    "the device; --stream-cache streams packed columns "
-                    "from disk")
-            if args.hashed_rows > 0 and args.indexed_rows > 0:
+                    "--hashed-rows / --indexed-rows / --row-format pairs "
+                    "build their table on the device; --stream-cache "
+                    "streams packed columns from disk")
+            if (args.hashed_rows > 0) + (args.indexed_rows > 0) + pairs > 1:
                 raise SystemExit(
-                    "--hashed-rows and --indexed-rows name two tables: "
-                    "give one")
+                    "--hashed-rows, --indexed-rows and --row-format pairs "
+                    "each name a table, and two tables were named: give "
+                    "one")
             indexed = args.indexed_rows > 0
             cards = None
             if args.field_values is not None:
@@ -1311,6 +1340,19 @@ def _dispatch(args, jax):
                 comm=args.comm, sync=args.sync, eval_test=False)
 
             def run_once():
+                if pairs:
+                    from tpu_distalg.models import ssgd_pairs
+
+                    return ssgd_pairs.train(
+                        ssgd_pairs.PairsSpec(
+                            n_rows=args.pair_rows,
+                            n_features=args.features,
+                            length_mu=args.length_mu,
+                            block_slots=args.pair_block_slots,
+                            length_min=args.min_pairs,
+                            length_max=args.max_pairs),
+                        mesh, cfg, checkpoint_dir=args.checkpoint_dir,
+                        checkpoint_every=args.checkpoint_every)
                 if indexed:
                     return m.train_hashed(
                         args.indexed_rows,

@@ -940,7 +940,13 @@ def _make_train_fn_tp(mesh: Mesh, config: SSGDConfig, n_padded: int):
 def fused_gather_geometry(config: SSGDConfig, meta: dict, n_shards: int):
     """Per-shard block-sampling geometry of the 'fused_gather' sampler:
     (blocks per shard, blocks sampled per shard per step). Single source
-    of truth for the bytes a step moves."""
+    of truth for the bytes a step moves. A table of ragged rows
+    (``row_format`` pairs) is a grid of blocks of pair slots, which
+    ``gather_block_rows`` does not size."""
+    if meta.get("row_format") == "pairs":
+        from tpu_distalg.models import ssgd_pairs
+
+        return ssgd_pairs.blocks_geometry(config, meta, n_shards)
     if config.gather_block_rows % meta["pack"]:
         # the kernel raises the same constraint at trace time; catching it
         # here keeps the derived n_blocks/n_sampled from silently using a
@@ -998,6 +1004,11 @@ def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
     from tpu_distalg.ops import pallas_kernels
     from tpu_distalg.parallel import DATA_AXIS
 
+    if meta.get("row_format", "packed") == "pairs":
+        # ragged rows of (feature, value) pairs: models/ssgd_pairs.py
+        from tpu_distalg.models import ssgd_pairs
+
+        return ssgd_pairs.make_train_fn(mesh, config, meta)
     if meta.get("row_format", "packed") in INDEX_ROW_FORMATS:
         # the loader's meta decides: rows that are indices have their
         # own two passes and share everything round them
@@ -1900,9 +1911,12 @@ def _train_fused(
 # ``indexed``, the fields' ranges end to end (``meta["cardinalities"]``),
 # every value its own weight. Everything round the passes is the packed
 # rows': the block grid of ``fused_gather_geometry``, the draw,
-# ``_build_scan``'s step, the psum.
+# ``_build_scan``'s step, the psum. The third format, ``pairs``: a row is
+# a list of (feature, value) pairs of its own length, float32 values
+# (``ops/pairs.py``, ``models/ssgd_pairs.py``); it shares the same
+# things and the refusals of :func:`_check_hashed_config`.
 
-INDEX_ROW_FORMATS = ("hashed", "indexed")
+INDEX_ROW_FORMATS = ("hashed", "indexed", "pairs")
 
 
 @dataclasses.dataclass
@@ -1935,8 +1949,9 @@ def hashed_geometry(config: SSGDConfig, meta: dict):
 def _check_hashed_config(config: SSGDConfig,
                          row_format: str = "hashed") -> None:
     """The one place that says which trainers take rows of indices
-    (``row_format`` hashed or indexed): the per-step block-sampled one
-    ('fused_gather'), dense BSP. The others read a row as columns."""
+    (``row_format`` hashed, indexed or pairs): the per-step
+    block-sampled one ('fused_gather'), dense BSP. The others read a
+    row as columns."""
     from tpu_distalg.parallel import ssp as pssp
 
     rows = f"{row_format} rows"
@@ -1948,8 +1963,8 @@ def _check_hashed_config(config: SSGDConfig,
                  "kernel",
         "fused_train": "keeps a packed step's 40 weights in the "
                        "megakernel's VMEM; a weight table (2**hash_bits "
-                       "slots hashed, a slot a feature indexed) and a "
-                       "psum a step do not fit one launch",
+                       "slots hashed, a slot a feature indexed or in "
+                       "pairs) and a psum a step do not fit one launch",
         "virtual": "regenerates packed columns on the device",
     }
     if config.sampler != "fused_gather":
@@ -2161,6 +2176,9 @@ def build_hashed_table(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
     from tpu_distalg.parallel import DATA_AXIS
     from tpu_distalg.utils import datasets as dsets
 
+    if row_format == "pairs":
+        raise ValueError("row_format 'pairs': ragged rows have their own "
+                         "loader, models/ssgd_pairs.build_table")
     if row_format not in INDEX_ROW_FORMATS:
         raise ValueError(f"row_format {row_format!r}: one of "
                          f"{INDEX_ROW_FORMATS}")
@@ -2235,6 +2253,33 @@ def evaluate_hashed(w, meta: dict, *, data_seed: int = 0,
     return float(acc), float(loss)
 
 
+def run_index_rows(fn, X, w0, meta: dict, mesh: Mesh, config: SSGDConfig,
+                   fields: dict, *, tag: str, what: str,
+                   checkpoint_dir: str | None, checkpoint_every: int):
+    """The run of a table whose rows ride in ``X`` alone (hashed,
+    indexed, pairs), straight through or in checkpointed segments:
+    ``(w, accs)``."""
+    dummy = jnp.zeros((1,), jnp.float32)
+    fields = dict(_draw_fields(config, meta, mesh), **fields)
+    if checkpoint_dir is None:
+        with _train_span(config, **fields):
+            w, accs = fn(X, dummy, dummy, dummy, dummy, w0)
+            metrics.guard_finite(w, what)
+        return w, accs
+    from tpu_distalg.parallel import partition
+    from tpu_distalg.utils import checkpoint as ckpt
+
+    (w, _), accs, _ = ckpt.run_segmented(
+        checkpoint_dir, checkpoint_every, config.n_iterations,
+        make_seg_fn=lambda seg: make_train_fn_fused(
+            mesh, dataclasses.replace(config, n_iterations=seg), meta),
+        run_seg=_acc_carrying_run_seg(X, dummy, dummy, dummy, dummy),
+        state0=(w0, partition.put(jnp.float32(0), "acc0", "ssgd", mesh)),
+        tag=tag, span_fields=fields,
+    )
+    return w, accs
+
+
 def train_hashed(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
                  config: SSGDConfig, *, data_seed: int = 0,
                  cardinalities=None, row_format: str = "hashed",
@@ -2247,28 +2292,11 @@ def train_hashed(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
     fn, X, w0, meta = prepare_hashed_synthetic(
         n_rows, nnz, hash_bits, mesh, config, data_seed=data_seed,
         cardinalities=cardinalities, row_format=row_format)
-    dummy = jnp.zeros((1,), jnp.float32)
-    fields = dict(_draw_fields(config, meta, mesh),
-                  **_hashed_fields(config, meta))
-    if checkpoint_dir is None:
-        with _train_span(config, **fields):
-            w, accs = fn(X, dummy, dummy, dummy, dummy, w0)
-            metrics.guard_finite(w, "SSGD (hashed) weights")
-    else:
-        from tpu_distalg.parallel import partition
-        from tpu_distalg.utils import checkpoint as ckpt
-
-        (w, _), accs, _ = ckpt.run_segmented(
-            checkpoint_dir, checkpoint_every, config.n_iterations,
-            make_seg_fn=lambda seg: make_train_fn_fused(
-                mesh, dataclasses.replace(config, n_iterations=seg),
-                meta),
-            run_seg=_acc_carrying_run_seg(X, dummy, dummy, dummy, dummy),
-            state0=(w0, partition.put(jnp.float32(0), "acc0", "ssgd",
-                                      mesh)),
-            tag=f"ssgd:{row_format}:{nnz}x{hash_bits or meta['d_total']}",
-            span_fields=fields,
-        )
+    w, accs = run_index_rows(
+        fn, X, w0, meta, mesh, config, _hashed_fields(config, meta),
+        tag=f"ssgd:{row_format}:{nnz}x{hash_bits or meta['d_total']}",
+        what="SSGD (hashed) weights", checkpoint_dir=checkpoint_dir,
+        checkpoint_every=checkpoint_every)
     with tevents.span("ssgd:heldout"):
         acc, loss = evaluate_hashed(w, meta, data_seed=data_seed)
     return HashedResult(w=jnp.asarray(w), accs=jnp.asarray(accs),

@@ -30,6 +30,11 @@ SSGD_SCATTER = "tda.ssgd.scatter"  # the residuals added up slot by slot
 # benchmarks/layer_metrics/hbm_fields_ms_per_step.lr reads it, and
 # update_ms_per_step.lr the 219 MB update under SSGD_UPDATE
 SSGD_TABLE_HBM = "tda.ssgd.table_hbm"
+# inside either pass of a table of ragged (feature, value) rows
+# (ops/pairs.py): the sum over a row's pairs and a row's residual handed
+# back to its pairs; benchmarks/layer_metrics/rowsum_ms_per_step.lr
+# reads it. The pairs against the table are under SSGD_TABLE_HBM there.
+SSGD_ROWSUM = "tda.ssgd.rowsum"
 # the three parts of a fused PageRank sweep (models/pagerank.py); the
 # benchmark's spmv_ms_per_sweep.graph and pagerank_spmv_roofline read
 # the first, sync_ms_per_sweep.graph and sync_exposed_ms_per_sweep.graph

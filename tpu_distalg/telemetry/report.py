@@ -216,6 +216,7 @@ def summarize(evts: list[dict]) -> dict:
     sums_forms: list[str] = []
     dist_forms: list[str] = []
     row_formats: list[str] = []
+    pair_tables: list[tuple] = []
     pass_forms: dict[str, list[str]] = {"gather": [], "scatter": []}
     field_splits: list[tuple] = []
     addr_calls: list[tuple] = []
@@ -311,6 +312,18 @@ def summarize(evts: list[dict]) -> dict:
                               e.get("table_bytes", 0))
                 if split not in field_splits:
                     field_splits.append(split)
+            # and what a table of ragged (feature, value) rows holds
+            # (models/ssgd_pairs.fields)
+            if "pair_slots" in e:
+                got = (e.get("pairs_rows", 0), e.get("pairs", 0),
+                       e["pair_slots"], e.get("longest_row", 0),
+                       e.get("pair_blocks_used", 0),
+                       e.get("pair_blocks", 0),
+                       e.get("pair_block_slots", 0),
+                       e.get("table_bytes", 0),
+                       e.get("rowsum_form", "?"))
+                if got not in pair_tables:
+                    pair_tables.append(got)
             # and ALS' how R is held (a dense R says nothing; a ratings
             # list says so) with the form of each piece of a half-sweep
             # (models/als.segment_fields: xla / mosaic)
@@ -431,6 +444,7 @@ def summarize(evts: list[dict]) -> dict:
         "sums_forms": sums_forms,
         "dist_forms": dist_forms,
         "row_formats": row_formats,
+        "pair_tables": pair_tables,
         "pass_forms": pass_forms,
         "field_splits": field_splits,
         "addr_calls": addr_calls,
@@ -545,6 +559,15 @@ def render(s: dict) -> str:
             line += (f", in HBM: {indexed[0]} (a table of "
                      f"{indexed[1] / 1e6:.1f} MB)")
         lines.append(line)
+    for (rows, pairs, slots, longest, used, blocks, block_slots, table,
+         rowsum) in s.get("pair_tables") or ():
+        lines.append(
+            f"pairs: {rows} rows of {pairs} (feature, value) pairs, "
+            f"longest {longest}, in {used} of {blocks} blocks of "
+            f"{block_slots} slots ({slots - pairs} of {slots} slots hold "
+            f"no pair: {(1 - pairs / max(slots, 1)) * 100:.2f}%); "
+            f"{table / 1e6:.1f} MB of weights in HBM, row sums by "
+            f"{rowsum}")
     for kernel, fields, rows, pairs, smem_rows in s.get("addr_calls") or ():
         lines.append(f"by-address call: {kernel} over fields "
                      f"{list(fields)}: {rows} rows a trip ({pairs} pairs), "

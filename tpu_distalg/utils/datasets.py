@@ -333,6 +333,180 @@ def _click_rows(draw, planted_scale: float, click_rate: float):
     return make_rows
 
 
+def ragged_pair_rows(n_rows: int, n_features: int, *, length_mu: float,
+                     length_sigma: float = 1.0, length_min: int = 8,
+                     length_max: int = 1 << 16,
+                     zipf_exponent: float = 1.1, scatter_a: int = 251,
+                     scatter_c: int = 0, planted_scale: float = 0.25,
+                     positive_rate: float = 0.6):
+    """Jittable generator of *ragged* rows of (feature, value) pairs: a
+    LIBSVM file's ``SparseVector``s (an n-gram or text set: webspam's
+    trigrams), counter based like the click-log generators (a row is a
+    function of the seed and its id alone, a pair of the seed, its
+    row's id and its place in the row), every draw a 32-bit hash
+    (:func:`_mix32`). Nothing here knows a block or a layout.
+
+    * **A row's length** is a quantile of a log-normal, ``round(exp(
+      length_mu + length_sigma z))`` clipped to ``[length_min,
+      length_max]``. Row ``i`` of the table's ``n_rows`` takes the
+      quantile ``(pi(i) + 1/2) / n_rows``, ``pi`` a permutation of the
+      rows keyed by the seed (:func:`feistel_permutation`): every seed
+      deals the same lengths to other rows, so the pairs add up to the
+      same total whatever the seed. A row past the table (held-out
+      rows, the rows the label's bias is set on) draws its quantile
+      from a hash of its id.
+    * **A pair's feature** is a rank drawn from a bounded power law of
+      ``zipf_exponent`` over ``n_features`` (the indexed generator's
+      exact draw: a stratum by one hash, a place in it by a second)
+      and then scattered over the id space by the fixed bijection ``id
+      = (scatter_a * rank + scatter_c) mod n_features``: no range of
+      ids is hot by construction. Two pairs of a row may name one
+      feature; both are kept.
+    * **A pair's raw value** is ``1 +`` a geometric draw (the leading
+      zeros of a hash: p = 1/2), an integer; :func:`unit_values` scales a
+      row to unit Euclidean length from the exact integer sum of its
+      squares.
+    * **The label** is a Bernoulli draw of a planted logistic model: a
+      feature weighs an odd integer in [-255, 255] hashed from ``(seed,
+      id)``, a row's score is the exact int32 sum of weight x raw value
+      over its pairs, scaled to unit variance a pair, by the row's
+      length and by ``planted_scale``; the bias is set by counting on a
+      calibration set so that ``positive_rate`` of its rows are
+      positive (:func:`set_bias`). Every sum a label rests on is an
+      integer, so two programs that add in different orders draw the
+      same labels.
+
+    Returns a namespace of jittable functions that take the seed
+    (traced or not) last."""
+    import math
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    from jax.scipy.special import ndtri
+
+    u = np.uint32
+    if math.gcd(scatter_a, n_features) != 1:
+        raise ValueError(f"scatter_a {scatter_a} shares a factor with "
+                         f"n_features {n_features}: not a bijection")
+    if not 0 <= scatter_c < n_features \
+            or scatter_a * (n_features - 1) + scatter_c >= 1 << 32:
+        raise ValueError(
+            f"scatter_a {scatter_a} x rank + scatter_c {scatter_c} must "
+            f"stay under 2**32 over {n_features} ranks")
+    if not 0 <= length_min <= length_max:
+        raise ValueError(f"lengths {length_min} .. {length_max}")
+    a1, span = _power_law_span(np.float32(n_features), zipf_exponent)
+    span = np.float32(span)
+    stratum = np.float32(abs(span / a1) * 2.0 ** -24)
+    deal, _ = feistel_permutation(max(n_rows, 2))
+    planted_sd = math.sqrt((256 ** 2 - 1) / 3.0)   # of the odd integers
+
+    def key_of(seed, k: int):
+        return _mix32(jnp.asarray(seed).astype(jnp.uint32) * u(0x9E3779B1)
+                      + u((0x85EBCA6B * (k + 1)) & 0xFFFFFFFF))
+
+    def word(key, ids):
+        return _mix32(jnp.asarray(ids).astype(jnp.uint32) * u(0x9E3779B1)
+                      + key)
+
+    def unit(bits):
+        """24 bits of a word as a float32 in (0, 1): a level's middle."""
+        return ((bits >> u(8)).astype(jnp.float32) + 0.5) * (2.0 ** -24)
+
+    def lengths(row_ids, seed):
+        ids = jnp.asarray(row_ids, jnp.int32)
+        inside = ids < n_rows
+        dealt = deal(jnp.where(inside, ids, 0).astype(jnp.uint32),
+                     key_of(seed, 0))
+        q = jnp.where(
+            inside,
+            (dealt.astype(jnp.float32) + 0.5) / np.float32(n_rows),
+            unit(word(key_of(seed, 1), ids)))
+        x = jnp.exp(np.float32(length_mu)
+                    + np.float32(length_sigma) * ndtri(q))
+        return jnp.clip(jnp.round(x), length_min,
+                        length_max).astype(jnp.int32)
+
+    def pairs(row_ids, places, seed):
+        """``(ids int32, raw int32)`` of pair ``places`` of rows
+        ``row_ids`` (broadcast against each other)."""
+        row_key = word(key_of(seed, 2), row_ids)
+        j = jnp.asarray(places).astype(jnp.uint32) * u(0x85EBCA6B)
+        h_rank = _mix32(row_key + j)
+        h_place = _mix32((row_key ^ u(0x68E31DA4)) + j)
+        h_value = _mix32((row_key ^ u(0xB5297A4D)) + j)
+        x = (1.0 + (h_rank >> u(8)).astype(jnp.float32) * (2.0 ** -24)
+             * span) ** np.float32(1.0 / a1)
+        whole = jnp.floor(x)
+        # how far below ``x`` the point lies, less what ``floor`` took
+        down = jnp.ceil(
+            (h_place >> u(8)).astype(jnp.float32) * (2.0 ** -24)
+            * stratum * x ** np.float32(zipf_exponent) - (x - whole))
+        rank = whole.astype(jnp.int32) \
+            - jnp.maximum(down, 0.0).astype(jnp.int32) - 1
+        rank = jnp.clip(rank, 0, n_features - 1)
+        raw = 1 + jax.lax.clz(h_value).astype(jnp.int32)
+        return scatter(rank), raw
+
+    def scatter(rank):
+        """The feature id of power-law rank ``rank``: the bijection."""
+        return ((jnp.asarray(rank).astype(jnp.uint32) * u(scatter_a)
+                 + u(scatter_c)) % u(n_features)).astype(jnp.int32)
+
+    def planted(ids, seed):
+        """The planted weight of feature ``ids``: an odd int32 in
+        [-255, 255]."""
+        named = _mix32((jnp.asarray(ids).astype(jnp.uint32) + u(1))
+                       * u(0x9E3779B1))
+        return 2 * (_mix32(named ^ key_of(seed, 3)) >> u(24)).astype(
+            jnp.int32) - 255
+
+    def unit_values(raw, sum_squares):
+        """A pair's float32 value: its raw value over its row's
+        Euclidean length (``sum_squares``: the row's exact integer sum,
+        broadcast to the pair; 0 where there is no row)."""
+        norm = jnp.sqrt(jnp.maximum(sum_squares, 1).astype(jnp.float32))
+        return raw.astype(jnp.float32) / norm
+
+    def scores(weighted_sum, sum_squares):
+        """A row's planted score from its two integer sums."""
+        norm = jnp.sqrt(jnp.maximum(sum_squares, 1).astype(jnp.float32))
+        return np.float32(planted_scale / planted_sd) \
+            * weighted_sum.astype(jnp.float32) / norm
+
+    def coins(row_ids, seed):
+        return unit(word(key_of(seed, 4), row_ids))
+
+    def set_bias(z, coin, live):
+        """The bias under which ``positive_rate`` of the rows ``live``
+        marks are positive, by bisection on a count."""
+        n_live = jnp.sum(live.astype(jnp.int32))
+        want = jnp.floor(np.float32(positive_rate)
+                         * n_live.astype(jnp.float32)).astype(jnp.int32)
+
+        def halve(_, lo_hi):
+            lo, hi = lo_hi
+            mid = 0.5 * (lo + hi)
+            got = jnp.sum(((coin < jax.nn.sigmoid(mid + z)) & live)
+                          .astype(jnp.int32))
+            over = got > want
+            return jnp.where(over, lo, mid), jnp.where(over, mid, hi)
+
+        lo, hi = jax.lax.fori_loop(
+            0, 40, halve, (jnp.float32(-30.0), jnp.float32(30.0)))
+        return 0.5 * (lo + hi)
+
+    def labels(z, coin, bias):
+        return (coin < jax.nn.sigmoid(bias + z)).astype(jnp.int32)
+
+    return types.SimpleNamespace(
+        lengths=lengths, pairs=pairs, scatter=scatter, planted=planted,
+        unit_values=unit_values, scores=scores, coins=coins,
+        set_bias=set_bias, labels=labels, n_rows=n_rows,
+        n_features=n_features)
+
+
 def power_law_degrees(n_owners: int, total: int, d_min: int, d_max: int,
                       salt: int) -> np.ndarray:
     """How many ratings each of ``n_owners`` owners has: a bounded power
