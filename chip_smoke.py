@@ -921,10 +921,12 @@ def stage_ssgd_pairs(s: Smoke):
     """SSGD over rows of (feature, value) pairs as ``tda ssgd
     --row-format pairs`` runs it, on every chip the stage has: the
     loader's table at a small size (40 000 ragged rows of 8 to 16 384
-    pairs, 20M pairs, 2M weights in HBM, blocks of 2^16 pair slots
-    sharded over the data axis), 12 steps of the block-sampled trainer
-    (the valued gather and scatter against the table in HBM, the row
-    sums by vectors, on a mesh the 8 MB gradient psummed); against the
+    pairs, 20M pairs, 2M weights, blocks of 2^16 pair slots sharded
+    over the data axis), 12 steps of the block-sampled trainer (the
+    valued gather and scatter by address, the 8 MB model vector resident
+    in VMEM a pass: ``pairs.pass_form`` must say ``vmem`` here, on a
+    mesh each shard its own blocks; the row sums by vectors, on a mesh
+    the 8 MB gradient psummed); against the
     same steps in float64 on the host over the table's own CSR arrays
     on the first step's blocks, to float32 rounding; and held-out rows
     scored better than zero weights score them."""
@@ -953,6 +955,9 @@ def stage_ssgd_pairs(s: Smoke):
                                                    data_seed=5)
     s.check_sharded("X", X)
     geom = ssgd_pairs.geometry(meta)
+    if geom.pass_form != "vmem":
+        raise AssertionError(f"pass form {geom.pass_form} on a TPU whose "
+                             f"VMEM holds the {4 * geom.w_len} B vector")
     d = jnp.zeros((1,), jnp.float32)
     w1, _ = fn(X, d, d, d, d, w0, t0=7)
     # the first step again in float64, from the table's own rows
@@ -983,7 +988,7 @@ def stage_ssgd_pairs(s: Smoke):
         raise AssertionError(f"held-out log-loss {loss:.4f} after 12 "
                              f"steps (zero weights: 0.6931)")
     return (f"dp={shards} | table {tuple(X.shape)} | {geom.n_slots} "
-            f"weights in HBM | {meta['n_pairs']} pairs in "
+            f"weights, passes {geom.pass_form} | {meta['n_pairs']} pairs in "
             f"{meta['blocks_used']} of {meta['n_blocks']} blocks | a "
             f"step of {len(y)} rows against float64 {err:.2g} | "
             f"held-out log-loss {loss:.4f} acc {acc:.4f}")
@@ -1168,8 +1173,9 @@ STAGES = (
                    "pallas_hashed._hashed_value_gather_kernel",
                    "pallas_hashed._hashed_value_sums_kernel",
                    "pallas_hashed._hashed_hbm_gather_kernel"))),
-    # XLA's gather and scatter-add: no kernel of the program's
-    ("ssgd_pairs", stage_ssgd_pairs, {}),
+    ("ssgd_pairs", stage_ssgd_pairs,
+     dict(kernels=("pallas_pairs._pairs_gather_kernel",
+                   "pallas_pairs._pairs_scatter_kernel"))),
     # one chip builds pallas_als._als_gather_kernel, a mesh no kernel
     ("als_sparse", stage_als_sparse, {}),
     # one chip builds pallas_closure._compose_kernel, a mesh no kernel

@@ -10,7 +10,9 @@ admits; a half-sweep's steps at the cell's batch, whose compiled module
 copies no batch of Gramians into another layout; indexed LR's gather
 from a weight table in HBM at KDD Cup 2012's shape; and the dense
 closure's donated round at BigDatalog's Grid250, which holds the matrix
-it reads and the one it writes and no third."""
+it reads and the one it writes and no third; and the pairs passes by
+address at webspam's width, the 66.4 MB model vector one copy in VMEM,
+alone and in the trainer's whole segment."""
 
 import re
 
@@ -276,3 +278,80 @@ def test_closure_round_compiles_at_grid250_and_holds_two_matrices(one_chip):
     start = tc.make_start_fn(mesh, geom).lower(arcs, arcs).compile()
     mem = start.memory_analysis()
     assert mem.temp_size_in_bytes < 3.1 * (v // 8) * v
+
+
+WEBSPAM = dict(n_features=16_609_143, block_slots=1 << 18, block_rows=512,
+               n_blocks=5248, on_tpu=True)
+
+
+@pytest.mark.parametrize("which", ["gather", "scatter"])
+def test_pairs_kernels_compile_at_webspams_width(one_chip, which):
+    """``ops/pallas_pairs.py`` at the ``lrpairs3728_350k_frac01`` cell's
+    shape (52 sampled blocks of 2048 vectors, the model vector
+    ``f32[129759, 128]``): the chip's compiler grants ONE single-buffered
+    copy of 66.4 MB as a VMEM scratch beside the chunk's buffers (every
+    DMA a whole array of whole tiles, 129 760 rows), the table itself
+    read where it lies (no copy of a sampled block)."""
+    from tpu_distalg.ops import pairs, pallas_pairs
+
+    geom = pairs.PairsGeometry(**WEBSPAM)
+    assert pairs.vmem_bytes(geom.w_len) < pairs.VMEM_BUDGET_BYTES
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    X = arr((geom.n_blocks, geom.held_rows, 128), jnp.int32)
+    ids = arr((52,), jnp.int32)
+    if which == "gather":
+        done = jax.jit(lambda X, w, ids: pallas_pairs.vector_products(
+            X, w, ids, ids, geom)).lower(
+                X, arr((geom.w_len,), jnp.float32), ids).compile()
+        out = 52 * 2048 * 128 * 4
+    else:
+        done = jax.jit(lambda X, back, ids: pallas_pairs.slot_sums(
+            X, back, ids, ids, geom)).lower(
+                X, arr((52, 2048), jnp.float32), ids).compile()
+        out = 129759 * 128 * 4
+    assert f"_pairs_{which}_kernel" in done.as_text()
+    mem = done.memory_analysis()
+    assert out <= mem.output_size_in_bytes <= out + 4096   # 1-D tiles
+    # one copy of the vector in whole tiles (129 760 rows) beside it
+    assert mem.temp_size_in_bytes < 129760 * 512 + (1 << 20)
+
+
+def test_pairs_segment_compiles_with_both_kernels(one_chip):
+    """The trainer's segment at the cell's shape on a described chip
+    (its ``meta`` says a TPU's loader wrote it: the ``vmem`` form): one
+    call of each kernel a trip of 13 blocks, XLA's sort and scatter of
+    the pairs gone, and the step's temporaries (a trip's 13.6 MB of
+    products, the vectors of sums) far under a trip's of the ``xla``
+    form."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpu_distalg.models import ssgd
+
+    mesh = Mesh(np.array([one_chip._device]).reshape(1, 1),
+                ("data", "model"))
+    rep = NamedSharding(mesh, P())
+    meta = dict(WEBSPAM, row_format="pairs", pack=1, n_rows=350_000,
+                d_total=129759 * 128)
+    cfg = ssgd.SSGDConfig(
+        n_iterations=2, eta=0.1, lam=0.0, mini_batch_fraction=0.01,
+        seed=42, eval_test=False, sampler="fused_gather")
+    d = jax.ShapeDtypeStruct((1,), jnp.float32, sharding=rep)
+    done = ssgd.make_train_fn_fused(mesh, cfg, meta).lower(
+        jax.ShapeDtypeStruct((5248, 4120, 128), jnp.int32,
+                             sharding=NamedSharding(
+                                 mesh, P("data", None, None))),
+        d, d, d, d,
+        jax.ShapeDtypeStruct((129759 * 128,), jnp.float32, sharding=rep),
+        t0=0).compile()
+    text = done.as_text()
+    for kernel in ("_pairs_gather_kernel", "_pairs_scatter_kernel"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    # the only scatters left are the row sums' (2048 numbers a block)
+    assert " sort(" not in text
+    for line in text.splitlines():
+        if " scatter(" in line:
+            assert "tda.ssgd.rowsum/" in line, line[:300]
+    assert done.memory_analysis().temp_size_in_bytes < 256 << 20

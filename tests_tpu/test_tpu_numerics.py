@@ -1242,8 +1242,9 @@ def test_indexed_passes_against_the_plain_reference(tpu_mesh):
 
 def test_pairs_passes_against_the_plain_reference(tpu_mesh):
     """The compiled steps of SSGD over rows of (feature, value) pairs
-    (40 000 ragged rows of 8 to 16 384 pairs, 2M weights in HBM, blocks
-    of 2^16 pair slots) against the benchmark's plain reference
+    (40 000 ragged rows of 8 to 16 384 pairs, 2M weights resident in
+    VMEM a pass, blocks of 2^16 pair slots) against the benchmark's
+    plain reference
     (``benchmarks/reference/ssgd_pairs_ref.py``: no table, the sampled
     blocks' rows regenerated, ``w[idx] * val``, a ``segment_sum`` a row,
     ``.at[idx].add`` a block) over two calls of three steps: every
@@ -1298,6 +1299,129 @@ def test_pairs_passes_against_the_plain_reference(tpu_mesh):
         print(f"[pairs] call {k + 1}: program against reference "
               f"{err:.3g}, bfloat16 control {ctl:.3g}")
         assert err < 3e-4 < ctl
+
+
+# What either form's per-slot sums may stand off a float64 sum in norm at
+# webspam's width over 8 blocks of the seeded draw (1.76M pairs, 144 851 of
+# them in the hottest slot). Read on the chip (PR 56, call 183): the
+# ``vmem`` form 2.21e-5, XLA's own 4.16e-5, 0.999 and 1.000 of the squared
+# difference in the 16 hottest of 294 073 slots: float32 rounding of the
+# hot slots' sums and nothing else (the whole-number pass of the same test
+# is exact to the bit). 3.6 times the larger reading; a bfloat16 form
+# stands a hundred times the readings off.
+PAIRS_SUMS_LIMIT = 1.5e-4
+
+
+def test_pairs_kernels_at_webspams_width_against_float64(tpu_mesh):
+    """The two by-address kernels compiled at the benchmark cell's width
+    (16 609 143 features: the 66.4 MB model vector one copy in VMEM;
+    blocks of 2^18 pair slots, 2048 vectors) and at its skew (the
+    loader's power law of 1.1: a piece's four pairs often share a row of
+    the table, a ninth of all pairs one slot) over 8 sampled blocks, an
+    empty one and one drawn twice among them, against a float64 sum
+    over the blocks' own CSR arrays and against the ``xla`` form:
+
+    * with every value and residual a small whole number each partial
+      sum is one too, so float32 adds in any order are exact: both
+      forms equal the float64 sums TO THE BIT. No addend is lost,
+      doubled or carried to another row, whatever shares a piece;
+    * with the loader's values: margins to float32 rounding; the sums
+      within ``PAIRS_SUMS_LIMIT`` in norm, and what they are off by
+      lies in the few hottest slots, whose addends a call adds one
+      after another."""
+    import dataclasses
+
+    from tpu_distalg.models import ssgd_pairs
+    from tpu_distalg.ops import pairs
+    from tpu_distalg.parallel import get_mesh
+
+    spec = ssgd_pairs.PairsSpec(
+        n_rows=1500, n_features=16_609_143, length_mu=7.72585391998291,
+        block_slots=1 << 18, block_rows=512, n_blocks=24, scatter_c=0)
+    assert spec.zipf_exponent == 1.1
+    mesh = get_mesh(data=1, devices=jax.devices()[:1])
+    X, meta = ssgd_pairs.build_table(spec, mesh, data_seed=56)
+    geom = ssgd_pairs.geometry(meta)
+    assert geom.pass_form == "vmem" and geom.w_len == 129759 * 128
+    assert meta["blocks_used"] < 24          # the last block holds no row
+    forms = {"vmem": geom, "xla": dataclasses.replace(geom, on_tpu=False)}
+    assert forms["xla"].pass_form == "xla"
+    sel = [3, 23, 0, 11, 7, 3, 19, 1]
+    ids = jnp.asarray(sel, jnp.int32)
+    V, R = geom.vectors, geom.block_rows
+    valid = pairs.labels(X, ids, geom)[1]
+
+    def both(X, w, r):
+        return {form: jax.jit(lambda X, w, r, g=g: (
+            pairs.margins(X, w, ids, g), pairs.slot_sums(X, r, ids, g)))(
+                X, w, r) for form, g in forms.items()}
+
+    def float64(X, w, r):
+        """From the sampled blocks' own rows, on the host."""
+        indptr, h, v, _, block_of = pairs.csr_from_blocks(
+            np.asarray(X[ids]), geom)
+        n = np.diff(indptr)
+        slot = np.concatenate([np.arange(c) for c in
+                               np.bincount(block_of, minlength=len(sel))])
+        row = np.repeat(np.arange(len(n)), n)
+        r_row = np.asarray(r, np.float64)[block_of, slot]
+        w64 = np.asarray(w, np.float64)
+        m = np.full((len(sel), R), w64[geom.n_slots])
+        m[block_of, slot] += np.bincount(
+            row, weights=w64[h] * v.astype(np.float64), minlength=len(n))
+        g = np.bincount(h, weights=r_row[row] * v.astype(np.float64),
+                        minlength=geom.w_len)
+        g[geom.n_slots] = np.asarray(r, np.float64).sum()
+        return m, g, np.bincount(h, minlength=geom.w_len)
+
+    # whole numbers: the values -3 .. 3 where a slot holds a pair, the
+    # residuals -2 .. 2
+    k1, k2, k3 = jax.random.split(jax.random.key(3), 3)
+    held = X[:, V:2 * V] != 0
+    whole = jax.random.randint(k1, held.shape, -3, 4).astype(jnp.float32)
+    Xw = X.at[:, V:2 * V].set(jnp.where(
+        held, jax.lax.bitcast_convert_type(whole, jnp.int32), 0))
+    rw = jax.random.randint(k2, (len(sel), R), -2, 3).astype(
+        jnp.float32) * valid
+    ww = jax.random.randint(k3, (geom.w_len,), -4, 5).astype(jnp.float32)
+    m64, g64, count = float64(Xw, ww, rw)
+    assert np.abs(g64).max() < 1 << 24 and np.abs(m64).max() < 1 << 24
+    hot = int(count[:geom.n_slots].max())
+    for form, (m, g) in both(Xw, ww, rw).items():
+        assert np.array_equal(np.asarray(m, np.float64), m64), form
+        assert np.array_equal(np.asarray(g, np.float64), g64), form
+    print(f"[pairs] whole numbers at webspam's width: both forms equal "
+          f"the float64 sums to the bit; {int(count.sum())} pairs, the "
+          f"hottest slot {hot} of them, largest sum "
+          f"{np.abs(g64).max():.0f}")
+
+    # the loader's values
+    w = jax.random.normal(jax.random.key(1), (geom.w_len,), jnp.float32)
+    r = jax.random.normal(jax.random.key(2), (len(sel), R),
+                          jnp.float32) * valid
+    m64, g64, count = float64(X, w, r)
+    top = np.argsort(count)[-16:]
+    read = {}
+    for form, (m, g) in both(X, w, r).items():
+        off = np.asarray(g, np.float64) - g64
+        read[form] = (
+            float(np.abs(np.asarray(m, np.float64) - m64).max()
+                  / np.abs(m64).max()),
+            float(np.linalg.norm(off) / np.linalg.norm(g64)),
+            float((off[top] ** 2).sum() / max((off ** 2).sum(), 1e-300)))
+        print(f"[pairs] {form} against float64 at webspam's width: "
+              f"margins {read[form][0]:.3g} of the largest, sums "
+              f"{read[form][1]:.3g} in norm, {read[form][2]:.3f} of that "
+              f"(squared) in the 16 hottest of "
+              f"{int(np.count_nonzero(count))} slots")
+        assert abs(float(g[geom.n_slots]) - g64[geom.n_slots]) < 1e-3, form
+        assert not np.asarray(g[geom.n_slots + 1:]).any(), form
+    for form in forms:
+        assert read[form][0] < 1e-5, form
+        assert read[form][1] < PAIRS_SUMS_LIMIT, form
+    # rounding of serial sums, not a fault in a cold slot: it lies where
+    # the addends are
+    assert read["vmem"][2] > 0.5
 
 
 def test_sparse_als_half_sweep_at_rank_100(tpu_mesh):

@@ -32,6 +32,7 @@ from tpu_distalg.ops import pairs, sampling
 from tpu_distalg.parallel import (
     DATA_AXIS,
     data_parallel,
+    mesh_on_tpu,
     tree_allreduce_sum,
 )
 from tpu_distalg.telemetry import events as tevents
@@ -43,13 +44,27 @@ BIAS_BLOCKS = 64           # blocks of the stream the label's bias is set on
 HELDOUT_BLOCKS = 64        # ... and of the stream that is scored
 HELDOUT_OFFSET = 1 << 20   # where that stream starts past the table's rows
 FILL_BLOCKS = 8            # blocks the loader draws at a time, at most
-STEP_BLOCKS = 4            # sampled blocks a trip of a step's loop takes,
-#                            at most: their per-slot sums are finished in a
-#                            vector of their own before they are added to
-#                            the step's (a slot that most rows hold gets a
-#                            trip's addends, not a step's, one after another
-#                            in float32), and a step's temporaries are a
-#                            trip's
+STEP_BLOCKS = {"xla": 4, "vmem": 16}
+#                          sampled blocks a trip of a step's loop takes, at
+#                          most, by the passes' form: their per-slot sums
+#                          are finished in a vector of their own before
+#                          they are added to the step's. A slot that most
+#                          rows hold then gets a trip's addends, not a
+#                          step's, one after another in float32: at
+#                          webspam's shape on the chip the ``vmem`` form's
+#                          whole step in one call read ``w_rel_err`` up to
+#                          1.6e-4 of a limit of 3e-4, four trips of 13
+#                          blocks 5.8e-5 at most on 14 readings, for 2.5 ms
+#                          of a step of 82 (a copy of the vector in and of
+#                          the sums out a trip; PR 56). The ``xla`` form's
+#                          temporaries are a trip's too
+
+
+def trip_blocks(n_sampled: int, form: str) -> int:
+    """Blocks a trip: the largest divisor of a step's ``n_sampled`` that
+    ``STEP_BLOCKS`` allows the form (webspam's 52: 4 and 13)."""
+    return max(d for d in range(1, STEP_BLOCKS[form] + 1)
+               if n_sampled % d == 0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,9 +124,12 @@ def length_mu_for(n_rows: int, mean_pairs: float, *, sigma: float = 1.0,
 
 
 def geometry(meta: dict) -> pairs.PairsGeometry:
+    """The table's geometry; ``on_tpu`` is what the loader's mesh said
+    (a ``meta`` that does not say lies on no TPU)."""
     return pairs.PairsGeometry(
         n_features=meta["n_features"], block_slots=meta["block_slots"],
-        block_rows=meta["block_rows"], n_blocks=meta["n_blocks"])
+        block_rows=meta["block_rows"], n_blocks=meta["n_blocks"],
+        on_tpu=meta.get("on_tpu", False))
 
 
 def blocks_geometry(config: ssgd.SSGDConfig, meta: dict, n_shards: int):
@@ -147,8 +165,9 @@ def fields(meta: dict) -> dict:
 
 def describe_forms(meta: dict) -> str:
     geom = geometry(meta)
+    where = {"vmem": "in VMEM for a pass", "xla": "in HBM"}[geom.pass_form]
     return (f"row format pairs: {geom.n_slots} weights "
-            f"({4 * geom.n_slots / 1e6:.1f} MB) in HBM, "
+            f"({4 * geom.n_slots / 1e6:.1f} MB) {where}, "
             f"{meta['n_rows']} rows of {meta['n_pairs']} pairs (longest "
             f"{meta['longest_row']}) in {meta['blocks_used']} of "
             f"{meta['n_blocks']} blocks of {meta['block_slots']} slots "
@@ -179,7 +198,7 @@ def make_train_fn(mesh: Mesh, config: ssgd.SSGDConfig, meta: dict):
                     jax.random.fold_in(key, t),
                     n_shards, n_blocks, n_sampled))(ts)     # (T, S, ns)
 
-    per = math.gcd(n_sampled, STEP_BLOCKS)
+    per = trip_blocks(n_sampled, geom.pass_form)
 
     def _local_grad(X, w, idx_shards):
         shard = lax.axis_index(DATA_AXIS)
@@ -381,6 +400,7 @@ def build_table(spec: PairsSpec, mesh: Mesh, *, data_seed: int = 0):
     do. Returns ``(X, meta)``."""
     n_shards = mesh.shape[DATA_AXIS]
     devices = mesh.local_devices
+    on_tpu = mesh_on_tpu(mesh)
     seed = np.int32(data_seed)
     with tevents.span("ssgd:prepare", devices, rows=spec.n_rows,
                       row_format=ROW_FORMAT):
@@ -400,7 +420,7 @@ def build_table(spec: PairsSpec, mesh: Mesh, *, data_seed: int = 0):
                     f"n_blocks={n_blocks} over {n_shards} shard(s) does "
                     f"not hold them (no row is dropped or cut)")
             geom = pairs.PairsGeometry(spec.n_features, spec.block_slots,
-                                       spec.block_rows, n_blocks)
+                                       spec.block_rows, n_blocks, on_tpu)
             starts, counts, used = _blocks_of(cuts, n_blocks, 0)
             ends = np.concatenate([[0], np.cumsum(host, dtype=np.int64)])
             meta = dict(
@@ -413,7 +433,7 @@ def build_table(spec: PairsSpec, mesh: Mesh, *, data_seed: int = 0):
                 longest_row=int(host.max()) if host.size else 0,
                 block_starts=starts, block_counts=counts,
                 block_pairs=ends[starts + counts] - ends[starts],
-                spec=spec)
+                spec=spec, on_tpu=on_tpu)
             tevents.current().fields.update(
                 blocks=n_blocks, blocks_used=used, pairs=n_pairs)
         for name, n in (("rows", spec.n_rows), ("pairs", n_pairs),
@@ -429,6 +449,10 @@ def build_table(spec: PairsSpec, mesh: Mesh, *, data_seed: int = 0):
             bias = planted_bias(spec, geom, seed)
             X = table_fn(mesh, spec, geom)(seed, bias, lengths, starts,
                                            counts)
+            if geom.pass_form == "vmem":
+                # the kernels' module (Pallas' first import: a second on
+                # the chip's host) while the device fills the table
+                from tpu_distalg.ops import pallas_pairs  # noqa: F401
             X.block_until_ready()
             meta["bias"] = float(bias)
             tevents.current().fields["bytes"] = metrics.nbytes(X)
@@ -456,7 +480,11 @@ def evaluate(w, meta: dict, *, data_seed: int = 0,
     spec, geom = meta["spec"], geometry(meta)
     X, _, _, _ = _stream(spec, geom, spec.n_rows + HELDOUT_OFFSET,
                          n_blocks, np.int32(data_seed), meta["bias"])
-    return _score_fn(geom, n_blocks)(X, jnp.asarray(w, jnp.float32))
+    # one device's program: a mesh's replicated weights go where the
+    # stream lies (a Mosaic kernel under a plain jit is not partitioned)
+    (device,) = X.devices()
+    w = jax.device_put(jnp.asarray(w, jnp.float32), device)
+    return _score_fn(geom, n_blocks)(X, w)
 
 
 @functools.lru_cache(maxsize=8)

@@ -49,14 +49,51 @@ the sampled blocks ``ids``:
               g[n_features] = sum_i r_i
 
 with ``r`` the residual times validity; a feature that occurs twice in
-a row counts twice. Both passes take the one form there is today,
-``xla``: the table stays in HBM (16.6M weights at webspam's shape, four
-VMEM tables' worth, and no field to split it by), ``w[idx]`` and
-``zeros.at[idx].add`` over every pair under ``tda.ssgd.table_hbm``, the
-segmented sum and the way back under ``tda.ssgd.rowsum``. On one v5e at
-webspam's shape (52 blocks, 12.9M pairs a step; my chip run, PR 54) the
-gather reads 98.3 ms a step (7.6 ns a pair), the scatter 132.7 (10.3 ns
-a pair, XLA's sort of a trip's ids in it), the row sums 2.2.
+a row counts twice. Each pass has two forms that give the same numbers
+up to the order of float32 additions, and :func:`pass_form` picks one
+from what it can observe, where the loader laid the table (``on_tpu``,
+from its mesh) and the vector's size (no option, no environment
+variable):
+
+``vmem``  on a TPU whose VMEM holds the model vector whole beside a
+          chunk's buffers (``vmem_bytes`` against ``VMEM_BUDGET_BYTES``:
+          webspam's 16.6M weights are 66.4 MB of a v5e core's 128 MiB):
+          ``ops/pallas_pairs.py``'s two Mosaic kernels, a call a pass
+          over the sampled blocks it is given. The gather copies ``w``
+          into VMEM once and serves every pair by address (row
+          ``h >> 7``, lane ``h & 127`` by a mask, times the value); the
+          scatter adds ``v * r_row`` the same way into ONE accumulator
+          of the vector's shape and copies the sums out once. No head,
+          no count, no sort: every pair costs the same whatever the
+          skew. float32 only.
+``xla``   everywhere else (the CPU, where it is also the tests' oracle;
+          a vector past the budget, such as kddb's 29.9M features):
+          the table stays in HBM, ``w[idx]`` and ``zeros.at[idx].add``
+          over every pair under ``tda.ssgd.table_hbm``.
+
+A slot's addends are added one after another in float32 in either form,
+so the trainer takes a step's blocks a few at a time and adds the trips'
+finished sums (``ssgd_pairs.STEP_BLOCKS``: 4 blocks a trip in the
+``xla`` form, whose temporaries are a trip's too, up to 16 in the
+``vmem`` form).
+
+The segmented sum and the way back are XLA's in both forms, under
+``tda.ssgd.rowsum``; in the ``vmem`` form ``tda.ssgd.table_hbm`` holds
+only what XLA still does on the 66 MB vectors in HBM (``w`` brought to
+whole tiles, the sums cut back, the bias's slot set; the copies in and
+out are the kernels' own DMAs). On one v5e at webspam's shape (52
+blocks, 13.63M pair slots of which 12.9M hold a pair, a step):
+
+  ``xla``   the gather 98.3 ms (7.6 ns a pair), the scatter 135.1 (10.4
+            ns a pair, XLA's sort of a trip's ids in it), the row sums
+            2.2 (ledger, PR 54)
+  ``vmem``  the gather 31.7 ms (2.45 ns a pair), the scatter 50.0 (3.86
+            ns a pair), the row sums 2.1, in 4 trips of 13 blocks
+            (builder's chip run, PR 56; one call a pass a step read
+            30.8 and 48.5 and three times the error in the weights);
+            the same at a flat draw (``scripts/step0_pairs.py``: 2.31 /
+            2.30 ns a slot the gather, 3.62 / 3.65 the scatter, seeded /
+            flat). By the static schedule 3.3 and 5.2 bundles a pair.
 """
 
 from __future__ import annotations
@@ -67,16 +104,40 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_distalg.telemetry import events as tevents
 from tpu_distalg.telemetry import names
 
 LANES = 128
 SUBLANES = 8
 BLOCK_ROWS = 512      # row slots a block, unless a spec states another
 NO_ROW = -1            # the label of a row slot that holds no row
+VMEM_BUDGET_BYTES = 80 << 20   # what a by-address pass may ask of a v5e
+#                                core's VMEM (128 MiB by its compiler's own
+#                                refusal): the largest asked that has run on
+#                                the chip is 83.6 MB (PR 56, Step 0)
+VMEM_ROOM_BYTES = 8 << 20      # ... of which beside the one copy of the
+#                                model vector: a chunk's buffers and what
+#                                Mosaic keeps for itself
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def vmem_bytes(w_len: int) -> int:
+    """What a by-address pass over a model vector of ``w_len`` float32
+    asks of VMEM: one copy of the vector (the gather's table, the
+    scatter's sums) and the room beside it."""
+    return 4 * w_len + VMEM_ROOM_BYTES
+
+
+def pass_form(w_len: int, on_tpu: bool) -> str:
+    """How a table's two passes run: ``'vmem'`` (``ops/pallas_pairs.py``)
+    on a TPU whose VMEM holds the model vector whole, else ``'xla'``
+    (the CPU; a vector past the budget, such as kddb's 29.9M features'
+    119.6 MB)."""
+    fits = vmem_bytes(w_len) <= VMEM_BUDGET_BYTES
+    return "vmem" if on_tpu and fits else "xla"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,9 +148,9 @@ class PairsGeometry:
     block_slots: int       # pair slots a block: whole vectors of 128
     block_rows: int        # row slots a block
     n_blocks: int          # blocks of the whole table, every shard's
+    on_tpu: bool = False   # where the table lies: the loader's mesh
 
     row_format = "pairs"
-    pass_form = "xla"
 
     def __post_init__(self):
         if self.block_slots < LANES or self.block_slots % LANES:
@@ -141,6 +202,10 @@ class PairsGeometry:
     @property
     def w_len(self) -> int:
         return _round_up(self.n_features + 1, LANES)
+
+    @property
+    def pass_form(self) -> str:
+        return pass_form(self.w_len, self.on_tpu)
 
 
 # ---- which rows a block holds (host) ------------------------------------
@@ -248,81 +313,154 @@ def _check(X, geom: PairsGeometry):
             f"{(geom.held_rows, LANES)}")
 
 
-def _blocks(X, ids):
-    """The sampled blocks, a dynamic slice each: a block is one run of
-    2 MB, and XLA's gather of such rows (``X[ids]``) copies the whole
-    table in three slices first (seen in a chipless compile at
-    webspam's shape: 3.8 GB of temporaries a trip)."""
-    return jnp.stack([
-        jax.lax.dynamic_index_in_dim(X, ids[i], axis=0, keepdims=False)
+def _rows(X, ids, lo: int, hi: int):
+    """Rows ``[lo, hi)`` of the sampled blocks, a dynamic slice each
+    (the ``xla`` form's ids and values, a trip's few blocks): a block is
+    one run of 2 MB, and XLA's gather of such rows (``X[ids]``) copies
+    the whole table in three slices first (seen in a chipless compile
+    at webspam's shape: 3.8 GB of temporaries a trip)."""
+    return jnp.concatenate([
+        jax.lax.dynamic_slice(X, (ids[i], lo, 0), (1, hi - lo, LANES))
         for i in range(ids.shape[0])])
 
 
-def parts(X, ids, geom: PairsGeometry):
-    """``(idx int32 (n, V, 128), val float32 (n, V, 128), vrow int32
-    (n, V), labels int32 (n, block_rows))`` of the sampled blocks."""
-    _check(X, geom)
+def _values(X, ids, geom: PairsGeometry):
     V = geom.vectors
-    blocks = _blocks(X, ids)
-    n = blocks.shape[0]
-    val = jax.lax.bitcast_convert_type(blocks[:, V:2 * V], jnp.float32)
-    vrow = blocks[:, geom.at_vrow:geom.at_labels].reshape(n, -1)[:, :V]
-    lab = blocks[:, geom.at_labels:geom.at_lengths].reshape(
-        n, -1)[:, :geom.block_rows]
-    return blocks[:, :V], val, vrow, lab
+    return jax.lax.bitcast_convert_type(_rows(X, ids, V, 2 * V),
+                                        jnp.float32)
+
+
+class _Tails:
+    """What the sampled blocks hold behind their pairs (12 KB of a 2 MB
+    block at webspam's shape): the vectors' row numbers, the row slots'
+    labels and pair counts. One loop of dynamic slices, a trip a block:
+    traced once however many blocks a call is given."""
+
+    def __init__(self, X, ids, geom: PairsGeometry):
+        _check(X, geom)
+        lo, n = geom.at_vrow, geom.held_rows - geom.at_vrow
+        held = jax.lax.map(
+            lambda i: jax.lax.dynamic_slice(
+                X, (i, lo, 0), (1, n, LANES))[0], ids)
+        self._geom, self._held = geom, held
+
+    def _part(self, at: int, rows: int, n: int):
+        """The first ``n`` words of ``rows`` held rows from ``at``."""
+        lo = at - self._geom.at_vrow
+        return self._held[:, lo:lo + rows].reshape(
+            self._held.shape[0], -1)[:, :n]
+
+    @property
+    def vrow(self):
+        g = self._geom
+        return self._part(g.at_vrow, g.vector_rows, g.vectors)
+
+    @property
+    def labels(self):
+        g = self._geom
+        return self._part(g.at_labels, g.label_rows, g.block_rows)
+
+    @property
+    def counts(self):
+        g = self._geom
+        return self._part(g.at_lengths, g.label_rows, g.block_rows)
+
+    @property
+    def used(self):
+        """``int32[n_sampled]``: the vectors of each block that belong
+        to a row (its rows' pairs in whole vectors); the block's vectors
+        from there on hold no pair."""
+        return jnp.sum(-(-self.counts // LANES), axis=1).astype(jnp.int32)
 
 
 def pair_counts(X, ids, geom: PairsGeometry):
     """``int32[n_sampled, block_rows]``: the pairs of each row slot of
     the sampled blocks (0 where a slot holds no row)."""
-    _check(X, geom)
-    return _blocks(X, ids)[
-        :, geom.at_lengths:geom.at_lengths + geom.label_rows] \
-        .reshape(ids.shape[0], -1)[:, :geom.block_rows]
+    return _Tails(X, ids, geom).counts
+
+
+def used_vectors(X, ids, geom: PairsGeometry):
+    """``int32[n_sampled]``: :attr:`_Tails.used` of the sampled blocks."""
+    return _Tails(X, ids, geom).used
 
 
 def labels(X, ids, geom: PairsGeometry):
     """``(y float32 (n, block_rows), valid float32 (n, block_rows))``:
     the 0/1 labels of the sampled blocks' row slots and which of them
     hold a row."""
-    lab = parts(X, ids, geom)[3]
+    lab = _Tails(X, ids, geom).labels
     valid = lab != NO_ROW
     return (jnp.where(valid, lab, 0).astype(jnp.float32),
             valid.astype(jnp.float32))
 
 
-def _segments(vrow, geom: PairsGeometry):
+def _row_sums(vec, vrow, geom: PairsGeometry):
+    """A row slot's sum over its vectors' ``vec`` (``(n, V)``)."""
     n = vrow.shape[0]
-    return (jnp.arange(n, dtype=jnp.int32)[:, None] * geom.block_rows
-            + vrow).reshape(-1)
+    seg = (jnp.arange(n, dtype=jnp.int32)[:, None] * geom.block_rows
+           + vrow).reshape(-1)
+    return jax.ops.segment_sum(
+        vec.reshape(-1), seg, num_segments=n * geom.block_rows
+    ).reshape(n, geom.block_rows)
+
+
+def _say_xla(which: str, ids) -> None:
+    """The ``xla`` form's word for ``ssgd:pairs_pass``, said when the
+    pass is traced (``pallas_pairs._call`` says the ``vmem`` form's)."""
+    tevents.emit("ssgd:pairs_pass", kernel=f"xla {which}", form="xla",
+                 vmem_bytes=0, trip_pairs=0, blocks=int(ids.shape[0]))
+
+
+def _vmem(geom: PairsGeometry, dtype) -> bool:
+    """Whether a pass takes its ``vmem`` form, which is float32's."""
+    if geom.pass_form != "vmem":
+        return False
+    if dtype != jnp.float32:
+        raise ValueError(f"the vmem form of a pairs pass is float32's, "
+                         f"not {jnp.dtype(dtype).name}'s")
+    return True
 
 
 def margins(X, w, ids, geom: PairsGeometry, *, dtype=jnp.float32):
     """``f32[n_sampled, block_rows]``: the margins of the sampled
     blocks' row slots (a slot without a row reads the bias). ``dtype``
-    other than float32 is the tests' control: values, weights and the
-    gathered products in it."""
-    idx, val, vrow, _ = parts(X, ids, geom)
-    n = idx.shape[0]
-    with jax.named_scope(names.SSGD_TABLE_HBM):
-        got = w.astype(dtype)[idx]
-    prod = got * val.astype(dtype)
+    other than float32 is the tests' control of the ``xla`` form:
+    values, weights and the gathered products in it."""
+    tails = _Tails(X, ids, geom)
+    if _vmem(geom, dtype):
+        from tpu_distalg.ops import pallas_pairs
+
+        prod = pallas_pairs.vector_products(X, w, ids, tails.used, geom)
+    else:
+        _say_xla("gather", ids)
+        with jax.named_scope(names.SSGD_TABLE_HBM):
+            got = w.astype(dtype)[_rows(X, ids, 0, geom.vectors)]
+        prod = (got * _values(X, ids, geom).astype(dtype)).astype(
+            jnp.float32)
     with jax.named_scope(names.SSGD_ROWSUM):
-        vec = jnp.sum(prod.astype(jnp.float32), axis=-1)
-        m = jax.ops.segment_sum(
-            vec.reshape(-1), _segments(vrow, geom),
-            num_segments=n * geom.block_rows)
-    return m.reshape(n, geom.block_rows) + w[geom.n_slots]
+        m = _row_sums(jnp.sum(prod, axis=-1), tails.vrow, geom)
+    return m + w[geom.n_slots]
 
 
 def slot_sums(X, r, ids, geom: PairsGeometry, *, dtype=jnp.float32):
     """``f32[w_len]``: the per-slot sums of ``r`` (``(n_sampled,
     block_rows)``, zero where a slot holds no row) times the pairs'
     values, and ``sum(r)`` at the bias."""
-    idx, val, vrow, _ = parts(X, ids, geom)
+    tails = _Tails(X, ids, geom)
     with jax.named_scope(names.SSGD_ROWSUM):
-        back = jnp.take_along_axis(r, vrow, axis=1)
-    add = (val.astype(dtype) * back[..., None].astype(dtype))
+        back = jnp.take_along_axis(r, tails.vrow, axis=1)
+    if _vmem(geom, dtype):
+        from tpu_distalg.ops import pallas_pairs
+
+        g = pallas_pairs.slot_sums(X, back, ids, tails.used, geom)
+    else:
+        _say_xla("scatter", ids)
+        add = _values(X, ids, geom).astype(dtype) \
+            * back[..., None].astype(dtype)
+        with jax.named_scope(names.SSGD_TABLE_HBM):
+            g = jnp.zeros((geom.w_len,), dtype).at[
+                _rows(X, ids, 0, geom.vectors)].add(add)
+    bias = jnp.sum(r)
+    # the vector of sums where it lies in HBM: the bias's slot
     with jax.named_scope(names.SSGD_TABLE_HBM):
-        g = jnp.zeros((geom.w_len,), dtype).at[idx].add(add)
-    return g.astype(jnp.float32).at[geom.n_slots].set(jnp.sum(r))
+        return g.astype(jnp.float32).at[geom.n_slots].set(bias)

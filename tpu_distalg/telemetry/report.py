@@ -220,6 +220,7 @@ def summarize(evts: list[dict]) -> dict:
     pass_forms: dict[str, list[str]] = {"gather": [], "scatter": []}
     field_splits: list[tuple] = []
     addr_calls: list[tuple] = []
+    pairs_passes: list[tuple] = []
     als_forms: list[str] = []
     ranks_forms: list[str] = []
     closure_forms: list[str] = []
@@ -321,7 +322,8 @@ def summarize(evts: list[dict]) -> dict:
                        e.get("pair_blocks", 0),
                        e.get("pair_block_slots", 0),
                        e.get("table_bytes", 0),
-                       e.get("rowsum_form", "?"))
+                       e.get("rowsum_form", "?"),
+                       e.get("gather_form", "xla"))
                 if got not in pair_tables:
                     pair_tables.append(got)
             # and ALS' how R is held (a dense R says nothing; a ratings
@@ -380,6 +382,14 @@ def summarize(evts: list[dict]) -> dict:
                     e.get("rows"), e.get("pairs"), e.get("smem_rows"))
             if call not in addr_calls:
                 addr_calls.append(call)
+        elif ev == "ssgd:pairs_pass":
+            # what one pass over rows of (feature, value) pairs runs,
+            # said when it is traced (ops/pairs.py, ops/pallas_pairs.py)
+            call = (e.get("kernel", "?"), e.get("form", "?"),
+                    e.get("vmem_bytes", 0), e.get("trip_pairs", 0),
+                    e.get("blocks", 0))
+            if call not in pairs_passes:
+                pairs_passes.append(call)
         elif ev == "mark":
             marks += 1
         elif ev == "heartbeat":
@@ -448,6 +458,7 @@ def summarize(evts: list[dict]) -> dict:
         "pass_forms": pass_forms,
         "field_splits": field_splits,
         "addr_calls": addr_calls,
+        "pairs_passes": pairs_passes,
         "als_forms": als_forms,
         "ranks_forms": ranks_forms,
         "closure_forms": closure_forms,
@@ -560,14 +571,21 @@ def render(s: dict) -> str:
                      f"{indexed[1] / 1e6:.1f} MB)")
         lines.append(line)
     for (rows, pairs, slots, longest, used, blocks, block_slots, table,
-         rowsum) in s.get("pair_tables") or ():
+         rowsum, form) in s.get("pair_tables") or ():
+        where = "resident in VMEM a pass" if form == "vmem" else "in HBM"
         lines.append(
             f"pairs: {rows} rows of {pairs} (feature, value) pairs, "
             f"longest {longest}, in {used} of {blocks} blocks of "
             f"{block_slots} slots ({slots - pairs} of {slots} slots hold "
             f"no pair: {(1 - pairs / max(slots, 1)) * 100:.2f}%); "
-            f"{table / 1e6:.1f} MB of weights in HBM, row sums by "
+            f"{table / 1e6:.1f} MB of weights {where}, row sums by "
             f"{rowsum}")
+    for kernel, form, vmem, trip, blocks in s.get("pairs_passes") or ():
+        line = f"pairs pass: {kernel} ({form}) over {blocks} blocks a call"
+        if form == "vmem":
+            line += (f", {vmem / 1e6:.1f} MB of VMEM asked, {trip} pairs "
+                     f"a trip")
+        lines.append(line)
     for kernel, fields, rows, pairs, smem_rows in s.get("addr_calls") or ():
         lines.append(f"by-address call: {kernel} over fields "
                      f"{list(fields)}: {rows} rows a trip ({pairs} pairs), "
