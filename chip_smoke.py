@@ -676,27 +676,6 @@ def stage_local_sgd_megakernel(s: Smoke):
     return " | ".join(out)
 
 
-def stage_v3_sampler(s: Smoke):
-    """The on-core-PRNG streaming kernel (no interpret lowering exists,
-    so only the chip can say): the smoke rows for 300 steps vs the XLA
-    trainer, and breast-cancer against the reference band."""
-    from tpu_distalg.models import ssgd
-    from tpu_distalg.utils import datasets
-
-    mesh = s.mesh()
-    res = ssgd.train(*_smoke_rows(s), mesh, ssgd.SSGDConfig(
-        n_iterations=300, eval_test=False, sampler="fused",
-        x_dtype="bfloat16", init_seed=7))
-    out = [_acc_check(s, "fused(v3) 300 steps", res.w, tol=0.05)]
-    acc = ssgd.train(*datasets.breast_cancer_split(), mesh,
-                     ssgd.SSGDConfig(n_iterations=1500,
-                                     sampler="fused")).final_acc
-    if acc < REFERENCE_BAND:
-        raise AssertionError(f"fused(v3) breast-cancer acc {acc}")
-    out.append(f"breast-cancer acc {acc:.4f} (band >= {REFERENCE_BAND})")
-    return " | ".join(out)
-
-
 def stage_kmeans_kernel(s: Smoke):
     """ops/pallas_lloyd.lloyd_pass (the kernel ``kmeans20_100m_k10``
     runs) vs ops/kmeans on a small lanes geometry at the cell's dim and
@@ -1152,8 +1131,6 @@ STAGES = (
      dict(kernels=("pallas_pagerank._spmv_kernel",))),
     ("local_sgd_megakernel", stage_local_sgd_megakernel,
      dict(kernels=("pallas_kernels._train_kernel_gathered",))),
-    ("v3_on_core_prng_sampler", stage_v3_sampler,
-     dict(kernels=("pallas_kernels._grad_kernel_packed",))),
     ("kmeans_kernel", stage_kmeans_kernel,
      dict(kernels=("pallas_lloyd._lloyd_kernel",))),
     ("kmeans_wide", stage_kmeans_wide,

@@ -78,14 +78,6 @@ def test_checkpoints_pruned(mesh8, data, tmp_path):
     assert len(files) <= 3
 
 
-def test_pallas_with_fixed_sampler_rejected(mesh8, data):
-    X_train, y_train, X_test, y_test = data
-    with pytest.raises(ValueError, match="use_pallas"):
-        ssgd.train(X_train, y_train, X_test, y_test, mesh8,
-                   ssgd.SSGDConfig(n_iterations=5, sampler="fixed",
-                                   use_pallas=True))
-
-
 # ---- local-update family (MA / BMUF / EASGD) ----
 
 @pytest.mark.parametrize("mod_name", ["ma", "bmuf", "easgd"])

@@ -455,7 +455,6 @@ def test_fused_gather_comm_schedule(mesh4):
 
 def test_comm_rejected_where_no_per_step_collective(mesh4, cancer_data):
     for bad in (dict(sampler="fused_train", comm="bf16"),
-                dict(sampler="fixed", comm="int8"),
                 dict(feature_sharded=True, comm="topk")):
         with pytest.raises(ValueError, match="comm"):
             ssgd.train(*cancer_data, mesh4,

@@ -52,6 +52,36 @@ def test_grad_sum_matches_autodiff():
                                atol=1e-5)
 
 
+def _grad_data(n, d, seed):
+    rng = np.random.default_rng(seed)
+    X = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, 2, n), jnp.float32)
+    w = jnp.asarray(rng.normal(size=d) * 0.1, jnp.float32)
+    mask = jnp.asarray(rng.random(n) < 0.3, jnp.float32)
+    return X, y, w, mask
+
+
+def test_grad_sum_zero_mask_is_zero_sum_and_zero_count():
+    """An empty sample: the pair the step's ``max(count, 1)`` guards."""
+    X, y, w, mask = _grad_data(256, 32, seed=3)
+    g, c = logistic.grad_sum(X, y, w, jnp.zeros_like(mask))
+    assert float(c) == 0.0
+    assert g.shape == (32,)
+    np.testing.assert_array_equal(np.asarray(g), 0.0)
+
+
+def test_grad_sum_bf16_storage_keeps_f32_sums():
+    """X served in bfloat16 (``x_dtype='bfloat16'``): the sums stay
+    float32 and within bf16's 2-3 decimal digits of the f32 table's."""
+    X, y, w, mask = _grad_data(512, 128, seed=2)
+    g0, c0 = logistic.grad_sum(X, y, w, mask)
+    g1, c1 = logistic.grad_sum(X.astype(jnp.bfloat16), y, w, mask)
+    assert g1.dtype == jnp.float32 and float(c1) == float(c0)
+    np.testing.assert_allclose(np.asarray(g0), np.asarray(g1),
+                               rtol=0.05, atol=0.5)
+    assert np.abs(np.asarray(g0) - np.asarray(g1)).max() > 0
+
+
 def test_sigmoid_stable_at_extremes():
     """The reference's 1/(exp(-z)+1) overflows at z=-1000; ours must not
     (SURVEY.md §5 NaN hazard)."""

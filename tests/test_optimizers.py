@@ -104,19 +104,6 @@ def test_local_sgd_resample_mode(mesh4, cancer_data):
     assert res.final_acc >= 0.80
 
 
-def test_ssgd_fixed_sampler(mesh8, cancer_data):
-    """Gather-based fixed-size sampler (TPU HBM-traffic-optimal path)."""
-    X_train, y_train, X_test, y_test = cancer_data
-    res = ssgd.train(
-        X_train, y_train, X_test, y_test, mesh8,
-        ssgd.SSGDConfig(n_iterations=1500, sampler="fixed"),
-    )
-    # reference-golden band instead of a platform pin: the original rig
-    # measured 0.9181, this container 0.9298 (the ssgd.py:130 golden
-    # exactly) — both clear the band, a real convergence break does not
-    assert res.final_acc > 0.91, res.final_acc
-
-
 def test_ssgd_fused_gather_sampler(mesh4, cancer_data):
     """The traffic-proportional gathered kernel end-to-end on the CPU mesh
     (interpret mode — same code path that compiles to Mosaic on TPU).
@@ -246,14 +233,13 @@ def test_ssgd_feature_sharded_fused_checkpoints_bitwise(mesh_2x4,
 
 def test_ssgd_feature_sharded_invalid_combos(mesh_2x4, cancer_data):
     X_train, y_train, X_test, y_test = cancer_data
+    cfg = ssgd.SSGDConfig(n_iterations=5, feature_sharded=True,
+                          sampler="fused_train")
     with pytest.raises(ValueError, match="feature_sharded"):
-        ssgd.train(X_train, y_train, X_test, y_test, mesh_2x4,
-                   ssgd.SSGDConfig(n_iterations=5, feature_sharded=True,
-                                   sampler="fixed"))
-    with pytest.raises(ValueError, match="fused"):
-        ssgd.train(X_train, y_train, X_test, y_test, mesh_2x4,
-                   ssgd.SSGDConfig(n_iterations=5, feature_sharded=True,
-                                   sampler="fused"))
+        ssgd.train(X_train, y_train, X_test, y_test, mesh_2x4, cfg)
+    # the XLA builder takes the tp split's plain form only
+    with pytest.raises(ValueError, match="fused_train"):
+        ssgd.make_train_fn(mesh_2x4, cfg, 512)
 
 
 def test_ssgd_eval_every(mesh8, cancer_data):

@@ -1,66 +1,14 @@
-"""Pallas fused-gradient kernel vs the XLA path (interpret mode on CPU;
-the same kernel compiles to Mosaic on TPU — exercised by tests_tpu/
-and chip_smoke.py)."""
+"""The packed layout and the gathered kernels vs the XLA path
+(interpret mode on CPU; the same kernels compile to Mosaic on TPU —
+exercised by tests_tpu/ and chip_smoke.py)."""
 
 import jax.numpy as jnp
 import numpy as np
 
 from tpu_distalg.ops import logistic
-from tpu_distalg.ops.pallas_kernels import fused_grad_sum
 
 
-def _data(n, d, seed=0):
-    rng = np.random.default_rng(seed)
-    X = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
-    y = jnp.asarray(rng.integers(0, 2, n), jnp.float32)
-    w = jnp.asarray(rng.normal(size=d) * 0.1, jnp.float32)
-    mask = jnp.asarray(rng.random(n) < 0.3, jnp.float32)
-    return X, y, w, mask
-
-
-def test_fused_grad_matches_xla():
-    X, y, w, mask = _data(1000, 129)
-    g0, c0 = logistic.grad_sum(X, y, w, mask)
-    g1, c1 = fused_grad_sum(X, y, mask, w, block_rows=256, interpret=True)
-    assert float(c0) == float(c1)
-    np.testing.assert_allclose(np.asarray(g0), np.asarray(g1),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_fused_grad_unaligned_shapes():
-    """n not a block multiple AND d not a lane multiple: padding path."""
-    X, y, w, mask = _data(777, 61, seed=1)
-    g0, c0 = logistic.grad_sum(X, y, w, mask)
-    g1, c1 = fused_grad_sum(X, y, mask, w, block_rows=128, interpret=True)
-    assert g1.shape == (61,)
-    assert float(c0) == float(c1)
-    np.testing.assert_allclose(np.asarray(g0), np.asarray(g1),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_fused_grad_bf16_storage():
-    X, y, w, mask = _data(512, 128, seed=2)
-    g0, _ = logistic.grad_sum(X, y, w, mask)
-    g1, c1 = fused_grad_sum(
-        X.astype(jnp.bfloat16), y, mask, w, block_rows=256, interpret=True
-    )
-    assert g1.dtype == jnp.float32  # accumulator stays f32
-    # bf16 storage: ~2-3 decimal digits
-    np.testing.assert_allclose(np.asarray(g0), np.asarray(g1),
-                               rtol=0.05, atol=0.5)
-
-
-def test_fused_grad_zero_mask():
-    X, y, w, mask = _data(256, 32, seed=3)
-    g, c = fused_grad_sum(X, y, jnp.zeros_like(mask), w, block_rows=128,
-                          interpret=True)
-    assert float(c) == 0.0
-    np.testing.assert_allclose(np.asarray(g), 0.0, atol=1e-6)
-
-
-# ---- packed one-pass kernel (v3): CPU-testable pieces ----
-# The kernel itself needs the TPU on-core PRNG (no interpret lowering);
-# its layout/packing/selector algebra is pure XLA and is verified here.
+# ---- the packed layout: packing and selector algebra are pure XLA ----
 
 from tpu_distalg.ops.pallas_kernels import build_selector, pack_augmented
 
@@ -227,20 +175,6 @@ def test_pack_augmented_shuffle_seed():
     for i in range(n):
         orig = int(flat[i, meta["y_col"]])
         np.testing.assert_array_equal(flat[i, :d], X[orig])
-
-
-def test_fused_sampler_requires_tpu(mesh4):
-    """On a CPU mesh the 'fused' sampler must fail loudly, not wrongly."""
-    import pytest
-
-    from tpu_distalg.models import ssgd
-
-    X2, meta = pack_augmented(
-        np.zeros((64, 4), np.float32), np.zeros(64, np.float32),
-        np.ones(64, np.float32), pack=16, block_rows=64)
-    with pytest.raises(ValueError, match="TPU"):
-        ssgd.make_train_fn_fused(
-            mesh4, ssgd.SSGDConfig(sampler="fused"), meta)
 
 
 # ---- the gathered kernels' block loop (PR 27): a ring of block copies

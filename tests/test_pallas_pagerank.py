@@ -613,3 +613,11 @@ def test_run_auto_falls_back_when_no_plan(mesh8):
     r = np.asarray(res.ranks)
     assert np.isfinite(r).all()
     np.testing.assert_allclose(r.sum(), 1.0, rtol=1e-4)
+
+
+def test_pagerank_reference_mode_rejects_scatter_flag(mesh8):
+    from tpu_distalg.models import pagerank
+
+    cfg = pagerank.PageRankConfig(mode="reference", scatter="pallas")
+    with pytest.raises(ValueError, match="standard"):
+        pagerank.make_run_fn(mesh8, cfg, 64, None)

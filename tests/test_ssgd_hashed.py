@@ -346,14 +346,10 @@ def test_generated_rows_are_skewed_and_click_at_the_rate():
 
 @pytest.mark.parametrize("change,word", [
     (dict(sampler="bernoulli"), "bernoulli"),
-    (dict(sampler="fixed"), "fixed"),
-    (dict(sampler="fused"), "fused"),
     (dict(sampler="fused_train"), "fused_train"),
-    (dict(sampler="virtual"), "virtual"),
     (dict(feature_sharded=True), "feature_sharded"),
     (dict(comm="int8"), "int8"),
     (dict(sync="ssp:4"), "ssp:4"),
-    (dict(use_pallas=True), "use_pallas"),
 ])
 def test_what_cannot_take_hashed_rows_refuses_by_name(mesh1, change, word):
     cfg = dataclasses.replace(_cfg(128, 0.25, 1), **change)

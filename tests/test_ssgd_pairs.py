@@ -421,17 +421,13 @@ META = dict(row_format="pairs", pack=1, n_rows=600, n_features=5000,
 
 @pytest.mark.parametrize("change,word", [
     (dict(sampler="bernoulli"), "masks every row of a dense matrix"),
-    (dict(sampler="fixed"), "gathers dense rows one at a time"),
-    (dict(sampler="fused"), "streams packed bfloat16 columns"),
     (dict(sampler="fused_train"), "megakernel"),
-    (dict(sampler="virtual"), "regenerates packed columns"),
     (dict(comm="int8"), "comm='int8'"),
     (dict(comm="bf16"), "comm='bf16'"),
     (dict(comm="topk:0.1"), "comm='topk:0.1'"),
     (dict(comm="bucketed"), "comm='bucketed'"),
     (dict(sync="ssp:4"), "the guarantee is BSP"),
     (dict(feature_sharded=True), "sharded over chips"),
-    (dict(use_pallas=True), "dense one-pass kernel"),
 ])
 def test_what_cannot_take_pairs_rows_refuses_by_name(mesh1, change, word):
     cfg = dataclasses.replace(_cfg(0.1, 1), **change)
@@ -570,7 +566,7 @@ def test_cli_trains_pairs_and_prints_the_forms(capsys):
     (["--row-format", "pairs", "--stream-cache", "x"], "--stream-cache"),
     (["--row-format", "pairs", "--pair-rows", "10", "--max-pairs", "512",
       "--pair-block-slots", "256"], "no row is split or cut"),
-    (["--row-format", "pairs", "--sampler", "fused"], "pairs rows"),
+    (["--row-format", "pairs", "--sampler", "fused_train"], "pairs rows"),
 ])
 def test_cli_refuses_what_names_no_pairs_table(argv, word):
     from tpu_distalg import cli

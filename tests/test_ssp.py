@@ -518,20 +518,14 @@ def test_local_sgd_ssp_converges_within_band(mesh4, cancer_data):
 
 # --------------------------------------------------- rejection guards
 
-def test_ssp_rejects_megakernel_and_fixed_samplers(mesh4,
-                                                   cancer_data):
+def test_ssp_rejects_megakernel_samplers(mesh4, cancer_data):
     # PR 9's fused_gather rejection is LIFTED (the fused-SSP tests
     # below); the megakernel (no per-window collective inside a
-    # launch) and the legacy 'fixed' gather path stay BSP, as does
-    # the local_sgd family's fused path
+    # launch) stays BSP, as does the local_sgd family's fused path
     with pytest.raises(ValueError, match="fused_train"):
         ssgd.train(*cancer_data, mesh4,
                    ssgd.SSGDConfig(n_iterations=8, sync="ssp:4",
                                    sampler="fused_train"))
-    with pytest.raises(ValueError, match="stale-synchronous"):
-        ssgd.train(*cancer_data, mesh4,
-                   ssgd.SSGDConfig(n_iterations=8, sync="ssp:4",
-                                   sampler="fixed"))
     with pytest.raises(ValueError, match="bernoulli"):
         bmuf.train(*cancer_data, mesh4,
                    bmuf.BMUFConfig(n_iterations=8, sync="ssp:4",

@@ -1,10 +1,9 @@
 """On-TPU numerical validation of the fused Pallas kernels.
 
 The CPU suite verifies packing layout, selector algebra and the gathered
-kernel end-to-end in interpret mode; what it cannot verify is the v3
-kernel's on-core PRNG path and real-Mosaic convergence. These tests close
-that gap against the reference goldens (``/root/reference/optimization/
-ssgd.py:122-130``, final acc 0.929825).
+kernel end-to-end in interpret mode; what it cannot verify is real-Mosaic
+convergence. These tests close that gap against the reference goldens
+(``/root/reference/optimization/ssgd.py:122-130``, final acc 0.929825).
 """
 
 import functools
@@ -20,19 +19,10 @@ from tpu_distalg.ops import pallas_kernels as pk
 from tpu_distalg.utils import prng
 
 
-def test_fused_v3_convergence(tpu_mesh, cancer_data):
-    """sampler='fused' (on-core-PRNG streaming kernel) reaches the
-    reference's SSGD quality band on breast-cancer."""
-    res = ssgd.train(
-        *cancer_data, tpu_mesh,
-        ssgd.SSGDConfig(n_iterations=1500, sampler="fused"),
-    )
-    assert res.final_acc >= 0.92, res.final_acc
-
-
 def test_fused_gather_convergence(tpu_mesh, cancer_data):
-    """sampler='fused_gather' (block-gather kernel) reaches the same
-    band; fine-grained blocks so the 398-row task has real stochasticity."""
+    """sampler='fused_gather' (block-gather kernel) reaches the
+    reference's SSGD quality band on breast-cancer; fine-grained blocks
+    so the 398-row task has real stochasticity."""
     res = ssgd.train(
         *cancer_data, tpu_mesh,
         ssgd.SSGDConfig(n_iterations=1500, sampler="fused_gather",
@@ -42,50 +32,11 @@ def test_fused_gather_convergence(tpu_mesh, cancer_data):
     assert res.final_acc >= 0.92, res.final_acc
 
 
-def test_fused_v3_gradient_expectation(tpu_mesh):
-    """The v3 kernel's on-core-PRNG Bernoulli gradient is an unbiased
-    estimator: the mean normalized gradient over many steps must match
-    the full-batch mean gradient within standard-error tolerance (the
-    XLA path and the kernel use different PRNGs, so compare in
-    expectation, not per-draw)."""
-    rng = np.random.default_rng(0)
-    n, d = 1 << 16, 30
-    X = rng.normal(size=(n, d)).astype(np.float32)
-    y = (rng.random(n) > 0.5).astype(np.float32)
-    X2, meta = pk.pack_augmented(X, y, np.ones(n, np.float32),
-                                 dtype=jnp.float32, pack=16,
-                                 block_rows=8192)
-    w = np.zeros(meta["d_total"], np.float32)
-    w[:d] = rng.normal(size=(d,)).astype(np.float32) * 0.1
-    w_j = jnp.asarray(w)
-    T = 800
-    kern = functools.partial(
-        pk.fused_grad_sum_packed, pack=16, d_total=meta["d_total"],
-        y_col=meta["y_col"], v_col=meta["v_col"], fraction=0.1,
-        block_rows=8192)
-
-    @jax.jit
-    def mean_grad():
-        def step(acc, t):
-            g, cnt = kern(X2, w_j, t, 0)
-            return acc + g / jnp.maximum(cnt, 1.0), ()
-        acc, _ = jax.lax.scan(step, jnp.zeros((meta["d_total"],)),
-                              jnp.arange(T))
-        return acc / T
-
-    gm = np.asarray(mean_grad())[:d]
-    g_full, cnt = logistic.grad_sum(
-        jnp.asarray(X), jnp.asarray(y), jnp.asarray(w[:d]),
-        jnp.ones(n))
-    gf = np.asarray(g_full / cnt)
-    # std-err of the mean-of-means ≈ σ_row/√(batch·T); bound generously
-    se = float(np.std(X) * 0.5 / np.sqrt(0.1 * n * T))
-    np.testing.assert_allclose(gm, gf, atol=20 * se)
-
-
 def test_fused_gather_gradient_expectation(tpu_mesh):
-    """Same unbiasedness check for the v4 block-gather kernel (block-
-    cluster sampling over i.i.d. rows)."""
+    """The v4 block-gather kernel's gradient is an unbiased estimator
+    (block-cluster sampling over i.i.d. rows): the mean normalized
+    gradient over many steps matches the full-batch mean gradient
+    within standard-error tolerance."""
     rng = np.random.default_rng(1)
     n, d = 1 << 16, 30
     X = rng.normal(size=(n, d)).astype(np.float32)
@@ -578,24 +529,6 @@ def test_streamed_ssgd_bitwise_on_tpu(tpu_mesh, cancer_data):
                                   np.asarray(streamed.w))
 
 
-def test_virtual_ssgd_converges_on_tpu(tpu_mesh):
-    """Round-4 virtual sampler on hardware: a 4M-logical-row run
-    reaches the generator's held-out band and is deterministic."""
-    import numpy as np
-
-    from tpu_distalg.models import ssgd, ssgd_virtual
-
-    data = ssgd_virtual.VirtualData(n_rows=4_000_000, n_features=30,
-                                    data_seed=0)
-    cfg = ssgd.SSGDConfig(n_iterations=200, sampler="virtual",
-                          mini_batch_fraction=0.01,
-                          gather_block_rows=8192, eval_every=50)
-    res = ssgd_virtual.train(tpu_mesh, cfg, data)
-    assert res.final_acc > 0.75
-    res2 = ssgd_virtual.train(tpu_mesh, cfg, data)
-    assert np.array_equal(np.asarray(res.w), np.asarray(res2.w))
-
-
 def test_fused_topk_matches_xla_topk_incl_ties(tpu_mesh):
     """The compiled fused matmul+top-k kernel against ``xla_matmul_topk``
     on hardware. Small-integer factors are exact in every matmul
@@ -627,28 +560,6 @@ def test_fused_topk_matches_xla_topk_incl_ties(tpu_mesh):
     np.testing.assert_allclose(np.asarray(fv), np.asarray(xv),
                                rtol=1e-5, atol=1e-5)
     assert (np.asarray(fi) == np.asarray(xi)).mean() >= 0.99
-
-
-def test_v1_fused_grad_sum_matches_xla(tpu_mesh):
-    """The opt-in v1 kernel (``SSGDConfig.use_pallas``) through the real
-    Mosaic compiler: its (d, 1) VMEM and (1, 1) SMEM scratches had only
-    met the interpreter. bf16-exact inputs keep the default-precision
-    MXU passes exact, so the sums match ``logistic.grad_sum`` to f32
-    accumulation order."""
-    rng = np.random.default_rng(2)
-    n, d = 8192, 30
-    X = rng.integers(-2, 3, size=(n, d)).astype(np.float32)
-    y = (rng.random(n) > 0.5).astype(np.float32)
-    mask = (rng.random(n) < 0.1).astype(np.float32)
-    w = (rng.integers(-4, 5, size=d) / 8.0).astype(np.float32)
-    g, cnt = pk.fused_grad_sum(jnp.asarray(X), jnp.asarray(y),
-                               jnp.asarray(mask), jnp.asarray(w))
-    g_ref, cnt_ref = logistic.grad_sum(
-        jnp.asarray(X), jnp.asarray(y), jnp.asarray(w),
-        jnp.asarray(mask))
-    assert float(cnt) == float(cnt_ref)
-    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
-                               rtol=2e-2, atol=2e-2)
 
 
 def test_tp_split_kernels_match_one_pass_kernel(tpu_mesh):

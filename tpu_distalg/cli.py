@@ -77,8 +77,7 @@ def _mesh(args):
     ).mesh
 
 
-def _add_common(p, n_iterations, eta=None, frac=None, samplers=None,
-                sync=False):
+def _add_common(p, n_iterations, eta=None, frac=None, sync=False):
     p.add_argument("--n-slices", type=int, default=0,
                    help="data-axis size; 0 = all devices")
     _add_mesh_shape(p)
@@ -124,11 +123,12 @@ def _add_common(p, n_iterations, eta=None, frac=None, samplers=None,
                  "instead of rejecting")
     if frac is not None:
         p.add_argument("--mini-batch-fraction", type=float, default=frac)
-        # TPU perf knobs (see ssgd.SSGDConfig.sampler for semantics);
-        # each subcommand advertises only the samplers its training
-        # path accepts
+        # TPU perf knobs; the samplers are ssgd.SAMPLERS, which says
+        # what each is (the local-update family takes the same three)
+        from tpu_distalg.models.ssgd import SAMPLERS
+
         p.add_argument("--sampler", default="bernoulli",
-                       choices=samplers)
+                       choices=SAMPLERS)
         p.add_argument("--x-dtype", default="float32",
                        choices=["float32", "bfloat16"])
         p.add_argument("--gather-block-rows", type=int, default=1024)
@@ -279,9 +279,7 @@ def main(argv=None):
     _add_common(p, 1500, eta=0.1)
 
     p = sub.add_parser("ssgd", help="synchronous minibatch SGD")
-    _add_common(p, 1500, eta=0.1, frac=0.1,
-                samplers=["bernoulli", "fixed", "fused", "fused_gather",
-                          "fused_train"], sync=True)
+    _add_common(p, 1500, eta=0.1, frac=0.1, sync=True)
     p.add_argument("--lam", type=float, default=0.0)
     p.add_argument("--reg-type", default="l2",
                    choices=["none", "l2", "l1", "elastic_net"])
@@ -358,9 +356,7 @@ def main(argv=None):
     for name in ("ma", "bmuf", "easgd"):
         p = sub.add_parser(name)
         _add_common(p, 1500 if name == "easgd" else 300, eta=0.1,
-                    frac=0.1,
-                    samplers=["bernoulli", "fused_gather",
-                              "fused_train"], sync=True)
+                    frac=0.1, sync=True)
         p.add_argument("--n-local-iterations", type=int,
                        default=1 if name == "easgd" else 5)
         p.add_argument("--resample-per-local-step", action="store_true")

@@ -53,9 +53,9 @@ def block_geometry(n_rows: int, block_rows: int, n_shards: int,
                    fraction: float | None = None):
     """The block grid every out-of-core path samples on: rows per shard
     padded up to whole blocks, blocks per shard, and (when ``fraction``
-    is given) blocks sampled per shard per step. Shared by the virtual
-    sampler (``models/ssgd_virtual``), the stream trainer and the
-    minibatch k-means/ALS paths so the grids cannot drift apart.
+    is given) blocks sampled per shard per step (since PR 57 retired
+    its one caller in the package only ``tests/test_data.py`` calls it:
+    ROADMAP D14).
     Returns ``(rows_per_shard, n_blocks, n_sampled)`` (``n_sampled``
     None when ``fraction`` is)."""
     rows_per_shard = -(-n_rows // (n_shards * block_rows)) * block_rows

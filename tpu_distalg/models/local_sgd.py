@@ -65,7 +65,7 @@ class LocalSGDConfig:
     # rows (reference sample() semantics); 'fused_gather' = the packed
     # traffic-proportional Pallas kernel: each replica's local step DMAs
     # only its sampled gather_block_rows-row blocks (same grad_sum
-    # contract, block-cluster sampling — see ssgd.SSGDConfig.sampler);
+    # contract, block-cluster sampling — see ssgd.SAMPLERS);
     # 'fused_train' = 'fused_gather' with each round's n_local steps
     # fused into ONE megakernel launch per replica (weights in VMEM,
     # update + elastic pull in-kernel). Unlike SSGD's megakernel this
@@ -969,11 +969,13 @@ def train(
     runs are bitwise-identical because round PRNG keys use absolute
     round ids.
     """
+    from tpu_distalg.models.ssgd import check_sampler
     from tpu_distalg.telemetry import events as tevents
 
     # progress mark: the heartbeat names this phase if a round wedges
     # (checkpointed runs also mark per segment inside run_segmented)
     tevents.mark(f"local_sgd:{config.global_update}", emit_event=False)
+    check_sampler(config)
     _check_sync_sampler(config)
     from tpu_distalg.parallel import ssp as _pssp
 
@@ -982,14 +984,12 @@ def train(
             X_train, y_train, X_test, y_test, mesh, config,
             checkpoint_dir=checkpoint_dir,
             checkpoint_every=checkpoint_every)
-    if config.sampler in ("fused_gather", "fused_train"):
+    if config.sampler != "bernoulli":
         return _train_fused(
             X_train, y_train, X_test, y_test, mesh, config,
             checkpoint_dir=checkpoint_dir,
             checkpoint_every=checkpoint_every,
         )
-    if config.sampler != "bernoulli":
-        raise ValueError(f"unknown sampler {config.sampler!r}")
     Xs = parallelize(X_train, mesh, dtype=jnp.dtype(config.x_dtype))
     ys = parallelize(y_train, mesh)
     D = X_train.shape[1]

@@ -35,6 +35,37 @@ from tpu_distalg.telemetry import names
 from tpu_distalg.utils import metrics, prng
 
 
+# The ways to draw and score a mini-batch, stated once: SSGDConfig.sampler
+# takes one of these, :func:`check_sampler` refuses any other name, and
+# ``tda ssgd --sampler`` offers them (``cli.py``; the local-update family
+# takes the same three).
+#   'bernoulli'    the XLA reference: a reference-parity mask over ALL rows
+#                  (sample() semantics, ssgd.py:97), two passes over a dense
+#                  X. What the tests and tests_tpu/ compare the kernels
+#                  with, and the one path every contract composes with:
+#                  SSP, every comm schedule, the tp split's plain form.
+#   'fused_gather' the traffic-proportional kernel: whole
+#                  gather_block_rows-row blocks are drawn XLA-side and ONLY
+#                  those blocks are copied (block-cluster sampling: i.i.d.
+#                  per-row equivalent when rows are i.i.d. or pack-time
+#                  shuffled), one launch and one psum a step. The mesh's
+#                  path (cell lr30_400m_dp4) and the one that takes rows of
+#                  indices (INDEX_ROW_FORMATS: cells lrhash39_46m_frac01,
+#                  lrwide11_150m_frac01, lrpairs3728_350k_frac01), the comm
+#                  schedules, SSP and feature_sharded.
+#   'fused_train'  'fused_gather' with the WHOLE schedule fused into one
+#                  kernel launch per mega_steps segment (weights live in
+#                  VMEM, update runs in-kernel): fastest path (cells
+#                  lr30_100m_bigbatch, lr30_100m_smallbatch), but
+#                  single-data-shard only (no per-step psum), lam=0 only,
+#                  eval at segment boundaries only.
+# A table larger than HBM is not a sampler: models/ssgd_stream.train stages
+# the sampled blocks of REAL bytes (host RAM / disk memmap) host→device per
+# step, bitwise 'fused_gather' on a resident copy, and
+# :func:`prepare_fused_synthetic` makes seeded rows on the device.
+SAMPLERS = ("bernoulli", "fused_gather", "fused_train")
+
+
 @dataclasses.dataclass(frozen=True)
 class SSGDConfig:
     """Knob names follow ``ssgd.py:17-21``."""
@@ -54,40 +85,16 @@ class SSGDConfig:
     eval_every: int = 1
     # TPU perf knobs (not in the reference):
     x_dtype: str = "float32"    # 'bfloat16' halves HBM traffic for X
-    use_pallas: bool = False    # v1 fused one-pass kernel (interpretable)
-    pallas_block_rows: int = 2048
-    # 'bernoulli' = reference-parity mask over ALL rows (sample() semantics,
-    # ssgd.py:97); 'fixed' = gather exactly frac·n_local rows per shard —
-    # row-granular HBM gathers, measured SLOWER than streaming on TPU;
-    # 'fused' = TPU-only packed Pallas kernel: sampling + forward +
-    # backward in ONE HBM pass over ALL of X (Bernoulli semantics,
-    # shard/block-dependent mask like Spark's per-partition sample());
-    # 'fused_gather' = the traffic-proportional kernel: sample whole
-    # gather_block_rows-row blocks XLA-side, DMA ONLY those blocks
-    # (≈frac× the HBM bytes of 'fused'; block-cluster sampling — i.i.d.
-    # per-row equivalent when rows are i.i.d. or pack-time shuffled);
-    # 'fused_train' = 'fused_gather' with the WHOLE schedule fused into
-    # one kernel launch per mega_steps segment (weights live in VMEM,
-    # update runs in-kernel): fastest path, but single-data-shard only
-    # (no per-step psum), lam=0 only, eval at segment boundaries only;
-    # 'virtual' = NO resident dataset: sampled blocks are regenerated
-    # on device from the counter-based row generator each step, so the
-    # logical row count is unbounded by HBM (build via
-    # models/ssgd_virtual.make_train_fn). For >HBM datasets of REAL
-    # bytes (host RAM / disk memmap, not a row-id function) use the
-    # streamed trainer instead: models/ssgd_stream.train stages the
-    # sampled blocks host→device per step, double-buffered, and is
-    # bitwise-identical to 'fused_gather' on a resident copy.
+    # one of SAMPLERS, which says what each is and who runs it.
     # Precision note: with x_dtype='bfloat16' the fused kernels cast the
     # residual AND the selector-replicated weights to bf16 (the XLA bf16
     # path keeps both f32) — a small extra deviation; convergence to the
     # reference band is verified on-TPU (tests_tpu/, chip_smoke.py)
     sampler: str = "bernoulli"
-    fused_pack: int = 16        # rows packed per sublane row ('fused*')
-    fused_block_rows: int = 8192
-    gather_block_rows: int = 1024   # rows per sampled block ('fused_gather')
+    fused_pack: int = 16        # rows packed per sublane row ('fused_*')
+    gather_block_rows: int = 1024   # rows per sampled block ('fused_*')
     mega_steps: int = 125       # steps per kernel launch ('fused_train')
-    shuffle_seed: int | None = None  # pack-time row shuffle ('fused_gather')
+    shuffle_seed: int | None = None  # pack-time row shuffle ('fused_*')
     # shard the FEATURE dim over the mesh model axis (tensor parallelism):
     # the forward matvec psums partial X_l·w_l over 'model', the gradient
     # contraction psums over 'data' only, and w lives sharded P('model')
@@ -103,10 +110,10 @@ class SSGDConfig:
     # behind bucket b−1's unpack and the reg-gradient math; append
     # '@seq' (e.g. 'int8@seq') for the sequential A/B reference
     # (bitwise-identical, slower; a no-op for the single-bucket
-    # topk/hier). Composes with samplers 'bernoulli',
-    # 'fused' and 'fused_gather'; the megakernel ('fused_train': no
-    # per-step collective exists to compress), 'fixed' and
-    # feature_sharded reject non-dense comm.
+    # topk/hier). Composes with samplers 'bernoulli' and
+    # 'fused_gather'; the megakernel ('fused_train': no per-step
+    # collective exists to compress) and feature_sharded reject
+    # non-dense comm.
     comm: str = "dense"
     # synchronization discipline (parallel/ssp.py): 'bsp' (classic
     # lock-step, one collective per step — bitwise the pre-SSP trainer,
@@ -118,9 +125,9 @@ class SSGDConfig:
     # straggler no longer serializes every step). Seeded
     # 'shard:straggle'/'shard:leave' fault-plan rules compile into the
     # deterministic straggler/membership schedules; same plan => a
-    # bitwise-identical replay. SSP composes with the 'bernoulli'
-    # sampler (the XLA path) and any --comm schedule; the fused
-    # kernels and feature_sharded stay BSP.
+    # bitwise-identical replay. SSP composes with the 'bernoulli' and
+    # 'fused_gather' samplers and any --comm schedule; the megakernel
+    # and feature_sharded stay BSP.
     sync: str = "bsp"
 
 
@@ -268,44 +275,23 @@ def make_train_fn(mesh: Mesh, config: SSGDConfig, n_padded: int,
     length — the comm layer sizes its residual/byte accounting off it)
     and call the returned fn as ``fn(X, y, valid, X_test, y_test, w0,
     res0, t0=0, acc0=0.0)`` → ``(w, accs, res)``."""
-    if config.sampler in ("fused", "fused_gather"):
+    check_sampler(config)
+    if config.sampler != "bernoulli":
         raise ValueError(
             f"sampler={config.sampler!r} packs labels into X — build via "
             "make_train_fn_fused(mesh, config, meta) with meta from "
-            "pallas_kernels.pack_augmented, or use ssgd.train()"
+            "pallas_kernels.pack_augmented (feature_sharded: "
+            "make_train_fn_fused_tp), or use ssgd.train()"
         )
     _check_comm_sampler(config)
     if config.feature_sharded:
-        if config.sampler != "bernoulli" or config.use_pallas:
-            raise ValueError(
-                "feature_sharded composes with the 'bernoulli' sampler "
-                "(this XLA builder) or sampler='fused_gather' (the "
-                "two-pass kernel path, via ssgd.train / "
-                "make_train_fn_fused_tp) — not with "
-                f"sampler={config.sampler!r} use_pallas={config.use_pallas}"
-            )
         return _make_train_fn_tp(mesh, config, n_padded)
-    if config.sampler == "fixed":
-        return _make_train_fn_fixed(mesh, config, n_padded)
-    if config.sampler != "bernoulli":
-        raise ValueError(f"unknown sampler {config.sampler!r}")
     if config.comm != "dense":
         return _make_train_fn_comm(mesh, config, n_padded, d)
-    if config.use_pallas:
-        from tpu_distalg.ops import pallas_kernels
 
-        interpret = not mesh_on_tpu(mesh)
-
-        def _local_grad(X, y, mask, w):
-            g, cnt = pallas_kernels.fused_grad_sum(
-                X, y, mask, w,
-                block_rows=config.pallas_block_rows, interpret=interpret,
-            )
-            return tree_allreduce_sum((g, cnt))
-    else:
-        def _local_grad(X, y, mask, w):
-            g, cnt = logistic.grad_sum(X, y, w, mask)
-            return tree_allreduce_sum((g, cnt))
+    def _local_grad(X, y, mask, w):
+        g, cnt = logistic.grad_sum(X, y, w, mask)
+        return tree_allreduce_sum((g, cnt))
 
     grad_fn = data_parallel(
         _local_grad,
@@ -324,6 +310,15 @@ def make_train_fn(mesh: Mesh, config: SSGDConfig, n_padded: int,
     return _build_scan(config, sample_and_grad)
 
 
+def check_sampler(config) -> None:
+    """The one refusal of a sampler's name, made by every public entry
+    (the local-update family's too) before any check of a format, a
+    mesh or a schedule."""
+    if config.sampler not in SAMPLERS:
+        raise ValueError(
+            f"unknown sampler {config.sampler!r}: one of {SAMPLERS}")
+
+
 def _check_comm_sampler(config: SSGDConfig) -> None:
     """Reject schedule/sampler combinations that have no per-step
     collective to re-schedule, up front and with the remedy named."""
@@ -336,14 +331,12 @@ def _check_comm_sampler(config: SSGDConfig) -> None:
             "traffic, not a gradient sync); run the comm schedules on "
             "a pure-dp mesh"
         )
-    if config.sampler in ("fused_train", "fixed"):
+    if config.sampler == "fused_train":
         raise ValueError(
             f"comm={config.comm!r} applies to the per-step gradient "
-            f"sync, which sampler={config.sampler!r} does not expose "
-            "('fused_train' fuses whole segments into one launch with "
-            "no per-step collective; 'fixed' is the measured-slower "
-            "legacy gather path) — use 'bernoulli', 'fused' or "
-            "'fused_gather'"
+            "sync, which sampler='fused_train' does not expose (it "
+            "fuses whole segments into one launch with no per-step "
+            "collective) — use 'bernoulli' or 'fused_gather'"
         )
 
 
@@ -354,16 +347,14 @@ def _check_sync_sampler(config: SSGDConfig) -> None:
     spec = pssp.SyncSpec.parse(config.sync)
     if not spec.is_ssp:
         return
-    if config.sampler not in ("bernoulli", "fused", "fused_gather") \
-            or config.use_pallas or config.feature_sharded:
+    if config.sampler == "fused_train" or config.feature_sharded:
         raise ValueError(
             f"sync={config.sync!r} (stale-synchronous) composes with "
-            f"the 'bernoulli', 'fused' and 'fused_gather' samplers on "
-            f"a pure-dp mesh — got sampler={config.sampler!r} "
-            f"use_pallas={config.use_pallas} "
+            f"the 'bernoulli' and 'fused_gather' samplers on a pure-dp "
+            f"mesh — got sampler={config.sampler!r} "
             f"feature_sharded={config.feature_sharded}; 'fused_train' "
-            f"(no per-window collective exists inside the megakernel), "
-            f"'fixed' and the tp split stay BSP")
+            f"(no per-window collective exists inside the megakernel) "
+            f"and the tp split stay BSP")
 
 
 def make_ssp_train_fn(mesh: Mesh, config: SSGDConfig, n_padded: int,
@@ -393,17 +384,13 @@ def make_ssp_train_fn(mesh: Mesh, config: SSGDConfig, n_padded: int,
     nothing is ever lost.
 
     With ``meta`` (from ``pallas_kernels.pack_augmented``) the local
-    tick gradient runs the FUSED kernels instead of the XLA
-    bernoulli-mask path — ``config.sampler`` picks 'fused_gather'
-    (block-gather kernel, interpretable on CPU) or 'fused' (the
-    streaming one-pass kernel, TPU-only) — and the carry layout is
+    tick gradient runs the 'fused_gather' block-gather kernel instead
+    of the XLA bernoulli-mask path, and the carry layout is
     UNCHANGED (``ssp_init_state`` at ``d = meta['d_total']``): the
     window/merge/gate algebra is sampler-independent, so at ``s=1``
     on one shard the trajectory is bitwise the BSP fused trainer's
     (the parity pin).
     """
-    import functools
-
     import numpy as np
 
     from tpu_distalg.parallel import DATA_AXIS, comms
@@ -429,60 +416,32 @@ def make_ssp_train_fn(mesh: Mesh, config: SSGDConfig, n_padded: int,
                     key, t, n_padded, config.mini_batch_fraction,
                     valid))(ts)
     else:
-        from jax import lax
-
         from tpu_distalg.ops import pallas_kernels
 
-        on_tpu = mesh_on_tpu(mesh)
         d_t = meta["d_total"]
         col_keep = (jnp.arange(d_t) < meta["y_col"]).astype(
             jnp.float32)
-        if config.sampler == "fused_gather":
-            n_blocks, n_sampled = fused_gather_geometry(
-                config, meta, n_shards_m)
-            kern = functools.partial(
-                pallas_kernels.fused_grad_sum_gathered,
-                pack=meta["pack"], d_total=d_t, y_col=meta["y_col"],
-                v_col=meta["v_col"],
-                gather_block_rows=config.gather_block_rows,
-                interpret=not on_tpu)
-            payload_spec = P(None, "data", None)  # (s, S, ns) draws
+        n_blocks, n_sampled = fused_gather_geometry(
+            config, meta, n_shards_m)
+        kern = functools.partial(
+            pallas_kernels.fused_grad_sum_gathered,
+            pack=meta["pack"], d_total=d_t, y_col=meta["y_col"],
+            v_col=meta["v_col"],
+            gather_block_rows=config.gather_block_rows,
+            interpret=not mesh_on_tpu(mesh))
+        payload_spec = P(None, "data", None)  # (s, S, ns) draws
 
-            def tick_grad(X2, y, w_l, payload_t):
-                del y                            # packed into X2
-                g, cnt = kern(X2, w_l, payload_t[0])
-                return g * col_keep, cnt
+        def tick_grad(X2, y, w_l, payload_t):
+            del y                            # packed into X2
+            g, cnt = kern(X2, w_l, payload_t[0])
+            return g * col_keep, cnt
 
-            def window_payload(ts, valid):
-                del valid                        # validity rides X2
-                return jax.vmap(
-                    lambda t: sampling.sample_block_ids(
-                        jax.random.fold_in(key, t),
-                        n_shards_m, n_blocks, n_sampled))(ts)
-        else:                                    # 'fused'
-            if not on_tpu:
-                raise ValueError(
-                    "sampler='fused' needs a TPU (the on-core PRNG "
-                    "has no interpret-mode lowering); use "
-                    "'fused_gather' or 'bernoulli' elsewhere")
-            kern = functools.partial(
-                pallas_kernels.fused_grad_sum_packed,
-                pack=meta["pack"], d_total=d_t, y_col=meta["y_col"],
-                v_col=meta["v_col"],
-                fraction=config.mini_batch_fraction,
-                block_rows=config.fused_block_rows)
-            payload_spec = P(None)               # (s,) absolute ticks
-
-            def tick_grad(X2, y, w_l, payload_t):
-                del y
-                shard = lax.axis_index(DATA_AXIS)
-                g, cnt = kern(X2, w_l, payload_t + config.seed,
-                              shard)
-                return g * col_keep, cnt
-
-            def window_payload(ts, valid):
-                del valid
-                return ts
+        def window_payload(ts, valid):
+            del valid                        # validity rides X2
+            return jax.vmap(
+                lambda t: sampling.sample_block_ids(
+                    jax.random.fold_in(key, t),
+                    n_shards_m, n_blocks, n_sampled))(ts)
 
     def window_body(X, y, payloads, w, clocks, pend, basegen, wl,
                     accd, res, extra, tickv, winid):
@@ -739,8 +698,7 @@ def _train_ssp(
     T = config.n_iterations
     d_orig = X_train.shape[1]
     n_shards = int(mesh.shape[DATA_AXIS])
-    fused = config.sampler in ("fused", "fused_gather")
-    if fused:
+    if config.sampler == "fused_gather":
         # the packed-kernel SSP path: same carry (ssp_init_state at
         # d_total), same window/merge algebra — only the local tick
         # gradient runs the fused kernel (PR 9's named leftover)
@@ -863,11 +821,6 @@ def _make_train_fn_comm(mesh: Mesh, config: SSGDConfig, n_padded: int,
             "make_train_fn(mesh, config, n_padded, d=X.shape[1]) "
             "(ssgd.train does this for you)"
         )
-    if config.use_pallas:
-        raise ValueError(
-            "comm != 'dense' composes with the XLA 'bernoulli' path "
-            "or the fused kernels, not use_pallas=True"
-        )
     sync = _comm_sync(mesh, config, d)
 
     def _local_grad(X, y, mask, w, t, res):
@@ -986,24 +939,24 @@ def warn_quantized_fraction(prefix: str, n_blocks: int, n_sampled: int,
 
 
 def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
-    """Scan builder for the packed-layout samplers.
+    """Scan builder for the packed-layout samplers (and, by ``meta``,
+    for rows of indices).
 
-    'fused': the streaming one-pass Pallas kernel
-    (``pallas_kernels.fused_grad_sum_packed``) — reads ALL of X each step,
-    samples with the on-core PRNG (TPU-only).  'fused_gather': the
-    traffic-proportional kernel (``fused_grad_sum_gathered``) — samples
-    ``frac·n_blocks`` block ids XLA-side each step and DMAs only those
-    (runs under interpret on CPU too).  Either way the kernel sits inside
-    ``shard_map`` over the data axis with (Σg, count) psum'd across
-    shards; the carried weight vector is the augmented (d_total,) layout
-    and the y/v/pad columns are re-zeroed every step (their gradient
-    entries are kernel garbage).
+    'fused_gather': the traffic-proportional kernel
+    (``fused_grad_sum_gathered``) — samples ``frac·n_blocks`` block ids
+    XLA-side each step and DMAs only those (runs under interpret on CPU
+    too). The kernel sits inside ``shard_map`` over the data axis with
+    (Σg, count) psum'd across shards; the carried weight vector is the
+    augmented (d_total,) layout and the y/v/pad columns are re-zeroed
+    every step (their gradient entries are kernel garbage).
+    'fused_train': :func:`_make_train_fn_mega`.
     """
     from jax import lax
 
     from tpu_distalg.ops import pallas_kernels
     from tpu_distalg.parallel import DATA_AXIS
 
+    check_sampler(config)
     if meta.get("row_format", "packed") == "pairs":
         # ragged rows of (feature, value) pairs: models/ssgd_pairs.py
         from tpu_distalg.models import ssgd_pairs
@@ -1013,104 +966,71 @@ def make_train_fn_fused(mesh: Mesh, config: SSGDConfig, meta: dict):
         # the loader's meta decides: rows that are indices have their
         # own two passes and share everything round them
         return _make_train_fn_hashed(mesh, config, meta)
+    if config.sampler == "bernoulli":
+        raise ValueError(
+            "sampler='bernoulli' masks dense rows, not the packed "
+            "layout — build via make_train_fn(mesh, config, n_padded), "
+            "or use ssgd.train()")
     on_tpu = mesh_on_tpu(mesh)
     d_t = meta["d_total"]
     col_keep = (jnp.arange(d_t) < meta["y_col"]).astype(jnp.float32)
     n_shards = mesh.shape[DATA_AXIS]
-    prep_xs = None
     _check_comm_sampler(config)
     sync = (_comm_sync(mesh, config, d_t)
             if config.comm != "dense" else None)
 
     if config.sampler == "fused_train":
         return _make_train_fn_mega(mesh, config, meta, on_tpu, n_shards)
+    # geometry warns when n_blocks quantizes the fraction coarsely
+    n_blocks, n_sampled = fused_gather_geometry(config, meta, n_shards)
+    key = prng.root_key(config.seed)
+    kern = functools.partial(
+        pallas_kernels.fused_grad_sum_gathered,
+        pack=meta["pack"], d_total=d_t, y_col=meta["y_col"],
+        v_col=meta["v_col"],
+        gather_block_rows=config.gather_block_rows,
+        interpret=not on_tpu,
+    )
 
-    if config.sampler == "fused_gather":
-        # geometry warns when n_blocks quantizes the fraction coarsely
-        n_blocks, n_sampled = fused_gather_geometry(
-            config, meta, n_shards)
-        key = prng.root_key(config.seed)
-        kern = functools.partial(
-            pallas_kernels.fused_grad_sum_gathered,
-            pack=meta["pack"], d_total=d_t, y_col=meta["y_col"],
-            v_col=meta["v_col"],
-            gather_block_rows=config.gather_block_rows,
-            interpret=not on_tpu,
-        )
-
-        def prep_xs(ts):
-            # ALL (step, shard) block draws in one batched threefry —
-            # the shared without-replacement draw
-            # (sampling.sample_block_ids), per-round key = fold_in(key,
-            # absolute step id)
-            with jax.named_scope(names.SSGD_DRAW):
-                return jax.vmap(
-                    lambda t: sampling.sample_block_ids(
-                        jax.random.fold_in(key, t),
-                        n_shards, n_blocks, n_sampled,
-                    )
-                )(ts)                                    # (T, S, ns)
-
-        if sync is not None:
-            def _local_grad(X2, w, idx_shards, t, res):
-                shard = lax.axis_index(DATA_AXIS)
-                idx = lax.dynamic_index_in_dim(
-                    idx_shards, shard, keepdims=False
+    def prep_xs(ts):
+        # ALL (step, shard) block draws in one batched threefry —
+        # the shared without-replacement draw
+        # (sampling.sample_block_ids), per-round key = fold_in(key,
+        # absolute step id)
+        with jax.named_scope(names.SSGD_DRAW):
+            return jax.vmap(
+                lambda t: sampling.sample_block_ids(
+                    jax.random.fold_in(key, t),
+                    n_shards, n_blocks, n_sampled,
                 )
-                with jax.named_scope(names.SSGD_KERNEL):
-                    g, cnt = kern(X2, w, idx)
-                    g = g * col_keep
-                with jax.named_scope(names.SSGD_SYNC):
-                    (g, cnt), res, reg = sync.reduce(
-                        (g, cnt), res, t,
-                        compute=lambda: logistic.reg_gradient(
-                            w, config.reg_type, config.elastic_alpha))
-                return g, cnt, res, reg
-        else:
-            def _local_grad(X2, w, idx_shards):
-                shard = lax.axis_index(DATA_AXIS)
-                idx = lax.dynamic_index_in_dim(
-                    idx_shards, shard, keepdims=False
-                )
-                with jax.named_scope(names.SSGD_KERNEL):
-                    g, cnt = kern(X2, w, idx)
-                    g = g * col_keep
-                with jax.named_scope(names.SSGD_SYNC):
-                    return tree_allreduce_sum((g, cnt))
-    else:
-        if not on_tpu:
-            raise ValueError(
-                "sampler='fused' needs a TPU (the on-core PRNG has no "
-                "interpret-mode lowering); use 'fused_gather' or "
-                "'bernoulli' elsewhere"
+            )(ts)                                    # (T, S, ns)
+
+    if sync is not None:
+        def _local_grad(X2, w, idx_shards, t, res):
+            shard = lax.axis_index(DATA_AXIS)
+            idx = lax.dynamic_index_in_dim(
+                idx_shards, shard, keepdims=False
             )
-        kern = functools.partial(
-            pallas_kernels.fused_grad_sum_packed,
-            pack=meta["pack"], d_total=d_t, y_col=meta["y_col"],
-            v_col=meta["v_col"], fraction=config.mini_batch_fraction,
-            block_rows=config.fused_block_rows,
-        )
-
-        if sync is not None:
-            def _local_grad(X2, w, t_payload, t, res):
-                shard = lax.axis_index(DATA_AXIS)
-                with jax.named_scope(names.SSGD_KERNEL):
-                    g, cnt = kern(X2, w, t_payload + config.seed, shard)
-                    g = g * col_keep
-                with jax.named_scope(names.SSGD_SYNC):
-                    (g, cnt), res, reg = sync.reduce(
-                        (g, cnt), res, t,
-                        compute=lambda: logistic.reg_gradient(
-                            w, config.reg_type, config.elastic_alpha))
-                return g, cnt, res, reg
-        else:
-            def _local_grad(X2, w, t):
-                shard = lax.axis_index(DATA_AXIS)
-                with jax.named_scope(names.SSGD_KERNEL):
-                    g, cnt = kern(X2, w, t + config.seed, shard)
-                    g = g * col_keep
-                with jax.named_scope(names.SSGD_SYNC):
-                    return tree_allreduce_sum((g, cnt))
+            with jax.named_scope(names.SSGD_KERNEL):
+                g, cnt = kern(X2, w, idx)
+                g = g * col_keep
+            with jax.named_scope(names.SSGD_SYNC):
+                (g, cnt), res, reg = sync.reduce(
+                    (g, cnt), res, t,
+                    compute=lambda: logistic.reg_gradient(
+                        w, config.reg_type, config.elastic_alpha))
+            return g, cnt, res, reg
+    else:
+        def _local_grad(X2, w, idx_shards):
+            shard = lax.axis_index(DATA_AXIS)
+            idx = lax.dynamic_index_in_dim(
+                idx_shards, shard, keepdims=False
+            )
+            with jax.named_scope(names.SSGD_KERNEL):
+                g, cnt = kern(X2, w, idx)
+                g = g * col_keep
+            with jax.named_scope(names.SSGD_SYNC):
+                return tree_allreduce_sum((g, cnt))
 
     if sync is not None:
         grad_fn = data_parallel(
@@ -1381,49 +1301,6 @@ def make_train_fn_fused_tp(mesh: Mesh, config: SSGDConfig, meta: dict):
     return _build_scan(config, sample_and_grad, prep_xs=prep_xs)
 
 
-def _make_train_fn_fixed(mesh: Mesh, config: SSGDConfig, n_padded: int):
-    """Fixed-size per-shard gather sampling: each shard draws exactly
-    ``frac·n_local`` local row indices per step and gathers only those rows
-    — the HBM-traffic-optimal sampler (the Bernoulli mask touches every
-    row of X every step). Gathered padding rows carry zero mask weight.
-
-    The draw is WITHOUT replacement (a per-step permutation slice),
-    matching ``sample(False, ...)``'s contract (``ssgd.py:97``) — no row
-    can count twice in (Σg, cnt). The permutation is O(n_local log
-    n_local) per step, which is immaterial here: this sampler's gather
-    path is already the measured-slower, non-default option."""
-    from jax import lax
-
-    from tpu_distalg.parallel import DATA_AXIS
-
-    if config.use_pallas:
-        raise ValueError(
-            "use_pallas applies to the 'bernoulli' sampler only; the "
-            "'fixed' sampler's gather path does not use the fused kernel"
-        )
-
-    n_shards = mesh.shape[DATA_AXIS]
-    n_local = n_padded // n_shards
-    b_local = max(1, round(config.mini_batch_fraction * n_local))
-    key = prng.root_key(config.seed)
-
-    def _local_grad(X, y, valid, w, t):
-        shard = lax.axis_index(DATA_AXIS)
-        k = jax.random.fold_in(jax.random.fold_in(key, t), shard)
-        idx = jax.random.permutation(k, X.shape[0])[:b_local]
-        g, cnt = logistic.grad_sum(X[idx], y[idx], w, valid[idx])
-        return tree_allreduce_sum((g, cnt))
-
-    grad_fn = data_parallel(
-        _local_grad,
-        mesh,
-        in_specs=(P("data", None), P("data"), P("data"), P(), P()),
-        out_specs=(P(), P()),
-    )
-
-    return _build_scan(config, grad_fn)
-
-
 def fused_train_segment_lengths(checkpoint_dir, checkpoint_every: int,
                                 n_iterations: int) -> set[int]:
     """The distinct compiled-segment lengths a checkpointed run will
@@ -1460,12 +1337,9 @@ def _train_span(config: SSGDConfig, **fields):
 def _draw_fields(config: SSGDConfig, meta: dict, mesh: Mesh) -> dict:
     """What the training spans of a fused run say about its block draw:
     the form ``sampling.sample_block_ids`` takes at this geometry
-    (``tda report`` prints it). 'fused' draws on the core, not by
-    blocks, and says nothing."""
+    (``tda report`` prints it)."""
     from tpu_distalg.parallel import DATA_AXIS
 
-    if config.sampler not in ("fused_gather", "fused_train"):
-        return {}
     with warnings.catch_warnings():      # the builder has warned already
         warnings.simplefilter("ignore")
         n_blocks, n_sampled = fused_gather_geometry(
@@ -1511,6 +1385,7 @@ def train(
 
     from tpu_distalg.parallel import MODEL_AXIS, partition
 
+    check_sampler(config)
     _check_comm_sampler(config)
     _check_sync_sampler(config)
     from tpu_distalg.parallel import ssp as _pssp
@@ -1520,7 +1395,7 @@ def train(
             X_train, y_train, X_test, y_test, mesh, config,
             checkpoint_dir=checkpoint_dir,
             checkpoint_every=checkpoint_every)
-    if config.sampler in ("fused", "fused_gather", "fused_train"):
+    if config.sampler != "bernoulli":
         if config.feature_sharded:
             if config.sampler != "fused_gather":
                 raise ValueError(
@@ -1656,9 +1531,6 @@ def prepare_fused(X_train, y_train, mesh: Mesh, config: SSGDConfig):
     n_shards = mesh.shape[DATA_AXIS]
     d_orig = X_train.shape[1]
     n = X_train.shape[0]
-    block = (config.gather_block_rows
-             if config.sampler in ("fused_gather", "fused_train")
-             else config.fused_block_rows)
     with tevents.span("ssgd:prepare", mesh.local_devices, rows=n):
         with tevents.span("ssgd:pack", rows=n):
             X2, meta = pallas_kernels.pack_augmented(
@@ -1666,7 +1538,7 @@ def prepare_fused(X_train, y_train, mesh: Mesh, config: SSGDConfig):
                 np.ones(n, np.float32),
                 dtype=jnp.dtype(config.x_dtype),
                 pack=config.fused_pack,
-                block_rows=block * n_shards,
+                block_rows=config.gather_block_rows * n_shards,
                 shuffle_seed=config.shuffle_seed,
             )
         with tevents.span("ssgd:h2d", mesh.local_devices,
@@ -1713,10 +1585,7 @@ def prepare_fused_synthetic(
     pk = config.fused_pack
     d = n_features + 1  # + bias column (ssgd.py:83-84)
     d_t, y_col, v_col = pallas_kernels.packed_dims(d, pk)
-    block = (config.gather_block_rows
-             if config.sampler in ("fused_gather", "fused_train")
-             else config.fused_block_rows)
-    mult = max(block, pk) * n_shards
+    mult = max(config.gather_block_rows, pk) * n_shards
     n_t = n_rows + ((-n_rows) % mult)
     n_local = n_t // n_shards
     chunk = min(chunk_rows, n_local)
@@ -1814,7 +1683,8 @@ def _train_fused(
     checkpoint_dir: str | None = None,
     checkpoint_every: int = 500,
 ) -> TrainResult:
-    """'fused'-sampler training: pack once, stream the packed matrix.
+    """Packed-layout training ('fused_gather', 'fused_train'): pack
+    once, then read only the sampled blocks of the packed matrix.
 
     The packed layout bakes labels and row validity into X
     (``pallas_kernels.pack_augmented``), so the scan carries an augmented
@@ -1824,10 +1694,9 @@ def _train_fused(
 
     With ``checkpoint_dir``, training runs in compiled segments exactly
     like the XLA-sampler path: the only carry is the augmented weight
-    vector, and both fused samplers key their PRNG off the ABSOLUTE step
-    id (on-core seed ``t + seed`` for 'fused', ``fold_in(key, t)`` for
-    'fused_gather'), so segmented resume is bitwise-equal to a straight
-    run.
+    vector, and both fused samplers key their draw off the ABSOLUTE step
+    id (``fold_in(key, t)``), so segmented resume is bitwise-equal to a
+    straight run.
     """
     import numpy as np
 
@@ -1954,24 +1823,20 @@ def _check_hashed_config(config: SSGDConfig,
     row as columns."""
     from tpu_distalg.parallel import ssp as pssp
 
+    check_sampler(config)
     rows = f"{row_format} rows"
 
     why = {
         "bernoulli": "masks every row of a dense matrix each step",
-        "fixed": "gathers dense rows one at a time",
-        "fused": "streams packed bfloat16 columns through the one-pass "
-                 "kernel",
         "fused_train": "keeps a packed step's 40 weights in the "
                        "megakernel's VMEM; a weight table (2**hash_bits "
                        "slots hashed, a slot a feature indexed or in "
                        "pairs) and a psum a step do not fit one launch",
-        "virtual": "regenerates packed columns on the device",
     }
     if config.sampler != "fused_gather":
         raise ValueError(
             f"{rows}: sampler={config.sampler!r} cannot take the "
-            f"format ({why.get(config.sampler, 'unknown sampler')}); "
-            f"use sampler='fused_gather'")
+            f"format ({why[config.sampler]}); use sampler='fused_gather'")
     if config.feature_sharded:
         raise ValueError(
             f"{rows}: feature_sharded splits packed columns over "
@@ -1987,10 +1852,6 @@ def _check_hashed_config(config: SSGDConfig,
         raise ValueError(
             f"{rows}: sync={config.sync!r} cannot take the format: "
             f"the guarantee is BSP (no slot updated from stale weights)")
-    if config.use_pallas:
-        raise ValueError(
-            f"{rows}: use_pallas names the dense one-pass kernel of "
-            f"the 'bernoulli' sampler and cannot take the format")
 
 
 def hashed_field_plan(config: SSGDConfig, meta: dict):

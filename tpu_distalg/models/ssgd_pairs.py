@@ -32,7 +32,9 @@ from tpu_distalg.ops import pairs, sampling
 from tpu_distalg.parallel import (
     DATA_AXIS,
     data_parallel,
+    get_mesh,
     mesh_on_tpu,
+    partition,
     tree_allreduce_sum,
 )
 from tpu_distalg.telemetry import events as tevents
@@ -296,8 +298,6 @@ def table_fn(mesh: Mesh, spec: PairsSpec, geom: pairs.PairsGeometry):
     """The compiled loader of one geometry: ``f(seed, bias, lengths,
     starts, counts) -> X``; the seed and the blocks' rows are its
     arguments, so a second seed costs no compile."""
-    from tpu_distalg.parallel import partition
-
     n_local = geom.n_blocks // mesh.shape[DATA_AXIS]
     per = math.gcd(n_local, FILL_BLOCKS)
     block = _block_fn(spec, geom, 0)
@@ -463,8 +463,6 @@ def prepare_synthetic(spec: PairsSpec, mesh: Mesh, config: ssgd.SSGDConfig,
                       *, data_seed: int = 0):
     """``ssgd.prepare_hashed_synthetic`` for ragged rows: ``(fn, X, w0,
     meta)``, the weights zero as the source's."""
-    from tpu_distalg.parallel import partition
-
     ssgd._check_hashed_config(config, ROW_FORMAT)
     X, meta = build_table(spec, mesh, data_seed=data_seed)
     w0 = partition.put(jnp.zeros((meta["d_total"],), jnp.float32), "w",
@@ -481,9 +479,10 @@ def evaluate(w, meta: dict, *, data_seed: int = 0,
     X, _, _, _ = _stream(spec, geom, spec.n_rows + HELDOUT_OFFSET,
                          n_blocks, np.int32(data_seed), meta["bias"])
     # one device's program: a mesh's replicated weights go where the
-    # stream lies (a Mosaic kernel under a plain jit is not partitioned)
-    (device,) = X.devices()
-    w = jax.device_put(jnp.asarray(w, jnp.float32), device)
+    # stream lies (a Mosaic kernel under a plain jit is not partitioned):
+    # the table's rule for ``w`` on the mesh of that one device
+    w = partition.put(jnp.asarray(w, jnp.float32), "w", "ssgd",
+                      get_mesh(data=1, devices=list(X.devices())))
     return _score_fn(geom, n_blocks)(X, w)
 
 

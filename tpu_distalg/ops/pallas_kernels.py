@@ -3,21 +3,24 @@
 The XLA-fused SSGD step reads X from HBM twice per iteration — once for the
 forward matvec ``X·w`` and once for the gradient contraction ``Xᵀ·resid``
 (``tpu_distalg.ops.logistic.grad_sum``) — and the step is bandwidth-bound.
-:func:`fused_grad_sum_packed` fuses sampling, forward, masking and backward
-into ONE pass over X, the only remaining HBM traffic.
+:func:`fused_grad_sum_gathered` fuses forward, masking and backward into
+ONE pass over the SAMPLED blocks of X, the only remaining HBM traffic.
 
 The design is driven by TPU layout constraints (/opt/skills/guides/
-pallas_guide.md), discovered the hard way across three kernel generations:
+pallas_guide.md), discovered the hard way across kernel generations;
+the first three are gone (PR 57: PERF.md §6 has their last chip
+readings) and are kept here as what they taught:
 
-  v1 (:func:`fused_grad_sum`, kept for CPU-interpretable tests): separate
-     (n, 1) y/mask operands. A (rows, 1) array is physically lane-padded
-     128-wide on TPU, so each "tiny" stream moved as many bytes as X
-     itself; per-call feature padding also re-copied X every step.
+  v1: separate (n, 1) y/mask operands. A (rows, 1) array is physically
+     lane-padded 128-wide on TPU, so each "tiny" stream moved as many
+     bytes as X itself; per-call feature padding also re-copied X
+     every step.
   v2: y/validity folded into X as two ordinary columns, Bernoulli mask
      drawn from the on-core PRNG — one X pass, but every per-row value
      ((B,1) shapes) still wasted 127/128 of each VPU register row.
-  v3 (production): P consecutive rows packed per sublane row,
-     X2 = X.reshape(n/P, P·D). All per-row values live in (rows, P)
+  v3: P consecutive rows packed per sublane row,
+     X2 = X.reshape(n/P, P·D) — the layout that stayed
+     (:func:`pack_augmented`). All per-row values live in (rows, P)
      shapes. The forward matvec becomes one matmul against a block-
      diagonal replication of w; label/validity extraction are two more
      selector blocks of the same constant matrix (single fused (P·D, 3P)
@@ -25,25 +28,20 @@ pallas_guide.md), discovered the hard way across three kernel generations:
      contraction runs on the MXU with a (P, P·D) tile-shaped accumulator
      whose diagonal band is folded outside the kernel. The deliberate P×
      FLOP overhead buys layout sanity: the MXU is idle in a bandwidth-
-     bound step.
+     bound step. It still streamed 100% of X to sample ``fraction`` of
+     it.
 
-  v4 (:func:`fused_grad_sum_gathered`, production): v3 still streams
-     100% of X to sample ``fraction`` of it. v4 moves the sampling into
-     the *grid*: the caller draws ``frac·n_blocks`` block ids XLA-side
-     and the kernel copies exactly those blocks, ids scalar-prefetched
-     (a ring of VMEM slots since PR 27, see ``_ring_fetch``) — HBM
-     traffic ≈ fraction × |X| per step. (Row-granular gathers are NOT
-     the answer: the XLA 'fixed' row-gather sampler measures ~2× slower
-     than streaming everything; random access serializes on TPU.)
+  v4 (:func:`fused_grad_sum_gathered`, production): moves the sampling
+     into the *grid*: the caller draws ``frac·n_blocks`` block ids
+     XLA-side and the kernel copies exactly those blocks, ids
+     scalar-prefetched (a ring of VMEM slots since PR 27, see
+     ``_ring_fetch``) — HBM traffic ≈ fraction × |X| per step.
+     (Row-granular gathers are NOT the answer: random access serializes
+     on TPU.)
+  v5 (:func:`fused_train_gathered`, production on one data shard): v4
+     with the whole schedule of a segment in one launch.
 
-Measured on one v5e chip before PR 1 (jax 0.4.37; not re-measured on
-current code — see PERF.md), 1M rows × 128 packed columns, fraction 0.1
-(steps/s, timed over 1500-step scan segments with host-fetch so
-dispatch overhead is amortized): XLA two-pass f32 503 ·
-XLA two-pass bf16 668 · XLA 'fixed' row-gather 317-349 · v1 92 · v3
-1398 · **v4 ≈ 11000-13100** (marginal per-step cost 41 µs vs v3's
-360 µs — the traffic argument, realised). The gathered kernels'
-current readings, cell by cell, are in PERF.md §5.
+The gathered kernels' current readings, cell by cell, are in PERF.md §5.
 """
 
 from __future__ import annotations
@@ -54,95 +52,6 @@ import jax
 import jax.numpy as jnp
 
 from tpu_distalg.ops.pallas_api import pl, pltpu
-
-
-# Weyl-sequence constant (2^32/φ, as int32) for mixing the block index
-# into the 2-word hardware PRNG seed.
-_WEYL = -1640531527
-
-
-def _grad_kernel(x_ref, y_ref, mask_ref, w_ref, g_ref, cnt_ref, acc_ref,
-                 cacc_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        cacc_ref[0, 0] = 0.0
-
-    x = x_ref[:]                                   # (B, D) in VMEM
-    w = w_ref[:]                                   # (D, 1)
-    z = jnp.dot(x, w, preferred_element_type=jnp.float32)  # (B, 1) MXU
-    resid = (jax.nn.sigmoid(z) - y_ref[:]) * mask_ref[:]   # (B, 1) VPU
-    # second MXU pass over the SAME VMEM-resident block: Xᵀ·resid
-    acc_ref[:] += jax.lax.dot_general(
-        x, resid, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                              # (D, 1)
-    cacc_ref[0, 0] += jnp.sum(mask_ref[:])
-
-    @pl.when(i == pl.num_programs(0) - 1)
-    def _done():
-        g_ref[:] = acc_ref[:]
-        cnt_ref[0, 0] = cacc_ref[0, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def fused_grad_sum(X, y, mask, w, *, block_rows: int = 2048,
-                   interpret: bool = False):
-    """Masked (Σ gradient, count) in ONE pass over X — v1 layout.
-
-    Same contract as ``logistic.grad_sum`` (the reference's treeAggregate
-    pair, ``ssgd.py:99-103``) for one shard. X may be f32 or bf16; the
-    accumulator is always f32. Superseded on TPU by
-    :func:`fused_grad_sum_packed`; kept because it runs under
-    ``interpret=True`` on CPU (the packed kernel's on-core PRNG does not).
-    """
-    n, d = X.shape
-    d_pad = (-d) % 128
-    b = min(block_rows, n)
-    n_pad = (-n) % b
-    if d_pad or n_pad:
-        X = jnp.pad(X, ((0, n_pad), (0, d_pad)))
-        y = jnp.pad(y, (0, n_pad))
-        mask = jnp.pad(mask, (0, n_pad))  # padded rows masked out
-        w = jnp.pad(w, (0, d_pad))
-    n_t, d_t = X.shape
-
-    grid = (n_t // b,)
-    g, cnt = pl.pallas_call(
-        _grad_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((b, d_t), lambda i: (i, 0)),
-            pl.BlockSpec((b, 1), lambda i: (i, 0)),
-            pl.BlockSpec((b, 1), lambda i: (i, 0)),
-            pl.BlockSpec((d_t, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((d_t, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((d_t, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((d_t, 1), jnp.float32),
-            pltpu.SMEM((1, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-    )(
-        X,
-        y.reshape(-1, 1).astype(jnp.float32),
-        mask.reshape(-1, 1).astype(jnp.float32),
-        w.reshape(-1, 1).astype(X.dtype),
-    )
-    return g[:d, 0], cnt[0, 0]
 
 
 def packed_dims(d: int, pack: int):
@@ -162,8 +71,8 @@ def packed_dims(d: int, pack: int):
 def pack_augmented(X, y, valid, *, dtype=jnp.bfloat16, pack: int = 16,
                    block_rows: int = 8192, shuffle_seed: int | None = None,
                    as_numpy: bool = False):
-    """Pack (X, y, valid) for :func:`fused_grad_sum_packed` /
-    :func:`fused_grad_sum_gathered` — done ONCE, outside the training scan.
+    """Pack (X, y, valid) for the gathered kernels
+    (:func:`fused_grad_sum_gathered`) — done ONCE, outside the training scan.
 
     Layout: ``[features… | y | valid | zero-pad]`` per row, row i of the
     augmented matrix at packed position ``[i // pack, (i % pack)·D …]``.
@@ -171,8 +80,7 @@ def pack_augmented(X, y, valid, *, dtype=jnp.bfloat16, pack: int = 16,
     multiple and rows to a ``block_rows`` multiple (zero rows carry
     valid=0 and are inert).  ``shuffle_seed`` permutes rows once at pack
     time so the gathered sampler's block-cluster draws are exchangeable
-    with row-level draws even when the input rows are ordered (for the
-    v3 streaming kernel shuffling is a no-op statistically).  Returns
+    with row-level draws even when the input rows are ordered.  Returns
     ``(X2, meta)`` where ``X2`` has shape (n_padded/pack, pack·D) and
     ``meta`` is the static dict of (pack, d_total, y_col, v_col,
     n_padded).
@@ -200,40 +108,6 @@ def pack_augmented(X, y, valid, *, dtype=jnp.bfloat16, pack: int = 16,
     meta = dict(pack=pack, d_total=d_t, y_col=y_col, v_col=v_col,
                 n_padded=n_t)
     return X2, meta
-
-
-def _grad_kernel_packed(s_ref, x_ref, c_ref, gacc_ref, cnt_ref, acc_ref,
-                        cacc_ref, *, pack: int, thresh: int):
-    """See the module docstring (v3). Shapes, with P = pack and D the
-    padded per-row width: x2 (Bp, P·D) · C (P·D, 3P) = [Wbig | Ey | Ev]
-    → zyv (Bp, 3P); backward residᵀ·x2 accumulates into a (P, P·D) tile
-    whose diagonal band is the gradient (folded by the wrapper)."""
-    P = pack
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        cacc_ref[0, 0] = 0.0
-
-    x2 = x_ref[:]                                   # (Bp, P·D), ONE read
-    zyv = jnp.dot(x2, c_ref[:], preferred_element_type=jnp.float32)
-    z, y, v = zyv[:, :P], zyv[:, P:2 * P], zyv[:, 2 * P:3 * P]
-    # Bernoulli(frac) from the on-core PRNG; 2-word seed = (t, shard⊕blk)
-    pltpu.prng_seed(s_ref[0], s_ref[1] ^ (i * _WEYL))
-    bits = pltpu.bitcast(pltpu.prng_random_bits(z.shape), jnp.uint32)
-    m = jnp.where(bits < jnp.uint32(thresh), 1.0, 0.0) * v
-    resid = ((jax.nn.sigmoid(z) - y) * m).astype(x2.dtype)  # (Bp, P)
-    acc_ref[:] += jax.lax.dot_general(
-        resid, x2, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                               # (P, P·D) MXU
-    cacc_ref[0, 0] += jnp.sum(m)
-
-    @pl.when(i == pl.num_programs(0) - 1)
-    def _done():
-        gacc_ref[:] = acc_ref[:]
-        cnt_ref[0, 0] = cacc_ref[0, 0]
 
 
 # The gathered kernels' block loop (v4's ``_grad_kernel_gathered`` and
@@ -390,15 +264,14 @@ def fused_grad_sum_gathered(X2, w_aug, block_idx, *, pack: int,
     """Traffic-proportional (Σ gradient, count): ONE pass over only the
     SAMPLED blocks of X (v4).
 
-    The v3 kernel (:func:`fused_grad_sum_packed`) still streams 100% of X
-    to sample a ``fraction`` of it — HBM traffic 1/fraction× what the
+    A kernel that samples on the core still streams 100% of X to
+    sample a ``fraction`` of it — HBM traffic 1/fraction× what the
     algorithm needs. Here the minibatch is drawn at *block* granularity:
     the caller samples ``block_idx`` (ids of ``gather_block_rows``-row
     blocks, XLA-side PRNG) and the kernel copies exactly those blocks
     (a ring of VMEM slots fed by the scalar-prefetched ids), so traffic
     ≈ fraction × |X| per step. Row-level random gathers are NOT the
-    answer on TPU — they serialize (the 'fixed' sampler measures ~2×
-    *slower* than streaming everything); whole-block DMA keeps transfers
+    answer on TPU — they serialize; whole-block DMA keeps transfers
     wide.
 
     Semantics: block-cluster sampling — sampling whole blocks of
@@ -408,7 +281,7 @@ def fused_grad_sum_gathered(X2, w_aug, block_idx, *, pack: int,
     (``pack_augmented(shuffle_seed=...)``) the sampled-gradient
     distribution is identical to row-level sampling at equal batch size.
 
-    No on-core PRNG → runs under ``interpret=True`` on CPU, unlike v3.
+    No on-core PRNG → runs under ``interpret=True`` on CPU.
     Returns the (d_total,) gradient (garbage y/v/pad entries — zero via
     the meta col mask) and the kept-row count.
     """
@@ -834,69 +707,3 @@ def build_selector_t(w_aug, *, pack: int, d_total: int, y_col: int,
          for r in rows], axis=0)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("pack", "d_total", "y_col", "v_col", "fraction",
-                     "block_rows"),
-)
-def fused_grad_sum_packed(X2, w_aug, t, shard, *, pack: int, d_total: int,
-                          y_col: int, v_col: int, fraction: float,
-                          block_rows: int = 8192):
-    """On-core-sampled (Σ gradient, count) in ONE pass over X (v3).
-
-    Aggregation contract matches ``logistic.grad_sum`` / the reference's
-    treeAggregate pair (``ssgd.py:99-103``) for one shard, with the
-    sampler fused in: row i is kept iff hash(t, shard, block, i) <
-    fraction — Bernoulli like ``RDD.sample(False, frac, 42+t)``
-    (``ssgd.py:97``) and, like Spark's per-partition sampling, dependent
-    on the (shard, block_rows) partitioning. TPU-only (the on-core PRNG
-    has no interpret-mode lowering).
-
-    Returns the (d_total,) gradient — garbage in the y/v/pad columns,
-    zero them with ``meta``-derived col mask — and the sampled count.
-    """
-    P, D = pack, d_total
-    n2, pd = X2.shape
-    bp = block_rows // P
-    if pd != P * D or (P * D) % 128 or block_rows % P or n2 % bp:
-        raise ValueError(
-            f"fused_grad_sum_packed: X2 {X2.shape} incompatible with "
-            f"pack={P}, d_total={D}, block_rows={block_rows}"
-        )
-    thresh = min(int(fraction * 2.0**32), 2**32 - 1)
-    C = build_selector(w_aug, pack=P, d_total=D, y_col=y_col,
-                       v_col=v_col, dtype=X2.dtype)
-    s = jnp.stack([jnp.asarray(t, jnp.int32),
-                   jnp.asarray(shard, jnp.int32)])
-    kernel = functools.partial(_grad_kernel_packed, pack=P, thresh=thresh)
-    gacc, cnt = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n2 // bp,),
-            in_specs=[
-                pl.BlockSpec((bp, P * D), lambda i, s: (i, 0)),
-                pl.BlockSpec((P * D, 3 * P), lambda i, s: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((P, P * D), lambda i, s: (0, 0)),
-                pl.BlockSpec((1, 1), lambda i, s: (0, 0),
-                             memory_space=pltpu.SMEM),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((P, P * D), jnp.float32),
-                pltpu.SMEM((1, 1), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((P, P * D), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=100 * 1024 * 1024,
-        ),
-    )(s, X2, C)
-    # fold the diagonal band: g[j] = gacc[c, c·D+j] summed over slots c
-    g = jnp.einsum("ccj->j", gacc.reshape(P, P, D))
-    return g, cnt[0, 0]
