@@ -845,7 +845,8 @@ def stage_ssgd_indexed(s: Smoke):
     in three groups, two id fields of 5M and 4.5M values whose ranges
     stay in HBM: 18.0M weights), 12 steps of the block-sampled trainer
     with every Mosaic pass compiled, the two ranges in HBM gathered a
-    row a DMA and scattered by XLA; against the same steps with every field in XLA's
+    row a DMA and summed by address in one accumulator in VMEM (2^16
+    rows, 33.6 MB: a piece a field); against the same steps with every field in XLA's
     form over the whole table, which no chip had run past 2**22 slots
     before this stage, to float32 rounding; and held-out rows scored
     better than zero weights score them."""
@@ -1149,7 +1150,8 @@ STAGES = (
                    "pallas_hashed._hashed_rows_kernel",
                    "pallas_hashed._hashed_value_gather_kernel",
                    "pallas_hashed._hashed_value_sums_kernel",
-                   "pallas_hashed._hashed_hbm_gather_kernel"))),
+                   "pallas_hashed._hashed_hbm_gather_kernel",
+                   "pallas_hashed._hashed_field_scatter_kernel"))),
     ("ssgd_pairs", stage_ssgd_pairs,
      dict(kernels=("pallas_pairs._pairs_gather_kernel",
                    "pallas_pairs._pairs_scatter_kernel"))),

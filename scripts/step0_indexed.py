@@ -5,6 +5,10 @@ sampled blocks of 8192 rows, 11 slots a row, 54 686 452 weights), ms a
 call, least of three.
 
     chiprun -- python3 scripts/step0_indexed.py [--quick | --trips]
+    chiprun -- timeout 300 python3 -u scripts/step0_indexed.py \
+        --field-scatter <field>
+    chiprun -- timeout 120 python3 -u scripts/step0_indexed.py \
+        --grant <slots>
     JAX_PLATFORMS=cpu python3 scripts/step0_indexed.py --rehearse
     JAX_PLATFORMS=cpu python3 scripts/step0_indexed.py --bundles
 
@@ -37,7 +41,20 @@ cell's generator parameters). Rows of the output, one a line as
   hashed4x20.<pass>.rows<r>   a hashed table of 4 fields and 2**20 slots
                               (no cell has so few fields): the rule's
                               trip and the parent's 2 rows
+  hbm.scatter.rows256         PR 59: the fields past VMEM's sums by
+                              address in ONE accumulator in VMEM, a piece
+                              of a field's range at a time
+                              (``_hashed_field_scatter_kernel``, one call)
 ``--trips`` runs the sweep, ``fields.*`` and ``step.fields`` alone.
+``--field-scatter <field>`` (PR 59's Step 0; a field a process, each
+under a ``timeout`` of its own: an accumulator of a field's whole range,
+87.7 and 97.2 MB, never came back) reads for that field alone, over the
+seeded table and over a flat draw of the field's range: whether the call
+runs three times to the same bits, ``hbm.[<field>].scatter`` against
+``hbm.[<field>].xla.scatter`` (ms a call and ns a pair) and both forms'
+sums against float64 on the host; its summary lands in
+``chiprun_out/step0_indexed_field<field>.json``. ``--grant <slots>``
+reads the same over a table of its own with one field of ``slots`` slots.
 ``--bundles`` needs no chip: it compiles each call of the sweep for a
 described ``v5e:2x2`` with the schedule dumped and counts the post-RA
 bundles of a trip of its loop (where a trip stops being one chain's
@@ -70,7 +87,8 @@ GROUP_ROWS = (2, 4, 8, 16)
 HBM_ROWS = (2, 8, 16)
 KERNELS = {"gather": "_hashed_gather_kernel",
            "scatter": "_hashed_scatter_kernel",
-           "hbm": "_hashed_hbm_gather_kernel"}
+           "hbm": "_hashed_hbm_gather_kernel",
+           "field": "_hashed_field_scatter_kernel"}
 
 
 def least_ms(fn, *args, n: int = 3) -> float:
@@ -87,7 +105,8 @@ def least_ms(fn, *args, n: int = 3) -> float:
 
 def sweep_calls(plan, rehearse: bool = False):
     """``(tag, pass, group or None, rows)`` of every call of the sweep
-    (``pass`` is gather, scatter or hbm)."""
+    (``pass`` is gather, scatter, hbm or, with the fields in HBM where
+    the group is, field)."""
     def some(rows):
         return rows[::len(rows) - 1] if rehearse else rows
 
@@ -100,18 +119,27 @@ def sweep_calls(plan, rehearse: bool = False):
     if plan.hbm_fields:
         for rows in some(HBM_ROWS):
             yield "hbm.gather", "hbm", None, rows
+    if plan.hbm_fields:
+        from tpu_distalg.ops import pallas_hashed as ph
+
+        yield "hbm.scatter", "field", plan.hbm_fields, ph.CHUNK_ROWS
 
 
 def call_of(ph, which, geom, plan, group, rows, interpret=False):
     """One call of the sweep as a function of (X, w or r, ids); with no
     ``group`` a gather or a scatter runs over every field of a hashed
     ``geom``."""
+    import jax.numpy as jnp
+
     if which == "gather":
         return lambda X, w, ids: ph.margins_vmem(
             X, w, ids, geom, interpret=interpret, group=group, rows=rows)
     if which == "scatter":
         return lambda X, r, ids: ph.slot_sums_vmem(
             X, r, ids, geom, interpret=interpret, group=group, rows=rows)
+    if which == "field":
+        return lambda X, r, ids: jnp.concatenate(ph.slot_sums_fields(
+            X, r, ids, geom, group, interpret=interpret))
     return lambda X, w, ids: ph.margins_hbm(
         X, w, ids, geom, plan.hbm_fields, interpret=interpret, rows=rows)
 
@@ -154,7 +182,7 @@ def compile_one(spec: str) -> None:
 
     ns = sh["n_sampled"]
     second = arr((ns, geom.block_rows), jnp.float32) \
-        if which == "scatter" else arr((geom.w_len,), jnp.float32)
+        if which in ("scatter", "field") else arr((geom.w_len,), jnp.float32)
     jax.jit(call_of(ph, which, geom, plan, group, rows)).lower(
         arr((sh["n_blocks"], geom.fields_held, geom.block_rows), jnp.int32),
         second, arr((ns,), jnp.int32)).compile()
@@ -168,7 +196,9 @@ def bundles() -> int:
     geom, plan, _ = cell_geometry()
     out = {}
     for n, (tag, which, group, rows) in enumerate(sweep_calls(plan)):
-        fields = plan.hbm_fields if group is None else group.fields
+        # a grid step of the field scatter serves one field's chunk
+        fields = (group[0],) if which == "field" else \
+            plan.hbm_fields if group is None else group.fields
         env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled")
         with tempfile.TemporaryDirectory(prefix="llo_step0_") as dump:
             done = subprocess.run(
@@ -186,7 +216,9 @@ def bundles() -> int:
             continue
         (lo, hi, inner), = nest[-1:]         # the grid's loop is the last
         loops = [h - l + 1 for l, h, _ in inner]
-        trip = loops[-1]                     # the by-address loop
+        # the by-address loop; the one-field scatter has none: a chunk
+        # is the grid step's own basic block
+        trip = hi - lo + 1 - sum(loops) if which == "field" else loops[-1]
         pairs = rows * len(fields)
         out[name] = {"trip_bundles": trip, "pairs": pairs,
                      "bundles_a_pair": trip / pairs,
@@ -262,7 +294,7 @@ def sweep(say, out, ph, X, w, r, ids, geom, plan, interpret, rehearse):
 
     first = {}
     for tag, which, group, rows in sweep_calls(plan, rehearse):
-        second = r if which == "scatter" else w
+        second = r if which in ("scatter", "field") else w
         fn = jax.jit(call_of(ph, which, geom, plan, group, rows, interpret))
         name = f"{tag}.rows{rows}"
         say(name, least_ms(fn, X, second, ids))
@@ -275,6 +307,97 @@ def sweep(say, out, ph, X, w, r, ids, geom, plan, interpret, rehearse):
     print(f"[step0] sweep: every trip bit for bit its first: "
           f"{all(v for k, v in out.items() if k.endswith('.equal'))}",
           flush=True)
+
+
+def field_scatter(say, out, ph, geom, field, r, draws, interpret):
+    """PR 59's Step 0 for one field past VMEM: its sums by address in
+    an accumulator in VMEM (``ph.slot_sums_fields``) against XLA's
+    scatter-add (``ph.slot_sums_hbm``) and against float64 on the
+    host, over each of ``draws`` (a name, a table, the sampled blocks'
+    ids)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    lo, hi = geom.offsets[field], geom.offsets[field + 1]
+    pairs = r.size
+    kern = jax.jit(lambda X, r, ids: ph.slot_sums_fields(
+        X, r, ids, geom, (field,), interpret=interpret)[0])
+    xla = jax.jit(lambda X, r, ids: ph.slot_sums_hbm(
+        X, r, ids, geom, (field,))[lo:hi])
+    rows, phases = ph.field_phases(geom, (field,))
+    print(f"[step0] field {field}: range {hi - lo} "
+          f"({4 * (hi - lo) / 1e6:.1f} MB) in {len(phases)} piece(s) of "
+          f"{rows} rows, asks "
+          f"{ph._vmem_limit(geom, 1, rows * ph.LANES) / 1e6:.1f} MB of "
+          f"VMEM, form {ph.field_scatter_form(hi - lo, not interpret)}",
+          flush=True)
+    r64 = np.asarray(r, np.float64).reshape(-1)
+    for draw, Xd, idd in draws:
+        tag = f"{draw}.hbm.[{field}]"
+        got = [jax.block_until_ready(kern(Xd, r, idd)) for _ in range(3)]
+        out[tag + ".same_bits"] = all(
+            bool(jnp.array_equal(got[0], g)) for g in got[1:])
+        print(f"[step0] {tag}.scatter ran three times, the same bits: "
+              f"{out[tag + '.same_bits']}", flush=True)
+        for name, fn in (("scatter", kern), ("xla.scatter", xla)):
+            ms = least_ms(fn, Xd, r, idd)
+            say(f"{tag}.{name}", ms)
+            out[f"{tag}.{name}.ns_a_pair"] = ms * 1e6 / pairs
+            print(f"[step0] {tag}.{name} {ms * 1e6 / pairs:.3f} ns a pair",
+                  flush=True)
+        slots = np.asarray(Xd[idd][:, field, :]).reshape(-1) - lo
+        want = np.bincount(slots, weights=r64, minlength=hi - lo)
+        scale = np.linalg.norm(want)
+        for name, g in (("scatter", got[0]), ("xla.scatter",
+                                              xla(Xd, r, idd))):
+            err = float(np.linalg.norm(np.asarray(g, np.float64) - want)
+                        / scale)
+            out[f"{tag}.{name}.err64"] = err
+            print(f"[step0] {tag}.{name} off float64 in norm {err:.3g}",
+                  flush=True)
+        top = int(np.bincount(slots).max())
+        out[tag + ".hottest"] = top
+        print(f"[step0] {tag}: the hottest slot holds {top} of {pairs} "
+              f"pairs", flush=True)
+
+
+def flat_draw(geom, field, ns: int, seed: int):
+    """``ns`` blocks whose ``field`` holds a flat draw of its range (the
+    other fields slot 0: the field scatter reads one row of a block)."""
+    import jax
+    import jax.numpy as jnp
+
+    lo, hi = geom.offsets[field], geom.offsets[field + 1]
+    return jnp.zeros((ns, geom.fields_held, geom.block_rows),
+                     jnp.int32).at[:, field, :].set(jax.random.randint(
+                         jax.random.key(seed), (ns, geom.block_rows), lo,
+                         hi, jnp.int32))
+
+
+def grant(slots: int, interpret: bool, rows: int = 8192, ns: int = 183):
+    """Whether the chip runs the field scatter over a field of
+    ``slots`` slots (a table of its own: a field of 1000 values and one
+    of ``slots``, a flat draw, ``ns`` blocks; 16 777 000 slots are one
+    piece of ``FIELD_PIECE_ROWS`` rows, 67.1 MB). A size a process,
+    under a ``timeout``: an accumulator of a whole range of 87.7 MB did
+    not come back (PR 59)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_distalg.ops import pallas_hashed as ph
+
+    geom = ph.HashedGeometry(nnz=2, hash_bits=0, block_rows=rows,
+                             field_sizes=(1000, slots))
+    r = jax.random.normal(jax.random.key(slots), (ns, rows))
+    out = {}
+    field_scatter(lambda name, ms: print(f"[step0] {name} {ms:.3f}",
+                                         flush=True),
+                  out, ph, geom, 1, r,
+                  [("flat", flat_draw(geom, 1, ns, slots),
+                    jnp.arange(ns, dtype=jnp.int32))], interpret)
+    return 0 if out["flat.hbm.[1].same_bits"] \
+        and out["flat.hbm.[1].scatter.err64"] < 1e-5 else 1
 
 
 def few_fields(say, out, ph, ns, interpret, rehearse):
@@ -332,6 +455,9 @@ def main(argv) -> int:
         print("step0_indexed: no chip (--rehearse interprets a tiny "
               "shape here)", file=sys.stderr)
         return 2
+    if "--grant" in argv:
+        slots = int(argv[argv.index("--grant") + 1])
+        return grant(slots, not on_tpu, *((256, 3) if rehearse else ()))
     cell = mf.Cell(os.path.join(ROOT, "BENCHMARK.json"), CELL)
     c, t = dict(cell.config), cell.traffic
     if rehearse:
@@ -365,6 +491,23 @@ def main(argv) -> int:
     def say(name, ms):
         out[name] = ms
         print(f"[step0] {name} {ms:.3f}", flush=True)
+
+    if "--field-scatter" in argv:
+        field = int(argv[argv.index("--field-scatter") + 1])
+        if field not in plan.hbm_fields:
+            print(f"step0_indexed: field {field} is not in HBM "
+                  f"{plan.hbm_fields}", file=sys.stderr)
+            return 2
+        field_scatter(say, out, ph, geom, field, r, [
+            ("seeded", X, ids),
+            ("flat", flat_draw(geom, field, ns, 59),
+             jnp.arange(ns, dtype=jnp.int32))], interpret)
+        os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(ROOT, "chiprun_out",
+                               f"step0_indexed_field{field}.json"),
+                  "w") as f:
+            json.dump(out, f, indent=1)
+        return 0
 
     def gather_of(fields):
         return jax.jit(

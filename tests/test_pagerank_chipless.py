@@ -243,6 +243,141 @@ def test_hbm_gather_kernel_compiles_at_kdd12s_shape(one_chip):
     assert done.memory_analysis().output_size_in_bytes == 184 * 8192 * 4
 
 
+KDD12 = (24323, 594098, 13745, 3, 3, 24296581, 1157062, 3750862, 2936510,
+         21913244, 21)
+
+
+def test_field_scatter_kernel_compiles_at_kdd12s_two_ranges(one_chip):
+    """Indexed LR's sums of the two id fields at the benchmark's shape
+    (183 sampled blocks of 8192 rows; the query ids' 24 296 581 slots,
+    the user ids' 21 913 244): one call, four phases, and the chip's
+    compiler grants ONE accumulator of 2^17 rows (67.1 MB, no second
+    buffer) as a VMEM scratch beside a chunk's residuals; each range
+    comes back cut from wherever in a row it starts."""
+    from tpu_distalg.ops import pallas_hashed as ph
+
+    geom = ph.HashedGeometry(11, 0, 8192, field_sizes=KDD12)
+    assert [ph.field_scatter_form(KDD12[f], True) for f in (5, 9)] == [
+        "vmem", "vmem"]
+    assert geom.offsets[5] % 128 and geom.offsets[9] % 128
+    rows, phases = ph.field_phases(geom, (5, 9))
+    assert (rows, len(phases)) == (1 << 17, 4)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    done = jax.jit(lambda X, r, ids: ph.slot_sums_fields(
+        X, r, ids, geom, (5, 9))).lower(
+            arr((18267, 16, 8192), jnp.int32),
+            arr((183, 8192), jnp.float32), arr((183,), jnp.int32)).compile()
+    assert len(re.findall(r"%_hashed_field_scatter_kernel[.\d]* = ",
+                          done.as_text())) == 1
+    mem = done.memory_analysis()
+    slots = KDD12[5] + KDD12[9]
+    assert 4 * slots <= mem.output_size_in_bytes <= 4 * slots + 8192
+    # the residuals a lane each (768 MB) and the four pieces' copies in
+    # HBM before the ranges are cut from them
+    assert mem.temp_size_in_bytes < 183 * 8192 * 512 + 4 * (64 << 20) \
+        + (1 << 20)
+
+
+def test_indexed_trainer_on_a_tpu_mesh_scatters_its_id_fields_in_vmem(
+        one_chip, monkeypatch, tmp_path):
+    """The trainer's segment over an indexed table on a described chip
+    (``mesh_on_tpu``: the passes compile), the VMEM bound shrunk so that
+    two fields are past it: one call of ``_hashed_field_scatter_kernel``
+    for both, ``_hashed_hbm_gather_kernel`` still their gather, and the
+    step says which form each field's sums took."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpu_distalg.models import ssgd
+    from tpu_distalg.ops import pallas_hashed as ph
+    from tpu_distalg.telemetry import events, report
+    from tpu_distalg.utils import datasets
+
+    monkeypatch.setattr(ph, "VMEM_BITS", 12)
+    cards = (300, 2000, 150, 3, 3, 9000, 1500, 3000, 2500, 7000, 21)
+    mesh = Mesh(np.array([one_chip._device]).reshape(1, 1),
+                ("data", "model"))
+    rep = NamedSharding(mesh, P())
+    cfg = ssgd.SSGDConfig(
+        n_iterations=2, eta=0.1, lam=0.0, mini_batch_fraction=0.25,
+        seed=42, eval_test=False, sampler="fused_gather",
+        gather_block_rows=256)
+    meta = dict(row_format="indexed", nnz=11, hash_bits=0, pack=1,
+                n_rows=20000, n_padded=20224, d_total=25600,
+                cardinalities=cards,
+                dictionaries=datasets.indexed_field_dictionaries(cards))
+    assert ssgd.hashed_field_plan(cfg, meta).hbm_fields == (5, 9)
+    fields = ssgd._hashed_fields(cfg, meta, mesh)
+    assert (fields["fields_hbm"], fields["fields_hbm_scatter_vmem"],
+            fields["fields_hbm_scatter_xla"]) == (2, 2, 0)
+    d = jax.ShapeDtypeStruct((1,), jnp.float32, sharding=rep)
+    events.configure(str(tmp_path))
+    try:
+        text = ssgd.make_train_fn_fused(mesh, cfg, meta).lower(
+            jax.ShapeDtypeStruct((79, 16, 256), jnp.int32,
+                                 sharding=NamedSharding(
+                                     mesh, P("data", None, None))),
+            d, d, d, d,
+            jax.ShapeDtypeStruct((25600,), jnp.float32, sharding=rep),
+            t0=0).as_text(debug_info=True)
+    finally:
+        events.configure(False)
+    # one call, under the scatter's scope of the table in HBM
+    assert text.count('kernel_name = "_hashed_field_scatter_kernel"') == 1
+    assert ("tda.ssgd.scatter/tda.ssgd.table_hbm/"
+            "_hashed_field_scatter_kernel") in text
+    assert "_hashed_hbm_gather_kernel" in text
+    said = sorted((e["field"], e["form"], e["kernel"], e["pieces"])
+                  for e in report.load_events(str(tmp_path))
+                  if e["ev"] == "ssgd:field_scatter")
+    assert said == [(5, "vmem", "_hashed_field_scatter_kernel", 1),
+                    (9, "vmem", "_hashed_field_scatter_kernel", 1)]
+
+
+def test_a_hashed_tables_step_on_a_tpu_mesh_has_no_field_scatter(one_chip):
+    """The control: a hashed table has no field ranges, so its plan
+    leaves nothing in HBM and its segment on a described chip lowers
+    the by-address and by-value kernels alone."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpu_distalg.models import ssgd
+    from tpu_distalg.utils import datasets
+
+    cards = datasets.click_field_cardinalities(8)
+    mesh = Mesh(np.array([one_chip._device]).reshape(1, 1),
+                ("data", "model"))
+    rep = NamedSharding(mesh, P())
+    cfg = ssgd.SSGDConfig(
+        n_iterations=2, eta=0.1, lam=0.0, mini_batch_fraction=0.25,
+        seed=42, eval_test=False, sampler="fused_gather",
+        gather_block_rows=1024)
+    meta = dict(row_format="hashed", nnz=8, hash_bits=14, pack=1,
+                n_rows=20000, n_padded=20480, d_total=(1 << 14) + 128,
+                cardinalities=cards,
+                dictionaries=datasets.click_field_dictionaries(cards, 14))
+    plan = ssgd.hashed_field_plan(cfg, meta)
+    assert plan is not None and plan.hbm_fields == ()
+    fields = ssgd._hashed_fields(cfg, meta, mesh)
+    assert (fields["fields_hbm"], fields["fields_hbm_scatter_vmem"],
+            fields["fields_hbm_scatter_xla"]) == (0, 0, 0)
+    d = jax.ShapeDtypeStruct((1,), jnp.float32, sharding=rep)
+    text = ssgd.make_train_fn_fused(mesh, cfg, meta).lower(
+        jax.ShapeDtypeStruct((20, 16, 1024), jnp.int32,
+                             sharding=NamedSharding(
+                                 mesh, P("data", None, None))),
+        d, d, d, d,
+        jax.ShapeDtypeStruct(((1 << 14) + 128,), jnp.float32, sharding=rep),
+        t0=0).as_text(debug_info=True)
+    for kernel in ("_hashed_gather_kernel", "_hashed_scatter_kernel",
+                   "_hashed_value_gather_kernel",
+                   "_hashed_value_sums_kernel"):
+        assert kernel in text, kernel
+    assert "_hashed_field_scatter_kernel" not in text
+    assert "_hashed_hbm_gather_kernel" not in text
+
+
 def test_closure_round_compiles_at_grid250_and_holds_two_matrices(one_chip):
     """The byte kernel at the shipped tiles on the padded 63 488 vertices
     (an 8 MB float32 accumulator, the byte tiles and their bfloat16

@@ -466,14 +466,26 @@ def test_spans_report_and_result_say_the_forms(mesh1, small_vmem, tmp_path):
                                              2000, 2048 * 64)
     assert (prep["fields_dict"], prep["fields_vmem"],
             prep["fields_hbm"]) == (5, 4, 2)
+    # the CPU: both fields in HBM keep XLA's scatter-add
+    assert (prep["fields_hbm_scatter_vmem"],
+            prep["fields_hbm_scatter_xla"]) == (0, 2)
     seg = ends["train:segment"]
     assert (seg["row_format"], seg["gather_form"], seg["scatter_form"],
-            seg["fields_hbm"]) == ("indexed", "fields", "fields", 2)
+            seg["fields_hbm"], seg["fields_hbm_scatter_vmem"],
+            seg["fields_hbm_scatter_xla"]) == (
+                "indexed", "fields", "fields", 2, 0, 2)
+    said = [(e["kernel"], e["form"], e["field"], e["range_slots"])
+            for e in evts if e["ev"] == "ssgd:field_scatter"]
+    assert set(said) == {("xla scatter-add", "xla", 5, 9000),
+                         ("xla scatter-add", "xla", 9, 7000)}
     lines = report.render(report.summarize(evts)).splitlines()
     for line in ("row format: indexed", "gather pass: fields",
                  "scatter pass: fields",
                  "fields by value: 5 (477 values), by address: 4, in "
-                 "HBM: 2 (a table of 0.1 MB)",
+                 "HBM: 2 (a table of 0.1 MB; the sums of 0 in VMEM a "
+                 "call, of 2 through XLA)",
+                 "field scatter: xla scatter-add (xla) over field 5, a "
+                 "range of 9000 slots",
                  "by-address call: _hashed_gather_kernel over fields "
                  "[1, 6]: 16 rows a trip (32 pairs), 16 index rows a "
                  "chunk in SMEM",

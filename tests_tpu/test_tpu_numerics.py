@@ -1100,8 +1100,9 @@ def test_hashed_passes_at_the_published_widths(tpu_mesh):
 def test_indexed_passes_against_the_plain_reference(tpu_mesh):
     """The compiled steps of SSGD over indexed rows (eleven fields in
     the benchmark's order, 18.0M weights: three fields by value, six by
-    address in three groups, two ranges of 5M and 4.5M slots in HBM
-    under XLA's gather and scatter-add) against the benchmark's plain
+    address in three groups, two ranges of 5M and 4.5M slots in HBM,
+    gathered a row a DMA and each summed in one accumulator of its
+    range in VMEM) against the benchmark's plain
     reference (``benchmarks/reference/ssgd_indexed_ref.py``: no table,
     the rows regenerated, one flat ``w[idx]`` and ``.at[idx].add``) over
     two calls of three steps: every weight and the bias to float32
@@ -1149,6 +1150,87 @@ def test_indexed_passes_against_the_plain_reference(tpu_mesh):
         print(f"[indexed] call {k + 1}: program against reference "
               f"{err:.3g}, bfloat16 control {ctl:.3g}")
         assert err < 6e-5 < ctl
+
+
+def test_field_scatter_at_the_wide_cells_two_ranges(tpu_mesh):
+    """``_hashed_field_scatter_kernel`` at ``lrwide11_150m_frac01``'s two
+    id fields (ranges of 24 296 581 and 21 913 244 slots, each in two
+    pieces of an accumulator of 2^17 rows, 67.1 MB, in VMEM: one call of
+    four phases) over 183 sampled blocks of 8192 rows of
+    the loader's seeded table: each field's sums against XLA's
+    scatter-add and both against float64 on the host, in norm (the
+    hottest query id holds a tenth of the rows: a serial float32 sum in
+    either form); then two calls of four steps of the trainer, the
+    kernel in it, against the benchmark's plain reference under the
+    cell's limit."""
+    import os
+    import sys
+
+    from tpu_distalg.ops import pallas_hashed as ph
+    from tpu_distalg.parallel import get_mesh
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import ssgd_indexed_ref as ref_mod
+
+    cards = (24323, 594098, 13745, 3, 3, 24296581, 1157062, 3750862,
+             2936510, 21913244, 21)
+    n_blocks, ns = 366, 183
+    c = dict(n_rows=n_blocks * 8192, nnz=11, n_features=sum(cards),
+             gather_block_rows=8192, eta=0.1, field_cardinalities=cards,
+             zipf_exponent=1.1, planted_scale=0.25, click_rate=0.0349)
+    mesh = get_mesh(data=1, devices=jax.devices()[:1])
+    cfg = ssgd.SSGDConfig(
+        n_iterations=4, eta=0.1, lam=0.0, mini_batch_fraction=0.5,
+        seed=42, eval_test=False, sampler="fused_gather",
+        gather_block_rows=8192)
+    fn, X, w, meta = ssgd.prepare_hashed_synthetic(
+        c["n_rows"], 11, 0, mesh, cfg, data_seed=59, cardinalities=cards,
+        row_format="indexed", zipf_exponent=1.1, planted_scale=0.25,
+        click_rate=0.0349)
+    geom = ssgd.hashed_geometry(cfg, meta)
+    plan = ssgd.hashed_field_plan(cfg, meta)
+    assert plan.hbm_fields == (5, 9)
+    key = jax.random.key(59)
+    ids = jnp.sort(jax.random.permutation(key, n_blocks)[:ns]).astype(
+        jnp.int32)
+    r = jax.random.normal(jax.random.fold_in(key, 1), (ns, 8192))
+    r64 = np.asarray(r, np.float64).reshape(-1)
+    assert ph.field_phases(geom, (5, 9))[0] == 1 << 17
+    sums = jax.jit(lambda X, r, ids: ph.slot_sums_fields(
+        X, r, ids, geom, (5, 9)))(X, r, ids)
+    for f, got in zip(plan.hbm_fields, sums):
+        lo, hi = geom.offsets[f], geom.offsets[f + 1]
+        assert ph.field_scatter_form(hi - lo, True) == "vmem"
+        xla = jax.jit(lambda X, r, ids, f=f: ph.slot_sums_hbm(
+            X, r, ids, geom, (f,)))(X, r, ids)[lo:hi]
+        slots = np.asarray(X[ids][:, f, :]).reshape(-1) - lo
+        want = np.bincount(slots, weights=r64, minlength=hi - lo)
+        scale = np.linalg.norm(want)
+        errs = [float(np.linalg.norm(np.asarray(g, np.float64) - want)
+                      / scale) for g in (got, xla)]
+        both = float(jnp.linalg.norm(got - xla) / jnp.linalg.norm(xla))
+        print(f"[field scatter] field {f}: range {hi - lo}, hottest slot "
+              f"{int(np.bincount(slots).max())} of {slots.size} pairs; "
+              f"off float64 in norm {errs[0]:.3g} (XLA's {errs[1]:.3g}), "
+              f"off XLA's {both:.3g}")
+        assert errs[0] < 1e-5 and both < 1e-5
+    d = jnp.zeros((1,), jnp.float32)
+    got = []
+    for call in range(2):
+        w, _ = fn(X, d, d, d, d, w, t0=4 * call)
+        got.append(ref_mod.model_vector(w, c["n_features"]))
+    ref = ref_mod.Reference(config=c, fraction=0.5, data_seed=59,
+                            sample_seed=42)
+    w0 = np.zeros((c["n_features"] + 1,), np.float32)
+    good = ref.follow(2, 4)
+    for k in range(2):
+        err = ref_mod.rel_err(got[k], good[k], w0)
+        print(f"[field scatter] call {k + 1}: 4 steps against the "
+              f"reference {err:.3g}")
+        assert err < 6e-5
 
 
 def test_pairs_passes_against_the_plain_reference(tpu_mesh):

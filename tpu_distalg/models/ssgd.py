@@ -1864,17 +1864,25 @@ def hashed_field_plan(config: SSGDConfig, meta: dict):
                                     meta.get("dictionaries"))
 
 
-def _hashed_fields(config: SSGDConfig, meta: dict) -> dict:
+def _hashed_fields(config: SSGDConfig, meta: dict, mesh: Mesh) -> dict:
     """What the spans of a hashed or indexed run say (``tda report``
     prints it): the format, the table's bytes, the passes' form and how
     many fields take each form (by value, by address in VMEM, in HBM;
-    an ``xla`` pass reads every field the same way and counts none)."""
+    an ``xla`` pass reads every field the same way and counts none),
+    and of the fields in HBM how many scatter into an accumulator of
+    their whole range in VMEM on ``mesh`` and how many through XLA
+    (``pallas_hashed.field_scatter_form``)."""
+    from tpu_distalg.ops import pallas_hashed
+
     geom = hashed_geometry(config, meta)
     form = geom.pass_form
     plan = hashed_field_plan(config, meta)
     n_dict = len(plan.dict_fields) if plan else 0
     n_hbm = len(plan.hbm_fields) if plan else 0
     n_addr = 0 if form == "xla" else meta["nnz"] - n_dict - n_hbm
+    on_tpu = mesh_on_tpu(mesh)
+    scatter = [pallas_hashed.field_scatter_form(geom.field_sizes[f], on_tpu)
+               for f in (plan.hbm_fields if plan else ())]
     return {"row_format": meta["row_format"], "nnz": meta["nnz"],
             "hash_bits": meta["hash_bits"],
             "table_bytes": 4 * geom.n_slots, "gather_form": form,
@@ -1882,7 +1890,9 @@ def _hashed_fields(config: SSGDConfig, meta: dict) -> dict:
             "addr_fields": meta["nnz"] - n_dict - n_hbm,
             "dict_values": plan.n_values if plan else 0,
             "fields_dict": n_dict, "fields_vmem": n_addr,
-            "fields_hbm": n_hbm}
+            "fields_hbm": n_hbm,
+            "fields_hbm_scatter_vmem": scatter.count("vmem"),
+            "fields_hbm_scatter_xla": scatter.count("xla")}
 
 
 def describe_forms(config: SSGDConfig, meta: dict) -> str:
@@ -2065,7 +2075,7 @@ def build_hashed_table(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
     devices = mesh.local_devices
     with tevents.span("ssgd:prepare", devices, rows=n_rows,
                       bytes=meta["n_padded"] * geom.row_bytes,
-                      **_hashed_fields(config, meta)):
+                      **_hashed_fields(config, meta, mesh)):
         with tevents.span("ssgd:generate", devices,
                           rows=meta["n_padded"]):
             X = hashed_table_fn(mesh, n_rows, meta["n_padded"], geom,
@@ -2154,7 +2164,7 @@ def train_hashed(n_rows: int, nnz: int, hash_bits: int, mesh: Mesh,
         n_rows, nnz, hash_bits, mesh, config, data_seed=data_seed,
         cardinalities=cardinalities, row_format=row_format)
     w, accs = run_index_rows(
-        fn, X, w0, meta, mesh, config, _hashed_fields(config, meta),
+        fn, X, w0, meta, mesh, config, _hashed_fields(config, meta, mesh),
         tag=f"ssgd:{row_format}:{nnz}x{hash_bits or meta['d_total']}",
         what="SSGD (hashed) weights", checkpoint_dir=checkpoint_dir,
         checkpoint_every=checkpoint_every)

@@ -101,10 +101,54 @@ field's range. :func:`field_plan` makes the choice for a table.
           head: a pair costs the same whatever the skew (on one v5e at
           KDD Cup 2012's shape 32.6 ms a step for two fields of 1.5M
           rows where XLA's ``w[idx]`` takes 52.6, the same float32
-          weights bit for bit: PR 47's Step 0). The
-          scatter is XLA's ``zeros.at[idx].add`` over those fields'
-          pairs (30.8 ms): a read-modify-write of HBM rows by DMA has no
-          order between two copies to one row.
+          weights bit for bit: PR 47's Step 0). A read-modify-write
+          of HBM rows by DMA has no order between two copies to one
+          row, so the scatter does not mirror it. Its form is a field's
+          own, :func:`field_scatter_form` from the field's range and the
+          platform alone:
+          ``vmem``  on a TPU, a range that goes in ``FIELD_MAX_PIECES``
+                    pieces of ``FIELD_PIECE_ROWS`` rows of 128 lanes or
+                    fewer: ``_hashed_field_scatter_kernel``, ONE call
+                    for all such fields, whose grid's first axis runs
+                    *phases*, a (field, piece) each, over the sampled
+                    blocks. The ONE float32 accumulator is a VMEM
+                    scratch with no ``BlockSpec`` (no second buffer) of
+                    a power of two of rows (the widest field's, at most
+                    ``FIELD_PIECE_ROWS``: 67.1 MB), zeroed at a phase's
+                    first grid step and copied to HBM once at its last;
+                    with ``rel = (h >> 7) - first`` (``first`` the row
+                    of the model vector the range starts in) a pair
+                    adds its row's residual at lane ``h & 127`` of row
+                    ``rel & (rows - 1)`` where ``rel >> log2(rows)`` is
+                    the phase's piece and 0.0 where it is not, so a
+                    phase visits all of its field's pairs and a range
+                    of two pieces costs two visits a pair. The ranges
+                    are cut from the pieces' copies and laid into the
+                    sums. No head, no count, no sort: a pair costs the
+                    same whatever the skew, and every addend of a step
+                    is in the sums. An accumulator of a field's WHOLE
+                    range was built first and does not run: at 87.7 and
+                    97.2 MB (KDD Cup 2012's user and query ids; 97.1 and
+                    106.6 MB asked of a core's 128 MiB, which the
+                    chip's compiler grants) the call never came back on
+                    one v5e (PR 59, call 1), where 75.2 MB had run
+                    (PR 56); a piece is a size that runs. On one v5e at
+                    KDD Cup 2012's shape (PR 59's Step 0,
+                    ``scripts/step0_indexed.py --field-scatter``, ms a
+                    call over 1.5M pairs with the residuals' 768 MB of
+                    lanes made in it) a field of two pieces read 11.4 /
+                    11.5 ms seeded and 11.0 / 11.1 flat where XLA's
+                    form takes 16.2 to 16.3, a field of one piece 6.3
+                    to 6.7 (3.4 ns a visit; 4.13 bundles by the static
+                    schedule); in the cell the two id fields' sums fell
+                    from 29.9 to 19.7 ms a step, the same float32 sums
+                    to 4e-6 of float64 in norm (XLA's 3e-6).
+          ``xla``   ``zeros.at[idx].add`` over the pairs of the fields
+                    that are left (:func:`slot_sums_hbm`; 9.9 ns a pair
+                    with XLA's sort, ledger PR 57): the CPU, where it is
+                    also the tests' oracle, and a range of more pieces
+                    (past 33.5M slots), whose every piece would visit
+                    every pair.
 ``dict``  by value: ``_hashed_rows_kernel`` copies the field's rows of
           the sampled blocks from one sublane of a block's tiles to
           whole vectors of 1024 rows, and for every entry ``d`` of the
@@ -162,6 +206,17 @@ ACC_VMEM_BYTES = 64 << 20   # ... and what they and their second buffers
 #                             may take of VMEM: 4 up to 2**21 slots, 2 at
 #                             2**22
 VMEM_BITS = 22         # a table of 16 MB and its accumulators fit VMEM
+FIELD_PIECE_ROWS = 1 << 17   # rows of 128 lanes of the ONE accumulator the
+#                              scatter of the fields past that keeps in
+#                              VMEM: 67.1 MB, a size that runs on a v5e
+#                              core (the module docstring says what did
+#                              not); a power of two, so that a row's place
+#                              in its piece is a mask away
+FIELD_MAX_PIECES = 2   # pieces a field's range may go in: every piece
+#                        visits all of the field's pairs
+FIELD_RUN = 4          # rows of that accumulator loaded before any of them
+#                        is stored
+ZERO_ROWS = 32         # rows of it a trip of the loop that zeroes it covers
 HBM_TRIP = 16          # rows whose copies from a table in HBM a loop trip
 #                        starts, and a wait lands
 MIN_BITS = 10          # one (8, 128) tile of slots
@@ -214,6 +269,31 @@ def field_form(n_values: int, block_rows: int,
     if range_slots is not None and range_slots > 1 << VMEM_BITS:
         return "hbm"
     return "addr"
+
+
+def field_scatter_form(range_slots: int, on_tpu: bool) -> str:
+    """How the sums of one ``'hbm'`` field (:func:`field_form`) are
+    added up: ``'vmem'`` (``_hashed_field_scatter_kernel``: an
+    accumulator in VMEM that holds the field's range a piece of
+    ``FIELD_PIECE_ROWS`` rows at a time) on a TPU where the range goes
+    in ``FIELD_MAX_PIECES`` pieces or fewer, else ``'xla'``
+    (:func:`slot_sums_hbm`: the CPU, where it is also the tests'
+    oracle; a range of more pieces, whose every piece would visit
+    every pair). The field's gather is ``_hashed_hbm_gather_kernel``
+    either way."""
+    return "vmem" if on_tpu and field_pieces(range_slots) \
+        <= FIELD_MAX_PIECES else "xla"
+
+
+def _field_rows(range_slots: int) -> int:
+    """Rows of 128 lanes of the model vector that a field's range
+    touches, wherever in a row it starts."""
+    return -(-range_slots // LANES) + 1
+
+
+def field_pieces(range_slots: int) -> int:
+    """Pieces of ``FIELD_PIECE_ROWS`` rows a field's range goes in."""
+    return -(-_field_rows(range_slots) // FIELD_PIECE_ROWS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -732,6 +812,166 @@ def slot_sums_vmem(X, r, ids, geom: HashedGeometry, *,
     return jnp.concatenate([g, tail])
 
 
+def _hashed_field_scatter_kernel(ids_ref, phase_ref, idx_ref, rb_ref,
+                                 out_hbm, acc_ref, sem):
+    """One chunk of one sampled block in one *phase* (the grid's first
+    axis; ``phase_ref`` holds a field, the model vector's row its range
+    starts in, and a piece for each): the field's pairs whose row lies
+    in the piece add their row's residual into the ONE accumulator,
+    which stays in VMEM over the phase: at lane ``h & 127`` of row
+    ``rel & (rows - 1)`` where ``rel = (h >> 7) - first`` and the piece
+    is ``rel >> log2(rows)``; a pair of another piece adds 0.0 there.
+    Zeroed at a phase's first step, copied to ``out[phase]`` at its last.
+
+    A load waits 7 bundles for the last store to its allocation, and
+    there is one allocation: a run of ``FIELD_RUN`` rows is loaded
+    before any of it is stored, so a later pair of the run that lands
+    in an earlier one's row takes that one's addend with it and the
+    last store to a row holds every addend of the run once
+    (``pallas_pairs._pairs_scatter_kernel``'s device). The addends are
+    summed before the loads land, so a link of the chain is four
+    loads, one add, four stores and the wait. The chunk is one basic
+    block (the run traced once and written out again at lowering, its
+    index a constant in every copy): a pair's place in SMEM and its
+    residual's in VMEM are constants, where a loop over the chunk's
+    rows paid five scalar operations a pair for the SMEM tile address
+    alone (3.9 bundles a pair by the static schedule against 5.4; the
+    two scalar slots were the bound)."""
+    del ids_ref
+    rows = acc_ref.shape[0]              # a power of two
+    k = pl.program_id(0)
+    field, first, piece = (phase_ref[3 * k], phase_ref[3 * k + 1],
+                           phase_ref[3 * k + 2])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _zero():
+        def some(i, _):
+            at = pl.ds(pl.multiple_of(i * ZERO_ROWS, ZERO_ROWS), ZERO_ROWS)
+            acc_ref[at, :] = jnp.zeros((ZERO_ROWS, LANES), jnp.float32)
+            return 0
+
+        jax.lax.fori_loop(0, rows // ZERO_ROWS, some, 0)
+
+    def splat(x):
+        return jax.lax.broadcast_in_dim(jnp.int32(x), (1, LANES), ())
+
+    zero = jnp.zeros((1, LANES), jnp.float32)
+    low, seven, last = splat(LANES - 1), splat(7), splat(rows - 1)
+    bits, vfirst, vpiece = splat(rows.bit_length() - 1), splat(first), \
+        splat(piece)
+
+    def run(v, carry):
+        at = [v * FIELD_RUN + u for u in range(FIELD_RUN)]
+        hs = [idx_ref[field, i] for i in at]
+        # the lane, the piece and the compares of the run's rows on the
+        # vector side: a VALU slot is free where a scalar slot is not
+        # (``jax.lax`` by name: the run is lowered a chunk's 64 times
+        # on every run of the program, and a ``jnp.where`` is a nested
+        # call each time)
+        vhs = [splat(h) for h in hs]
+        rels = [jax.lax.shift_right_arithmetic(vh, seven) - vfirst
+                for vh in vhs]
+        adds = [jax.lax.select(
+            (lane == jax.lax.bitwise_and(vh, low))
+            & (jax.lax.shift_right_arithmetic(rel, bits) == vpiece),
+            rb_ref[pl.ds(i, 1), :], zero)
+            for vh, rel, i in zip(vhs, rels, at)]
+        vrows = [jax.lax.bitwise_and(rel, last) for rel in rels]
+        sums = list(adds)
+        for n in range(1, FIELD_RUN):
+            for m in range(n):
+                sums[n] = sums[n] + jax.lax.select(
+                    vrows[m] == vrows[n], adds[m], zero)
+        where = [((h >> 7) - first) & (rows - 1) for h in hs]
+        olds = [acc_ref[pl.ds(row, 1), :] for row in where]
+        for row, old, add in zip(where, olds, sums):
+            acc_ref[pl.ds(row, 1), :] = old + add
+        return carry
+
+    jax.lax.fori_loop(0, idx_ref.shape[1] // FIELD_RUN, run, 0,
+                      unroll=True)
+
+    @pl.when((pl.program_id(1) == pl.num_programs(1) - 1)
+             & (pl.program_id(2) == pl.num_programs(2) - 1))
+    def _store():
+        copy = pltpu.make_async_copy(acc_ref, out_hbm.at[k], sem)
+        copy.start()
+        copy.wait()
+
+
+def field_phases(geom: HashedGeometry, fields) -> tuple:
+    """``(acc_rows, phases)`` of one call of the field scatter over
+    ``fields``: the accumulator's rows (a power of two, the widest
+    field's or ``FIELD_PIECE_ROWS``) and a ``(field, first row, piece)``
+    for every piece of every field's range."""
+    off = geom.offsets
+    widest = max(_field_rows(off[f + 1] - off[f]) for f in fields)
+    rows = min(FIELD_PIECE_ROWS,
+               max(ZERO_ROWS, 1 << (widest - 1).bit_length()))
+    return rows, tuple(
+        (f, off[f] >> 7, p) for f in fields
+        for p in range(-(-_field_rows(off[f + 1] - off[f]) // rows)))
+
+
+def slot_sums_fields(X, r, ids, geom: HashedGeometry, fields, *,
+                     interpret: bool = False) -> list:
+    """``[f32[range] for each of fields]``: the per-slot sums of
+    indexed fields whose ranges are past ``2 ** VMEM_BITS``, each in
+    its range's own order: ONE call whose grid runs every piece of
+    every field's range over the sampled blocks in turn, one
+    accumulator in VMEM (a scratch with no ``BlockSpec``, hence no
+    second buffer; a copy out a piece). No head, no count, no sort: a
+    pair costs the same whatever the skew."""
+    cr = geom.chunk_rows
+    fields = tuple(fields)
+    if cr % FIELD_RUN:
+        raise ValueError(f"a chunk of {cr} rows is not whole runs of "
+                         f"{FIELD_RUN}")
+    rows, phases = field_phases(geom, fields)
+    off = geom.offsets
+    pieces = {f: sum(ph[0] == f for ph in phases) for f in fields}
+    # the accumulator, a chunk's residuals and room
+    vmem_bytes = _vmem_limit(geom, 1, rows * LANES)
+    for f in fields:
+        tevents.emit("ssgd:field_scatter",
+                     kernel="_hashed_field_scatter_kernel", form="vmem",
+                     field=f, range_slots=off[f + 1] - off[f],
+                     pieces=pieces[f], vmem_bytes=vmem_bytes)
+    rb = jnp.broadcast_to(r[:, :, None], r.shape + (LANES,))
+    with jax.named_scope(names.SSGD_TABLE_HBM):
+        acc = pl.pallas_call(
+            _hashed_field_scatter_kernel,
+            name="_hashed_field_scatter_kernel",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,          # ids, phases
+                grid=(len(phases), ids.shape[0], geom.block_rows // cr),
+                in_specs=[
+                    pl.BlockSpec((None, geom.fields_held, cr),
+                                 lambda k, s, c, ids, ph: (ids[s], 0, c),
+                                 memory_space=pltpu.SMEM),
+                    pl.BlockSpec((None, cr, LANES),
+                                 lambda k, s, c, ids, ph: (s, c, 0))],
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.float32),
+                                pltpu.SemaphoreType.DMA(())]),
+            out_shape=jax.ShapeDtypeStruct((len(phases), rows, LANES),
+                                           jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                # the accumulator lives across a phase
+                dimension_semantics=("arbitrary",) * 3,
+                vmem_limit_bytes=vmem_bytes),
+            interpret=interpret,
+        )(ids, jnp.asarray(phases, jnp.int32).reshape(-1), X, rb)
+        out, at = [], 0
+        for f in fields:
+            lo, hi = off[f], off[f + 1]
+            out.append(acc[at:at + pieces[f]].reshape(-1)[
+                lo % LANES:lo % LANES + hi - lo])
+            at += pieces[f]
+        return out
+
+
 # ---- by value: the fields whose dictionaries the loader states -----------
 
 def _hashed_value_gather_kernel(gf_ref, d_ref, wd_ref, x_ref, out_ref, *,
@@ -979,14 +1219,32 @@ def slot_sums(X, r, ids, geom: HashedGeometry, *,
 
 
 def _slot_sums_fields(X, r, ids, geom, plan, interpret):
-    """An indexed table's sums: the fields in HBM scattered into the
-    zeroed vector, each group's table added back range by range, the
+    """An indexed table's sums: the fields in HBM whose ranges go in
+    too many pieces scattered into the zeroed vector by XLA, the
+    others' sums (``_hashed_field_scatter_kernel``, one call) laid at
+    their ranges, each group's table added back range by range, the
     by-value entries added at their slots, the residuals' sum where the
     bias is."""
-    if plan.hbm_fields:
-        g = slot_sums_hbm(X, r, ids, geom, plan.hbm_fields)
+    off = geom.offsets
+    # a mesh's platform reaches here as the trainer's ``interpret``
+    forms = {f: field_scatter_form(off[f + 1] - off[f], not interpret)
+             for f in plan.hbm_fields}
+    by_xla = tuple(f for f in plan.hbm_fields if forms[f] == "xla")
+    in_vmem = tuple(f for f in plan.hbm_fields if forms[f] == "vmem")
+    for f in by_xla:
+        tevents.emit("ssgd:field_scatter", kernel="xla scatter-add",
+                     form="xla", field=f, range_slots=off[f + 1] - off[f],
+                     pieces=0, vmem_bytes=0)
+    if by_xla:
+        g = slot_sums_hbm(X, r, ids, geom, by_xla)
     else:
         g = jnp.zeros((geom.w_len,), jnp.float32)
+    if in_vmem:
+        sums = slot_sums_fields(X, r, ids, geom, in_vmem,
+                                interpret=interpret)
+        with jax.named_scope(names.SSGD_TABLE_HBM):
+            for f, at in zip(in_vmem, sums):
+                g = g.at[off[f]:off[f + 1]].set(at)
     for group in plan.addr_groups:
         acc = slot_sums_vmem(X, r, ids, geom, interpret=interpret,
                              group=group)

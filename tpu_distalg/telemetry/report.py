@@ -221,6 +221,7 @@ def summarize(evts: list[dict]) -> dict:
     field_splits: list[tuple] = []
     addr_calls: list[tuple] = []
     pairs_passes: list[tuple] = []
+    field_scatters: list[tuple] = []
     als_forms: list[str] = []
     ranks_forms: list[str] = []
     closure_forms: list[str] = []
@@ -310,7 +311,9 @@ def summarize(evts: list[dict]) -> dict:
                     # an indexed table: each field its own form, the
                     # ranges past VMEM left in HBM (field_form 'hbm')
                     split += (e.get("fields_hbm", 0),
-                              e.get("table_bytes", 0))
+                              e.get("table_bytes", 0),
+                              e.get("fields_hbm_scatter_vmem", 0),
+                              e.get("fields_hbm_scatter_xla", 0))
                 if split not in field_splits:
                     field_splits.append(split)
             # and what a table of ragged (feature, value) rows holds
@@ -382,6 +385,14 @@ def summarize(evts: list[dict]) -> dict:
                     e.get("rows"), e.get("pairs"), e.get("smem_rows"))
             if call not in addr_calls:
                 addr_calls.append(call)
+        elif ev == "ssgd:field_scatter":
+            # which form the sums of one field in HBM take, said when
+            # the step is traced (pallas_hashed._slot_sums_fields)
+            call = (e.get("kernel", "?"), e.get("form", "?"),
+                    e.get("field"), e.get("range_slots", 0),
+                    e.get("pieces", 0), e.get("vmem_bytes", 0))
+            if call not in field_scatters:
+                field_scatters.append(call)
         elif ev == "ssgd:pairs_pass":
             # what one pass over rows of (feature, value) pairs runs,
             # said when it is traced (ops/pairs.py, ops/pallas_pairs.py)
@@ -459,6 +470,7 @@ def summarize(evts: list[dict]) -> dict:
         "field_splits": field_splits,
         "addr_calls": addr_calls,
         "pairs_passes": pairs_passes,
+        "field_scatters": field_scatters,
         "als_forms": als_forms,
         "ranks_forms": ranks_forms,
         "closure_forms": closure_forms,
@@ -568,7 +580,9 @@ def render(s: dict) -> str:
                 f"by address: {n_addr}")
         if indexed:
             line += (f", in HBM: {indexed[0]} (a table of "
-                     f"{indexed[1] / 1e6:.1f} MB)")
+                     f"{indexed[1] / 1e6:.1f} MB; the sums of "
+                     f"{indexed[2]} in VMEM a call, of {indexed[3]} "
+                     f"through XLA)")
         lines.append(line)
     for (rows, pairs, slots, longest, used, blocks, block_slots, table,
          rowsum, form) in s.get("pair_tables") or ():
@@ -590,6 +604,14 @@ def render(s: dict) -> str:
         lines.append(f"by-address call: {kernel} over fields "
                      f"{list(fields)}: {rows} rows a trip ({pairs} pairs), "
                      f"{smem_rows} index rows a chunk in SMEM")
+    for kernel, form, field, slots, pieces, vmem in \
+            s.get("field_scatters") or ():
+        line = (f"field scatter: {kernel} ({form}) over field {field}, a "
+                f"range of {slots} slots")
+        if form == "vmem":
+            line += (f" in {pieces} piece(s) of an accumulator in VMEM, "
+                     f"{vmem / 1e6:.1f} MB asked")
+        lines.append(line)
     if s.get("als_forms"):
         lines.append(f"R layout: {', '.join(s['als_forms'])}")
     if s.get("ranks_forms"):
