@@ -1053,10 +1053,13 @@ def stage_closure(s: Smoke):
     side 63 (4096 vertices, 8064 arcs, 126 across: 8 doubling rounds),
     labels permuted, against the generator's closed form; on one chip
     the round is the Mosaic byte kernel (``ops/pallas_closure.py``), on
-    several XLA's product of row-sharded paths. Sparse, which had never
-    compiled for a chip either: the grid at side 20 (441 vertices, 40
-    linear rounds) through ``run_sparse``, its pair set against the
-    dense form's matrix, cell for cell."""
+    several XLA's product of row-sharded paths. The pair-set form's
+    semi-naive round (one chip's worth, whatever the mesh): the grid at
+    side 50 (2 601 vertices, 1 755 675 pairs, 100 linear rounds, many
+    derivations a pair) through ``run_sparse``, its pair set against the
+    dense form's matrix, cell for cell, and the closed form; and a tree
+    of height 11 (69 398 vertices, 783 991 pairs, 12 rounds) to its
+    fixpoint as ``tda closure --tree-height`` runs it."""
     import numpy as np
 
     from tpu_distalg.models import transitive_closure as tc
@@ -1079,26 +1082,37 @@ def stage_closure(s: Smoke):
     if (s.n == 1) != (False in s.spy.built.get(kernel, ())):
         raise AssertionError(
             f"{kernel} compiled: {s.spy.built.get(kernel)} on {s.n} chip(s)")
-    small = datasets.grid_edges(20, 3)
-    v = 21 * 21
+    side_s, v = 50, 51 * 51
+    small = datasets.grid_edges(side_s, 3)
     dense = tc.run(small, mesh, n_vertices=v)
     t0 = time.perf_counter()
-    sparse = tc.run_sparse(small, mesh,
-                           tc.SparseClosureConfig(capacity=1 << 16),
-                           n_vertices=v)
+    sparse = tc.run_sparse(small, mesh, tc.SparseClosureConfig(
+        capacity=1 << 21, delta_capacity=1 << 16, join_capacity=1 << 17),
+        n_vertices=v)
     t_sparse = time.perf_counter() - t0
     got = np.zeros((v, v), bool)
     got[sparse.paths[:, 0], sparse.paths[:, 1]] = True
-    if sparse.n_paths != dense.n_paths != datasets.grid_closure_pairs(20) \
+    if not (sparse.n_paths == dense.n_paths
+            == datasets.grid_closure_pairs(side_s)) \
+            or sparse.n_rounds != 2 * side_s \
             or not np.array_equal(got, np.asarray(dense.paths)[:v, :v]):
         raise AssertionError(
             f"sparse {sparse.n_paths} pairs in {sparse.n_rounds} rounds, "
             f"dense {dense.n_paths} in {dense.n_rounds}")
+    t0 = time.perf_counter()
+    out = s.cli(["closure", "--tree-height", "11", "--seed", "5"])
+    t_tree = time.perf_counter() - t0
+    tree_pairs = datasets.tree_closure_pairs(11)
+    if f"has {tree_pairs} paths (12 rounds)" not in out \
+            or "[closure] sparse:" not in out \
+            or "pairs (equal)" not in out:
+        raise AssertionError(f"pair-set closure of Tree11: {out!r}")
     return (f"dp={mesh.shape['data']} | dense Grid{side}: {pairs} pairs, 8 "
             f"rounds, compose {geom.form}, padded {geom.v_padded}, "
-            f"{t_dense:.1f}s | sparse Grid20: {sparse.n_paths} pairs in "
-            f"{sparse.n_rounds} rounds = dense ({dense.n_rounds} rounds), "
-            f"{t_sparse:.1f}s")
+            f"{t_dense:.1f}s | sparse Grid{side_s}: {sparse.n_paths} pairs "
+            f"in {sparse.n_rounds} rounds = dense ({dense.n_rounds} "
+            f"rounds), {t_sparse:.1f}s | Tree11: {tree_pairs} pairs in 12 "
+            f"rounds, {t_tree:.1f}s")
 
 
 def _comm_stage(s: Smoke, comm: str):

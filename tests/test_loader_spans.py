@@ -644,8 +644,10 @@ def test_the_manifest_lists_the_new_metrics_where_the_issue_says():
     lr = ["lrhash39_46m_frac01", "lrwide11_150m_frac01", pairs]
     # the five loader cells of PR 49, the dense closure's, which PR 52
     # appended to the three lists its loader's spans feed, and PR 54's
-    # cell of ragged rows, appended to those and to ``generate_s.lr``
-    five = [lr[0], *als, *graph, lr[1], "closure_grid250_round1", pairs]
+    # cell of ragged rows, appended to those and to ``generate_s.lr``,
+    # and PR 60's pair-set closure, appended to the same three
+    five = [lr[0], *als, *graph, lr[1], "closure_grid250_round1", pairs,
+            "closure_tree17_round1"]
     want = {"pack_s.als": als, "generate_s.als": als, "heldout_s.als": als,
             "lists_s.als": als, "generate_s.graph": graph,
             "dedup_s.graph": graph, "generate_s.lr": lr,

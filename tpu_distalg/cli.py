@@ -467,8 +467,9 @@ def main(argv=None):
     p = sub.add_parser(
         "closure",
         help="transitive closure: a V x V byte matrix whose round "
-             "doubles the path length (dense), or a sorted pair "
-             "buffer joined with the edges a round (--sparse)")
+             "doubles the path length (dense), or a sorted set of "
+             "pairs whose round joins the pairs the last round found "
+             "new with the arcs (--sparse, --tree-height)")
     p.add_argument("--n-slices", type=int, default=0)
     _add_mesh_shape(p)
     p.add_argument("--n-vertices", type=int, default=0)
@@ -479,12 +480,27 @@ def main(argv=None):
                         "picked from the bytes each would hold "
                         "(Grid250: 63 001 vertices, 1 000 140 875 "
                         "pairs, dense)")
+    p.add_argument("--tree-height", type=int, default=0,
+                   help="close BigDatalog's Tree<N> (SIGMOD'16, Table "
+                        "2): a tree of N + 2 levels whose non-leaf "
+                        "vertices have 2 to 6 children, its shape and "
+                        "labels drawn from --seed; the form is picked "
+                        "from the bytes each would hold and the three "
+                        "buffers sized from the tree's closed form "
+                        "(Tree17: 13 766 856 vertices, 237 977 708 "
+                        "pairs: the pair set, 18 rounds)")
     p.add_argument("--seed", type=int, default=0,
-                   help="permutes the grid's vertex labels (--grid-side)")
+                   help="permutes the grid's vertex labels (--grid-side);"
+                        " draws the tree's shape and labels "
+                        "(--tree-height)")
     p.add_argument("--sparse", action="store_true",
-                   help="sort-dedup path-set closure (O(closure) memory "
-                        "— what a matrix of V x V bytes cannot hold; "
-                        "its round is the reference's linear join). "
+                   help="the pair-set closure (O(closure) memory: what "
+                        "a matrix of V x V bytes cannot hold): the set "
+                        "as a sorted buffer of (x, z) pairs, a round "
+                        "semi-naive: the pairs the round before found "
+                        "new joined with the arcs, one sort of set and "
+                        "candidates, what the set held taken out, until "
+                        "a round finds nothing new. "
                         "NOTE: with "
                         "--n-vertices the generated graph is a chain "
                         "forest, not the dense mode's Erdős–Rényi graph "
@@ -492,7 +508,14 @@ def main(argv=None):
                         "output); results are not comparable across "
                         "modes")
     p.add_argument("--capacity", type=int, default=0,
-                   help="sparse path-buffer capacity; 0 = 8x edges")
+                   help="pairs the pair set can hold (a static shape); "
+                        "0 = 8x edges, or the next power of two over the "
+                        "closed form with --tree-height. A round's new "
+                        "pairs get as many slots and its candidates "
+                        "twice as many (the tree: the next power of two "
+                        "over its arcs each); a closure, or a round, "
+                        "that overflows fails the run, it is never "
+                        "truncated")
     _add_ckpt(p, 8)
 
     p = sub.add_parser("als", help="ALS matrix decomposition")
@@ -1738,8 +1761,19 @@ def _dispatch(args, jax):
         from tpu_distalg.models import transitive_closure as m
         from tpu_distalg.utils import datasets
 
-        pairs_bound = None
-        if args.grid_side:
+        pairs_bound = sparse_config = None
+        if args.tree_height:
+            edges = datasets.tree_edges(args.tree_height, args.seed)
+            pairs_bound = datasets.tree_closure_pairs(args.tree_height)
+            # no round of a tree joins or finds more pairs than it has
+            # arcs; powers of two, so that Tree17's buffers are the ones
+            # the benchmark's configuration states
+            room = 1 << (len(edges) - 1).bit_length()
+            sparse_config = m.SparseClosureConfig(
+                capacity=args.capacity
+                or 1 << (pairs_bound - 1).bit_length(),
+                delta_capacity=room, join_capacity=room)
+        elif args.grid_side:
             edges = datasets.grid_edges(args.grid_side, args.seed)
             pairs_bound = datasets.grid_closure_pairs(args.grid_side)
         elif args.n_vertices == 0:
@@ -1754,24 +1788,30 @@ def _dispatch(args, jax):
 
         mesh = _mesh(args)
         sparse = args.sparse
-        if args.grid_side and not sparse:
+        if pairs_bound is not None and not sparse:
             # the generator knows its answer's size: the form comes from
             # the bytes each would hold (models/transitive_closure.py)
             picked = m.choose_form(
-                int(edges.max()) + 1, len(edges), mesh,
+                len(edges) + 1 if args.tree_height
+                else int(edges.max()) + 1, len(edges), mesh,
                 pairs_bound=pairs_bound)
             sparse = picked["closure_form"] == "sparse"
             print(f"[closure] {picked['closure_form']}: two byte matrices "
-                  f"{picked['dense_bytes'] / 1e9:.3f} GB, a pair buffer "
+                  f"{picked['dense_bytes'] / 1e9:.3f} GB, a pair set "
                   f"{picked['sparse_bytes'] / 1e9:.3f} GB, budget "
                   f"{picked['budget_bytes'] / 1e9:.3f} GB")
         if sparse:
+            if sparse_config is None:
+                sparse_config = m.SparseClosureConfig(
+                    capacity=args.capacity or None)
+
             def run_once():
+                # the pairs stay on the device: a count is what is shown
                 return m.run_sparse(
-                    edges, mesh,
-                    m.SparseClosureConfig(capacity=args.capacity or None),
+                    edges, mesh, sparse_config,
                     checkpoint_dir=args.checkpoint_dir,
-                    checkpoint_every=args.checkpoint_every)
+                    checkpoint_every=args.checkpoint_every,
+                    keep_paths=False)
         else:
             def run_once():
                 return m.run(edges, mesh,
@@ -1782,7 +1822,8 @@ def _dispatch(args, jax):
         print(f"The original graph has {res.n_paths} paths "
               f"({res.n_rounds} rounds)")
         if pairs_bound is not None:
-            print(f"[closure] the grid's closed form: {pairs_bound} pairs"
+            print(f"[closure] the {'tree' if args.tree_height else 'grid'}'s "
+                  f"closed form: {pairs_bound} pairs"
                   f" ({'equal' if res.n_paths == pairs_bound else 'NOT EQUAL'})")
             if res.n_paths != pairs_bound:
                 return 1

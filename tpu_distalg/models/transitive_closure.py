@@ -25,6 +25,8 @@ checkpointed segments and the benchmark's adapter all use those two.
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -258,216 +260,422 @@ def run(edges: np.ndarray, mesh: Mesh,
 
 @dataclasses.dataclass(frozen=True)
 class SparseClosureConfig:
-    """Config for :func:`run_sparse` — the O(closure-size) formulation.
+    """Config for the pair-set form (:func:`run_sparse`).
 
-    ``capacity`` bounds the number of distinct paths the buffer can hold
-    (static shape; auto = 8×edges). ``join_capacity`` bounds the number
-    of (path ⋈ edge) candidates one round may produce (auto =
-    max(2×capacity, 8×edges)); unlike a per-vertex-degree pad this is a
-    bound on the TRUE join size, so skewed degree distributions cost
-    nothing extra. ``max_iterations`` caps the fixpoint (auto = longest
-    possible path, V)."""
+    ``capacity`` bounds the distinct pairs the set can hold (static
+    shape; auto = 8×edges). ``delta_capacity`` bounds the pairs one
+    round may find new (auto = ``capacity``: nothing smaller is known
+    without the graph's answer) and ``join_capacity`` the (δ ⋈ arc)
+    candidates one round may produce, duplicates included (auto =
+    max(2×capacity, 8×edges)); both are bounds on TRUE sizes, so skewed
+    degrees cost nothing extra. A generator that knows its answer says
+    all three (a tree: no round finds more than ``edges`` pairs).
+    ``max_iterations`` caps the fixpoint (auto = longest possible path,
+    V)."""
 
     capacity: int | None = None
     join_capacity: int | None = None
     max_iterations: int | None = None
+    delta_capacity: int | None = None
 
 
 @dataclasses.dataclass
 class SparseClosureResult:
-    paths: np.ndarray  # (n_paths, 2) distinct (x, z) pairs
+    paths: np.ndarray | None  # (n_paths, 2) distinct (x, z) pairs, sorted
     n_paths: int
     n_rounds: int
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseGeometry:
+    """What the pair-set form's entry points are built from: the graph's
+    vertices and arcs and the three static capacities (pairs of the set,
+    pairs a round finds new, candidates a round joins)."""
+
+    n_vertices: int
+    n_edges: int
+    capacity: int
+    delta_capacity: int
+    join_capacity: int
+
+    @property
+    def resident_bytes(self) -> int:
+        """The bytes carried from call to call: the set and δ as two
+        int32 columns each, the arcs by source (degree and offset a
+        vertex, target an arc)."""
+        return (8 * (self.capacity + self.delta_capacity)
+                + 8 * (self.n_vertices + 1) + 4 * (self.n_edges + 1))
+
+    @property
+    def working_bytes(self) -> int:
+        """``SPARSE_BYTES_PER_CAPACITY_SLOT`` over the slots one round
+        sorts (the set and the candidates)."""
+        return SPARSE_BYTES_PER_CAPACITY_SLOT * (
+            self.capacity + self.join_capacity)
+
+
+def sparse_geometry(n_vertices: int, n_edges: int,
+                    config: SparseClosureConfig = SparseClosureConfig()
+                    ) -> SparseGeometry:
+    """The capacities for a graph: the caller's where it says them, else
+    the defaults :class:`SparseClosureConfig` documents."""
+    if n_vertices >= 1 << 30:
+        raise ValueError(f"{n_vertices} vertices: the pair-set form sorts "
+                         f"2 z + tag in an int32 and holds under 2^30")
+    cap = (config.capacity if config.capacity is not None
+           else max(8 * n_edges, 1024))
+    if n_edges > cap:
+        raise ValueError(f"capacity {cap} < edge count {n_edges}")
+    delta = (config.delta_capacity if config.delta_capacity is not None
+             else cap)
+    join = (config.join_capacity if config.join_capacity is not None
+            else max(2 * cap, 8 * n_edges, 1024))
+    if cap + join >= 1 << 31:
+        raise ValueError(f"capacity {cap} + join_capacity {join} slots "
+                         f"are past int32")
+    return SparseGeometry(n_vertices, n_edges, cap, max(delta, n_edges, 1),
+                          join)
+
+
+class SparseState(NamedTuple):
+    """The pair-set form's carried state, all on the device. The set's
+    ``n`` pairs are the slots of ``(sx, sz)`` that do not hold the
+    sentinel vertex V, in (x, z) order (a round leaves a sentinel where
+    a candidate repeated a pair; its next sort moves them to the end);
+    δ's (the pairs the last round found new, the arcs at the start) are
+    ``(dx, dz)[:nd]``, sentinels after them. ``overflow`` says that some
+    round's candidates, new pairs or set did not fit: the state is then
+    worthless and :func:`check_sparse` raises."""
+
+    sx: jax.Array
+    sz: jax.Array
+    dx: jax.Array
+    dz: jax.Array
+    n: jax.Array
+    nd: jax.Array
+    overflow: jax.Array
+
+
+class Arcs(NamedTuple):
+    """The arcs by source on the device: ``deg`` and ``off`` a vertex
+    (the sentinel V included, degree 0), ``dst`` an arc in source order
+    and one sentinel past the last."""
+
+    deg: jax.Array
+    off: jax.Array
+    dst: jax.Array
+
+
+def _repeats(x, z):
+    """Which pairs of a sorted run equal the pair before them."""
+    return jnp.concatenate([
+        jnp.zeros((min(1, x.shape[0]),), bool),
+        (x[1:] == x[:-1]) & (z[1:] == z[:-1])])
+
+
+def sparse_join(state: SparseState, arcs: Arcs, g: SparseGeometry):
+    """δ ⋈ arc by a segmented expand: δ's pair p = (x, y) owns the
+    candidate slots ``[start_p, start_p + deg(y))``, one an arc (y, z),
+    and each holds (x, z). The work follows the TRUE join size: no
+    padding to a largest degree. Returns the candidates (sentinels past
+    the ``K`` joined), ``K`` and whether they overflowed."""
+    V, D, J = g.n_vertices, g.delta_capacity, g.join_capacity
+    k = arcs.deg[state.dz]                       # 0 at a sentinel
+    ends = jnp.cumsum(k)
+    start = ends - k
+    K = ends[-1]
+    # K is int32 and wraps past 2^31: K < 0 catches true sizes in
+    # (2^31, 2^32), the float sum those beyond (compared with 2^31, not
+    # J: its rounding could trip a sound round with K near J)
+    overflow = ((K > J) | (K < 0)
+                | (jnp.sum(k.astype(jnp.float32)) > jnp.float32(2**31)))
+    # slot start_p is marked p + 1 (pairs with arcs only), a running
+    # maximum fills the segment: the owning pair of every slot
+    marks = jnp.zeros((J,), jnp.int32).at[
+        jnp.where(k > 0, start, J)].max(
+            jnp.arange(D, dtype=jnp.int32) + 1, mode="drop")
+    pid = jnp.maximum(jax.lax.cummax(marks) - 1, 0)
+    slot = jnp.arange(J, dtype=jnp.int32)
+    valid = slot < K
+    # the arc of slot s is off[y_p] + (s - start_p): one gather of the
+    # difference
+    eidx = jnp.clip((arcs.off[state.dz] - start)[pid] + slot,
+                    0, g.n_edges)
+    cx = jnp.where(valid, state.dx[pid], V)
+    cz = jnp.where(valid, arcs.dst[eidx], V)
+    return cx, cz, K, overflow
+
+
+def sparse_distinct(state: SparseState, cx, cz, g: SparseGeometry):
+    """The set and the candidates sorted as one run by (x, z, whose):
+    a candidate equal to the pair before it (the set's, which sorts
+    first, or an earlier copy of itself) is a duplicate, every other is
+    new. The one sort of ``capacity + join_capacity`` slots is the
+    round's only one: the merged set is the sorted run with a sentinel
+    in every duplicate's place, cut to ``capacity`` (the sentinels the
+    round before left have sorted to the end), and the new pairs alone
+    are brought to the front in order (``gops.compact_front``). Returns
+    the set, the new pairs, which slots of the run are held and which
+    are new, and whether a held pair fell past ``capacity`` (the set and
+    its candidates' copies together did not fit)."""
+    V, C, D = g.n_vertices, g.capacity, g.delta_capacity
+    ux = jnp.concatenate([state.sx, cx])
+    uk = jnp.concatenate([state.sz * 2, cz * 2 + 1])
+    ux, uk = jax.lax.sort((ux, uk), num_keys=2)
+    uz = uk >> 1
+    held = (ux < V) & ~_repeats(ux, uz)
+    new = held & ((uk & 1) == 1)
+    sx = jnp.where(held, ux, V)[:C]
+    sz = jnp.where(held, uz, V)[:C]
+    dx, dz = gops.compact_front(new, (ux, uz), (V, V), D)
+    return sx, sz, dx, dz, held, new, jnp.any(held[C:])
+
+
+@functools.lru_cache(maxsize=8)
+def make_sparse_round_fn(mesh: Mesh, geom: SparseGeometry):
+    """The compiled semi-naive round of the pair-set form: ``(state,
+    arcs) -> (state', count', still, stats)``, the state donated.
+
+    Round k joins only the pairs round k - 1 found new with the arcs
+    (δ ⋈ arc) and takes out what the set holds already, which is how a
+    Datalog engine evaluates ``tc(X,Y) <- tc(X,Z), arc(Z,Y).``. The
+    reference script (``transitive_closure.py:27-40``) is naive, it
+    re-joins every path every round; the set after every round (every
+    pair joined by a path of 1 .. k + 1 arcs, each once), its count and
+    the fixpoint are the same, because a path of k + 1 arcs extends one
+    of k arcs whose pair was new in round k - 1 or reached earlier by a
+    shorter path that has been extended already. ``count'`` is
+    ``ops/graph.path_count``'s two words; ``still`` says the round
+    found nothing new: the count stands, the fixpoint. ``stats`` are the
+    round's three counters as ``int32[3]`` (in this order: the
+    candidates joined, the pairs found new, whether a buffer has
+    overflowed), a value of their own that outlives the donated state.
+    An overflow of any of the three buffers sets ``state'.overflow`` for
+    good and never truncates quietly (:func:`check_sparse`)."""
+    D = geom.delta_capacity
+
+    def one_round(state: SparseState, arcs: Arcs):
+        with jax.named_scope(tnames.CLOSURE_JOIN):
+            cx, cz, K, over_join = sparse_join(state, arcs, geom)
+        with jax.named_scope(tnames.CLOSURE_DISTINCT):
+            sx, sz, dx, dz, held, new, over_set = sparse_distinct(
+                state, cx, cz, geom)
+        with jax.named_scope(tnames.CLOSURE_COUNT):
+            n = jnp.sum(held, dtype=jnp.int32)
+            nd = jnp.sum(new, dtype=jnp.int32)
+            overflow = state.overflow | over_join | over_set | (nd > D)
+            still = nd == 0
+            count = gops.path_count(n[None])
+            stats = jnp.stack([K, nd, overflow.astype(jnp.int32)])
+        return (SparseState(sx, sz, dx, dz, n, nd, overflow), count, still,
+                stats)
+
+    return jax.jit(one_round, donate_argnums=(0,))
+
+
+def make_sparse_start_fns(mesh: Mesh, geom: SparseGeometry):
+    """The three small compiled programs round the round that make a
+    job's start on the device, ``(seed, arcs_of, start)``:
+
+    ``seed(src, dst)`` lays an edge list of ``geom.n_edges`` arcs, in
+    any order, an arc perhaps given twice, into an otherwise empty set
+    with nothing new. One call of the compiled round on it (nothing to
+    join) sorts the arcs by source and holds each once: the program's
+    one sort serves the loader too, and no second one is compiled (XLA
+    compiles a sort of any length for a minute).
+    ``arcs_of(state)`` reads that set as the arcs by source: ``(Arcs,
+    src, dst, n)``, the degrees counted and the offsets summed there.
+    ``start(src, dst, n)`` is a job's start state from those sorted
+    arcs: the set and δ both hold them, paths of one arc."""
+    V, E = geom.n_vertices, geom.n_edges
+    C, D = geom.capacity, geom.delta_capacity
+
+    def pad(a, m):
+        return jnp.concatenate(
+            [a, jnp.full((m - a.shape[0],), V, jnp.int32)])
+
+    def seed(src, dst):
+        none = jnp.full((D,), V, jnp.int32)
+        empty = Arcs(jnp.zeros((V + 1,), jnp.int32),
+                     jnp.zeros((V + 1,), jnp.int32),
+                     jnp.full((E + 1,), V, jnp.int32))
+        return SparseState(pad(src, C), pad(dst, C), none, none,
+                           jnp.int32(E), jnp.int32(0),
+                           jnp.bool_(False)), empty
+
+    def arcs_of(state):
+        # every arc lies in the first E slots, a sentinel where one was
+        # given twice
+        src, dst = state.sx[:E], state.sz[:E]
+        src, dst = gops.compact_front(src < V, (src, dst), (V, V))
+        deg = jnp.zeros((V + 1,), jnp.int32).at[src].add(
+            (src < V).astype(jnp.int32), mode="drop",
+            indices_are_sorted=True)
+        return (Arcs(deg, jnp.cumsum(deg) - deg, pad(dst, E + 1)),
+                src, dst, state.n)
+
+    def start(src, dst, n):
+        return SparseState(pad(src, C), pad(dst, C), pad(src, D),
+                           pad(dst, D), n, n, jnp.bool_(False))
+
+    return jax.jit(seed), jax.jit(arcs_of), jax.jit(start)
+
+
+@dataclasses.dataclass
+class SparseJob:
+    """A pair-set closure job as ``closure:prepare`` leaves it: the
+    geometry, the compiled round, the arcs by source (as the round
+    reads them and as a sorted edge list), the compiled start and the
+    start state (donated to the first round)."""
+
+    geom: SparseGeometry
+    round_fn: object
+    arcs: Arcs
+    src: jax.Array
+    dst: jax.Array
+    n_arcs: jax.Array
+    start_fn: object
+    state: SparseState
+
+    def start(self) -> SparseState:
+        """The start state again, from the sorted arcs on the device."""
+        return self.start_fn(self.src, self.dst, self.n_arcs)
+
+
+def prepare_sparse(edges: np.ndarray, mesh: Mesh,
+                   n_vertices: int | None = None,
+                   config: SparseClosureConfig = SparseClosureConfig()
+                   ) -> SparseJob:
+    """``closure:prepare`` of the pair-set form: the edge list laid on
+    the device as it is given, sorted by source by the round's own sort
+    and counted there (:func:`make_sparse_start_fns`)."""
+    devices = list(mesh.local_devices)
+    with tevents.span("closure:prepare", devices):
+        edges = np.asarray(edges).reshape(-1, 2)
+        if n_vertices is None:
+            n_vertices = int(edges.max()) + 1 if len(edges) else 0
+        elif len(edges) and int(edges.max()) >= n_vertices:
+            raise ValueError(
+                f"n_vertices={n_vertices} but the edge list references "
+                f"vertex id {int(edges.max())}")
+        geom = sparse_geometry(n_vertices, len(edges), config)
+        tevents.emit("closure:sparse_plan", **dataclasses.asdict(geom),
+                     resident_bytes=geom.resident_bytes,
+                     working_bytes=geom.working_bytes)
+        round_fn = make_sparse_round_fn(mesh, geom)
+        seed, arcs_of, start_fn = make_sparse_start_fns(mesh, geom)
+        sorted_state = round_fn(*seed(
+            jnp.asarray(edges[:, 0], jnp.int32),
+            jnp.asarray(edges[:, 1], jnp.int32)))[0]
+        arcs, src, dst, n_arcs = arcs_of(sorted_state)
+        del sorted_state
+        state = start_fn(src, dst, n_arcs)
+        jax.block_until_ready(state)
+    return SparseJob(geom, round_fn, arcs, src, dst, n_arcs, start_fn,
+                     state)
+
+
+def check_sparse(state: SparseState, geom: SparseGeometry) -> None:
+    """Raises where a round overflowed a buffer (one flag read)."""
+    if bool(state.overflow):
+        raise ValueError(
+            f"closure overflowed its buffers (capacity {geom.capacity}, "
+            f"delta_capacity {geom.delta_capacity}, join_capacity "
+            f"{geom.join_capacity}); rerun with a larger "
+            f"SparseClosureConfig.capacity/delta_capacity/join_capacity")
 
 
 def run_sparse(edges: np.ndarray, mesh: Mesh,
                config: SparseClosureConfig = SparseClosureConfig(),
                n_vertices: int | None = None, *,
                checkpoint_dir: str | None = None,
-               checkpoint_every: int = 8) -> SparseClosureResult:
+               checkpoint_every: int = 8,
+               keep_paths: bool = True) -> SparseClosureResult:
     """Transitive closure without the V×V matrix — O(closure size) memory.
 
     The dense fixpoint (:func:`run`) is the right shape for small/dense
     graphs (boolean matmul rides the MXU) but its V×V path matrix is dead
-    at ~100k+ vertices (SURVEY.md §2.2 names the alternative: "sort-based
-    dedup for sparse"). Here the path set is what Spark's RDD was — a set
-    of (x, z) pairs — mapped to static shapes:
+    at ~100k+ vertices. Here the path set is what Spark's RDD was — a set
+    of (x, z) pairs — in static shapes: a capacity-capped pair buffer
+    kept sorted, and the loop over :func:`make_sparse_round_fn`'s round
+    (δ ⋈ arc by a segmented expand, one sort of set and candidates, the
+    new pairs and the merged set brought to the front) until a round
+    finds nothing new: the reference's count-based convergence
+    (``:38-40``). The host reads the round's three counters, one small
+    array a round.
 
-      * a capacity-capped ``(C,)`` pair buffer, valid entries sorted
-        first, sentinel (V, V) padding sorting last;
-      * one round ≙ the reference's ``join`` + ``union().distinct()``
-        (``transitive_closure.py:33-37``): a CSR segmented-expand joins
-        every path (x, y) with y's out-edges — per-path counts →
-        prefix-sum → scatter-max path markers → ``cummax`` recovers the
-        owning path of each candidate slot, so the round's work is
-        proportional to the TRUE join size (no per-vertex degree
-        padding; skewed graphs cost nothing extra) — then concatenate
-        with the known set (union), two-key ``lax.sort`` +
-        neighbor-diff mask (distinct), and one more sort to compact
-        uniques back into the buffer;
-      * fixpoint when ``count`` stops growing — the reference's
-        count-based convergence (``:38-40``), inside ``lax.while_loop``.
+    The pair buffer stays on one device: the sort is global, as Spark's
+    shuffle was, and memory is O(closure), not O(V²).
 
-    Like the reference it re-joins the FULL path set each round (naïve,
-    not frontier/semi-naïve — same asymptotics as the original). The
-    sort-dedup is the shuffle equivalent and runs as one global XLA sort.
-
-    Raises if ``capacity`` or ``join_capacity`` overflow (closure or
-    one round's join bigger than its buffer).
+    Raises if a buffer overflows (closure, one round's new pairs or its
+    join bigger than its capacity). ``keep_paths=False`` leaves the
+    pairs on the device and returns their count alone (a closure of
+    2.4e8 pairs is 1.9 GB on the host).
     """
-    el = gops.prepare_edges(edges, n_vertices)
-    V = el.n_vertices
-    E = el.n_edges
-    n_shards = mesh.shape[DATA_AXIS]
-    C = (config.capacity if config.capacity is not None
-         else max(8 * E, 1024))
-    C = -(-C // n_shards) * n_shards
-    J = (config.join_capacity if config.join_capacity is not None
-         else max(2 * C, 8 * E, 1024))
+    job = prepare_sparse(edges, mesh, n_vertices, config)
+    geom, arcs, state = job.geom, job.arcs, job.state
+    job.state = None                    # the first round donates it
     cap = (config.max_iterations if config.max_iterations is not None
-           else V + 1)
+           else geom.n_vertices + 1)
+    round_fn = job.round_fn
 
-    from tpu_distalg import native
+    def rounds(state, still, it, seg):
+        # up to ``seg`` more rounds from the carried state; a round past
+        # the fixpoint (or an overflow) is never run, so segments of any
+        # length run the same sequence of rounds, bit for bit
+        it_hi = min(it + seg, cap)
+        while it < it_hi and not still:
+            state, _, _, stats = round_fn(state, arcs)
+            joined, found, overflowed = (int(x) for x in np.asarray(stats))
+            still, it = found == 0, it + 1
+            tevents.counter("closure.sparse.candidates", joined)
+            tevents.counter("closure.sparse.new_pairs", found)
+            if overflowed:
+                tevents.counter("closure.sparse.overflow")
+                break
+        return state, still, it
 
-    if E > C:
-        raise ValueError(f"capacity {C} < edge count {E}")
-    # CSR over src (prepare_edges sorts by src); sentinel vertex V has
-    # degree 0 so expanding an invalid path yields nothing
-    offsets = np.zeros(V + 2, dtype=np.int64)
-    if E:
-        offsets[: V + 1] = native.csr_offsets(el.src.astype(np.int64), V)
-        offsets[V + 1] = offsets[V]
-    deg = np.diff(offsets).astype(np.int32)          # (V+1,)
-    px0 = np.full(C, V, dtype=np.int32)
-    pz0 = np.full(C, V, dtype=np.int32)
-    px0[:E] = el.src
-    pz0[:E] = el.dst
+    with tevents.span("closure:fit", list(mesh.local_devices),
+                      closure_form="sparse", vertices=geom.n_vertices,
+                      capacity=geom.capacity,
+                      delta_capacity=geom.delta_capacity,
+                      join_capacity=geom.join_capacity,
+                      resident_bytes=geom.resident_bytes):
+        if checkpoint_dir is None:
+            state, _, n_rounds = rounds(state, False, 0, cap)
+        else:
+            from tpu_distalg.utils import checkpoint as ckpt
 
-    # the path buffer stays REPLICATED: the sort-dedup is inherently
-    # global, and XLA's partitioned sort on a row-sharded buffer (tested
-    # on the 8-device CPU mesh) is orders of magnitude slower than one
-    # local sort — the shuffle this replaces was Spark's global shuffle
-    # too. Memory is O(closure), not O(V²), so replication is cheap.
-    px0 = jnp.asarray(px0)
-    pz0 = jnp.asarray(pz0)
-    deg_d = jnp.asarray(deg)
-    off_d = jnp.asarray(offsets[: V + 1].astype(np.int32))
-    dst_d = jnp.asarray(el.dst)                      # src-sorted
+            def state_of(saved):
+                return SparseState(**{k: jnp.asarray(saved[k])
+                                      for k in SparseState._fields})
 
-    def make_seg_fn(seg):
-        # one compiled segment of up to ``seg`` more rounds from the
-        # carried fixpoint state; seg=cap is the straight run, smaller
-        # seg adds checkpoint boundaries (bitwise-identical rounds)
-        @jax.jit
-        def fixpoint(px, pz, old_cnt0, cnt0, it0, overflow0,
-                     deg, off, dst):
-            it_hi = jnp.minimum(it0 + seg, cap)
+            def run_seg(seg, saved, t0):
+                state, still, it = rounds(
+                    state_of(saved), bool(saved["still"]),
+                    int(saved["it"]), seg)
+                new = dict(state._asdict(), still=np.bool_(still),
+                           it=np.int32(it))
+                return new, np.asarray(state.n, np.float32)[None]
 
-            def count_valid(x):
-                return jnp.sum((x < V).astype(jnp.int32))
-
-            def cond(state):
-                _, _, old_cnt, cnt, it, overflow = state
-                # ~overflow: fail fast — once a round overflows its
-                # buffers the result can never be trusted, so don't pay
-                # the remaining rounds
-                return (cnt != old_cnt) & (it < it_hi) & ~overflow
-
-            def body(state):
-                px, pz, _, cnt, it, overflow = state
-                # join (x,y) ⋈ edges(y,·) via segmented expand: path p owns
-                # candidate slots [start_p, start_p + deg(pz_p))
-                k = deg[pz]                              # (C,)
-                start = jnp.cumsum(k) - k                # exclusive prefix
-                K = start[-1] + k[-1]                    # true join size
-                # K is int32 and can wrap when the true join exceeds 2^31. The
-                # exact K > J test catches every non-wrapping overflow; K < 0
-                # catches true sizes in (2^31, 2^32); the f32 sum catches
-                # >= 2^32 wrap-to-positive. Kf is compared against 2^31 (not J)
-                # because the tree-reduction rounding of the f32 sum could
-                # otherwise spuriously trip on a valid round with K ~ J.
-                Kf = jnp.sum(k.astype(jnp.float32))
-                overflow = (overflow | (K > J) | (K < 0)
-                            | (Kf > jnp.float32(2**31)))
-                # mark slot start_p with p+1 (k>0 paths only), cummax fills
-                # the segment; -1 → owning path id
-                marks = jnp.zeros((J,), jnp.int32).at[
-                    jnp.where(k > 0, start, J)
-                ].max(jnp.arange(C, dtype=jnp.int32) + 1, mode="drop")
-                pid = jax.lax.cummax(marks) - 1          # (J,)
-                slot = jnp.arange(J, dtype=jnp.int32)
-                valid = (slot < K) & (pid >= 0)
-                pid = jnp.where(valid, pid, 0)
-                rank = slot - start[pid]
-                eidx = jnp.clip(off[pz[pid]] + rank, 0, max(E - 1, 0))
-                cx = jnp.where(valid, px[pid], V)
-                cz = jnp.where(valid, dst[eidx], V) if E else jnp.full(
-                    (J,), V, jnp.int32)
-                ax = jnp.concatenate([px, cx])           # union
-                az = jnp.concatenate([pz, cz])
-                ax, az = jax.lax.sort((ax, az), num_keys=2)
-                dup = jnp.concatenate([
-                    jnp.zeros((1,), bool),
-                    (ax[1:] == ax[:-1]) & (az[1:] == az[:-1]),
-                ])
-                uniq = (ax < V) & ~dup                   # distinct
-                ax = jnp.where(uniq, ax, V)
-                az = jnp.where(uniq, az, V)
-                ax, az = jax.lax.sort((ax, az), num_keys=2)  # compact
-                new_cnt = count_valid(ax)
-                overflow = overflow | (new_cnt > C)
-                return (ax[:C], az[:C], cnt, jnp.minimum(new_cnt, C),
-                        it + 1, overflow)
-
-            return jax.lax.while_loop(
-                cond, body,
-                (px, pz, old_cnt0, cnt0, it0, overflow0),
-            )
-
-        return fixpoint
-
-    cnt0 = jnp.int32(E)  # every buffer entry < V is a real edge
-    state0 = (px0, pz0, jnp.int32(-1), cnt0, jnp.int32(0),
-              jnp.bool_(False))
-
-    if checkpoint_dir is None:
-        px, pz, _, cnt, rounds, overflow = make_seg_fn(cap)(
-            *state0, deg_d, off_d, dst_d)
-    else:
-        from tpu_distalg.utils import checkpoint as ckpt
-
-        def run_seg(fn, state, t0):
-            px, pz, old, cnt, it, ov = fn(
-                state["px"], state["pz"], state["old"], state["cnt"],
-                state["it"], state["ov"], deg_d, off_d, dst_d)
-            new = {"px": px, "pz": pz, "old": old, "cnt": cnt,
-                   "it": it, "ov": ov}
-            return new, np.asarray(cnt, np.float32)[None]
-
-        state, _, _ = ckpt.run_segmented(
-            checkpoint_dir, checkpoint_every, cap, make_seg_fn,
-            run_seg,
-            {"px": state0[0], "pz": state0[1], "old": state0[2],
-             "cnt": state0[3], "it": state0[4], "ov": state0[5]},
-            tag="closure_sparse",
-            stop_when=lambda s: (bool(s["ov"])
-                                 or int(s["cnt"]) == int(s["old"])))
-        px, pz = jnp.asarray(state["px"]), jnp.asarray(state["pz"])
-        cnt, rounds, overflow = state["cnt"], state["it"], state["ov"]
-
-    n_paths = int(cnt)
-    if bool(overflow):
-        raise ValueError(
-            f"closure overflowed its buffers (capacity {C}, "
-            f"join_capacity {J}); rerun with a larger "
-            f"SparseClosureConfig.capacity/join_capacity"
-        )
-    pairs = np.stack(
-        [np.asarray(px[:n_paths]), np.asarray(pz[:n_paths])], axis=1
-    )
-    return SparseClosureResult(
-        paths=pairs, n_paths=n_paths, n_rounds=int(rounds)
-    )
+            saved, _, _ = ckpt.run_segmented(
+                checkpoint_dir, checkpoint_every, cap, lambda seg: seg,
+                run_seg,
+                dict(state._asdict(), still=np.bool_(False),
+                     it=np.int32(0)),
+                tag="closure_sparse",
+                stop_when=lambda s: bool(s["still"]) or bool(s["overflow"]))
+            state, n_rounds = state_of(saved), int(saved["it"])
+    check_sparse(state, geom)
+    n_paths = int(state.n)
+    tevents.counter("closure.rounds", n_rounds)
+    tevents.counter("closure.pairs", n_paths)
+    pairs = None
+    if keep_paths:
+        sx, sz = np.asarray(state.sx), np.asarray(state.sz)
+        pairs = np.stack([sx, sz], axis=1)[sx < geom.n_vertices]
+    return SparseClosureResult(paths=pairs, n_paths=n_paths,
+                               n_rounds=n_rounds)
 
 
 #: the bytes a closure may plan with where nobody says otherwise:
@@ -475,10 +683,16 @@ def run_sparse(edges: np.ndarray, mesh: Mesh,
 #: devices keep no memory statistics (the CPU)
 DEFAULT_BUDGET_BYTES = 4 << 30
 
-#: per-path buffer cost of one :func:`run_sparse` fixpoint round:
-#: px/pz (2 int32) plus the two-key sort's union copy at C + J slots
-#: (J defaults to 2C) — ~8 B/slot across ~4C live slots. The auto-
-#: sizer budgets against THIS figure, so its refusal names real bytes.
+#: bytes a slot that the pair-set round sorts (``capacity +
+#: join_capacity`` of them): the carried set and δ, the sorted run of
+#: two int32 a slot beside its unsorted copy, the compaction's distance
+#: word and its shifted copy. XLA plans 7.31 GB for Tree17's round (2.45
+#: GB of arguments, 4.87 GB of temporaries over 2^28 + 2^24 slots: 25.6 B
+#: a slot, ``benchmarks/tools/compile_check_closure_sparse.py``); 32
+#: leaves a quarter over. :func:`choose_form` counts it once a pair of
+#: the answer (a caller who knows the answer sizes the candidates small
+#: beside it), :func:`run_sparse_auto` a slot of the default geometry,
+#: so both name real bytes.
 SPARSE_BYTES_PER_CAPACITY_SLOT = 32
 
 def choose_form(n_vertices: int, n_edges: int, mesh: Mesh, *,
@@ -489,13 +703,14 @@ def choose_form(n_vertices: int, n_edges: int, mesh: Mesh, *,
     ``closure.form`` counts the decisions).
 
     The dense form holds two V × V byte matrices (the one a round reads,
-    the one it writes) whatever the answer; the sparse form's buffer must
+    the one it writes) whatever the answer; the sparse form's set must
     hold every pair of the answer at ``SPARSE_BYTES_PER_CAPACITY_SLOT``
-    (``run_sparse_auto``'s rule), which nobody knows beforehand:
+    (the round's sort beside it), which nobody knows beforehand:
     ``pairs_bound`` is what the caller can say (a generator's closed
     form), V^2 otherwise. The smaller of the two that fits the budget
     (three quarters of the mesh's device memory) runs; neither: raises.
-    BigDatalog's Grid250: 8.06 GB dense against 32.0 GB sparse."""
+    BigDatalog's Grid250: 8.06 GB dense against 32.0 GB sparse; its
+    Tree17: 379 TB dense against 7.6 GB sparse."""
     geom = dense_geometry(n_vertices, mesh)
     dense = 2 * geom.matrix_bytes
     pairs = n_vertices * n_vertices
@@ -539,7 +754,8 @@ def run_sparse_auto(edges: np.ndarray, mesh: Mesh, *,
     the doubling schedule bounds total work at ≤ 2× the final run.
 
     The DOCUMENTED REFUSAL: a capacity whose working set
-    (``capacity × SPARSE_BYTES_PER_CAPACITY_SLOT``) would exceed
+    (``SPARSE_BYTES_PER_CAPACITY_SLOT`` a slot of the set and of the
+    candidates, twice as many) would exceed
     ``budget_bytes`` raises ``ValueError`` naming the budget, the
     capacity it refused, and the remedy (a bigger ``budget_bytes`` or
     the dense path) — it never silently truncates a closure.
@@ -561,10 +777,13 @@ def run_sparse_auto(edges: np.ndarray, mesh: Mesh, *,
     # growth starting point, not a hard error
     cap = max(cap, E)
     while True:
-        if cap * SPARSE_BYTES_PER_CAPACITY_SLOT > budget_bytes:
+        # the default geometry: the set and twice as many candidates
+        working = sparse_geometry(
+            0, E, SparseClosureConfig(capacity=cap)).working_bytes
+        if working > budget_bytes:
             raise ValueError(
                 f"sparse closure refused: capacity {cap} needs "
-                f"~{cap * SPARSE_BYTES_PER_CAPACITY_SLOT / 1e9:.1f} GB "
+                f"~{working / 1e9:.1f} GB "
                 f"working set, over the {budget_bytes / 1e9:.1f} GB "
                 f"budget — the closure is larger than the budget "
                 f"allows; raise budget_bytes, or use the dense path "

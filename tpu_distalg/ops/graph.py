@@ -179,6 +179,48 @@ def path_count(partials: jax.Array) -> jax.Array:
     return jnp.stack([hi, ((mid & 0xFF) << 8) | (lo & 0xFF)])
 
 
+def compact_sources(keep: jax.Array, out_len: int | None = None,
+                    max_shift: int | None = None) -> jax.Array:
+    """Where each slot of the front comes from when the kept elements of
+    a 1-D buffer are moved there in order: ``int32[out_len]``, the index
+    of the ``q``-th kept element at ``q`` and -1 past the last. No sort,
+    no scatter: an element moves left by the number of dropped ones
+    before it, one bit of that distance a pass, lowest bit first (a
+    shifted select over one int32 a slot, the distance itself, -1 where
+    nothing is held); two kept elements never meet, because after the
+    passes for bits 0 .. t element ``i`` sits at ``i - (d_i mod
+    2^(t+1))`` and for kept ``i < j`` the distances differ by at most
+    ``j - i - 1``. The distance that arrives at ``q`` names its source,
+    ``q + d``. ``max_shift`` bounds the distance where the caller knows
+    one (fewer passes)."""
+    n = keep.shape[0]
+    top = n - 1 if max_shift is None else min(max_shift, n - 1)
+    drop = (~keep).astype(jnp.int32)
+    dist = jnp.where(keep, jnp.cumsum(drop) - drop, -1)
+    for t in range(max(top, 0).bit_length()):
+        # dist[i + 2^t] at i: one pad with a negative low edge, which
+        # XLA fuses into the select that reads it
+        come = jax.lax.pad(dist, jnp.int32(-1), [(-(1 << t), 1 << t, 0)])
+        comes = (come >= 0) & (((come >> t) & 1) == 1)
+        stays = (dist >= 0) & (((dist >> t) & 1) == 0)
+        dist = jnp.where(comes, come, jnp.where(stays, dist, -1))
+    dist = dist[:out_len]
+    return jnp.where(dist >= 0,
+                     dist + jnp.arange(dist.shape[0], dtype=jnp.int32), -1)
+
+
+def compact_front(keep: jax.Array, cols, fills, out_len: int | None = None,
+                  max_shift: int | None = None):
+    """The kept elements of equal-length 1-D ``cols`` at the front of
+    ``out_len`` slots, their order preserved, ``fills`` after them: the
+    ``filter`` of a static-shape buffer. The sources by
+    :func:`compact_sources` (passes over one word a slot of the whole
+    buffer), then one gather of ``out_len`` a column."""
+    src = compact_sources(keep, out_len, max_shift)
+    return [jnp.where(src >= 0, c[jnp.maximum(src, 0)], f)
+            for c, f in zip(cols, fills)]
+
+
 def count_of(words) -> int:
     """The Python integer of :func:`path_count`'s words."""
     hi, lo = (int(w) for w in np.asarray(words))

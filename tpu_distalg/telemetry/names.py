@@ -80,3 +80,15 @@ CLOSURE_COMPOSE = "tda.closure.compose"  # the boolean product or-ed into
 #                                          product and the rows' sums
 CLOSURE_COUNT = "tda.closure.count"      # the partials' sum in two words
 #                                          and the fixpoint test
+# the two other parts of a pair-set closure round (the same module's
+# make_sparse_round_fn, which counts under CLOSURE_COUNT too); the
+# benchmark's join_ms_per_round.closure reads the first,
+# distinct_ms_per_round.closure the second, closure_sparse_roofline both
+CLOSURE_JOIN = "tda.closure.join"          # delta joined with the arcs:
+#                                            the segmented expand and its
+#                                            gathers
+CLOSURE_DISTINCT = "tda.closure.distinct"  # the one sort of set and
+#                                            candidates, the duplicates
+#                                            marked, the merged set and
+#                                            the new pairs brought to
+#                                            the front

@@ -262,6 +262,13 @@ def summarize(evts: list[dict]) -> dict:
                         f"pair buffer of {e.get('sparse_bytes', 0) / 1e9:.3f}"
                         f" GB, budget {e.get('budget_bytes', 0) / 1e9:.3f} "
                         f"GB")
+            elif e["closure_form"] == "sparse":
+                form = (f"sparse, a set of {e.get('capacity', '?')} pairs "
+                        f"(a round's new {e.get('delta_capacity', '?')}, "
+                        f"its candidates {e.get('join_capacity', '?')}) "
+                        f"over {e.get('vertices', '?')} vertices, "
+                        f"{e.get('resident_bytes', 0) / 1e9:.3f} GB "
+                        f"carried")
             else:
                 form = (f"{e['closure_form']}, compose "
                         f"{e.get('compose_form', '?')} over "
@@ -621,7 +628,12 @@ def render(s: dict) -> str:
         lines.append(
             f"closure: {'; '.join(s['closure_forms'])}; "
             f"{c.get('closure.rounds', '?')} round(s), "
-            f"{c.get('closure.pairs', '?')} pairs")
+            f"{c.get('closure.pairs', '?')} pairs"
+            + (f" ({c['closure.sparse.candidates']} candidates joined, "
+               f"{c.get('closure.sparse.new_pairs', 0)} found new"
+               + (", A BUFFER OVERFLOWED"
+                  if c.get("closure.sparse.overflow") else "") + ")"
+               if "closure.sparse.candidates" in c else ""))
     if s.get("dist_forms"):
         lines.append(f"distances: {', '.join(s['dist_forms'])}")
     if s.get("sums_forms"):

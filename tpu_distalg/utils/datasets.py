@@ -806,6 +806,86 @@ def grid_closure_pairs(side: int) -> int:
     return (n * (n + 1) // 2) ** 2 - n * n
 
 
+#: the ratio of one level's vertices to the level above's in
+#: :func:`tree_level_sizes`, and the two deepest levels of Tree17, which
+#: make the vertices and the pairs total the published row
+TREE_LEVEL_RATIO = 2.422
+TREE17_DEEPEST = (3_370_020, 8_008_694)
+TREE_CHILDREN = (2, 6)
+
+
+def tree_level_sizes(height: int) -> list[int]:
+    """The vertices of each level of BigDatalog's ``Tree<height>``
+    (Shkapsky et al., SIGMOD'16, Table 2: "a tree of height ``height``
+    whose non-leaf vertices have a random number of children"), the
+    root's level first. The paper gives no generator; the published
+    Tree17 row (13 766 856 vertices, closure 237 977 708 pairs, 17.29 a
+    vertex: the mean depth) binds it: the deepest level lies 18 arcs
+    under the root, so ``height + 2`` levels (depths 0 .. height + 1),
+    and a geometric profile, level l holding ``round(2.422^l)``
+    vertices, comes within half a percent of both totals; at height 17
+    the two deepest levels are set so that both are met exactly."""
+    if height < 0:
+        raise ValueError(f"tree height {height} < 0")
+    sizes = [round(TREE_LEVEL_RATIO ** lvl) for lvl in range(height + 2)]
+    if height == 17:
+        sizes[-2:] = TREE17_DEEPEST
+    return sizes
+
+
+def tree_nonleaves(n_level: int, n_below: int) -> int:
+    """How many of a level's ``n_level`` vertices have children, given
+    the ``n_below`` of the level under it: a quarter of the children
+    (four a parent, the middle of 2 .. 6), held to what 2 .. 6 children
+    a parent allow."""
+    lo, hi = TREE_CHILDREN
+    return max(-(-n_below // hi), min(round(n_below / 4), n_level,
+                                      n_below // lo))
+
+
+def tree_edges(height: int, seed: int | None = None) -> np.ndarray:
+    """``Tree<height>`` as arcs from parent to child, shape (V - 1, 2):
+    :func:`tree_level_sizes`' levels on every seed; which vertices of a
+    level have children, how many each has (2 .. 6: two each, the rest
+    dealt to four more places a parent by a permutation) and the labels
+    (permuted over all vertices, as :func:`grid_edges`' are) drawn from
+    ``seed``. ``None``: the first vertices of a level are the parents,
+    the counts as even as they come, labels level by level."""
+    sizes = tree_level_sizes(height)
+    starts = np.concatenate([[0], np.cumsum(sizes)])
+    rng = None if seed is None else np.random.default_rng(int(seed))
+    lo, hi = TREE_CHILDREN
+    src = []
+    for lvl in range(len(sizes) - 1):
+        n, below = sizes[lvl], sizes[lvl + 1]
+        p = tree_nonleaves(n, below)
+        extra, places = below - lo * p, (hi - lo) * p
+        if not 0 <= extra <= places:
+            raise ValueError(f"level {lvl + 1}: {below} children do not "
+                             f"go to {p} parents at {lo} .. {hi} each")
+        if rng is None:
+            parents, taken = np.arange(p), np.arange(extra) % p
+        else:
+            parents = rng.permutation(n)[:p]
+            taken = rng.permutation(places)[:extra] // (hi - lo)
+        counts = lo + np.bincount(taken, minlength=p)
+        src.append(starts[lvl] + np.repeat(parents, counts))
+    src = np.concatenate(src) if src else np.zeros((0,), np.int64)
+    if rng is None:
+        return np.stack([src, np.arange(1, starts[-1])], axis=1)
+    labels = rng.permutation(int(starts[-1]))
+    return np.stack([labels[src], labels[1:]], axis=1)
+
+
+def tree_closure_pairs(height: int, max_arcs: int | None = None) -> int:
+    """The pairs of :func:`tree_edges`' transitive closure, or of its
+    paths of at most ``max_arcs`` arcs: a vertex is reached from each of
+    its ancestors, so the sum of the depths (each cut at ``max_arcs``);
+    Tree17: the source's published 237 977 708."""
+    return sum(n * (lvl if max_arcs is None else min(lvl, max_arcs))
+               for lvl, n in enumerate(tree_level_sizes(height)))
+
+
 def toy_graph_edges() -> np.ndarray:
     """The reference's 4-edge toy graph (``pagerank.py:35-38``,
     ``transitive_closure.py:18``), 0-indexed."""
